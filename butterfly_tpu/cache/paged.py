@@ -57,6 +57,7 @@ from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
 from butterfly_tpu.models.common import (
     _cast_float, attend, attn_output, embed_tokens, ffn_block,
     final_logits, make_mask, pre_norm, qkv_proj, quantize_kv)
+from butterfly_tpu.ops import note_kernel
 from butterfly_tpu.ops.flash_attention import flash_attention_sharded
 from butterfly_tpu.ops.paged_attention import paged_attention_sharded
 
@@ -95,35 +96,45 @@ class PagedKVCache(NamedTuple):
 
 
 def init_paged_cache(cfg: ModelConfig, runtime: RuntimeConfig,
-                     dtype: Optional[jnp.dtype] = None) -> PagedKVCache:
+                     dtype: Optional[jnp.dtype] = None,
+                     shardings: Optional[PagedKVCache] = None
+                     ) -> PagedKVCache:
     """Pool sized from the runtime config (+1 reserved null page).
 
     runtime.kv_quant="int8" allocates int8 code pools + f32 scale pools
-    (the serving-path twin of models.common.init_cache(quant="int8"))."""
+    (the serving-path twin of models.common.init_cache(quant="int8")).
+    `shardings` (a PagedKVCache of NamedShardings, from
+    parallel/partition.py paged_cache_specs) allocates every leaf in its
+    mesh layout — the whole pool never sits on one device."""
     dtype = dtype or jnp.dtype(cfg.dtype)
     page = runtime.page_size
     max_pages = -(-runtime.max_seq_len // page)
     P = runtime.num_pages or runtime.max_batch_size * max_pages
     P += 1  # null page
     shape = (cfg.num_layers, P, cfg.num_kv_heads, page, cfg.head_dim)
-    table = jnp.full((runtime.max_batch_size, max_pages), P - 1, jnp.int32)
-    lengths = jnp.zeros((runtime.max_batch_size,), jnp.int32)
-    if runtime.kv_quant == "int8":
-        sshape = (cfg.num_layers, P, cfg.num_kv_heads * page)
-        return PagedKVCache(
-            k_pages=jnp.zeros(shape, jnp.int8),
-            v_pages=jnp.zeros(shape, jnp.int8),
-            page_table=table, lengths=lengths,
-            k_scale_pages=jnp.zeros(sshape, jnp.float32),
-            v_scale_pages=jnp.zeros(sshape, jnp.float32),
-        )
-    if runtime.kv_quant != "none":
+    if runtime.kv_quant not in ("none", "int8"):
         raise ValueError(f"unknown kv quant {runtime.kv_quant!r}")
-    return PagedKVCache(
-        k_pages=jnp.zeros(shape, dtype),
-        v_pages=jnp.zeros(shape, dtype),
-        page_table=table, lengths=lengths,
-    )
+
+    def build():
+        table = jnp.full((runtime.max_batch_size, max_pages), P - 1,
+                         jnp.int32)
+        lengths = jnp.zeros((runtime.max_batch_size,), jnp.int32)
+        if runtime.kv_quant == "int8":
+            sshape = (cfg.num_layers, P, cfg.num_kv_heads * page)
+            return PagedKVCache(
+                k_pages=jnp.zeros(shape, jnp.int8),
+                v_pages=jnp.zeros(shape, jnp.int8),
+                page_table=table, lengths=lengths,
+                k_scale_pages=jnp.zeros(sshape, jnp.float32),
+                v_scale_pages=jnp.zeros(sshape, jnp.float32),
+            )
+        return PagedKVCache(
+            k_pages=jnp.zeros(shape, dtype),
+            v_pages=jnp.zeros(shape, dtype),
+            page_table=table, lengths=lengths,
+        )
+
+    return jax.jit(build, out_shardings=shardings)()
 
 
 def write_paged_layer(k_pages: jax.Array, v_pages: jax.Array,
@@ -219,8 +230,7 @@ def gather_paged_layer_q(pages: jax.Array, scale_pages: jax.Array,
 # and because the pool rides the block scan's carry, XLA cannot alias the
 # scatter in place: each step pays a pool-sized copy per pool tensor (the
 # same term models/common.py's fused-generate window retired for the
-# contiguous cache; BENCH_r05's 8x serving-vs-engine gap names it for the
-# serving path). With kv_write_combine the pool is READ-ONLY inside the
+# contiguous cache). With kv_write_combine the pool is READ-ONLY inside the
 # block: fresh K/V stages into a small per-slot window [L, S, Kv, W, H]
 # riding the scan carry, attention reads pool + window, and the window
 # flushes into the pool with ONE scatter per pool tensor per drain.
@@ -267,20 +277,27 @@ class KVWindow(NamedTuple):
         return self.k_scale is not None
 
 
-def init_kv_window(cache: PagedKVCache, width: int) -> KVWindow:
+def init_kv_window(cache: PagedKVCache, width: int,
+                   shardings: Optional[KVWindow] = None) -> KVWindow:
     """Allocate a window sized to `width` staged tokens per slot, in the
-    pool's representation."""
+    pool's representation (and, given `shardings` from
+    parallel/partition.py kv_window_specs, in its mesh layout)."""
     L, _, Kv, _, H = cache.k_pages.shape
     S = cache.num_slots
     shape = (L, S, Kv, width, H)
-    if cache.quantized:
-        return KVWindow(
-            k=jnp.zeros(shape, jnp.int8),
-            v=jnp.zeros(shape, jnp.int8),
-            k_scale=jnp.zeros(shape[:-1], jnp.float32),
-            v_scale=jnp.zeros(shape[:-1], jnp.float32))
-    return KVWindow(k=jnp.zeros(shape, cache.k_pages.dtype),
-                    v=jnp.zeros(shape, cache.v_pages.dtype))
+    quantized, dtype = cache.quantized, cache.k_pages.dtype
+
+    def build():
+        if quantized:
+            return KVWindow(
+                k=jnp.zeros(shape, jnp.int8),
+                v=jnp.zeros(shape, jnp.int8),
+                k_scale=jnp.zeros(shape[:-1], jnp.float32),
+                v_scale=jnp.zeros(shape[:-1], jnp.float32))
+        return KVWindow(k=jnp.zeros(shape, dtype),
+                        v=jnp.zeros(shape, dtype))
+
+    return jax.jit(build, out_shardings=shardings)()
 
 
 def stage_window_layer(wk, wv, k, v, win_len, wks=None, wvs=None):
@@ -519,6 +536,7 @@ def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
         kp, vp, ksp, vsp = write_paged_layer(kp, vp, page_table, k, v,
                                              start, active, ksp, vsp)
     out = None
+    tried_kernel = True
     if use_kernel and T == 1:
         if win is not None:
             # pool-valid lengths are the FLUSHED base; the staged run
@@ -573,9 +591,15 @@ def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
             out = flash_attention_sharded(q, k, v, causal=True,
                                           prefix_k=ckg, prefix_v=cvg,
                                           prefix_len=base)
+    else:
+        tried_kernel = False
     if out is None:
         # no mesh axis can shard the kernel operands (or kernels off):
-        # dense gather attention, which GSPMD partitions itself.
+        # dense gather attention, which GSPMD partitions itself. A call
+        # site that wanted a kernel says so (ops.record_kernels): on a
+        # mesh that should shard it this is a fault, not a choice.
+        if tried_kernel:
+            note_kernel("dense_fallback")
         if quant:
             ck, k_s = gather_paged_layer_q(kp, ksp, page_table)
             cv, v_s = gather_paged_layer_q(vp, vsp, page_table)

@@ -8,6 +8,7 @@ every layer), `data` outermost (least traffic, may cross DCN).
 """
 from __future__ import annotations
 
+import contextlib
 import os
 from typing import Optional, Sequence
 
@@ -120,6 +121,21 @@ def init_distributed(coordinator: Optional[str] = None,
         num_processes=num_processes,
         process_id=process_id,
     )
+
+
+def device_report() -> dict:
+    """The devices as JAX reports them: what every entry point prints
+    so that a result names what it ran on."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
+def mesh_ctx(mesh: Optional[Mesh]):
+    """`with` context making `mesh` the ambient mesh for jit dispatch
+    and for the mesh-aware call sites that read it while tracing (kernel
+    wrappers, EP dispatch); a no-op for an unmeshed engine (None)."""
+    return contextlib.nullcontext() if mesh is None else jax.set_mesh(mesh)
 
 
 def named_sharding(mesh: Mesh, *spec) -> NamedSharding:

@@ -16,6 +16,7 @@ Realizes the reference's planned "Distributed Inference Engine"
 """
 from __future__ import annotations
 
+import contextlib
 from collections import deque
 from dataclasses import dataclass
 from functools import partial
@@ -26,6 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
+from butterfly_tpu.core.mesh import mesh_ctx
+from butterfly_tpu.ops import kernel_mode, kernels_default, record_kernels
 from butterfly_tpu.engine.sampling import SamplingParams, sample
 from butterfly_tpu.models.common import KVCache, Model, forward, init_cache
 
@@ -120,10 +123,14 @@ class InferenceEngine:
         elif S <= 1:
             virtual_stages = 1  # no stage axis: schedule knob is moot
         if use_flash_prefill is None:
-            # Pallas kernels are TPU-only; under a mesh the call sites go
-            # through ops/*_sharded (shard_map over data/tensor), so a
-            # mesh no longer disables them.
-            use_flash_prefill = jax.default_backend() == "tpu"
+            # on everywhere but the CPU backend (ops/__init__.py); under
+            # a mesh the call sites go through ops/*_sharded (shard_map
+            # over data/tensor), so a mesh does not disable them
+            use_flash_prefill = kernels_default()
+        self.kernel_mode = kernel_mode(use_flash_prefill)
+        # kernel call sites traced by this engine's programs
+        # (ops.record_kernels); `generate` prints it
+        self.kernel_calls: dict = {}
 
         # One forward callable per step kind: the plain single-program
         # forward, or the GPipe pipeline when the mesh has stage > 1.
@@ -517,9 +524,10 @@ class InferenceEngine:
             self._verify_cache[cache_key] = jax.jit(step, donate_argnums=(2,))
         return self._verify_cache[cache_key]
 
+    @contextlib.contextmanager
     def _mesh_ctx(self):
-        from butterfly_tpu.core import compat
-        return compat.mesh_ctx(self.mesh)
+        with mesh_ctx(self.mesh), record_kernels(self.kernel_calls):
+            yield
 
 
 # ---------------------------------------------------------------------------

@@ -46,6 +46,7 @@ pins the mixed block token-for-token against the alternating path.
 """
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Dict, Optional, Tuple
 
@@ -59,6 +60,8 @@ from butterfly_tpu.cache.paged import (
     init_paged_cache, paged_forward, paged_forward_window,
     permute_paged_tail, permute_window_tail)
 from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
+from butterfly_tpu.core.mesh import mesh_ctx
+from butterfly_tpu.ops import kernel_mode, kernels_default, record_kernels
 from butterfly_tpu.engine.sampling import (
     _filter_logits, speculative_accept, speculative_tree_accept,
     tree_ancestor_matrix, tree_depth, tree_node_index)
@@ -253,19 +256,26 @@ class ServingEngine:
                 f"{self.cfg.num_layers} layers not divisible by "
                 f"{stage} pipeline stages")
         if use_kernels is None:
-            # Pallas kernels are TPU-only; under a mesh the call sites go
-            # through ops/*_sharded (shard_map over data/tensor), so a
-            # mesh no longer disables them.
-            use_kernels = jax.default_backend() == "tpu"
-        self.cache = init_paged_cache(self.cfg, self.runtime)
+            # on everywhere but the CPU backend (ops/__init__.py); under
+            # a mesh the call sites go through ops/*_sharded (shard_map
+            # over data/tensor), so a mesh does not disable them
+            use_kernels = kernels_default()
+        #: 'off' | 'interpret' | 'compiled' (ops.kernel_mode)
+        self.kernel_mode = kernel_mode(use_kernels)
+        # kernel call sites traced by this engine's programs, counted
+        # while they trace (ops.record_kernels) — /health reports both
+        self.kernel_calls: Dict[str, int] = {}
+        cache_shardings = None
         if mesh is not None:
             # Megatron param layout + paged pool sharded to match (kv
             # heads over `tensor`, slots over `data`): prefill/decode
             # below then compile to one SPMD program over the mesh.
             # Quantized trees route through the quant-aware specs (the
             # float specs would shard a scale's size-1 contraction dim).
+            # A tree that arrives in this layout (cli.load_params) is
+            # left where it is; the pool is allocated in its layout.
             from butterfly_tpu.parallel.partition import (
-                shard_paged_cache, shard_params)
+                paged_cache_specs, shard_params, to_shardings)
             from butterfly_tpu.quant.int8 import (
                 shard_quantized_params, tree_is_quantized)
             if tree_is_quantized(self.params):
@@ -273,7 +283,11 @@ class ServingEngine:
                                                      mesh)
             else:
                 self.params = shard_params(self.params, self.cfg, mesh)
-            self.cache = shard_paged_cache(self.cache, self.cfg, mesh)
+            cache_shardings = to_shardings(paged_cache_specs(
+                self.cfg, mesh, self.runtime.max_batch_size,
+                quant=self.runtime.kv_quant == "int8"), mesh)
+        self.cache = init_paged_cache(self.cfg, self.runtime,
+                                      shardings=cache_shardings)
         # Host-side block-table mirror (see set_table_row). Built from
         # the known init value (all rows -> null page) rather than
         # fetching the device array: a multi-process data-sharded table
@@ -406,9 +420,12 @@ class ServingEngine:
                         "the stage-local pipeline scan has no slot for")
                 self._tree_width, self._tree_nodes = w, n
 
+    @contextlib.contextmanager
     def _mesh_ctx(self):
-        from butterfly_tpu.core import compat
-        return compat.mesh_ctx(self.mesh)
+        """Every dispatch runs inside this: the ambient mesh, and the
+        kernel record for whatever the dispatch has to trace."""
+        with mesh_ctx(self.mesh), record_kernels(self.kernel_calls):
+            yield
 
     @property
     def num_slots(self) -> int:
@@ -480,8 +497,8 @@ class ServingEngine:
         self.cache = self.cache._replace(page_table=tbl)
         self._table_dirty = False
         if self.tracer is not None:
-            # table syncs are a measured share of the full-batch serving
-            # gap (docs/decode_profile_r5.md) — count them in the trace
+            # a table sync is a host->device transfer on the serving
+            # loop's critical path — count them in the trace
             self.tracer.event(None, "engine.table_sync")
 
     # -- write-combined KV window (kv_write_combine) ------------------------
@@ -499,13 +516,15 @@ class ServingEngine:
                 self.flush_kv_window()
             if width < need:
                 width = max(1, self.runtime.inflight_blocks) * need
-                with self._mesh_ctx():
-                    win = init_kv_window(self.cache, width)
+                shardings = None
                 if self.mesh is not None:
-                    from butterfly_tpu.parallel.partition import \
-                        shard_kv_window
-                    win = shard_kv_window(win, self.cfg, self.mesh)
-                self._kv_window = win
+                    from butterfly_tpu.parallel.partition import (
+                        kv_window_specs, to_shardings)
+                    shardings = to_shardings(kv_window_specs(
+                        self.cfg, self.mesh, self.num_slots,
+                        quant=self.cache.quantized), self.mesh)
+                self._kv_window = init_kv_window(self.cache, width,
+                                                 shardings)
                 self._win_len = None
         if self._win_len is None:
             self._win_len = jax.device_put(
@@ -644,7 +663,6 @@ class ServingEngine:
             return prog
         from jax.sharding import PartitionSpec as P
 
-        from butterfly_tpu.core import compat
         from butterfly_tpu.core.mesh import replicated
         from butterfly_tpu.parallel.sequence import sp_chunk_body
 
@@ -681,14 +699,14 @@ class ServingEngine:
                 kv_out = (P(None, None, "seq"), P(None, None, "seq"))
             layers = params["layers"]
             head = {k: v for k, v in params.items() if k != "layers"}
-            fn = compat.shard_map(
-                body, mesh,
+            fn = jax.shard_map(
+                body, mesh=mesh,
                 in_specs=(jax.tree.map(lambda _: P(), layers),
                           jax.tree.map(lambda _: P(), head),
                           P(None, "seq"), P()) + tuple(
                               P() for _ in pre_args),
                 out_specs=(P(None, "seq"), kv_out),
-                axis_names={"seq"})
+                axis_names={"seq"}, check_vma=False)
             logits, kv = fn(layers, head, tokens, start, *pre_args)
             # flush-style all-layer scatter of the fresh chunk into the
             # pool; pad rows (>= clen) route to the null page
@@ -719,17 +737,15 @@ class ServingEngine:
                                             keepdims=False)
             return last, (kp, vp, ksp, vsp)
 
-        # pin every output fully replicated EXPLICITLY (not via
-        # with_sharding_constraint inside the trace — that left the
-        # shard_map-manual layout metadata on the results): a program
-        # containing a full-manual shard_map otherwise hands back
-        # arrays whose seq-sharded provenance poisons later stacked
-        # fetches on jax 0.4.x — a drain's multi-part concatenate
-        # recompiles under the mesh and sums the seq shards, so every
-        # drained token comes back multiplied by the seq degree.
-        rep = replicated(mesh)
+        # The pools leave in the layout they came in with (they are
+        # donated: the chunk's scatter lands in place, and the next
+        # block program sees the shardings it was compiled for); the
+        # logits leave replicated.
+        pool_sh = tuple(None if p is None else p.sharding for p in (
+            self.cache.k_pages, self.cache.v_pages,
+            self.cache.k_scale_pages, self.cache.v_scale_pages))
         prog = jax.jit(run, donate_argnums=(2,),
-                       out_shardings=(rep, (rep, rep, rep, rep)))
+                       out_shardings=(replicated(mesh), pool_sh))
         self._sp_chunk_progs[C] = prog
         return prog
 

@@ -33,6 +33,7 @@ from butterfly_tpu.core.config import ModelConfig
 # a lazy in-function import executes on every trace — the same per-trace
 # tax PR 12's quantize_kv hoist removed from cache/paged.py. No cycle:
 # ops.flash_attention imports nothing project-local at module level.
+from butterfly_tpu.ops import note_kernel
 from butterfly_tpu.ops.flash_attention import flash_attention_sharded
 from butterfly_tpu.quant.int8 import qeinsum
 
@@ -314,6 +315,8 @@ def attention_block(x: jax.Array, p: Params, cfg: ModelConfig,
         out = None
         if cfg.attn_impl == "flash" and x.shape[1] > 1:
             out = flash_attention_sharded(q, k, v, causal=True)
+            if out is None:
+                note_kernel("dense_fallback")
         if out is None:
             out = attend(q, k, v, mask, cfg)
         return attn_output(out, p, cfg), k, v
@@ -349,6 +352,8 @@ def attention_block(x: jax.Array, p: Params, cfg: ModelConfig,
             out = flash_attention_sharded(
                 q, kf, vf, causal=True, prefix_k=ck, prefix_v=cv,
                 prefix_len=start, prefix_k_scale=k_s, prefix_v_scale=v_s)
+        if out is None:
+            note_kernel("dense_fallback")
     if out is None:
         out = attend(q, ck, cv, mask, cfg, k_s, v_s)
     if k_s is not None:
@@ -707,7 +712,7 @@ def _decode_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
 # (r4 had a [.., C, ..] window buffer updated per step with
 # dynamic-update-slice; XLA's layout assignment made every insert a
 # strided scatter of H-byte segments at 15 GiB/s — 19% of the decode step
-# on v5e, docs/decode_profile_r5.md — and reassigned any step-major
+# in the r5 v5e profile — and reassigned any step-major
 # layout right back). The window uses the cache's representation (int8
 # codes + scales in quant mode), so attention numerics are bit-identical
 # to the step-by-step path.
@@ -770,7 +775,7 @@ def flush_window(cache: KVCache, steps: list,
     with the scan carry and performs in place; the general ragged path
     (vmapped per-row updates) rolls into a loop whose first update
     COPIES each pool — ~1.8 ms per pool per flush at the 1B/batch-128
-    operating point (docs/decode_profile_r5.md)."""
+    operating point in the r5 v5e profile."""
     start = cache.length
     C = len(steps)
     if cache.quantized:

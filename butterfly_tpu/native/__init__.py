@@ -3,15 +3,19 @@
 The compute path is JAX/XLA/Pallas; the host runtime around it —
 here the paged-KV page allocator on the scheduler's hot path — has a
 native implementation (native/allocator.cc) with this loader and a
-pure-Python fallback (cache/allocator.py), selected automatically:
+pure-Python fallback (cache/allocator.py). Which one runs is decided
+from what git commits, never from what an earlier command happened to
+leave on disk: the binary is not committed, so `load_native` builds it
+when it is missing or older than its source — the same rule for the
+server in a fresh clone and for the tests.
 
-* lib present  -> NativePageAllocator (identical semantics, parity-
+* g++ present  -> NativePageAllocator (identical semantics, parity-
   tested in tests/test_native.py)
-* lib absent   -> Python PageAllocator (no build step required)
+* no g++       -> Python PageAllocator
 * BUTTERFLY_NATIVE=0 forces the Python path.
 
-Build the lib with `python -m butterfly_tpu.native.build` (or
-`make -C native`); it lands next to this file so wheels can ship it.
+`python -m butterfly_tpu.native.build` (or `make -C native`) builds it
+by hand; it lands next to this file so wheels can ship it.
 """
 from __future__ import annotations
 
@@ -21,11 +25,31 @@ from pathlib import Path
 from typing import List, Optional
 
 _LIB_PATH = Path(__file__).parent / "libbutterfly_native.so"
+_SRC_PATH = Path(__file__).resolve().parent.parent.parent \
+    / "native" / "allocator.cc"
 _lib = None
 
 
+def _ensure_built() -> bool:
+    """Is a current lib on disk? Builds it when it is missing or older
+    than the C++ source (a stale binary must never be what serves, or
+    what the parity tests validate). False only where it cannot be
+    built: no g++, or no source beside an installed package. A real
+    compile error raises — loudly, not a silent Python fallback."""
+    have = _LIB_PATH.exists()
+    if not _SRC_PATH.exists() or (
+            have and _SRC_PATH.stat().st_mtime <= _LIB_PATH.stat().st_mtime):
+        return have
+    from butterfly_tpu.native.build import build
+    try:
+        build(verbose=False)
+    except FileNotFoundError:  # no g++ in this environment
+        return False
+    return True
+
+
 def load_native():
-    """The loaded CDLL, or None (missing lib / disabled via env).
+    """The loaded CDLL, or None (cannot be built / disabled via env).
 
     The env gate is re-read on every call so BUTTERFLY_NATIVE=0 takes
     effect immediately even after the lib was loaded once; only the
@@ -36,7 +60,7 @@ def load_native():
         return None
     if _lib is not None:
         return _lib
-    if not _LIB_PATH.exists():
+    if not _ensure_built():
         return None
     lib = ctypes.CDLL(str(_LIB_PATH))
     i32, p = ctypes.c_int32, ctypes.c_void_p

@@ -21,23 +21,39 @@ from typing import Dict, Optional
 
 import numpy as np
 
-# Usable HBM bandwidth per chip, bytes/sec. v5e: ~819 GB/s.
+# Published per-chip peaks (Google Cloud TPU documentation), keyed by a
+# substring of `device_kind`. HBM bandwidth in bytes/sec (v5e: 819 GB/s):
 HBM_BW = {"TPU v5 lite": 819e9, "TPU v5e": 819e9, "TPU v4": 1228e9,
           "TPU v5p": 2765e9, "TPU v6 lite": 1640e9, "TPU v6e": 1640e9}
-DEFAULT_HBM_BW = 819e9
-# bf16 dense peak matmul throughput per chip, FLOP/s, per device kind.
+# bf16 dense peak matmul throughput in FLOP/s:
 PEAK_FLOPS = {"TPU v5 lite": 197e12, "TPU v5e": 197e12, "TPU v4": 275e12,
               "TPU v5p": 459e12, "TPU v6 lite": 918e12, "TPU v6e": 918e12}
-DEFAULT_PEAK_FLOPS = 197e12
 
 
-def _chip_lookup(table: Dict[str, float], default: float) -> float:
+def require_chip(what: str) -> None:
+    """Exit non-zero unless JAX's default device is an accelerator: a
+    CPU timing is never written under a device metric's name."""
     import jax
-    kind = getattr(jax.devices()[0], "device_kind", "")
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        raise SystemExit(
+            f"{what} measures the device, and JAX found no accelerator "
+            f"(platform 'cpu', device_kind {dev.device_kind!r}): nothing "
+            "timed on a CPU is a device metric. Run it on a chip.")
+
+
+def chip_peak(table: Dict[str, float]) -> float:
+    """The default device's entry in a peaks table. A device_kind the
+    table does not know is an error, not a default: a utilization
+    against another chip's peak is a wrong number."""
+    import jax
+    kind = jax.devices()[0].device_kind
     for k, v in table.items():
         if k.lower() in kind.lower():
             return v
-    return default
+    raise ValueError(
+        f"no published peak for device_kind {kind!r} in obs/benchmark.py "
+        f"(known: {sorted(table)}); add it with its source")
 
 
 def run_decode_benchmark(model, params, batch: int, prompt_len: int,
@@ -48,6 +64,8 @@ def run_decode_benchmark(model, params, batch: int, prompt_len: int,
     from butterfly_tpu.core.config import RuntimeConfig
     from butterfly_tpu.engine import InferenceEngine, SamplingParams
 
+    # before any timing: an unknown device has no roofline to report
+    hbm_bw, peak_flops = chip_peak(HBM_BW), chip_peak(PEAK_FLOPS)
     engine = InferenceEngine(
         model, params, RuntimeConfig(max_seq_len=prompt_len + max_new,
                                      kv_quant=kv_quant),
@@ -92,11 +110,9 @@ def run_decode_benchmark(model, params, batch: int, prompt_len: int,
     n_chips = mesh.size if mesh is not None else 1
     dp = mesh.shape.get("data", 1) if mesh is not None else 1
     bytes_per_step = param_bytes * dp + kv_bytes
-    hbm_util = (bytes_per_step * steps_per_sec /
-                (_chip_lookup(HBM_BW, DEFAULT_HBM_BW) * n_chips))
+    hbm_util = bytes_per_step * steps_per_sec / (hbm_bw * n_chips)
     # Decode matmul FLOPs ~= 2 * weight params * batch per step.
-    mfu = (2 * param_count * batch * steps_per_sec /
-           (_chip_lookup(PEAK_FLOPS, DEFAULT_PEAK_FLOPS) * n_chips))
+    mfu = 2 * param_count * batch * steps_per_sec / (peak_flops * n_chips)
 
     total = batch * max_new
     return {
@@ -389,9 +405,9 @@ def run_warm_prefill_benchmark(model, params, *, n_requests: int = 6,
     Emits the on/off pair the bench JSON carries (PR 12's `_nowin`
     pattern): warm_prefill_ttft_p50/p95 + warm_prefill_tokens_per_sec
     with `_dense` twins, plus `warm_prefill_kernelized` saying whether
-    the on leg actually took the kernel (False on CPU, where kernels
-    are TPU-only and the measured delta is the gang-merge half of the
-    change; the kernel half is still exercised bit-exactly by the
+    the on leg actually took the kernel (False on CPU, where the
+    engines leave kernels off and the measured delta is the gang-merge
+    half of the change; the kernel half is still exercised bit-exactly by the
     interpret-mode parity tests). TTFT medians are over `repeats`
     backlog drains — a single CPU drain carries scheduler jitter larger
     than the effect (the PR 12 median-of-3 lesson).
@@ -493,7 +509,8 @@ def run_longctx_benchmark(model, params, *, prompt_len: int = 256,
 
     # -- ring microbench pair (mesh-free): one chunk's worth of queries
     # against the full prompt's keys, the ring block's production shape
-    kernelized = jax.default_backend() == "tpu"
+    from butterfly_tpu.ops import kernels_default
+    kernelized = kernels_default()
     out["longctx_ring_kernelized"] = kernelized
     rng = np.random.RandomState(seed)
     Nq, Kv, H = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim

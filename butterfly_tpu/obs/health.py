@@ -65,11 +65,10 @@ def all_hosts_probe() -> bool:
     global _HOSTS_PROBE
     ndev = jax.device_count()
     if _HOSTS_PROBE is None or _HOSTS_PROBE[1] != ndev:
-        from butterfly_tpu.core import compat
         mesh = Mesh(np.asarray(jax.devices()), ("all",))
-        fn = jax.jit(compat.shard_map(
-            lambda x: jax.lax.psum(x, "all"), mesh,
-            in_specs=P("all"), out_specs=P()))
+        fn = jax.jit(jax.shard_map(
+            lambda x: jax.lax.psum(x, "all"), mesh=mesh,
+            in_specs=P("all"), out_specs=P(), check_vma=False))
         _HOSTS_PROBE = (fn, ndev, mesh)
     fn, _, mesh = _HOSTS_PROBE
     # each process contributes its local shards (a host-local array

@@ -22,7 +22,7 @@ from typing import Dict, Optional
 
 PREFIX = "butterfly"
 
-# NB (ADVICE.md round 5 / ISSUE 10): with pipelined decode dispatch,
+# NB (round-5 review / ISSUE 10): with pipelined decode dispatch,
 # tokens surface in per-tick stacked-drain BURSTS, so the raw-gap ITL
 # percentiles bimodalize (p50 identically 0.0 between burst-mates at
 # decode_steps_per_tick > 1) and ttft_* includes up to one extra tick

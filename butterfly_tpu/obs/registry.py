@@ -4,7 +4,7 @@ Replaces the scheduler's ad-hoc ``Dict[str, float]`` with real
 instruments so /metrics can expose *distributions* — fixed-bucket
 Prometheus histograms with ``_bucket``/``_sum``/``_count`` series —
 instead of deque-percentile snapshots whose semantics silently shift
-with the emission pattern (ADVICE.md round 5: deferred emission skews
+with the emission pattern (round-5 review: deferred emission skews
 the raw itl_p50/p95 keys).
 
 Threading contract: ONE writer thread (the scheduler loop owns every

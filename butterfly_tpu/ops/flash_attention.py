@@ -20,8 +20,9 @@ star attributes to the original design"). Design:
   count-masked at the scalar-prefetched `start` — the append-to-KV-
   history attention shape online softmax was built for, replacing the
   dense O(T*S) warm fallback.
-* Off-TPU the wrapper runs the same kernel in interpreter mode, so CPU
-  tests validate the exact kernel code path numerics.
+* On the CPU backend the wrapper runs the same kernel in interpreter
+  mode, so CPU tests validate the exact kernel code path numerics;
+  everywhere else it is compiled (ops/__init__.py has the rule).
 
 Used by the engine for fresh AND warm multi-token prefills
 (cfg.attn_impl="flash"); decode-side paged attention lives in
@@ -36,10 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names it TPUCompilerParams; alias so both resolve (the
-# interpret-mode CPU tests otherwise die before interpretation starts)
-if not hasattr(pltpu, "CompilerParams"):  # pragma: no cover
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
+from butterfly_tpu.ops import (note_kernel, resolve_interpret,
+                               sublane_multiple)
 
 NEG_INF = -1e30
 
@@ -176,25 +175,14 @@ def _auto_axes(mesh) -> set:
             if t == AxisType.Auto}
 
 
-def _abstract_mesh():
-    """The ambient abstract mesh, or None on jax < 0.5: 0.4.x has no
-    jax.sharding.get_abstract_mesh — and no jax.set_mesh to install an
-    ambient mesh in the first place, so "no mesh" is the truth there,
-    not a guess. Same compat class as the TPUCompilerParams alias above
-    (without it, every use_kernels serving path dies on 0.4.37 before
-    a single kernel runs)."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    return get() if get is not None else None
-
-
 def shardable_axes(batch: int, nq: int, kv: int):
     """(data_axis, tensor_axis) of the ambient mesh usable to shard an
     attention operand set: `data` must divide the batch/slot dim, `tensor`
     must divide both head counts; an axis is skipped when absent, size 1,
     or already Manual from an enclosing shard_map (e.g. the pipeline's
     `stage`). Shared eligibility rule for both kernel wrappers."""
-    mesh = _abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return None, None
     auto = _auto_axes(mesh)
     d = "data" if ("data" in auto and mesh.shape["data"] > 1
@@ -205,12 +193,33 @@ def shardable_axes(batch: int, nq: int, kv: int):
     return d, t
 
 
+def shard_kernel(fn, in_specs, out_specs):
+    """`fn` (a kernel call) under the ambient mesh, or `fn` itself where
+    no mesh axis is left to the partitioner.
+
+    The shard_map names EVERY mesh axis, not only the axes the specs
+    shard over: Mosaic refuses a kernel in a program that leaves any
+    mesh axis — even one of size 1 — to the automatic partitioner
+    ("Mosaic kernels cannot be automatically partitioned"; only the TPU
+    lowering checks, so interpret mode on the CPU never sees it), and
+    it judges by the innermost shard_map alone, so inside the
+    pipeline's `stage` body or an SP `seq` body the kernel's own wrap
+    names those already-Manual axes again. An axis the specs do not
+    name sees its operands replicated, which is what GSPMD would have
+    done with them."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not _auto_axes(mesh):
+        return fn
+    return jax.shard_map(fn, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=set(mesh.axis_names), check_vma=False)
+
+
 def live_auto_mesh() -> bool:
     """True when the ambient mesh has any multi-device axis still under
     GSPMD (Auto) control — a bare pallas_call traced there would be an
     opaque custom call the partitioner can't shard."""
-    mesh = _abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return False
     return any(mesh.shape[n] > 1 for n in _auto_axes(mesh))
 
@@ -225,14 +234,15 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     """Mesh-aware flash attention (SURVEY.md §7 stages 4/6).
 
     A pallas_call is an opaque custom call GSPMD cannot partition, so under
-    an active mesh we wrap the kernel in `shard_map` over the axes whose
-    sharding the partitioner gave these operands: batch over `data`, heads
-    over `tensor` (parallel/partition.py puts q-heads/kv-heads there via
-    the column-parallel wq/wk/wv). Attention is purely local to a
-    (batch, head) shard — each shard runs the unmodified kernel on its
-    slice, no collectives. Axes that don't divide (or are already Manual
-    from an enclosing shard_map, e.g. the pipeline's `stage`) are left
-    alone; with no mesh at all this is exactly `flash_attention`.
+    an active mesh we wrap the kernel in `shard_map` (shard_kernel: manual
+    over every mesh axis), sharding the operands the way the partitioner
+    did: batch over `data`, heads over `tensor` (parallel/partition.py
+    puts q-heads/kv-heads there via the column-parallel wq/wk/wv).
+    Attention is purely local to a (batch, head) shard — each shard runs
+    the unmodified kernel on its slice, no collectives. Axes that don't
+    divide see replicated operands; axes already Manual from an enclosing
+    shard_map (e.g. the pipeline's `stage`) are left alone; with no mesh
+    at all this is exactly `flash_attention`.
 
     Returns None when a live multi-device Auto mesh is present but no
     axis can shard the operands: the caller MUST fall back to its dense
@@ -260,21 +270,15 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     B, T, Nq, H = q.shape
     Kv = k.shape[2]
     d, t = shardable_axes(B, Nq, Kv)
-    if d is None and t is None:
-        if live_auto_mesh():
-            return None
-        return flash_attention(q, k, v, causal=causal,
-                               prefix_k=prefix_k, prefix_v=prefix_v,
-                               prefix_len=prefix_len,
-                               prefix_k_scale=prefix_k_scale,
-                               prefix_v_scale=prefix_v_scale)
+    if d is None and t is None and live_auto_mesh():
+        return None
+    note_kernel("flash" + ("_warm" if prefix_k is not None else "")
+                + ("_int8" if prefix_k_scale is not None else ""),
+                resolve_interpret(None))
     spec = P(d, None, t, None)
     if prefix_k is None:
-        fn = jax.shard_map(
-            functools.partial(flash_attention, causal=causal),
-            in_specs=(spec, spec, spec), out_specs=spec,
-            axis_names={a for a in (d, t) if a is not None},
-            check_vma=False)
+        fn = shard_kernel(functools.partial(flash_attention, causal=causal),
+                          in_specs=(spec, spec, spec), out_specs=spec)
         return fn(q, k, v)
     # lazy: partition imports models.common at module level, which now
     # imports this module — an import here would close the cycle
@@ -291,11 +295,9 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
         return flash_attention(q, k, v, causal=causal, prefix_k=pk,
                                prefix_v=pv, prefix_len=plen, **kw)
 
-    fn = jax.shard_map(
-        _warm,
-        in_specs=(spec, spec, spec) + warm_prefix_specs(d, t, quant),
-        out_specs=spec,
-        axis_names={a for a in (d, t) if a is not None}, check_vma=False)
+    fn = shard_kernel(
+        _warm, in_specs=(spec, spec, spec) + warm_prefix_specs(d, t, quant),
+        out_specs=spec)
     return fn(*args)
 
 
@@ -334,14 +336,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     B, T, Nq, H = q.shape
     Kv = k.shape[2]
     G = Nq // Kv
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
 
-    # Block shapes must keep the sublane dim a multiple of 8 for Mosaic
-    # lowering on real TPU (odd T like 20 would otherwise produce 20xH
-    # blocks); padding below already handles T < block.
-    bq = min(block_q, -(-max(T, 8) // 8) * 8)
-    bk = min(block_k, -(-max(T, 8) // 8) * 8)
+    # Block shapes must keep the sublane dim a whole number of Mosaic
+    # tiles (8 rows of f32, 16 of bf16): odd T like 20 would otherwise
+    # produce 20xH blocks; padding below already handles T < block.
+    sub = sublane_multiple(q.dtype)
+    bq = min(block_q, -(-T // sub) * sub)
+    bk = min(block_k, -(-T // sub) * sub)
     Tq = -(-T // bq) * bq
     Tk = -(-T // bk) * bk
 
@@ -411,7 +413,8 @@ def _flash_warm_call(qt, kt, vt, prefix_k, prefix_v, prefix_len,
         pk = jnp.moveaxis(prefix_k, 2, 1)      # [B, Sp, Kv, H] -> kv-major
         pv = jnp.moveaxis(prefix_v, 2, 1)
     Sp = pk.shape[2]
-    bp = min(block_k, -(-max(Sp, 8) // 8) * 8)
+    sub = sublane_multiple(pk.dtype)    # 32 rows for an int8 prefix
+    bp = min(block_k, -(-Sp // sub) * sub)
     Sp_pad = -(-Sp // bp) * bp
     np_blocks = Sp_pad // bp
     nf = kt.shape[2] // bk

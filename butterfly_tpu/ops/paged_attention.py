@@ -25,8 +25,9 @@ slot's block table and touches ONLY its live pages:
   the contiguous int8 cache: K scales multiply the score columns, V
   scales fold into the probs. No dequantized copy is ever materialized.
 
-Off-TPU the wrapper runs the kernel in interpreter mode (CPU tests cover
-the exact kernel path).
+On the CPU backend the wrapper runs the kernel in interpreter mode (CPU
+tests cover the exact kernel path); everywhere else it is compiled
+(ops/__init__.py has the rule).
 """
 from __future__ import annotations
 
@@ -37,10 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names it TPUCompilerParams; alias so both resolve (the
-# interpret-mode CPU tests otherwise die before interpretation starts)
-if not hasattr(pltpu, "CompilerParams"):  # pragma: no cover
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
+from butterfly_tpu.ops import note_kernel, resolve_interpret
 
 NEG_INF = -1e30
 
@@ -168,8 +166,9 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
                             win_v_scale: jax.Array = None) -> jax.Array:
     """Mesh-aware paged attention for meshed serving (SURVEY.md §7 stage 6).
 
-    shard_map over the axes the paged partitioner uses
-    (parallel/partition.py paged_cache_specs): slots over `data`, q/kv
+    shard_map (flash_attention.shard_kernel: manual over every mesh
+    axis) with the operands laid out as the paged partitioner lays them
+    out (parallel/partition.py paged_cache_specs): slots over `data`, q/kv
     heads over `tensor`; the page-id dim stays replicated (any slot may
     reference any page). A `tensor` shard of the flat [Kv*page] scale dim
     is the same contiguous kv-group chunk as the code pool's Kv shard, so
@@ -189,20 +188,17 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
     from jax.sharding import PartitionSpec as P
 
     from butterfly_tpu.ops.flash_attention import (live_auto_mesh,
+                                                   shard_kernel,
                                                    shardable_axes)
 
     S, Nq, H = q.shape
     Kv = k_pages.shape[1]          # pools are [P, Kv, page, H]
     d, t = shardable_axes(S, Nq, Kv)
-    if d is None and t is None:
-        if live_auto_mesh():
-            return None
-        return paged_attention(q, k_pages, v_pages, page_table, lengths,
-                               k_scale_pages, v_scale_pages,
-                               win_k=win_k, win_v=win_v,
-                               win_count=win_count,
-                               win_k_scale=win_k_scale,
-                               win_v_scale=win_v_scale)
+    if d is None and t is None and live_auto_mesh():
+        return None
+    note_kernel("paged" + ("_int8" if k_scale_pages is not None else "")
+                + ("_win" if win_k is not None else ""),
+                resolve_interpret(None))
     kv_spec = P(None, t, None, None)
     in_specs = [P(d, t, None), kv_spec, kv_spec, P(d, None), P(d)]
     args = [q, k_pages, v_pages, page_table, lengths]
@@ -227,11 +223,8 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
         target = _kernel
     else:
         target = paged_attention
-    fn = jax.shard_map(
-        target,
-        in_specs=tuple(in_specs),
-        out_specs=P(d, t, None),
-        axis_names={a for a in (d, t) if a is not None}, check_vma=False)
+    fn = shard_kernel(target, in_specs=tuple(in_specs),
+                      out_specs=P(d, t, None))
     return fn(*args)
 
 
@@ -270,8 +263,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     max_pages = page_table.shape[1]
     quant = k_scale_pages is not None
     window = 0 if win_k is None else win_k.shape[2]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
 
     # scalar-prefetch operands: (table, lengths[, win_count]) — the
     # index maps see them all; the pool maps clamp j to the page grid

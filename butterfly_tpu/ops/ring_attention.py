@@ -33,12 +33,14 @@ exactly the warm-prefix flash segment / models.common.attend order).
 Two legs with one contract:
 
 * `ring_block_stats` — the Pallas kernel (grid (B, Nq, Tq/bq, S/bk),
-  reduction axis "arbitrary", VMEM f32 scratch). Off-TPU it runs in
-  interpreter mode so CPU tests cover the exact kernel numerics.
-* `ring_block_stats_ref` — the jnp twin, the jax-0.4.37 / CPU
-  fallback inside shard_map and the parity reference.
+  reduction axis "arbitrary", VMEM f32 scratch). Called directly on
+  the CPU backend it runs in interpreter mode so CPU tests cover the
+  exact kernel numerics.
+* `ring_block_stats_ref` — the jnp twin: the CPU path inside
+  shard_map and the parity reference.
 
-`block_stats` dispatches between them on the backend.
+`block_stats` dispatches between them on the backend
+(ops/__init__.py has the rule).
 """
 from __future__ import annotations
 
@@ -48,8 +50,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-from butterfly_tpu.ops.flash_attention import NEG_INF, _block_update
+from butterfly_tpu.ops import (kernels_default, note_kernel,
+                               resolve_interpret, sublane_multiple)
+from butterfly_tpu.ops.flash_attention import (NEG_INF, _block_update,
+                                               shard_kernel, shardable_axes)
 
 #: sanitized "never attend" key position: k_pos <= q_pos is False for
 #: every real query position.
@@ -95,19 +101,35 @@ def finalize_stats(stats, dtype):
 
 def block_stats(q, k, v, q_pos, k_pos, k_scale=None, v_scale=None,
                 kernel=None):
-    """Backend dispatch: Pallas kernel on TPU, jnp twin elsewhere.
+    """Backend dispatch: the jnp twin on the CPU backend, the Pallas
+    kernel everywhere else.
 
-    The twin is not a stopgap — it is the jax-0.4.37/CPU fallback the
-    shard_map bodies rely on (interpret-mode pallas inside shard_map
-    is both slow and version-fragile); the kernel leg is covered on
-    CPU by calling `ring_block_stats` directly in interpreter mode
+    The shard_map bodies run the twin on CPU because interpret-mode
+    pallas inside shard_map is slow; the kernel leg is covered there by
+    calling `ring_block_stats` directly in interpreter mode
     (tests/test_longctx.py parity grid).
     """
     if kernel is None:
-        kernel = jax.default_backend() == "tpu"
-    if kernel:
-        return ring_block_stats(q, k, v, q_pos, k_pos, k_scale, v_scale)
-    return ring_block_stats_ref(q, k, v, q_pos, k_pos, k_scale, v_scale)
+        kernel = kernels_default()
+    if not kernel:
+        return ring_block_stats_ref(q, k, v, q_pos, k_pos, k_scale,
+                                    v_scale)
+    quant = k_scale is not None
+    note_kernel("ring_int8" if quant else "ring", resolve_interpret(None))
+    # The SP bodies are shard_maps manual over `seq` alone, so the other
+    # mesh axes are still Auto here and the kernel needs its own wrap
+    # (shard_kernel): batch over `data`, heads over `tensor`, where they
+    # divide — the layout GSPMD gave q/k/v — and replicated otherwise.
+    B, _, Nq, _ = q.shape
+    d, t = shardable_axes(B, Nq, k.shape[1] if quant else k.shape[2])
+    qspec = P(d, None, t, None)
+    kspec = P(d, t, None, None) if quant else qspec
+    in_specs = (qspec, kspec, kspec, P(d, None), P(d, None)) \
+        + ((P(d, t, None),) * 2 if quant else ())
+    fn = shard_kernel(
+        ring_block_stats, in_specs=in_specs,
+        out_specs=(P(d, t, None), P(d, t, None), P(d, t, None, None)))
+    return fn(q, k, v, q_pos, k_pos, *((k_scale, v_scale) if quant else ()))
 
 
 # ---------------------------------------------------------------------------
@@ -211,11 +233,12 @@ def ring_block_stats(q, k, v, q_pos, k_pos, k_scale=None, v_scale=None,
     Kv = k.shape[1] if quant else k.shape[2]
     S = k.shape[2] if quant else k.shape[1]
     G = Nq // Kv
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
 
-    bq = min(block_q, -(-max(T, 8) // 8) * 8)
-    bk = min(block_k, -(-max(S, 8) // 8) * 8)
+    # whole Mosaic tiles: 16 rows of bf16 queries, 32 of int8 codes
+    sub_q, sub_k = sublane_multiple(q.dtype), sublane_multiple(k.dtype)
+    bq = min(block_q, -(-T // sub_q) * sub_q)
+    bk = min(block_k, -(-S // sub_k) * sub_k)
     Tq = -(-T // bq) * bq
     Tk = -(-S // bk) * bk
 

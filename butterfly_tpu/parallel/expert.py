@@ -44,9 +44,8 @@ from butterfly_tpu.quant.int8 import qeinsum
 
 def _constrain(x: jax.Array, spec: P) -> jax.Array:
     """with_sharding_constraint iff a mesh with the spec's axes is active."""
-    from butterfly_tpu.ops.flash_attention import _abstract_mesh
-    mesh = _abstract_mesh()   # None on jax 0.4.x: no ambient mesh exists
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     names = set()
     for part in spec:
@@ -82,9 +81,9 @@ def moe_block_ep(x: jax.Array, p: Params, cfg: ModelConfig,
     may drop different tokens: the einsum path budgets per sequence, the
     a2a path pools its shard's budget — same volume, different victims.
     """
-    from butterfly_tpu.ops.flash_attention import _abstract_mesh, _auto_axes
-    mesh = _abstract_mesh()   # None on jax 0.4.x -> einsum fallback
-    if (mesh is not None and not mesh.empty
+    from butterfly_tpu.ops.flash_attention import _auto_axes
+    mesh = jax.sharding.get_abstract_mesh()
+    if (not mesh.empty
             and "expert" in _auto_axes(mesh)   # not Manual from an outer map
             and mesh.shape["expert"] > 1
             and x.shape[1] > 1                 # decode: einsum path is fine
@@ -145,8 +144,7 @@ def _moe_ep_a2a(x: jax.Array, p: Params, cfg: ModelConfig,
     shard, expert) — with a no-drop cf this equals the einsum path and
     the dense reference exactly.
     """
-    from butterfly_tpu.ops.flash_attention import _abstract_mesh
-    mesh = _abstract_mesh()   # non-None: moe_block_ep gates on it
+    mesh = jax.sharding.get_abstract_mesh()   # live: moe_block_ep gates on it
     N = mesh.shape["expert"]
     B, T, D = x.shape
     E, k = cfg.num_experts, cfg.num_experts_per_tok
@@ -163,14 +161,13 @@ def _moe_ep_a2a(x: jax.Array, p: Params, cfg: ModelConfig,
         C = expert_capacity(cfg, B * Tl)
 
     body = partial(_a2a_body, cfg=cfg, N=N, ne=ne, C=C)
-    from butterfly_tpu.core import compat
-    fn = compat.shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(None, "expert", None),
                   {"router": P(), "w_gate": P("expert"), "w_up": P("expert"),
                    "w_down": P("expert")}),
         out_specs=P(None, "expert", None),
-        axis_names={"expert"})
+        axis_names={"expert"}, check_vma=False)
     return fn(x, {kk: p[kk] for kk in
                   ("router", "w_gate", "w_up", "w_down")})
 

@@ -176,12 +176,6 @@ def paged_cache_specs(cfg: ModelConfig, mesh: Mesh, num_slots: int,
                         k_scale_pages=sc, v_scale_pages=sc)
 
 
-def shard_paged_cache(cache, cfg: ModelConfig, mesh: Mesh):
-    specs = paged_cache_specs(cfg, mesh, cache.num_slots,
-                              quant=cache.quantized)
-    return jax.device_put(cache, to_shardings(specs, mesh))
-
-
 def kv_window_specs(cfg: ModelConfig, mesh: Mesh, num_slots: int,
                     quant: bool = False):
     """Specs for the write-combined KV window (cache/paged.py KVWindow,
@@ -197,12 +191,6 @@ def kv_window_specs(cfg: ModelConfig, mesh: Mesh, num_slots: int,
     kv = P(None, dslots, tspec, None, None)
     sc = P(None, dslots, tspec, None) if quant else None
     return KVWindow(k=kv, v=kv, k_scale=sc, v_scale=sc)
-
-
-def shard_kv_window(window, cfg: ModelConfig, mesh: Mesh):
-    specs = kv_window_specs(cfg, mesh, window.k.shape[1],
-                            quant=window.quantized)
-    return jax.device_put(window, to_shardings(specs, mesh))
 
 
 def warm_prefix_specs(d: Optional[str], t: Optional[str],
@@ -263,12 +251,10 @@ def compiled_hlo(fn, *args, mesh: Optional[Mesh] = None, **jit_kw) -> str:
     """Lower+compile fn under `mesh` and return optimized HLO text."""
     jfn = jax.jit(fn, **jit_kw)
     if mesh is not None:
-        # compat.mesh_ctx resolves to set_mesh where it exists: that
-        # also installs the abstract mesh that mesh-aware call sites
-        # (kernel wrappers, EP a2a dispatch) consult during tracing —
-        # matching how the engines actually run.
-        from butterfly_tpu.core import compat
-        with compat.mesh_ctx(mesh):
+        # set_mesh also installs the abstract mesh that mesh-aware call
+        # sites (kernel wrappers, EP a2a dispatch) consult during
+        # tracing — matching how the engines actually run.
+        with jax.set_mesh(mesh):
             lowered = jfn.lower(*args)
     else:
         lowered = jfn.lower(*args)

@@ -144,14 +144,13 @@ def _run_gpipe(body, mesh: Mesh, layers, stage_ops, rep_ops, S: int, M: int,
     activation off the last stage instead of all-reducing S zero-padded
     copies (VERDICT r2 weak item 4).
     """
-    from butterfly_tpu.core import compat
     layer_in = jax.tree.map(lambda _: P("stage"), layers)
-    pipe = compat.shard_map(
-        body, mesh,
+    pipe = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(layer_in, *([P("stage")] * len(stage_ops)),
                   *([P()] * len(rep_ops))),
         out_specs=(P("stage"), *([P("stage")] * len(stage_ops))),
-        axis_names={"stage"})
+        axis_names={"stage"}, check_vma=False)
     outs, *new_stage = pipe(layers, *stage_ops, *rep_ops)
     return outs[(S - 1) * M:].reshape(x.shape), tuple(new_stage)
 

@@ -6,8 +6,8 @@ from all 6 files); this realizes the survey's required surface the TPU way:
 * **Ring attention** (context parallel): Q/K/V are sequence-sharded over
   the `seq` mesh axis. Each of the N ring steps computes the visiting
   K/V block's *partial flash statistics* — the Pallas online-softmax
-  kernel on TPU, its jnp twin elsewhere (`ops/ring_attention`, ISSUE 20;
-  the jnp leg is the jax-0.4.37/CPU fallback) — folds them into the
+  kernel, or its jnp twin on the CPU backend (`ops/ring_attention`,
+  ISSUE 20) — folds them into the
   running stats with the associative merge, then rotates K/V (+ their
   positions, + int8 scales) to the next neighbor with `lax.ppermute`.
   On TPU the ring rides neighbor ICI links and the permute overlaps the
@@ -47,7 +47,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from butterfly_tpu.core import compat
 from butterfly_tpu.core.config import ModelConfig
 from butterfly_tpu.models.common import (
     KVCache, Params, _cast_float, attend, attn_output, embed_tokens,
@@ -71,7 +70,7 @@ def ring_stats(q: jax.Array, k: jax.Array, v: jax.Array,
     that segment's before one shared `finalize_stats`.
     """
     B, Tq, Nq, H = q.shape
-    N = compat.axis_size(axis_name)
+    N = lax.axis_size(axis_name)
     perm = [(i, (i + 1) % N) for i in range(N)]
     stats = zero_stats(B, Nq, Tq, H)
 
@@ -118,7 +117,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     block contracts with — the seq axis is no longer capped at Kv, at
     the cost of r x the K/V all_to_all volume. Returns [B, T/N, Nq, H].
     """
-    N = compat.axis_size(axis_name)
+    N = lax.axis_size(axis_name)
     B, Tl, Nq, H = q.shape
     Kv = k.shape[2]
     if Kv % N != 0:
@@ -179,11 +178,11 @@ def sp_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     else:
         cache_out = (P(None, None, "seq"),               # [L,B,T,Kv,H]
                      P(None, None, "seq"))
-    fn = compat.shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(layer_in, head_in, P(None, "seq")),
         out_specs=(P(None, "seq"), cache_out),
-        axis_names={"seq"})
+        axis_names={"seq"}, check_vma=False)
     logits, cache_parts = fn(params["layers"],
                              {k: v for k, v in params.items()
                               if k != "layers"},
@@ -256,11 +255,11 @@ def sp_decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
         cache_args = (prefix.k, prefix.v, suffix.k, suffix.v)
         cache_in = (seq_kv, seq_kv, P(), P())
         out_specs = (P(), P(), P())
-    fn = compat.shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(layer_in, head_in, P(), P()) + cache_in + (P(), P()),
         out_specs=out_specs,
-        axis_names={"seq"})
+        axis_names={"seq"}, check_vma=False)
     out = fn(params["layers"], head, tokens, positions, *cache_args,
              suffix.length, prefix_len)
     if quant:

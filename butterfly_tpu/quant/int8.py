@@ -24,10 +24,12 @@ biases, MoE router) stays in the master dtype.
 """
 from __future__ import annotations
 
+from functools import partial as _partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Params = Dict[str, Any]
 
@@ -133,70 +135,34 @@ def quantize_int8(params: Params, cfg) -> Params:
     return go(params)
 
 
-from functools import partial as _partial
+def _contraction_axes(path_names) -> Optional[Tuple[int, ...]]:
+    """quantize_int8's contraction axes for the leaf at this tree path,
+    or None for a leaf that stays float (embeddings, norms, biases,
+    the MoE router)."""
+    name = path_names[-1]
+    parent = path_names[-2] if len(path_names) > 1 else ""
+    if name in ("wq", "wk", "wv"):
+        return (1,)
+    if name == "wo":
+        return (1, 2)
+    if parent == "moe" and name in ("w_gate", "w_up", "w_down"):
+        return (2,)
+    if parent == "mlp" and name in ("w_gate", "w_up", "w_down"):
+        return (1,)
+    if name == "lm_head":
+        return (0,)
+    return None
 
 
-@_partial(jax.jit, static_argnames=("shape", "axes", "dt"))
-def _init_quant_leaf(k, shape, axes, dt):
-    w = jax.random.normal(k, shape, jnp.float32) * 0.02
-    return _quant(w, axes, dt)
-
-
-@_partial(jax.jit, static_argnames=("shape", "pdt", "kind"))
-def _init_plain_leaf(k, shape, pdt, kind):
+def _leaf_values(k, *, shape, kind, axes, dt):
+    """One leaf in its final form: ones / zeros / N(0, .02), quantized
+    over `axes` when given, else cast to the compute dtype."""
     if kind == "ones":
-        return jnp.ones(shape, pdt)
+        return jnp.ones(shape, dt)
     if kind == "zeros":
-        return jnp.zeros(shape, pdt)
-    return (jax.random.normal(k, shape, jnp.float32) * 0.02).astype(pdt)
-
-
-def init_params_quantized(cfg, key: jax.Array) -> Params:
-    """Random-init an already-int8-quantized tree without ever holding
-    the float tree in HBM.
-
-    `init_params` + `quantize_int8` as two device programs peaks at the
-    full master-dtype tree (8B f32 = 32 GB — double a v5e chip's HBM);
-    fusing them into one jit does NOT help — XLA schedules the cheap
-    RNG ops ahead of the quantizations and materializes the float tree
-    anyway (measured: the fused program ResourceExhausted a v5e).
-    So each leaf is its own tiny program: init one float leaf,
-    quantize, free — peak = int8 tree + one float leaf. Leaf roles
-    (matmul -> quantize with quantize_int8's contraction axes;
-    norm-scales -> ones; biases -> zeros; everything else -> N(0, .02))
-    are resolved by path over init_params' eval_shape tree, so the
-    structure can't drift from the real initializer. Benchmark/smoke
-    use (real deployments load checkpoints via ckpt/)."""
-    from butterfly_tpu.models.common import init_params
-
-    dt = jnp.dtype(cfg.dtype)
-    shapes = jax.eval_shape(_partial(init_params, cfg),
-                            jax.ShapeDtypeStruct(key.shape, key.dtype))
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
-    keys = jax.random.split(key, len(leaves))
-    out = []
-    for (path, sd), k in zip(leaves, keys):
-        names = [getattr(p, "key", getattr(p, "name", "")) for p in path]
-        name, parent = names[-1], names[-2] if len(names) > 1 else ""
-        if name in ("wq", "wk", "wv"):
-            axes = (1,)
-        elif name == "wo":
-            axes = (1, 2)
-        elif parent == "moe" and name in ("w_gate", "w_up", "w_down"):
-            axes = (2,)
-        elif parent == "mlp" and name in ("w_gate", "w_up", "w_down"):
-            axes = (1,)
-        elif name == "lm_head":
-            axes = (0,)
-        else:
-            axes = None
-        if axes is not None:
-            out.append(_init_quant_chunked(k, sd.shape, axes, dt))
-        else:
-            kind = "ones" if name == "scale" else \
-                "zeros" if name.startswith("b") else "normal"
-            out.append(_init_plain_chunked(k, sd.shape, sd.dtype, kind))
-    return jax.tree_util.tree_unflatten(treedef, out)
+        return jnp.zeros(shape, dt)
+    w = jax.random.normal(k, shape, jnp.float32) * 0.02
+    return _quant(w, axes, dt) if axes is not None else w.astype(dt)
 
 
 #: Per-program element budget for random init: the RNG's bit buffers and
@@ -205,46 +171,109 @@ def init_params_quantized(cfg, key: jax.Array) -> Params:
 _INIT_CHUNK_ELEMS = 128 * 2**20
 
 
-def _chunks(k, shape, ax):
-    n = shape[ax]
+def _chunk_plan(shape, axes, shard_factor):
+    """(axis, chunk length) to build a large random leaf in pieces, or
+    None when one program is within budget. Chunks run along a
+    non-contracted axis (per-output-channel scales make them exactly
+    independent), preferring one the mesh does not shard — joining
+    chunks along a sharded axis costs a reshard — and a chunk along a
+    sharded axis keeps a multiple of its shard count."""
     size = 1
-    for s in shape:
-        size *= s
-    nchunks = min(n, -(-size // _INIT_CHUNK_ELEMS))
-    if nchunks <= 1:
+    for n in shape:
+        size *= n
+    nchunks = -(-size // _INIT_CHUNK_ELEMS)
+    cand = [d for d in range(len(shape)) if d not in (axes or ())]
+    if nchunks <= 1 or not cand:
         return None
-    csize = -(-n // nchunks)
-    keys = jax.random.split(k, nchunks)
-    spans = []
-    lo = 0
-    while lo < n:
-        spans.append((keys[len(spans)], min(csize, n - lo)))
-        lo += csize
-    return spans
-
-def _init_quant_chunked(k, shape, axes, dt):
-    # chunk along the largest non-contracted axis: per-output-channel
-    # scales make chunks exactly independent
-    ax = max((d for d in range(len(shape)) if d not in axes),
-             key=lambda d: shape[d])
-    spans = _chunks(k, shape, ax)
-    if spans is None:
-        return _init_quant_leaf(k, shape, axes, dt)
-    parts = []
-    for ck, clen in spans:
-        cshape = tuple(clen if d == ax else s for d, s in enumerate(shape))
-        parts.append(_init_quant_leaf(ck, cshape, axes, dt))
-    return {"q8": jnp.concatenate([p["q8"] for p in parts], axis=ax),
-            "s": jnp.concatenate([p["s"] for p in parts], axis=ax)}
+    ax = max(cand, key=lambda d: (shard_factor[d] == 1, shape[d]))
+    n, f = shape[ax], shard_factor[ax]
+    clen = -(-n // min(n, nchunks))
+    clen = -(-clen // f) * f
+    return (ax, clen) if clen < n else None
 
 
-def _init_plain_chunked(k, shape, pdt, kind):
-    spans = _chunks(k, shape, 0) if kind == "normal" and shape else None
-    if spans is None:
-        return _init_plain_leaf(k, shape, pdt, kind)
-    parts = [_init_plain_leaf(ck, (clen,) + tuple(shape[1:]), pdt, kind)
-             for ck, clen in spans]
-    return jnp.concatenate(parts, axis=0)
+def init_params_by_leaf(cfg, key: jax.Array, quant: str = "none",
+                        mesh=None) -> Params:
+    """Random-init a weight tree one leaf at a time, each leaf born in
+    the form the engines hold it in: int8 codes + scales for the matmul
+    weights when quant="int8", the compute dtype for everything else,
+    and — under a mesh — already in its partitioned layout
+    (parallel/partition.py param_specs). Neither the master-dtype float
+    tree nor an unsharded copy ever exists.
+
+    `init_params` + `quantize_int8` as two device programs peaks at the
+    full master-dtype tree (8B f32 = 32 GB — double a v5e chip's HBM);
+    fusing them into one jit does NOT help — XLA schedules the cheap
+    RNG ops ahead of the quantizations and materializes the float tree
+    anyway (measured: the fused program ResourceExhausted a v5e). So
+    each leaf is its own small program: peak = finished tree + one
+    float chunk. Leaf roles (matmul -> quantize with quantize_int8's
+    contraction axes; norm-scales -> ones; biases -> zeros; everything
+    else -> N(0, .02)) are resolved by path over init_params'
+    eval_shape tree, so the structure can't drift from the real
+    initializer. This is the no-checkpoint path of the CLI, bench.py
+    and the tools (real deployments load checkpoints via ckpt/)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from butterfly_tpu.models.common import init_params
+
+    if quant not in ("none", "int8"):
+        raise ValueError(f"unknown weight quant {quant!r}")
+    dt = jnp.dtype(cfg.dtype)
+    shapes = jax.eval_shape(_partial(init_params, cfg),
+                            jax.ShapeDtypeStruct(key.shape, key.dtype))
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    specs = [None] * len(leaves)
+    if mesh is not None:
+        from butterfly_tpu.parallel.partition import param_specs
+        specs = jax.tree.leaves(param_specs(cfg, mesh),
+                                is_leaf=lambda x: isinstance(x, P))
+        if len(specs) != len(leaves):
+            raise ValueError("param_specs does not mirror init_params")
+    keys = jax.random.split(key, len(leaves))
+    out = []
+    progs = {}  # one jit per output layout: same-shaped leaves share it
+    for (path, sd), k, spec in zip(leaves, keys, specs):
+        names = [getattr(p, "key", getattr(p, "name", "")) for p in path]
+        axes = _contraction_axes(names) if quant == "int8" else None
+        kind = "ones" if names[-1] == "scale" else \
+            "zeros" if names[-1].startswith("b") else "normal"
+        sharding, factor = None, (1,) * len(sd.shape)
+        if mesh is not None:
+            dims = tuple(spec) + (None,) * (len(sd.shape) - len(spec))
+            factor = tuple(
+                int(np.prod([mesh.shape[a] for a in
+                             (e if isinstance(e, tuple) else (e,))]))
+                if e is not None else 1 for e in dims)
+            sharding = NamedSharding(mesh, spec)
+            if axes is not None:
+                # the scale keeps the weight's spec except on its
+                # contraction dims, which keepdims collapsed to 1
+                sharding = {"q8": sharding, "s": NamedSharding(mesh, P(*[
+                    None if i in axes else e for i, e in enumerate(dims)]))}
+        prog = progs.get((spec, axes))
+        if prog is None:
+            prog = progs[spec, axes] = jax.jit(
+                _leaf_values, out_shardings=sharding,
+                static_argnames=("shape", "kind", "axes", "dt"))
+        plan = _chunk_plan(sd.shape, axes, factor) if kind == "normal" \
+            else None
+        if plan is None:
+            out.append(prog(k, shape=sd.shape, kind=kind, axes=axes, dt=dt))
+            continue
+        ax, clen = plan
+        starts = range(0, sd.shape[ax], clen)
+        parts = [prog(ck, shape=tuple(
+                     min(clen, n - lo) if d == ax else n
+                     for d, n in enumerate(sd.shape)),
+                      kind=kind, axes=axes, dt=dt)
+                 for ck, lo in zip(jax.random.split(k, len(starts)), starts)]
+        join = jax.jit(
+            lambda *ps: jax.tree.map(
+                lambda *xs: jnp.concatenate(xs, axis=ax), *ps),
+            out_shardings=sharding)
+        out.append(join(*parts))
+    return jax.tree_util.tree_unflatten(treedef, out)
 
 
 def quant_specs_like(qparams: Params, specs: Params) -> Params:

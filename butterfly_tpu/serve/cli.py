@@ -531,19 +531,26 @@ def resolve_model(args):
     return Model(cfg)
 
 
-def load_params(model, args):
-    """Load (or random-init) weights; apply --quant before any sharding."""
+def load_params(model, args, mesh=None):
+    """Weights in the form the engines hold them: --quant applied, laid
+    out over `mesh`. Without --ckpt they are random, and every leaf is
+    built directly in that form (quant/int8.py init_params_by_leaf): the
+    float tree of an 8B model is twice one chip's memory, and a tree
+    built on one device and then sharded is a whole copy on that device.
+    """
     import jax
-    if args.ckpt:
-        from butterfly_tpu.ckpt import load_checkpoint
-        params = load_checkpoint(args.ckpt, model.cfg)
-    else:
+    quant = getattr(args, "quant", "none")
+    if not args.ckpt:
+        from butterfly_tpu.quant.int8 import init_params_by_leaf
         # btf: disable=BTF006 demo mode: no-ckpt random-init weights are deliberately identical across runs
-        params = model.init(jax.random.PRNGKey(0))
-    if getattr(args, "quant", "none") == "int8":
+        return init_params_by_leaf(model.cfg, jax.random.PRNGKey(0),
+                                   quant=quant, mesh=mesh)
+    from butterfly_tpu.ckpt import load_checkpoint
+    params = load_checkpoint(args.ckpt, model.cfg)
+    if quant == "int8":
         from butterfly_tpu.quant import quantize_int8
         params = quantize_int8(params, model.cfg)
-    return params
+    return shard_for_mesh(params, model.cfg, mesh)
 
 
 def build_mesh(args):
@@ -594,6 +601,17 @@ def shard_for_mesh(params, cfg, mesh):
     return shard_params(params, cfg, mesh)
 
 
+def _device_line(engine) -> str:
+    """What a run ran on: the device as JAX reports it, and which
+    kernels the engine's programs hold (ops.record_kernels)."""
+    from butterfly_tpu.core.mesh import device_report
+    dev = device_report()
+    return (f"[butterfly] platform={dev['platform']} "
+            f"device_kind={dev['kind']!r} devices={dev['count']} "
+            f"kernels={engine.kernel_mode} "
+            f"kernel_calls={json.dumps(engine.kernel_calls, sort_keys=True)}")
+
+
 def cmd_generate(args) -> int:
     from butterfly_tpu.core.config import RuntimeConfig
     from butterfly_tpu.engine import InferenceEngine, SamplingParams
@@ -602,7 +620,7 @@ def cmd_generate(args) -> int:
     model = resolve_model(args)
     tok = load_tokenizer(args.tokenizer or args.ckpt)
     mesh = build_mesh(args)
-    params = shard_for_mesh(load_params(model, args), model.cfg, mesh)
+    params = load_params(model, args, mesh)
     engine = InferenceEngine(
         model, params,
         runtime=RuntimeConfig(max_seq_len=args.max_seq,
@@ -637,6 +655,7 @@ def cmd_generate(args) -> int:
         print(tok.decode(res.tokens[0, :n].tolist()))
         print(f"[butterfly] {n} tokens in {dt:.2f}s over "
               f"{args.seq_parallel}-way sequence parallelism", file=sys.stderr)
+        print(_device_line(engine), file=sys.stderr)
         return 0
     if args.speculate > 0:
         try:
@@ -652,6 +671,7 @@ def cmd_generate(args) -> int:
         print(f"[butterfly] {n} tokens in {dt:.2f}s via {res.forwards} "
               f"forwards ({res.tokens_per_forward:.2f} tok/forward, "
               f"{res.accepted_drafts} drafts accepted)", file=sys.stderr)
+        print(_device_line(engine), file=sys.stderr)
         return 0
     res = engine.generate([ids], sp, seed=args.seed)
     dt = time.perf_counter() - t0
@@ -660,6 +680,7 @@ def cmd_generate(args) -> int:
     print(text)
     print(f"[butterfly] {n} tokens in {dt:.2f}s "
           f"({n / dt:.1f} tok/s incl. compile)", file=sys.stderr)
+    print(_device_line(engine), file=sys.stderr)
     return 0
 
 
@@ -680,7 +701,7 @@ def cmd_bench(args) -> int:
 
     model = resolve_model(args)
     mesh = build_mesh(args)
-    params = shard_for_mesh(load_params(model, args), model.cfg, mesh)
+    params = load_params(model, args, mesh)
     stats = run_decode_benchmark(model, params, batch=args.batch,
                                  prompt_len=args.prompt_len,
                                  max_new=args.max_new, mesh=mesh,
@@ -940,6 +961,10 @@ def cmd_dash(args) -> int:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    if args.cmd in ("generate", "serve", "bench", "fleet", "workload"):
+        # the commands that compile; route/lint/dash never import jax
+        from butterfly_tpu.core.compile_cache import place_compile_cache
+        place_compile_cache()
     return {"generate": cmd_generate, "serve": cmd_serve,
             "bench": cmd_bench, "route": cmd_route,
             "fleet": cmd_fleet, "workload": cmd_workload,

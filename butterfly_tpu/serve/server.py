@@ -23,8 +23,13 @@ zero-egress environment):
   "free_pages", "inflight_depth"} — one cheap JSON probe carrying every
   load/placement signal the router AND the fleet control plane read
   (queue depth + page headroom + pipeline depth + replica role; no
-  Prometheus text scrape, no second poll path); 503 with a detail
-  string when wedged.
+  Prometheus text scrape, no second poll path); plus what the replica
+  runs on: "device" {platform, kind, count, memory: per-device
+  bytes_in_use / peak_bytes_in_use / bytes_limit where the backend
+  reports them}, "kernels" {mode: off | interpret | compiled, calls:
+  kernel call sites its programs traced, with "dense_fallback" for a
+  site that wanted a kernel and took the dense path} and "allocator"
+  (native | python). 503 with a detail string when wedged.
 * GET /kv/pages?hashes=h1,h2,...   export registered prefix-cache KV
   pages by chain hash (fleet/kvtransfer.py payload: base64 page bytes +
   geometry; the leading registered run ships, the rest come back
@@ -133,11 +138,32 @@ class StopSequenceMatcher:
         return out
 
 
+def runtime_report(sched) -> dict:
+    """What this replica runs on, for the start-up line and /health:
+    the device as JAX reports it and which page allocator the scheduler
+    got. Static for the life of the process."""
+    from butterfly_tpu.core.mesh import device_report
+    native = type(sched.alloc).__name__ == "NativePageAllocator"
+    return {"device": device_report(),
+            "allocator": "native" if native else "python"}
+
+
+def device_memory() -> list:
+    """Per local device, the allocator's bytes in use, their peak and
+    the limit — the keys the backend reports (none on the CPU). A
+    client-side query, not a device program: safe off the tick thread."""
+    import jax
+    keys = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+    return [{k: ms[k] for k in keys if k in ms}
+            for ms in (d.memory_stats() or {} for d in jax.local_devices())]
+
+
 class ServerState:
     def __init__(self, scheduler, tokenizer, max_queue: int = 256,
                  heartbeat=None, model_name: str = "butterfly",
                  role: str = "both"):
         self.sched = scheduler
+        self.runtime = runtime_report(scheduler)
         self.tok = tokenizer
         self.model_name = model_name  # echoed by /v1/completions
         # fleet placement advertisement (prefill | decode | both):
@@ -569,7 +595,14 @@ def make_handler(state: ServerState):
                             # monotonic events on the control plane's
                             # clock via offset = now_wall - probe RTT
                             # midpoint
-                            "now_wall": time.time()}
+                            "now_wall": time.time(),
+                            "device": {**state.runtime["device"],
+                                       "memory": device_memory()},
+                            "allocator": state.runtime["allocator"],
+                            "kernels": {
+                                "mode": state.sched.engine.kernel_mode,
+                                "calls": dict(
+                                    state.sched.engine.kernel_calls)}}
                     if state.heartbeat is not None:
                         body["heartbeats"] = state.heartbeat.beats
                     self._json(200, body)
@@ -1175,6 +1208,8 @@ def serve_forever(scheduler, tokenizer, host: str = "0.0.0.0",
                   heartbeat=None, model_name: str = "butterfly",
                   role: str = "both"):
     """Blocking serve loop. `ready_event` is set once listening (tests).
+    Returns when interrupted: 0, or 1 if serving was wedged (a latched
+    tick error or heartbeat failure), so the process's exit code says so.
 
     `heartbeat`: a HeartbeatMonitor to use (callers may tune interval /
     misses / probe); defaults to the LOCAL device probe. Deliberately so
@@ -1206,11 +1241,16 @@ def serve_forever(scheduler, tokenizer, host: str = "0.0.0.0",
         ready_event.set()
     try:
         httpd.serve_forever()
+    except KeyboardInterrupt:
+        pass
     finally:
         state.stop.set()
         if state.heartbeat is not None:
             state.heartbeat.stop()
         httpd.server_close()
+    if state.error:
+        print(f"[butterfly] serving was wedged: {state.error}", flush=True)
+        return 1
     return 0
 
 
@@ -1225,7 +1265,7 @@ def run_server(args) -> int:
     model = resolve_model(args)
     tok = load_tokenizer(args.tokenizer or args.ckpt)
     mesh = build_mesh(args)
-    params = load_params(model, args)
+    params = load_params(model, args, mesh)
     rt = RuntimeConfig(max_batch_size=args.max_batch,
                        max_seq_len=args.max_seq, page_size=args.page_size,
                        top_k=args.top_k, top_p=args.top_p,
@@ -1304,20 +1344,38 @@ def run_server(args) -> int:
     # doesn't pay 20-40s of XLA compile, and the heartbeat watchdog
     # never mistakes the startup compile for a dead device.
     print("[butterfly] warming serving programs...", flush=True)
-    warm_len = min(2 * rt.prefill_chunk, rt.max_seq_len - 4)
+    # The long prompt decodes for more than two blocks after its last
+    # chunk: a fused block with no prompt in flight is a program of its
+    # own (chunk width 1), and a request whose answer outlasts its
+    # prefill must not be the one that compiles it inside a tick.
+    warm_new = 2 * rt.decode_steps_per_tick + 2
+    warm_len = min(2 * rt.prefill_chunk, rt.max_seq_len - warm_new - 2)
     # a full gang of smallest-bucket prompts first (compiles the widest
     # [B, 16] batched-prefill program a burst will hit), then the long
     # chunked prompt (fresh + warm-continuation [1, T] buckets)
     gang = max(1, min(rt.prefill_max_batch, rt.max_batch_size))
     warms = [sched.submit([1], max_new_tokens=2) for _ in range(gang)]
-    warms.append(sched.submit([1] * max(1, warm_len), max_new_tokens=2))
+    warms.append(sched.submit([1] * max(1, warm_len),
+                              max_new_tokens=warm_new))
     sched.run_until_done()
     assert all(w.done for w in warms)
     mesh_desc = "" if mesh is None else \
         " mesh=" + "x".join(f"{k}{v}" for k, v in mesh.shape.items() if v > 1)
+    rep = runtime_report(sched)
+    dev = rep["device"]
     print(f"[butterfly] serving {args.model} on {args.host}:{args.port} "
           f"(slots={rt.max_batch_size}, pages={engine.cache.num_pages - 1}"
-          f"x{rt.page_size}tok{mesh_desc})", flush=True)
+          f"x{rt.page_size}tok{mesh_desc}; platform={dev['platform']} "
+          f"device_kind={dev['kind']!r} devices={dev['count']} "
+          f"kernels={engine.kernel_mode} allocator={rep['allocator']})",
+          flush=True)
+    # SIGTERM ends serving the way Ctrl-C does: serve_forever returns,
+    # and the exit code says whether serving was wedged
+    import signal
+
+    def _interrupt(signum, frame):
+        raise KeyboardInterrupt
+    signal.signal(signal.SIGTERM, _interrupt)
     return serve_forever(sched, tok, args.host, args.port,
                          max_queue=rt.max_queue, model_name=args.model,
                          role=getattr(args, "role", "both"))

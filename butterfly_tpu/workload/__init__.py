@@ -1,7 +1,7 @@
 """Workload modeling: stochastic mixed traffic for honest serving numbers.
 
 Every serving number before ISSUE 10 was earned against uniform 128/128
-closed-loop traffic (`serving_preemptions: 0` in BENCH_r05) — chunked
+closed-loop traffic (`serving_preemptions: 0` by construction) — chunked
 prefill, bucketing, preemption, the prefix cache, and the PR-8 admission
 machinery were unmeasured exactly where real traffic hits them.
 Production traces show heterogeneous prompt/decode lengths and bursty

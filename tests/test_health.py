@@ -19,16 +19,6 @@ from butterfly_tpu.obs.health import (
 
 def test_probes_pass_on_live_backend():
     assert device_probe()
-    import jax
-    if not hasattr(jax, "shard_map"):
-        # jax < 0.6 exposes shard_map only under jax.experimental;
-        # all_hosts_probe (and the whole sharded serving path) targets
-        # the top-level API, so on this runtime the collective probe is
-        # an environment gap, not a regression
-        import pytest
-        pytest.skip("jax.shard_map unavailable on this jax "
-                    f"({jax.__version__}): all_hosts_probe needs the "
-                    "top-level shard_map API")
     assert all_hosts_probe()  # psum over all 8 fake devices
 
 

@@ -897,7 +897,7 @@ def test_registry_histograms_observe_through_scheduler():
 
 
 def test_written_counts_undrained_first_token():
-    """ADVICE.md r5 off-by-one: after prefill sampled the first token
+    """r5 review off-by-one: after prefill sampled the first token
     on-device but before the stacked drain, every prompt token's K/V is
     written — _written must not subtract one (it loses a page of
     prefix-cache registration at page boundaries)."""

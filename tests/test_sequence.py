@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from butterfly_tpu.core import compat
 from butterfly_tpu.core.config import MeshConfig, tiny
 from butterfly_tpu.core.mesh import make_mesh
 from butterfly_tpu.models.common import (
@@ -44,13 +43,13 @@ def test_ring_attention_matches_dense(nq, kv):
     ref = dense_ref(q, k, v)
 
     pos = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda q, k, v, qp, kp: ring_attention(q, k, v, qp, kp),
-        mesh,
+        mesh=mesh,
         in_specs=(P(None, "seq"), P(None, "seq"), P(None, "seq"),
                   P(None, "seq"), P(None, "seq")),
-        out_specs=P(None, "seq"), axis_names={"seq"})
-    with compat.mesh_ctx(mesh):
+        out_specs=P(None, "seq"), axis_names={"seq"}, check_vma=False)
+    with jax.set_mesh(mesh):
         out = jax.jit(fn)(shard_seq(mesh, q), shard_seq(mesh, k),
                           shard_seq(mesh, v), shard_seq(mesh, pos),
                           shard_seq(mesh, pos))
@@ -72,13 +71,13 @@ def test_ulysses_matches_dense(Kv):
     ref = dense_ref(q, k, v)
 
     pos = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda q, k, v, qp: ulysses_attention(q, k, v, qp),
-        mesh,
+        mesh=mesh,
         in_specs=(P(None, "seq"), P(None, "seq"), P(None, "seq"),
                   P(None, "seq")),
-        out_specs=P(None, "seq"), axis_names={"seq"})
-    with compat.mesh_ctx(mesh):
+        out_specs=P(None, "seq"), axis_names={"seq"}, check_vma=False)
+    with jax.set_mesh(mesh):
         out = jax.jit(fn)(shard_seq(mesh, q), shard_seq(mesh, k),
                           shard_seq(mesh, v), shard_seq(mesh, pos))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -91,14 +90,14 @@ def test_ulysses_invalid_head_config_rejected():
     q = jnp.zeros((B, T, 8, H))
     k = v = jnp.zeros((B, T, 3, H))  # Kv=3: neither divides nor divides N
     pos = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-    fn = compat.shard_map(
-        lambda q, k, v, qp: ulysses_attention(q, k, v, qp), mesh,
+    fn = jax.shard_map(
+        lambda q, k, v, qp: ulysses_attention(q, k, v, qp), mesh=mesh,
         in_specs=(P(None, "seq"), P(None, "seq"), P(None, "seq"),
                   P(None, "seq")),
-        out_specs=P(None, "seq"), axis_names={"seq"})
+        out_specs=P(None, "seq"), axis_names={"seq"}, check_vma=False)
     # the body's ValueError surfaces through shard_map's tracing wrapped
     # in its own ValueError — assert the type, not the message
-    with compat.mesh_ctx(mesh), pytest.raises(ValueError):
+    with jax.set_mesh(mesh), pytest.raises(ValueError):
         fn(shard_seq(mesh, q), shard_seq(mesh, k), shard_seq(mesh, v),
            shard_seq(mesh, pos))
 
@@ -128,7 +127,7 @@ def test_sp_forward_parity(impl, arch, moe_impl):
     ref_logits, ref_cache = jax.jit(lambda p, t, c: forward(p, cfg, t, c))(
         params, tokens, cache)
 
-    with compat.mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         logits, sp_cache = jax.jit(
             lambda p, t: sp_forward(p, cfg, t, mesh, impl=impl))(
                 params, tokens)
@@ -154,7 +153,7 @@ def test_sp_forward_seq_tp_compose():
     cache = init_cache(cfg, batch=2, max_seq=16)
     ref_logits, _ = jax.jit(lambda p, t, c: forward(p, cfg, t, c))(
         params, tokens, cache)
-    with compat.mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         logits, _ = jax.jit(
             lambda p, t: sp_forward(p, cfg, t, mesh, impl="ring"))(
                 sparams, tokens)
@@ -198,7 +197,7 @@ def test_sp_decode_parity(arch, kv):
 
     # SP: prefill leaves the prefix sharded over seq; decode merges
     # per-device partials + the replicated suffix cache
-    with compat.mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         logits, prefix = jax.jit(
             lambda p, t: sp_forward(p, cfg, t, mesh, impl="ring"))(
                 params, tokens)
@@ -265,7 +264,8 @@ def test_generate_long_cli_parity(capsys):
 
     cfg = tiny("llama", dtype="float32", param_dtype="float32")
     tok = ByteTokenizer()
-    params = Model(cfg).init(jax.random.PRNGKey(0))  # CLI random-init seed
+    from butterfly_tpu.quant.int8 import init_params_by_leaf
+    params = init_params_by_leaf(cfg, jax.random.PRNGKey(0))  # as the CLI
     eng = InferenceEngine(Model(cfg), params)
     ids = tok.encode("hello")
     stop = tok.eos_id if tok.eos_id is not None else -1

@@ -1,0 +1,436 @@
+#!/usr/bin/env python
+"""Compile every Pallas kernel variant on the chip and check it.
+
+Tier-1 runs on the CPU, where a kernel is interpreted or gives way to
+`jnp`; only Mosaic on a real chip says whether a kernel compiles. This
+program builds each variant the engines use, at the Llama-3-8B head
+geometry (32 query heads over 8 KV heads of 128, page 16, the serving
+window width), and for each one:
+
+* compiles it and looks for the Mosaic call (`tpu_custom_call`) in the
+  compiled HLO text;
+* runs it and compares with the `jnp` reference the dense paths use
+  (`models.common.attend`, `ops.ring_attention.ring_block_stats_ref`).
+
+Variants: flash fresh; flash warm over a float prefix and over an int8
+prefix; paged plain, int8, and both with the write-combined window
+segment; ring float and int8. On a host with four or more devices each
+runs again under the mesh wrappers the engines call (`*_sharded` on a
+tensor=4 mesh: 8 query and 2 KV heads per shard; the ring under
+`shard_map` on a seq=4 mesh).
+
+Last, the program `serve` spends its time in — the serving engine's
+fused decode block, at the full 8B width with the depth cut to two
+layers — is compiled the way the engine compiles it, alone and on the
+tensor=4 mesh, and its HLO must hold the Mosaic call (on the mesh, the
+all-reduces too): a layer that quietly took the dense gather path
+(cache/paged.py) would compile and serve, and only this shows it.
+
+Exit code 0 only if every variant compiled to a Mosaic call and agreed
+with its reference. On the CPU backend the kernels are interpreted, so
+the run proves nothing about the compiler and exits 1 (`--small` cuts
+the shapes so that such a run finishes: a debugging aid, not a check).
+The last line of output is one JSON object; `--out FILE` also writes it.
+"""
+from __future__ import annotations
+
+import argparse
+import functools
+import json
+import sys
+import time
+from pathlib import Path
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+MOSAIC_CALL = "tpu_custom_call"
+
+
+def build_cases(small: bool, windows):
+    """[(name, kernel_fn, reference_fn, args, sharded_fn or None)].
+    kernel/reference take the same args and return comparable arrays;
+    `sharded_fn(mesh)` returns (fn, args placed for the mesh)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from butterfly_tpu.cache.paged import (gather_paged_layer,
+                                           gather_paged_layer_q)
+    from butterfly_tpu.models.common import attend, quantize_kv
+    from butterfly_tpu.ops.flash_attention import (flash_attention,
+                                                   flash_attention_sharded)
+    from butterfly_tpu.ops.paged_attention import (paged_attention,
+                                                   paged_attention_sharded)
+    from butterfly_tpu.ops.ring_attention import (INVALID_POS,
+                                                  ring_block_stats,
+                                                  ring_block_stats_ref)
+
+    Nq, Kv, H, page = (8, 4, 128, 16) if small else (32, 8, 128, 16)
+    B, T, Sp = (1, 32, 64) if small else (2, 256, 2048)
+    S, max_pages, P = (4, 4, 17) if small else (32, 128, 513)
+    rng = np.random.RandomState(0)
+    dt = jnp.bfloat16
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), dt)
+
+    def f32(x):
+        return x.astype(jnp.float32)
+
+    def kv_major_q(x):
+        """[.., S, Kv, H] float -> int8 codes [.., Kv, S, H] + scales
+        [.., Kv, S] (the pool representation)."""
+        codes, scales = quantize_kv(x)
+        return jnp.moveaxis(codes, -3, -2), jnp.moveaxis(scales, -2, -1)
+
+    cases = []
+
+    # -- flash ---------------------------------------------------------
+    q, k, v = normal(B, T, Nq, H), normal(B, T, Kv, H), normal(B, T, Kv, H)
+    pos = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
+    causal = pos[:, None, :] <= pos[:, :, None]
+    pk, pv = normal(B, Sp, Kv, H), normal(B, Sp, Kv, H)
+    plen = jnp.asarray([40] if small else [300, 0], jnp.int32)
+    warm_mask = jnp.concatenate(
+        [jnp.broadcast_to((jnp.arange(Sp)[None] < plen[:, None])[:, None],
+                          (B, T, Sp)), causal], axis=2)
+
+    def flash(q, k, v, pk=None, pv=None, plen=None, pks=None, pvs=None,
+              fn=flash_attention):
+        return fn(q, k, v, causal=True, prefix_k=pk, prefix_v=pv,
+                  prefix_len=plen, prefix_k_scale=pks, prefix_v_scale=pvs)
+
+    def flash_ref(q, k, v, pk=None, pv=None, plen=None, pks=None, pvs=None):
+        if pk is None:
+            n = q.shape[1]
+            return attend(f32(q), f32(k), f32(v), causal[:, :n, :n], None)
+        if pks is not None:  # codes [B,Kv,Sp,H] -> float [B,Sp,Kv,H]
+            pk = jnp.moveaxis(f32(pk) * pks[..., None], 1, 2)
+            pv = jnp.moveaxis(f32(pv) * pvs[..., None], 1, 2)
+        return attend(f32(q), jnp.concatenate([f32(pk), f32(k)], 1),
+                      jnp.concatenate([f32(pv), f32(v)], 1), warm_mask, None)
+
+    flash_sh = functools.partial(flash, fn=flash_attention_sharded)
+    pkq, pks = kv_major_q(pk)
+    pvq, pvs = kv_major_q(pv)
+    for name, args in (
+            ("flash_fresh", (q, k, v)),
+            # a prompt that is no whole number of tiles (`generate`)
+            ("flash_fresh_t20", (q[:, :20], k[:, :20], v[:, :20])),
+            ("flash_warm", (q, k, v, pk, pv, plen)),
+            ("flash_warm_int8", (q, k, v, pkq, pvq, plen, pks, pvs))):
+        cases.append((name, flash, flash_ref, args, flash_sh))
+
+    # -- paged ---------------------------------------------------------
+    qd = normal(S, Nq, H)
+    kp, vp = normal(P, Kv, page, H), normal(P, Kv, page, H)
+    table = jnp.asarray(rng.randint(0, P - 1, (S, max_pages)), jnp.int32)
+    lens = jnp.asarray(rng.randint(0, max_pages * page + 1, (S,)), jnp.int32)
+    lens = lens.at[0].set(0).at[1].set(max_pages * page)
+    kq, ks = quantize_kv(jnp.moveaxis(kp, 1, 2))   # [P,page,Kv,H] in
+    vq, vs = quantize_kv(jnp.moveaxis(vp, 1, 2))
+    kpq, vpq = jnp.moveaxis(kq, 1, 2), jnp.moveaxis(vq, 1, 2)  # [P,Kv,pg,H]
+    ksp = jnp.moveaxis(ks, 1, 2).reshape(P, Kv * page)         # kv-major
+    vsp = jnp.moveaxis(vs, 1, 2).reshape(P, Kv * page)
+    smax = max_pages * page
+
+    def paged(qd, kp, vp, table, lens, ksp=None, vsp=None, wk=None,
+              wv=None, wcnt=None, wks=None, wvs=None, fn=paged_attention):
+        return fn(qd, kp, vp, table, lens, ksp, vsp, win_k=wk, win_v=wv,
+                  win_count=wcnt, win_k_scale=wks, win_v_scale=wvs)
+
+    def paged_ref(qd, kp, vp, table, lens, ksp=None, vsp=None, wk=None,
+                  wv=None, wcnt=None, wks=None, wvs=None):
+        """Dense gather + attend (cache/paged.py's dense path), the
+        window appended as one more key segment; a slot with no keys
+        reads 0, as the kernel leaves an inactive slot."""
+        mask = (jnp.arange(smax)[None] < lens[:, None])[:, None]
+        if wk is not None:
+            mask = jnp.concatenate(
+                [mask, (jnp.arange(wk.shape[2])[None]
+                        < wcnt[:, None])[:, None]], 2)
+        if ksp is None:
+            ck, cv = (f32(gather_paged_layer(a, table)) for a in (kp, vp))
+            if wk is not None:  # window [S,Kv,W,H] -> [S,W,Kv,H]
+                ck, cv = (jnp.concatenate([a, f32(jnp.moveaxis(w, 1, 2))], 1)
+                          for a, w in ((ck, wk), (cv, wv)))
+            out = attend(f32(qd)[:, None], ck, cv, mask, None)
+        else:
+            ck, k_s = gather_paged_layer_q(kp, ksp, table)
+            cv, v_s = gather_paged_layer_q(vp, vsp, table)
+            if wk is not None:
+                ck, cv, k_s, v_s = (
+                    jnp.concatenate([a, w], 2) for a, w in
+                    ((ck, wk), (cv, wv), (k_s, wks), (v_s, wvs)))
+            out = attend(f32(qd)[:, None], ck, cv, mask, None, k_s, v_s)
+        return jnp.where(mask.any(-1)[:, :, None, None], out, 0)[:, 0]
+
+    paged_sh = functools.partial(paged, fn=paged_attention_sharded)
+    paged_args = [("paged", (qd, kp, vp, table, lens)),
+                  ("paged_int8", (qd, kpq, vpq, table, lens, ksp, vsp))]
+    for W in windows:
+        wk, wv = normal(S, Kv, W, H), normal(S, Kv, W, H)
+        wkq, wks = kv_major_q(jnp.moveaxis(wk, 1, 2))
+        wvq, wvs = kv_major_q(jnp.moveaxis(wv, 1, 2))
+        wcnt = jnp.asarray(rng.randint(0, W + 1, (S,)), jnp.int32)
+        wcnt = wcnt.at[0].set(1).at[1].set(W)
+        paged_args += [
+            (f"paged_win{W}", (qd, kp, vp, table, lens, None, None,
+                               wk, wv, wcnt)),
+            (f"paged_int8_win{W}", (qd, kpq, vpq, table, lens, ksp, vsp,
+                                    wkq, wvq, wcnt, wks, wvs))]
+    for name, args in paged_args:
+        cases.append((name, paged, paged_ref, args, paged_sh))
+
+    # -- ring ----------------------------------------------------------
+    Tr = 64 if small else 512
+    qr, kr, vr = normal(1, Tr, Nq, H), normal(1, Tr, Kv, H), \
+        normal(1, Tr, Kv, H)
+    q_pos = jnp.arange(Tr, 2 * Tr, dtype=jnp.int32)[None]
+    k_pos = jnp.arange(Tr // 2, Tr // 2 + Tr, dtype=jnp.int32)[None]
+    k_pos = k_pos.at[0, -3:].set(INVALID_POS)
+
+    def normalized(stats):
+        """(m, l, acc / l): the sums are compared as the attention
+        output they finalize to — the MXU rounds the probabilities of
+        p @ v to bf16, an error that scales with l like acc does."""
+        m, l, acc = stats
+        return m, l, acc / jnp.maximum(l, 1e-30)[..., None]
+
+    def ring(q, k, v, qp, kp, *scales):
+        return normalized(ring_block_stats(q, k, v, qp, kp, *scales))
+
+    def ring_ref(q, k, v, qp, kp, *scales):
+        k, v = (a if scales else f32(a) for a in (k, v))
+        return normalized(
+            ring_block_stats_ref(f32(q), k, v, qp, kp, *scales))
+
+    cases.append(("ring", ring, ring_ref, (qr, kr, vr, q_pos, k_pos), None))
+    krq, krs = kv_major_q(kr)
+    vrq, vrs = kv_major_q(vr)
+    cases.append(("ring_int8", ring, ring_ref,
+                  (qr, krq, vrq, q_pos, k_pos, krs, vrs), None))
+    return cases
+
+
+def ring_sharded_case(small: bool):
+    """The ring as the SP paths run it: `parallel.sequence.ring_attention`
+    under shard_map over a seq=4 mesh, against dense causal attention."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from butterfly_tpu.core.config import MeshConfig
+    from butterfly_tpu.core.mesh import make_mesh
+    from butterfly_tpu.models.common import attend
+    from butterfly_tpu.parallel.sequence import ring_attention
+
+    Nq, Kv, H, T = (8, 4, 128, 64) if small else (32, 8, 128, 1024)
+    mesh = make_mesh(MeshConfig(seq=4), jax.devices()[:4])
+    rng = np.random.RandomState(1)
+    seq = NamedSharding(mesh, P(None, "seq"))
+    q, k, v = (jax.device_put(jnp.asarray(rng.standard_normal((1, T, n, H)),
+                                          jnp.bfloat16), seq)
+               for n in (Nq, Kv, Kv))
+    pos = jax.device_put(jnp.arange(T, dtype=jnp.int32)[None], seq)
+    fn = jax.shard_map(
+        lambda q, k, v, p: ring_attention(q, k, v, p, p), mesh=mesh,
+        in_specs=(P(None, "seq"),) * 4, out_specs=P(None, "seq"),
+        axis_names={"seq"}, check_vma=False)
+
+    def ref(q, k, v, p):
+        f = jnp.float32
+        return attend(q.astype(f), k.astype(f), v.astype(f),
+                      p[:, None, :] <= p[:, :, None], None)
+
+    return mesh, fn, ref, (q, k, v, pos)
+
+
+def tp_place(mesh, name, args):
+    """Operands laid out as the partitioner lays them out on a tensor
+    mesh: heads over `tensor` (parallel/partition.py paged_cache_specs,
+    kv_window_specs, warm_prefix_specs), everything else replicated."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    t = "tensor"
+    if name.startswith("flash"):
+        quant = len(args) > 6
+        pre = P(None, t, None, None) if quant else P(None, None, t, None)
+        specs = [P(None, None, t, None)] * 3 + [pre, pre, P()] \
+            + [P(None, t, None)] * 2
+    else:  # q, pools, table, lens, pool scales, window, count, its scales
+        specs = [P(None, t, None)] + [P(None, t, None, None)] * 2 \
+            + [P(), P()] + [P(None, t)] * 2 \
+            + [P(None, t, None, None)] * 2 + [P()] + [P(None, t, None)] * 2
+    return tuple(None if a is None
+                 else jax.device_put(a, NamedSharding(mesh, s))
+                 for a, s in zip(args, specs))
+
+
+def serving_block_hlo(small: bool, mesh):
+    """Compiled HLO of the serving engine's fused decode block (mixed
+    block at chunk width 1, write-combined int8 window) at the 8B width,
+    two layers deep, built exactly as `serve` builds it; plus the kernel
+    call sites the engine recorded while it traced."""
+    import jax
+    import jax.numpy as jnp
+
+    from butterfly_tpu.core.config import RuntimeConfig, llama3_8b
+    from butterfly_tpu.engine.serving import ServingEngine
+    from butterfly_tpu.models.common import Model
+    from butterfly_tpu.quant.int8 import init_params_by_leaf
+
+    cfg = llama3_8b().replace(num_layers=2, max_seq_len=2048)
+    if small:
+        cfg = cfg.replace(vocab_size=512, hidden_size=256, num_heads=8,
+                          num_kv_heads=4, intermediate_size=512)
+    S, k = 8, 4
+    rt = RuntimeConfig(max_batch_size=S, max_seq_len=256, kv_quant="int8",
+                       decode_steps_per_tick=k)
+    params = init_params_by_leaf(cfg, jax.random.PRNGKey(0), quant="int8",
+                                 mesh=mesh)
+    eng = ServingEngine(Model(cfg), params, rt, mesh=mesh, use_kernels=True)
+    eng._ensure_window(k)
+    i32 = functools.partial(jnp.zeros, dtype=jnp.int32)
+    with eng._mesh_ctx():
+        hlo = eng._mixed_block_win_prog(k, 1).lower(
+            eng.params, i32((S,)), i32((S,)), eng.cache, eng._kv_window,
+            eng._win_len, i32((S, eng.cache.max_seq)), i32((S,)),
+            jnp.ones((S,), bool), jnp.zeros((S,), jnp.float32),
+            jnp.full((S,), -1, jnp.int32), jnp.full((S,), k, jnp.int32),
+            0, 1.0, jax.random.PRNGKey(0)).compile().as_text()
+    return hlo, dict(eng.kernel_calls)
+
+
+def run_serving_block(name, small, mesh, want):
+    rec = {"name": name, "ok": False}
+    t0 = time.perf_counter()
+    try:
+        hlo, calls = serving_block_hlo(small, mesh)
+        rec["compile_s"] = round(time.perf_counter() - t0, 2)
+        rec["hlo_has"] = {w: w in hlo for w in want}
+        rec["kernel_calls"] = calls
+        rec["ok"] = bool(all(rec["hlo_has"].values())
+                         and "dense_fallback" not in calls
+                         and any(c.startswith("paged_int8_win")
+                                 for c in calls))
+    except Exception as e:
+        rec["error"] = f"{type(e).__name__}: {e}"[:1500]
+    return rec
+
+
+def run_case(name, fn, ref, args, mesh=None, want=(MOSAIC_CALL,)):
+    """Compile `fn`, look for `want` in its HLO, run it, compare."""
+    import jax
+    import numpy as np
+
+    from butterfly_tpu.core.mesh import mesh_ctx
+
+    rec = {"name": name, "ok": False}
+    t0 = time.perf_counter()
+    try:
+        with mesh_ctx(mesh):
+            compiled = jax.jit(fn).lower(*args).compile()
+            hlo = compiled.as_text()
+            out = jax.block_until_ready(compiled(*args))
+        rec["compile_run_s"] = round(time.perf_counter() - t0, 2)
+        rec["hlo_has"] = {w: w in hlo for w in want}
+        with jax.default_matmul_precision("highest"):
+            want_out = jax.jit(ref)(*args)
+        # |a - b| / (1 + |b|): absolute near zero, relative for the
+        # ring's unnormalized sums (and 0 on its -1e30 "masked" maxima)
+        errs = [float(np.max(np.abs(a - b) / (1 + np.abs(b))))
+                for a, b in zip(
+                    (np.asarray(x, np.float32) for x in jax.tree.leaves(out)),
+                    (np.asarray(x, np.float32)
+                     for x in jax.tree.leaves(want_out)))]
+        rec["max_err"] = round(max(errs), 5)
+        finite = all(np.isfinite(np.asarray(a, np.float32)).all()
+                     for a in jax.tree.leaves(out))
+        # same bf16 operands on both sides; the kernel's f32 dots may
+        # run at the MXU's default precision, the reference at highest
+        rec["ok"] = bool(finite and max(errs) < 3e-2
+                         and all(rec["hlo_has"].values()))
+    except Exception as e:  # a compiler refusal is the finding: record it
+        rec["error"] = f"{type(e).__name__}: {e}"[:1500]
+    return rec
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--windows", default="256,16",
+                    help="window widths to check: inflight_blocks x "
+                         "decode_steps_per_tick x chunk width; 256 is "
+                         "`serve --decode-steps-per-tick 4` (chip_smoke), "
+                         "16 is two blocks of 8 with mixed dispatch off")
+    ap.add_argument("--small", action="store_true",
+                    help="cut shapes so an interpreted CPU run finishes "
+                         "(debugging aid; such a run still exits 1)")
+    ap.add_argument("--only", default="",
+                    help="run only the cases whose name contains this "
+                         "(e.g. '@' = the mesh cases, already proven alone)")
+    ap.add_argument("--out", default=None, help="also write the JSON here")
+    args = ap.parse_args()
+
+    import jax
+
+    from butterfly_tpu.core.compile_cache import place_compile_cache
+    from butterfly_tpu.core.config import MeshConfig
+    from butterfly_tpu.core.mesh import make_mesh
+    from butterfly_tpu.ops import kernel_mode
+
+    place_compile_cache()
+    devs = jax.devices()
+    mode = kernel_mode(True)
+    windows = [int(w) for w in args.windows.split(",") if w]
+    cases = build_cases(args.small, windows)
+    want = (MOSAIC_CALL,) if mode == "compiled" else ()
+    def wanted(name):
+        return args.only in name
+
+    results = [run_case(n, k, r, a, want=want)
+               for n, k, r, a, _ in cases if wanted(n)]
+    if wanted("serve_block"):
+        results.append(run_serving_block("serve_block", args.small, None,
+                                         want))
+    if len(devs) >= 4:
+        mesh = make_mesh(MeshConfig(tensor=4), devs[:4])
+        for n, _, r, a, sharded in cases:
+            if sharded is not None and wanted(n + "@tp4"):
+                results.append(run_case(n + "@tp4", sharded, r,
+                                        tp_place(mesh, n, a), mesh, want))
+        if wanted("ring@sp4"):
+            smesh, fn, ref, a = ring_sharded_case(args.small)
+            results.append(run_case("ring@sp4", fn, ref, a, smesh,
+                                    want + ("collective-permute",)))
+        if wanted("serve_block@tp4"):
+            results.append(run_serving_block(
+                "serve_block@tp4", args.small, mesh, want + ("all-reduce",)))
+    for r in results:
+        print(f"{'ok  ' if r['ok'] else 'FAIL'} {r['name']:<24}"
+              f" err={r.get('max_err')} hlo={r.get('hlo_has')}"
+              f" t={r.get('compile_run_s', r.get('compile_s'))}"
+              + (f" calls={r['kernel_calls']}" if "kernel_calls" in r else "")
+              + (f"\n     {r['error']}" if "error" in r else ""))
+    ok = mode == "compiled" and all(r["ok"] for r in results)
+    if mode != "compiled":
+        print(f"chip_kernels: kernels were {mode}ed on platform "
+              f"{devs[0].platform!r}, not compiled — this run says nothing "
+              "about Mosaic", file=sys.stderr)
+    summary = {"ok": ok, "kernels": mode,
+               "device": {"platform": devs[0].platform,
+                          "kind": devs[0].device_kind, "count": len(devs)},
+               "passed": sum(r["ok"] for r in results),
+               "failed": [r["name"] for r in results if not r["ok"]],
+               "results": results}
+    line = json.dumps(summary)
+    if args.out:
+        Path(args.out).parent.mkdir(parents=True, exist_ok=True)
+        Path(args.out).write_text(line + "\n")
+    print(line)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

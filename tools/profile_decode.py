@@ -3,8 +3,8 @@
 
 Captures a trace of ONLY the fused decode program (prefill + first sample
 run outside the trace window), converts the xplane with xprof's
-`hlo_stats` tool, and prints the top HLO ops by self time — the artifact
-VERDICT r4 item 2 asks for (docs/decode_profile_r5.md).
+`hlo_stats` tool, and prints the top HLO ops by self time. A device
+trace needs a device: without an accelerator this exits non-zero.
 
 `--serving` traces the SERVING path's fused decode block instead: one
 Scheduler tick's k-step jitted scan (engine._decode_scan) over the paged
@@ -119,50 +119,36 @@ def main() -> int:
                          "paged pool) plus one fused decode block "
                          "beside it — the ISSUE 20 scheduler lane. "
                          "Builds a seq=4 mesh; the device count must be "
-                         "a multiple of 4 (on CPU, 8 host devices are "
-                         "forced like tests/conftest.py)")
+                         "a multiple of 4")
     args = ap.parse_args()
-
-    if args.long_context:
-        # must land before the first jax import initializes the backend
-        import os
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count=8").strip()
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from butterfly_tpu.core.config import ModelConfig, RuntimeConfig, tiny
+    from butterfly_tpu.core.compile_cache import place_compile_cache
+    from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
     from butterfly_tpu.engine import InferenceEngine, SamplingParams
     from butterfly_tpu.engine.engine import pad_prompts
     from butterfly_tpu.engine.sampling import sample
     from butterfly_tpu.models.common import Model
-    from butterfly_tpu.quant.int8 import (init_params_quantized,
-                                          quantize_int8)
+    from butterfly_tpu.obs.benchmark import require_chip
+    from butterfly_tpu.quant.int8 import init_params_by_leaf
 
-    on_tpu = jax.devices()[0].platform != "cpu"
-    if on_tpu and args.preset == "8b":
+    require_chip("tools/profile_decode.py")
+    place_compile_cache()
+    if args.preset == "8b":
         from butterfly_tpu.core.config import llama3_8b
         cfg = llama3_8b().replace(max_seq_len=2048)
-    elif on_tpu:
+    else:
         cfg = ModelConfig(arch="llama", vocab_size=32000, hidden_size=2048,
                           num_layers=16, num_heads=16, num_kv_heads=8,
                           head_dim=128, intermediate_size=5632,
                           max_seq_len=2048)
-    else:
-        if args.preset != "1b":
-            print(f"warning: no TPU visible — profiling the tiny CPU "
-                  f"config, NOT --preset {args.preset}", file=sys.stderr)
-        cfg = tiny("llama", dtype="float32", param_dtype="float32")
-        args.batch, args.prompt_len, args.max_new = 4, 32, 16
 
     model = Model(cfg)
-    params = init_params_quantized(cfg, jax.random.PRNGKey(0)) if on_tpu \
-        else quantize_int8(model.init(jax.random.PRNGKey(0)), cfg)
-    kv_quant = "int8" if on_tpu else "none"
+    params = init_params_by_leaf(cfg, jax.random.PRNGKey(0), quant="int8")
+    kv_quant = "int8"
     if args.long_context:
         return _profile_longctx(args, model, params, kv_quant)
     if args.prefill:
@@ -229,8 +215,7 @@ def _profile_serving_block(args, model, params, kv_quant: str) -> int:
     k = args.steps_per_tick
     cfg = model.cfg
     # budget for the warmup blocks PLUS the traced one (a request that
-    # finishes during warmup would leave the traced dispatch a no-op —
-    # the CPU fallback's max_new=16 is smaller than one k=16 block);
+    # finishes during warmup would leave the traced dispatch a no-op);
     # prefill_chunk sized to admit the whole batch in one tick: the
     # warmup then costs ~3 ticks, so slots can't finish (and free)
     # before the trace window captures a FULL-batch block

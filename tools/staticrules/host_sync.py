@@ -1,9 +1,10 @@
 """BTF003 — no host synchronization inside the dispatch hot path.
 
-Past incident class: the BENCH_r05 serving-vs-engine gap (502 vs 6,988
-tok/s on the same chip) was host-bound — every per-token host<->device
-round trip (``int(np.asarray(tok))`` and friends) serialized the device
-behind the host section (ROADMAP item 1). PRs 3/5/9 rebuilt the tick
+Past incident class: a serving loop an order of magnitude slower than
+the isolated decode on the same chip, because it was host-bound — every
+per-token host<->device round trip (``int(np.asarray(tok))`` and
+friends) serialized the device behind the host section (ROADMAP A1:
+502 against 6,988 tok/s on the r5 machine). PRs 3/5/9 rebuilt the tick
 around dispatch-ahead precisely so the HOT functions (tick, operand
 assembly, block dispatch) never materialize a device value; draining is
 where synchronization is *intended* and the drain functions are
@@ -150,5 +151,6 @@ class HostSyncRule(Rule):
                 yield self.finding(
                     ctx, node,
                     f"{name}() over a device-carry value {where} is a "
-                    f"per-token host readback (the BENCH_r05 serving-"
-                    f"gap shape) — keep the value device-resident")
+                    f"per-token host readback (it serializes the "
+                    f"device behind the host) — keep the value "
+                    f"device-resident")
