@@ -200,6 +200,7 @@ def write_paged_layer(k_pages: jax.Array, v_pages: jax.Array,
     return k_pages, v_pages, None, None
 
 
+@jax.named_scope("kv_gather")
 def gather_paged_layer(pages: jax.Array, page_table: jax.Array) -> jax.Array:
     """One layer's pages -> contiguous [B, S_max, Kv, H] view (XLA Gather)."""
     Pp, Kv, page, H = pages.shape
@@ -209,6 +210,7 @@ def gather_paged_layer(pages: jax.Array, page_table: jax.Array) -> jax.Array:
     return out.reshape(B, max_pages * page, Kv, H)
 
 
+@jax.named_scope("kv_gather")
 def gather_paged_layer_q(pages: jax.Array, scale_pages: jax.Array,
                          page_table: jax.Array):
     """Quantized gather: codes [B, Kv, S, H] + scales [B, Kv, S] — the
@@ -300,6 +302,7 @@ def init_kv_window(cache: PagedKVCache, width: int,
     return jax.jit(build, out_shardings=shardings)()
 
 
+@jax.named_scope("kv_window_write")
 def stage_window_layer(wk, wv, k, v, win_len, wks=None, wvs=None):
     """Stage one layer's fresh K/V into its window slice.
 
@@ -330,6 +333,7 @@ def stage_window_layer(wk, wv, k, v, win_len, wks=None, wvs=None):
     return wk, wv, None, None
 
 
+@jax.named_scope("kv_gather")
 def insert_window_view(view, wl, base):
     """Insert a layer's window entries into the gathered float view at
     their absolute positions: view [B, S_max, Kv, H], wl [S, Kv, W, H],
@@ -346,6 +350,7 @@ def insert_window_view(view, wl, base):
         wl.transpose(0, 2, 1, 3), mode="drop")
 
 
+@jax.named_scope("kv_gather")
 def insert_window_view_q(codes, scales, wl, wsl, base):
     """Quantized twin: codes [B, Kv, S_max, H] + scales [B, Kv, S_max]
     gain the window's codes wl [S, Kv, W, H] + scales wsl [S, Kv, W] at

@@ -42,6 +42,7 @@ def _filter_logits(scaled: jax.Array, top_k: int, top_p: float) -> jax.Array:
     return scaled
 
 
+@jax.named_scope("sample")
 def speculative_accept(logits: jax.Array, drafts: jax.Array, key: jax.Array,
                        temps: jax.Array, top_k: int, top_p: float,
                        spec_mask: jax.Array = None,
@@ -196,6 +197,7 @@ def tree_ancestor_matrix(width: int, nodes: int) -> np.ndarray:
     return anc
 
 
+@jax.named_scope("sample")
 def speculative_tree_accept(logits: jax.Array, drafts: jax.Array,
                             key: jax.Array, temps: jax.Array,
                             top_k: int, top_p: float,

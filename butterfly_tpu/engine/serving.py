@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from butterfly_tpu.cache.paged import (
     KVWindow, PagedKVCache, flush_paged_window, init_kv_window,
@@ -66,6 +67,26 @@ from butterfly_tpu.engine.sampling import (
     _filter_logits, speculative_accept, speculative_tree_accept,
     tree_ancestor_matrix, tree_depth, tree_node_index)
 from butterfly_tpu.models.common import Model
+
+
+def named(fn, name: str):
+    """`fn` (a partial or a closure built here, never a shared
+    function) under a stable `__name__`. jax.jit names its program
+    after it, so the program reaches a device trace's `XLA Modules`
+    as `jit_<name>` (a bare partial is `jit__unknown`) and a trace can
+    tell a mixed block from a decode block. Static sizes (k, C, rounds)
+    stay out of the name: one name per kind of program."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+def _block_name(C: int) -> str:
+    """A plain mixed block's program name. With chunk width 1 no lane
+    prefills (the scheduler collapses C to 1 only then): the program
+    is a decode block in shape and in use, and takes the decode
+    block's name, so that a trace splits token generation from prompt
+    processing."""
+    return "bf_decode_block" if C == 1 else "bf_mixed_block"
 
 
 def bucket_len(n: int, lo: int = 16, hi: Optional[int] = None) -> int:
@@ -100,6 +121,7 @@ def bucket_batch(n: int, hi: int) -> int:
     return min(b, hi)
 
 
+@jax.named_scope("sample")
 def sample_batched(logits: jax.Array, key: jax.Array, temps: jax.Array,
                    top_k: int, top_p: float) -> jax.Array:
     """Per-slot-temperature sampling: temp 0 rows are greedy. [S,V]->[S]."""
@@ -315,13 +337,16 @@ class ServingEngine:
         warm_cfg = prefill_cfg if self.runtime.prefill_flash_warm \
             else self.cfg
         self._prefill = jax.jit(
-            partial(_prefill_slot, prefill_cfg, True, fwd),
+            named(partial(_prefill_slot, prefill_cfg, True, fwd),
+                  "bf_prefill"),
             donate_argnums=(2,))
         self._prefill_warm = jax.jit(
-            partial(_prefill_slot, warm_cfg, False, fwd),
+            named(partial(_prefill_slot, warm_cfg, False, fwd),
+                  "bf_prefill_warm"),
             donate_argnums=(2,))
         self._decode = jax.jit(
-            partial(_decode_all, self.cfg, fwd, use_kernel=use_kernels),
+            named(partial(_decode_all, self.cfg, fwd,
+                          use_kernel=use_kernels), "bf_decode_step"),
             static_argnums=(5, 6), donate_argnums=(2,))
         # Fused decode blocks: one jitted program per block width k
         # (_decode_scan — k is a static scan length). Built lazily; a
@@ -329,6 +354,10 @@ class ServingEngine:
         # once in practice.
         self._fwd = fwd
         self._use_kernels = use_kernels
+        # what _launch last called and how many calls it has made: the
+        # scheduler's tick record and the launch span carry both
+        self.last_program: Optional[str] = None
+        self.blocks_launched = 0
         self._decode_blocks: Dict[int, object] = {}
         # Write-combined KV decode window (RuntimeConfig.kv_write_combine,
         # default on): fused decode/spec blocks stage fresh K/V into an
@@ -426,6 +455,23 @@ class ServingEngine:
         kernel record for whatever the dispatch has to trace."""
         with mesh_ctx(self.mesh), record_kernels(self.kernel_calls):
             yield
+
+    def _put(self, *operands):
+        """The block table and a dispatch's host operands, each a
+        (value, dtype or None) pair, to the device under one span."""
+        with TraceAnnotation("bf.tick.dispatch.put"):
+            self._sync_table()
+            return [jnp.asarray(v, dt) for v, dt in operands]
+
+    def _launch(self, prog, *args):
+        """Call a jitted program under its launch span (the caller
+        holds _mesh_ctx). The span and the tick record name it."""
+        self.blocks_launched += 1
+        self.last_program = prog.__name__
+        with TraceAnnotation("bf.tick.dispatch.launch",
+                             program=self.last_program,
+                             block=self.blocks_launched):
+            return prog(*args)
 
     @property
     def num_slots(self) -> int:
@@ -626,16 +672,16 @@ class ServingEngine:
                               slots=list(slots), batch=B, batch_bucket=Bb,
                               tokens=int(sum(len(c) for c in chunks)),
                               bucket=T, fresh=fresh)
-        self._sync_table()
+        buf, rows, lens_dev, sts_dev = self._put(
+            (buf, None), (rows, None), (lens, None), (sts, None))
         with self._mesh_ctx():
             # pools are donated (scatters land in place); the table rows
             # ride separately so the donation set has no unaliasable
             # leaves (the rows have no matching output)
             pools = (self.cache.k_pages, self.cache.v_pages,
                      self.cache.k_scale_pages, self.cache.v_scale_pages)
-            logits, pools = prog(
-                self.params, jnp.asarray(buf), pools, jnp.asarray(rows),
-                jnp.asarray(lens), jnp.asarray(sts))
+            logits, pools = self._launch(
+                prog, self.params, buf, pools, rows, lens_dev, sts_dev)
             new_lens = jnp.asarray(sts[:B] + lens[:B])
             self.cache = self.cache._replace(
                 k_pages=pools[0], v_pages=pools[1],
@@ -744,7 +790,7 @@ class ServingEngine:
         pool_sh = tuple(None if p is None else p.sharding for p in (
             self.cache.k_pages, self.cache.v_pages,
             self.cache.k_scale_pages, self.cache.v_scale_pages))
-        prog = jax.jit(run, donate_argnums=(2,),
+        prog = jax.jit(named(run, "bf_sp_chunk"), donate_argnums=(2,),
                        out_shardings=(replicated(mesh), pool_sh))
         self._sp_chunk_progs[C] = prog
         return prog
@@ -770,7 +816,7 @@ class ServingEngine:
         buf[0, :len(tokens)] = tokens
         if self._win_dirty:
             self.flush_kv_window()
-        self._sync_table()
+        buf, row = self._put((buf, None), (self._host_table[slot], None))
         if self.tracer is not None:
             self.tracer.event(None, "engine.sp_prefill_dispatch",
                               slot=slot, tokens=len(tokens), bucket=C,
@@ -779,9 +825,8 @@ class ServingEngine:
         with self._mesh_ctx():
             pools = (self.cache.k_pages, self.cache.v_pages,
                      self.cache.k_scale_pages, self.cache.v_scale_pages)
-            logits, pools = prog(
-                self.params, jnp.asarray(buf), pools,
-                jnp.asarray(self._host_table[slot]),
+            logits, pools = self._launch(
+                prog, self.params, buf, pools, row,
                 jnp.int32(start), jnp.int32(len(tokens)))
             self.cache = self.cache._replace(
                 k_pages=pools[0], v_pages=pools[1],
@@ -814,12 +859,12 @@ class ServingEngine:
         # staged window first so lengths/pool state line up
         if self._win_dirty:
             self.flush_kv_window()
-        self._sync_table()
+        tokens, active, temps = self._put(
+            (tokens, None), (active, None), (temps, None))
         with self._mesh_ctx():
-            nxt, logits, cache = self._decode(
-                self.params, jnp.asarray(tokens), self.cache,
-                jnp.asarray(active), jnp.asarray(temps),
-                self.runtime_top_k, self.runtime_top_p, key)
+            nxt, logits, cache = self._launch(
+                self._decode, self.params, tokens, self.cache, active,
+                temps, self.runtime_top_k, self.runtime_top_p, key)
         self.cache = cache
         return nxt, logits
 
@@ -827,8 +872,9 @@ class ServingEngine:
         prog = self._decode_blocks.get(k)
         if prog is None:
             prog = jax.jit(
-                partial(_decode_scan, self.cfg, self._fwd, k,
-                        use_kernel=self._use_kernels),
+                named(partial(_decode_scan, self.cfg, self._fwd, k,
+                              use_kernel=self._use_kernels),
+                      "bf_decode_block"),
                 static_argnums=(7, 8), donate_argnums=(2,))
             self._decode_blocks[k] = prog
         return prog
@@ -841,8 +887,9 @@ class ServingEngine:
         prog = self._decode_win_blocks.get(k)
         if prog is None:
             prog = jax.jit(
-                partial(_decode_scan_win, self.cfg, k,
-                        use_kernel=self._use_kernels),
+                named(partial(_decode_scan_win, self.cfg, k,
+                              use_kernel=self._use_kernels),
+                      "bf_decode_block_win"),
                 static_argnums=(9, 10), donate_argnums=(2, 3, 4))
             self._decode_win_blocks[k] = prog
         return prog
@@ -871,28 +918,27 @@ class ServingEngine:
         next drain flushes it — one pool scatter per drain instead of
         k x L per block. Token outputs are byte-identical either way.
         """
-        self._sync_table()
+        tokens, active, temps, stops, budgets = self._put(
+            (tokens, None), (active, bool), (temps, None),
+            (stops, jnp.int32), (budgets, jnp.int32))
         if self._window_mode:
             self._ensure_window(k)
             with self._mesh_ctx():
-                block, final, cache, window, wlen = \
-                    self._decode_block_win_prog(k)(
-                        self.params, jnp.asarray(tokens), self.cache,
-                        self._kv_window, self._win_len,
-                        jnp.asarray(active, bool), jnp.asarray(temps),
-                        jnp.asarray(stops, jnp.int32),
-                        jnp.asarray(budgets, jnp.int32),
-                        self.runtime_top_k, self.runtime_top_p, key)
+                block, final, cache, window, wlen = self._launch(
+                    self._decode_block_win_prog(k),
+                    self.params, tokens, self.cache,
+                    self._kv_window, self._win_len,
+                    active, temps, stops, budgets,
+                    self.runtime_top_k, self.runtime_top_p, key)
             self.cache, self._kv_window, self._win_len = cache, window, wlen
             self._win_dirty = True
             self._win_hwm += k
             return block, final
         with self._mesh_ctx():
-            block, final, cache = self._decode_block_prog(k)(
-                self.params, jnp.asarray(tokens), self.cache,
-                jnp.asarray(active, bool), jnp.asarray(temps),
-                jnp.asarray(stops, jnp.int32),
-                jnp.asarray(budgets, jnp.int32),
+            block, final, cache = self._launch(
+                self._decode_block_prog(k),
+                self.params, tokens, self.cache,
+                active, temps, stops, budgets,
                 self.runtime_top_k, self.runtime_top_p, key)
         self.cache = cache
         return block, final
@@ -950,8 +996,9 @@ class ServingEngine:
         prog = self._mixed_blocks.get((k, C))
         if prog is None:
             prog = jax.jit(
-                partial(_mixed_scan, self.cfg, self._fwd, k, C,
-                        use_kernel=self._use_kernels),
+                named(partial(_mixed_scan, self.cfg, self._fwd, k, C,
+                              use_kernel=self._use_kernels),
+                      _block_name(C)),
                 static_argnums=(10, 11), donate_argnums=(2, 3))
             self._mixed_blocks[(k, C)] = prog
         return prog
@@ -964,8 +1011,9 @@ class ServingEngine:
         prog = self._mixed_win_blocks.get((k, C))
         if prog is None:
             prog = jax.jit(
-                partial(_mixed_scan_win, self.cfg, k, C,
-                        use_kernel=self._use_kernels),
+                named(partial(_mixed_scan_win, self.cfg, k, C,
+                              use_kernel=self._use_kernels),
+                      _block_name(C) + "_win"),
                 static_argnums=(12, 13), donate_argnums=(2, 3, 4, 5))
             self._mixed_win_blocks[(k, C)] = prog
         return prog
@@ -995,32 +1043,29 @@ class ServingEngine:
         decode_block_async — worst case k * C staged entries (prefill
         lanes advance win_len by their real chunk length; filler past
         it is never flushed)."""
-        self._sync_table()
+        tokens, plen, active, temps, stops, budgets = self._put(
+            (tokens, None), (plen, jnp.int32), (active, bool),
+            (temps, None), (stops, jnp.int32), (budgets, jnp.int32))
         if self._window_mode:
             self._ensure_window(k * C)
             with self._mesh_ctx():
                 block, valid, final, cursor, cache, window, wlen = \
-                    self._mixed_block_win_prog(k, C)(
-                        self.params, jnp.asarray(tokens), cursor,
+                    self._launch(
+                        self._mixed_block_win_prog(k, C),
+                        self.params, tokens, cursor,
                         self.cache, self._kv_window, self._win_len,
-                        pbuf, jnp.asarray(plen, jnp.int32),
-                        jnp.asarray(active, bool), jnp.asarray(temps),
-                        jnp.asarray(stops, jnp.int32),
-                        jnp.asarray(budgets, jnp.int32),
+                        pbuf, plen, active, temps, stops, budgets,
                         self.runtime_top_k, self.runtime_top_p, key)
             self.cache, self._kv_window, self._win_len = cache, window, wlen
             self._win_dirty = True
             self._win_hwm += k * C
             return block, valid, final, cursor
         with self._mesh_ctx():
-            block, valid, final, cursor, cache = \
-                self._mixed_block_prog(k, C)(
-                    self.params, jnp.asarray(tokens), cursor, self.cache,
-                    pbuf, jnp.asarray(plen, jnp.int32),
-                    jnp.asarray(active, bool), jnp.asarray(temps),
-                    jnp.asarray(stops, jnp.int32),
-                    jnp.asarray(budgets, jnp.int32),
-                    self.runtime_top_k, self.runtime_top_p, key)
+            block, valid, final, cursor, cache = self._launch(
+                self._mixed_block_prog(k, C),
+                self.params, tokens, cursor, self.cache,
+                pbuf, plen, active, temps, stops, budgets,
+                self.runtime_top_k, self.runtime_top_p, key)
         self.cache = cache
         return block, valid, final, cursor
 
@@ -1094,9 +1139,10 @@ class ServingEngine:
             # the source carries one (the "model" draft KV cache)
             dn = (1, 3, 4) if self._draft_stateful else (1, 3)
             prog = jax.jit(
-                partial(_spec_scan, self.cfg, self._fwd, rounds,
-                        rt.speculative_gamma, rt.speculative_ngram,
-                        self._draft_src, use_kernel=self._use_kernels),
+                named(partial(_spec_scan, self.cfg, self._fwd, rounds,
+                              rt.speculative_gamma, rt.speculative_ngram,
+                              self._draft_src, use_kernel=self._use_kernels),
+                      "bf_spec_block"),
                 static_argnums=(9, 10), donate_argnums=dn)
             self._spec_blocks[rounds] = prog
         return prog
@@ -1111,9 +1157,10 @@ class ServingEngine:
             rt = self.runtime
             dn = (1, 3, 4, 5, 6) if self._draft_stateful else (1, 3, 5, 6)
             prog = jax.jit(
-                partial(_spec_scan_win, self.cfg, rounds,
-                        rt.speculative_gamma, rt.speculative_ngram,
-                        self._draft_src, use_kernel=self._use_kernels),
+                named(partial(_spec_scan_win, self.cfg, rounds,
+                              rt.speculative_gamma, rt.speculative_ngram,
+                              self._draft_src, use_kernel=self._use_kernels),
+                      "bf_spec_block_win"),
                 static_argnums=(11, 12), donate_argnums=dn)
             self._spec_win_blocks[rounds] = prog
         return prog
@@ -1126,9 +1173,10 @@ class ServingEngine:
         if prog is None:
             dn = (1, 3, 4) if self._draft_stateful else (1, 3)
             prog = jax.jit(
-                partial(_spec_tree_scan, self.cfg, self._fwd, rounds,
-                        self._tree_width, self._tree_nodes,
-                        self._draft_src, use_kernel=self._use_kernels),
+                named(partial(_spec_tree_scan, self.cfg, self._fwd, rounds,
+                              self._tree_width, self._tree_nodes,
+                              self._draft_src, use_kernel=self._use_kernels),
+                      "bf_spec_tree_block"),
                 static_argnums=(9, 10), donate_argnums=dn)
             self._spec_tree_blocks[rounds] = prog
         return prog
@@ -1139,9 +1187,10 @@ class ServingEngine:
         if prog is None:
             dn = (1, 3, 4, 5, 6) if self._draft_stateful else (1, 3, 5, 6)
             prog = jax.jit(
-                partial(_spec_tree_scan_win, self.cfg, rounds,
-                        self._tree_width, self._tree_nodes,
-                        self._draft_src, use_kernel=self._use_kernels),
+                named(partial(_spec_tree_scan_win, self.cfg, rounds,
+                              self._tree_width, self._tree_nodes,
+                              self._draft_src, use_kernel=self._use_kernels),
+                      "bf_spec_tree_block_win"),
                 static_argnums=(11, 12), donate_argnums=dn)
             self._spec_tree_win_blocks[rounds] = prog
         return prog
@@ -1183,7 +1232,9 @@ class ServingEngine:
         width C becomes spec_emit_width (tree max-depth + 1), and the
         window stages N entries per round of which only the accepted
         path survives the in-window compaction."""
-        self._sync_table()
+        hist_len, active, temps, stops, budgets, spec_mask = self._put(
+            (hist_len, jnp.int32), (active, bool), (temps, None),
+            (stops, jnp.int32), (budgets, jnp.int32), (spec_mask, bool))
         tree = self.spec_tree_mode
         if self._window_mode:
             # per-round window demand is the VERIFY width: N staged
@@ -1196,16 +1247,12 @@ class ServingEngine:
                 else self._spec_block_win_prog(rounds)
             with self._mesh_ctx():
                 (toks, valid, hist, hist_len, rem, cache, window, wlen,
-                 dstate) = prog(
-                        self.params, hist,
-                        jnp.asarray(hist_len, jnp.int32), self.cache,
-                        self._draft_state,
-                        self._kv_window, self._win_len,
-                        jnp.asarray(active, bool), jnp.asarray(temps),
-                        jnp.asarray(stops, jnp.int32),
-                        jnp.asarray(budgets, jnp.int32),
+                 dstate) = self._launch(
+                        prog, self.params, hist, hist_len, self.cache,
+                        self._draft_state, self._kv_window, self._win_len,
+                        active, temps, stops, budgets,
                         self.runtime_top_k, self.runtime_top_p, key,
-                        jnp.asarray(spec_mask, bool))
+                        spec_mask)
             self.cache, self._kv_window, self._win_len = cache, window, wlen
             self._draft_state = dstate
             self._win_dirty = True
@@ -1214,14 +1261,10 @@ class ServingEngine:
         prog = self._spec_tree_prog(rounds) if tree \
             else self._spec_block_prog(rounds)
         with self._mesh_ctx():
-            toks, valid, hist, hist_len, rem, cache, dstate = prog(
-                    self.params, hist, jnp.asarray(hist_len, jnp.int32),
-                    self.cache, self._draft_state,
-                    jnp.asarray(active, bool),
-                    jnp.asarray(temps), jnp.asarray(stops, jnp.int32),
-                    jnp.asarray(budgets, jnp.int32),
-                    self.runtime_top_k, self.runtime_top_p, key,
-                    jnp.asarray(spec_mask, bool))
+            toks, valid, hist, hist_len, rem, cache, dstate = self._launch(
+                prog, self.params, hist, hist_len, self.cache,
+                self._draft_state, active, temps, stops, budgets,
+                self.runtime_top_k, self.runtime_top_p, key, spec_mask)
         self.cache, self._draft_state = cache, dstate
         return toks, valid, hist, hist_len, rem
 
@@ -1230,9 +1273,10 @@ class ServingEngine:
         if prog is None:
             rt = self.runtime
             prog = jax.jit(
-                partial(_mixed_spec_scan, self.cfg, self._fwd, rounds,
-                        rt.speculative_gamma, rt.speculative_ngram,
-                        self._draft_src, use_kernel=self._use_kernels),
+                named(partial(_mixed_spec_scan, self.cfg, self._fwd, rounds,
+                              rt.speculative_gamma, rt.speculative_ngram,
+                              self._draft_src, use_kernel=self._use_kernels),
+                      "bf_mixed_spec_block"),
                 static_argnums=(10, 11), donate_argnums=(1, 3, 5))
             self._mixed_spec_blocks[rounds] = prog
         return prog
@@ -1246,9 +1290,10 @@ class ServingEngine:
         if prog is None:
             rt = self.runtime
             prog = jax.jit(
-                partial(_mixed_spec_scan_win, self.cfg, rounds,
-                        rt.speculative_gamma, rt.speculative_ngram,
-                        self._draft_src, use_kernel=self._use_kernels),
+                named(partial(_mixed_spec_scan_win, self.cfg, rounds,
+                              rt.speculative_gamma, rt.speculative_ngram,
+                              self._draft_src, use_kernel=self._use_kernels),
+                      "bf_mixed_spec_block_win"),
                 static_argnums=(12, 13), donate_argnums=(1, 3, 5, 6, 7))
             self._mixed_spec_win_blocks[rounds] = prog
         return prog
@@ -1269,36 +1314,32 @@ class ServingEngine:
         column 0 of its completion round, so the drain needs no new
         unpacking. Stateless draft sources only (mixed_dispatch_ready).
         """
-        self._sync_table()
+        hist_len, plen, active, temps, stops, budgets, spec_mask = \
+            self._put((hist_len, jnp.int32), (plen, jnp.int32),
+                      (active, bool), (temps, None), (stops, jnp.int32),
+                      (budgets, jnp.int32), (spec_mask, bool))
         if self._window_mode:
             C = self.runtime.speculative_gamma + 1
             self._ensure_window(rounds * C)
             with self._mesh_ctx():
                 (toks, valid, hist, hist_len, rem, cursor, cache,
-                 window, wlen) = self._mixed_spec_win_prog(rounds)(
-                        self.params, hist,
-                        jnp.asarray(hist_len, jnp.int32), cursor,
-                        jnp.asarray(plen, jnp.int32), self.cache,
-                        self._kv_window, self._win_len,
-                        jnp.asarray(active, bool), jnp.asarray(temps),
-                        jnp.asarray(stops, jnp.int32),
-                        jnp.asarray(budgets, jnp.int32),
+                 window, wlen) = self._launch(
+                        self._mixed_spec_win_prog(rounds),
+                        self.params, hist, hist_len, cursor, plen,
+                        self.cache, self._kv_window, self._win_len,
+                        active, temps, stops, budgets,
                         self.runtime_top_k, self.runtime_top_p, key,
-                        jnp.asarray(spec_mask, bool))
+                        spec_mask)
             self.cache, self._kv_window, self._win_len = cache, window, wlen
             self._win_dirty = True
             self._win_hwm += rounds * C
             return toks, valid, hist, hist_len, rem, cursor
         with self._mesh_ctx():
-            toks, valid, hist, hist_len, rem, cursor, cache = \
-                self._mixed_spec_prog(rounds)(
-                    self.params, hist, jnp.asarray(hist_len, jnp.int32),
-                    cursor, jnp.asarray(plen, jnp.int32), self.cache,
-                    jnp.asarray(active, bool),
-                    jnp.asarray(temps), jnp.asarray(stops, jnp.int32),
-                    jnp.asarray(budgets, jnp.int32),
-                    self.runtime_top_k, self.runtime_top_p, key,
-                    jnp.asarray(spec_mask, bool))
+            toks, valid, hist, hist_len, rem, cursor, cache = self._launch(
+                self._mixed_spec_prog(rounds),
+                self.params, hist, hist_len, cursor, plen, self.cache,
+                active, temps, stops, budgets,
+                self.runtime_top_k, self.runtime_top_p, key, spec_mask)
         self.cache = cache
         return toks, valid, hist, hist_len, rem, cursor
 

@@ -208,6 +208,7 @@ def update_cache_layer_q(ck, cv, k_s, v_s, k, v, start):
     return ck, cv, k_s, v_s
 
 
+@jax.named_scope("attn")
 def attend(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array,
            cfg: ModelConfig, k_scale: Optional[jax.Array] = None,
            v_scale: Optional[jax.Array] = None) -> jax.Array:
@@ -250,6 +251,7 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array,
 # Layer bodies
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attn")
 def qkv_proj(x: jax.Array, p: Params, cfg: ModelConfig,
              cos: jax.Array, sin: jax.Array
              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -269,6 +271,7 @@ def qkv_proj(x: jax.Array, p: Params, cfg: ModelConfig,
     return q, k, v
 
 
+@jax.named_scope("attn")
 def attn_output(out: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
     """Output projection of the attention sublayer. out: [B,T,Nq,H]."""
     out = qeinsum("btnh,nhd->btd", out, p["wo"], out.dtype)
@@ -416,6 +419,7 @@ def pre_norm(x: jax.Array, norm_p: Params, cfg: ModelConfig) -> jax.Array:
     return rms_norm(x, norm_p["scale"], cfg.norm_eps)
 
 
+@jax.named_scope("mlp")
 def ffn_block(h: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
     """FFN dispatch shared by every forward variant (contiguous, paged,
     pipeline, sequence-parallel): dense MLP, dense MoE, or EP MoE per

@@ -1,37 +1,27 @@
-"""Profiling/tracing hooks (SURVEY.md §5: jax.profiler + named scopes).
+"""The process's side of the profiler and of the compiler's own events.
 
-The reference only *planned* observability (/root/reference/CLAUDE.md:42);
-the TPU-native mechanism is XProf: `trace()` captures a TensorBoard-
-loadable profile of any code region (XLA ops, Pallas kernels, collectives,
-host activity), `start_profiler_server()` enables on-demand capture from
-a live serving process, and `step_timer` is a zero-dependency host-side
-ring buffer for per-tick latency percentiles.
+`start_profiler_server()` lets TensorBoard/XProf capture from a live
+serving process (`serve --profiler-port`); `POST /debug/profile`
+(serve/server.py) captures without it. `count_compiles()` counts what
+the process compiles, from JAX's own monitoring events: a compilation
+inside a tick stalls every stream, and only the process sees one that
+the persistent cache answered or that ran with the cache off.
 """
 from __future__ import annotations
 
-import contextlib
 import sys
-import time
-from collections import deque
-from typing import Dict, Iterator, Optional
-
-import numpy as np
-
-
-@contextlib.contextmanager
-def trace(logdir: str) -> Iterator[None]:
-    """Capture an XProf trace of the enclosed region into `logdir`."""
-    import jax
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
+import threading
 
 #: the live ProfilerServer (jax returns a handle that must stay
 #: referenced; dropping it would stop the server)
 _PROFILER_SERVER = None
+
+#: jax.monitoring duration events (jax/_src/dispatch.py): a program
+#: built by the backend compiler or fetched from the persistent cache,
+#: and the tracing and lowering that come before either
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_TRACE_AND_LOWER = ("/jax/core/compile/jaxpr_trace_duration",
+                    "/jax/core/compile/jaxpr_to_mlir_module_duration")
 
 
 def start_profiler_server(port: int = 9999) -> bool:
@@ -56,31 +46,24 @@ def start_profiler_server(port: int = 9999) -> bool:
         return False
 
 
-def annotate(name: str):
-    """Named region: shows up in XProf timelines (jax.named_scope)."""
+def count_compiles(registry):
+    """Feed `registry`'s `compiles_total` (one per program the backend
+    compiled or fetched from the persistent cache) and
+    `compile_seconds_total` (tracing, lowering and compiling) from
+    JAX's monitoring events, whichever thread compiles. Returns the
+    listener, for `jax.monitoring.unregister_event_duration_listener`:
+    a process that serves registers once and never removes it."""
     import jax
-    return jax.named_scope(name)
+    n = registry.counter("compiles_total")
+    secs = registry.counter("compile_seconds_total")
+    lock = threading.Lock()
 
+    def listener(event: str, duration: float, **_) -> None:
+        if event == _BACKEND_COMPILE or event in _TRACE_AND_LOWER:
+            with lock:
+                secs.inc(max(0.0, duration))
+                if event == _BACKEND_COMPILE:
+                    n.inc()
 
-class StepTimer:
-    """Host-side ring buffer of step latencies -> percentiles."""
-
-    def __init__(self, capacity: int = 1024):
-        self._lat = deque(maxlen=capacity)
-
-    @contextlib.contextmanager
-    def step(self) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self._lat.append(time.perf_counter() - t0)
-
-    def percentiles(self) -> Dict[str, float]:
-        if not self._lat:
-            return {}
-        a = np.asarray(self._lat)
-        return {"step_p50_s": float(np.percentile(a, 50)),
-                "step_p95_s": float(np.percentile(a, 95)),
-                "step_p99_s": float(np.percentile(a, 99)),
-                "steps_recorded": float(len(a))}
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    return listener

@@ -7,7 +7,11 @@ Two bounded, always-cheap instruments the scheduler feeds:
   time, per-phase host-section durations (the ``TICK_PHASES``
   vocabulary shared with docs/serving.md's tick-pipeline section),
   the stacked-fetch device wait, in-flight depth, barrier causes,
-  batch occupancy and page headroom. One dict append per tick under an
+  batch occupancy and page headroom, the block program the tick
+  dispatched, the loop's wait for the serving lock and the process's
+  count of compilations. The sequence number is also the ``seq`` of
+  the tick's ``bf.tick`` span in a profiler trace: the join between
+  the two needs no clock. One dict append per tick under an
   uncontended lock — the software answer to "where does the tick's
   host time go" that a TPU profile then confirms. Served raw at
   ``GET /debug/ticks`` and rendered by ``tools/tick_report.py``.
@@ -77,7 +81,9 @@ class TickLog:
                fetch_s: float = 0.0, inflight: int = 0,
                barrier_causes=(), batch: int = 0, waiting: int = 0,
                pages_free: int = 0, generated: int = 0,
-               spec: bool = False) -> None:
+               spec: bool = False, program: Optional[str] = None,
+               block: int = 0, lock_s: float = 0.0,
+               compiles: int = 0) -> None:
         """Append one tick record (hot path: one dict build + one
         locked append per TICK, never per token). `phases` is copied —
         callers may reuse/zero their accumulator dict."""
@@ -94,10 +100,20 @@ class TickLog:
             "pages_free": pages_free,
             "generated": generated,
             "spec": spec,
+            "program": program,
+            "block": block,
+            "lock_s": lock_s,
+            "compiles": compiles,
         }
         with self._lock:
             self._ring.append(entry)
             self._seq += 1
+
+    @property
+    def next_seq(self) -> int:
+        """The sequence number the next record will take (the tick
+        thread is the one writer, so it names the tick under way)."""
+        return self._seq
 
     def dump(self, n: Optional[int] = None,
              since: Optional[int] = None) -> Dict[str, Any]:

@@ -2,7 +2,7 @@
 
 Every request admitted to the scheduler gets a timeline of structured
 events (submit, admit, prefill chunks, first token, preemption, finish)
-plus a global ring of scheduler-tick and engine-dispatch events — the
+plus a global ring of engine-dispatch events — the
 per-request "where did the time go" view that aggregate percentiles
 can't answer (Orca's per-iteration scheduling and vLLM's production
 stack both lean on exactly this to debug tail latency; PAPERS.md).
@@ -68,8 +68,9 @@ class Tracer:
         self.event(rid, "submit", **attrs)
 
     def event(self, rid: Optional[int], name: str, **attrs) -> None:
-        """Record one span event. rid=None -> the global ring (scheduler
-        ticks, engine dispatches — events not owned by one request)."""
+        """Record one span event. rid=None -> the global ring (engine
+        dispatches — events not owned by one request; what a tick held
+        is in its record, obs/ticklog.py)."""
         ev = {"t": time.monotonic(), "name": name}
         if attrs:
             ev.update(attrs)
@@ -145,7 +146,9 @@ class Tracer:
 def summarize_timeline(rec: Dict[str, Any]) -> Dict[str, Any]:
     """Phase durations from one request's event list.
 
-    Returns queue_wait_s (submit->admit), prefill_s (admit->prefill
+    Returns lock_wait_s (the handler's wait for the serving lock, which
+    ends at submit; None for a request no server submitted),
+    queue_wait_s (submit->admit), prefill_s (admit->prefill
     done), ttft_s (submit->first token), decode_s (first token->finish),
     total_s, plus token/preemption counts pulled off the events. Missing
     phases (aborted early, events evicted) come back as None — report
@@ -178,6 +181,7 @@ def summarize_timeline(rec: Dict[str, Any]) -> Dict[str, Any]:
         "request_id": rec.get("request_id"),
         "state": finish.get("state",
                             "done" if rec.get("done") else "live"),
+        "lock_wait_s": by_name.get("submit", {}).get("lock_wait_s"),
         "queue_wait_s": delta("submit", "admit"),
         "prefill_s": delta("admit", "prefill_done"),
         "ttft_s": delta("submit", "first_token"),

@@ -224,6 +224,7 @@ def live_auto_mesh() -> bool:
     return any(mesh.shape[n] > 1 for n in _auto_axes(mesh))
 
 
+@jax.named_scope("attn")
 def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
                             causal: bool = True,
                             prefix_k: jax.Array = None,

@@ -154,6 +154,7 @@ def _paged_kernel(table_ref, len_ref, *rest, page: int, kv_heads: int,
                     jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
 
 
+@jax.named_scope("attn")
 def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
                             v_pages: jax.Array, page_table: jax.Array,
                             lengths: jax.Array,

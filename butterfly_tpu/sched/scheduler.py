@@ -47,6 +47,7 @@ engine/serving.py:
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import time
 from collections import deque
@@ -56,6 +57,7 @@ from typing import Callable, Deque, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from butterfly_tpu.cache.allocator import make_page_allocator
 from butterfly_tpu.engine.serving import (
@@ -574,13 +576,31 @@ class Scheduler:
         self._bubbles: Deque[float] = deque(maxlen=4096)
         # -- tick anatomy (ISSUE 15) -----------------------------------------
         # Per-tick phase attribution: tick() zeroes the accumulator,
-        # the structural sections add their exclusive time.monotonic()
-        # deltas (host->host arithmetic only — the timers themselves
-        # must never sync, BTF003 covers these paths), and the record
-        # lands in the bounded timeline ring GET /debug/ticks serves.
+        # the structural sections run inside _span(), which adds their
+        # exclusive time.monotonic() deltas (host->host arithmetic
+        # only — the timers themselves must never sync, BTF003 covers
+        # these paths), and the record lands in the bounded timeline
+        # ring GET /debug/ticks serves.
         self.ticklog = TickLog(capacity=512)
         self._tick_phases: Dict[str, float] = {p: 0.0 for p in TICK_PHASES}
+        # the span stack: the phase each open span is charged to,
+        # innermost last, over "other" (the tick itself); _span_t is
+        # the last boundary, from which the innermost span is owed
+        self._span_stack: List[str] = ["other"]
+        self._span_t = time.monotonic()
         self._tick_causes: List[str] = []
+        # ServerState._loop's wait for the serving lock before the
+        # tick under way (it sets this; the tick record carries it)
+        self.loop_lock_s = 0.0
+        # compilations of this process (obs/profile.py count_compiles
+        # feeds them): a tick that compiles stalls every stream
+        self._c_compiles = reg.counter(
+            "compiles_total",
+            "Programs built by the backend compiler or fetched from "
+            "the persistent compile cache (one per new program shape)")
+        reg.counter(
+            "compile_seconds_total",
+            "Seconds spent tracing, lowering and compiling programs")
         # stacked-fetch device wait within this tick's drains: feeds
         # the host/device split (tick_host_frac / tick_device_frac) —
         # the fetch is the one tick section that blocks on the device
@@ -597,10 +617,30 @@ class Scheduler:
                 LATENCY_BUCKETS)
             for p in TICK_PHASES}
 
-    def _phase_add(self, name: str, dt: float) -> None:
-        """Accumulate one phase section's exclusive wall time into the
-        current tick's record (plain dict arithmetic — never a sync)."""
-        self._tick_phases[name] += dt
+    @contextlib.contextmanager
+    def _span(self, name: str, **attrs):
+        """One section of the tick on both clocks: a TraceAnnotation
+        `bf.tick.<name>` in the profiler's trace (with no capture
+        running: an atomic load, and `attrs` are never formatted),
+        and exclusive time.monotonic() time in the tick record.
+        Entering pauses the enclosing span's timer and leaving resumes
+        it, so phases never overlap and sum to the tick's wall time. A
+        TICK_PHASES name is charged to itself, any other name (a
+        sub-span such as `drain.fetch`) to the phase around it. Yields
+        the annotation (set_metadata adds what is known only at the
+        end). Plain dict arithmetic — never a sync."""
+        stack, tp = self._span_stack, self._tick_phases
+        now = time.monotonic()
+        tp[stack[-1]] += now - self._span_t
+        self._span_t = now
+        stack.append(name if name in tp else stack[-1])
+        try:
+            with TraceAnnotation("bf.tick." + name, **attrs) as ann:
+                yield ann
+        finally:
+            now = time.monotonic()
+            tp[stack.pop()] += now - self._span_t
+            self._span_t = now
 
     # -- public API ---------------------------------------------------------
 
@@ -610,7 +650,9 @@ class Scheduler:
                request_id: Optional[str] = None,
                priority: str = "interactive",
                deadline_s: Optional[float] = None,
-               speculative: bool = True) -> Request:
+               speculative: bool = True,
+               lock_wait_s: Optional[float] = None,
+               t_recv: Optional[float] = None) -> Request:
         # Reject what can never fit: a request that exceeds the per-seq
         # page limit or the whole pool would self-preempt forever.
         worst = -(-(len(prompt) + max_new_tokens) // self.alloc.page_size)
@@ -631,9 +673,14 @@ class Scheduler:
         self.waiting.append(req)
         self._c_requests.inc()
         if self.trace is not None:
+            # a server passes what came before the scheduler: its wait
+            # for the serving lock and when the request was received
+            front = {} if lock_wait_s is None else \
+                {"lock_wait_s": lock_wait_s, "t_recv": t_recv}
             self.trace.begin_request(req.id, request_id=request_id,
                                      prompt_len=len(prompt),
-                                     max_new_tokens=max_new_tokens)
+                                     max_new_tokens=max_new_tokens,
+                                     **front)
         return req
 
     # -- overload protection (ISSUE 8) --------------------------------------
@@ -831,26 +878,34 @@ class Scheduler:
         Returns the number of tokens generated this round (throughput
         accounting for the serve loop)."""
         before = self._c_tokens.value
+        # tick-anatomy reset: zero the phase accumulator (the spans
+        # below add their exclusive monotonic deltas), clear the
+        # barrier-cause list, zero the fetch wait
+        t_tick0 = self._span_t = time.monotonic()
+        for p in TICK_PHASES:
+            self._tick_phases[p] = 0.0
+        self._tick_causes = []
+        self._tick_fetch = 0.0
+        blocks0 = self.engine.blocks_launched
+        with TraceAnnotation("bf.tick", seq=self.ticklog.next_seq,
+                             batch=len(self.running),
+                             waiting=len(self.waiting)):
+            self._tick_sections()
+            made = int(self._c_tokens.value - before)
+            self._record_tick(t_tick0, made, blocks0)
+        return made
+
+    def _tick_sections(self) -> None:
+        """The tick's sections in order, each under its span."""
         rt = self.engine.runtime
         spec = self._spec_mode
         k = max(1, rt.decode_steps_per_tick)
         depth = max(1, rt.inflight_blocks)
-        # tick-anatomy reset: zero the phase accumulator (sections add
-        # their exclusive monotonic deltas below; drains self-accrue),
-        # clear the barrier-cause list, zero the fetch wait
-        t_tick0 = time.monotonic()
-        tp = self._tick_phases
-        for p in TICK_PHASES:
-            tp[p] = 0.0
-        self._tick_causes = []
-        self._tick_fetch = 0.0
         # deadline scrub first: an expired request must not survive
         # into this tick's admission or decode dispatch (a drain it
         # forces accrues to drain_barrier, not to expire)
-        d0 = self._drain_accrued()
-        self._expire_due()
-        self._phase_add("expire", max(0.0, time.monotonic() - t_tick0
-                                      - (self._drain_accrued() - d0)))
+        with self._span("expire"):
+            self._expire_due()
         self._t_host0 = time.monotonic()
         self._had_inflight_at_host0 = bool(self._inflight)
         self._idle_at_host0 = self._had_inflight_at_host0 and \
@@ -870,10 +925,9 @@ class Scheduler:
         # prefill_inline_budget just like ordinary chunked prefill).
         sp_used = 0
         if self._sp_enabled:
-            t_sp = time.monotonic()
-            self._sp_admit()
-            sp_used = self._sp_prefill_step()
-            self._phase_add("admit", time.monotonic() - t_sp)
+            with self._span("admit"):
+                self._sp_admit()
+                sp_used = self._sp_prefill_step()
         # admission barrier — retired as a class under mixed dispatch,
         # where admission is a host-side carry edit between dispatches
         # (_admit_inline) and the prompt rides the next fused block.
@@ -884,12 +938,11 @@ class Scheduler:
                           or (self.waiting
                               and self._free_slot() is not None)):
             self._drain_inflight("admission")
-        t_admit = time.monotonic()
-        if mixed:
-            self._admit_inline()
-        else:
-            self._admit(sp_used // max(1, self.engine.sp_degree))
-        self._phase_add("admit", time.monotonic() - t_admit)
+        with self._span("admit"):
+            if mixed:
+                self._admit_inline()
+            else:
+                self._admit(sp_used // max(1, self.engine.sp_degree))
         if self.running:
             self._h_batch.observe(len(self.running))
         # Preallocate pages for every step still in flight PLUS this
@@ -930,21 +983,15 @@ class Scheduler:
                     need = min(len(req.all_tokens) + pf_h,
                                len(req.prompt) + req.max_new_tokens)
                     self._ensure_or_preempt(req, need)
-        t_disp = time.monotonic()
-        a0 = tp["assemble"]
-        if mixed:
-            # the fused block covers both phases: its dispatch section
-            # gets its own phase label so tick anatomy stays honest
-            # about where admission+prefill time went
-            dispatched = self._mixed_block(k)
-            self._phase_add("mixed", max(0.0, time.monotonic() - t_disp
-                                         - (tp["assemble"] - a0)))
-        else:
-            dispatched = self._spec_block(k) if spec \
-                else self._decode_block(k)
-            self._phase_add("dispatch",
-                            max(0.0, time.monotonic() - t_disp
-                                - (tp["assemble"] - a0)))
+        # the fused block covers both phases: its dispatch section
+        # gets its own phase label so tick anatomy stays honest about
+        # where admission+prefill time went
+        with self._span("mixed" if mixed else "dispatch"):
+            if mixed:
+                dispatched = self._mixed_block(k)
+            else:
+                dispatched = self._spec_block(k) if spec \
+                    else self._decode_block(k)
         if not dispatched and (self._inflight or self._pending_first):
             # nothing dispatchable (every budget is spent on device):
             # the remaining tokens exist only in flight — fetch them
@@ -953,36 +1000,20 @@ class Scheduler:
             # the remainders), hence the distinct cause label.
             self._drain_inflight("spec" if spec else "idle")
         self._g_inflight.set(len(self._inflight))
-        made = int(self._c_tokens.value - before)
-        if self.trace is not None:
-            # one global event per tick: the decode batch this round —
-            # slot composition plus what the stacked drain surfaced
-            self.trace.event(None, "decode_tick",
-                             batch=len(self.running),
-                             waiting=len(self.waiting),
-                             steps=k, block_steps=k, spec=spec,
-                             inflight=len(self._inflight),
-                             generated=made)
-        self._record_tick(time.monotonic() - t_tick0, made, spec)
-        return made
 
-    def _drain_accrued(self) -> float:
-        """Drain-owned phase time accrued so far this tick (plain dict
-        reads): lets an enclosing section subtract the drains it
-        triggered, keeping the phase sections non-overlapping."""
+    def _record_tick(self, t_tick0: float, made: int, blocks0: int) -> None:
+        """Close the tick's anatomy record: charge the tick's own
+        exclusive time to "other" (untimed host work — page prealloc,
+        trace appends), feed the per-phase histograms, the host/device
+        split, the timeline ring, and the flight-recorder trigger
+        poll. Host arithmetic only — no device value is ever touched
+        here."""
         tp = self._tick_phases
-        return (tp["drain_barrier"] + tp["drain_oldest"]
-                + tp["flush"] + tp["spec_emit"])
-
-    def _record_tick(self, wall: float, made: int, spec: bool) -> None:
-        """Close the tick's anatomy record: compute the residual
-        ("other" = untimed host work — page prealloc, trace appends),
-        feed the per-phase histograms, the host/device split, the
-        timeline ring, and the flight-recorder trigger poll. Host
-        arithmetic only — no device value is ever touched here."""
-        tp = self._tick_phases
-        known = sum(tp[p] for p in TICK_PHASES if p != "other")
-        tp["other"] = max(0.0, wall - known)
+        now = time.monotonic()
+        tp["other"] += now - self._span_t
+        self._span_t = now
+        wall = now - t_tick0
+        blocks = self.engine.blocks_launched
         for name, h in self._h_phase.items():
             h.observe(tp[name])
         fetch = min(self._tick_fetch, wall)
@@ -994,7 +1025,12 @@ class Scheduler:
                             batch=len(self.running),
                             waiting=len(self.waiting),
                             pages_free=self.alloc.free_pages,
-                            generated=made, spec=spec)
+                            generated=made, spec=self._spec_mode,
+                            program=self.engine.last_program
+                            if blocks > blocks0 else None,
+                            block=blocks, lock_s=self.loop_lock_s,
+                            compiles=int(self._c_compiles.value))
+        self.loop_lock_s = 0.0
         if self.flightrec is not None:
             self.flightrec.poll({
                 "slo_burn_rate": self._g_slo_burn.value,
@@ -1733,37 +1769,36 @@ class Scheduler:
         emission allowance (output is empty unless resumed from a
         preemption)."""
         if self._operands_epoch != self._epoch:
-            t0 = time.monotonic()
-            S = self.engine.num_slots
-            active = np.zeros((S,), bool)
-            temps = np.zeros((S,), np.float32)
-            stops = np.full((S,), -1, np.int32)
-            base = np.zeros((S,), np.int32)
-            specm = np.zeros((S,), bool)
-            # seq-parallel-lane members never ride a block: their
-            # prefill happens in dedicated sp_prefill_chunk dispatches
-            # and they enter `running` only via _finish_prefill.
-            batch = (list(self.running) + list(self._prefill_group)
-                     if self._mixed_mode else self.running)
-            for req in batch:
-                active[req.slot] = True
-                temps[req.slot] = req.temperature
-                stops[req.slot] = req.stop_token
-                specm[req.slot] = req.speculative
-                # tokens the request may still emit: max_new minus what
-                # the host has drained, minus an undrained
-                # admission-time first token (queued in _pending_first;
-                # set lookup — the old per-runner linear scan over the
-                # pending list was O(running x pending) every block)
-                pending = (req.id,
-                           req.preemptions) in self._pending_first_keys
-                base[req.slot] = (req.max_new_tokens - len(req.output)
-                                  - int(pending))
-            self._operands = (active, temps, stops, base, specm,
-                              {req.slot: (req, req.preemptions)
-                               for req in batch})
-            self._operands_epoch = self._epoch
-            self._phase_add("assemble", time.monotonic() - t0)
+            with self._span("assemble"):
+                S = self.engine.num_slots
+                active = np.zeros((S,), bool)
+                temps = np.zeros((S,), np.float32)
+                stops = np.full((S,), -1, np.int32)
+                base = np.zeros((S,), np.int32)
+                specm = np.zeros((S,), bool)
+                # seq-parallel-lane members never ride a block: their
+                # prefill happens in dedicated sp_prefill_chunk dispatches
+                # and they enter `running` only via _finish_prefill.
+                batch = (list(self.running) + list(self._prefill_group)
+                         if self._mixed_mode else self.running)
+                for req in batch:
+                    active[req.slot] = True
+                    temps[req.slot] = req.temperature
+                    stops[req.slot] = req.stop_token
+                    specm[req.slot] = req.speculative
+                    # tokens the request may still emit: max_new minus what
+                    # the host has drained, minus an undrained
+                    # admission-time first token (queued in _pending_first;
+                    # set lookup — the old per-runner linear scan over the
+                    # pending list was O(running x pending) every block)
+                    pending = (req.id,
+                               req.preemptions) in self._pending_first_keys
+                    base[req.slot] = (req.max_new_tokens - len(req.output)
+                                      - int(pending))
+                self._operands = (active, temps, stops, base, specm,
+                                  {req.slot: (req, req.preemptions)
+                                   for req in batch})
+                self._operands_epoch = self._epoch
         return self._operands
 
     def _note_bubble(self) -> None:
@@ -1942,22 +1977,16 @@ class Scheduler:
         (the membership-change class that forced it: admission, finish,
         page_pressure, cancel, spec, idle, expired, flush) and rides
         the tick's timeline record + the flight-recorder ring."""
-        t0 = time.monotonic()
-        if self._inflight or self._pending_first:
-            self._c_barriers.labels(cause).inc()
-            self._tick_causes.append(cause)
-            if self.flightrec is not None:
-                self.flightrec.note("barrier", cause=cause,
-                                    inflight=len(self._inflight))
-        blocks, self._inflight = self._inflight, []
-        self._spec_rem = None
-        tp = self._tick_phases
-        sub0 = tp["flush"] + tp["spec_emit"]
-        out = self._drain_blocks(blocks)
-        self._phase_add("drain_barrier",
-                        max(0.0, time.monotonic() - t0
-                            - (tp["flush"] + tp["spec_emit"] - sub0)))
-        return out
+        with self._span("drain_barrier"):
+            if self._inflight or self._pending_first:
+                self._c_barriers.labels(cause).inc()
+                self._tick_causes.append(cause)
+                if self.flightrec is not None:
+                    self.flightrec.note("barrier", cause=cause,
+                                        inflight=len(self._inflight))
+            blocks, self._inflight = self._inflight, []
+            self._spec_rem = None
+            return self._drain_blocks(blocks)
 
     def _drain_oldest(self) -> bool:
         """Lazy-drain step: fetch the pending firsts and ONLY the
@@ -1965,15 +1994,9 @@ class Scheduler:
         device (the dispatch-ahead overlap — the device computes block
         t+1 while the host emits block t). Returns True if any request
         finished (the caller escalates that to a full barrier)."""
-        t0 = time.monotonic()
-        tp = self._tick_phases
-        sub0 = tp["flush"] + tp["spec_emit"]
-        out = self._drain_blocks([self._inflight.pop(0)]
-                                 if self._inflight else [])
-        self._phase_add("drain_oldest",
-                        max(0.0, time.monotonic() - t0
-                            - (tp["flush"] + tp["spec_emit"] - sub0)))
-        return out
+        with self._span("drain_oldest"):
+            return self._drain_blocks([self._inflight.pop(0)]
+                                      if self._inflight else [])
 
     def _drain_blocks(self, blocks: List[tuple]) -> bool:
         """Fetch + emit the given decode blocks (ONE stacked device
@@ -1999,12 +2022,12 @@ class Scheduler:
         # when nothing is staged; the flushed-token count is a device
         # scalar that rides this drain's one stacked fetch.
         t_flush = time.monotonic()
-        flushed = self.engine.flush_kv_window()
+        with self._span("flush"):
+            flushed = self.engine.flush_kv_window()
         if flushed is not None:
             dt = time.monotonic() - t_flush
             self._h_kv_flush.observe(dt)
             self._kv_flushes.append(dt)
-            self._phase_add("flush", dt)
             if self.flightrec is not None:
                 self.flightrec.note("flush", dispatch_s=dt)
         firsts, self._pending_first = self._pending_first, []
@@ -2014,7 +2037,6 @@ class Scheduler:
                 self._c_kv_flushed.inc(int(flushed))
             return False
         finished_before = self._c_finished.value
-        C = self.engine.spec_emit_width
         parts = [f[3].reshape(1) for f in firsts]
         for ent in blocks:
             if ent[0] == "decode":
@@ -2032,11 +2054,24 @@ class Scheduler:
         # The parts are joined on the device, so one transfer crosses
         # to the host (checked on a four-chip seq mesh, PR 21: the
         # joined values match the parts under every mesh form).
-        t_fetch = time.monotonic()
-        vals = np.asarray(jnp.concatenate(parts)) if len(parts) > 1 \
-            else np.asarray(parts[0])
-        self._tick_fetch += time.monotonic() - t_fetch
-        if flushed is not None:
+        with self._span("drain.fetch", blocks=len(blocks)):
+            t_fetch = time.monotonic()
+            vals = np.asarray(jnp.concatenate(parts)) if len(parts) > 1 \
+                else np.asarray(parts[0])
+            self._tick_fetch += time.monotonic() - t_fetch
+        tokens0 = self._c_tokens.value
+        with self._span("drain.emit") as ann:
+            self._emit_drained(firsts, blocks, vals, flushed is not None)
+            ann.set_metadata(tokens=int(self._c_tokens.value - tokens0))
+        self._epoch += 1  # outputs / pending-first changed
+        return self._c_finished.value > finished_before
+
+    def _emit_drained(self, firsts: List[tuple], blocks: List[tuple],
+                      vals: np.ndarray, flushed: bool) -> None:
+        """Hand one stacked fetch's values to their requests, in
+        chronological order: pending firsts, then each block's rows."""
+        C = self.engine.spec_emit_width
+        if flushed:
             self._c_kv_flushed.inc(int(vals[-1]))
         now = time.monotonic()
         nf = len(firsts)
@@ -2062,9 +2097,8 @@ class Scheduler:
                 off += k * S * C
                 valid3 = vals[off:off + k * S * C].reshape(k, S, C) != 0
                 off += k * S * C
-                t_se = time.monotonic()
-                self._emit_spec(toks3, valid3, snapshot)
-                self._phase_add("spec_emit", time.monotonic() - t_se)
+                with self._span("spec_emit"):
+                    self._emit_spec(toks3, valid3, snapshot)
                 continue
             if kind == "mixed":
                 # [k, S] tokens + validity: a lane emits at most one
@@ -2101,8 +2135,6 @@ class Scheduler:
                     self._emit(req, tok)
                     if req.done:
                         break
-        self._epoch += 1  # outputs / pending-first changed
-        return self._c_finished.value > finished_before
 
     def _mixed_transitions(self, pf_slots, snapshot: Dict) -> None:
         """Drain-time completion transitions for a mixed block's

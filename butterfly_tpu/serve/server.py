@@ -23,7 +23,8 @@ zero-egress environment):
   "free_pages", "inflight_depth"} — one cheap JSON probe carrying every
   load/placement signal the router AND the fleet control plane read
   (queue depth + page headroom + pipeline depth + replica role; no
-  Prometheus text scrape, no second poll path); plus what the replica
+  Prometheus text scrape, no second poll path); "compiles_after_ready"
+  (programs compiled since the ready line); plus what the replica
   runs on: "device" {platform, kind, count, memory: per-device
   bytes_in_use / peak_bytes_in_use / bytes_limit where the backend
   reports them}, "kernels" {mode: off | interpret | compiled, calls:
@@ -58,6 +59,8 @@ import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from pathlib import Path
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 from butterfly_tpu.obs.metrics import ThroughputWindow, render_prometheus
 
@@ -159,6 +162,9 @@ def device_memory() -> list:
 
 
 class ServerState:
+    #: how long POST /debug/profile waits for a capture's export
+    EXPORT_LIMIT_S = 600.0
+
     def __init__(self, scheduler, tokenizer, max_queue: int = 256,
                  heartbeat=None, model_name: str = "butterfly",
                  role: str = "both"):
@@ -211,7 +217,14 @@ class ServerState:
         self._profile_pending: Optional[tuple] = None
         self._profile_active: Optional[tuple] = None
         self._profile_result: Optional[dict] = None
+        # set when the loop hands the capture's end to the export
+        # thread (the loop is alive), and when the export has finished
+        self._profile_stopping = threading.Event()
         self._profile_done = threading.Event()
+        # compilations up to here are set-up: the ready line has been
+        # printed (run_server warms every program before it builds us)
+        self._c_compiles = scheduler.registry.counter("compiles_total")
+        self._compiles_at_ready = self._c_compiles.value
         self.thread = threading.Thread(target=self._loop, daemon=True)
         # Optional HeartbeatMonitor (obs/health.py): the scheduler
         # thread beats after every tick and runs the probe in-thread
@@ -305,9 +318,19 @@ class ServerState:
                 self.wake.clear()
                 continue
             try:
-                with self.lock:
+                # the loop's side of the race for the serving lock: the
+                # wait goes into the trace and into the tick's record
+                t_lock = time.monotonic()
+                with TraceAnnotation("bf.loop.lock"):
+                    # btf: disable=BTF004 the scheduler loop owns the device and may wait unboundedly; acquire() and not `with`, so that the span ends where the lock is taken
+                    self.lock.acquire()
+                try:
                     has_work = self.sched.has_work
+                    if has_work:
+                        self.sched.loop_lock_s = time.monotonic() - t_lock
                     made = self.sched.tick() if has_work else 0
+                finally:
+                    self.lock.release()
             except Exception as e:  # device/OOM errors must not wedge:
                 # set the error; the wedged branch above drains on the
                 # next iteration (one drain path, not two)
@@ -321,7 +344,8 @@ class ServerState:
             else:
                 if self.heartbeat is not None:
                     self.heartbeat.maybe_probe()  # idle: probe in-thread
-                self.wake.wait(timeout=0.05)
+                with TraceAnnotation("bf.loop.wait"):
+                    self.wake.wait(timeout=0.05)
                 self.wake.clear()
 
     # -- live on-demand profiling (loop thread + handler threads) -------------
@@ -340,10 +364,13 @@ class ServerState:
 
     def _maybe_profile(self) -> None:
         """Runs on the scheduler loop thread, OUTSIDE the serving lock:
-        start a pending capture, stop an expired one. The capture
-        therefore brackets whole ticks of the live loop and never
-        blocks admission — the serving lock is untouched on this path
-        (the BTF004 contract; pinned by test)."""
+        start a pending capture, end an expired one. The capture
+        therefore starts and ends between ticks of the live loop and
+        never blocks admission — the serving lock is untouched on this
+        path (the BTF004 contract; pinned by test). Ending a capture
+        is stop_trace, which also exports it (seconds; 50 s for four
+        chips): that runs on a thread of its own, where it releases
+        the interpreter lock, and the loop goes on ticking."""
         req = self._profile_pending
         if req is not None and self._profile_active is None:
             self._profile_pending = None
@@ -354,28 +381,36 @@ class ServerState:
             except Exception as e:  # no profiler plugin / busy / bad dir
                 self._profile_result = {
                     "error": f"{type(e).__name__}: {e}"}
+                self._profile_stopping.set()
                 self._profile_done.set()
                 return
             self._profile_active = (t0 + dur_s, logdir, t0)
         act = self._profile_active
         if act is not None and time.monotonic() >= act[0]:
             self._profile_active = None
-            deadline, logdir, t0 = act
-            result = {"logdir": logdir,
-                      "duration_s": time.monotonic() - t0}
-            try:
-                self._profiler_stop()
-            except Exception as e:
-                result["error"] = f"{type(e).__name__}: {e}"
-            self._profile_result = result
-            self._profile_done.set()
+            self._profile_stopping.set()
+            threading.Thread(target=self._profile_export, daemon=True,
+                             args=(act[1], act[2])).start()
+
+    def _profile_export(self, logdir: str, t0: float) -> None:
+        """The capture's end and its export, off the loop thread."""
+        t_stop = time.monotonic()
+        result = {"logdir": logdir, "duration_s": t_stop - t0}
+        try:
+            self._profiler_stop()
+        except Exception as e:
+            result["error"] = f"{type(e).__name__}: {e}"
+        result["export_s"] = time.monotonic() - t_stop
+        self._profile_result = result
+        self._profile_done.set()
 
     def request_profile(self, duration_ms: float,
                         logdir: Optional[str] = None) -> dict:
         """POST /debug/profile body -> result. Blocks the HANDLER
-        thread (bounded: duration + slack) while the loop thread
-        captures; never touches the serving lock, so admission and
-        every other endpoint proceed normally through the capture."""
+        thread while the loop thread captures (bounded: duration +
+        slack) and then until the export thread has written the files
+        (EXPORT_LIMIT_S); never touches the serving lock, so admission
+        and every other endpoint proceed normally through both."""
         import glob
         import tempfile
         duration_ms = min(max(float(duration_ms), 10.0), 60000.0)
@@ -385,16 +420,20 @@ class ServerState:
             if logdir is None:
                 logdir = tempfile.mkdtemp(prefix="butterfly_profile_")
             self._profile_result = None
+            self._profile_stopping.clear()
             self._profile_done.clear()
             self._profile_pending = (duration_ms / 1e3, str(logdir))
             self.wake.set()  # an idle loop wakes to start the capture
-            if not self._profile_done.wait(timeout=duration_ms / 1e3 + 30.0):
+            if not self._profile_stopping.wait(duration_ms / 1e3 + 30.0):
                 # a truly hung tick never reaches _maybe_profile: drop
                 # the request so a later loop iteration doesn't start a
                 # stale capture, and tell the client
                 self._profile_pending = None
                 raise ProfilerUnavailable(
                     "capture did not complete (tick loop stalled?)")
+            if not self._profile_done.wait(timeout=self.EXPORT_LIMIT_S):
+                raise ProfilerUnavailable(
+                    f"export not done after {self.EXPORT_LIMIT_S:.0f} s")
             res = dict(self._profile_result or {})
         finally:
             self._profile_guard.release()
@@ -443,8 +482,11 @@ class ServerState:
 
     def submit(self, tokens, max_tokens, temperature, stop_token,
                request_id=None, priority="interactive", deadline_s=None,
-               speculative=True):
-        """Admit one request. Returns (req, queue); (None, retry_after
+               speculative=True, t_recv=None):
+        """Admit one request. The wait for the serving lock goes on
+        the request's `submit` event as `lock_wait_s`, beside `t_recv`,
+        the handler's time.monotonic() when the request came in.
+        Returns (req, queue); (None, retry_after
         float) when SLO-aware admission SHED it (predicted TTFT busts
         the declared objective — the handler answers 429 with the
         computed Retry-After); (None, None) when the waiting queue is
@@ -458,7 +500,9 @@ class ServerState:
         def on_finish(req):
             q.put(None)  # completion sentinel (after the last on_token)
 
+        t_lock = time.monotonic()
         with self._locked(timeout=self.submit_lock_timeout):
+            lock_wait_s = time.monotonic() - t_lock
             # re-check under the lock: the heartbeat may have wedged the
             # server between the handler's check and this admission
             if self.error:
@@ -475,7 +519,9 @@ class ServerState:
                                     request_id=request_id,
                                     priority=priority,
                                     deadline_s=deadline_s,
-                                    speculative=speculative)
+                                    speculative=speculative,
+                                    lock_wait_s=lock_wait_s,
+                                    t_recv=t_recv)
         self.wake.set()
         return req, q
 
@@ -528,7 +574,7 @@ class ServerState:
         (hung tick holding self.lock) can still be inspected.
         `request_id` filters to one client id's timelines and drops the
         global ring (the fleet trace merge wants exactly one request's
-        events, not every tick in the window)."""
+        events, not every dispatch in the window)."""
         tracer = getattr(self.sched, "trace", None)
         if tracer is None:
             return {"enabled": False, "requests": []}
@@ -599,6 +645,11 @@ def make_handler(state: ServerState):
                             "device": {**state.runtime["device"],
                                        "memory": device_memory()},
                             "allocator": state.runtime["allocator"],
+                            # programs compiled since the ready line:
+                            # each one stalled a tick of live serving
+                            "compiles_after_ready": int(
+                                state._c_compiles.value
+                                - state._compiles_at_ready),
                             "kernels": {
                                 "mode": state.sched.engine.kernel_mode,
                                 "calls": dict(
@@ -668,6 +719,7 @@ def make_handler(state: ServerState):
             return out
 
         def do_POST(self):
+            self._t_recv = time.monotonic()
             self._rid = self._header_rid()
             if self.path == "/generate":
                 self._handle_generate()
@@ -888,7 +940,8 @@ def make_handler(state: ServerState):
                 req, q = state.submit(tokens, max_tokens, temperature, stop,
                                       request_id=rid, priority=priority,
                                       deadline_s=deadline_s,
-                                      speculative=speculative)
+                                      speculative=speculative,
+                                      t_recv=self._t_recv)
             except ValueError as e:  # can never fit the page pool
                 err(400, str(e), "invalid_request_error")
                 return None
@@ -1329,6 +1382,8 @@ def run_server(args) -> int:
                       slo_ttft_s=slo_ttft / 1e3 if slo_ttft else None,
                       slo_itl_s=slo_itl / 1e3 if slo_itl else None,
                       flightrec=flightrec, timeseries=timeseries)
+    from butterfly_tpu.obs.profile import count_compiles
+    count_compiles(sched.registry)  # once: the warm-up's count as set-up
     # On-demand XProf server (--profiler-port): TensorBoard/XProf can
     # then trigger captures of the live process. Failure to start
     # (port in use, no profiler plugin) logs and serves without it —

@@ -174,7 +174,7 @@ def test_tracer_bounds_requests_and_events():
 def test_tracer_global_ring():
     tr = Tracer(max_global_events=4)
     for i in range(10):
-        tr.event(None, "decode_tick", batch=i)
+        tr.event(None, "engine.table_sync", batch=i)
     evs = tr.global_events()
     assert len(evs) == 4
     assert [e["batch"] for e in evs] == [6, 7, 8, 9]
@@ -205,11 +205,11 @@ def test_tracer_dump_is_json_serializable():
     tr = Tracer()
     tr.begin_request(0, request_id=None)
     tr.event(0, "finish", state="finished", tokens=1)
-    tr.event(None, "decode_tick", batch=1)
+    tr.event(None, "engine.table_sync")
     blob = json.dumps(tr.dump())
     back = json.loads(blob)
     assert back["requests"][0]["id"] == 0
-    assert back["global_events"][0]["name"] == "decode_tick"
+    assert back["global_events"][0]["name"] == "engine.table_sync"
 
 
 # -- exposition parsing + fleet aggregation ---------------------------------
@@ -389,7 +389,7 @@ def _synthetic_dump(path):
             tr.event(rid, "admit", slot=0, resumed=True)
         tr.event(rid, "finish", state="finished", tokens=4)
     for i in range(5):
-        tr.event(None, "decode_tick", batch=2, generated=2)
+        tr.event(None, "engine.table_sync")
     tr.dump_json(str(path))
     return path
 
@@ -407,7 +407,14 @@ def test_trace_report_summary_and_timeline(tmp_path):
     assert rows[1]["preemptions"] == 1
     text = mod.render_summary(mod.load_dump(str(dump)))
     assert "client-0" in text and "3 request(s)" in text
-    assert "5 global event(s), 5 decode tick(s)" in text
+    assert "5 global event(s)" in text and "tick(s)" not in text
+    # the tick count comes from a /debug/ticks body
+    from butterfly_tpu.obs.ticklog import TickLog
+    log = TickLog()
+    for _ in range(5):
+        log.record(0.01, {"mixed": 0.01}, batch=2, generated=2)
+    text = mod.render_summary(mod.load_dump(str(dump)), log.dump())
+    assert "5 tick(s), 10 token(s) generated" in text
     tl = mod.render_timeline(mod.load_dump(str(dump)), 1)
     assert "preempt" in tl and "request_id=client-1" in tl
     with pytest.raises(ValueError):

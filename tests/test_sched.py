@@ -5,7 +5,9 @@ exactly the tokens InferenceEngine.generate produces on the contiguous
 cache. Plus: staggered admission, preemption under page pressure, metrics.
 """
 import jax
+import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from butterfly_tpu.core.config import RuntimeConfig, tiny
 from butterfly_tpu.engine import InferenceEngine, SamplingParams
@@ -848,10 +850,15 @@ def test_scheduler_trace_timeline():
     assert ts == sorted(ts)
     fin = tl["events"][-1]
     assert fin["name"] == "finish" and fin["tokens"] == 4
-    # the global ring saw decode ticks and engine dispatches
+    # the global ring saw the engine's dispatches; what each tick held
+    # (batch, waiting, inflight, generated, spec) is in its tick record
     globs = [e["name"] for e in tr.global_events()]
-    assert "decode_tick" in globs
     assert "engine.prefill_dispatch" in globs
+    ticks = sched.ticklog.dump()["ticks"]
+    assert sum(t["generated"] for t in ticks) == 4
+    assert any(t["batch"] == 1 for t in ticks)
+    assert all(t["waiting"] == 0 and t["spec"] is False
+               and t["inflight"] <= 2 for t in ticks)
 
     plain, _ = make_sched()
     assert plain.trace is None  # default: no tracer, bare None check
@@ -1404,3 +1411,134 @@ def test_mixed_fallback_counter_and_reason():
     m2 = sched2.metrics()
     assert m2["spec_mixed_fallback_total"] == 0.0
     assert "spec_mixed_fallback_reason" not in m2
+
+
+# ---------------------------------------------------------------------------
+# the tick on the profiler's clock: the span stack, the programs' names,
+# the compile counter (ISSUE 24)
+# ---------------------------------------------------------------------------
+
+def test_span_stack_keeps_phases_exclusive():
+    """_span pauses the enclosing span's timer: nested sections never
+    count twice, a sub-span is charged to the phase around it, and with
+    the tick's own time in `other` the phases sum to the wall time."""
+    import time as _time
+    sched, _ = make_sched()
+    tp = sched._tick_phases
+    for p in tp:
+        tp[p] = 0.0
+    t0 = sched._span_t = _time.monotonic()
+    with sched._span("admit"):
+        _time.sleep(0.02)
+        with sched._span("drain_barrier"):
+            _time.sleep(0.03)
+            with sched._span("drain.fetch", blocks=1):   # a sub-span
+                _time.sleep(0.02)
+            with sched._span("flush"):
+                _time.sleep(0.01)
+        _time.sleep(0.01)
+    sched._record_tick(t0, 0, sched.engine.blocks_launched)
+    rec = sched.ticklog.dump()["ticks"][-1]
+    ph = rec["phases"]
+    assert sched._span_stack == ["other"]
+    assert 0.03 <= ph["admit"] < 0.045          # 20 + 10 ms, not 90
+    assert 0.05 <= ph["drain_barrier"] < 0.065  # 30 + the fetch's 20
+    assert 0.01 <= ph["flush"] < 0.02
+    assert sum(ph.values()) == pytest.approx(rec["wall_s"], rel=1e-6)
+    assert rec["program"] is None and rec["lock_s"] == 0.0
+
+
+def _engine(**rt_kw):
+    rt_kw.setdefault("kv_write_combine", False)
+    model = Model(CFG)
+    rt = RuntimeConfig(max_batch_size=2, max_seq_len=64, page_size=8,
+                       **rt_kw)
+    return ServingEngine(model, model.init(jax.random.PRNGKey(0)), rt)
+
+
+_SPEC = dict(speculative_gamma=2)
+_TREE = dict(speculative_gamma=2, draft_model="model", spec_tree_width=2,
+             spec_tree_nodes=3)
+
+
+@pytest.mark.parametrize("build,rt_kw,name", [
+    (lambda e: e._prefill, {}, "bf_prefill"),
+    (lambda e: e._prefill_warm, {}, "bf_prefill_warm"),
+    (lambda e: e._decode, {}, "bf_decode_step"),
+    (lambda e: e._flush, {}, "flush_paged_window"),
+    (lambda e: e._decode_block_prog(4), {}, "bf_decode_block"),
+    (lambda e: e._decode_block_win_prog(4), {}, "bf_decode_block_win"),
+    (lambda e: e._mixed_block_prog(4, 8), {}, "bf_mixed_block"),
+    (lambda e: e._mixed_block_win_prog(4, 8), {}, "bf_mixed_block_win"),
+    # chunk width 1: no lane prefills, a decode block in shape and use
+    (lambda e: e._mixed_block_prog(4, 1), {}, "bf_decode_block"),
+    (lambda e: e._mixed_block_win_prog(4, 1), {}, "bf_decode_block_win"),
+    (lambda e: e._spec_block_prog(2), _SPEC, "bf_spec_block"),
+    (lambda e: e._spec_block_win_prog(2), _SPEC, "bf_spec_block_win"),
+    (lambda e: e._mixed_spec_prog(2), _SPEC, "bf_mixed_spec_block"),
+    (lambda e: e._mixed_spec_win_prog(2), _SPEC, "bf_mixed_spec_block_win"),
+    (lambda e: e._spec_tree_prog(2), _TREE, "bf_spec_tree_block"),
+    (lambda e: e._spec_tree_win_prog(2), _TREE, "bf_spec_tree_block_win"),
+])
+def test_program_names(build, rt_kw, name):
+    """Every jitted program of the engine has a stable name, which is
+    what a device trace's `XLA Modules` line calls it (`jit_<name>`)."""
+    assert build(_engine(**rt_kw)).__name__ == name
+
+
+def test_program_name_and_scopes_reach_the_module_and_the_tick_record():
+    sched, _ = make_sched()
+    req = sched.submit([5, 7, 11], max_new_tokens=6)
+    sched.run_until_done()
+    assert req.state == "finished"
+    ticks = sched.ticklog.dump()["ticks"]
+    progs = [t["program"] for t in ticks]
+    # the prompt rides a mixed block; once it is in, blocks are decode
+    assert progs[0] == "bf_mixed_block_win"
+    assert "bf_decode_block_win" in progs
+    blocks = [t["block"] for t in ticks]
+    assert blocks == sorted(blocks) and blocks[-1] == \
+        sched.engine.blocks_launched
+    assert all(t["program"] is None for t, b0 in
+               zip(ticks[1:], blocks) if t["block"] == b0)
+    eng = sched.engine
+    k, C = 1, sched._mixed_chunk
+    text = eng._mixed_block_win_prog(k, C).lower(
+        eng.params, jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
+        eng.cache, eng._kv_window, eng._win_len,
+        jnp.zeros((2, 64), jnp.int32), jnp.zeros((2,), jnp.int32),
+        jnp.ones((2,), bool), jnp.zeros((2,), jnp.float32),
+        jnp.full((2,), -1, jnp.int32), jnp.ones((2,), jnp.int32),
+        0, 1.0, jax.random.PRNGKey(0)).as_text(debug_info=True)
+    assert "module @jit_bf_mixed_block_win" in text
+    # the five parts XProf's op profile groups by are scopes of the ops
+    for scope in ("attn", "mlp", "kv_gather", "kv_window_write", "sample"):
+        assert f'loc("{scope}/' in text, scope
+
+
+def test_compiles_rise_with_a_new_shape_and_not_otherwise():
+    """`compiles` in the tick record is the process's count of programs
+    compiled: ticks over shapes already built leave it alone, the first
+    dispatch of a new one raises it."""
+    from butterfly_tpu.obs.profile import count_compiles
+    sched, _ = make_sched()
+    listener = count_compiles(sched.registry)
+    try:
+        def run(prompt, n):
+            req = sched.submit(prompt, max_new_tokens=n)
+            sched.run_until_done()
+            assert req.state == "finished"
+            return [t["compiles"] for t in sched.ticklog.dump()["ticks"]]
+        first = run([5, 7, 11], 6)
+        assert first[-1] > 0 and first == sorted(first)
+        warm = run([3, 1, 4], 6)           # the same shapes again
+        assert warm[-1] == first[-1]
+        assert sched.registry.get("compiles_total").value == warm[-1]
+        # a new program shape: a decode block of another length
+        sched.engine.runtime = sched.engine.runtime.replace(
+            decode_steps_per_tick=3)
+        new = run([5, 7, 11], 6)
+        assert new[-1] > warm[-1]
+        assert sched.registry.get("compile_seconds_total").value > 0
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)

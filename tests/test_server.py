@@ -668,7 +668,8 @@ def test_profile_path_never_touches_serving_lock():
     is the contract, and here the flag needs no serving lock."""
     import inspect
     from butterfly_tpu.serve.server import ServerState
-    for fn in (ServerState._maybe_profile, ServerState.request_profile):
+    for fn in (ServerState._maybe_profile, ServerState._profile_export,
+               ServerState.request_profile):
         src = inspect.getsource(fn)
         assert "self.lock" not in src
         assert "acquire_lock" not in src
@@ -684,3 +685,155 @@ def test_profiler_server_start_guarded():
     assert isinstance(first, bool) and isinstance(second, bool)
     # whatever the environment supports, a repeat start must degrade
     assert second is False
+
+
+# ---------------------------------------------------------------------------
+# the tick on the profiler's clock (ISSUE 24): bf. spans in a capture,
+# the export off the loop thread, the lock wait on the submit event
+# ---------------------------------------------------------------------------
+
+import contextlib  # noqa: E402
+
+
+@contextlib.contextmanager
+def _serving(**rt_kw):
+    """A server of its own: (url, state)."""
+    from http.server import ThreadingHTTPServer
+    from butterfly_tpu.obs.trace import Tracer
+    model = Model(CFG)
+    rt = RuntimeConfig(max_batch_size=2, max_seq_len=64, page_size=8,
+                       **rt_kw)
+    sched = Scheduler(ServingEngine(model, model.init(jax.random.PRNGKey(0)),
+                                    rt), tracer=Tracer())
+    state = ServerState(sched, ByteTokenizer())
+    state.thread.start()
+    httpd = ThreadingHTTPServer(("127.0.0.1", 0), make_handler(state))
+    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    try:
+        yield f"http://127.0.0.1:{httpd.server_port}", state
+    finally:
+        state.stop.set()
+        httpd.shutdown()
+        state.thread.join(timeout=30)
+
+
+def _capture(url, tmp_path, duration_ms):
+    """POST /debug/profile under steady traffic. Returns the answer, the
+    wall time it came, the capture's bf. events [(name, stats)] and
+    every tick record (followed as the traffic goes: the ring is short).
+    """
+    from jax.profiler import ProfileData
+    body = {"tokens": [5, 7, 11], "max_tokens": 24, "stop_token": -1}
+    for _ in range(3):      # compile outside the capture: a request
+        post(url, "/generate", body)    # into a used slot has programs
+                                        # of its own
+    stop = threading.Event()
+    ticks, since = [], 0
+
+    def traffic():
+        nonlocal since
+        while not stop.is_set():
+            post(url, "/generate", body)
+            d = json.loads(get(url, f"/debug/ticks?since={since}"))
+            ticks.extend(d["ticks"])
+            since = d["next_seq"]
+
+    t = threading.Thread(target=traffic)
+    t.start()
+    try:
+        resp = post(url, "/debug/profile",
+                    {"duration_ms": duration_ms, "logdir": str(tmp_path)})
+        t_resp = time.time()
+    finally:
+        stop.set()
+        t.join(timeout=120)
+    assert not t.is_alive()
+    files = [f for f in resp["files"] if f.endswith(".xplane.pb")]
+    assert files, resp
+    data = ProfileData.from_file(str(tmp_path / files[-1]))
+    events = [(e.name, dict(e.stats)) for p in data.planes
+              for ln in p.lines for e in ln.events
+              if e.name.startswith("bf.")]
+    return resp, t_resp, events, ticks
+
+
+def test_capture_holds_the_ticks_spans_and_exports_off_the_loop(tmp_path):
+    """Every phase of the mixed path and every sub-span is a `bf.` event
+    of the capture; `bf.tick` carries the tick record's `seq`; the
+    handler answers 200 once the export is done, and the loop went on
+    ticking while it ran."""
+    with _serving() as (url, state):
+        resp, t_resp, events, ticks = _capture(url, tmp_path, 2500)
+    names = {n for n, _ in events}
+    assert {"bf.tick", "bf.loop.lock", "bf.loop.wait",
+            "bf.tick.drain.fetch", "bf.tick.drain.emit",
+            "bf.tick.dispatch.put", "bf.tick.dispatch.launch"} <= names
+    assert {"bf.tick." + p for p in (
+        "expire", "drain_oldest", "drain_barrier", "admit", "assemble",
+        "mixed", "flush")} <= names
+    seqs = [st["seq"] for n, st in events if n == "bf.tick"]
+    assert seqs and all(isinstance(s, int) for s in seqs)
+    # the join with /debug/ticks
+    assert set(seqs) <= {t["seq"] for t in ticks}
+    by_seq = {t["seq"]: t for t in ticks}
+    launches = [st for n, st in events if n == "bf.tick.dispatch.launch"]
+    assert {st["program"] for st in launches} <= {
+        "bf_mixed_block_win", "bf_decode_block_win"}
+    assert {st["block"] for st in launches} & {t["block"] for t in ticks}
+    assert all("blocks" in st for n, st in events
+               if n == "bf.tick.drain.fetch")
+    assert any(st.get("tokens", 0) > 0 for n, st in events
+               if n == "bf.tick.drain.emit")
+    assert all(by_seq[s]["batch"] <= 2 for s in seqs if s in by_seq)
+    # the export ran on its own thread: ticks completed meanwhile
+    assert resp["export_s"] > 0 and resp["duration_s"] >= 2.5
+    during = [t for t in ticks
+              if t_resp - resp["export_s"] < t["t_wall"] < t_resp]
+    assert during, (resp, len(ticks))
+    assert all("lock_s" in t and t["compiles"] >= 0 for t in ticks)
+
+
+def test_capture_holds_the_alternating_and_spec_phases(tmp_path):
+    """`dispatch` (the alternating path) and `spec_emit` are spans too."""
+    with _serving(mixed_dispatch=False, speculative_gamma=2) as (url, _):
+        _, _, events, _ = _capture(url, tmp_path, 1500)
+    names = {n for n, _ in events}
+    assert {"bf.tick.dispatch", "bf.tick.spec_emit", "bf.tick.admit",
+            "bf.tick.drain.emit"} <= names
+    assert "bf.tick.mixed" not in names
+    assert {st["program"] for n, st in events
+            if n == "bf.tick.dispatch.launch"} >= {"bf_spec_block_win"}
+
+
+def test_submit_event_carries_the_lock_wait():
+    """The handler's wait for the serving lock is on the `submit` event
+    (`lock_wait_s`, beside `t_recv`), and in the timeline's summary."""
+    from butterfly_tpu.obs.trace import summarize_timeline
+    with _serving() as (url, state):
+        post(url, "/generate", {"tokens": [5, 7], "max_tokens": 2,
+                                "stop_token": -1})
+        out = {}
+        assert state.lock.acquire(timeout=30)
+        try:
+            t = threading.Thread(target=lambda: out.update(post(
+                url, "/generate", {"tokens": [5, 7, 11], "max_tokens": 2,
+                                   "stop_token": -1,
+                                   "request_id": "held"})))
+            t.start()
+            time.sleep(0.4)
+        finally:
+            state.lock.release()
+        t.join(timeout=60)
+        assert len(out["tokens"]) == 2
+        health = json.loads(get(url, "/health"))
+        recs = json.loads(get(url, "/debug/requests"))["requests"]
+    rec = next(r for r in recs if r["request_id"] == "held")
+    submit = next(e for e in rec["events"] if e["name"] == "submit")
+    assert submit["lock_wait_s"] >= 0.3
+    assert submit["t_recv"] <= submit["t"] - submit["lock_wait_s"] + 1e-3
+    assert summarize_timeline(rec)["lock_wait_s"] == submit["lock_wait_s"]
+    other = next(r for r in recs if r["request_id"] != "held")
+    assert next(e for e in other["events"]
+                if e["name"] == "submit")["lock_wait_s"] < 0.3
+    # nothing compiled after the server was built but new shapes
+    assert health["compiles_after_ready"] == 0

@@ -38,14 +38,14 @@ from . import FileContext, Finding, Rule, call_name, dotted_name, register
 #: hot section, so they are IN the set: a timer that materialized a
 #: device value would reintroduce exactly the sync it exists to find.
 HOT_FUNCTIONS: Set[str] = {
-    "tick", "_decode_block", "_spec_block", "_assemble", "_admit",
+    "tick", "_tick_sections", "_decode_block", "_spec_block", "_assemble", "_admit",
     "_admit_round", "_finish_prefill", "_note_bubble",
     "decode_block_async", "spec_block_async", "decode_active_async",
     "prefill_batch", "_sync_table",
     # ISSUE 20: the seq-parallel long-prompt lane — one chunk dispatch
     # per tick; a per-chunk readback would serialize the whole prefill
     "_sp_prefill_step", "sp_prefill_chunk",
-    "_phase_add", "_drain_accrued", "_record_tick",
+    "_span", "_record_tick",
     "record", "note", "poll",
     # ISSUE 16: the signal recorder samples inside _record_tick (the
     # tail of the hot section) — it must consume host floats only

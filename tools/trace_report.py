@@ -11,6 +11,9 @@ did this request's time go" view.
     python tools/trace_report.py trace.json
     python tools/trace_report.py trace.json --timeline 17
 
+``--ticks FILE`` (a ``GET /debug/ticks`` body) adds the count of
+scheduler ticks and of the tokens they generated to the summary.
+
 ``--fleet`` renders a MERGED cross-replica trace instead — the JSON a
 fleet control plane returns from ``GET /fleet/trace?request_id=``: the
 control-plane leg waterfall (classify → prefill_leg → kv transfer →
@@ -76,7 +79,8 @@ def summary_rows(dump: Dict[str, Any]) -> List[Dict[str, Any]]:
     return [summarize(rec) for rec in dump.get("requests", ())]
 
 
-def render_summary(dump: Dict[str, Any]) -> str:
+def render_summary(dump: Dict[str, Any],
+                   ticks: Optional[Dict[str, Any]] = None) -> str:
     rows = summary_rows(dump)
     cols = [("id", 5), ("request_id", 14), ("state", 9), ("queue", 8),
             ("prefill", 8), ("ttft", 8), ("decode", 8), ("total", 8),
@@ -103,9 +107,12 @@ def render_summary(dump: Dict[str, Any]) -> str:
                 f"max {_fmt_s(ttfts[-1])}")
     n_glob = len(dump.get("global_events", ()))
     if n_glob:
-        ticks = sum(1 for ev in dump["global_events"]
-                    if ev.get("name") == "decode_tick")
-        out.append(f"{n_glob} global event(s), {ticks} decode tick(s)")
+        out.append(f"{n_glob} global event(s)")
+    if ticks is not None:
+        recs = ticks.get("ticks", ())
+        out.append(f"{len(recs)} tick(s), "
+                   f"{sum(t.get('generated', 0) for t in recs)} token(s) "
+                   "generated")
     return "\n".join(out)
 
 
@@ -213,6 +220,9 @@ def main(argv=None) -> int:
     p.add_argument("--fleet", action="store_true",
                    help="render a merged cross-replica fleet trace "
                         "(the GET /fleet/trace?request_id= body)")
+    p.add_argument("--ticks", default=None, metavar="FILE",
+                   help="a GET /debug/ticks body: adds the tick count "
+                        "to the summary")
     p.add_argument("--json", action="store_true",
                    help="emit the per-request summaries as JSON instead "
                         "of a table")
@@ -227,7 +237,11 @@ def main(argv=None) -> int:
         elif args.json:
             print(json.dumps(summary_rows(dump)))
         else:
-            print(render_summary(dump))
+            ticks = None
+            if args.ticks:
+                with open(args.ticks) as f:
+                    ticks = json.load(f)
+            print(render_summary(dump, ticks))
     except (ValueError, OSError) as e:
         print(f"error: {e}", file=sys.stderr)
         return 2
