@@ -584,8 +584,9 @@ class ServingEngine:
         staging dispatch and before anything chained later — so the
         scheduler calls it at its drain points, before page
         registration/reclaim ever reads pool state. Returns the
-        device-resident flushed-token count (rides the scheduler's next
-        stacked drain fetch), or None if nothing was staged."""
+        device-resident flushed-token count (the scheduler reads it once
+        it is ready, never in the fetch of the drain that dispatched
+        it), or None if nothing was staged."""
         if not self._win_dirty:
             return None
         with self._mesh_ctx():

@@ -6,7 +6,8 @@ Two bounded, always-cheap instruments the scheduler feeds:
 * ``TickLog`` — a ring of per-tick records: tick sequence number, wall
   time, per-phase host-section durations (the ``TICK_PHASES``
   vocabulary shared with docs/serving.md's tick-pipeline section),
-  the stacked-fetch device wait, in-flight depth, barrier causes,
+  the drain's fetch wait and whether a newer block was still running
+  when it returned, in-flight depth, barrier causes,
   batch occupancy and page headroom, the block program the tick
   dispatched, the loop's wait for the serving lock and the process's
   count of compilations. The sequence number is also the ``seq`` of
@@ -78,8 +79,9 @@ class TickLog:
         self._seq = 0
 
     def record(self, wall_s: float, phases: Dict[str, float], *,
-               fetch_s: float = 0.0, inflight: int = 0,
-               barrier_causes=(), batch: int = 0, waiting: int = 0,
+               fetch_s: float = 0.0, overlapped: Optional[bool] = None,
+               inflight: int = 0, barrier_causes=(), batch: int = 0,
+               waiting: int = 0,
                pages_free: int = 0, generated: int = 0,
                spec: bool = False, program: Optional[str] = None,
                block: int = 0, lock_s: float = 0.0,
@@ -93,6 +95,7 @@ class TickLog:
             "wall_s": wall_s,
             "phases": dict(phases),
             "fetch_s": fetch_s,
+            "overlapped": overlapped,
             "inflight": inflight,
             "barrier_causes": list(barrier_causes),
             "batch": batch,

@@ -19,8 +19,10 @@ engine/serving.py:
   device-resident carry]. Up to RuntimeConfig.inflight_blocks decode
   blocks stay in flight (dispatch-ahead): block t+1 is dispatched
   before block t is drained, so the tick's host section — admission,
-  operand assembly, the stacked fetch itself — overlaps the device
-  computing earlier blocks instead of idling it. A membership change
+  operand assembly, emission — overlaps the device computing the newer
+  blocks instead of idling it: a drain reads only arrays the blocks it
+  drains produced themselves and launches no program to read them, so
+  its fetch returns when the oldest block ends. A membership change
   (admission work, a finish surfacing at drain, preemption, cancel)
   forces a FULL drain barrier so host and device bookkeeping reconcile
   before the next dispatch. Speculative mode dispatches fused SPEC
@@ -312,8 +314,9 @@ class Scheduler:
         self._had_inflight_at_host0 = False
         # First tokens sampled on-device at admission, not yet fetched:
         # [(req, generation=req.preemptions, slot, device scalar)].
-        # Fetched with the same stacked drain (a per-admission host
-        # fetch would pay the full dispatch+fetch RTT per request).
+        # Fetched with the next drain, all in one jax.device_get (a
+        # per-admission host fetch would pay the full dispatch+fetch
+        # RTT per request).
         self._pending_first: List[tuple] = []
         # Membership index over _pending_first, keyed (request id,
         # preemptions) and refreshed at drain time: _decode_block's
@@ -402,6 +405,13 @@ class Scheduler:
             "/ tick count: a healthy pipeline drains lazily and "
             "barriers only on membership changes, never once per "
             "decode or spec round", ("cause",))
+        self._c_overlap = reg.counter_family(
+            "drain_overlap_total",
+            "Lazy drains by what the device was doing when the fetch "
+            "of the oldest block returned: overlapped (the newest "
+            "block in flight was still running, so the tick's host "
+            "work hides behind it) or exposed (it had ended: the "
+            "device idles until the next launch)", ("state",))
         self._h_ttft = reg.histogram(
             "ttft_seconds",
             "Time to first token (submit -> first token drained)",
@@ -454,8 +464,9 @@ class Scheduler:
         # one scatter per pool tensor, BEFORE any finish registers or
         # reclaims pages. The histogram times the host-side flush
         # dispatch section (on an async backend the device cost shows
-        # up in decode_block_seconds instead); the counter rides the
-        # drain's stacked fetch, so it costs no extra sync.
+        # up in decode_block_seconds instead); the counter takes each
+        # flush's count once it is ready (_count_flushed), never in
+        # the drain that dispatched it.
         self._h_kv_flush = reg.histogram(
             "kv_flush_seconds",
             "Host wall time of the write-combined KV window flush "
@@ -469,6 +480,11 @@ class Scheduler:
             "whose requests died before a flush are dropped, not "
             "counted")
         self._kv_flushes: Deque[float] = deque(maxlen=4096)
+        # Flush counts dispatched and not yet read, in device order: a
+        # drain never waits for the flush it has just dispatched (it
+        # queues behind every block in flight). A lazy drain adds the
+        # counts that are ready; a full barrier reads what is left.
+        self._flush_counts: List = []
         # Host-RAM KV tier (ISSUE 17, cache/hosttier.py): prefix-cache
         # eviction demotes page bytes to host DRAM (optionally spilling
         # to disk) instead of dropping them, and admission's prefix
@@ -589,6 +605,9 @@ class Scheduler:
         self._span_stack: List[str] = ["other"]
         self._span_t = time.monotonic()
         self._tick_causes: List[str] = []
+        # the tick's lazy drain, if it had one with a newer block in
+        # flight: was that block still running when the fetch returned
+        self._tick_overlapped: Optional[bool] = None
         # ServerState._loop's wait for the serving lock before the
         # tick under way (it sets this; the tick record carries it)
         self.loop_lock_s = 0.0
@@ -601,7 +620,7 @@ class Scheduler:
         reg.counter(
             "compile_seconds_total",
             "Seconds spent tracing, lowering and compiling programs")
-        # stacked-fetch device wait within this tick's drains: feeds
+        # the fetch's device wait within this tick's drains: feeds
         # the host/device split (tick_host_frac / tick_device_frac) —
         # the fetch is the one tick section that blocks on the device
         self._tick_fetch = 0.0
@@ -803,6 +822,7 @@ class Scheduler:
         self._inflight = []
         self._pending_first = []
         self._pending_first_keys.clear()
+        self._flush_counts = []  # device scalars: dropped unread
         self._spec_rem = None
         # staged-but-unflushed window K/V is DROPPED, not flushed (no
         # device calls here): every owning request is being cancelled,
@@ -855,8 +875,15 @@ class Scheduler:
         overlaps the device computing earlier blocks instead of idling
         it. Draining is lazy: only the
         oldest block is fetched, and only once the in-flight queue is
-        full; a FULL barrier (everything drained) runs only when host
-        and device state must reconcile:
+        full. A lazy drain waits for the block it drains and for
+        nothing newer: it reads that block's own output arrays,
+        launches no program to read them, and leaves the count of the
+        flush it dispatches to a later drain, so its fetch returns
+        while the newer block still runs and the rest of the tick
+        hides behind that block (drain_overlap_total counts how
+        often). A FULL barrier
+        (everything drained) runs only when host and device state must
+        reconcile:
 
         * admission can make progress (a mid-prefill group, or a waiter
           with a free slot) — prefill bookkeeping and budget assembly
@@ -886,6 +913,7 @@ class Scheduler:
             self._tick_phases[p] = 0.0
         self._tick_causes = []
         self._tick_fetch = 0.0
+        self._tick_overlapped = None
         blocks0 = self.engine.blocks_launched
         with TraceAnnotation("bf.tick", seq=self.ticklog.next_seq,
                              batch=len(self.running),
@@ -1020,6 +1048,7 @@ class Scheduler:
         self._t_device_total += fetch
         self._t_host_total += max(0.0, wall - fetch)
         self.ticklog.record(wall, tp, fetch_s=fetch,
+                            overlapped=self._tick_overlapped,
                             inflight=len(self._inflight),
                             barrier_causes=self._tick_causes,
                             batch=len(self.running),
@@ -1752,9 +1781,7 @@ class Scheduler:
         block, final = self.engine.decode_block_async(
             cur, active, temps, stops, budgets, sub, k)
         self._next_dev = final
-        self._inflight.append(("decode", final, block, k, snapshot,
-                               time.monotonic()))
-        self._note_bubble()
+        self._enqueue_block("decode", final, block, k, snapshot)
         return True
 
     def _assemble(self) -> tuple:
@@ -1800,6 +1827,16 @@ class Scheduler:
                                    for req in batch})
                 self._operands_epoch = self._epoch
         return self._operands
+
+    def _enqueue_block(self, kind: str, carry, outs, k: int,
+                       snapshot: Dict, *mixed) -> None:
+        """A dispatched block joins the in-flight queue: (kind, chain
+        carry, emission outputs, steps, slot snapshot, dispatch time,
+        and for the mixed kinds the completing slots and the emission
+        estimate)."""
+        self._inflight.append((kind, carry, outs, k, snapshot,
+                               time.monotonic(), *mixed))
+        self._note_bubble()
 
     def _note_bubble(self) -> None:
         if self._idle_at_host0:
@@ -1852,9 +1889,7 @@ class Scheduler:
             self._hist_dev, self._hist_len_dev, active, temps, stops,
             budgets, specm, sub, rounds)
         self._hist_dev, self._hist_len_dev, self._spec_rem = hist, hlen, rem
-        self._inflight.append(("spec", hlen, (toks, valid), rounds,
-                               snapshot, time.monotonic()))
-        self._note_bubble()
+        self._enqueue_block("spec", hlen, (toks, valid), rounds, snapshot)
         return True
 
     def _mixed_block(self, k: int) -> bool:
@@ -1912,10 +1947,8 @@ class Scheduler:
                     active, temps, stops, budgets, specm, sub, k)
             self._hist_dev, self._hist_len_dev = hist, hlen
             self._spec_rem, self._cursor_dev = rem, cursor
-            self._inflight.append(("mixed_spec", hlen, (toks, valid), k,
-                                   snapshot, time.monotonic(), pf_done,
-                                   None))
-            self._note_bubble()
+            self._enqueue_block("mixed_spec", hlen, (toks, valid), k,
+                                snapshot, pf_done, None)
             return True
         # plain mixed: chunk width C only while a prompt is actually in
         # flight — with no prefill lane the program collapses to C=1,
@@ -1960,18 +1993,17 @@ class Scheduler:
             cur, cursor, self._pbuf_dev, plen, active, temps, stops,
             budgets, sub, k, C)
         self._next_dev, self._cursor_dev = final, cursor
-        self._inflight.append(("mixed", final, (block, valid), k,
-                               snapshot, time.monotonic(), pf_done,
-                               emit_vec))
-        self._note_bubble()
+        self._enqueue_block("mixed", final, (block, valid), k, snapshot,
+                            pf_done, emit_vec)
         return True
 
     def _drain_inflight(self, cause: str = "finish") -> bool:
         """FULL drain barrier: fetch every pending first token and
-        in-flight block in ONE stacked device read. Returns True if any
-        request finished. In spec mode the device budget carry resets
-        to None — the host again knows every emitted token, so the
-        next dispatch reseeds it from exact host state.
+        in-flight block, and read every flush count still pending.
+        Returns True if any request finished. In spec mode the device
+        budget carry resets to None — the host again knows every
+        emitted token, so the next dispatch reseeds it from exact host
+        state.
 
         `cause` labels the barrier in drain_barriers_total{cause=}
         (the membership-change class that forced it: admission, finish,
@@ -1986,26 +2018,46 @@ class Scheduler:
                                         inflight=len(self._inflight))
             blocks, self._inflight = self._inflight, []
             self._spec_rem = None
-            return self._drain_blocks(blocks)
+            finished = self._drain_blocks(blocks)
+            # it waits for everything because it drains everything:
+            # the last flush runs behind the emission above
+            if self._flush_counts:
+                with self._span("drain.fetch", blocks=0):
+                    t_fetch = time.monotonic()
+                    self._count_flushed(wait=True)
+                    self._tick_fetch += time.monotonic() - t_fetch
+            return finished
 
     def _drain_oldest(self) -> bool:
         """Lazy-drain step: fetch the pending firsts and ONLY the
         oldest in-flight block, leaving newer blocks running on the
         device (the dispatch-ahead overlap — the device computes block
-        t+1 while the host emits block t). Returns True if any request
-        finished (the caller escalates that to a full barrier)."""
+        t+1 while the host emits block t). The fetch reads arrays that
+        block t produced itself and launches nothing, so it returns
+        when block t ends, whatever was launched after it. Returns
+        True if any request finished (the caller escalates that to a
+        full barrier)."""
         with self._span("drain_oldest"):
-            return self._drain_blocks([self._inflight.pop(0)]
-                                      if self._inflight else [])
+            finished = self._drain_blocks([self._inflight.pop(0)]
+                                          if self._inflight else [])
+            self._count_flushed(wait=False)
+            return finished
+
+    def _count_flushed(self, wait: bool) -> None:
+        """Add pending flush counts to kv_window_tokens_flushed_total,
+        oldest first: those already on hand, or with `wait` (a full
+        barrier) all of them. Reading a count launches nothing."""
+        pend = self._flush_counts
+        while pend and (wait or _device_ready(pend[0])):
+            self._c_kv_flushed.inc(int(pend.pop(0)))
 
     def _drain_blocks(self, blocks: List[tuple]) -> bool:
-        """Fetch + emit the given decode blocks (ONE stacked device
-        fetch) and do their host bookkeeping in chronological order.
-        Pending first tokens always ride along: they are queued at an
-        admission barrier, when nothing is in flight, so they predate
-        every dispatched block; each block's [k, S] rows are then
-        emitted in step order per live slot, truncated per request at
-        its stop token / max_new by _emit.
+        """Fetch + emit the given blocks and do their host bookkeeping
+        in chronological order. Pending first tokens always ride along:
+        they are queued at an admission barrier, when nothing is in
+        flight, so they predate every dispatched block; each block's
+        [k, S] rows are then emitted in step order per live slot,
+        truncated per request at its stop token / max_new by _emit.
 
         Requests that finished, were cancelled, or were preempted
         between dispatch and drain have their tokens discarded — the
@@ -2018,9 +2070,11 @@ class Scheduler:
         # the flush dispatch lands after every staged block in device
         # order, so by the time an emission below finishes a request —
         # registering its pages for prefix reuse and releasing them for
-        # reclaim — every staged K/V byte is in the pool. No-op (None)
-        # when nothing is staged; the flushed-token count is a device
-        # scalar that rides this drain's one stacked fetch.
+        # reclaim — every staged K/V byte is in the pool. Its place in
+        # that order is what makes this safe, not its completion: the
+        # flushed-token count is a device scalar that _count_flushed
+        # reads once it is ready, never this drain's fetch. No-op
+        # (None) when nothing is staged.
         t_flush = time.monotonic()
         with self._span("flush"):
             flushed = self.engine.flush_kv_window()
@@ -2028,64 +2082,55 @@ class Scheduler:
             dt = time.monotonic() - t_flush
             self._h_kv_flush.observe(dt)
             self._kv_flushes.append(dt)
+            self._flush_counts.append(flushed)
             if self.flightrec is not None:
                 self.flightrec.note("flush", dispatch_s=dt)
         firsts, self._pending_first = self._pending_first, []
         self._pending_first_keys.clear()  # refreshed: all entries drain
         if not blocks and not firsts:
-            if flushed is not None:
-                self._c_kv_flushed.inc(int(flushed))
             return False
         finished_before = self._c_finished.value
-        parts = [f[3].reshape(1) for f in firsts]
-        for ent in blocks:
-            if ent[0] == "decode":
-                parts.append(ent[2].reshape(-1))
-            else:  # spec/mixed: stacked emissions + validity mask ride
-                # the same single fetch (bool widened to the int dtype)
-                toks3, valid3 = ent[2]
-                parts.append(toks3.reshape(-1))
-                parts.append(valid3.astype(jnp.int32).reshape(-1))
-        if flushed is not None:
-            parts.append(flushed.reshape(1))  # trailing; offsets unaffected
-        # the ONE stacked device fetch: the only tick section that
-        # blocks on the device — timed for the tick_host_frac /
-        # tick_device_frac split (everything else in a tick is host).
-        # The parts are joined on the device, so one transfer crosses
-        # to the host (checked on a four-chip seq mesh, PR 21: the
-        # joined values match the parts under every mesh form).
+        # the ONE device fetch: the only tick section that blocks on
+        # the device — timed for the tick_host_frac / tick_device_frac
+        # split (everything else in a tick is host). It reads the
+        # drained blocks' own output arrays as they are and launches no
+        # program: anything launched here would queue behind the newest
+        # block in flight, and the fetch would wait for that block too.
+        # Shapes, the validity masks' dtype and the order are the
+        # host's to handle.
         with self._span("drain.fetch", blocks=len(blocks)):
             t_fetch = time.monotonic()
-            vals = np.asarray(jnp.concatenate(parts)) if len(parts) > 1 \
-                else np.asarray(parts[0])
+            first_vals, block_vals = jax.device_get(
+                ([f[3] for f in firsts], [ent[2] for ent in blocks]))
             self._tick_fetch += time.monotonic() - t_fetch
+        if self._inflight:
+            # a lazy drain with a newer block in flight: if its carry
+            # is not ready the device has work queued while the host
+            # goes on (overlapped); if it is, the fetch outlasted it
+            self._tick_overlapped = not _device_ready(self._inflight[-1][1])
+            self._c_overlap.labels("overlapped" if self._tick_overlapped
+                                   else "exposed").inc()
         tokens0 = self._c_tokens.value
         with self._span("drain.emit") as ann:
-            self._emit_drained(firsts, blocks, vals, flushed is not None)
+            self._emit_drained(firsts, first_vals, blocks, block_vals)
             ann.set_metadata(tokens=int(self._c_tokens.value - tokens0))
         self._epoch += 1  # outputs / pending-first changed
         return self._c_finished.value > finished_before
 
-    def _emit_drained(self, firsts: List[tuple], blocks: List[tuple],
-                      vals: np.ndarray, flushed: bool) -> None:
-        """Hand one stacked fetch's values to their requests, in
+    def _emit_drained(self, firsts: List[tuple], first_vals: List,
+                      blocks: List[tuple], block_vals: List) -> None:
+        """Hand one fetch's host arrays to their requests, in
         chronological order: pending firsts, then each block's rows."""
-        C = self.engine.spec_emit_width
-        if flushed:
-            self._c_kv_flushed.inc(int(vals[-1]))
         now = time.monotonic()
-        nf = len(firsts)
-        S = self.engine.num_slots
-        for (req, gen, slot, _), tok in zip(firsts, vals[:nf]):
+        for (req, gen, slot, _), tok in zip(firsts, first_vals):
             # stale if the request was cancelled or preempted (a
             # readmission queues a fresh entry with a new generation)
             if req.done or req.slot != slot or req.preemptions != gen:
                 continue
             self._next_tokens[slot] = int(tok)
             self._emit(req, int(tok))
-        off = nf
-        for ent in blocks:
-            kind, _, _, k, snapshot, t_dispatch = ent[:6]
+        for ent, vals in zip(blocks, block_vals):
+            kind, _, _, _, snapshot, t_dispatch = ent[:6]
             self._h_decode_block.observe(now - t_dispatch)
             if kind in ("mixed", "mixed_spec"):
                 # prefill lanes that completed inside this block leave
@@ -2093,36 +2138,14 @@ class Scheduler:
                 # the block's emission arrays) is emitted below
                 self._mixed_transitions(ent[6], snapshot)
             if kind in ("spec", "mixed_spec"):
-                toks3 = vals[off:off + k * S * C].reshape(k, S, C)
-                off += k * S * C
-                valid3 = vals[off:off + k * S * C].reshape(k, S, C) != 0
-                off += k * S * C
+                toks3, valid3 = vals  # [rounds, S, C] each
                 with self._span("spec_emit"):
                     self._emit_spec(toks3, valid3, snapshot)
                 continue
-            if kind == "mixed":
-                # [k, S] tokens + validity: a lane emits at most one
-                # token per step, valid only on decode steps and the
-                # completion step's first token
-                rows = vals[off:off + k * S].reshape(k, S)
-                off += k * S
-                ok = vals[off:off + k * S].reshape(k, S) != 0
-                off += k * S
-                for slot, (req, gen) in snapshot.items():
-                    if req.done or req.slot != slot \
-                            or req.preemptions != gen:
-                        continue
-                    for tok, good in zip(rows[:, slot].tolist(),
-                                         ok[:, slot].tolist()):
-                        if not good:
-                            continue
-                        self._next_tokens[slot] = tok
-                        self._emit(req, tok)
-                        if req.done:
-                            break
-                continue
-            rows = vals[off:off + k * S].reshape(k, S)
-            off += k * S
+            # [k, S] tokens; a mixed block adds the validity mask: a
+            # lane emits at most one token per step, valid only on
+            # decode steps and the completion step's first token
+            rows, ok = vals if kind == "mixed" else (vals, None)
             for slot, (req, gen) in snapshot.items():
                 if req.done or req.slot != slot or req.preemptions != gen:
                     continue
@@ -2130,7 +2153,9 @@ class Scheduler:
                 # live slot instead of k per-element int(row[slot])
                 # casts over the whole [k, S] block (O(k*S) Python work
                 # per drain at S=32, k=16)
-                for tok in rows[:, slot].tolist():
+                toks = rows[:, slot] if ok is None \
+                    else rows[:, slot][ok[:, slot]]
+                for tok in toks.tolist():
                     self._next_tokens[slot] = tok
                     self._emit(req, tok)
                     if req.done:

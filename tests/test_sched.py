@@ -1542,3 +1542,257 @@ def test_compiles_rise_with_a_new_shape_and_not_otherwise():
         assert sched.registry.get("compile_seconds_total").value > 0
     finally:
         jax.monitoring.unregister_event_duration_listener(listener)
+
+
+# -- the lazy drain (PR 25): it reads what the drained block produced,
+# launches nothing to read it, and waits for nothing newer ----------------
+
+class _Untouchable:
+    """Stands for a device value the drain must neither read, wait on
+    nor probe."""
+
+    def __getattr__(self, name):
+        raise AssertionError(f"the drain touched a newer value: .{name}")
+
+    def __array__(self, *a, **kw):
+        raise AssertionError("the drain read a newer value")
+
+    def __int__(self):
+        raise AssertionError("the drain read a newer value")
+
+
+class _NotYet(_Untouchable):
+    """A device value still being computed: it may be asked whether it
+    is ready, and says no."""
+
+    def is_ready(self):
+        return False
+
+
+class _HostOnly:
+    """A device array that may be copied to the host and nothing else:
+    any method that would launch a program (reshape, astype, indexing,
+    use as a jnp operand) fails."""
+
+    def __init__(self, arr):
+        self._arr = arr
+
+    def copy_to_host_async(self):
+        self._arr.copy_to_host_async()
+
+    def is_ready(self):
+        return self._arr.is_ready()
+
+    def __array__(self, *a, **kw):
+        return np.asarray(self._arr)
+
+    def __getattr__(self, name):
+        raise AssertionError(f"the drain launched a program: .{name}")
+
+    def __getitem__(self, idx):
+        raise AssertionError("the drain launched a program: indexing")
+
+
+class _Carry:
+    def __init__(self, ready):
+        self.ready = ready
+
+    def is_ready(self):
+        return self.ready
+
+
+_BLOCK_KINDS = {
+    "decode": dict(mixed_dispatch=False),
+    "spec": dict(mixed_dispatch=False, speculative_gamma=2),
+    "mixed": dict(),
+    "mixed_spec": dict(speculative_gamma=2),
+}
+_DRAIN_PROMPTS = [[5, 7, 11], [3, 1, 4, 1, 5]]
+
+
+def _two_in_flight(kind, max_new=24, **rt_kw):
+    """A scheduler of `kind`'s blocks with two of them in flight, the
+    state in which a tick begins with a lazy drain."""
+    sched, params = make_sched(decode_steps_per_tick=2, **_BLOCK_KINDS[kind],
+                               **rt_kw)
+    reqs = [sched.submit(p, max_new_tokens=max_new) for p in _DRAIN_PROMPTS]
+    for _ in range(40):
+        if len(sched._inflight) >= 2:
+            break
+        sched.tick()
+    assert [e[0] for e in sched._inflight] == [kind, kind]
+    return sched, params, reqs
+
+
+def _swap_outputs(sched, i, wrap):
+    ent = sched._inflight[i]
+    real = ent[2]
+    sched._inflight[i] = ent[:2] + (jax.tree_util.tree_map(wrap, real),) \
+        + ent[3:]
+    return real
+
+
+@pytest.mark.parametrize("kind", list(_BLOCK_KINDS))
+def test_lazy_drain_waits_for_nothing_newer(kind):
+    """With two blocks in flight, the newer block's outputs and the
+    count of the flush the drain itself dispatches fail when read or
+    waited on: the drain still hands out the oldest block's tokens."""
+    sched, params, reqs = _two_in_flight(kind)
+    newer = _swap_outputs(sched, 1, lambda a: _Untouchable())
+    flush, counts = sched.engine.flush_kv_window, []
+
+    def flush_unreadable():
+        counts.append(flush())
+        return None if counts[-1] is None else _NotYet()
+    sched.engine.flush_kv_window = flush_unreadable
+    had = [len(r.output) for r in reqs]
+    finished = sched._drain_oldest()
+    sched.engine.flush_kv_window = flush
+    assert not finished and len(sched._inflight) == 1
+    assert counts and counts[0] is not None      # the flush was dispatched
+    assert sum(len(r.output) for r in reqs) > sum(had)
+    for r, p in zip(reqs, _DRAIN_PROMPTS):
+        assert r.output == ref_tokens(params, p, 24)[:len(r.output)]
+    # the real values back: the run ends as any other, nothing lost
+    ent = sched._inflight[0]
+    sched._inflight[0] = ent[:2] + (newer,) + ent[3:]
+    sched._flush_counts = [counts[0] if isinstance(c, _NotYet) else c
+                           for c in sched._flush_counts]
+    sched.run_until_done()
+    for r, p in zip(reqs, _DRAIN_PROMPTS):
+        assert r.output == ref_tokens(params, p, 24)
+
+
+@pytest.mark.parametrize("kind", list(_BLOCK_KINDS))
+def test_lazy_drain_launches_no_program_to_read(kind, monkeypatch):
+    """Between the entry of `_drain_oldest` and the end of its fetch no
+    device program is launched but the flush: the drained block's
+    outputs allow a copy to the host and nothing else, `jnp` joins
+    nothing, and the process compiles nothing."""
+    from butterfly_tpu.obs.profile import count_compiles
+    from butterfly_tpu.sched import scheduler as S
+    sched, params, reqs = _two_in_flight(kind)
+    # one lazy drain first, so that the flush's program is built
+    sched.tick()
+    assert len(sched._inflight) == 2
+    _swap_outputs(sched, 0, _HostOnly)
+
+    def no_join(*a, **kw):
+        raise AssertionError("the drain joined its parts on the device")
+    monkeypatch.setattr(S.jnp, "concatenate", no_join)
+    listener = count_compiles(sched.registry)
+    try:
+        n0 = sched.registry.get("compiles_total").value
+        assert not sched._drain_oldest()
+        assert sched.registry.get("compiles_total").value == n0
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    monkeypatch.undo()
+    sched.run_until_done()
+    for r, p in zip(reqs, _DRAIN_PROMPTS):
+        assert r.output == ref_tokens(params, p, 24)
+
+
+def test_pending_firsts_fetched_without_a_program():
+    """The alternating path's pending first tokens ride the same fetch:
+    read as they are, many small arrays in one `jax.device_get`."""
+    sched, params = make_sched(mixed_dispatch=False, decode_steps_per_tick=2)
+    reqs = [sched.submit(p, max_new_tokens=8) for p in _DRAIN_PROMPTS]
+    for _ in range(10):
+        if sched._pending_first:
+            break
+        sched.tick()
+    assert len(sched._pending_first) == 2
+    sched._pending_first = [f[:3] + (_HostOnly(f[3]),)
+                            for f in sched._pending_first]
+    calls = []
+    real = jax.device_get
+    try:
+        jax.device_get = lambda x: calls.append(x) or real(x)
+        sched._drain_inflight("idle")
+    finally:
+        jax.device_get = real
+    assert len(calls) == 1 and len(calls[0][0]) == 2
+    sched.run_until_done()
+    for r, p in zip(reqs, _DRAIN_PROMPTS):
+        assert r.output == ref_tokens(params, p, 8)
+
+
+@pytest.mark.parametrize("kind", ["decode", "mixed"])
+def test_flush_counts_read_later_are_not_lost(kind):
+    """`kv_window_tokens_flushed_total`, once the scheduler is idle, is
+    the sum of every flush's count, as when each rode its own drain's
+    fetch."""
+    sched, params = make_sched(decode_steps_per_tick=2, **_BLOCK_KINDS[kind])
+    flush, counts = sched.engine.flush_kv_window, []
+
+    def recording():
+        counts.append(flush())
+        return counts[-1]
+    sched.engine.flush_kv_window = recording
+    reqs = [sched.submit(p, max_new_tokens=24) for p in _DRAIN_PROMPTS]
+    sched.run_until_done()
+    assert all(r.state == "finished" for r in reqs)
+    assert sched._flush_counts == []          # the last barrier read them
+    total = sum(int(c) for c in counts if c is not None)
+    assert total > 0
+    assert sched.registry.get("kv_window_tokens_flushed_total").value == total
+    assert sched.metrics()["kv_window_tokens_flushed_total"] == total
+
+
+def test_lazy_drain_adds_only_the_flush_counts_that_are_ready():
+    """In device order, and stopping at the first that is not ready; a
+    full barrier then reads the rest."""
+    class Count(_Carry):
+        def __init__(self, n, ready):
+            self.n, self.ready = n, ready
+
+        def __int__(self):
+            return self.n
+    sched, _ = make_sched()
+    total = sched.registry.get("kv_window_tokens_flushed_total")
+    sched._flush_counts = [Count(3, True), _NotYet(), Count(5, True)]
+    sched._count_flushed(wait=False)
+    assert total.value == 3 and len(sched._flush_counts) == 2
+    sched._flush_counts[0] = Count(4, False)
+    sched._drain_inflight("idle")
+    assert total.value == 12 and sched._flush_counts == []
+
+
+def test_abort_all_drops_pending_flush_counts_unread():
+    sched, _, reqs = _two_in_flight("mixed")
+    _swap_outputs(sched, 0, lambda a: _Untouchable())
+    _swap_outputs(sched, 1, lambda a: _Untouchable())
+    sched._flush_counts = [_Untouchable(), _Untouchable()]
+    before = sched.registry.get("kv_window_tokens_flushed_total").value
+    sched.abort_all()
+    assert sched._flush_counts == [] and sched._inflight == []
+    assert sched.registry.get("kv_window_tokens_flushed_total").value == before
+    assert all(r.state == "cancelled" for r in reqs)
+
+
+def test_drain_overlap_counter_and_tick_record():
+    """`drain_overlap_total` and the tick record's `overlapped` follow
+    the newest in-flight block's chain carry at the end of a lazy
+    drain's fetch: not ready is overlapped, ready is exposed; a tick
+    with no lazy drain records null."""
+    sched, _, _ = _two_in_flight("mixed", max_new=40)
+    fam = sched._c_overlap
+    first = sched.ticklog.dump()["ticks"][0]
+    assert first["overlapped"] is None        # nothing to drain yet
+    script = [False, True, True, False, True]
+    for ready in script:
+        assert len(sched._inflight) == 2
+        ent = sched._inflight[-1]
+        sched._inflight[-1] = ent[:1] + (_Carry(ready),) + ent[2:]
+        sched.tick()
+        rec = sched.ticklog.dump()["ticks"][-1]
+        assert rec["overlapped"] is (not ready)
+        assert rec["fetch_s"] >= 0.0
+    assert fam.labels("overlapped").value == script.count(False)
+    assert fam.labels("exposed").value == script.count(True)
+    text = sched.registry.render()
+    assert 'drain_overlap_total{state="overlapped"} 2' in text
+    # a full barrier drains everything: no newer block, nothing counted
+    sched._drain_inflight("flush")
+    assert fam.labels("overlapped").value + fam.labels("exposed").value == 5

@@ -791,6 +791,9 @@ def test_capture_holds_the_ticks_spans_and_exports_off_the_loop(tmp_path):
               if t_resp - resp["export_s"] < t["t_wall"] < t_resp]
     assert during, (resp, len(ticks))
     assert all("lock_s" in t and t["compiles"] >= 0 for t in ticks)
+    # lazy drains say whether the newer block was still running
+    assert {t["overlapped"] for t in ticks} <= {None, True, False}
+    assert any(t["overlapped"] is not None for t in ticks)
 
 
 def test_capture_holds_the_alternating_and_spec_phases(tmp_path):

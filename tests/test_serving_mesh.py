@@ -58,6 +58,47 @@ def test_meshed_scheduler_token_parity(params, mesh):
     assert not bad, f"meshed serving donation failed to alias: {bad}"
 
 
+@pytest.mark.parametrize("axes", [
+    dict(data=2, tensor=4), dict(tensor=8), dict(stage=2, tensor=4),
+    dict(stage=2, data=4), dict(seq=8), dict(seq=2, tensor=4),
+], ids=lambda a: "x".join(f"{k}{v}" for k, v in a.items()))
+def test_drain_fetches_each_array_whole_under_every_mesh_form(
+        params, axes, monkeypatch):
+    """The drain fetches each drained array as it is and joins nothing
+    on the device (PR 25; PR 21 checked the joined values on the chip):
+    under every mesh form the host's copy of each array agrees with
+    every shard the devices hold, replicated or split, and the tokens
+    with the unmeshed scheduler's."""
+    import numpy as np
+    ref = _make_sched(params)
+    ref_reqs = [ref.submit(p, max_new_tokens=9) for p in PROMPTS]
+    ref.run_until_done()
+
+    real, seen = jax.device_get, []
+
+    def checked(tree):
+        out = real(tree)
+        for arr, host in zip(jax.tree_util.tree_leaves(tree),
+                             jax.tree_util.tree_leaves(out)):
+            assert host.shape == arr.shape and host.dtype == arr.dtype
+            for shard in arr.addressable_shards:
+                np.testing.assert_array_equal(np.asarray(shard.data),
+                                              host[shard.index])
+            seen.append(len(arr.sharding.device_set))
+        return out
+    monkeypatch.setattr(jax, "device_get", checked)
+    rt = RuntimeConfig(max_batch_size=4, max_seq_len=64, page_size=8,
+                       decode_steps_per_tick=2)
+    sched = Scheduler(ServingEngine(Model(CFG), params, rt,
+                                    mesh=make_mesh(MeshConfig(**axes))))
+    reqs = [sched.submit(p, max_new_tokens=9) for p in PROMPTS]
+    sched.run_until_done()
+    assert [r.output for r in reqs] == [r.output for r in ref_reqs]
+    assert seen and max(seen) > 1   # arrays that live on several devices
+    assert sched._c_overlap.labels("overlapped").value \
+        + sched._c_overlap.labels("exposed").value > 0   # lazy drains ran
+
+
 def test_meshed_scheduler_kernels_token_parity(params, mesh):
     """Pallas kernels (interpret mode) under the mesh == unmeshed gather
     path, token-exact — the round-2 VERDICT item 1 regression test."""

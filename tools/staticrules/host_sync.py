@@ -32,13 +32,15 @@ from typing import Iterator, Set
 from . import FileContext, Finding, Rule, call_name, dotted_name, register
 
 #: functions whose bodies must stay sync-free. Drain/emit functions are
-#: intentionally absent: the stacked drain is the one blessed fetch.
+#: intentionally absent: the drain's one `jax.device_get` over the
+#: drained blocks' own outputs is the one blessed fetch.
 #: The ISSUE 15 tick-anatomy paths (phase timers, the ticklog ring
 #: append, the flight-recorder note/poll) run once per tick inside the
 #: hot section, so they are IN the set: a timer that materialized a
 #: device value would reintroduce exactly the sync it exists to find.
 HOT_FUNCTIONS: Set[str] = {
-    "tick", "_tick_sections", "_decode_block", "_spec_block", "_assemble", "_admit",
+    "tick", "_tick_sections", "_decode_block", "_spec_block",
+    "_enqueue_block", "_assemble", "_admit",
     "_admit_round", "_finish_prefill", "_note_bubble",
     "decode_block_async", "spec_block_async", "decode_active_async",
     "prefill_batch", "_sync_table",
