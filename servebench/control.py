@@ -69,7 +69,7 @@ def measure(config: dict, seeds, control_seeds, paths=None) -> dict:
     sound, control = [], {k: [] for k in kinds}
     for n, seed in enumerate(seeds):
         rms = {k: 0.0 for k in ("sound",) + kinds}
-        for toks in refcheck.sample(seed, b.cfg.vocab_size):
+        for toks in refcheck.sample(seed, b.cfg.vocab_size, b.width):
             want = b.ref.logits(toks, b.leaf, config)
             rows = refcheck.program_rows(b, toks)
             rms["sound"] = max(rms["sound"], refcheck.errors(rows, want)[0])

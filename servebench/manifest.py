@@ -30,6 +30,20 @@ def find_under_paths(manifest: Dict, root: Path, *parts: str) -> Path:
                             + ", ".join(manifest["paths"]))
 
 
+def decode_width(config: Dict) -> int:
+    """Positions of each live stream that one decode call carries: the
+    configuration file's top-level `decode_width`, 1 where it has none
+    (a token a step). A model that generates by blocks of B positions
+    states B: each of its steps is a forward of B positions a stream.
+    Read by the reference check (how the program is driven) and by the
+    least time of a block (servebench/peaks.py)."""
+    w = config.get("decode_width", 1)
+    if not isinstance(w, int) or isinstance(w, bool) or w < 1:
+        raise ValueError(f"`decode_width` of {config.get('name')!r} is "
+                         f"{w!r}: a whole number of positions, 1 or more")
+    return w
+
+
 class Cell:
     """One entry of `workloads`, with its files resolved."""
 

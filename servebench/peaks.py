@@ -9,6 +9,8 @@ from __future__ import annotations
 
 from typing import Dict
 
+from servebench.manifest import decode_width
+
 #: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s
 #: int8, 16 GB of HBM at 819 GB/s per chip.
 PEAKS: Dict[str, Dict[str, float]] = {
@@ -93,15 +95,18 @@ def block_least_seconds(config: Dict, device_kind: str, chips: int,
                         steps: int, live_streams: float,
                         live_context_tokens: float) -> Dict[str, float]:
     """The least time `chips` chips could take for one block of `steps`
-    decode steps: each step streams the weights its live streams touch
-    once (`streamed_params`) and the live context's keys and values, and
-    does two operations per live stream and parameter the stream's token
-    is multiplied by (`matmul_params`). Returns both bounds and which
-    one binds."""
+    decode steps of w = `decode_width` positions a stream: each step
+    streams the weights that the positions of its live streams touch
+    once (`streamed_params` at live streams x w draws) and the live
+    context's keys and values once, and does two operations per
+    position and parameter the position is multiplied by
+    (`matmul_params`). Returns both bounds and which one binds."""
     pk = peaks_of(device_kind)
-    by = steps * (weight_bytes(config, live_streams)
+    w = decode_width(config)
+    live = max(1.0, live_streams)
+    by = steps * (weight_bytes(config, live * w)
                   + live_context_tokens * kv_bytes_per_token(config))
-    fl = steps * 2.0 * matmul_params(config) * max(1.0, live_streams)
+    fl = steps * 2.0 * matmul_params(config) * live * w
     t_mem = by / (chips * pk["hbm_bytes_per_s"])
     t_cmp = fl / (chips * pk["bf16_flops_per_s"])
     return {"bytes": by, "flops": fl, "memory_s": t_mem, "compute_s": t_cmp,

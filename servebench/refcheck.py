@@ -8,9 +8,24 @@ Run by the harness as a child BEFORE the server starts (one process
 holds the chip at a time). It builds the weights exactly as `butterfly
 serve` does (`serve/cli.py:load_params`: PRNGKey(0), the configuration's
 quantization and mesh), runs the program's model over a seeded sample of
-short prompts (prefill through the cache, then decode steps through it)
+short prompts (prefill through the cache, then decode calls through it)
 and compares LOGITS, not tokens, with the configuration's float32
 reference.
+
+The width of a decode call is the configuration file's top-level
+`decode_width` (1 where the file has none: a model that yields a token
+a step). At width 1 the prefill is PREFILL tokens and DECODE calls of
+one token follow. A model that generates by blocks of w positions sees
+the whole block from each of its positions, so the program is fed as its
+timed path feeds it: a prefill of whole blocks (the largest multiple of
+w not over PREFILL, one block at least), then calls of w positions
+through the cache, two of them or DECODE tokens' worth if that is more,
+so that one decoded block attends a block that an earlier decode call
+wrote. EVERY row of
+each call is compared with the reference's row at that position, the
+prefill's last row as well. The check's cache holds CACHE positions; a
+width whose prefill and calls do not fit it is an error that names the
+key.
 
 The reference owns the names of its weights. A file
 `<path>/references/<name>.py` under one of the manifest's `paths`
@@ -26,6 +41,18 @@ tuple: layer and expert), so that a reference holds one layer's, or one
 expert's, float32 weights at a time. `config` is the configuration FILE:
 the reference takes its sizes from the published keys, not from the
 program's ModelConfig. This file knows no leaf name of any family.
+
+What a reference for a wide step owes: `logits` is still ONE full
+forward of the whole sequence, and its row p is what position p reads
+under the configuration's OWN mask (for generation by blocks of B:
+position p attends j where j // B <= p // B). The reference owns that
+mask as it owns its leaf names; this file knows only how many positions
+a call carries. A program whose calls of w positions are causal inside
+the call differs from such a reference at every row compared, the last
+row of a block too once there are two layers (tests/servebench/: by
+hundreds of times a float32 limit; PERF.md has the reading against a
+bfloat16 one), and so does a block-masked program from a causal
+reference.
 
 The error is the root of the mean squared difference over the standard
 deviation of the reference's logits at that position, the worst over
@@ -52,12 +79,14 @@ ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 
 from servebench.launcher import NO_CHIP, model_fields  # noqa: E402
-from servebench.manifest import find_under_paths, load_manifest  # noqa: E402
+from servebench.manifest import decode_width, find_under_paths, load_manifest  # noqa: E402
 
 #: rms logit difference / std of the reference's logits, where the
 #: configuration's file states no `reference_tolerance`
 TOLERANCE = 0.12
 PROMPTS, PREFILL, DECODE = 2, 12, 4
+#: positions the check's cache holds
+CACHE = 32
 
 
 def load_reference(name: str, paths=None):
@@ -94,6 +123,20 @@ def leaf_reader(params, is_quantized):
     return leaf
 
 
+def lengths(width: int) -> tuple:
+    """(prefill, decode) tokens of one sequence at a decode width: whole
+    blocks before the first decode call, one at least, then two calls or
+    more."""
+    prefill = max(1, PREFILL // width) * width
+    decode = max(DECODE, 2 * width)
+    if prefill + decode > CACHE:
+        raise ValueError(
+            f"`decode_width` {width}: a prefill of {prefill} and "
+            f"{decode // width} calls of {width} do not fit the check's "
+            f"cache of {CACHE} positions")
+    return prefill, decode
+
+
 def build(config: dict, paths=None) -> SimpleNamespace:
     """The program's model with the weights `butterfly serve` would hold
     for this configuration, and the configuration's reference over them."""
@@ -102,6 +145,8 @@ def build(config: dict, paths=None) -> SimpleNamespace:
     from butterfly_tpu.models.common import Model
     from butterfly_tpu.quant.int8 import is_quantized_leaf
     from butterfly_tpu.serve import cli
+    width = decode_width(config)
+    lengths(width)          # a width that does not fit fails before the weights
     cfg = ModelConfig(**model_fields(config))
     model = Model(cfg)
     serve = config["serve"]
@@ -109,29 +154,33 @@ def build(config: dict, paths=None) -> SimpleNamespace:
                          tensor_parallel=serve.get("tensor_parallel", 1))
     params = cli.load_params(model, ns, cli.build_mesh(ns))
     return SimpleNamespace(
-        cfg=cfg, model=model, params=params,
+        cfg=cfg, model=model, params=params, width=width,
         fwd=jax.jit(lambda p, t, c: model(p, t, c)),
         ref=load_reference(config["reference"], paths=paths),
         leaf=leaf_reader(params, is_quantized_leaf))
 
 
-def sample(seed: int, vocab: int) -> list:
-    """The seed's PROMPTS sequences of PREFILL + DECODE token ids."""
+def sample(seed: int, vocab: int, width: int = 1) -> list:
+    """The seed's PROMPTS sequences of token ids, prefill and decode
+    (`lengths`: PREFILL + DECODE at width 1)."""
     rng = random.Random(int(seed))
-    return [[rng.randrange(1, vocab) for _ in range(PREFILL + DECODE)]
+    return [[rng.randrange(1, vocab) for _ in range(sum(lengths(width)))]
             for _ in range(PROMPTS)]
 
 
 def program_rows(b: SimpleNamespace, toks: list) -> list:
     """[(position, logits [V])] of the program: the prefill's last
-    position, then each decode step through the cache."""
+    position, then every row of each decode call of `b.width` positions
+    through the cache."""
     import jax.numpy as jnp
-    cache = b.model.init_cache(1, 32)
-    got, cache = b.fwd(b.params, jnp.asarray([toks[:PREFILL]], jnp.int32), cache)
-    rows = [(PREFILL - 1, got[0, -1])]
-    for j in range(PREFILL, len(toks)):
-        got, cache = b.fwd(b.params, jnp.asarray([[toks[j]]], jnp.int32), cache)
-        rows.append((j, got[0, -1]))
+    prefill = lengths(b.width)[0]
+    cache = b.model.init_cache(1, CACHE)
+    got, cache = b.fwd(b.params, jnp.asarray([toks[:prefill]], jnp.int32), cache)
+    rows = [(prefill - 1, got[0, -1])]
+    for j in range(prefill, len(toks), b.width):
+        got, cache = b.fwd(b.params,
+                           jnp.asarray([toks[j:j + b.width]], jnp.int32), cache)
+        rows += [(j + i, got[0, i]) for i in range(b.width)]
     return rows
 
 
@@ -168,17 +217,20 @@ def main() -> int:
     b = build(config)
     tolerance = float(config.get("reference_tolerance", TOLERANCE))
     worst = big = 0.0
-    for toks in sample(args.seed, b.cfg.vocab_size):
-        rms, most = errors(program_rows(b, toks),
-                           b.ref.logits(toks, b.leaf, config))
+    positions = 0
+    for toks in sample(args.seed, b.cfg.vocab_size, b.width):
+        rows = program_rows(b, toks)
+        rms, most = errors(rows, b.ref.logits(toks, b.leaf, config))
         worst, big = max(worst, rms), max(big, most)
+        positions += len(rows)
     out = {"ok": worst <= tolerance, "rms_err": worst, "max_err": big,
            "tolerance": tolerance,
            "tolerance_why": config.get(
                "reference_tolerance_why",
                "servebench/refcheck.py TOLERANCE: no limit in the file"),
            "reference": config["reference"],
-           "positions": PROMPTS * (DECODE + 1), "seconds": time.monotonic() - t0,
+           "positions": positions, "decode_width": b.width,
+           "seconds": time.monotonic() - t0,
            "platform": devs[0].platform}
     Path(args.out).write_text(json.dumps(out))
     print(json.dumps(out), flush=True)

@@ -20,7 +20,9 @@ FILES = "tests/servebench/files"
 
 @pytest.mark.parametrize("name, is_stated, control_is", [
     ("tiny-moe", "float32", "bfloat16"),
-    ("tiny-llama", "float32", "bfloat16")])
+    ("tiny-llama", "float32", "bfloat16"),
+    # decode calls of 4 positions: nine rows a prompt, the same verdicts
+    ("tiny-llama-w4", "float32", "bfloat16")])
 def test_control_is_not_correct_and_the_program_is(name, is_stated, control_is):
     config = json.loads((ROOT / FILES / "configs" / (name + ".json")).read_text())
     # the toys state float32; their files' limit is for that (the llama

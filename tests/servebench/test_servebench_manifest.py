@@ -236,6 +236,59 @@ def test_params_streamed_and_multiplied_of_two_moes(n):
     assert r["bound"] == ("memory" if n < 1024 else "compute")
 
 
+#: published sizes (config.json of JetLM/SDAR-30B-A3B-Chat) at 8 of its 48
+#: layers, in bfloat16; generation by blocks of 4 positions
+SDAR_30B_A3B = dict(hidden_size=2048, head_dim=128, num_attention_heads=32,
+                    num_key_value_heads=4, num_hidden_layers=8,
+                    intermediate_size=6144, moe_intermediate_size=768,
+                    vocab_size=151936, num_experts=128, num_experts_per_tok=8,
+                    decode_width=4, serve={"quant": "none", "kv_quant": "none"})
+
+
+def test_a_step_of_four_positions_a_stream_with_experts():
+    """Worked by hand. Attention 2048 x 128 x (32 + 4) x 2 = 18,874,368 a
+    layer, router 2048 x 128 = 262,144, one expert 3 x 2048 x 768 =
+    4,718,592 (`moe_intermediate_size`, not the 6144 of
+    `intermediate_size`), head 151,936 x 2048 = 311,164,928. A position
+    meets 8 experts: 18,874,368 + 262,144 + 8 x 4,718,592 = 56,885,248 a
+    layer; 8 layers and the head 455,081,984 + 311,164,928 = 766,246,912
+    (all 48: 2,730,491,904 + 311,164,928 = 3,041,656,832, the "A3B")."""
+    sdar = (18_874_368, 4_718_592, 262_144, 8, 311_164_928)
+    assert by_hand(*sdar, 8) == 8 * 56_885_248 + 311_164_928 == 766_246_912
+    assert peaks.matmul_params(SDAR_30B_A3B) == 766_246_912
+    assert peaks.matmul_params(dict(SDAR_30B_A3B, num_hidden_layers=48)) == \
+        48 * 56_885_248 + 311_164_928 == 3_041_656_832
+    # a step of 32 streams x 4 positions is 128 tokens' draws of 8 in 128:
+    # 0.9375^128 = 2.587e-4 of the experts are missed, 0.9375^32 = 0.1268
+    wide, narrow = 128 * (1 - 0.9375 ** 128), 128 * (1 - 0.9375 ** 32)
+    assert wide == pytest.approx(127.967, abs=1e-3)
+    assert narrow == pytest.approx(111.771, abs=1e-3)
+    assert peaks.streamed_params(SDAR_30B_A3B, 32 * 4) == pytest.approx(
+        by_hand(*sdar, wide), rel=1e-12)
+    # the block: 4 steps, 32 streams, 32 x 300 tokens of live context at
+    # 8 layers x 2 x 4 KV heads x 128 x 2 bytes = 16,384 bytes a token,
+    # which a step reads ONCE whatever its width
+    assert peaks.kv_bytes_per_token(SDAR_30B_A3B) == 16_384
+    r = peaks.block_least_seconds(SDAR_30B_A3B, "TPU v5 lite", 1, 4, 32, 9600)
+    assert r["flops"] == 4 * 2 * 766_246_912 * 32 * 4 == 784_636_837_888
+    assert r["bytes"] == pytest.approx(
+        4 * (2 * by_hand(*sdar, wide) + 9600 * 16_384), rel=1e-12)
+    assert r["bound"] == "memory"       # 43.0 GB: 52.5 ms against 4.0 ms
+    assert r["memory_s"] == pytest.approx(0.0525, rel=0.01)
+    assert r["compute_s"] == pytest.approx(0.00398, rel=0.01)
+    # the same file read at width 1, as the count stood before the key:
+    # a quarter of the operations, and 111.77 / 127.97 of the experts' bytes
+    r1 = peaks.block_least_seconds(dict(SDAR_30B_A3B, decode_width=1),
+                                   "TPU v5 lite", 1, 4, 32, 9600)
+    assert r1["flops"] * 4 == r["flops"]
+    assert r1["bytes"] == pytest.approx(
+        4 * (2 * by_hand(*sdar, narrow) + 9600 * 16_384), rel=1e-12)
+    assert 1 - narrow / wide == pytest.approx(0.1266, abs=1e-4)
+    # fewer than one live stream counts as one, of 4 positions
+    assert peaks.block_least_seconds(SDAR_30B_A3B, "TPU v5 lite", 1, 4, 0.2, 0)[
+        "flops"] == 4 * 2 * 766_246_912 * 4
+
+
 def test_leading_dense_layers_and_shared_experts_count():
     """A DeepSeek-style layout at toy sizes: 1 dense layer of width 10,
     2 expert layers of 4 experts of width 3 with 2 per token and 1
