@@ -39,6 +39,7 @@ semantics, TPU-native mechanics):
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -303,7 +304,8 @@ def init_kv_window(cache: PagedKVCache, width: int,
 
 
 @jax.named_scope("kv_window_write")
-def stage_window_layer(wk, wv, k, v, win_len, wks=None, wvs=None):
+def stage_window_layer(wk, wv, k, v, win_len, wks=None, wvs=None,
+                       rows=None):
     """Stage one layer's fresh K/V into its window slice.
 
     wk/wv: [S, Kv, W, H] (this layer's window); k/v: [B, T, Kv, H]
@@ -316,9 +318,13 @@ def stage_window_layer(wk, wv, k, v, win_len, wks=None, wvs=None):
     so dead slots need no masking: their win_len never advances and
     their staged bytes stay unattendable garbage. Returns the updated
     (wk, wv, wks, wvs).
+
+    rows [B] (the packed mixed step): row b belongs to slot rows[b]
+    and lands at window index win_len[b] + t, win_len then one entry a
+    ROW; an index of W or more drops the row's write.
     """
     B, T = k.shape[0], k.shape[1]
-    rows = jnp.arange(B)[:, None]                       # [B, 1]
+    rows = (jnp.arange(B) if rows is None else rows)[:, None]  # [B, 1]
     idx = win_len[:, None] + jnp.arange(T)[None, :]     # [B, T]
     if wks is not None:
         kq, ks = quantize_kv(k)
@@ -502,44 +508,36 @@ def permute_paged_tail(cache: PagedKVCache, perm, active=None
 # Paged forward pass (reference path; Pallas decode kernel lives in ops/)
 # ---------------------------------------------------------------------------
 
-def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
-                     positions, mask, cos, sin, active, use_kernel: bool,
-                     fresh: bool, ksp=None, vsp=None, win=None,
-                     force_dense: bool = False):
-    """One transformer layer against one layer's page pool slice.
+def _settled(*view):
+    """The gathered view as attend() reads it, closed to fusion with
+    what built it. Window on and off build the same elements by
+    different routes (gather + insert against write + gather); left
+    open, XLA fuses each route into attend's products its own way and
+    the two programs part in the last digits, enough to move an int8
+    scale downstream. Behind the barrier attend compiles from the same
+    operands in both: the parity the window promises, to the bit."""
+    return lax.optimization_barrier(view)
 
-    Shared by paged_forward's full-stack scan, the stage-local scan of
-    the pipeline serving path (parallel/pipeline.py), and the
-    write-combined window path (paged_forward_window) so the three
-    cannot drift. x: [B,T,D]; kp/vp: [P,Kv,page,H]; ksp/vsp: [P,Kv*page]
-    scale slices iff the pool is int8. Returns (x, kp, vp[, ksp, vsp]).
 
-    win (kv_write_combine): (wk, wv, wks, wvs, win_len) — this layer's
-    window slices [S, Kv, W, H] (+ [S, Kv, W] scales iff quantized) and
-    the per-slot staged count. The pool slice is then READ-ONLY: fresh
-    K/V stages into the window instead of scattering the pool, and
-    attention reads pool + window (kernel: window segment folded into
-    the online softmax; dense: window inserted into the gathered view
-    at absolute positions, element-wise identical to the window-off
-    written view). Returns (x, wk, wv[, wks, wvs]) — the pool rides
-    outside the scan unchanged.
-    """
-    T = x.shape[1]
+def paged_attend(q, k, v, kp, vp, *, cfg: ModelConfig, page_table,
+                 positions, mask, active, use_kernel: bool, fresh: bool,
+                 ksp=None, vsp=None, win=None, force_dense: bool = False):
+    """One layer's attention for [B,T] queries whose K/V is already
+    written: the dispatch between the paged kernel (T == 1), the flash
+    kernels (fresh chunk; warm chunk over the cached prefix) and the
+    dense gather, shared by paged_layer_body and the packed mixed step
+    (paged_forward_packed) so the two cannot drift. q: [B,T,Nq,H];
+    k/v: [B,T,Kv,H] (the fresh projections, read by the flash
+    branches only); page_table: the B rows' own table rows
+    [B, max_pages]; win: (wk, wv, wks, wvs, win_len) AFTER staging,
+    window slices [B, Kv, W, H] and the staged count BEFORE it.
+    Returns [B,T,Nq,H]."""
+    T = q.shape[1]
     quant = ksp is not None
-    compute_dtype = jnp.dtype(cfg.dtype)
-    lp = jax.tree.map(lambda a: _cast_float(a, compute_dtype), lp)
     start = positions[:, 0]
-
-    h = pre_norm(x, lp["ln1"], cfg)
-    q, k, v = qkv_proj(h, lp["attn"], cfg, cos, sin)
     if win is not None:
         wk, wv, wks, wvs, win_len = win
-        base = start - win_len  # flushed pool length per slot
-        wk, wv, wks, wvs = stage_window_layer(wk, wv, k, v, win_len,
-                                              wks, wvs)
-    else:
-        kp, vp, ksp, vsp = write_paged_layer(kp, vp, page_table, k, v,
-                                             start, active, ksp, vsp)
+        base = start - win_len  # flushed pool length per row
     out = None
     tried_kernel = True
     if use_kernel and T == 1:
@@ -611,16 +609,75 @@ def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
             if win is not None:
                 ck, k_s = insert_window_view_q(ck, k_s, wk, wks, base)
                 cv, v_s = insert_window_view_q(cv, v_s, wv, wvs, base)
-            out = attend(q, ck, cv, mask, cfg, k_s, v_s)
+            out = attend(q, *_settled(ck, cv), mask, cfg, *_settled(k_s, v_s))
         else:
             ck = gather_paged_layer(kp, page_table)
             cv = gather_paged_layer(vp, page_table)
             if win is not None:
                 ck = insert_window_view(ck, wk, base)
                 cv = insert_window_view(cv, wv, base)
-            out = attend(q, ck, cv, mask, cfg)
+            out = attend(q, *_settled(ck, cv), mask, cfg)
+    return out
+
+
+def _layer_open(x, lp, cfg: ModelConfig, cos, sin):
+    """A layer up to its attention: the weights in the compute dtype,
+    the pre-norm and the rotated projections. Returns (lp, q, k, v).
+    With _layer_close, the part of a layer that paged_layer_body and
+    the packed step (packed_layer) share, so that a change to a norm or
+    a projection reaches both."""
+    lp = jax.tree.map(lambda a: _cast_float(a, jnp.dtype(cfg.dtype)), lp)
+    q, k, v = qkv_proj(pre_norm(x, lp["ln1"], cfg), lp["attn"], cfg,
+                       cos, sin)
+    return lp, q, k, v
+
+
+def _layer_close(x, out, lp, cfg: ModelConfig):
+    """A layer from its attention's output on: the output projection
+    and the feed-forward, each with its residual."""
     x = x + attn_output(out, lp["attn"], cfg)
-    x = x + ffn_block(pre_norm(x, lp["ln2"], cfg), lp, cfg)
+    return x + ffn_block(pre_norm(x, lp["ln2"], cfg), lp, cfg)
+
+
+def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
+                     positions, mask, cos, sin, active, use_kernel: bool,
+                     fresh: bool, ksp=None, vsp=None, win=None,
+                     force_dense: bool = False):
+    """One transformer layer against one layer's page pool slice.
+
+    Shared by paged_forward's full-stack scan, the stage-local scan of
+    the pipeline serving path (parallel/pipeline.py), and the
+    write-combined window path (paged_forward_window) so the three
+    cannot drift. x: [B,T,D]; kp/vp: [P,Kv,page,H]; ksp/vsp: [P,Kv*page]
+    scale slices iff the pool is int8. Returns (x, kp, vp[, ksp, vsp]).
+
+    win (kv_write_combine): (wk, wv, wks, wvs, win_len) — this layer's
+    window slices [S, Kv, W, H] (+ [S, Kv, W] scales iff quantized) and
+    the per-slot staged count. The pool slice is then READ-ONLY: fresh
+    K/V stages into the window instead of scattering the pool, and
+    attention reads pool + window (kernel: window segment folded into
+    the online softmax; dense: window inserted into the gathered view
+    at absolute positions, element-wise identical to the window-off
+    written view). Returns (x, wk, wv[, wks, wvs]) — the pool rides
+    outside the scan unchanged.
+    """
+    quant = ksp is not None
+    lp, q, k, v = _layer_open(x, lp, cfg, cos, sin)
+    if win is not None:
+        wk, wv, wks, wvs, win_len = win
+        wk, wv, wks, wvs = stage_window_layer(wk, wv, k, v, win_len,
+                                              wks, wvs)
+    else:
+        kp, vp, ksp, vsp = write_paged_layer(kp, vp, page_table, k, v,
+                                             positions[:, 0], active,
+                                             ksp, vsp)
+    out = paged_attend(
+        q, k, v, kp, vp, cfg=cfg, page_table=page_table,
+        positions=positions, mask=mask, active=active,
+        use_kernel=use_kernel, fresh=fresh, ksp=ksp, vsp=vsp,
+        win=None if win is None else (wk, wv, wks, wvs, win_len),
+        force_dense=force_dense)
+    x = _layer_close(x, out, lp, cfg)
     if win is not None:
         if quant:
             return x, wk, wv, wks, wvs
@@ -767,3 +824,190 @@ def paged_forward_window(params, cfg: ModelConfig, tokens: jax.Array,
     (x, _), new_win = lax.scan(body, (x, 0), xs)
     logits = final_logits(params, cfg, x)
     return logits, KVWindow(*new_win)
+
+
+class PackedRows(NamedTuple):
+    """What every layer of one packed mixed step reads about its rows:
+    N = S + P*C of them, the S decode rows first, then the P chunks
+    column by column. Built once a step (packed_rows)."""
+    slot: jax.Array         # [N] the slot a row belongs to
+    pos: jax.Array          # [N] its absolute position
+    ok: jax.Array           # [N] real: it writes its K/V
+    table: Optional[jax.Array]    # [N, max_pages], window off
+    widx: Optional[jax.Array]     # [N] window index (W: dropped), window on
+    cos: jax.Array
+    sin: jax.Array
+    page_table: jax.Array   # [S, max_pages] the decode rows' tables
+    written: jax.Array      # [S] a slot's written length
+    active: jax.Array       # [S] the slots that decode this step
+    dec_mask: jax.Array
+    win_len: Optional[jax.Array]  # [S] staged counts, window on
+    chunk_slot: jax.Array   # [P]
+    chunk_ok: jax.Array     # [P] the chunk carries something
+    chunk_pos: jax.Array    # [P, C]
+    chunk_mask: jax.Array
+    chunk_table: jax.Array  # [P, max_pages] each chunk's OWN slot's row
+    head: jax.Array         # [S] the row the LM head reads for a slot
+
+
+def packed_rows(params, cfg: ModelConfig, tokens, cache: PagedKVCache,
+                chunk_tokens, chunk_slot, chunk_count, active,
+                window: Optional[KVWindow] = None, win_len=None):
+    """The packed step before its layers: embedded rows x [N, 1, D]
+    and their PackedRows. Arguments as paged_forward_packed's."""
+    S, (P, C) = tokens.shape[0], chunk_tokens.shape
+    written = cache.lengths if window is None else cache.lengths + win_len
+    chunk_ok = chunk_count > 0
+    ccol = jnp.arange(C)[None, :]
+    chunk_pos = written[chunk_slot][:, None] + ccol           # [P, C]
+    slot = jnp.concatenate([jnp.arange(S), jnp.repeat(chunk_slot, C)])
+    pos = jnp.concatenate([written, chunk_pos.reshape(-1)])
+    ok = jnp.concatenate([active, (ccol < chunk_count[:, None]).reshape(-1)])
+    tok = jnp.concatenate([tokens, chunk_tokens.reshape(-1)])
+    x, cos, sin = embed_tokens(params, cfg, tok[:, None], pos[:, None])
+    table = widx = None
+    if window is None:
+        table = cache.page_table[slot]
+    else:
+        widx = jnp.where(
+            ok, win_len[slot] + jnp.concatenate(
+                [jnp.zeros((S,), jnp.int32),
+                 jnp.broadcast_to(ccol, (P, C)).reshape(-1)]),
+            window.width)
+    # a chunk's last real column stands in for its slot's own (masked)
+    # decode row under the head
+    hit = chunk_ok[:, None] & (chunk_slot[:, None] == jnp.arange(S)[None, :])
+    last = S + jnp.arange(P) * C + jnp.clip(chunk_count - 1, 0, C - 1)
+    head = jnp.where(hit.any(0), (hit * last[:, None]).sum(0), jnp.arange(S))
+    return x, PackedRows(
+        slot=slot, pos=pos, ok=ok, table=table, widx=widx, cos=cos, sin=sin,
+        page_table=cache.page_table, written=written, active=active,
+        dec_mask=make_mask(written[:, None], cache.max_seq)
+        & active[:, None, None],
+        win_len=win_len, chunk_slot=chunk_slot, chunk_ok=chunk_ok,
+        chunk_pos=chunk_pos,
+        chunk_mask=make_mask(chunk_pos, cache.max_seq)
+        & chunk_ok[:, None, None],
+        chunk_table=cache.page_table[chunk_slot], head=head)
+
+
+def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
+                 use_kernel: bool):
+    """One layer of the packed step. pools: this layer's (kp, vp, ksp,
+    vsp), scales None unless int8; wl: its window slices (wk, wv, wks,
+    wvs), or None with the window off. Every real row's entry goes
+    where the lane-wide step put it, the window at win_len (+ t) or
+    the pool at the row's position, in ONE stage or scatter. Attention
+    is paged_attend twice: the S decode rows as its T == 1 case (the
+    paged kernel, with the window segment), each chunk as its T == C
+    case over its OWN slot's table row and window slice. Returns
+    (x, pools, wl) as written."""
+    S, (P, C) = rows.written.shape[0], rows.chunk_pos.shape
+    lp, q, k, v = _layer_open(x, lp, cfg, rows.cos, rows.sin)
+    dec_win = chunk_win = None
+    if wl is not None:
+        wl = stage_window_layer(wl[0], wl[1], k, v, rows.widx, wl[2], wl[3],
+                                rows=rows.slot)
+        dec_win = (*wl, rows.win_len)
+        chunk_win = (*(None if a is None else a[rows.chunk_slot]
+                       for a in wl), rows.win_len[rows.chunk_slot])
+    else:
+        pools = write_paged_layer(pools[0], pools[1], rows.table, k, v,
+                                  rows.pos, rows.ok, pools[2], pools[3])
+    kp, vp, ksp, vsp = pools
+    attend_rows = partial(paged_attend, kp=kp, vp=vp, cfg=cfg,
+                          use_kernel=use_kernel, fresh=False,
+                          ksp=ksp, vsp=vsp)
+    out = attend_rows(q[:S], k[:S], v[:S], page_table=rows.page_table,
+                      positions=rows.written[:, None], mask=rows.dec_mask,
+                      active=rows.active, win=dec_win)
+    if P:
+        def chunks(a):
+            """Rows S.. of a packed [N, 1, ...] array as [P, C, ...]."""
+            return a[S:].reshape(P, C, *a.shape[2:])
+
+        out_c = attend_rows(chunks(q), chunks(k), chunks(v),
+                            page_table=rows.chunk_table,
+                            positions=rows.chunk_pos, mask=rows.chunk_mask,
+                            active=rows.chunk_ok, win=chunk_win)
+        out = jnp.concatenate(
+            [out, out_c.reshape(P * C, 1, *out_c.shape[2:])])
+    return _layer_close(x, out, lp, cfg), pools, wl
+
+
+_POOL_LEAVES = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages")
+
+
+def pool_leaves(cache: PagedKVCache, pools=None):
+    """The pool tensors that ride a layer scan: codes, and scales iff
+    int8. Given `pools`, the cache with them put back instead."""
+    names = _POOL_LEAVES[:4 if cache.quantized else 2]
+    if pools is None:
+        return tuple(getattr(cache, n) for n in names)
+    return cache._replace(**dict(zip(names, pools)))
+
+
+def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
+                         cache: PagedKVCache, chunk_tokens: jax.Array,
+                         chunk_slot: jax.Array, chunk_count: jax.Array,
+                         active: jax.Array,
+                         window: Optional[KVWindow] = None,
+                         win_len: Optional[jax.Array] = None,
+                         use_kernel: bool = False):
+    """One PACKED mixed step: S decode rows of one token beside P
+    prefill chunks of C tokens, S + P*C rows through every projection,
+    the feed-forward and (on a mesh) the all-reduces, where the
+    lane-wide step ran S*C.
+
+    tokens [S]: each slot's chain token; `active` [S] marks the slots
+    that decode this step (a slot in prefill phase is NOT among them).
+    chunk_tokens [P, C]: the next C prompt tokens of slot chunk_slot[p],
+    of which the first chunk_count[p] are real (0: chunk p carries
+    nothing this step, and writes and yields nothing). A row sits at
+    its slot's written length (cache.lengths, plus win_len when the
+    window is on), a chunk's column t at that plus t.
+
+    Keys, values and attention: packed_layer. Filler columns, idle
+    chunks and inactive slots write nothing; a chunk's dense view is
+    [P, S_max], not the whole pool, and flash takes it where
+    cfg.attn_impl and the window allow.
+
+    Returns (logits [S, V] float32, pools-or-window): the LM head runs
+    on S rows, a decoding slot's own and, for a slot with a chunk, the
+    chunk's last real column (its first token, if the prompt ends
+    there). Window off the second value is the cache with its pools
+    written and its lengths as they were; window on, the window.
+    Neither length advances here: the block scan knows what it keeps.
+    (Under pipeline stages: parallel/pipeline.py paged_pipeline_packed,
+    the same pieces over stage-local layers.)
+    """
+    x, rows = packed_rows(params, cfg, tokens, cache, chunk_tokens,
+                          chunk_slot, chunk_count, active, window, win_len)
+    n_leaves = 4 if cache.quantized else 2
+    pad = (None,) * (4 - n_leaves)
+    if window is None:
+        def body(x, scanned):
+            lp, *pools = scanned
+            x, pools, _ = packed_layer(x, lp, (*pools, *pad), None, rows,
+                                       cfg, use_kernel)
+            return x, pools[:n_leaves]
+
+        x, pools = lax.scan(body, x, (params["layers"], *pool_leaves(cache)))
+        state = pool_leaves(cache, pools)
+    else:
+        # the pool is read-only and indexed in-body, as in
+        # paged_forward_window; only the window's leaves ride the scan
+        def body(carry, scanned):
+            x, i = carry
+            lp, *wl = scanned
+            pools = tuple(lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+                          for a in pool_leaves(cache))
+            x, _, wl = packed_layer(x, lp, (*pools, *pad), (*wl, *pad),
+                                    rows, cfg, use_kernel)
+            return (x, i + 1), wl[:n_leaves]
+
+        (x, _), new_win = lax.scan(
+            body, (x, 0), (params["layers"], window.k, window.v,
+                           window.k_scale, window.v_scale)[:1 + n_leaves])
+        state = KVWindow(*new_win)
+    return final_logits(params, cfg, x[rows.head])[:, 0], state

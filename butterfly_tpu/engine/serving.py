@@ -58,8 +58,8 @@ from jax.profiler import TraceAnnotation
 
 from butterfly_tpu.cache.paged import (
     KVWindow, PagedKVCache, flush_paged_window, init_kv_window,
-    init_paged_cache, paged_forward, paged_forward_window,
-    permute_paged_tail, permute_window_tail)
+    init_paged_cache, paged_forward, paged_forward_packed,
+    paged_forward_window, permute_paged_tail, permute_window_tail)
 from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
 from butterfly_tpu.core.mesh import mesh_ctx
 from butterfly_tpu.ops import kernel_mode, kernels_default, record_kernels
@@ -78,15 +78,6 @@ def named(fn, name: str):
     stay out of the name: one name per kind of program."""
     fn.__name__ = fn.__qualname__ = name
     return fn
-
-
-def _block_name(C: int) -> str:
-    """A plain mixed block's program name. With chunk width 1 no lane
-    prefills (the scheduler collapses C to 1 only then): the program
-    is a decode block in shape and in use, and takes the decode
-    block's name, so that a trace splits token generation from prompt
-    processing."""
-    return "bf_decode_block" if C == 1 else "bf_mixed_block"
 
 
 def bucket_len(n: int, lo: int = 16, hi: Optional[int] = None) -> int:
@@ -287,7 +278,12 @@ class ServingEngine:
         # kernel call sites traced by this engine's programs, counted
         # while they trace (ops.record_kernels) — /health reports both
         self.kernel_calls: Dict[str, int] = {}
-        cache_shardings = None
+        # Without a mesh the pool and the window are COMMITTED to the
+        # device all the same: a block's outputs are, so state that
+        # began uncommitted would give a block program two executables,
+        # one for its first call and one for the rest.
+        cache_shardings = self._home = None if mesh is not None \
+            else jax.sharding.SingleDeviceSharding(jax.local_devices()[0])
         if mesh is not None:
             # Megatron param layout + paged pool sharded to match (kv
             # heads over `tensor`, slots over `data`): prefill/decode
@@ -322,10 +318,13 @@ class ServingEngine:
         # stage>1 routes every paged program through the GPipe schedule
         # (microbatches of slots; pool L dim stage-sharded to match).
         if stage > 1:
-            from butterfly_tpu.parallel.pipeline import paged_pipeline_forward
+            from butterfly_tpu.parallel.pipeline import (
+                paged_pipeline_forward, paged_pipeline_packed)
             fwd = partial(paged_pipeline_forward, mesh=mesh)
+            self._packed_fwd = partial(paged_pipeline_packed, mesh=mesh)
         else:
             fwd = paged_forward
+            self._packed_fwd = paged_forward_packed
         prefill_cfg = self.cfg.replace(attn_impl="flash") \
             if use_kernels else self.cfg
         # Two prefill programs: fresh (start==0, flash over the chunk
@@ -357,6 +356,7 @@ class ServingEngine:
         # what _launch last called and how many calls it has made: the
         # scheduler's tick record and the launch span carry both
         self.last_program: Optional[str] = None
+        self.last_rows = 0
         self.blocks_launched = 0
         self._decode_blocks: Dict[int, object] = {}
         # Write-combined KV decode window (RuntimeConfig.kv_write_combine,
@@ -377,14 +377,14 @@ class ServingEngine:
         self._win_hwm = 0          # host upper bound on staged entries
         self._decode_win_blocks: Dict[int, object] = {}
         self._spec_win_blocks: Dict[int, object] = {}
-        # Mixed blocks (ISSUE 18): the decode scan generalized with
-        # prefill lanes, keyed (k, C) — the chunk width is a static
-        # shape, and the scheduler collapses C to 1 whenever no slot is
-        # in prefill phase, so the steady-state program is exactly the
-        # decode block's shape. Spec-mixed programs key on rounds alone
-        # (their C is pinned to gamma + 1).
-        self._mixed_blocks: Dict[Tuple[int, int], object] = {}
-        self._mixed_win_blocks: Dict[Tuple[int, int], object] = {}
+        # Mixed blocks: the decode scan with P prefill chunks of C
+        # tokens packed beside its S rows, keyed (k, C, P) — static
+        # shapes; the scheduler asks for P == 0 whenever no slot is in
+        # prefill phase, so the steady-state program is the decode
+        # block's shape. Spec-mixed programs key on rounds alone
+        # (lane-wide: their C is pinned to gamma + 1, every lane's
+        # width real work).
+        self._mixed_blocks: Dict[Tuple[int, int, int], object] = {}
         self._mixed_spec_blocks: Dict[int, object] = {}
         self._mixed_spec_win_blocks: Dict[int, object] = {}
         # Seq-parallel chunk-prefill programs (ISSUE 20 move 3), one per
@@ -456,6 +456,14 @@ class ServingEngine:
         with mesh_ctx(self.mesh), record_kernels(self.kernel_calls):
             yield
 
+    def carry(self, value):
+        """A host vector [S] as the FIRST binding of a block's carry
+        (chain tokens, chunk cursor, staged counts): committed and laid
+        out beside cache.lengths, as every later binding, a block's
+        output, is — one executable serves the first dispatch and the
+        rest."""
+        return jax.device_put(np.asarray(value), self.cache.lengths.sharding)
+
     def _put(self, *operands):
         """The block table and a dispatch's host operands, each a
         (value, dtype or None) pair, to the device under one span."""
@@ -463,11 +471,14 @@ class ServingEngine:
             self._sync_table()
             return [jnp.asarray(v, dt) for v, dt in operands]
 
-    def _launch(self, prog, *args):
+    def _launch(self, prog, rows: int, *args):
         """Call a jitted program under its launch span (the caller
-        holds _mesh_ctx). The span and the tick record name it."""
+        holds _mesh_ctx). The span and the tick record name it; `rows`
+        is what one step of it puts through the projections (a packed
+        mixed step S + P*C, a decode step S, a prefill B*T)."""
         self.blocks_launched += 1
         self.last_program = prog.__name__
+        self.last_rows = rows
         with TraceAnnotation("bf.tick.dispatch.launch",
                              program=self.last_program,
                              block=self.blocks_launched):
@@ -562,7 +573,7 @@ class ServingEngine:
                 self.flush_kv_window()
             if width < need:
                 width = max(1, self.runtime.inflight_blocks) * need
-                shardings = None
+                shardings = self._home
                 if self.mesh is not None:
                     from butterfly_tpu.parallel.partition import (
                         kv_window_specs, to_shardings)
@@ -573,9 +584,7 @@ class ServingEngine:
                                                  shardings)
                 self._win_len = None
         if self._win_len is None:
-            self._win_len = jax.device_put(
-                np.zeros((self.num_slots,), np.int32),
-                self.cache.lengths.sharding)
+            self._win_len = self.carry(np.zeros((self.num_slots,), np.int32))
 
     def flush_kv_window(self):
         """Flush every staged window entry into the page pool: ONE
@@ -682,7 +691,8 @@ class ServingEngine:
             pools = (self.cache.k_pages, self.cache.v_pages,
                      self.cache.k_scale_pages, self.cache.v_scale_pages)
             logits, pools = self._launch(
-                prog, self.params, buf, pools, rows, lens_dev, sts_dev)
+                prog, buf.shape[0] * buf.shape[1], self.params, buf, pools,
+                rows, lens_dev, sts_dev)
             new_lens = jnp.asarray(sts[:B] + lens[:B])
             self.cache = self.cache._replace(
                 k_pages=pools[0], v_pages=pools[1],
@@ -827,7 +837,7 @@ class ServingEngine:
             pools = (self.cache.k_pages, self.cache.v_pages,
                      self.cache.k_scale_pages, self.cache.v_scale_pages)
             logits, pools = self._launch(
-                prog, self.params, buf, pools, row,
+                prog, C, self.params, buf, pools, row,
                 jnp.int32(start), jnp.int32(len(tokens)))
             self.cache = self.cache._replace(
                 k_pages=pools[0], v_pages=pools[1],
@@ -864,7 +874,8 @@ class ServingEngine:
             (tokens, None), (active, None), (temps, None))
         with self._mesh_ctx():
             nxt, logits, cache = self._launch(
-                self._decode, self.params, tokens, self.cache, active,
+                self._decode, self.num_slots, self.params, tokens,
+                self.cache, active,
                 temps, self.runtime_top_k, self.runtime_top_p, key)
         self.cache = cache
         return nxt, logits
@@ -926,7 +937,7 @@ class ServingEngine:
             self._ensure_window(k)
             with self._mesh_ctx():
                 block, final, cache, window, wlen = self._launch(
-                    self._decode_block_win_prog(k),
+                    self._decode_block_win_prog(k), self.num_slots,
                     self.params, tokens, self.cache,
                     self._kv_window, self._win_len,
                     active, temps, stops, budgets,
@@ -937,7 +948,7 @@ class ServingEngine:
             return block, final
         with self._mesh_ctx():
             block, final, cache = self._launch(
-                self._decode_block_prog(k),
+                self._decode_block_prog(k), self.num_slots,
                 self.params, tokens, self.cache,
                 active, temps, stops, budgets,
                 self.runtime_top_k, self.runtime_top_p, key)
@@ -993,42 +1004,38 @@ class ServingEngine:
                     "admission drain barrier for draft_prefill")
         return "tree speculation has no fused mixed program"
 
-    def _mixed_block_prog(self, k: int, C: int):
-        prog = self._mixed_blocks.get((k, C))
+    def _mixed_block_prog(self, k: int, C: int, P: int):
+        """The packed mixed block (_packed_scan) of k steps, P chunks
+        of C tokens: cursor and cache are donated, and window on the
+        window buffer and its staged count too (the pool passes
+        through unmodified, aliased); the cursor is the carry the
+        scheduler must rebind every dispatch (BTF002 contract). With
+        no chunk (P == 0) no slot prefills: the program is a decode
+        block in shape and in use and takes the decode block's name,
+        so that a trace splits token generation from prompt
+        processing."""
+        prog = self._mixed_blocks.get((k, C, P))
         if prog is None:
+            name = ("bf_mixed_block" if P else "bf_decode_block") \
+                + ("_win" if self._window_mode else "")
             prog = jax.jit(
-                named(partial(_mixed_scan, self.cfg, self._fwd, k, C,
-                              use_kernel=self._use_kernels),
-                      _block_name(C)),
-                static_argnums=(10, 11), donate_argnums=(2, 3))
-            self._mixed_blocks[(k, C)] = prog
-        return prog
-
-    def _mixed_block_win_prog(self, k: int, C: int):
-        """Windowed twin of _mixed_block_prog: cursor, cache, window
-        buffer, and staged count are all donated — the pool passes
-        through unmodified (aliased); the cursor is the NEW carry the
-        scheduler must rebind every dispatch (BTF002 contract)."""
-        prog = self._mixed_win_blocks.get((k, C))
-        if prog is None:
-            prog = jax.jit(
-                named(partial(_mixed_scan_win, self.cfg, k, C,
-                              use_kernel=self._use_kernels),
-                      _block_name(C) + "_win"),
+                named(partial(_packed_scan, self.cfg, self._packed_fwd, k, C,
+                              P, use_kernel=self._use_kernels), name),
                 static_argnums=(12, 13), donate_argnums=(2, 3, 4, 5))
-            self._mixed_win_blocks[(k, C)] = prog
+            self._mixed_blocks[(k, C, P)] = prog
         return prog
 
     def mixed_block_async(self, tokens, cursor, pbuf, plen,
                           active: np.ndarray, temps: np.ndarray,
                           stops: np.ndarray, budgets, key: jax.Array,
-                          k: int, C: int):
+                          k: int, C: int, P: int):
         """Dispatch ONE fused k-step MIXED block, no host sync: decode
-        slots advance a token per step while prefill-phase slots chew a
-        C-token chunk of their `pbuf` row per step (_mixed_scan), in a
-        single jitted scan covering both phases — admission no longer
-        costs a drain barrier, just the host-side cursor/pbuf/table
-        edits the scheduler does between dispatches.
+        slots advance a token per step while up to P prefill-phase
+        slots chew a C-token chunk of their `pbuf` row per step, the
+        S + P*C rows of a step packed into one forward (_packed_scan),
+        in a single jitted scan covering both phases — admission costs
+        no drain barrier, just the host-side cursor/pbuf/table edits
+        the scheduler does between dispatches.
 
         `cursor` [S] is the device-resident chunk-cursor carry
         (DONATED — rebind from the result, exactly like the cache);
@@ -1041,33 +1048,26 @@ class ServingEngine:
         dispatch.
 
         kv_write_combine: stages through the engine window like
-        decode_block_async — worst case k * C staged entries (prefill
-        lanes advance win_len by their real chunk length; filler past
-        it is never flushed)."""
+        decode_block_async — worst case k * C staged entries (a
+        prefilling slot advances win_len by its real chunk length), k
+        with no chunk."""
         tokens, plen, active, temps, stops, budgets = self._put(
             (tokens, None), (plen, jnp.int32), (active, bool),
             (temps, None), (stops, jnp.int32), (budgets, jnp.int32))
+        need = k * C if P else k
         if self._window_mode:
-            self._ensure_window(k * C)
-            with self._mesh_ctx():
-                block, valid, final, cursor, cache, window, wlen = \
-                    self._launch(
-                        self._mixed_block_win_prog(k, C),
-                        self.params, tokens, cursor,
-                        self.cache, self._kv_window, self._win_len,
-                        pbuf, plen, active, temps, stops, budgets,
-                        self.runtime_top_k, self.runtime_top_p, key)
-            self.cache, self._kv_window, self._win_len = cache, window, wlen
-            self._win_dirty = True
-            self._win_hwm += k * C
-            return block, valid, final, cursor
+            self._ensure_window(need)
         with self._mesh_ctx():
-            block, valid, final, cursor, cache = self._launch(
-                self._mixed_block_prog(k, C),
+            block, valid, final, cursor, cache, window, wlen = self._launch(
+                self._mixed_block_prog(k, C, P), self.num_slots + P * C,
                 self.params, tokens, cursor, self.cache,
+                self._kv_window, self._win_len,  # None with the window off
                 pbuf, plen, active, temps, stops, budgets,
                 self.runtime_top_k, self.runtime_top_p, key)
-        self.cache = cache
+        self.cache, self._kv_window, self._win_len = cache, window, wlen
+        if self._window_mode:
+            self._win_dirty = True
+            self._win_hwm += need
         return block, valid, final, cursor
 
     def read_pages(self, pids: list[int]) -> Tuple[np.ndarray, np.ndarray,
@@ -1237,19 +1237,20 @@ class ServingEngine:
             (hist_len, jnp.int32), (active, bool), (temps, None),
             (stops, jnp.int32), (budgets, jnp.int32), (spec_mask, bool))
         tree = self.spec_tree_mode
+        # the VERIFY width, and window on the per-round window demand:
+        # N staged tree nodes (rejected branches die unflushed), or the
+        # linear chunk gamma+1
+        C = self._tree_nodes if tree \
+            else self.runtime.speculative_gamma + 1
         if self._window_mode:
-            # per-round window demand is the VERIFY width: N staged
-            # tree nodes (rejected branches die unflushed), or the
-            # linear chunk gamma+1
-            C = self._tree_nodes if tree \
-                else self.runtime.speculative_gamma + 1
             self._ensure_window(rounds * C)
             prog = self._spec_tree_win_prog(rounds) if tree \
                 else self._spec_block_win_prog(rounds)
             with self._mesh_ctx():
                 (toks, valid, hist, hist_len, rem, cache, window, wlen,
                  dstate) = self._launch(
-                        prog, self.params, hist, hist_len, self.cache,
+                        prog, self.num_slots * C,
+                        self.params, hist, hist_len, self.cache,
                         self._draft_state, self._kv_window, self._win_len,
                         active, temps, stops, budgets,
                         self.runtime_top_k, self.runtime_top_p, key,
@@ -1263,7 +1264,8 @@ class ServingEngine:
             else self._spec_block_prog(rounds)
         with self._mesh_ctx():
             toks, valid, hist, hist_len, rem, cache, dstate = self._launch(
-                prog, self.params, hist, hist_len, self.cache,
+                prog, self.num_slots * C,
+                self.params, hist, hist_len, self.cache,
                 self._draft_state, active, temps, stops, budgets,
                 self.runtime_top_k, self.runtime_top_p, key, spec_mask)
         self.cache, self._draft_state = cache, dstate
@@ -1319,13 +1321,14 @@ class ServingEngine:
             self._put((hist_len, jnp.int32), (plen, jnp.int32),
                       (active, bool), (temps, None), (stops, jnp.int32),
                       (budgets, jnp.int32), (spec_mask, bool))
+        C = self.runtime.speculative_gamma + 1
         if self._window_mode:
-            C = self.runtime.speculative_gamma + 1
             self._ensure_window(rounds * C)
             with self._mesh_ctx():
                 (toks, valid, hist, hist_len, rem, cursor, cache,
                  window, wlen) = self._launch(
                         self._mixed_spec_win_prog(rounds),
+                        self.num_slots * C,
                         self.params, hist, hist_len, cursor, plen,
                         self.cache, self._kv_window, self._win_len,
                         active, temps, stops, budgets,
@@ -1337,7 +1340,7 @@ class ServingEngine:
             return toks, valid, hist, hist_len, rem, cursor
         with self._mesh_ctx():
             toks, valid, hist, hist_len, rem, cursor, cache = self._launch(
-                self._mixed_spec_prog(rounds),
+                self._mixed_spec_prog(rounds), self.num_slots * C,
                 self.params, hist, hist_len, cursor, plen, self.cache,
                 active, temps, stops, budgets,
                 self.runtime_top_k, self.runtime_top_p, key, spec_mask)
@@ -1876,42 +1879,45 @@ def _spec_tree_scan_win(cfg: ModelConfig, rounds: int, width: int,
             win_len, dstate)
 
 
-def _mixed_scan(cfg: ModelConfig, fwd, k: int, C: int, params, tokens,
-                cursor, cache: PagedKVCache, pbuf, plen, active, temps,
-                stops, budgets, top_k: int, top_p: float, key,
-                use_kernel: bool = False):
-    """k chained MIXED iterations in ONE lax.scan (ISSUE 18): each
-    step, every slot is in exactly one phase — decode slots advance one
-    token (_decode_scan's semantics, token-for-token) while prefill
-    slots chew a C-token chunk of their prompt-buffer row through the
-    warm multi-token path, the same [S, C] program shape the spec
-    verify runs. Phase is a pure function of the carry: a slot is in
+def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
+                 tokens, cursor, cache: PagedKVCache,
+                 window: Optional[KVWindow], win_len, pbuf, plen, active,
+                 temps, stops, budgets, top_k: int, top_p: float, key,
+                 use_kernel: bool = False):
+    """k chained PACKED mixed iterations in ONE lax.scan: each step,
+    every slot is in exactly one phase. A decode slot advances one
+    token, _decode_scan[_win]'s semantics token for token; a slot in
+    prefill phase chews the next C tokens of its prompt-buffer row.
+    A step computes S + P*C rows (`fwd`: cache/paged.py
+    paged_forward_packed, or its pipelined twin under stages):
+    one row a slot and P chunks, where the lane-wide step this
+    replaces gave every slot a C-wide lane and filled S - P of them
+    with filler. Phase is a pure function of the carry: a slot is in
     prefill phase while cursor < plen. The scheduler seeds cursor at
-    the cached-prefix length on admission and keeps the invariant
-    cursor == the slot's written-token count (cache.lengths), so the
-    forward's per-row position base is exact for both phases.
+    the cached-prefix length on admission, keeps cursor == the slot's
+    written-token count, and holds at most P slots in prefill phase
+    (_mixed_max_pf); which chunk a slot gets is its rank among them,
+    computed here. With P == 0 the step is a decode step, and the
+    program is named a decode block.
 
     A prefill step consumes count = min(C, plen - cursor) real
-    positions; columns past count — and every column past the first of
-    a decode slot, whose chain token rides broadcast across the chunk
-    width — carry filler whose K/V lands past the slot's advanced
-    length. The advance is rolled back to the real count via the
-    lengths-replace pattern (_spec_scan's rollback), and the stale run
-    is rewritten before any query can attend it (write-then-attend):
-    the next step's C-wide write starts exactly at the rolled-back
-    length.
+    positions and advances the slot by that; filler columns write
+    nothing. Window on (`window`, `win_len` given; the pool READ-ONLY)
+    the advance is win_len's and the entries are staged; window off
+    (both None) it is cache.lengths' and they are written to the pool.
 
     Emissions: a decode step emits its sampled token; a prefill step
-    emits ONLY at the step its prefill completes — the slot's first
+    emits ONLY at the step its prefill completes, the slot's first
     token, sampled on device from the chunk's last real column (the
     same last-position logits the alternating path's gang prefill
     hands _finish_prefill). valid[i, s] marks block[i, s] as a real
     emission; the drain walks it like the spec block's validity mask.
-    With no prefill-phase slot in the batch and C == 1 the program
-    degenerates to _decode_scan exactly (same RNG stream fold_in(key,
-    i), same liveness algebra) — the parity grid pins this.
+    Sampling (fold_in(key, i)), liveness, budgets and stops are
+    _decode_scan's: the parity grid pins the tokens to the alternating
+    path's.
 
-    Returns (block [k, S], valid [k, S], final [S], cursor, cache).
+    Returns (block [k, S], valid [k, S], final [S], cursor, cache,
+    window, win_len).
     """
     S = tokens.shape[0]
     H = pbuf.shape[1]
@@ -1923,101 +1929,51 @@ def _mixed_scan(cfg: ModelConfig, fwd, k: int, C: int, params, tokens,
     live = active & (budgets > 0) \
         & jnp.where(has_stop & ~is_pf0, tokens != stops, True)
 
+    windowed = window is not None
+
     def body(carry, i):
-        cur, cursor, cache, live, rem = carry
+        # kv: the window and its staged counts (the pool, read-only,
+        # stays outside the carry), or window off the cache itself
+        cur, cursor, kv, live, rem = carry
+        pool, win, wlen = (cache, *kv) if windowed else (kv, None, None)
         is_pf = cursor < plen
-        count = jnp.where(is_pf, jnp.clip(plen - cursor, 0, C), 0)
-        pchunk = jnp.take_along_axis(
-            pbuf, jnp.clip(cursor[:, None] + ccol, 0, H - 1), axis=1)
-        toks = jnp.where(is_pf[:, None], pchunk,
-                         jnp.broadcast_to(cur[:, None], (S, C)))
-        W = cache.lengths
-        logits, cache = fwd(params, cfg, toks, cache, active=live,
-                            use_kernel=use_kernel)
-        completing = is_pf & (cursor + count >= plen)
-        sidx = jnp.where(is_pf, jnp.clip(count - 1, 0, C - 1), 0)
-        lg = jnp.take_along_axis(logits, sidx[:, None, None],
-                                 axis=1)[:, 0, :]
-        nxt = sample_batched(lg, jax.random.fold_in(key, i), temps,
+        # chunk p belongs to the p-th live slot in prefill phase
+        cand = is_pf & live
+        rank = jnp.cumsum(cand) - 1
+        mine = cand[None, :] & (rank[None, :] == jnp.arange(P)[:, None])
+        chunk_slot = jnp.argmax(mine, axis=1)                  # [P]
+        has_chunk = cand & (rank < P)
+        count = jnp.where(has_chunk, jnp.clip(plen - cursor, 0, C), 0)
+        chunk_tokens = jnp.take_along_axis(
+            pbuf[chunk_slot],
+            jnp.clip(cursor[chunk_slot][:, None] + ccol, 0, H - 1), axis=1)
+        chunk_count = jnp.where(mine.any(axis=1), count[chunk_slot], 0)
+        logits, new = fwd(
+            params, cfg, cur, pool, chunk_tokens, chunk_slot, chunk_count,
+            live & ~is_pf, win, wlen, use_kernel=use_kernel)
+        completing = has_chunk & (cursor + count >= plen)
+        nxt = sample_batched(logits, jax.random.fold_in(key, i), temps,
                              top_k, top_p)
         emit = live & (completing | ~is_pf)
         nxt = jnp.where(emit, nxt, cur)
         adv = jnp.where(live, jnp.where(is_pf, count, 1), 0)
-        cache = cache._replace(lengths=W + adv)
-        cursor = jnp.where(live & is_pf, cursor + count, cursor)
+        kv = (new, wlen + adv) if windowed \
+            else new._replace(lengths=pool.lengths + adv)
+        cursor = cursor + count
         rem = jnp.where(emit, rem - 1, rem)
         live = live & jnp.where(
             emit, (rem > 0) & jnp.where(has_stop, nxt != stops, True),
             True)
-        return (nxt, cursor, cache, live, rem), (nxt, emit)
+        return (nxt, cursor, kv, live, rem), (nxt, emit)
 
-    (final, cursor, cache, _, _), (block, valid) = lax.scan(
-        body, (tokens, cursor, cache, live, budgets),
+    (final, cursor, kv, _, _), (block, valid) = lax.scan(
+        body, (tokens, cursor, (window, win_len) if windowed else cache,
+               live, budgets),
         jnp.arange(k, dtype=jnp.int32))
-    return block, valid, final, cursor, cache
-
-
-def _mixed_scan_win(cfg: ModelConfig, k: int, C: int, params, tokens,
-                    cursor, cache: PagedKVCache, window: KVWindow,
-                    win_len, pbuf, plen, active, temps, stops, budgets,
-                    top_k: int, top_p: float, key,
-                    use_kernel: bool = False):
-    """Write-combined twin of _mixed_scan — phase/emission/RNG
-    semantics are IDENTICAL (the parity grid pins token equality);
-    only the K/V write target differs. A step stages its full C-wide
-    chunk at the slot's win_len and win_len advances by the REAL count
-    only (chunk length for a prefill step, 1 for a decode step, 0
-    dead): filler and dead-step repeats sit past win_len, unattendable
-    and never flushed, and the next step's C-wide stage rewrites them
-    inside the window buffer — the spec window's rollback argument
-    applied to chunk raggedness. The pool stays READ-ONLY; a freshly
-    admitted slot's registered-prefix pages are flushed state by
-    construction (registration happens at drain points, after the
-    flush), so its chunk attends prefix from the pool and its own
-    staged run from the window with no ordering hazard.
-
-    Returns (block [k, S], valid [k, S], final [S], cursor, cache,
-    window, win_len).
-    """
-    S = tokens.shape[0]
-    H = pbuf.shape[1]
-    ccol = jnp.arange(C)[None, :]
-    has_stop = stops >= 0
-    is_pf0 = cursor < plen
-    live = active & (budgets > 0) \
-        & jnp.where(has_stop & ~is_pf0, tokens != stops, True)
-
-    def body(carry, i):
-        cur, cursor, win, wlen, live, rem = carry
-        is_pf = cursor < plen
-        count = jnp.where(is_pf, jnp.clip(plen - cursor, 0, C), 0)
-        pchunk = jnp.take_along_axis(
-            pbuf, jnp.clip(cursor[:, None] + ccol, 0, H - 1), axis=1)
-        toks = jnp.where(is_pf[:, None], pchunk,
-                         jnp.broadcast_to(cur[:, None], (S, C)))
-        logits, win = paged_forward_window(params, cfg, toks, cache,
-                                           win, wlen, active=live,
-                                           use_kernel=use_kernel)
-        completing = is_pf & (cursor + count >= plen)
-        sidx = jnp.where(is_pf, jnp.clip(count - 1, 0, C - 1), 0)
-        lg = jnp.take_along_axis(logits, sidx[:, None, None],
-                                 axis=1)[:, 0, :]
-        nxt = sample_batched(lg, jax.random.fold_in(key, i), temps,
-                             top_k, top_p)
-        emit = live & (completing | ~is_pf)
-        nxt = jnp.where(emit, nxt, cur)
-        adv = jnp.where(live, jnp.where(is_pf, count, 1), 0)
-        wlen = wlen + adv
-        cursor = jnp.where(live & is_pf, cursor + count, cursor)
-        rem = jnp.where(emit, rem - 1, rem)
-        live = live & jnp.where(
-            emit, (rem > 0) & jnp.where(has_stop, nxt != stops, True),
-            True)
-        return (nxt, cursor, win, wlen, live, rem), (nxt, emit)
-
-    (final, cursor, window, win_len, _, _), (block, valid) = lax.scan(
-        body, (tokens, cursor, window, win_len, live, budgets),
-        jnp.arange(k, dtype=jnp.int32))
+    if windowed:
+        window, win_len = kv
+    else:
+        cache = kv
     return block, valid, final, cursor, cache, window, win_len
 
 

@@ -9,8 +9,8 @@ Two bounded, always-cheap instruments the scheduler feeds:
   the drain's fetch wait and whether a newer block was still running
   when it returned, in-flight depth, barrier causes,
   batch occupancy and page headroom, the block program the tick
-  dispatched, the loop's wait for the serving lock and the process's
-  count of compilations. The sequence number is also the ``seq`` of
+  dispatched and the rows one step of it computes, the loop's wait
+  for the serving lock and the process's count of compilations. The sequence number is also the ``seq`` of
   the tick's ``bf.tick`` span in a profiler trace: the join between
   the two needs no clock. One dict append per tick under an
   uncontended lock — the software answer to "where does the tick's
@@ -84,6 +84,7 @@ class TickLog:
                waiting: int = 0,
                pages_free: int = 0, generated: int = 0,
                spec: bool = False, program: Optional[str] = None,
+               rows: Optional[int] = None,
                block: int = 0, lock_s: float = 0.0,
                compiles: int = 0) -> None:
         """Append one tick record (hot path: one dict build + one
@@ -104,6 +105,7 @@ class TickLog:
             "generated": generated,
             "spec": spec,
             "program": program,
+            "rows": rows,
             "block": block,
             "lock_s": lock_s,
             "compiles": compiles,

@@ -214,6 +214,59 @@ def paged_pipeline_forward(params: Params, cfg: ModelConfig,
                                 cache.page_table, new_len, *new_pools[2:])
 
 
+def paged_pipeline_packed(params: Params, cfg: ModelConfig,
+                          tokens: jax.Array, cache, chunk_tokens: jax.Array,
+                          chunk_slot: jax.Array, chunk_count: jax.Array,
+                          active: jax.Array, window=None, win_len=None,
+                          use_kernel: bool = False, *, mesh: Mesh):
+    """cache.paged.paged_forward_packed pipelined over `stage`: the same
+    rows, layer and head (packed_rows, packed_layer), the layer stack
+    and the pool's L dim stage-sharded as in paged_pipeline_forward.
+    The S + P*C packed rows go through the stages as ONE microbatch: a
+    chunk's rows and its slot's decode row cannot part, and a step that
+    streams each stage's weights once is what a decode step costs
+    anyway. Pipeline serving keeps per-token pool writes, so there is
+    no window here.
+    """
+    from butterfly_tpu.cache.paged import (
+        packed_layer, packed_rows, paged_forward_packed, pool_leaves)
+    from butterfly_tpu.models.common import final_logits
+
+    assert window is None and win_len is None
+    S = mesh.shape["stage"]
+    if S == 1:
+        return paged_forward_packed(params, cfg, tokens, cache, chunk_tokens,
+                                    chunk_slot, chunk_count, active,
+                                    use_kernel=use_kernel)
+    x, rows = packed_rows(params, cfg, tokens, cache, chunk_tokens,
+                          chunk_slot, chunk_count, active)
+    pools = pool_leaves(cache)
+    pad = (None,) * (4 - len(pools))
+
+    def body(layers, *ops):
+        *pools, x, rows = ops
+
+        def step(pools, mc, valid, inp):
+            # a bubble tick runs on garbage: it writes nothing
+            live = rows._replace(ok=rows.ok & valid)
+
+            def layer(x, scanned):
+                lp, *pl = scanned
+                x, pl, _ = packed_layer(x, lp, (*pl, *pad), None, live,
+                                        cfg, use_kernel)
+                return x, pl[:len(pools)]
+
+            return lax.scan(layer, inp, (layers, *pools))
+
+        outs, pools = _gpipe_schedule(S, 1, x[None], step, tuple(pools))
+        return (outs, *pools)
+
+    y, pools = _run_gpipe(body, mesh, params["layers"], pools, (x, rows),
+                          S, 1, x)
+    logits = final_logits(params, cfg, y[rows.head])[:, 0]
+    return logits, pool_leaves(cache, pools)
+
+
 def _gpipe_schedule(S: int, M: int, xs, step_fn, carry0):
     """The GPipe tick skeleton shared by the contiguous and paged bodies.
 

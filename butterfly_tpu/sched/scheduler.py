@@ -192,13 +192,14 @@ class Scheduler:
         self.waiting: Deque[Request] = deque()
         self.running: List[Request] = []
         # Mixed dispatch (ISSUE 18): prefill chunks and decode/spec
-        # tokens ride ONE fused block per tick (engine._mixed_scan and
-        # twins) — admission becomes a host-side carry edit between
-        # dispatches (_seed_mixed_slot) instead of a drain barrier +
-        # separate prefill dispatch, retiring the admission barrier
-        # cause as a class. Continuous scheduler only; stateful draft
-        # sources fall back to the alternating path (their admission
-        # reseed hook needs the barrier this mode deletes).
+        # tokens ride ONE fused block per tick (engine._packed_scan, and
+        # under speculation _mixed_spec_scan[_win]) — admission becomes
+        # a host-side carry edit between dispatches (_seed_mixed_slot)
+        # instead of a drain barrier + separate prefill dispatch,
+        # retiring the admission barrier cause as a class. Continuous
+        # scheduler only; stateful draft sources fall back to the
+        # alternating path (their admission reseed hook needs the
+        # barrier this mode deletes).
         self._mixed_mode = (rt.scheduler == "continuous"
                             and engine.mixed_dispatch_ready)
         # visibility (ISSUE 19 satellite): mixed dispatch was ASKED
@@ -212,13 +213,16 @@ class Scheduler:
         # per-step chunk width C: under spec the verify shape pins it
         # to gamma+1; otherwise the inline budget (clamped by the tick
         # chunk budget) IS the width — one prefilling slot chews C
-        # tokens per scan step
+        # tokens per scan step, as one chunk of the packed step
         self._mixed_chunk = (rt.speculative_gamma + 1) if rt.speculative_gamma > 0 \
             else max(1, min(rt.prefill_inline_budget, rt.prefill_chunk))
         # concurrent-prefill cap — THE ITL-tail knob: at most this many
         # slots may be in prefill phase at once, so a scan step never
         # chews more than ~prefill_inline_budget prompt tokens while
-        # decode slots wait on it
+        # decode slots wait on it. It is also P, the chunks a packed
+        # step carries: the device gives the p-th slot in prefill phase
+        # chunk p, so one more than P would wait unseen by the lockstep
+        # simulation below
         self._mixed_max_pf = max(1, rt.prefill_inline_budget // self._mixed_chunk)
         # mixed-dispatch device carries: the per-slot chunk cursor
         # (DONATED to every mixed block, rebound from its result —
@@ -229,10 +233,6 @@ class Scheduler:
         self._cursor_dev = None
         self._pbuf_dev = None
         self._plen_host = np.zeros((engine.num_slots,), np.int32)
-        # prompt tokens advanced INSIDE fused mixed blocks (the work
-        # the retired admission barrier used to serialize) — the bench
-        # key mixed_dispatch_prefill_tokens_inline
-        self._inline_pf_tokens = 0
         # The prefill GROUP: requests admitted to slots whose prompts are
         # not yet fully in the KV cache. Each tick their next chunks are
         # packed under the prefill_chunk token budget and dispatched as
@@ -479,6 +479,18 @@ class Scheduler:
             "window into the page pool (kv_write_combine); tokens "
             "whose requests died before a flush are dropped, not "
             "counted")
+        # a mixed block's chunks: positions offered (steps x chunks x
+        # chunk width of a block dispatched with a prompt in flight)
+        # and prompt tokens they consumed; their ratio is the fill
+        self._c_chunk_offered = reg.counter(
+            "mixed_chunk_positions_total",
+            "Prefill chunk positions that mixed blocks carried (steps "
+            "x chunks x chunk width of every block dispatched with a "
+            "prompt in flight), real or filler")
+        self._c_chunk_tokens = reg.counter(
+            "mixed_chunk_tokens_total",
+            "Prompt tokens consumed inside mixed blocks; over "
+            "mixed_chunk_positions_total it is the chunk's fill")
         self._kv_flushes: Deque[float] = deque(maxlen=4096)
         # Flush counts dispatched and not yet read, in device order: a
         # drain never waits for the flush it has just dispatched (it
@@ -1057,6 +1069,8 @@ class Scheduler:
                             generated=made, spec=self._spec_mode,
                             program=self.engine.last_program
                             if blocks > blocks0 else None,
+                            rows=self.engine.last_rows
+                            if blocks > blocks0 else None,
                             block=blocks, lock_s=self.loop_lock_s,
                             compiles=int(self._c_compiles.value))
         self.loop_lock_s = 0.0
@@ -1245,7 +1259,7 @@ class Scheduler:
             # pairs with drain_barriers admission == 0 as the evidence
             # that the admission barrier class is retired
             m["mixed_dispatch_prefill_tokens_inline"] = \
-                float(self._inline_pf_tokens)
+                self._c_chunk_tokens.value
         if self._sp_enabled:
             m["seq_parallel_prefill_tokens_total"] = \
                 self._c_sp_tokens.value
@@ -1449,13 +1463,13 @@ class Scheduler:
 
         The concurrent-prefill cap (_mixed_max_pf, derived from
         RuntimeConfig.prefill_inline_budget) bounds how many slots may
-        be in prefill phase at once — with chunk width C per slot per
-        scan step, at most ~prefill_inline_budget prompt tokens are
-        chewed per step while decode slots wait on that step's
-        forward. That bound IS the ITL-tail knob."""
+        be in prefill phase at once (_prefilling_ahead) — with chunk
+        width C per slot per scan step, at most ~prefill_inline_budget
+        prompt tokens are chewed per step while decode slots wait on
+        that step's forward. That bound IS the ITL-tail knob."""
         admitted = False
         while (self.waiting
-               and len(self._prefill_group) < self._mixed_max_pf):
+               and self._prefilling_ahead() < self._mixed_max_pf):
             slot = self._free_slot()
             if slot is None:
                 break
@@ -1489,6 +1503,18 @@ class Scheduler:
         if admitted:
             self._epoch += 1  # membership changed: operands rebuild
 
+    def _prefilling_ahead(self) -> int:
+        """Slots that will be in prefill phase when the NEXT block
+        runs: members of the prefill group whose prompt the blocks
+        dispatched so far do not finish (the lockstep simulation moved
+        `prefilled` at dispatch). A member whose last chunk is in
+        flight stays in the group until that block drains, but on the
+        device it decodes from then on: it needs no chunk and holds no
+        place under the cap, so the next request is admitted a tick
+        sooner and a block behind a finished prompt is a decode block."""
+        return sum(r.prefilled < self._plen_host[r.slot]
+                   for r in self._prefill_group)
+
     def _seed_mixed_slot(self, req: Request) -> None:
         """Device-carry seeding for one mixed-dispatch admission. Every
         write is an ``.at[slot].set`` on the CURRENT carry binding —
@@ -1512,9 +1538,10 @@ class Scheduler:
                 lengths=eng.cache.lengths.at[slot].set(cached))
             if eng._win_len is not None:
                 eng._win_len = eng._win_len.at[slot].set(0)
-            cur = self._cursor_dev if self._cursor_dev is not None \
-                else jnp.zeros((eng.num_slots,), jnp.int32)
-            self._cursor_dev = cur.at[slot].set(cached)
+            if self._cursor_dev is None:
+                self._cursor_dev = eng.carry(
+                    np.zeros((eng.num_slots,), np.int32))
+            self._cursor_dev = self._cursor_dev.at[slot].set(cached)
             self._plen_host[slot] = len(toks)
             if self._spec_mode:
                 row = np.zeros((self._hist_dev.shape[1],), np.int32)
@@ -1704,7 +1731,7 @@ class Scheduler:
             np.asarray([r.temperature for r in reqs], np.float32),
             self.engine.runtime_top_k, self.engine.runtime_top_p)
         base = self._next_dev if self._next_dev is not None \
-            else jnp.asarray(self._next_tokens)
+            else self.engine.carry(self._next_tokens)
         slots_arr = np.asarray([r.slot for r in reqs], np.int32)
         self._next_dev = base.at[slots_arr].set(firsts)
         if self._spec_mode:
@@ -1777,7 +1804,7 @@ class Scheduler:
         # the previous block's final vector seeded); the host vector
         # only on the cold first dispatch
         cur = self._next_dev if self._next_dev is not None \
-            else self._next_tokens
+            else self.engine.carry(self._next_tokens)
         block, final = self.engine.decode_block_async(
             cur, active, temps, stops, budgets, sub, k)
         self._next_dev = final
@@ -1921,7 +1948,7 @@ class Scheduler:
         self._key, sub = jax.random.split(self._key)
         plen = self._plen_host
         cursor = self._cursor_dev if self._cursor_dev is not None \
-            else jnp.zeros((S,), jnp.int32)
+            else self.engine.carry(np.zeros((S,), np.int32))
         if self._spec_mode:
             C = self._mixed_chunk  # gamma + 1: the verify shape
             if self._spec_rem is None:
@@ -1933,11 +1960,12 @@ class Scheduler:
             # deterministic cursor advance: C prompt tokens per round
             # while mid-prefill (emissions can't kill the lane first)
             pf_done = []
+            self._c_chunk_offered.inc(k * C * self._prefilling_ahead())
             for req in list(self._prefill_group):
                 p = int(plen[req.slot])
                 if req.prefilled < p:
                     adv = min(p, req.prefilled + k * C)
-                    self._inline_pf_tokens += adv - req.prefilled
+                    self._c_chunk_tokens.inc(adv - req.prefilled)
                     req.prefilled = adv
                 if req.prefilled >= p:
                     pf_done.append(req.slot)
@@ -1950,10 +1978,11 @@ class Scheduler:
             self._enqueue_block("mixed_spec", hlen, (toks, valid), k,
                                 snapshot, pf_done, None)
             return True
-        # plain mixed: chunk width C only while a prompt is actually in
-        # flight — with no prefill lane the program collapses to C=1,
-        # the exact _decode_scan shape (and its RNG stream)
-        C = self._mixed_chunk if self._prefill_group else 1
+        # plain mixed: the packed step carries P chunks of C tokens
+        # only while a prompt is actually in flight — with no slot in
+        # prefill phase it is the decode step (and its RNG stream)
+        C = self._mixed_chunk
+        P = self._mixed_max_pf if self._prefilling_ahead() else 0
         ahead = np.zeros((S,), np.int64)
         for ent in self._inflight:
             ahead = ahead + ent[7]  # per-slot emission estimates
@@ -1979,19 +2008,20 @@ class Scheduler:
                 if e >= b:
                     break
             if c != req.prefilled:
-                self._inline_pf_tokens += c - req.prefilled
+                self._c_chunk_tokens.inc(c - req.prefilled)
                 req.prefilled = c
             emit_vec[slot] = e
             if req.state == "prefilling" and c >= p:
                 pf_done.append(slot)
         cur = self._next_dev if self._next_dev is not None \
-            else self._next_tokens
+            else self.engine.carry(self._next_tokens)
         if self._pbuf_dev is None:
             self._pbuf_dev = jnp.zeros((S, self.engine.cache.max_seq),
                                        jnp.int32)
         block, valid, final, cursor = self.engine.mixed_block_async(
             cur, cursor, self._pbuf_dev, plen, active, temps, stops,
-            budgets, sub, k, C)
+            budgets, sub, k, C, P)
+        self._c_chunk_offered.inc(k * P * C)
         self._next_dev, self._cursor_dev = final, cursor
         self._enqueue_block("mixed", final, (block, valid), k, snapshot,
                             pf_done, emit_vec)

@@ -1401,8 +1401,9 @@ def run_server(args) -> int:
     print("[butterfly] warming serving programs...", flush=True)
     # The long prompt decodes for more than two blocks after its last
     # chunk: a fused block with no prompt in flight is a program of its
-    # own (chunk width 1), and a request whose answer outlasts its
-    # prefill must not be the one that compiles it inside a tick.
+    # own (no chunk: a decode block), and a request whose answer
+    # outlasts its prefill must not be the one that compiles it inside
+    # a tick.
     warm_new = 2 * rt.decode_steps_per_tick + 2
     warm_len = min(2 * rt.prefill_chunk, rt.max_seq_len - warm_new - 2)
     # a full gang of smallest-bucket prompts first (compiles the widest

@@ -330,10 +330,10 @@ def test_kernel_wrappers_lower_for_tpu_under_meshes(as_tpu):
 ], ids=["tp4", "sp2tp2", "pp2tp2"])
 def test_serving_programs_lower_for_tpu_under_meshes(as_tpu, axes, kv_quant,
                                                      holds):
-    """The fused decode block (and, with a seq axis, the SP chunk
-    program) of a meshed ServingEngine, kernels on, 8B head geometry at a
-    small width: lowers for the TPU with the Mosaic call inside, and no
-    layer gave way to the dense path."""
+    """The packed mixed block (and, with a seq axis, the SP chunk
+    program) of a meshed ServingEngine, kernels on, 8B head geometry at
+    a small width: lowers for the TPU with the Mosaic call inside, and
+    no layer gave way to the dense path."""
     from butterfly_tpu.core.config import RuntimeConfig
     from butterfly_tpu.engine.serving import ServingEngine
     from butterfly_tpu.models.common import Model
@@ -353,18 +353,16 @@ def test_serving_programs_lower_for_tpu_under_meshes(as_tpu, axes, kv_quant,
     tail = (jnp.ones((S,), bool), jnp.zeros((S,), jnp.float32),
             jnp.full((S,), -1, jnp.int32), jnp.full((S,), k, jnp.int32),
             0, 1.0, jax.random.PRNGKey(0))
+    C = 32
     with eng._mesh_ctx():
         if eng._window_mode:
-            eng._ensure_window(k)
-            text = _lower_for_tpu(
-                eng._mixed_block_win_prog(k, 1), eng.params, i32((S,)),
-                i32((S,)), eng.cache, eng._kv_window, eng._win_len,
-                i32((S, eng.cache.max_seq)), i32((S,)), *tail)
-        else:  # pipeline serving keeps per-token pool writes
-            text = _lower_for_tpu(
-                eng._mixed_block_prog(k, 1), eng.params, i32((S,)),
-                i32((S,)), eng.cache, i32((S, eng.cache.max_seq)),
-                i32((S,)), *tail)
+            eng._ensure_window(k * C)
+        # pipeline serving keeps per-token pool writes: no window there
+        assert eng._window_mode == ("stage" not in axes)
+        text = _lower_for_tpu(
+            eng._mixed_block_prog(k, C, 1), eng.params, i32((S,)),
+            i32((S,)), eng.cache, eng._kv_window, eng._win_len,
+            i32((S, eng.cache.max_seq)), i32((S,)), *tail)
         assert "tpu_custom_call" in text
         if eng.supports_seq_parallel:
             pools = (eng.cache.k_pages, eng.cache.v_pages,

@@ -1,5 +1,6 @@
 """Unified mixed dispatch (ISSUE 18): prefill chunks and decode blocks
-in ONE fused program per tick.
+in ONE fused program per tick; since ISSUE 29 a step of it is PACKED,
+S decode rows of one token beside P chunks of C prompt tokens.
 
 The contract under test:
 
@@ -15,7 +16,11 @@ The contract under test:
 * `prefill_inline_budget` caps CONCURRENT prefill lanes (the ITL-tail
   knob) — the mutcheck drop-the-budget mutant must die here;
 * mid-prefill preemption and cancel under the fused block keep the
-  flush-before-reclaim invariant (exercised with kv_write_combine on).
+  flush-before-reclaim invariant (exercised with kv_write_combine on);
+* the packed step's shapes: S + P*C rows through the layers and S rows
+  through the head, in the lowered program and in the tick record's
+  `rows`; its decode rows take the paged kernel (window segment and
+  all) and nothing falls back to the dense path unasked.
 """
 import jax
 import numpy as np
@@ -86,7 +91,24 @@ def _run_warm(sched):
     return [r1.output, r2.output, r3.output]
 
 
-SCENARIOS = {"fresh": _run_fresh, "ragged": _run_ragged, "warm": _run_warm}
+def _run_lengths(sched):
+    """Prompt lengths against the chunk (C = 8) and the block (k = 4
+    steps, so k x C = 32): shorter than C; 19 = 8 + 8 + 3, which ends
+    on the third step of a block; 41, which takes a second block; and
+    two admitted in consecutive ticks while the others decode."""
+    r1 = sched.submit([3, 1, 4], max_new_tokens=9)
+    r2 = sched.submit(list(range(2, 21)), max_new_tokens=7)
+    sched.tick()
+    r3 = sched.submit(list(range(30, 71)), max_new_tokens=6)
+    sched.tick()
+    r4 = sched.submit(list(range(7, 12)), max_new_tokens=8)
+    sched.run_until_done()
+    return [r1.output, r2.output, r3.output, r4.output]
+
+
+SCENARIOS = {"fresh": _run_fresh, "ragged": _run_ragged, "warm": _run_warm,
+             "lengths": _run_lengths}
+_K4 = dict(decode_steps_per_tick=4, max_batch=4)
 
 #: the parity grid: every dimension value (scenario, chunk 8/16,
 #: f32/int8, spec on/off, window on/off) appears at least twice,
@@ -104,12 +126,33 @@ GRID = [
                   prefix_caching=True, kv_write_combine=True)),
     ("warm", dict(prefill_chunk=16, prefill_inline_budget=16,
                   prefix_caching=True, speculative_gamma=3)),
+    # the packed step (ISSUE 29): window on and off x int8 KV on and
+    # off, against every length class, a prefix-cache hit under the
+    # chunk, and two chunks a step (budget 64 over chunks of 32: P = 2)
+    ("lengths", dict(prefill_chunk=8, prefill_inline_budget=8, **_K4)),
+    ("lengths", dict(prefill_chunk=8, prefill_inline_budget=8,
+                     kv_quant="int8", **_K4)),
+    ("lengths", dict(prefill_chunk=8, prefill_inline_budget=8,
+                     kv_write_combine=False, **_K4)),
+    ("lengths", dict(prefill_chunk=8, prefill_inline_budget=16,
+                     kv_write_combine=False, kv_quant="int8", **_K4)),
+    ("lengths", dict(prefill_chunk=32, prefill_inline_budget=64,
+                     kv_quant="int8", **_K4)),
+    ("ragged", dict(prefill_chunk=32, prefill_inline_budget=64,
+                    kv_write_combine=False, decode_steps_per_tick=2)),
+    ("warm", dict(prefill_chunk=8, prefill_inline_budget=8,
+                  prefix_caching=True, kv_write_combine=False,
+                  kv_quant="int8")),
+    ("warm", dict(prefill_chunk=8, prefill_inline_budget=16,
+                  prefix_caching=True, kv_quant="int8",
+                  decode_steps_per_tick=2)),
 ]
 
 
 @pytest.mark.parametrize("scenario,rt_kw", GRID,
-                         ids=[f"{s}-" + "-".join(sorted(k for k in kw))
-                              for s, kw in GRID])
+                         ids=[f"{s}-" + "-".join(
+                             f"{k}={v}" for k, v in sorted(kw.items()))
+                             for s, kw in GRID])
 def test_mixed_vs_alternating_token_parity(scenario, rt_kw):
     run = SCENARIOS[scenario]
     alt = run(make_sched(mixed_dispatch=False, **rt_kw))
@@ -118,6 +161,11 @@ def test_mixed_vs_alternating_token_parity(scenario, rt_kw):
     assert mix == alt
     # the tentpole's headline: admission-cause barriers retired
     assert sched.barrier_causes().get("admission", 0) == 0
+    # every prompt token past a cached prefix rode a chunk, and the
+    # chunks offered at least as many positions: the fill
+    m = sched.registry.snapshot()
+    assert 0 < m["mixed_chunk_tokens_total"] \
+        <= m["mixed_chunk_positions_total"]
 
 
 def test_alternating_path_unchanged_barriers():
@@ -193,16 +241,30 @@ def test_inline_budget_caps_concurrent_prefill():
     assert sched._mixed_max_pf == 1
     reqs = [sched.submit(list(range(1 + 20 * i, 19 + 20 * i)),
                          max_new_tokens=4) for i in range(4)]
+    # the invariant on the DEVICE: a dispatched block meets at most P
+    # slots in prefill phase (one more would get no chunk, unseen by
+    # the lockstep simulation), read from the operands it is handed
+    launch, on_device = sched.engine.mixed_block_async, []
+
+    def spy(tokens, cursor, pbuf, plen, *rest):
+        on_device.append(int((np.asarray(cursor) < plen).sum()))
+        assert on_device[-1] <= rest[-1], "more prefilling slots than chunks"
+        return launch(tokens, cursor, pbuf, plen, *rest)
+
+    sched.engine.mixed_block_async = spy
     seen_pf = 0
     for _ in range(60):
         if not sched.has_work:
             break
         sched.tick()
-        pf = len(sched._prefill_group)
+        # slots still in prefill phase when the next block runs: a
+        # member whose last chunk is in flight holds no place
+        pf = sched._prefilling_ahead()
         seen_pf = max(seen_pf, pf)
         assert pf <= 1, "inline budget must cap concurrent prefill lanes"
+        assert len(sched._prefill_group) <= 1 + len(sched._inflight)
     assert all(r.state == "finished" for r in reqs)
-    assert seen_pf == 1
+    assert seen_pf == 1 and max(on_device) == 1
     # a wider budget admits wider gangs: the knob is live in BOTH
     # directions (budget 32 / chunk 8 -> 4 concurrent lanes allowed)
     wide = make_sched(max_batch=4, max_seq=96,
@@ -349,3 +411,147 @@ def test_mixed_tick_phase_recorded():
                for t in dump["ticks"])
     m = sched.metrics()
     assert "tick_phase_mixed_p50" in m
+
+
+# -- the packed step's shapes and kernels (ISSUE 29) ---------------------------
+
+def _all_shapes(jaxpr, out=None):
+    """Every array shape a traced program computes, scan bodies and
+    other sub-programs included."""
+    out = set() if out is None else out
+    for eqn in jaxpr.eqns:
+        out.update(tuple(v.aval.shape) for v in eqn.outvars
+                   if hasattr(v.aval, "shape"))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _all_shapes(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("P", [1, 2, 0])
+@pytest.mark.parametrize("window", [True, False], ids=["win", "nowin"])
+def test_packed_step_shapes_and_rows(window, P):
+    """A step of the packed block puts S + P*C rows through the layers
+    and S rows through the head: no array of the traced program has
+    the lane-wide S*C rows, the logits are [S, V], and the tick record
+    says the same in `rows` (S for a block with no prompt in flight)."""
+    S, C, k = 3, 8, 2
+    sched = make_sched(max_batch=S, prefill_chunk=C,
+                       prefill_inline_budget=max(1, P) * C,
+                       decode_steps_per_tick=k, kv_write_combine=window)
+    assert (sched._mixed_chunk, sched._mixed_max_pf) == (C, max(1, P))
+    eng = sched.engine
+    if window:
+        eng._ensure_window(k * C)
+    i32 = lambda *shape: np.zeros(shape, np.int32)  # noqa: E731
+    traced = eng._mixed_block_prog(k, C, P).trace(
+        eng.params, i32(S), i32(S), eng.cache, eng._kv_window, eng._win_len,
+        i32(S, eng.cache.max_seq), i32(S), np.ones(S, bool),
+        np.zeros(S, np.float32), i32(S) - 1, i32(S) + k, 0, 1.0,
+        jax.random.PRNGKey(0))
+    shapes = _all_shapes(traced.jaxpr.jaxpr)
+    D, V, N = CFG.hidden_size, CFG.vocab_size, S + P * C
+    assert (N, 1, D) in shapes            # the packed rows, through a layer
+    assert (S, V) in shapes               # the head, one row a slot
+    wide = [sh for sh in shapes if sh and sh[-1] in (D, V)
+            and int(np.prod(sh[:-1])) >= S * C]
+    assert not wide, f"lane-wide activations in the packed step: {wide}"
+
+    if P == 2:
+        return  # the tick record: once a window mode is enough
+    r1 = sched.submit(list(range(1, 20)), max_new_tokens=6)
+    sched.run_until_done()
+    assert r1.state == "finished"
+    rows = {(t["program"], t["rows"]) for t in sched.ticklog.dump()["ticks"]
+            if t["program"]}
+    win = "_win" if window else ""
+    assert rows == {("bf_mixed_block" + win, S + C),
+                    ("bf_decode_block" + win, S)}
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+def test_packed_block_kernel_notes(kv_quant):
+    """Kernels interpreted: a packed block's decode rows go through the
+    paged kernel with the window segment (the kernel a configuration
+    declares it must hold), its chunk through the dense view of its own
+    slot BY CHOICE: no `dense_fallback` note, which would mark a
+    benchmark run as not correct. Tokens are those of the kernels-off
+    run."""
+    rt = RuntimeConfig(max_batch_size=3, max_seq_len=96, page_size=8,
+                       prefill_chunk=8, prefill_inline_budget=8,
+                       decode_steps_per_tick=2, kv_quant=kv_quant)
+    outs = []
+    for use_kernels in (False, True):
+        eng = ServingEngine(Model(CFG), params(), rt,
+                            use_kernels=use_kernels)
+        outs.append(_run_fresh(Scheduler(eng, seed=0)))
+    assert outs[0] == outs[1]
+    held = "paged_int8_win" if kv_quant == "int8" else "paged_win"
+    assert eng.kernel_mode == "interpret"
+    assert eng.kernel_calls.get(held + ":interpret", 0) >= 2  # both programs
+    assert "dense_fallback" not in eng.kernel_calls
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+def test_packed_block_under_pipeline_stages(kv_quant):
+    """Under pipeline stages the packed step goes through the GPipe
+    schedule as one microbatch (parallel/pipeline.py
+    paged_pipeline_packed): mixed dispatch stays on, no admission
+    barrier comes back, the block is 2 + P*C rows, and the tokens are
+    the unmeshed engine's and the alternating path's."""
+    from butterfly_tpu.core.config import MeshConfig
+    from butterfly_tpu.core.mesh import make_mesh
+    if len(jax.devices()) < 2:
+        pytest.skip("needs 2 fake devices")
+    mesh = make_mesh(MeshConfig(stage=2), jax.devices()[:2])
+    rt = RuntimeConfig(max_batch_size=2, max_seq_len=64, page_size=8,
+                       prefill_chunk=8, prefill_inline_budget=8,
+                       decode_steps_per_tick=2, kv_quant=kv_quant)
+
+    def run(rt, mesh=None):
+        sched = Scheduler(ServingEngine(Model(CFG), params(), rt, mesh=mesh),
+                          seed=0)
+        r1 = sched.submit([5, 7, 11], max_new_tokens=8)
+        sched.tick()
+        r2 = sched.submit(list(range(1, 20)), max_new_tokens=6)
+        sched.run_until_done()
+        return sched, [r1.output, r2.output]
+
+    sched, staged = run(rt, mesh)
+    assert sched._mixed_mode and sched._mixed_fallback_reason is None
+    assert sched.barrier_causes().get("admission", 0) == 0
+    rows = {(t["program"], t["rows"]) for t in sched.ticklog.dump()["ticks"]}
+    assert ("bf_mixed_block", 2 + 8) in rows
+    assert staged == run(rt)[1]
+    assert staged == run(rt.replace(mixed_dispatch=False), mesh)[1]
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+def test_packed_step_logits_beside_the_lane_wide_step(kv_quant):
+    """tools/mixed_parity.py's check, rehearsed on the toy: the packed
+    step's logits are the lane-wide step's over three blocks with a
+    flush after each, a prompt that crosses a flush, one chunk and two;
+    a chunk fed one token late reads far over the limit, and in float32
+    even a chunk staged one index late shows. Without a TPU the tool
+    refuses to run unless told it is a rehearsal."""
+    import json
+    import sys
+    from pathlib import Path
+    root = Path(__file__).resolve().parent.parent
+    sys.path.insert(0, str(root / "tools"))
+    try:
+        import mixed_parity
+    finally:
+        sys.path.remove(str(root / "tools"))
+    config = json.loads((root / "tests/servebench/files/configs"
+                         / "tiny-llama.json").read_text())
+    config["serve"]["kv_quant"] = kv_quant
+    with pytest.raises(SystemExit, match="no TPU"):
+        mixed_parity.check(config)
+    out = mixed_parity.check(config, toy=True)
+    assert out["ok"] and out["evidence"] == "cpu toy"
+    for P in ("P1", "P2"):
+        clean, late = out[P]["clean"], out[P]["index_shift"]
+        assert clean["max"] < 1e-5
+        assert clean["argmax_agree"] == clean["rows"]
+        assert out[P]["chunk_shift"]["max"] > 5 * mixed_parity.LIMIT
+        assert late["chunk_slot_after_flush_max"] > 1e3 * clean["max"]

@@ -1457,6 +1457,7 @@ def _engine(**rt_kw):
 
 
 _SPEC = dict(speculative_gamma=2)
+_WIN = dict(kv_write_combine=True)
 _TREE = dict(speculative_gamma=2, draft_model="model", spec_tree_width=2,
              spec_tree_nodes=3)
 
@@ -1468,11 +1469,11 @@ _TREE = dict(speculative_gamma=2, draft_model="model", spec_tree_width=2,
     (lambda e: e._flush, {}, "flush_paged_window"),
     (lambda e: e._decode_block_prog(4), {}, "bf_decode_block"),
     (lambda e: e._decode_block_win_prog(4), {}, "bf_decode_block_win"),
-    (lambda e: e._mixed_block_prog(4, 8), {}, "bf_mixed_block"),
-    (lambda e: e._mixed_block_win_prog(4, 8), {}, "bf_mixed_block_win"),
-    # chunk width 1: no lane prefills, a decode block in shape and use
-    (lambda e: e._mixed_block_prog(4, 1), {}, "bf_decode_block"),
-    (lambda e: e._mixed_block_win_prog(4, 1), {}, "bf_decode_block_win"),
+    (lambda e: e._mixed_block_prog(4, 8, 1), {}, "bf_mixed_block"),
+    (lambda e: e._mixed_block_prog(4, 8, 1), _WIN, "bf_mixed_block_win"),
+    # no chunk: no slot prefills, a decode block in shape and in use
+    (lambda e: e._mixed_block_prog(4, 8, 0), {}, "bf_decode_block"),
+    (lambda e: e._mixed_block_prog(4, 8, 0), _WIN, "bf_decode_block_win"),
     (lambda e: e._spec_block_prog(2), _SPEC, "bf_spec_block"),
     (lambda e: e._spec_block_win_prog(2), _SPEC, "bf_spec_block_win"),
     (lambda e: e._mixed_spec_prog(2), _SPEC, "bf_mixed_spec_block"),
@@ -1503,7 +1504,7 @@ def test_program_name_and_scopes_reach_the_module_and_the_tick_record():
                zip(ticks[1:], blocks) if t["block"] == b0)
     eng = sched.engine
     k, C = 1, sched._mixed_chunk
-    text = eng._mixed_block_win_prog(k, C).lower(
+    text = eng._mixed_block_prog(k, C, 1).lower(
         eng.params, jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
         eng.cache, eng._kv_window, eng._win_len,
         jnp.zeros((2, 64), jnp.int32), jnp.zeros((2,), jnp.int32),

@@ -249,3 +249,43 @@ def test_cli_build_mesh_flags():
                              expert_parallel=1, data_parallel=1)
     with pytest.raises(SystemExit):
         build_mesh(big)
+
+
+@pytest.mark.parametrize("use_kernels", [False, True],
+                         ids=["dense", "kernels"])
+def test_packed_mixed_block_on_tp4_matches_single_device(use_kernels):
+    """The packed mixed step (ISSUE 29) under tensor-parallel 4 with 2
+    KV heads a shard, window on: prompts that take several chunks,
+    admitted while others decode, give the single-device tokens; with
+    kernels (interpreted) the decode rows hold the paged kernel with
+    its window segment on every shard."""
+    cfg = tiny("llama", dtype="float32", param_dtype="float32",
+               num_heads=8, num_kv_heads=8, head_dim=8)
+    params = Model(cfg).init(jax.random.PRNGKey(7))
+    rt = RuntimeConfig(max_batch_size=4, max_seq_len=96, page_size=8,
+                       prefill_chunk=8, prefill_inline_budget=8,
+                       decode_steps_per_tick=2)
+    assert rt.kv_write_combine and rt.mixed_dispatch
+
+    def run(mesh, kernels):
+        eng = ServingEngine(Model(cfg), params, rt, mesh=mesh,
+                            use_kernels=kernels)
+        sched = Scheduler(eng)
+        reqs = [sched.submit(list(range(1, 20)), max_new_tokens=7),
+                sched.submit([5, 7, 11], max_new_tokens=9)]
+        sched.tick()
+        reqs.append(sched.submit(list(range(40, 51)), max_new_tokens=6))
+        sched.run_until_done()
+        progs = {t["program"] for t in sched.ticklog.dump()["ticks"]}
+        assert "bf_mixed_block_win" in progs
+        return [r.output for r in reqs], eng
+
+    ref, _ = run(None, False)
+    mesh = make_mesh(MeshConfig(tensor=4), jax.devices()[:4])
+    got, eng = run(mesh, use_kernels)
+    assert got == ref
+    assert eng.cache.k_pages.sharding.shard_shape(
+        eng.cache.k_pages.shape)[2] == 2          # 2 KV heads a shard
+    if use_kernels:
+        assert eng.kernel_calls.get("paged_win:interpret", 0) >= 1
+        assert "dense_fallback" not in eng.kernel_calls

@@ -269,8 +269,9 @@ def tp_place(mesh, name, args):
 
 
 def serving_block_hlo(small: bool, mesh):
-    """Compiled HLO of the serving engine's fused decode block (mixed
-    block at chunk width 1, write-combined int8 window) at the 8B width,
+    """Compiled HLO of the serving engine's packed mixed block (its S
+    decode rows beside one 32-token chunk, write-combined int8 window)
+    at the 8B width,
     two layers deep, built exactly as `serve` builds it; plus the kernel
     call sites the engine recorded while it traced."""
     import jax
@@ -291,10 +292,11 @@ def serving_block_hlo(small: bool, mesh):
     params = init_params_by_leaf(cfg, jax.random.PRNGKey(0), quant="int8",
                                  mesh=mesh)
     eng = ServingEngine(Model(cfg), params, rt, mesh=mesh, use_kernels=True)
-    eng._ensure_window(k)
+    C = 32  # one packed prefill chunk beside the S decode rows
+    eng._ensure_window(k * C)
     i32 = functools.partial(jnp.zeros, dtype=jnp.int32)
     with eng._mesh_ctx():
-        hlo = eng._mixed_block_win_prog(k, 1).lower(
+        hlo = eng._mixed_block_prog(k, C, 1).lower(
             eng.params, i32((S,)), i32((S,)), eng.cache, eng._kv_window,
             eng._win_len, i32((S, eng.cache.max_seq)), i32((S,)),
             jnp.ones((S,), bool), jnp.zeros((S,), jnp.float32),
