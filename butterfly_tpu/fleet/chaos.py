@@ -44,7 +44,7 @@ Hook points:
   refused connect would).
 
 Driven by ``butterfly fleet --chaos plan.json`` and the chaos soak in
-tests/test_fleet.py / obs/benchmark.py:run_chaos_benchmark.
+tests/test_fleet.py.
 
 stdlib-only (importable without jax, like the rest of the router tier).
 """

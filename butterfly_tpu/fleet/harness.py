@@ -6,10 +6,10 @@ behind `butterfly route --disaggregate`): each replica is a full
 Scheduler + ServingEngine + HTTP front on a loopback port, the control
 plane is the real ControlPlaneState/FleetHandler — only the network is
 loopback. Used by `butterfly fleet --topology 2p2d` (manual
-debugging), tests/test_fleet.py (the soak), and the fleet benchmark
-(obs/benchmark.py). All replicas share ONE param tree (same weights,
-as a real fleet would load from one checkpoint), which is also what
-makes cross-replica KV bytes interchangeable.
+debugging) and the soaks of tests/test_fleet.py and
+tests/test_autoscale.py. All replicas share ONE param tree (same
+weights, as a real fleet would load from one checkpoint), which is also
+what makes cross-replica KV bytes interchangeable.
 
 ``ReplicaHandle.restart()`` bounces the replica's HTTP front (the
 listener drops mid-fleet and comes back on the same port) — the

@@ -1,7 +1,7 @@
 """Observability: metrics exposition, typed instruments, tracing, health.
 
 Only the stdlib-light modules are re-exported here (registry, trace,
-metrics); benchmark/profile/health import jax and stay lazy.
+metrics); profile/health import jax and stay lazy.
 """
 from butterfly_tpu.obs.metrics import (  # noqa: F401
     ThroughputWindow,

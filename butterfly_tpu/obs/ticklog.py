@@ -140,7 +140,7 @@ class TickLog:
     def phase_percentiles(self) -> Dict[str, Dict[str, float]]:
         """Per-phase p50/p95 seconds over the ring window, plus the
         combined "drain" pseudo-phase (drain_oldest + drain_barrier per
-        tick — the key bench.py reports)."""
+        tick)."""
         with self._lock:
             ticks = list(self._ring)
         if not ticks:

@@ -211,7 +211,7 @@ def init_params_by_leaf(cfg, key: jax.Array, quant: str = "none",
     contraction axes; norm-scales -> ones; biases -> zeros; everything
     else -> N(0, .02)) are resolved by path over init_params'
     eval_shape tree, so the structure can't drift from the real
-    initializer. This is the no-checkpoint path of the CLI, bench.py
+    initializer. This is the no-checkpoint path of the CLI
     and the tools (real deployments load checkpoints via ckpt/)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -600,7 +600,7 @@ class Scheduler:
         self._itl_means: Deque[float] = deque(maxlen=4096)
         # per-dispatch device-bubble samples (seconds; 0 = the pipeline
         # kept the device busy through the host section) for the
-        # metrics() percentile keys bench.py reports
+        # metrics() percentile keys
         self._bubbles: Deque[float] = deque(maxlen=4096)
         # -- tick anatomy (ISSUE 15) -----------------------------------------
         # Per-tick phase attribution: tick() zeroes the accumulator,
@@ -1267,8 +1267,8 @@ class Scheduler:
 
     def barrier_causes(self) -> Dict[str, float]:
         """Per-cause FULL-barrier counts: the drain_barriers_total
-        {cause=} family as a plain dict (bench.py's breakdown key —
-        which membership-change class is costing the pipeline)."""
+        {cause=} family as a plain dict (which membership-change class
+        is costing the pipeline)."""
         fam = self._c_barriers
         with fam._lock:
             items = list(fam._children.items())

@@ -4,9 +4,8 @@
 
     butterfly generate --model gpt2-124m --prompt "hello" --max-new 32
     butterfly serve    --model llama3-8b --port 8000
-    butterfly bench    --model tiny [--serving --mixed]
     butterfly route    --backends 10.0.0.1:8000,10.0.0.2:8000
-    butterfly workload generate|replay|sweep   (workload subsystem)
+    butterfly workload generate|replay   (workload subsystem)
     butterfly lint     [paths...]   (project-native static analysis)
 
 Models load from --ckpt (HF safetensors dir or our sharded checkpoint);
@@ -256,45 +255,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "recorder). 0 disables the recorder entirely "
                         "(zero extra per-tick host work)")
 
-    b = sub.add_parser("bench", help="throughput microbenchmark")
-    common(b)
-    kv_quant_flag(b)
-    b.add_argument("--batch", type=int, default=8)
-    b.add_argument("--prompt-len", type=int, default=128)
-    b.add_argument("--max-new", type=int, default=128)
-    b.add_argument("--serving", action="store_true",
-                   help="also run the PRODUCT serving-path benchmark "
-                        "(Scheduler + ServingEngine under staggered "
-                        "arrivals) at this operating point and merge "
-                        "its serving_* keys into the JSON line")
-    b.add_argument("--inflight-blocks", type=positive_int, default=2,
-                   help="dispatch-ahead depth for --serving (see "
-                        "`serve --inflight-blocks`); the serving JSON "
-                        "carries device_bubble_p50/p95 so the overlap "
-                        "is measurable at this depth")
-    b.add_argument("--max-batch", type=positive_int, default=0,
-                   help="serving slot count for --serving/--mixed "
-                        "(default: --batch) — decouples the serving "
-                        "operating point from the isolated-decode "
-                        "batch, so e.g. the ROADMAP item 1 batch-128 "
-                        "serving run is `--serving --max-batch 128` "
-                        "without re-timing isolated decode at 128")
-    b.add_argument("--mixed", action="store_true",
-                   help="also run the mixed-workload serving phase "
-                        "(ISSUE 10): the canned mixed_chat population "
-                        "fired open-loop in bursts against an under-"
-                        "provisioned page pool — preemption, shedding, "
-                        "and deadline scrubbing measured instead of "
-                        "idle — plus the decode_steps_per_tick x "
-                        "inflight_blocks operating-point table + knee; "
-                        "merges mixed_* keys into the JSON line")
-    b.add_argument("--host-tier-mb", type=float, default=0.0,
-                   help="with --mixed: give the engine a host-RAM KV "
-                        "tier of this many MiB so the contested pool "
-                        "demotes/revives instead of dropping — merges "
-                        "kv_tier_hit_rate and kv_tier_restore_seconds_"
-                        "p50/p95 into the JSON line")
-
     # multi-replica router: fronts N `butterfly serve` replicas with
     # prefix-affinity routing + health-aware failover (router/). Loads no
     # model and touches no accelerator — deliberately NOT given the
@@ -398,14 +358,11 @@ def build_parser() -> argparse.ArgumentParser:
     # SLO-aware admission shedding on every in-process replica
 
     # workload subsystem (butterfly_tpu/workload/): generate seeded
-    # stochastic traffic traces, replay them open-loop at a live URL,
-    # and sweep scheduler operating points — the measurement substrate
-    # the mixed bench phase runs on.
+    # stochastic traffic traces and replay them open-loop at a live URL.
     w = sub.add_parser("workload",
                        help="stochastic workload tooling: generate a "
-                            "seeded trace, replay one at a server "
-                            "open-loop, or sweep scheduler operating "
-                            "points")
+                            "seeded trace, or replay one at a server "
+                            "open-loop")
     wsub = w.add_subparsers(dest="wcmd", required=True)
 
     def workload_shape_flags(sp, for_generate=True):
@@ -456,31 +413,9 @@ def build_parser() -> argparse.ArgumentParser:
     wr.add_argument("--slo-ttft-ms", type=float, default=None)
     wr.add_argument("--slo-itl-ms", type=float, default=None)
 
-    ws = wsub.add_parser("sweep",
-                         help="run one workload across a "
-                              "decode_steps_per_tick x inflight_blocks "
-                              "grid (in-process engine) and emit the "
-                              "latency/throughput table + knee")
-    workload_shape_flags(ws)
-    ws.add_argument("--model", default="tiny")
-    ws.add_argument("--quant", choices=["none", "int8"], default="none")
-    kv_quant_flag(ws)
-    ws.add_argument("--ckpt", default=None)
-    ws.add_argument("--grid", default="1,4x1,2",
-                    help="'<k1>,<k2>x<d1>,<d2>' decode_steps_per_tick "
-                         "x inflight_blocks values, full cross product")
-    ws.add_argument("--max-batch", type=int, default=8)
-    ws.add_argument("--num-pages", type=int, default=0,
-                    help="KV page pool size (0 = full provisioning; "
-                         "set below max_batch x pages-per-seq to "
-                         "measure preemption behavior)")
-    ws.add_argument("--slo-ttft-ms", type=float, default=None,
-                    help="arm SLO-aware admission shedding during the "
-                         "sweep (sheds are counted per point)")
-
     # project-native static analysis (tools/staticcheck.py, ISSUE 11):
     # the donation/lock/host-sync/determinism contracts as AST rules —
-    # the same walk the tier-1 test and bench.py's preflight run.
+    # the same walk the tier-1 test runs.
     li = sub.add_parser("lint",
                         help="AST lint for the serving contracts "
                              "(donation, locks, host-sync, HTTP "
@@ -695,58 +630,6 @@ def cmd_serve(args) -> int:
     return run_server(args)
 
 
-def cmd_bench(args) -> int:
-    from butterfly_tpu.obs.benchmark import (run_decode_benchmark,
-                                             run_serving_benchmark)
-
-    model = resolve_model(args)
-    mesh = build_mesh(args)
-    params = load_params(model, args, mesh)
-    stats = run_decode_benchmark(model, params, batch=args.batch,
-                                 prompt_len=args.prompt_len,
-                                 max_new=args.max_new, mesh=mesh,
-                                 kv_quant=args.kv_quant)
-    serving_batch = args.max_batch or args.batch
-    if args.serving:
-        # the serving path is single-engine: a mesh-sharded tree would
-        # need the serving mesh wiring (ServingEngine(mesh=...)); keep
-        # the CLI smoke single-chip like bench.py's driver
-        serving = run_serving_benchmark(
-            model, params, n_requests=2 * serving_batch,
-            prompt_len=args.prompt_len, max_new=args.max_new,
-            max_batch=serving_batch, kv_quant=args.kv_quant,
-            inflight_blocks=args.inflight_blocks,
-            isolated_decode_tok_s_chip=stats[
-                "decode_tokens_per_sec_per_chip"])
-        stats.update(serving)
-        if mesh is None:
-            # long-context row (ISSUE 20): builds its own seq=4 mesh
-            # when the device count allows; on fewer devices it reports
-            # longctx_supported: false plus the ring microbench pair
-            from butterfly_tpu.obs.benchmark import run_longctx_benchmark
-            stats.update(run_longctx_benchmark(
-                model, params, kv_quant=args.kv_quant))
-    if getattr(args, "mixed", False):
-        # mixed-workload phase (ISSUE 10): mixed_chat open-loop bursts
-        # against an under-provisioned pool + the operating-point sweep
-        # (single-engine, like --serving)
-        from butterfly_tpu.obs.benchmark import run_mixed_benchmark
-        stats.update(run_mixed_benchmark(
-            model, params, n_requests=2 * serving_batch,
-            max_batch=serving_batch,
-            prompt_lo=max(8, args.prompt_len // 4),
-            prompt_hi=args.prompt_len,
-            max_new_lo=max(4, args.max_new // 4),
-            max_new_hi=args.max_new,
-            inflight_blocks=args.inflight_blocks,
-            host_kv_tier_mb=getattr(args, "host_tier_mb", 0.0),
-            kv_quant=args.kv_quant))
-    print(json.dumps({"metric": "decode_tokens_per_sec_per_chip",
-                      "value": stats["decode_tokens_per_sec_per_chip"],
-                      "unit": "tokens/sec/chip", **stats}))
-    return 0
-
-
 def cmd_route(args) -> int:
     backends = [b for b in args.backends.split(",") if b.strip()]
     if args.disaggregate:
@@ -837,9 +720,9 @@ def cmd_fleet(args) -> int:
 
 
 def cmd_workload(args) -> int:
-    """`butterfly workload generate|replay|sweep` (ISSUE 10): the
-    seeded traffic-modeling subsystem's CLI surface. generate/replay
-    are stdlib-fast (no engine); sweep builds an in-process engine."""
+    """`butterfly workload generate|replay` (ISSUE 10): the seeded
+    traffic-modeling subsystem's CLI surface, stdlib-fast (no
+    engine)."""
     from butterfly_tpu.workload import (assign_arrivals, get_workload,
                                         parse_arrival)
     from butterfly_tpu.workload import replay as replay_mod
@@ -866,42 +749,15 @@ def cmd_workload(args) -> int:
             "max_new_tokens": sum(s.max_new for s in specs),
             "span_s": round(specs[-1].arrival_s, 3) if specs else 0.0}))
         return 0
-    if args.wcmd == "replay":
-        _, specs = replay_mod.load_trace(args.trace)
-        stats = replay_mod.replay_trace(
-            args.url, specs, speed=args.speed, timeout=args.timeout,
-            slo_ttft_ms=args.slo_ttft_ms, slo_itl_ms=args.slo_itl_ms)
-        print(json.dumps(stats, indent=2))
-        # like loadgen: sheds/504s are requested backpressure; only
-        # transport errors / 5xx faults fail the replay
-        return 0 if stats["outcomes"]["error"] == 0 else 1
-    # sweep: in-process engine over the operating-point grid
-    import jax
-    from butterfly_tpu.core.config import PRESETS, tiny
-    from butterfly_tpu.models.common import Model
-    from butterfly_tpu.workload.sweep import (parse_grid,
-                                              run_operating_point_sweep)
-    cfg = tiny("llama", dtype="float32", param_dtype="float32") \
-        if args.model == "tiny" else PRESETS[args.model]()
-    model = Model(cfg)
-    params = load_params(model, args)
-    # the sweep drives a real engine, so the workload's vocabulary is
-    # the MODEL's (the --vocab flag applies to `generate`, whose trace
-    # may target any server)
-    wl = get_workload(args.workload, page_size=args.page_size,
-                      vocab=model.cfg.vocab_size,
-                      prompt_lo=args.prompt_lo, prompt_hi=args.prompt_hi,
-                      max_new_lo=args.max_new_lo,
-                      max_new_hi=args.max_new_hi,
-                      deadline_ms=args.deadline_ms)
-    out = run_operating_point_sweep(
-        model, params, workload=wl, arrival=args.arrival,
-        n_requests=args.n, grid=parse_grid(args.grid),
-        max_batch=args.max_batch, num_pages=args.num_pages,
-        kv_quant=args.kv_quant, slo_ttft_ms=args.slo_ttft_ms,
-        seed=args.seed)
-    print(json.dumps(out, indent=2))
-    return 0
+    # replay
+    _, specs = replay_mod.load_trace(args.trace)
+    stats = replay_mod.replay_trace(
+        args.url, specs, speed=args.speed, timeout=args.timeout,
+        slo_ttft_ms=args.slo_ttft_ms, slo_itl_ms=args.slo_itl_ms)
+    print(json.dumps(stats, indent=2))
+    # like loadgen: sheds/504s are requested backpressure; only
+    # transport errors / 5xx faults fail the replay
+    return 0 if stats["outcomes"]["error"] == 0 else 1
 
 
 def cmd_lint(args) -> int:
@@ -961,12 +817,12 @@ def cmd_dash(args) -> int:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
-    if args.cmd in ("generate", "serve", "bench", "fleet", "workload"):
+    if args.cmd in ("generate", "serve", "fleet"):
         # the commands that compile; route/lint/dash never import jax
         from butterfly_tpu.core.compile_cache import place_compile_cache
         place_compile_cache()
     return {"generate": cmd_generate, "serve": cmd_serve,
-            "bench": cmd_bench, "route": cmd_route,
+            "route": cmd_route,
             "fleet": cmd_fleet, "workload": cmd_workload,
             "lint": cmd_lint, "dash": cmd_dash}[args.cmd](args)
 

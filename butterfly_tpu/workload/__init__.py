@@ -19,13 +19,10 @@ substrate:
   replay.py    JSONL trace serialization + an absolute-time replay
                driver over a live server/router/control-plane URL
                (reuses tools/loadgen.py's request/judging machinery)
-  sweep.py     operating-point sweep engine: one workload across a
-               decode_steps_per_tick x inflight_blocks grid, emitting
-               the latency/throughput curve + knee point
 
-models/arrivals/replay are stdlib-only (no jax, no numpy) so traces can
-be generated and replayed from any host; sweep drives an in-process
-Scheduler and imports the engine lazily.
+All three are stdlib-only (no jax, no numpy) so traces can be generated
+and replayed from any host. The benchmark of record does not use this
+package: its traffic is servebench/traffic.py.
 """
 from butterfly_tpu.workload.arrivals import (  # noqa: F401
     MarkovOnOff,

@@ -36,8 +36,7 @@ TRACE_VERSION = 1
 
 
 def _loadgen():
-    """Import tools/loadgen.py (lives outside the package; same
-    sys.path dance obs/benchmark.py uses)."""
+    """Import tools/loadgen.py (lives outside the package)."""
     if "loadgen" in sys.modules:
         return sys.modules["loadgen"]
     tools = str(Path(__file__).resolve().parents[2] / "tools")

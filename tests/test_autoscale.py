@@ -13,6 +13,7 @@ Two layers, matching the two-tier suite:
   serves, retire drains without dropping a request, and the full
   closed loop reshapes a real topology both directions.
 """
+import json
 import threading
 import urllib.request
 
@@ -318,7 +319,6 @@ PAGE = 8
 
 
 def post_completion(url, prompt_tokens, max_new=4, timeout=60):
-    import json
     body = json.dumps({"tokens": prompt_tokens, "max_tokens": max_new,
                        "stop_token": -1}).encode()
     req = urllib.request.Request(
@@ -441,19 +441,92 @@ def test_autoscaler_closes_the_loop_on_a_live_fleet():
         fleet.stop()
 
 
-def test_autoscale_benchmark_beats_static_peak():
+def autoscale_soak(loadgen):
+    """A ramp-arrival open loop (2 -> 16 req/s over 4 s, then hold)
+    against a 1p1d in-process fleet WITH the closed-loop autoscaler
+    live on the decode tier: the fleet starts small and correct for the
+    head of the ramp, the scraped queue-depth rings rise with the
+    offered rate, and the loop must grow the decode tier mid-soak.
+    After the load ends a settle window lets the hysteresis-guarded
+    scale-down fire. Returns what the soak test asserts: the client's
+    drops and SLO attainment, the scale-ups, the replica-seconds spent
+    against a fleet held at the peak shape throughout, and the scale
+    decisions served by /debug/flightrecorder."""
+    import time
+
+    from butterfly_tpu.fleet.harness import start_fleet
+
+    max_tokens = 8
+    slo_ttft_ms, slo_itl_ms = 10000.0, 2000.0
+    shared_len = PAGE * 4
+    tail = PAGE // 2
+    fleet = start_fleet("1p1d", page_size=PAGE, max_batch=2,
+                        max_seq=shared_len + tail + max_tokens + 16,
+                        probe_interval=0.1,
+                        slo_ttft_s=slo_ttft_ms / 1e3,
+                        slo_itl_s=slo_itl_ms / 1e3,
+                        warm_len=shared_len + tail)
+    try:
+        n0 = len(fleet.replicas)
+        pol = TierPolicy("decode", min_replicas=1, max_replicas=3,
+                         signal="queue_depth", high=0.5, low=0.05,
+                         window=2, cooldown_up_s=0.5,
+                         cooldown_down_s=1.0)
+        scaler = Autoscaler(fleet.state, fleet.spawn, fleet.retire,
+                            [pol], interval_s=0.2)
+        scaler.start()
+        t0 = time.monotonic()
+        load = loadgen.run_load(fleet.url, clients=4,
+                                requests_per_client=6, prefix_share=0.5,
+                                shared_len=shared_len, tail_len=tail,
+                                max_tokens=max_tokens, seed=0,
+                                slo_ttft_ms=slo_ttft_ms,
+                                slo_itl_ms=slo_itl_ms,
+                                arrival="ramp:2:16:4")
+        # settle: idle rings drain below the low band and the
+        # hysteresis window elapses — the scale-down half of the claim
+        deadline = time.monotonic() + 6.0
+        while time.monotonic() < deadline:
+            if scaler.stats()["scale_downs"] > 0:
+                break
+            time.sleep(0.2)
+        wall = time.monotonic() - t0
+        scaler.stop()
+        st = scaler.stats()
+        # replay the event log to find the peak shape the fleet reached
+        peak = n = n0
+        for e in st["events"]:
+            n += 1 if e["direction"] == "up" else -1
+            peak = max(peak, n)
+        with urllib.request.urlopen(fleet.url + "/debug/flightrecorder",
+                                    timeout=10.0) as resp:
+            rec = json.loads(resp.read())
+    finally:
+        fleet.stop()
+    return {
+        "dropped": load["failed"],
+        "slo_attainment": load.get("slo_attainment"),
+        "scale_ups": st["scale_ups"],
+        # the cost side: integral of live replicas over the soak vs a
+        # static fleet provisioned at the peak shape the whole time
+        "replica_seconds": st["replica_seconds"],
+        "static_peak_replica_seconds": peak * wall,
+        "flightrec_scale_events": sum(
+            e.get("kind") == "scale" for e in rec.get("events", ())),
+    }
+
+
+def test_autoscale_benchmark_beats_static_peak(loadgen):
     """ISSUE 17 acceptance: ramp-arrival soak where the autoscaler
     holds SLO attainment at the objective while spending fewer
     replica-seconds than a static fleet provisioned at the peak shape,
     with the decisions auditable via /debug/flightrecorder."""
-    from butterfly_tpu.obs.benchmark import run_autoscale_benchmark
-    out = run_autoscale_benchmark()
-    assert out["autoscale_dropped"] == 0
-    assert out["autoscale_slo_attainment"] == 1.0
-    assert out["autoscale_scale_ups"] >= 1
-    assert out["autoscale_replica_seconds"] \
-        < out["autoscale_static_peak_replica_seconds"]
-    assert out["autoscale_flightrec_scale_events"] >= 1
+    out = autoscale_soak(loadgen)
+    assert out["dropped"] == 0
+    assert out["slo_attainment"] == 1.0
+    assert out["scale_ups"] >= 1
+    assert out["replica_seconds"] < out["static_peak_replica_seconds"]
+    assert out["flightrec_scale_events"] >= 1
 
 
 def test_parse_topology_arbitrary_shapes():

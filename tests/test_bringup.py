@@ -119,16 +119,6 @@ def test_init_by_leaf_never_builds_the_float_8b_tree(monkeypatch):
 
 # -- refusals that replaced fallbacks ---------------------------------------
 
-def test_unknown_device_kind_is_an_error():
-    from butterfly_tpu.obs.benchmark import (HBM_BW, PEAK_FLOPS, chip_peak,
-                                             require_chip)
-    for table in (HBM_BW, PEAK_FLOPS):
-        with pytest.raises(ValueError, match="device_kind 'cpu'"):
-            chip_peak(table)
-    with pytest.raises(SystemExit, match="no accelerator"):
-        require_chip("a benchmark")
-
-
 def test_interpret_mode_off_the_cpu_backend_raises(monkeypatch):
     from butterfly_tpu import ops
     from butterfly_tpu.ops.paged_attention import paged_attention

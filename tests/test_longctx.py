@@ -216,23 +216,3 @@ def test_prefix_hit_after_long_prefill(tiny_model, sp_mesh):
     s.run_until_done()
     assert b.cached_at_admit > 0
     assert a.output == b.output
-
-
-def test_longctx_benchmark_smoke(tiny_model):
-    """The bench row end to end at a tiny shape: the SP lane must be
-    exercised (sp tokens > 0), the ring microbench pair must carry the
-    CPU honesty key, and the declared ITL budget must be emitted (the
-    within-budget bool itself is asserted by the driver's bench run,
-    not here — a loaded CI box can blow any wall-clock bound)."""
-    from butterfly_tpu.obs.benchmark import run_longctx_benchmark
-    model, params = tiny_model
-    out = run_longctx_benchmark(model, params, prompt_len=128,
-                                prefill_chunk=16, max_new=4,
-                                n_decoders=2, decode_new=12, repeats=1)
-    assert out["longctx_supported"]
-    assert out["longctx_ring_kernelized"] is False
-    assert out["longctx_sp_prefill_tokens"] > 0
-    assert out["longctx_prefill_tokens_per_sec"] > 0
-    assert out["longctx_ring_block_ms_jnp"] > 0
-    assert "longctx_itl_budget_s" in out
-    assert isinstance(out["longctx_itl_within_budget"], bool)

@@ -254,21 +254,14 @@ def test_same_prefix_lands_on_same_replica_and_hits_cache(cluster):
     assert aff and float(aff[0].split()[-1]) >= 2
 
 
-def test_affinity_beats_round_robin_under_shared_load(cluster):
+def test_affinity_beats_round_robin_under_shared_load(cluster, loadgen):
     """ISSUE 2 acceptance: 50% shared-prefix workload -> prefix hits
     concentrate on the affinity replica, zero failed requests."""
-    import sys
-    from pathlib import Path
-    sys.path.insert(0, str(Path(__file__).parent.parent / "tools"))
-    try:
-        from loadgen import run_load
-    finally:
-        sys.path.pop(0)
     before = {r.rid: r.sched.alloc.hit_tokens for r in cluster.reps}
-    stats = run_load(cluster.router.url, clients=3,
-                     requests_per_client=4, prefix_share=0.5,
-                     shared_len=AFF_BLOCKS * PAGE, tail_len=4,
-                     max_tokens=4, seed=7, vocab=64)
+    stats = loadgen.run_load(cluster.router.url, clients=3,
+                             requests_per_client=4, prefix_share=0.5,
+                             shared_len=AFF_BLOCKS * PAGE, tail_len=4,
+                             max_tokens=4, seed=7, vocab=64)
     assert stats["failed"] == 0, stats["errors"]
     assert stats["ok"] == 12
     assert stats["shared_prefix_requests"] >= 2  # workload sanity

@@ -180,16 +180,3 @@ def test_butterfly_lint_subcommand():
     the direct driver on the clean tree."""
     from butterfly_tpu.serve.cli import main
     assert main(["lint"]) == 0
-
-
-def test_bench_preflight_gate():
-    """bench.py refuses to publish a JSON line from a dirty tree: its
-    preflight is the same run_default() walk, so on the committed tree
-    it must come back empty (and the bench JSON records the 0)."""
-    sys.path.insert(0, str(REPO))
-    try:
-        import bench
-    finally:
-        sys.path.remove(str(REPO))
-    findings = bench.lint_preflight()
-    assert findings == []

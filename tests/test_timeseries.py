@@ -737,25 +737,3 @@ def test_tick_report_follow_polls_since(tmp_path, capsys):
     assert out.count("tick ") == 5
     assert "dom=dispatch" in out
     assert cursors == [0, 5, 5]
-
-
-# ---------------------------------------------------------------------------
-# bench JSON series summaries ride along
-# ---------------------------------------------------------------------------
-
-@pytest.mark.slow
-def test_mixed_benchmark_carries_series_summary():
-    import jax
-    from butterfly_tpu.core.config import tiny
-    from butterfly_tpu.models.common import Model
-    from butterfly_tpu.obs.benchmark import run_mixed_benchmark
-    cfg = tiny("llama", dtype="float32", param_dtype="float32")
-    model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    out = run_mixed_benchmark(model, params, n_requests=6,
-                              prompt_lo=8, prompt_hi=32,
-                              max_new_lo=4, max_new_hi=8,
-                              page_size=4, max_seconds=60.0)
-    summ = out["mixed_series_summary"]
-    assert "kv_pages_free" in summ
-    assert {"peak", "mean", "slope", "n"} <= set(summ["kv_pages_free"])

@@ -12,10 +12,7 @@ Three layers:
   least one 429 through the PR-8 path — with the loadgen/replay
   summary folding the server-side counters in (client-observed vs
   server-counted in one artifact);
-* bench smoke: a tiny run_mixed_benchmark (seconds) pins the mixed
-  bench phase's JSON contract — mixed_* fields, preemptions > 0, the
-  >= 2x2 operating-point table + knee — so the subsystem can't
-  silently rot, plus `butterfly workload generate|replay` CLI smoke.
+* `butterfly workload generate|replay` CLI smoke.
 """
 import json
 import statistics
@@ -40,7 +37,7 @@ from butterfly_tpu.workload.replay import (load_trace, replay_trace,
 
 CFG = tiny("llama", dtype="float32", param_dtype="float32")
 
-#: the CPU-smoke mixed_chat shape (bench.py's CPU sizing, shrunk):
+#: the CPU-smoke mixed_chat shape:
 #: decode budgets long enough to keep slots alive across blocks, so a
 #: near-instant burst against a tight pool provably contests pages
 SMOKE_WL = dict(page_size=8, vocab=258, prompt_lo=8, prompt_hi=48,
@@ -311,43 +308,8 @@ def test_shed_429_through_admission_path(shed_server):
 
 
 # ---------------------------------------------------------------------------
-# bench phase + CLI smoke (tier-1-safe: seconds, not minutes)
+# CLI smoke (tier-1-safe: seconds, not minutes)
 # ---------------------------------------------------------------------------
-
-
-def test_mixed_bench_phase_smoke():
-    """The tiny `--mixed` bench phase: run_mixed_benchmark on the
-    smallest preemption-forcing shape and pin its JSON contract —
-    mixed_* TTFT/ITL/tok/s fields, serving_preemptions > 0, and a
-    >= 2x2 decode_steps_per_tick x inflight_blocks operating-point
-    table with a knee (the ISSUE 10 acceptance keys)."""
-    from butterfly_tpu.obs.benchmark import run_mixed_benchmark
-    model = Model(CFG)
-    params = model.init(jax.random.PRNGKey(0))
-    out = run_mixed_benchmark(
-        model, params, n_requests=10, max_batch=4,
-        prompt_lo=8, prompt_hi=40, max_new_lo=16, max_new_hi=40,
-        page_size=8, pool_fraction=0.3, decode_steps_per_tick=2,
-        inflight_blocks=2, prefill_max_batch=4, kv_quant="none",
-        arrival=SMOKE_ARRIVAL, grid=[(1, 1), (1, 2), (2, 1), (2, 2)])
-    assert out["mixed_serving_preemptions"] > 0
-    assert out["mixed_serving_tokens_per_sec"] > 0
-    for k in ("mixed_ttft_p50", "mixed_ttft_p95",
-              "mixed_itl_req_mean_p50", "mixed_shed_total",
-              "mixed_deadline_expired_total"):
-        assert k in out, k
-    pts = out["operating_points"]
-    assert len(pts) == 4
-    assert {(p["decode_steps_per_tick"], p["inflight_blocks"])
-            for p in pts} == {(1, 1), (1, 2), (2, 1), (2, 2)}
-    for p in pts:
-        assert p["ok"] + p["shed_429"] + p["expired_504"] \
-            + p["skipped_too_long"] == 10
-        assert p["tokens_per_sec"] > 0 and "ttft_p95" in p
-    knee = out["operating_point_knee"]
-    assert knee is not None
-    assert (knee["decode_steps_per_tick"], knee["inflight_blocks"]) \
-        in {(1, 1), (1, 2), (2, 1), (2, 2)}
 
 
 def test_cli_workload_generate_deterministic(tmp_path):

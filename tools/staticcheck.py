@@ -21,9 +21,9 @@ Suppression syntax (reason MANDATORY — a bare disable is itself a
 BTF000 finding):
     something_flagged()  # btf: disable=BTF001 one-line reason
 
-The same engine runs as the tier-1 test (tests/test_staticcheck.py),
-as `butterfly lint` (serve/cli.py), and as bench.py's preflight — one
-registry, so no surface can silently drop a rule.
+The same engine runs as the tier-1 test (tests/test_staticcheck.py)
+and as `butterfly lint` (serve/cli.py) — one registry, so no surface
+can silently drop a rule.
 """
 from __future__ import annotations
 
@@ -35,7 +35,7 @@ from typing import Iterable, List, Optional
 
 try:  # script mode: tools/ is sys.path[0]
     import staticrules
-except ImportError:  # imported from elsewhere (cli, bench preflight)
+except ImportError:  # imported from elsewhere (cli, tests)
     sys.path.insert(0, str(Path(__file__).resolve().parent))
     import staticrules
 from staticrules import Finding, check_context, make_context
@@ -97,8 +97,8 @@ def run_paths(paths: Iterable[Path],
 
 
 def run_default(root: Optional[Path] = None) -> List[Finding]:
-    """The canonical repo walk (tier-1 test + bench preflight):
-    butterfly_tpu/, tools/, tests/ minus the fixture snippets.
+    """The canonical repo walk (the tier-1 test's): butterfly_tpu/,
+    tools/, tests/ minus the fixture snippets.
     Returns only the UNSUPPRESSED findings."""
     base = root or REPO
     found = run_paths([base / t for t in DEFAULT_TREES])
