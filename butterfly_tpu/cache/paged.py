@@ -201,26 +201,36 @@ def write_paged_layer(k_pages: jax.Array, v_pages: jax.Array,
     return k_pages, v_pages, None, None
 
 
+def _table_pages(pages: jax.Array, page_table: jax.Array, layer):
+    """pages[page_table] of one layer: `pages` is that layer's
+    [P, ...] (layer None) or the whole [L, P, ...] pool, indexed by
+    layer and page in ONE gather, so that no layer is cut out first."""
+    return pages[page_table] if layer is None else pages[layer, page_table]
+
+
 @jax.named_scope("kv_gather")
-def gather_paged_layer(pages: jax.Array, page_table: jax.Array) -> jax.Array:
-    """One layer's pages -> contiguous [B, S_max, Kv, H] view (XLA Gather)."""
-    Pp, Kv, page, H = pages.shape
+def gather_paged_layer(pages: jax.Array, page_table: jax.Array,
+                       layer=None) -> jax.Array:
+    """One layer's pages -> contiguous [B, S_max, Kv, H] view (XLA
+    Gather). pages: [P, Kv, page, H], or with `layer` [L, P, Kv, page, H]."""
+    Kv, page, H = pages.shape[-3:]
     B, max_pages = page_table.shape
-    out = pages[page_table]                 # [B, max_pages, Kv, page, H]
+    out = _table_pages(pages, page_table, layer)  # [B, mp, Kv, page, H]
     out = out.transpose(0, 1, 3, 2, 4)      # [B, max_pages, page, Kv, H]
     return out.reshape(B, max_pages * page, Kv, H)
 
 
 @jax.named_scope("kv_gather")
 def gather_paged_layer_q(pages: jax.Array, scale_pages: jax.Array,
-                         page_table: jax.Array):
+                         page_table: jax.Array, layer=None):
     """Quantized gather: codes [B, Kv, S, H] + scales [B, Kv, S] — the
-    kv-major order models.common.attend expects for int8 caches."""
-    Pp, Kv, page, H = pages.shape
+    kv-major order models.common.attend expects for int8 caches.
+    `layer`: as gather_paged_layer's."""
+    Kv, page, H = pages.shape[-3:]
     B, max_pages = page_table.shape
-    codes = pages[page_table]               # [B, mp, Kv, page, H]
+    codes = _table_pages(pages, page_table, layer)  # [B, mp, Kv, page, H]
     codes = codes.transpose(0, 2, 1, 3, 4).reshape(B, Kv, max_pages * page, H)
-    sc = scale_pages[page_table]            # [B, mp, Kv*page]
+    sc = _table_pages(scale_pages, page_table, layer)   # [B, mp, Kv*page]
     sc = sc.reshape(B, max_pages, Kv, page).transpose(0, 2, 1, 3)
     return codes, sc.reshape(B, Kv, max_pages * page)
 
@@ -519,7 +529,7 @@ def _settled(*view):
     return lax.optimization_barrier(view)
 
 
-def paged_attend(q, k, v, kp, vp, *, cfg: ModelConfig, page_table,
+def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
                  positions, mask, active, use_kernel: bool, fresh: bool,
                  ksp=None, vsp=None, win=None, force_dense: bool = False):
     """One layer's attention for [B,T] queries whose K/V is already
@@ -528,7 +538,12 @@ def paged_attend(q, k, v, kp, vp, *, cfg: ModelConfig, page_table,
     dense gather, shared by paged_layer_body and the packed mixed step
     (paged_forward_packed) so the two cannot drift. q: [B,T,Nq,H];
     k/v: [B,T,Kv,H] (the fresh projections, read by the flash
-    branches only); page_table: the B rows' own table rows
+    branches only); kp/vp [L,P,Kv,page,H] (ksp/vsp [L,P,Kv*page] iff
+    int8): the WHOLE pool, and `layer` the one to attend. The kernel
+    takes both as they are; a branch that needs the layer's view in XLA
+    indexes layer and page in its own gather (_table_pages). Nobody
+    cuts the layer out beforehand: handed to a custom call, such a
+    slice is a copy of the layer. page_table: the B rows' own table rows
     [B, max_pages]; win: (wk, wv, wks, wvs, win_len) AFTER staging,
     window slices [B, Kv, W, H] and the staged count BEFORE it.
     Returns [B,T,Nq,H]."""
@@ -547,8 +562,8 @@ def paged_attend(q, k, v, kp, vp, *, cfg: ModelConfig, page_table,
             # segment with its own count
             lens = jnp.where(active, base, 0)
             wcnt = jnp.where(active, win_len + T, 0)
-            out = paged_attention_sharded(q[:, 0], kp, vp, page_table,
-                                          lens, ksp, vsp,
+            out = paged_attention_sharded(q[:, 0], kp, vp, layer,
+                                          page_table, lens, ksp, vsp,
                                           win_k=wk, win_v=wv,
                                           win_count=wcnt,
                                           win_k_scale=wks, win_v_scale=wvs)
@@ -556,8 +571,8 @@ def paged_attend(q, k, v, kp, vp, *, cfg: ModelConfig, page_table,
             # lengths INCLUDING the token just written (inactive: 0 ->
             # no pages visited, output discarded)
             lens = jnp.where(active, positions[:, 0] + 1, 0)
-            out = paged_attention_sharded(q[:, 0], kp, vp, page_table,
-                                          lens, ksp, vsp)
+            out = paged_attention_sharded(q[:, 0], kp, vp, layer,
+                                          page_table, lens, ksp, vsp)
         out = out[:, None] if out is not None else None
     elif cfg.attn_impl == "flash" and T > 1 and fresh:
         # fresh prefill attends over the just-projected bf16 K/V, so the
@@ -576,8 +591,8 @@ def paged_attend(q, k, v, kp, vp, *, cfg: ModelConfig, page_table,
         # window entries are not in the pool.)
         base = jnp.where(active, start, 0)
         if quant:
-            ckg, k_sg = gather_paged_layer_q(kp, ksp, page_table)
-            cvg, v_sg = gather_paged_layer_q(vp, vsp, page_table)
+            ckg, k_sg = gather_paged_layer_q(kp, ksp, page_table, layer)
+            cvg, v_sg = gather_paged_layer_q(vp, vsp, page_table, layer)
             # mirror the chunk's in-pool representation (the dense path
             # reads the quantized write back) — operand-parity with the
             # gather path by construction
@@ -589,8 +604,8 @@ def paged_attend(q, k, v, kp, vp, *, cfg: ModelConfig, page_table,
                 q, kf, vf, causal=True, prefix_k=ckg, prefix_v=cvg,
                 prefix_len=base, prefix_k_scale=k_sg, prefix_v_scale=v_sg)
         else:
-            ckg = gather_paged_layer(kp, page_table)
-            cvg = gather_paged_layer(vp, page_table)
+            ckg = gather_paged_layer(kp, page_table, layer)
+            cvg = gather_paged_layer(vp, page_table, layer)
             out = flash_attention_sharded(q, k, v, causal=True,
                                           prefix_k=ckg, prefix_v=cvg,
                                           prefix_len=base)
@@ -604,15 +619,15 @@ def paged_attend(q, k, v, kp, vp, *, cfg: ModelConfig, page_table,
         if tried_kernel:
             note_kernel("dense_fallback")
         if quant:
-            ck, k_s = gather_paged_layer_q(kp, ksp, page_table)
-            cv, v_s = gather_paged_layer_q(vp, vsp, page_table)
+            ck, k_s = gather_paged_layer_q(kp, ksp, page_table, layer)
+            cv, v_s = gather_paged_layer_q(vp, vsp, page_table, layer)
             if win is not None:
                 ck, k_s = insert_window_view_q(ck, k_s, wk, wks, base)
                 cv, v_s = insert_window_view_q(cv, v_s, wv, wvs, base)
             out = attend(q, *_settled(ck, cv), mask, cfg, *_settled(k_s, v_s))
         else:
-            ck = gather_paged_layer(kp, page_table)
-            cv = gather_paged_layer(vp, page_table)
+            ck = gather_paged_layer(kp, page_table, layer)
+            cv = gather_paged_layer(vp, page_table, layer)
             if win is not None:
                 ck = insert_window_view(ck, wk, base)
                 cv = insert_window_view(cv, wv, base)
@@ -639,27 +654,37 @@ def _layer_close(x, out, lp, cfg: ModelConfig):
     return x + ffn_block(pre_norm(x, lp["ln2"], cfg), lp, cfg)
 
 
+def _as_pool(pools):
+    """One layer's pool tensors (None where absent), as a scan that
+    writes them holds them, seen as a whole pool of that one layer (a
+    free reshape) for paged_attend: (pools, layer 0)."""
+    return tuple(None if a is None else a[None] for a in pools), 0
+
+
 def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
                      positions, mask, cos, sin, active, use_kernel: bool,
-                     fresh: bool, ksp=None, vsp=None, win=None,
+                     fresh: bool, ksp=None, vsp=None, win=None, layer=None,
                      force_dense: bool = False):
-    """One transformer layer against one layer's page pool slice.
+    """One transformer layer against its page pool.
 
     Shared by paged_forward's full-stack scan, the stage-local scan of
     the pipeline serving path (parallel/pipeline.py), and the
     write-combined window path (paged_forward_window) so the three
-    cannot drift. x: [B,T,D]; kp/vp: [P,Kv,page,H]; ksp/vsp: [P,Kv*page]
-    scale slices iff the pool is int8. Returns (x, kp, vp[, ksp, vsp]).
+    cannot drift. x: [B,T,D]; window off, kp/vp: [P,Kv,page,H], this
+    layer's slice of the scanned pool, which the layer WRITES; ksp/vsp:
+    [P,Kv*page] scale slices iff the pool is int8. Returns
+    (x, kp, vp[, ksp, vsp]).
 
     win (kv_write_combine): (wk, wv, wks, wvs, win_len) — this layer's
     window slices [S, Kv, W, H] (+ [S, Kv, W] scales iff quantized) and
-    the per-slot staged count. The pool slice is then READ-ONLY: fresh
-    K/V stages into the window instead of scattering the pool, and
-    attention reads pool + window (kernel: window segment folded into
-    the online softmax; dense: window inserted into the gathered view
-    at absolute positions, element-wise identical to the window-off
-    written view). Returns (x, wk, wv[, wks, wvs]) — the pool rides
-    outside the scan unchanged.
+    the per-slot staged count. The pool is then READ-ONLY and comes
+    whole, kp/vp [L,P,Kv,page,H] (ksp/vsp [L,P,Kv*page]) with `layer`
+    this layer's index in it: fresh K/V stages into the window instead
+    of scattering the pool, and attention reads pool + window (kernel:
+    window segment folded into the online softmax; dense: window
+    inserted into the gathered view at absolute positions,
+    element-wise identical to the window-off written view). Returns
+    (x, wk, wv[, wks, wvs]) — the pool rides outside the scan unchanged.
     """
     quant = ksp is not None
     lp, q, k, v = _layer_open(x, lp, cfg, cos, sin)
@@ -671,10 +696,12 @@ def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
         kp, vp, ksp, vsp = write_paged_layer(kp, vp, page_table, k, v,
                                              positions[:, 0], active,
                                              ksp, vsp)
+    pool, layer = ((kp, vp, ksp, vsp), layer) if win is not None \
+        else _as_pool((kp, vp, ksp, vsp))
     out = paged_attend(
-        q, k, v, kp, vp, cfg=cfg, page_table=page_table,
+        q, k, v, pool[0], pool[1], layer, cfg=cfg, page_table=page_table,
         positions=positions, mask=mask, active=active,
-        use_kernel=use_kernel, fresh=fresh, ksp=ksp, vsp=vsp,
+        use_kernel=use_kernel, fresh=fresh, ksp=pool[2], vsp=pool[3],
         win=None if win is None else (wk, wv, wks, wvs, win_len),
         force_dense=force_dense)
     x = _layer_close(x, out, lp, cfg)
@@ -776,10 +803,15 @@ def paged_forward_window(params, cfg: ModelConfig, tokens: jax.Array,
     unattendable and never flushed). Returns (logits [B,T,V], updated
     window).
 
-    The pool is closed over and indexed in-body (lax.dynamic_index) à
-    la models/common._decode_forward — threading the read-only pools
-    through scan xs would materialize a layer-slice copy per step. Only
-    the small window leaves ride the scan as xs/ys.
+    The pool is closed over, whole, and the scan carries the layer's
+    index: the paged kernel takes the pool as it lies and the index as
+    a prefetched scalar, and the dense branches index layer and page in
+    one gather (paged_attend). Neither scanning the read-only pools as
+    xs nor a lax.dynamic_index in the body will do: each hands the
+    kernel one layer's slice, and a custom call's operand is a buffer,
+    so XLA copied that layer (67 MB of int8 codes at 7B, keys and again
+    values) in every layer of every step. Only the small window leaves
+    ride the scan as xs/ys.
 
     `positions`/`attn_mask` override the causal defaults for the
     tree-verify path (paged_forward's attn_mask docs): staging still
@@ -802,20 +834,14 @@ def paged_forward_window(params, cfg: ModelConfig, tokens: jax.Array,
     def body(carry, scanned):
         x, i = carry
         lp, wk, wv, *wsc = scanned
-        kp = lax.dynamic_index_in_dim(cache.k_pages, i, 0, keepdims=False)
-        vp = lax.dynamic_index_in_dim(cache.v_pages, i, 0, keepdims=False)
-        ksp = vsp = None
-        if quant:
-            ksp = lax.dynamic_index_in_dim(cache.k_scale_pages, i, 0,
-                                           keepdims=False)
-            vsp = lax.dynamic_index_in_dim(cache.v_scale_pages, i, 0,
-                                           keepdims=False)
         wks, wvs = wsc if wsc else (None, None)
         out = paged_layer_body(
-            x, lp, kp, vp, cfg=cfg, page_table=cache.page_table,
+            x, lp, cache.k_pages, cache.v_pages, cfg=cfg,
+            page_table=cache.page_table,
             positions=positions, mask=mask, cos=cos, sin=sin,
             active=active, use_kernel=use_kernel, fresh=False,
-            ksp=ksp, vsp=vsp, win=(wk, wv, wks, wvs, win_len))
+            ksp=cache.k_scale_pages, vsp=cache.v_scale_pages,
+            win=(wk, wv, wks, wvs, win_len), layer=i)
         return (out[0], i + 1), tuple(out[1:])
 
     xs = (params["layers"], window.k, window.v)
@@ -892,10 +918,13 @@ def packed_rows(params, cfg: ModelConfig, tokens, cache: PagedKVCache,
 
 
 def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
-                 use_kernel: bool):
-    """One layer of the packed step. pools: this layer's (kp, vp, ksp,
-    vsp), scales None unless int8; wl: its window slices (wk, wv, wks,
-    wvs), or None with the window off. Every real row's entry goes
+                 use_kernel: bool, layer=None):
+    """One layer of the packed step. pools: (kp, vp, ksp, vsp), scales
+    None unless int8: with the window off this layer's slices, which it
+    writes; with it on the WHOLE read-only pool, and `layer` this
+    layer's index in it (paged_layer_body has the same two cases). wl:
+    the layer's window slices (wk, wv, wks, wvs), or None with the
+    window off. Every real row's entry goes
     where the lane-wide step put it, the window at win_len (+ t) or
     the pool at the row's position, in ONE stage or scatter. Attention
     is paged_attend twice: the S decode rows as its T == 1 case (the
@@ -914,8 +943,9 @@ def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
     else:
         pools = write_paged_layer(pools[0], pools[1], rows.table, k, v,
                                   rows.pos, rows.ok, pools[2], pools[3])
-    kp, vp, ksp, vsp = pools
-    attend_rows = partial(paged_attend, kp=kp, vp=vp, cfg=cfg,
+    (kp, vp, ksp, vsp), layer = (pools, layer) if wl is not None \
+        else _as_pool(pools)
+    attend_rows = partial(paged_attend, kp=kp, vp=vp, layer=layer, cfg=cfg,
                           use_kernel=use_kernel, fresh=False,
                           ksp=ksp, vsp=vsp)
     out = attend_rows(q[:S], k[:S], v[:S], page_table=rows.page_table,
@@ -995,15 +1025,15 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
         x, pools = lax.scan(body, x, (params["layers"], *pool_leaves(cache)))
         state = pool_leaves(cache, pools)
     else:
-        # the pool is read-only and indexed in-body, as in
-        # paged_forward_window; only the window's leaves ride the scan
+        # the pool is read-only and goes in whole beside the layer's
+        # index, as in paged_forward_window; only the window's leaves
+        # ride the scan
         def body(carry, scanned):
             x, i = carry
             lp, *wl = scanned
-            pools = tuple(lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
-                          for a in pool_leaves(cache))
-            x, _, wl = packed_layer(x, lp, (*pools, *pad), (*wl, *pad),
-                                    rows, cfg, use_kernel)
+            x, _, wl = packed_layer(x, lp, (*pool_leaves(cache), *pad),
+                                    (*wl, *pad), rows, cfg, use_kernel,
+                                    layer=i)
             return (x, i + 1), wl[:n_leaves]
 
         (x, _), new_win = lax.scan(

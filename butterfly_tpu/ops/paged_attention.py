@@ -6,16 +6,25 @@ materializes every slot's full [S_max] K/V view — reading null pages and
 unallocated tail pages for short sequences. This kernel instead walks each
 slot's block table and touches ONLY its live pages:
 
-* `PrefetchScalarGridSpec(num_scalar_prefetch=2)`: the block table and
-  lengths arrive before the body runs, so the K/V BlockSpec *index maps*
-  dereference `table[slot, j]` — the DMA engine streams exactly the pages
-  the slot owns, straight from HBM, double-buffered by the Mosaic
+* `PrefetchScalarGridSpec`: the layer, the block table and the lengths
+  arrive before the body runs, so the K/V BlockSpec *index maps*
+  dereference `(layer, table[slot, j])` — the DMA engine streams exactly
+  the pages the slot owns, straight from where the WHOLE pool
+  [L, P, Kv, page, H] lies in HBM, double-buffered by the Mosaic
   pipeline. This is the TPU analogue of vLLM's CUDA paged-attention
-  gather, with the page walk moved into the grid index maps.
-* grid (slots, max_pages): per-slot online softmax across its pages
-  (f32 scratch, same recurrence as ops/flash_attention.py); pages at or
-  past the slot's length are predicated off with `pl.when` (their DMA
-  still runs — at one page it is cheaper than a branchy pipeline).
+  gather, with the page walk moved into the grid index maps. The layer
+  is an index and not a slice because a custom call's operand is a
+  buffer: one layer cut out of the pool in XLA is a copy of that layer
+  (67 MB of int8 codes at 7B, for keys and again for values) in every
+  layer of every step, of which the kernel then reads the live pages.
+* grid (slots, pages of the LONGEST live context): per-slot online
+  softmax across its pages (f32 scratch, same recurrence as
+  ops/flash_attention.py); pages at or past the slot's length are
+  predicated off with `pl.when` (their DMA still runs — at one page it
+  is cheaper than a branchy pipeline). The second bound is a value, the
+  maximum of `lengths`, not max_pages: a predicated-off step is not
+  free (its index maps, the pipeline's bookkeeping), and with a table
+  sized for max_seq most steps were such.
 * Decode has one query token per slot, so the MXU sees [Nq, H] x
   [H, page] per step — small, but the kernel is bandwidth-bound and reads
   ceil(len/page) pages instead of S_max.
@@ -60,13 +69,17 @@ def _block_update(s, mask, vf, m_ref, l_ref, acc_ref, vs_row):
     m_ref[:] = m_new
 
 
-def _paged_kernel(table_ref, len_ref, *rest, page: int, kv_heads: int,
-                  quant: bool, window: int):
-    """window > 0: one extra trailing grid step attends the slot's
-    write-combined window segment [Kv, W, H] — staged-but-unflushed
-    K/V at absolute positions length..length+win_count-1 — folded into
-    the same online-softmax recurrence as the page blocks (the
-    kv_write_combine serving path; cache/paged.py window docs)."""
+def _paged_kernel(layer_ref, table_ref, len_ref, *rest, page: int,
+                  kv_heads: int, quant: bool, window: int):
+    """layer_ref is read by the index maps alone: the pool's blocks
+    arrive as [1, Kv, page, H], the layer dim squeezed. The grid's
+    second dim is as long as the longest live context needs (the
+    wrapper's dynamic bound), not max_pages. window > 0: one extra
+    trailing grid step attends the slot's write-combined window segment
+    [Kv, W, H] — staged-but-unflushed K/V at absolute positions
+    length..length+win_count-1 — folded into the same online-softmax
+    recurrence as the page blocks (the kv_write_combine serving path;
+    cache/paged.py window docs)."""
     if window:
         wc_ref, *rest = rest
     q_ref, k_ref, v_ref, *rest = rest
@@ -156,8 +169,8 @@ def _paged_kernel(table_ref, len_ref, *rest, page: int, kv_heads: int,
 
 @jax.named_scope("attn")
 def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
-                            v_pages: jax.Array, page_table: jax.Array,
-                            lengths: jax.Array,
+                            v_pages: jax.Array, layer,
+                            page_table: jax.Array, lengths: jax.Array,
                             k_scale_pages: jax.Array = None,
                             v_scale_pages: jax.Array = None,
                             win_k: jax.Array = None,
@@ -170,8 +183,9 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
     shard_map (flash_attention.shard_kernel: manual over every mesh
     axis) with the operands laid out as the paged partitioner lays them
     out (parallel/partition.py paged_cache_specs): slots over `data`, q/kv
-    heads over `tensor`; the page-id dim stays replicated (any slot may
-    reference any page). A `tensor` shard of the flat [Kv*page] scale dim
+    heads over `tensor`; the layer and page-id dims stay replicated (any
+    slot may reference any page), so the whole pool enters as it lies,
+    with no movement. A `tensor` shard of the flat [Kv*page] scale dim
     is the same contiguous kv-group chunk as the code pool's Kv shard, so
     one spec set covers both. Each shard walks its own slots' block
     tables with the unmodified kernel — purely local, no collectives.
@@ -193,18 +207,19 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
                                                    shardable_axes)
 
     S, Nq, H = q.shape
-    Kv = k_pages.shape[1]          # pools are [P, Kv, page, H]
+    Kv = k_pages.shape[2]          # pools are [L, P, Kv, page, H]
     d, t = shardable_axes(S, Nq, Kv)
     if d is None and t is None and live_auto_mesh():
         return None
     note_kernel("paged" + ("_int8" if k_scale_pages is not None else "")
                 + ("_win" if win_k is not None else ""),
                 resolve_interpret(None))
-    kv_spec = P(None, t, None, None)
-    in_specs = [P(d, t, None), kv_spec, kv_spec, P(d, None), P(d)]
-    args = [q, k_pages, v_pages, page_table, lengths]
+    kv_spec = P(None, None, t, None, None)
+    in_specs = [P(d, t, None), kv_spec, kv_spec, P(), P(d, None), P(d)]
+    args = [q, k_pages, v_pages, jnp.asarray(layer, jnp.int32), page_table,
+            lengths]
     if k_scale_pages is not None:
-        in_specs += [P(None, t), P(None, t)]
+        in_specs += [P(None, None, t), P(None, None, t)]
         args += [k_scale_pages, v_scale_pages]
     if win_k is not None:
         win_spec = P(d, t, None, None)
@@ -215,7 +230,7 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
             args += [win_k_scale, win_v_scale]
 
         def _kernel(*a):
-            pos = a[:5] if k_scale_pages is None else a[:7]
+            pos = a[:6] if k_scale_pages is None else a[:8]
             rest = a[len(pos):]
             kw = dict(win_k=rest[0], win_v=rest[1], win_count=rest[2])
             if len(rest) > 3:
@@ -231,7 +246,7 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    page_table: jax.Array, lengths: jax.Array,
+                    layer, page_table: jax.Array, lengths: jax.Array,
                     k_scale_pages: jax.Array = None,
                     v_scale_pages: jax.Array = None,
                     win_k: jax.Array = None,
@@ -243,10 +258,14 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Single-token attention over each slot's paged KV.
 
     q: [slots, Nq, H] (the one decode token per slot, post-rope);
-    k_pages/v_pages: [P, Kv, page, H] (one layer's pool);
+    k_pages/v_pages: [L, P, Kv, page, H], the WHOLE pool, every layer's,
+    as it lies in HBM; layer: int32 scalar, the layer to attend (it
+    rides the scalar prefetch, so only that layer's live pages are ever
+    fetched; a caller that holds one layer's [P, Kv, page, H] passes
+    `pages[None]` and layer 0, a free reshape);
     page_table: [slots, max_pages] int32; lengths: [slots] int32 —
     number of cache tokens INCLUDING the just-written current token;
-    k/v_scale_pages: [P, Kv*page] f32 per-vector scales iff the pool
+    k/v_scale_pages: [L, P, Kv*page] f32 per-vector scales iff the pool
     holds int8 codes. Returns [slots, Nq, H].
 
     Write-combined window (kv_write_combine): win_k/win_v [S, Kv, W, H]
@@ -260,46 +279,67 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     slot — the staged run never round-trips through the pool).
     """
     S, Nq, H = q.shape
-    Pp, Kv, page, H2 = k_pages.shape
+    L, Pp, Kv, page, H2 = k_pages.shape
     max_pages = page_table.shape[1]
     quant = k_scale_pages is not None
     window = 0 if win_k is None else win_k.shape[2]
     interpret = resolve_interpret(interpret)
+    layer = jnp.asarray(layer, jnp.int32)
+    # The page steps of the grid end with the longest live context, not
+    # with max_seq: a step past every slot's length fetches the table's
+    # tail (the null page) and attends nothing, yet costs its index
+    # maps and the pipeline's bookkeeping, and a slot's table is mostly
+    # tail (at 32 slots of 2048 with contexts of a few hundred tokens,
+    # nine steps in ten). The bound is a value, so one compiled kernel
+    # serves every length.
+    npages = jnp.clip(-(-jnp.max(lengths) // page), 0 if window else 1,
+                      max_pages)
 
-    # scalar-prefetch operands: (table, lengths[, win_count]) — the
-    # index maps see them all; the pool maps clamp j to the page grid
-    # (the trailing window step re-fetches the last page, unused)
-    npre = 3 if window else 2
+    # scalar-prefetch operands: (layer, table, lengths[, win_count]) —
+    # the index maps see them all; the pool maps clamp j to the page
+    # grid (the trailing window step re-fetches the last page, unused)
+    npre = 4 if window else 3
 
-    def pool_map(s, j, t, ln, *wc):
-        return (t[s, jnp.minimum(j, max_pages - 1)], 0, 0, 0)
+    def pool_map(s, j, ly, t, ln, *wc):
+        return (ly[0], t[s, jnp.minimum(j, max_pages - 1)], 0, 0, 0)
 
-    def pool_scale_map(s, j, t, ln, *wc):
+    def pool_scale_map(s, j, ly, t, ln, *wc):
         return (t[s, jnp.minimum(j, max_pages - 1)], 0, 0)
 
-    def slot_map(s, j, t, ln, *wc):
+    def slot_map(s, j, ly, t, ln, *wc):
         return (s, 0, 0)
 
-    def win_map(s, j, t, ln, *wc):
+    def win_map(s, j, ly, t, ln, *wc):
         return (s, 0, 0, 0)
 
+    # the layer dim is squeezed out of the block (None): the body sees
+    # the [1, Kv, page, H] page it always saw
     in_specs = [
         pl.BlockSpec((1, Nq, H), slot_map),
-        pl.BlockSpec((1, Kv, page, H), pool_map),
-        pl.BlockSpec((1, Kv, page, H), pool_map),
+        pl.BlockSpec((None, 1, Kv, page, H), pool_map),
+        pl.BlockSpec((None, 1, Kv, page, H), pool_map),
     ]
     args = [q, k_pages, v_pages]
     if quant:
-        # [P, C] -> [P, 1, C] (free bitcast): Mosaic requires the block's
-        # minor-two dims to tile (8, 128) or equal the array's — a (1, C)
-        # block of a [P, C] array does neither, but (1, 1, C) of
-        # [P, 1, C] matches the array exactly.
+        # The scales alone are cut to the layer here, in XLA, and not
+        # fetched from the whole pool by the index map. Mosaic requires
+        # a block's minor-two dims to tile (8, 128) or equal the
+        # array's: a (1, C) block of [.., P, C] does neither, (1, 1, C)
+        # of [.., P, 1, C] matches the array exactly. But that reshape
+        # is no bitcast on the chip (the tiled layout pads P to eights,
+        # and the TPU compiler keeps f32[L, P, C] with L, not P, second
+        # minor), so whatever enters it is copied: one layer's rows
+        # (2 MB at 7B), where the whole scale pool would be 67 MB, for
+        # keys and again for values, in every layer of every step.
+        def layer_scales(a):
+            return jax.lax.dynamic_index_in_dim(
+                a, layer, 0, keepdims=False).reshape(Pp, 1, Kv * page)
+
         in_specs += [
             pl.BlockSpec((1, 1, Kv * page), pool_scale_map),
             pl.BlockSpec((1, 1, Kv * page), pool_scale_map),
         ]
-        args += [k_scale_pages.reshape(Pp, 1, Kv * page),
-                 v_scale_pages.reshape(Pp, 1, Kv * page)]
+        args += [layer_scales(k_scale_pages), layer_scales(v_scale_pages)]
     if window:
         in_specs += [
             pl.BlockSpec((1, Kv, window, H), win_map),
@@ -315,7 +355,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      win_v_scale.reshape(S, 1, Kv * window)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=npre,
-        grid=(S, max_pages + (1 if window else 0)),
+        grid=(S, npages + (1 if window else 0)),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Nq, H), slot_map),
         scratch_shapes=[
@@ -326,7 +366,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     )
     kernel = functools.partial(_paged_kernel, page=page, kv_heads=Kv,
                                quant=quant, window=window)
-    prefetch = [page_table, lengths]
+    prefetch = [layer.reshape(1), page_table, lengths]
     if window:
         prefetch.append(win_count)
     return pl.pallas_call(

@@ -136,8 +136,8 @@ def test_interpret_mode_off_the_cpu_backend_raises(monkeypatch):
     with pytest.raises(RuntimeError, match="interpret mode"):
         ring_block_stats(q, q, q, pos, pos, interpret=True)
     with pytest.raises(RuntimeError, match="interpret mode"):
-        paged_attention(jnp.zeros((2, 4, 128)), jnp.zeros((3, 4, 16, 128)),
-                        jnp.zeros((3, 4, 16, 128)),
+        paged_attention(jnp.zeros((2, 4, 128)), jnp.zeros((1, 3, 4, 16, 128)),
+                        jnp.zeros((1, 3, 4, 16, 128)), 0,
                         jnp.zeros((2, 2), jnp.int32),
                         jnp.zeros((2,), jnp.int32), interpret=True)
 

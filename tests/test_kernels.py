@@ -4,6 +4,8 @@ flash_attention and paged_attention must match the dense XLA reference
 bit-for-nearly-bit; the serving stack with use_kernels=True must produce
 token-identical output to the gather path.
 """
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -77,7 +79,7 @@ def test_paged_attention_parity():
     table = jnp.asarray([[0, 2, 9, 9], [3, 1, 4, 9], [5, 6, 7, 8]],
                         jnp.int32)
     lengths = jnp.asarray([6, 3, 15], jnp.int32)
-    out = paged_attention(q, k_pages, v_pages, table, lengths)
+    out = paged_attention(q, k_pages[None], v_pages[None], 0, table, lengths)
 
     kk = _gather_pool(k_pages, table)
     vv = _gather_pool(v_pages, table)
@@ -104,7 +106,8 @@ def test_paged_attention_int8_parity():
     table = jnp.asarray([[0, 2, 9, 9], [3, 1, 4, 9], [5, 6, 7, 8]],
                         jnp.int32)
     lengths = jnp.asarray([6, 3, 15], jnp.int32)
-    out = paged_attention(q, kq, vq, table, lengths, ksp, vsp)
+    out = paged_attention(q, kq[None], vq[None], 0, table, lengths,
+                          ksp[None], vsp[None])
 
     # dense reference: dequantize the gathered view, plain attend
     kk = _gather_pool(kq.astype(jnp.float32) * ksc[..., None], table)
@@ -144,7 +147,7 @@ def test_paged_attention_window_segment_parity():
                         jnp.int32)
     lengths = jnp.asarray([6, 3, 9], jnp.int32)   # FLUSHED pool lengths
     counts = jnp.asarray([3, 5, 0], jnp.int32)    # staged entries/slot
-    out = paged_attention(q, k_pages, v_pages, table, lengths,
+    out = paged_attention(q, k_pages[None], v_pages[None], 0, table, lengths,
                           win_k=win_k, win_v=win_v, win_count=counts)
 
     kk = _insert_window(_gather_pool(k_pages, table), win_k, lengths,
@@ -159,10 +162,10 @@ def test_paged_attention_window_segment_parity():
 
     # garbage past win_count must not leak into the output
     poisoned = win_k.at[:, :, 4:].set(1e3)
-    out2 = paged_attention(q, k_pages, v_pages, table, lengths,
+    out2 = paged_attention(q, k_pages[None], v_pages[None], 0, table, lengths,
                            win_k=poisoned, win_v=win_v,
                            win_count=jnp.minimum(counts, 4))
-    ref2 = paged_attention(q, k_pages, v_pages, table, lengths,
+    ref2 = paged_attention(q, k_pages[None], v_pages[None], 0, table, lengths,
                            win_k=win_k, win_v=win_v,
                            win_count=jnp.minimum(counts, 4))
     np.testing.assert_array_equal(np.asarray(out2), np.asarray(ref2))
@@ -188,9 +191,9 @@ def test_paged_attention_window_segment_int8_parity():
                         jnp.int32)
     lengths = jnp.asarray([6, 3, 9], jnp.int32)
     counts = jnp.asarray([2, 4, 0], jnp.int32)
-    out = paged_attention(q, kq, vq, table, lengths,
-                          ksc.reshape(P, Kv * page),
-                          vsc.reshape(P, Kv * page),
+    out = paged_attention(q, kq[None], vq[None], 0, table, lengths,
+                          ksc.reshape(1, P, Kv * page),
+                          vsc.reshape(1, P, Kv * page),
                           win_k=wkq, win_v=wvq, win_count=counts,
                           win_k_scale=wks, win_v_scale=wvs)
 
@@ -215,7 +218,8 @@ def test_paged_attention_zero_length_slot():
     q = jax.random.normal(jax.random.PRNGKey(4), (S, Nq, H))
     kp = jax.random.normal(jax.random.PRNGKey(5), (P, Kv, page, H))
     table = jnp.zeros((S, 2), jnp.int32)
-    out = paged_attention(q, kp, kp, table, jnp.asarray([0, 4], jnp.int32))
+    out = paged_attention(q, kp[None], kp[None], 0, table,
+                          jnp.asarray([0, 4], jnp.int32))
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_array_equal(np.asarray(out[0]), 0.0)
 
@@ -310,11 +314,124 @@ def test_paged_attention_sharded_parity(mesh_dt):
     table = jnp.asarray([[0, 2, 11], [3, 1, 11], [5, 6, 7], [8, 9, 10]],
                         jnp.int32)
     lengths = jnp.asarray([6, 3, 12, 9], jnp.int32)
-    ref = paged_attention(q, kp, vp, table, lengths)
+    ref = paged_attention(q, kp[None], vp[None], 0, table, lengths)
     with jax.set_mesh(mesh_dt):
-        out = jax.jit(paged_attention_sharded)(q, kp, vp, table, lengths)
+        out = jax.jit(paged_attention_sharded)(q, kp[None], vp[None], 0,
+                                               table, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+def _layered_case(quant, window):
+    """A pool of 3 layers, every layer's contents its own, for the
+    kernel's whole-pool operand: (q, table, pool lengths, kernel pool
+    args [kp, vp, ksp, vsp], window kwargs, and a function layer ->
+    the dense float reference of THAT layer)."""
+    from butterfly_tpu.models.common import quantize_kv
+
+    L, S, Nq, Kv, H, page, P, W = 3, 4, 8, 4, 16, 4, 12, 3
+    ks = jax.random.split(jax.random.PRNGKey(31), 5)
+    q = jax.random.normal(ks[0], (S, Nq, H))
+    table = jnp.asarray([[0, 2, 11], [3, 1, 11], [5, 6, 7], [8, 9, 10]],
+                        jnp.int32)
+    lengths = jnp.asarray([6, 3, 12, 9], jnp.int32)
+    counts = jnp.asarray([2, 3, 0, 1], jnp.int32)
+    kf = jax.random.normal(ks[1], (L, P, Kv, page, H), jnp.bfloat16)
+    vf = jax.random.normal(ks[2], (L, P, Kv, page, H), jnp.bfloat16)
+    wkf = jax.random.normal(ks[3], (S, Kv, W, H), jnp.bfloat16)
+    wvf = jax.random.normal(ks[4], (S, Kv, W, H), jnp.bfloat16)
+    f32 = jnp.float32
+    if quant:
+        (kq, ksc), (vq, vsc) = quantize_kv(kf), quantize_kv(vf)
+        (wkq, wks), (wvq, wvs) = quantize_kv(wkf), quantize_kv(wvf)
+        pool = [kq, vq, ksc.reshape(L, P, Kv * page),
+                vsc.reshape(L, P, Kv * page)]
+        win = dict(win_k=wkq, win_v=wvq, win_count=counts,
+                   win_k_scale=wks, win_v_scale=wvs)
+        dense = [kq.astype(f32) * ksc[..., None],
+                 vq.astype(f32) * vsc[..., None],
+                 wkq.astype(f32) * wks[..., None],
+                 wvq.astype(f32) * wvs[..., None]]
+    else:
+        pool = [kf, vf, None, None]
+        win = dict(win_k=wkf, win_v=wvf, win_count=counts)
+        dense = [a.astype(f32) for a in (kf, vf, wkf, wvf)]
+    if not window:
+        win = {}
+
+    def ref(layer):
+        kk = _gather_pool(dense[0][layer], table)
+        vv = _gather_pool(dense[1][layer], table)
+        total = lengths
+        if window:
+            kk = _insert_window(kk, dense[2], lengths, counts)
+            vv = _insert_window(vv, dense[3], lengths, counts)
+            total = lengths + counts
+        mask = jnp.arange(kk.shape[1])[None, None, :] < total[:, None, None]
+        return attend(q[:, None], kk, vv, mask, None)[:, 0]
+
+    return q, table, lengths, pool, win, ref
+
+
+@pytest.mark.parametrize("via", ["plain", "sharded"])
+@pytest.mark.parametrize("window", [False, True], ids=["nowin", "win"])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_paged_attention_layer_of_whole_pool(layer, quant, window, via,
+                                             mesh_dt):
+    """The kernel over the WHOLE pool with the layer as a (traced)
+    prefetched scalar: the dense reference of that layer, and to the
+    bit what it gives over that layer alone as a pool of one (the
+    operand of a caller that scans the pool). Every layer holds other
+    values, so a wrong layer fails both."""
+    from butterfly_tpu.ops.paged_attention import paged_attention_sharded
+    q, table, lengths, pool, win, ref = _layered_case(quant, window)
+
+    def call(kp, vp, ly, ksp, vsp):
+        fn = paged_attention if via == "plain" else paged_attention_sharded
+        return fn(q, kp, vp, ly, table, lengths, ksp, vsp, **win)
+
+    def run(*a):
+        with contextlib.nullcontext() if via == "plain" \
+                else jax.set_mesh(mesh_dt):
+            return jax.jit(call)(*a)
+
+    out = run(pool[0], pool[1], jnp.int32(layer), pool[2], pool[3])
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref(layer)),
+                               rtol=2e-5, atol=2e-5)
+    alone = [None if a is None else a[layer][None] for a in pool]
+    out1 = run(alone[0], alone[1], jnp.int32(0), alone[2], alone[3])
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out1))
+    other = np.asarray(ref((layer + 1) % 3))
+    assert np.abs(np.asarray(out) - other).max() > 1e-2
+
+
+@pytest.mark.parametrize("window", [False, True], ids=["nowin", "win"])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_paged_attention_grid_ends_with_longest_context(quant, window):
+    """The grid's page steps are bounded by the longest live context, a
+    value, not by the table's width: the call's grid carries one dynamic
+    bound, and a table twice as wide, its new tail on pages full of huge
+    values, gives the same output to the bit."""
+    q, table, lengths, pool, win, ref = _layered_case(quant, window)
+    layer = jnp.int32(1)
+
+    def call(table, pool):
+        return paged_attention(q, pool[0], pool[1], layer, table, lengths,
+                               pool[2], pool[3], **win)
+
+    out = call(table, pool)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref(1)),
+                               rtol=2e-5, atol=2e-5)
+    # pages 12.. of a taller pool: never to be read
+    hot = [None if a is None else jnp.concatenate(
+        [a, jnp.full_like(a[:, :3], 100)], axis=1) for a in pool]
+    wide = jnp.concatenate([table, jnp.full_like(table, 13)], axis=1)
+    np.testing.assert_array_equal(np.asarray(call(wide, hot)),
+                                  np.asarray(out))
+    eqns = [e for e in jax.make_jaxpr(call)(wide, hot).jaxpr.eqns[0]
+            .params["jaxpr"].eqns if e.primitive.name == "pallas_call"]
+    assert eqns[0].params["grid_mapping"].num_dynamic_grid_bounds == 1
 
 
 def test_serving_with_kernels_token_parity():

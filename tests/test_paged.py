@@ -196,3 +196,79 @@ def test_near_capacity_prompt_bucket_padding_no_corruption():
         [prompt], SamplingParams(max_new_tokens=5))
     want = ref.tokens[0, :int(ref.lengths[0])].tolist()
     assert req.output == want
+
+
+# -- the read-only pool reaches the kernel whole (ISSUE 31) -----------------
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+@pytest.mark.parametrize("forward", ["packed", "window"])
+def test_windowed_forward_hands_the_kernel_the_whole_pool(forward, kv_quant):
+    """With the window on and kernels on, the paged kernel's call takes
+    the cache's own k_pages / v_pages, every layer of them, and nothing
+    in the program cuts one layer's [P, Kv, page, H] out of the pool: a
+    custom call's operand is a buffer, so on the chip such a slice was
+    a copy of the layer in every layer of every step. Read off the
+    jaxpr, so it holds on any backend."""
+    from butterfly_tpu.cache.paged import (
+        init_kv_window, paged_forward_packed, paged_forward_window)
+
+    rt = RuntimeConfig(max_batch_size=2, max_seq_len=64, page_size=8,
+                       kv_quant=kv_quant)
+    params = Model(CFG).init(jax.random.PRNGKey(0))
+    cache = seq_table(init_paged_cache(CFG, rt), 2, 64 // rt.page_size)
+    window = init_kv_window(cache, 8)
+    win_len = jnp.zeros((2,), jnp.int32)
+    active = jnp.ones((2,), bool)
+    tokens = jnp.asarray([3, 5], jnp.int32)
+    if forward == "packed":
+        def fn(params, cache, window):
+            return paged_forward_packed(
+                params, CFG, tokens, cache, jnp.zeros((1, 4), jnp.int32),
+                jnp.asarray([1]), jnp.asarray([0]), active, window, win_len,
+                use_kernel=True)
+    else:
+        def fn(params, cache, window):
+            return paged_forward_window(params, CFG, tokens[:, None], cache,
+                                        window, win_len, active,
+                                        use_kernel=True)
+    eqns = list(_eqns(jax.make_jaxpr(fn)(params, cache, window).jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert calls
+    pool = cache.k_pages.shape
+    for e in calls:
+        shapes = [v.aval.shape for v in e.invars]
+        assert shapes.count(pool) == 2, shapes
+    cut = [e for e in eqns if e.primitive.name in ("dynamic_slice", "gather")
+           and e.outvars[0].aval.shape[-4:] == pool[1:]]
+    assert not cut, cut
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+def test_gather_indexes_layer_and_page_at_once(quant):
+    """gather_paged_layer(_q) over the whole pool with a traced layer is
+    the gather over that layer's slice, to the bit."""
+    from butterfly_tpu.cache.paged import gather_paged_layer_q
+    L, P, Kv, page, H = 3, 7, 2, 4, 8
+    ks = jax.random.split(jax.random.PRNGKey(5), 2)
+    pool = jax.random.normal(ks[0], (L, P, Kv, page, H))
+    scales = jax.random.uniform(ks[1], (L, P, Kv * page))
+    table = jnp.asarray([[0, 3, 6], [5, 1, 6]], jnp.int32)
+    for layer in range(L):
+        ly = jnp.int32(layer)
+        if quant:
+            codes = (pool * 20).astype(jnp.int8)
+            got = jax.jit(gather_paged_layer_q)(codes, scales, table, ly)
+            want = gather_paged_layer_q(codes[layer], scales[layer], table)
+        else:
+            got = (jax.jit(gather_paged_layer)(pool, table, ly),)
+            want = (gather_paged_layer(pool[layer], table),)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

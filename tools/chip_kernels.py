@@ -122,24 +122,28 @@ def build_cases(small: bool, windows):
         cases.append((name, flash, flash_ref, args, flash_sh))
 
     # -- paged ---------------------------------------------------------
+    # the pool whole, three layers of it, and the middle one attended:
+    # a kernel that reads another layer's pages disagrees with its
+    # reference
+    L, ly = 3, jnp.int32(1)
     qd = normal(S, Nq, H)
-    kp, vp = normal(P, Kv, page, H), normal(P, Kv, page, H)
+    kp, vp = normal(L, P, Kv, page, H), normal(L, P, Kv, page, H)
     table = jnp.asarray(rng.randint(0, P - 1, (S, max_pages)), jnp.int32)
     lens = jnp.asarray(rng.randint(0, max_pages * page + 1, (S,)), jnp.int32)
     lens = lens.at[0].set(0).at[1].set(max_pages * page)
-    kq, ks = quantize_kv(jnp.moveaxis(kp, 1, 2))   # [P,page,Kv,H] in
-    vq, vs = quantize_kv(jnp.moveaxis(vp, 1, 2))
-    kpq, vpq = jnp.moveaxis(kq, 1, 2), jnp.moveaxis(vq, 1, 2)  # [P,Kv,pg,H]
-    ksp = jnp.moveaxis(ks, 1, 2).reshape(P, Kv * page)         # kv-major
-    vsp = jnp.moveaxis(vs, 1, 2).reshape(P, Kv * page)
+    kq, ks = quantize_kv(jnp.moveaxis(kp, 2, 3))   # [L,P,page,Kv,H] in
+    vq, vs = quantize_kv(jnp.moveaxis(vp, 2, 3))
+    kpq, vpq = jnp.moveaxis(kq, 2, 3), jnp.moveaxis(vq, 2, 3)  # [L,P,Kv,pg,H]
+    ksp = jnp.moveaxis(ks, 2, 3).reshape(L, P, Kv * page)      # kv-major
+    vsp = jnp.moveaxis(vs, 2, 3).reshape(L, P, Kv * page)
     smax = max_pages * page
 
-    def paged(qd, kp, vp, table, lens, ksp=None, vsp=None, wk=None,
+    def paged(qd, kp, vp, ly, table, lens, ksp=None, vsp=None, wk=None,
               wv=None, wcnt=None, wks=None, wvs=None, fn=paged_attention):
-        return fn(qd, kp, vp, table, lens, ksp, vsp, win_k=wk, win_v=wv,
+        return fn(qd, kp, vp, ly, table, lens, ksp, vsp, win_k=wk, win_v=wv,
                   win_count=wcnt, win_k_scale=wks, win_v_scale=wvs)
 
-    def paged_ref(qd, kp, vp, table, lens, ksp=None, vsp=None, wk=None,
+    def paged_ref(qd, kp, vp, ly, table, lens, ksp=None, vsp=None, wk=None,
                   wv=None, wcnt=None, wks=None, wvs=None):
         """Dense gather + attend (cache/paged.py's dense path), the
         window appended as one more key segment; a slot with no keys
@@ -150,14 +154,14 @@ def build_cases(small: bool, windows):
                 [mask, (jnp.arange(wk.shape[2])[None]
                         < wcnt[:, None])[:, None]], 2)
         if ksp is None:
-            ck, cv = (f32(gather_paged_layer(a, table)) for a in (kp, vp))
+            ck, cv = (f32(gather_paged_layer(a, table, ly)) for a in (kp, vp))
             if wk is not None:  # window [S,Kv,W,H] -> [S,W,Kv,H]
                 ck, cv = (jnp.concatenate([a, f32(jnp.moveaxis(w, 1, 2))], 1)
                           for a, w in ((ck, wk), (cv, wv)))
             out = attend(f32(qd)[:, None], ck, cv, mask, None)
         else:
-            ck, k_s = gather_paged_layer_q(kp, ksp, table)
-            cv, v_s = gather_paged_layer_q(vp, vsp, table)
+            ck, k_s = gather_paged_layer_q(kp, ksp, table, ly)
+            cv, v_s = gather_paged_layer_q(vp, vsp, table, ly)
             if wk is not None:
                 ck, cv, k_s, v_s = (
                     jnp.concatenate([a, w], 2) for a, w in
@@ -166,8 +170,8 @@ def build_cases(small: bool, windows):
         return jnp.where(mask.any(-1)[:, :, None, None], out, 0)[:, 0]
 
     paged_sh = functools.partial(paged, fn=paged_attention_sharded)
-    paged_args = [("paged", (qd, kp, vp, table, lens)),
-                  ("paged_int8", (qd, kpq, vpq, table, lens, ksp, vsp))]
+    paged_args = [("paged", (qd, kp, vp, ly, table, lens)),
+                  ("paged_int8", (qd, kpq, vpq, ly, table, lens, ksp, vsp))]
     for W in windows:
         wk, wv = normal(S, Kv, W, H), normal(S, Kv, W, H)
         wkq, wks = kv_major_q(jnp.moveaxis(wk, 1, 2))
@@ -175,9 +179,9 @@ def build_cases(small: bool, windows):
         wcnt = jnp.asarray(rng.randint(0, W + 1, (S,)), jnp.int32)
         wcnt = wcnt.at[0].set(1).at[1].set(W)
         paged_args += [
-            (f"paged_win{W}", (qd, kp, vp, table, lens, None, None,
+            (f"paged_win{W}", (qd, kp, vp, ly, table, lens, None, None,
                                wk, wv, wcnt)),
-            (f"paged_int8_win{W}", (qd, kpq, vpq, table, lens, ksp, vsp,
+            (f"paged_int8_win{W}", (qd, kpq, vpq, ly, table, lens, ksp, vsp,
                                     wkq, wvq, wcnt, wks, wvs))]
     for name, args in paged_args:
         cases.append((name, paged, paged_ref, args, paged_sh))
@@ -259,9 +263,9 @@ def tp_place(mesh, name, args):
         pre = P(None, t, None, None) if quant else P(None, None, t, None)
         specs = [P(None, None, t, None)] * 3 + [pre, pre, P()] \
             + [P(None, t, None)] * 2
-    else:  # q, pools, table, lens, pool scales, window, count, its scales
-        specs = [P(None, t, None)] + [P(None, t, None, None)] * 2 \
-            + [P(), P()] + [P(None, t)] * 2 \
+    else:  # q, pools, layer, table, lens, pool scales, window, count, scales
+        specs = [P(None, t, None)] + [P(None, None, t, None, None)] * 2 \
+            + [P(), P(), P()] + [P(None, None, t)] * 2 \
             + [P(None, t, None, None)] * 2 + [P()] + [P(None, t, None)] * 2
     return tuple(None if a is None
                  else jax.device_put(a, NamedSharding(mesh, s))

@@ -127,6 +127,12 @@ MUTANTS = [
      "s = s * ks_ref[0]",
      "s = s * 1.0",
      ["tests/test_kernels.py"], {}),
+    # paged decode kernel: every layer attends layer 0's pages of the
+    # whole pool it is handed
+    ("butterfly_tpu/ops/paged_attention.py",
+     "return (ly[0], t[s, jnp.minimum(j, max_pages - 1)], 0, 0, 0)",
+     "return (ly[0] * 0, t[s, jnp.minimum(j, max_pages - 1)], 0, 0, 0)",
+     ["tests/test_kernels.py"], {}),
     # contiguous int8 attend: V scale not folded into the probs
     ("butterfly_tpu/models/common.py",
      "probs = probs * v_scale[:, :, None, None, :]",
