@@ -5,7 +5,13 @@ the larger of bytes over the published memory bandwidth and operations
 over the published bf16 peak (servebench/peaks.py): each of the block's
 steps streams the weights its live streams touch once and the keys and
 values of the live context, which is read from the clients' timelines
-at the middle of the trace."""
+at the middle of the trace.
+
+`serve.decode_steps_per_tick` is what the least time of ONE forward of
+`decode_width` positions is multiplied by, so for a model that
+generates by blocks it counts FORWARDS in one block program, not tokens
+and not blocks: S denoising forwards that write no keys and values and
+the one forward that commits them count S + 1 for each block generated."""
 import statistics
 
 from servebench.metrics import live_context

@@ -13,7 +13,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 from servebench import peaks  # noqa: E402
 from servebench.knee import find_knee, sustained  # noqa: E402
 from servebench.launcher import model_fields, serve_argv  # noqa: E402
-from servebench.manifest import Cell, load_manifest  # noqa: E402
+from servebench.manifest import Cell, find_under_paths, load_manifest  # noqa: E402
 from servebench.metrics import END_TO_END  # noqa: E402
 from servebench.traffic import load_traffic, make_plan  # noqa: E402
 
@@ -76,54 +76,170 @@ def test_every_per_layer_metric_has_a_reader_and_a_layer():
         assert m["moves"] in {e["name"] for e in MANIFEST["end_to_end"]}
 
 
-#: (hidden, layers, heads, KV heads, head size, feed-forward or expert
-#: width, vocabulary), rope_theta, arch, (experts, experts per token),
-#: slots: the first two rows are Mistral-7B-v0.3's published sizes
-PUBLISHED = {
-    "mistral-7b-v0.3": ((4096, 32, 32, 8, 128, 14336, 32768), 1e6, "llama",
-                        None, 32),
-    "mistral-7b-v0.3-bf16-tp4": ((4096, 32, 32, 8, 128, 14336, 32768), 1e6,
-                                 "llama", None, 32),
-    "tiny-llama": ((64, 2, 4, 2, 16, 128, 512), 1e4, "llama", None, 4),
-    "tiny-moe": ((64, 2, 4, 2, 16, 96, 512), 1e4, "mixtral", (4, 2), 4),
-}
+#: keys of the source a pin must state under `published`, whatever
+#: else it pins: the sizes, `rope_theta` and the norm's epsilon
+PIN_PUBLISHED = {"hidden_size", "num_hidden_layers", "num_attention_heads",
+                 "num_key_value_heads", "head_dim", "intermediate_size",
+                 "vocab_size", "rms_norm_eps", "rope_theta"}
 
 
-@pytest.mark.parametrize("cfg", TOY["configs"], ids=lambda c: c["name"])
-def test_configuration_builds_the_published_model(cfg):
+def pin_of(manifest, name):
+    """`<path>/pins/<name>.json` under one of the manifest's `paths`:
+    what the configuration is held to, written by hand from the source
+    by the PR that brings the configuration (PERF.md, section 7, says
+    what each key holds). A configuration without one fails, by name."""
+    try:
+        path = find_under_paths(manifest, ROOT, "pins", name + ".json")
+    except FileNotFoundError as e:
+        pytest.fail(f"configuration {name!r} of BENCHMARK.json has no pin "
+                    f"pins/{name}.json: {e}")
+    return json.loads(path.read_text())
+
+
+def held_to_its_pin(cfg, manifest):
+    """One entry of `configs` against its file and its pin."""
     config = json.loads((ROOT / cfg["file"]).read_text())
-    assert config["name"] == cfg["name"]
-    assert cfg["reduced"] == config["reduced"] == []
-    sizes, theta, arch, experts, slots = PUBLISHED[cfg["name"]]
-    f = model_fields(config)
-    assert (f["hidden_size"], f["num_layers"], f["num_heads"], f["num_kv_heads"],
-            f["head_dim"], f["intermediate_size"], f["vocab_size"]) == sizes
-    assert f["rope_theta"] == theta and f["norm_eps"] == 1e-5 and f["arch"] == arch
-    if experts:
-        assert (f["num_experts"], f["num_experts_per_tok"]) == experts
-    else:
-        assert "num_experts" not in f
+    pin = pin_of(manifest, cfg["name"])
+    assert config["name"] == cfg["name"] == pin["name"]
+    assert config["source"] == pin["source"]
     if cfg in MANIFEST["configs"]:
         assert config["source"] == cfg["source"]
-        # the two Mistral files carry no "model" group: field for field
-        # what they built before there was one
-        assert "model" not in config and f == {
-            "vocab_size": 32768, "hidden_size": 4096, "num_layers": 32,
-            "num_heads": 32, "num_kv_heads": 8, "head_dim": 128,
-            "intermediate_size": 14336, "max_seq_len": 32768,
-            "norm_eps": 1e-5, "rope_theta": 1e6, "tie_embeddings": False,
-            "act": "silu", "arch": "llama", "dtype": "bfloat16"}
+    # `reduced` is compared, not forbidden: the entry's list is the
+    # file's, the pin holds a value for each of its keys and for no
+    # other, the file states the published value beside the one it holds
+    # and the two differ; every other key is the source's
+    published, held = pin["published"], pin["held"]
+    assert PIN_PUBLISHED <= set(published), cfg["name"]
+    assert cfg["reduced"] == config["reduced"], \
+        f"{cfg['name']}: the entry's `reduced` is not the file's"
+    assert len(set(config["reduced"])) == len(config["reduced"])
+    assert set(config["reduced"]) == set(held) == set(config.get("published", {})), \
+        f"{cfg['name']}: `reduced`, the pin's `held` and the file's " \
+        "`published` group do not name the same keys"
+    for key, value in published.items():
+        if key in held:
+            assert config["published"][key] == value, (cfg["name"], key)
+            assert config[key] == held[key] != value, \
+                f"{cfg['name']}: {key!r} is listed in `reduced` and not cut"
+        else:
+            assert config[key] == value, \
+                f"{cfg['name']}: {key!r} differs from the source and is not in `reduced`"
+    # the program's ModelConfig is built with the pin's `fields`, the
+    # whole dict and no more: the family, the epsilon, the types too
+    f = model_fields(config)
+    assert f == pin["fields"], cfg["name"]
+    assert ("model" in config) == pin["model_group"]
+    experts = "num_experts" in pin["fields"]
+    assert experts == ("num_experts_per_tok" in pin["fields"]) == ("num_experts" in f)
     from butterfly_tpu.core.config import ModelConfig
-    assert ModelConfig(**f).is_moe == bool(experts)
+    assert ModelConfig(**f).is_moe == experts
     argv = serve_argv(config, 1234)
     assert argv[:3] == ["serve", "--model", cfg["name"]]
     assert "--max-batch" in argv and "--decode-steps-per-tick" in argv
     from butterfly_tpu.serve.cli import build_parser
     args = build_parser().parse_args(argv)
-    assert args.max_batch == slots and args.max_seq in (128, 2048)
+    assert (args.max_batch, args.max_seq) == (pin["slots"], pin["max_seq"])
     assert args.max_queue == config["serve"].get("max_queue", 256)
     assert any((ROOT / d / "references" / (config["reference"] + ".py")).exists()
-               for d in TOY["paths"])
+               for d in manifest["paths"])
+
+
+@pytest.mark.parametrize("cfg", TOY["configs"], ids=lambda c: c["name"])
+def test_configuration_builds_the_published_model(cfg):
+    held_to_its_pin(cfg, TOY)
+
+
+def pin_files():
+    """Every pin under the manifest's paths and the toys', as paths
+    relative to the checkout."""
+    return sorted(str(p.relative_to(ROOT)) for d in TOY["paths"]
+                  for p in (ROOT / d / "pins").glob("*.json"))
+
+
+@pytest.mark.parametrize("pin", pin_files())
+def test_every_pin_is_some_configurations(pin):
+    """A pin lies beside the configuration it holds, under the same
+    path and the same name, and that configuration is one of the
+    manifest's or a toy: a pin of nothing would be held to nothing."""
+    path = ROOT / pin
+    name = json.loads(path.read_text())["name"]
+    assert name == path.stem
+    file = path.parent.parent / "configs" / path.name
+    assert [c["name"] for c in TOY["configs"]
+            if ROOT / c["file"] == file] == [name]
+
+
+def cut_file(tmp_path, config, pin):
+    """A throw-away configuration and its pin under a path of their
+    own: the manifest entry that names them, and the manifest."""
+    files = tmp_path / "cut"
+    for sub, body in (("configs", config), ("pins", pin)):
+        (files / sub).mkdir(parents=True, exist_ok=True)
+        (files / sub / (config["name"] + ".json")).write_text(json.dumps(body))
+    entry = {"name": config["name"], "source": "tests only",
+             "reduced": list(config["reduced"]),
+             "file": str(files / "configs" / (config["name"] + ".json"))}
+    return entry, dict(TOY, paths=TOY["paths"] + [str(files)])
+
+
+def cut_toy():
+    """`tiny-moe` cut from a source of 6 layers to the 2 it has, with
+    its pin."""
+    config = json.loads((ROOT / FILES / "configs" / "tiny-moe.json").read_text())
+    pin = json.loads((ROOT / FILES / "pins" / "tiny-moe.json").read_text())
+    config.update(name="tiny-cut", reduced=["num_hidden_layers"],
+                  published={"num_hidden_layers": 6})
+    pin.update(name="tiny-cut", held={"num_hidden_layers": 2},
+               published=dict(pin["published"], num_hidden_layers=6))
+    return config, pin
+
+
+def test_a_cut_depth_passes_when_file_pin_and_entry_agree(tmp_path):
+    held_to_its_pin(*cut_file(tmp_path, *cut_toy()))
+
+
+def not_cut(config, pin, entry):
+    config["num_hidden_layers"] = 6
+
+
+def not_listed(config, pin, entry):
+    config.update(reduced=[], published={})
+    pin["held"] = {}
+
+
+def no_published_value(config, pin, entry):
+    del config["published"]
+
+
+def entry_lists_nothing(config, pin, entry):
+    entry["reduced"] = []
+
+
+def another_width(config, pin, entry):
+    config["hidden_size"] = 32
+
+
+@pytest.mark.parametrize("fault, says", [
+    (not_cut, "'num_hidden_layers' is listed in `reduced` and not cut"),
+    (not_listed, "'num_hidden_layers' differs from the source and is not in `reduced`"),
+    (no_published_value, "`reduced`, the pin's `held` and the file's `published` group do"),
+    (entry_lists_nothing, "the entry's `reduced` is not the file's"),
+    (another_width, "'hidden_size' differs from the source"),
+], ids=lambda v: v.__name__ if callable(v) else "")
+def test_a_cut_that_the_lists_do_not_bear_out_fails(tmp_path, fault, says):
+    config, pin = cut_toy()
+    over = {}
+    fault(config, pin, over)
+    entry, manifest = cut_file(tmp_path, config, pin)
+    with pytest.raises(AssertionError, match="tiny-cut: " + says):
+        held_to_its_pin(dict(entry, **over), manifest)
+
+
+def test_a_configuration_without_a_pin_fails_by_name(tmp_path):
+    entry, manifest = cut_file(tmp_path, *cut_toy())
+    (tmp_path / "cut" / "pins" / "tiny-cut.json").unlink()
+    with pytest.raises(pytest.fail.Exception, match="'tiny-cut'.*has no pin"):
+        held_to_its_pin(entry, manifest)
 
 
 def moe_file(**model):

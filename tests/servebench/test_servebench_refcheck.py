@@ -23,7 +23,12 @@ from servebench.manifest import decode_width, load_manifest  # noqa: E402
 
 FILES = "tests/servebench/files"
 PATHS = ["servebench", FILES]
-MISTRAL = [c["file"] for c in load_manifest(ROOT)["configs"]]
+#: the two files whose check must stay the parent's, by name: a later
+#: configuration of the manifest is held to `test_every_configuration_
+#: of_the_manifest_can_be_checked`, not to Mistral's width
+MISTRAL = ["servebench/configs/mistral-7b-v0.3.json",
+           "servebench/configs/mistral-7b-v0.3-bf16-tp4.json"]
+MANIFEST = load_manifest(ROOT)
 SEEDS = [2 ** 31 + 5, 7, 11]
 
 
@@ -176,6 +181,7 @@ def parent_program_rows(b, toks):
 
 @pytest.mark.parametrize("file", MISTRAL)
 def test_mistral_files_are_sampled_as_the_parent_did(file):
+    assert file in [c["file"] for c in MANIFEST["configs"]]
     config = json.loads((ROOT / file).read_text())
     assert "decode_width" not in config and decode_width(config) == 1
     assert refcheck.lengths(1) == (12, 4)
@@ -183,6 +189,22 @@ def test_mistral_files_are_sampled_as_the_parent_did(file):
         assert refcheck.sample(seed, config["vocab_size"]) == \
             refcheck.sample(seed, config["vocab_size"], decode_width(config)) == \
             parent_sample(seed, config["vocab_size"])
+
+
+@pytest.mark.parametrize("cfg", MANIFEST["configs"], ids=lambda c: c["name"])
+def test_every_configuration_of_the_manifest_can_be_checked(cfg):
+    """Whatever its family and width: the width is a whole number of 1
+    or more, a prefill and two calls of it fit the check's cache, and
+    the reference it names lies under one of the manifest's `paths`."""
+    config = json.loads((ROOT / cfg["file"]).read_text())
+    w = decode_width(config)            # raises unless a whole number, 1 or more
+    prefill, decode = refcheck.lengths(w)       # raises unless they fit
+    assert prefill % w == 0 and decode >= 2 * w
+    assert prefill + decode <= refcheck.CACHE
+    assert len(refcheck.sample(2 ** 31 + 5, config["vocab_size"], w)[0]) == \
+        prefill + decode
+    assert callable(refcheck.load_reference(config["reference"]).logits)
+    assert float(config.get("reference_tolerance", refcheck.TOLERANCE)) > 0
 
 
 def test_a_file_without_the_key_is_driven_as_the_parent_did():

@@ -30,7 +30,7 @@ def test_burst_is_the_same_work_under_every_seed():
     a, b = (plan("batch", s) for s in SEEDS)
     ra = [q[0] for q in a.queues]
     rb = [q[0] for q in b.queues]
-    assert len(ra) == len(rb) == 1024 and all(len(q) == 1 for q in a.queues)
+    assert len(ra) == len(rb) == 4096 and all(len(q) == 1 for q in a.queues)
     # the server takes requests in the order sent, so the order is part of
     # the work: the file fixes it, and the seed changes token ids alone
     assert [(len(r.tokens), r.max_tokens) for r in ra] == \
@@ -38,10 +38,10 @@ def test_burst_is_the_same_work_under_every_seed():
     assert all(x.tokens != y.tokens for x, y in zip(ra[:32], rb[:32]))
     # every round of 32 is the same multiset, and any stretch of eight
     # answers asks for about the same number of tokens
-    rounds = [pairs(ra[i:i + 32]) for i in range(0, 1024, 32)]
+    rounds = [pairs(ra[i:i + 32]) for i in range(0, 4096, 32)]
     assert all(r == rounds[0] for r in rounds)
     outs = [r.max_tokens for r in ra]
-    sums = [sum(outs[i:i + 8]) for i in range(0, 1016)]
+    sums = [sum(outs[i:i + 8]) for i in range(0, 4088)]
     assert max(sums) / min(sums) < 1.08
     assert min(outs) >= 128 and max(outs) <= 256
     assert 185 <= sorted(outs[:32])[16] <= 199
@@ -55,11 +55,35 @@ def test_burst_is_the_same_work_under_every_seed():
     for c in load_manifest(ROOT)["configs"]:
         serve = json.loads((ROOT / c["file"]).read_text())["serve"]
         assert serve.get("max_queue", 256) >= a.keep_unstarted
-    # the batch outlasts lead-in and window on a program eight times as
-    # fast as four chips are today (329 tokens/s and 25 s of lead-in,
-    # ledger and my chip run, PR 25: then 8 x 329 for 25 / 8 s and the
-    # 45 s window) by half again
-    assert sum(outs) == 196_608 > 1.5 * 8 * 329 * (25 / 8 + 45)
+    # the batch outlasts lead-in and window on a program nine times as
+    # fast as four chips are today (1,553.7 tokens/s and about 25 s of
+    # lead-in at the most, ledger, PR 31: then 9 x 1,553.7 for 25 / 9 s
+    # and the 45 s window with the tenth of it that an edge may wait).
+    # The 1,024 requests it held until PR 32 (196,608 tokens) lasted a
+    # program up to two and a half times as fast and no further
+    assert sum(outs) == 786_432 > 1.05 * 9 * 1553.7 * (25 / 9 + 49.5)
+    assert sum(outs[:1024]) == 196_608 < 2.5 * 1553.7 * (25 / 2.5 + 49.5)
+
+
+@pytest.mark.parametrize("seed", [1, 41, 2 ** 31 + 12345])
+def test_the_first_1024_requests_are_the_parents_whole_batch(seed):
+    """PR 32 made `rounds` 128 where it was 32, and nothing else that
+    the generator reads: the rounds are equal multisets and the token
+    ids come from one generator in the order sent, so what a server is
+    sent before the window closes (449 requests on one chip, 642 on four,
+    PERF.md) is what the parent's file sent it, to the byte."""
+    traffic = load_traffic(TRAFFIC / "batch.json")
+    assert (traffic["per_round"], traffic["rounds"]) == (32, 128)
+    now = make_plan(traffic, seed, 45.0, 32768, 2048)
+    was = make_plan(dict(traffic, rounds=32), seed, 45.0, 32768, 2048)
+    assert len(was.queues) == 1024 and len(now.queues) == 4096
+    assert now.queues[:1024] == was.queues
+    first = [q[0] for q in now.queues[:1024]]
+    assert [r.rid for r in first] == [
+        f"s{seed}-b{j}-{i}" for j in range(32) for i in range(32)]
+    assert (now.keep_unstarted, now.refill_below) == (256, 64)
+    assert {f: getattr(now, f) for f in vars(now) if f != "queues"} == \
+        {f: getattr(was, f) for f in vars(was) if f != "queues"}
 
 
 def test_a_burst_file_without_a_depth_sends_its_whole_batch():
