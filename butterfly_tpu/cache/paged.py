@@ -56,8 +56,9 @@ from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
 # ops.flash_attention, none of which import this module; the ops kernel
 # wrappers import nothing project-local at module level.
 from butterfly_tpu.models.common import (
-    _cast_float, attend, attn_output, embed_tokens, ffn_block,
-    final_logits, make_mask, pre_norm, qkv_proj, quantize_kv)
+    _cast_float, attend, attn_output, early_router_logits, embed_tokens,
+    expert_load, ffn_block, final_logits, layer_mask, layer_pattern_of,
+    layer_stack, make_mask, pre_norm, qkv_proj, quantize_kv, router_logits)
 from butterfly_tpu.ops import note_kernel
 from butterfly_tpu.ops.flash_attention import flash_attention_sharded
 from butterfly_tpu.ops.paged_attention import paged_attention_sharded
@@ -531,7 +532,8 @@ def _settled(*view):
 
 def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
                  positions, mask, active, use_kernel: bool, fresh: bool,
-                 ksp=None, vsp=None, win=None, force_dense: bool = False):
+                 ksp=None, vsp=None, win=None, force_dense: bool = False,
+                 sliding_window=None):
     """One layer's attention for [B,T] queries whose K/V is already
     written: the dispatch between the paged kernel (T == 1), the flash
     kernels (fresh chunk; warm chunk over the cached prefix) and the
@@ -546,6 +548,10 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
     slice is a copy of the layer. page_table: the B rows' own table rows
     [B, max_pages]; win: (wk, wv, wks, wvs, win_len) AFTER staging,
     window slices [B, Kv, W, H] and the staged count BEFORE it.
+    sliding_window: the layer's, out of its pattern (a traced scalar, 0
+    = a full layer; None = the model has none): every branch attends
+    position j from p only where p - j < sliding_window, the kernels by
+    their prefetched scalar, the dense gather by its mask.
     Returns [B,T,Nq,H]."""
     T = q.shape[1]
     quant = ksp is not None
@@ -566,18 +572,21 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
                                           page_table, lens, ksp, vsp,
                                           win_k=wk, win_v=wv,
                                           win_count=wcnt,
-                                          win_k_scale=wks, win_v_scale=wvs)
+                                          win_k_scale=wks, win_v_scale=wvs,
+                                          sliding_window=sliding_window)
         else:
             # lengths INCLUDING the token just written (inactive: 0 ->
             # no pages visited, output discarded)
             lens = jnp.where(active, positions[:, 0] + 1, 0)
             out = paged_attention_sharded(q[:, 0], kp, vp, layer,
-                                          page_table, lens, ksp, vsp)
+                                          page_table, lens, ksp, vsp,
+                                          sliding_window=sliding_window)
         out = out[:, None] if out is not None else None
     elif cfg.attn_impl == "flash" and T > 1 and fresh:
         # fresh prefill attends over the just-projected bf16 K/V, so the
         # kernel path is identical for int8 pools
-        out = flash_attention_sharded(q, k, v, causal=True)
+        out = flash_attention_sharded(q, k, v, causal=True,
+                                      sliding_window=sliding_window)
     elif cfg.attn_impl == "flash" and T > 1 and win is None \
             and not force_dense:
         # warm chunked prefill (ISSUE 13): the kernel attends the
@@ -602,13 +611,15 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
             vf = (vq.astype(jnp.float32) * vsc[..., None]).astype(v.dtype)
             out = flash_attention_sharded(
                 q, kf, vf, causal=True, prefix_k=ckg, prefix_v=cvg,
-                prefix_len=base, prefix_k_scale=k_sg, prefix_v_scale=v_sg)
+                prefix_len=base, prefix_k_scale=k_sg, prefix_v_scale=v_sg,
+                sliding_window=sliding_window)
         else:
             ckg = gather_paged_layer(kp, page_table, layer)
             cvg = gather_paged_layer(vp, page_table, layer)
             out = flash_attention_sharded(q, k, v, causal=True,
                                           prefix_k=ckg, prefix_v=cvg,
-                                          prefix_len=base)
+                                          prefix_len=base,
+                                          sliding_window=sliding_window)
     else:
         tried_kernel = False
     if out is None:
@@ -618,6 +629,7 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
         # mesh that should shard it this is a fault, not a choice.
         if tried_kernel:
             note_kernel("dense_fallback")
+        mask = layer_mask(mask, positions, sliding_window)
         if quant:
             ck, k_s = gather_paged_layer_q(kp, ksp, page_table, layer)
             cv, v_s = gather_paged_layer_q(vp, vsp, page_table, layer)
@@ -637,21 +649,35 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
 
 def _layer_open(x, lp, cfg: ModelConfig, cos, sin):
     """A layer up to its attention: the weights in the compute dtype,
-    the pre-norm and the rotated projections. Returns (lp, q, k, v).
-    With _layer_close, the part of a layer that paged_layer_body and
-    the packed step (packed_layer) share, so that a change to a norm or
-    a projection reaches both."""
+    the pre-norm, the router's logits where the router stands before
+    attention, and the projections, rotated where the layer rotates.
+    Returns (lp, q, k, v, route, sliding_window): `route` (None for
+    most models) is carried across attention to _layer_close, the
+    layer's sliding window (None for a model without a pattern) goes to
+    paged_attend. With _layer_close, the part of a layer that
+    paged_layer_body and the packed step (packed_layer) share, so that
+    a change to a norm or a projection reaches both."""
     lp = jax.tree.map(lambda a: _cast_float(a, jnp.dtype(cfg.dtype)), lp)
-    q, k, v = qkv_proj(pre_norm(x, lp["ln1"], cfg), lp["attn"], cfg,
-                       cos, sin)
-    return lp, q, k, v
+    h = pre_norm(x, lp["ln1"], cfg)
+    rope, sliding_window = layer_pattern_of(lp.get("pattern"))
+    q, k, v = qkv_proj(h, lp["attn"], cfg, cos, sin, rope)
+    return lp, q, k, v, early_router_logits(x, lp, cfg), sliding_window
 
 
-def _layer_close(x, out, lp, cfg: ModelConfig):
+def _layer_close(x, out, lp, cfg: ModelConfig, route=None, ok=None):
     """A layer from its attention's output on: the output projection
-    and the feed-forward, each with its residual."""
+    and the feed-forward, each with its residual. route: _layer_open's.
+    Returns (x, load): with `ok` [B,T], the rows that are real, and a
+    model of experts, `load` is what the layer's routing asked of them
+    (models.common.expert_load), else None."""
     x = x + attn_output(out, lp["attn"], cfg)
-    return x + ffn_block(pre_norm(x, lp["ln2"], cfg), lp, cfg)
+    h = pre_norm(x, lp["ln2"], cfg)
+    load = None
+    if ok is not None and cfg.is_moe:
+        if route is None:
+            route = router_logits(h, lp["moe"]["router"])
+        load = expert_load(route, cfg.num_experts_per_tok, ok)
+    return x + ffn_block(h, lp, cfg, route), load
 
 
 def _as_pool(pools):
@@ -687,7 +713,7 @@ def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
     (x, wk, wv[, wks, wvs]) — the pool rides outside the scan unchanged.
     """
     quant = ksp is not None
-    lp, q, k, v = _layer_open(x, lp, cfg, cos, sin)
+    lp, q, k, v, route, sliding_window = _layer_open(x, lp, cfg, cos, sin)
     if win is not None:
         wk, wv, wks, wvs, win_len = win
         wk, wv, wks, wvs = stage_window_layer(wk, wv, k, v, win_len,
@@ -703,8 +729,8 @@ def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
         positions=positions, mask=mask, active=active,
         use_kernel=use_kernel, fresh=fresh, ksp=pool[2], vsp=pool[3],
         win=None if win is None else (wk, wv, wks, wvs, win_len),
-        force_dense=force_dense)
-    x = _layer_close(x, out, lp, cfg)
+        force_dense=force_dense, sliding_window=sliding_window)
+    x, _ = _layer_close(x, out, lp, cfg, route)
     if win is not None:
         if quant:
             return x, wk, wv, wks, wvs
@@ -771,7 +797,7 @@ def paged_forward(params, cfg: ModelConfig, tokens: jax.Array,
             force_dense=attn_mask is not None)
         return out[0], tuple(out[1:])
 
-    xs = (params["layers"], cache.k_pages, cache.v_pages)
+    xs = (layer_stack(params["layers"], cfg), cache.k_pages, cache.v_pages)
     if quant:
         xs = xs + (cache.k_scale_pages, cache.v_scale_pages)
     x, new_pools = lax.scan(body, x, xs)
@@ -844,7 +870,7 @@ def paged_forward_window(params, cfg: ModelConfig, tokens: jax.Array,
             win=(wk, wv, wks, wvs, win_len), layer=i)
         return (out[0], i + 1), tuple(out[1:])
 
-    xs = (params["layers"], window.k, window.v)
+    xs = (layer_stack(params["layers"], cfg), window.k, window.v)
     if quant:
         xs = xs + (window.k_scale, window.v_scale)
     (x, _), new_win = lax.scan(body, (x, 0), xs)
@@ -930,9 +956,12 @@ def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
     is paged_attend twice: the S decode rows as its T == 1 case (the
     paged kernel, with the window segment), each chunk as its T == C
     case over its OWN slot's table row and window slice. Returns
-    (x, pools, wl) as written."""
+    (x, pools, wl, load): pools and window as written, and what the
+    layer's routing asked of its experts for the step's real rows
+    (_layer_close; None for a dense model)."""
     S, (P, C) = rows.written.shape[0], rows.chunk_pos.shape
-    lp, q, k, v = _layer_open(x, lp, cfg, rows.cos, rows.sin)
+    lp, q, k, v, route, sliding_window = _layer_open(x, lp, cfg, rows.cos,
+                                                     rows.sin)
     dec_win = chunk_win = None
     if wl is not None:
         wl = stage_window_layer(wl[0], wl[1], k, v, rows.widx, wl[2], wl[3],
@@ -947,7 +976,7 @@ def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
         else _as_pool(pools)
     attend_rows = partial(paged_attend, kp=kp, vp=vp, layer=layer, cfg=cfg,
                           use_kernel=use_kernel, fresh=False,
-                          ksp=ksp, vsp=vsp)
+                          ksp=ksp, vsp=vsp, sliding_window=sliding_window)
     out = attend_rows(q[:S], k[:S], v[:S], page_table=rows.page_table,
                       positions=rows.written[:, None], mask=rows.dec_mask,
                       active=rows.active, win=dec_win)
@@ -962,7 +991,8 @@ def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
                             active=rows.chunk_ok, win=chunk_win)
         out = jnp.concatenate(
             [out, out_c.reshape(P * C, 1, *out_c.shape[2:])])
-    return _layer_close(x, out, lp, cfg), pools, wl
+    x, load = _layer_close(x, out, lp, cfg, route, rows.ok[:, None])
+    return x, pools, wl, load
 
 
 _POOL_LEAVES = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages")
@@ -1002,12 +1032,15 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
     [P, S_max], not the whole pool, and flash takes it where
     cfg.attn_impl and the window allow.
 
-    Returns (logits [S, V] float32, pools-or-window): the LM head runs
+    Returns (logits [S, V] float32, pools-or-window, load): the LM head runs
     on S rows, a decoding slot's own and, for a slot with a chunk, the
     chunk's last real column (its first token, if the prompt ends
     there). Window off the second value is the cache with its pools
     written and its lengths as they were; window on, the window.
     Neither length advances here: the block scan knows what it keeps.
+    `load` f32 [3] is models.common.expert_load of the step's real
+    rows, the mean over the layers (None for a dense model): distinct
+    experts touched, rows of the fullest expert, mean rows an expert.
     (Under pipeline stages: parallel/pipeline.py paged_pipeline_packed,
     the same pieces over stage-local layers.)
     """
@@ -1018,11 +1051,13 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
     if window is None:
         def body(x, scanned):
             lp, *pools = scanned
-            x, pools, _ = packed_layer(x, lp, (*pools, *pad), None, rows,
-                                       cfg, use_kernel)
-            return x, pools[:n_leaves]
+            x, pools, _, load = packed_layer(x, lp, (*pools, *pad), None,
+                                             rows, cfg, use_kernel)
+            return x, (pools[:n_leaves], load)
 
-        x, pools = lax.scan(body, x, (params["layers"], *pool_leaves(cache)))
+        x, (pools, load) = lax.scan(
+            body, x, (layer_stack(params["layers"], cfg),
+                      *pool_leaves(cache)))
         state = pool_leaves(cache, pools)
     else:
         # the pool is read-only and goes in whole beside the layer's
@@ -1031,13 +1066,16 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
         def body(carry, scanned):
             x, i = carry
             lp, *wl = scanned
-            x, _, wl = packed_layer(x, lp, (*pool_leaves(cache), *pad),
-                                    (*wl, *pad), rows, cfg, use_kernel,
-                                    layer=i)
-            return (x, i + 1), wl[:n_leaves]
+            x, _, wl, load = packed_layer(
+                x, lp, (*pool_leaves(cache), *pad), (*wl, *pad), rows, cfg,
+                use_kernel, layer=i)
+            return (x, i + 1), (wl[:n_leaves], load)
 
-        (x, _), new_win = lax.scan(
-            body, (x, 0), (params["layers"], window.k, window.v,
-                           window.k_scale, window.v_scale)[:1 + n_leaves])
+        (x, _), (new_win, load) = lax.scan(
+            body, (x, 0), (layer_stack(params["layers"], cfg), window.k,
+                           window.v, window.k_scale,
+                           window.v_scale)[:1 + n_leaves])
         state = KVWindow(*new_win)
-    return final_logits(params, cfg, x[rows.head])[:, 0], state
+    if load is not None:
+        load = load.mean(axis=0)
+    return final_logits(params, cfg, x[rows.head])[:, 0], state, load

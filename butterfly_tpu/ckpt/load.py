@@ -47,6 +47,8 @@ def load_checkpoint(path: str, cfg: ModelConfig):
         from butterfly_tpu.models.llama import params_from_hf_state_dict
     elif cfg.arch == "mixtral":
         from butterfly_tpu.models.mixtral import params_from_hf_state_dict
+    elif cfg.arch == "smallthinker":
+        from butterfly_tpu.models.smallthinker import params_from_hf_state_dict
     else:
         raise ValueError(f"unknown arch {cfg.arch!r}")
     return params_from_hf_state_dict(sd, cfg)
@@ -91,12 +93,23 @@ def config_from_hf_dir(path: str) -> ModelConfig:
         num_kv_heads=cj.get("num_key_value_heads", cj["num_attention_heads"]),
         head_dim=cj.get("head_dim",
                         cj["hidden_size"] // cj["num_attention_heads"]),
-        intermediate_size=cj["intermediate_size"],
+        # SmallThinker has experts only, and its own key for their width
+        intermediate_size=cj["moe_ffn_hidden_size" if mt == "smallthinker"
+                             else "intermediate_size"],
         max_seq_len=cj.get("max_position_embeddings", 8192),
         norm_eps=cj.get("rms_norm_eps", 1e-5),
         rope_theta=cj.get("rope_theta", 500000.0),
         tie_embeddings=cj.get("tie_word_embeddings", False),
     )
+    if mt == "smallthinker":
+        common.update(
+            num_experts=cj["moe_num_primary_experts"],
+            num_experts_per_tok=cj["moe_num_active_primary_experts"],
+            sliding_window=cj["sliding_window_size"],
+            sliding_window_layout=cj["sliding_window_layout"],
+            rope_layout=cj["rope_layout"])
+        return ModelConfig(arch="smallthinker", act="relu",
+                           router_input="attn", **common)
     if mt == "mixtral":
         return ModelConfig(arch="mixtral",
                            num_experts=cj.get("num_local_experts", 8),

@@ -11,16 +11,19 @@ import dataclasses
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
+import numpy as np
+
 
 @dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters for a transformer LM.
 
-    One config class covers the three model families (GPT-2, Llama-3,
-    Mixtral) — the family is selected by `arch` and the MoE fields.
+    One config class covers the model families (GPT-2, Llama-3,
+    Mixtral, SmallThinker) — the family is selected by `arch`, the MoE
+    fields and the per-layer attention pattern.
     """
 
-    arch: str = "llama"  # "gpt2" | "llama" | "mixtral"
+    arch: str = "llama"  # "gpt2" | "llama" | "mixtral" | "smallthinker"
     vocab_size: int = 32000
     hidden_size: int = 4096
     num_layers: int = 32
@@ -45,6 +48,25 @@ class ModelConfig:
     num_experts_per_tok: int = 2
     moe_impl: str = "dense"           # "dense" | "ep" (GShard dispatch)
     moe_capacity_factor: float = 2.0  # per-expert slots multiplier (ep)
+    router_input: str = "ffn"         # what the router reads: "ffn" = the
+                                      # feed-forward's normed input (Mixtral);
+                                      # "attn" = the ATTENTION's normed input,
+                                      # the router placed before attention
+                                      # (SmallThinker)
+
+    # per-layer attention pattern: layers of one model that differ in
+    # mask and rotation. A layout has one 0/1 entry per layer (a longer
+    # one is read up to num_layers: a config cut in depth, a draft of
+    # the first layers); () = every layer alike. The pattern rides the
+    # layer scan as data (models/common.py layer_stack), so unlike
+    # layers share one compiled body.
+    sliding_window: int = 0           # p attends j only if p - j < this;
+                                      # 0 = no layer slides
+    sliding_window_layout: Tuple[int, ...] = ()  # 1 = the layer slides;
+                                      # given whenever sliding_window > 0
+    rope_layout: Tuple[int, ...] = ()  # 1 = the layer rotates q and k; 0 =
+                                      # no positional encoding in the layer;
+                                      # () = every layer, as pos_embedding says
 
     # numerics
     dtype: str = "bfloat16"           # activation/weight compute dtype
@@ -58,9 +80,41 @@ class ModelConfig:
     # engines swap it in for exactly those steps.
     attn_impl: str = "dense"
 
+    def __post_init__(self):
+        # a layout read from JSON is a list; the dataclass is a static
+        # jit argument and must hash
+        for name in ("sliding_window_layout", "rope_layout"):
+            layout = tuple(int(v) for v in getattr(self, name))
+            if layout and len(layout) < self.num_layers:
+                raise ValueError(f"{name} has {len(layout)} entries for "
+                                 f"{self.num_layers} layers")
+            object.__setattr__(self, name, layout)
+        if self.router_input not in ("ffn", "attn"):
+            raise ValueError(f"unknown router_input {self.router_input!r}")
+        if bool(self.sliding_window_layout) != (self.sliding_window > 0):
+            raise ValueError("sliding_window and sliding_window_layout "
+                             "come together: which layers slide is stated")
+        if self.router_input == "attn" and self.moe_impl == "ep":
+            raise ValueError("expert parallelism (moe_impl 'ep') does not "
+                             "carry router logits taken before attention")
+
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    def layer_pattern(self):
+        """{"sliding_window": int32 [L] (0 = the layer is full),
+        "rope": int32 [L]} where some layer slides or some layer does
+        not rotate, else None: the model's layers are all alike and no
+        program carries a pattern."""
+        L = self.num_layers
+        if not (self.sliding_window > 0 or 0 in self.rope_layout[:L]):
+            return None
+        slides = self.sliding_window_layout[:L] or (0,) * L
+        rope = self.rope_layout[:L] or (1,) * L
+        return {"sliding_window": np.asarray(slides, np.int32)
+                * np.int32(self.sliding_window),
+                "rope": np.asarray(rope, np.int32)}
 
     @property
     def q_per_kv(self) -> int:
@@ -108,6 +162,24 @@ def mixtral_8x7b() -> ModelConfig:
     )
 
 
+def smallthinker_21b_a3b() -> ModelConfig:
+    """SmallThinker-21BA3B-Instruct (huggingface.co/PowerInfer): 64 ReGLU
+    experts of width 768, 6 a token, routed on the attention's normed
+    input; of every four layers the first is full attention without
+    positional encoding, the other three slide over 4,096 tokens and
+    rotate."""
+    L = 52
+    return ModelConfig(
+        arch="smallthinker", vocab_size=151936, hidden_size=2560,
+        num_layers=L, num_heads=28, num_kv_heads=4, head_dim=128,
+        intermediate_size=768, max_seq_len=16384, norm_eps=1e-6,
+        rope_theta=1500000.0, act="relu",
+        num_experts=64, num_experts_per_tok=6, router_input="attn",
+        sliding_window=4096, sliding_window_layout=(0, 1, 1, 1) * (L // 4),
+        rope_layout=(0, 1, 1, 1) * (L // 4),
+    )
+
+
 def tiny(arch: str = "llama", **kw) -> ModelConfig:
     """Small config for tests: runs in <1s on CPU, exercises every code path."""
     base = dict(
@@ -120,6 +192,15 @@ def tiny(arch: str = "llama", **kw) -> ModelConfig:
                     act="gelu_new", pos_embedding="learned")
     if arch == "mixtral":
         base.update(num_experts=4, num_experts_per_tok=2)
+    if arch == "smallthinker":
+        # the shape of the real one: 7 queries a KV head, ReGLU experts,
+        # the pattern [0, 1, 1, 1], a window short enough to bind
+        base.update(num_layers=4, num_heads=14, num_kv_heads=2,
+                    intermediate_size=48, act="relu",
+                    num_experts=8, num_experts_per_tok=3,
+                    router_input="attn", sliding_window=8,
+                    sliding_window_layout=(0, 1, 1, 1),
+                    rope_layout=(0, 1, 1, 1))
     base.update(kw)
     return ModelConfig(arch=arch, **base)
 
@@ -129,6 +210,7 @@ PRESETS = {
     "llama3-8b": llama3_8b,
     "llama3-70b": llama3_70b,
     "mixtral-8x7b": mixtral_8x7b,
+    "smallthinker-21b-a3b": smallthinker_21b_a3b,
 }
 
 

@@ -357,6 +357,10 @@ class ServingEngine:
         # scheduler's tick record and the launch span carry both
         self.last_program: Optional[str] = None
         self.last_rows = 0
+        # what the newest mixed block's routing asked of the experts, a
+        # device f32 [3] (_packed_scan's `load`); None for a dense model.
+        # The scheduler fetches it with the block's tokens.
+        self.last_expert_load = None
         self.blocks_launched = 0
         self._decode_blocks: Dict[int, object] = {}
         # Write-combined KV decode window (RuntimeConfig.kv_write_combine,
@@ -1058,7 +1062,8 @@ class ServingEngine:
         if self._window_mode:
             self._ensure_window(need)
         with self._mesh_ctx():
-            block, valid, final, cursor, cache, window, wlen = self._launch(
+            (block, valid, final, cursor, cache, window, wlen,
+             self.last_expert_load) = self._launch(
                 self._mixed_block_prog(k, C, P), self.num_slots + P * C,
                 self.params, tokens, cursor, self.cache,
                 self._kv_window, self._win_len,  # None with the window off
@@ -1917,7 +1922,11 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
     path's.
 
     Returns (block [k, S], valid [k, S], final [S], cursor, cache,
-    window, win_len).
+    window, win_len, load): load f32 [3] is what the block's routing
+    asked of the experts (models.common.expert_load: distinct experts
+    touched, rows of the fullest expert, mean rows an expert), the mean
+    over its layers and the steps with a real row; None for a dense
+    model.
     """
     S = tokens.shape[0]
     H = pbuf.shape[1]
@@ -1948,7 +1957,7 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
             pbuf[chunk_slot],
             jnp.clip(cursor[chunk_slot][:, None] + ccol, 0, H - 1), axis=1)
         chunk_count = jnp.where(mine.any(axis=1), count[chunk_slot], 0)
-        logits, new = fwd(
+        logits, new, load = fwd(
             params, cfg, cur, pool, chunk_tokens, chunk_slot, chunk_count,
             live & ~is_pf, win, wlen, use_kernel=use_kernel)
         completing = has_chunk & (cursor + count >= plen)
@@ -1964,9 +1973,9 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
         live = live & jnp.where(
             emit, (rem > 0) & jnp.where(has_stop, nxt != stops, True),
             True)
-        return (nxt, cursor, kv, live, rem), (nxt, emit)
+        return (nxt, cursor, kv, live, rem), (nxt, emit, load)
 
-    (final, cursor, kv, _, _), (block, valid) = lax.scan(
+    (final, cursor, kv, _, _), (block, valid, load) = lax.scan(
         body, (tokens, cursor, (window, win_len) if windowed else cache,
                live, budgets),
         jnp.arange(k, dtype=jnp.int32))
@@ -1974,7 +1983,12 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
         window, win_len = kv
     else:
         cache = kv
-    return block, valid, final, cursor, cache, window, win_len
+    if load is not None:
+        # the mean over the steps that had a real row (a block's last
+        # steps may run on slots that have all finished)
+        had = (load[:, 2] > 0).astype(load.dtype)
+        load = (load * had[:, None]).sum(axis=0) / jnp.maximum(had.sum(), 1)
+    return block, valid, final, cursor, cache, window, win_len, load
 
 
 def _mixed_spec_scan(cfg: ModelConfig, fwd, rounds: int, gamma: int,

@@ -253,10 +253,13 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array,
 
 @jax.named_scope("attn")
 def qkv_proj(x: jax.Array, p: Params, cfg: ModelConfig,
-             cos: jax.Array, sin: jax.Array
+             cos: jax.Array, sin: jax.Array, rope=None
              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """QKV projections (+bias, +rope). x: [B,T,D] -> q [B,T,Nq,H],
-    k/v [B,T,Kv,H]. Shared by the contiguous and paged attention paths."""
+    k/v [B,T,Kv,H]. Shared by the contiguous and paged attention paths.
+    rope: the layer's flag out of its pattern (layer_stack), a traced
+    scalar: 0 = this layer has no positional encoding. It turns the
+    rotation into the identity (cos 1, sin 0), exactly: x*1 - y*0."""
     dt = x.dtype
     q = qeinsum("btd,dnh->btnh", x, p["wq"], dt)
     k = qeinsum("btd,dkh->btkh", x, p["wk"], dt)
@@ -266,6 +269,9 @@ def qkv_proj(x: jax.Array, p: Params, cfg: ModelConfig,
         k = k + p["bk"]
         v = v + p["bv"]
     if cfg.pos_embedding == "rope":
+        if rope is not None:
+            cos = jnp.where(rope > 0, cos, 1.0)
+            sin = jnp.where(rope > 0, sin, 0.0)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
     return q, k, v
@@ -286,7 +292,8 @@ def attention_block(x: jax.Array, p: Params, cfg: ModelConfig,
                     cos: jax.Array, sin: jax.Array,
                     fresh: bool = False,
                     k_s: Optional[jax.Array] = None,
-                    v_s: Optional[jax.Array] = None):
+                    v_s: Optional[jax.Array] = None,
+                    pattern: Optional[Params] = None):
     """One attention sublayer with contiguous-cache update.
 
     x: [B,T,D]; ck/cv: [B,S,Kv,H]; positions: [B,T]; mask: [B,T,S].
@@ -311,13 +318,20 @@ def attention_block(x: jax.Array, p: Params, cfg: ModelConfig,
     runs over the just-projected K/V (flash, or a dense causal fallback
     over the same values), and the raw k/v come back so the caller can
     write the pools itself: returns (out, k, v).
+
+    pattern: this layer's entry of cfg.layer_pattern() (layer_stack),
+    traced scalars: whether it rotates, and its sliding window, which
+    narrows `mask` and rides into the flash kernels.
     """
-    q, k, v = qkv_proj(x, p, cfg, cos, sin)
+    rope, sw = layer_pattern_of(pattern)
+    q, k, v = qkv_proj(x, p, cfg, cos, sin, rope)
+    mask = layer_mask(mask, positions, sw)
     if ck is None:
         assert fresh, "no-cache attention_block is fresh-prefill only"
         out = None
         if cfg.attn_impl == "flash" and x.shape[1] > 1:
-            out = flash_attention_sharded(q, k, v, causal=True)
+            out = flash_attention_sharded(q, k, v, causal=True,
+                                          sliding_window=sw)
             if out is None:
                 note_kernel("dense_fallback")
         if out is None:
@@ -335,7 +349,8 @@ def attention_block(x: jax.Array, p: Params, cfg: ModelConfig,
         # (Fresh prefill attends over the just-projected bf16 K/V, so the
         # kernel path is identical for int8 caches.)
         if fresh:
-            out = flash_attention_sharded(q, k, v, causal=True)
+            out = flash_attention_sharded(q, k, v, causal=True,
+                                          sliding_window=sw)
         else:
             # warm chunk (ISSUE 13): the kernel attends the cache as a
             # prefix segment count-masked at `start` (the chunk's own
@@ -354,7 +369,8 @@ def attention_block(x: jax.Array, p: Params, cfg: ModelConfig,
                       * vsc[..., None]).astype(v.dtype)
             out = flash_attention_sharded(
                 q, kf, vf, causal=True, prefix_k=ck, prefix_v=cv,
-                prefix_len=start, prefix_k_scale=k_s, prefix_v_scale=v_s)
+                prefix_len=start, prefix_k_scale=k_s, prefix_v_scale=v_s,
+                sliding_window=sw)
         if out is None:
             note_kernel("dense_fallback")
     if out is None:
@@ -379,27 +395,65 @@ def mlp_block(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
     return qeinsum("btf,fd->btd", h, p["w_down"], dt)
 
 
-def route_tokens(x: jax.Array, router_w: jax.Array,
-                 k: int) -> Tuple[jax.Array, jax.Array]:
+@jax.named_scope("moe_route")
+def router_logits(x: jax.Array, router_w: jax.Array) -> jax.Array:
+    """Router logits [B,T,E] of the router's input x [B,T,D], in
+    float32 at full precision. The choice of experts is discrete: a
+    logit that is rounded to bfloat16 (its product's output, before a
+    cast back up) sits within an ulp of its neighbour at every near-tie
+    and picks another expert than the float32 model would, a whole
+    expert's output wrong for a rounding. The product is [rows, D] x
+    [D, E]: a thousandth of the expert products behind it."""
+    return jnp.einsum("btd,de->bte", x.astype(jnp.float32),
+                      router_w.astype(jnp.float32),
+                      precision=lax.Precision.HIGHEST)
+
+
+@jax.named_scope("moe_route")
+def route_tokens(x: jax.Array, router_w: jax.Array, k: int,
+                 logits: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
     """Top-k MoE routing: f32 logits -> (gates [.., k], expert idx [.., k]).
 
-    Softmax is over the SELECTED k (Mixtral convention). The single
+    Softmax is over the SELECTED k (Mixtral convention; a softmax over
+    all experts, its top k renormalised, is the same numbers). The single
     definition shared by the dense block and both EP dispatch paths —
     their exact-parity contract depends on byte-identical routing.
+    `logits`: router logits taken elsewhere (cfg.router_input "attn":
+    from the attention's normed input), which x is then not read for.
     """
-    logits = jnp.einsum("btd,de->bte", x, router_w).astype(jnp.float32)
+    if logits is None:
+        logits = router_logits(x, router_w)
     gates, idx = lax.top_k(logits, k)
     return jax.nn.softmax(gates, axis=-1), idx
 
 
-def moe_block(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
+@jax.named_scope("moe_route")
+def expert_load(logits: jax.Array, k: int, ok: jax.Array) -> jax.Array:
+    """What one layer's routing asks of its experts in one step, f32 [3]:
+    how many DISTINCT experts the rows marked `ok` touch (the experts a
+    dispatch that skips unrouted ones would still stream), the rows of
+    the fullest expert, and the mean rows of an expert (ok rows x k /
+    E). logits [B,T,E] as route_tokens takes them, ok [B,T] bool."""
+    E = logits.shape[-1]
+    _, idx = lax.top_k(logits, k)
+    rows = jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32)
+                   * ok[..., None, None], axis=(0, 1, 2))           # [E]
+    return jnp.stack([jnp.sum(rows > 0), jnp.max(rows), jnp.sum(rows) / E])
+
+
+@jax.named_scope("moe_experts")
+def moe_block(x: jax.Array, p: Params, cfg: ModelConfig,
+              logits: Optional[jax.Array] = None) -> jax.Array:
     """Dense-compute MoE (every expert sees every token, masked by router).
 
     The expert-parallel all_to_all path lives in parallel/expert.py; this
     dense form is the single-device reference and the EP fallback.
+    `logits`: as route_tokens'.
     """
     B, T, D = x.shape
-    weights, idx = route_tokens(x, p["router"], cfg.num_experts_per_tok)
+    weights, idx = route_tokens(x, p["router"], cfg.num_experts_per_tok,
+                                logits)
     onehot = jax.nn.one_hot(idx, cfg.num_experts, dtype=jnp.float32)  # [B,T,k,E]
     comb = jnp.einsum("btk,btke->bte", weights, onehot)  # [B,T,E]
 
@@ -419,16 +473,32 @@ def pre_norm(x: jax.Array, norm_p: Params, cfg: ModelConfig) -> jax.Array:
     return rms_norm(x, norm_p["scale"], cfg.norm_eps)
 
 
+def early_router_logits(x: jax.Array, lp: Params,
+                        cfg: ModelConfig) -> Optional[jax.Array]:
+    """The layer's router logits where the router stands BEFORE
+    attention (cfg.router_input "attn"): read from the attention's
+    normed input and carried across attention to ffn_block. x is the
+    layer's INPUT: the norm is taken again in float32 for the router
+    (router_logits says why; the attention reads the compute dtype's).
+    None where the router reads the feed-forward's own input."""
+    if cfg.is_moe and cfg.router_input == "attn":
+        h = pre_norm(x.astype(jnp.float32), lp["ln1"], cfg)
+        return router_logits(h, lp["moe"]["router"])
+    return None
+
+
 @jax.named_scope("mlp")
-def ffn_block(h: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
+def ffn_block(h: jax.Array, lp: Params, cfg: ModelConfig,
+              logits: Optional[jax.Array] = None) -> jax.Array:
     """FFN dispatch shared by every forward variant (contiguous, paged,
     pipeline, sequence-parallel): dense MLP, dense MoE, or EP MoE per
-    cfg — one definition so the variants can't drift."""
+    cfg — one definition so the variants can't drift. `logits`:
+    early_router_logits' of this layer, where the model has them."""
     if cfg.is_moe:
-        if cfg.moe_impl == "ep":
+        if cfg.moe_impl == "ep":   # no early logits here: ModelConfig refuses
             from butterfly_tpu.parallel.expert import moe_block_ep
             return moe_block_ep(h, lp["moe"], cfg)
-        return moe_block(h, lp["moe"], cfg)
+        return moe_block(h, lp["moe"], cfg, logits)
     return mlp_block(h, lp["mlp"], cfg)
 
 
@@ -446,11 +516,12 @@ def transformer_layer(x: jax.Array, lp: Params, cfg: ModelConfig,
     the layer's raw projected K/V.
     """
     h = pre_norm(x, lp["ln1"], cfg)
+    route = early_router_logits(x, lp, cfg)
     attn_out, *rest = attention_block(
         h, lp["attn"], cfg, ck, cv, positions, mask, cos, sin, fresh,
-        k_s, v_s)
+        k_s, v_s, lp.get("pattern"))
     x = x + attn_out
-    x = x + ffn_block(pre_norm(x, lp["ln2"], cfg), lp, cfg)
+    x = x + ffn_block(pre_norm(x, lp["ln2"], cfg), lp, cfg, route)
     return (x, *rest)
 
 
@@ -458,15 +529,65 @@ def transformer_layer(x: jax.Array, lp: Params, cfg: ModelConfig,
 # Full forward
 # ---------------------------------------------------------------------------
 
-def make_mask(positions: jax.Array, S: int) -> jax.Array:
+def make_mask(positions: jax.Array, S: int,
+              sliding_window=None) -> jax.Array:
     """Causal mask over the cache: [B,T,S], True where query may attend.
 
     A query at absolute position p attends to cache slots j <= p. Slots
     beyond the written region have j > p and are excluded automatically
     (new tokens are written into the cache before attending).
+    sliding_window (a scalar, may be traced): the mask's lower bound,
+    p - j < sliding_window; 0 = none, as for a full layer of a model
+    whose other layers slide.
     """
     j = jnp.arange(S)[None, None, :]
-    return j <= positions[:, :, None]
+    p = positions[:, :, None]
+    mask = j <= p
+    if sliding_window is not None:
+        mask = mask & ((sliding_window <= 0) | (p - j < sliding_window))
+    return mask
+
+
+def layer_stack(layers: Params, cfg: ModelConfig) -> Params:
+    """The layer-stacked tree a layer scan rides as xs: the weights
+    and, where the model's layers are unlike (cfg.layer_pattern), each
+    layer's pattern beside them under "pattern", as data: the scan's
+    one compiled body serves every kind of layer. The leading dim may
+    be a draft's first layers; a slice that does not start at layer 0
+    (a pipeline stage) is not handled here."""
+    pattern = cfg.layer_pattern()
+    if pattern is None:
+        return layers
+    n = jax.tree.leaves(layers)[0].shape[0]
+    return {**layers,
+            "pattern": {k: jnp.asarray(v[:n]) for k, v in pattern.items()}}
+
+
+def uniform_layers_only(cfg: ModelConfig, what: str) -> None:
+    """Refuse a model whose layers are unlike, or whose router stands
+    before attention, on a path that scans layer slices of its own
+    (pipeline stages, sequence-parallel bodies): neither carries the
+    pattern nor the early router logits yet."""
+    if cfg.layer_pattern() is not None \
+            or (cfg.is_moe and cfg.router_input == "attn"):
+        raise NotImplementedError(
+            f"{what} runs models whose layers are all alike; this one "
+            "has a per-layer attention pattern or routes before attention")
+
+
+def layer_pattern_of(pattern: Optional[Params]):
+    """(rope, sliding_window) of one layer's slice of layer_stack's
+    "pattern" (traced scalars), or (None, None) for a model without."""
+    if pattern is None:
+        return None, None
+    return pattern["rope"], pattern["sliding_window"]
+
+
+def layer_mask(mask: jax.Array, positions: jax.Array, sliding_window):
+    """`mask` [B,T,S] narrowed to the layer's sliding window."""
+    if sliding_window is None:
+        return mask
+    return mask & make_mask(positions, mask.shape[-1], sliding_window)
 
 
 def embed_tokens(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -508,6 +629,7 @@ def scan_layers(layer_params: Params, cfg: ModelConfig, x: jax.Array,
                                    *kv[2:])
         return x, tuple(kv)
 
+    layer_params = layer_stack(layer_params, cfg)
     xs = (layer_params, k, v, k_s, v_s) if quant else (layer_params, k, v)
     x, out = lax.scan(body, x, xs)
     return (x, *out)
@@ -537,7 +659,8 @@ def decode_attend(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                   wk: Optional[jax.Array] = None,
                   wv: Optional[jax.Array] = None,
                   wk_s: Optional[jax.Array] = None,
-                  wv_s: Optional[jax.Array] = None) -> jax.Array:
+                  wv_s: Optional[jax.Array] = None,
+                  sliding_window=None) -> jax.Array:
     """One-token attention over (old cache) + (the token itself).
 
     The general path writes K/V into the cache BEFORE attending, which
@@ -562,6 +685,10 @@ def decode_attend(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     exactly the steps decoded so far — see decode_step_win); they sit
     at absolute positions start..start+W-1. `start` is the FLUSHED
     length per row (= tokens actually in ck/cv).
+
+    sliding_window (the layer's, a traced scalar; 0 = a full layer):
+    the token, at position start + W, attends position c only where
+    start + W - c < sliding_window.
     """
     B, _, Nq, H = q.shape
     quant = k_s is not None
@@ -578,6 +705,11 @@ def decode_attend(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
         s_c = s_c * k_s[:, :, None, :]
     s_c = s_c * scale
     older = jnp.arange(S)[None, :] < start[:, None]          # strictly past
+    W = 0 if wk is None else wk.shape[0]
+    if sliding_window is not None:
+        # the token's own lower bound, over the cache and the window
+        lo = jnp.where(sliding_window > 0, start + W - sliding_window, -1)
+        older = older & (jnp.arange(S)[None, :] > lo[:, None])
     s_c = jnp.where(older[:, None, None, :], s_c, -1e30)
     parts_s = [s_c]
 
@@ -587,6 +719,9 @@ def decode_attend(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
         if quant:
             s_w = s_w * jnp.moveaxis(wk_s, 0, -1)[:, :, None, :]
         s_w = s_w * scale
+        if sliding_window is not None:
+            inside = start[:, None] + jnp.arange(W)[None, :] > lo[:, None]
+            s_w = jnp.where(inside[:, None, None, :], s_w, -1e30)
         parts_s.append(s_w)
 
     s_self = jnp.sum(qg.astype(jnp.float32) *
@@ -631,11 +766,13 @@ def _decode_layer_body(x, lp, cfg: ModelConfig, cache: KVCache, i,
         k_s = lax.dynamic_index_in_dim(cache.k_scale, i, 0, keepdims=False)
         v_s = lax.dynamic_index_in_dim(cache.v_scale, i, 0, keepdims=False)
     h = pre_norm(x, lp["ln1"], cfg)
-    q, k, v = qkv_proj(h, lp["attn"], cfg, cos, sin)
+    route = early_router_logits(x, lp, cfg)
+    rope, sw = layer_pattern_of(lp.get("pattern"))
+    q, k, v = qkv_proj(h, lp["attn"], cfg, cos, sin, rope)
     out = decode_attend(q, k, v, ck, cv, start, cfg, k_s, v_s,
-                        wk_i, wv_i, wks_i, wvs_i)
+                        wk_i, wv_i, wks_i, wvs_i, sliding_window=sw)
     x = x + attn_output(out, lp["attn"], cfg)
-    x = x + ffn_block(pre_norm(x, lp["ln2"], cfg), lp, cfg)
+    x = x + ffn_block(pre_norm(x, lp["ln2"], cfg), lp, cfg, route)
     return x, k, v
 
 
@@ -670,7 +807,8 @@ def _decode_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         return (x, i + 1), (k.astype(cache.k.dtype),
                             v.astype(cache.v.dtype))
 
-    (x, _), outs = lax.scan(layer, (x, 0), params["layers"])
+    (x, _), outs = lax.scan(layer, (x, 0),
+                            layer_stack(params["layers"], cfg))
     logits = final_logits(params, cfg, x)
 
     if quant:
@@ -761,7 +899,8 @@ def decode_step_win(params: Params, cfg: ModelConfig, tokens: jax.Array,
         return (x, i + 1), (k[:, 0].astype(cache.k.dtype),
                             v[:, 0].astype(cache.v.dtype))
 
-    (x, _), new_kv = lax.scan(layer, (x, 0), (params["layers"], win))
+    (x, _), new_kv = lax.scan(
+        layer, (x, 0), (layer_stack(params["layers"], cfg), win))
     return final_logits(params, cfg, x), new_kv
 
 
@@ -882,7 +1021,8 @@ def _fresh_prefill_forward(params: Params, cfg: ModelConfig,
         return (x, (ck, cv, cks, cvs), i + 1), None
 
     pools0 = (cache.k, cache.v, cache.k_scale, cache.v_scale)
-    (x, pools, _), _ = lax.scan(body, (x, pools0, 0), params["layers"])
+    (x, pools, _), _ = lax.scan(body, (x, pools0, 0),
+                                layer_stack(params["layers"], cfg))
     if last_index is not None:
         x = jnp.take_along_axis(
             x, last_index[:, None, None].astype(jnp.int32), axis=1)

@@ -10,7 +10,8 @@ Two bounded, always-cheap instruments the scheduler feeds:
   when it returned, in-flight depth, barrier causes,
   batch occupancy and page headroom, the block program the tick
   dispatched and the rows one step of it computes, the loop's wait
-  for the serving lock and the process's count of compilations. The sequence number is also the ``seq`` of
+  for the serving lock, the process's count of compilations and, for a
+  model of experts, what the drained blocks' routing asked of them. The sequence number is also the ``seq`` of
   the tick's ``bf.tick`` span in a profiler trace: the join between
   the two needs no clock. One dict append per tick under an
   uncontended lock — the software answer to "where does the tick's
@@ -86,10 +87,15 @@ class TickLog:
                spec: bool = False, program: Optional[str] = None,
                rows: Optional[int] = None,
                block: int = 0, lock_s: float = 0.0,
-               compiles: int = 0) -> None:
+               compiles: int = 0, expert_load=None) -> None:
         """Append one tick record (hot path: one dict build + one
         locked append per TICK, never per token). `phases` is copied —
-        callers may reuse/zero their accumulator dict."""
+        callers may reuse/zero their accumulator dict. `expert_load`:
+        [touched, rows_max, rows_mean] of the mixed blocks the tick
+        drained (a model of experts; models.common.expert_load), as
+        `experts_touched`, `expert_rows_max` and `expert_rows_mean`;
+        None for a dense model or a tick that drained no block."""
+        touched, rows_max, rows_mean = expert_load or (None, None, None)
         entry = {
             "seq": self._seq,
             "t_wall": time.time(),
@@ -109,6 +115,9 @@ class TickLog:
             "block": block,
             "lock_s": lock_s,
             "compiles": compiles,
+            "experts_touched": touched,
+            "expert_rows_max": rows_max,
+            "expert_rows_mean": rows_mean,
         }
         with self._lock:
             self._ring.append(entry)

@@ -62,8 +62,23 @@ def _block_update(s, mask, vf, m_ref, l_ref, acc_ref, vs_row=None):
     m_ref[:] = m_new
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                  *, bq: int, bk: int, seq_len: int, causal: bool):
+def _in_window(q_pos, k_pos, sw_ref):
+    """A layer's sliding window on absolute (or equally offset)
+    positions: q attends k only where q - k < sw; sw 0 = the layer is
+    full. sw_ref: the scalar-prefetched [1] window."""
+    sw = sw_ref[0]
+    return (sw <= 0) | (q_pos - k_pos < sw)
+
+
+def _flash_kernel(*refs, bq: int, bk: int, seq_len: int, causal: bool,
+                  sliding: bool):
+    """sliding: the first ref is the scalar-prefetched sliding window
+    of the layer (a value: layers that slide and layers that do not
+    share this one compiled kernel)."""
+    sw_ref = None
+    if sliding:
+        sw_ref, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     i = pl.program_id(2)          # q block
     j = pl.program_id(3)          # k block (reduction axis)
     nk = pl.num_programs(3)
@@ -85,6 +100,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     mask = k_pos < seq_len                          # padded keys
     if causal:
         mask = mask & (q_pos >= k_pos)
+    if sliding:
+        mask = mask & _in_window(q_pos, k_pos, sw_ref)
     _block_update(s, mask, v, m_ref, l_ref, acc_ref)
 
     @pl.when(j == nk - 1)
@@ -93,9 +110,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                        jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
 
 
-def _flash_warm_kernel(start_ref, q_ref, k_ref, v_ref, *rest,
+def _flash_warm_kernel(start_ref, *rest,
                        bq: int, bk: int, bp: int, np_blocks: int,
-                       seq_len: int, quant: bool):
+                       seq_len: int, quant: bool, sliding: bool):
     """Warm-prefix flash prefill kernel (ISSUE 13): the reduction axis
     runs `np_blocks` cached-prefix blocks — read from the contiguous
     cache view, masked per row by the scalar-prefetched `start` (the
@@ -114,8 +131,16 @@ def _flash_warm_kernel(start_ref, q_ref, k_ref, v_ref, *rest,
     models.common.attend / the paged kernel's int8 blocks. The fresh
     chunk is always float (the caller mirrors the cache's
     quantize-dequantize there for operand parity with the dense path).
+
+    sliding: a second prefetched scalar, the layer's sliding window. A
+    prefix position c is then attended by the query at start + t only
+    where start + t - c < window, and a prefix block wholly before
+    every query's window skips its compute like a block past `start`.
     """
-    pk_ref, pv_ref, *rest = rest
+    sw_ref = None
+    if sliding:
+        sw_ref, *rest = rest
+    q_ref, k_ref, v_ref, pk_ref, pv_ref, *rest = rest
     pks_ref = pvs_ref = None
     if quant:
         pks_ref, pvs_ref, *rest = rest
@@ -132,7 +157,13 @@ def _flash_warm_kernel(start_ref, q_ref, k_ref, v_ref, *rest,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when((j < np_blocks) & (j * bp < start))
+    live = (j < np_blocks) & (j * bp < start)
+    if sliding:
+        # the block's last column against the first query's lower bound
+        lo = jnp.where(sw_ref[0] > 0, start + i * bq - sw_ref[0] + 1, 0)
+        live = live & ((j + 1) * bp > lo)
+
+    @pl.when(live)
     def _prefix():
         q = q_ref[0, 0].astype(jnp.float32)        # [BQ, H]
         kf = pk_ref[0, 0].astype(jnp.float32)      # [BP, H]
@@ -144,6 +175,10 @@ def _flash_warm_kernel(start_ref, q_ref, k_ref, v_ref, *rest,
         s = s * scale
         cols = j * bp + jax.lax.broadcasted_iota(jnp.int32, (bq, bp), 1)
         mask = cols < start
+        if sliding:
+            q_pos = start + i * bq + jax.lax.broadcasted_iota(
+                jnp.int32, (bq, bp), 0)
+            mask = mask & _in_window(q_pos, cols, sw_ref)
         _block_update(s, mask, vf, m_ref, l_ref, acc_ref,
                       pvs_ref[0, 0] if quant else None)
 
@@ -160,6 +195,8 @@ def _flash_warm_kernel(start_ref, q_ref, k_ref, v_ref, *rest,
         q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         k_pos = jf * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         mask = (k_pos < seq_len) & (q_pos >= k_pos)
+        if sliding:
+            mask = mask & _in_window(q_pos, k_pos, sw_ref)
         _block_update(s, mask, vf, m_ref, l_ref, acc_ref)
 
     @pl.when(j == nj - 1)
@@ -231,7 +268,8 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
                             prefix_v: jax.Array = None,
                             prefix_len: jax.Array = None,
                             prefix_k_scale: jax.Array = None,
-                            prefix_v_scale: jax.Array = None) -> jax.Array:
+                            prefix_v_scale: jax.Array = None,
+                            sliding_window=None) -> jax.Array:
     """Mesh-aware flash attention (SURVEY.md §7 stages 4/6).
 
     A pallas_call is an opaque custom call GSPMD cannot partition, so under
@@ -265,6 +303,10 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     masking. This kernel remains the ALTERNATING path's chunked-prefill
     engine (`mixed_dispatch=False`, or a stateful draft source's
     automatic fallback).
+
+    sliding_window: the layer's window out of its pattern (a traced
+    scalar, 0 = a full layer; None = the model has no pattern and the
+    kernels compile without it), replicated to every shard.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -277,10 +319,17 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
                 + ("_int8" if prefix_k_scale is not None else ""),
                 resolve_interpret(None))
     spec = P(d, None, t, None)
+    sw, sw_spec = (), ()
+    if sliding_window is not None:
+        sw, sw_spec = (jnp.asarray(sliding_window, jnp.int32),), (P(),)
     if prefix_k is None:
-        fn = shard_kernel(functools.partial(flash_attention, causal=causal),
-                          in_specs=(spec, spec, spec), out_specs=spec)
-        return fn(q, k, v)
+        def _fresh(q, k, v, *sw):
+            return flash_attention(q, k, v, causal=causal,
+                                   sliding_window=sw[0] if sw else None)
+
+        fn = shard_kernel(_fresh, in_specs=(spec, spec, spec) + sw_spec,
+                          out_specs=spec)
+        return fn(q, k, v, *sw)
     # lazy: partition imports models.common at module level, which now
     # imports this module — an import here would close the cycle
     from butterfly_tpu.parallel.partition import warm_prefix_specs
@@ -289,17 +338,19 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     if quant:
         args += [prefix_k_scale, prefix_v_scale]
 
-    def _warm(q, k, v, pk, pv, plen, *scales):
+    def _warm(q, k, v, pk, pv, plen, *rest):
         kw = {}
-        if scales:
-            kw = dict(prefix_k_scale=scales[0], prefix_v_scale=scales[1])
+        if quant:
+            kw = dict(prefix_k_scale=rest[0], prefix_v_scale=rest[1])
+        if sw:
+            kw["sliding_window"] = rest[-1]
         return flash_attention(q, k, v, causal=causal, prefix_k=pk,
                                prefix_v=pv, prefix_len=plen, **kw)
 
     fn = shard_kernel(
-        _warm, in_specs=(spec, spec, spec) + warm_prefix_specs(d, t, quant),
-        out_specs=spec)
-    return fn(*args)
+        _warm, in_specs=(spec, spec, spec) + warm_prefix_specs(d, t, quant)
+        + sw_spec, out_specs=spec)
+    return fn(*args, *sw)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
@@ -312,7 +363,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     prefix_v: jax.Array = None,
                     prefix_len: jax.Array = None,
                     prefix_k_scale: jax.Array = None,
-                    prefix_v_scale: jax.Array = None) -> jax.Array:
+                    prefix_v_scale: jax.Array = None,
+                    sliding_window=None) -> jax.Array:
     """Blockwise (flash) attention over fresh Q/K/V.
 
     q: [B, T, Nq, H]; k/v: [B, T, Kv, H] (same T: self-attention).
@@ -333,6 +385,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     garbage, batch padding rows, the chunk's own already-written copy —
     never contribute). Queries sit at absolute positions
     prefix_len[b] + 0..T-1, so `causal` must be True.
+
+    sliding_window (int32 scalar, may be traced; None = none): a query
+    attends a key only where their positions are less than this apart;
+    0 = no bound. It rides the scalar prefetch, so one compiled kernel
+    serves the layers of a model that slide and those that do not.
     """
     B, T, Nq, H = q.shape
     Kv = k.shape[2]
@@ -361,41 +418,46 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         out = _flash_warm_call(qt, kt, vt, prefix_k, prefix_v, prefix_len,
                                prefix_k_scale, prefix_v_scale, T=T, bq=bq,
                                bk=bk, block_k=block_k, G=G,
-                               interpret=interpret)
+                               interpret=interpret,
+                               sliding_window=sliding_window)
         return jnp.moveaxis(out[:, :, :T, :], 1, 2)  # [B, T, Nq, H]
 
-    grid = (B, Nq, Tq // bq, Tk // bk)
-    kernel = functools.partial(_flash_kernel, bq=bq, bk=bk, seq_len=T,
-                               causal=causal)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, H), lambda b, n, i, j: (b, n, i, 0)),
-            pl.BlockSpec((1, 1, bk, H),
-                         lambda b, n, i, j, G=G: (b, n // G, j, 0)),
-            pl.BlockSpec((1, 1, bk, H),
-                         lambda b, n, i, j, G=G: (b, n // G, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, H),
-                               lambda b, n, i, j: (b, n, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Nq, Tq, H), q.dtype),
+    sliding = sliding_window is not None
+    # index maps take the prefetched window, where there is one, last
+    q_spec = pl.BlockSpec((1, 1, bq, H), lambda b, n, i, j, *_: (b, n, i, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, H),
+                           lambda b, n, i, j, *_: (b, n // G, j, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=int(sliding),
+        grid=(B, Nq, Tq // bq, Tk // bk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),       # running max
             pltpu.VMEM((bq, 1), jnp.float32),       # running denom
             pltpu.VMEM((bq, H), jnp.float32),       # accumulator
         ],
+    )
+    kernel = functools.partial(_flash_kernel, bq=bq, bk=bk, seq_len=T,
+                               causal=causal, sliding=sliding)
+    prefetch = [jnp.asarray(sliding_window, jnp.int32).reshape(1)] \
+        if sliding else []
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Nq, Tq, H), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(qt, kt, vt)
+    )(*prefetch, qt, kt, vt)
     return jnp.moveaxis(out[:, :, :T, :], 1, 2)     # [B, T, Nq, H]
 
 
 def _flash_warm_call(qt, kt, vt, prefix_k, prefix_v, prefix_len,
                      prefix_k_scale, prefix_v_scale, *, T: int, bq: int,
-                     bk: int, block_k: int, G: int, interpret: bool):
+                     bk: int, block_k: int, G: int, interpret: bool,
+                     sliding_window=None):
     """Build + dispatch the warm-prefix pallas_call. qt/kt/vt arrive
     head-major and padded ([B, N, Tq/Tk, H]); returns [B, Nq, Tq, H].
 
@@ -404,7 +466,8 @@ def _flash_warm_call(qt, kt, vt, prefix_k, prefix_v, prefix_len,
     operands already pay) and pads Sp to the prefix block. The per-row
     `start` vector rides as the one scalar-prefetch operand so the
     BlockSpec index maps and the in-kernel masks see it before the body
-    runs (the paged kernel's PrefetchScalarGridSpec pattern)."""
+    runs (the paged kernel's PrefetchScalarGridSpec pattern); the
+    layer's sliding window, where the model has one, rides beside it."""
     B, Nq, Tq, H = qt.shape
     Kv = kt.shape[1]
     quant = prefix_k_scale is not None
@@ -422,18 +485,18 @@ def _flash_warm_call(qt, kt, vt, prefix_k, prefix_v, prefix_len,
     pk = jnp.pad(pk, ((0, 0), (0, 0), (0, Sp_pad - Sp), (0, 0)))
     pv = jnp.pad(pv, ((0, 0), (0, 0), (0, Sp_pad - Sp), (0, 0)))
 
-    def q_map(b, n, i, j, st):
+    def q_map(b, n, i, j, *_):
         return (b, n, i, 0)
 
-    def k_map(b, n, i, j, st):
+    def k_map(b, n, i, j, *_):
         # prefix steps clamp to fresh block 0 (DMA runs, block unused)
         return (b, n // G, jnp.clip(j - np_blocks, 0, nf - 1), 0)
 
-    def p_map(b, n, i, j, st):
+    def p_map(b, n, i, j, *_):
         # fresh steps clamp to the last prefix block (unused)
         return (b, n // G, jnp.minimum(j, np_blocks - 1), 0)
 
-    def ps_map(b, n, i, j, st):
+    def ps_map(b, n, i, j, *_):
         return (b, n // G, 0, jnp.minimum(j, np_blocks - 1))
 
     in_specs = [
@@ -457,8 +520,12 @@ def _flash_warm_call(qt, kt, vt, prefix_k, prefix_v, prefix_len,
         ]
         args += [pks.reshape(B, Kv, 1, Sp_pad),
                  pvs.reshape(B, Kv, 1, Sp_pad)]
+    sliding = sliding_window is not None
+    prefetch = [prefix_len.astype(jnp.int32)]
+    if sliding:
+        prefetch.append(jnp.asarray(sliding_window, jnp.int32).reshape(1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, Nq, Tq // bq, np_blocks + nf),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, bq, H), q_map),
@@ -469,7 +536,8 @@ def _flash_warm_call(qt, kt, vt, prefix_k, prefix_v, prefix_len,
         ],
     )
     kernel = functools.partial(_flash_warm_kernel, bq=bq, bk=bk, bp=bp,
-                               np_blocks=np_blocks, seq_len=T, quant=quant)
+                               np_blocks=np_blocks, seq_len=T, quant=quant,
+                               sliding=sliding)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -478,4 +546,4 @@ def _flash_warm_call(qt, kt, vt, prefix_k, prefix_v, prefix_len,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(prefix_len.astype(jnp.int32), *args)
+    )(*prefetch, *args)

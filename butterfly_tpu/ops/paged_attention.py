@@ -34,6 +34,15 @@ slot's block table and touches ONLY its live pages:
   the contiguous int8 cache: K scales multiply the score columns, V
   scales fold into the probs. No dequantized copy is ever materialized.
 
+* A layer that slides (`sliding_window`; not to be confused with the
+  write-combined staging `window` below) attends only the last
+  `sliding_window` positions. The layer's window rides the scalar
+  prefetch beside the layer index, so the layers of one model that
+  slide and those that do not share ONE compiled kernel. Pages wholly
+  before every live slot's lower bound are skipped by the grid, which
+  then STARTS at the first page any slot still reads; a slot's own
+  dead pages are predicated off and its first live page is masked.
+
 On the CPU backend the wrapper runs the kernel in interpreter mode (CPU
 tests cover the exact kernel path); everywhere else it is compiled
 (ops/__init__.py has the rule).
@@ -70,8 +79,11 @@ def _block_update(s, mask, vf, m_ref, l_ref, acc_ref, vs_row):
 
 
 def _paged_kernel(layer_ref, table_ref, len_ref, *rest, page: int,
-                  kv_heads: int, quant: bool, window: int):
-    """layer_ref is read by the index maps alone: the pool's blocks
+                  kv_heads: int, quant: bool, window: int, sliding: bool):
+    """layer_ref [layer] is read by the index maps alone, or with
+    `sliding` [layer, sliding_window, first_page]: the layer's sliding
+    window (0 = a full layer) and the page the grid's page steps start
+    at. The pool's blocks
     arrive as [1, Kv, page, H], the layer dim squeezed. The grid's
     second dim is as long as the longest live context needs (the
     wrapper's dynamic bound), not max_pages. window > 0: one extra
@@ -96,6 +108,14 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *rest, page: int,
     nj = pl.num_programs(1)
     npages = nj - 1 if window else nj
     length = len_ref[slot]
+    # the slot's lower bound: the query sits at the last of its `total`
+    # positions and attends position c only where total - 1 - c < sw
+    first, lo = 0, None
+    if sliding:
+        first = layer_ref[2]
+        total = length + (wc_ref[slot] if window else 0)
+        lo = jnp.where(layer_ref[1] > 0, total - layer_ref[1], 0)
+    jp = first + j                 # the page this step attends
 
     @pl.when(j == 0)
     def _init():
@@ -103,7 +123,11 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *rest, page: int,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when((j < npages) & (j * page < length))
+    live = (j < npages) & (jp * page < length)
+    if sliding:
+        live = live & ((jp + 1) * page > lo)
+
+    @pl.when(live)
     def _compute():
         # Mosaic-friendly GQA: ONE 2D matmul against the flattened
         # [Kv*page, H] block, with cross-group scores masked off. The
@@ -131,8 +155,10 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *rest, page: int,
         rows = jax.lax.broadcasted_iota(jnp.int32, (Nq, kv_heads * page), 0)
         col_kv, col_p = cols // page, cols % page
         group_ok = col_kv == rows // G                 # head n <-> kv n//G
-        pos = j * page + col_p
+        pos = jp * page + col_p
         mask = group_ok & (pos < length)
+        if sliding:
+            mask = mask & (pos >= lo)
         _block_update(s, mask, vf, m_ref, l_ref, acc_ref,
                       vs_ref[0] if quant else None)
 
@@ -158,6 +184,8 @@ def _paged_kernel(layer_ref, table_ref, len_ref, *rest, page: int,
                 jnp.int32, (Nq, kv_heads * window), 0)
             col_kv, col_w = cols // window, cols % window
             mask = (col_kv == rows // G) & (col_w < wc_ref[slot])
+            if sliding:
+                mask = mask & (length + col_w >= lo)
             _block_update(s, mask, vf, m_ref, l_ref, acc_ref,
                           wvs_ref[0] if quant else None)
 
@@ -177,7 +205,8 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
                             win_v: jax.Array = None,
                             win_count: jax.Array = None,
                             win_k_scale: jax.Array = None,
-                            win_v_scale: jax.Array = None) -> jax.Array:
+                            win_v_scale: jax.Array = None,
+                            sliding_window=None) -> jax.Array:
     """Mesh-aware paged attention for meshed serving (SURVEY.md §7 stage 6).
 
     shard_map (flash_attention.shard_kernel: manual over every mesh
@@ -199,6 +228,10 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
     win_count [S]: the write-combined window segment (kv_write_combine)
     — slots shard over `data` with q/table/lengths, kv-heads over
     `tensor` with the pools.
+
+    sliding_window: the layer's window out of its pattern (a traced
+    scalar, 0 = a full layer; None = the model has no pattern and the
+    kernel compiles without it). It rides beside `layer` to every shard.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -218,30 +251,29 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
     in_specs = [P(d, t, None), kv_spec, kv_spec, P(), P(d, None), P(d)]
     args = [q, k_pages, v_pages, jnp.asarray(layer, jnp.int32), page_table,
             lengths]
+    # the operands only some callers have, by paged_attention's names
+    named = {}
     if k_scale_pages is not None:
-        in_specs += [P(None, None, t), P(None, None, t)]
-        args += [k_scale_pages, v_scale_pages]
+        named.update(k_scale_pages=(k_scale_pages, P(None, None, t)),
+                     v_scale_pages=(v_scale_pages, P(None, None, t)))
     if win_k is not None:
         win_spec = P(d, t, None, None)
-        in_specs += [win_spec, win_spec, P(d)]
-        args += [win_k, win_v, win_count]
+        named.update(win_k=(win_k, win_spec), win_v=(win_v, win_spec),
+                     win_count=(win_count, P(d)))
         if win_k_scale is not None:
-            in_specs += [P(d, t, None), P(d, t, None)]
-            args += [win_k_scale, win_v_scale]
+            named.update(win_k_scale=(win_k_scale, P(d, t, None)),
+                         win_v_scale=(win_v_scale, P(d, t, None)))
+    if sliding_window is not None:
+        named.update(sliding_window=(
+            jnp.asarray(sliding_window, jnp.int32), P()))
 
-        def _kernel(*a):
-            pos = a[:6] if k_scale_pages is None else a[:8]
-            rest = a[len(pos):]
-            kw = dict(win_k=rest[0], win_v=rest[1], win_count=rest[2])
-            if len(rest) > 3:
-                kw.update(win_k_scale=rest[3], win_v_scale=rest[4])
-            return paged_attention(*pos, **kw)
-        target = _kernel
-    else:
-        target = paged_attention
-    fn = shard_kernel(target, in_specs=tuple(in_specs),
+    def _kernel(*a):
+        return paged_attention(*a[:6], **dict(zip(named, a[6:])))
+
+    fn = shard_kernel(_kernel if named else paged_attention,
+                      in_specs=(*in_specs, *(s for _, s in named.values())),
                       out_specs=P(d, t, None))
-    return fn(*args)
+    return fn(*args, *(v for v, _ in named.values()))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -254,6 +286,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     win_count: jax.Array = None,
                     win_k_scale: jax.Array = None,
                     win_v_scale: jax.Array = None,
+                    sliding_window=None,
                     interpret: bool | None = None) -> jax.Array:
     """Single-token attention over each slot's paged KV.
 
@@ -277,6 +310,10 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     is one extra grid step folded into the same online-softmax
     recurrence as the page blocks (its DMA is one [Kv, W, H] block per
     slot — the staged run never round-trips through the pool).
+
+    sliding_window (int32 scalar, may be traced; None = none): the
+    slot's one query, at the last of its lengths (+ win_count)
+    positions, attends only the last `sliding_window` of them; 0 = all.
     """
     S, Nq, H = q.shape
     L, Pp, Kv, page, H2 = k_pages.shape
@@ -292,19 +329,36 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     # tail (at 32 slots of 2048 with contexts of a few hundred tokens,
     # nine steps in ten). The bound is a value, so one compiled kernel
     # serves every length.
-    npages = jnp.clip(-(-jnp.max(lengths) // page), 0 if window else 1,
-                      max_pages)
+    least = 0 if window else 1
+    npages = jnp.clip(-(-jnp.max(lengths) // page), least, max_pages)
+    meta = [layer.reshape(1)]
+    sliding = sliding_window is not None
+    if sliding:
+        # the page steps START where the first live slot's lower bound
+        # lies: a page wholly before every slot's window is no step
+        sw = jnp.asarray(sliding_window, jnp.int32)
+        total = lengths + (win_count if window else 0)
+        lo = jnp.where(sw > 0, jnp.maximum(total - sw, 0), 0)
+        first = jnp.min(jnp.where(total > 0, lo // page, max_pages))
+        first = jnp.clip(first, 0, npages - least)
+        npages = npages - first
+        meta += [sw.reshape(1), first.reshape(1)]
 
-    # scalar-prefetch operands: (layer, table, lengths[, win_count]) —
-    # the index maps see them all; the pool maps clamp j to the page
-    # grid (the trailing window step re-fetches the last page, unused)
+    # scalar-prefetch operands: (layer[, sliding window, first page],
+    # table, lengths[, win_count]) — the index maps see them all; the
+    # pool maps clamp the page to the table (the trailing window step
+    # re-fetches the last page, unused)
     npre = 4 if window else 3
 
+    def page_of(s, j, ly, t):
+        return t[s, jnp.minimum((ly[2] if sliding else 0) + j,
+                                max_pages - 1)]
+
     def pool_map(s, j, ly, t, ln, *wc):
-        return (ly[0], t[s, jnp.minimum(j, max_pages - 1)], 0, 0, 0)
+        return (ly[0], page_of(s, j, ly, t), 0, 0, 0)
 
     def pool_scale_map(s, j, ly, t, ln, *wc):
-        return (t[s, jnp.minimum(j, max_pages - 1)], 0, 0)
+        return (page_of(s, j, ly, t), 0, 0)
 
     def slot_map(s, j, ly, t, ln, *wc):
         return (s, 0, 0)
@@ -365,8 +419,10 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         ],
     )
     kernel = functools.partial(_paged_kernel, page=page, kv_heads=Kv,
-                               quant=quant, window=window)
-    prefetch = [layer.reshape(1), page_table, lengths]
+                               quant=quant, window=window, sliding=sliding)
+    # (one operand: the layer's own [1], a free reshape, as it always was)
+    prefetch = [jnp.concatenate(meta) if sliding else meta[0], page_table,
+                lengths]
     if window:
         prefetch.append(win_count)
     return pl.pallas_call(

@@ -32,7 +32,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from butterfly_tpu.core.config import ModelConfig
 from butterfly_tpu.models.common import (
-    KVCache, Params, embed_tokens, final_logits, make_mask, scan_layers)
+    KVCache, Params, embed_tokens, final_logits, make_mask, scan_layers,
+    uniform_layers_only)
 
 
 def pipeline_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -65,6 +66,7 @@ def pipeline_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     if S == 1:
         from butterfly_tpu.models.common import forward
         return forward(params, cfg, tokens, cache, positions, fresh=fresh)
+    uniform_layers_only(cfg, "pipeline parallelism")
 
     M = num_microbatches or _default_microbatches(B, S)
     if B % M != 0:
@@ -185,6 +187,7 @@ def paged_pipeline_forward(params: Params, cfg: ModelConfig,
     if S == 1:
         return paged_forward(params, cfg, tokens, cache, positions, active,
                              use_kernel, fresh, last_index)
+    uniform_layers_only(cfg, "pipeline serving")
     B, T = tokens.shape
     if positions is None:
         positions = cache.lengths[:, None] + jnp.arange(T)[None, :]
@@ -238,6 +241,7 @@ def paged_pipeline_packed(params: Params, cfg: ModelConfig,
         return paged_forward_packed(params, cfg, tokens, cache, chunk_tokens,
                                     chunk_slot, chunk_count, active,
                                     use_kernel=use_kernel)
+    uniform_layers_only(cfg, "pipeline serving")
     x, rows = packed_rows(params, cfg, tokens, cache, chunk_tokens,
                           chunk_slot, chunk_count, active)
     pools = pool_leaves(cache)
@@ -252,8 +256,8 @@ def paged_pipeline_packed(params: Params, cfg: ModelConfig,
 
             def layer(x, scanned):
                 lp, *pl = scanned
-                x, pl, _ = packed_layer(x, lp, (*pl, *pad), None, live,
-                                        cfg, use_kernel)
+                x, pl, _, _ = packed_layer(x, lp, (*pl, *pad), None, live,
+                                           cfg, use_kernel)
                 return x, pl[:len(pools)]
 
             return lax.scan(layer, inp, (layers, *pools))
@@ -264,7 +268,7 @@ def paged_pipeline_packed(params: Params, cfg: ModelConfig,
     y, pools = _run_gpipe(body, mesh, params["layers"], pools, (x, rows),
                           S, 1, x)
     logits = final_logits(params, cfg, y[rows.head])[:, 0]
-    return logits, pool_leaves(cache, pools)
+    return logits, pool_leaves(cache, pools), None
 
 
 def _gpipe_schedule(S: int, M: int, xs, step_fn, carry0):

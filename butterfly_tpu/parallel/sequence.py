@@ -51,7 +51,7 @@ from butterfly_tpu.core.config import ModelConfig
 from butterfly_tpu.models.common import (
     KVCache, Params, _cast_float, attend, attn_output, embed_tokens,
     ffn_block, final_logits, pre_norm, qkv_proj, quantize_kv,
-    update_cache_layer, update_cache_layer_q)
+    uniform_layers_only, update_cache_layer, update_cache_layer_q)
 from butterfly_tpu.ops.ring_attention import (
     INVALID_POS, block_stats, finalize_stats, merge_stats, zero_stats)
 
@@ -158,6 +158,7 @@ def sp_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     int8 codes+scales when kv_quant="int8", sharded over the S dim of
     the kv-major layout).
     """
+    uniform_layers_only(cfg, "sequence parallelism")
     N = mesh.shape["seq"]
     B, T = tokens.shape
     if T % N != 0:
@@ -229,6 +230,7 @@ def sp_decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
     whole decode run — a step past suffix.max_seq would clamp its write
     onto the last slot. Checked eagerly when lengths are concrete.
     """
+    uniform_layers_only(cfg, "sequence parallelism")
     if not isinstance(suffix.length, jax.core.Tracer):
         if int(jnp.max(suffix.length)) >= suffix.max_seq:
             raise ValueError(
@@ -364,6 +366,7 @@ def sp_chunk_body(layers, head, tokens, start, *rest, cfg: ModelConfig,
     representation: int8 codes+scales when quant, compute-dtype floats
     otherwise).
     """
+    uniform_layers_only(cfg, "the sequence-parallel prefill lane")
     if quant:
         pk, pv, pks, pvs = rest
     else:

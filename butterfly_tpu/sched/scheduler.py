@@ -638,6 +638,22 @@ class Scheduler:
         self._tick_fetch = 0.0
         self._t_host_total = 0.0
         self._t_device_total = 0.0
+        # what the routing of the mixed blocks drained this tick asked
+        # of the experts (engine _packed_scan's `load`, fetched with
+        # the blocks' tokens in the drain's one device_get): one
+        # [touched, rows_max, rows_mean] a block. Empty for a dense
+        # model. The gauges hold the newest block's.
+        self._tick_expert_loads: List = []
+        self._g_experts_touched = reg.gauge(
+            "moe_experts_touched",
+            "Distinct experts that the rows of one step touched in one "
+            "layer, the mean over the newest drained block's steps and "
+            "layers: what a dispatch that skips unrouted experts would "
+            "still stream")
+        self._g_expert_rows_max = reg.gauge(
+            "moe_expert_rows_max",
+            "Rows routed to the fullest expert of a layer in one step, "
+            "the mean over the newest drained block's steps and layers")
         # per-phase histograms in the registry: real _bucket series per
         # structural phase, so dashboards see distributions, not means
         self._h_phase = {
@@ -926,6 +942,7 @@ class Scheduler:
         self._tick_causes = []
         self._tick_fetch = 0.0
         self._tick_overlapped = None
+        self._tick_expert_loads = []
         blocks0 = self.engine.blocks_launched
         with TraceAnnotation("bf.tick", seq=self.ticklog.next_seq,
                              batch=len(self.running),
@@ -1059,7 +1076,10 @@ class Scheduler:
         fetch = min(self._tick_fetch, wall)
         self._t_device_total += fetch
         self._t_host_total += max(0.0, wall - fetch)
-        self.ticklog.record(wall, tp, fetch_s=fetch,
+        loads = self._tick_expert_loads
+        load = [float(sum(v[i] for v in loads)) / len(loads)
+                for i in range(3)] if loads else None
+        self.ticklog.record(wall, tp, fetch_s=fetch, expert_load=load,
                             overlapped=self._tick_overlapped,
                             inflight=len(self._inflight),
                             barrier_causes=self._tick_causes,
@@ -2023,7 +2043,10 @@ class Scheduler:
             budgets, sub, k, C, P)
         self._c_chunk_offered.inc(k * P * C)
         self._next_dev, self._cursor_dev = final, cursor
-        self._enqueue_block("mixed", final, (block, valid), k, snapshot,
+        # a model of experts: the block's routing load rides the same fetch
+        load = self.engine.last_expert_load
+        outs = (block, valid) if load is None else (block, valid, load)
+        self._enqueue_block("mixed", final, outs, k, snapshot,
                             pf_done, emit_vec)
         return True
 
@@ -2175,7 +2198,11 @@ class Scheduler:
             # [k, S] tokens; a mixed block adds the validity mask: a
             # lane emits at most one token per step, valid only on
             # decode steps and the completion step's first token
-            rows, ok = vals if kind == "mixed" else (vals, None)
+            rows, ok, *load = vals if kind == "mixed" else (vals, None)
+            if load and load[0][2] > 0:    # some step had a real row
+                self._tick_expert_loads.append(load[0])
+                self._g_experts_touched.set(float(load[0][0]))
+                self._g_expert_rows_max.set(float(load[0][1]))
             for slot, (req, gen) in snapshot.items():
                 if req.done or req.slot != slot or req.preemptions != gen:
                     continue

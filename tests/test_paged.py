@@ -233,7 +233,7 @@ def test_windowed_forward_hands_the_kernel_the_whole_pool(forward, kv_quant):
             return paged_forward_packed(
                 params, CFG, tokens, cache, jnp.zeros((1, 4), jnp.int32),
                 jnp.asarray([1]), jnp.asarray([0]), active, window, win_len,
-                use_kernel=True)
+                use_kernel=True)[:2]
     else:
         def fn(params, cache, window):
             return paged_forward_window(params, CFG, tokens[:, None], cache,

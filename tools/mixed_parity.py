@@ -155,10 +155,10 @@ def run(cfg, params, mesh, sv, P, seed=29):
                                   for s in slot])
                 ccnt = np.array([cnt[s] if i < len(pf) else 0
                                  for i, s in enumerate(slot)], np.int32)
-                got, fw = packed(params, cfg, jnp.asarray(tokens), fc,
-                                 jnp.asarray(ctoks), jnp.asarray(slot),
-                                 jnp.asarray(ccnt),
-                                 jnp.asarray(live & ~is_pf), fw, fl)
+                got, fw, _ = packed(params, cfg, jnp.asarray(tokens), fc,
+                                    jnp.asarray(ctoks), jnp.asarray(slot),
+                                    jnp.asarray(ccnt),
+                                    jnp.asarray(live & ~is_pf), fw, fl)
                 sides[f] = (fc, fw, fl + adv)
                 read = _reading(np.asarray(got, np.float32), want)
                 o = out[f]
