@@ -247,7 +247,8 @@ def gather_paged_layer_q(pages: jax.Array, scale_pages: jax.Array,
 # contiguous cache). With kv_write_combine the pool is READ-ONLY inside the
 # block: fresh K/V stages into a small per-slot window [L, S, Kv, W, H]
 # riding the scan carry, attention reads pool + window, and the window
-# flushes into the pool with ONE scatter per pool tensor per drain.
+# flushes into the pool once per drain, in place, page by staged page
+# (flush_paged_window).
 #
 # The window stores the pool's EXACT representation (int8 codes + f32
 # scales when the pool is quantized, pool dtype otherwise), and the
@@ -383,51 +384,106 @@ def insert_window_view_q(codes, scales, wl, wsl, base):
     return codes, scales
 
 
-def flush_paged_window(cache: PagedKVCache, window: KVWindow, win_len):
-    """Flush every slot's staged window entries into the page pool: ONE
-    scatter per pool tensor covering ALL layers (the window's write
-    combining — the per-token path pays this scatter, and the carried
-    pool copy behind it, once per token per layer).
-
-    Entries past win_len (dead-step repeats, rejected speculative
-    drafts) route to the null page exactly like write_paged_layer's
-    inactive-slot writes — the flushed pool never holds them, which is
-    what makes spec rollback exact for flushed state. Under mixed
-    dispatch (ISSUE 18) prefill-chunk K/V stages through this same
-    window: win_len for a prefill-phase slot grows by chunk widths
-    rather than 1 per step, and an admission seeds the slot's win_len
-    to 0 (the freed slot was flushed at its drain), so a fused block's
-    staged prompt entries can never interleave with a predecessor's.
-    Returns (cache with lengths advanced by win_len, zeroed win_len,
-    flushed token count [scalar]).
-    """
-    L, Pp, Kv, page, H = cache.k_pages.shape
+def _staged_runs(cache: PagedKVCache, win_len, W: int):
+    """The staged entries as (slot, page) RUNS: a slot's entries lie at
+    consecutive positions from its flushed length, so they fall into one
+    run a page they touch (a decode slot's few tokens one page or two, a
+    chunk's 128 eight or nine). Returns the number of runs (a value:
+    it follows what was staged) and an int32 table [R, 4], R the static
+    most (every slot's whole window, one page more for a start inside a
+    page), of which the first `runs` rows are real: slot, physical
+    page, the window index that lies at the page's row 0 (negative
+    where the run starts inside the page) and the slot's count of
+    entries that land. Positions at or past max_pages x page are not
+    among them: they are dropped, as the table holds no page for them."""
     S = win_len.shape[0]
+    page, mp = cache.page_size, cache.page_table.shape[1]
+    ln = cache.lengths
+    n = jnp.clip(mp * page - ln, 0, win_len)               # [S] that land
+    first = ln // page
+    runs = jnp.where(n > 0, (ln + n - 1) // page - first + 1, 0)
+    ends = jnp.cumsum(runs)
+    j = jnp.arange(S * (-(-W // page) + 1), dtype=jnp.int32)
+    slot = jnp.minimum(jnp.searchsorted(ends, j, side="right",
+                                        method="compare_all"), S - 1)
+    lp = first[slot] + j - (ends - runs)[slot]             # logical page
+    pg = cache.page_table[slot, jnp.clip(lp, 0, mp - 1)]
+    return ends[-1], jnp.stack([slot, pg, lp * page - ln[slot], n[slot]],
+                               axis=1).astype(jnp.int32)
+
+
+def flush_paged_window(cache: PagedKVCache, window: KVWindow, win_len):
+    """Flush every slot's staged window entries into the page pool, in
+    place: a loop over the (slot, page) runs that were staged
+    (_staged_runs), each reading ONE page of every pool tensor (all
+    layers: [L, 1, Kv, page, H], 512 KB of int8 for Mistral-7B),
+    replacing the run's rows from the window and writing the page back
+    with a dynamic-update-slice of the carried pool. Under donation that
+    is an in-place write on the chip, and a whole page is whole tiles
+    whatever the row's packing (an int8 row is a quarter of a packed
+    word), so the one algorithm serves float and int8 pools and their
+    scale pools ([L, 1, Kv*page], the page's row). The cost follows the
+    runs, never the pool's size or the window's: nothing is scattered,
+    transposed or copied at the pool's size (a scatter that indexes the
+    page and the in-page offset makes XLA move the layers and the
+    offset beside the page dim and back: two copies and two relayouts
+    of the whole pool a flush on the chip, PERF.md PR 35).
+
+    Entries at or past win_len (dead-step repeats, rejected speculative
+    drafts) are in no run and are written nowhere — the flushed pool
+    never holds them, which is what makes spec rollback exact for
+    flushed state. Under mixed dispatch (ISSUE 18) prefill-chunk K/V
+    stages through this same window: win_len for a prefill-phase slot
+    grows by chunk widths rather than 1 per step, and an admission
+    seeds the slot's win_len to 0 (the freed slot was flushed at its
+    drain), so a fused block's staged prompt entries can never
+    interleave with a predecessor's. Returns (cache with lengths
+    advanced by win_len, zeroed win_len, flushed token count
+    [scalar]).
+    """
+    L, _, Kv, page, _ = cache.k_pages.shape
     W = window.width
-    mp = cache.page_table.shape[1]
-    pos = cache.lengths[:, None] + jnp.arange(W)[None, :]     # [S, W]
-    valid = jnp.arange(W)[None, :] < win_len[:, None]
-    page_idx = jnp.take_along_axis(cache.page_table,
-                                   jnp.clip(pos // page, 0, mp - 1), axis=1)
-    page_idx = jnp.where(valid & (pos < mp * page), page_idx, Pp - 1)
-    flat_pages = page_idx.reshape(-1)                          # [S*W]
-    flat_off = (pos % page).reshape(-1)
-    # advanced indices at dims 1 and 3 (slices between) put the index
-    # dim FIRST: values arrive [S*W, L, Kv, H]
-    kv_vals = window.k.transpose(1, 3, 0, 2, 4).reshape(S * W, L, Kv, H)
-    vv_vals = window.v.transpose(1, 3, 0, 2, 4).reshape(S * W, L, Kv, H)
-    k_pages = cache.k_pages.at[:, flat_pages, :, flat_off].set(kv_vals)
-    v_pages = cache.v_pages.at[:, flat_pages, :, flat_off].set(vv_vals)
-    ksp, vsp = cache.k_scale_pages, cache.v_scale_pages
-    if window.quantized:
-        # flat scale dim is kv-major: col = kv*page + offset; adjacent
-        # advanced dims (1, 2) stay in place: values arrive [L, S*W, Kv]
-        cols = jnp.arange(Kv)[None, :] * page + flat_off[:, None]
-        ks_vals = window.k_scale.transpose(0, 1, 3, 2).reshape(L, S * W, Kv)
-        vs_vals = window.v_scale.transpose(0, 1, 3, 2).reshape(L, S * W, Kv)
-        ksp = ksp.at[:, flat_pages[:, None], cols].set(ks_vals)
-        vsp = vsp.at[:, flat_pages[:, None], cols].set(vs_vals)
-    cache = cache._replace(k_pages=k_pages, v_pages=v_pages,
+    seg = min(page, W)          # window rows one run can take
+    runs, table = _staged_runs(cache, win_len, W)
+    rows = jnp.arange(page, dtype=jnp.int32)
+
+    def body(i, pools):
+        slot, pg, base, n = lax.dynamic_slice(table, (i, 0), (1, 4))[0]
+        # the page's row r holds window index base + r: a slice of the
+        # window from the nearest index that keeps it inside, rolled so
+        # that its rows meet the page's
+        keep = (base + rows >= 0) & (base + rows < n)           # [page]
+        at = jnp.clip(base, 0, W - seg)
+
+        def merge(pool, staged):
+            """pool [L, P, ...] with the run's rows of page pg taken
+            from staged [L, S, Kv, W, ...]."""
+            tail = staged.shape[4:]
+            old = lax.dynamic_slice(pool, (0, pg) + (0,) * (pool.ndim - 2),
+                                    (L, 1) + pool.shape[2:])
+            new = lax.dynamic_slice(
+                staged, (0, slot, 0, at) + (0,) * len(tail),
+                (L, 1, Kv, seg) + tail)
+            if seg < page:
+                new = jnp.pad(new, [(0, 0)] * 3 + [(0, page - seg)]
+                              + [(0, 0)] * len(tail))
+            new = jnp.roll(new, at - base, axis=3)
+            mask = keep.reshape((page,) + (1,) * len(tail))
+            # a scale pool's row is [Kv*page], kv-major: the same rows
+            new = jnp.where(mask, new, old.reshape(new.shape))
+            return lax.dynamic_update_slice(
+                pool, new.reshape(old.shape), (0, pg) + (0,) * (pool.ndim - 2))
+
+        kp, vp, ksp, vsp = pools
+        kp, vp = merge(kp, window.k), merge(vp, window.v)
+        if window.quantized:
+            ksp, vsp = merge(ksp, window.k_scale), merge(vsp, window.v_scale)
+        return kp, vp, ksp, vsp
+
+    kp, vp, ksp, vsp = lax.fori_loop(
+        0, runs, body, (cache.k_pages, cache.v_pages,
+                        cache.k_scale_pages, cache.v_scale_pages))
+    cache = cache._replace(k_pages=kp, v_pages=vp,
                            k_scale_pages=ksp, v_scale_pages=vsp,
                            lengths=cache.lengths + win_len)
     return cache, jnp.zeros_like(win_len), win_len.sum()
@@ -494,9 +550,9 @@ def permute_paged_tail(cache: PagedKVCache, perm, active=None
     src_pages, src_off = flat(base + perm)
     dst_pages, dst_off = flat(base + jnp.arange(C)[None, :])
     # advanced indices at dims 1 and 3 (slice between) put the index
-    # dim FIRST: values move as [S*C, L, Kv, H] (flush_paged_window's
-    # idiom); the gather materializes before the scatter, so the
-    # overlapping in-place permute is safe
+    # dim FIRST: values move as [S*C, L, Kv, H]; the gather
+    # materializes before the scatter, so the overlapping in-place
+    # permute is safe
     k_pages = cache.k_pages.at[:, dst_pages, :, dst_off].set(
         cache.k_pages[:, src_pages, :, src_off])
     v_pages = cache.v_pages.at[:, dst_pages, :, dst_off].set(

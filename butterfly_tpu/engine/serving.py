@@ -591,8 +591,9 @@ class ServingEngine:
             self._win_len = self.carry(np.zeros((self.num_slots,), np.int32))
 
     def flush_kv_window(self):
-        """Flush every staged window entry into the page pool: ONE
-        scatter per pool tensor (cache/paged.py flush_paged_window).
+        """Flush every staged window entry into the page pool, in
+        place, one page of the pool a staged (slot, page) run
+        (cache/paged.py flush_paged_window).
         Dispatched like any block — device order puts it after every
         staging dispatch and before anything chained later — so the
         scheduler calls it at its drain points, before page
@@ -715,9 +716,8 @@ class ServingEngine:
         seq-sharded through sp_chunk_body (ring over the fresh chunk,
         flash-stats merge with the replicated prefix), then scatter the
         chunk's K/V into the page pool with ONE all-layer scatter per
-        pool tensor (flush_paged_window's idiom) — so the prompt lands
-        paged, prefix-registry-visible and evictable, and decode
-        proceeds as an ordinary paged slot.
+        pool tensor — so the prompt lands paged, prefix-registry-visible
+        and evictable, and decode proceeds as an ordinary paged slot.
         """
         prog = self._sp_chunk_progs.get(C)
         if prog is not None:

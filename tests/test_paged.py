@@ -272,3 +272,195 @@ def test_gather_indexes_layer_and_page_at_once(quant):
             want = (gather_paged_layer(pool[layer], table),)
         for a, b in zip(got, want):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- the window flush writes what was staged, in place (ISSUE 35) -----------
+
+def _flush_by_scatter(cache, window, win_len):
+    """The flush as it was until PR 35, kept as the oracle: ONE scatter
+    per pool tensor over ALL S x W window entries, the entries at or
+    past win_len (and the positions past the table) routed to the null
+    page. On the chip it copied and relaid the whole pool to land a few
+    hundred rows; its result, on every page but the null page, is what
+    the flush must still leave."""
+    L, Pp, Kv, page, H = cache.k_pages.shape
+    S = win_len.shape[0]
+    W = window.width
+    mp = cache.page_table.shape[1]
+    pos = cache.lengths[:, None] + jnp.arange(W)[None, :]     # [S, W]
+    valid = jnp.arange(W)[None, :] < win_len[:, None]
+    page_idx = jnp.take_along_axis(cache.page_table,
+                                   jnp.clip(pos // page, 0, mp - 1), axis=1)
+    page_idx = jnp.where(valid & (pos < mp * page), page_idx, Pp - 1)
+    flat_pages = page_idx.reshape(-1)                          # [S*W]
+    flat_off = (pos % page).reshape(-1)
+    kv_vals = window.k.transpose(1, 3, 0, 2, 4).reshape(S * W, L, Kv, H)
+    vv_vals = window.v.transpose(1, 3, 0, 2, 4).reshape(S * W, L, Kv, H)
+    k_pages = cache.k_pages.at[:, flat_pages, :, flat_off].set(kv_vals)
+    v_pages = cache.v_pages.at[:, flat_pages, :, flat_off].set(vv_vals)
+    ksp, vsp = cache.k_scale_pages, cache.v_scale_pages
+    if window.quantized:
+        cols = jnp.arange(Kv)[None, :] * page + flat_off[:, None]
+        ks_vals = window.k_scale.transpose(0, 1, 3, 2).reshape(L, S * W, Kv)
+        vs_vals = window.v_scale.transpose(0, 1, 3, 2).reshape(L, S * W, Kv)
+        ksp = ksp.at[:, flat_pages[:, None], cols].set(ks_vals)
+        vsp = vsp.at[:, flat_pages[:, None], cols].set(vs_vals)
+    cache = cache._replace(k_pages=k_pages, v_pages=v_pages,
+                           k_scale_pages=ksp, v_scale_pages=vsp,
+                           lengths=cache.lengths + win_len)
+    return cache, jnp.zeros_like(win_len), win_len.sum()
+
+
+def _staged_case(quant, lengths, W=8, L=3, Kv=2, page=4, H=8, mp=3,
+                 seed=0):
+    """A pool and a window full of random bytes (stale rows past
+    win_len included), each slot on distinct pages in shuffled order."""
+    from butterfly_tpu.cache.paged import KVWindow
+    S = len(lengths)
+    P = S * mp + 2                              # one page spare, one null
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    if quant:
+        rnd = lambda k, sh: jax.random.randint(k, sh, -127, 128, jnp.int8)
+        sc = lambda k, sh: jax.random.uniform(k, sh, jnp.float32)
+    else:
+        rnd = lambda k, sh: jax.random.normal(k, sh, jnp.float32)
+        sc = lambda k, sh: None
+    table = np.random.RandomState(seed).permutation(P - 1)[:S * mp]
+    cache = PagedKVCache(
+        k_pages=rnd(ks[0], (L, P, Kv, page, H)),
+        v_pages=rnd(ks[1], (L, P, Kv, page, H)),
+        page_table=jnp.asarray(table.reshape(S, mp), jnp.int32),
+        lengths=jnp.asarray(lengths, jnp.int32),
+        k_scale_pages=sc(ks[2], (L, P, Kv * page)),
+        v_scale_pages=sc(ks[3], (L, P, Kv * page)))
+    window = KVWindow(
+        k=rnd(ks[4], (L, S, Kv, W, H)), v=rnd(ks[5], (L, S, Kv, W, H)),
+        k_scale=sc(ks[6], (L, S, Kv, W)), v_scale=sc(ks[7], (L, S, Kv, W)))
+    return cache, window
+
+
+def _pools(cache):
+    return [np.asarray(p) for p in (cache.k_pages, cache.v_pages,
+                                    cache.k_scale_pages, cache.v_scale_pages)
+            if p is not None]
+
+
+#: (flushed lengths, staged entries) a slot; pages of 4, a window of 8,
+#: a table of 3 pages (12 positions)
+FLUSH_CASES = {
+    "nothing": ([0, 5, 3, 7], [0, 0, 0, 0]),
+    "one": ([0, 5, 3, 7], [1, 0, 1, 1]),
+    "straddling": ([2, 3, 7, 1], [3, 5, 2, 8]),
+    "whole-window": ([0, 1, 4, 3], [8, 8, 8, 8]),
+    # slot 0 lands 8..11 of 8..15, slot 1 10..11 of 10..13, slot 2
+    # none (it is full), slot 3 5..11 of 5..12: the rest is dropped
+    "past-the-table": ([8, 10, 12, 5], [8, 4, 3, 8]),
+}
+
+
+@pytest.mark.parametrize("case", list(FLUSH_CASES))
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+def test_flush_equals_the_scatter_it_replaced(quant, case):
+    """To the byte on every page but the null page, codes and scales;
+    the null page keeps what it held (an entry at or past win_len goes
+    nowhere); lengths advance, win_len comes back zeroed, and the count
+    is the entries staged."""
+    from butterfly_tpu.cache.paged import flush_paged_window
+    lengths, staged = FLUSH_CASES[case]
+    cache, window = _staged_case(quant, lengths)
+    win_len = jnp.asarray(staged, jnp.int32)
+    want, _, _ = jax.jit(_flush_by_scatter)(cache, window, win_len)
+    got, zeroed, count = jax.jit(flush_paged_window)(cache, window, win_len)
+    before = _pools(cache)
+    for new, old, was in zip(_pools(got), _pools(want), before):
+        np.testing.assert_array_equal(new[:, :-1], old[:, :-1])
+        np.testing.assert_array_equal(new[:, -1], was[:, -1])
+    np.testing.assert_array_equal(np.asarray(got.lengths),
+                                  np.asarray(lengths) + np.asarray(staged))
+    np.testing.assert_array_equal(np.asarray(got.page_table),
+                                  np.asarray(cache.page_table))
+    assert not np.asarray(zeroed).any() and zeroed.dtype == win_len.dtype
+    assert int(count) == sum(staged)
+    # the rows that changed are the rows that were staged and land
+    landed = sum(min(n, max(0, cache.max_seq - ln))
+                 for ln, n in zip(lengths, staged))
+    rows = (_pools(got)[0] != before[0]).any(axis=(0, 2, 4)).sum()
+    assert rows == landed <= sum(staged), (rows, landed)
+
+
+@pytest.mark.parametrize("W", [1, 2, 16])
+def test_flush_of_a_window_narrower_or_wider_than_a_page(W):
+    """One step a tick keeps a window of 1 or 2 entries beside pages of
+    4; a long block one of several pages."""
+    from butterfly_tpu.cache.paged import flush_paged_window
+    cache, window = _staged_case(True, [3, 0, 6, 11], W=W, mp=8)
+    win_len = jnp.asarray([W, 0, max(1, W - 1), W], jnp.int32)
+    want, _, _ = jax.jit(_flush_by_scatter)(cache, window, win_len)
+    got, _, count = jax.jit(flush_paged_window)(cache, window, win_len)
+    for new, old in zip(_pools(got), _pools(want)):
+        np.testing.assert_array_equal(new[:, :-1], old[:, :-1])
+    assert int(count) == int(win_len.sum())
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+def test_flush_holds_no_scatter_into_the_pool_and_no_transpose_of_it(quant):
+    """Read off the jaxpr, so it holds on any backend: nothing scatters
+    into a pool (one scatter over all S x W entries made XLA move the
+    layers and the in-page offset beside the page dim and back: two
+    copies and two relayouts of the whole pool on the chip), nothing
+    transposes or reshapes one, and the only operations whose result
+    has a pool's shape are the loop that carries it and the
+    dynamic-update-slice that writes one page of it in place."""
+    from butterfly_tpu.cache.paged import flush_paged_window
+    cache, window = _staged_case(quant, [2, 3, 7, 1])
+    win_len = jnp.asarray([3, 5, 2, 8], jnp.int32)
+    eqns = list(_eqns(jax.make_jaxpr(flush_paged_window)(
+        cache, window, win_len).jaxpr))
+    pools = {p.shape for p in (cache.k_pages, cache.k_scale_pages)
+             if p is not None}
+    assert window.k.shape not in pools
+    makes = {e.primitive.name for e in eqns
+             if any(v.aval.shape in pools for v in e.outvars)}
+    assert makes == {"while", "dynamic_update_slice"}, makes
+    takes = {e.primitive.name for e in eqns
+             if any(getattr(v, "aval", None) is not None
+                    and v.aval.shape in pools for v in e.invars)}
+    assert takes <= {"while", "dynamic_update_slice", "dynamic_slice"}, takes
+    # and nothing is as large as the window's S x W entries either: the
+    # work follows what was staged
+    S, W = win_len.shape[0], window.width
+    entries = window.k.size // (S * W)
+    big = [e.primitive.name for e in eqns if e.primitive.name != "while"
+           and any(v.aval.size >= S * W * entries and
+                   v.aval.shape not in pools for v in e.outvars)]
+    assert not big, big
+
+
+def test_flush_under_a_mesh_equals_the_unsharded_flush():
+    """Four CPU devices on the `tensor` axis, the pools and the window
+    sharded over their KV heads as the serving engine lays them out:
+    the same bytes as on one device, and the pools keep their layout."""
+    from butterfly_tpu.cache.paged import flush_paged_window
+    from butterfly_tpu.core.config import MeshConfig
+    from butterfly_tpu.core.mesh import make_mesh
+    from butterfly_tpu.parallel.partition import (
+        kv_window_specs, paged_cache_specs, to_shardings)
+    mesh = make_mesh(MeshConfig(tensor=4), jax.devices()[:4])
+    cfg = tiny("llama", num_heads=8, num_kv_heads=4, head_dim=8)
+    for quant in (False, True):
+        cache, window = _staged_case(quant, [2, 3, 7, 1], Kv=4)
+        win_len = jnp.asarray([3, 5, 2, 8], jnp.int32)
+        want, _, n = jax.jit(flush_paged_window)(cache, window, win_len)
+        csh = to_shardings(paged_cache_specs(cfg, mesh, 4, quant=quant), mesh)
+        wsh = to_shardings(kv_window_specs(cfg, mesh, 4, quant=quant), mesh)
+        with mesh:
+            got, zeroed, m = jax.jit(flush_paged_window,
+                                     donate_argnums=(0, 2))(
+                jax.device_put(cache, csh), jax.device_put(window, wsh),
+                win_len)
+        assert got.k_pages.sharding.is_equivalent_to(csh.k_pages, 5)
+        for new, old in zip(_pools(got), _pools(want)):
+            np.testing.assert_array_equal(new, old)
+        np.testing.assert_array_equal(np.asarray(got.lengths),
+                                      np.asarray(want.lengths))
+        assert int(m) == int(n) and not np.asarray(zeroed).any()

@@ -1166,6 +1166,35 @@ def test_kv_window_cancel_mid_block_flush_before_reclaim():
     assert sched.alloc.free_pages == sched.alloc.num_pages
 
 
+@pytest.mark.parametrize("spec", [0, 3], ids=["mixed", "speculative"])
+def test_kv_window_flushed_counter_counts_the_rows_the_flush_writes(spec):
+    """kv_window_tokens_flushed_total is the count the flush returns,
+    and the flush writes exactly that many rows of the pool (PR 35: the
+    staged entries and no other; a rejected draft or a dead step's
+    repeat is in no run). Counted off the pool itself: the (page,
+    offset) rows whose bytes a flush changed."""
+    sched, _ = make_sched(max_batch=2, speculative_gamma=spec)
+    eng = sched.engine
+    flush, rows = eng._flush, []
+
+    def counting(cache, window, win_len):
+        before = np.asarray(cache.k_pages)
+        out = flush(cache, window, win_len)
+        changed = (np.asarray(out[0].k_pages) != before).any(axis=(0, 2, 4))
+        assert not changed[-1].any()            # the null page: never
+        rows.append((int(changed.sum()), int(out[2])))
+        return out
+
+    eng._flush = counting
+    reqs = [sched.submit(p, max_new_tokens=12)
+            for p in ([5, 7, 11, 13, 2], [3, 1], [9, 9, 4])]
+    sched.run_until_done()
+    assert all(r.state == "finished" for r in reqs)
+    assert rows and all(wrote == said for wrote, said in rows), rows
+    assert sched.metrics()["kv_window_tokens_flushed_total"] == \
+        sum(said for _, said in rows) > 0
+
+
 def test_kv_window_spec_rejection_never_flushed():
     """The rollback-by-construction contract: a rejected draft's K/V
     sits past win_len and is NEVER flushed, so pool bytes beyond each
