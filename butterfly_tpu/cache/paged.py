@@ -57,8 +57,10 @@ from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
 # wrappers import nothing project-local at module level.
 from butterfly_tpu.models.common import (
     _cast_float, attend, attn_output, early_router_logits, embed_tokens,
-    expert_load, ffn_block, final_logits, layer_mask, layer_pattern_of,
-    layer_stack, make_mask, pre_norm, qkv_proj, quantize_kv, router_logits)
+    expert_load, ffn_block, final_logits, index_proj, index_scores,
+    indexer_unsupported, layer_mask, layer_pattern_of, layer_stack,
+    make_mask, pre_norm, qkv_proj, quantize_kv, router_logits, select_mask,
+    select_topk)
 from butterfly_tpu.ops import note_kernel
 from butterfly_tpu.ops.flash_attention import flash_attention_sharded
 from butterfly_tpu.ops.paged_attention import paged_attention_sharded
@@ -71,6 +73,10 @@ class PagedKVCache(NamedTuple):
     lengths: jax.Array     # [slots] int32 tokens written per slot
     k_scale_pages: Optional[jax.Array] = None  # [L, P, Kv*page] f32 iff int8
     v_scale_pages: Optional[jax.Array] = None
+    # [L, P, 1, page, Hi]: the index keys of a model with a sparse-
+    # attention indexer (one a token), a third kind of cached row under
+    # the same page table, free list, window and flush
+    ki_pages: Optional[jax.Array] = None
 
     @property
     def page_size(self) -> int:
@@ -116,6 +122,9 @@ def init_paged_cache(cfg: ModelConfig, runtime: RuntimeConfig,
     shape = (cfg.num_layers, P, cfg.num_kv_heads, page, cfg.head_dim)
     if runtime.kv_quant not in ("none", "int8"):
         raise ValueError(f"unknown kv quant {runtime.kv_quant!r}")
+    if runtime.kv_quant == "int8":
+        indexer_unsupported(cfg, "the int8 KV cache")
+    ki_shape = (cfg.num_layers, P, 1, page, cfg.index_head_dim)
 
     def build():
         table = jnp.full((runtime.max_batch_size, max_pages), P - 1,
@@ -134,9 +143,27 @@ def init_paged_cache(cfg: ModelConfig, runtime: RuntimeConfig,
             k_pages=jnp.zeros(shape, dtype),
             v_pages=jnp.zeros(shape, dtype),
             page_table=table, lengths=lengths,
+            ki_pages=jnp.zeros(ki_shape, dtype) if cfg.has_indexer else None,
         )
 
     return jax.jit(build, out_shardings=shardings)()
+
+
+def _write_targets(page_table, num_pages: int, page: int, start, T: int,
+                   active=None):
+    """(page, offset), each [B*T], of T new tokens a slot from `start`
+    [B]: where write_paged_layer and write_index_layer scatter."""
+    pos = start[:, None] + jnp.arange(T)[None, :]          # [B,T] absolute
+    page_idx = jnp.take_along_axis(page_table, pos // page, axis=1)  # [B,T]
+    # Prefill buckets pad T past the true prompt, so pos can exceed the
+    # table row's capacity. Route those positions to the null page
+    # explicitly rather than relying on take_along_axis's out-of-bounds
+    # fill (INT32_MIN) being dropped by the scatter.
+    page_idx = jnp.where(pos < page_table.shape[1] * page, page_idx,
+                         num_pages - 1)
+    if active is not None:
+        page_idx = jnp.where(active[:, None], page_idx, num_pages - 1)
+    return page_idx.reshape(-1), (pos % page).reshape(-1)
 
 
 def write_paged_layer(k_pages: jax.Array, v_pages: jax.Array,
@@ -169,18 +196,8 @@ def write_paged_layer(k_pages: jax.Array, v_pages: jax.Array,
     """
     Pp, Kv, page, H = k_pages.shape
     B, T = k.shape[0], k.shape[1]
-    pos = start[:, None] + jnp.arange(T)[None, :]          # [B,T] absolute
-    page_idx = jnp.take_along_axis(page_table, pos // page, axis=1)  # [B,T]
-    # Prefill buckets pad T past the true prompt, so pos can exceed the
-    # table row's capacity. Route those positions to the null page
-    # explicitly rather than relying on take_along_axis's out-of-bounds
-    # fill (INT32_MIN) being dropped by the scatter below.
-    page_idx = jnp.where(pos < page_table.shape[1] * page, page_idx, Pp - 1)
-    if active is not None:
-        page_idx = jnp.where(active[:, None], page_idx, Pp - 1)
-    offset = pos % page                                     # [B,T]
-    flat_pages = page_idx.reshape(-1)
-    flat_off = offset.reshape(-1)
+    flat_pages, flat_off = _write_targets(page_table, Pp, page, start, T,
+                                          active)
     if k_scale_pages is not None:
         kq, ks = quantize_kv(k)   # codes [B,T,Kv,H], scales [B,T,Kv]
         vq, vs = quantize_kv(v)
@@ -200,6 +217,19 @@ def write_paged_layer(k_pages: jax.Array, v_pages: jax.Array,
     k_pages = k_pages.at[flat_pages, :, flat_off].set(kf)
     v_pages = v_pages.at[flat_pages, :, flat_off].set(vf)
     return k_pages, v_pages, None, None
+
+
+def write_index_layer(ki_pages: jax.Array, page_table: jax.Array,
+                      ki: jax.Array, start: jax.Array,
+                      active: Optional[jax.Array] = None) -> jax.Array:
+    """write_paged_layer for the third kind of row: index keys ki
+    [B, T, Hi] into one layer's ki_pages [P, 1, page, Hi], at the pages
+    and offsets their keys and values go to."""
+    Pp, _, page, _ = ki_pages.shape
+    B, T = ki.shape[:2]
+    pages, off = _write_targets(page_table, Pp, page, start, T, active)
+    return ki_pages.at[pages, 0, off].set(
+        ki.reshape(B * T, -1).astype(ki_pages.dtype))
 
 
 def _table_pages(pages: jax.Array, page_table: jax.Array, layer):
@@ -282,6 +312,8 @@ class KVWindow(NamedTuple):
     v: jax.Array
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
+    ki: Optional[jax.Array] = None   # [L, S, 1, W, Hi] iff the pool has
+                                     # index keys (PagedKVCache.ki_pages)
 
     @property
     def width(self) -> int:
@@ -309,8 +341,10 @@ def init_kv_window(cache: PagedKVCache, width: int,
                 v=jnp.zeros(shape, jnp.int8),
                 k_scale=jnp.zeros(shape[:-1], jnp.float32),
                 v_scale=jnp.zeros(shape[:-1], jnp.float32))
+        ki = None if cache.ki_pages is None else jnp.zeros(
+            (L, S, 1, width, cache.ki_pages.shape[-1]), cache.ki_pages.dtype)
         return KVWindow(k=jnp.zeros(shape, dtype),
-                        v=jnp.zeros(shape, dtype))
+                        v=jnp.zeros(shape, dtype), ki=ki)
 
     return jax.jit(build, out_shardings=shardings)()
 
@@ -349,6 +383,17 @@ def stage_window_layer(wk, wv, k, v, win_len, wks=None, wvs=None,
     wk = wk.at[rows, :, idx].set(k.astype(wk.dtype), mode="drop")
     wv = wv.at[rows, :, idx].set(v.astype(wv.dtype), mode="drop")
     return wk, wv, None, None
+
+
+@jax.named_scope("kv_window_write")
+def stage_index_layer(wki, ki, win_len, rows=None):
+    """stage_window_layer for the index keys: ki [B, T, Hi] into this
+    layer's window slice wki [S, 1, W, Hi], at the window indices their
+    keys and values take."""
+    B, T = ki.shape[:2]
+    rows = (jnp.arange(B) if rows is None else rows)[:, None]
+    idx = win_len[:, None] + jnp.arange(T)[None, :]
+    return wki.at[rows, 0, idx].set(ki.astype(wki.dtype), mode="drop")
 
 
 @jax.named_scope("kv_gather")
@@ -416,7 +461,8 @@ def flush_paged_window(cache: PagedKVCache, window: KVWindow, win_len):
     """Flush every slot's staged window entries into the page pool, in
     place: a loop over the (slot, page) runs that were staged
     (_staged_runs), each reading ONE page of every pool tensor (all
-    layers: [L, 1, Kv, page, H], 512 KB of int8 for Mistral-7B),
+    layers: [L, 1, Kv, page, H], 512 KB of int8 for Mistral-7B; the
+    index keys of a model with an indexer are one more such tensor),
     replacing the run's rows from the window and writing the page back
     with a dynamic-update-slice of the carried pool. Under donation that
     is an in-place write on the chip, and a whole page is whole tiles
@@ -441,11 +487,12 @@ def flush_paged_window(cache: PagedKVCache, window: KVWindow, win_len):
     advanced by win_len, zeroed win_len, flushed token count
     [scalar]).
     """
-    L, _, Kv, page, _ = cache.k_pages.shape
+    L, _, _, page, _ = cache.k_pages.shape
     W = window.width
     seg = min(page, W)          # window rows one run can take
     runs, table = _staged_runs(cache, win_len, W)
     rows = jnp.arange(page, dtype=jnp.int32)
+    staged_leaves = window_leaves(window)
 
     def body(i, pools):
         slot, pg, base, n = lax.dynamic_slice(table, (i, 0), (1, 4))[0]
@@ -463,7 +510,7 @@ def flush_paged_window(cache: PagedKVCache, window: KVWindow, win_len):
                                     (L, 1) + pool.shape[2:])
             new = lax.dynamic_slice(
                 staged, (0, slot, 0, at) + (0,) * len(tail),
-                (L, 1, Kv, seg) + tail)
+                (L, 1, staged.shape[2], seg) + tail)
             if seg < page:
                 new = jnp.pad(new, [(0, 0)] * 3 + [(0, page - seg)]
                               + [(0, 0)] * len(tail))
@@ -474,18 +521,11 @@ def flush_paged_window(cache: PagedKVCache, window: KVWindow, win_len):
             return lax.dynamic_update_slice(
                 pool, new.reshape(old.shape), (0, pg) + (0,) * (pool.ndim - 2))
 
-        kp, vp, ksp, vsp = pools
-        kp, vp = merge(kp, window.k), merge(vp, window.v)
-        if window.quantized:
-            ksp, vsp = merge(ksp, window.k_scale), merge(vsp, window.v_scale)
-        return kp, vp, ksp, vsp
+        return tuple(merge(p, w) for p, w in zip(pools, staged_leaves))
 
-    kp, vp, ksp, vsp = lax.fori_loop(
-        0, runs, body, (cache.k_pages, cache.v_pages,
-                        cache.k_scale_pages, cache.v_scale_pages))
-    cache = cache._replace(k_pages=kp, v_pages=vp,
-                           k_scale_pages=ksp, v_scale_pages=vsp,
-                           lengths=cache.lengths + win_len)
+    pools = lax.fori_loop(0, runs, body, pool_leaves(cache))
+    cache = pool_leaves(cache, pools)._replace(
+        lengths=cache.lengths + win_len)
     return cache, jnp.zeros_like(win_len), win_len.sum()
 
 
@@ -520,7 +560,8 @@ def permute_window_tail(window: KVWindow, win_len, perm) -> KVWindow:
         gs = lambda a: jnp.take_along_axis(             # noqa: E731
             a, idx[None, :, None, :], axis=3)           # [L,S,Kv,W]
         ks, vs = gs(window.k_scale), gs(window.v_scale)
-    return KVWindow(k=k, v=v, k_scale=ks, v_scale=vs)
+    ki = None if window.ki is None else gather(window.ki)
+    return KVWindow(k=k, v=v, k_scale=ks, v_scale=vs, ki=ki)
 
 
 def permute_paged_tail(cache: PagedKVCache, perm, active=None
@@ -567,8 +608,12 @@ def permute_paged_tail(cache: PagedKVCache, perm, active=None
             ksp[:, src_pages[:, None], src_cols])
         vsp = vsp.at[:, dst_pages[:, None], dst_cols].set(
             vsp[:, src_pages[:, None], src_cols])
+    kip = cache.ki_pages
+    if kip is not None:
+        kip = kip.at[:, dst_pages, :, dst_off].set(
+            kip[:, src_pages, :, src_off])
     return cache._replace(k_pages=k_pages, v_pages=v_pages,
-                          k_scale_pages=ksp, v_scale_pages=vsp)
+                          k_scale_pages=ksp, v_scale_pages=vsp, ki_pages=kip)
 
 
 # ---------------------------------------------------------------------------
@@ -586,10 +631,106 @@ def _settled(*view):
     return lax.optimization_barrier(view)
 
 
+def _pool_rows(pages: jax.Array, layer, pg: jax.Array, off: jax.Array):
+    """Rows of the pool by (page, offset): pages [L, P, Kv, page, H],
+    pg/off [B, K] -> [B, K, Kv, H]. The pool is seen as its rows
+    [L*P*Kv*page, H] (free: the two minor dims are whole tiles) and one
+    `take` reads the K x Kv rows a stream selected and no others; of
+    the forms of this gather tried on the chip it is the fastest
+    (PERF.md PR 36)."""
+    L, P, Kv, page, H = pages.shape
+    row = ((layer * P + pg)[..., None] * Kv + jnp.arange(Kv)) * page \
+        + off[..., None]
+    return jnp.take(pages.reshape(L * P * Kv * page, H), row, axis=0)
+
+
+def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
+                        page_table, positions, mask, win=None, wki=None,
+                        select: str = "index"):
+    """paged_attend for a model with a sparse-attention indexer: each
+    query scores every live position of its stream against the cached
+    index keys (models.common.index_scores) and attends the
+    cfg.index_topk that score highest. q [B,T,Nq,H]; qi [B,T,Ni,Hi] and
+    w [B,T,Ni] from index_proj; kp/vp [L,P,Kv,page,H] and kip
+    [L,P,1,page,Hi] the whole pools, `layer` the one to read; mask
+    [B,T,S_max] what each query MAY attend (causal, live); win as
+    paged_attend's, AFTER staging, with wki [B,1,W,Hi] the window's
+    index keys. Returns (out [B,T,Nq,H], count f32 [3]): the rows that
+    had anything to attend, the positions they could attend and the
+    positions they read, summed over the rows.
+
+    A decode row (T == 1) READS ONLY WHAT IT SELECTED: the index keys
+    of its context (128 B a position), then the selected rows of keys
+    and values out of the pool by (page, offset) (_pool_rows), beside
+    the window's few staged entries, which are read whole and masked
+    to the selection. A chunk's rows (T > 1) share one stream's prefix:
+    it is read once, whole, and masked row by row (select_mask), which
+    is the same mathematics and cheaper than T gathers. So is any
+    program whose whole context is no longer than index_topk.
+
+    select (tools/sparse_parity.py's controls; "index" everywhere
+    else): "all" attends every position, "recent" the last index_topk
+    in place of the indexer's choice."""
+    B, T = q.shape[:2]
+    page = kp.shape[3]
+    S_max = page_table.shape[1] * page
+    topk = cfg.index_topk
+    if win is not None:
+        wk, wv, _, _, win_len = win
+        base = positions[:, 0] - win_len    # flushed pool length per row
+    with jax.named_scope("attn_index"):
+        # the stream's index keys as keys are viewed: one KV head
+        kiv = gather_paged_layer(kip, page_table, layer)   # [B,S_max,1,Hi]
+        if win is not None:
+            kiv = insert_window_view(kiv, wki, base)
+        scores = index_scores(qi, w, kiv[:, :, 0])         # [B,T,S_max]
+    if select == "all":
+        scores = jnp.zeros_like(scores)
+        topk = S_max
+    elif select == "recent":
+        scores = jnp.broadcast_to(jnp.arange(S_max, dtype=scores.dtype),
+                                  scores.shape)
+    live = jnp.sum(mask, axis=-1)                          # [B,T]
+    if T > 1 or S_max <= topk:
+        sel = select_mask(scores, mask, topk)
+        with jax.named_scope("attn_sparse"):
+            ck = gather_paged_layer(kp, page_table, layer)
+            cv = gather_paged_layer(vp, page_table, layer)
+            if win is not None:
+                ck = insert_window_view(ck, wk, base)
+                cv = insert_window_view(cv, wv, base)
+            out = attend(q, *_settled(ck, cv), sel, cfg)
+        read = jnp.sum(sel, axis=-1)
+    else:
+        idx, ok, sel = select_topk(scores[:, 0], mask[:, 0], topk,
+                                   with_mask=win is not None)
+        with jax.named_scope("attn_sparse"):
+            if win is not None:
+                # a selected position at or past the flushed length is
+                # staged, not in the pool: the window's W entries ride
+                # whole behind the selected rows, masked to the selection
+                ok = ok & (idx < base[:, None])
+                wpos = base[:, None] + jnp.arange(wk.shape[2])[None, :]
+                wsel = jnp.take_along_axis(
+                    sel, jnp.minimum(wpos, S_max - 1), axis=1) \
+                    & (wpos < S_max)
+            pg = jnp.take_along_axis(page_table, idx // page, axis=1)
+            kg = _pool_rows(kp, layer, pg, idx % page)     # [B,K,Kv,H]
+            vg = _pool_rows(vp, layer, pg, idx % page)
+            if win is not None:
+                kg = jnp.concatenate([kg, wk.transpose(0, 2, 1, 3)], axis=1)
+                vg = jnp.concatenate([vg, wv.transpose(0, 2, 1, 3)], axis=1)
+                ok = jnp.concatenate([ok, wsel], axis=1)
+            out = attend(q, kg, vg, ok[:, None], cfg)
+        read = jnp.sum(ok, axis=-1)[:, None]
+    count = jnp.stack([jnp.sum(live > 0), jnp.sum(live), jnp.sum(read)])
+    return out, count.astype(jnp.float32)
+
+
 def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
                  positions, mask, active, use_kernel: bool, fresh: bool,
                  ksp=None, vsp=None, win=None, force_dense: bool = False,
-                 sliding_window=None):
+                 sliding_window=None, index=None):
     """One layer's attention for [B,T] queries whose K/V is already
     written: the dispatch between the paged kernel (T == 1), the flash
     kernels (fresh chunk; warm chunk over the cached prefix) and the
@@ -608,7 +749,17 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
     = a full layer; None = the model has none): every branch attends
     position j from p only where p - j < sliding_window, the kernels by
     their prefetched scalar, the dense gather by its mask.
-    Returns [B,T,Nq,H]."""
+    index: (qi, w, kip, wki) for a model with a sparse-attention
+    indexer: _layer_open's index queries and weights, the pool of index
+    keys and the window's slice of them [B,1,W,Hi] (None, window off).
+    Such a layer attends through sparse_paged_attend and nowhere else.
+    Returns [B,T,Nq,H]; with `index`, that and sparse_paged_attend's
+    count."""
+    if index is not None:
+        qi, w, kip, wki = index
+        return sparse_paged_attend(
+            q, qi, w, kp, vp, kip, layer, cfg=cfg, page_table=page_table,
+            positions=positions, mask=mask, win=win, wki=wki)
     T = q.shape[1]
     quant = ksp is not None
     start = positions[:, 0]
@@ -707,17 +858,21 @@ def _layer_open(x, lp, cfg: ModelConfig, cos, sin):
     """A layer up to its attention: the weights in the compute dtype,
     the pre-norm, the router's logits where the router stands before
     attention, and the projections, rotated where the layer rotates.
-    Returns (lp, q, k, v, route, sliding_window): `route` (None for
-    most models) is carried across attention to _layer_close, the
+    Returns (lp, q, k, v, route, sliding_window, index): `route` (None
+    for most models) is carried across attention to _layer_close, the
     layer's sliding window (None for a model without a pattern) goes to
-    paged_attend. With _layer_close, the part of a layer that
-    paged_layer_body and the packed step (packed_layer) share, so that
-    a change to a norm or a projection reaches both."""
+    paged_attend, and so does `index`, the indexer's (qI, kI, w) of
+    these rows (models.common.index_proj; None for a model without
+    one), once kI is cached beside k and v. With _layer_close, the part
+    of a layer that paged_layer_body and the packed step (packed_layer)
+    share, so that a change to a norm or a projection reaches both."""
     lp = jax.tree.map(lambda a: _cast_float(a, jnp.dtype(cfg.dtype)), lp)
     h = pre_norm(x, lp["ln1"], cfg)
     rope, sliding_window = layer_pattern_of(lp.get("pattern"))
     q, k, v = qkv_proj(h, lp["attn"], cfg, cos, sin, rope)
-    return lp, q, k, v, early_router_logits(x, lp, cfg), sliding_window
+    index = index_proj(x, lp, cfg, cos, sin) if cfg.has_indexer else None
+    return (lp, q, k, v, early_router_logits(x, lp, cfg), sliding_window,
+            index)
 
 
 def _layer_close(x, out, lp, cfg: ModelConfig, route=None, ok=None):
@@ -746,7 +901,7 @@ def _as_pool(pools):
 def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
                      positions, mask, cos, sin, active, use_kernel: bool,
                      fresh: bool, ksp=None, vsp=None, win=None, layer=None,
-                     force_dense: bool = False):
+                     force_dense: bool = False, kip=None, wki=None):
     """One transformer layer against its page pool.
 
     Shared by paged_forward's full-stack scan, the stage-local scan of
@@ -767,33 +922,42 @@ def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
     inserted into the gathered view at absolute positions,
     element-wise identical to the window-off written view). Returns
     (x, wk, wv[, wks, wvs]) — the pool rides outside the scan unchanged.
+
+    kip, wki (a model with an indexer): the index keys' pool and window
+    slice, as kp and wk are given; what was written comes back last.
     """
     quant = ksp is not None
-    lp, q, k, v, route, sliding_window = _layer_open(x, lp, cfg, cos, sin)
+    lp, q, k, v, route, sliding_window, index = _layer_open(x, lp, cfg,
+                                                            cos, sin)
     if win is not None:
         wk, wv, wks, wvs, win_len = win
         wk, wv, wks, wvs = stage_window_layer(wk, wv, k, v, win_len,
                                               wks, wvs)
+        if index is not None:
+            wki = stage_index_layer(wki, index[1], win_len)
     else:
         kp, vp, ksp, vsp = write_paged_layer(kp, vp, page_table, k, v,
                                              positions[:, 0], active,
                                              ksp, vsp)
-    pool, layer = ((kp, vp, ksp, vsp), layer) if win is not None \
-        else _as_pool((kp, vp, ksp, vsp))
+        if index is not None:
+            kip = write_index_layer(kip, page_table, index[1],
+                                    positions[:, 0], active)
+    pool, layer = ((kp, vp, ksp, vsp, kip), layer) if win is not None \
+        else _as_pool((kp, vp, ksp, vsp, kip))
     out = paged_attend(
         q, k, v, pool[0], pool[1], layer, cfg=cfg, page_table=page_table,
         positions=positions, mask=mask, active=active,
         use_kernel=use_kernel, fresh=fresh, ksp=pool[2], vsp=pool[3],
         win=None if win is None else (wk, wv, wks, wvs, win_len),
-        force_dense=force_dense, sliding_window=sliding_window)
+        force_dense=force_dense, sliding_window=sliding_window,
+        index=None if index is None
+        else (index[0], index[2], pool[4], wki))
+    if index is not None:
+        out = out[0]
     x, _ = _layer_close(x, out, lp, cfg, route)
-    if win is not None:
-        if quant:
-            return x, wk, wv, wks, wvs
-        return x, wk, wv
-    if quant:
-        return x, kp, vp, ksp, vsp
-    return x, kp, vp
+    written = (wk, wv, wks, wvs, wki) if win is not None \
+        else (kp, vp, ksp, vsp, kip)
+    return (x, *(a for a in written if a is not None))
 
 
 def paged_forward(params, cfg: ModelConfig, tokens: jax.Array,
@@ -831,7 +995,6 @@ def paged_forward(params, cfg: ModelConfig, tokens: jax.Array,
     entries' storage positions equal their RoPE positions again.
     """
     B, T = tokens.shape
-    quant = cache.quantized
     if positions is None:
         positions = cache.lengths[:, None] + jnp.arange(T)[None, :]
     if active is None:
@@ -843,27 +1006,23 @@ def paged_forward(params, cfg: ModelConfig, tokens: jax.Array,
     mask = mask & active[:, None, None]
 
     def body(x, scanned):
-        lp, kp, vp, *scales = scanned
+        lp, kp, vp, ksp, vsp, kip = scanned
         out = paged_layer_body(
             x, lp, kp, vp, cfg=cfg, page_table=cache.page_table,
             positions=positions, mask=mask, cos=cos, sin=sin, active=active,
-            use_kernel=use_kernel, fresh=fresh,
-            ksp=scales[0] if scales else None,
-            vsp=scales[1] if scales else None,
+            use_kernel=use_kernel, fresh=fresh, ksp=ksp, vsp=vsp, kip=kip,
             force_dense=attn_mask is not None)
         return out[0], tuple(out[1:])
 
-    xs = (layer_stack(params["layers"], cfg), cache.k_pages, cache.v_pages)
-    if quant:
-        xs = xs + (cache.k_scale_pages, cache.v_scale_pages)
-    x, new_pools = lax.scan(body, x, xs)
+    # an absent pool tensor rides the scan as None (no leaf)
+    x, new_pools = lax.scan(body, x, (layer_stack(params["layers"], cfg),
+                                      *pool_leaves(cache, absent=True)))
     if last_index is not None:
         x = jnp.take_along_axis(
             x, last_index[:, None, None].astype(jnp.int32), axis=1)
     logits = final_logits(params, cfg, x)
     new_len = jnp.where(active, cache.lengths + T, cache.lengths)
-    return logits, PagedKVCache(new_pools[0], new_pools[1],
-                                cache.page_table, new_len, *new_pools[2:])
+    return logits, pool_leaves(cache, new_pools)._replace(lengths=new_len)
 
 
 def paged_forward_window(params, cfg: ModelConfig, tokens: jax.Array,
@@ -902,7 +1061,6 @@ def paged_forward_window(params, cfg: ModelConfig, tokens: jax.Array,
     insert path.
     """
     B, T = tokens.shape
-    quant = cache.quantized
     if active is None:
         active = jnp.ones((B,), bool)
     if positions is None:
@@ -915,23 +1073,22 @@ def paged_forward_window(params, cfg: ModelConfig, tokens: jax.Array,
 
     def body(carry, scanned):
         x, i = carry
-        lp, wk, wv, *wsc = scanned
-        wks, wvs = wsc if wsc else (None, None)
+        lp, wk, wv, wks, wvs, wki = scanned
         out = paged_layer_body(
             x, lp, cache.k_pages, cache.v_pages, cfg=cfg,
             page_table=cache.page_table,
             positions=positions, mask=mask, cos=cos, sin=sin,
             active=active, use_kernel=use_kernel, fresh=False,
             ksp=cache.k_scale_pages, vsp=cache.v_scale_pages,
-            win=(wk, wv, wks, wvs, win_len), layer=i)
+            win=(wk, wv, wks, wvs, win_len), layer=i,
+            kip=cache.ki_pages, wki=wki)
         return (out[0], i + 1), tuple(out[1:])
 
-    xs = (layer_stack(params["layers"], cfg), window.k, window.v)
-    if quant:
-        xs = xs + (window.k_scale, window.v_scale)
-    (x, _), new_win = lax.scan(body, (x, 0), xs)
+    (x, _), new_win = lax.scan(
+        body, (x, 0), (layer_stack(params["layers"], cfg),
+                       *window_leaves(window, absent=True)))
     logits = final_logits(params, cfg, x)
-    return logits, KVWindow(*new_win)
+    return logits, window_leaves(window, new_win)
 
 
 class PackedRows(NamedTuple):
@@ -1001,11 +1158,12 @@ def packed_rows(params, cfg: ModelConfig, tokens, cache: PagedKVCache,
 
 def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
                  use_kernel: bool, layer=None):
-    """One layer of the packed step. pools: (kp, vp, ksp, vsp), scales
-    None unless int8: with the window off this layer's slices, which it
+    """One layer of the packed step. pools: (kp, vp, ksp, vsp, kip),
+    scales None unless int8 and kip None unless the model has an
+    indexer: with the window off this layer's slices, which it
     writes; with it on the WHOLE read-only pool, and `layer` this
     layer's index in it (paged_layer_body has the same two cases). wl:
-    the layer's window slices (wk, wv, wks, wvs), or None with the
+    the layer's window slices (wk, wv, wks, wvs, wki), or None with the
     window off. Every real row's entry goes
     where the lane-wide step put it, the window at win_len (+ t) or
     the pool at the row's position, in ONE stage or scatter. Attention
@@ -1014,28 +1172,51 @@ def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
     case over its OWN slot's table row and window slice. Returns
     (x, pools, wl, load): pools and window as written, and what the
     layer's routing asked of its experts for the step's real rows
-    (_layer_close; None for a dense model)."""
+    (_layer_close; None for a dense model). For a model with an indexer
+    `load` carries three values more: sparse_paged_attend's count of
+    the step's DECODE rows."""
     S, (P, C) = rows.written.shape[0], rows.chunk_pos.shape
-    lp, q, k, v, route, sliding_window = _layer_open(x, lp, cfg, rows.cos,
-                                                     rows.sin)
+    lp, q, k, v, route, sliding_window, index = _layer_open(
+        x, lp, cfg, rows.cos, rows.sin)
     dec_win = chunk_win = None
+    wki = chunk_wki = None
     if wl is not None:
-        wl = stage_window_layer(wl[0], wl[1], k, v, rows.widx, wl[2], wl[3],
-                                rows=rows.slot)
-        dec_win = (*wl, rows.win_len)
+        wl4 = stage_window_layer(wl[0], wl[1], k, v, rows.widx, wl[2], wl[3],
+                                 rows=rows.slot)
+        if index is not None:
+            wki = stage_index_layer(wl[4], index[1], rows.widx,
+                                    rows=rows.slot)
+            chunk_wki = wki[rows.chunk_slot]
+        wl = (*wl4, wki)
+        dec_win = (*wl4, rows.win_len)
         chunk_win = (*(None if a is None else a[rows.chunk_slot]
-                       for a in wl), rows.win_len[rows.chunk_slot])
+                       for a in wl4), rows.win_len[rows.chunk_slot])
     else:
-        pools = write_paged_layer(pools[0], pools[1], rows.table, k, v,
-                                  rows.pos, rows.ok, pools[2], pools[3])
-    (kp, vp, ksp, vsp), layer = (pools, layer) if wl is not None \
+        kip = pools[4]
+        if index is not None:
+            kip = write_index_layer(kip, rows.table, index[1], rows.pos,
+                                    rows.ok)
+        pools = (*write_paged_layer(pools[0], pools[1], rows.table, k, v,
+                                    rows.pos, rows.ok, pools[2], pools[3]),
+                 kip)
+    (kp, vp, ksp, vsp, kip), layer = (pools, layer) if wl is not None \
         else _as_pool(pools)
     attend_rows = partial(paged_attend, kp=kp, vp=vp, layer=layer, cfg=cfg,
                           use_kernel=use_kernel, fresh=False,
                           ksp=ksp, vsp=vsp, sliding_window=sliding_window)
+
+    def rows_index(cut, wki):
+        """paged_attend's `index` for one group of rows."""
+        return None if index is None \
+            else (cut(index[0]), cut(index[2]), kip, wki)
+
     out = attend_rows(q[:S], k[:S], v[:S], page_table=rows.page_table,
                       positions=rows.written[:, None], mask=rows.dec_mask,
-                      active=rows.active, win=dec_win)
+                      active=rows.active, win=dec_win,
+                      index=rows_index(lambda a: a[:S], wki))
+    count = None
+    if index is not None:
+        out, count = out
     if P:
         def chunks(a):
             """Rows S.. of a packed [N, 1, ...] array as [P, C, ...]."""
@@ -1044,23 +1225,46 @@ def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
         out_c = attend_rows(chunks(q), chunks(k), chunks(v),
                             page_table=rows.chunk_table,
                             positions=rows.chunk_pos, mask=rows.chunk_mask,
-                            active=rows.chunk_ok, win=chunk_win)
+                            active=rows.chunk_ok, win=chunk_win,
+                            index=rows_index(chunks, chunk_wki))
+        if index is not None:
+            out_c = out_c[0]
         out = jnp.concatenate(
             [out, out_c.reshape(P * C, 1, *out_c.shape[2:])])
     x, load = _layer_close(x, out, lp, cfg, route, rows.ok[:, None])
+    if count is not None:
+        load = jnp.concatenate([jnp.zeros((3,), jnp.float32)
+                                if load is None else load, count])
     return x, pools, wl, load
 
 
-_POOL_LEAVES = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages")
+_POOL_LEAVES = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages",
+                "ki_pages")
+_WINDOW_LEAVES = ("k", "v", "k_scale", "v_scale", "ki")
 
 
-def pool_leaves(cache: PagedKVCache, pools=None):
-    """The pool tensors that ride a layer scan: codes, and scales iff
-    int8. Given `pools`, the cache with them put back instead."""
-    names = _POOL_LEAVES[:4 if cache.quantized else 2]
-    if pools is None:
-        return tuple(getattr(cache, n) for n in names)
-    return cache._replace(**dict(zip(names, pools)))
+def _leaves(holder, names, new, absent: bool):
+    there = [n for n in names if getattr(holder, n) is not None]
+    if new is not None:
+        # all the places (None where absent), or the tensors there are
+        keys = names if len(new) == len(names) else there
+        return holder._replace(**dict(zip(keys, new, strict=True)))
+    return tuple(getattr(holder, n) for n in (names if absent else there))
+
+
+def pool_leaves(cache: PagedKVCache, pools=None, absent: bool = False):
+    """The cache's pool tensors as a list: keys and values, their
+    scales iff int8, the index keys iff the model has an indexer; what
+    rides a layer scan, what a flush writes. absent: all five places,
+    None where the cache has no such tensor (a scan takes None as no
+    leaf). Given `pools`, the tensors that are there in that order, the
+    cache with them put back instead."""
+    return _leaves(cache, _POOL_LEAVES, pools, absent)
+
+
+def window_leaves(window: KVWindow, staged=None, absent: bool = False):
+    """pool_leaves for the window: its tensors in the pool's order."""
+    return _leaves(window, _WINDOW_LEAVES, staged, absent)
 
 
 def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
@@ -1097,23 +1301,24 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
     `load` f32 [3] is models.common.expert_load of the step's real
     rows, the mean over the layers (None for a dense model): distinct
     experts touched, rows of the fullest expert, mean rows an expert.
+    A model with an indexer adds sparse_paged_attend's three: decode
+    rows, the positions they could attend, the positions they read.
     (Under pipeline stages: parallel/pipeline.py paged_pipeline_packed,
     the same pieces over stage-local layers.)
     """
     x, rows = packed_rows(params, cfg, tokens, cache, chunk_tokens,
                           chunk_slot, chunk_count, active, window, win_len)
-    n_leaves = 4 if cache.quantized else 2
-    pad = (None,) * (4 - n_leaves)
+    # an absent pool or window tensor rides the scan as None (no leaf)
     if window is None:
         def body(x, scanned):
             lp, *pools = scanned
-            x, pools, _, load = packed_layer(x, lp, (*pools, *pad), None,
-                                             rows, cfg, use_kernel)
-            return x, (pools[:n_leaves], load)
+            x, pools, _, load = packed_layer(x, lp, pools, None, rows, cfg,
+                                             use_kernel)
+            return x, (pools, load)
 
         x, (pools, load) = lax.scan(
             body, x, (layer_stack(params["layers"], cfg),
-                      *pool_leaves(cache)))
+                      *pool_leaves(cache, absent=True)))
         state = pool_leaves(cache, pools)
     else:
         # the pool is read-only and goes in whole beside the layer's
@@ -1123,14 +1328,13 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
             x, i = carry
             lp, *wl = scanned
             x, _, wl, load = packed_layer(
-                x, lp, (*pool_leaves(cache), *pad), (*wl, *pad), rows, cfg,
+                x, lp, pool_leaves(cache, absent=True), wl, rows, cfg,
                 use_kernel, layer=i)
-            return (x, i + 1), (wl[:n_leaves], load)
+            return (x, i + 1), (wl, load)
 
         (x, _), (new_win, load) = lax.scan(
-            body, (x, 0), (layer_stack(params["layers"], cfg), window.k,
-                           window.v, window.k_scale,
-                           window.v_scale)[:1 + n_leaves])
+            body, (x, 0), (layer_stack(params["layers"], cfg),
+                           *window_leaves(window, absent=True)))
         state = KVWindow(*new_win)
     if load is not None:
         load = load.mean(axis=0)

@@ -19,11 +19,12 @@ class ModelConfig:
     """Architecture hyperparameters for a transformer LM.
 
     One config class covers the model families (GPT-2, Llama-3,
-    Mixtral, SmallThinker) — the family is selected by `arch`, the MoE
-    fields and the per-layer attention pattern.
+    Mixtral, SmallThinker, Keye) — the family is selected by `arch`, the
+    MoE fields, the per-layer attention pattern and the sparse-attention
+    indexer.
     """
 
-    arch: str = "llama"  # "gpt2" | "llama" | "mixtral" | "smallthinker"
+    arch: str = "llama"  # "gpt2" | "llama" | "mixtral" | "smallthinker" | "keye"
     vocab_size: int = 32000
     hidden_size: int = 4096
     num_layers: int = 32
@@ -68,6 +69,20 @@ class ModelConfig:
                                       # no positional encoding in the layer;
                                       # () = every layer, as pos_embedding says
 
+    # attention variants
+    qk_norm: bool = False             # an RMSNorm with a learned weight over
+                                      # each head's queries and keys, before
+                                      # the rotation
+    # learned sparse attention (the lightning indexer of DeepSeek Sparse
+    # Attention): index_heads queries of index_head_dim and ONE index key
+    # a token score every cached position, and a query attends only the
+    # index_topk positions that score highest (all of them while there
+    # are no more). The index keys are a cached row of their own
+    # (cache/paged.py). All three are 0 for a model without an indexer.
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+
     # numerics
     dtype: str = "bfloat16"           # activation/weight compute dtype
     param_dtype: str = "float32"      # master param dtype
@@ -94,6 +109,13 @@ class ModelConfig:
         if bool(self.sliding_window_layout) != (self.sliding_window > 0):
             raise ValueError("sliding_window and sliding_window_layout "
                              "come together: which layers slide is stated")
+        if len({self.index_heads > 0, self.index_head_dim > 0,
+                self.index_topk > 0}) > 1:
+            raise ValueError("index_heads, index_head_dim and index_topk "
+                             "come together: an indexer has all three")
+        if self.index_topk and self.layer_pattern() is not None:
+            raise ValueError("a sparse-attention indexer beside a per-layer "
+                             "attention pattern is not supported")
         if self.router_input == "attn" and self.moe_impl == "ep":
             raise ValueError("expert parallelism (moe_impl 'ep') does not "
                              "carry router logits taken before attention")
@@ -115,6 +137,10 @@ class ModelConfig:
         return {"sliding_window": np.asarray(slides, np.int32)
                 * np.int32(self.sliding_window),
                 "rope": np.asarray(rope, np.int32)}
+
+    @property
+    def has_indexer(self) -> bool:
+        return self.index_topk > 0
 
     @property
     def q_per_kv(self) -> int:
@@ -180,6 +206,23 @@ def smallthinker_21b_a3b() -> ModelConfig:
     )
 
 
+def keye_vl2_30b_a3b() -> ModelConfig:
+    """The language model of Keye-VL-2.0-30B-A3B (huggingface.co/Kwai-Keye):
+    grouped-query attention, 32 queries over 4 KV heads of 128, a norm on
+    each head's queries and keys, and a sparse-attention indexer (16 index
+    heads of 64, one index key a token) that picks the 2,048 cached
+    positions a query attends; 128 experts of 768, 8 a token, every layer
+    sparse. Text only: the vision tower is not here, and a text sequence
+    gives the three position ids of `mrope_section` one value, plain RoPE."""
+    return ModelConfig(
+        arch="keye", vocab_size=151936, hidden_size=2048, num_layers=48,
+        num_heads=32, num_kv_heads=4, head_dim=128, intermediate_size=768,
+        max_seq_len=262144, norm_eps=1e-6, rope_theta=1e7,
+        num_experts=128, num_experts_per_tok=8, qk_norm=True,
+        index_heads=16, index_head_dim=64, index_topk=2048,
+    )
+
+
 def tiny(arch: str = "llama", **kw) -> ModelConfig:
     """Small config for tests: runs in <1s on CPU, exercises every code path."""
     base = dict(
@@ -201,6 +244,12 @@ def tiny(arch: str = "llama", **kw) -> ModelConfig:
                     router_input="attn", sliding_window=8,
                     sliding_window_layout=(0, 1, 1, 1),
                     rope_layout=(0, 1, 1, 1))
+    if arch == "keye":
+        # the shape of the real one: norms on heads, experts in every
+        # layer, an indexer whose top-k binds inside the toy's contexts
+        base.update(intermediate_size=32, num_experts=8,
+                    num_experts_per_tok=2, qk_norm=True, index_heads=2,
+                    index_head_dim=16, index_topk=8)
     base.update(kw)
     return ModelConfig(arch=arch, **base)
 
@@ -211,6 +260,7 @@ PRESETS = {
     "llama3-70b": llama3_70b,
     "mixtral-8x7b": mixtral_8x7b,
     "smallthinker-21b-a3b": smallthinker_21b_a3b,
+    "keye-vl2-30b-a3b": keye_vl2_30b_a3b,
 }
 
 

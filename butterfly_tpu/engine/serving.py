@@ -57,7 +57,7 @@ from jax import lax
 from jax.profiler import TraceAnnotation
 
 from butterfly_tpu.cache.paged import (
-    KVWindow, PagedKVCache, flush_paged_window, init_kv_window,
+    KVWindow, PagedKVCache, flush_paged_window, init_kv_window, pool_leaves,
     init_paged_cache, paged_forward, paged_forward_packed,
     paged_forward_window, permute_paged_tail, permute_window_tail)
 from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
@@ -66,7 +66,7 @@ from butterfly_tpu.ops import kernel_mode, kernels_default, record_kernels
 from butterfly_tpu.engine.sampling import (
     _filter_logits, speculative_accept, speculative_tree_accept,
     tree_ancestor_matrix, tree_depth, tree_node_index)
-from butterfly_tpu.models.common import Model
+from butterfly_tpu.models.common import Model, indexer_unsupported
 
 
 def named(fn, name: str):
@@ -268,6 +268,18 @@ class ServingEngine:
             raise ValueError(
                 f"{self.cfg.num_layers} layers not divisible by "
                 f"{stage} pipeline stages")
+        # what cannot take the index keys of a sparse-attention indexer,
+        # the third kind of cached row, refuses the model by name
+        if stage > 1:
+            indexer_unsupported(self.cfg, "pipeline serving")
+        if mesh is not None and mesh.shape.get("seq", 1) > 1:
+            indexer_unsupported(self.cfg, "the sequence-parallel prefill "
+                                          "lane")
+        if self.runtime.speculative_gamma > 0:
+            indexer_unsupported(self.cfg, "speculative decoding")
+        if self.runtime.prefix_caching:
+            indexer_unsupported(self.cfg, "prefix caching (and the host "
+                                          "KV tier behind it)")
         if use_kernels is None:
             # on everywhere but the CPU backend (ops/__init__.py); under
             # a mesh the call sites go through ops/*_sharded (shard_map
@@ -693,15 +705,12 @@ class ServingEngine:
             # pools are donated (scatters land in place); the table rows
             # ride separately so the donation set has no unaliasable
             # leaves (the rows have no matching output)
-            pools = (self.cache.k_pages, self.cache.v_pages,
-                     self.cache.k_scale_pages, self.cache.v_scale_pages)
             logits, pools = self._launch(
-                prog, buf.shape[0] * buf.shape[1], self.params, buf, pools,
-                rows, lens_dev, sts_dev)
+                prog, buf.shape[0] * buf.shape[1], self.params, buf,
+                pool_leaves(self.cache, absent=True), rows, lens_dev,
+                sts_dev)
             new_lens = jnp.asarray(sts[:B] + lens[:B])
-            self.cache = self.cache._replace(
-                k_pages=pools[0], v_pages=pools[1],
-                k_scale_pages=pools[2], v_scale_pages=pools[3],
+            self.cache = pool_leaves(self.cache, pools)._replace(
                 lengths=self.cache.lengths.at[
                     np.asarray(slots, np.int32)].set(new_lens))
         return logits[:B]
@@ -1087,6 +1096,7 @@ class ServingEngine:
         REGISTERED pages (content-immutable — a shared full page is
         never rewritten) may be exported, so in-flight decode blocks
         writing other pages cannot race the bytes."""
+        indexer_unsupported(self.cfg, "KV page export (read_pages)")
         if self._win_dirty:
             self.flush_kv_window()
         idx = jnp.asarray(pids, jnp.int32)
@@ -1108,6 +1118,7 @@ class ServingEngine:
         admission attaches them read-only via the prefix registry — so
         no in-flight dispatch can be reading them while this scatter
         runs."""
+        indexer_unsupported(self.cfg, "KV page import (write_pages)")
         idx = jnp.asarray(pids, jnp.int32)
         with self._mesh_ctx():
             kp = self.cache.k_pages.at[:, idx].set(
@@ -1366,7 +1377,8 @@ def _prefill_slot(cfg: ModelConfig, fresh: bool, fwd, params, tokens,
                   pools, table_rows, true_len, start):
     """[B,T] prompt chunks against B slots' table rows; pool-wide scatter.
 
-    `pools` is the (k, v[, k_scale, v_scale]) pool tuple (donated —
+    `pools` is the cache's pool tensors, all five places
+    (cache/paged.py pool_leaves; donated —
     scatters land in place), paired with the B member slots' table rows
     [B, max_pages]; `start` [B] is each chunk's first absolute position;
     `fresh` (static) means every start==0 and the members' pages are
@@ -1377,7 +1389,7 @@ def _prefill_slot(cfg: ModelConfig, fresh: bool, fwd, params, tokens,
     """
     cache1 = PagedKVCache(pools[0], pools[1], table_rows,
                           jnp.zeros((tokens.shape[0],), jnp.int32),
-                          pools[2], pools[3])
+                          *pools[2:])
     B, T = tokens.shape
     positions = start[:, None] + jnp.broadcast_to(jnp.arange(T)[None, :],
                                                   (B, T))
@@ -1388,8 +1400,7 @@ def _prefill_slot(cfg: ModelConfig, fresh: bool, fwd, params, tokens,
     if logits.shape[1] != 1:
         logits = jnp.take_along_axis(logits, (true_len - 1)[:, None, None],
                                      axis=1)
-    return logits[:, 0, :], (cache1.k_pages, cache1.v_pages,
-                             cache1.k_scale_pages, cache1.v_scale_pages)
+    return logits[:, 0, :], pool_leaves(cache1, absent=True)
 
 
 def _decode_all(cfg: ModelConfig, fwd, params, tokens, cache: PagedKVCache,
@@ -1926,7 +1937,9 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
     asked of the experts (models.common.expert_load: distinct experts
     touched, rows of the fullest expert, mean rows an expert), the mean
     over its layers and the steps with a real row; None for a dense
-    model.
+    model. A model with a sparse-attention indexer adds two: the
+    positions a live decode row could attend and the positions it
+    read, the mean over the block's layers, rows and steps.
     """
     S = tokens.shape[0]
     H = pbuf.shape[1]
@@ -1987,7 +2000,13 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
         # the mean over the steps that had a real row (a block's last
         # steps may run on slots that have all finished)
         had = (load[:, 2] > 0).astype(load.dtype)
-        load = (load * had[:, None]).sum(axis=0) / jnp.maximum(had.sum(), 1)
+        experts = (load[:, :3] * had[:, None]).sum(axis=0) \
+            / jnp.maximum(had.sum(), 1)
+        # a model with an indexer: what a live decode row could attend
+        # and what it read, the mean over the block's rows
+        rows = load[:, 3:].sum(axis=0)
+        load = jnp.concatenate([experts, rows[1:] / jnp.maximum(rows[0], 1)]) \
+            if cfg.has_indexer else experts
     return block, valid, final, cursor, cache, window, win_len, load
 
 

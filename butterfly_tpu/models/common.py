@@ -70,6 +70,8 @@ class KVCache(NamedTuple):
     length: jax.Array  # [B] int32
     k_scale: Optional[jax.Array] = None  # [L,B,Kv,S] f32 iff k is int8
     v_scale: Optional[jax.Array] = None
+    ki: Optional[jax.Array] = None  # [L,B,S,Hi] index keys iff the model
+                                    # has a sparse-attention indexer
 
     @property
     def max_seq(self) -> int:
@@ -85,6 +87,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
                quant: str = "none") -> KVCache:
     dtype = dtype or jnp.dtype(cfg.dtype)
     shape = (cfg.num_layers, batch, max_seq, cfg.num_kv_heads, cfg.head_dim)
+    ki = jnp.zeros((cfg.num_layers, batch, max_seq, cfg.index_head_dim),
+                   dtype) if cfg.has_indexer else None
     if quant == "int8":
         qshape = (cfg.num_layers, batch, cfg.num_kv_heads, max_seq,
                   cfg.head_dim)
@@ -93,14 +97,14 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
             v=jnp.zeros(qshape, jnp.int8),
             length=jnp.zeros((batch,), jnp.int32),
             k_scale=jnp.zeros(qshape[:-1], jnp.float32),
-            v_scale=jnp.zeros(qshape[:-1], jnp.float32),
+            v_scale=jnp.zeros(qshape[:-1], jnp.float32), ki=ki,
         )
     if quant != "none":
         raise ValueError(f"unknown kv quant {quant!r}")
     return KVCache(
         k=jnp.zeros(shape, dtype),
         v=jnp.zeros(shape, dtype),
-        length=jnp.zeros((batch,), jnp.int32),
+        length=jnp.zeros((batch,), jnp.int32), ki=ki,
     )
 
 
@@ -255,8 +259,12 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array,
 def qkv_proj(x: jax.Array, p: Params, cfg: ModelConfig,
              cos: jax.Array, sin: jax.Array, rope=None
              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """QKV projections (+bias, +rope). x: [B,T,D] -> q [B,T,Nq,H],
-    k/v [B,T,Kv,H]. Shared by the contiguous and paged attention paths.
+    """QKV projections (+bias, +norm on heads, +rope). x: [B,T,D] ->
+    q [B,T,Nq,H], k/v [B,T,Kv,H]. Shared by the contiguous and paged
+    attention paths of every family the program has (GPT-2, Llama,
+    Mixtral, SmallThinker, Keye).
+    cfg.qk_norm: an RMSNorm with a learned weight [H] over each head's
+    queries and keys, before the rotation (the Qwen3 families, Keye).
     rope: the layer's flag out of its pattern (layer_stack), a traced
     scalar: 0 = this layer has no positional encoding. It turns the
     rotation into the identity (cos 1, sin 0), exactly: x*1 - y*0."""
@@ -268,6 +276,9 @@ def qkv_proj(x: jax.Array, p: Params, cfg: ModelConfig,
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
+    if cfg.qk_norm:
+        q = rms_norm(q, p["q_norm"]["scale"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"]["scale"], cfg.norm_eps)
     if cfg.pos_embedding == "rope":
         if rope is not None:
             cos = jnp.where(rope > 0, cos, 1.0)
@@ -275,6 +286,119 @@ def qkv_proj(x: jax.Array, p: Params, cfg: ModelConfig,
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
     return q, k, v
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention: the indexer (cfg.index_heads/_head_dim/_topk)
+#
+# Beside its keys and values a token caches ONE index key kI [Hi]; a
+# query brings index_heads index queries qI [Ni,Hi] and a weight a head
+# w [Ni]. The index score of query t for position s <= t is
+#     I[t,s] = sum_j w[t,j] * relu(qI[t,j] . kI[s])
+# and the query attends the index_topk positions that score highest
+# (every position while there are no more; a tie goes to the lower
+# position). One selection a token and layer, shared by all heads. The
+# selection is discrete, as a routing is: projections and scores run in
+# float32 at full precision (router_logits says why), and the indexer's
+# weights stay out of the int8 quantiser. These functions are the ONE
+# definition that the contiguous path, the paged path and the packed
+# step share.
+# ---------------------------------------------------------------------------
+
+@jax.named_scope("attn_index")
+def index_proj(x: jax.Array, lp: Params, cfg: ModelConfig,
+               cos: jax.Array, sin: jax.Array):
+    """The indexer's three projections of a layer's INPUT x [B,T,D] (the
+    norm is taken again in float32, as early_router_logits does):
+    (qI [B,T,Ni,Hi], kI [B,T,Hi], w [B,T,Ni]), float32. kI passes a
+    LayerNorm; qI and kI are rotated (rotate-half over all Hi dims, the
+    model's theta). cos/sin are the attention's own [B,T,H/2]: the
+    index head's frequencies theta^(-j/(Hi/2)) are every (H/Hi)-th of
+    the attention head's theta^(-m/(H/2)), to the bit."""
+    ip = lp["index"]
+    hp = lax.Precision.HIGHEST
+    h = pre_norm(x.astype(jnp.float32), lp["ln1"], cfg)
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    qi = jnp.einsum("btd,dnh->btnh", h, f32(ip["w_qi"]), precision=hp)
+    ki = jnp.einsum("btd,dh->bth", h, f32(ip["w_ki"]), precision=hp)
+    w = jnp.einsum("btd,dn->btn", h, f32(ip["w_w"]), precision=hp)
+    ki = layer_norm(ki, ip["k_norm"]["scale"], ip["k_norm"]["bias"],
+                    cfg.norm_eps)
+    step = cfg.head_dim // cfg.index_head_dim
+    cos, sin = cos[..., ::step], sin[..., ::step]
+    qi = apply_rope(qi, cos, sin)
+    ki = apply_rope(ki[:, :, None], cos, sin)[:, :, 0]
+    return qi, ki, w
+
+
+@jax.named_scope("attn_index")
+def index_scores(qi: jax.Array, w: jax.Array, ki: jax.Array) -> jax.Array:
+    """I [B,T,S] float32 of index queries qI [B,T,Ni,Hi] with their
+    weights w [B,T,Ni] against index keys kI [B,S,Hi] as cached (any
+    float dtype). A positive constant on I (DeepSeek's Hi^-1/2 Ni^-1/2)
+    changes no selection and is left out."""
+    s = jnp.einsum("btnh,bsh->btns", qi, ki.astype(jnp.float32),
+                   precision=lax.Precision.HIGHEST)
+    return jnp.einsum("btns,btn->bts", jax.nn.relu(s), w,
+                      precision=lax.Precision.HIGHEST)
+
+
+def _selected(s: jax.Array, valid: jax.Array, kth: jax.Array,
+              k: int) -> jax.Array:
+    """The selection as a mask, from the k-th highest score `kth`
+    [..., 1] of s (scores, -inf where not valid): what scores above it,
+    and of what scores equal to it the lower positions, as many as make
+    k, which is the order lax.top_k takes them in."""
+    above = s > kth
+    level = (s == kth) & valid
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    return valid & (above | (level & (jnp.cumsum(level, axis=-1) <= room)))
+
+
+@jax.named_scope("attn_select")
+def select_topk(scores: jax.Array, valid: jax.Array, k: int,
+                with_mask: bool = False):
+    """The k valid positions of the last axis that score highest, as
+    (idx [..., k] int32, ok [..., k], mask): ok is False on the entries
+    past the number of valid positions (idx then points anywhere).
+    lax.top_k orders equal scores by position, the lower first. mask
+    (with_mask; else None): the same selection over the last axis, as
+    select_mask gives it."""
+    s = jnp.where(valid, scores, -jnp.inf)
+    vals, idx = lax.top_k(s, k)
+    mask = _selected(s, valid, vals[..., -1:], k) if with_mask else None
+    return idx, vals > -jnp.inf, mask
+
+
+@jax.named_scope("attn_select")
+def select_mask(scores: jax.Array, valid: jax.Array, k: int) -> jax.Array:
+    """select_topk's selection as a mask over the last axis: `valid`
+    narrowed to its k highest scores, every valid position where there
+    are no more than k."""
+    if scores.shape[-1] <= k:
+        return valid
+    s = jnp.where(valid, scores, -jnp.inf)
+    return _selected(s, valid, lax.top_k(s, k)[0][..., -1:], k)
+
+
+def index_cache_write(cki: jax.Array, ki: jax.Array,
+                      start: jax.Array) -> jax.Array:
+    """Write index keys ki [B,T,Hi] into the contiguous cache's
+    cki [B,S,Hi] at per-sequence offsets (update_cache_layer's twin)."""
+    def upd(cache_b, new_b, start_b):
+        return lax.dynamic_update_slice(cache_b, new_b, (start_b, 0))
+    return jax.vmap(upd)(cki, ki.astype(cki.dtype), start)
+
+
+def indexer_unsupported(cfg: ModelConfig, what: str) -> None:
+    """Refuse a model with a sparse-attention indexer on a path that
+    does not carry its third kind of cached row, the index keys, or
+    that attends through a kernel which knows no selection."""
+    if cfg.has_indexer:
+        raise NotImplementedError(
+            f"{what} does not carry the index keys of a sparse-attention "
+            f"indexer (index_topk {cfg.index_topk}): not supported for "
+            "this model")
 
 
 @jax.named_scope("attn")
@@ -293,7 +417,8 @@ def attention_block(x: jax.Array, p: Params, cfg: ModelConfig,
                     fresh: bool = False,
                     k_s: Optional[jax.Array] = None,
                     v_s: Optional[jax.Array] = None,
-                    pattern: Optional[Params] = None):
+                    pattern: Optional[Params] = None,
+                    index=None, cki: Optional[jax.Array] = None):
     """One attention sublayer with contiguous-cache update.
 
     x: [B,T,D]; ck/cv: [B,S,Kv,H]; positions: [B,T]; mask: [B,T,S].
@@ -322,10 +447,20 @@ def attention_block(x: jax.Array, p: Params, cfg: ModelConfig,
     pattern: this layer's entry of cfg.layer_pattern() (layer_stack),
     traced scalars: whether it rotates, and its sliding window, which
     narrows `mask` and rides into the flash kernels.
+
+    index, cki (a model with an indexer; never with ck None): index_proj's
+    (qI, kI, w) of this layer and its index keys cki [B,S,Hi]. The new
+    index keys are written first, the mask is narrowed to each query's
+    selection (select_mask: the reference's arithmetic, dense) and the
+    return gains the updated cki as its last value.
     """
     rope, sw = layer_pattern_of(pattern)
     q, k, v = qkv_proj(x, p, cfg, cos, sin, rope)
     mask = layer_mask(mask, positions, sw)
+    if index is not None:
+        qi, ki, w = index
+        cki = index_cache_write(cki, ki, positions[:, 0])
+        mask = select_mask(index_scores(qi, w, cki), mask, cfg.index_topk)
     if ck is None:
         assert fresh, "no-cache attention_block is fresh-prefill only"
         out = None
@@ -344,7 +479,7 @@ def attention_block(x: jax.Array, p: Params, cfg: ModelConfig,
     else:
         ck, cv = update_cache_layer(ck, cv, k, v, start)
     out = None
-    if cfg.attn_impl == "flash" and x.shape[1] > 1:
+    if cfg.attn_impl == "flash" and x.shape[1] > 1 and index is None:
         # None = no mesh axis can shard the kernel operands; use dense.
         # (Fresh prefill attends over the just-projected bf16 K/V, so the
         # kernel path is identical for int8 caches.)
@@ -375,9 +510,10 @@ def attention_block(x: jax.Array, p: Params, cfg: ModelConfig,
             note_kernel("dense_fallback")
     if out is None:
         out = attend(q, ck, cv, mask, cfg, k_s, v_s)
+    tail = () if index is None else (cki,)
     if k_s is not None:
-        return attn_output(out, p, cfg), ck, cv, k_s, v_s
-    return attn_output(out, p, cfg), ck, cv
+        return (attn_output(out, p, cfg), ck, cv, k_s, v_s, *tail)
+    return (attn_output(out, p, cfg), ck, cv, *tail)
 
 
 def mlp_block(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
@@ -508,18 +644,21 @@ def transformer_layer(x: jax.Array, lp: Params, cfg: ModelConfig,
                       cos: jax.Array, sin: jax.Array,
                       fresh: bool = False,
                       k_s: Optional[jax.Array] = None,
-                      v_s: Optional[jax.Array] = None):
+                      v_s: Optional[jax.Array] = None,
+                      cki: Optional[jax.Array] = None):
     """Pre-norm residual block: x + attn(norm(x)); x + ffn(norm(x)).
 
-    Returns (x, ck, cv), or (x, ck, cv, k_s, v_s) with an int8 cache;
-    in attention_block's no-cache fresh mode (ck None), (x, k, v) with
-    the layer's raw projected K/V.
+    Returns (x, ck, cv), or (x, ck, cv, k_s, v_s) with an int8 cache,
+    and the layer's index keys cki after them for a model with an
+    indexer; in attention_block's no-cache fresh mode (ck None),
+    (x, k, v) with the layer's raw projected K/V.
     """
     h = pre_norm(x, lp["ln1"], cfg)
     route = early_router_logits(x, lp, cfg)
+    index = index_proj(x, lp, cfg, cos, sin) if cfg.has_indexer else None
     attn_out, *rest = attention_block(
         h, lp["attn"], cfg, ck, cv, positions, mask, cos, sin, fresh,
-        k_s, v_s, lp.get("pattern"))
+        k_s, v_s, lp.get("pattern"), index, cki)
     x = x + attn_out
     x = x + ffn_block(pre_norm(x, lp["ln2"], cfg), lp, cfg, route)
     return (x, *rest)
@@ -610,27 +749,32 @@ def scan_layers(layer_params: Params, cfg: ModelConfig, x: jax.Array,
                 mask: jax.Array, cos: jax.Array, sin: jax.Array,
                 fresh: bool = False,
                 k_s: Optional[jax.Array] = None,
-                v_s: Optional[jax.Array] = None):
+                v_s: Optional[jax.Array] = None,
+                ki: Optional[jax.Array] = None):
     """lax.scan of transformer_layer over layer-stacked leaves.
 
     Works on any leading-layer-count slice (full model, or one pipeline
     stage's slice — parallel/pipeline.py scans each stage's local layers
     with this same body). Returns (x, new_k, new_v), plus
-    (new_k_s, new_v_s) when scanning an int8 cache (k_s/v_s [L,B,Kv,S]).
+    (new_k_s, new_v_s) when scanning an int8 cache (k_s/v_s [L,B,Kv,S]),
+    plus new_ki last for a model with an indexer (ki [L,B,S,Hi]).
     """
     compute_dtype = jnp.dtype(cfg.dtype)
     quant = k_s is not None
 
     def body(x, scanned):
-        lp, *kv = scanned
+        lp, ck, cv, *rest = scanned
         lp = jax.tree.map(lambda a: _cast_float(a, compute_dtype), lp)
-        x, *kv = transformer_layer(x, lp, cfg, *kv[:2],
+        scales = rest[:2] if quant else (None, None)
+        x, *kv = transformer_layer(x, lp, cfg, ck, cv,
                                    positions, mask, cos, sin, fresh,
-                                   *kv[2:])
+                                   *scales, rest[-1] if cfg.has_indexer
+                                   else None)
         return x, tuple(kv)
 
     layer_params = layer_stack(layer_params, cfg)
-    xs = (layer_params, k, v, k_s, v_s) if quant else (layer_params, k, v)
+    xs = (layer_params, k, v) + ((k_s, v_s) if quant else ()) \
+        + ((ki,) if cfg.has_indexer else ())
     x, out = lax.scan(body, x, xs)
     return (x, *out)
 
@@ -877,6 +1021,7 @@ def decode_step_win(params: Params, cfg: ModelConfig, tokens: jax.Array,
     inside the layer body instead costs ~2x the step's window traffic in
     128KB strided slices + concats — measured on v5e, r5 profile).
     """
+    indexer_unsupported(cfg, "the write-combined fused generate")
     quant = cache.quantized
     positions = (cache.length + wstep)[:, None]
     x, cos, sin = embed_tokens(params, cfg, tokens, positions)
@@ -1057,23 +1202,28 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     B, T = tokens.shape
     if positions is None:
         positions = cache.length[:, None] + jnp.arange(T)[None, :]
-    if T == 1 and not fresh:
+    # A model with an indexer takes the general path for every shape:
+    # the two fast paths below attend before the cache is written, and
+    # neither carries the index keys (the serving path, cache/paged.py,
+    # is where such a model reads only what it selected).
+    if T == 1 and not fresh and not cfg.has_indexer:
         return _decode_forward(params, cfg, tokens, cache, positions)
-    if fresh and T > 1:
+    if fresh and T > 1 and not cfg.has_indexer:
         return _fresh_prefill_forward(params, cfg, tokens, cache,
                                       positions, last_index)
 
     x, cos, sin = embed_tokens(params, cfg, tokens, positions)
     mask = make_mask(positions, cache.max_seq)
-    x, *new_kv = scan_layers(params["layers"], cfg, x, cache.k, cache.v,
-                             positions, mask, cos, sin, fresh,
-                             cache.k_scale, cache.v_scale)
+    x, new_k, new_v, *rest = scan_layers(
+        params["layers"], cfg, x, cache.k, cache.v, positions, mask, cos,
+        sin, fresh, cache.k_scale, cache.v_scale, cache.ki)
     if last_index is not None:
         x = jnp.take_along_axis(
             x, last_index[:, None, None].astype(jnp.int32), axis=1)
     logits = final_logits(params, cfg, x)
-    new_len = cache.length + T
-    return logits, KVCache(*new_kv[:2], new_len, *new_kv[2:])
+    new_ki = rest.pop() if cfg.has_indexer else None
+    return logits, KVCache(new_k, new_v, cache.length + T, *rest,
+                           **({} if new_ki is None else {"ki": new_ki}))
 
 
 # ---------------------------------------------------------------------------
@@ -1101,6 +1251,18 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             "wo": w(next(keys), L, Nq, H, D),
         },
     }
+    if cfg.qk_norm:
+        layers["attn"]["q_norm"] = {"scale": jnp.ones((L, H), pdt)}
+        layers["attn"]["k_norm"] = {"scale": jnp.ones((L, H), pdt)}
+    if cfg.has_indexer:
+        Ni, Hi = cfg.index_heads, cfg.index_head_dim
+        layers["index"] = {
+            "w_qi": w(next(keys), L, D, Ni, Hi),
+            "w_ki": w(next(keys), L, D, Hi),
+            "w_w": w(next(keys), L, D, Ni),
+            "k_norm": {"scale": jnp.ones((L, Hi), pdt),
+                       "bias": jnp.zeros((L, Hi), pdt)},
+        }
     if cfg.use_bias:
         layers["ln1"]["bias"] = jnp.zeros((L, D), pdt)
         layers["ln2"]["bias"] = jnp.zeros((L, D), pdt)

@@ -94,8 +94,12 @@ class TickLog:
         [touched, rows_max, rows_mean] of the mixed blocks the tick
         drained (a model of experts; models.common.expert_load), as
         `experts_touched`, `expert_rows_max` and `expert_rows_mean`;
-        None for a dense model or a tick that drained no block."""
-        touched, rows_max, rows_mean = expert_load or (None, None, None)
+        None for a dense model or a tick that drained no block. A model
+        with a sparse-attention indexer gives two values more,
+        `kv_rows_live` and `kv_rows_selected`: the positions a live
+        decode row could attend and those it read (null otherwise)."""
+        touched, rows_max, rows_mean, kv_live, kv_selected = \
+            (tuple(expert_load or ()) + (None,) * 5)[:5]
         entry = {
             "seq": self._seq,
             "t_wall": time.time(),
@@ -118,6 +122,8 @@ class TickLog:
             "experts_touched": touched,
             "expert_rows_max": rows_max,
             "expert_rows_mean": rows_mean,
+            "kv_rows_live": kv_live,
+            "kv_rows_selected": kv_selected,
         }
         with self._lock:
             self._ring.append(entry)

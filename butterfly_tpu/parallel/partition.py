@@ -78,6 +78,17 @@ def param_specs(cfg: ModelConfig, mesh: Mesh) -> Specs:
             "wo": P(L, tp(Nq), None, None),   # row-parallel -> all-reduce
         },
     }
+    if cfg.qk_norm:   # one weight [H] for every head: replicated
+        layers["attn"]["q_norm"] = {"scale": P(L, None)}
+        layers["attn"]["k_norm"] = {"scale": P(L, None)}
+    if cfg.has_indexer:
+        # the indexer is a thousandth of a layer and its selection is one
+        # a token for all heads: every chip holds it whole
+        layers["index"] = {
+            "w_qi": P(L, None, None, None), "w_ki": P(L, None, None),
+            "w_w": P(L, None, None),
+            "k_norm": {"scale": P(L, None), "bias": P(L, None)},
+        }
     if cfg.use_bias:
         layers["ln1"]["bias"] = P(L, None)
         layers["ln2"]["bias"] = P(L, None)
@@ -140,7 +151,9 @@ def cache_specs(cfg: ModelConfig, mesh: Mesh, quant: bool = False) -> KVCache:
     else:
         kv = P(lspec, dspec, None, tspec, None)
         sc = None
-    return KVCache(k=kv, v=kv, length=P(dspec), k_scale=sc, v_scale=sc)
+    ki = P(lspec, dspec, None, None) if cfg.has_indexer else None
+    return KVCache(k=kv, v=kv, length=P(dspec), k_scale=sc, v_scale=sc,
+                   ki=ki)
 
 
 def _div_any(mesh: Mesh, axis: str) -> Optional[str]:
@@ -171,9 +184,11 @@ def paged_cache_specs(cfg: ModelConfig, mesh: Mesh, num_slots: int,
     tspec = _div(cfg.num_kv_heads, mesh, "tensor")
     kv = P(lspec, None, tspec, None, None)
     sc = P(lspec, None, tspec) if quant else None
+    # the index keys have ONE head a token: every chip holds them whole
+    ki = P(lspec, None, None, None, None) if cfg.has_indexer else None
     return PagedKVCache(k_pages=kv, v_pages=kv,
                         page_table=P(dslots, None), lengths=P(dslots),
-                        k_scale_pages=sc, v_scale_pages=sc)
+                        k_scale_pages=sc, v_scale_pages=sc, ki_pages=ki)
 
 
 def kv_window_specs(cfg: ModelConfig, mesh: Mesh, num_slots: int,
@@ -190,7 +205,8 @@ def kv_window_specs(cfg: ModelConfig, mesh: Mesh, num_slots: int,
     tspec = _div(cfg.num_kv_heads, mesh, "tensor")
     kv = P(None, dslots, tspec, None, None)
     sc = P(None, dslots, tspec, None) if quant else None
-    return KVWindow(k=kv, v=kv, k_scale=sc, v_scale=sc)
+    ki = P(None, dslots, None, None, None) if cfg.has_indexer else None
+    return KVWindow(k=kv, v=kv, k_scale=sc, v_scale=sc, ki=ki)
 
 
 def warm_prefix_specs(d: Optional[str], t: Optional[str],

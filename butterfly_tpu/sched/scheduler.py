@@ -654,6 +654,16 @@ class Scheduler:
             "moe_expert_rows_max",
             "Rows routed to the fullest expert of a layer in one step, "
             "the mean over the newest drained block's steps and layers")
+        self._g_kv_rows_live = reg.gauge(
+            "kv_rows_live",
+            "Cached positions a live decode row could attend, the mean "
+            "over the newest drained block's layers, rows and steps (a "
+            "model with a sparse-attention indexer; 0 without)")
+        self._g_kv_rows_selected = reg.gauge(
+            "kv_rows_selected",
+            "Cached positions a live decode row read, the index_topk its "
+            "indexer selected at most: the mean over the newest drained "
+            "block's layers, rows and steps")
         # per-phase histograms in the registry: real _bucket series per
         # structural phase, so dashboards see distributions, not means
         self._h_phase = {
@@ -1078,7 +1088,7 @@ class Scheduler:
         self._t_host_total += max(0.0, wall - fetch)
         loads = self._tick_expert_loads
         load = [float(sum(v[i] for v in loads)) / len(loads)
-                for i in range(3)] if loads else None
+                for i in range(len(loads[0]))] if loads else None
         self.ticklog.record(wall, tp, fetch_s=fetch, expert_load=load,
                             overlapped=self._tick_overlapped,
                             inflight=len(self._inflight),
@@ -2203,6 +2213,9 @@ class Scheduler:
                 self._tick_expert_loads.append(load[0])
                 self._g_experts_touched.set(float(load[0][0]))
                 self._g_expert_rows_max.set(float(load[0][1]))
+                if len(load[0]) > 3:       # a model with an indexer
+                    self._g_kv_rows_live.set(float(load[0][3]))
+                    self._g_kv_rows_selected.set(float(load[0][4]))
             for slot, (req, gen) in snapshot.items():
                 if req.done or req.slot != slot or req.preemptions != gen:
                     continue

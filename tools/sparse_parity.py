@@ -1,0 +1,146 @@
+"""What holds a model with a learned sparse-attention indexer to its plain
+reference PAST the selection, on the chip, at the published widths:
+
+    python3 tools/sparse_parity.py <config.json> <out.json> [--toy]
+
+A cell's own reference check (servebench/refcheck.py) feeds 16 tokens, so
+it never reaches a context longer than `index_topk` (2,048), where the
+indexer first leaves a position out. Here ONE stream of STREAM tokens goes
+through the packed mixed step the server runs (tools/window_parity.py's
+driver: chunks of C prompt tokens into the write-combined window, a flush
+every k steps as the scheduler drains, then decode rows, each of which
+reads only the rows it selected: cache/paged.py sparse_paged_attend), and
+its logits are compared with the configuration's reference computed in
+blocks of rows: every chunk's last column and every decode row, in two
+groups: BEFORE position topk (its last quarter: nothing is left out yet)
+and PAST 2 x topk - topk / 32 (4,032 at 2,048: half the context or more
+is left out). Logits, not tokens; the reading is the rms of the
+difference over the reference's spread, per row, as refcheck.py reads it.
+
+Beside the clean run, two controls planted in the program: `select_all`
+(every position is attended: the model without its indexer) and
+`select_recent` (the last topk positions in place of the indexer's
+choice: a sliding window). Each must read as the clean run BEFORE topk
+and pass LIMIT past it. A reading means something only between the clean
+run's and a control's, and LIMIT lies there (PERF.md, PR 36, gives the
+readings it was set from). The MEDIAN over a group's rows is what is held
+to it: in bfloat16 a near-tie among 128 router logits flips an expert in
+some rows, and a near-tie at the selection's edge swaps one of 2,048
+attended rows for another, while a wrong selection moves every row. The
+largest reading of each group is reported beside it.
+
+The tool reports chip evidence and refuses to run without a TPU; `--toy`
+(the CPU rehearsal of tests/test_keye.py) says so in its output.
+"""
+import contextlib
+import json
+import sys
+from functools import partial
+from pathlib import Path
+
+import numpy as np
+
+ROOT = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT))
+sys.path.insert(0, str(ROOT / "tools"))
+
+from window_parity import _reading, served_rows  # noqa: E402
+
+#: rms of the difference over the reference's spread: a group of rows whose
+#: median is above it is wrong. Set between the chip's two readings past
+#: 2 x topk (PERF.md, PR 36): the clean run 0.0206, `select_all` 0.1234
+#: (2.4 times of room on either side; `select_recent` reads 0.71)
+LIMIT = 0.05
+STREAM, SLOTS, MAX_SEQ = 4500, 8, 5120
+#: decode rows behind the prompt
+DECODE = 84
+#: the clean run and the controls: what sparse_paged_attend selects by
+FAULTS = {"clean": "index", "select_all": "all", "select_recent": "recent"}
+
+
+@contextlib.contextmanager
+def planted(fault: str):
+    """The program's selection replaced while a step is traced."""
+    from butterfly_tpu.cache import paged
+    real = paged.sparse_paged_attend
+    paged.sparse_paged_attend = partial(real, select=FAULTS[fault])
+    try:
+        yield
+    finally:
+        paged.sparse_paged_attend = real
+
+
+def check(config: dict, toy: bool = False, stream: int = STREAM,
+          decode: int = DECODE, seed: int = 36) -> dict:
+    import jax
+    from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
+    from butterfly_tpu.quant.int8 import init_params_by_leaf, is_quantized_leaf
+    from servebench.launcher import model_fields
+    from servebench.refcheck import leaf_reader, load_reference
+
+    kind = str(jax.devices()[0].device_kind)
+    if jax.default_backend() != "tpu" and not toy:
+        raise SystemExit(f"no TPU here ({kind}): this is chip evidence; "
+                         "--toy rehearses on the CPU and says so")
+    cfg = ModelConfig(**model_fields(config))
+    sv = config["serve"]
+    rt = RuntimeConfig(
+        max_batch_size=sv["max_batch"] if toy else SLOTS,
+        max_seq_len=sv["max_seq"] if toy else MAX_SEQ,
+        page_size=sv["page_size"], kv_quant=sv.get("kv_quant", "none"),
+        decode_steps_per_tick=sv["decode_steps_per_tick"],
+        prefill_inline_budget=sv.get("prefill_inline_budget", 32))
+    C = min(rt.prefill_inline_budget, rt.prefill_chunk)
+    topk = cfg.index_topk
+    past = 2 * topk - topk // 32
+    if not 0 < past < stream - decode <= stream <= rt.max_seq_len:
+        raise ValueError(
+            f"a stream of {stream} tokens ({decode} of them decoded) must "
+            f"pass position {past}, twice the model's index_topk {topk}, in "
+            f"its prompt and fit max_seq {rt.max_seq_len}")
+    params = init_params_by_leaf(cfg, jax.random.PRNGKey(0),
+                                 quant=sv.get("quant", "none"))
+    tokens = np.random.default_rng(seed).integers(
+        1, cfg.vocab_size, stream).astype(np.int32)
+    n_prompt = (stream - decode) // C * C
+    served = served_rows(cfg, params, rt, tokens, n_prompt,
+                         faults={f: {} for f in FAULTS}, planted=planted)
+    pos = np.asarray(served["clean"][0])
+    before = (pos >= topk * 3 // 4) & (pos < topk)
+    after = pos >= past
+    keep = np.flatnonzero(before | after)
+    want = np.asarray(load_reference(config["reference"]).logits(
+        tokens, leaf_reader(params, is_quantized_leaf), config,
+        rows=pos[keep].tolist()), np.float32)
+    after = after[keep]
+    out = {"device": kind, "evidence": "cpu toy" if toy else "chip",
+           "limit": LIMIT, "stream": int(stream),
+           "prompt": int(n_prompt), "chunk_width": C, "index_topk": topk,
+           "past": past, "rows_before": int((~after).sum()),
+           "rows_after": int(after.sum())}
+    for fault, (_, got) in served.items():
+        read = _reading(got[keep], want)
+        out[fault] = {
+            f"{group}_{stat}": float(fn(read[sel]))
+            for group, sel in (("before", ~after), ("after", after))
+            for stat, fn in (("max", np.max), ("median", np.median))}
+        out[fault]["argmax_agree"] = int(
+            (got[keep].argmax(-1) == want.argmax(-1)).sum())
+        out[fault]["rows"] = [round(float(r), 4) for r in read]
+    out["positions"] = pos[keep].tolist()
+    clean = out["clean"]
+    out["ok"] = bool(
+        max(clean["before_median"], clean["after_median"]) < LIMIT
+        and all(out[f]["before_median"] < LIMIT < out[f]["after_median"]
+                for f in FAULTS if f != "clean"))
+    return out
+
+
+if __name__ == "__main__":
+    args = [a for a in sys.argv[1:] if a != "--toy"]
+    result = check(json.loads(Path(args[0]).read_text()),
+                   toy="--toy" in sys.argv)
+    Path(args[1]).parent.mkdir(parents=True, exist_ok=True)
+    Path(args[1]).write_text(json.dumps(result))
+    print(json.dumps(result))
+    sys.exit(0 if result["ok"] else 1)

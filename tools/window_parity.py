@@ -58,10 +58,14 @@ def _reading(a, b):
     return np.sqrt(((a - b) ** 2).mean(-1)) / b.std(-1)
 
 
-def served_rows(cfg, params, rt, tokens, n_prompt):
-    """The stream through the packed step under each of FAULTS:
-    {fault: ([positions], logits [rows, V])}, a chunk's last column and
-    each decode row."""
+def served_rows(cfg, params, rt, tokens, n_prompt, faults=None, planted=None):
+    """The stream through the packed step under each of `faults` (FAULTS:
+    {fault: the layouts it sets}): {fault: ([positions], logits
+    [rows, V])}, a chunk's last column and each decode row. planted
+    (tools/sparse_parity.py): fault -> a context manager that plants it
+    in the program while the fault's step is traced and run."""
+    import contextlib
+
     import jax
     import jax.numpy as jnp
     from butterfly_tpu.cache.paged import (
@@ -79,14 +83,16 @@ def served_rows(cfg, params, rt, tokens, n_prompt):
         eng.set_table_row(s, list(range(s * per, (s + 1) * per)))
     eng._ensure_window(k * C)
     eng._sync_table()
-    packed = jax.jit(partial(paged_forward_packed, use_kernel=eng._use_kernels),
-                     static_argnums=(1,))
     flush = jax.jit(flush_paged_window)
     L = cfg.num_layers
     out = {}
     with eng._mesh_ctx():
-        for fault, layouts in FAULTS.items():
+        for fault, layouts in (FAULTS if faults is None else faults).items():
             fcfg = cfg.replace(**{name: (v,) * L for name, v in layouts.items()})
+            # a step of its own for each fault: what is planted is traced
+            packed = jax.jit(partial(paged_forward_packed,
+                                     use_kernel=eng._use_kernels),
+                             static_argnums=(1,))
             cache, win, wlen = eng.cache, eng._kv_window, eng._win_len
             pos, rows = [], []
             chain = np.zeros((S,), np.int32)
@@ -99,10 +105,11 @@ def served_rows(cfg, params, rt, tokens, n_prompt):
                 else:       # slot 0 decodes, the chunk is empty
                     chain[0] = tokens[at]
                     chunk, active = np.zeros((1, C), np.int32), idle.at[0].set(True)
-                got, win, _ = packed(
-                    params, fcfg, jnp.asarray(chain), cache, jnp.asarray(chunk),
-                    jnp.asarray([0]), jnp.asarray([n], jnp.int32), active,
-                    win, wlen)
+                with planted(fault) if planted else contextlib.nullcontext():
+                    got, win, _ = packed(
+                        params, fcfg, jnp.asarray(chain), cache,
+                        jnp.asarray(chunk), jnp.asarray([0]),
+                        jnp.asarray([n], jnp.int32), active, win, wlen)
                 wlen = wlen.at[0].add(n or 1)
                 pos.append(at + max(n, 1) - 1)
                 rows.append(np.asarray(got[0], np.float32))
