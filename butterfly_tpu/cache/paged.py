@@ -36,6 +36,19 @@ semantics, TPU-native mechanics):
   touched.
 * Page allocation/free is host-side (cache/allocator.py) — the device
   never sees dynamic shapes, only a static pool and int32 tables.
+* TWO layouts of a page, chosen in one place from the configuration
+  (pool_row): HEAD-major [L, P, Kv, page, H], above, for every model the
+  paged kernel serves (a head's page is whole tiles, and tensor
+  parallelism shards dim 2); TOKEN-major [L, P, 1, page, Kv*H], "one
+  head of Kv*H", for a model with a sparse-attention indexer, whose
+  decode rows read single selected tokens: XLA's gather pays by the ROW
+  (about 10 ns whatever the row holds), and a token whose Kv heads lie
+  contiguous is ONE row of Kv*H values where the head-major page makes
+  it Kv rows of H (PERF.md PR 37). A page is the same bytes and whole
+  tiles either way, and writes, staging, the flush, the table gathers
+  and the window are indifferent to which: they see Kv' heads of H'.
+  Under tensor parallelism a token-major row shards its minor dim, a
+  chip's KV heads contiguous in it (parallel/partition.py).
 """
 from __future__ import annotations
 
@@ -56,19 +69,21 @@ from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
 # ops.flash_attention, none of which import this module; the ops kernel
 # wrappers import nothing project-local at module level.
 from butterfly_tpu.models.common import (
-    _cast_float, attend, attn_output, early_router_logits, embed_tokens,
-    expert_load, ffn_block, final_logits, index_proj, index_scores,
-    indexer_unsupported, layer_mask, layer_pattern_of, layer_stack,
-    make_mask, pre_norm, qkv_proj, quantize_kv, router_logits, select_mask,
-    select_topk)
+    _cast_float, attend, attend_token_rows, attn_output,
+    early_router_logits, embed_tokens, expert_load, ffn_block, final_logits,
+    index_proj, index_scores, indexer_unsupported, layer_mask,
+    layer_pattern_of, layer_stack, make_mask, pre_norm, qkv_proj,
+    quantize_kv, router_logits, select_mask, select_topk)
 from butterfly_tpu.ops import note_kernel
 from butterfly_tpu.ops.flash_attention import flash_attention_sharded
 from butterfly_tpu.ops.paged_attention import paged_attention_sharded
 
 
 class PagedKVCache(NamedTuple):
-    k_pages: jax.Array     # [L, P, Kv, page, H] (int8 codes when quantized)
-    v_pages: jax.Array     # [L, P, Kv, page, H]
+    # [L, P, Kv, page, H] (int8 codes when quantized); token-major
+    # [L, P, 1, page, Kv*H] for a model with an indexer (pool_row)
+    k_pages: jax.Array
+    v_pages: jax.Array
     page_table: jax.Array  # [slots, max_pages] int32, null = P-1
     lengths: jax.Array     # [slots] int32 tokens written per slot
     k_scale_pages: Optional[jax.Array] = None  # [L, P, Kv*page] f32 iff int8
@@ -103,6 +118,23 @@ class PagedKVCache(NamedTuple):
         return self.k_scale_pages is not None
 
 
+def pool_row(cfg: ModelConfig) -> Tuple[int, int]:
+    """(heads, width) of a cached token in a page [heads, page, width]:
+    the ONE place that decides the pool's layout, from the configuration.
+    A model with a sparse-attention indexer holds a token as one row
+    across its KV heads (token-major: (1, Kv*H)), every other model a
+    row a KV head (head-major: (Kv, H)); the module's docstring says
+    why. The window and the sharding specs follow the pool."""
+    if cfg.has_indexer:
+        return 1, cfg.num_kv_heads * cfg.head_dim
+    return cfg.num_kv_heads, cfg.head_dim
+
+
+def pool_layout(cfg: ModelConfig) -> str:
+    """pool_row by name, as /health reports it: "token" or "head"."""
+    return "token" if pool_row(cfg)[0] != cfg.num_kv_heads else "head"
+
+
 def init_paged_cache(cfg: ModelConfig, runtime: RuntimeConfig,
                      dtype: Optional[jnp.dtype] = None,
                      shardings: Optional[PagedKVCache] = None
@@ -119,7 +151,8 @@ def init_paged_cache(cfg: ModelConfig, runtime: RuntimeConfig,
     max_pages = -(-runtime.max_seq_len // page)
     P = runtime.num_pages or runtime.max_batch_size * max_pages
     P += 1  # null page
-    shape = (cfg.num_layers, P, cfg.num_kv_heads, page, cfg.head_dim)
+    heads, width = pool_row(cfg)
+    shape = (cfg.num_layers, P, heads, page, width)
     if runtime.kv_quant not in ("none", "int8"):
         raise ValueError(f"unknown kv quant {runtime.kv_quant!r}")
     if runtime.kv_quant == "int8":
@@ -298,8 +331,9 @@ class KVWindow(NamedTuple):
     """Staged-but-unflushed K/V for every slot, all layers.
 
     k/v: [L, S, Kv, W, H] in the pool's representation (int8 codes when
-    the pool is quantized, else the pool dtype); k/v_scale [L, S, Kv, W]
-    f32 iff quantized. Entry w of slot s sits at absolute position
+    the pool is quantized, else the pool dtype; [L, S, 1, W, Kv*H]
+    beside a token-major pool); k/v_scale [L, S, Kv, W] f32 iff
+    quantized. Entry w of slot s sits at absolute position
     lengths[s] + w of that slot's sequence, where lengths is the
     FLUSHED pool length; a separate win_len [S] vector (ridden through
     the block-scan carry beside this buffer, not stored here — it is
@@ -372,6 +406,8 @@ def stage_window_layer(wk, wv, k, v, win_len, wks=None, wvs=None,
     B, T = k.shape[0], k.shape[1]
     rows = (jnp.arange(B) if rows is None else rows)[:, None]  # [B, 1]
     idx = win_len[:, None] + jnp.arange(T)[None, :]     # [B, T]
+    # the heads as the window lays them (a token-major row: one of Kv*H)
+    k, v = (a.reshape(B, T, wk.shape[1], wk.shape[3]) for a in (k, v))
     if wks is not None:
         kq, ks = quantize_kv(k)
         vq, vs = quantize_kv(v)
@@ -631,17 +667,34 @@ def _settled(*view):
     return lax.optimization_barrier(view)
 
 
-def _pool_rows(pages: jax.Array, layer, pg: jax.Array, off: jax.Array):
-    """Rows of the pool by (page, offset): pages [L, P, Kv, page, H],
-    pg/off [B, K] -> [B, K, Kv, H]. The pool is seen as its rows
-    [L*P*Kv*page, H] (free: the two minor dims are whole tiles) and one
-    `take` reads the K x Kv rows a stream selected and no others; of
-    the forms of this gather tried on the chip it is the fastest
-    (PERF.md PR 36)."""
-    L, P, Kv, page, H = pages.shape
-    row = ((layer * P + pg)[..., None] * Kv + jnp.arange(Kv)) * page \
-        + off[..., None]
-    return jnp.take(pages.reshape(L * P * Kv * page, H), row, axis=0)
+def _row_addresses(page_table: jax.Array, num_pages: int, page: int,
+                   layer) -> jax.Array:
+    """[B, S_max] int32: for each position of each stream's table the
+    ROW of a token-major pool seen flat [L*P*page, Kv*H] that holds it in
+    `layer`. The table broadcast over a page's offsets: no lookup."""
+    B, mp = page_table.shape
+    addr = (layer * num_pages + page_table)[:, :, None] * page \
+        + jnp.arange(page, dtype=jnp.int32)
+    return addr.reshape(B, mp * page)
+
+
+def _pool_rows(pages: jax.Array, row: jax.Array) -> jax.Array:
+    """Token rows of a token-major pool by address: pages
+    [L, P, 1, page, R] (R = Kv*H: a token's KV heads contiguous), row
+    [B, K] (_row_addresses) -> [B, K, R]. The pool is seen as its rows
+    [L*P*page, R] (free: the two minor dims are whole tiles) and ONE
+    `take` reads the B x K rows the streams selected and no others.
+    XLA's gather pays by the row, about 11 ns up to 1 KB: a token a row
+    is a quarter of the rows a (token, KV head) a row was (PERF.md PRs
+    36, 37). An address below 0 (not in the pool: masked by the caller)
+    reads row 0: clipped, where take's default would pass both results
+    through a select again. The take is of the addresses FLAT: its
+    result [B*K, R] is a shape the benchmark's reader of the sparse
+    path knows by its first dim (servebench/sparse_peaks.py)."""
+    L, P, _, page, R = pages.shape
+    got = jnp.take(pages.reshape(L * P * page, R), row.reshape(-1), axis=0,
+                   mode="clip")
+    return got.reshape(*row.shape, R)
 
 
 def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
@@ -651,19 +704,19 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
     query scores every live position of its stream against the cached
     index keys (models.common.index_scores) and attends the
     cfg.index_topk that score highest. q [B,T,Nq,H]; qi [B,T,Ni,Hi] and
-    w [B,T,Ni] from index_proj; kp/vp [L,P,Kv,page,H] and kip
-    [L,P,1,page,Hi] the whole pools, `layer` the one to read; mask
-    [B,T,S_max] what each query MAY attend (causal, live); win as
-    paged_attend's, AFTER staging, with wki [B,1,W,Hi] the window's
-    index keys. Returns (out [B,T,Nq,H], count f32 [3]): the rows that
+    w [B,T,Ni] from index_proj; kp/vp [L,P,1,page,Kv*H] (token-major:
+    pool_row) and kip [L,P,1,page,Hi] the whole pools, `layer` the one
+    to read; mask [B,T,S_max] what each query MAY attend (causal,
+    live); win as paged_attend's, AFTER staging (window slices
+    [B,1,W,Kv*H]), with wki [B,1,W,Hi] the window's index keys. Returns (out [B,T,Nq,H], count f32 [3]): the rows that
     had anything to attend, the positions they could attend and the
     positions they read, summed over the rows.
 
     A decode row (T == 1) READS ONLY WHAT IT SELECTED: the index keys
     of its context (128 B a position), then the selected rows of keys
-    and values out of the pool by (page, offset) (_pool_rows), beside
-    the window's few staged entries, which are read whole and masked
-    to the selection. A chunk's rows (T > 1) share one stream's prefix:
+    and values out of the pool, a token a row, by the row's address
+    (_row_addresses, _pool_rows), beside the window's few staged
+    entries, which are read whole and masked to the selection. A chunk's rows (T > 1) share one stream's prefix:
     it is read once, whole, and masked row by row (select_mask), which
     is the same mathematics and cheaper than T gathers. So is any
     program whose whole context is no longer than index_topk.
@@ -699,29 +752,36 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
             if win is not None:
                 ck = insert_window_view(ck, wk, base)
                 cv = insert_window_view(cv, wv, base)
-            out = attend(q, *_settled(ck, cv), sel, cfg)
+            out = attend_token_rows(
+                q, *_settled(ck[:, :, 0], cv[:, :, 0]), sel)
         read = jnp.sum(sel, axis=-1)
     else:
-        idx, ok, sel = select_topk(scores[:, 0], mask[:, 0], topk,
-                                   with_mask=win is not None)
+        # a position's pool row rides the selection as the sort's
+        # payload (a lookup of the K selected positions in the table
+        # afterwards is a gather of K scalars a stream, at a row's cost
+        # each); -1: staged in the window, not in the pool
+        addr = _row_addresses(page_table, kp.shape[1], page, layer)
+        if win is not None:
+            addr = jnp.where(jnp.arange(S_max)[None, :] < base[:, None],
+                             addr, -1)
+        row, ok, sel = select_topk(scores[:, 0], mask[:, 0], topk,
+                                   with_mask=win is not None, payload=addr)
         with jax.named_scope("attn_sparse"):
+            ok = ok & (row >= 0)
+            kg = _pool_rows(kp, row)                       # [B,K,Kv*H]
+            vg = _pool_rows(vp, row)
             if win is not None:
-                # a selected position at or past the flushed length is
-                # staged, not in the pool: the window's W entries ride
-                # whole behind the selected rows, masked to the selection
-                ok = ok & (idx < base[:, None])
+                # the window's W staged entries ride whole behind the
+                # selected rows, masked to the selection; token-major as
+                # the pool's rows are, so this is no relayout
                 wpos = base[:, None] + jnp.arange(wk.shape[2])[None, :]
                 wsel = jnp.take_along_axis(
                     sel, jnp.minimum(wpos, S_max - 1), axis=1) \
                     & (wpos < S_max)
-            pg = jnp.take_along_axis(page_table, idx // page, axis=1)
-            kg = _pool_rows(kp, layer, pg, idx % page)     # [B,K,Kv,H]
-            vg = _pool_rows(vp, layer, pg, idx % page)
-            if win is not None:
-                kg = jnp.concatenate([kg, wk.transpose(0, 2, 1, 3)], axis=1)
-                vg = jnp.concatenate([vg, wv.transpose(0, 2, 1, 3)], axis=1)
+                kg = jnp.concatenate([kg, wk[:, 0]], axis=1)
+                vg = jnp.concatenate([vg, wv[:, 0]], axis=1)
                 ok = jnp.concatenate([ok, wsel], axis=1)
-            out = attend(q, kg, vg, ok[:, None], cfg)
+            out = attend_token_rows(q, kg, vg, ok[:, None])
         read = jnp.sum(ok, axis=-1)[:, None]
     count = jnp.stack([jnp.sum(live > 0), jnp.sum(live), jnp.sum(read)])
     return out, count.astype(jnp.float32)

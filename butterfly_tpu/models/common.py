@@ -251,6 +251,36 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array,
     return out.reshape(B, T, Nq, H)
 
 
+@jax.named_scope("attn")
+def attend_token_rows(q: jax.Array, k: jax.Array, v: jax.Array,
+                      mask: jax.Array) -> jax.Array:
+    """attend over TOKEN-major rows (cache/paged.py pool_row): q
+    [B, T, Nq, H]; k/v [B, S, Kv*H], a token's KV heads contiguous in
+    its row; mask [B, T, S]. Returns [B, T, Nq, H]. A KV head is read
+    as a lane-aligned slice of H of the row, one product a head: the
+    [B, S, Kv, H] view attend takes is a relayout of every row on the
+    chip (a tile row holds H values), which cost a fifth of the whole
+    read there (PERF.md PR 37). The same products, scale, mask and
+    float32 softmax as attend's, head by head: the same bits."""
+    B, T, Nq, H = q.shape
+    Kv = k.shape[-1] // H
+    q = q.reshape(B, T, Kv, Nq // Kv, H)
+    scale = 1.0 / jnp.sqrt(jnp.asarray(H, jnp.float32))
+
+    def head(rows, i):
+        return rows[..., i * H:(i + 1) * H]
+
+    scores = jnp.stack(
+        [jnp.einsum("btgh,bsh->btgs", q[:, :, i], head(k, i),
+                    preferred_element_type=jnp.float32)
+         for i in range(Kv)], axis=1)                      # [B,Kv,T,G,S]
+    scores = jnp.where(mask[:, None, :, None, :], scores * scale, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.stack([jnp.einsum("btgs,bsh->btgh", probs[:, i], head(v, i))
+                     for i in range(Kv)], axis=2)          # [B,T,Kv,G,H]
+    return out.reshape(B, T, Nq, H)
+
+
 # ---------------------------------------------------------------------------
 # Layer bodies
 # ---------------------------------------------------------------------------
@@ -357,15 +387,28 @@ def _selected(s: jax.Array, valid: jax.Array, kth: jax.Array,
 
 @jax.named_scope("attn_select")
 def select_topk(scores: jax.Array, valid: jax.Array, k: int,
-                with_mask: bool = False):
+                with_mask: bool = False,
+                payload: Optional[jax.Array] = None):
     """The k valid positions of the last axis that score highest, as
     (idx [..., k] int32, ok [..., k], mask): ok is False on the entries
     past the number of valid positions (idx then points anywhere).
     lax.top_k orders equal scores by position, the lower first. mask
     (with_mask; else None): the same selection over the last axis, as
-    select_mask gives it."""
+    select_mask gives it.
+
+    payload [..., S] int32: what to return of a selected position in
+    place of its index (the paged cache: the pool row that holds it).
+    It rides ONE stable sort beside the scores, highest first and equal
+    scores by position, the lower first: the same k in the same order
+    as lax.top_k's, without a lookup of k scalars behind it."""
     s = jnp.where(valid, scores, -jnp.inf)
-    vals, idx = lax.top_k(s, k)
+    if payload is None:
+        vals, idx = lax.top_k(s, k)
+    else:
+        # s + 0.0: a -0.0 sorts with the zeros, as it compares
+        neg, idx = lax.sort((-(s + 0.0), payload), is_stable=True,
+                            num_keys=1)
+        vals, idx = -neg[..., :k], idx[..., :k]
     mask = _selected(s, valid, vals[..., -1:], k) if with_mask else None
     return idx, vals > -jnp.inf, mask
 

@@ -176,19 +176,33 @@ def paged_cache_specs(cfg: ModelConfig, mesh: Mesh, num_slots: int,
     then runs data-parallel over slots. int8 pools add scale leaves
     [L,P,Kv*page] whose flat dim shards over `tensor` iff Kv does (a
     tensor chunk of the kv-major flat dim is exactly one kv-group's
-    scales — see cache/paged.py layout notes).
+    scales — see cache/paged.py layout notes). A token-major pool
+    [L,P,1,page,Kv*H] (cache/paged.py pool_row: a model with an
+    indexer) shards its MINOR dim over `tensor`: a chip's KV heads lie
+    contiguous in a token's row, so its chunk is the same heads.
     """
     from butterfly_tpu.cache.paged import PagedKVCache
     dslots = _div(num_slots, mesh, "data")
     lspec = _div(cfg.num_layers, mesh, "stage")
     tspec = _div(cfg.num_kv_heads, mesh, "tensor")
-    kv = P(lspec, None, tspec, None, None)
+    kv = P(lspec, None, *_kv_row_spec(cfg, mesh))
     sc = P(lspec, None, tspec) if quant else None
     # the index keys have ONE head a token: every chip holds them whole
     ki = P(lspec, None, None, None, None) if cfg.has_indexer else None
     return PagedKVCache(k_pages=kv, v_pages=kv,
                         page_table=P(dslots, None), lengths=P(dslots),
                         k_scale_pages=sc, v_scale_pages=sc, ki_pages=ki)
+
+
+def _kv_row_spec(cfg: ModelConfig, mesh: Mesh) -> Tuple:
+    """The (heads, page or window, width) dims of a paged pool or its
+    window: KV heads over `tensor`, in the dim that holds them (dim 2 of
+    a head-major page, the minor dim of a token-major one)."""
+    from butterfly_tpu.cache.paged import pool_layout
+    tspec = _div(cfg.num_kv_heads, mesh, "tensor")
+    if pool_layout(cfg) == "head":
+        return tspec, None, None
+    return None, None, tspec
 
 
 def kv_window_specs(cfg: ModelConfig, mesh: Mesh, num_slots: int,
@@ -199,11 +213,12 @@ def kv_window_specs(cfg: ModelConfig, mesh: Mesh, num_slots: int,
     window segment, and the flush scatter all stay local to the shard
     that owns the matching pool bytes. L stays replicated (the window
     only exists on the non-pipeline serving path; stage > 1 falls back
-    to per-token writes)."""
+    to per-token writes). A token-major window [L, S, 1, W, Kv*H]
+    shards its minor dim, as its pool does."""
     from butterfly_tpu.cache.paged import KVWindow
     dslots = _div(num_slots, mesh, "data")
     tspec = _div(cfg.num_kv_heads, mesh, "tensor")
-    kv = P(None, dslots, tspec, None, None)
+    kv = P(None, dslots, *_kv_row_spec(cfg, mesh))
     sc = P(None, dslots, tspec, None) if quant else None
     ki = P(None, dslots, None, None, None) if cfg.has_indexer else None
     return KVWindow(k=kv, v=kv, k_scale=sc, v_scale=sc, ki=ki)

@@ -29,8 +29,10 @@ zero-egress environment):
   bytes_in_use / peak_bytes_in_use / bytes_limit where the backend
   reports them}, "kernels" {mode: off | interpret | compiled, calls:
   kernel call sites its programs traced, with "dense_fallback" for a
-  site that wanted a kernel and took the dense path} and "allocator"
-  (native | python). 503 with a detail string when wedged.
+  site that wanted a kernel and took the dense path}, "allocator"
+  (native | python) and "pool_layout" (head | token: a page of the KV
+  pool holds a row a KV head, or, for a model with a sparse-attention
+  indexer, a row a token). 503 with a detail string when wedged.
 * GET /kv/pages?hashes=h1,h2,...   export registered prefix-cache KV
   pages by chain hash (fleet/kvtransfer.py payload: base64 page bytes +
   geometry; the leading registered run ships, the rest come back
@@ -143,12 +145,15 @@ class StopSequenceMatcher:
 
 def runtime_report(sched) -> dict:
     """What this replica runs on, for the start-up line and /health:
-    the device as JAX reports it and which page allocator the scheduler
-    got. Static for the life of the process."""
+    the device as JAX reports it, which page allocator the scheduler
+    got and which layout the page pool has (cache/paged.py pool_row).
+    Static for the life of the process."""
+    from butterfly_tpu.cache.paged import pool_layout
     from butterfly_tpu.core.mesh import device_report
     native = type(sched.alloc).__name__ == "NativePageAllocator"
     return {"device": device_report(),
-            "allocator": "native" if native else "python"}
+            "allocator": "native" if native else "python",
+            "pool_layout": pool_layout(sched.engine.cfg)}
 
 
 def device_memory() -> list:
@@ -645,6 +650,7 @@ def make_handler(state: ServerState):
                             "device": {**state.runtime["device"],
                                        "memory": device_memory()},
                             "allocator": state.runtime["allocator"],
+                            "pool_layout": state.runtime["pool_layout"],
                             # programs compiled since the ready line:
                             # each one stalled a tick of live serving
                             "compiles_after_ready": int(
@@ -1423,7 +1429,8 @@ def run_server(args) -> int:
           f"(slots={rt.max_batch_size}, pages={engine.cache.num_pages - 1}"
           f"x{rt.page_size}tok{mesh_desc}; platform={dev['platform']} "
           f"device_kind={dev['kind']!r} devices={dev['count']} "
-          f"kernels={engine.kernel_mode} allocator={rep['allocator']})",
+          f"kernels={engine.kernel_mode} allocator={rep['allocator']} "
+          f"pool={rep['pool_layout']})",
           flush=True)
     # SIGTERM ends serving the way Ctrl-C does: serve_forever returns,
     # and the exit code says whether serving was wedged

@@ -8,6 +8,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 import numpy as np
 import pytest
 
@@ -15,12 +16,15 @@ from butterfly_tpu.cache import paged
 from butterfly_tpu.cache.paged import (
     flush_paged_window, init_kv_window, init_paged_cache, paged_forward,
     paged_forward_packed, paged_forward_window, permute_paged_tail,
-    permute_window_tail, pool_leaves, window_leaves)
+    permute_window_tail, pool_layout, pool_leaves, window_leaves)
 from butterfly_tpu.core.config import (
-    ModelConfig, RuntimeConfig, keye_vl2_30b_a3b, tiny)
+    MeshConfig, ModelConfig, RuntimeConfig, keye_vl2_30b_a3b, tiny)
+from butterfly_tpu.core.mesh import make_mesh
 from butterfly_tpu.models import keye_f32 as ref
 from butterfly_tpu.models.common import (
     Model, index_scores, layer_stack, select_mask, select_topk)
+from butterfly_tpu.parallel.partition import (
+    kv_window_specs, paged_cache_specs, shard_params, to_shardings)
 from butterfly_tpu.quant.int8 import is_quantized_leaf, quantize_int8
 
 CFG = tiny("keye", hidden_size=64, num_layers=2, num_heads=4,
@@ -131,15 +135,19 @@ def test_prefill_then_decode_through_the_contiguous_cache(params, tokens, want):
 RT = RuntimeConfig(max_batch_size=2, max_seq_len=64, page_size=4)
 
 
-def paged_cache(cfg=CFG, rt=RT):
-    cache = init_paged_cache(cfg, rt)
+def paged_cache(cfg=CFG, rt=RT, mesh=None):
+    """mesh: every leaf in its mesh layout (paged_cache_specs)."""
+    sh = None if mesh is None else to_shardings(
+        paged_cache_specs(cfg, mesh, rt.max_batch_size), mesh)
+    cache = init_paged_cache(cfg, rt, shardings=sh)
     per = rt.max_seq_len // rt.page_size
     table = np.full(np.asarray(cache.page_table).shape, cache.null_page,
                     np.int32)
     for b in range(2):
         # a slot's pages lie scattered and out of order in the pool
         table[b, :per] = np.arange(per)[::-1] * 2 + b
-    return cache._replace(page_table=jnp.asarray(table))
+    return cache._replace(page_table=jax.device_put(
+        table, None if sh is None else sh.page_table))
 
 
 def test_paged_prefill_chunks_then_decode(params, tokens, want):
@@ -179,30 +187,38 @@ def test_windowed_decode_across_a_flush(params, tokens, want):
     assert worst(jnp.concatenate(rows, axis=1), want) < TOL
 
 
-def packed_run(params, tokens, cfg=CFG, windowed=True, C=6):
+def packed_run(params, tokens, cfg=CFG, windowed=True, C=6, mesh=None):
     """Sequence 0 decodes from position 20 while sequence 1's prompt is
     fed in chunks of C from position 3 (crossing topk) through the
     packed mixed step; a flush every third step. Returns the logits
-    [(sequence, position, row [V])] and the loads."""
-    cache = paged_cache(cfg)
+    [(sequence, position, row [V])] and the loads. mesh: the pool and
+    the window in their mesh layouts, the step and the flush jitted
+    (GSPMD partitions them), and the pool and window as they ended."""
+    cache = paged_cache(cfg, mesh=mesh)
+    step, flush = paged_forward_packed, flush_paged_window
+    if mesh is not None:
+        step = jax.jit(paged_forward_packed, static_argnums=(1,))
+        flush = jax.jit(flush_paged_window)
     _, cache = paged_forward(
         params, cfg, jnp.asarray(tokens[:, :20]), cache, fresh=True,
         active=jnp.asarray([True, False]))
     _, cache = paged_forward(
         params, cfg, jnp.asarray(tokens[:, :3]), cache, fresh=True,
         active=jnp.asarray([False, True]))
-    window = init_kv_window(cache, 3 * C) if windowed else None
+    window = init_kv_window(cache, 3 * C, None if mesh is None else
+                            to_shardings(kv_window_specs(cfg, mesh, 2), mesh)
+                            ) if windowed else None
     wlen = jnp.zeros((2,), jnp.int32) if windowed else None
     out, loads = [], []
     t0, t1 = 20, 3
-    step = 0
+    n = 0
     while t0 < T:
         count = min(C, T - t1)
         chunk = np.zeros((1, C), np.int32)
         chunk[0, :count] = tokens[1, t1:t1 + count]
-        if windowed and step % 3 == 0:
-            cache, wlen, _ = flush_paged_window(cache, window, wlen)
-        logits, state, load = paged_forward_packed(
+        if windowed and n % 3 == 0:
+            cache, wlen, _ = flush(cache, window, wlen)
+        logits, state, load = step(
             params, cfg, jnp.asarray(tokens[:, t0]), cache,
             jnp.asarray(chunk), jnp.asarray([1]), jnp.asarray([count]),
             jnp.asarray([True, False]), window, wlen)
@@ -215,7 +231,9 @@ def packed_run(params, tokens, cfg=CFG, windowed=True, C=6):
         if count:
             out.append((1, t1 + count - 1, logits[1]))
         loads.append(np.asarray(load))
-        t0, t1, step = t0 + 1, t1 + count, step + 1
+        t0, t1, n = t0 + 1, t1 + count, n + 1
+    if mesh is not None:
+        return out, loads, cache, window
     return out, loads
 
 
@@ -439,6 +457,163 @@ def test_a_model_without_an_indexer_has_no_third_pool(arch):
     assert "index" not in p["layers"] and "q_norm" not in p["layers"]["attn"]
     assert "index" not in layer_stack(p["layers"], cfg)
     assert len(jax.tree.leaves(cache)) == 4
+    # the pool, the window and their sharding specs as they were before
+    # a token-major layout existed: a row a KV head, heads on dim 2
+    L, Kv, H = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+    pages = RT.max_batch_size * (RT.max_seq_len // RT.page_size) + 1
+    assert pool_layout(cfg) == "head"
+    assert cache.k_pages.shape == cache.v_pages.shape == \
+        (L, pages, Kv, RT.page_size, H)
+    assert window.k.shape == window.v.shape == (L, 2, Kv, 4, H)
+    mesh = make_mesh(MeshConfig(tensor=2), jax.devices()[:2])
+    assert Kv % 2 == 0
+    specs = paged_cache_specs(cfg, mesh, 2)
+    assert specs.k_pages == specs.v_pages == P(None, None, "tensor", None,
+                                                None)
+    assert specs.page_table == P(None, None) and specs.lengths == P(None)
+    assert specs.ki_pages is None and specs.k_scale_pages is None
+    assert paged_cache_specs(cfg, mesh, 2, quant=True).k_scale_pages == \
+        P(None, None, "tensor")
+    wspecs = kv_window_specs(cfg, mesh, 2)
+    assert wspecs.k == wspecs.v == P(None, None, "tensor", None, None)
+    assert wspecs.ki is None and wspecs.k_scale is None
+    assert kv_window_specs(cfg, mesh, 2, quant=True).k_scale == \
+        P(None, None, "tensor", None)
+
+
+# -- the token-major pool: a selected token is ONE row ------------------------
+
+def test_a_model_with_an_indexer_holds_a_token_as_one_row():
+    """Keys and values [L, P, 1, page, Kv*H], the window beside them,
+    the index keys as they were; under a tensor mesh the row's minor
+    dim is what is sharded, a chip's KV heads contiguous in it."""
+    L, Kv, H = CFG.num_layers, CFG.num_kv_heads, CFG.head_dim
+    assert pool_layout(CFG) == "token" and paged.pool_row(CFG) == (1, Kv * H)
+    cache = init_paged_cache(CFG, RT)
+    assert cache.k_pages.shape == cache.v_pages.shape == (L, 33, 1, 4, Kv * H)
+    assert cache.ki_pages.shape == (L, 33, 1, 4, CFG.index_head_dim)
+    assert cache.page_size == 4 and cache.null_page == 32
+    window = init_kv_window(cache, 8)
+    assert window.k.shape == window.v.shape == (L, 2, 1, 8, Kv * H)
+    mesh = make_mesh(MeshConfig(tensor=2), jax.devices()[:2])
+    specs, wspecs = (f(CFG, mesh, 2) for f in (paged_cache_specs,
+                                               kv_window_specs))
+    assert specs.k_pages == specs.v_pages == P(None, None, None, None,
+                                                "tensor")
+    assert wspecs.k == wspecs.v == P(None, None, None, None, "tensor")
+    assert specs.ki_pages == wspecs.ki == P(None, None, None, None, None)
+    # KV heads the mesh cannot divide: every chip holds the rows whole
+    odd = make_mesh(MeshConfig(tensor=4), jax.devices()[:4])
+    assert paged_cache_specs(CFG, odd, 2).k_pages == P(*[None] * 5)
+
+
+def head_major_rows(pages, layer, pg, off):
+    """The read of a head-major pool [L, P, Kv, page, H] by (page,
+    offset) as PR 36 made it: Kv rows of H a token out of the pool seen
+    flat [L*P*Kv*page, H] -> [B, K, Kv, H]."""
+    L, Pn, Kv, page, H = pages.shape
+    row = ((layer * Pn + pg)[..., None] * Kv + jnp.arange(Kv)) * page \
+        + off[..., None]
+    return jnp.take(pages.reshape(L * Pn * Kv * page, H), row, axis=0)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_a_token_row_is_the_head_major_gather_to_the_bit(seed):
+    """_pool_rows by _row_addresses on a token-major pool against the
+    head-major read of the same values: random pools, layers, tables
+    (null-page entries among them) and positions, in any order and
+    repeated; an entry that is not in the pool (address -1, `ok` false
+    in sparse_paged_attend) reads a row of the pool, whichever."""
+    rng = np.random.RandomState(seed)
+    L, Pn, Kv, page, H = 2 + seed % 3, 9 + seed, 1 + seed % 4, 4, 8
+    B, mp, K = 3, 5, 11
+    head = jnp.asarray(rng.randn(L, Pn, Kv, page, H), jnp.float32)
+    head = head.astype(jnp.bfloat16 if seed % 2 else jnp.float32)
+    head = head.at[:, -1].set(0)                         # the null page
+    token = head.transpose(0, 1, 3, 2, 4).reshape(L, Pn, 1, page, Kv * H)
+    table = rng.randint(0, Pn, (B, mp)).astype(np.int32)
+    table[:, -1] = Pn - 1                                # not allocated
+    idx = rng.randint(0, mp * page, (B, K)).astype(np.int32)
+    layer = jnp.int32(rng.randint(L))
+    addr = paged._row_addresses(jnp.asarray(table), Pn, page, layer)
+    assert addr.shape == (B, mp * page) and addr.dtype == jnp.int32
+    row = np.take_along_axis(np.asarray(addr), idx, axis=1)
+    ok = rng.rand(B, K) < 0.8
+    row = np.where(ok, row, -1)
+    got = jax.jit(paged._pool_rows)(token, jnp.asarray(row))
+    want = head_major_rows(head, layer, np.take_along_axis(
+        table, idx // page, axis=1), idx % page)
+    assert got.shape == (B, K, Kv * H) and got.dtype == head.dtype
+    got = np.asarray(got.astype(jnp.float32)).reshape(B, K, Kv, H)
+    want = np.asarray(want.astype(jnp.float32))
+    np.testing.assert_array_equal(got[ok], want[ok])
+    null = np.take_along_axis(table, idx // page, axis=1) == Pn - 1
+    assert null.any() and not got[ok & null].any()
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(
+        got[~ok], np.broadcast_to(np.asarray(token.astype(
+            jnp.float32))[0, 0, 0, 0].reshape(Kv, H), got[~ok].shape))
+
+
+@pytest.mark.parametrize("k", [1, 5, 8, 12])
+def test_a_payload_rides_the_selection_in_top_k_s_order(k):
+    """select_topk with a payload: the payload of the positions that
+    lax.top_k picks, in its order (ties to the lower position, the
+    entries past the valid ones included), and the same mask."""
+    rng = np.random.RandomState(k)
+    scores = jnp.asarray(np.round(rng.randn(4, 12), 1), jnp.float32)
+    valid = jnp.asarray(rng.rand(4, 12) < 0.7)
+    payload = jnp.asarray(rng.permutation(48).reshape(4, 12), jnp.int32)
+    idx, ok, mask = select_topk(scores, valid, k, with_mask=True)
+    got, ok2, mask2 = select_topk(scores, valid, k, with_mask=True,
+                                  payload=payload)
+    np.testing.assert_array_equal(
+        got, np.take_along_axis(np.asarray(payload), np.asarray(idx), 1))
+    np.testing.assert_array_equal(ok, ok2)
+    np.testing.assert_array_equal(mask, mask2)
+    assert len(np.unique(np.asarray(scores))) < scores.size   # ties
+
+
+def test_packed_step_on_a_tensor_mesh_shards_a_token_s_row(params, tokens,
+                                                           want):
+    """The packed step, window on, over two chips (one KV head each):
+    the single-device logits, from a pool and a window whose rows are
+    sharded on their minor dim."""
+    mesh = make_mesh(MeshConfig(tensor=2), jax.devices()[:2])
+    out, _, cache, window = packed_run(shard_params(params, CFG, mesh),
+                                       tokens, mesh=mesh)
+    assert len(out) > 20
+    for seq, pos, row in out:
+        assert worst(row, want[seq, pos]) < TOL, (seq, pos)
+    half = CFG.num_kv_heads * CFG.head_dim // 2
+    for a in (cache.k_pages, cache.v_pages, window.k, window.v):
+        assert a.sharding.spec == P(None, None, None, None, "tensor")
+        assert a.sharding.shard_shape(a.shape)[2:] == (1, a.shape[3], half)
+    assert cache.ki_pages.sharding.is_fully_replicated
+
+
+def test_served_tokens_on_a_tensor_mesh_are_the_single_device_s(params):
+    """Through the scheduler (mixed blocks, the window, the flush) on
+    two chips: the tokens one chip serves."""
+    from butterfly_tpu.engine.serving import ServingEngine
+    from butterfly_tpu.sched.scheduler import Scheduler
+    rt = RuntimeConfig(max_batch_size=3, max_seq_len=64, page_size=4,
+                       decode_steps_per_tick=2, prefill_inline_budget=8)
+    rng = np.random.RandomState(12)
+    prompts = [rng.randint(1, CFG.vocab_size, n).tolist() for n in (7, 19)]
+
+    def run(mesh):
+        eng = ServingEngine(Model(CFG), params, rt, mesh=mesh)
+        sched = Scheduler(eng, seed=0)
+        reqs = [sched.submit(p, max_new_tokens=12) for p in prompts]
+        sched.run_until_done()
+        return [r.output for r in reqs], eng
+
+    ref_out, one = run(None)
+    got, eng = run(make_mesh(MeshConfig(tensor=2), jax.devices()[:2]))
+    assert got == ref_out and all(len(o) == 12 for o in got)
+    assert one.cache.k_pages.shape == eng.cache.k_pages.shape
+    assert eng.cache.k_pages.sharding.spec[4] == "tensor"
 
 
 # -- what cannot take the third pool refuses the model by name ----------------
@@ -524,6 +699,20 @@ def test_served_tokens_are_the_reference_s_greedy_tokens(params):
                for t in ticks)
     assert sched._g_kv_rows_selected.value == TOPK
     assert sched._g_kv_rows_live.value > TOPK
+
+
+@pytest.mark.parametrize("arch, layout", [("keye", "token"),
+                                          ("mixtral", "head")])
+def test_the_runtime_report_says_which_pool_serves(arch, layout):
+    """What /health and the ready line report beside the kernels."""
+    from butterfly_tpu.engine.serving import ServingEngine
+    from butterfly_tpu.sched.scheduler import Scheduler
+    from butterfly_tpu.serve.server import runtime_report
+    cfg = tiny(arch, dtype="float32", param_dtype="float32")
+    rt = RuntimeConfig(max_batch_size=2, max_seq_len=64, page_size=8)
+    sched = Scheduler(ServingEngine(
+        Model(cfg), Model(cfg).init(jax.random.PRNGKey(0)), rt), seed=0)
+    assert runtime_report(sched)["pool_layout"] == layout
 
 
 def test_a_model_without_an_indexer_s_ticks_carry_no_kv_rows():

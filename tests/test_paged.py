@@ -464,3 +464,120 @@ def test_flush_under_a_mesh_equals_the_unsharded_flush():
         np.testing.assert_array_equal(np.asarray(got.lengths),
                                       np.asarray(want.lengths))
         assert int(m) == int(n) and not np.asarray(zeroed).any()
+
+
+# -- the token-major pool of a model with an indexer (ISSUE 37) ---------------
+#
+# cache/paged.py pool_row: [L, P, 1, page, Kv*H], a token's KV heads
+# contiguous. The code that is indifferent to the layout (writes, staging,
+# the flush, the table gathers, the window's insert and permutation) must
+# leave in it the values it leaves in a head-major pool.
+
+def _token_major(a):
+    """[.., N, Kv, R, H] -> [.., N, 1, R, Kv*H]: the same values with a
+    token's KV heads contiguous (a pool, a window, or one layer of
+    either)."""
+    *lead, Kv, R, H = a.shape
+    return jnp.swapaxes(a, -3, -2).reshape(*lead, 1, R, Kv * H)
+
+
+def _both_layouts(lengths, **kw):
+    cache, window = _staged_case(False, lengths, **kw)
+    return (cache, window), (
+        cache._replace(k_pages=_token_major(cache.k_pages),
+                       v_pages=_token_major(cache.v_pages)),
+        window._replace(k=_token_major(window.k), v=_token_major(window.v)))
+
+
+@pytest.mark.parametrize("case", list(FLUSH_CASES))
+def test_flush_of_a_token_major_pool_lands_the_head_major_values(case):
+    from butterfly_tpu.cache.paged import flush_paged_window
+    lengths, staged = FLUSH_CASES[case]
+    head, token = _both_layouts(lengths)
+    win_len = jnp.asarray(staged, jnp.int32)
+    want, _, n = jax.jit(flush_paged_window)(*head, win_len)
+    got, zeroed, m = jax.jit(flush_paged_window)(*token, win_len)
+    assert got.k_pages.shape == token[0].k_pages.shape
+    for new, old in ((got.k_pages, want.k_pages), (got.v_pages, want.v_pages)):
+        np.testing.assert_array_equal(np.asarray(new),
+                                      np.asarray(_token_major(old)))
+    np.testing.assert_array_equal(np.asarray(got.lengths),
+                                  np.asarray(want.lengths))
+    assert int(m) == int(n) == sum(staged) and not np.asarray(zeroed).any()
+
+
+def test_flush_of_a_token_major_pool_copies_and_relays_no_pool():
+    """test_flush_holds_no_scatter_into_the_pool_and_no_transpose_of_it,
+    for the layout whose page is [1, page, Kv*H]."""
+    from butterfly_tpu.cache.paged import flush_paged_window
+    _, (cache, window) = _both_layouts([2, 3, 7, 1])
+    win_len = jnp.asarray([3, 5, 2, 8], jnp.int32)
+    eqns = list(_eqns(jax.make_jaxpr(flush_paged_window)(
+        cache, window, win_len).jaxpr))
+    pool = cache.k_pages.shape
+    assert pool[2] == 1 and window.k.shape != pool
+    makes = {e.primitive.name for e in eqns
+             if any(v.aval.shape == pool for v in e.outvars)}
+    assert makes == {"while", "dynamic_update_slice"}, makes
+    takes = {e.primitive.name for e in eqns
+             if any(getattr(v, "aval", None) is not None
+                    and v.aval.shape == pool for v in e.invars)}
+    assert takes <= {"while", "dynamic_update_slice", "dynamic_slice"}, takes
+
+
+def _write(pool, table, k, v, start):
+    kp, vp, _, _ = write_paged_layer(pool.k_pages[1], pool.v_pages[1], table,
+                                     k, v, start)
+    return kp, vp
+
+
+def _stage(window, k, v, wlen):
+    from butterfly_tpu.cache.paged import stage_window_layer
+    wk, wv, _, _ = stage_window_layer(window.k[1], window.v[1], k, v, wlen,
+                                      rows=jnp.asarray([2, 0, 3]))
+    return wk, wv
+
+
+INDIFFERENT = ["write", "stage", "gather", "insert", "permute"]
+
+
+@pytest.mark.parametrize("what", INDIFFERENT)
+def test_code_indifferent_to_the_layout_leaves_the_same_values(what):
+    """Each function on a head-major pool or window and on the
+    token-major one of the same values, given the same fresh rows
+    [B, T, Kv, H]: the results are each other's relayout, to the bit."""
+    from butterfly_tpu.cache.paged import (
+        insert_window_view, permute_window_tail)
+    (hc, hw), (tc, tw) = _both_layouts([2, 3, 7, 1], Kv=4)
+    Kv, H = hc.k_pages.shape[2], hc.k_pages.shape[4]
+    ks = jax.random.split(jax.random.PRNGKey(5), 2)
+    k = jax.random.normal(ks[0], (3, 2, Kv, H))
+    v = jax.random.normal(ks[1], (3, 2, Kv, H))
+    if what == "write":
+        start, table = jnp.asarray([2, 3, 7]), hc.page_table[:3]
+        want = _write(hc, table, k, v, start)
+        got = _write(tc, table, k, v, start)
+    elif what == "stage":
+        wlen = jnp.asarray([1, 6, 7])       # row 2's second entry drops
+        want, got = _stage(hw, k, v, wlen), _stage(tw, k, v, wlen)
+    elif what == "gather":
+        want = [gather_paged_layer(hc.k_pages, hc.page_table, 2)]
+        got = [gather_paged_layer(tc.k_pages, tc.page_table, 2)]
+        assert got[0].shape == (4, 12, 1, Kv * H)
+        want = [want[0].reshape(got[0].shape)]
+    elif what == "insert":
+        view = gather_paged_layer(hc.k_pages, hc.page_table, 0)
+        want = [insert_window_view(view, hw.k[0], hc.lengths)]
+        got = [insert_window_view(view.reshape(4, 12, 1, Kv * H), tw.k[0],
+                                  hc.lengths)]
+        want = [want[0].reshape(got[0].shape)]
+    else:
+        perm = jnp.asarray([[2, 0, 1]] * 4, jnp.int32)
+        wlen = jnp.asarray([1, 4, 0, 5], jnp.int32)
+        want = permute_window_tail(hw, wlen, perm)[:2]
+        got = permute_window_tail(tw, wlen, perm)[:2]
+    for new, old in zip(got, want):
+        if what in ("write", "stage", "permute"):
+            old = _token_major(old)
+        assert new.shape == old.shape
+        np.testing.assert_array_equal(np.asarray(new), np.asarray(old))

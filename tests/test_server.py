@@ -60,6 +60,9 @@ def test_health(server):
     # probe instead of a Prometheus text scrape
     assert isinstance(body["queue_depth"], int) and body["queue_depth"] >= 0
     assert isinstance(body["active"], int) and body["active"] >= 0
+    # which layout the KV pool has (a model without an indexer: a row a
+    # KV head), beside the kernels and the allocator
+    assert body["pool_layout"] == "head" and "kernels" in body
 
 
 def test_generate_blocking(server):
