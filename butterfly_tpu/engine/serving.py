@@ -69,6 +69,17 @@ from butterfly_tpu.engine.sampling import (
 from butterfly_tpu.models.common import Model, indexer_unsupported
 
 
+#: the span a program launch runs under (`bf.tick.dispatch.launch` in a
+#: trace); its end tells a scheduler that the device has work again
+LAUNCH_SPAN = "dispatch.launch"
+
+
+def _trace_span(name: str, **attrs):
+    """`bf.tick.<name>` in the profiler's trace and nothing else: the
+    span of an engine that no scheduler drives."""
+    return TraceAnnotation("bf.tick." + name, **attrs)
+
+
 def named(fn, name: str):
     """`fn` (a partial or a closure built here, never a shared
     function) under a stable `__name__`. jax.jit names its program
@@ -369,6 +380,11 @@ class ServingEngine:
         # scheduler's tick record and the launch span carry both
         self.last_program: Optional[str] = None
         self.last_rows = 0
+        # the span _put and _launch run under: a context manager
+        # `span(name, **attrs)` that writes `bf.tick.<name>` into the
+        # profiler's trace. A scheduler that drives this engine puts
+        # its own _span here, which also times the tick's phases
+        self.span = _trace_span
         # what the newest mixed block's routing asked of the experts, a
         # device f32 [3] (_packed_scan's `load`); None for a dense model.
         # The scheduler fetches it with the block's tokens.
@@ -483,7 +499,7 @@ class ServingEngine:
     def _put(self, *operands):
         """The block table and a dispatch's host operands, each a
         (value, dtype or None) pair, to the device under one span."""
-        with TraceAnnotation("bf.tick.dispatch.put"):
+        with self.span("dispatch.put"):
             self._sync_table()
             return [jnp.asarray(v, dt) for v, dt in operands]
 
@@ -495,9 +511,8 @@ class ServingEngine:
         self.blocks_launched += 1
         self.last_program = prog.__name__
         self.last_rows = rows
-        with TraceAnnotation("bf.tick.dispatch.launch",
-                             program=self.last_program,
-                             block=self.blocks_launched):
+        with self.span(LAUNCH_SPAN, program=self.last_program,
+                       block=self.blocks_launched):
             return prog(*args)
 
     @property

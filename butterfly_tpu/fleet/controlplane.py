@@ -405,8 +405,7 @@ class ControlPlaneState(RouterState):
     #: queue-depth snapshots across replicas is not a meaningful series.
     AUTOSCALE_GAUGES = ("queue_depth", "active_requests", "kv_pages_free",
                         "kv_pages_total", "inflight_depth",
-                        "tokens_per_sec", "device_bubble_p50",
-                        "device_bubble_p95", "slo_burn_rate",
+                        "tokens_per_sec", "slo_burn_rate",
                         # tick anatomy (ISSUE 15): host-bound vs
                         # device-bound per replica — an autoscaler that
                         # only sees queue depth can't tell which tier

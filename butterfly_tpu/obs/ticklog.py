@@ -11,7 +11,9 @@ Two bounded, always-cheap instruments the scheduler feeds:
   batch occupancy and page headroom, the block program the tick
   dispatched and the rows one step of it computes, the loop's wait
   for the serving lock, the process's count of compilations and, for a
-  model of experts, what the drained blocks' routing asked of them. The sequence number is also the ``seq`` of
+  model of experts, what the drained blocks' routing asked of them,
+  and the starvation clock: how long the device waited for the host
+  before the tick's launches, why, and in which spans. The sequence number is also the ``seq`` of
   the tick's ``bf.tick`` span in a profiler trace: the join between
   the two needs no clock. One dict append per tick under an
   uncontended lock — the software answer to "where does the tick's
@@ -20,8 +22,8 @@ Two bounded, always-cheap instruments the scheduler feeds:
 
 * ``FlightRecorder`` — a bounded ring of recent structured serving
   events (admission, preempt, shed, deadline 504, breaker transition,
-  window flush, drain barrier, wedge) plus trigger predicates over
-  per-tick signal snapshots. When a trigger fires (SLO burn rate over
+  window flush, drain barrier, stalled fetch, wedge) plus trigger
+  predicates over per-tick signal snapshots. When a trigger fires (SLO burn rate over
   threshold, preemption storm, deadline-expiry burst, wedge latch) the
   recorder freezes the ring into a JSON post-mortem artifact —
   in-memory always, on disk when ``dump_dir`` is set — so the events
@@ -87,7 +89,11 @@ class TickLog:
                spec: bool = False, program: Optional[str] = None,
                rows: Optional[int] = None,
                block: int = 0, lock_s: float = 0.0,
-               compiles: int = 0, expert_load=None) -> None:
+               compiles: int = 0, expert_load=None,
+               starved_s: Optional[float] = None,
+               starved_cause: Optional[str] = None,
+               starved_by: Optional[Dict[str, float]] = None,
+               gap_s: float = 0.0, profiled: bool = False) -> None:
         """Append one tick record (hot path: one dict build + one
         locked append per TICK, never per token). `phases` is copied —
         callers may reuse/zero their accumulator dict. `expert_load`:
@@ -97,7 +103,17 @@ class TickLog:
         None for a dense model or a tick that drained no block. A model
         with a sparse-attention indexer gives two values more,
         `kv_rows_live` and `kv_rows_selected`: the positions a live
-        decode row could attend and those it read (null otherwise)."""
+        decode row could attend and those it read (null otherwise).
+        The starvation clock (Scheduler._starve): `starved_s`, the
+        seconds the device waited for the host before this tick's
+        launches, whichever tick the wait began in (0.0 where they
+        found the device busy, None where the tick launched nothing),
+        `starved_cause`, why the clock started (a barrier's cause,
+        `exposed`, `late_tick`; None where nothing starved) and
+        `starved_by`, the same seconds by the innermost span the host
+        was in (`other`: the tick's own time; `outside_tick`: between
+        two ticks). `gap_s`: this tick's start less the last tick's
+        end. `profiled`: a /debug/profile capture was running."""
         touched, rows_max, rows_mean, kv_live, kv_selected = \
             (tuple(expert_load or ()) + (None,) * 5)[:5]
         entry = {
@@ -124,6 +140,11 @@ class TickLog:
             "expert_rows_mean": rows_mean,
             "kv_rows_live": kv_live,
             "kv_rows_selected": kv_selected,
+            "starved_s": starved_s,
+            "starved_cause": starved_cause,
+            "starved_by": dict(starved_by or {}),
+            "gap_s": gap_s,
+            "profiled": profiled,
         }
         with self._lock:
             self._ring.append(entry)

@@ -50,7 +50,9 @@ engine/serving.py:
 from __future__ import annotations
 
 import contextlib
+import gc
 import itertools
+import statistics
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -63,7 +65,7 @@ from jax.profiler import TraceAnnotation
 
 from butterfly_tpu.cache.allocator import make_page_allocator
 from butterfly_tpu.engine.serving import (
-    ServingEngine, bucket_len, sample_batched)
+    LAUNCH_SPAN, ServingEngine, bucket_len, sample_batched)
 from butterfly_tpu.obs.registry import (
     BATCH_BUCKETS, LATENCY_BUCKETS, TOKEN_BUCKETS, MetricsRegistry)
 from butterfly_tpu.obs.ticklog import TICK_PHASES, TickLog
@@ -77,13 +79,20 @@ SPEC_ACCEPT_BUCKETS = (0.01, 0.125, 0.25, 0.375, 0.5,
 def _device_ready(x) -> bool:
     """Non-blocking completion probe for a device array (jax.Array
     .is_ready — true once the async dispatch has materialized it). On a
-    runtime without the probe, report not-ready: the device_bubble
-    metric then reads a constant 0 (silently disabled) instead of
-    claiming a bubble on every tick."""
+    runtime without the probe, report not-ready: the starvation clock
+    then starts at full barriers only (which need no probe) instead of
+    claiming a wait on every tick."""
     try:
         return bool(x.is_ready())
     except AttributeError:
         return False
+
+
+#: a block fetch is a STALL (flight recorder note `stall`) when it took
+#: more than STALL_FACTOR times the median of the last 64 block fetches
+#: and more than STALL_MIN_S seconds
+STALL_FACTOR = 10.0
+STALL_MIN_S = 0.25
 
 
 @dataclass
@@ -304,14 +313,27 @@ class Scheduler:
         self._epoch = 0
         self._operands_epoch = -1
         self._operands: Optional[tuple] = None
-        # device_bubble_seconds observation points, set at tick start:
-        # host-section start time and whether the device was ALREADY
-        # idle then (the newest in-flight block's carry ready before
-        # any host work ran — exactly the gap dispatch-ahead exists to
-        # close). _decode_block observes the gap at dispatch.
-        self._t_host0 = 0.0
-        self._idle_at_host0 = False
-        self._had_inflight_at_host0 = False
+        # The starvation clock: the device's wait for the host, on the
+        # host's clock. `_starved_by` is None while the device has work
+        # (or the server has none to give it); while the clock runs it
+        # is {innermost span name: seconds}, fed by _lap at every span
+        # boundary, and `_starved_cause` says why the clock started.
+        # _starve starts it, the launch span's end (_fed) stops it.
+        self._starved_by: Optional[Dict[str, float]] = None
+        self._starved_cause: Optional[str] = None
+        # the waits that this tick's launches ended: seconds (None
+        # until the tick launches), the first one's cause, by span
+        self._tick_starved: Optional[float] = None
+        self._tick_starved_cause: Optional[str] = None
+        self._tick_starved_by: Dict[str, float] = {}
+        # this tick's start less the last tick's end
+        self._tick_gap = 0.0
+        # a /debug/profile capture is running (ServerState._maybe_profile
+        # sets it): the tick record says so, because a capture
+        # multiplies the host's phases
+        self.profiled = False
+        # the last 64 block fetches, seconds: what a stall is told by
+        self._fetches: Deque[float] = deque(maxlen=64)
         # First tokens sampled on-device at admission, not yet fetched:
         # [(req, generation=req.preemptions, slot, device scalar)].
         # Fetched with the next drain, all in one jax.device_get (a
@@ -450,10 +472,13 @@ class Scheduler:
             "undrained while newer blocks ran)", LATENCY_BUCKETS)
         self._h_bubble = reg.histogram(
             "device_bubble_seconds",
-            "Device idle gap per dispatched decode block: 0 when the "
-            "newest in-flight block was still running as the tick's "
-            "host section began; otherwise the (lower-bound) time the "
-            "idle device waited for the next dispatch",
+            "The starvation clock at each program launch: 0 when the "
+            "launch found the device busy; otherwise the seconds from "
+            "the moment the host learned that nothing it had launched "
+            "still ran (a barrier's or an exposed lazy drain's fetch "
+            "returned, or a tick began with the newest block done) to "
+            "the launch's return. A lower bound: idle time before the "
+            "host looked is not counted",
             LATENCY_BUCKETS)
         self._g_inflight = reg.gauge(
             "inflight_depth",
@@ -598,10 +623,6 @@ class Scheduler:
         # effective per-token rate a streaming client experiences.
         self._itls: Deque[float] = deque(maxlen=4096)
         self._itl_means: Deque[float] = deque(maxlen=4096)
-        # per-dispatch device-bubble samples (seconds; 0 = the pipeline
-        # kept the device busy through the host section) for the
-        # metrics() percentile keys
-        self._bubbles: Deque[float] = deque(maxlen=4096)
         # -- tick anatomy (ISSUE 15) -----------------------------------------
         # Per-tick phase attribution: tick() zeroes the accumulator,
         # the structural sections run inside _span(), which adds their
@@ -616,6 +637,12 @@ class Scheduler:
         # the last boundary, from which the innermost span is owed
         self._span_stack: List[str] = ["other"]
         self._span_t = time.monotonic()
+        # the innermost open span by its own name (sub-spans too):
+        # "other" inside a tick, "outside_tick" between two
+        self._span_name = "outside_tick"
+        # the engine's put and launch spans run through _span, so their
+        # time lands in the tick record and the launch's end is known
+        engine.span = self._span
         self._tick_causes: List[str] = []
         # the tick's lazy drain, if it had one with a newer block in
         # flight: was that block still running when the fetch returned
@@ -674,6 +701,18 @@ class Scheduler:
                 LATENCY_BUCKETS)
             for p in TICK_PHASES}
 
+    def _lap(self, now: float) -> None:
+        """A span boundary: the time since the last one is owed to the
+        innermost open span's phase and, while the starvation clock
+        runs, to the innermost span's own name in the clock's table.
+        Plain dict arithmetic — never a sync."""
+        d = now - self._span_t
+        self._span_t = now
+        self._tick_phases[self._span_stack[-1]] += d
+        by = self._starved_by
+        if by is not None:
+            by[self._span_name] = by.get(self._span_name, 0.0) + d
+
     @contextlib.contextmanager
     def _span(self, name: str, **attrs):
         """One section of the tick on both clocks: a TraceAnnotation
@@ -683,21 +722,67 @@ class Scheduler:
         Entering pauses the enclosing span's timer and leaving resumes
         it, so phases never overlap and sum to the tick's wall time. A
         TICK_PHASES name is charged to itself, any other name (a
-        sub-span such as `drain.fetch`) to the phase around it. Yields
-        the annotation (set_metadata adds what is known only at the
+        sub-span such as `drain.fetch`) to the phase around it; while
+        the starvation clock runs the same time also goes to the
+        clock's table under the span's OWN name (_lap). The end of the
+        engine's launch span stops the clock (_fed). Yields the
+        annotation (set_metadata adds what is known only at the
         end). Plain dict arithmetic — never a sync."""
-        stack, tp = self._span_stack, self._tick_phases
-        now = time.monotonic()
-        tp[stack[-1]] += now - self._span_t
-        self._span_t = now
-        stack.append(name if name in tp else stack[-1])
-        try:
-            with TraceAnnotation("bf.tick." + name, **attrs) as ann:
+        stack = self._span_stack
+        self._lap(time.monotonic())
+        stack.append(name if name in self._tick_phases else stack[-1])
+        outer, self._span_name = self._span_name, name
+        with TraceAnnotation("bf.tick." + name, **attrs) as ann:
+            try:
                 yield ann
-        finally:
-            now = time.monotonic()
-            tp[stack.pop()] += now - self._span_t
-            self._span_t = now
+            finally:
+                now = time.monotonic()
+                self._lap(now)
+                stack.pop()
+                self._span_name = outer
+                if name == LAUNCH_SPAN:
+                    self._fed(ann)
+
+    def _starve(self, cause: str) -> None:
+        """Start the starvation clock: the host has just learned that
+        nothing it launched is still running, so from here to the next
+        launch's return the device waits for the host. Three callers:
+        a full barrier's fetch returned (`cause` is the barrier's), a
+        lazy drain's fetch returned with the newest block in flight
+        already done ("exposed"), a tick began with the newest block
+        done ("late_tick"). The flush that a drain dispatches before
+        its fetch runs right behind the fetched blocks and is under a
+        millisecond (PERF.md section 5): it is not waited for, and
+        counts as the device waiting. Where the device ran dry BEFORE
+        the host looked (a probe that found the block done, a fetch
+        that did not block), the time before the look is not counted:
+        the clock is a lower bound, exact where the fetch blocked. A
+        clock that already runs keeps its start and its cause."""
+        if self._starved_by is None:
+            self._lap(time.monotonic())
+            self._starved_by = {}
+            self._starved_cause = cause
+
+    def _fed(self, ann) -> None:
+        """A program launch has returned (the end of the engine's
+        launch span): the device has work again. Stop the starvation
+        clock if it runs and charge the wait to the tick under way,
+        whichever tick it began in; a launch onto a busy device counts
+        0.0. Feeds device_bubble_seconds, and the launch span carries
+        `starved_ms`, so a trace shows the wait at the launch that
+        ended it."""
+        by, s = self._starved_by, 0.0
+        if by is not None:
+            self._starved_by = None
+            s = sum(by.values())
+            if self._tick_starved_cause is None:
+                self._tick_starved_cause = self._starved_cause
+            mine = self._tick_starved_by
+            for k, v in by.items():
+                mine[k] = mine.get(k, 0.0) + v
+        self._tick_starved = (self._tick_starved or 0.0) + s
+        self._h_bubble.observe(s)
+        ann.set_metadata(starved_ms=1e3 * s)
 
     # -- public API ---------------------------------------------------------
 
@@ -862,6 +947,7 @@ class Scheduler:
         self._pending_first_keys.clear()
         self._flush_counts = []  # device scalars: dropped unread
         self._spec_rem = None
+        self._starved_by = None  # nothing left to serve: not starved
         # staged-but-unflushed window K/V is DROPPED, not flushed (no
         # device calls here): every owning request is being cancelled,
         # and dropping resets the staged count so a later flush can
@@ -945,10 +1031,17 @@ class Scheduler:
         before = self._c_tokens.value
         # tick-anatomy reset: zero the phase accumulator (the spans
         # below add their exclusive monotonic deltas), clear the
-        # barrier-cause list, zero the fetch wait
-        t_tick0 = self._span_t = time.monotonic()
+        # barrier-cause list, zero the fetch wait. The time since the
+        # last tick's end (lock, wake, profile poll) is this tick's
+        # gap, and the starvation clock's `outside_tick` if it runs.
+        t_tick0 = time.monotonic()
+        self._tick_gap = t_tick0 - self._span_t
+        self._lap(t_tick0)
+        self._span_name = "other"
         for p in TICK_PHASES:
             self._tick_phases[p] = 0.0
+        self._tick_starved = self._tick_starved_cause = None
+        self._tick_starved_by = {}
         self._tick_causes = []
         self._tick_fetch = 0.0
         self._tick_overlapped = None
@@ -968,15 +1061,15 @@ class Scheduler:
         spec = self._spec_mode
         k = max(1, rt.decode_steps_per_tick)
         depth = max(1, rt.inflight_blocks)
+        # the newest block in flight is done before the tick has done
+        # anything: the device ran dry while the host was elsewhere
+        if self._inflight and _device_ready(self._inflight[-1][1]):
+            self._starve("late_tick")
         # deadline scrub first: an expired request must not survive
         # into this tick's admission or decode dispatch (a drain it
         # forces accrues to drain_barrier, not to expire)
         with self._span("expire"):
             self._expire_due()
-        self._t_host0 = time.monotonic()
-        self._had_inflight_at_host0 = bool(self._inflight)
-        self._idle_at_host0 = self._had_inflight_at_host0 and \
-            _device_ready(self._inflight[-1][1])
         # lazy drain: consume the oldest block once the queue is full
         # (depth=1 degenerates to the old drain-every-tick loop). A
         # finish surfacing there is a membership change -> full barrier.
@@ -1077,8 +1170,11 @@ class Scheduler:
         here."""
         tp = self._tick_phases
         now = time.monotonic()
-        tp["other"] += now - self._span_t
-        self._span_t = now
+        self._lap(now)
+        self._span_name = "outside_tick"
+        if not self.has_work:
+            # an empty server is not starved
+            self._starved_by = None
         wall = now - t_tick0
         blocks = self.engine.blocks_launched
         for name, h in self._h_phase.items():
@@ -1102,7 +1198,11 @@ class Scheduler:
                             rows=self.engine.last_rows
                             if blocks > blocks0 else None,
                             block=blocks, lock_s=self.loop_lock_s,
-                            compiles=int(self._c_compiles.value))
+                            compiles=int(self._c_compiles.value),
+                            starved_s=self._tick_starved,
+                            starved_cause=self._tick_starved_cause,
+                            starved_by=self._tick_starved_by,
+                            gap_s=self._tick_gap, profiled=self.profiled)
         self.loop_lock_s = 0.0
         if self.flightrec is not None:
             self.flightrec.poll({
@@ -1247,13 +1347,6 @@ class Scheduler:
             m["slo_violations_total"] = viol
             m["slo_burn_rate"] = self._g_slo_burn.value
             m["slo_attainment"] = ok / (ok + viol) if ok + viol else 1.0
-        if self._bubbles:
-            # device idle per dispatched block (0 = pipeline kept the
-            # device busy through the tick's host section): the number
-            # dispatch-ahead exists to drive to ~0
-            a = np.asarray(self._bubbles)
-            m["device_bubble_p50"] = float(np.percentile(a, 50))
-            m["device_bubble_p95"] = float(np.percentile(a, 95))
         if self._kv_flushes:
             # write-combined KV window flush (kv_write_combine): host
             # wall per drain-time flush dispatch + tokens landed per
@@ -1518,7 +1611,8 @@ class Scheduler:
             self.slots[slot] = req
             self._prefill_group.append(req)
             self.engine.set_table_row(slot, self.alloc.pages_of(slot))
-            self._seed_mixed_slot(req)
+            with self._span("admit.seed"):
+                self._seed_mixed_slot(req)
             admitted = True
             wait = time.monotonic() - req.t_enqueued
             self._h_queue_wait.observe(wait)
@@ -1893,20 +1987,6 @@ class Scheduler:
         estimate)."""
         self._inflight.append((kind, carry, outs, k, snapshot,
                                time.monotonic(), *mixed))
-        self._note_bubble()
-
-    def _note_bubble(self) -> None:
-        if self._idle_at_host0:
-            # the newest in-flight carry was already materialized when
-            # this tick's host section began: the device sat idle
-            # through all of it — the bubble dispatch-ahead closes
-            bubble = time.monotonic() - self._t_host0
-            self._h_bubble.observe(bubble)
-            self._bubbles.append(bubble)
-        elif self._had_inflight_at_host0:
-            self._h_bubble.observe(0.0)
-            self._bubbles.append(0.0)
-        self._idle_at_host0 = self._had_inflight_at_host0 = False
 
     def _spec_block(self, rounds: int) -> bool:
         """Dispatch ONE fused speculative block (engine.spec_block_async)
@@ -2081,11 +2161,14 @@ class Scheduler:
                                         inflight=len(self._inflight))
             blocks, self._inflight = self._inflight, []
             self._spec_rem = None
-            finished = self._drain_blocks(blocks)
+            finished = self._drain_blocks(blocks, cause)
             # it waits for everything because it drains everything:
-            # the last flush runs behind the emission above
+            # the last flush runs behind the emission above. A wait on
+            # the device (fetch_s counts it) for the value of a
+            # counter, under a span of its own: the blocks are fetched
+            # already, so the device idles through it
             if self._flush_counts:
-                with self._span("drain.fetch", blocks=0):
+                with self._span("drain.flush_count"):
                     t_fetch = time.monotonic()
                     self._count_flushed(wait=True)
                     self._tick_fetch += time.monotonic() - t_fetch
@@ -2114,9 +2197,11 @@ class Scheduler:
         while pend and (wait or _device_ready(pend[0])):
             self._c_kv_flushed.inc(int(pend.pop(0)))
 
-    def _drain_blocks(self, blocks: List[tuple]) -> bool:
+    def _drain_blocks(self, blocks: List[tuple],
+                      cause: Optional[str] = None) -> bool:
         """Fetch + emit the given blocks and do their host bookkeeping
-        in chronological order. Pending first tokens always ride along:
+        in chronological order (`cause`: the full barrier's, None for
+        a lazy drain). Pending first tokens always ride along:
         they are queued at an admission barrier, when nothing is in
         flight, so they predate every dispatched block; each block's
         [k, S] rows are then emitted in step order per live slot,
@@ -2165,20 +2250,51 @@ class Scheduler:
             t_fetch = time.monotonic()
             first_vals, block_vals = jax.device_get(
                 ([f[3] for f in firsts], [ent[2] for ent in blocks]))
-            self._tick_fetch += time.monotonic() - t_fetch
+            fetch_s = time.monotonic() - t_fetch
+            self._tick_fetch += fetch_s
+        newest_ready = None
         if self._inflight:
             # a lazy drain with a newer block in flight: if its carry
             # is not ready the device has work queued while the host
             # goes on (overlapped); if it is, the fetch outlasted it
-            self._tick_overlapped = not _device_ready(self._inflight[-1][1])
-            self._c_overlap.labels("overlapped" if self._tick_overlapped
-                                   else "exposed").inc()
+            # and the device waits from here
+            newest_ready = _device_ready(self._inflight[-1][1])
+            self._tick_overlapped = not newest_ready
+            self._c_overlap.labels("exposed" if newest_ready
+                                   else "overlapped").inc()
+            if newest_ready:
+                self._starve("exposed")
+        else:
+            # everything in flight has been fetched (a full barrier, or
+            # a lazy drain of the only block): the device waits from here
+            self._starve(cause or "exposed")
+        if blocks:
+            self._note_fetch(fetch_s, newest_ready)
         tokens0 = self._c_tokens.value
         with self._span("drain.emit") as ann:
             self._emit_drained(firsts, first_vals, blocks, block_vals)
             ann.set_metadata(tokens=int(self._c_tokens.value - tokens0))
         self._epoch += 1  # outputs / pending-first changed
         return self._c_finished.value > finished_before
+
+    def _note_fetch(self, fetch_s: float,
+                    newest_ready: Optional[bool]) -> None:
+        """Keep the last 64 block fetches, and leave a `stall` note in
+        the flight recorder where this one took more than STALL_FACTOR
+        times their median and more than STALL_MIN_S: one fetch in a
+        few thousand takes 0.3-8 s on the chip (ROADMAP.md A7), and
+        until now only a run's throughput reading low told of it.
+        `tick` is the seq of the tick under way (the note's own `seq`
+        is the recorder's), `newest_ready` whether the newest block in
+        flight had ended when the fetch returned (None: none was)."""
+        past = self._fetches
+        if (fetch_s > STALL_MIN_S and past and self.flightrec is not None
+                and fetch_s > STALL_FACTOR * statistics.median(past)):
+            self.flightrec.note(
+                "stall", tick=self.ticklog.next_seq, fetch_s=fetch_s,
+                newest_ready=newest_ready, gc=list(gc.get_count()),
+                profiled=self.profiled)
+        past.append(fetch_s)
 
     def _emit_drained(self, firsts: List[tuple], first_vals: List,
                       blocks: List[tuple], block_vals: List) -> None:

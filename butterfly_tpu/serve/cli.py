@@ -243,9 +243,13 @@ def build_parser() -> argparse.ArgumentParser:
                         "t's device-resident carry before t is "
                         "drained, so host scheduling overlaps device "
                         "compute. 1 = the synchronous drain-every-tick "
-                        "loop; the device_bubble_seconds histogram "
-                        "shows whether the depth is enough to keep the "
-                        "device busy through a tick's host section")
+                        "loop; the starvation clock (starved_s of "
+                        "GET /debug/ticks, the device_bubble_seconds "
+                        "histogram) shows how long the device waited "
+                        "for the host before each launch, and "
+                        "starved_cause whether a deeper queue would "
+                        "have helped (exposed, late_tick) or a full "
+                        "barrier drained it whatever the depth")
     s.add_argument("--timeseries-interval", type=float, default=1.0,
                    metavar="SECONDS",
                    help="periodic signal-history sampling interval for "

@@ -390,9 +390,11 @@ class ServerState:
                 self._profile_done.set()
                 return
             self._profile_active = (t0 + dur_s, logdir, t0)
+            self.sched.profiled = True  # the tick records say so
         act = self._profile_active
         if act is not None and time.monotonic() >= act[0]:
             self._profile_active = None
+            self.sched.profiled = False
             self._profile_stopping.set()
             threading.Thread(target=self._profile_export, daemon=True,
                              args=(act[1], act[2])).start()

@@ -526,6 +526,81 @@ def test_ticklog_phase_percentiles_and_combined_drain():
     assert TickLog().phase_percentiles() == {}
 
 
+def test_ticklog_record_carries_the_starvation_clock():
+    """The record's fields of the starvation clock and their nulls: a
+    tick that launched nothing has no `starved_s`, one that launched onto
+    a busy device has 0.0 and no cause, and the table is a copy."""
+    from butterfly_tpu.obs.ticklog import TickLog
+    log = TickLog()
+    log.record(0.01, {"mixed": 0.01})                       # launched nothing
+    by = {"drain.flush_count": 0.002, "admit.seed": 0.001}
+    log.record(0.02, {"mixed": 0.02}, program="bf_mixed_block_win",
+               starved_s=0.003, starved_cause="finish", starved_by=by,
+               gap_s=0.0004, profiled=True)
+    log.record(0.02, {"mixed": 0.02}, program="bf_decode_block_win",
+               starved_s=0.0, starved_by={})                # a busy device
+    by["admit.seed"] = 9.0
+    idle, starved, busy = log.dump()["ticks"]
+    assert idle["starved_s"] is None and idle["starved_cause"] is None
+    assert idle["starved_by"] == {} and idle["gap_s"] == 0.0
+    assert idle["profiled"] is False
+    assert starved["starved_s"] == 0.003 and starved["starved_cause"] == "finish"
+    assert starved["starved_by"] == {"drain.flush_count": 0.002,
+                                     "admit.seed": 0.001}
+    assert starved["gap_s"] == 0.0004 and starved["profiled"] is True
+    assert busy["starved_s"] == 0.0 and busy["starved_cause"] is None
+    assert busy["starved_by"] == {}
+    json.dumps(log.dump())
+
+
+def test_trace_report_prints_the_starved_seconds(tmp_path):
+    """`trace_report.py --ticks` reads a /debug/ticks dump without the
+    benchmark: the starved seconds as a share of what the ticks span, by
+    cause and by span, most first; nothing for an older program's dump."""
+    import importlib.util
+    from butterfly_tpu.obs.ticklog import TickLog
+    spec = importlib.util.spec_from_file_location(
+        "trace_report", REPO / "tools" / "trace_report.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    log = TickLog()
+    log.record(0.10, {}, generated=4, starved_s=0.0, starved_by={},
+               gap_s=0.01)
+    log.record(0.10, {}, generated=4, starved_s=0.030, starved_cause="finish",
+               starved_by={"drain.flush_count": 0.020, "admit.seed": 0.010},
+               gap_s=0.01, profiled=True)
+    log.record(0.10, {}, generated=4, starved_s=0.010, starved_cause="exposed",
+               starved_by={"drain.emit": 0.004, "admit.seed": 0.006},
+               gap_s=0.07)
+    log.record(0.10, {}, generated=0, gap_s=0.01)            # launched nothing
+    lines = mod.starved_lines(log.dump()["ticks"])
+    assert lines == [
+        "device starved 40.0ms of 500.0ms (8.0%) in 2 of 4 tick(s), "
+        "1 under a capture",
+        "  by cause: finish 30.0ms  exposed 10.0ms",
+        "  by span: drain.flush_count 20.0ms  admit.seed 16.0ms  "
+        "drain.emit 4.0ms"]
+    dump = _synthetic_dump(tmp_path / "trace.json")
+    text = mod.render_summary(mod.load_dump(str(dump)), log.dump())
+    assert "4 tick(s), 12 token(s) generated" in text
+    assert text.endswith("\n".join(lines))
+    # a dump of a program older than the clock: the counts and no more
+    old = {"ticks": [{k: v for k, v in t.items()
+                      if not k.startswith("starved") and k != "gap_s"}
+                     for t in log.dump()["ticks"]]}
+    assert mod.starved_lines(old["ticks"]) == []
+    assert mod.render_summary(mod.load_dump(str(dump)), old).endswith(
+        "4 tick(s), 12 token(s) generated")
+    # and the CLI prints it
+    ticks = tmp_path / "ticks.json"
+    ticks.write_text(json.dumps(log.dump()))
+    out = subprocess.run(
+        [sys.executable, str(REPO / "tools" / "trace_report.py"), str(dump),
+         "--ticks", str(ticks)], capture_output=True, text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+    assert "by cause: finish 30.0ms" in out.stdout
+
+
 # -- anomaly flight recorder (ISSUE 15) -------------------------------------
 
 def _validate_artifact(art):

@@ -810,16 +810,22 @@ def test_pipelined_parity_under_page_pressure():
 
 
 def test_pipelined_metrics_surface():
-    """The dispatch-ahead observability contract: inflight_depth gauge
-    and device_bubble_seconds histogram/percentiles populate once
-    blocks pipeline."""
+    """The dispatch-ahead observability contract: the inflight_depth
+    gauge populates once blocks pipeline, and the starvation clock feeds
+    the device_bubble_seconds histogram once a launch, what the tick
+    records carry as `starved_s`."""
     sched, _ = make_sched(inflight_blocks=2)
     sched.submit([5, 7, 11], max_new_tokens=8)
     sched.run_until_done()
     m = sched.metrics()
     assert "inflight_depth" in m
-    assert m.get("device_bubble_p50", 0.0) >= 0.0
-    assert sched.registry.get("device_bubble_seconds").count >= 1
+    assert "device_bubble_p50" not in m and "device_bubble_p95" not in m
+    ticks = sched.ticklog.dump()["ticks"]
+    launched = [t for t in ticks if t["program"] is not None]
+    assert all(t["starved_s"] >= 0.0 for t in launched)
+    h = sched.registry.get("device_bubble_seconds")
+    assert h.count >= len(launched) >= 1
+    assert h.sum == pytest.approx(sum(t["starved_s"] for t in launched))
     assert sched.registry.get("inflight_depth") is not None
 
 
@@ -1826,3 +1832,309 @@ def test_drain_overlap_counter_and_tick_record():
     # a full barrier drains everything: no newer block, nothing counted
     sched._drain_inflight("flush")
     assert fam.labels("overlapped").value + fam.labels("exposed").value == 5
+
+
+# ---------------------------------------------------------------------------
+# the starvation clock (ISSUE 38): the device's wait for the host, on the
+# host's clock, with `_device_ready` and the clock in the test's hands
+# ---------------------------------------------------------------------------
+
+class _Clock:
+    """`time` as the scheduler sees it: every read of monotonic() moves
+    1 ms on, `last` is what the newest read returned."""
+
+    def __init__(self):
+        self.now = self.last = 1000.0
+
+    def monotonic(self):
+        self.last = self.now
+        self.now += 0.001
+        return self.last
+
+    def time(self):
+        return 0.0
+
+
+def _clocked(monkeypatch, ready, fetch_s=0.5, **rt_kw):
+    """A scheduler on a fake clock, two slots and three requests (the
+    third waits for a slot); every device fetch takes `fetch_s` of that
+    clock, and `ready(x)` answers each `_device_ready`. Returns (sched,
+    clock, log); log["fetched"], ["starved"] and ["fed"] hold the clock's
+    newest reading as each fetch returned, each clock start ended and
+    each launch was told of."""
+    import butterfly_tpu.sched.scheduler as S
+    clock, log = _Clock(), {"fetched": [], "starved": [], "fed": []}
+    monkeypatch.setattr(S, "time", clock)
+    monkeypatch.setattr(S, "_device_ready", ready)
+    sched, _ = make_sched(decode_steps_per_tick=2, **rt_kw)
+    real_get = jax.device_get
+
+    def device_get(x):
+        out = real_get(x)
+        clock.now += fetch_s
+        log["fetched"].append(clock.now)
+        return out
+    monkeypatch.setattr(S.jax, "device_get", device_get)
+    starve, fed = sched._starve, sched._fed
+
+    def _starve(cause):
+        running = sched._starved_by is not None
+        starve(cause)
+        if not running:
+            log["starved"].append(clock.last)
+
+    def _fed(ann):
+        log["fed"].append(clock.last)
+        fed(ann)
+    monkeypatch.setattr(sched, "_starve", _starve)
+    monkeypatch.setattr(sched, "_fed", _fed)
+    for p, n in (([5, 7, 11], 6), ([3, 1, 4], 30), ([2, 7], 8)):
+        sched.submit(p, max_new_tokens=n)
+    return sched, clock, log
+
+
+def _last_tick(sched):
+    return sched.ticklog.dump()["ticks"][-1]
+
+
+def test_starvation_clock_at_a_finish_barrier(monkeypatch):
+    """A finish barrier: the clock runs from the return of the barrier's
+    block fetch to the return of the next launch, the tick record says
+    `finish`, and `starved_by` sums to `starved_s` and names the spans
+    the host was in, the wait for the flush count and the admission's
+    device edits among them. No block is ever ready, so every lazy drain
+    is overlapped and every other launch counts 0.0."""
+    sched, clock, log = _clocked(monkeypatch, lambda x: False)
+    for _ in range(60):
+        sched.tick()
+        if _last_tick(sched)["barrier_causes"] == ["finish"]:
+            break
+    rec = _last_tick(sched)
+    assert rec["barrier_causes"] == ["finish"] and rec["program"]
+    start, stop = log["starved"][-1], log["fed"][-1]
+    # the clock started two reads after the barrier's fetch returned (the
+    # fetch's own timer, then the start) and stopped at the launch's end
+    assert start == pytest.approx(log["fetched"][-1] + 0.001)
+    assert rec["starved_s"] == pytest.approx(stop - start, abs=1e-9)
+    assert rec["starved_s"] > 0.01 and rec["starved_cause"] == "finish"
+    by = rec["starved_by"]
+    assert sum(by.values()) == pytest.approx(rec["starved_s"], abs=1e-6)
+    assert {"drain.flush_count", "drain.emit", "admit", "admit.seed",
+            "dispatch.put", "dispatch.launch"} <= set(by)
+    assert "outside_tick" not in by and "drain.fetch" not in by
+    assert all(v > 0.0 for v in by.values())
+    # the wait for the count is a wait on the device: fetch_s holds it
+    assert rec["fetch_s"] >= 0.5 + by["drain.flush_count"]
+    # the ticks before it: overlapped lazy drains, launches onto a busy device
+    earlier = sched.ticklog.dump()["ticks"][:-1]
+    assert any(t["overlapped"] for t in earlier)
+    for t in earlier:
+        assert t["starved_s"] == 0.0 and t["starved_cause"] is None
+        assert t["starved_by"] == {} and t["gap_s"] >= 0.0
+    h = sched.registry.get("device_bubble_seconds")
+    assert h.count == len(log["fed"]) and h.sum == pytest.approx(
+        rec["starved_s"])
+    assert sched._starved_by is None
+
+
+def test_starvation_clock_runs_across_ticks(monkeypatch):
+    """A barrier whose tick launches nothing: the wait passes through the
+    loop and is charged, with its cause, to the NEXT tick that launches,
+    the time between the two under `outside_tick`."""
+    sched, clock, log = _clocked(monkeypatch, lambda x: False)
+    sched.tick()
+    sched.tick()
+    assert sched._inflight
+    sched._drain_inflight("finish")    # as a tick that ends on its barrier
+    assert sched._starved_by is not None and not sched._inflight
+    clock.now += 0.25                  # the lock, the wake
+    sched.tick()
+    rec = _last_tick(sched)
+    start, stop = log["starved"][-1], log["fed"][-1]
+    assert start == pytest.approx(log["fetched"][-1] + 0.001)
+    assert rec["starved_s"] == pytest.approx(stop - start, abs=1e-9)
+    assert rec["starved_cause"] == "finish" and rec["barrier_causes"] == []
+    assert rec["starved_by"]["outside_tick"] >= 0.25
+    assert rec["gap_s"] >= 0.25
+    assert sum(rec["starved_by"].values()) == pytest.approx(
+        rec["starved_s"], abs=1e-6)
+
+
+def test_starvation_clock_exposed_and_late(monkeypatch):
+    """A lazy drain whose fetch outlasts the newest block starts the
+    clock at the fetch's return (`exposed`); a tick that begins with the
+    newest block done starts it there (`late_tick`), and a later start
+    in the same wait changes neither the start nor the cause."""
+    state = {"ready": False}
+    sched, clock, log = _clocked(monkeypatch, lambda x: state["ready"])
+    for _ in range(40):
+        if len(sched._inflight) >= 2:
+            break
+        sched.tick()
+    assert len(sched._inflight) == 2
+
+    import butterfly_tpu.sched.scheduler as S
+    get = S.jax.device_get
+
+    def outlasted(x):
+        out = get(x)
+        state["ready"] = True          # the newer block ended meanwhile
+        return out
+    monkeypatch.setattr(S.jax, "device_get", outlasted)
+    sched.tick()
+    rec = _last_tick(sched)
+    assert rec["overlapped"] is False and rec["starved_cause"] == "exposed"
+    assert log["starved"][-1] == pytest.approx(log["fetched"][-1] + 0.001)
+    assert rec["starved_s"] == pytest.approx(
+        log["fed"][-1] - log["starved"][-1], abs=1e-9)
+    assert "drain.fetch" not in rec["starved_by"]
+    assert "drain.emit" in rec["starved_by"]
+    # the next tick begins with the newest block done
+    n = len(log["starved"])
+    sched.tick()
+    rec = _last_tick(sched)
+    assert rec["starved_cause"] == "late_tick"
+    assert len(log["starved"]) == n + 1        # one start, at the tick's top
+    assert "expire" in rec["starved_by"] and "drain.fetch" in rec["starved_by"]
+    assert rec["starved_s"] >= 0.5             # the fetch is inside this wait
+    assert sum(rec["starved_by"].values()) == pytest.approx(
+        rec["starved_s"], abs=1e-6)
+
+
+def test_starvation_clock_stops_for_an_empty_server(monkeypatch):
+    """An empty server is not starved: the last barrier starts the clock,
+    the tick that leaves nothing to serve clears it, and the request that
+    comes an hour later launches onto a device that waited for no one."""
+    sched, clock, log = _clocked(monkeypatch, lambda x: False)
+    sched.run_until_done()
+    rec = _last_tick(sched)
+    assert rec["starved_s"] is None and rec["starved_cause"] is None
+    assert rec["starved_by"] == {} and rec["program"] is None
+    assert not sched.has_work and sched._starved_by is None
+    clock.now += 3600.0
+    sched.submit([9, 9], max_new_tokens=20)
+    sched.tick()
+    rec = _last_tick(sched)
+    assert rec["program"] and rec["starved_s"] == 0.0
+    assert rec["gap_s"] >= 3600.0 and rec["starved_by"] == {}
+    # abort_all: nothing left to serve, whatever ran
+    sched.tick()
+    sched.tick()
+    sched._drain_inflight("cancel")
+    assert sched._starved_by is not None
+    sched.abort_all()
+    assert sched._starved_by is None
+
+
+def test_launch_span_carries_the_wait_and_the_engine_its_span():
+    """The scheduler hands the engine its `_span`, so `dispatch.put` and
+    `dispatch.launch` are timed like every other section; an engine that
+    no scheduler drives writes plain annotations."""
+    from butterfly_tpu.engine.serving import LAUNCH_SPAN, _trace_span
+    model = Model(CFG)
+    eng = ServingEngine(model, model.init(jax.random.PRNGKey(0)),
+                        RuntimeConfig(max_batch_size=2, max_seq_len=64,
+                                      page_size=8))
+    assert eng.span is _trace_span and LAUNCH_SPAN == "dispatch.launch"
+    with eng.span("dispatch.put"):
+        pass
+    sched = Scheduler(eng)
+    assert eng.span == sched._span
+    seen = []
+
+    class Ann:
+        def set_metadata(self, **kw):
+            seen.append(kw)
+    sched._starved_by = {"admit": 0.002, "other": 0.001}
+    sched._starved_cause = "finish"
+    sched._fed(Ann())
+    assert seen == [{"starved_ms": pytest.approx(3.0)}]
+    assert sched._tick_starved == pytest.approx(0.003)
+    sched._fed(Ann())                       # a second launch: a busy device
+    assert seen[-1] == {"starved_ms": 0.0}
+    assert sched._tick_starved == pytest.approx(0.003)
+    assert sched._tick_starved_cause == "finish"
+
+
+def test_profiled_follows_the_capture(monkeypatch, tmp_path):
+    """`ServerState._maybe_profile` sets the scheduler's `profiled` when
+    it starts a capture and clears it when it ends one; the tick records
+    in between say so."""
+    import threading
+    from butterfly_tpu.serve.server import ServerState
+    from butterfly_tpu.utils.tokenizer import ByteTokenizer
+    sched, _ = make_sched()
+    state = ServerState(sched, ByteTokenizer())
+    stopped = threading.Event()
+    monkeypatch.setattr(ServerState, "_profiler_start",
+                        staticmethod(lambda logdir: None))
+    monkeypatch.setattr(ServerState, "_profiler_stop",
+                        staticmethod(stopped.set))
+    sched.submit([5, 7, 11], max_new_tokens=12)
+    sched.tick()
+    assert _last_tick(sched)["profiled"] is False
+    state._profile_pending = (3600.0, str(tmp_path))
+    state._maybe_profile()
+    assert sched.profiled is True
+    sched.tick()
+    assert _last_tick(sched)["profiled"] is True
+    state._profile_active = (0.0,) + state._profile_active[1:]   # it is over
+    state._maybe_profile()
+    assert sched.profiled is False and stopped.wait(timeout=30)
+    sched.tick()
+    assert _last_tick(sched)["profiled"] is False
+    # a capture that cannot start changes nothing
+
+    def boom(logdir):
+        raise ImportError("no xprof in this build")
+    monkeypatch.setattr(ServerState, "_profiler_start", staticmethod(boom))
+    state._profile_pending = (1.0, str(tmp_path))
+    state._maybe_profile()
+    assert sched.profiled is False
+
+
+def test_a_stalled_fetch_leaves_a_note(monkeypatch):
+    """A block fetch of more than ten times the median of the last 64 and
+    more than 0.25 s leaves a `stall` note in the flight recorder; a slow
+    fetch among slow ones, or a short one, leaves none."""
+    from butterfly_tpu.obs.ticklog import FlightRecorder
+    import butterfly_tpu.sched.scheduler as S
+    assert (S.STALL_FACTOR, S.STALL_MIN_S) == (10.0, 0.25)
+    sched, _ = make_sched()
+    sched.flightrec = FlightRecorder()
+    sched.profiled = True
+
+    def stalls():
+        return [e for e in sched.flightrec.dump()["events"]
+                if e["kind"] == "stall"]
+    sched._note_fetch(3.0, None)             # nothing to compare with yet
+    sched._fetches.clear()
+    for _ in range(64):
+        sched._note_fetch(0.05, False)
+    sched._note_fetch(0.2, False)            # four times the median, but short
+    sched._note_fetch(0.49, False)           # long, but under ten times
+    assert stalls() == []
+    sched._note_fetch(2.1, True)
+    (note,) = stalls()
+    assert note["fetch_s"] == 2.1 and note["newest_ready"] is True
+    assert note["tick"] == sched.ticklog.next_seq and note["profiled"] is True
+    assert len(note["gc"]) == 3 and all(isinstance(n, int) for n in note["gc"])
+    assert len(sched._fetches) == 64         # the stall is among them now
+    # through the drain itself: the fetch of a block, on a fake clock
+    sched2, clock, log = _clocked(monkeypatch, lambda x: False, fetch_s=0.01)
+    sched2.flightrec = FlightRecorder()
+    for _ in range(6):
+        sched2.tick()
+    assert len(sched2._fetches) >= 3
+    clock_get = S.jax.device_get
+
+    def slow(x):
+        clock.now += 5.0
+        return clock_get(x)
+    monkeypatch.setattr(S.jax, "device_get", slow)
+    sched2.tick()
+    (note,) = [e for e in sched2.flightrec.dump()["events"]
+               if e["kind"] == "stall"]
+    assert note["fetch_s"] == pytest.approx(5.011, abs=1e-6)
+    assert note["newest_ready"] is False and note["profiled"] is False
+    assert note["tick"] == _last_tick(sched2)["seq"]

@@ -783,6 +783,24 @@ def test_capture_holds_the_ticks_spans_and_exports_off_the_loop(tmp_path):
     assert {st["program"] for st in launches} <= {
         "bf_mixed_block_win", "bf_decode_block_win"}
     assert {st["block"] for st in launches} & {t["block"] for t in ticks}
+    # the launch that ended a wait of the device says how long it was,
+    # and the tick record of the same block holds the same number
+    assert all(st["starved_ms"] >= 0.0 for st in launches)
+    by_block = {t["block"]: t for t in ticks if t["program"]}
+    joined = [(st, by_block[st["block"]]) for st in launches
+              if st["block"] in by_block]
+    assert joined
+    for st, t in joined:
+        assert st["starved_ms"] == pytest.approx(1e3 * t["starved_s"],
+                                                 abs=1e-3)
+    assert "bf.tick.admit.seed" in names
+    # the ticks of the capture say so, the ones before and after do not
+    # (the capture is stopped on a thread of its own: the first ticks
+    # after the flag fell may still be in it)
+    assert {t["profiled"] for t in ticks} == {True, False}
+    flags = [by_seq[s]["profiled"] for s in seqs if s in by_seq]
+    assert flags[0] and sum(flags) >= 0.8 * len(flags)
+    assert flags == sorted(flags, reverse=True)
     assert all("blocks" in st for n, st in events
                if n == "bf.tick.drain.fetch")
     assert any(st.get("tokens", 0) > 0 for n, st in events

@@ -41,7 +41,8 @@ from . import FileContext, Finding, Rule, call_name, dotted_name, register
 HOT_FUNCTIONS: Set[str] = {
     "tick", "_tick_sections", "_decode_block", "_spec_block",
     "_enqueue_block", "_assemble", "_admit",
-    "_admit_round", "_finish_prefill", "_note_bubble",
+    "_admit_round", "_finish_prefill",
+    "_lap", "_starve", "_fed", "_note_fetch",
     "decode_block_async", "spec_block_async", "decode_active_async",
     "prefill_batch", "_sync_table",
     # ISSUE 20: the seq-parallel long-prompt lane — one chunk dispatch

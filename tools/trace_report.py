@@ -12,7 +12,9 @@ did this request's time go" view.
     python tools/trace_report.py trace.json --timeline 17
 
 ``--ticks FILE`` (a ``GET /debug/ticks`` body) adds the count of
-scheduler ticks and of the tokens they generated to the summary.
+scheduler ticks and of the tokens they generated to the summary, and
+the starvation clock's reading of those ticks: the seconds the device
+waited for the host before their launches, by cause and by span.
 
 ``--fleet`` renders a MERGED cross-replica trace instead — the JSON a
 fleet control plane returns from ``GET /fleet/trace?request_id=``: the
@@ -113,7 +115,39 @@ def render_summary(dump: Dict[str, Any],
         out.append(f"{len(recs)} tick(s), "
                    f"{sum(t.get('generated', 0) for t in recs)} token(s) "
                    "generated")
+        out.extend(starved_lines(recs))
     return "\n".join(out)
+
+
+def starved_lines(recs) -> List[str]:
+    """The starvation clock of a /debug/ticks dump: how long the device
+    waited for the host before the ticks' launches, as a share of the
+    time the ticks span, then by cause and by span, most first. Nothing
+    for the records of a program older than the clock."""
+    recs = [t for t in recs if "starved_s" in t]
+    if not recs:
+        return []
+    total = sum(t["starved_s"] or 0.0 for t in recs)
+    span = sum(t["wall_s"] + t["gap_s"] for t in recs)
+    causes: Dict[str, float] = {}
+    spans: Dict[str, float] = {}
+    for t in recs:
+        if t["starved_s"]:
+            c = t["starved_cause"]
+            causes[c] = causes.get(c, 0.0) + t["starved_s"]
+        for name, s in t["starved_by"].items():
+            spans[name] = spans.get(name, 0.0) + s
+    out = [f"device starved {_fmt_s(total)} of {_fmt_s(span)}"
+           + (f" ({100.0 * total / span:.1f}%)" if span > 0 else "")
+           + f" in {sum(1 for t in recs if t['starved_s'])} of "
+           f"{len(recs)} tick(s), "
+           f"{sum(1 for t in recs if t['profiled'])} under a capture"]
+    for label, table in (("by cause", causes), ("by span", spans)):
+        if table:
+            out.append(f"  {label}: " + "  ".join(
+                f"{k} {_fmt_s(v)}" for k, v in
+                sorted(table.items(), key=lambda kv: -kv[1])))
+    return out
 
 
 def render_timeline(dump: Dict[str, Any], rid: int) -> str:
@@ -221,7 +255,8 @@ def main(argv=None) -> int:
                    help="render a merged cross-replica fleet trace "
                         "(the GET /fleet/trace?request_id= body)")
     p.add_argument("--ticks", default=None, metavar="FILE",
-                   help="a GET /debug/ticks body: adds the tick count "
+                   help="a GET /debug/ticks body: adds the starved "
+                        "seconds by cause and by span, and the tick count "
                         "to the summary")
     p.add_argument("--json", action="store_true",
                    help="emit the per-request summaries as JSON instead "
