@@ -7,7 +7,8 @@ Two bounded, always-cheap instruments the scheduler feeds:
   time, per-phase host-section durations (the ``TICK_PHASES``
   vocabulary shared with docs/serving.md's tick-pipeline section),
   the drain's fetch wait and whether a newer block was still running
-  when it returned, in-flight depth, barrier causes,
+  when it returned, in-flight depth, barrier causes, the finishes
+  taken at the lazy drain without a barrier,
   batch occupancy and page headroom, the block program the tick
   dispatched and the rows one step of it computes, the loop's wait
   for the serving lock, the process's count of compilations and, for a
@@ -83,7 +84,8 @@ class TickLog:
 
     def record(self, wall_s: float, phases: Dict[str, float], *,
                fetch_s: float = 0.0, overlapped: Optional[bool] = None,
-               inflight: int = 0, barrier_causes=(), batch: int = 0,
+               inflight: int = 0, barrier_causes=(),
+               finishes_inline: int = 0, batch: int = 0,
                waiting: int = 0,
                pages_free: int = 0, generated: int = 0,
                spec: bool = False, program: Optional[str] = None,
@@ -96,7 +98,10 @@ class TickLog:
                gap_s: float = 0.0, profiled: bool = False) -> None:
         """Append one tick record (hot path: one dict build + one
         locked append per TICK, never per token). `phases` is copied —
-        callers may reuse/zero their accumulator dict. `expert_load`:
+        callers may reuse/zero their accumulator dict.
+        `finishes_inline`: requests whose finish this tick's lazy drain
+        took with the newer blocks still in flight, no full barrier
+        (finishes_inline_total). `expert_load`:
         [touched, rows_max, rows_mean] of the mixed blocks the tick
         drained (a model of experts; models.common.expert_load), as
         `experts_touched`, `expert_rows_max` and `expert_rows_mean`;
@@ -125,6 +130,7 @@ class TickLog:
             "overlapped": overlapped,
             "inflight": inflight,
             "barrier_causes": list(barrier_causes),
+            "finishes_inline": finishes_inline,
             "batch": batch,
             "waiting": waiting,
             "pages_free": pages_free,

@@ -23,9 +23,13 @@ engine/serving.py:
   blocks instead of idling it: a drain reads only arrays the blocks it
   drains produced themselves and launches no program to read them, so
   its fetch returns when the oldest block ends. A membership change
-  (admission work, a finish surfacing at drain, preemption, cancel)
-  forces a FULL drain barrier so host and device bookkeeping reconcile
-  before the next dispatch. Speculative mode dispatches fused SPEC
+  (admission work on the alternating path, preemption, cancel, an
+  expired deadline) forces a FULL drain barrier so host and device
+  bookkeeping reconcile before the next dispatch. A finish surfacing
+  at a lazy drain forces one too on the alternating and the
+  speculative paths; under mixed dispatch without speculation it is
+  taken there, with the newer blocks still in flight (tick()'s
+  docstring has the argument). Speculative mode dispatches fused SPEC
   blocks through the same pipeline: drafts come from a device-resident
   token history, acceptance (with the rejection-sampling correction at
   temperature > 0) is computed inside the scan, and blocks chain on
@@ -367,6 +371,16 @@ class Scheduler:
             H = engine.cache.max_seq
             self._hist_dev = jnp.zeros((engine.num_slots, H), jnp.int32)
             self._hist_len_dev = jnp.zeros((engine.num_slots,), jnp.int32)
+        # A finish that surfaces at a lazy drain is taken there, with
+        # the newer blocks in flight, where the scheduler runs mixed
+        # dispatch without speculation (tick()'s docstring). SEPARATE
+        # by mode, not a parameter: the speculative paths reset the
+        # device's budget carry (_spec_rem) to host truth at a finish
+        # barrier, the alternating path's admission barriers anyway,
+        # and the seq-parallel lane donates the pool binding in
+        # dispatches of its own; they keep the barrier.
+        self._finish_inline = (self._mixed_mode and not self._spec_mode
+                               and not self._sp_enabled)
         # Typed instruments (obs/registry.py) replace the old ad-hoc
         # Dict[str, float]: counters for the monotonic totals, fixed-
         # bucket histograms for the latency/size distributions /metrics
@@ -427,6 +441,13 @@ class Scheduler:
             "/ tick count: a healthy pipeline drains lazily and "
             "barriers only on membership changes, never once per "
             "decode or spec round", ("cause",))
+        self._c_finish_inline = reg.counter(
+            "finishes_inline_total",
+            "Requests whose finish surfaced at a lazy drain and was "
+            "taken there, with the newer blocks still in flight and no "
+            "full barrier (mixed dispatch without speculation); a "
+            "finish on any other path counts in "
+            "drain_barriers_total{cause=\"finish\"}")
         self._c_overlap = reg.counter_family(
             "drain_overlap_total",
             "Lazy drains by what the device was doing when the fetch "
@@ -644,6 +665,8 @@ class Scheduler:
         # time lands in the tick record and the launch's end is known
         engine.span = self._span
         self._tick_causes: List[str] = []
+        # requests that finished at this tick's lazy drain with no barrier
+        self._tick_finishes_inline = 0
         # the tick's lazy drain, if it had one with a newer block in
         # flight: was that block still running when the fetch returned
         self._tick_overlapped: Optional[bool] = None
@@ -1009,14 +1032,51 @@ class Scheduler:
         (everything drained) runs only when host and device state must
         reconcile:
 
-        * admission can make progress (a mid-prefill group, or a waiter
-          with a free slot) — prefill bookkeeping and budget assembly
-          need every in-flight token on the host;
-        * a finish surfaced at a lazy drain — the freed slot/pages and
-          the shrunken batch must be visible before the next dispatch;
+        * the alternating path only: admission can make progress (a
+          mid-prefill group, or a waiter with a free slot) — prefill
+          bookkeeping and budget assembly need every in-flight token
+          on the host;
+        * the alternating and the speculative paths only: a finish
+          surfaced at a lazy drain — the speculative budget carry
+          (_spec_rem) is reset to host truth there, and the
+          alternating path's admission barriers anyway;
         * page pressure (_ensure_or_preempt) — preemption must never
           reclaim pages a dispatched block still writes;
-        * cancel() — same hazard, external trigger.
+        * cancel() and an expired deadline — same hazard, external
+          trigger: the request's lane is LIVE in the blocks in flight.
+
+        Under mixed dispatch without speculation (_finish_inline) a
+        finish that surfaces at a lazy drain is taken THERE, with the
+        newer blocks still in flight: the freed slot and pages are
+        visible to this tick's admission, page preallocation and
+        operand assembly as they were behind the barrier, and the
+        block this tick dispatches chains on the newest block in
+        flight. Nothing on that list needs the newer blocks on the
+        host, because a request that has finished is DEAD in every
+        newer block from its first step:
+
+        * it ended by its budget: every newer block was given budget 0
+          for its slot (_mixed_block: base less the estimates of the
+          blocks in flight, which are exact up to a stop token, and a
+          request that spent its budget met none), or it ended by its
+          stop token: its chain token is frozen at the stop id, and
+          _packed_scan starts a decode-phase lane whose token is its
+          stop id dead. A dead lane advances no length, stages nothing
+          and writes the null page;
+        * the flush that precedes the emission was dispatched before
+          _finish released a page (_drain_blocks) and runs after every
+          block in flight: the old owner's staged rows land before
+          anything the page's next owner writes, and before a prefix
+          hit on a page the finish registered is read;
+        * reset_slot and _seed_mixed_slot edit the CURRENT carry
+          bindings, the newest block's results, so they land after it;
+          the block table is a host mirror pushed once a dispatch, and
+          the blocks in flight hold the table they were given;
+        * the drain of a newer block discards the tokens of a lane
+          whose snapshot names a request that is done (or another
+          generation), and _mixed_block counts a newer block's
+          emission estimate only for the request it was made for: the
+          next request of the slot starts with its own budget.
 
         Speculative mode (speculative_gamma > 0) runs the SAME pipeline
         with _spec_block in place of _decode_block: drafts come from
@@ -1043,6 +1103,7 @@ class Scheduler:
         self._tick_starved = self._tick_starved_cause = None
         self._tick_starved_by = {}
         self._tick_causes = []
+        self._tick_finishes_inline = 0
         self._tick_fetch = 0.0
         self._tick_overlapped = None
         self._tick_expert_loads = []
@@ -1072,9 +1133,18 @@ class Scheduler:
             self._expire_due()
         # lazy drain: consume the oldest block once the queue is full
         # (depth=1 degenerates to the old drain-every-tick loop). A
-        # finish surfacing there is a membership change -> full barrier.
+        # finish surfacing there is taken there under mixed dispatch
+        # without speculation (the newer blocks stay in flight: the
+        # finished lane is dead in them); on every other path it is a
+        # membership change -> full barrier.
         while len(self._inflight) >= depth:
-            if self._drain_oldest():
+            finished = self._drain_oldest()
+            if not finished:
+                continue
+            if self._finish_inline:
+                self._c_finish_inline.inc(finished)
+                self._tick_finishes_inline += finished
+            else:
                 self._drain_inflight("finish")
         mixed = self._mixed_mode
         # seq-parallel long-prompt lane (ISSUE 20): at most one chunk
@@ -1189,6 +1259,7 @@ class Scheduler:
                             overlapped=self._tick_overlapped,
                             inflight=len(self._inflight),
                             barrier_causes=self._tick_causes,
+                            finishes_inline=self._tick_finishes_inline,
                             batch=len(self.running),
                             waiting=len(self.waiting),
                             pages_free=self.alloc.free_pages,
@@ -1274,6 +1345,7 @@ class Scheduler:
             # compat: the unlabeled sum over the {cause} family — the
             # key every pre-ISSUE-15 consumer (spec bench, tests) reads
             "drain_barriers_total": sum(self.barrier_causes().values()),
+            "finishes_inline_total": self._c_finish_inline.value,
         }
         if self._spec_mode:
             fwd = self._c_spec_fwd.value
@@ -1582,7 +1654,9 @@ class Scheduler:
         lanes. Admission here is pure host bookkeeping plus per-slot
         device carry edits between dispatches (_seed_mixed_slot, the
         established reset_slot pattern: ``.at[slot].set`` on arrays
-        in-flight blocks never touch for a free slot).
+        in-flight blocks never touch for a free slot, nor for the
+        slot of a request that finished at this tick's lazy drain: its
+        lane is dead in them).
 
         The concurrent-prefill cap (_mixed_max_pf, derived from
         RuntimeConfig.prefill_inline_budget) bounds how many slots may
@@ -1643,10 +1717,18 @@ class Scheduler:
         """Device-carry seeding for one mixed-dispatch admission. Every
         write is an ``.at[slot].set`` on the CURRENT carry binding —
         i.e. on the result of the newest in-flight block — so it lands
-        after that block in device program order. The slot is free in
-        every in-flight block's snapshot (inactive lanes advance
-        nothing and their writes land on the null page), so nothing
-        here races a dispatched program.
+        after that block in device program order. In every in-flight
+        block's snapshot the slot is free OR dead: free (an inactive
+        lane), or held by a request whose finish a lazy drain has just
+        taken with that block still in flight, whose lane started the
+        block dead (tick()'s docstring: budget 0, or its chain token
+        frozen at its stop id). Either lane advances nothing and its
+        writes land on the null page, so nothing here races a
+        dispatched program. The dead request's chain token stays in
+        _next_dev[slot]; a new request with the SAME stop id does not
+        start dead, because _packed_scan skips the chain-token check
+        for a slot in prefill phase, and a seeded slot is in prefill
+        phase (the cached prefix is always shorter than the prompt).
 
         Seeds: pool lengths at the cached prefix (the warm-prefix
         contract), window count at zero, the chunk cursor at the
@@ -2095,7 +2177,20 @@ class Scheduler:
         P = self._mixed_max_pf if self._prefilling_ahead() else 0
         ahead = np.zeros((S,), np.int64)
         for ent in self._inflight:
-            ahead = ahead + ent[7]  # per-slot emission estimates
+            # per-slot emission estimates, each counted for the request
+            # it was made for: a block dispatched before a finish that
+            # was taken at a lazy drain names the slot's OLD request
+            # (0 after a budget finish, positive after a stop-death),
+            # and the slot's next request starts with its own budget
+            est = ent[7]
+            if ent[4] is not snapshot:     # the batch changed since
+                stale = [slot for slot, (req, gen) in ent[4].items()
+                         if req.done or req.slot != slot
+                         or req.preemptions != gen]
+                if stale:
+                    est = est.copy()
+                    est[stale] = 0
+            ahead = ahead + est
         budgets = np.maximum(base - ahead, 0).astype(np.int32)
         if not (active & (budgets > 0)).any():
             return False  # every lane is out of budget on device
@@ -2140,10 +2235,10 @@ class Scheduler:
                             pf_done, emit_vec)
         return True
 
-    def _drain_inflight(self, cause: str = "finish") -> bool:
+    def _drain_inflight(self, cause: str = "finish") -> int:
         """FULL drain barrier: fetch every pending first token and
         in-flight block, and read every flush count still pending.
-        Returns True if any request finished. In spec mode the device
+        Returns how many requests finished. In spec mode the device
         budget carry resets to None — the host again knows every
         emitted token, so the next dispatch reseeds it from exact host
         state.
@@ -2174,15 +2269,16 @@ class Scheduler:
                     self._tick_fetch += time.monotonic() - t_fetch
             return finished
 
-    def _drain_oldest(self) -> bool:
+    def _drain_oldest(self) -> int:
         """Lazy-drain step: fetch the pending firsts and ONLY the
         oldest in-flight block, leaving newer blocks running on the
         device (the dispatch-ahead overlap — the device computes block
         t+1 while the host emits block t). The fetch reads arrays that
         block t produced itself and launches nothing, so it returns
         when block t ends, whatever was launched after it. Returns
-        True if any request finished (the caller escalates that to a
-        full barrier)."""
+        how many requests finished (the caller takes them there under
+        mixed dispatch without speculation, and escalates to a full
+        barrier on every other path)."""
         with self._span("drain_oldest"):
             finished = self._drain_blocks([self._inflight.pop(0)]
                                           if self._inflight else [])
@@ -2198,10 +2294,11 @@ class Scheduler:
             self._c_kv_flushed.inc(int(pend.pop(0)))
 
     def _drain_blocks(self, blocks: List[tuple],
-                      cause: Optional[str] = None) -> bool:
+                      cause: Optional[str] = None) -> int:
         """Fetch + emit the given blocks and do their host bookkeeping
         in chronological order (`cause`: the full barrier's, None for
-        a lazy drain). Pending first tokens always ride along:
+        a lazy drain); returns how many requests finished. Pending
+        first tokens always ride along:
         they are queued at an admission barrier, when nothing is in
         flight, so they predate every dispatched block; each block's
         [k, S] rows are then emitted in step order per live slot,
@@ -2236,7 +2333,7 @@ class Scheduler:
         firsts, self._pending_first = self._pending_first, []
         self._pending_first_keys.clear()  # refreshed: all entries drain
         if not blocks and not firsts:
-            return False
+            return 0
         finished_before = self._c_finished.value
         # the ONE device fetch: the only tick section that blocks on
         # the device — timed for the tick_host_frac / tick_device_frac
@@ -2275,7 +2372,7 @@ class Scheduler:
             self._emit_drained(firsts, first_vals, blocks, block_vals)
             ann.set_metadata(tokens=int(self._c_tokens.value - tokens0))
         self._epoch += 1  # outputs / pending-first changed
-        return self._c_finished.value > finished_before
+        return int(self._c_finished.value - finished_before)
 
     def _note_fetch(self, fetch_s: float,
                     newest_ready: Optional[bool]) -> None:

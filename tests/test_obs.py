@@ -568,11 +568,17 @@ def test_trace_report_prints_the_starved_seconds(tmp_path):
                gap_s=0.01)
     log.record(0.10, {}, generated=4, starved_s=0.030, starved_cause="finish",
                starved_by={"drain.flush_count": 0.020, "admit.seed": 0.010},
-               gap_s=0.01, profiled=True)
+               gap_s=0.01, profiled=True, barrier_causes=["finish"])
     log.record(0.10, {}, generated=4, starved_s=0.010, starved_cause="exposed",
                starved_by={"drain.emit": 0.004, "admit.seed": 0.006},
-               gap_s=0.07)
-    log.record(0.10, {}, generated=0, gap_s=0.01)            # launched nothing
+               gap_s=0.07, finishes_inline=2)
+    log.record(0.10, {}, generated=0, gap_s=0.01,            # launched nothing
+               barrier_causes=["cancel", "finish"], finishes_inline=1)
+    # the barriers by cause, most first, beside the finishes a lazy
+    # drain took without one
+    barriers = mod.barrier_lines(log.dump()["ticks"])
+    assert barriers == ["full barriers: finish 2  cancel 1; finishes at a "
+                        "lazy drain, no barrier: 3"]
     lines = mod.starved_lines(log.dump()["ticks"])
     assert lines == [
         "device starved 40.0ms of 500.0ms (8.0%) in 2 of 4 tick(s), "
@@ -583,12 +589,14 @@ def test_trace_report_prints_the_starved_seconds(tmp_path):
     dump = _synthetic_dump(tmp_path / "trace.json")
     text = mod.render_summary(mod.load_dump(str(dump)), log.dump())
     assert "4 tick(s), 12 token(s) generated" in text
-    assert text.endswith("\n".join(lines))
+    assert text.endswith("\n".join(barriers + lines))
     # a dump of a program older than the clock: the counts and no more
     old = {"ticks": [{k: v for k, v in t.items()
-                      if not k.startswith("starved") and k != "gap_s"}
+                      if not k.startswith("starved")
+                      and k not in ("gap_s", "finishes_inline")}
                      for t in log.dump()["ticks"]]}
     assert mod.starved_lines(old["ticks"]) == []
+    assert mod.barrier_lines(old["ticks"]) == []
     assert mod.render_summary(mod.load_dump(str(dump)), old).endswith(
         "4 tick(s), 12 token(s) generated")
     # and the CLI prints it
@@ -697,7 +705,7 @@ def _synthetic_ticks(path, n=12):
         wall = sum(phases.values())
         log.record(wall, phases, fetch_s=0.0015, inflight=2,
                    barrier_causes=["admission"] if i % 3 == 0 else [],
-                   batch=4, waiting=i % 2, pages_free=10, generated=8)
+                   finishes_inline=i % 2, batch=4, waiting=i % 2, pages_free=10, generated=8)
     path.write_text(json.dumps({"enabled": True, **log.dump()}))
     return path
 
@@ -719,8 +727,12 @@ def test_tick_report_stats_and_reconciliation(tmp_path):
     totals = [p["total_s"] for p in s["phases"]]
     assert totals == sorted(totals, reverse=True)
     assert s["barrier_causes"] == {"admission": 4}
+    assert s["finishes_inline"] == 6
     text = mod.render(dump)
     assert "dispatch" in text and "barriers by cause" in text
+    assert "finishes taken at a lazy drain, no barrier: 6" in text
+    assert mod.tick_line(dump["ticks"][1]).endswith(
+        "barriers=- finishes_inline=1")
     # the top-terms table speaks the mixed-dispatch vocabulary: the
     # fused phase renders with its glossary note
     assert "mixed" in text and "ONE fused dispatch" in text

@@ -1898,19 +1898,24 @@ def _last_tick(sched):
 
 
 def test_starvation_clock_at_a_finish_barrier(monkeypatch):
-    """A finish barrier: the clock runs from the return of the barrier's
-    block fetch to the return of the next launch, the tick record says
-    `finish`, and `starved_by` sums to `starved_s` and names the spans
-    the host was in, the wait for the flush count and the admission's
-    device edits among them. No block is ever ready, so every lazy drain
-    is overlapped and every other launch counts 0.0."""
-    sched, clock, log = _clocked(monkeypatch, lambda x: False)
+    """A finish barrier (a mode that still runs one: speculation, whose
+    budget carry is reset to host truth there): the clock runs from the
+    return of the barrier's block fetch to the return of the next
+    launch, the tick record says `finish`, and `starved_by` sums to
+    `starved_s` and names the spans the host was in, the wait for the
+    flush count and the admission's device edits among them. No block is
+    ever ready, so every lazy drain is overlapped and every other launch
+    counts 0.0."""
+    sched, clock, log = _clocked(monkeypatch, lambda x: False,
+                                 speculative_gamma=3)
+    assert not sched._finish_inline
     for _ in range(60):
         sched.tick()
         if _last_tick(sched)["barrier_causes"] == ["finish"]:
             break
     rec = _last_tick(sched)
     assert rec["barrier_causes"] == ["finish"] and rec["program"]
+    assert rec["finishes_inline"] == 0
     start, stop = log["starved"][-1], log["fed"][-1]
     # the clock started two reads after the barrier's fetch returned (the
     # fetch's own timer, then the start) and stopped at the launch's end
@@ -1935,6 +1940,58 @@ def test_starvation_clock_at_a_finish_barrier(monkeypatch):
     assert h.count == len(log["fed"]) and h.sum == pytest.approx(
         rec["starved_s"])
     assert sched._starved_by is None
+
+
+@pytest.mark.parametrize("newer_done", [False, True],
+                         ids=["overlapped", "exposed"])
+def test_starvation_clock_at_a_finish_without_a_barrier(monkeypatch,
+                                                        newer_done):
+    """A finish taken at the lazy drain (mixed dispatch, no speculation):
+    no barrier runs and the newer block stays in flight. While that
+    block still runs when the fetch returns, the finish, the admission
+    behind it and the launch hide behind it and the tick starves
+    nothing; if it has ended, the clock starts at the fetch's return
+    under `exposed` and holds the emission, the admission's device edits
+    and the launch."""
+    state = {"ready": False}
+    sched, clock, log = _clocked(monkeypatch, lambda x: state["ready"])
+    assert sched._finish_inline
+    import butterfly_tpu.sched.scheduler as S
+    get = S.jax.device_get
+
+    def fetched(x):
+        out = get(x)
+        state["ready"] = newer_done    # has the newer block ended meanwhile
+        return out
+    monkeypatch.setattr(S.jax, "device_get", fetched)
+    for _ in range(60):
+        state["ready"] = False
+        sched.tick()
+        if _last_tick(sched)["finishes_inline"]:
+            break
+    rec = _last_tick(sched)
+    assert rec["finishes_inline"] == 1 and rec["barrier_causes"] == []
+    assert rec["inflight"] == 2 and rec["program"]   # chained on the newer
+    assert sched.barrier_causes().get("finish", 0) == 0
+    # the waiter took the freed slot in the same tick
+    assert not sched.waiting and rec["waiting"] == 0
+    if not newer_done:
+        assert rec["overlapped"] is True
+        assert rec["starved_s"] == 0.0 and rec["starved_cause"] is None
+        assert rec["starved_by"] == {}
+        return
+    assert rec["overlapped"] is False and rec["starved_cause"] == "exposed"
+    start, stop = log["starved"][-1], log["fed"][-1]
+    assert start == pytest.approx(log["fetched"][-1] + 0.001)
+    assert rec["starved_s"] == pytest.approx(stop - start, abs=1e-9)
+    by = rec["starved_by"]
+    assert sum(by.values()) == pytest.approx(rec["starved_s"], abs=1e-6)
+    assert {"drain.emit", "admit", "admit.seed", "dispatch.put",
+            "dispatch.launch"} <= set(by)
+    # no full barrier: nothing waited for a flush count, and the one
+    # fetch is the lazy drain's
+    assert "drain.flush_count" not in by and "drain.fetch" not in by
+    assert rec["fetch_s"] == pytest.approx(0.5, abs=0.01)
 
 
 def test_starvation_clock_runs_across_ticks(monkeypatch):

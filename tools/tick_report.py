@@ -4,8 +4,9 @@
 The software answer to "what are the top host terms in a serving tick"
 (ROADMAP item 1) — a top-terms table of the structural tick phases
 (total seconds, share of tick wall, p50/p95), the host/device wall
-split, the per-cause barrier counts, and a reconciliation line proving
-the phase sums account for the measured tick wall time.
+split, the per-cause barrier counts beside the finishes taken at a lazy
+drain without a barrier, and a reconciliation line proving the phase
+sums account for the measured tick wall time.
 
 stdlib-only (no jax, no numpy): runs anywhere, like trace_report.py.
 
@@ -76,7 +77,9 @@ def phase_stats(dump: dict) -> dict:
     wall_total = 0.0
     fetch_total = 0.0
     causes: Dict[str, int] = {}
+    finishes_inline = 0
     for t in ticks:
+        finishes_inline += t.get("finishes_inline", 0)
         wall_total += t.get("wall_s", 0.0)
         fetch_total += t.get("fetch_s", 0.0)
         for name, v in t.get("phases", {}).items():
@@ -103,6 +106,9 @@ def phase_stats(dump: dict) -> dict:
         "device_frac": (fetch_total / wall_total) if wall_total else 0.0,
         "phases": phases,
         "barrier_causes": causes,
+        # finishes a lazy drain took with the newer blocks in flight
+        # (mixed dispatch without speculation): no barrier ran for them
+        "finishes_inline": finishes_inline,
     }
 
 
@@ -133,6 +139,8 @@ def render(dump: dict) -> str:
             lines.append(f"  {cause:>14} {n}")
     else:
         lines.append("no full drain barriers in the window")
+    lines.append(f"finishes taken at a lazy drain, no barrier: "
+                 f"{s['finishes_inline']}")
     return "\n".join(lines)
 
 
@@ -149,7 +157,8 @@ def tick_line(t: dict) -> str:
             f"batch={t.get('batch', 0)} wait={t.get('waiting', 0)} "
             f"inflight={t.get('inflight', 0)} "
             f"pages={t.get('pages_free', 0)} "
-            f"gen={t.get('generated', 0)} barriers={causes}")
+            f"gen={t.get('generated', 0)} barriers={causes} "
+            f"finishes_inline={t.get('finishes_inline', 0)}")
 
 
 def follow(url: str, interval: float, timeout: float,

@@ -12,8 +12,9 @@ did this request's time go" view.
     python tools/trace_report.py trace.json --timeline 17
 
 ``--ticks FILE`` (a ``GET /debug/ticks`` body) adds the count of
-scheduler ticks and of the tokens they generated to the summary, and
-the starvation clock's reading of those ticks: the seconds the device
+scheduler ticks and of the tokens they generated to the summary, the
+full drain barriers by cause beside the finishes taken at a lazy drain
+without one, and the starvation clock's reading of those ticks: the seconds the device
 waited for the host before their launches, by cause and by span.
 
 ``--fleet`` renders a MERGED cross-replica trace instead — the JSON a
@@ -115,8 +116,27 @@ def render_summary(dump: Dict[str, Any],
         out.append(f"{len(recs)} tick(s), "
                    f"{sum(t.get('generated', 0) for t in recs)} token(s) "
                    "generated")
+        out.extend(barrier_lines(recs))
         out.extend(starved_lines(recs))
     return "\n".join(out)
+
+
+def barrier_lines(recs) -> List[str]:
+    """The full drain barriers of a /debug/ticks dump by cause, most
+    first, beside the finishes a lazy drain took without one. Nothing
+    for the records of a program that took no finish so."""
+    recs = [t for t in recs if "finishes_inline" in t]
+    if not recs:
+        return []
+    causes: Dict[str, int] = {}
+    for t in recs:
+        for c in t["barrier_causes"]:
+            causes[c] = causes.get(c, 0) + 1
+    return ["full barriers: " + ("  ".join(
+        f"{c} {n}" for c, n in sorted(causes.items(),
+                                      key=lambda kv: -kv[1])) or "none")
+        + "; finishes at a lazy drain, no barrier: "
+        f"{sum(t['finishes_inline'] for t in recs)}"]
 
 
 def starved_lines(recs) -> List[str]:
