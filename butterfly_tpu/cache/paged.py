@@ -70,18 +70,24 @@ from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
 # wrappers import nothing project-local at module level.
 from butterfly_tpu.models.common import (
     _cast_float, attend, attend_token_rows, attn_output,
-    early_router_logits, embed_tokens, expert_load, ffn_block, final_logits,
-    index_proj, index_scores, indexer_unsupported, layer_mask,
-    layer_pattern_of, layer_stack, make_mask, pre_norm, qkv_proj,
-    quantize_kv, router_logits, select_mask, select_topk)
+    early_router_logits, embed_tokens, ffn_close, final_logits,
+    index_proj, index_scores, indexer_unsupported, layer_at, layer_mask,
+    layer_pattern_of, layer_runs, layer_stack, make_mask, pre_norm,
+    qkv_proj, quantize_kv, residual_add, select_mask, select_topk,
+    ssm_unsupported)
 from butterfly_tpu.ops import note_kernel
 from butterfly_tpu.ops.flash_attention import flash_attention_sharded
 from butterfly_tpu.ops.paged_attention import paged_attention_sharded
+from butterfly_tpu.cache.ssm_state import SSMState, advance_packed
 
 
 class PagedKVCache(NamedTuple):
     # [L, P, Kv, page, H] (int8 codes when quantized); token-major
-    # [L, P, 1, page, Kv*H] for a model with an indexer (pool_row)
+    # [L, P, 1, page, Kv*H] for a model with an indexer (pool_row). L
+    # counts the layers that OWN rows: a model with Mamba-2 layers holds
+    # pages for its attention layers alone (cfg.num_attn_layers, 0 for
+    # a model of Mamba layers only: the table, the lengths and the
+    # flush still work, over no layer)
     k_pages: jax.Array
     v_pages: jax.Array
     page_table: jax.Array  # [slots, max_pages] int32, null = P-1
@@ -152,19 +158,20 @@ def init_paged_cache(cfg: ModelConfig, runtime: RuntimeConfig,
     P = runtime.num_pages or runtime.max_batch_size * max_pages
     P += 1  # null page
     heads, width = pool_row(cfg)
-    shape = (cfg.num_layers, P, heads, page, width)
+    L = cfg.num_attn_layers
+    shape = (L, P, heads, page, width)
     if runtime.kv_quant not in ("none", "int8"):
         raise ValueError(f"unknown kv quant {runtime.kv_quant!r}")
     if runtime.kv_quant == "int8":
         indexer_unsupported(cfg, "the int8 KV cache")
-    ki_shape = (cfg.num_layers, P, 1, page, cfg.index_head_dim)
+    ki_shape = (L, P, 1, page, cfg.index_head_dim)
 
     def build():
         table = jnp.full((runtime.max_batch_size, max_pages), P - 1,
                          jnp.int32)
         lengths = jnp.zeros((runtime.max_batch_size,), jnp.int32)
         if runtime.kv_quant == "int8":
-            sshape = (cfg.num_layers, P, cfg.num_kv_heads * page)
+            sshape = (L, P, cfg.num_kv_heads * page)
             return PagedKVCache(
                 k_pages=jnp.zeros(shape, jnp.int8),
                 v_pages=jnp.zeros(shape, jnp.int8),
@@ -656,6 +663,12 @@ def permute_paged_tail(cache: PagedKVCache, perm, active=None
 # Paged forward pass (reference path; Pallas decode kernel lives in ops/)
 # ---------------------------------------------------------------------------
 
+#: what paged_forward and paged_forward_window are to a model whose
+#: streams hold a recurrent state: the packed mixed step carries it
+ALTERNATING = ("the alternating prefill/decode path (paged_forward: "
+               "mixed_dispatch off, the static scheduler, a draft model)")
+
+
 def _settled(*view):
     """The gathered view as attend() reads it, closed to fusion with
     what built it. Window on and off build the same elements by
@@ -941,14 +954,8 @@ def _layer_close(x, out, lp, cfg: ModelConfig, route=None, ok=None):
     Returns (x, load): with `ok` [B,T], the rows that are real, and a
     model of experts, `load` is what the layer's routing asked of them
     (models.common.expert_load), else None."""
-    x = x + attn_output(out, lp["attn"], cfg)
-    h = pre_norm(x, lp["ln2"], cfg)
-    load = None
-    if ok is not None and cfg.is_moe:
-        if route is None:
-            route = router_logits(h, lp["moe"]["router"])
-        load = expert_load(route, cfg.num_experts_per_tok, ok)
-    return x + ffn_block(h, lp, cfg, route), load
+    x = residual_add(x, attn_output(out, lp["attn"], cfg), cfg)
+    return ffn_close(x, lp, cfg, route, ok)
 
 
 def _as_pool(pools):
@@ -1054,6 +1061,7 @@ def paged_forward(params, cfg: ModelConfig, tokens: jax.Array,
     (base + tree depth): after the accepted-path compaction the kept
     entries' storage positions equal their RoPE positions again.
     """
+    ssm_unsupported(cfg, ALTERNATING)
     B, T = tokens.shape
     if positions is None:
         positions = cache.lengths[:, None] + jnp.arange(T)[None, :]
@@ -1120,6 +1128,7 @@ def paged_forward_window(params, cfg: ModelConfig, tokens: jax.Array,
     base + tree depth for RoPE, and the explicit mask forces the dense
     insert path.
     """
+    ssm_unsupported(cfg, ALTERNATING)
     B, T = tokens.shape
     if active is None:
         active = jnp.ones((B,), bool)
@@ -1298,6 +1307,63 @@ def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
     return x, pools, wl, load
 
 
+def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
+                 cache: PagedKVCache, window: Optional[KVWindow],
+                 state: SSMState, use_kernel: bool):
+    """The layers of a packed step for a model with layers of two kinds
+    (cfg.layer_types), in the published order: each run of one kind
+    (models.common.layer_runs) is one scan that rides the layers'
+    indices, into params["layers"] (norms, feed-forward) and into its
+    kind's own stack. A Mamba run carries the recurrent state
+    (ssm_state.advance_packed); an attention run is packed_layer as
+    every other model runs it, over the pool's layer a (the pool holds
+    attention layers only): window on, the read-only pool whole and the
+    run's window slices as xs; window off, the run's pool slices.
+    Returns (x, window or cache as written, state, load): load the
+    mean of the layers' expert_load."""
+    pools = pool_leaves(cache, absent=True)
+    held = pools if window is None else window_leaves(window, absent=True)
+    written, loads = [], []
+
+    def mamba(carry, idx):
+        x, st = carry
+        l, m = idx
+        x, st, load = advance_packed(
+            x, layer_at(params["layers"], l, cfg),
+            layer_at(params["mamba"], m, cfg), st, m, rows, cfg)
+        return (x, st), load
+
+    def attention(x, scanned):
+        (l, a), *mine = scanned
+        lp = {**layer_at(params["layers"], l, cfg),
+              "attn": layer_at(params["attn"], a, cfg)}
+        if window is None:
+            x, new, _, load = packed_layer(x, lp, mine, None, rows, cfg,
+                                           use_kernel)
+        else:
+            x, _, new, load = packed_layer(x, lp, pools, mine, rows, cfg,
+                                           use_kernel, layer=a)
+        return x, (new, load)
+
+    for kind, first, n, at in layer_runs(cfg):
+        idx = (first + jnp.arange(n), at + jnp.arange(n))
+        if kind == "mamba":
+            (x, state), load = lax.scan(mamba, (x, state), idx)
+        else:
+            x, (new, load) = lax.scan(
+                attention, x, (idx, *(None if a is None else a[at:at + n]
+                                      for a in held)))
+            written.append(new)
+        loads.append(load)
+    if written:
+        # one run's slices as they are; several runs' joined in order
+        held = written[0] if len(written) == 1 else tuple(
+            None if a[0] is None else jnp.concatenate(a)
+            for a in zip(*written))
+    kv = pool_leaves(cache, held) if window is None else KVWindow(*held)
+    return x, kv, state, jnp.concatenate(loads).mean(axis=0)
+
+
 _POOL_LEAVES = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages",
                 "ki_pages")
 _WINDOW_LEAVES = ("k", "v", "k_scale", "v_scale", "ki")
@@ -1333,7 +1399,8 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
                          active: jax.Array,
                          window: Optional[KVWindow] = None,
                          win_len: Optional[jax.Array] = None,
-                         use_kernel: bool = False):
+                         use_kernel: bool = False,
+                         state: Optional[SSMState] = None):
     """One PACKED mixed step: S decode rows of one token beside P
     prefill chunks of C tokens, S + P*C rows through every projection,
     the feed-forward and (on a mesh) the all-reduces, where the
@@ -1365,9 +1432,24 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
     rows, the positions they could attend, the positions they read.
     (Under pipeline stages: parallel/pipeline.py paged_pipeline_packed,
     the same pieces over stage-local layers.)
+
+    state (a model with Mamba-2 layers: cache/ssm_state.py): every
+    slot's recurrent state, which the step's real rows advance. The
+    layers then run as scans over runs of one kind (_packed_runs) and
+    the return gains the state as a fourth value; `load` gains two:
+    the positions pushed through a recurrence this step (decode rows
+    and real chunk columns) and the slots that started from zero.
     """
     x, rows = packed_rows(params, cfg, tokens, cache, chunk_tokens,
                           chunk_slot, chunk_count, active, window, win_len)
+    if cfg.has_ssm:
+        x, kv, state, load = _packed_runs(params, cfg, x, rows, cache,
+                                          window, state, use_kernel)
+        ssm = jnp.stack([jnp.sum(rows.ok), jnp.sum(
+            rows.chunk_ok & (rows.chunk_pos[:, 0] == 0))])
+        load = jnp.concatenate([load, ssm.astype(jnp.float32)])
+        return (final_logits(params, cfg, x[rows.head])[:, 0], kv, load,
+                state)
     # an absent pool or window tensor rides the scan as None (no leaf)
     if window is None:
         def body(x, scanned):

@@ -19,12 +19,14 @@ class ModelConfig:
     """Architecture hyperparameters for a transformer LM.
 
     One config class covers the model families (GPT-2, Llama-3,
-    Mixtral, SmallThinker, Keye) — the family is selected by `arch`, the
-    MoE fields, the per-layer attention pattern and the sparse-attention
-    indexer.
+    Mixtral, SmallThinker, Keye, Granite-4.0-H) — the family is selected
+    by `arch`, the MoE fields, the per-layer attention pattern, the
+    sparse-attention indexer and the per-layer KIND (`layer_types`:
+    Mamba-2 mixers beside attention layers).
     """
 
-    arch: str = "llama"  # "gpt2" | "llama" | "mixtral" | "smallthinker" | "keye"
+    arch: str = "llama"  # "gpt2" | "llama" | "mixtral" | "smallthinker"
+                         # | "keye" | "granite_hybrid"
     vocab_size: int = 32000
     hidden_size: int = 4096
     num_layers: int = 32
@@ -41,7 +43,8 @@ class ModelConfig:
     act: str = "silu"                 # gpt2: "gelu_new"; llama/mixtral: "silu"
 
     # positional encoding
-    pos_embedding: str = "rope"       # "rope" | "learned"
+    pos_embedding: str = "rope"       # "rope" | "learned" | "none" (no
+                                      # positional encoding in any layer)
     rope_theta: float = 500000.0
 
     # MoE (mixtral)
@@ -54,6 +57,36 @@ class ModelConfig:
                                       # "attn" = the ATTENTION's normed input,
                                       # the router placed before attention
                                       # (SmallThinker)
+
+    shared_intermediate_size: int = 0  # width of ONE shared expert every
+                                      # token passes beside its routed
+                                      # experts, added unweighted; 0 = none
+
+    # per-layer KIND: "mamba" (a Mamba-2 mixer with a fixed-size
+    # recurrent state a stream: cache/ssm_state.py) or "attention" (keys
+    # and values in the paged pool), one entry a layer (a longer list is
+    # read up to num_layers); () = every layer is attention. Kinds have
+    # unlike parameter SHAPES, so each kind's mixer weights are stacked
+    # apart (params["mamba"], params["attn"]) and the layers run as
+    # scans over runs of one kind (models/common.py layer_runs); the
+    # feed-forward of all layers is one stack (params["layers"]).
+    layer_types: Tuple[str, ...] = ()
+    ssm_heads: int = 0                # Mamba-2 heads
+    ssm_head_dim: int = 0             # values a head
+    ssm_state: int = 0                # d_state: the state of a head is
+                                      # [ssm_head_dim, ssm_state]
+    ssm_groups: int = 1               # groups that share B and C
+    ssm_conv: int = 4                 # taps of the causal depthwise conv
+    # what a slot keeps between steps (state, conv tail) is in `dtype`;
+    # a step's arithmetic is float32
+
+    # Granite's four multipliers; 0 = the family has none (the term is
+    # left out of the program, not multiplied by one)
+    embedding_multiplier: float = 0.0  # on the token embedding
+    residual_multiplier: float = 0.0   # on each sublayer's output
+    attention_multiplier: float = 0.0  # the score scale, in place of
+                                       # head_dim ** -0.5
+    logits_scaling: float = 0.0        # logits are DIVIDED by it
 
     # per-layer attention pattern: layers of one model that differ in
     # mask and rotation. A layout has one 0/1 entry per layer (a longer
@@ -104,6 +137,23 @@ class ModelConfig:
                 raise ValueError(f"{name} has {len(layout)} entries for "
                                  f"{self.num_layers} layers")
             object.__setattr__(self, name, layout)
+        kinds = tuple(str(k) for k in self.layer_types)[:self.num_layers]
+        if kinds:
+            if len(kinds) < self.num_layers \
+                    or set(kinds) - {"mamba", "attention"}:
+                raise ValueError(
+                    f"layer_types names {len(kinds)} layers of "
+                    f"{self.num_layers}, each 'mamba' or 'attention': "
+                    f"{kinds}")
+            if "mamba" in kinds and not (self.ssm_heads and self.ssm_head_dim
+                                         and self.ssm_state):
+                raise ValueError("a 'mamba' layer needs ssm_heads, "
+                                 "ssm_head_dim and ssm_state")
+            if self.layer_pattern() is not None or self.has_indexer:
+                raise ValueError(
+                    "layer_types beside a per-layer attention pattern or "
+                    "a sparse-attention indexer is not supported")
+        object.__setattr__(self, "layer_types", kinds)
         if self.router_input not in ("ffn", "attn"):
             raise ValueError(f"unknown router_input {self.router_input!r}")
         if bool(self.sliding_window_layout) != (self.sliding_window > 0):
@@ -141,6 +191,32 @@ class ModelConfig:
     @property
     def has_indexer(self) -> bool:
         return self.index_topk > 0
+
+    @property
+    def has_ssm(self) -> bool:
+        """Some layer is a Mamba-2 mixer: a stream holds a recurrent
+        state beside (or in place of) its pages."""
+        return "mamba" in self.layer_types
+
+    @property
+    def num_ssm_layers(self) -> int:
+        return self.layer_types.count("mamba")
+
+    @property
+    def num_attn_layers(self) -> int:
+        """Layers that own rows of the paged pool."""
+        return self.layer_types.count("attention") if self.layer_types \
+            else self.num_layers
+
+    @property
+    def ssm_inner(self) -> int:
+        """Width of the mixer's inner stream: heads x head_dim."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels through the conv: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def q_per_kv(self) -> int:
@@ -223,6 +299,32 @@ def keye_vl2_30b_a3b() -> ModelConfig:
     )
 
 
+#: one period of granite-4.0-h-small's published pattern (layers 0-9)
+_GRANITE_PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+
+
+def granite_4_h_small() -> ModelConfig:
+    """granite-4.0-h-small (huggingface.co/ibm-granite, `granitemoehybrid`,
+    32B-A9B): 40 layers of which 36 are Mamba-2 mixers (128 heads of 64,
+    d_state 128, one group, conv of 4) and 4 are grouped-query attention
+    without positional encoding; every layer is followed by 72 SiLU
+    experts of 768, 10 a token, and one shared expert of 1,536; Granite's
+    four multipliers; tied embeddings."""
+    # attention at layers 5, 15, 25, 35: 9 Mamba layers to 1
+    kinds = _GRANITE_PERIOD * 4
+    return ModelConfig(
+        arch="granite_hybrid", vocab_size=100352, hidden_size=4096,
+        num_layers=40, num_heads=32, num_kv_heads=8, head_dim=128,
+        intermediate_size=768, max_seq_len=131072, norm_eps=1e-5,
+        pos_embedding="none", tie_embeddings=True,
+        num_experts=72, num_experts_per_tok=10,
+        shared_intermediate_size=1536, layer_types=kinds,
+        ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssm_groups=1,
+        ssm_conv=4, embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=0.0078125, logits_scaling=16.0,
+    )
+
+
 def tiny(arch: str = "llama", **kw) -> ModelConfig:
     """Small config for tests: runs in <1s on CPU, exercises every code path."""
     base = dict(
@@ -250,6 +352,17 @@ def tiny(arch: str = "llama", **kw) -> ModelConfig:
         base.update(intermediate_size=32, num_experts=8,
                     num_experts_per_tok=2, qk_norm=True, index_heads=2,
                     index_head_dim=16, index_topk=8)
+    if arch == "granite_hybrid":
+        # every mechanism of the real one: both layer kinds, conv of 4,
+        # heads x head_dim x state, experts with a shared one, the four
+        # multipliers, a tied head, no positional encoding
+        base.update(num_layers=4, intermediate_size=32, num_experts=8,
+                    num_experts_per_tok=3, shared_intermediate_size=48,
+                    layer_types=("mamba", "mamba", "attention", "mamba"),
+                    ssm_heads=8, ssm_head_dim=16, ssm_state=16,
+                    pos_embedding="none", tie_embeddings=True,
+                    embedding_multiplier=12.0, residual_multiplier=0.22,
+                    attention_multiplier=0.0625, logits_scaling=16.0)
     base.update(kw)
     return ModelConfig(arch=arch, **base)
 
@@ -261,6 +374,7 @@ PRESETS = {
     "mixtral-8x7b": mixtral_8x7b,
     "smallthinker-21b-a3b": smallthinker_21b_a3b,
     "keye-vl2-30b-a3b": keye_vl2_30b_a3b,
+    "granite-4.0-h-small": granite_4_h_small,
 }
 
 

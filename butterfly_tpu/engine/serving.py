@@ -57,7 +57,8 @@ from jax import lax
 from jax.profiler import TraceAnnotation
 
 from butterfly_tpu.cache.paged import (
-    KVWindow, PagedKVCache, flush_paged_window, init_kv_window, pool_leaves,
+    ALTERNATING, KVWindow, PagedKVCache, flush_paged_window, init_kv_window,
+    pool_leaves,
     init_paged_cache, paged_forward, paged_forward_packed,
     paged_forward_window, permute_paged_tail, permute_window_tail)
 from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
@@ -66,7 +67,9 @@ from butterfly_tpu.ops import kernel_mode, kernels_default, record_kernels
 from butterfly_tpu.engine.sampling import (
     _filter_logits, speculative_accept, speculative_tree_accept,
     tree_ancestor_matrix, tree_depth, tree_node_index)
-from butterfly_tpu.models.common import Model, indexer_unsupported
+from butterfly_tpu.cache.ssm_state import SSMState, init_ssm_state
+from butterfly_tpu.models.common import (
+    Model, indexer_unsupported, ssm_unsupported)
 
 
 #: the span a program launch runs under (`bf.tick.dispatch.launch` in a
@@ -291,6 +294,28 @@ class ServingEngine:
         if self.runtime.prefix_caching:
             indexer_unsupported(self.cfg, "prefix caching (and the host "
                                           "KV tier behind it)")
+        # so does what cannot take a recurrent state a slot (Mamba-2
+        # layers): it has no pages to hash, export or roll back, and no
+        # sharding of its own yet
+        for axis, what in (("stage", "pipeline serving"),
+                           ("seq", "the sequence-parallel prefill lane"),
+                           ("tensor", "tensor parallelism (the mixer's "
+                                      "projections and state)"),
+                           ("data", "a data-parallel mesh"),
+                           ("expert", "an expert-parallel mesh")):
+            if mesh is not None and mesh.shape.get(axis, 1) > 1:
+                ssm_unsupported(self.cfg, what)
+        if self.runtime.speculative_gamma > 0:
+            ssm_unsupported(self.cfg, "speculative decoding (a rejected "
+                                      "draft would have to roll a state "
+                                      "back)")
+        if self.runtime.prefix_caching:
+            ssm_unsupported(self.cfg, "prefix caching (and the host KV "
+                                      "tier behind it: a state has no "
+                                      "pages to key by token hash)")
+        if not self.runtime.mixed_dispatch \
+                or self.runtime.scheduler != "continuous":
+            ssm_unsupported(self.cfg, ALTERNATING)
         if use_kernels is None:
             # on everywhere but the CPU backend (ops/__init__.py); under
             # a mesh the call sites go through ops/*_sharded (shard_map
@@ -329,6 +354,11 @@ class ServingEngine:
                 quant=self.runtime.kv_quant == "int8"), mesh)
         self.cache = init_paged_cache(self.cfg, self.runtime,
                                       shardings=cache_shardings)
+        # a model with Mamba-2 layers: every slot's recurrent state
+        # (cache/ssm_state.py), DONATED to each mixed block and rebound
+        # from its result like the window; None for every other model
+        self._ssm_state: Optional[SSMState] = init_ssm_state(
+            self.cfg, self.runtime.max_batch_size, self._home)
         # Host-side block-table mirror (see set_table_row). Built from
         # the known init value (all rows -> null page) rather than
         # fetching the device array: a multi-process data-sharded table
@@ -1049,7 +1079,9 @@ class ServingEngine:
             prog = jax.jit(
                 named(partial(_packed_scan, self.cfg, self._packed_fwd, k, C,
                               P, use_kernel=self._use_kernels), name),
-                static_argnums=(12, 13), donate_argnums=(2, 3, 4, 5))
+                static_argnums=(12, 13),
+                donate_argnums=(2, 3, 4, 5) + ((15,) if self.cfg.has_ssm
+                                               else ()))
             self._mixed_blocks[(k, C, P)] = prog
         return prog
 
@@ -1087,12 +1119,13 @@ class ServingEngine:
             self._ensure_window(need)
         with self._mesh_ctx():
             (block, valid, final, cursor, cache, window, wlen,
-             self.last_expert_load) = self._launch(
+             self.last_expert_load, self._ssm_state) = self._launch(
                 self._mixed_block_prog(k, C, P), self.num_slots + P * C,
                 self.params, tokens, cursor, self.cache,
                 self._kv_window, self._win_len,  # None with the window off
                 pbuf, plen, active, temps, stops, budgets,
-                self.runtime_top_k, self.runtime_top_p, key)
+                self.runtime_top_k, self.runtime_top_p, key,
+                *(() if self._ssm_state is None else (self._ssm_state,)))
         self.cache, self._kv_window, self._win_len = cache, window, wlen
         if self._window_mode:
             self._win_dirty = True
@@ -1112,6 +1145,7 @@ class ServingEngine:
         never rewritten) may be exported, so in-flight decode blocks
         writing other pages cannot race the bytes."""
         indexer_unsupported(self.cfg, "KV page export (read_pages)")
+        ssm_unsupported(self.cfg, "KV page export (read_pages)")
         if self._win_dirty:
             self.flush_kv_window()
         idx = jnp.asarray(pids, jnp.int32)
@@ -1134,6 +1168,7 @@ class ServingEngine:
         no in-flight dispatch can be reading them while this scatter
         runs."""
         indexer_unsupported(self.cfg, "KV page import (write_pages)")
+        ssm_unsupported(self.cfg, "KV page import (write_pages)")
         idx = jnp.asarray(pids, jnp.int32)
         with self._mesh_ctx():
             kp = self.cache.k_pages.at[:, idx].set(
@@ -1914,6 +1949,7 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
                  tokens, cursor, cache: PagedKVCache,
                  window: Optional[KVWindow], win_len, pbuf, plen, active,
                  temps, stops, budgets, top_k: int, top_p: float, key,
+                 state: Optional[SSMState] = None,
                  use_kernel: bool = False):
     """k chained PACKED mixed iterations in ONE lax.scan: each step,
     every slot is in exactly one phase. A decode slot advances one
@@ -1955,6 +1991,14 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
     model. A model with a sparse-attention indexer adds two: the
     positions a live decode row could attend and the positions it
     read, the mean over the block's layers, rows and steps.
+
+    state (a model with Mamba-2 layers; None for every other): the
+    slots' recurrent state (cache/ssm_state.py). It is read and written
+    by every step, so it rides the CARRY (the pool stays outside it,
+    read-only, as ever) and comes back as the ninth value; `load` then
+    ends in two SUMS over the block's steps: the positions pushed
+    through a recurrence (decode rows and real chunk columns) and the
+    slots that started from zero.
     """
     S = tokens.shape[0]
     H = pbuf.shape[1]
@@ -1971,7 +2015,7 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
     def body(carry, i):
         # kv: the window and its staged counts (the pool, read-only,
         # stays outside the carry), or window off the cache itself
-        cur, cursor, kv, live, rem = carry
+        cur, cursor, kv, live, rem, *st = carry
         pool, win, wlen = (cache, *kv) if windowed else (kv, None, None)
         is_pf = cursor < plen
         # chunk p belongs to the p-th live slot in prefill phase
@@ -1985,9 +2029,10 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
             pbuf[chunk_slot],
             jnp.clip(cursor[chunk_slot][:, None] + ccol, 0, H - 1), axis=1)
         chunk_count = jnp.where(mine.any(axis=1), count[chunk_slot], 0)
-        logits, new, load = fwd(
+        logits, new, load, *st = fwd(
             params, cfg, cur, pool, chunk_tokens, chunk_slot, chunk_count,
-            live & ~is_pf, win, wlen, use_kernel=use_kernel)
+            live & ~is_pf, win, wlen, use_kernel=use_kernel,
+            **({"state": st[0]} if st else {}))
         completing = has_chunk & (cursor + count >= plen)
         nxt = sample_batched(logits, jax.random.fold_in(key, i), temps,
                              top_k, top_p)
@@ -2001,11 +2046,11 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
         live = live & jnp.where(
             emit, (rem > 0) & jnp.where(has_stop, nxt != stops, True),
             True)
-        return (nxt, cursor, kv, live, rem), (nxt, emit, load)
+        return (nxt, cursor, kv, live, rem, *st), (nxt, emit, load)
 
-    (final, cursor, kv, _, _), (block, valid, load) = lax.scan(
+    (final, cursor, kv, _, _, *st), (block, valid, load) = lax.scan(
         body, (tokens, cursor, (window, win_len) if windowed else cache,
-               live, budgets),
+               live, budgets, *(() if state is None else (state,))),
         jnp.arange(k, dtype=jnp.int32))
     if windowed:
         window, win_len = kv
@@ -2022,7 +2067,10 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
         rows = load[:, 3:].sum(axis=0)
         load = jnp.concatenate([experts, rows[1:] / jnp.maximum(rows[0], 1)]) \
             if cfg.has_indexer else experts
-    return block, valid, final, cursor, cache, window, win_len, load
+        if cfg.has_ssm:
+            load = jnp.concatenate([experts, rows])
+    return (block, valid, final, cursor, cache, window, win_len, load,
+            st[0] if st else None)
 
 
 def _mixed_spec_scan(cfg: ModelConfig, fwd, rounds: int, gamma: int,

@@ -72,6 +72,10 @@ class KVCache(NamedTuple):
     v_scale: Optional[jax.Array] = None
     ki: Optional[jax.Array] = None  # [L,B,S,Hi] index keys iff the model
                                     # has a sparse-attention indexer
+    # a model with Mamba-2 layers (cfg.layer_types): k/v hold its
+    # ATTENTION layers only ([La, ...]) and each row's recurrent state
+    # is a slot of `ssm` (cache/ssm_state.py SSMState of B slots)
+    ssm: Optional[Any] = None
 
     @property
     def max_seq(self) -> int:
@@ -86,6 +90,15 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
                dtype: Optional[jnp.dtype] = None,
                quant: str = "none") -> KVCache:
     dtype = dtype or jnp.dtype(cfg.dtype)
+    if cfg.has_ssm:
+        if quant != "none":
+            ssm_unsupported(cfg, "the int8 contiguous KV cache")
+        from butterfly_tpu.cache.ssm_state import init_ssm_state
+        kv = (cfg.num_attn_layers, batch, max_seq, cfg.num_kv_heads,
+              cfg.head_dim)
+        return KVCache(k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype),
+                       length=jnp.zeros((batch,), jnp.int32),
+                       ssm=init_ssm_state(cfg, batch))
     shape = (cfg.num_layers, batch, max_seq, cfg.num_kv_heads, cfg.head_dim)
     ki = jnp.zeros((cfg.num_layers, batch, max_seq, cfg.index_head_dim),
                    dtype) if cfg.has_indexer else None
@@ -309,6 +322,12 @@ def qkv_proj(x: jax.Array, p: Params, cfg: ModelConfig,
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"]["scale"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"]["scale"], cfg.norm_eps)
+    if cfg.attention_multiplier:
+        # the family's score scale in place of H ** -0.5, which every
+        # attend and kernel behind this applies: the queries carry the
+        # ratio of the two
+        q = q * jnp.asarray(cfg.attention_multiplier * cfg.head_dim ** 0.5,
+                            q.dtype)
     if cfg.pos_embedding == "rope":
         if rope is not None:
             cos = jnp.where(rope > 0, cos, 1.0)
@@ -638,8 +657,16 @@ def moe_block(x: jax.Array, p: Params, cfg: ModelConfig,
 
     act = ACTIVATIONS[cfg.act]
     dt = x.dtype
-    g = qeinsum("btd,edf->ebtf", x, p["w_gate"], dt)
-    u = qeinsum("btd,edf->ebtf", x, p["w_up"], dt)
+    # The experts' codes stand FIRST in the gate and up products: the
+    # TPU compiler then reads them as they are stored. Handed the rows
+    # first, at 128, 256 or 384 rows (whole tiles) it wants the codes
+    # contraction-minor and hoists a relayout of the WHOLE layer-stacked
+    # tensor out of the layer scan (2.1 GB each for gate and up of 72
+    # experts in ten layers: a decode block of 128 slots did not fit the
+    # chip). At 32 and 64 rows the two orders run alike (PERF.md, PR 41:
+    # measured in both older cells of experts), so there is one order.
+    g = qeinsum("edf,btd->ebtf", p["w_gate"], x, dt)
+    u = qeinsum("edf,btd->ebtf", p["w_up"], x, dt)
     h = act(g) * u
     y = qeinsum("ebtf,efd->ebtd", h, p["w_down"], dt)
     return jnp.einsum("ebtd,bte->btd", y, comb.astype(y.dtype))
@@ -677,8 +704,174 @@ def ffn_block(h: jax.Array, lp: Params, cfg: ModelConfig,
         if cfg.moe_impl == "ep":   # no early logits here: ModelConfig refuses
             from butterfly_tpu.parallel.expert import moe_block_ep
             return moe_block_ep(h, lp["moe"], cfg)
-        return moe_block(h, lp["moe"], cfg, logits)
+        out = moe_block(h, lp["moe"], cfg, logits)
+        if cfg.shared_intermediate_size:
+            # one shared expert, every token, added unweighted
+            with jax.named_scope("moe_shared"):
+                out = out + mlp_block(h, lp["shared"], cfg)
+        return out
     return mlp_block(h, lp["mlp"], cfg)
+
+
+def residual_add(x: jax.Array, y: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """x + y, a sublayer's output onto the stream; a family with a
+    residual multiplier (Granite) scales the output first."""
+    if cfg.residual_multiplier:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return x + y
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 mixer (cfg.layer_types "mamba"): a layer whose memory of a
+# stream is a FIXED-SIZE recurrent state, not rows that grow.
+#
+#   [z | xBC | dt] = in_proj(h)          widths Di | Dc | Nh, no bias
+#   xBC = silu(conv(xBC))                causal, depthwise, K taps with
+#                                        bias, over the current and the
+#                                        K-1 previous positions
+#   xBC -> x [Nh, Hd], B [G, N], C [G, N]
+#   dt = softplus(dt + dt_bias);  A = -exp(A_log)        (a head)
+#   H_t = exp(dt A) H_{t-1} + dt x_t (outer) B_t         [Hd, N] a head
+#   y_t = H_t C_t + D x_t
+#   out = out_proj(RMSNorm_Di(y * silu(z)) * w)          gate, then ONE norm
+#
+# What a stream keeps between calls is H [Nh, Hd, N] and the conv's last
+# K-1 inputs [K-1, Dc], in cfg.dtype; a call's arithmetic is
+# float32. The pieces below are composed into a layer in ONE place,
+# cache/ssm_state.py advance_packed, which the packed serving step
+# (cache/paged.py) and the contiguous path (forward) both run: a decode
+# row is T == 1, a prefill chunk T == C of which the first `count`
+# columns are real.
+# ---------------------------------------------------------------------------
+
+def ssm_unsupported(cfg: ModelConfig, what: str) -> None:
+    """Refuse a model with Mamba-2 layers on a path that does not carry
+    a recurrent state a stream (it has no pages to hash, export, roll
+    back or shard)."""
+    if cfg.has_ssm:
+        raise NotImplementedError(
+            f"{what} does not carry the recurrent state of a model with "
+            f"Mamba-2 layers ({cfg.num_ssm_layers} of {cfg.num_layers}): "
+            "not supported for this model")
+
+
+def layer_runs(cfg: ModelConfig):
+    """The model's layers as runs of one kind, in the published order:
+    [(kind, first layer, layers in the run, index of the first among the
+    layers of ITS kind)]. Kinds have unlike parameter shapes, so one
+    scan body cannot carry both: each run is one scan over its kind's
+    stack. A model without layer_types is one run of attention."""
+    kinds = cfg.layer_types or ("attention",) * cfg.num_layers
+    runs, seen = [], {"mamba": 0, "attention": 0}
+    for l, kind in enumerate(kinds):
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, l, 1, seen[kind]])
+        seen[kind] += 1
+    return [tuple(r) for r in runs]
+
+
+def layer_at(stack: Params, i, cfg: ModelConfig) -> Params:
+    """Layer i (a traced index) of a layer-stacked tree, in the compute
+    dtype: the slice a scan's xs would hand its body, for a scan that
+    rides the layer's index instead (two stacks of unlike length)."""
+    return jax.tree.map(
+        lambda a: _cast_float(lax.dynamic_index_in_dim(a, i, 0,
+                                                       keepdims=False),
+                              jnp.dtype(cfg.dtype)), stack)
+
+
+@jax.named_scope("ssm_proj")
+def ssm_in_proj(h: jax.Array, mp: Params, cfg: ModelConfig):
+    """(z [B,T,Di], xBC [B,T,Dc], dt [B,T,Nh]) of the normed input."""
+    zxd = qeinsum("btd,dp->btp", h, mp["in_proj"], h.dtype)
+    Di, Dc = cfg.ssm_inner, cfg.ssm_conv_dim
+    return zxd[..., :Di], zxd[..., Di:Di + Dc], zxd[..., Di + Dc:]
+
+
+@jax.named_scope("ssm_conv")
+def ssm_conv(xbc: jax.Array, tail: jax.Array, mp: Params, count):
+    """The causal depthwise conv over a row's stream: xbc [B,T,Dc] the
+    call's inputs, tail [B,K-1,Dc] the K-1 before them. Returns
+    (silu(conv) [B,T,Dc] float32, the tail after the row's first
+    `count` [B] inputs: unchanged where count is 0)."""
+    T = xbc.shape[1]
+    w = mp["conv_w"].astype(jnp.float32)                     # [K, Dc]
+    K = w.shape[0]
+    full = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
+    u = sum(full[:, k:k + T].astype(jnp.float32) * w[k] for k in range(K))
+    u = jax.nn.silu(u + mp["conv_b"].astype(jnp.float32))
+    at = count[:, None] + jnp.arange(K - 1)[None, :]         # [B, K-1]
+    return u, jnp.take_along_axis(full, at[:, :, None], axis=1)
+
+
+@jax.named_scope("ssm_scan")
+def ssm_scan(u: jax.Array, dt: jax.Array, mp: Params, cfg: ModelConfig,
+             state: jax.Array, count):
+    """The selective scan: u [B,T,Dc] float32 (ssm_conv), dt [B,T,Nh]
+    as projected, state [B,Nh,Hd,N] float32 BEFORE the call. A row's
+    state advances through its first `count` [B] positions and no
+    further (a chunk's filler columns, a dead decode row). Returns
+    (y [B,T,Nh,Hd] float32, state after). T == 1 is the one-step
+    recurrence of a decode row; longer rows scan their positions."""
+    B, T = u.shape[:2]
+    Nh, Hd, N, G = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, \
+        cfg.ssm_groups
+    Di = cfg.ssm_inner
+    x = u[..., :Di].reshape(B, T, Nh, Hd)
+    Bm = jnp.repeat(u[..., Di:Di + G * N].reshape(B, T, G, N), Nh // G,
+                    axis=2)                                  # [B,T,Nh,N]
+    Cm = jnp.repeat(u[..., Di + G * N:].reshape(B, T, G, N), Nh // G, axis=2)
+    dt = jax.nn.softplus(dt.astype(jnp.float32)
+                         + mp["dt_bias"].astype(jnp.float32))
+    dA = jnp.exp(dt * -jnp.exp(mp["A_log"].astype(jnp.float32)))  # [B,T,Nh]
+    dtx = dt[..., None] * x                                  # [B,T,Nh,Hd]
+    real = jnp.arange(T)[None, :] < count[:, None]           # [B,T]
+
+    def step(h, t):
+        dA_t, dtx_t, B_t, C_t, real_t = t
+        new = dA_t[:, :, None, None] * h \
+            + dtx_t[..., None] * B_t[:, :, None, :]
+        h = jnp.where(real_t[:, None, None, None], new, h)
+        return h, jnp.sum(h * C_t[:, :, None, :], axis=-1)   # [B,Nh,Hd]
+
+    if T == 1:
+        state, y = step(state, (dA[:, 0], dtx[:, 0], Bm[:, 0], Cm[:, 0],
+                                real[:, 0]))
+        y = y[:, None]
+    else:
+        state, y = lax.scan(step, state, tuple(
+            jnp.moveaxis(a, 1, 0) for a in (dA, dtx, Bm, Cm, real)))
+        y = jnp.moveaxis(y, 0, 1)
+    return y + mp["D"].astype(jnp.float32)[:, None] * x, state
+
+
+@jax.named_scope("ssm_gate")
+def ssm_gate_out(y: jax.Array, z: jax.Array, mp: Params,
+                 cfg: ModelConfig) -> jax.Array:
+    """y [B,T,Nh,Hd] float32 gated by silu(z), ONE RMSNorm over all Di
+    (one group) with a learned weight, then the out-projection."""
+    B, T = y.shape[:2]
+    g = y.reshape(B, T, -1) * jax.nn.silu(z.astype(jnp.float32))
+    g = rms_norm(g, mp["norm"]["scale"], cfg.norm_eps).astype(z.dtype)
+    return qeinsum("bti,id->btd", g, mp["out_proj"], z.dtype)
+
+
+def ffn_close(x: jax.Array, lp: Params, cfg: ModelConfig, route=None,
+              ok=None):
+    """A layer from its mixer's residual on: the feed-forward with its
+    norm and residual. route: early_router_logits' of the layer. Returns
+    (x, load): with `ok` [B,T], the rows that are real, and a model of
+    experts, `load` is what the layer's routing asked of them
+    (expert_load), else None."""
+    h = pre_norm(x, lp["ln2"], cfg)
+    load = None
+    if ok is not None and cfg.is_moe:
+        if route is None:
+            route = router_logits(h, lp["moe"]["router"])
+        load = expert_load(route, cfg.num_experts_per_tok, ok)
+    return residual_add(x, ffn_block(h, lp, cfg, route), cfg), load
 
 
 def transformer_layer(x: jax.Array, lp: Params, cfg: ModelConfig,
@@ -702,8 +895,9 @@ def transformer_layer(x: jax.Array, lp: Params, cfg: ModelConfig,
     attn_out, *rest = attention_block(
         h, lp["attn"], cfg, ck, cv, positions, mask, cos, sin, fresh,
         k_s, v_s, lp.get("pattern"), index, cki)
-    x = x + attn_out
-    x = x + ffn_block(pre_norm(x, lp["ln2"], cfg), lp, cfg, route)
+    x = residual_add(x, attn_out, cfg)
+    x = residual_add(x, ffn_block(pre_norm(x, lp["ln2"], cfg), lp, cfg,
+                                  route), cfg)
     return (x, *rest)
 
 
@@ -779,6 +973,8 @@ def embed_tokens(params: Params, cfg: ModelConfig, tokens: jax.Array,
     B, T = tokens.shape
     compute_dtype = jnp.dtype(cfg.dtype)
     x = params["embed"]["tok"].astype(compute_dtype)[tokens]
+    if cfg.embedding_multiplier:
+        x = x * jnp.asarray(cfg.embedding_multiplier, compute_dtype)
     if cfg.pos_embedding == "learned":
         x = x + params["embed"]["pos"].astype(compute_dtype)[positions]
         cos = sin = jnp.zeros((B, T, cfg.head_dim // 2), jnp.float32)
@@ -831,12 +1027,17 @@ def final_logits(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     else:
         x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
 
-    if cfg.tie_embeddings:
+    if cfg.tie_embeddings and "lm_head" not in params:
         logits = jnp.einsum("btd,vd->btv", x,
                             params["embed"]["tok"].astype(compute_dtype))
     else:
+        # untied, or a tied head held a second time as int8 codes
+        # (quant/int8.py tied_head): a step reads half the bytes
         logits = qeinsum("btd,dv->btv", x, params["lm_head"], compute_dtype)
-    return logits.astype(jnp.float32)
+    logits = logits.astype(jnp.float32)
+    if cfg.logits_scaling:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def decode_attend(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
@@ -958,8 +1159,9 @@ def _decode_layer_body(x, lp, cfg: ModelConfig, cache: KVCache, i,
     q, k, v = qkv_proj(h, lp["attn"], cfg, cos, sin, rope)
     out = decode_attend(q, k, v, ck, cv, start, cfg, k_s, v_s,
                         wk_i, wv_i, wks_i, wvs_i, sliding_window=sw)
-    x = x + attn_output(out, lp["attn"], cfg)
-    x = x + ffn_block(pre_norm(x, lp["ln2"], cfg), lp, cfg, route)
+    x = residual_add(x, attn_output(out, lp["attn"], cfg), cfg)
+    x = residual_add(x, ffn_block(pre_norm(x, lp["ln2"], cfg), lp, cfg,
+                                  route), cfg)
     return x, k, v
 
 
@@ -1065,6 +1267,7 @@ def decode_step_win(params: Params, cfg: ModelConfig, tokens: jax.Array,
     128KB strided slices + concats — measured on v5e, r5 profile).
     """
     indexer_unsupported(cfg, "the write-combined fused generate")
+    ssm_unsupported(cfg, "the write-combined fused generate")
     quant = cache.quantized
     positions = (cache.length + wstep)[:, None]
     x, cos, sin = embed_tokens(params, cfg, tokens, positions)
@@ -1219,6 +1422,67 @@ def _fresh_prefill_forward(params: Params, cfg: ModelConfig,
     return logits, KVCache(pools[0], pools[1], new_len, pools[2], pools[3])
 
 
+def _hybrid_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
+                    cache: KVCache, positions: jax.Array,
+                    last_index: Optional[jax.Array]
+                    ) -> Tuple[jax.Array, KVCache]:
+    """forward for a model with Mamba-2 layers (cfg.layer_types): the
+    layers run as scans over runs of one kind (layer_runs), each run
+    riding its kind's stack by index. cache.k/v hold the attention
+    layers alone. A Mamba layer is the packed serving step's
+    (cache/ssm_state.py advance_packed): the batch's B rows are B chunks
+    of T columns, row b the chunk of slot b of cache.ssm, so a row's
+    state advances by its REAL positions: all T, or last_index + 1
+    where the caller pads its prompts (engine/engine.py), and a row at
+    position 0 starts from zero whatever the recycled buffers hold."""
+    from butterfly_tpu.cache.ssm_state import StateRows, advance_packed
+    B, T = tokens.shape
+    x, cos, sin = embed_tokens(params, cfg, tokens, positions)
+    mask = make_mask(positions, cache.max_seq)
+    count = jnp.full((B,), T, jnp.int32) if last_index is None \
+        else last_index.astype(jnp.int32) + 1
+    rows = StateRows(
+        active=jnp.zeros((0,), bool),
+        ok=(jnp.arange(T)[None, :] < count[:, None]).reshape(-1),
+        chunk_slot=jnp.arange(B), chunk_ok=count > 0, chunk_pos=positions)
+
+    def mamba(carry, idx):
+        x, state = carry
+        l, m = idx
+        x, state, _ = advance_packed(
+            x, layer_at(params["layers"], l, cfg),
+            layer_at(params["mamba"], m, cfg), state, m, rows, cfg)
+        return (x, state), None
+
+    def attention(carry, idx):
+        x, ck, cv = carry
+        l, a = idx
+        lp = layer_at(params["layers"], l, cfg)
+        out, k, v = attention_block(
+            pre_norm(x, lp["ln1"], cfg), layer_at(params["attn"], a, cfg),
+            cfg, lax.dynamic_index_in_dim(ck, a, 0, keepdims=False),
+            lax.dynamic_index_in_dim(cv, a, 0, keepdims=False),
+            positions, mask, cos, sin)
+        x, _ = ffn_close(residual_add(x, out, cfg), lp, cfg)
+        return (x, lax.dynamic_update_index_in_dim(ck, k, a, 0),
+                lax.dynamic_update_index_in_dim(cv, v, a, 0)), None
+
+    k, v, state = cache.k, cache.v, cache.ssm
+    for kind, first, n, at in layer_runs(cfg):
+        idx = (first + jnp.arange(n), at + jnp.arange(n))
+        if kind == "mamba":
+            (x, state), _ = lax.scan(
+                mamba, (x.reshape(B * T, 1, -1), state), idx)
+            x = x.reshape(B, T, -1)
+        else:
+            (x, k, v), _ = lax.scan(attention, (x, k, v), idx)
+    if last_index is not None:
+        x = jnp.take_along_axis(
+            x, last_index[:, None, None].astype(jnp.int32), axis=1)
+    return final_logits(params, cfg, x), KVCache(
+        k, v, cache.length + T, ssm=state)
+
+
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             cache: KVCache, positions: Optional[jax.Array] = None,
             fresh: bool = False,
@@ -1245,6 +1509,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     B, T = tokens.shape
     if positions is None:
         positions = cache.length[:, None] + jnp.arange(T)[None, :]
+    if cfg.has_ssm:
+        return _hybrid_forward(params, cfg, tokens, cache, positions,
+                               last_index)
     # A model with an indexer takes the general path for every shape:
     # the two fast paths below attend before the cache is written, and
     # neither carries the index keys (the serving path, cache/paged.py,
@@ -1284,19 +1551,25 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     def w(k, *shape, std=0.02):
         return (jax.random.normal(k, shape, jnp.float32) * std).astype(pdt)
 
+    # a model with layer_types stacks each KIND's mixer apart, top-level
+    # beside "layers", which keeps what every layer has (norms,
+    # feed-forward); every other model's attention is in "layers"
+    La = cfg.num_attn_layers
+    attn = {
+        "wq": w(next(keys), La, D, Nq, H),
+        "wk": w(next(keys), La, D, Kv, H),
+        "wv": w(next(keys), La, D, Kv, H),
+        "wo": w(next(keys), La, Nq, H, D),
+    }
     layers: Params = {
         "ln1": {"scale": jnp.ones((L, D), pdt)},
         "ln2": {"scale": jnp.ones((L, D), pdt)},
-        "attn": {
-            "wq": w(next(keys), L, D, Nq, H),
-            "wk": w(next(keys), L, D, Kv, H),
-            "wv": w(next(keys), L, D, Kv, H),
-            "wo": w(next(keys), L, Nq, H, D),
-        },
     }
+    if not cfg.layer_types:
+        layers["attn"] = attn
     if cfg.qk_norm:
-        layers["attn"]["q_norm"] = {"scale": jnp.ones((L, H), pdt)}
-        layers["attn"]["k_norm"] = {"scale": jnp.ones((L, H), pdt)}
+        attn["q_norm"] = {"scale": jnp.ones((La, H), pdt)}
+        attn["k_norm"] = {"scale": jnp.ones((La, H), pdt)}
     if cfg.has_indexer:
         Ni, Hi = cfg.index_heads, cfg.index_head_dim
         layers["index"] = {
@@ -1309,9 +1582,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     if cfg.use_bias:
         layers["ln1"]["bias"] = jnp.zeros((L, D), pdt)
         layers["ln2"]["bias"] = jnp.zeros((L, D), pdt)
-        layers["attn"].update(
-            bq=jnp.zeros((L, Nq, H), pdt), bk=jnp.zeros((L, Kv, H), pdt),
-            bv=jnp.zeros((L, Kv, H), pdt), bo=jnp.zeros((L, D), pdt),
+        attn.update(
+            bq=jnp.zeros((La, Nq, H), pdt), bk=jnp.zeros((La, Kv, H), pdt),
+            bv=jnp.zeros((La, Kv, H), pdt), bo=jnp.zeros((La, D), pdt),
         )
     if cfg.is_moe:
         E = cfg.num_experts
@@ -1321,6 +1594,13 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             "w_up": w(next(keys), L, E, D, F),
             "w_down": w(next(keys), L, E, F, D),
         }
+        if cfg.shared_intermediate_size:
+            Fs = cfg.shared_intermediate_size
+            layers["shared"] = {
+                "w_gate": w(next(keys), L, D, Fs),
+                "w_up": w(next(keys), L, D, Fs),
+                "w_down": w(next(keys), L, Fs, D),
+            }
     elif cfg.arch == "gpt2":
         layers["mlp"] = {
             "w_up": w(next(keys), L, D, F), "b_up": jnp.zeros((L, F), pdt),
@@ -1338,6 +1618,21 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         "layers": layers,
         "final_norm": {"scale": jnp.ones((D,), pdt)},
     }
+    if cfg.layer_types:
+        params["attn"] = attn
+    if cfg.has_ssm:
+        Lm, Di, Dc = cfg.num_ssm_layers, cfg.ssm_inner, cfg.ssm_conv_dim
+        Nh = cfg.ssm_heads
+        params["mamba"] = {
+            "in_proj": w(next(keys), Lm, D, Di + Dc + Nh),
+            "conv_w": w(next(keys), Lm, cfg.ssm_conv, Dc, std=0.5),
+            "conv_b": w(next(keys), Lm, Dc),
+            "dt_bias": w(next(keys), Lm, Nh),
+            "A_log": w(next(keys), Lm, Nh),
+            "D": w(next(keys), Lm, Nh, std=1.0),
+            "norm": {"scale": jnp.ones((Lm, Di), pdt)},
+            "out_proj": w(next(keys), Lm, Di, D),
+        }
     if cfg.pos_embedding == "learned":
         params["embed"]["pos"] = w(next(keys), cfg.max_seq_len, D)
     if cfg.arch == "gpt2":

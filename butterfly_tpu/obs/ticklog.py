@@ -42,7 +42,7 @@ import json
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence
 
 #: The tick-phase vocabulary — one name per structural host section of
 #: Scheduler.tick() (docs/serving.md cross-links these to the pipeline
@@ -95,7 +95,8 @@ class TickLog:
                starved_s: Optional[float] = None,
                starved_cause: Optional[str] = None,
                starved_by: Optional[Dict[str, float]] = None,
-               gap_s: float = 0.0, profiled: bool = False) -> None:
+               gap_s: float = 0.0, profiled: bool = False,
+               ssm_load: Optional[Sequence[float]] = None) -> None:
         """Append one tick record (hot path: one dict build + one
         locked append per TICK, never per token). `phases` is copied —
         callers may reuse/zero their accumulator dict.
@@ -109,6 +110,12 @@ class TickLog:
         with a sparse-attention indexer gives two values more,
         `kv_rows_live` and `kv_rows_selected`: the positions a live
         decode row could attend and those it read (null otherwise).
+        `ssm_load` (a model with Mamba-2 layers; null otherwise):
+        [rows, resets, steps] SUMMED over the mixed blocks the tick
+        drained, as `ssm_rows` (positions their steps pushed through a
+        recurrence: decode rows and real chunk columns),
+        `state_resets` (slots that started from a zero state inside
+        them) and `ssm_steps` (the steps those blocks ran).
         The starvation clock (Scheduler._starve): `starved_s`, the
         seconds the device waited for the host before this tick's
         launches, whichever tick the wait began in (0.0 where they
@@ -121,6 +128,7 @@ class TickLog:
         end. `profiled`: a /debug/profile capture was running."""
         touched, rows_max, rows_mean, kv_live, kv_selected = \
             (tuple(expert_load or ()) + (None,) * 5)[:5]
+        ssm_rows, state_resets, ssm_steps = ssm_load or (None,) * 3
         entry = {
             "seq": self._seq,
             "t_wall": time.time(),
@@ -146,6 +154,9 @@ class TickLog:
             "expert_rows_mean": rows_mean,
             "kv_rows_live": kv_live,
             "kv_rows_selected": kv_selected,
+            "ssm_rows": ssm_rows,
+            "state_resets": state_resets,
+            "ssm_steps": ssm_steps,
             "starved_s": starved_s,
             "starved_cause": starved_cause,
             "starved_by": dict(starved_by or {}),

@@ -61,7 +61,10 @@ def maybe_dequant(w: Any, dtype) -> jax.Array:
 
 def qeinsum(spec: str, x: jax.Array, w: Any,
             dtype: Optional[Any] = None) -> jax.Array:
-    """einsum(spec, x, W) for a possibly-quantized right operand W.
+    """einsum(spec, x, W) for a possibly-quantized operand W. W stands
+    second nearly everywhere; it may stand FIRST (qeinsum("edf,btd->ebtf",
+    W, x): the same sum, the operands handed to the product in that
+    order; models/common.py moe_block says where the order matters).
 
     Quantized: contracts x against the raw int8 codes (the int8->dtype
     convert fuses into the dot's operand read — only int8 bytes stream
@@ -72,15 +75,18 @@ def qeinsum(spec: str, x: jax.Array, w: Any,
     an all-ones x surrogate (every dim 1) and the scale — shape algebra
     only; it broadcasts over the batch dims of the real output.
     """
-    dtype = dtype or x.dtype
-    if not is_quantized_leaf(w):
-        if jnp.issubdtype(w.dtype, jnp.floating) and w.dtype != dtype:
-            w = w.astype(dtype)  # master-dtype leaves compute in `dtype`
-        return jnp.einsum(spec, x, w)
-    y = jnp.einsum(spec, x, w["q8"].astype(dtype))
-    ones = jnp.ones((1,) * x.ndim, dtype)
-    s_out = jnp.einsum(spec, ones, w["s"].astype(dtype))
-    return y * s_out
+    ops = [x, w]
+    dtype = dtype or next(o for o in ops if not is_quantized_leaf(o)).dtype
+    ones = None
+    for i, o in enumerate(ops):
+        if is_quantized_leaf(o):
+            ops[i] = o["q8"].astype(dtype)
+            ones = [jnp.ones((1,) * t.ndim, dtype) for t in ops]
+            ones[i] = o["s"].astype(dtype)
+        elif jnp.issubdtype(o.dtype, jnp.floating) and o.dtype != dtype:
+            ops[i] = o.astype(dtype)  # master-dtype leaves compute in `dtype`
+    y = jnp.einsum(spec, *ops)
+    return y if ones is None else y * jnp.einsum(spec, *ones)
 
 
 def _quant(w: jax.Array, axes: Tuple[int, ...], dtype) -> Dict[str, jax.Array]:
@@ -100,7 +106,13 @@ def quantize_int8(params: Params, cfg) -> Params:
       wq/wk/wv [L,D,N,H] -> D;  wo [L,N,H,D] -> (N,H)
       mlp w_gate/w_up [L,D,F] -> D;  w_down [L,F,D] -> F
       moe w_* [L,E,D,F] / [L,E,F,D] -> the D/F contraction axis
-      lm_head [D,V] -> D
+      shared w_* [L,D,F] / [L,F,D] -> as the dense mlp's
+      mamba in_proj [Lm,D,P] -> D;  out_proj [Lm,Di,D] -> Di (the
+        conv, A_log, D, dt_bias and the norm stay float)
+      lm_head [D,V] -> D; a tied head gets one from the embedding
+        (tied_head)
+    A model with layer_types holds its attention stack top-level
+    (params["attn"]), every other under params["layers"].
     Runs as one jit so a large tree quantizes device-side in one program.
     """
 
@@ -109,11 +121,23 @@ def quantize_int8(params: Params, cfg) -> Params:
     @jax.jit
     def go(params):
         layers = dict(params["layers"])
-        attn = dict(layers["attn"])
+        out = dict(params)
+        attn = dict(layers["attn"] if "attn" in layers else params["attn"])
         for k in ("wq", "wk", "wv"):
             attn[k] = _quant(attn[k], (1,), dt)
         attn["wo"] = _quant(attn["wo"], (1, 2), dt)
-        layers["attn"] = attn
+        if "attn" in layers:
+            layers["attn"] = attn
+        else:
+            out["attn"] = attn
+        if "mamba" in params:
+            out["mamba"] = dict(
+                params["mamba"],
+                in_proj=_quant(params["mamba"]["in_proj"], (1,), dt),
+                out_proj=_quant(params["mamba"]["out_proj"], (1,), dt))
+        if "shared" in layers:
+            layers["shared"] = {k: _quant(w, (1,), dt)
+                                for k, w in layers["shared"].items()}
         if "mlp" in layers:
             mlp = dict(layers["mlp"])
             for k in ("w_gate", "w_up"):
@@ -126,13 +150,22 @@ def quantize_int8(params: Params, cfg) -> Params:
             for k in ("w_gate", "w_up", "w_down"):
                 moe[k] = _quant(moe[k], (2,), dt)
             layers["moe"] = moe
-        out = dict(params)
         out["layers"] = layers
         if "lm_head" in params:
             out["lm_head"] = _quant(params["lm_head"], (0,), dt)
+        elif cfg.tie_embeddings:
+            out["lm_head"] = tied_head(params["embed"]["tok"], dt)
         return out
 
     return go(params)
+
+
+def tied_head(tok: jax.Array, dt) -> Dict[str, jax.Array]:
+    """The [D, V] head of an embedding tok [V, D], quantized over D: a
+    tied output head is held a SECOND time, as int8 codes. The
+    embedding stays float (a lookup of a few rows), and the head, which
+    a decode step streams whole, reads half the bytes."""
+    return _quant(tok.T, (0,), dt)
 
 
 def _contraction_axes(path_names) -> Optional[Tuple[int, ...]]:
@@ -147,7 +180,9 @@ def _contraction_axes(path_names) -> Optional[Tuple[int, ...]]:
         return (1, 2)
     if parent == "moe" and name in ("w_gate", "w_up", "w_down"):
         return (2,)
-    if parent == "mlp" and name in ("w_gate", "w_up", "w_down"):
+    if parent in ("mlp", "shared") and name in ("w_gate", "w_up", "w_down"):
+        return (1,)
+    if parent == "mamba" and name in ("in_proj", "out_proj"):
         return (1,)
     if name == "lm_head":
         return (0,)
@@ -273,7 +308,11 @@ def init_params_by_leaf(cfg, key: jax.Array, quant: str = "none",
                 lambda *xs: jnp.concatenate(xs, axis=ax), *ps),
             out_shardings=sharding)
         out.append(join(*parts))
-    return jax.tree_util.tree_unflatten(treedef, out)
+    params = jax.tree_util.tree_unflatten(treedef, out)
+    if quant == "int8" and cfg.tie_embeddings:
+        params["lm_head"] = jax.jit(tied_head, static_argnums=(1,))(
+            params["embed"]["tok"], dt)
+    return params
 
 
 def quant_specs_like(qparams: Params, specs: Params) -> Params:
@@ -284,6 +323,10 @@ def quant_specs_like(qparams: Params, specs: Params) -> Params:
     to 1 by keepdims must not be sharded).
     """
     from jax.sharding import PartitionSpec as P
+    if "lm_head" in qparams and "lm_head" not in specs:
+        # a tied head's second copy (tied_head): the embedding's
+        # [V, D] layout turned to [D, V]
+        specs = {**specs, "lm_head": P(*specs["embed"]["tok"][::-1])}
 
     def rec(qp, sp):
         if is_quantized_leaf(qp):

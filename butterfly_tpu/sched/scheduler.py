@@ -68,6 +68,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from butterfly_tpu.cache.allocator import make_page_allocator
+from butterfly_tpu.cache.ssm_state import state_info
 from butterfly_tpu.engine.serving import (
     LAUNCH_SPAN, ServingEngine, bucket_len, sample_batched)
 from butterfly_tpu.obs.registry import (
@@ -694,6 +695,19 @@ class Scheduler:
         # [touched, rows_max, rows_mean] a block. Empty for a dense
         # model. The gauges hold the newest block's.
         self._tick_expert_loads: List = []
+        # a model with Mamba-2 layers: the same vector ends in the
+        # block's recurrence rows and state resets (sums over its
+        # steps); the tick record holds their sums over the blocks it
+        # drained, with the steps those ran. None for every other model
+        self._has_ssm = engine.cfg.has_ssm
+        self._tick_ssm: Optional[List[float]] = None
+        self._g_ssm_state_bytes = reg.gauge(
+            "ssm_state_bytes",
+            "Bytes of recurrent state the slots hold for a model with "
+            "Mamba-2 layers (cache/ssm_state.py): fixed, whatever the "
+            "streams' lengths; 0 for a model without")
+        self._g_ssm_state_bytes.set(float(
+            (state_info(engine.cfg, engine.num_slots) or {}).get("bytes", 0)))
         self._g_experts_touched = reg.gauge(
             "moe_experts_touched",
             "Distinct experts that the rows of one step touched in one "
@@ -1107,6 +1121,7 @@ class Scheduler:
         self._tick_fetch = 0.0
         self._tick_overlapped = None
         self._tick_expert_loads = []
+        self._tick_ssm = None
         blocks0 = self.engine.blocks_launched
         with TraceAnnotation("bf.tick", seq=self.ticklog.next_seq,
                              batch=len(self.running),
@@ -1256,6 +1271,7 @@ class Scheduler:
         load = [float(sum(v[i] for v in loads)) / len(loads)
                 for i in range(len(loads[0]))] if loads else None
         self.ticklog.record(wall, tp, fetch_s=fetch, expert_load=load,
+                            ssm_load=self._tick_ssm,
                             overlapped=self._tick_overlapped,
                             inflight=len(self._inflight),
                             barrier_causes=self._tick_causes,
@@ -2422,6 +2438,13 @@ class Scheduler:
             # lane emits at most one token per step, valid only on
             # decode steps and the completion step's first token
             rows, ok, *load = vals if kind == "mixed" else (vals, None)
+            if load and self._has_ssm:
+                # [.., rows, resets] summed over the block's steps
+                ssm = self._tick_ssm = self._tick_ssm or [0.0, 0.0, 0]
+                ssm[0] += float(load[0][3])
+                ssm[1] += float(load[0][4])
+                ssm[2] += len(rows)
+                load = [load[0][:3]]
             if load and load[0][2] > 0:    # some step had a real row
                 self._tick_expert_loads.append(load[0])
                 self._g_experts_touched.set(float(load[0][0]))

@@ -32,7 +32,10 @@ zero-egress environment):
   site that wanted a kernel and took the dense path}, "allocator"
   (native | python) and "pool_layout" (head | token: a page of the KV
   pool holds a row a KV head, or, for a model with a sparse-attention
-  indexer, a row a token). 503 with a detail string when wedged.
+  indexer, a row a token) and "state" ({layers, bytes_per_slot, dtype,
+  bytes}: the recurrent state a slot keeps for a model with Mamba-2
+  layers, cache/ssm_state.py; null for every other model). 503 with a
+  detail string when wedged.
 * GET /kv/pages?hashes=h1,h2,...   export registered prefix-cache KV
   pages by chain hash (fleet/kvtransfer.py payload: base64 page bytes +
   geometry; the leading registered run ships, the rest come back
@@ -149,11 +152,13 @@ def runtime_report(sched) -> dict:
     got and which layout the page pool has (cache/paged.py pool_row).
     Static for the life of the process."""
     from butterfly_tpu.cache.paged import pool_layout
+    from butterfly_tpu.cache.ssm_state import state_info
     from butterfly_tpu.core.mesh import device_report
     native = type(sched.alloc).__name__ == "NativePageAllocator"
     return {"device": device_report(),
             "allocator": "native" if native else "python",
-            "pool_layout": pool_layout(sched.engine.cfg)}
+            "pool_layout": pool_layout(sched.engine.cfg),
+            "state": state_info(sched.engine.cfg, sched.engine.num_slots)}
 
 
 def device_memory() -> list:
@@ -653,6 +658,7 @@ def make_handler(state: ServerState):
                                        "memory": device_memory()},
                             "allocator": state.runtime["allocator"],
                             "pool_layout": state.runtime["pool_layout"],
+                            "state": state.runtime["state"],
                             # programs compiled since the ready line:
                             # each one stalled a tick of live serving
                             "compiles_after_ready": int(
@@ -1432,8 +1438,11 @@ def run_server(args) -> int:
           f"x{rt.page_size}tok{mesh_desc}; platform={dev['platform']} "
           f"device_kind={dev['kind']!r} devices={dev['count']} "
           f"kernels={engine.kernel_mode} allocator={rep['allocator']} "
-          f"pool={rep['pool_layout']})",
-          flush=True)
+          f"pool={rep['pool_layout']}"
+          + ("" if rep["state"] is None else " state: " + json.dumps(
+              {k: rep["state"][k]
+               for k in ("layers", "bytes_per_slot", "dtype")}))
+          + ")", flush=True)
     # SIGTERM ends serving the way Ctrl-C does: serve_forever returns,
     # and the exit code says whether serving was wedged
     import signal

@@ -1,0 +1,19 @@
+"""Cache manager: the positions one step pushed through a recurrence
+(/debug/ticks: `ssm_rows` over `ssm_steps`, the sums over the mixed
+blocks a tick drained: a live decode row counts one, a prefill chunk its
+real columns, filler columns and idle rows none; counted on the device
+and fetched with the blocks' tokens), over the ticks of the window that
+drained a block. Beside `slot_occupancy` it says that decode rows AND
+chunks reach the state: live slots plus the chunk's columns. None on a
+program whose tick records hold no such count (a model without Mamba
+layers, or a program older than the counter)."""
+from servebench.spans import ticks_in_window
+
+
+def read(ctx):
+    ticks = [t for t in ticks_in_window(ctx)
+             if t.get("ssm_rows") is not None and t.get("ssm_steps")]
+    if not ticks:
+        return None
+    return sum(t["ssm_rows"] for t in ticks) \
+        / sum(t["ssm_steps"] for t in ticks)

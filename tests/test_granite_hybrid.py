@@ -1,0 +1,779 @@
+"""granite-4.0-h-small's family on the CPU at a toy's size with every
+mechanism present: both layer kinds, a conv of 4, heads x head_dim x
+state, 8 experts with a shared one, the four multipliers, a tied head.
+LOGITS (and states) against the plain float32 reference
+(models/granite_hybrid_f32.py), which shares no code with the program."""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from butterfly_tpu.cache.paged import (
+    flush_paged_window, init_kv_window, init_paged_cache,
+    paged_forward_packed)
+from butterfly_tpu.cache.ssm_state import (
+    bytes_per_slot, init_ssm_state, reset_slots, state_info)
+from butterfly_tpu.core.config import (
+    PRESETS, ModelConfig, RuntimeConfig, granite_4_h_small, tiny)
+from butterfly_tpu.models import granite_hybrid_f32 as ref
+from butterfly_tpu.models.common import (
+    Model, forward, init_cache, layer_runs)
+from butterfly_tpu.quant.int8 import (
+    init_params_by_leaf, is_quantized_leaf, quantize_int8)
+
+#: an eager lax.scan compiles its body at every call (its closures are
+#: new functions each time): the tests drive jitted programs, one compile
+#: a shape, as the engines do
+forward = jax.jit(forward, static_argnums=(1,))
+_packed_step = jax.jit(
+    lambda params, cfg, *a, state: paged_forward_packed(
+        params, cfg, *a, state=state), static_argnums=(1,))
+
+CFG = tiny("granite_hybrid", dtype="float32", param_dtype="float32")
+#: no attention layer at all: the pool holds no layer, and the paths
+#: that flush and read pages still work
+ALL_MAMBA = CFG.replace(num_layers=2, layer_types=("mamba", "mamba"))
+T, C = 40, 6
+#: rms difference over the standard deviation of the reference's logits
+#: at the position. float32 on both sides on the CPU reads 1e-7 to 1e-6
+#: (sums in another order); a bfloat16 program reads 1e-2, a term left
+#: out or a state leaked reads 1e-1 and more
+TOL = 2e-5
+
+
+def file_config(cfg: ModelConfig) -> dict:
+    """The published keys the reference reads, as a configuration file
+    of `cfg` would hold them."""
+    return dict(
+        rms_norm_eps=cfg.norm_eps, num_hidden_layers=cfg.num_layers,
+        layer_types=list(cfg.layer_types),
+        num_local_experts=cfg.num_experts,
+        num_experts_per_tok=cfg.num_experts_per_tok,
+        mamba_n_heads=cfg.ssm_heads, mamba_d_head=cfg.ssm_head_dim,
+        mamba_d_state=cfg.ssm_state, mamba_n_groups=cfg.ssm_groups,
+        mamba_d_conv=cfg.ssm_conv,
+        embedding_multiplier=cfg.embedding_multiplier,
+        residual_multiplier=cfg.residual_multiplier,
+        attention_multiplier=cfg.attention_multiplier,
+        logits_scaling=cfg.logits_scaling)
+
+
+def leaf_of(params):
+    def leaf(path, layer=None):
+        node = params
+        for key in path.split("/"):
+            node = node[key]
+        if is_quantized_leaf(node):
+            q8, s = node["q8"], node["s"]
+            if layer is not None:
+                q8, s = q8[layer], s[layer]
+            return q8.astype(jnp.float32) * s.astype(jnp.float32)
+        return (node if layer is None else node[layer]).astype(jnp.float32)
+    return leaf
+
+
+def seeded_params(cfg=CFG):
+    p = Model(cfg).init(jax.random.PRNGKey(0))
+    # norms that are not all ones, so that a norm put in the wrong place
+    # shows; mixers loud enough to move the stream off the embedding
+    # (a tied head over an untouched stream repeats its token); decays
+    # slow enough that a state is remembered for tens of positions
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 8))
+
+    def jitter(a):
+        return 1 + 0.3 * jax.random.normal(next(keys), a.shape)
+
+    for g in (p["layers"]["ln1"], p["layers"]["ln2"], p["mamba"]["norm"],
+              p["final_norm"]):
+        g["scale"] = jitter(g["scale"])
+    p["mamba"]["out_proj"] = p["mamba"]["out_proj"] * 40
+    p["mamba"]["in_proj"] = p["mamba"]["in_proj"] * 10
+    p["mamba"]["A_log"] = p["mamba"]["A_log"] - 2.0
+    if "wo" in p["attn"] and p["attn"]["wo"].shape[0]:
+        p["attn"]["wo"] = p["attn"]["wo"] * 40
+        p["attn"]["wq"] = p["attn"]["wq"] * 20
+        p["attn"]["wk"] = p["attn"]["wk"] * 20
+    p["layers"]["moe"]["w_down"] = p["layers"]["moe"]["w_down"] * 40
+    p["layers"]["shared"]["w_down"] = p["layers"]["shared"]["w_down"] * 40
+    return p
+
+
+@pytest.fixture(scope="module")
+def params():
+    return seeded_params()
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return np.random.RandomState(3).randint(1, CFG.vocab_size, (3, T))
+
+
+def reference(params, tokens, cfg=CFG, states=None):
+    return np.asarray(ref.logits(np.asarray(tokens), leaf_of(params),
+                                 file_config(cfg), states=states))
+
+
+@pytest.fixture(scope="module")
+def want(params, tokens):
+    """The reference's full forward of the three sequences: [3, T, V]."""
+    return np.stack([reference(params, t) for t in tokens])
+
+
+def err(got, want):
+    """rms difference over the std of the reference's row."""
+    d = np.asarray(got, np.float64) - want
+    return float(np.sqrt(np.mean(d * d)) / np.std(want))
+
+
+# -- the contiguous cache ---------------------------------------------------
+
+def test_the_reference_is_not_trivial(want, tokens):
+    """The logits move with the context: a tied head over a stream the
+    mixers never touched would put each token's own id on top."""
+    top = want.argmax(-1)
+    assert np.mean(top == tokens) < 0.5
+    assert len(np.unique(top)) > 10
+
+
+def test_contiguous_forward_whole(params, tokens, want):
+    got, cache = forward(params, CFG, jnp.asarray(tokens),
+                         init_cache(CFG, 3, 64))
+    for s in range(3):
+        for t in range(T):
+            assert err(got[s, t], want[s, t]) < TOL, (s, t)
+    assert cache.k.shape[0] == 1 and cache.ssm.h.shape[0] == 3
+
+
+def test_prefill_then_decode_through_the_cache_and_the_state(params, tokens,
+                                                             want):
+    """A padded prefill (the engine's last_index contract: 12 real
+    tokens in a bucket of 16, the state advanced by 12 and no further),
+    then decode calls of one token through the cache and the state."""
+    cache = init_cache(CFG, 3, 64)
+    padded = np.zeros((3, 16), np.int32)
+    padded[:, :12] = tokens[:, :12]
+    got, cache = forward(params, CFG, jnp.asarray(padded), cache,
+                         last_index=jnp.full((3,), 11))
+    cache = cache._replace(length=jnp.full((3,), 12, jnp.int32))
+    rows = [got]
+    for t in range(12, T):
+        got, cache = forward(params, CFG, jnp.asarray(tokens[:, t:t + 1]),
+                             cache)
+        rows.append(got)
+    got = jnp.concatenate(rows, axis=1)
+    for s in range(3):
+        for i, t in enumerate(range(11, T)):
+            assert err(got[s, i], want[s, t]) < TOL, (s, t)
+
+
+# -- the packed mixed step ----------------------------------------------------
+
+RT = RuntimeConfig(max_batch_size=3, max_seq_len=64, page_size=4)
+
+
+class Packed:
+    """What engine._packed_scan does around one packed step, by hand:
+    three slots with a page-table row each, the KV window and its flush
+    every third step, the recurrent state through every step."""
+
+    def __init__(self, params, cfg=CFG, windowed=True, width=C):
+        self.params, self.cfg, self.C = params, cfg, width
+        cache = init_paged_cache(cfg, RT)
+        S, mp = cache.page_table.shape
+        self.cache = cache._replace(page_table=jnp.arange(
+            S * mp, dtype=jnp.int32).reshape(S, mp))
+        self.window = init_kv_window(self.cache, 3 * width) \
+            if windowed else None
+        self.wlen = jnp.zeros((S,), jnp.int32) if windowed else None
+        self.state = init_ssm_state(cfg, S)
+        self.steps, self.loads = 0, []
+
+    def flush(self):
+        if self.window is not None:
+            self.cache, self.wlen, _ = flush_paged_window(
+                self.cache, self.window, self.wlen)
+
+    def restart(self, slot):
+        """A slot's next tenant: what Scheduler._seed_mixed_slot edits
+        (lengths and staged count at zero) and nothing of the state."""
+        self.flush()
+        self.cache = self.cache._replace(
+            lengths=self.cache.lengths.at[slot].set(0))
+
+    def step(self, decode: dict, chunk=None):
+        """decode {slot: token}; chunk (slot, tokens up to C) or None.
+        Returns {slot: logits [V]} of the rows the head read."""
+        S = self.cache.num_slots
+        if self.steps % 3 == 0:
+            self.flush()
+        self.steps += 1
+        toks, active = np.zeros((S,), np.int32), np.zeros((S,), bool)
+        for s, t in decode.items():
+            toks[s], active[s] = t, True
+        ctok, cslot, count = np.zeros((1, self.C), np.int32), 0, 0
+        if chunk is not None:
+            cslot, count = chunk[0], len(chunk[1])
+            ctok[0, :count] = chunk[1]
+        logits, kv, load, self.state = _packed_step(
+            self.params, self.cfg, jnp.asarray(toks), self.cache,
+            jnp.asarray(ctok), jnp.asarray([cslot]), jnp.asarray([count]),
+            jnp.asarray(active), self.window, self.wlen, state=self.state)
+        adv = jnp.asarray(active, jnp.int32).at[cslot].add(count)
+        if self.window is not None:
+            self.window, self.wlen = kv, self.wlen + adv
+        else:
+            self.cache = kv._replace(lengths=self.cache.lengths + adv)
+        self.loads.append(np.asarray(load))
+        heads = dict(decode)
+        if count:
+            heads[cslot] = None
+        return {s: np.asarray(logits[s]) for s in heads}
+
+
+def scripted_run(params, tokens, windowed=True, cfg=CFG):
+    """Slot 1 takes sequence 1's first 20 tokens in chunks of 6 (the
+    last holds 2 and 4 of filler) and decodes to position 30 while slot
+    0 takes sequence 0's first 15 (6, 6, 3) and decodes beside it; then
+    slot 1's stream ends and the slot is given to sequence 2 from
+    position 0 while slot 0 decodes on. Slot 2 never holds a stream.
+    Returns ([(sequence, position, logits)], the driver, {slot:
+    (sequence, tokens it has seen)})."""
+    drv, out = Packed(params, cfg, windowed), []
+    at = {0: 0, 1: 0}                       # positions fed, by slot
+    seq = {0: 0, 1: 1}
+
+    def feed(decode_slots, chunk_slot=None, n=0):
+        decode = {s: tokens[seq[s], at[s]] for s in decode_slots}
+        chunk = None if chunk_slot is None else (
+            chunk_slot, tokens[seq[chunk_slot],
+                               at[chunk_slot]:at[chunk_slot] + n])
+        got = drv.step(decode, chunk)
+        for s in decode_slots:
+            at[s] += 1
+        if chunk_slot is not None:
+            at[chunk_slot] += n
+        out.extend((seq[s], at[s] - 1, row) for s, row in got.items())
+
+    for n in (6, 6, 6, 2):
+        feed([], 1, n)
+    for n in (6, 6, 3):
+        feed([1], 0, n)
+    while at[1] < 30:
+        feed([0, 1])
+    drv.restart(1)
+    seq[1], at[1] = 2, 0
+    for n in (6, 6, 5):
+        feed([0], 1, n)
+    for _ in range(4):
+        feed([0, 1])
+    return out, drv, {s: (seq[s], at[s]) for s in at}
+
+
+@pytest.fixture(scope="module")
+def scripted(params, tokens):
+    """The scripted run through the window and its flush, once."""
+    return scripted_run(params, tokens)
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["pool", "window"])
+def test_packed_steps_chunks_filler_decode_rows_and_a_reused_slot(
+        params, tokens, want, windowed, scripted):
+    out, drv, _ = scripted if windowed \
+        else scripted_run(params, tokens, windowed)
+    assert len(out) > 30
+    assert {s for s, _, _ in out} == {0, 1, 2}
+    for s, pos, row in out:
+        assert err(row, want[s, pos]) < TOL, (s, pos)
+    # the load's last two: positions pushed through a recurrence, and
+    # the slots that started from zero (three streams began)
+    loads = np.stack(drv.loads)
+    assert loads.shape[1] == 5 and loads[0, 3] == 6 and loads[3, 3] == 2
+    assert loads[4, 3] == 1 + 6 and loads[:, 4].sum() == 3
+    assert loads[:, 0].max() <= CFG.num_experts
+
+
+def test_the_recurrence_is_tied_to_the_scan(params, tokens, scripted):
+    """The two ways a state leaks, neither of which shows in tokens
+    with random weights. (1) After a prompt fed as chunks of C, the
+    last partly filler, then decode steps, each slot's state IS the
+    state the reference's position-by-position loop holds after the
+    same tokens, to float32 rounding: filler columns advanced nothing.
+    (2) A slot given to a second stream holds that stream's state and
+    nothing of the first's (its logits are a fresh server's:
+    test_packed_steps_...); a slot that never held a stream is zero."""
+    _, drv, seen = scripted
+    for slot, (s, n) in seen.items():
+        held = []
+        reference(params, tokens[s, :n], states=held)
+        assert len(held) == CFG.num_ssm_layers
+        for m, (H, tail) in enumerate(held):
+            got_h = np.asarray(drv.state.h[m, slot])
+            got_t = np.asarray(drv.state.conv[m, :, slot])
+            scale = np.abs(np.asarray(H)).max()
+            assert scale > 1e-3         # a state worth comparing
+            assert np.abs(got_h - H).max() < 1e-5 * scale, (slot, m)
+            assert np.abs(got_t - tail).max() < 1e-5 * np.abs(tail).max()
+    assert not np.asarray(drv.state.h[:, 2]).any()
+    assert not np.asarray(drv.state.conv[:, :, 2]).any()
+
+
+def test_chunked_prefill_equals_one_shot(params, tokens):
+    """A prompt of 17 as chunks of 6 (6, 6, 5) and as one chunk of 32
+    with 15 of filler: the same logits and the same state."""
+    a, b = Packed(params), Packed(params, width=32)
+    for lo in (0, 6, 12):
+        got_a = a.step({}, (0, tokens[0, lo:min(lo + 6, 17)]))
+    got_b = b.step({}, (0, tokens[0, :17]))
+    assert err(got_a[0], got_b[0]) < TOL
+    ha, hb = np.asarray(a.state.h[:, 0]), np.asarray(b.state.h[:, 0])
+    assert np.abs(ha - hb).max() < 1e-5 * np.abs(hb).max()
+    np.testing.assert_allclose(np.asarray(a.state.conv[:, :, 0]),
+                               np.asarray(b.state.conv[:, :, 0]), atol=1e-5)
+
+
+def test_a_model_of_mamba_layers_only_has_a_pool_of_no_layer(tokens):
+    """No attention layer: the pool and the window hold no layer, the
+    table, the lengths and the flush work all the same."""
+    p = seeded_params(ALL_MAMBA)
+    want = reference(p, tokens[0], ALL_MAMBA)
+    drv = Packed(p, ALL_MAMBA)
+    assert drv.cache.k_pages.shape[0] == drv.window.k.shape[0] == 0
+    for lo in (0, 6, 12):
+        got = drv.step({}, (0, tokens[0, lo:lo + 6]))
+        assert err(got[0], want[lo + 5]) < TOL
+    for t in range(18, 26):
+        got = drv.step({0: tokens[0, t]})
+        assert err(got[0], want[t]) < TOL
+    drv.flush()
+    assert int(drv.cache.lengths[0]) == 26
+
+
+# -- precisions ---------------------------------------------------------------
+
+def test_a_bfloat16_program_fails_the_limit_float32_passes(params, tokens,
+                                                          want):
+    """The limit of these tests tells the precision below: the same
+    weights run in bfloat16 (state kept in bfloat16 between calls, as
+    the benchmark's configuration keeps it) read a thousand times the
+    float32 program's error."""
+    cfg = CFG.replace(dtype="bfloat16")
+    p = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
+    cache = init_cache(cfg, 1, 64)
+    assert cache.ssm.h.dtype == jnp.bfloat16
+    got, cache = forward(p, cfg, jnp.asarray(tokens[:1, :12]), cache)
+    rows = [got[0, -1]]
+    for t in range(12, 20):
+        got, cache = forward(p, cfg, jnp.asarray(tokens[:1, t:t + 1]), cache)
+        rows.append(got[0, 0])
+    errs = [err(r, want[0, 11 + i]) for i, r in enumerate(rows)]
+    assert min(errs) > 50 * TOL
+    assert max(errs) < 0.2          # and it is the same model
+
+
+def test_int8_weights_quantize_the_projections_and_nothing_delicate(tokens):
+    """Weight-only codes for in_proj, out_proj, the experts, the shared
+    expert, the attention and a second copy of the tied head; the conv,
+    A_log, D, dt_bias, the norms, the router and the embedding stay
+    float. Against the reference over the SAME codes times scales the
+    program differs by float32 rounding."""
+    p = quantize_int8(seeded_params(), CFG)
+    q = {k for k, v in p["mamba"].items() if is_quantized_leaf(v)}
+    assert q == {"in_proj", "out_proj"}
+    assert all(is_quantized_leaf(v) for v in p["layers"]["shared"].values())
+    assert all(is_quantized_leaf(v) for v in p["attn"].values())
+    assert not is_quantized_leaf(p["layers"]["moe"]["router"])
+    assert not is_quantized_leaf(p["embed"]["tok"])
+    assert is_quantized_leaf(p["lm_head"])
+    assert p["lm_head"]["q8"].shape == (CFG.hidden_size, CFG.vocab_size)
+    got, _ = forward(p, CFG, jnp.asarray(tokens[:1]), init_cache(CFG, 1, 64))
+    # the head's codes are the program's alone (the reference reads the
+    # embedding): compare below the head, through a reference that is
+    # given the head's own dequantized rows as its embedding for the
+    # product
+    plain = dict(p)
+    head = plain.pop("lm_head")
+    got_plain, _ = forward(plain, CFG, jnp.asarray(tokens[:1]),
+                           init_cache(CFG, 1, 64))
+    want = reference(plain, tokens[0])
+    for t in range(T):
+        assert err(got_plain[0, t], want[t]) < TOL, t
+        # the int8 head: one rounding of 1/127 a channel
+        assert err(got[0, t], want[t]) < 0.02, t
+
+
+def test_the_expert_products_take_their_weights_first():
+    """moe_block writes the gate and up products weights first, which
+    the TPU compiler reads as stored where the other order, at 128 rows
+    (this model's decode block: 128 slots), made it relay out the whole
+    stacked tensor (models/common.py moe_block); the sum is the same
+    one, for float and for int8 leaves, whichever operand stands first,
+    and a step of 128 rows is its two halves of 64."""
+    from butterfly_tpu.models.common import moe_block
+    from butterfly_tpu.quant.int8 import qeinsum
+    p = seeded_params()
+    x = jax.random.normal(jax.random.PRNGKey(2), (128, 1, CFG.hidden_size))
+    for params in (p, quantize_int8(p, CFG)):
+        moe = jax.tree.map(lambda a: a[0], params["layers"]["moe"])
+        whole = moe_block(x, moe, CFG)
+        halves = jnp.concatenate([moe_block(x[:64], moe, CFG),
+                                  moe_block(x[64:], moe, CFG)])
+        assert np.abs(np.asarray(whole - halves)).max() \
+            < 1e-5 * np.abs(np.asarray(halves)).max()
+        a = qeinsum("btd,edf->ebtf", x, moe["w_up"], jnp.float32)
+        b = qeinsum("edf,btd->ebtf", moe["w_up"], x, jnp.float32)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-6)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e chip (no chip attached: the TPU's compiler is
+    installed here); skipped where none can be described. The library
+    reads where to log when it loads: told not to, for the module's
+    tests only."""
+    import os
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    env = pytest.MonkeyPatch()
+    if "TPU_LOG_DIR" not in os.environ:
+        env.setenv("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        env.undo()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    env.undo()
+
+
+@pytest.mark.parametrize("rows", [128, 160])
+def test_the_expert_products_compile_for_the_chip_without_a_relayout(
+        one_chip, rows):
+    """The published widths, compiled for the TPU: ten layers of 72 int8
+    experts ride a layer scan at 128 rows (the decode block) and at 160
+    (the mixed block) with no copy of a stacked tensor among the
+    temporaries. Rows first, at 128 rows this compile holds 4.5 GB of them
+    (two tensors of 2.1 GB), and the whole decode block 16.5 GB of a
+    chip's 15.75."""
+    from butterfly_tpu.models.common import moe_block
+    cfg = granite_4_h_small().replace(num_layers=10)
+    L, E, D, F = 10, cfg.num_experts, cfg.hidden_size, cfg.intermediate_size
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def codes(*shape):
+        return {"q8": sds((L, E) + shape, jnp.int8),
+                "s": sds((L, E, 1, shape[-1]), jnp.bfloat16)}
+
+    moe = {"router": sds((L, D, E), jnp.bfloat16), "w_gate": codes(D, F),
+           "w_up": codes(D, F), "w_down": codes(F, D)}
+
+    def prog(x, moe):
+        def layer(x, i):
+            lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, i, 0, keepdims=False), moe)
+            return x + moe_block(x, lp, cfg), None
+
+        def step(x, _):         # a block is steps of layers
+            return jax.lax.scan(layer, x, jnp.arange(L))[0], None
+        return jax.lax.scan(step, x, jnp.arange(4))[0]
+
+    try:
+        with jax.disable_jit(False):
+            compiled = jax.jit(prog).lower(
+                sds((rows, 1, D), jnp.bfloat16), moe).compile()
+    except Exception as e:  # the TPU library is one process's at a time
+        pytest.skip(f"the TPU compiler could not be used here: {e}")
+    assert compiled.memory_analysis().temp_size_in_bytes < 256e6
+
+
+def test_weights_built_leaf_by_leaf_have_the_same_tree():
+    """cli.load_params' path (no checkpoint): every leaf born in its
+    final form, the tied head's codes beside the embedding."""
+    cfg = CFG.replace(dtype="bfloat16")
+    p = init_params_by_leaf(cfg, jax.random.PRNGKey(0), quant="int8")
+    q = quantize_int8(Model(cfg).init(jax.random.PRNGKey(0)), cfg)
+    assert jax.tree.structure(p) == jax.tree.structure(q)
+    assert jax.tree.map(lambda a: a.shape, p) == \
+        jax.tree.map(lambda a: a.shape, q)
+    deq = np.asarray(p["lm_head"]["q8"], np.float32) \
+        * np.asarray(p["lm_head"]["s"], np.float32)
+    tok = np.asarray(p["embed"]["tok"], np.float32).T
+    assert np.abs(deq - tok).max() < np.asarray(p["lm_head"]["s"],
+                                                np.float32).max()
+
+
+# -- what cannot take the state refuses the model by name ---------------------
+
+def _engine(**rt):
+    from butterfly_tpu.engine.serving import ServingEngine
+    mesh = rt.pop("mesh", None)
+    return ServingEngine(Model(CFG), seeded_params(), RuntimeConfig(
+        max_batch_size=2, max_seq_len=64, page_size=4, **rt), mesh=mesh)
+
+
+def _mesh(axis):
+    from jax.sharding import Mesh
+    return Mesh(np.asarray(jax.devices()[:2]), (axis,))
+
+
+def _stages():
+    from butterfly_tpu.parallel.pipeline import paged_pipeline_packed
+    paged_pipeline_packed(None, CFG, None, None, None, None, None, None,
+                          mesh=_mesh("stage"))
+
+
+def _seq_parallel():
+    from butterfly_tpu.parallel.sequence import sp_forward
+    sp_forward(None, CFG, jnp.zeros((1, 8), jnp.int32), _mesh("seq"))
+
+
+def _fused_generate():
+    from butterfly_tpu.models.common import decode_step_win
+    decode_step_win(None, CFG, None, None, [], 0)
+
+
+REFUSALS = {
+    "prefix caching": lambda: _engine(prefix_caching=True),
+    "host KV tier": lambda: _engine(prefix_caching=True, host_kv_tier_mb=1),
+    "export": lambda: _engine().read_pages([0]),
+    "import": lambda: _engine().write_pages([0], None, None),
+    "pipeline serving": lambda: _engine(mesh=_mesh("stage")),
+    "pipeline": _stages,
+    "sequence-parallel prefill lane": lambda: _engine(mesh=_mesh("seq")),
+    "sequence parallelism": _seq_parallel,
+    "tensor parallelism": lambda: _engine(mesh=_mesh("tensor")),
+    "speculative": lambda: _engine(speculative_gamma=2),
+    "alternating prefill/decode path": lambda: _engine(mixed_dispatch=False),
+    "paged_forward": lambda: _engine().prefill_slot(0, [1, 2, 3]),
+    "static scheduler": lambda: _engine(scheduler="static"),
+    "int8 contiguous KV cache": lambda: init_cache(CFG, 1, 16, quant="int8"),
+    "write-combined fused generate": _fused_generate,
+}
+
+
+@pytest.mark.parametrize("what", list(REFUSALS))
+def test_refused_by_name(what):
+    with pytest.raises(NotImplementedError, match=what) as e:
+        REFUSALS[what]()
+    assert "recurrent state" in str(e.value)
+    assert "Mamba-2" in str(e.value)
+
+
+# -- through the scheduler: the server's own path -----------------------------
+
+def served(params, prompts, new, cfg=CFG, together=False, **rt):
+    from butterfly_tpu.engine.serving import ServingEngine
+    from butterfly_tpu.sched.scheduler import Scheduler
+    rt = RuntimeConfig(**{**dict(max_batch_size=2, max_seq_len=64,
+                                 page_size=4, decode_steps_per_tick=2,
+                                 prefill_inline_budget=8), **rt})
+    sched = Scheduler(ServingEngine(Model(cfg), params, rt), seed=0)
+    reqs = [sched.submit(prompts[0], max_new_tokens=new[0])]
+    for _ in range(0 if together else 2):
+        sched.tick()
+    reqs += [sched.submit(p, max_new_tokens=n)
+             for p, n in zip(prompts[1:], new[1:])]
+    sched.run_until_done()
+    return sched, reqs
+
+
+def greedy_of_the_reference(params, prompt, output, cfg=CFG):
+    """Every served token is the argmax of the reference's logits over
+    the tokens before it, by a margin a rounding cannot close (the
+    reference is causal: one forward of prompt + output holds every
+    such row)."""
+    seq = list(prompt) + list(output)
+    rows = reference(params, seq, cfg)
+    for i, tok in enumerate(output):
+        row = rows[len(prompt) + i - 1]
+        order = np.argsort(row)
+        assert row[order[-1]] - row[order[-2]] > 1e-4 * np.std(row), i
+        assert tok == order[-1], i
+
+
+def test_served_tokens_slot_reuse_and_a_recomputed_preemption(params,
+                                                              monkeypatch):
+    """Four requests over two slots through the continuous scheduler
+    (mixed blocks, the lazy drain, the window and its flush), a pool of
+    16 pages that the first two streams outgrow together: the younger
+    is preempted MID-DECODE and recomputed from position 0, prompt and
+    the tokens it had made (its slot's state starts from zero inside
+    the program: Scheduler._preempt does nothing for it), both slots
+    are reused after a finish, and every served token is the
+    reference's greedy token. The tick records count what went through
+    a recurrence and the states that started from zero."""
+    from butterfly_tpu.sched.scheduler import Scheduler
+    victims = []
+    preempt = Scheduler._preempt
+    monkeypatch.setattr(Scheduler, "_preempt", lambda self, req: (
+        victims.append((req.state, len(req.output))), preempt(self, req))[1])
+    rng = np.random.RandomState(11)
+    prompts = [rng.randint(1, CFG.vocab_size, n).tolist()
+               for n in (5, 6, 13, 9)]
+    new = (40, 40, 10, 6)
+    sched, reqs = served(params, prompts, new, together=True, num_pages=16,
+                         prefill_inline_budget=4)
+    for prompt, req, n in zip(prompts, reqs, new):
+        assert len(req.output) == n
+        greedy_of_the_reference(params, prompt, req.output)
+    assert sched.alloc.free_pages == 16
+    # a stream that had made tokens was preempted, and it started again
+    # from a zero state: one reset a request and one a recompute (a
+    # victim that had not begun its prompt restarts nothing)
+    begun = [made for state, made in victims if state == "running"]
+    assert begun and max(begun) > 8
+    assert int(sched.metrics()["preemptions_total"]) == len(victims)
+    ticks = [t for t in sched.ticklog.dump()["ticks"]
+             if t["ssm_rows"] is not None]
+    assert ticks and all(t["experts_touched"] is not None for t in ticks)
+    assert sum(t["state_resets"] for t in ticks) == len(reqs) + len(begun)
+    # every prompt token and every decode step went through a
+    # recurrence once, the recomputed stream's twice
+    once = sum(len(p) for p in prompts) + sum(new) - len(new)
+    assert once + sum(begun) <= sum(t["ssm_rows"] for t in ticks) \
+        <= once + sum(begun) + 6 * len(begun) + 16
+    assert all(t["ssm_steps"] % 2 == 0 and t["ssm_rows"]
+               <= t["ssm_steps"] * (2 + 4) for t in ticks)
+    assert sched.registry.snapshot()["ssm_state_bytes"] == \
+        2 * bytes_per_slot(CFG)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_state_parity_tool_separates_its_faults_on_the_toy(dtype):
+    """The check of the chip (three requests through the scheduler over
+    two slots, each slot's final state against the reference's loop), at
+    a toy's size on the CPU: the clean run holds the reference's states
+    in the long stream's slot and in the reused one, to float32 rounding
+    in float32 and, in bfloat16, to a hundredth beside the loop that
+    keeps its state in bfloat16 too (beside the float32 loop the same
+    states read several times that: `drift`); a slot that is not reset,
+    or filler that advances, shows in the reused slot."""
+    import json
+    import sys
+    from pathlib import Path
+    root = Path(__file__).resolve().parent.parent
+    sys.path.insert(0, str(root / "tools"))
+    import state_parity
+    toy = json.loads((root / "tests/servebench/files/configs/"
+                      "tiny-granite.json").read_text())
+    out = state_parity.check(dict(toy, torch_dtype=dtype), toy=True,
+                             long_short=72,
+                             requests=((40, 80), (10, 6), (20, 30)))
+    assert out["evidence"] == "cpu toy", out
+    assert out["clean"]["slots"] == [0, 1, 1]
+    assert out["clean"]["long"]["positions"] == 119
+    limit = 1e-5 if dtype == "float32" else 1e-2
+    for name in ("long", "second"):
+        got = out["clean"][name]
+        assert max(got["h_worst"], got["conv_worst"]) < limit, got
+        assert ("drift" in got) == (dtype == "bfloat16")
+    if dtype == "bfloat16":
+        assert out["clean"]["long"]["drift_worst"] \
+            > 2 * out["clean"]["long"]["h_worst"]
+    for fault in ("no_reset", "filler_advances"):
+        assert out[fault]["second"]["h_worst"] > 5 * limit, out[fault]
+
+
+def test_a_model_of_mamba_layers_only_is_served():
+    p = seeded_params(ALL_MAMBA)
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(1, CFG.vocab_size, n).tolist() for n in (7, 12)]
+    sched, reqs = served(p, prompts, (8, 8), cfg=ALL_MAMBA)
+    for prompt, req in zip(prompts, reqs):
+        greedy_of_the_reference(p, prompt, req.output, ALL_MAMBA)
+
+
+@pytest.mark.parametrize("arch", ["llama", "mixtral", "keye"])
+def test_a_model_without_mamba_layers_has_no_state(arch):
+    """No leaf, no gauge value, no tick field, no fourth value of the
+    packed step: the older families' programs carry nothing of it."""
+    from butterfly_tpu.engine.serving import ServingEngine
+    from butterfly_tpu.sched.scheduler import Scheduler
+    cfg = tiny(arch, dtype="float32")
+    assert not cfg.has_ssm and layer_runs(cfg) == [
+        ("attention", 0, cfg.num_layers, 0)]
+    assert init_ssm_state(cfg, 4) is None and state_info(cfg, 4) is None
+    eng = ServingEngine(Model(cfg), Model(cfg).init(jax.random.PRNGKey(0)),
+                        RuntimeConfig(max_batch_size=2, max_seq_len=64,
+                                      page_size=4, decode_steps_per_tick=2))
+    assert eng._ssm_state is None
+    assert eng.cache.k_pages.shape[0] == cfg.num_layers
+    sched = Scheduler(eng, seed=0)
+    sched.submit([1, 2, 3, 4, 5], max_new_tokens=6)
+    sched.run_until_done()
+    ticks = sched.ticklog.dump()["ticks"]
+    assert ticks and all(t["ssm_rows"] is None and t["state_resets"] is None
+                         and t["ssm_steps"] is None for t in ticks)
+    assert sched.registry.snapshot()["ssm_state_bytes"] == 0
+
+
+def test_the_runtime_report_says_what_state_a_slot_keeps(params):
+    from butterfly_tpu.engine.serving import ServingEngine
+    from butterfly_tpu.sched.scheduler import Scheduler
+    from butterfly_tpu.serve.server import runtime_report
+    sched = Scheduler(ServingEngine(Model(CFG), params, RuntimeConfig(
+        max_batch_size=2, max_seq_len=64, page_size=4)))
+    state = runtime_report(sched)["state"]
+    per = 3 * (8 * 16 * 16 + 3 * (8 * 16 + 2 * 16)) * 4
+    assert state == {"layers": 3, "bytes_per_slot": per, "dtype": "float32",
+                     "bytes": 2 * per}
+    assert runtime_report(sched)["pool_layout"] == "head"
+
+
+def test_reset_slots_zeroes_every_layer_of_the_slots_named():
+    st = init_ssm_state(CFG, 3)
+    st = st._replace(h=st.h + 1, conv=st.conv + 1)
+    st = reset_slots(st, [0, 2])
+    assert not np.asarray(st.h[:, 0]).any() and np.asarray(st.h[:, 1]).all()
+    assert not np.asarray(st.conv[:, :, 2]).any()
+    assert np.asarray(st.conv[:, :, 1]).all()
+
+
+# -- the preset and the layer runs --------------------------------------------
+
+def test_preset_is_the_published_model():
+    cfg = PRESETS["granite-4.0-h-small"]()
+    assert cfg == granite_4_h_small()
+    assert (cfg.num_layers, cfg.hidden_size, cfg.vocab_size) == \
+        (40, 4096, 100352)
+    assert [i for i, k in enumerate(cfg.layer_types)
+            if k == "attention"] == [5, 15, 25, 35]
+    assert (cfg.num_ssm_layers, cfg.num_attn_layers) == (36, 4)
+    assert (cfg.ssm_inner, cfg.ssm_conv_dim) == (8192, 8448)
+    assert cfg.ssm_inner + cfg.ssm_conv_dim + cfg.ssm_heads == 16768
+    assert (cfg.num_experts, cfg.num_experts_per_tok,
+            cfg.shared_intermediate_size) == (72, 10, 1536)
+    assert cfg.tie_embeddings and cfg.pos_embedding == "none"
+    assert cfg.attention_multiplier == 1 / 128
+    # the benchmark's cut: one period, 9 Mamba layers to 1 attention
+    cut = cfg.replace(num_layers=10)
+    assert layer_runs(cut) == [("mamba", 0, 5, 0), ("attention", 5, 1, 0),
+                               ("mamba", 6, 4, 5)]
+    # 19.3 MB a slot in bfloat16 (ISSUE 41 said 19.5: four taps of tail)
+    assert bytes_per_slot(cut) == 9 * (128 * 64 * 128 + 3 * 8448) * 2 \
+        == 19_330_560
+
+
+def test_layer_types_are_checked():
+    with pytest.raises(ValueError, match="layer_types names 2 layers of 4"):
+        tiny("granite_hybrid", layer_types=("mamba", "attention"))
+    with pytest.raises(ValueError, match="'mamba' or 'attention'"):
+        tiny("granite_hybrid", layer_types=("mamba", "conv", "mamba", "mamba"))
+    with pytest.raises(ValueError, match="needs ssm_heads"):
+        tiny("granite_hybrid", ssm_state=0)
+    # a longer list is read up to num_layers, as a cut in depth leaves it
+    assert tiny("granite_hybrid", num_layers=2).layer_types == \
+        ("mamba", "mamba")
+    assert dataclasses.asdict(CFG)["layer_types"] == CFG.layer_types
+
+
+def test_reference_copies_are_equal():
+    from pathlib import Path
+    root = Path(__file__).resolve().parent.parent
+    assert (root / "butterfly_tpu/models/granite_hybrid_f32.py").read_text() \
+        == (root / "servebench/references/granite_hybrid_f32.py").read_text()

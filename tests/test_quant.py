@@ -100,6 +100,36 @@ def test_meshed_serving_quantized_token_parity():
     assert outs[True] == outs[False]
 
 
+def test_a_tied_head_is_held_as_codes_and_sharded_like_one():
+    """A tied model (GPT-2) with int8 weights holds its head a second
+    time as codes (quant/int8.py tied_head); under a mesh the copy takes
+    the embedding's layout turned round, and the served tokens are the
+    single device's."""
+    from butterfly_tpu.core.config import RuntimeConfig
+    from butterfly_tpu.engine.serving import ServingEngine
+    from butterfly_tpu.quant.int8 import init_params_by_leaf
+    from butterfly_tpu.sched.scheduler import Scheduler
+
+    cfg = tiny("gpt2", dtype="float32", param_dtype="float32",
+               vocab_size=256)
+    model = Model(cfg)
+    qparams = quantize_int8(model.init(jax.random.PRNGKey(3)), cfg)
+    assert qparams["lm_head"]["q8"].shape == (cfg.hidden_size, 256)
+    rt = RuntimeConfig(max_batch_size=4, max_seq_len=64, page_size=8)
+    mesh = make_mesh(MeshConfig(data=2, tensor=4))
+    outs = []
+    for m in (None, mesh):
+        sched = Scheduler(ServingEngine(model, qparams, rt, mesh=m))
+        reqs = [sched.submit(p, max_new_tokens=6)
+                for p in ([5, 7, 11], [3, 1])]
+        sched.run_until_done()
+        outs.append([r.output for r in reqs])
+    assert outs[0] == outs[1]
+    born = init_params_by_leaf(cfg, jax.random.PRNGKey(0), quant="int8",
+                               mesh=mesh)
+    assert tuple(born["lm_head"]["q8"].sharding.spec) == (None, "tensor")
+
+
 def test_cli_quant_flag_quantizes():
     """--quant int8 produces a quantized tree through the CLI load path."""
     import argparse

@@ -224,12 +224,27 @@ def _spin_server(rt: RuntimeConfig, slo_ttft_s=None):
 
 @pytest.fixture(scope="module")
 def pressure_server():
-    """Tiny replica with the page pool at ~30% of worst-case demand
-    (16 pages vs 4 slots x 14 pages): the mixed_chat burst must
-    contest it. No SLO declared — admission never sheds, so the
-    preemption pressure is undiluted."""
+    """Tiny replica whose page pool is the LARGEST single request's
+    own need and no more: smoke_specs' twelve requests end at 4-10
+    pages (prompt + max_new over pages of 8; 10 for the two prompts of
+    48), 75 in all, so one request alone always fits and finishes (the
+    tightest pool that can serve the burst at all), and nothing else
+    does for long: the burst arrives within 8 ms, admission gives
+    whatever is free to the next waiting prompt (2-6 pages), so from
+    the first tick the pool is full while most of the burst still
+    waits, and every running stream has 16-48 tokens (2-6 pages) left
+    to grow: its next page can only come from a preemption. The
+    pressure is then a property of pages against the working set,
+    whatever order the burst's threads land in and however the ticks
+    interleave with them. At 16 pages the first three or four prompts
+    left room to grow into, and whether that room ran out depended on
+    that order: eight replays on one idle machine counted 1, 3, 8 and
+    15 preemptions at 16 pages and 5, 7, 18 and 20 at 10 (PR 41), and
+    beside five other test workers 0 came up (the test failed at PRs
+    35 and 40 and on the tree PR 41 started from). No SLO declared:
+    admission never sheds, so the preemption pressure is undiluted."""
     rt = RuntimeConfig(max_batch_size=4, max_seq_len=112, page_size=8,
-                       num_pages=16, prefix_caching=True,
+                       num_pages=10, prefix_caching=True,
                        decode_steps_per_tick=4, inflight_blocks=2,
                        prefill_max_batch=4)
     url, state, httpd = _spin_server(rt)
