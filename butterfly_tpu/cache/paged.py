@@ -1330,7 +1330,7 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
         l, m = idx
         x, st, load = advance_packed(
             x, layer_at(params["layers"], l, cfg),
-            layer_at(params["mamba"], m, cfg), st, m, rows, cfg)
+            layer_at(params["mamba"], m, cfg), st, m, rows, cfg, use_kernel)
         return (x, st), load
 
     def attention(x, scanned):

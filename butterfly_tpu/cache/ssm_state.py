@@ -33,6 +33,17 @@ bfloat16, five times the bytes). Every write is a dynamic-update-slice
 of the carried buffer at the layer's index, which XLA performs in place
 (a scatter into a scan carry copies the whole buffer: cache/paged.py's
 window docs have the measurement).
+
+Who computes the recurrence: a DECODE row's one step is the Pallas
+kernel ops/ssm_step.py when the engine's kernels are on and the state's
+minor dims are whole tiles (the kernel takes the whole h, aliased to
+its result, and the layer's index: one pass over layer m's slots where
+they lie, y read from the float32 value before it is rounded to the
+stored dtype); with kernels off (the CPU) or any other state it is
+models.common.ssm_scan at T == 1 and the update in place, which is also
+what the kernel is tested against. A CHUNK's is ssm_scan's scan over its
+positions, either way; it reads its slot after the decode rows' step (a
+chunk's slot is no live decode row, so that step left it as it was).
 """
 from __future__ import annotations
 
@@ -45,7 +56,8 @@ from jax import lax
 from butterfly_tpu.core.config import ModelConfig
 from butterfly_tpu.models.common import (
     ffn_close, pre_norm, residual_add, ssm_conv, ssm_gate_out, ssm_in_proj,
-    ssm_scan)
+    ssm_scan, ssm_skip, ssm_step_inputs)
+from butterfly_tpu.ops.ssm_step import fits, ssm_step
 
 
 class SSMState(NamedTuple):
@@ -112,12 +124,35 @@ def reset_slots(state: SSMState, slots) -> SSMState:
                     conv=state.conv.at[:, :, slots].set(0))
 
 
-def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig):
+def decode_rows_step(h, m, u, dt, mp, cfg: ModelConfig, count,
+                     use_kernel: bool = False):
+    """The recurrence of layer m's decode rows, one position: h
+    [Lm, S, Nh, Hd, N] the whole carried state, u [S, 1, Dc] float32
+    (ssm_conv), dt [S, 1, Nh], count [S] (1: the row decodes). With
+    use_kernel and a state the kernel can cut (ops/ssm_step.py fits) one
+    pass over the state where it lies; else ssm_scan's one step on a
+    float32 view of the layer and an update in place: the kernel's
+    reference. Returns (y [S, 1, Nh, Hd] float32, h)."""
+    if use_kernel and fits(h):
+        x, dA, dtx, Bg, Cg, real = ssm_step_inputs(u, dt, mp, cfg, count)
+        y, h = ssm_step(h, m, dA[:, 0], dtx[:, 0], Bg[:, 0], Cg[:, 0],
+                        real[:, 0])
+        return ssm_skip(y[:, None], x, mp), h
+    h_m = lax.dynamic_index_in_dim(h, m, 0, keepdims=False)
+    y, new = ssm_scan(u, dt, mp, cfg, h_m.astype(jnp.float32), count)
+    return y, lax.dynamic_update_index_in_dim(h, new.astype(h.dtype), m, 0)
+
+
+def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig,
+                   use_kernel: bool = False):
     """One Mamba-2 layer (mixer, feed-forward, both residuals) of a
     packed mixed step over x [N, 1, D], N = S + P*C rows: the S decode
     rows first, then P chunks of C columns (`rows`: StateRows'
     fields). lp, mp: the layer's slices of params["layers"] and
     params["mamba"]; m: its index among the Mamba layers (traced).
+    use_kernel: the engine's kernel rule (ops/__init__.py); with it,
+    and a state of whole tiles, the decode rows' recurrence is the
+    ssm_step kernel, one pass over the state where it lies.
 
     The projections, the gate and the feed-forward run once over all N
     rows (the weights stream once); the conv and the recurrence run on
@@ -140,9 +175,12 @@ def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig):
         # state
         count = rows.active.astype(jnp.int32)
         u, tail_d = ssm_conv(xbc[:S], tails, mp, count)
-        y, h_d = ssm_scan(u, dt[:S], mp, cfg, h_all.astype(jnp.float32),
-                          count)
-        h = lax.dynamic_update_index_in_dim(h, h_d.astype(sdt), m, 0)
+        y, h = decode_rows_step(h, m, u, dt[:S], mp, cfg, count, use_kernel)
+        if use_kernel:
+            # a chunk's slot is no live decode row: the step left it as it
+            # was, and reading it from the RESULT leaves no reader of the
+            # buffer a kernel wrote in place (else: a copy of it)
+            h_all = lax.dynamic_index_in_dim(h, m, 0, keepdims=False)
         tails_new = tail_d.astype(sdt)
         ys.append(y)
     if P:

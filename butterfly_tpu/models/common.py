@@ -806,6 +806,33 @@ def ssm_conv(xbc: jax.Array, tail: jax.Array, mp: Params, count):
     return u, jnp.take_along_axis(full, at[:, :, None], axis=1)
 
 
+def ssm_step_inputs(u: jax.Array, dt: jax.Array, mp: Params,
+                    cfg: ModelConfig, count):
+    """What the recurrence reads of its positions, formed before any
+    state is touched: u [B,T,Dc] float32 (ssm_conv), dt [B,T,Nh] as
+    projected, count [B] the row's real positions. Returns (x
+    [B,T,Nh,Hd], dA [B,T,Nh], dtx [B,T,Nh,Hd], B and C [B,T,G,N] by
+    GROUP, real [B,T]), all float32 but `real`."""
+    B, T = u.shape[:2]
+    Nh, Hd, N, G = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, \
+        cfg.ssm_groups
+    Di = cfg.ssm_inner
+    x = u[..., :Di].reshape(B, T, Nh, Hd)
+    Bg = u[..., Di:Di + G * N].reshape(B, T, G, N)
+    Cg = u[..., Di + G * N:].reshape(B, T, G, N)
+    dt = jax.nn.softplus(dt.astype(jnp.float32)
+                         + mp["dt_bias"].astype(jnp.float32))
+    dA = jnp.exp(dt * -jnp.exp(mp["A_log"].astype(jnp.float32)))  # [B,T,Nh]
+    dtx = dt[..., None] * x                                  # [B,T,Nh,Hd]
+    real = jnp.arange(T)[None, :] < count[:, None]           # [B,T]
+    return x, dA, dtx, Bg, Cg, real
+
+
+def ssm_skip(y: jax.Array, x: jax.Array, mp: Params) -> jax.Array:
+    """The readout y [B,T,Nh,Hd] plus the mixer's skip term D x."""
+    return y + mp["D"].astype(jnp.float32)[:, None] * x
+
+
 @jax.named_scope("ssm_scan")
 def ssm_scan(u: jax.Array, dt: jax.Array, mp: Params, cfg: ModelConfig,
              state: jax.Array, count):
@@ -814,20 +841,13 @@ def ssm_scan(u: jax.Array, dt: jax.Array, mp: Params, cfg: ModelConfig,
     state advances through its first `count` [B] positions and no
     further (a chunk's filler columns, a dead decode row). Returns
     (y [B,T,Nh,Hd] float32, state after). T == 1 is the one-step
-    recurrence of a decode row; longer rows scan their positions."""
-    B, T = u.shape[:2]
-    Nh, Hd, N, G = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, \
-        cfg.ssm_groups
-    Di = cfg.ssm_inner
-    x = u[..., :Di].reshape(B, T, Nh, Hd)
-    Bm = jnp.repeat(u[..., Di:Di + G * N].reshape(B, T, G, N), Nh // G,
-                    axis=2)                                  # [B,T,Nh,N]
-    Cm = jnp.repeat(u[..., Di + G * N:].reshape(B, T, G, N), Nh // G, axis=2)
-    dt = jax.nn.softplus(dt.astype(jnp.float32)
-                         + mp["dt_bias"].astype(jnp.float32))
-    dA = jnp.exp(dt * -jnp.exp(mp["A_log"].astype(jnp.float32)))  # [B,T,Nh]
-    dtx = dt[..., None] * x                                  # [B,T,Nh,Hd]
-    real = jnp.arange(T)[None, :] < count[:, None]           # [B,T]
+    recurrence of a decode row (ops/ssm_step.py is the same step as one
+    pass over the stored state); longer rows scan their positions."""
+    T = u.shape[1]
+    rep = cfg.ssm_heads // cfg.ssm_groups
+    x, dA, dtx, Bg, Cg, real = ssm_step_inputs(u, dt, mp, cfg, count)
+    Bm = jnp.repeat(Bg, rep, axis=2)                         # [B,T,Nh,N]
+    Cm = jnp.repeat(Cg, rep, axis=2)
 
     def step(h, t):
         dA_t, dtx_t, B_t, C_t, real_t = t
@@ -844,7 +864,7 @@ def ssm_scan(u: jax.Array, dt: jax.Array, mp: Params, cfg: ModelConfig,
         state, y = lax.scan(step, state, tuple(
             jnp.moveaxis(a, 1, 0) for a in (dA, dtx, Bm, Cm, real)))
         y = jnp.moveaxis(y, 0, 1)
-    return y + mp["D"].astype(jnp.float32)[:, None] * x, state
+    return ssm_skip(y, x, mp), state
 
 
 @jax.named_scope("ssm_gate")

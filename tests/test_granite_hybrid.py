@@ -28,8 +28,9 @@ from butterfly_tpu.quant.int8 import (
 #: a shape, as the engines do
 forward = jax.jit(forward, static_argnums=(1,))
 _packed_step = jax.jit(
-    lambda params, cfg, *a, state: paged_forward_packed(
-        params, cfg, *a, state=state), static_argnums=(1,))
+    lambda params, cfg, *a, state, use_kernel=False: paged_forward_packed(
+        params, cfg, *a, state=state, use_kernel=use_kernel),
+    static_argnums=(1,), static_argnames=("use_kernel",))
 
 CFG = tiny("granite_hybrid", dtype="float32", param_dtype="float32")
 #: no attention layer at all: the pool holds no layer, and the paths
@@ -178,8 +179,10 @@ class Packed:
     three slots with a page-table row each, the KV window and its flush
     every third step, the recurrent state through every step."""
 
-    def __init__(self, params, cfg=CFG, windowed=True, width=C):
+    def __init__(self, params, cfg=CFG, windowed=True, width=C,
+                 use_kernel=False):
         self.params, self.cfg, self.C = params, cfg, width
+        self.use_kernel = use_kernel
         cache = init_paged_cache(cfg, RT)
         S, mp = cache.page_table.shape
         self.cache = cache._replace(page_table=jnp.arange(
@@ -219,7 +222,8 @@ class Packed:
         logits, kv, load, self.state = _packed_step(
             self.params, self.cfg, jnp.asarray(toks), self.cache,
             jnp.asarray(ctok), jnp.asarray([cslot]), jnp.asarray([count]),
-            jnp.asarray(active), self.window, self.wlen, state=self.state)
+            jnp.asarray(active), self.window, self.wlen, state=self.state,
+            use_kernel=self.use_kernel)
         adv = jnp.asarray(active, jnp.int32).at[cslot].add(count)
         if self.window is not None:
             self.window, self.wlen = kv, self.wlen + adv
@@ -232,7 +236,7 @@ class Packed:
         return {s: np.asarray(logits[s]) for s in heads}
 
 
-def scripted_run(params, tokens, windowed=True, cfg=CFG):
+def scripted_run(params, tokens, windowed=True, cfg=CFG, use_kernel=False):
     """Slot 1 takes sequence 1's first 20 tokens in chunks of 6 (the
     last holds 2 and 4 of filler) and decodes to position 30 while slot
     0 takes sequence 0's first 15 (6, 6, 3) and decodes beside it; then
@@ -240,7 +244,7 @@ def scripted_run(params, tokens, windowed=True, cfg=CFG):
     position 0 while slot 0 decodes on. Slot 2 never holds a stream.
     Returns ([(sequence, position, logits)], the driver, {slot:
     (sequence, tokens it has seen)})."""
-    drv, out = Packed(params, cfg, windowed), []
+    drv, out = Packed(params, cfg, windowed, use_kernel=use_kernel), []
     at = {0: 0, 1: 0}                       # positions fed, by slot
     seq = {0: 0, 1: 1}
 
@@ -317,6 +321,33 @@ def test_the_recurrence_is_tied_to_the_scan(params, tokens, scripted):
             assert np.abs(got_t - tail).max() < 1e-5 * np.abs(tail).max()
     assert not np.asarray(drv.state.h[:, 2]).any()
     assert not np.asarray(drv.state.conv[:, :, 2]).any()
+
+
+def test_the_kernel_step_is_the_jnp_step_through_the_packed_run(tokens):
+    """The scripted run (chunks, filler, decode rows beside a chunk that
+    reads ITS slot's old state, a reused slot) on a toy whose state is
+    whole tiles, kernels on (interpreted here): the decode rows'
+    recurrence is the ssm_step kernel, every other row's is the scan,
+    and logits and states are the `jnp` run's to float32 rounding."""
+    from butterfly_tpu.ops import record_kernels
+    cfg = CFG.replace(ssm_state=128)
+    p = seeded_params(cfg)
+    out_j, drv_j, _ = scripted_run(p, tokens, cfg=cfg)
+    with record_kernels({}) as calls:
+        out_k, drv_k, _ = scripted_run(p, tokens, cfg=cfg, use_kernel=True)
+    # a call site is a traced Mamba run's body (the toy's second run
+    # reuses the first's trace), in the one program the driver steps
+    assert calls["ssm_step:interpret"] >= 1 and "dense_fallback" not in calls
+    assert [(s, pos) for s, pos, _ in out_k] == \
+        [(s, pos) for s, pos, _ in out_j] and len(out_k) > 30
+    for (s, pos, row_k), (_, _, row_j) in zip(out_k, out_j):
+        assert err(row_k, row_j) < TOL, (s, pos)
+    for got, ref_ in ((drv_k.state.h, drv_j.state.h),
+                      (drv_k.state.conv, drv_j.state.conv)):
+        got, ref_ = np.asarray(got), np.asarray(ref_)
+        assert np.abs(ref_).max() > 1e-3
+        assert np.abs(got - ref_).max() < 1e-5 * np.abs(ref_).max()
+    assert not np.asarray(drv_k.state.h[:, 2]).any()
 
 
 def test_chunked_prefill_equals_one_shot(params, tokens):
@@ -489,6 +520,87 @@ def test_the_expert_products_compile_for_the_chip_without_a_relayout(
     except Exception as e:  # the TPU library is one process's at a time
         pytest.skip(f"the TPU compiler could not be used here: {e}")
     assert compiled.memory_analysis().temp_size_in_bytes < 256e6
+
+
+def test_the_decode_rows_step_compiles_for_the_chip_as_one_pass_in_place(
+        one_chip, monkeypatch):
+    """A mixed step's Mamba layers at the published widths (128 decode
+    rows beside one chunk of 32, the state 2.4 GB of bfloat16 riding two
+    scans as the engine's block carries it, donated), kernels on,
+    compiled for the TPU: the decode rows' recurrence is the Mosaic call
+    `ssm_step`, nothing copies the state, whole or a layer of it (the
+    chunk reads ITS slot from the kernel's result, so no reader of the
+    old buffer is left), and the call's HLO text, which is all a device
+    trace knows of it, is caught by the benchmark's reader of the mixers
+    (servebench/ssm_peaks.py) and not by the paged kernel's."""
+    import json
+    import sys
+    from pathlib import Path
+
+    from butterfly_tpu.cache.ssm_state import StateRows, advance_packed
+    from butterfly_tpu.models.common import layer_at
+    from servebench.ssm_peaks import ssm_patterns
+    from servebench.xplane import clean
+    root = Path(__file__).resolve().parent.parent
+    sys.path.insert(0, str(root / "tools"))
+    try:
+        from chip_kernels import state_copies
+    finally:
+        sys.path.remove(str(root / "tools"))
+    cfg = granite_4_h_small().replace(num_layers=10, dtype="bfloat16")
+    S, P, C, Lm = 128, 1, 32, 5
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: init_params_by_leaf(
+        cfg, jax.random.PRNGKey(0), quant="int8")))
+    state = on_chip(jax.eval_shape(lambda: init_ssm_state(cfg, S)))
+    rows = on_chip(StateRows(
+        active=jnp.zeros((S,), bool), ok=jnp.zeros((S + P * C,), bool),
+        chunk_slot=jnp.zeros((P,), jnp.int32), chunk_ok=jnp.zeros((P,), bool),
+        chunk_pos=jnp.zeros((P, C), jnp.int32)))
+
+    def prog(x, state, params, rows):
+        def layer(carry, i):
+            x, st = carry
+            x, st, _ = advance_packed(
+                x, layer_at(params["layers"], i, cfg),
+                layer_at(params["mamba"], i, cfg), st, i, rows, cfg,
+                use_kernel=True)
+            return (x, st), None
+
+        def step(carry, _):     # a block is steps of a run of layers
+            return jax.lax.scan(layer, carry, jnp.arange(Lm))[0], None
+        return jax.lax.scan(step, (x, state), jnp.arange(4))[0]
+
+    jax.clear_caches()          # no interpreted trace of the kernel is met
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        with jax.disable_jit(False):
+            compiled = jax.jit(prog, donate_argnums=1).lower(
+                on_chip(jnp.zeros((S + P * C, 1, cfg.hidden_size),
+                                  jnp.bfloat16)), state, params, rows
+            ).compile()
+    except Exception as e:  # the TPU library is one process's at a time
+        if "Mosaic" in str(e):      # the kernel refused is no skip
+            raise
+        pytest.skip(f"the TPU compiler could not be used here: {e}")
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    hlo = compiled.as_text()
+    assert state_copies(hlo, state.h) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 256e6
+    calls = [line.strip() for line in hlo.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert calls and all(c.startswith("%ssm_step") for c in calls)
+    config = json.loads((root / "servebench" / "configs"
+                         / "granite-4.0-h-small.json").read_text())
+    mixers = ssm_patterns(config)
+    for name in map(clean, calls):  # as xplane.py names an operation
+        assert mixers.search(name) and "paged_att" not in name, name
 
 
 def test_weights_built_leaf_by_leaf_have_the_same_tree():

@@ -12,9 +12,11 @@ import numpy as np
 import pytest
 
 from butterfly_tpu.core.config import RuntimeConfig, tiny
+from butterfly_tpu.cache.ssm_state import decode_rows_step
 from butterfly_tpu.models.common import Model, attend
 from butterfly_tpu.ops.flash_attention import flash_attention
 from butterfly_tpu.ops.paged_attention import paged_attention
+from butterfly_tpu.ops.ssm_step import fits, heads_per_block, ssm_step
 
 
 def causal_ref(q, k, v):
@@ -468,3 +470,80 @@ def test_engine_flash_prefill_token_parity():
     b = InferenceEngine(model, params,
                         use_flash_prefill=True).generate(prompts, sp)
     np.testing.assert_array_equal(a.tokens, b.tokens)
+
+
+# -- ssm_step: a decode row's Mamba-2 recurrence, one pass over the state -----
+
+def _ssm_case(dtype, groups, seed=0):
+    """A toy state of whole tiles (N = 128 on the lanes), three Mamba
+    layers, four slots of which slot 2 does not decode, and what
+    ssm_conv and the in-projection would hand one decode step."""
+    cfg = tiny("granite_hybrid", ssm_state=128, ssm_groups=groups)
+    Lm, S = 3, 4
+    Nh, Hd, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    h = jax.random.normal(ks[0], (Lm, S, Nh, Hd, N)).astype(dtype)
+    u = jax.random.normal(ks[1], (S, 1, cfg.ssm_conv_dim))
+    dt = jax.random.normal(ks[2], (S, 1, Nh))
+    mp = {"dt_bias": jax.random.normal(ks[3], (Nh,)),
+          "A_log": jax.random.uniform(ks[4], (Nh,), minval=-1.0, maxval=1.0),
+          "D": jax.random.normal(ks[5], (Nh,))}
+    count = jnp.asarray([1, 1, 0, 1], jnp.int32)
+    return cfg, h, u, dt, mp, count
+
+
+@pytest.mark.parametrize("case", [
+    "bf16-g1", "f32-g1", "bf16-g2", "f32-g4", "dead-row", "other-layers",
+    "layer-in-a-scan", "a-state-that-does-not-fit"])
+def test_ssm_step_is_the_jnp_step(case):
+    """The kernel (interpreted) against ssm_scan(T == 1) + the update in
+    place (cache/ssm_state.py decode_rows_step, kernels on and off): the
+    state as stored in both dtypes, one group and several, a
+    row that does not decode, the layers the call does not name, and
+    the layer's index traced inside a scan as the engine's runs do."""
+    dtype = jnp.float32 if case.startswith("f32") else jnp.bfloat16
+    groups = int(case[-1]) if case[-2:-1] == "g" else 1
+    cfg, h, u, dt, mp, count = _ssm_case(dtype, groups)
+    # one bfloat16 ulp where a float32 sum in another order rounds the
+    # other way; float32 to its own rounding
+    tol = dict(rtol=8e-3, atol=1e-6) if dtype == jnp.bfloat16 \
+        else dict(rtol=2e-6, atol=1e-6)
+    if case == "a-state-that-does-not-fit":
+        small = h[..., :16]
+        assert fits(h) and not fits(small)
+        assert not fits(h[..., :8, :])             # bf16: 16 sublanes
+        assert fits(h.astype(jnp.float32)[..., :8, :])
+        # blocks of heads: 8 rows at a time or all of them, three pieces
+        # of a block in the MXU's 128 lanes
+        assert heads_per_block(jnp.zeros((1, 1, 128, 64, 128),
+                                         jnp.bfloat16)) == 32
+        assert heads_per_block(h) == 8
+        assert not fits(jnp.zeros((1, 1, 44, 16, 128)))    # 44, 22, 11: none
+        with pytest.raises(ValueError, match="whole tiles"):
+            ssm_step(small, 0, *(jnp.zeros(()),) * 5)
+        return
+    if case == "layer-in-a-scan":
+        def run(use_kernel):
+            def body(h, m):
+                y, h = decode_rows_step(h, m, u, dt, mp, cfg, count,
+                                        use_kernel)
+                return h, y
+            return jax.jit(lambda h: jax.lax.scan(body, h, jnp.arange(3)))(h)
+        (h_k, y_k), (h_j, y_j) = run(True), run(False)
+        assert not np.array_equal(np.asarray(h_k[0], np.float32),
+                                  np.asarray(h[0], np.float32))
+    else:
+        m = jnp.int32(1)
+        y_k, h_k = decode_rows_step(h, m, u, dt, mp, cfg, count, True)
+        y_j, h_j = decode_rows_step(h, m, u, dt, mp, cfg, count, False)
+    assert h_k.dtype == h.dtype and y_k.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_j),
+                               rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(h_k, np.float32),
+                               np.asarray(h_j, np.float32), **tol)
+    if case == "dead-row":      # bit for bit, where the live rows moved
+        assert np.array_equal(np.asarray(h_k[1, 2]), np.asarray(h[1, 2]))
+        assert not np.array_equal(np.asarray(h_k[1, 1]), np.asarray(h[1, 1]))
+    if case == "other-layers":
+        assert np.array_equal(np.asarray(h_k[0]), np.asarray(h[0]))
+        assert np.array_equal(np.asarray(h_k[2]), np.asarray(h[2]))

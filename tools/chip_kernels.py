@@ -17,7 +17,11 @@ prefix; paged plain, int8, and both with the write-combined window
 segment; ring float and int8. On a host with four or more devices each
 runs again under the mesh wrappers the engines call (`*_sharded` on a
 tensor=4 mesh: 8 query and 2 KV heads per shard; the ring under
-`shard_map` on a seq=4 mesh).
+`shard_map` on a seq=4 mesh). `ssm_step`, the Mamba-2 decode step, runs
+at granite-4.0-h-small's state geometry (9 layers, 128 slots, 128 heads
+of 64, state 128, bfloat16: 2.4 GB) against `ssm_scan` at T == 1 and
+the update in place, the state donated as the engine donates it, and
+its compiled HLO must hold no copy of the state.
 
 Last, the program `serve` spends its time in — the serving engine's
 fused decode block, at the full 8B width with the depth cut to two
@@ -326,6 +330,80 @@ def run_serving_block(name, small, mesh, want):
     return rec
 
 
+def state_copies(hlo: str, h) -> list:
+    """The instructions of a compiled HLO text that COPY the recurrent
+    state h [Lm, S, Nh, Hd, N], whole or one layer of it: a `copy`, or a
+    fusion the compiler named for one (`copy_bitcast_fusion`)."""
+    import re
+    whole = ",".join(map(str, h.shape))
+    layer = ",".join(map(str, h.shape[1:]))
+    made = re.compile(rf"^\s*(?:ROOT )?%(\S*copy\S*) = \(?\w+\[(?:1,)?"
+                      rf"(?:{whole}|{layer})\]")
+    return [m.group(1) for m in map(made.match, hlo.splitlines()) if m]
+
+
+def run_ssm_step(name, small, want):
+    """ops/ssm_step.py at granite-4.0-h-small's state geometry, the
+    layer index traced, against the `jnp` step it replaces
+    (cache/ssm_state.py: ssm_scan at T == 1, then the update in place);
+    a fifth of the slots do not decode."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from butterfly_tpu.cache.ssm_state import decode_rows_step
+    from butterfly_tpu.core.config import granite_4_h_small, tiny
+
+    cfg = tiny("granite_hybrid", ssm_state=128) if small \
+        else granite_4_h_small()
+    Lm, S = (3, 4) if small else (9, 128)
+    Nh, Hd, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    ks = jax.random.split(jax.random.PRNGKey(0), 7)
+    h = jax.random.normal(ks[0], (Lm, S, Nh, Hd, N), jnp.bfloat16)
+    u = jax.random.normal(ks[1], (S, 1, cfg.ssm_conv_dim))
+    dt = jax.random.normal(ks[2], (S, 1, Nh))
+    mp = {"dt_bias": jax.random.normal(ks[3], (Nh,)),
+          "A_log": jax.random.uniform(ks[4], (Nh,), minval=-1., maxval=1.),
+          "D": jax.random.normal(ks[5], (Nh,))}
+    count = (jax.random.uniform(ks[6], (S,)) > 0.2).astype(jnp.int32)
+    m = jnp.int32(Lm // 2)
+
+    def step(use_kernel):
+        return lambda h, m, u, dt, mp, count: decode_rows_step(
+            h, m, u, dt, mp, cfg, count, use_kernel)
+
+    rec = {"name": name, "ok": False}
+    t0 = time.perf_counter()
+    try:
+        args = (m, u, dt, mp, count)
+        want_y, want_h = jax.jit(step(False))(h, *args)
+        dead = np.flatnonzero(np.asarray(count) == 0)
+        kept = h[m][dead]
+        compiled = jax.jit(step(True), donate_argnums=0).lower(
+            h, *args).compile()
+        hlo = compiled.as_text()
+        y, h = jax.block_until_ready(compiled(h, *args))    # h is consumed
+        rec["compile_run_s"] = round(time.perf_counter() - t0, 2)
+        rec["hlo_has"] = {w: w in hlo for w in want}
+        rec["state_copies"] = state_copies(hlo, h)
+
+        @jax.jit        # one fused reduction: no float32 copy of 2.4 GB
+        def err(a, b):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            return jnp.max(jnp.abs(a - b) / (1 + jnp.abs(b)))
+
+        errs = [float(err(y, want_y)), float(err(h, want_h))]
+        rec["max_err"] = round(max(errs), 5)
+        rec["dead_rows_kept"] = bool(jnp.array_equal(h[m][dead], kept))
+        rec["ok"] = bool(np.isfinite(max(errs)) and max(errs) < 3e-2
+                         and rec["dead_rows_kept"] and len(dead)
+                         and not rec["state_copies"]
+                         and all(rec["hlo_has"].values()))
+    except Exception as e:  # a compiler refusal is the finding: record it
+        rec["error"] = f"{type(e).__name__}: {e}"[:1500]
+    return rec
+
+
 def run_case(name, fn, ref, args, mesh=None, want=(MOSAIC_CALL,)):
     """Compile `fn`, look for `want` in its HLO, run it, compare."""
     import jax
@@ -397,6 +475,8 @@ def main() -> int:
 
     results = [run_case(n, k, r, a, want=want)
                for n, k, r, a, _ in cases if wanted(n)]
+    if wanted("ssm_step"):
+        results.append(run_ssm_step("ssm_step", args.small, want))
     if wanted("serve_block"):
         results.append(run_serving_block("serve_block", args.small, None,
                                          want))

@@ -79,14 +79,14 @@ def planted(fault: str):
     from butterfly_tpu.cache import paged
     real = paged.advance_packed
 
-    def faulty(x, lp, mp, state, m, rows, cfg):
+    def faulty(x, lp, mp, state, m, rows, cfg, use_kernel=False):
         if fault == "no_reset":         # no chunk is at position 0
             rows = rows._replace(chunk_pos=rows.chunk_pos + 1)
         elif fault == "filler_advances":  # every column of a chunk is real
             S, C = rows.active.shape[0], rows.chunk_pos.shape[1]
             rows = rows._replace(ok=rows.ok.at[S:].set(
                 jnp.repeat(rows.chunk_ok, C)))
-        return real(x, lp, mp, state, m, rows, cfg)
+        return real(x, lp, mp, state, m, rows, cfg, use_kernel)
 
     paged.advance_packed = real if fault == "clean" else faulty
     try:
