@@ -1,7 +1,8 @@
 """Kernels: the least time the chip could take for the sparse-attention
 path of one block, over the time that path took in one block. The least
 time is the MODEL's bytes over the published memory bandwidth
-(servebench/sparse_peaks.py): per layer and decode step the index keys
+(servebench/peaks.py:sparse_least_seconds, from the counts of
+servebench/sparse_peaks.py): per layer and decode step the index keys
 of the live context, min(context, topk) rows of keys and values a
 stream and the indexer's weights, the contexts read from the clients'
 timelines at the middle of the trace, as block_roofline takes them. The
@@ -13,8 +14,9 @@ their slot's context whole and masked, which the least time does not
 count: the share reads lower for it, never higher."""
 import statistics
 
-from servebench.sparse_peaks import (
-    live_contexts, sparse_least_seconds, sparse_op_seconds)
+from servebench.metrics import live_contexts
+from servebench.peaks import sparse_least_seconds
+from servebench.sparse_peaks import sparse_op_seconds
 from servebench.spans import DECODE_BLOCKS, MIXED_BLOCKS, block_durations
 
 

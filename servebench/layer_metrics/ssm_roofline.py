@@ -1,7 +1,8 @@
 """Kernels: the least time the chip could take for the Mamba-2 mixers of
 one block, over the time they took in one block. The least time is the
 MODEL's bytes over the published memory bandwidth
-(servebench/ssm_peaks.py): per Mamba layer and decode step the mixer's
+(servebench/peaks.py:ssm_least_seconds, from the counts of
+servebench/ssm_peaks.py): per Mamba layer and decode step the mixer's
 two projections once and every live stream's recurrent state read and
 written once, the live streams read from the clients' timelines at the
 middle of the trace, as block_roofline takes them. The time is the
@@ -15,9 +16,10 @@ out-projection's result is not told from another layer's
 what PERF.md section 5 gives."""
 import statistics
 
-from servebench.metrics import live_context
+from servebench.metrics import live_contexts
+from servebench.peaks import ssm_least_seconds
 from servebench.spans import DECODE_BLOCKS, MIXED_BLOCKS, block_durations
-from servebench.ssm_peaks import ssm_least_seconds, ssm_op_seconds
+from servebench.ssm_peaks import ssm_op_seconds
 
 
 def read(ctx):
@@ -28,7 +30,7 @@ def read(ctx):
     runs = sum(d for name, rs in ctx.trace["module_runs"].items()
                if MIXED_BLOCKS in name or DECODE_BLOCKS in name
                for _, d in rs)
-    live, _ = live_context(ctx.streams, ctx.trace_at)
+    live = len(live_contexts(ctx.streams, ctx.trace_at))
     if not runs or not live:
         return None
     least = ssm_least_seconds(

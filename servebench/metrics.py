@@ -12,7 +12,7 @@ from __future__ import annotations
 import math
 import statistics
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence
 
 #: a stream needs this many tokens inside the window to give a TPOT
 MIN_TOKENS = 16
@@ -157,12 +157,9 @@ def thirds(streams, w0, w1, names) -> Dict[str, List]:
                 for i in range(3)] for n in names if n in END_TO_END}
 
 
-def live_context(streams, t: float) -> Tuple[int, int]:
-    """(streams generating at time t, tokens of context they hold):
-    prompt plus the tokens each had received by t."""
-    n = ctx = 0
-    for s in streams:
-        if s.times and s.times[0] <= t and (s.end is None or t < s.end):
-            n += 1
-            ctx += s.prompt_len + sum(1 for x in s.times if x <= t)
-    return n, ctx
+def live_contexts(streams, t: float) -> List[int]:
+    """Tokens of context of each stream generating at time t: prompt plus
+    the tokens it had received by t."""
+    return [s.prompt_len + sum(1 for x in s.times if x <= t)
+            for s in streams
+            if s.times and s.times[0] <= t and (s.end is None or t < s.end)]

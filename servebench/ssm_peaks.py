@@ -1,27 +1,31 @@
-"""The least time of the Mamba-2 mixers of a decode block: the bytes a
-model with state-space layers (`layer_types` and the `mamba_*` keys of
-its configuration file) must move to advance every live stream by one
-position, over the published memory bandwidth (servebench/peaks.py).
+"""A Mamba-2 mixer, counted from a configuration file: what a model with
+state-space layers (`layer_types` and the `mamba_*` keys) must move to
+advance every live stream by one position in one such layer, and how a
+device trace tells the mixers' operations.
 
 It counts the work of the MODEL, not of an implementation: per Mamba
 layer and decode step,
 
 * the mixer's two projections, once: hidden x (2 x Di + 2 x G x N + Nh)
   in (z, x, B, C and dt) and Di x hidden out, Di = `mamba_n_heads` x
-  `mamba_d_head`; one byte a parameter where the configuration serves
-  int8 codes, else two (the conv's 4 taps, A, D, dt_bias and the norm
-  are under a thousandth of that and left out, as peaks.py leaves the
-  scales out);
+  `mamba_d_head` (`proj_params`; the conv's 4 taps, A, D, dt_bias and
+  the norm are under a thousandth of that and left out, as peaks.py
+  leaves the scales out);
 * every live stream's recurrent state, READ and WRITTEN once: a head's
   state `mamba_n_heads` x `mamba_d_head` x `mamba_d_state` and the
   conv's last `mamba_d_conv` - 1 inputs of Di + 2 x G x N channels, two
-  bytes a value (the state is held in the model's dtype).
+  bytes a value, the state being held in the model's dtype
+  (`state_bytes`);
+* the decay, the outer product, the sum and the readout over a head's
+  state, six operations a value (`state_flops`), beside two a parameter
+  and row for the projections.
 
 Whatever serves it moves at least that: a kernel that kept the state in
 fast memory over a block's steps would beat the count, and the count
-would say so (a share over 100 %). The operations (two a parameter and
-row for the projections; the decay, the outer product, the sum and the
-readout over the state, six a value) are returned beside the bytes.
+would say so (a share over 100 %). These are the ONLY formulas of a
+Mamba layer in the benchmark: `servebench/peaks.py` adds them up, for the
+mixers alone (`ssm_least_seconds`, read by `ssm_roofline`) and for the
+whole step (`block_least_seconds`, read by `block_roofline`).
 
 stdlib only.
 """
@@ -30,16 +34,20 @@ from __future__ import annotations
 import re
 from typing import Dict
 
-from servebench.peaks import peaks_of
-
 #: bf16: what a slot keeps between steps
 STATE_BYTES = 2.0
 
 
+def is_mamba(config: Dict, layer: int) -> bool:
+    """Whether layer `layer` of the configuration is a Mamba-2 mixer:
+    `layer_types[layer] == "mamba"`; a file without the list has none."""
+    kinds = config.get("layer_types") or []
+    return layer < len(kinds) and kinds[layer] == "mamba"
+
+
 def mamba_layers(config: Dict) -> int:
     """Layers of the configuration AS RUN that are Mamba-2 mixers."""
-    kinds = config.get("layer_types") or []
-    return list(kinds[:config["num_hidden_layers"]]).count("mamba")
+    return sum(is_mamba(config, l) for l in range(config["num_hidden_layers"]))
 
 
 def sizes(config: Dict) -> Dict[str, int]:
@@ -51,41 +59,34 @@ def sizes(config: Dict) -> Dict[str, int]:
             "proj": 2 * inner + groups + config["mamba_n_heads"]}
 
 
-def proj_params(config: Dict) -> float:
+def proj_params(config: Dict) -> int:
     """Parameters of one mixer's in- and out-projection."""
     s = sizes(config)
-    return float(config["hidden_size"] * (s["proj"] + s["inner"]))
+    return config["hidden_size"] * (s["proj"] + s["inner"])
 
 
-def state_values(config: Dict) -> float:
+def heads_state(config: Dict) -> int:
+    """Values of the heads' state one stream keeps for one Mamba layer."""
+    return (config["mamba_n_heads"] * config["mamba_d_head"]
+            * config["mamba_d_state"])
+
+
+def state_values(config: Dict) -> int:
     """Values one stream keeps for one Mamba layer: the heads' state and
     the conv's tail."""
-    s = sizes(config)
-    return float(config["mamba_n_heads"] * config["mamba_d_head"]
-                 * config["mamba_d_state"]
-                 + (config["mamba_d_conv"] - 1) * s["conv"])
+    return heads_state(config) \
+        + (config["mamba_d_conv"] - 1) * sizes(config)["conv"]
 
 
-def ssm_least_seconds(config: Dict, device_kind: str, chips: int,
-                      steps: int, live_streams: float) -> Dict[str, float]:
-    """The least time `chips` chips could take for the Mamba-2 mixers of
-    one block of `steps` decode steps with `live_streams` live streams.
-    Returns the bytes, the operations and both bounds."""
-    pk = peaks_of(device_kind)
-    Lm = mamba_layers(config)
-    per = 1.0 if config["serve"].get("quant") == "int8" else 2.0
-    state = state_values(config)
-    by = steps * Lm * (proj_params(config) * per
-                       + live_streams * state * STATE_BYTES * 2.0)
-    heads_state = (config["mamba_n_heads"] * config["mamba_d_head"]
-                   * config["mamba_d_state"])
-    fl = steps * Lm * live_streams * (2.0 * proj_params(config)
-                                      + 6.0 * heads_state)
-    t_mem = by / (chips * pk["hbm_bytes_per_s"])
-    t_cmp = fl / (chips * pk["bf16_flops_per_s"])
-    return {"bytes": by, "flops": fl, "memory_s": t_mem, "compute_s": t_cmp,
-            "least_s": max(t_mem, t_cmp),
-            "bound": "memory" if t_mem >= t_cmp else "compute"}
+def state_bytes(config: Dict) -> float:
+    """Bytes one step moves for one stream's state in one Mamba layer:
+    read once and written once."""
+    return state_values(config) * STATE_BYTES * 2.0
+
+
+def state_flops(config: Dict) -> float:
+    """Operations one position costs over one layer's heads' state."""
+    return 6.0 * heads_state(config)
 
 
 # -- the mixers' operations in a device trace --------------------------------

@@ -294,12 +294,14 @@ def test_block_bytes_of_mistral_7b():
     four = json.loads((ROOT / "servebench/configs/mistral-7b-v0.3-bf16-tp4.json").read_text())
     assert peaks.matmul_params(one) == pytest.approx(7.11e9, rel=0.01)
     assert peaks.weight_bytes(four) == 2 * peaks.weight_bytes(one)
-    assert peaks.kv_bytes_per_token(one) == 32 * 2 * 8 * 132
-    assert peaks.kv_bytes_per_token(four) == 32 * 2 * 8 * 256
-    r = peaks.block_least_seconds(one, "TPU v5 lite", 1, 4, 32, 32 * 300)
+    # a cached row of one layer: int8 codes and a float32 scale a vector, or bf16
+    assert peaks.cached_row_bytes(one) == 2 * 8 * 132
+    assert peaks.cached_row_bytes(four) == 2 * 8 * 256
+    r = peaks.block_least_seconds(one, "TPU v5 lite", 1, 4, [300] * 32)
     assert r["bound"] == "memory"
+    assert r["parts"]["rows"] == 9600 * 32 * 2 * 8 * 132 == 9600 * 67584
     assert r["least_s"] == pytest.approx(4 * (7.11e9 + 9600 * 67584) / 819e9, rel=0.01)
-    r4 = peaks.block_least_seconds(four, "TPU v5 lite", 4, 4, 32, 32 * 300)
+    r4 = peaks.block_least_seconds(four, "TPU v5 lite", 4, 4, [300] * 32)
     assert r4["least_s"] < r["least_s"]
 
 
@@ -346,7 +348,7 @@ def test_params_streamed_and_multiplied_of_two_moes(n):
         assert peaks.streamed_params(MIXTRAL_8X7B, n) == pytest.approx(46_571_454_464)
         assert peaks.streamed_params(OLMOE_1B_7B, n) == pytest.approx(6_816_006_144)
     # a block: bytes by what is streamed, operations by what multiplies
-    r = peaks.block_least_seconds(OLMOE_1B_7B, "TPU v5 lite", 1, 4, n, 0)
+    r = peaks.block_least_seconds(OLMOE_1B_7B, "TPU v5 lite", 1, 4, [0] * n)
     assert r["bytes"] == pytest.approx(4 * peaks.streamed_params(OLMOE_1B_7B, n))
     assert r["flops"] == 4 * 2 * 1_178_861_568 * n
     assert r["bound"] == ("memory" if n < 1024 else "compute")
@@ -383,25 +385,29 @@ def test_a_step_of_four_positions_a_stream_with_experts():
         by_hand(*sdar, wide), rel=1e-12)
     # the block: 4 steps, 32 streams, 32 x 300 tokens of live context at
     # 8 layers x 2 x 4 KV heads x 128 x 2 bytes = 16,384 bytes a token,
-    # which a step reads ONCE whatever its width
-    assert peaks.kv_bytes_per_token(SDAR_30B_A3B) == 16_384
-    r = peaks.block_least_seconds(SDAR_30B_A3B, "TPU v5 lite", 1, 4, 32, 9600)
-    assert r["flops"] == 4 * 2 * 766_246_912 * 32 * 4 == 784_636_837_888
+    # which a step reads ONCE whatever its width; each of a stream's 4
+    # positions multiplies the rows it reads: 4 x 32 heads x 128 a row
+    assert 8 * peaks.cached_row_bytes(SDAR_30B_A3B) == 16_384
+    assert peaks.row_flops(SDAR_30B_A3B) == 16_384
+    r = peaks.block_least_seconds(SDAR_30B_A3B, "TPU v5 lite", 1, 4, [300] * 32)
+    assert 4 * 2 * 766_246_912 * 32 * 4 == 784_636_837_888
+    assert r["flops"] == 784_636_837_888 + 4 * 4 * 8 * 9600 * 16_384 \
+        == 804_769_497_088
     assert r["bytes"] == pytest.approx(
         4 * (2 * by_hand(*sdar, wide) + 9600 * 16_384), rel=1e-12)
     assert r["bound"] == "memory"       # 43.0 GB: 52.5 ms against 4.0 ms
     assert r["memory_s"] == pytest.approx(0.0525, rel=0.01)
-    assert r["compute_s"] == pytest.approx(0.00398, rel=0.01)
+    assert r["compute_s"] == pytest.approx(0.00409, rel=0.01)
     # the same file read at width 1, as the count stood before the key:
     # a quarter of the operations, and 111.77 / 127.97 of the experts' bytes
     r1 = peaks.block_least_seconds(dict(SDAR_30B_A3B, decode_width=1),
-                                   "TPU v5 lite", 1, 4, 32, 9600)
+                                   "TPU v5 lite", 1, 4, [300] * 32)
     assert r1["flops"] * 4 == r["flops"]
     assert r1["bytes"] == pytest.approx(
         4 * (2 * by_hand(*sdar, narrow) + 9600 * 16_384), rel=1e-12)
     assert 1 - narrow / wide == pytest.approx(0.1266, abs=1e-4)
-    # fewer than one live stream counts as one, of 4 positions
-    assert peaks.block_least_seconds(SDAR_30B_A3B, "TPU v5 lite", 1, 4, 0.2, 0)[
+    # no live stream counts as one, of 4 positions
+    assert peaks.block_least_seconds(SDAR_30B_A3B, "TPU v5 lite", 1, 4, [])[
         "flops"] == 4 * 2 * 766_246_912 * 4
 
 

@@ -137,9 +137,9 @@ def test_thirds_split_the_window():
     assert "setup_s" not in th
 
 
-def test_live_context_counts_prompt_and_tokens_so_far():
+def test_live_contexts_count_prompt_and_tokens_so_far_a_stream_at_a_time():
     a = stream(every(1.0, 1.0, 10), prompt=100)         # live from 1.0 on
     b = stream([2.0, 3.0], prompt=50, finished=True, end=3.0)
-    assert M.live_context([a, b], 2.5) == (2, 100 + 2 + 50 + 1)
-    assert M.live_context([a, b], 3.5) == (1, 103)
-    assert M.live_context([a, b], 0.5) == (0, 0)
+    assert M.live_contexts([a, b], 2.5) == [100 + 2, 50 + 1]
+    assert M.live_contexts([a, b], 3.5) == [103]
+    assert M.live_contexts([a, b], 0.5) == []

@@ -124,7 +124,8 @@ def test_with_one_layer_only_the_last_row_of_a_block_agrees():
 def test_a_width_under_one_or_no_whole_number_is_an_error_that_names_the_key(width):
     config = toy(decode_width=width)
     for read in (decode_width, lambda c: refcheck.build(c, paths=PATHS),
-                 lambda c: peaks.block_least_seconds(c, "TPU v5 lite", 1, 4, 32, 0)):
+                 lambda c: peaks.block_least_seconds(c, "TPU v5 lite", 1, 4,
+                                                     [0] * 32)):
         with pytest.raises(ValueError, match="`decode_width` of 'tiny-llama-w4'"):
             read(config)
 
@@ -138,7 +139,7 @@ def test_a_width_that_does_not_fit_the_checks_cache_is_an_error_that_names_the_k
             refcheck.build(toy(decode_width=width), paths=PATHS)
     # the least time of a block has no cache to fit
     assert peaks.block_least_seconds(
-        toy(decode_width=64), "TPU v5 lite", 1, 4, 32, 0)["flops"] > 0
+        toy(decode_width=64), "TPU v5 lite", 1, 4, [0] * 32)["flops"] > 0
 
 
 def test_refcheck_as_the_harness_starts_it_on_the_wide_toy(tmp_path):
@@ -223,32 +224,42 @@ def test_a_file_without_the_key_is_driven_as_the_parent_did():
         assert all(bool(jnp.array_equal(g, w)) for (_, g), (_, w) in zip(rows, was))
 
 
-#: (live streams, tokens of live context): a one-chip window of the
-#: ledger (27 of 32 slots, block_roofline 3.83 of a 947.5 ms block is
-#: 7.43 GB a step: 4,700 tokens of context), a full batch, an idle one
-LEDGER_SIZES = [(27.04, 4731.5), (26.08, 4506.25), (32, 9600), (0.4, 10.0)]
+#: the live streams' contexts: a one-chip window of the ledger (27 of 32
+#: slots, block_roofline 3.83 of a 947.5 ms block is 7.43 GB a step: 4,700
+#: tokens of context), one like it with every context different, a full
+#: batch, an idle one
+LEDGER_SIZES = [[175] * 26 + [181], [33 + 11 * i for i in range(26)],
+                [300] * 32, []]
 
 
-@pytest.mark.parametrize("file, chips, per, kv", [
+@pytest.mark.parametrize("file, chips, per, row", [
     # int8 codes: 1 byte a parameter, 128 + 4 bytes a cached vector
-    ("servebench/configs/mistral-7b-v0.3.json", 1, 1.0, 32 * 2 * 8 * 132.0),
+    ("servebench/configs/mistral-7b-v0.3.json", 1, 1.0, 2 * 8 * 132.0),
     # bfloat16: 2 bytes a parameter, 256 bytes a cached vector
-    ("servebench/configs/mistral-7b-v0.3-bf16-tp4.json", 4, 2.0, 32 * 2 * 8 * 256.0)])
-def test_least_time_of_the_mistral_files_is_the_parents(file, chips, per, kv):
+    ("servebench/configs/mistral-7b-v0.3-bf16-tp4.json", 4, 2.0, 2 * 8 * 256.0)])
+def test_least_time_of_the_mistral_files_is_the_parents(file, chips, per, row):
     """Every field of `block_least_seconds`, equal (==, not approx) to
-    the parent's formula (PR 26) written out over the dense count."""
+    the parent's formula (PR 26) written out over the dense count: the
+    bytes and both times to the last bit whatever the contexts are (a
+    dense file reads every row of every layer), and the operations with
+    the attention's own products beside them (PR 43: 4 x 32 heads x 128
+    a row read)."""
     assert file in MISTRAL
     config = json.loads((ROOT / file).read_text())
     params = 32 * (4096 * 32 * 128 * 2 + 4096 * 8 * 128 * 2 + 3 * 4096 * 14336) \
         + 32768 * 4096
     assert params == 7_113_539_584
     steps = config["serve"]["decode_steps_per_tick"]
-    for live, tokens in LEDGER_SIZES:
-        by = steps * (params * per + tokens * kv)
-        fl = steps * 2.0 * params * max(1.0, live)
+    for contexts in LEDGER_SIZES:
+        live, tokens = len(contexts), sum(contexts)
+        by = steps * (params * per + tokens * 32 * row)
+        fl = steps * (2.0 * params * max(1.0, live)
+                      + 32 * tokens * 4.0 * 32 * 128)
         t_mem, t_cmp = by / (chips * 819e9), fl / (chips * 197e12)
         assert peaks.block_least_seconds(
-            config, "TPU v5 lite", chips, steps, live, tokens) == {
+            config, "TPU v5 lite", chips, steps, contexts) == {
                 "bytes": by, "flops": fl, "memory_s": t_mem, "compute_s": t_cmp,
                 "least_s": max(t_mem, t_cmp),
-                "bound": "memory" if t_mem >= t_cmp else "compute"}
+                "bound": "memory" if t_mem >= t_cmp else "compute",
+                "parts": {"weights": params * per, "rows": tokens * 32 * row,
+                          "index_keys": 0.0, "state": 0.0}}

@@ -1,6 +1,7 @@
 """What PR 41 adds to the benchmark, on records written out by hand: the
 least time of the Mamba-2 mixers at the cell's sizes
-(`servebench/ssm_peaks.py`), its three readers, the traffic file
+(`servebench/peaks.py:ssm_least_seconds` over the counts of
+`servebench/ssm_peaks.py`), its three readers, the traffic file
 `rollout.json`, the configuration file and the entries in the manifest;
 and a toy of the family through the harness on the CPU (a rehearsal),
 added from files alone."""
@@ -47,7 +48,7 @@ def test_sizes_of_one_mixer_and_of_one_stream_s_state():
 def test_least_time_of_a_block_by_hand():
     """128 live streams, 4 steps: per layer and step the projections'
     int8 codes once and 128 states read and written in bf16; 9 layers."""
-    got = ssm_peaks.ssm_least_seconds(CONFIG, V5E, 1, 4, 128)
+    got = peaks.ssm_least_seconds(CONFIG, V5E, 1, 4, 128)
     layer_step = 102_236_160 + 128 * 1_073_920 * 2 * 2
     assert layer_step == 652_083_200
     assert got["bytes"] == 4 * 9 * layer_step == 23_474_995_200
@@ -58,14 +59,19 @@ def test_least_time_of_a_block_by_hand():
     assert got["bound"] == "memory"
     # the state is most of it: 84 % of the bytes at 128 streams, and a
     # lone stream reads the projections and little else
-    one = ssm_peaks.ssm_least_seconds(CONFIG, V5E, 1, 1, 1)
+    one = peaks.ssm_least_seconds(CONFIG, V5E, 1, 1, 1)
     assert one["bytes"] == 9 * (102_236_160 + 4 * 1_073_920)
     # twice the chips, half the time; bf16 weights, two bytes a parameter
-    two = ssm_peaks.ssm_least_seconds(CONFIG, V5E, 2, 4, 128)
+    two = peaks.ssm_least_seconds(CONFIG, V5E, 2, 4, 128)
     assert two["memory_s"] == pytest.approx(got["memory_s"] / 2)
     bf16 = dict(CONFIG, serve=dict(CONFIG["serve"], quant="none"))
-    assert ssm_peaks.ssm_least_seconds(bf16, V5E, 1, 1, 0)["bytes"] == \
+    assert peaks.ssm_least_seconds(bf16, V5E, 1, 1, 0)["bytes"] == \
         9 * 2 * 102_236_160
+    # the functions the whole step's count adds up too (peaks.step_parts)
+    assert ssm_peaks.state_bytes(CONFIG) == 4 * 1_073_920
+    assert ssm_peaks.state_flops(CONFIG) == 6 * 1_048_576
+    assert [ssm_peaks.is_mamba(CONFIG, l) for l in range(10)] == \
+        [True] * 5 + [False] + [True] * 4
 
 
 def test_a_configuration_cut_in_depth_counts_the_layers_it_runs():
@@ -156,7 +162,7 @@ def test_ssm_share_on_a_trace_written_by_hand():
 def test_ssm_roofline_on_a_trace_written_by_hand():
     """Three streams generate at the trace's middle; the mixers took 0.8
     of the 1.9 s of block runs, so 0.3 x 0.8 / 1.9 of a whole block."""
-    least = ssm_peaks.ssm_least_seconds(CONFIG, V5E, 1, 4, 3)["least_s"]
+    least = peaks.ssm_least_seconds(CONFIG, V5E, 1, 4, 3)["least_s"]
     assert CELL.reader("ssm_roofline")(traced_ctx()) == \
         pytest.approx(100 * least / (0.3 * 0.8 / 1.9))
 
@@ -254,12 +260,18 @@ def test_the_entries_this_pr_added():
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         ("granite-4.0-h-small", "rollout", 1)
     assert [w["name"] for w in MANIFEST["workloads"]][-1] == cell["name"]
-    # the expert counters keep their lists: the new cell does not report them
-    assert "granite4h.rollout" not in by["experts_touched_share"]["workloads"]
+    # since PR 43 the cell reports the two expert counters too
+    for name in ("experts_touched_share", "expert_rows_skew"):
+        assert by[name]["workloads"] == ["smallthinker21b.batch",
+                                         "keye30b.think", "granite4h.rollout"]
     assert {m["name"] for m in CELL.per_layer} >= {
         "ssm_share", "ssm_roofline", "ssm_rows_per_step", "block_roofline",
         "flush_ms_p50", "paged_attn_share", "mixed_block_ms_p50",
-        "slot_occupancy", "starved_share"}
+        "slot_occupancy", "starved_share", "experts_touched_share",
+        "expert_rows_skew"}
+    # a run whose state quietly took the jnp step is not correct
+    assert CONFIG["kernels_must_hold"] == ["paged_win", "ssm_step"]
+    assert "XLA's own operations" not in CONFIG["kernels_must_hold_why"]
     assert {m["name"] for m in CELL.end_to_end} == {
         "out_tok_s", "tpot_p50_ms", "setup_s"}
 
@@ -294,22 +306,68 @@ def test_the_file_holds_every_published_key_and_its_bytes():
         "gated_norm", "layer_types", "torch_dtype"}
 
 
-def test_block_roofline_s_count_for_the_cell_is_peaks_py_s_own():
-    """servebench/peaks.py counts TEN attention layers' projections and
-    keys and values and no state: beside the true count by layer kind
-    (PERF.md section 7, the next `benchmark` PR's first line)."""
-    live, ctx = 128, 128 * 1164
-    counted = peaks.weight_bytes(CONFIG, live) \
-        + ctx * peaks.kv_bytes_per_token(CONFIG)
+@pytest.mark.parametrize("context", [500, 1164, 2000])
+def test_block_roofline_s_count_for_the_cell_is_peaks_py_s_own(context):
+    """servebench/peaks.py counts by layer kind (PR 43): nine mixers'
+    projections and ONE attention layer's, every live stream's state read
+    and written in nine layers, keys and values in one. To the byte at 128
+    streams, whatever the context; until PR 43 it counted ten attention
+    layers, ten layers' keys and values and no state: 10.4 GB at context
+    500 against these 13.6, equal only near 1,164."""
+    live = 128
+    got = peaks.block_least_seconds(CONFIG, V5E, 1, 1, [context] * live)
     experts = 72 * 3 * 4096 * 768 * (1 - (1 - 10 / 72) ** 128)
-    assert peaks.kv_bytes_per_token(CONFIG) == 10 * 4096
-    assert counted == pytest.approx(
-        10 * (41_943_040 + 4096 * 72 + 2 * 3 * 4096 * 768 + experts)
-        + 100352 * 4096 + ctx * 40_960)
-    true = 8_356_626_432 + 10 * 4096 * 72 * 2 \
-        + ssm_peaks.ssm_least_seconds(CONFIG, V5E, 1, 1, live)["bytes"] \
-        - 9 * 102_236_160 + ctx * 4096
-    assert 13.8e9 < counted < 14.0e9 and 13.8e9 < true < 14.1e9
+    weights = 9 * 102_236_160 + 41_943_040 \
+        + 10 * (4096 * 72 + 2 * 3 * 4096 * 768 + experts) + 100352 * 4096
+    # the codes of the file's own arithmetic and the routers, a byte a
+    # parameter as peaks.py counts every file's (ISSUE 43's 8,362,524,672
+    # took the routers at two), 33 B short of every expert: 1,280 draws
+    # of 72 are expected to miss one in 2e8
+    assert weights == pytest.approx(8_356_626_432 + 10 * 4096 * 72, abs=40)
+    assert got["parts"] == {
+        "weights": pytest.approx(weights, rel=1e-12),
+        "rows": live * context * 4096, "index_keys": 0.0,
+        "state": 4_948_623_360}
+    assert 128 * 9 * 1_073_920 * 2 * 2 == 4_948_623_360
+    assert got["bytes"] == pytest.approx(
+        weights + 4_948_623_360 + live * context * 4096, rel=1e-12)
+    # the mixers' own count is the same functions: the parts of the whole
+    mixers = peaks.ssm_least_seconds(CONFIG, V5E, 1, 1, live)["bytes"]
+    assert mixers == 9 * 102_236_160 + got["parts"]["state"]
+    assert {500: 13.57e9, 1164: 13.92e9, 2000: 14.36e9}[context] == \
+        pytest.approx(got["bytes"], rel=1e-3)
+    # the operations: a position meets ten experts and the shared one in
+    # every layer; the state costs six a value, a row read 4 x 32 x 128
+    meets = 9 * 102_236_160 + 41_943_040 \
+        + 10 * (4096 * 72 + 12 * 3 * 4096 * 768) + 100352 * 4096
+    assert peaks.matmul_params(CONFIG) == meets
+    assert got["flops"] == 2.0 * meets * live \
+        + live * context * 16_384 + 9 * live * 6 * 1_048_576
+    assert got["bound"] == "memory"
+
+
+def test_the_expert_readers_on_tick_records_of_the_cell_s_shape():
+    """Six records of a traced run of this cell (my chip run, PR 42, seed
+    2147490003), as `/debug/ticks` gave them: the file names its 72
+    experts `num_local_experts`, the third of the names peaks.py reads E
+    under; 125-160 rows of ten draws touch every one of them."""
+    assert "num_experts" not in CONFIG and peaks.num_experts(CONFIG) == 72
+    ticks = json.loads((FILES.parent / "recorded_ticks" / "granite4h.rollout.json")
+                       .read_text())
+    ctx = SimpleNamespace(config=CONFIG, ticks=ticks, wall_minus_mono=0.0,
+                          w0=ticks[0]["t_wall"] - 1, w1=ticks[-1]["t_wall"] + 1)
+    assert [t["experts_touched"] for t in ticks] == [72.0] * 6
+    assert CELL.reader("experts_touched_share")(ctx) == 100.0
+    rows_max = sum(t["expert_rows_max"] for t in ticks)
+    rows_mean = sum(t["expert_rows_mean"] for t in ticks)
+    assert rows_max == pytest.approx(177.85, abs=0.01)
+    assert rows_mean == pytest.approx(116.007, abs=0.01)
+    assert CELL.reader("expert_rows_skew")(ctx) == \
+        pytest.approx(rows_max / rows_mean) == pytest.approx(1.533, abs=1e-3)
+    # a file that names its experts by neither key has nothing to read
+    bare = {k: v for k, v in CONFIG.items() if k != "num_local_experts"}
+    ctx.config = bare
+    assert CELL.reader("experts_touched_share")(ctx) is None
 
 
 # -- a toy of the family through the harness, from files alone ----------------
