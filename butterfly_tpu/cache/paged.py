@@ -49,6 +49,18 @@ semantics, TPU-native mechanics):
   and the window are indifferent to which: they see Kv' heads of H'.
   Under tensor parallelism a token-major row shards its minor dim, a
   chip's KV heads contiguous in it (parallel/partition.py).
+* A THIRD row, for a latent-attention model (cfg.kv_lora_rank): ONE
+  tensor [L, P, 1, page, Rp] and NO value pool (v_pages is None). A
+  token's row is its normed latent and its rotated key (cfg.latent_row
+  values: 576, 1,152 B, for JoyAI-LLM-Flash) laid in whole lanes, Rp =
+  latent_row rounded up to 128 (640), the lanes behind the values zero:
+  the chip's tiled layout pads a row of 576 bfloat16 to five lane tiles
+  whatever shape is declared, and the kernel that reads a stream's
+  pages (ops/latent_attention.py) copies whole tiles, so the declared
+  shape is what the memory holds. No heads, nothing to quantize a head
+  at a time, nothing to shard by head: int8 KV and every mesh refuse
+  it by name. Writes, staging, the flush and the table gathers see one
+  head of Rp, as they see a token-major pool.
 """
 from __future__ import annotations
 
@@ -71,11 +83,13 @@ from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
 from butterfly_tpu.models.common import (
     _cast_float, attend, attend_token_rows, attn_output,
     early_router_logits, embed_tokens, ffn_close, final_logits,
-    index_proj, index_scores, indexer_unsupported, layer_at, layer_mask,
+    ffn_run, index_proj, index_scores, indexer_unsupported, latent_attend,
+    latent_proj, latent_queries, latent_unsupported, layer_at, layer_mask,
     layer_pattern_of, layer_runs, layer_stack, make_mask, pre_norm,
-    qkv_proj, quantize_kv, residual_add, select_mask, select_topk,
-    ssm_unsupported)
+    qkv_proj, quantize_kv, residual_add, run_layer_at, select_mask,
+    select_topk, ssm_unsupported)
 from butterfly_tpu.ops import note_kernel
+from butterfly_tpu.ops import latent_attention
 from butterfly_tpu.ops.flash_attention import flash_attention_sharded
 from butterfly_tpu.ops.paged_attention import paged_attention_sharded
 from butterfly_tpu.cache.ssm_state import SSMState, advance_packed
@@ -89,7 +103,8 @@ class PagedKVCache(NamedTuple):
     # a model of Mamba layers only: the table, the lengths and the
     # flush still work, over no layer)
     k_pages: jax.Array
-    v_pages: jax.Array
+    v_pages: Optional[jax.Array]  # None for a latent-attention model:
+                           # k_pages holds its ONE row a token (pool_row)
     page_table: jax.Array  # [slots, max_pages] int32, null = P-1
     lengths: jax.Array     # [slots] int32 tokens written per slot
     k_scale_pages: Optional[jax.Array] = None  # [L, P, Kv*page] f32 iff int8
@@ -124,6 +139,10 @@ class PagedKVCache(NamedTuple):
         return self.k_scale_pages is not None
 
 
+#: a TPU tile's minor dim: a latent row is laid in whole lanes
+LANES = 128
+
+
 def pool_row(cfg: ModelConfig) -> Tuple[int, int]:
     """(heads, width) of a cached token in a page [heads, page, width]:
     the ONE place that decides the pool's layout, from the configuration.
@@ -131,13 +150,20 @@ def pool_row(cfg: ModelConfig) -> Tuple[int, int]:
     across its KV heads (token-major: (1, Kv*H)), every other model a
     row a KV head (head-major: (Kv, H)); the module's docstring says
     why. The window and the sharding specs follow the pool."""
+    if cfg.is_latent:
+        # the latent and the rotary key in whole lanes (the module's
+        # docstring): 576 values in a row of 640
+        return 1, -(-cfg.latent_row // LANES) * LANES
     if cfg.has_indexer:
         return 1, cfg.num_kv_heads * cfg.head_dim
     return cfg.num_kv_heads, cfg.head_dim
 
 
 def pool_layout(cfg: ModelConfig) -> str:
-    """pool_row by name, as /health reports it: "token" or "head"."""
+    """pool_row by name, as /health reports it: "token", "head", or
+    "latent" (one row a token and no value pool)."""
+    if cfg.is_latent:
+        return "latent"
     return "token" if pool_row(cfg)[0] != cfg.num_kv_heads else "head"
 
 
@@ -164,6 +190,7 @@ def init_paged_cache(cfg: ModelConfig, runtime: RuntimeConfig,
         raise ValueError(f"unknown kv quant {runtime.kv_quant!r}")
     if runtime.kv_quant == "int8":
         indexer_unsupported(cfg, "the int8 KV cache")
+        latent_unsupported(cfg, "the int8 KV cache")
     ki_shape = (L, P, 1, page, cfg.index_head_dim)
 
     def build():
@@ -181,7 +208,7 @@ def init_paged_cache(cfg: ModelConfig, runtime: RuntimeConfig,
             )
         return PagedKVCache(
             k_pages=jnp.zeros(shape, dtype),
-            v_pages=jnp.zeros(shape, dtype),
+            v_pages=None if cfg.is_latent else jnp.zeros(shape, dtype),
             page_table=table, lengths=lengths,
             ki_pages=jnp.zeros(ki_shape, dtype) if cfg.has_indexer else None,
         )
@@ -253,9 +280,10 @@ def write_paged_layer(k_pages: jax.Array, v_pages: jax.Array,
             vs.reshape(B * T, Kv))
         return k_pages, v_pages, k_scale_pages, v_scale_pages
     kf = k.reshape(B * T, Kv, H).astype(k_pages.dtype)
-    vf = v.reshape(B * T, Kv, H).astype(v_pages.dtype)
     k_pages = k_pages.at[flat_pages, :, flat_off].set(kf)
-    v_pages = v_pages.at[flat_pages, :, flat_off].set(vf)
+    if v_pages is not None:     # a latent pool has no values of its own
+        vf = v.reshape(B * T, Kv, H).astype(v_pages.dtype)
+        v_pages = v_pages.at[flat_pages, :, flat_off].set(vf)
     return k_pages, v_pages, None, None
 
 
@@ -350,7 +378,7 @@ class KVWindow(NamedTuple):
     a clear, like every other pool in this codebase)."""
 
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array]           # None beside a latent pool
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
     ki: Optional[jax.Array] = None   # [L, S, 1, W, Hi] iff the pool has
@@ -374,6 +402,7 @@ def init_kv_window(cache: PagedKVCache, width: int,
     S = cache.num_slots
     shape = (L, S, Kv, width, H)
     quantized, dtype = cache.quantized, cache.k_pages.dtype
+    values = cache.v_pages is not None
 
     def build():
         if quantized:
@@ -385,7 +414,7 @@ def init_kv_window(cache: PagedKVCache, width: int,
         ki = None if cache.ki_pages is None else jnp.zeros(
             (L, S, 1, width, cache.ki_pages.shape[-1]), cache.ki_pages.dtype)
         return KVWindow(k=jnp.zeros(shape, dtype),
-                        v=jnp.zeros(shape, dtype), ki=ki)
+                        v=jnp.zeros(shape, dtype) if values else None, ki=ki)
 
     return jax.jit(build, out_shardings=shardings)()
 
@@ -414,7 +443,8 @@ def stage_window_layer(wk, wv, k, v, win_len, wks=None, wvs=None,
     rows = (jnp.arange(B) if rows is None else rows)[:, None]  # [B, 1]
     idx = win_len[:, None] + jnp.arange(T)[None, :]     # [B, T]
     # the heads as the window lays them (a token-major row: one of Kv*H)
-    k, v = (a.reshape(B, T, wk.shape[1], wk.shape[3]) for a in (k, v))
+    k, v = (None if a is None else a.reshape(B, T, wk.shape[1], wk.shape[3])
+            for a in (k, v))
     if wks is not None:
         kq, ks = quantize_kv(k)
         vq, vs = quantize_kv(v)
@@ -424,7 +454,8 @@ def stage_window_layer(wk, wv, k, v, win_len, wks=None, wvs=None,
         wvs = wvs.at[rows, :, idx].set(vs, mode="drop")
         return wk, wv, wks, wvs
     wk = wk.at[rows, :, idx].set(k.astype(wk.dtype), mode="drop")
-    wv = wv.at[rows, :, idx].set(v.astype(wv.dtype), mode="drop")
+    if wv is not None:          # a latent window has no values of its own
+        wv = wv.at[rows, :, idx].set(v.astype(wv.dtype), mode="drop")
     return wk, wv, None, None
 
 
@@ -664,7 +695,9 @@ def permute_paged_tail(cache: PagedKVCache, perm, active=None
 # ---------------------------------------------------------------------------
 
 #: what paged_forward and paged_forward_window are to a model whose
-#: streams hold a recurrent state: the packed mixed step carries it
+#: streams hold a recurrent state, or whose layers run as runs of two
+#: feed-forward shapes over a latent pool: the packed mixed step
+#: carries both
 ALTERNATING = ("the alternating prefill/decode path (paged_forward: "
                "mixed_dispatch off, the static scheduler, a draft model)")
 
@@ -800,6 +833,54 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
     return out, count.astype(jnp.float32)
 
 
+def latent_paged_attend(q, kp, layer, *, cfg: ModelConfig, page_table,
+                        positions, mask, active, use_kernel: bool,
+                        win=None):
+    """paged_attend for a latent-attention model, the ABSORBED read
+    (models/common.py): q [B,T,Nq,Rp] latent_queries' of these rows, in
+    the pool's lanes; kp [L,P,1,page,Rp] the WHOLE pool of rows (there
+    is no value pool) and `layer` the one to read; page_table,
+    positions, mask, active as paged_attend's; win as paged_attend's,
+    AFTER staging (its slice of rows [B,1,W,Rp]; the others None).
+    Returns (o' [B,T,Nq,kv_lora_rank], count f32 [1]): the cached rows
+    the DECODE rows read (a live row at position p reads p + 1, itself
+    among them; a chunk's rows count nothing), what the tick record's
+    `latent_rows` sums over the layers.
+
+    A decode row (T == 1) reads its stream's live pages ONCE through
+    the Pallas kernel (ops/latent_attention.py) where kernels are on;
+    a chunk's rows (T > 1) share one stream's context, which is
+    gathered whole and attended by latent_attend, as is every row where
+    kernels are off. Either way no head's keys or values of the
+    context exist: scores and sums are over the rows as cached."""
+    T = q.shape[1]
+    start = positions[:, 0]
+    if win is not None:
+        wk, win_len = win[0], win[4]
+        base = start - win_len      # flushed pool length per row
+    out = None
+    if use_kernel and T == 1 and latent_attention.fits(kp, cfg.kv_lora_rank):
+        # pool rows up to the FLUSHED length and the window's staged run
+        # with the token just staged, or the pool alone with the token
+        # just written
+        lens = (jnp.where(active, base, 0), wk,
+                jnp.where(active, win_len + 1, 0)) if win is not None \
+            else (jnp.where(active, start + 1, 0),)
+        out = latent_attention.latent_attention(
+            q[:, 0], kp, layer, page_table, *lens, rank=cfg.kv_lora_rank,
+            scale=cfg.qk_head_dim ** -0.5)
+        out = out[:, None]
+    if out is None:
+        if use_kernel and T == 1:
+            note_kernel("dense_fallback")
+        rows = gather_paged_layer(kp, page_table, layer)    # [B,S_max,1,Rp]
+        if win is not None:
+            rows = insert_window_view(rows, wk, base)
+        out = latent_attend(q, *_settled(rows[:, :, 0]), mask, cfg)
+    read = jnp.sum(jnp.where(active, start + 1, 0)) if T == 1 else 0
+    return out, jnp.asarray(read, jnp.float32).reshape(1)
+
+
 def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
                  positions, mask, active, use_kernel: bool, fresh: bool,
                  ksp=None, vsp=None, win=None, force_dense: bool = False,
@@ -827,7 +908,14 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
     keys and the window's slice of them [B,1,W,Hi] (None, window off).
     Such a layer attends through sparse_paged_attend and nowhere else.
     Returns [B,T,Nq,H]; with `index`, that and sparse_paged_attend's
-    count."""
+    count. A latent-attention model (q its absorbed queries, k its
+    rows, no v and no vp) attends through latent_paged_attend and
+    nowhere else, and returns that function's pair."""
+    if cfg.is_latent:
+        return latent_paged_attend(
+            q, kp, layer, cfg=cfg, page_table=page_table,
+            positions=positions, mask=mask, active=active,
+            use_kernel=use_kernel, win=win)
     if index is not None:
         qi, w, kip, wki = index
         return sparse_paged_attend(
@@ -941,6 +1029,14 @@ def _layer_open(x, lp, cfg: ModelConfig, cos, sin):
     share, so that a change to a norm or a projection reaches both."""
     lp = jax.tree.map(lambda a: _cast_float(a, jnp.dtype(cfg.dtype)), lp)
     h = pre_norm(x, lp["ln1"], cfg)
+    if cfg.is_latent:
+        # the absorbed queries and the row a token caches, each laid in
+        # the pool's lanes (pool_row: zeros behind the values); no v
+        q_nope, q_rope, row = latent_proj(h, lp["attn"], cfg, cos, sin)
+        q = latent_queries(q_nope, q_rope, lp["attn"], cfg)
+        pad = [(0, 0)] * 3 + [(0, pool_row(cfg)[1] - cfg.latent_row)]
+        return (lp, jnp.pad(q, pad), jnp.pad(row[:, :, None], pad), None,
+                None, None, None)
     rope, sliding_window = layer_pattern_of(lp.get("pattern"))
     q, k, v = qkv_proj(h, lp["attn"], cfg, cos, sin, rope)
     index = index_proj(x, lp, cfg, cos, sin) if cfg.has_indexer else None
@@ -1062,6 +1158,7 @@ def paged_forward(params, cfg: ModelConfig, tokens: jax.Array,
     entries' storage positions equal their RoPE positions again.
     """
     ssm_unsupported(cfg, ALTERNATING)
+    latent_unsupported(cfg, ALTERNATING)
     B, T = tokens.shape
     if positions is None:
         positions = cache.lengths[:, None] + jnp.arange(T)[None, :]
@@ -1129,6 +1226,7 @@ def paged_forward_window(params, cfg: ModelConfig, tokens: jax.Array,
     insert path.
     """
     ssm_unsupported(cfg, ALTERNATING)
+    latent_unsupported(cfg, ALTERNATING)
     B, T = tokens.shape
     if active is None:
         active = jnp.ones((B,), bool)
@@ -1243,7 +1341,9 @@ def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
     layer's routing asked of its experts for the step's real rows
     (_layer_close; None for a dense model). For a model with an indexer
     `load` carries three values more: sparse_paged_attend's count of
-    the step's DECODE rows."""
+    the step's DECODE rows; for a latent-attention model one value
+    more, latent_paged_attend's count (and `load` is that alone in a
+    leading dense layer, which routes nothing)."""
     S, (P, C) = rows.written.shape[0], rows.chunk_pos.shape
     lp, q, k, v, route, sliding_window, index = _layer_open(
         x, lp, cfg, rows.cos, rows.sin)
@@ -1279,48 +1379,55 @@ def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
         return None if index is None \
             else (cut(index[0]), cut(index[2]), kip, wki)
 
-    out = attend_rows(q[:S], k[:S], v[:S], page_table=rows.page_table,
+    out = attend_rows(q[:S], k[:S], None if v is None else v[:S],
+                      page_table=rows.page_table,
                       positions=rows.written[:, None], mask=rows.dec_mask,
                       active=rows.active, win=dec_win,
                       index=rows_index(lambda a: a[:S], wki))
     count = None
-    if index is not None:
+    counted = index is not None or cfg.is_latent
+    if counted:
         out, count = out
     if P:
         def chunks(a):
             """Rows S.. of a packed [N, 1, ...] array as [P, C, ...]."""
-            return a[S:].reshape(P, C, *a.shape[2:])
+            return None if a is None else a[S:].reshape(P, C, *a.shape[2:])
 
         out_c = attend_rows(chunks(q), chunks(k), chunks(v),
                             page_table=rows.chunk_table,
                             positions=rows.chunk_pos, mask=rows.chunk_mask,
                             active=rows.chunk_ok, win=chunk_win,
                             index=rows_index(chunks, chunk_wki))
-        if index is not None:
+        if counted:
             out_c = out_c[0]
         out = jnp.concatenate(
             [out, out_c.reshape(P * C, 1, *out_c.shape[2:])])
     x, load = _layer_close(x, out, lp, cfg, route, rows.ok[:, None])
     if count is not None:
-        load = jnp.concatenate([jnp.zeros((3,), jnp.float32)
-                                if load is None else load, count])
+        if load is None and not cfg.is_latent:
+            load = jnp.zeros((3,), jnp.float32)
+        load = count if load is None else jnp.concatenate([load, count])
     return x, pools, wl, load
 
 
 def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
                  cache: PagedKVCache, window: Optional[KVWindow],
-                 state: SSMState, use_kernel: bool):
-    """The layers of a packed step for a model with layers of two kinds
-    (cfg.layer_types), in the published order: each run of one kind
-    (models.common.layer_runs) is one scan that rides the layers'
-    indices, into params["layers"] (norms, feed-forward) and into its
-    kind's own stack. A Mamba run carries the recurrent state
-    (ssm_state.advance_packed); an attention run is packed_layer as
-    every other model runs it, over the pool's layer a (the pool holds
-    attention layers only): window on, the read-only pool whole and the
-    run's window slices as xs; window off, the run's pool slices.
-    Returns (x, window or cache as written, state, load): load the
-    mean of the layers' expert_load."""
+                 state: Optional[SSMState], use_kernel: bool):
+    """The layers of a packed step for a model whose layers are of
+    unlike SHAPES, in the published order: mixers of two kinds
+    (cfg.layer_types) or feed-forwards of two kinds (cfg.first_k_dense).
+    Each run of one kind (models.common.layer_runs) is one scan that
+    rides the layers' indices, into params["layers"] (what every layer
+    has), into its mixer's own stack where there is one (params["mamba"],
+    params["attn"]) and into its feed-forward's (ffn_run). A Mamba run
+    carries the recurrent state (ssm_state.advance_packed); an
+    attention run is packed_layer as every other model runs it, over the
+    pool's layer a (the pool holds attention layers only): window on,
+    the read-only pool whole and the run's window slices as xs; window
+    off, the run's pool slices. Returns (x, window or cache as written,
+    state, load): load the mean of expert_load over the layers that
+    route; a latent-attention model's ends in the SUM over the layers
+    of latent_paged_attend's count."""
     pools = pool_leaves(cache, absent=True)
     held = pools if window is None else window_leaves(window, absent=True)
     written, loads = [], []
@@ -1333,10 +1440,11 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
             layer_at(params["mamba"], m, cfg), st, m, rows, cfg, use_kernel)
         return (x, st), load
 
-    def attention(x, scanned):
+    def attention(ffn, x, scanned):
         (l, a), *mine = scanned
-        lp = {**layer_at(params["layers"], l, cfg),
-              "attn": layer_at(params["attn"], a, cfg)}
+        lp = run_layer_at(params, ffn, l, cfg)
+        if "attn" in params:
+            lp = {**lp, "attn": layer_at(params["attn"], a, cfg)}
         if window is None:
             x, new, _, load = packed_layer(x, lp, mine, None, rows, cfg,
                                            use_kernel)
@@ -1351,8 +1459,8 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
             (x, state), load = lax.scan(mamba, (x, state), idx)
         else:
             x, (new, load) = lax.scan(
-                attention, x, (idx, *(None if a is None else a[at:at + n]
-                                      for a in held)))
+                partial(attention, ffn_run(params, first, cfg)), x,
+                (idx, *(None if a is None else a[at:at + n] for a in held)))
             written.append(new)
         loads.append(load)
     if written:
@@ -1361,6 +1469,13 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
             None if a[0] is None else jnp.concatenate(a)
             for a in zip(*written))
     kv = pool_leaves(cache, held) if window is None else KVWindow(*held)
+    if cfg.is_latent:
+        # a dense layer's load is its count alone, an expert layer's
+        # ends in it
+        experts = jnp.concatenate([l[:, :3] for l in loads
+                                   if l.shape[1] > 1]).mean(axis=0)
+        read = sum(l[:, -1].sum() for l in loads)
+        return x, kv, state, jnp.concatenate([experts, read[None]])
     return x, kv, state, jnp.concatenate(loads).mean(axis=0)
 
 
@@ -1429,7 +1544,9 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
     rows, the mean over the layers (None for a dense model): distinct
     experts touched, rows of the fullest expert, mean rows an expert.
     A model with an indexer adds sparse_paged_attend's three: decode
-    rows, the positions they could attend, the positions they read.
+    rows, the positions they could attend, the positions they read; a
+    latent-attention model one: the cached rows its decode rows read,
+    summed over the layers (latent_paged_attend).
     (Under pipeline stages: parallel/pipeline.py paged_pipeline_packed,
     the same pieces over stage-local layers.)
 
@@ -1442,14 +1559,16 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
     """
     x, rows = packed_rows(params, cfg, tokens, cache, chunk_tokens,
                           chunk_slot, chunk_count, active, window, win_len)
-    if cfg.has_ssm:
+    if cfg.has_ssm or cfg.first_k_dense:
         x, kv, state, load = _packed_runs(params, cfg, x, rows, cache,
                                           window, state, use_kernel)
+        logits = final_logits(params, cfg, x[rows.head])[:, 0]
+        if not cfg.has_ssm:
+            return logits, kv, load
         ssm = jnp.stack([jnp.sum(rows.ok), jnp.sum(
             rows.chunk_ok & (rows.chunk_pos[:, 0] == 0))])
         load = jnp.concatenate([load, ssm.astype(jnp.float32)])
-        return (final_logits(params, cfg, x[rows.head])[:, 0], kv, load,
-                state)
+        return logits, kv, load, state
     # an absent pool or window tensor rides the scan as None (no leaf)
     if window is None:
         def body(x, scanned):

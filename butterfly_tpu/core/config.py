@@ -19,14 +19,16 @@ class ModelConfig:
     """Architecture hyperparameters for a transformer LM.
 
     One config class covers the model families (GPT-2, Llama-3,
-    Mixtral, SmallThinker, Keye, Granite-4.0-H) — the family is selected
-    by `arch`, the MoE fields, the per-layer attention pattern, the
-    sparse-attention indexer and the per-layer KIND (`layer_types`:
-    Mamba-2 mixers beside attention layers).
+    Mixtral, SmallThinker, Keye, Granite-4.0-H, JoyAI-LLM-Flash) — the
+    family is selected by `arch`, the MoE fields, the per-layer
+    attention pattern, the sparse-attention indexer, the per-layer KIND
+    (`layer_types`: Mamba-2 mixers beside attention layers) and the
+    latent-attention fields (`kv_lora_rank` and the split head dims:
+    one cached latent a token in place of heads of keys and values).
     """
 
     arch: str = "llama"  # "gpt2" | "llama" | "mixtral" | "smallthinker"
-                         # | "keye" | "granite_hybrid"
+                         # | "keye" | "granite_hybrid" | "joyai"
     vocab_size: int = 32000
     hidden_size: int = 4096
     num_layers: int = 32
@@ -61,6 +63,47 @@ class ModelConfig:
     shared_intermediate_size: int = 0  # width of ONE shared expert every
                                       # token passes beside its routed
                                       # experts, added unweighted; 0 = none
+    moe_intermediate_size: int = 0    # width of one routed expert where
+                                      # it differs from the dense
+                                      # feed-forward's (intermediate_size);
+                                      # 0 = the same
+    first_k_dense: int = 0            # leading layers whose feed-forward
+                                      # is DENSE (intermediate_size) in a
+                                      # model of experts. The two kinds
+                                      # have unlike parameter shapes:
+                                      # params["dense"] and
+                                      # params["sparse"] stack each apart,
+                                      # and the layers run as scans over
+                                      # runs (models/common.py layer_runs)
+    router_score: str = "softmax"     # "softmax": a softmax over the
+                                      # chosen k's logits (Mixtral);
+                                      # "sigmoid": s = sigmoid(logits),
+                                      # the chosen k's s divided by their
+                                      # sum (DeepSeek-V3's noaux_tc)
+    router_bias: bool = False         # a stored bias an expert that is
+                                      # added to the scores for the
+                                      # CHOICE of the k and never to
+                                      # their weights
+    routed_scaling_factor: float = 0.0  # on the routed experts' weights;
+                                      # 0 = the family has none
+
+    # latent attention (MLA): the query through a latent of q_lora_rank,
+    # keys and values through ONE joint latent
+    # of kv_lora_rank a token beside ONE rotary key of qk_rope_head_dim
+    # shared by all heads. A head's query and key are qk_nope_head_dim
+    # + qk_rope_head_dim wide (the second part rotates), its value
+    # v_head_dim; the score scale is (nope + rope) ** -0.5. What a
+    # token caches is the normed latent and the rotated key: one row of
+    # kv_lora_rank + qk_rope_head_dim, no heads and no values
+    # (cache/paged.py pool_row); head_dim and num_kv_heads are not read.
+    # All five are 0 for a model without.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False     # the rotation pairs dims (2i, 2i+1)
+                                      # of a head, not (i, i + half)
 
     # per-layer KIND: "mamba" (a Mamba-2 mixer with a fixed-size
     # recurrent state a stream: cache/ssm_state.py) or "attention" (keys
@@ -156,6 +199,8 @@ class ModelConfig:
         object.__setattr__(self, "layer_types", kinds)
         if self.router_input not in ("ffn", "attn"):
             raise ValueError(f"unknown router_input {self.router_input!r}")
+        self._check_latent()
+        self._check_router()
         if bool(self.sliding_window_layout) != (self.sliding_window > 0):
             raise ValueError("sliding_window and sliding_window_layout "
                              "come together: which layers slide is stated")
@@ -170,9 +215,102 @@ class ModelConfig:
             raise ValueError("expert parallelism (moe_impl 'ep') does not "
                              "carry router logits taken before attention")
 
+    def _check_latent(self):
+        """The latent-attention fields come together, and beside
+        nothing that has no path for a cached latent yet."""
+        split = ("q_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+                 "v_head_dim")
+        missing = [n for n in split if getattr(self, n) <= 0]
+        if not self.kv_lora_rank:
+            given = [n for n in split if getattr(self, n)]
+            if given or self.rope_interleave:
+                raise ValueError(
+                    f"{given or ['rope_interleave']} without kv_lora_rank: "
+                    "the split head dims and the query's rank describe "
+                    "latent attention, which has a latent of kv_lora_rank")
+            return
+        if missing:
+            raise ValueError(
+                f"kv_lora_rank {self.kv_lora_rank} without {missing}: "
+                "latent attention has a query latent (q_lora_rank) and a "
+                "head's qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("qk_rope_head_dim rotates in pairs: it is even")
+        if self.pos_embedding != "rope":
+            raise ValueError("latent attention rotates its shared key: "
+                             f"pos_embedding is {self.pos_embedding!r}")
+        for name, on in (("layer_types", bool(self.layer_types)),
+                         ("index_topk", self.has_indexer),
+                         ("sliding_window", self.layer_pattern() is not None),
+                         ("qk_norm", self.qk_norm),
+                         ("use_bias", self.use_bias),
+                         ("attention_multiplier",
+                          bool(self.attention_multiplier))):
+            if on:
+                raise ValueError(f"{name} beside kv_lora_rank (latent "
+                                 "attention) is not supported")
+
+    def _check_router(self):
+        """The router's and the experts' fields describe a model of
+        experts; leading dense layers are fewer than the layers."""
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router_score {self.router_score!r}")
+        for name, on in (("router_score", self.router_score != "softmax"),
+                         ("router_bias", self.router_bias),
+                         ("routed_scaling_factor",
+                          bool(self.routed_scaling_factor)),
+                         ("moe_intermediate_size",
+                          bool(self.moe_intermediate_size)),
+                         ("first_k_dense", bool(self.first_k_dense))):
+            if not on:
+                continue
+            if not self.is_moe:
+                raise ValueError(f"{name} without num_experts: it "
+                                 "describes a model of routed experts")
+            if self.moe_impl == "ep" and name != "moe_intermediate_size":
+                raise ValueError(f"expert parallelism (moe_impl 'ep') does "
+                                 f"not carry {name}")
+        if not 0 <= self.first_k_dense < max(self.num_layers, 1):
+            raise ValueError(
+                f"first_k_dense {self.first_k_dense} of {self.num_layers} "
+                "layers: the leading dense layers are fewer than the "
+                "layers, and the rest have experts")
+        if self.first_k_dense and not self.is_latent:
+            raise ValueError(
+                "first_k_dense without kv_lora_rank: feed-forwards of two "
+                "shapes run as layer runs, which the latent-attention "
+                "family's forward carries and no other yet")
+
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_latent(self) -> bool:
+        """Latent attention: a token caches one latent row, not heads."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_row(self) -> int:
+        """Values of a token's cached row: the latent and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Width of a head's query and key (a latent model's two parts)."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim \
+            if self.is_latent else self.head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        """Dims of a head that rotate: all of them, or a latent model's
+        rotary part."""
+        return self.qk_rope_head_dim if self.is_latent else self.head_dim
+
+    @property
+    def expert_width(self) -> int:
+        """Width of one routed expert."""
+        return self.moe_intermediate_size or self.intermediate_size
 
     def layer_pattern(self):
         """{"sliding_window": int32 [L] (0 = the layer is full),
@@ -325,6 +463,28 @@ def granite_4_h_small() -> ModelConfig:
     )
 
 
+def joyai_llm_flash() -> ModelConfig:
+    """JoyAI-LLM-Flash (huggingface.co/jdopensource, `joyai_llm_flash`,
+    48B-A2.7B): 40 layers of latent attention (32 heads of 128 + 64
+    rotary against ONE cached row of 512 + 64 a token, values of 128,
+    the query through a latent of 1,536); layer 0's feed-forward is
+    dense (7,168), every other layer has 256 sigmoid-routed experts of
+    768, 8 a token chosen with a selection bias and weighted by their
+    normalised scores times 2.5, plus one shared expert; untied head.
+    The published prediction layer (`num_nextn_predict_layers` 1) takes
+    no part in the next-token distribution and is not held."""
+    return ModelConfig(
+        arch="joyai", vocab_size=129280, hidden_size=2048, num_layers=40,
+        num_heads=32, num_kv_heads=32, head_dim=64, intermediate_size=7168,
+        max_seq_len=131072, norm_eps=1e-6, rope_theta=32e6,
+        num_experts=256, num_experts_per_tok=8, moe_intermediate_size=768,
+        shared_intermediate_size=768, first_k_dense=1,
+        router_score="sigmoid", router_bias=True, routed_scaling_factor=2.5,
+        kv_lora_rank=512, q_lora_rank=1536, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, rope_interleave=True,
+    )
+
+
 def tiny(arch: str = "llama", **kw) -> ModelConfig:
     """Small config for tests: runs in <1s on CPU, exercises every code path."""
     base = dict(
@@ -363,6 +523,19 @@ def tiny(arch: str = "llama", **kw) -> ModelConfig:
                     pos_embedding="none", tie_embeddings=True,
                     embedding_multiplier=12.0, residual_multiplier=0.22,
                     attention_multiplier=0.0625, logits_scaling=16.0)
+    if arch == "joyai":
+        # every mechanism of the real one: the query's latent, one cached
+        # row of latent + rotary key, value heads narrower than the
+        # query's, interleaved rotation, a leading dense layer of its
+        # own width, sigmoid routing with a bias and a scale, a shared
+        # expert
+        base.update(num_layers=3, intermediate_size=96,
+                    moe_intermediate_size=32, shared_intermediate_size=32,
+                    num_experts=8, num_experts_per_tok=3, first_k_dense=1,
+                    router_score="sigmoid", router_bias=True,
+                    routed_scaling_factor=2.5, kv_lora_rank=32,
+                    q_lora_rank=48, qk_nope_head_dim=16,
+                    qk_rope_head_dim=8, v_head_dim=16, rope_interleave=True)
     base.update(kw)
     return ModelConfig(arch=arch, **base)
 
@@ -375,6 +548,7 @@ PRESETS = {
     "smallthinker-21b-a3b": smallthinker_21b_a3b,
     "keye-vl2-30b-a3b": keye_vl2_30b_a3b,
     "granite-4.0-h-small": granite_4_h_small,
+    "joyai-llm-flash": joyai_llm_flash,
 }
 
 

@@ -69,7 +69,7 @@ from butterfly_tpu.engine.sampling import (
     tree_ancestor_matrix, tree_depth, tree_node_index)
 from butterfly_tpu.cache.ssm_state import SSMState, init_ssm_state
 from butterfly_tpu.models.common import (
-    Model, indexer_unsupported, ssm_unsupported)
+    Model, indexer_unsupported, latent_unsupported, ssm_unsupported)
 
 
 #: the span a program launch runs under (`bf.tick.dispatch.launch` in a
@@ -263,6 +263,26 @@ def _draft_rollback(dstate, dlen0, live, m):
 class ServingEngine:
     """Device-side half of the serving stack (host half: sched/)."""
 
+    def _refuse_latent(self, mesh) -> None:
+        """What a latent-attention model's cached row is not carried
+        through refuses the model by name: the row has no heads (nothing
+        for a mesh to shard, nothing for int8 KV to scale a head at a
+        time), and its pool is one tensor where export, the host tier,
+        the lanes and a draft's rollback expect keys and values."""
+        rt = self.runtime
+        if mesh is not None and mesh.size > 1:
+            latent_unsupported(self.cfg, "a device mesh (" + ", ".join(
+                f"{a}={n}" for a, n in mesh.shape.items() if n > 1) + ")")
+        if rt.kv_quant != "none":
+            latent_unsupported(self.cfg, "the int8 KV cache")
+        if rt.speculative_gamma > 0:
+            latent_unsupported(self.cfg, "speculative decoding")
+        if rt.prefix_caching:
+            latent_unsupported(self.cfg, "prefix caching (and the host KV "
+                                         "tier behind it)")
+        if not rt.mixed_dispatch or rt.scheduler != "continuous":
+            latent_unsupported(self.cfg, ALTERNATING)
+
     def __init__(self, model: Model, params,
                  runtime: Optional[RuntimeConfig] = None, mesh=None,
                  use_kernels: Optional[bool] = None):
@@ -277,6 +297,7 @@ class ServingEngine:
         self.tracer = None
         self.params = cast_params(params, self.cfg)
         self.mesh = mesh
+        self._refuse_latent(mesh)
         stage = mesh.shape.get("stage", 1) if mesh is not None else 1
         if stage > 1 and self.cfg.num_layers % stage != 0:
             raise ValueError(
@@ -1146,6 +1167,7 @@ class ServingEngine:
         writing other pages cannot race the bytes."""
         indexer_unsupported(self.cfg, "KV page export (read_pages)")
         ssm_unsupported(self.cfg, "KV page export (read_pages)")
+        latent_unsupported(self.cfg, "KV page export (read_pages)")
         if self._win_dirty:
             self.flush_kv_window()
         idx = jnp.asarray(pids, jnp.int32)
@@ -1169,6 +1191,7 @@ class ServingEngine:
         runs."""
         indexer_unsupported(self.cfg, "KV page import (write_pages)")
         ssm_unsupported(self.cfg, "KV page import (write_pages)")
+        latent_unsupported(self.cfg, "KV page import (write_pages)")
         idx = jnp.asarray(pids, jnp.int32)
         with self._mesh_ctx():
             kp = self.cache.k_pages.at[:, idx].set(
@@ -1998,7 +2021,9 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
     read-only, as ever) and comes back as the ninth value; `load` then
     ends in two SUMS over the block's steps: the positions pushed
     through a recurrence (decode rows and real chunk columns) and the
-    slots that started from zero.
+    slots that started from zero. A latent-attention model's `load`
+    ends in ONE such sum: the cached rows its decode rows read, over
+    the layers (cache/paged.py latent_paged_attend).
     """
     S = tokens.shape[0]
     H = pbuf.shape[1]
@@ -2067,7 +2092,7 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
         rows = load[:, 3:].sum(axis=0)
         load = jnp.concatenate([experts, rows[1:] / jnp.maximum(rows[0], 1)]) \
             if cfg.has_indexer else experts
-        if cfg.has_ssm:
+        if cfg.has_ssm or cfg.is_latent:
             load = jnp.concatenate([experts, rows])
     return (block, valid, final, cursor, cache, window, win_len, load,
             st[0] if st else None)

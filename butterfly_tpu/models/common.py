@@ -35,7 +35,7 @@ from butterfly_tpu.core.config import ModelConfig
 # ops.flash_attention imports nothing project-local at module level.
 from butterfly_tpu.ops import note_kernel
 from butterfly_tpu.ops.flash_attention import flash_attention_sharded
-from butterfly_tpu.quant.int8 import qeinsum
+from butterfly_tpu.quant.int8 import maybe_dequant, qeinsum
 
 Params = Dict[str, Any]
 
@@ -66,7 +66,10 @@ class KVCache(NamedTuple):
     """
 
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array]  # None for a latent-attention model: k holds
+                            # ONE row a token, [L, B, S, 1, latent_row]
+                            # (the latent, which is also the values, and
+                            # the rotated key), and nothing else is cached
     length: jax.Array  # [B] int32
     k_scale: Optional[jax.Array] = None  # [L,B,Kv,S] f32 iff k is int8
     v_scale: Optional[jax.Array] = None
@@ -90,6 +93,13 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
                dtype: Optional[jnp.dtype] = None,
                quant: str = "none") -> KVCache:
     dtype = dtype or jnp.dtype(cfg.dtype)
+    if cfg.is_latent:
+        if quant != "none":
+            latent_unsupported(cfg, "the int8 contiguous KV cache")
+        return KVCache(
+            k=jnp.zeros((cfg.num_layers, batch, max_seq, 1, cfg.latent_row),
+                        dtype),
+            v=None, length=jnp.zeros((batch,), jnp.int32))
     if cfg.has_ssm:
         if quant != "none":
             ssm_unsupported(cfg, "the int8 contiguous KV cache")
@@ -169,8 +179,9 @@ ACTIVATIONS = {
 
 
 def rope_freqs(cfg: ModelConfig, positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """cos/sin tables for positions [..., T] -> [..., T, head_dim/2], f32."""
-    half = cfg.head_dim // 2
+    """cos/sin tables for positions [..., T] -> [..., T, rope_dim/2], f32
+    (rope_dim: head_dim, or a latent model's rotary part)."""
+    half = cfg.rope_dim // 2
     inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
     angles = positions.astype(jnp.float32)[..., None] * inv_freq  # [..., T, half]
     return jnp.cos(angles), jnp.sin(angles)
@@ -188,6 +199,22 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     r1 = x1 * cos - x2 * sin
     r2 = x2 * cos + x1 * sin
     return jnp.concatenate([r1, r2], axis=-1)
+
+
+def apply_rope_pairs(x: jax.Array, cos: jax.Array, sin: jax.Array
+                     ) -> jax.Array:
+    """The rotation over PAIRS (2i, 2i+1) of the last dim (cfg.
+    rope_interleave), pair i by frequency i. x: [B, T, N, H]; cos/sin
+    [B, T, H/2]. The rotated pair (2i, 2i+1) comes back at (i, i + H/2):
+    queries and keys are both laid so, and a score, a sum over the
+    dims, is the same whatever order both share. The even and the odd
+    dims are each one strided read; putting them back in pairs would be
+    a shuffle of every row for nothing."""
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    cos = cos[..., None, :].astype(x.dtype)
+    sin = sin[..., None, :].astype(x.dtype)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1)
 
 
 def update_cache_layer(ck: jax.Array, cv: jax.Array, k: jax.Array, v: jax.Array,
@@ -446,7 +473,8 @@ def select_mask(scores: jax.Array, valid: jax.Array, k: int) -> jax.Array:
 def index_cache_write(cki: jax.Array, ki: jax.Array,
                       start: jax.Array) -> jax.Array:
     """Write index keys ki [B,T,Hi] into the contiguous cache's
-    cki [B,S,Hi] at per-sequence offsets (update_cache_layer's twin)."""
+    cki [B,S,Hi] at per-sequence offsets (update_cache_layer's twin);
+    a latent-attention model's rows [B,T,latent_row] are written so too."""
     def upd(cache_b, new_b, start_b):
         return lax.dynamic_update_slice(cache_b, new_b, (start_b, 0))
     return jax.vmap(upd)(cki, ki.astype(cki.dtype), start)
@@ -465,11 +493,115 @@ def indexer_unsupported(cfg: ModelConfig, what: str) -> None:
 
 @jax.named_scope("attn")
 def attn_output(out: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
-    """Output projection of the attention sublayer. out: [B,T,Nq,H]."""
+    """Output projection of the attention sublayer. out: [B,T,Nq,H];
+    for a latent model [B,T,Nq,v_head_dim], or the ABSORBED read's o'
+    [B,T,Nq,kv_lora_rank], the weighted sum of latents, which each
+    head's value expansion W_uv takes to its v_head_dim first."""
+    if cfg.is_latent and out.shape[-1] == cfg.kv_lora_rank:
+        with jax.named_scope("attn_latent_expand"):
+            out = qeinsum("btnr,rnh->btnh", out, p["w_uv"], out.dtype)
     out = qeinsum("btnh,nhd->btd", out, p["wo"], out.dtype)
     if cfg.use_bias:
         out = out + p["bo"]
     return out
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA; cfg.kv_lora_rank and the split head dims)
+#
+#   c_q = norm_q(h W_dq);  [q_nope | q_rope] = c_q W_uq      a head: nope | rope
+#   [c_kv | k_r] = h W_dkv;  c_kv = norm_kv(c_kv);  k_r ONE rotary key a token
+#   q_rope, k_r rotated.  What a token CACHES: [c_kv | k_r], one row
+#
+# EXPANDED (no cached context is read): k_nope,h = c_kv W_uk,h and v_h =
+# c_kv W_uv,h a head, score = (q_nope . k_nope + q_rope . k_r) x scale.
+# ABSORBED (cached rows are read): q'_h = q_nope,h W_uk,h^T, score =
+# ([q'_h | q_rope,h] . [c_kv | k_r]) x scale: multi-query attention of
+# Nq heads over ONE shared row, whose first kv_lora_rank values are also
+# its "values": o'_h = sum p c_kv, and attn_output expands o'_h W_uv,h.
+# The same numbers; no per-head key or value of the context exists in
+# the absorbed form, and the row is read once. scale = (nope + rope) **
+# -0.5 both ways. latent_proj, latent_queries and the two attends below
+# are the ONE definition that the contiguous path and the paged path
+# (cache/paged.py latent_paged_attend) share.
+# ---------------------------------------------------------------------------
+
+def latent_unsupported(cfg: ModelConfig, what: str) -> None:
+    """Refuse a latent-attention model on a path that does not carry
+    its cached row: one latent a token, no heads (nothing to shard by
+    head or to quantize a head at a time) and no separate values."""
+    if cfg.is_latent:
+        raise NotImplementedError(
+            f"{what} does not carry the cached latent row of a "
+            f"latent-attention model (kv_lora_rank {cfg.kv_lora_rank}: one "
+            f"row of {cfg.latent_row} a token, no heads, no values): not "
+            "supported for this model")
+
+
+@jax.named_scope("attn_latent_proj")
+def latent_proj(h: jax.Array, p: Params, cfg: ModelConfig, cos, sin):
+    """The projections of the normed input h [B,T,D]: (q_nope
+    [B,T,Nq,nope], q_rope [B,T,Nq,rope] rotated, row [B,T,latent_row] =
+    [norm_kv(c_kv) | rotated k_r], what the token caches)."""
+    dt = h.dtype
+    rotate = apply_rope_pairs if cfg.rope_interleave else apply_rope
+    cq = rms_norm(qeinsum("btd,dr->btr", h, p["w_dq"], dt),
+                  p["q_norm"]["scale"], cfg.norm_eps)
+    q = qeinsum("btr,rnh->btnh", cq, p["w_uq"], dt)
+    q_nope, q_rope = q[..., :cfg.qk_nope_head_dim], \
+        q[..., cfg.qk_nope_head_dim:]
+    ckv = qeinsum("btd,dr->btr", h, p["w_dkv"], dt)
+    c = rms_norm(ckv[..., :cfg.kv_lora_rank], p["kv_norm"]["scale"],
+                 cfg.norm_eps)
+    k_r = rotate(ckv[..., None, cfg.kv_lora_rank:], cos, sin)[:, :, 0]
+    return q_nope, rotate(q_rope, cos, sin), \
+        jnp.concatenate([c, k_r], axis=-1)
+
+
+@jax.named_scope("attn_latent_proj")
+def latent_queries(q_nope: jax.Array, q_rope: jax.Array, p: Params,
+                   cfg: ModelConfig) -> jax.Array:
+    """The ABSORBED queries [B,T,Nq,latent_row]: a head's q_nope taken
+    through its key expansion W_uk,h^T onto the latent, beside its
+    rotary part. W_uk's int8 scale runs along the dim contracted here,
+    so the leaf is dequantized first (2 M values a layer)."""
+    w_uk = maybe_dequant(p["w_uk"], q_nope.dtype)          # [R, Nq, nope]
+    return jnp.concatenate(
+        [jnp.einsum("btnh,rnh->btnr", q_nope, w_uk), q_rope], axis=-1)
+
+
+@jax.named_scope("attn_latent")
+def latent_attend(q: jax.Array, rows: jax.Array, mask: jax.Array,
+                  cfg: ModelConfig) -> jax.Array:
+    """The absorbed read: q [B,T,Nq,latent_row] (latent_queries) against
+    cached rows [B,S,latent_row], mask [B,T,S]. Returns o'
+    [B,T,Nq,kv_lora_rank]. Softmax in float32, as attend's."""
+    scale = cfg.qk_head_dim ** -0.5
+    s = jnp.einsum("btnr,bsr->bnts", q, rows,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(mask[:, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bnts,bsr->btnr", p, rows[..., :cfg.kv_lora_rank])
+
+
+@jax.named_scope("attn_latent")
+def latent_attend_expanded(q_nope, q_rope, rows, mask, p: Params,
+                           cfg: ModelConfig) -> jax.Array:
+    """The expanded form over the call's OWN rows [B,T,latent_row] (a
+    prefill that reads no cached context): every head's keys and values
+    are materialised from the latents. Returns [B,T,Nq,v_head_dim]."""
+    dt = q_nope.dtype
+    c, k_r = rows[..., :cfg.kv_lora_rank], rows[..., cfg.kv_lora_rank:]
+    k_nope = qeinsum("bsr,rnh->bsnh", c, p["w_uk"], dt)
+    v = qeinsum("bsr,rnh->bsnh", c, p["w_uv"], dt)
+    scale = cfg.qk_head_dim ** -0.5
+    s = (jnp.einsum("btnh,bsnh->bnts", q_nope, k_nope,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("btnh,bsh->bnts", q_rope, k_r,
+                      preferred_element_type=jnp.float32)) * scale
+    s = jnp.where(mask[:, None], s, -1e30)
+    pr = jax.nn.softmax(s, axis=-1).astype(dt)
+    return jnp.einsum("bnts,bsnh->btnh", pr, v)
 
 
 def attention_block(x: jax.Array, p: Params, cfg: ModelConfig,
@@ -607,10 +739,25 @@ def router_logits(x: jax.Array, router_w: jax.Array) -> jax.Array:
                       precision=lax.Precision.HIGHEST)
 
 
+def _route_choice(logits: jax.Array, k: int, score: str, bias):
+    """(what the router scores an expert, the k chosen [.., k] int32,
+    their scores): the logits themselves, or (score "sigmoid") their
+    sigmoid, the choice then by score + bias (a stored correction an
+    expert, None for none) and the scores of the chosen WITHOUT it.
+    lax.top_k takes equal scores by index, the lower first."""
+    if score == "softmax":
+        vals, idx = lax.top_k(logits, k)
+        return idx, vals
+    s = jax.nn.sigmoid(logits)
+    _, idx = lax.top_k(s if bias is None else s + bias.astype(s.dtype), k)
+    return idx, jnp.take_along_axis(s, idx, axis=-1)
+
+
 @jax.named_scope("moe_route")
 def route_tokens(x: jax.Array, router_w: jax.Array, k: int,
-                 logits: Optional[jax.Array] = None
-                 ) -> Tuple[jax.Array, jax.Array]:
+                 logits: Optional[jax.Array] = None, *,
+                 score: str = "softmax", bias: Optional[jax.Array] = None,
+                 scale: float = 0.0) -> Tuple[jax.Array, jax.Array]:
     """Top-k MoE routing: f32 logits -> (gates [.., k], expert idx [.., k]).
 
     Softmax is over the SELECTED k (Mixtral convention; a softmax over
@@ -619,22 +766,34 @@ def route_tokens(x: jax.Array, router_w: jax.Array, k: int,
     their exact-parity contract depends on byte-identical routing.
     `logits`: router logits taken elsewhere (cfg.router_input "attn":
     from the attention's normed input), which x is then not read for.
+
+    score "sigmoid" (cfg.router_score; DeepSeek-V3's noaux_tc with one
+    group): s = sigmoid(logits); the k are chosen by s + `bias` [E]
+    (cfg.router_bias: it moves the CHOICE and never a weight); a
+    chosen expert's gate is its s over the sum of the chosen s, times
+    `scale` (cfg.routed_scaling_factor; 0 = none).
     """
     if logits is None:
         logits = router_logits(x, router_w)
-    gates, idx = lax.top_k(logits, k)
-    return jax.nn.softmax(gates, axis=-1), idx
+    idx, vals = _route_choice(logits, k, score, bias)
+    if score == "softmax":
+        return jax.nn.softmax(vals, axis=-1), idx
+    gates = vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-20)
+    return (gates * scale if scale else gates), idx
 
 
 @jax.named_scope("moe_route")
-def expert_load(logits: jax.Array, k: int, ok: jax.Array) -> jax.Array:
+def expert_load(logits: jax.Array, k: int, ok: jax.Array,
+                score: str = "softmax",
+                bias: Optional[jax.Array] = None) -> jax.Array:
     """What one layer's routing asks of its experts in one step, f32 [3]:
     how many DISTINCT experts the rows marked `ok` touch (the experts a
     dispatch that skips unrouted ones would still stream), the rows of
     the fullest expert, and the mean rows of an expert (ok rows x k /
-    E). logits [B,T,E] as route_tokens takes them, ok [B,T] bool."""
+    E). logits [B,T,E] as route_tokens takes them, ok [B,T] bool;
+    score and bias as route_tokens'."""
     E = logits.shape[-1]
-    _, idx = lax.top_k(logits, k)
+    idx, _ = _route_choice(logits, k, score, bias)
     rows = jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32)
                    * ok[..., None, None], axis=(0, 1, 2))           # [E]
     return jnp.stack([jnp.sum(rows > 0), jnp.max(rows), jnp.sum(rows) / E])
@@ -650,8 +809,10 @@ def moe_block(x: jax.Array, p: Params, cfg: ModelConfig,
     `logits`: as route_tokens'.
     """
     B, T, D = x.shape
-    weights, idx = route_tokens(x, p["router"], cfg.num_experts_per_tok,
-                                logits)
+    weights, idx = route_tokens(
+        x, p["router"], cfg.num_experts_per_tok, logits,
+        score=cfg.router_score, bias=p.get("router_bias"),
+        scale=cfg.routed_scaling_factor)
     onehot = jax.nn.one_hot(idx, cfg.num_experts, dtype=jnp.float32)  # [B,T,k,E]
     comb = jnp.einsum("btk,btke->bte", weights, onehot)  # [B,T,E]
 
@@ -700,7 +861,7 @@ def ffn_block(h: jax.Array, lp: Params, cfg: ModelConfig,
     pipeline, sequence-parallel): dense MLP, dense MoE, or EP MoE per
     cfg — one definition so the variants can't drift. `logits`:
     early_router_logits' of this layer, where the model has them."""
-    if cfg.is_moe:
+    if cfg.is_moe and "moe" in lp:   # not a leading dense layer's (first_k_dense)
         if cfg.moe_impl == "ep":   # no early logits here: ModelConfig refuses
             from butterfly_tpu.parallel.expert import moe_block_ep
             return moe_block_ep(h, lp["moe"], cfg)
@@ -760,16 +921,40 @@ def layer_runs(cfg: ModelConfig):
     [(kind, first layer, layers in the run, index of the first among the
     layers of ITS kind)]. Kinds have unlike parameter shapes, so one
     scan body cannot carry both: each run is one scan over its kind's
-    stack. A model without layer_types is one run of attention."""
+    stack. A model without layer_types is one run of attention. So have
+    a dense feed-forward and a layer of experts (cfg.first_k_dense):
+    a run ends where the leading dense layers do (ffn_run says which
+    stack a run's feed-forward is in)."""
     kinds = cfg.layer_types or ("attention",) * cfg.num_layers
     runs, seen = [], {"mamba": 0, "attention": 0}
     for l, kind in enumerate(kinds):
-        if runs and runs[-1][0] == kind:
+        if runs and runs[-1][0] == kind and l != cfg.first_k_dense:
             runs[-1][2] += 1
         else:
             runs.append([kind, l, 1, seen[kind]])
         seen[kind] += 1
     return [tuple(r) for r in runs]
+
+
+def ffn_run(params: Params, first: int, cfg: ModelConfig):
+    """(stack, first layer of the stack) of the feed-forward weights of
+    the run that starts at layer `first` (layer_runs): params["dense"]
+    for the leading dense layers of a model of experts
+    (cfg.first_k_dense), params["sparse"] (experts, router, shared
+    expert) for the layers behind them; None for a model whose
+    feed-forwards are all alike and lie in params["layers"]."""
+    if not cfg.first_k_dense:
+        return None
+    return (params["dense"], 0) if first < cfg.first_k_dense \
+        else (params["sparse"], cfg.first_k_dense)
+
+
+def run_layer_at(params: Params, ffn, l, cfg: ModelConfig) -> Params:
+    """Layer l (traced) of a model whose layers run as runs: what every
+    layer has (params["layers"]) beside its run's feed-forward
+    (ffn_run's)."""
+    lp = layer_at(params["layers"], l, cfg)
+    return lp if ffn is None else {**lp, **layer_at(ffn[0], l - ffn[1], cfg)}
 
 
 def layer_at(stack: Params, i, cfg: ModelConfig) -> Params:
@@ -887,10 +1072,11 @@ def ffn_close(x: jax.Array, lp: Params, cfg: ModelConfig, route=None,
     (expert_load), else None."""
     h = pre_norm(x, lp["ln2"], cfg)
     load = None
-    if ok is not None and cfg.is_moe:
+    if ok is not None and cfg.is_moe and "moe" in lp:
         if route is None:
             route = router_logits(h, lp["moe"]["router"])
-        load = expert_load(route, cfg.num_experts_per_tok, ok)
+        load = expert_load(route, cfg.num_experts_per_tok, ok,
+                           cfg.router_score, lp["moe"].get("router_bias"))
     return residual_add(x, ffn_block(h, lp, cfg, route), cfg), load
 
 
@@ -1288,6 +1474,7 @@ def decode_step_win(params: Params, cfg: ModelConfig, tokens: jax.Array,
     """
     indexer_unsupported(cfg, "the write-combined fused generate")
     ssm_unsupported(cfg, "the write-combined fused generate")
+    latent_unsupported(cfg, "the write-combined fused generate")
     quant = cache.quantized
     positions = (cache.length + wstep)[:, None]
     x, cos, sin = embed_tokens(params, cfg, tokens, positions)
@@ -1503,6 +1690,55 @@ def _hybrid_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         k, v, cache.length + T, ssm=state)
 
 
+def _latent_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
+                    cache: KVCache, positions: jax.Array, fresh: bool,
+                    last_index: Optional[jax.Array]
+                    ) -> Tuple[jax.Array, KVCache]:
+    """forward for a latent-attention model: the layers as runs
+    (layer_runs: a leading dense feed-forward, then layers of experts),
+    each a scan that rides the layers' indices and carries the cache,
+    which holds ONE row a token and layer. A call writes its rows and
+    then reads the cached rows ABSORBED (latent_attend), its own among
+    them: a decode call, a chunk over a cached context, and a prefill
+    alike, so what the paged path's decode rows compute is what this
+    path's calls compute. `fresh` with more than one token (nothing
+    cached is read) takes the EXPANDED form over the call's own rows."""
+    B, T = tokens.shape
+    x, cos, sin = embed_tokens(params, cfg, tokens, positions)
+    expanded = fresh and T > 1
+    mask = make_mask(positions, T if expanded else cache.max_seq)
+
+    def layer(ffn, carry, l):
+        x, ck = carry
+        lp = run_layer_at(params, ffn, l, cfg)
+        q_nope, q_rope, rows = latent_proj(pre_norm(x, lp["ln1"], cfg),
+                                           lp["attn"], cfg, cos, sin)
+        # the layer's rows [B,S,latent_row], as index keys are written
+        mine = index_cache_write(
+            lax.dynamic_index_in_dim(ck, l, 0, keepdims=False)[:, :, 0],
+            rows, positions[:, 0])
+        if expanded:
+            out = latent_attend_expanded(q_nope, q_rope, rows, mask,
+                                         lp["attn"], cfg)
+        else:
+            out = latent_attend(
+                latent_queries(q_nope, q_rope, lp["attn"], cfg), mine,
+                mask, cfg)
+        x = residual_add(x, attn_output(out, lp["attn"], cfg), cfg)
+        x, _ = ffn_close(x, lp, cfg)
+        return (x, lax.dynamic_update_index_in_dim(ck, mine[:, :, None], l,
+                                                   0)), None
+
+    ck = cache.k
+    for _, first, n, _ in layer_runs(cfg):
+        (x, ck), _ = lax.scan(partial(layer, ffn_run(params, first, cfg)),
+                              (x, ck), first + jnp.arange(n))
+    if last_index is not None:
+        x = jnp.take_along_axis(
+            x, last_index[:, None, None].astype(jnp.int32), axis=1)
+    return final_logits(params, cfg, x), KVCache(ck, None, cache.length + T)
+
+
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             cache: KVCache, positions: Optional[jax.Array] = None,
             fresh: bool = False,
@@ -1531,6 +1767,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         positions = cache.length[:, None] + jnp.arange(T)[None, :]
     if cfg.has_ssm:
         return _hybrid_forward(params, cfg, tokens, cache, positions,
+                               last_index)
+    if cfg.is_latent:
+        return _latent_forward(params, cfg, tokens, cache, positions, fresh,
                                last_index)
     # A model with an indexer takes the general path for every shape:
     # the two fast paths below attend before the cache is written, and
@@ -1575,12 +1814,28 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     # beside "layers", which keeps what every layer has (norms,
     # feed-forward); every other model's attention is in "layers"
     La = cfg.num_attn_layers
-    attn = {
-        "wq": w(next(keys), La, D, Nq, H),
-        "wk": w(next(keys), La, D, Kv, H),
-        "wv": w(next(keys), La, D, Kv, H),
-        "wo": w(next(keys), La, Nq, H, D),
-    }
+    if cfg.is_latent:
+        # latent attention: the query's latent, the joint latent with
+        # the rotary key, its two expansions a head
+        R, Rq = cfg.kv_lora_rank, cfg.q_lora_rank
+        nope, Hv = cfg.qk_nope_head_dim, cfg.v_head_dim
+        attn = {
+            "w_dq": w(next(keys), La, D, Rq),
+            "q_norm": {"scale": jnp.ones((La, Rq), pdt)},
+            "w_uq": w(next(keys), La, Rq, Nq, cfg.qk_head_dim),
+            "w_dkv": w(next(keys), La, D, cfg.latent_row),
+            "kv_norm": {"scale": jnp.ones((La, R), pdt)},
+            "w_uk": w(next(keys), La, R, Nq, nope),
+            "w_uv": w(next(keys), La, R, Nq, Hv),
+            "wo": w(next(keys), La, Nq, Hv, D),
+        }
+    else:
+        attn = {
+            "wq": w(next(keys), La, D, Nq, H),
+            "wk": w(next(keys), La, D, Kv, H),
+            "wv": w(next(keys), La, D, Kv, H),
+            "wo": w(next(keys), La, Nq, H, D),
+        }
     layers: Params = {
         "ln1": {"scale": jnp.ones((L, D), pdt)},
         "ln2": {"scale": jnp.ones((L, D), pdt)},
@@ -1606,31 +1861,43 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             bq=jnp.zeros((La, Nq, H), pdt), bk=jnp.zeros((La, Kv, H), pdt),
             bv=jnp.zeros((La, Kv, H), pdt), bo=jnp.zeros((La, D), pdt),
         )
+    # a model of experts with leading dense layers (first_k_dense)
+    # stacks each kind of feed-forward apart, top-level: "dense" and
+    # "sparse" (ffn_run); every other model's is in "layers"
+    Ld = cfg.first_k_dense
+    sparse = {} if Ld else layers
+    dense = {} if Ld else layers
     if cfg.is_moe:
-        E = cfg.num_experts
-        layers["moe"] = {
-            "router": w(next(keys), L, D, E),
-            "w_gate": w(next(keys), L, E, D, F),
-            "w_up": w(next(keys), L, E, D, F),
-            "w_down": w(next(keys), L, E, F, D),
+        E, Fe, Ls = cfg.num_experts, cfg.expert_width, L - Ld
+        sparse["moe"] = {
+            "router": w(next(keys), Ls, D, E),
+            "w_gate": w(next(keys), Ls, E, D, Fe),
+            "w_up": w(next(keys), Ls, E, D, Fe),
+            "w_down": w(next(keys), Ls, E, Fe, D),
         }
+        if cfg.router_bias:
+            # a stored buffer, zero in a checkpoint that never balanced;
+            # seeded away from zero here so that a program which adds it
+            # to the weights, or leaves it out of the choice, shows
+            sparse["moe"]["router_bias"] = w(next(keys), Ls, E, std=0.1)
         if cfg.shared_intermediate_size:
             Fs = cfg.shared_intermediate_size
-            layers["shared"] = {
-                "w_gate": w(next(keys), L, D, Fs),
-                "w_up": w(next(keys), L, D, Fs),
-                "w_down": w(next(keys), L, Fs, D),
+            sparse["shared"] = {
+                "w_gate": w(next(keys), Ls, D, Fs),
+                "w_up": w(next(keys), Ls, D, Fs),
+                "w_down": w(next(keys), Ls, Fs, D),
             }
-    elif cfg.arch == "gpt2":
+    if cfg.arch == "gpt2" and not cfg.is_moe:
         layers["mlp"] = {
             "w_up": w(next(keys), L, D, F), "b_up": jnp.zeros((L, F), pdt),
             "w_down": w(next(keys), L, F, D), "b_down": jnp.zeros((L, D), pdt),
         }
-    else:
-        layers["mlp"] = {
-            "w_gate": w(next(keys), L, D, F),
-            "w_up": w(next(keys), L, D, F),
-            "w_down": w(next(keys), L, F, D),
+    elif Ld or not cfg.is_moe:
+        n = Ld or L
+        dense["mlp"] = {
+            "w_gate": w(next(keys), n, D, F),
+            "w_up": w(next(keys), n, D, F),
+            "w_down": w(next(keys), n, F, D),
         }
 
     params: Params = {
@@ -1638,6 +1905,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         "layers": layers,
         "final_norm": {"scale": jnp.ones((D,), pdt)},
     }
+    if Ld:
+        params["dense"], params["sparse"] = dense, sparse
     if cfg.layer_types:
         params["attn"] = attn
     if cfg.has_ssm:

@@ -96,7 +96,8 @@ class TickLog:
                starved_cause: Optional[str] = None,
                starved_by: Optional[Dict[str, float]] = None,
                gap_s: float = 0.0, profiled: bool = False,
-               ssm_load: Optional[Sequence[float]] = None) -> None:
+               ssm_load: Optional[Sequence[float]] = None,
+               latent_load: Optional[Sequence[float]] = None) -> None:
         """Append one tick record (hot path: one dict build + one
         locked append per TICK, never per token). `phases` is copied —
         callers may reuse/zero their accumulator dict.
@@ -116,6 +117,11 @@ class TickLog:
         recurrence: decode rows and real chunk columns),
         `state_resets` (slots that started from a zero state inside
         them) and `ssm_steps` (the steps those blocks ran).
+        `latent_load` (a latent-attention model; null otherwise):
+        [rows, steps] SUMMED over the mixed blocks the tick drained, as
+        `latent_rows` (the cached latent rows their steps' decode rows
+        read, over the layers: a live row at position p reads p + 1 in
+        each) and `latent_steps` (the steps those blocks ran).
         The starvation clock (Scheduler._starve): `starved_s`, the
         seconds the device waited for the host before this tick's
         launches, whichever tick the wait began in (0.0 where they
@@ -129,6 +135,7 @@ class TickLog:
         touched, rows_max, rows_mean, kv_live, kv_selected = \
             (tuple(expert_load or ()) + (None,) * 5)[:5]
         ssm_rows, state_resets, ssm_steps = ssm_load or (None,) * 3
+        latent_rows, latent_steps = latent_load or (None,) * 2
         entry = {
             "seq": self._seq,
             "t_wall": time.time(),
@@ -157,6 +164,8 @@ class TickLog:
             "ssm_rows": ssm_rows,
             "state_resets": state_resets,
             "ssm_steps": ssm_steps,
+            "latent_rows": latent_rows,
+            "latent_steps": latent_steps,
             "starved_s": starved_s,
             "starved_cause": starved_cause,
             "starved_by": dict(starved_by or {}),

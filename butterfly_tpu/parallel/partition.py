@@ -63,6 +63,9 @@ def param_specs(cfg: ModelConfig, mesh: Mesh) -> Specs:
     active (mesh axis `stage` > 1) that dim is sharded over `stage` so each
     stage group holds only its own layers' weights.
     """
+    from butterfly_tpu.models.common import latent_unsupported
+    latent_unsupported(cfg, "a device mesh (parallel/partition.py has no "
+                            "layout for the latent projections)")
     D, Nq, Kv, F, V = (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
                        cfg.intermediate_size, cfg.vocab_size)
     tp = lambda n: _div(n, mesh, "tensor")  # noqa: E731

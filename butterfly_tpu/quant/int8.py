@@ -109,51 +109,28 @@ def quantize_int8(params: Params, cfg) -> Params:
       shared w_* [L,D,F] / [L,F,D] -> as the dense mlp's
       mamba in_proj [Lm,D,P] -> D;  out_proj [Lm,Di,D] -> Di (the
         conv, A_log, D, dt_bias and the norm stay float)
+      latent attention: w_dq [L,D,Rq], w_uq [L,Rq,N,H], w_dkv [L,D,R+r],
+        w_uk / w_uv [L,R,N,H] -> the dim behind L (w_uk is contracted
+        over H in the absorbed read, which dequantizes it first:
+        models/common.py latent_queries)
       lm_head [D,V] -> D; a tied head gets one from the embedding
         (tied_head)
-    A model with layer_types holds its attention stack top-level
-    (params["attn"]), every other under params["layers"].
+    The leaf's PATH decides (_contraction_axes), wherever its stack
+    lies: under params["layers"], or top-level for a model whose layers
+    run as runs (params["attn"], "mamba", "dense", "sparse").
     Runs as one jit so a large tree quantizes device-side in one program.
     """
 
     dt = jnp.dtype(cfg.dtype)
 
+    def leaf(path, w):
+        axes = _contraction_axes(_path_names(path))
+        return w if axes is None else _quant(w, axes, dt)
+
     @jax.jit
     def go(params):
-        layers = dict(params["layers"])
-        out = dict(params)
-        attn = dict(layers["attn"] if "attn" in layers else params["attn"])
-        for k in ("wq", "wk", "wv"):
-            attn[k] = _quant(attn[k], (1,), dt)
-        attn["wo"] = _quant(attn["wo"], (1, 2), dt)
-        if "attn" in layers:
-            layers["attn"] = attn
-        else:
-            out["attn"] = attn
-        if "mamba" in params:
-            out["mamba"] = dict(
-                params["mamba"],
-                in_proj=_quant(params["mamba"]["in_proj"], (1,), dt),
-                out_proj=_quant(params["mamba"]["out_proj"], (1,), dt))
-        if "shared" in layers:
-            layers["shared"] = {k: _quant(w, (1,), dt)
-                                for k, w in layers["shared"].items()}
-        if "mlp" in layers:
-            mlp = dict(layers["mlp"])
-            for k in ("w_gate", "w_up"):
-                if k in mlp:
-                    mlp[k] = _quant(mlp[k], (1,), dt)
-            mlp["w_down"] = _quant(mlp["w_down"], (1,), dt)
-            layers["mlp"] = mlp
-        if "moe" in layers:
-            moe = dict(layers["moe"])
-            for k in ("w_gate", "w_up", "w_down"):
-                moe[k] = _quant(moe[k], (2,), dt)
-            layers["moe"] = moe
-        out["layers"] = layers
-        if "lm_head" in params:
-            out["lm_head"] = _quant(params["lm_head"], (0,), dt)
-        elif cfg.tie_embeddings:
+        out = jax.tree_util.tree_map_with_path(leaf, params)
+        if "lm_head" not in params and cfg.tie_embeddings:
             out["lm_head"] = tied_head(params["embed"]["tok"], dt)
         return out
 
@@ -168,13 +145,17 @@ def tied_head(tok: jax.Array, dt) -> Dict[str, jax.Array]:
     return _quant(tok.T, (0,), dt)
 
 
+def _path_names(path) -> list:
+    return [getattr(p, "key", getattr(p, "name", "")) for p in path]
+
+
 def _contraction_axes(path_names) -> Optional[Tuple[int, ...]]:
     """quantize_int8's contraction axes for the leaf at this tree path,
     or None for a leaf that stays float (embeddings, norms, biases,
     the MoE router)."""
     name = path_names[-1]
     parent = path_names[-2] if len(path_names) > 1 else ""
-    if name in ("wq", "wk", "wv"):
+    if name in ("wq", "wk", "wv", "w_dq", "w_uq", "w_dkv", "w_uk", "w_uv"):
         return (1,)
     if name == "wo":
         return (1, 2)
@@ -269,7 +250,7 @@ def init_params_by_leaf(cfg, key: jax.Array, quant: str = "none",
     out = []
     progs = {}  # one jit per output layout: same-shaped leaves share it
     for (path, sd), k, spec in zip(leaves, keys, specs):
-        names = [getattr(p, "key", getattr(p, "name", "")) for p in path]
+        names = _path_names(path)
         axes = _contraction_axes(names) if quant == "int8" else None
         kind = "ones" if names[-1] == "scale" else \
             "zeros" if names[-1].startswith("b") else "normal"

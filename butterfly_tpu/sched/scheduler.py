@@ -701,6 +701,17 @@ class Scheduler:
         # drained, with the steps those ran. None for every other model
         self._has_ssm = engine.cfg.has_ssm
         self._tick_ssm: Optional[List[float]] = None
+        # a latent-attention model: the vector ends in the cached rows
+        # the block's decode rows read, over its layers and steps; the
+        # tick record holds [rows, steps] over the blocks it drained
+        self._is_latent = engine.cfg.is_latent
+        self._tick_latent: Optional[List[float]] = None
+        self._g_latent_rows = reg.gauge(
+            "latent_rows_read",
+            "Cached latent rows that the decode rows of one step read, "
+            "summed over the layers (cache/paged.py latent_paged_attend), "
+            "the mean over the newest mixed block's steps; 0 for a model "
+            "without latent attention")
         self._g_ssm_state_bytes = reg.gauge(
             "ssm_state_bytes",
             "Bytes of recurrent state the slots hold for a model with "
@@ -1122,6 +1133,7 @@ class Scheduler:
         self._tick_overlapped = None
         self._tick_expert_loads = []
         self._tick_ssm = None
+        self._tick_latent = None
         blocks0 = self.engine.blocks_launched
         with TraceAnnotation("bf.tick", seq=self.ticklog.next_seq,
                              batch=len(self.running),
@@ -1272,6 +1284,7 @@ class Scheduler:
                 for i in range(len(loads[0]))] if loads else None
         self.ticklog.record(wall, tp, fetch_s=fetch, expert_load=load,
                             ssm_load=self._tick_ssm,
+                            latent_load=self._tick_latent,
                             overlapped=self._tick_overlapped,
                             inflight=len(self._inflight),
                             barrier_causes=self._tick_causes,
@@ -2444,6 +2457,13 @@ class Scheduler:
                 ssm[0] += float(load[0][3])
                 ssm[1] += float(load[0][4])
                 ssm[2] += len(rows)
+                load = [load[0][:3]]
+            if load and self._is_latent:
+                # [.., rows read] summed over the block's steps
+                lat = self._tick_latent = self._tick_latent or [0.0, 0]
+                lat[0] += float(load[0][3])
+                lat[1] += len(rows)
+                self._g_latent_rows.set(float(load[0][3]) / len(rows))
                 load = [load[0][:3]]
             if load and load[0][2] > 0:    # some step had a real row
                 self._tick_expert_loads.append(load[0])

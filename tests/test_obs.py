@@ -553,6 +553,46 @@ def test_ticklog_record_carries_the_starvation_clock():
     json.dumps(log.dump())
 
 
+@pytest.mark.parametrize("kw, rows, steps", [
+    (dict(), None, None),                       # a model without, or no block
+    (dict(latent_load=[4 * 6 * 3100.0, 4]), 74400.0, 4),
+    (dict(latent_load=[0.0, 8]), 0.0, 8),       # blocks of chunks alone
+])
+def test_ticklog_record_carries_the_latent_rows(kw, rows, steps):
+    """A latent-attention model's tick records: the cached rows the
+    drained blocks' decode rows read and the steps those blocks ran, as
+    sums; null for every other model and beside the other families'
+    counters, which stay null."""
+    from butterfly_tpu.obs.ticklog import TickLog
+    log = TickLog()
+    log.record(0.02, {"mixed": 0.02}, program="bf_mixed_block_win",
+               expert_load=[244.0, 9.0, 4.0], **kw)
+    tick, = log.dump()["ticks"]
+    assert (tick["latent_rows"], tick["latent_steps"]) == (rows, steps)
+    assert tick["experts_touched"] == 244.0
+    assert tick["ssm_rows"] is None and tick["kv_rows_live"] is None
+    json.dumps(tick)
+
+
+def test_the_latent_rows_gauge_is_registered_with_its_help():
+    """`butterfly_latent_rows_read` in the scheduler's registry: a gauge
+    every model's scheduler holds (0 without latent attention), named in
+    the exposition with its help text."""
+    import jax
+    from butterfly_tpu.core.config import RuntimeConfig, tiny
+    from butterfly_tpu.engine.serving import ServingEngine
+    from butterfly_tpu.models.common import Model
+    from butterfly_tpu.sched.scheduler import Scheduler
+    cfg = tiny("llama", dtype="float32")
+    sched = Scheduler(ServingEngine(
+        Model(cfg), Model(cfg).init(jax.random.PRNGKey(0)),
+        RuntimeConfig(max_batch_size=2, max_seq_len=32, page_size=4)))
+    assert sched.registry.snapshot()["latent_rows_read"] == 0
+    text = sched.registry.render()
+    assert "# TYPE butterfly_latent_rows_read gauge" in text
+    assert "latent rows" in text
+
+
 def test_trace_report_prints_the_starved_seconds(tmp_path):
     """`trace_report.py --ticks` reads a /debug/ticks dump without the
     benchmark: the starved seconds as a share of what the ticks span, by

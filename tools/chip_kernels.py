@@ -21,7 +21,12 @@ tensor=4 mesh: 8 query and 2 KV heads per shard; the ring under
 at granite-4.0-h-small's state geometry (9 layers, 128 slots, 128 heads
 of 64, state 128, bfloat16: 2.4 GB) against `ssm_scan` at T == 1 and
 the update in place, the state donated as the engine donates it, and
-its compiled HLO must hold no copy of the state.
+its compiled HLO must hold no copy of the state. `latent`, the absorbed
+read of a latent-attention model's cached rows (ops/latent_attention.py),
+runs at JoyAI-LLM-Flash's geometry (96 slots, 32 heads, rows of 576
+values in 640 lanes, a pool of 6 GB in six layers, a window of 256)
+against `jnp` over the same pages, and is timed over one step's six
+layers beside the bytes the model's count gives the rows.
 
 Last, the program `serve` spends its time in — the serving engine's
 fused decode block, at the full 8B width with the depth cut to two
@@ -404,6 +409,101 @@ def run_ssm_step(name, small, want):
     return rec
 
 
+def run_latent(name, small, want):
+    """ops/latent_attention.py at JoyAI-LLM-Flash's geometry, the cell's
+    own: 96 slots, 32 heads against one cached row of 576 values in 640
+    lanes, 512 of them the values, a pool of 49,153 pages of 16 rows in
+    six layers (6 GB), a window of 256, contexts from a few tokens to
+    8,192 that end inside a page, a dead slot. The kernel against `jnp`
+    over the same pages, then its time for the six layers of one step
+    at contexts of 3,100 (the window's opening) beside the bytes the
+    model's count gives the rows (servebench/latent_peaks.py): what
+    `latent_attn_roofline` will read in the cell."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from butterfly_tpu.ops.latent_attention import latent_attention
+
+    rec = {"name": name, "ok": False}
+    S, Nq, Rp, R, rank, L, page, W = 96, 32, 640, 576, 512, 6, 16, 256
+    mp = 512
+    if small:
+        S, Nq, L, mp, W = 4, 4, 2, 8, 16
+    P = S * mp + 1
+    t0 = time.perf_counter()
+    try:
+        keys = jax.random.split(jax.random.PRNGKey(7), 4)
+        lanes = (jnp.arange(Rp) < R).astype(jnp.bfloat16)
+        pool = jax.jit(lambda k: jax.random.normal(
+            k, (L, P, 1, page, Rp), jnp.bfloat16) * lanes)(keys[0])
+        q = jax.random.normal(keys[1], (S, Nq, Rp), jnp.bfloat16) * lanes
+        win = jax.random.normal(keys[2], (S, 1, W, Rp), jnp.bfloat16) * lanes
+        rs = np.random.RandomState(3)
+        table = jnp.asarray(rs.permutation(P - 1).reshape(S, mp), jnp.int32)
+        lens = rs.randint(1, mp * page - W, S)
+        lens[0], lens[1], lens[2] = 0, 5, mp * page - W
+        wc = rs.randint(1, W + 1, S)
+        wc[0] = 0
+        lens, wc = jnp.asarray(lens, jnp.int32), jnp.asarray(wc, jnp.int32)
+        scale = 192 ** -0.5
+        fn = jax.jit(lambda q, pool, ly, t, n, w, c: latent_attention(
+            q, pool, ly, t, n, w, c, rank=rank, scale=scale))
+        compiled = fn.lower(q, pool, 3 % L, table, lens, win, wc).compile()
+        hlo = compiled.as_text()
+        out = jax.block_until_ready(compiled(q, pool, 3 % L, table, lens,
+                                             win, wc))
+        rec["compile_run_s"] = round(time.perf_counter() - t0, 2)
+        rec["hlo_has"] = {w: w in hlo for w in want}
+
+        @jax.jit
+        def ref(q, pool, ly, t, n, w, c):
+            # slot by slot: the gathered view of 96 tables is 1 GB
+            def one(args):
+                qs, ts, ns, ws, cs = args
+                rows = jnp.concatenate(
+                    [pool[ly, ts, 0].reshape(mp * page, Rp), ws[0]])
+                live = jnp.concatenate([jnp.arange(mp * page) < ns,
+                                        jnp.arange(W) < cs])
+                s = jnp.einsum("nr,cr->nc", qs, rows,
+                               preferred_element_type=jnp.float32) * scale
+                p = jax.nn.softmax(jnp.where(live, s, -1e30), -1) * live
+                return jnp.einsum("nc,cr->nr", p.astype(rows.dtype),
+                                  rows[:, :rank],
+                                  preferred_element_type=jnp.float32)
+            return jax.lax.map(one, (q, t, n, w, c))
+
+        want_out = ref(q, pool, 3 % L, table, lens, win, wc)
+        err = np.max(np.abs(np.asarray(out, np.float32)
+                            - np.asarray(want_out)) /
+                     (1 + np.abs(np.asarray(want_out))))
+        rec["max_err"] = round(float(err), 5)
+        rec["dead_slot_zero"] = not np.asarray(out[0], np.float32).any()
+        # one step's six layers at the opening's contexts
+        ctx = jnp.full((S,), min(3100, mp * page - W), jnp.int32)
+        step = jax.jit(lambda q, pool, t, n, w, c: sum(
+            latent_attention(q, pool, ly, t, n, w, c, rank=rank,
+                             scale=scale).astype(jnp.float32).sum()
+            for ly in range(L)))
+        one = jnp.ones((S,), jnp.int32)
+        jax.block_until_ready(step(q, pool, table, ctx, win, one))
+        t1 = time.perf_counter()
+        for _ in range(10):
+            r = step(q, pool, table, ctx, win, one)
+        jax.block_until_ready(r)
+        rec["step_ms"] = round((time.perf_counter() - t1) / 10 * 1e3, 3)
+        rows_bytes = float(S) * L * float(ctx[0]) * R * 2
+        rec["rows_gb"] = round(rows_bytes / 1e9, 3)
+        rec["share_of_819_gb_s"] = round(
+            100 * rows_bytes / 819e9 / (rec["step_ms"] / 1e3), 1)
+        rec["ok"] = bool(np.isfinite(err) and err < 3e-2
+                         and rec["dead_slot_zero"]
+                         and all(rec["hlo_has"].values()))
+    except Exception as e:  # a compiler refusal is the finding: record it
+        rec["error"] = f"{type(e).__name__}: {e}"[:1500]
+    return rec
+
+
 def run_case(name, fn, ref, args, mesh=None, want=(MOSAIC_CALL,)):
     """Compile `fn`, look for `want` in its HLO, run it, compare."""
     import jax
@@ -477,6 +577,8 @@ def main() -> int:
                for n, k, r, a, _ in cases if wanted(n)]
     if wanted("ssm_step"):
         results.append(run_ssm_step("ssm_step", args.small, want))
+    if wanted("latent"):
+        results.append(run_latent("latent", args.small, want))
     if wanted("serve_block"):
         results.append(run_serving_block("serve_block", args.small, None,
                                          want))
@@ -497,6 +599,9 @@ def main() -> int:
         print(f"{'ok  ' if r['ok'] else 'FAIL'} {r['name']:<24}"
               f" err={r.get('max_err')} hlo={r.get('hlo_has')}"
               f" t={r.get('compile_run_s', r.get('compile_s'))}"
+              + (f" step={r['step_ms']}ms rows={r['rows_gb']}GB "
+                 f"({r['share_of_819_gb_s']}% of 819 GB/s)"
+                 if "step_ms" in r else "")
               + (f" calls={r['kernel_calls']}" if "kernel_calls" in r else "")
               + (f"\n     {r['error']}" if "error" in r else ""))
     ok = mode == "compiled" and all(r["ok"] for r in results)
