@@ -92,6 +92,7 @@ from butterfly_tpu.ops import note_kernel
 from butterfly_tpu.ops import latent_attention
 from butterfly_tpu.ops.flash_attention import flash_attention_sharded
 from butterfly_tpu.ops.paged_attention import paged_attention_sharded
+from butterfly_tpu.ops.window_stage import stage_window_sharded, window_step
 from butterfly_tpu.cache.ssm_state import SSMState, advance_packed
 
 
@@ -348,6 +349,17 @@ def gather_paged_layer_q(pages: jax.Array, scale_pages: jax.Array,
 # flushes into the pool once per drain, in place, page by staged page
 # (flush_paged_window).
 #
+# The window rides every LAYER scan whole too, in the scan's carry, and is
+# read by layer index as the pool is: a layer stages its rows INTO the
+# carried leaf (stage_window_layer) and its readers take (layer, slot)'s
+# block of it. As scanned inputs and stacked outputs the leaves were read
+# and written whole in every step to stage one entry a decode row (a
+# scan's stacked output is a fresh buffer; PERF.md, PR 47). Where
+# kernels are on the writer is a Mosaic call (ops/window_stage.py), because
+# the reader is: XLA's scatter of single positions and a Mosaic call want
+# two layouts of one buffer, and XLA then relays the whole leaf between
+# them in every layer.
+#
 # The window stores the pool's EXACT representation (int8 codes + f32
 # scales when the pool is quantized, pool dtype otherwise), and the
 # non-kernel read path INSERTS the window entries into the gathered pool
@@ -367,8 +379,13 @@ class KVWindow(NamedTuple):
 
     k/v: [L, S, Kv, W, H] in the pool's representation (int8 codes when
     the pool is quantized, else the pool dtype; [L, S, 1, W, Kv*H]
-    beside a token-major pool); k/v_scale [L, S, Kv, W] f32 iff
-    quantized. Entry w of slot s sits at absolute position
+    beside a token-major pool); k/v_scale [L, S, W/ws, Kv*ws] f32 iff
+    quantized: the ws = window_step(W) positions the paged kernel
+    multiplies in one step of its window segment are ONE flat kv-major
+    row (column kv*ws + w), as a page's scales are one row of the pool's,
+    so that the kernel reads them where they lie (scales_by_head is the
+    [.., Kv, W] view XLA's readers take of a few slots' rows). Entry w
+    of slot s sits at absolute position
     lengths[s] + w of that slot's sequence, where lengths is the
     FLUSHED pool length; a separate win_len [S] vector (ridden through
     the block-scan carry beside this buffer, not stored here — it is
@@ -404,13 +421,16 @@ def init_kv_window(cache: PagedKVCache, width: int,
     quantized, dtype = cache.quantized, cache.k_pages.dtype
     values = cache.v_pages is not None
 
+    ws = window_step(width)
+    sshape = (L, S, width // ws, Kv * ws)
+
     def build():
         if quantized:
             return KVWindow(
                 k=jnp.zeros(shape, jnp.int8),
                 v=jnp.zeros(shape, jnp.int8),
-                k_scale=jnp.zeros(shape[:-1], jnp.float32),
-                v_scale=jnp.zeros(shape[:-1], jnp.float32))
+                k_scale=jnp.zeros(sshape, jnp.float32),
+                v_scale=jnp.zeros(sshape, jnp.float32))
         ki = None if cache.ki_pages is None else jnp.zeros(
             (L, S, 1, width, cache.ki_pages.shape[-1]), cache.ki_pages.dtype)
         return KVWindow(k=jnp.zeros(shape, dtype),
@@ -419,55 +439,110 @@ def init_kv_window(cache: PagedKVCache, width: int,
     return jax.jit(build, out_shardings=shardings)()
 
 
+def scales_by_head(scales: jax.Array, kv_heads: int) -> jax.Array:
+    """A window's scales as they are stored, [.., W/ws, Kv*ws] (KVWindow),
+    seen a head a row: [.., Kv, W]. For the few rows XLA's readers take
+    (a chunk's slot, a flush's run); the paged kernel reads them as
+    stored."""
+    *lead, steps, flat = scales.shape
+    ws = flat // kv_heads
+    a = scales.reshape(*lead, steps, kv_heads, ws)
+    return jnp.moveaxis(a, -3, -2).reshape(*lead, kv_heads, steps * ws)
+
+
+def scales_by_step(scales: jax.Array) -> jax.Array:
+    """scales_by_head's inverse: [.., Kv, W] as the window stores them."""
+    *lead, Kv, W = scales.shape
+    ws = window_step(W)
+    a = scales.reshape(*lead, Kv, W // ws, ws)
+    return jnp.moveaxis(a, -3, -2).reshape(*lead, W // ws, Kv * ws)
+
+
+def window_runs(slot, first, count, width: int) -> jax.Array:
+    """The table [3, R] of R runs of staged rows (ops/window_stage.py):
+    each run's slot, its first window index and how many of its
+    consecutive entries land: `count` of them, less what would pass the
+    window's width (a write at index W or more is dropped)."""
+    n = jnp.clip(jnp.minimum(count, width - first), 0)
+    return jnp.stack([slot, first, n]).astype(jnp.int32)
+
+
 @jax.named_scope("kv_window_write")
-def stage_window_layer(wk, wv, k, v, win_len, wks=None, wvs=None,
-                       rows=None):
-    """Stage one layer's fresh K/V into its window slice.
+def stage_window_layer(window: KVWindow, layer, k, v, ki, slot, idx, runs,
+                       widths, use_kernel: bool,
+                       looped: bool = True) -> KVWindow:
+    """Stage one layer's fresh rows INTO the whole window, which rides
+    the layer scan's carry: `L x rows x Kv x H` values move, the leaves
+    stay where they are.
 
-    wk/wv: [S, Kv, W, H] (this layer's window); k/v: [B, T, Kv, H]
-    floats (B == S); win_len: [S] valid entries BEFORE this call —
-    token t of slot b lands at window index win_len[b] + t, quantized
-    on the way in when scale slices wks/wvs [S, Kv, W] are given (the
-    pool representation, so a later flush copies bytes verbatim and
-    in-window attention dequantizes exactly like the pool read would).
-    Indices never collide with valid entries (writes start AT win_len),
-    so dead slots need no masking: their win_len never advances and
-    their staged bytes stay unattendable garbage. Returns the updated
-    (wk, wv, wks, wvs).
+    window: the leaves [L, S, Kv, W, H]; layer: the one written (a
+    traced scalar); k/v [B, T, Kv, H] floats, N = B*T rows (v None
+    beside a latent pool), ki [B, T, Hi] the index keys of a model with
+    an indexer (else None); row r belongs to slot[r] and lands at
+    window index idx[r], an index of W or more dropping its write;
+    quantized on the way in when the
+    window holds int8 codes (the pool's representation, so a later
+    flush copies bytes verbatim and in-window attention dequantizes
+    exactly like the pool read would). Indices never collide with valid
+    entries (writes start AT the staged count), so dead slots need no
+    masking: their count never advances and their staged bytes stay
+    unattendable garbage.
 
-    rows [B] (the packed mixed step): row b belongs to slot rows[b]
-    and lands at window index win_len[b] + t, win_len then one entry a
-    ROW; an index of W or more drops the row's write.
-    """
-    B, T = k.shape[0], k.shape[1]
-    rows = (jnp.arange(B) if rows is None else rows)[:, None]  # [B, 1]
-    idx = win_len[:, None] + jnp.arange(T)[None, :]     # [B, T]
+    runs, widths: the same rows as runs of consecutive entries of one
+    slot (window_runs; widths their static lengths, group by group), as
+    the Mosaic writer takes them where kernels are on
+    (ops/window_stage.py: a row is a read-modify-write of its tile
+    group, because the kernels that READ the window want their layout of
+    it). Kernels off, or a leaf the writer cannot take, it is XLA's
+    scatter at (layer, slot, :, idx): reader and writer are then both
+    XLA's and the carry is updated in place. looped: False from a run
+    of ONE layer, which is no loop to XLA (ops/window_stage.py holds an
+    int8 window's scales to HBM inside loops only)."""
+    N = slot.shape[0]
+    heads, width = window.k.shape[2], window.k.shape[4]
     # the heads as the window lays them (a token-major row: one of Kv*H)
-    k, v = (None if a is None else a.reshape(B, T, wk.shape[1], wk.shape[3])
+    k, v = (None if a is None else a.reshape(N, heads, width)
             for a in (k, v))
-    if wks is not None:
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        wk = wk.at[rows, :, idx].set(kq, mode="drop")
-        wv = wv.at[rows, :, idx].set(vq, mode="drop")
-        wks = wks.at[rows, :, idx].set(ks, mode="drop")
-        wvs = wvs.at[rows, :, idx].set(vs, mode="drop")
-        return wk, wv, wks, wvs
-    wk = wk.at[rows, :, idx].set(k.astype(wk.dtype), mode="drop")
-    if wv is not None:          # a latent window has no values of its own
-        wv = wv.at[rows, :, idx].set(v.astype(wv.dtype), mode="drop")
-    return wk, wv, None, None
+    rows, scale_rows = [], []
+    if window.quantized:
+        (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+        scale_rows = [ks, vs]
+    for leaf, a in ((window.k, k), (window.v, v), (window.ki, ki)):
+        if leaf is not None:
+            rows.append(a.reshape(N, *leaf.shape[2:3],
+                                  leaf.shape[4]).astype(leaf.dtype))
+    # the row leaves, then an int8 window's scales (which has no index
+    # keys): window_leaves' order
+    leaves = window_leaves(window)
+    n = len(rows)
+    staged = stage_window_sharded(
+        leaves[:n], leaves[n:], rows, scale_rows, layer, runs,
+        widths, looped) if use_kernel else None
+    if staged is not None:
+        return window_leaves(window, (*staged[0], *staged[1]))
+    ws = window_step(window.width)
+    cols = jnp.arange(heads)[None, :] * ws + (idx % ws)[:, None]  # [N, Kv]
+    return window_leaves(window, tuple(
+        leaf.at[layer, slot, :, idx].set(a, mode="drop") if leaf.ndim == 5
+        else leaf.at[layer, slot[:, None], (idx // ws)[:, None], cols].set(
+            a, mode="drop")
+        for leaf, a in zip(leaves, (*rows, *scale_rows), strict=True)))
 
 
-@jax.named_scope("kv_window_write")
-def stage_index_layer(wki, ki, win_len, rows=None):
-    """stage_window_layer for the index keys: ki [B, T, Hi] into this
-    layer's window slice wki [S, 1, W, Hi], at the window indices their
-    keys and values take."""
-    B, T = ki.shape[:2]
-    rows = (jnp.arange(B) if rows is None else rows)[:, None]
-    idx = win_len[:, None] + jnp.arange(T)[None, :]
-    return wki.at[rows, 0, idx].set(ki.astype(wki.dtype), mode="drop")
+def window_rows(leaf, layer, slots=None):
+    """One layer's entries of a window leaf [L, S, ...] as XLA's readers
+    take them: every slot's [S, ...] (slots None), or the rows' own,
+    [B, ...] for slots [B], each ONE dynamic slice by layer and slot
+    together (a chunk reads its one slot's run, not the layer's)."""
+    if leaf is None:
+        return None
+    if slots is None:
+        return lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False)
+    rest = (0,) * (leaf.ndim - 2)
+    return jnp.concatenate([
+        lax.dynamic_slice(leaf, (layer, slots[b], *rest),
+                          (1, 1, *leaf.shape[2:]))[0]
+        for b in range(slots.shape[0])])
 
 
 @jax.named_scope("kv_gather")
@@ -562,8 +637,10 @@ def flush_paged_window(cache: PagedKVCache, window: KVWindow, win_len):
     [scalar]).
     """
     L, _, _, page, _ = cache.k_pages.shape
-    W = window.width
+    W, Kv = window.width, window.k.shape[2]
     seg = min(page, W)          # window rows one run can take
+    ws = window_step(W)
+    steps = min(W // ws, (seg + ws - 2) // ws + 1)  # a run's, of scales
     runs, table = _staged_runs(cache, win_len, W)
     rows = jnp.arange(page, dtype=jnp.int32)
     staged_leaves = window_leaves(window)
@@ -582,9 +659,17 @@ def flush_paged_window(cache: PagedKVCache, window: KVWindow, win_len):
             tail = staged.shape[4:]
             old = lax.dynamic_slice(pool, (0, pg) + (0,) * (pool.ndim - 2),
                                     (L, 1) + pool.shape[2:])
+            src, mine, first = staged, slot, at
+            if staged.ndim == 4:
+                # an int8 window's scales, a step a row (KVWindow): the
+                # steps the run lies in, seen a head a row
+                j = jnp.clip(at // ws, 0, W // ws - steps)
+                src = scales_by_head(lax.dynamic_slice(
+                    staged, (0, slot, j, 0), (L, 1, steps, Kv * ws)), Kv)
+                mine, first = 0, at - j * ws
             new = lax.dynamic_slice(
-                staged, (0, slot, 0, at) + (0,) * len(tail),
-                (L, 1, staged.shape[2], seg) + tail)
+                src, (0, mine, 0, first) + (0,) * len(tail),
+                (L, 1, src.shape[2], seg) + tail)
             if seg < page:
                 new = jnp.pad(new, [(0, 0)] * 3 + [(0, page - seg)]
                               + [(0, 0)] * len(tail))
@@ -631,8 +716,9 @@ def permute_window_tail(window: KVWindow, win_len, perm) -> KVWindow:
     k, v = gather(window.k), gather(window.v)
     ks = vs = None
     if window.quantized:
-        gs = lambda a: jnp.take_along_axis(             # noqa: E731
-            a, idx[None, :, None, :], axis=3)           # [L,S,Kv,W]
+        Kv = window.k.shape[2]
+        gs = lambda a: scales_by_step(jnp.take_along_axis(  # noqa: E731
+            scales_by_head(a, Kv), idx[None, :, None, :], axis=3))
         ks, vs = gs(window.k_scale), gs(window.v_scale)
     ki = None if window.ki is None else gather(window.ki)
     return KVWindow(k=k, v=v, k_scale=ks, v_scale=vs, ki=ki)
@@ -744,7 +830,7 @@ def _pool_rows(pages: jax.Array, row: jax.Array) -> jax.Array:
 
 
 def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
-                        page_table, positions, mask, win=None, wki=None,
+                        page_table, positions, mask, win=None,
                         select: str = "index"):
     """paged_attend for a model with a sparse-attention indexer: each
     query scores every live position of its stream against the cached
@@ -753,8 +839,10 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
     w [B,T,Ni] from index_proj; kp/vp [L,P,1,page,Kv*H] (token-major:
     pool_row) and kip [L,P,1,page,Hi] the whole pools, `layer` the one
     to read; mask [B,T,S_max] what each query MAY attend (causal,
-    live); win as paged_attend's, AFTER staging (window slices
-    [B,1,W,Kv*H]), with wki [B,1,W,Hi] the window's index keys. Returns (out [B,T,Nq,H], count f32 [3]): the rows that
+    live); win as paged_attend's, AFTER staging: the whole window
+    (leaves [L,S,1,W,Kv*H], the index keys' [L,S,1,W,Hi]), of which the
+    rows' slots' entries of `layer` are read (window_rows). Returns
+    (out [B,T,Nq,H], count f32 [3]): the rows that
     had anything to attend, the positions they could attend and the
     positions they read, summed over the rows.
 
@@ -775,7 +863,9 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
     S_max = page_table.shape[1] * page
     topk = cfg.index_topk
     if win is not None:
-        wk, wv, _, _, win_len = win
+        window, win_len, slots = win
+        wk, wv, wki = (window_rows(a, layer, slots)
+                       for a in (window.k, window.v, window.ki))
         base = positions[:, 0] - win_len    # flushed pool length per row
     with jax.named_scope("attn_index"):
         # the stream's index keys as keys are viewed: one KV head
@@ -841,7 +931,7 @@ def latent_paged_attend(q, kp, layer, *, cfg: ModelConfig, page_table,
     the pool's lanes; kp [L,P,1,page,Rp] the WHOLE pool of rows (there
     is no value pool) and `layer` the one to read; page_table,
     positions, mask, active as paged_attend's; win as paged_attend's,
-    AFTER staging (its slice of rows [B,1,W,Rp]; the others None).
+    AFTER staging (the window's one leaf of rows [L,S,1,W,Rp]).
     Returns (o' [B,T,Nq,kv_lora_rank], count f32 [1]): the cached rows
     the DECODE rows read (a live row at position p reads p + 1, itself
     among them; a chunk's rows count nothing), what the tick record's
@@ -856,14 +946,15 @@ def latent_paged_attend(q, kp, layer, *, cfg: ModelConfig, page_table,
     T = q.shape[1]
     start = positions[:, 0]
     if win is not None:
-        wk, win_len = win[0], win[4]
+        window, win_len, slots = win
         base = start - win_len      # flushed pool length per row
     out = None
     if use_kernel and T == 1 and latent_attention.fits(kp, cfg.kv_lora_rank):
         # pool rows up to the FLUSHED length and the window's staged run
-        # with the token just staged, or the pool alone with the token
+        # with the token just staged (the window whole: the kernel reads
+        # the layer's block of it), or the pool alone with the token
         # just written
-        lens = (jnp.where(active, base, 0), wk,
+        lens = (jnp.where(active, base, 0), window.k,
                 jnp.where(active, win_len + 1, 0)) if win is not None \
             else (jnp.where(active, start + 1, 0),)
         out = latent_attention.latent_attention(
@@ -875,7 +966,8 @@ def latent_paged_attend(q, kp, layer, *, cfg: ModelConfig, page_table,
             note_kernel("dense_fallback")
         rows = gather_paged_layer(kp, page_table, layer)    # [B,S_max,1,Rp]
         if win is not None:
-            rows = insert_window_view(rows, wk, base)
+            rows = insert_window_view(
+                rows, window_rows(window.k, layer, slots), base)
         out = latent_attend(q, *_settled(rows[:, :, 0]), mask, cfg)
     read = jnp.sum(jnp.where(active, start + 1, 0)) if T == 1 else 0
     return out, jnp.asarray(read, jnp.float32).reshape(1)
@@ -897,15 +989,19 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
     indexes layer and page in its own gather (_table_pages). Nobody
     cuts the layer out beforehand: handed to a custom call, such a
     slice is a copy of the layer. page_table: the B rows' own table rows
-    [B, max_pages]; win: (wk, wv, wks, wvs, win_len) AFTER staging,
-    window slices [B, Kv, W, H] and the staged count BEFORE it.
+    [B, max_pages]; win: (window, win_len, slots) AFTER staging: the
+    WHOLE window (KVWindow, leaves [L, S, Kv, W, H]: the kernel reads
+    (layer, slot)'s block of it, the dense branches slice the rows'
+    slots' entries by layer and slot together, window_rows), the B
+    rows' staged counts BEFORE it, and their slots [B] (None: the B
+    rows are the S slots in order).
     sliding_window: the layer's, out of its pattern (a traced scalar, 0
     = a full layer; None = the model has none): every branch attends
     position j from p only where p - j < sliding_window, the kernels by
     their prefetched scalar, the dense gather by its mask.
-    index: (qi, w, kip, wki) for a model with a sparse-attention
-    indexer: _layer_open's index queries and weights, the pool of index
-    keys and the window's slice of them [B,1,W,Hi] (None, window off).
+    index: (qi, w, kip) for a model with a sparse-attention
+    indexer: _layer_open's index queries and weights and the pool of
+    index keys (the window's are in `win`).
     Such a layer attends through sparse_paged_attend and nowhere else.
     Returns [B,T,Nq,H]; with `index`, that and sparse_paged_attend's
     count. A latent-attention model (q its absorbed queries, k its
@@ -917,15 +1013,15 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
             positions=positions, mask=mask, active=active,
             use_kernel=use_kernel, win=win)
     if index is not None:
-        qi, w, kip, wki = index
+        qi, w, kip = index
         return sparse_paged_attend(
             q, qi, w, kp, vp, kip, layer, cfg=cfg, page_table=page_table,
-            positions=positions, mask=mask, win=win, wki=wki)
+            positions=positions, mask=mask, win=win)
     T = q.shape[1]
     quant = ksp is not None
     start = positions[:, 0]
     if win is not None:
-        wk, wv, wks, wvs, win_len = win
+        window, win_len, slots = win
         base = start - win_len  # flushed pool length per row
     out = None
     tried_kernel = True
@@ -938,9 +1034,10 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
             wcnt = jnp.where(active, win_len + T, 0)
             out = paged_attention_sharded(q[:, 0], kp, vp, layer,
                                           page_table, lens, ksp, vsp,
-                                          win_k=wk, win_v=wv,
+                                          win_k=window.k, win_v=window.v,
                                           win_count=wcnt,
-                                          win_k_scale=wks, win_v_scale=wvs,
+                                          win_k_scale=window.k_scale,
+                                          win_v_scale=window.v_scale,
                                           sliding_window=sliding_window)
         else:
             # lengths INCLUDING the token just written (inactive: 0 ->
@@ -998,12 +1095,18 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
         if tried_kernel:
             note_kernel("dense_fallback")
         mask = layer_mask(mask, positions, sliding_window)
+        if win is not None:
+            wk, wv, wks, wvs = (window_rows(a, layer, slots) for a in (
+                window.k, window.v, window.k_scale, window.v_scale))
         if quant:
             ck, k_s = gather_paged_layer_q(kp, ksp, page_table, layer)
             cv, v_s = gather_paged_layer_q(vp, vsp, page_table, layer)
             if win is not None:
-                ck, k_s = insert_window_view_q(ck, k_s, wk, wks, base)
-                cv, v_s = insert_window_view_q(cv, v_s, wv, wvs, base)
+                Kv = wk.shape[1]
+                ck, k_s = insert_window_view_q(
+                    ck, k_s, wk, scales_by_head(wks, Kv), base)
+                cv, v_s = insert_window_view_q(
+                    cv, v_s, wv, scales_by_head(wvs, Kv), base)
             out = attend(q, *_settled(ck, cv), mask, cfg, *_settled(k_s, v_s))
         else:
             ck = gather_paged_layer(kp, page_table, layer)
@@ -1064,7 +1167,7 @@ def _as_pool(pools):
 def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
                      positions, mask, cos, sin, active, use_kernel: bool,
                      fresh: bool, ksp=None, vsp=None, win=None, layer=None,
-                     force_dense: bool = False, kip=None, wki=None):
+                     force_dense: bool = False, kip=None):
     """One transformer layer against its page pool.
 
     Shared by paged_forward's full-stack scan, the stage-local scan of
@@ -1075,29 +1178,34 @@ def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
     [P,Kv*page] scale slices iff the pool is int8. Returns
     (x, kp, vp[, ksp, vsp]).
 
-    win (kv_write_combine): (wk, wv, wks, wvs, win_len) — this layer's
-    window slices [S, Kv, W, H] (+ [S, Kv, W] scales iff quantized) and
-    the per-slot staged count. The pool is then READ-ONLY and comes
-    whole, kp/vp [L,P,Kv,page,H] (ksp/vsp [L,P,Kv*page]) with `layer`
-    this layer's index in it: fresh K/V stages into the window instead
-    of scattering the pool, and attention reads pool + window (kernel:
-    window segment folded into the online softmax; dense: window
-    inserted into the gathered view at absolute positions,
-    element-wise identical to the window-off written view). Returns
-    (x, wk, wv[, wks, wvs]) — the pool rides outside the scan unchanged.
+    win (kv_write_combine): (window, win_len) — the WHOLE window
+    (KVWindow, leaves [L, S, Kv, W, H]), which rides the layer scan's
+    carry, and the per-slot staged count. The pool is then READ-ONLY
+    and comes whole too, kp/vp [L,P,Kv,page,H] (ksp/vsp [L,P,Kv*page]),
+    with `layer` this layer's index in both: fresh K/V stages into the
+    window's layer (stage_window_layer) instead of scattering the pool,
+    and attention reads pool + window (kernel: window segment folded
+    into the online softmax; dense: window inserted into the gathered
+    view at absolute positions, element-wise identical to the
+    window-off written view). Returns (x, window) — the pool rides
+    outside the scan unchanged.
 
-    kip, wki (a model with an indexer): the index keys' pool and window
-    slice, as kp and wk are given; what was written comes back last.
+    kip (a model with an indexer): the index keys' pool, as kp is
+    given; what was written comes back last.
     """
     quant = ksp is not None
     lp, q, k, v, route, sliding_window, index = _layer_open(x, lp, cfg,
                                                             cos, sin)
     if win is not None:
-        wk, wv, wks, wvs, win_len = win
-        wk, wv, wks, wvs = stage_window_layer(wk, wv, k, v, win_len,
-                                              wks, wvs)
-        if index is not None:
-            wki = stage_index_layer(wki, index[1], win_len)
+        window, win_len = win
+        B, T = k.shape[:2]
+        # token t of slot b lands at window index win_len[b] + t
+        window = stage_window_layer(
+            window, layer, k, v, None if index is None else index[1],
+            jnp.repeat(jnp.arange(B), T),
+            (win_len[:, None] + jnp.arange(T)[None, :]).reshape(-1),
+            (window_runs(jnp.arange(B), win_len, T, window.width),), (T,),
+            use_kernel)
     else:
         kp, vp, ksp, vsp = write_paged_layer(kp, vp, page_table, k, v,
                                              positions[:, 0], active,
@@ -1111,16 +1219,15 @@ def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
         q, k, v, pool[0], pool[1], layer, cfg=cfg, page_table=page_table,
         positions=positions, mask=mask, active=active,
         use_kernel=use_kernel, fresh=fresh, ksp=pool[2], vsp=pool[3],
-        win=None if win is None else (wk, wv, wks, wvs, win_len),
+        win=None if win is None else (window, win_len, None),
         force_dense=force_dense, sliding_window=sliding_window,
-        index=None if index is None
-        else (index[0], index[2], pool[4], wki))
+        index=None if index is None else (index[0], index[2], pool[4]))
     if index is not None:
         out = out[0]
     x, _ = _layer_close(x, out, lp, cfg, route)
-    written = (wk, wv, wks, wvs, wki) if win is not None \
-        else (kp, vp, ksp, vsp, kip)
-    return (x, *(a for a in written if a is not None))
+    if win is not None:
+        return x, window
+    return (x, *(a for a in (kp, vp, ksp, vsp, kip) if a is not None))
 
 
 def paged_forward(params, cfg: ModelConfig, tokens: jax.Array,
@@ -1216,8 +1323,9 @@ def paged_forward_window(params, cfg: ModelConfig, tokens: jax.Array,
     xs nor a lax.dynamic_index in the body will do: each hands the
     kernel one layer's slice, and a custom call's operand is a buffer,
     so XLA copied that layer (67 MB of int8 codes at 7B, keys and again
-    values) in every layer of every step. Only the small window leaves
-    ride the scan as xs/ys.
+    values) in every layer of every step. The window rides the scan's
+    CARRY, whole, and is written and read by the layer's index like the
+    pool (the comment above KVWindow says why not as xs/ys).
 
     `positions`/`attn_mask` override the causal defaults for the
     tree-verify path (paged_forward's attn_mask docs): staging still
@@ -1238,24 +1346,20 @@ def paged_forward_window(params, cfg: ModelConfig, tokens: jax.Array,
         else make_mask(positions, cache.max_seq)
     mask = mask & active[:, None, None]
 
-    def body(carry, scanned):
-        x, i = carry
-        lp, wk, wv, wks, wvs, wki = scanned
-        out = paged_layer_body(
+    def body(carry, lp):
+        x, i, window = carry
+        x, window = paged_layer_body(
             x, lp, cache.k_pages, cache.v_pages, cfg=cfg,
             page_table=cache.page_table,
             positions=positions, mask=mask, cos=cos, sin=sin,
             active=active, use_kernel=use_kernel, fresh=False,
             ksp=cache.k_scale_pages, vsp=cache.v_scale_pages,
-            win=(wk, wv, wks, wvs, win_len), layer=i,
-            kip=cache.ki_pages, wki=wki)
-        return (out[0], i + 1), tuple(out[1:])
+            win=(window, win_len), layer=i, kip=cache.ki_pages)
+        return (x, i + 1, window), None
 
-    (x, _), new_win = lax.scan(
-        body, (x, 0), (layer_stack(params["layers"], cfg),
-                       *window_leaves(window, absent=True)))
-    logits = final_logits(params, cfg, x)
-    return logits, window_leaves(window, new_win)
+    (x, _, window), _ = lax.scan(body, (x, 0, window),
+                                 layer_stack(params["layers"], cfg))
+    return final_logits(params, cfg, x), window
 
 
 class PackedRows(NamedTuple):
@@ -1267,6 +1371,9 @@ class PackedRows(NamedTuple):
     ok: jax.Array           # [N] real: it writes its K/V
     table: Optional[jax.Array]    # [N, max_pages], window off
     widx: Optional[jax.Array]     # [N] window index (W: dropped), window on
+    runs: Optional[Tuple[jax.Array, ...]]   # the same as runs of one
+    # slot's consecutive entries (window_runs): the decode rows', each a
+    # run of one, and with P > 0 the chunks', each a run of C
     cos: jax.Array
     sin: jax.Array
     page_table: jax.Array   # [S, max_pages] the decode rows' tables
@@ -1297,22 +1404,27 @@ def packed_rows(params, cfg: ModelConfig, tokens, cache: PagedKVCache,
     ok = jnp.concatenate([active, (ccol < chunk_count[:, None]).reshape(-1)])
     tok = jnp.concatenate([tokens, chunk_tokens.reshape(-1)])
     x, cos, sin = embed_tokens(params, cfg, tok[:, None], pos[:, None])
-    table = widx = None
+    table = widx = runs = None
     if window is None:
         table = cache.page_table[slot]
     else:
+        W = window.width
         widx = jnp.where(
             ok, win_len[slot] + jnp.concatenate(
                 [jnp.zeros((S,), jnp.int32),
-                 jnp.broadcast_to(ccol, (P, C)).reshape(-1)]),
-            window.width)
+                 jnp.broadcast_to(ccol, (P, C)).reshape(-1)]), W)
+        runs = (window_runs(jnp.arange(S), win_len, active, W),)
+        if P:
+            runs += (window_runs(chunk_slot, win_len[chunk_slot],
+                                 jnp.clip(chunk_count, 0, C), W),)
     # a chunk's last real column stands in for its slot's own (masked)
     # decode row under the head
     hit = chunk_ok[:, None] & (chunk_slot[:, None] == jnp.arange(S)[None, :])
     last = S + jnp.arange(P) * C + jnp.clip(chunk_count - 1, 0, C - 1)
     head = jnp.where(hit.any(0), (hit * last[:, None]).sum(0), jnp.arange(S))
     return x, PackedRows(
-        slot=slot, pos=pos, ok=ok, table=table, widx=widx, cos=cos, sin=sin,
+        slot=slot, pos=pos, ok=ok, table=table, widx=widx, runs=runs,
+        cos=cos, sin=sin,
         page_table=cache.page_table, written=written, active=active,
         dec_mask=make_mask(written[:, None], cache.max_seq)
         & active[:, None, None],
@@ -1323,21 +1435,23 @@ def packed_rows(params, cfg: ModelConfig, tokens, cache: PagedKVCache,
         chunk_table=cache.page_table[chunk_slot], head=head)
 
 
-def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
-                 use_kernel: bool, layer=None):
+def packed_layer(x, lp, pools, window: Optional[KVWindow], rows: PackedRows,
+                 cfg: ModelConfig, use_kernel: bool, layer=None,
+                 looped: bool = True):
     """One layer of the packed step. pools: (kp, vp, ksp, vsp, kip),
     scales None unless int8 and kip None unless the model has an
     indexer: with the window off this layer's slices, which it
     writes; with it on the WHOLE read-only pool, and `layer` this
-    layer's index in it (paged_layer_body has the same two cases). wl:
-    the layer's window slices (wk, wv, wks, wvs, wki), or None with the
-    window off. Every real row's entry goes
-    where the lane-wide step put it, the window at win_len (+ t) or
-    the pool at the row's position, in ONE stage or scatter. Attention
-    is paged_attend twice: the S decode rows as its T == 1 case (the
-    paged kernel, with the window segment), each chunk as its T == C
-    case over its OWN slot's table row and window slice. Returns
-    (x, pools, wl, load): pools and window as written, and what the
+    layer's index in it (paged_layer_body has the same two cases).
+    window: the WHOLE window, out of the layer scan's carry, written and
+    read at `layer` too, or None with the window off. Every real row's
+    entry goes where the lane-wide step put it, the window at win_len
+    (+ t) or the pool at the row's position, in ONE stage or scatter.
+    Attention is paged_attend twice: the S decode rows as its T == 1
+    case (the paged kernel, with the window segment), each chunk as its
+    T == C case over its OWN slot's table row and window entries.
+    Returns (x, pools, window, load): pools and window as written, and
+    what the
     layer's routing asked of its experts for the step's real rows
     (_layer_close; None for a dense model). For a model with an indexer
     `load` carries three values more: sparse_paged_attend's count of
@@ -1348,18 +1462,13 @@ def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
     lp, q, k, v, route, sliding_window, index = _layer_open(
         x, lp, cfg, rows.cos, rows.sin)
     dec_win = chunk_win = None
-    wki = chunk_wki = None
-    if wl is not None:
-        wl4 = stage_window_layer(wl[0], wl[1], k, v, rows.widx, wl[2], wl[3],
-                                 rows=rows.slot)
-        if index is not None:
-            wki = stage_index_layer(wl[4], index[1], rows.widx,
-                                    rows=rows.slot)
-            chunk_wki = wki[rows.chunk_slot]
-        wl = (*wl4, wki)
-        dec_win = (*wl4, rows.win_len)
-        chunk_win = (*(None if a is None else a[rows.chunk_slot]
-                       for a in wl4), rows.win_len[rows.chunk_slot])
+    if window is not None:
+        window = stage_window_layer(
+            window, layer, k, v, None if index is None else index[1],
+            rows.slot, rows.widx, rows.runs, (1, C)[:len(rows.runs)],
+            use_kernel, looped)
+        dec_win = (window, rows.win_len, None)
+        chunk_win = (window, rows.win_len[rows.chunk_slot], rows.chunk_slot)
     else:
         kip = pools[4]
         if index is not None:
@@ -1368,22 +1477,21 @@ def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
         pools = (*write_paged_layer(pools[0], pools[1], rows.table, k, v,
                                     rows.pos, rows.ok, pools[2], pools[3]),
                  kip)
-    (kp, vp, ksp, vsp, kip), layer = (pools, layer) if wl is not None \
+    (kp, vp, ksp, vsp, kip), layer = (pools, layer) if window is not None \
         else _as_pool(pools)
     attend_rows = partial(paged_attend, kp=kp, vp=vp, layer=layer, cfg=cfg,
                           use_kernel=use_kernel, fresh=False,
                           ksp=ksp, vsp=vsp, sliding_window=sliding_window)
 
-    def rows_index(cut, wki):
+    def rows_index(cut):
         """paged_attend's `index` for one group of rows."""
-        return None if index is None \
-            else (cut(index[0]), cut(index[2]), kip, wki)
+        return None if index is None else (cut(index[0]), cut(index[2]), kip)
 
     out = attend_rows(q[:S], k[:S], None if v is None else v[:S],
                       page_table=rows.page_table,
                       positions=rows.written[:, None], mask=rows.dec_mask,
                       active=rows.active, win=dec_win,
-                      index=rows_index(lambda a: a[:S], wki))
+                      index=rows_index(lambda a: a[:S]))
     count = None
     counted = index is not None or cfg.is_latent
     if counted:
@@ -1397,7 +1505,7 @@ def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
                             page_table=rows.chunk_table,
                             positions=rows.chunk_pos, mask=rows.chunk_mask,
                             active=rows.chunk_ok, win=chunk_win,
-                            index=rows_index(chunks, chunk_wki))
+                            index=rows_index(chunks))
         if counted:
             out_c = out_c[0]
         out = jnp.concatenate(
@@ -1407,7 +1515,7 @@ def packed_layer(x, lp, pools, wl, rows: PackedRows, cfg: ModelConfig,
         if load is None and not cfg.is_latent:
             load = jnp.zeros((3,), jnp.float32)
         load = count if load is None else jnp.concatenate([load, count])
-    return x, pools, wl, load
+    return x, pools, window, load
 
 
 def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
@@ -1423,13 +1531,14 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
     carries the recurrent state (ssm_state.advance_packed); an
     attention run is packed_layer as every other model runs it, over the
     pool's layer a (the pool holds attention layers only): window on,
-    the read-only pool whole and the run's window slices as xs; window
-    off, the run's pool slices. Returns (x, window or cache as written,
+    the read-only pool whole and the window, whole, in the carry of
+    every run's scan, each run starting from its first layer's index
+    among the attention layers; window off, the run's pool slices as
+    xs. Returns (x, window or cache as written,
     state, load): load the mean of expert_load over the layers that
     route; a latent-attention model's ends in the SUM over the layers
     of latent_paged_attend's count."""
     pools = pool_leaves(cache, absent=True)
-    held = pools if window is None else window_leaves(window, absent=True)
     written, loads = [], []
 
     def mamba(carry, idx):
@@ -1440,35 +1549,39 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
             layer_at(params["mamba"], m, cfg), st, m, rows, cfg, use_kernel)
         return (x, st), load
 
-    def attention(ffn, x, scanned):
+    def attention(ffn, looped, carry, scanned):
+        x, win = carry
         (l, a), *mine = scanned
         lp = run_layer_at(params, ffn, l, cfg)
         if "attn" in params:
             lp = {**lp, "attn": layer_at(params["attn"], a, cfg)}
-        if window is None:
+        if win is None:
             x, new, _, load = packed_layer(x, lp, mine, None, rows, cfg,
                                            use_kernel)
-        else:
-            x, _, new, load = packed_layer(x, lp, pools, mine, rows, cfg,
-                                           use_kernel, layer=a)
-        return x, (new, load)
+            return (x, None), (new, load)
+        x, _, win, load = packed_layer(x, lp, pools, win, rows, cfg,
+                                       use_kernel, layer=a, looped=looped)
+        return (x, win), (None, load)
 
     for kind, first, n, at in layer_runs(cfg):
         idx = (first + jnp.arange(n), at + jnp.arange(n))
         if kind == "mamba":
             (x, state), load = lax.scan(mamba, (x, state), idx)
         else:
-            x, (new, load) = lax.scan(
-                partial(attention, ffn_run(params, first, cfg)), x,
-                (idx, *(None if a is None else a[at:at + n] for a in held)))
+            mine = () if window is not None else (
+                None if a is None else a[at:at + n] for a in pools)
+            (x, window), (new, load) = lax.scan(
+                partial(attention, ffn_run(params, first, cfg), n > 1),
+                (x, window), (idx, *mine))
             written.append(new)
         loads.append(load)
-    if written:
+    kv = window
+    if window is None:
         # one run's slices as they are; several runs' joined in order
-        held = written[0] if len(written) == 1 else tuple(
-            None if a[0] is None else jnp.concatenate(a)
-            for a in zip(*written))
-    kv = pool_leaves(cache, held) if window is None else KVWindow(*held)
+        kv = pool_leaves(cache, pools if not written else written[0]
+                         if len(written) == 1 else tuple(
+                             None if a[0] is None else jnp.concatenate(a)
+                             for a in zip(*written)))
     if cfg.is_latent:
         # a dense layer's load is its count alone, an expert layer's
         # ends in it
@@ -1583,20 +1696,17 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
         state = pool_leaves(cache, pools)
     else:
         # the pool is read-only and goes in whole beside the layer's
-        # index, as in paged_forward_window; only the window's leaves
-        # ride the scan
-        def body(carry, scanned):
-            x, i = carry
-            lp, *wl = scanned
-            x, _, wl, load = packed_layer(
-                x, lp, pool_leaves(cache, absent=True), wl, rows, cfg,
+        # index, as in paged_forward_window; the window rides the carry,
+        # whole, written and read by the same index
+        def body(carry, lp):
+            x, i, window = carry
+            x, _, window, load = packed_layer(
+                x, lp, pool_leaves(cache, absent=True), window, rows, cfg,
                 use_kernel, layer=i)
-            return (x, i + 1), (wl, load)
+            return (x, i + 1, window), load
 
-        (x, _), (new_win, load) = lax.scan(
-            body, (x, 0), (layer_stack(params["layers"], cfg),
-                           *window_leaves(window, absent=True)))
-        state = KVWindow(*new_win)
+        (x, _, state), load = lax.scan(body, (x, 0, window),
+                                       layer_stack(params["layers"], cfg))
     if load is not None:
         load = load.mean(axis=0)
     return final_logits(params, cfg, x[rows.head])[:, 0], state, load

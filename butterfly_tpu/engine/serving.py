@@ -1543,7 +1543,9 @@ def _decode_scan_win(cfg: ModelConfig, k: int, params, tokens,
     cache.lengths does window-off. The pool scatter this scan no longer
     pays per step — and the pool COPY the scatter forced, because XLA
     cannot alias a scatter into a scan carry — happens once per
-    scheduler drain (engine.flush_kv_window).
+    scheduler drain (engine.flush_kv_window). The window is carried
+    through the layers whole as it is through the steps, and written in
+    place (cache/paged.py stage_window_layer).
 
     Returns (block [k, S], final [S], cache, window, win_len).
     """

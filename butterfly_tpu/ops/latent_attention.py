@@ -28,9 +28,10 @@ as keys and again as values, would fetch it twice):
   finite numbers like every page (the pool is born zero and only ever
   written with projections): its columns are masked to probability 0,
   so whole chunks are copied without a branch a page;
-* the write-combined window [S, 1, W, R] (cache/paged.py: staged rows
-  at positions lengths .. lengths + win_count - 1) is one more chunk,
-  pipelined a slot by its BlockSpec.
+* the write-combined window [L, S, 1, W, R] (cache/paged.py: staged
+  rows at positions lengths .. lengths + win_count - 1) comes whole, as
+  it rides the layer scan; (layer, slot)'s block of it is one more
+  chunk, pipelined a slot by its BlockSpec.
 
 On the CPU backend the wrapper runs the kernel in interpreter mode;
 everywhere else it is compiled (ops/__init__.py has the rule).
@@ -46,6 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from butterfly_tpu.ops import (note_kernel, resolve_interpret,
                                sublane_multiple)
+from butterfly_tpu.ops.window_stage import in_hbm
 
 NEG_INF = -1e30
 #: pages one chunk of the context takes: 32 pages of 16 tokens are 512
@@ -163,13 +165,14 @@ def latent_attention(q: jax.Array, pages: jax.Array, layer,
     values of a row that are its "values" (kv_lora_rank); scale: the
     score scale. Returns o' [slots, Nq, rank].
 
-    win [S, 1, W, R] + win_count [S]: the write-combined window's
+    win [L, S, 1, W, R] + win_count [S]: the write-combined window,
+    whole, of which `layer` is read: its
     staged rows at positions lengths[s] .. lengths[s] + win_count[s] - 1
     (win_count INCLUDES the just-staged current token; `lengths` is then
     the FLUSHED length alone), as ops/paged_attention.py takes them."""
     S, Nq, R = q.shape
     page = pages.shape[3]
-    window = 0 if win is None else win.shape[2]
+    window = 0 if win is None else win.shape[3]
     interpret = resolve_interpret(interpret)
     note_kernel("latent" + ("_win" if window else ""), interpret)
     chunk = PAGES_PER_CHUNK * page
@@ -183,9 +186,10 @@ def latent_attention(q: jax.Array, pages: jax.Array, layer,
     prefetch = [jnp.asarray(layer, jnp.int32).reshape(1), page_table,
                 lengths]
     if window:
-        in_specs.append(pl.BlockSpec((1, 1, window, R),
-                                     lambda s, *_: (s, 0, 0, 0)))
-        args.append(win)
+        in_specs.append(pl.BlockSpec(
+            (None, 1, 1, window, R),
+            lambda s, layer_ref, *_: (layer_ref[0], s, 0, 0, 0)))
+        args.append(win if interpret else in_hbm((win,))[0])
         prefetch.append(win_count)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch), grid=(S,), in_specs=in_specs,

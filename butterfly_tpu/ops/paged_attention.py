@@ -70,6 +70,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from butterfly_tpu.ops import (note_kernel, resolve_interpret,
                                sublane_multiple)
+from butterfly_tpu.ops.window_stage import in_hbm, window_step
 
 NEG_INF = -1e30
 #: bytes of VMEM the two buffers of keys and the two of values may take
@@ -81,8 +82,6 @@ MAX_PAGES_PER_CHUNK = 32
 #: large steps beat many small ones (8 pages a step read 8 % slower on
 #: the chip, 4 pages 12-26 %, 32 pages 17 % at contexts of 170: PERF.md)
 SUB_PAGES = 16
-#: positions of the window one step multiplies (an int8 tile's rows)
-WINDOW_STEP = 32
 
 
 def fits(k_pages: jax.Array, q_dtype) -> bool:
@@ -115,12 +114,6 @@ def _pages_per_chunk(kv_heads: int, page: int, head_dim: int, dtype) -> int:
     one = kv_heads * page * head_dim * jnp.dtype(dtype).itemsize
     n = max(1, VMEM_BUFFERS // (4 * one))
     return min(MAX_PAGES_PER_CHUNK, 1 << (n.bit_length() - 1))
-
-
-def _window_step(window: int) -> int:
-    """Positions of the write-combined window one step multiplies: whole
-    tiles of any pool's dtype where the window is such, else all."""
-    return WINDOW_STEP if window % WINDOW_STEP == 0 else window
 
 
 def _update(q, k, v, mask, carry, ks, vs, scale: float):
@@ -170,7 +163,11 @@ def _paged_kernel(meta_ref, table_ref, len_ref, *rest, page: int,
     window segment [Kv, W, H], staged-but-unflushed K/V at absolute
     positions length .. length + win_count - 1, is more steps of the
     same recurrence, as many as hold what is staged (the
-    kv_write_combine serving path; cache/paged.py window docs)."""
+    kv_write_combine serving path; cache/paged.py window docs). The
+    window's leaves come WHOLE, [L, S, Kv, W, H], and the block a grid
+    step sees is (layer, slot)'s, the layer out of the prefetched
+    scalars as the pool's is; an int8 window's scales come as they are
+    stored, a step's one flat kv-major row [W/ws, Kv*ws]."""
     if window:
         wc_ref, *rest = rest
     q_ref, k_ref, v_ref, *rest = rest
@@ -308,7 +305,7 @@ def _paged_kernel(meta_ref, table_ref, len_ref, *rest, page: int,
         # width `ws` at positions length + w, as many as hold the slot's
         # staged count (a decode row stages a token a step: a few of the
         # window's hundreds): kv-major flat columns c = kv*ws + w
-        ws = _window_step(window)
+        ws = window_step(window)
         staged = wc_ref[slot]
         w = jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, kv_heads * ws), 2) % ws
@@ -325,8 +322,10 @@ def _paged_kernel(meta_ref, table_ref, len_ref, *rest, page: int,
                     1, kv_heads * ws, H).astype(dt)
 
             return _update(q, flat(wk_ref), flat(wv_ref), live & own_w,
-                           carry, wks_ref[0, pl.ds(j, 1)] if quant else None,
-                           wvs_ref[0, pl.ds(j, 1)] if quant else None, scale)
+                           carry,
+                           wks_ref[0, pl.ds(j, 1)][None] if quant else None,
+                           wvs_ref[0, pl.ds(j, 1)][None] if quant else None,
+                           scale)
 
         carry = jax.lax.fori_loop(0, (staged + ws - 1) // ws, wstep, carry)
     _, l, acc = carry
@@ -363,10 +362,11 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
     flash_attention_sharded for the opaque-custom-call rationale); with
     no mesh at all this is exactly `paged_attention`.
 
-    win_k/win_v [S, Kv, W, H] (+ win_k/v_scale [S, Kv, W] iff quant) +
-    win_count [S]: the write-combined window segment (kv_write_combine)
-    — slots shard over `data` with q/table/lengths, kv-heads over
-    `tensor` with the pools.
+    win_k/win_v [L, S, Kv, W, H] (+ win_k/v_scale [L, S, W/ws, Kv*ws]
+    iff quant) + win_count [S]: the write-combined window, whole, of
+    which `layer` is read (kv_write_combine) — slots shard over `data`
+    with q/table/lengths, kv-heads over `tensor` with the pools (a
+    `tensor` shard of a scale row's flat kv-major dim is its heads').
 
     sliding_window: the layer's window out of its pattern (a traced
     scalar, 0 = a full layer; None = the model has no pattern and the
@@ -397,12 +397,12 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
         named.update(k_scale_pages=(k_scale_pages, P(None, None, t)),
                      v_scale_pages=(v_scale_pages, P(None, None, t)))
     if win_k is not None:
-        win_spec = P(d, t, None, None)
+        win_spec = P(None, d, t, None, None)
         named.update(win_k=(win_k, win_spec), win_v=(win_v, win_spec),
                      win_count=(win_count, P(d)))
         if win_k_scale is not None:
-            named.update(win_k_scale=(win_k_scale, P(d, t, None)),
-                         win_v_scale=(win_v_scale, P(d, t, None)))
+            named.update(win_k_scale=(win_k_scale, P(None, d, None, t)),
+                         win_v_scale=(win_v_scale, P(None, d, None, t)))
     if sliding_window is not None:
         named.update(sliding_window=(
             jnp.asarray(sliding_window, jnp.int32), P()))
@@ -441,16 +441,20 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     k/v_scale_pages: [L, P, Kv*page] f32 per-vector scales iff the pool
     holds int8 codes. Returns [slots, Nq, H].
 
-    Write-combined window (kv_write_combine): win_k/win_v [S, Kv, W, H]
-    hold each slot's staged-but-unflushed K/V (pool representation —
-    int8 codes with win_k/v_scale [S, Kv, W] when the pool is
-    quantized), at absolute positions lengths[s]..lengths[s] +
-    win_count[s] - 1; `lengths` is then the FLUSHED pool length only
-    and win_count INCLUDES the just-staged current token. The segment
-    is one more step of the same online-softmax recurrence as the
-    chunks of pages (its DMA is one [Kv, W, H] block per slot, pipelined
-    by its BlockSpec — the staged run never round-trips through the
-    pool).
+    Write-combined window (kv_write_combine): win_k/win_v
+    [L, S, Kv, W, H], the WHOLE window as it rides the layer scan (a
+    caller that holds one layer's [S, Kv, W, H] passes `win[None]`
+    beside a pool of that one layer), hold each slot's
+    staged-but-unflushed K/V (pool representation — int8 codes with
+    win_k/v_scale [L, S, W/ws, Kv*ws], a step's scales one flat kv-major
+    row as cache/paged.py stores them, when the pool is quantized), at
+    absolute positions lengths[s]..lengths[s] + win_count[s] - 1;
+    `lengths` is then the FLUSHED pool length only and win_count
+    INCLUDES the just-staged current token. The segment is more steps
+    of the same online-softmax recurrence as the chunks of pages (its
+    DMA is one [Kv, W, H] block per slot, (layer, slot)'s, pipelined by
+    its BlockSpec — the staged run never round-trips through the pool,
+    and no layer is cut out of the window).
 
     sliding_window (int32 scalar, may be traced; None = none): the
     slot's one query, at the last of its lengths (+ win_count)
@@ -459,7 +463,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     S, Nq, H = q.shape
     L, Pp, Kv, page, H2 = k_pages.shape
     quant = k_scale_pages is not None
-    window = 0 if win_k is None else win_k.shape[2]
+    window = 0 if win_k is None else win_k.shape[3]
     interpret = resolve_interpret(interpret)
     sliding = sliding_window is not None
     # scalar-prefetch operands: (layer[, sliding window], table,
@@ -476,8 +480,9 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     def slot_map(s, *_):
         return (s, 0, 0)
 
-    def win_map(s, *_):
-        return (s, 0, 0, 0)
+    def win_map(rank):
+        """(layer, slot)'s block of a window leaf of `rank` dims."""
+        return lambda s, meta_ref, *_: (meta_ref[0], s) + (0,) * (rank - 2)
 
     n = _pages_per_chunk(Kv, page, H, k_pages.dtype)
     pool = pl.BlockSpec(memory_space=pl.ANY)
@@ -506,22 +511,18 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         args.append(rows)
         scratch.append(pltpu.VMEM((2, n, *rows.shape[1:]), jnp.float32))
     if window:
-        in_specs += [
-            pl.BlockSpec((1, Kv, window, H), win_map),
-            pl.BlockSpec((1, Kv, window, H), win_map),
-        ]
+        if not interpret:
+            win_k, win_v, win_k_scale, win_v_scale = in_hbm(
+                (win_k, win_v, win_k_scale, win_v_scale))
+        # the layer's block of the whole leaf: the layer dim squeezed
+        in_specs += [pl.BlockSpec((None, 1, Kv, window, H), win_map(5))] * 2
         args += [win_k, win_v]
         if quant:
-            # a step's scales as one flat kv-major row, like a page's
-            ws = _window_step(window)
-
-            def by_step(a):
-                return a.reshape(S, Kv, window // ws, ws).transpose(
-                    0, 2, 1, 3).reshape(S, window // ws, 1, Kv * ws)
-
-            in_specs += [pl.BlockSpec((1, window // ws, 1, Kv * ws),
-                                      win_map)] * 2
-            args += [by_step(win_k_scale), by_step(win_v_scale)]
+            # a step's scales are one flat kv-major row, like a page's,
+            # and stored so: read where they lie
+            in_specs += [pl.BlockSpec((None, 1, *win_k_scale.shape[2:]),
+                                      win_map(4))] * 2
+            args += [win_k_scale, win_v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch), grid=(S,), in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Nq, H), slot_map),

@@ -222,7 +222,9 @@ def kv_window_specs(cfg: ModelConfig, mesh: Mesh, num_slots: int,
     dslots = _div(num_slots, mesh, "data")
     tspec = _div(cfg.num_kv_heads, mesh, "tensor")
     kv = P(None, dslots, *_kv_row_spec(cfg, mesh))
-    sc = P(None, dslots, tspec, None) if quant else None
+    # an int8 window's scales [L, S, W/ws, Kv*ws]: a `tensor` chunk of
+    # a step's flat kv-major row is its heads' scales, as a page's is
+    sc = P(None, dslots, None, tspec) if quant else None
     ki = P(None, dslots, None, None, None) if cfg.has_indexer else None
     return KVWindow(k=kv, v=kv, k_scale=sc, v_scale=sc, ki=ki)
 

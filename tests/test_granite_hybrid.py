@@ -632,13 +632,13 @@ def test_the_paged_kernel_compiles_for_the_chip_at_the_cells_geometries(
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    kw = dict(win_k=sds((S, Kv, W, H), pd), win_v=sds((S, Kv, W, H), pd),
-              win_count=sds((S,), i32))
+    kw = dict(win_k=sds((L, S, Kv, W, H), pd),
+              win_v=sds((L, S, Kv, W, H), pd), win_count=sds((S,), i32))
     if quant:
         kw.update(k_scale_pages=sds((L, P, Kv * page), f32),
                   v_scale_pages=sds((L, P, Kv * page), f32),
-                  win_k_scale=sds((S, Kv, W), f32),
-                  win_v_scale=sds((S, Kv, W), f32))
+                  win_k_scale=sds((L, S, W // 32, Kv * 32), f32),
+                  win_v_scale=sds((L, S, W // 32, Kv * 32), f32))
     if sliding:
         kw.update(sliding_window=sds((), i32))
     fn = jax.jit(lambda *a, **k: paged_attention.__wrapped__(
@@ -656,6 +656,102 @@ def test_the_paged_kernel_compiles_for_the_chip_at_the_cells_geometries(
     # the layer's scale rows of an int8 pool are cut out in XLA (4 MB at
     # these sizes); nothing else is made, and never a pool
     assert compiled.memory_analysis().temp_size_in_bytes < 16e6
+
+
+#: tiny models at the chip's tile sizes (heads of 128, pages of 16, a
+#: window of 64) whose windows the serving path stages and reads
+#: differently: name -> (tiny's arch and overrides, int8 KV)
+WINDOWED = {
+    "plain_bf16": (("llama", dict(head_dim=128)), "none"),
+    "plain_int8": (("llama", dict(head_dim=128, num_kv_heads=4)), "int8"),
+    "sliding": (("smallthinker", dict(head_dim=128)), "none"),
+    "runs_granite": (("granite_hybrid", dict(head_dim=128, ssm_state=128)),
+                     "none"),
+    "runs_joyai": (("joyai", dict(kv_lora_rank=128, num_layers=4)), "none"),
+    "keye": (("keye", dict(head_dim=128)), "none"),
+}
+
+
+@pytest.mark.parametrize("chunks", [1, 0], ids=["mixed", "decode"])
+@pytest.mark.parametrize("model", sorted(WINDOWED))
+def test_a_block_moves_no_window_inside_its_loops(model, chunks, one_chip,
+                                                  monkeypatch):
+    """The engine's mixed block and its decode block (engine/serving.py
+    _packed_scan: steps around layers), kernels ON, compiled for the
+    TPU: the window rides every layer scan whole, in the carry, and is
+    written in place, so no `copy`, `transpose` or fusion inside any
+    loop makes a value of a leaf's whole shape [L, S, Kv, W, H] nor of
+    one layer's slice of it (tools/chip_kernels.py window_moves; as
+    scanned inputs and stacked outputs the leaves were copied whole in
+    every step: PERF.md, PR 47). A plain scan with bfloat16 and int8
+    leaves and their scales, a sliding model, the runs of granite's and
+    JoyAI's layers, Keye's token-major leaves and index keys, which
+    XLA stages."""
+    import sys
+    from functools import partial
+    from pathlib import Path
+
+    from butterfly_tpu.cache.paged import paged_forward_packed
+    from butterfly_tpu.engine.serving import _packed_scan
+    root = Path(__file__).resolve().parent.parent
+    sys.path.insert(0, str(root / "tools"))
+    try:
+        from chip_kernels import window_moves
+    finally:
+        sys.path.remove(str(root / "tools"))
+    (arch, kw), kv_quant = WINDOWED[model]
+    cfg = tiny(arch, **kw).replace(dtype="bfloat16")
+    S, k, C, W = 4, 2, 32, 64
+    rt = RuntimeConfig(max_batch_size=S, max_seq_len=128, page_size=16,
+                       kv_quant=kv_quant)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(lambda: init_params_by_leaf(
+        cfg, jax.random.PRNGKey(0), quant="int8")))
+    cache = jax.eval_shape(lambda: init_paged_cache(cfg, rt))
+    window = on_chip(jax.eval_shape(lambda: init_kv_window(cache, W)))
+    i32 = jnp.int32
+    args = [params, sds((S,), i32), sds((S,), i32), on_chip(cache), window,
+            sds((S,), i32), sds((S, 128), i32), sds((S,), i32),
+            sds((S,), bool), sds((S,), jnp.float32), sds((S,), i32),
+            sds((S,), i32), 0, 1.0, sds((2,), jnp.uint32)]
+    donated = (2, 3, 4, 5)
+    if cfg.has_ssm:
+        args.append(on_chip(jax.eval_shape(lambda: init_ssm_state(cfg, S))))
+        donated += (15,)
+    prog = jax.jit(partial(_packed_scan, cfg, paged_forward_packed, k, C,
+                           chunks, use_kernel=True),
+                   static_argnums=(12, 13), donate_argnums=donated)
+    jax.clear_caches()          # no interpreted trace of a kernel is met
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        with jax.disable_jit(False):
+            compiled = prog.lower(*args).compile()
+    except Exception as e:  # the TPU library is one process's at a time
+        if "Mosaic" in str(e):      # a kernel refused is no skip
+            raise
+        pytest.skip(f"the TPU compiler could not be used here: {e}")
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    hlo = compiled.as_text()
+    leaves = jax.tree.leaves(window)
+    assert window_moves(hlo, leaves) == []
+    calls = [line for line in hlo.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    # the Mosaic writer stages every window but Keye's (rows of 16
+    # index keys are no whole lanes), and where it does the leaves are
+    # its operands whole
+    staged = [c for c in calls if c.strip().startswith("%stage_window")]
+    assert bool(staged) == (model != "keye")
+    whole = "[" + ",".join(map(str, leaves[0].shape)) + "]"
+    assert all(whole in c for c in staged)
 
 
 def test_weights_built_leaf_by_leaf_have_the_same_tree():

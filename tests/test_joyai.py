@@ -354,29 +354,36 @@ def test_the_kernel_read_is_the_jnp_read_through_the_packed_run(
 def test_the_kernel_alone_against_jnp_pages_window_and_dead_slots():
     """ops/latent_attention.py in interpret mode: contexts that end
     inside a page, inside a chunk and past one, a dead slot, with and
-    without the window, a layer other than the first."""
+    without the window, the first, the middle and the last layer; the
+    pool and the window WHOLE and the layer's index, to the bit what the
+    layer's slices alone give as a pool and a window of one."""
     import butterfly_tpu.ops.latent_attention as la
-    L, P, page, R, rank, Nq, S, mp, W = 2, 40, 16, 256, 128, 4, 4, 9, 8
+    L, P, page, R, rank, Nq, S, mp, W = 3, 40, 16, 256, 128, 4, 4, 9, 8
     rs = np.random.RandomState(0)
     pool = jnp.asarray(rs.randn(L, P, 1, page, R), jnp.float32)
     q = jnp.asarray(rs.randn(S, Nq, R), jnp.float32)
     table = jnp.asarray(rs.permutation(P - 1)[:S * mp].reshape(S, mp),
                         jnp.int32)
     lens = jnp.asarray([0, 37, 144, 64], jnp.int32)
-    win = jnp.asarray(rs.randn(S, 1, W, R), jnp.float32)
+    win = jnp.asarray(rs.randn(L, S, 1, W, R), jnp.float32)
     wc = jnp.asarray([0, 3, 8, 1], jnp.int32)
     assert la.fits(pool, rank) and not la.fits(pool[:, :, :0], rank)
     pages = la.PAGES_PER_CHUNK
     la.PAGES_PER_CHUNK = 4          # chunks of 64: two and a bit of 144
     try:
-        for layer, windowed in ((0, False), (1, True)):
+        for layer, windowed in ((0, False), (0, True), (1, True), (2, True)):
             got = la.latent_attention(
                 q, pool, layer, table, lens, *((win, wc) if windowed else ()),
                 rank=rank, scale=0.1)
+            alone = la.latent_attention(
+                q, pool[layer][None], 0, table, lens,
+                *((win[layer][None], wc) if windowed else ()),
+                rank=rank, scale=0.1)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(alone))
             rows = pool[layer][table][:, :, 0].reshape(S, mp * page, R)
             live = jnp.arange(mp * page)[None] < lens[:, None]
             if windowed:
-                rows = jnp.concatenate([rows, win[:, 0]], 1)
+                rows = jnp.concatenate([rows, win[layer, :, 0]], 1)
                 live = jnp.concatenate(
                     [live, jnp.arange(W)[None] < wc[:, None]], 1)
             s = jnp.einsum("snr,scr->snc", q, rows) * 0.1
@@ -790,7 +797,7 @@ def test_the_read_compiles_for_the_chip_at_the_published_geometry(
         compiled = fn.lower(
             sds((S, Nq, Rp), bf), sds((L, P, 1, 16, Rp), bf),
             sds((), jnp.int32), sds((S, 512), jnp.int32),
-            sds((S,), jnp.int32), sds((S, 1, W, Rp), bf),
+            sds((S,), jnp.int32), sds((L, S, 1, W, Rp), bf),
             sds((S,), jnp.int32)).compile()
     except Exception as e:  # the TPU library is one process's at a time
         if "Mosaic" in str(e):

@@ -150,7 +150,8 @@ def test_paged_attention_window_segment_parity():
     lengths = jnp.asarray([6, 3, 9], jnp.int32)   # FLUSHED pool lengths
     counts = jnp.asarray([3, 5, 0], jnp.int32)    # staged entries/slot
     out = paged_attention(q, k_pages[None], v_pages[None], 0, table, lengths,
-                          win_k=win_k, win_v=win_v, win_count=counts)
+                          win_k=win_k[None], win_v=win_v[None],
+                          win_count=counts)
 
     kk = _insert_window(_gather_pool(k_pages, table), win_k, lengths,
                         counts)
@@ -165,17 +166,19 @@ def test_paged_attention_window_segment_parity():
     # garbage past win_count must not leak into the output
     poisoned = win_k.at[:, :, 4:].set(1e3)
     out2 = paged_attention(q, k_pages[None], v_pages[None], 0, table, lengths,
-                           win_k=poisoned, win_v=win_v,
+                           win_k=poisoned[None], win_v=win_v[None],
                            win_count=jnp.minimum(counts, 4))
     ref2 = paged_attention(q, k_pages[None], v_pages[None], 0, table, lengths,
-                           win_k=win_k, win_v=win_v,
+                           win_k=win_k[None], win_v=win_v[None],
                            win_count=jnp.minimum(counts, 4))
     np.testing.assert_array_equal(np.asarray(out2), np.asarray(ref2))
 
 
 def test_paged_attention_window_segment_int8_parity():
-    """Quantized window segment: codes + [S, Kv, W] scales dequantize
-    inside the kernel's window step exactly like the pool blocks."""
+    """Quantized window segment: codes + scales (a step's one flat
+    kv-major row, as the window stores them) dequantize inside the
+    kernel's window step exactly like the pool blocks."""
+    from butterfly_tpu.cache.paged import scales_by_step
     from butterfly_tpu.models.common import quantize_kv
 
     S, Nq, Kv, H, page, P, MP, W = 3, 8, 2, 16, 4, 10, 4, 4
@@ -196,8 +199,10 @@ def test_paged_attention_window_segment_int8_parity():
     out = paged_attention(q, kq[None], vq[None], 0, table, lengths,
                           ksc.reshape(1, P, Kv * page),
                           vsc.reshape(1, P, Kv * page),
-                          win_k=wkq, win_v=wvq, win_count=counts,
-                          win_k_scale=wks, win_v_scale=wvs)
+                          win_k=wkq[None], win_v=wvq[None],
+                          win_count=counts,
+                          win_k_scale=scales_by_step(wks)[None],
+                          win_v_scale=scales_by_step(wvs)[None])
 
     kk = _insert_window(_gather_pool(kq.astype(jnp.float32)
                                      * ksc[..., None], table),
@@ -325,10 +330,12 @@ def test_paged_attention_sharded_parity(mesh_dt):
 
 
 def _pool_and_window(kf, vf, wkf, wvf, counts, quant):
-    """bfloat16 pools [L, P, Kv, page, H] and windows [S, Kv, W, H] as
-    the kernel takes them (int8 codes and flat kv-major scale rows if
-    `quant`): (kernel pool args [kp, vp, ksp, vsp], window kwargs, the
-    four dense float32 views the reference attends)."""
+    """bfloat16 pools [L, P, Kv, page, H] and windows [L, S, Kv, W, H]
+    as the kernel takes them, whole (int8 codes and flat kv-major scale
+    rows if `quant`, the window's a step a row): (kernel pool args
+    [kp, vp, ksp, vsp], window kwargs, the four dense float32 views the
+    reference attends)."""
+    from butterfly_tpu.cache.paged import scales_by_step
     from butterfly_tpu.models.common import quantize_kv
     f32 = jnp.float32
     if not quant:
@@ -340,7 +347,8 @@ def _pool_and_window(kf, vf, wkf, wvf, counts, quant):
     flat = kf.shape[:2] + (-1,)                 # [L, P, Kv*page]
     return ([kq, vq, ksc.reshape(flat), vsc.reshape(flat)],
             dict(win_k=wkq, win_v=wvq, win_count=counts,
-                 win_k_scale=wks, win_v_scale=wvs),
+                 win_k_scale=scales_by_step(wks),
+                 win_v_scale=scales_by_step(wvs)),
             [kq.astype(f32) * ksc[..., None], vq.astype(f32) * vsc[..., None],
              wkq.astype(f32) * wks[..., None],
              wvq.astype(f32) * wvs[..., None]])
@@ -360,8 +368,8 @@ def _layered_case(quant, window):
     counts = jnp.asarray([2, 3, 0, 1], jnp.int32)
     kf = jax.random.normal(ks[1], (L, P, Kv, page, H), jnp.bfloat16)
     vf = jax.random.normal(ks[2], (L, P, Kv, page, H), jnp.bfloat16)
-    wkf = jax.random.normal(ks[3], (S, Kv, W, H), jnp.bfloat16)
-    wvf = jax.random.normal(ks[4], (S, Kv, W, H), jnp.bfloat16)
+    wkf = jax.random.normal(ks[3], (L, S, Kv, W, H), jnp.bfloat16)
+    wvf = jax.random.normal(ks[4], (L, S, Kv, W, H), jnp.bfloat16)
     pool, win, dense = _pool_and_window(kf, vf, wkf, wvf, counts, quant)
     if not window:
         win = {}
@@ -371,8 +379,8 @@ def _layered_case(quant, window):
         vv = _gather_pool(dense[1][layer], table)
         total = lengths
         if window:
-            kk = _insert_window(kk, dense[2], lengths, counts)
-            vv = _insert_window(vv, dense[3], lengths, counts)
+            kk = _insert_window(kk, dense[2][layer], lengths, counts)
+            vv = _insert_window(vv, dense[3][layer], lengths, counts)
             total = lengths + counts
         mask = jnp.arange(kk.shape[1])[None, None, :] < total[:, None, None]
         return attend(q[:, None], kk, vv, mask, None)[:, 0]
@@ -386,15 +394,16 @@ def _layered_case(quant, window):
 @pytest.mark.parametrize("layer", [0, 1, 2])
 def test_paged_attention_layer_of_whole_pool(layer, quant, window, via,
                                              mesh_dt):
-    """The kernel over the WHOLE pool with the layer as a (traced)
-    prefetched scalar: the dense reference of that layer, and to the
-    bit what it gives over that layer alone as a pool of one (the
-    operand of a caller that scans the pool). Every layer holds other
-    values, so a wrong layer fails both."""
+    """The kernel over the WHOLE pool and the WHOLE window with the
+    layer as a (traced) prefetched scalar: the dense reference of that
+    layer, and to the bit what it gives over that layer's slices alone
+    as a pool and a window of one (the operand of a caller that scans
+    them). Every layer holds other values, pool and window, so a wrong
+    layer of either fails both."""
     from butterfly_tpu.ops.paged_attention import paged_attention_sharded
     q, table, lengths, pool, win, ref = _layered_case(quant, window)
 
-    def call(kp, vp, ly, ksp, vsp):
+    def call(kp, vp, ly, ksp, vsp, win):
         fn = paged_attention if via == "plain" else paged_attention_sharded
         return fn(q, kp, vp, ly, table, lengths, ksp, vsp, **win)
 
@@ -403,11 +412,13 @@ def test_paged_attention_layer_of_whole_pool(layer, quant, window, via,
                 else jax.set_mesh(mesh_dt):
             return jax.jit(call)(*a)
 
-    out = run(pool[0], pool[1], jnp.int32(layer), pool[2], pool[3])
+    out = run(pool[0], pool[1], jnp.int32(layer), pool[2], pool[3], win)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref(layer)),
                                rtol=2e-5, atol=2e-5)
     alone = [None if a is None else a[layer][None] for a in pool]
-    out1 = run(alone[0], alone[1], jnp.int32(0), alone[2], alone[3])
+    win1 = {k: a if k == "win_count" else a[layer][None]
+            for k, a in win.items()}
+    out1 = run(alone[0], alone[1], jnp.int32(0), alone[2], alone[3], win1)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out1))
     other = np.asarray(ref((layer + 1) % 3))
     assert np.abs(np.asarray(out) - other).max() > 1e-2
@@ -484,8 +495,8 @@ def _walk_case(quant, window, lengths):
         if window else jnp.zeros((S,), jnp.int32)
     kf = jax.random.normal(ks[1], (1, P, Kv, page, H), jnp.bfloat16)
     vf = jax.random.normal(ks[2], (1, P, Kv, page, H), jnp.bfloat16)
-    wkf = jax.random.normal(ks[3], (S, Kv, W, H), jnp.bfloat16)
-    wvf = jax.random.normal(ks[4], (S, Kv, W, H), jnp.bfloat16)
+    wkf = jax.random.normal(ks[3], (1, S, Kv, W, H), jnp.bfloat16)
+    wvf = jax.random.normal(ks[4], (1, S, Kv, W, H), jnp.bfloat16)
     pool, win, dense = _pool_and_window(kf, vf, wkf, wvf, counts, quant)
 
     def ref(sw):
@@ -494,8 +505,8 @@ def _walk_case(quant, window, lengths):
         vv = jnp.pad(_gather_pool(dense[1][0], table),
                      ((0, 0), (0, W), (0, 0), (0, 0)))
         if window:
-            kk = _insert_window(kk, dense[2], lengths, counts)
-            vv = _insert_window(vv, dense[3], lengths, counts)
+            kk = _insert_window(kk, dense[2][0], lengths, counts)
+            vv = _insert_window(vv, dense[3][0], lengths, counts)
         total = (lengths + counts)[:, None, None]
         pos = jnp.arange(kk.shape[1])[None, None, :]
         mask = (pos < total) & (pos >= total - (sw or kk.shape[1]))
@@ -551,8 +562,9 @@ def _kernel_jaxpr(pages_per_chunk, monkeypatch):
         sds((1, P, Kv, page, H), i8), sds((), jnp.int32),
         sds((S, 64), jnp.int32), sds((S,), jnp.int32),
         sds((1, P, Kv * page), f32), sds((1, P, Kv * page), f32),
-        sds((S, Kv, W, H), i8), sds((S, Kv, W, H), i8), sds((S,), jnp.int32),
-        sds((S, Kv, W), f32), sds((S, Kv, W), f32), sds((), jnp.int32))
+        sds((1, S, Kv, W, H), i8), sds((1, S, Kv, W, H), i8),
+        sds((S,), jnp.int32), sds((1, S, 1, Kv * W), f32),
+        sds((1, S, 1, Kv * W), f32), sds((), jnp.int32))
     call, = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
     assert call.params["grid_mapping"].grid == (S,)
     return call.params["jaxpr"]

@@ -478,7 +478,7 @@ def test_a_model_without_an_indexer_has_no_third_pool(arch):
     assert wspecs.k == wspecs.v == P(None, None, "tensor", None, None)
     assert wspecs.ki is None and wspecs.k_scale is None
     assert kv_window_specs(cfg, mesh, 2, quant=True).k_scale == \
-        P(None, None, "tensor", None)
+        P(None, None, None, "tensor")
 
 
 # -- the token-major pool: a selected token is ONE row ------------------------

@@ -241,11 +241,15 @@ def test_windowed_forward_hands_the_kernel_the_whole_pool(forward, kv_quant):
                                         use_kernel=True)
     eqns = list(_eqns(jax.make_jaxpr(fn)(params, cache, window).jaxpr))
     calls = [e for e in eqns if e.primitive.name == "pallas_call"]
-    assert calls
-    pool = cache.k_pages.shape
+    pool, leaf = cache.k_pages.shape, window.k.shape
+    # the writer of the staged rows and the reader: both take the
+    # window's leaves whole, the reader the pool's too
+    reads = [e for e in calls if pool in [v.aval.shape for v in e.invars]]
+    assert reads and len(calls) > len(reads)
     for e in calls:
         shapes = [v.aval.shape for v in e.invars]
-        assert shapes.count(pool) == 2, shapes
+        assert shapes.count(leaf) == 2, shapes
+        assert shapes.count(pool) == (2 if e in reads else 0), shapes
     cut = [e for e in eqns if e.primitive.name in ("dynamic_slice", "gather")
            and e.outvars[0].aval.shape[-4:] == pool[1:]]
     assert not cut, cut
@@ -301,8 +305,10 @@ def _flush_by_scatter(cache, window, win_len):
     ksp, vsp = cache.k_scale_pages, cache.v_scale_pages
     if window.quantized:
         cols = jnp.arange(Kv)[None, :] * page + flat_off[:, None]
-        ks_vals = window.k_scale.transpose(0, 1, 3, 2).reshape(L, S * W, Kv)
-        vs_vals = window.v_scale.transpose(0, 1, 3, 2).reshape(L, S * W, Kv)
+        from butterfly_tpu.cache.paged import scales_by_head
+        ks_vals, vs_vals = (
+            scales_by_head(a, Kv).transpose(0, 1, 3, 2).reshape(L, S * W, Kv)
+            for a in (window.k_scale, window.v_scale))
         ksp = ksp.at[:, flat_pages[:, None], cols].set(ks_vals)
         vsp = vsp.at[:, flat_pages[:, None], cols].set(vs_vals)
     cache = cache._replace(k_pages=k_pages, v_pages=v_pages,
@@ -315,7 +321,7 @@ def _staged_case(quant, lengths, W=8, L=3, Kv=2, page=4, H=8, mp=3,
                  seed=0):
     """A pool and a window full of random bytes (stale rows past
     win_len included), each slot on distinct pages in shuffled order."""
-    from butterfly_tpu.cache.paged import KVWindow
+    from butterfly_tpu.cache.paged import KVWindow, scales_by_step
     S = len(lengths)
     P = S * mp + 2                              # one page spare, one null
     ks = jax.random.split(jax.random.PRNGKey(seed), 8)
@@ -325,6 +331,7 @@ def _staged_case(quant, lengths, W=8, L=3, Kv=2, page=4, H=8, mp=3,
     else:
         rnd = lambda k, sh: jax.random.normal(k, sh, jnp.float32)
         sc = lambda k, sh: None
+    by_step = lambda a: None if a is None else scales_by_step(a)
     table = np.random.RandomState(seed).permutation(P - 1)[:S * mp]
     cache = PagedKVCache(
         k_pages=rnd(ks[0], (L, P, Kv, page, H)),
@@ -335,7 +342,8 @@ def _staged_case(quant, lengths, W=8, L=3, Kv=2, page=4, H=8, mp=3,
         v_scale_pages=sc(ks[3], (L, P, Kv * page)))
     window = KVWindow(
         k=rnd(ks[4], (L, S, Kv, W, H)), v=rnd(ks[5], (L, S, Kv, W, H)),
-        k_scale=sc(ks[6], (L, S, Kv, W)), v_scale=sc(ks[7], (L, S, Kv, W)))
+        k_scale=by_step(sc(ks[6], (L, S, Kv, W))),
+        v_scale=by_step(sc(ks[7], (L, S, Kv, W))))
     return cache, window
 
 
@@ -532,10 +540,13 @@ def _write(pool, table, k, v, start):
 
 
 def _stage(window, k, v, wlen):
-    from butterfly_tpu.cache.paged import stage_window_layer
-    wk, wv, _, _ = stage_window_layer(window.k[1], window.v[1], k, v, wlen,
-                                      rows=jnp.asarray([2, 0, 3]))
-    return wk, wv
+    from butterfly_tpu.cache.paged import stage_window_layer, window_runs
+    slots, T = jnp.asarray([2, 0, 3]), k.shape[1]
+    new = stage_window_layer(
+        window, 1, k, v, None, jnp.repeat(slots, T),
+        (wlen[:, None] + jnp.arange(T)[None, :]).reshape(-1),
+        (window_runs(slots, wlen, T, window.width),), (T,), False)
+    return new.k[1], new.v[1]
 
 
 INDIFFERENT = ["write", "stage", "gather", "insert", "permute"]

@@ -223,8 +223,8 @@ def test_paged_kernel_slides(sw, staged, lens):
     q = jax.random.normal(ks[2], (S, Nq, H))
     kw = {}
     if staged:
-        kw = dict(win_k=jax.random.normal(ks[3], (S, Kv, W, H)),
-                  win_v=jax.random.normal(ks[4], (S, Kv, W, H)),
+        kw = dict(win_k=jax.random.normal(ks[3], (2, S, Kv, W, H)),
+                  win_v=jax.random.normal(ks[4], (2, S, Kv, W, H)),
                   win_count=jnp.where(jnp.asarray(lens) > 0, staged, 0))
     got = paged_attention(q, pool_k, pool_v, 1, table,
                           jnp.asarray(lens, jnp.int32), sliding_window=sw,
@@ -234,8 +234,10 @@ def test_paged_kernel_slides(sw, staged, lens):
         v = pool_v[1, table[s]].transpose(0, 2, 1, 3).reshape(mp * page, Kv, H)
         k, v = k[:lens[s]], v[:lens[s]]
         if staged:
-            k = jnp.concatenate([k, kw["win_k"][s, :, :staged].transpose(1, 0, 2)])
-            v = jnp.concatenate([v, kw["win_v"][s, :, :staged].transpose(1, 0, 2)])
+            k = jnp.concatenate(
+                [k, kw["win_k"][1, s, :, :staged].transpose(1, 0, 2)])
+            v = jnp.concatenate(
+                [v, kw["win_v"][1, s, :, :staged].transpose(1, 0, 2)])
         n = k.shape[0]
         if sw:
             k, v = k[max(0, n - sw):], v[max(0, n - sw):]

@@ -70,7 +70,8 @@ def build_cases(small: bool, windows):
     import numpy as np
 
     from butterfly_tpu.cache.paged import (gather_paged_layer,
-                                           gather_paged_layer_q)
+                                           gather_paged_layer_q,
+                                           scales_by_head, scales_by_step)
     from butterfly_tpu.models.common import attend, quantize_kv
     from butterfly_tpu.ops.flash_attention import (flash_attention,
                                                    flash_attention_sharded)
@@ -161,10 +162,15 @@ def build_cases(small: bool, windows):
     def paged_ref(qd, kp, vp, ly, table, lens, ksp=None, vsp=None, wk=None,
                   wv=None, wcnt=None, wks=None, wvs=None):
         """Dense gather + attend (cache/paged.py's dense path), the
-        window appended as one more key segment; a slot with no keys
-        reads 0, as the kernel leaves an inactive slot."""
+        window's layer appended as one more key segment; a slot with no
+        keys reads 0, as the kernel leaves an inactive slot."""
         mask = (jnp.arange(smax)[None] < lens[:, None])[:, None]
         if wk is not None:
+            # the whole window as the serving path hands it: the layer's
+            wk, wv = wk[ly], wv[ly]
+            if wks is not None:
+                wks, wvs = (scales_by_head(a[ly], wk.shape[1])
+                            for a in (wks, wvs))
             mask = jnp.concatenate(
                 [mask, (jnp.arange(wk.shape[2])[None]
                         < wcnt[:, None])[:, None]], 2)
@@ -188,9 +194,10 @@ def build_cases(small: bool, windows):
     paged_args = [("paged", (qd, kp, vp, ly, table, lens)),
                   ("paged_int8", (qd, kpq, vpq, ly, table, lens, ksp, vsp))]
     for W in windows:
-        wk, wv = normal(S, Kv, W, H), normal(S, Kv, W, H)
-        wkq, wks = kv_major_q(jnp.moveaxis(wk, 1, 2))
-        wvq, wvs = kv_major_q(jnp.moveaxis(wv, 1, 2))
+        wk, wv = normal(L, S, Kv, W, H), normal(L, S, Kv, W, H)
+        wkq, wks = kv_major_q(jnp.moveaxis(wk, 2, 3))
+        wvq, wvs = kv_major_q(jnp.moveaxis(wv, 2, 3))
+        wks, wvs = scales_by_step(wks), scales_by_step(wvs)
         wcnt = jnp.asarray(rng.randint(0, W + 1, (S,)), jnp.int32)
         wcnt = wcnt.at[0].set(1).at[1].set(W)
         paged_args += [
@@ -281,7 +288,8 @@ def tp_place(mesh, name, args):
     else:  # q, pools, layer, table, lens, pool scales, window, count, scales
         specs = [P(None, t, None)] + [P(None, None, t, None, None)] * 2 \
             + [P(), P(), P()] + [P(None, None, t)] * 2 \
-            + [P(None, t, None, None)] * 2 + [P()] + [P(None, t, None)] * 2
+            + [P(None, None, t, None, None)] * 2 + [P()] \
+            + [P(None, None, None, t)] * 2
     return tuple(None if a is None
                  else jax.device_put(a, NamedSharding(mesh, s))
                  for a, s in zip(args, specs))
@@ -351,6 +359,38 @@ def state_copies(hlo: str, h) -> list:
     made = re.compile(rf"^\s*(?:ROOT )?%(\S*copy\S*) = \(?\w+\[(?:1,)?"
                       rf"(?:{whole}|{layer})\]")
     return [m.group(1) for m in map(made.match, hlo.splitlines()) if m]
+
+
+def window_moves(hlo: str, leaves) -> list:
+    """The instructions INSIDE a compiled HLO text's loops that make a
+    value of a window leaf's whole shape [L, S, Kv, W, H] or of one
+    layer's slice of it: a `copy`, a `transpose` or a fusion in any
+    computation that is some `while`'s body. (The loops' carries, the
+    Mosaic writer's aliased result and a chunk's one-slot view are none
+    of these: tuples, a custom call, another shape.) `leaves`: arrays or
+    shapes-and-dtypes, None skipped. What a window that rides the layer
+    scan whole and is written in place leaves none of (PERF.md, PR 47)."""
+    import re
+    shapes = set()
+    for a in leaves:
+        if a is not None:
+            shapes |= {",".join(map(str, a.shape)),
+                       ",".join(map(str, a.shape[1:]))}
+    bodies = set(re.findall(r"body=%([^\s,)]+)", hlo))
+    head = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{\s*$")
+    made = re.compile(
+        r"^\s*(?:ROOT )?%(\S+) = (.*?) (?:copy|transpose|fusion)\(")
+    found, inside = [], False
+    for line in hlo.splitlines():
+        m = head.match(line)
+        if m:
+            inside = m.group(1) in bodies
+        elif inside:
+            m = made.match(line)
+            if m and any(dims in shapes for dims in
+                         re.findall(r"\w+\[(?:1,)*([\d,]+)\]", m.group(2))):
+                found.append(m.group(1))
+    return found
 
 
 def run_ssm_step(name, small, want):
@@ -444,7 +484,8 @@ def run_latent(name, small, want):
         pool = jax.jit(lambda k: jax.random.normal(
             k, (L, P, 1, page, Rp), jnp.bfloat16) * lanes)(keys[0])
         q = jax.random.normal(keys[1], (S, Nq, Rp), jnp.bfloat16) * lanes
-        win = jax.random.normal(keys[2], (S, 1, W, Rp), jnp.bfloat16) * lanes
+        win = jax.jit(lambda k: jax.random.normal(
+            k, (L, S, 1, W, Rp), jnp.bfloat16) * lanes)(keys[2])
         rs = np.random.RandomState(3)
         table = jnp.asarray(rs.permutation(P - 1).reshape(S, mp), jnp.int32)
         lens = rs.randint(1, mp * page - W, S)
@@ -469,6 +510,7 @@ def run_latent(name, small, want):
                 qs, ts, ns, ws, cs = args
                 rows = jnp.concatenate(
                     [pool[ly, ts, 0].reshape(mp * page, Rp), ws[0]])
+                # (ws: the slot's entries of the window's layer)
                 live = jnp.concatenate([jnp.arange(mp * page) < ns,
                                         jnp.arange(W) < cs])
                 s = jnp.einsum("nr,cr->nc", qs, rows,
@@ -477,7 +519,7 @@ def run_latent(name, small, want):
                 return jnp.einsum("nc,cr->nr", p.astype(rows.dtype),
                                   rows[:, :rank],
                                   preferred_element_type=jnp.float32)
-            return jax.lax.map(one, (q, t, n, w, c))
+            return jax.lax.map(one, (q, t, n, w[ly], c))
 
         want_out = ref(q, pool, 3 % L, table, lens, win, wc)
         err = np.max(np.abs(np.asarray(out, np.float32)
@@ -536,6 +578,7 @@ def run_paged_cell(name, small, want):
 
     from butterfly_tpu.models.common import quantize_kv
     from butterfly_tpu.ops.paged_attention import paged_attention
+    from butterfly_tpu.ops.window_stage import window_step
 
     rec = {"name": name, "ok": False}
     S, Nq, Kv, quant, L, mp, (lo, hi), sw = PAGED_CELLS[name]
@@ -558,12 +601,15 @@ def run_paged_cell(name, small, want):
 
         (kp, ksp), (vp, vsp) = pools(keys[0]), pools(keys[1])
         q = jax.random.normal(keys[2], (S, Nq, H), bf)
-        wk, wv = (jax.random.normal(k, (S, Kv, W, H), bf) for k in keys[3:])
+        # the window whole, as the serving path hands it: every layer's
+        wk, wv = (jax.random.normal(k, (L, S, Kv, W, H), bf)
+                  for k in keys[3:])
         wks, win_scale = None, 1.0
         if quant:   # codes of one common scale
             win_scale = 0.025
             wk, wv = ((w / win_scale).astype(jnp.int8) for w in (wk, wv))
-            wks = jnp.full((S, Kv, W), win_scale, jnp.float32)
+            ws = window_step(W)
+            wks = jnp.full((L, S, W // ws, Kv * ws), win_scale, jnp.float32)
         rs = np.random.RandomState(5)
         table = jnp.asarray(rs.permutation(P - 1).reshape(S, mp), jnp.int32)
         lens = rs.randint(lo, hi + 1, S)
@@ -617,7 +663,7 @@ def run_paged_cell(name, small, want):
                 p = jax.nn.softmax(jnp.where(live, s, -1e30), -1) * live
                 return jnp.einsum("kgc,kch->kgh", p, v,
                                   precision="highest").reshape(Nq, H)
-            return jax.lax.map(one, (q, t, n, wk, wv, c))
+            return jax.lax.map(one, (q, t, n, wk[ly], wv[ly], c))
 
         want_out = ref(*args)
         err = np.max(np.abs(np.asarray(out, np.float32)
@@ -643,6 +689,123 @@ def run_paged_cell(name, small, want):
             100 * rows_bytes / 819e9 / (rec["call_us"] / 1e6), 1)
         rec["ok"] = bool(np.isfinite(err) and err < 3e-2
                          and rec.get("dead_slot_zero", True)
+                         and all(rec["hlo_has"].values()))
+    except Exception as e:  # a compiler refusal is the finding: record it
+        rec["error"] = f"{type(e).__name__}: {e}"[:1500]
+    return rec
+
+
+#: the cells' windows for ops/window_stage.py (W 256, a chunk of 32
+#: columns beside the decode rows): name -> layers, slots, the row
+#: leaves' (heads, width) and dtype, int8 scale leaves too? (Keye's
+#: window is staged by XLA: Mosaic copies no row of 64, its index keys'.)
+STAGE_CELLS = {
+    "stage_cell_int8": (32, 32, ((8, 128),) * 2, "int8", True),
+    "stage_cell_bf16": (16, 32, ((4, 128),) * 2, "bfloat16", False),
+    "stage_cell_latent": (6, 96, ((1, 640),), "bfloat16", False),
+    "stage_cell_rollout": (2, 128, ((8, 128),) * 2, "bfloat16", False),
+}
+
+
+def run_stage_cell(name, small, want):
+    """ops/window_stage.py at a cell's own window (STAGE_CELLS): one
+    step's rows (a decode row a live slot, a fifth of the slots dead,
+    one chunk of 32 columns astride two groups) staged into every layer
+    in turn, the leaves donated as the serving block donates them;
+    against XLA's scatter of the same rows, bit for bit over the whole
+    leaves, then its time a call (a layer-step)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from butterfly_tpu.ops.window_stage import stage_window, window_step
+
+    rec = {"name": name, "ok": False}
+    L, S, rows_of, dtype, quant = STAGE_CELLS[name]
+    W, C = 256, 32
+    if small:
+        L, S, W, C = 2, 4, 64, 8
+    dt, gw = jnp.dtype(dtype), window_step(W)
+    try:
+        keys = iter(jax.random.split(jax.random.PRNGKey(13), 16))
+
+        def rnd(shape, dt):
+            if dt == jnp.int8:
+                return jax.random.randint(next(keys), shape, -127, 128,
+                                          jnp.int8)
+            return jax.random.normal(next(keys), shape, jnp.float32
+                                     ).astype(dt)
+
+        leaves = tuple(rnd((L, S, kv, W, h), dt) for kv, h in rows_of)
+        Kv = rows_of[0][0]
+        scales = tuple(rnd((L, S, W // gw, Kv * gw), jnp.dtype("float32"))
+                       for _ in range(2 * quant))
+        N = S + C
+        fresh = tuple(rnd((N, kv, h), dt) for kv, h in rows_of)
+        fresh_s = tuple(rnd((N, Kv), jnp.dtype("float32")) for _ in scales)
+        rs = np.random.RandomState(9)
+        count = rs.randint(0, W - 2 * C, S)
+        active = rs.rand(S) > 0.2
+        active[1], count[1] = False, gw - 3      # slot 1 takes the chunk
+        count[2], count[3] = W - 1, W            # the last entry; a full one
+        runs = (jnp.asarray(np.stack([np.arange(S), count,
+                                      active & (count < W)]), jnp.int32),
+                jnp.asarray([[1], [count[1]], [C - 3]], jnp.int32))
+        slot = np.concatenate([np.arange(S), np.full(C, 1)])
+        idx = np.concatenate([np.where(active, count, W),
+                              np.where(np.arange(C) < C - 3,
+                                       count[1] + np.arange(C), W)])
+
+        def stage(leaves, scales, ly):
+            return stage_window(leaves, scales, fresh, fresh_s, ly, runs,
+                                widths=(1, C), looped=False)
+
+        t0 = time.perf_counter()
+        # (one call alone is no loop: `looped` False, as a run of one
+        # layer says it; the scan below is the serving programs' case)
+        lowered = jax.jit(stage).lower(leaves, scales, 1 % L)
+        t1 = time.perf_counter()
+        compiled = lowered.compile()
+        rec["trace_lower_s"] = round(t1 - t0, 3)
+        rec["compile_s"] = round(time.perf_counter() - t1, 3)
+        rec["hlo_has"] = {w: w in compiled.as_text() for w in want}
+
+        @jax.jit
+        def ref(leaves, scales, ly):
+            cols = jnp.arange(Kv)[None] * gw + (idx % gw)[:, None]
+            return (tuple(a.at[ly, slot, :, idx].set(r, mode="drop")
+                          for a, r in zip(leaves, fresh)),
+                    tuple(a.at[ly, slot[:, None], (idx // gw)[:, None],
+                               cols].set(r, mode="drop")
+                          for a, r in zip(scales, fresh_s)))
+
+        want_out = jax.block_until_ready(ref(leaves, scales, 1 % L))
+        out = jax.block_until_ready(compiled(leaves, scales, 1 % L))
+        rec["bytes_differ"] = int(sum(
+            np.count_nonzero(np.asarray(a).view(np.uint8)
+                             != np.asarray(b).view(np.uint8))
+            for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(want_out))))
+        rec["max_err"] = float(rec["bytes_differ"] > 0)
+
+        # a step's L layers in turn, the leaves carried and donated
+        def step(leaves, scales):
+            def layer(held, ly):
+                return stage_window(*held, fresh, fresh_s, ly, runs,
+                                    widths=(1, C)), None
+            return jax.lax.scan(layer, (leaves, scales), jnp.arange(L))[0]
+
+        step = jax.jit(step, donate_argnums=(0, 1))
+        held = jax.block_until_ready(step(*out))
+        t2 = time.perf_counter()
+        for _ in range(20):
+            held = step(*held)
+        jax.block_until_ready(held)
+        rec["call_us"] = round((time.perf_counter() - t2) / 20 / L * 1e6, 1)
+        row = sum(kv * h for kv, h in rows_of) * dt.itemsize
+        rec["rows_mb"] = round((int(active.sum()) + C - 3) * row / 1e6, 3)
+        rec["share_of_819_gb_s"] = round(
+            100 * rec["rows_mb"] * 1e6 / 819e9 / (rec["call_us"] / 1e6), 2)
+        rec["ok"] = bool(rec["bytes_differ"] == 0
                          and all(rec["hlo_has"].values()))
     except Exception as e:  # a compiler refusal is the finding: record it
         rec["error"] = f"{type(e).__name__}: {e}"[:1500]
@@ -726,6 +889,8 @@ def main() -> int:
         results.append(run_latent("latent", args.small, want))
     results += [run_paged_cell(n, args.small, want)
                 for n in PAGED_CELLS if wanted(n)]
+    results += [run_stage_cell(n, args.small, want)
+                for n in STAGE_CELLS if wanted(n)]
     if wanted("serve_block"):
         results.append(run_serving_block("serve_block", args.small, None,
                                          want))
