@@ -85,8 +85,9 @@ from butterfly_tpu.models.common import (
     early_router_logits, embed_tokens, ffn_close, final_logits,
     ffn_run, index_proj, index_scores, indexer_unsupported, latent_attend,
     latent_proj, latent_queries, latent_unsupported, layer_at, layer_mask,
-    layer_pattern_of, layer_runs, layer_stack, make_mask, pre_norm,
-    qkv_proj, quantize_kv, residual_add, run_layer_at, select_mask,
+    layer_pattern_of, layer_runs, layer_stack, make_mask, qkv_proj,
+    quantize_kv, run_layer_at, select_mask, stream_fold, stream_read,
+    stream_write,
     select_topk, ssm_unsupported)
 from butterfly_tpu.ops import note_kernel
 from butterfly_tpu.ops import latent_attention
@@ -871,7 +872,7 @@ def latent_paged_attend(q, kp, layer, *, cfg: ModelConfig, page_table,
             else (jnp.where(active, start + 1, 0),)
         out = latent_attention.latent_attention(
             q[:, 0], kp, layer, page_table, *lens, rank=cfg.kv_lora_rank,
-            scale=cfg.qk_head_dim ** -0.5)
+            scale=cfg.attn_scale)
         out = out[:, None]
     if out is None:
         if use_kernel and T == 1:
@@ -1033,16 +1034,19 @@ def _layer_open(x, lp, cfg: ModelConfig, cos, sin):
     """A layer up to its attention: the weights in the compute dtype,
     the pre-norm, the router's logits where the router stands before
     attention, and the projections, rotated where the layer rotates.
-    Returns (lp, q, k, v, route, sliding_window, index): `route` (None
-    for most models) is carried across attention to _layer_close, the
-    layer's sliding window (None for a model without a pattern) goes to
-    paged_attend, and so does `index`, the indexer's (qI, kI, w) of
-    these rows (models.common.index_proj; None for a model without
-    one), once kI is cached beside k and v. With _layer_close, the part
-    of a layer that paged_layer_body and the packed step (packed_layer)
-    share, so that a change to a norm or a projection reaches both."""
+    Returns (lp, q, k, v, route, sliding_window, index, mix): `route`
+    (None for most models) is carried across attention to _layer_close,
+    the layer's sliding window (None for a model without a pattern)
+    goes to paged_attend, and so does `index`, the indexer's (qI, kI, w)
+    of these rows (models.common.index_proj; None for a model without
+    one), once kI is cached beside k and v; `mix` is the residual
+    path's (models.common.stream_read: x is [n,B,T,D] for a model of n
+    streams, and None comes back for every other), which _layer_close
+    takes. With _layer_close, the part of a layer that paged_layer_body
+    and the packed step (packed_layer) share, so that a change to a
+    norm, a projection or the residual path reaches both."""
     lp = jax.tree.map(lambda a: _cast_float(a, jnp.dtype(cfg.dtype)), lp)
-    h = pre_norm(x, lp["ln1"], cfg)
+    h, mix = stream_read(x, lp, 1, cfg)
     if cfg.is_latent:
         # the absorbed queries and the row a token caches, each laid in
         # the pool's lanes (pool_row: zeros behind the values); no v
@@ -1050,21 +1054,22 @@ def _layer_open(x, lp, cfg: ModelConfig, cos, sin):
         q = latent_queries(q_nope, q_rope, lp["attn"], cfg)
         pad = [(0, 0)] * 3 + [(0, pool_row(cfg)[1] - cfg.latent_row)]
         return (lp, jnp.pad(q, pad), jnp.pad(row[:, :, None], pad), None,
-                None, None, None)
+                None, None, None, mix)
     rope, sliding_window = layer_pattern_of(lp.get("pattern"))
     q, k, v = qkv_proj(h, lp["attn"], cfg, cos, sin, rope)
     index = index_proj(x, lp, cfg, cos, sin) if cfg.has_indexer else None
     return (lp, q, k, v, early_router_logits(x, lp, cfg), sliding_window,
-            index)
+            index, mix)
 
 
-def _layer_close(x, out, lp, cfg: ModelConfig, route=None, ok=None):
+def _layer_close(x, out, lp, cfg: ModelConfig, mix, route=None, ok=None):
     """A layer from its attention's output on: the output projection
-    and the feed-forward, each with its residual. route: _layer_open's.
+    and the feed-forward, each written back onto the residual path
+    (models.common.stream_write; ffn_close). mix, route: _layer_open's.
     Returns (x, load): with `ok` [B,T], the rows that are real, and a
     model of experts, `load` is what the layer's routing asked of them
     (models.common.expert_load), else None."""
-    x = residual_add(x, attn_output(out, lp["attn"], cfg), cfg)
+    x = stream_write(x, attn_output(out, lp["attn"], cfg), mix, cfg)
     return ffn_close(x, lp, cfg, route, ok)
 
 
@@ -1105,8 +1110,8 @@ def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
     given; what was written comes back last.
     """
     quant = ksp is not None
-    lp, q, k, v, route, sliding_window, index = _layer_open(x, lp, cfg,
-                                                            cos, sin)
+    lp, q, k, v, route, sliding_window, index, mix = _layer_open(
+        x, lp, cfg, cos, sin)
     if win is not None:
         window, win_len = win
         B, T = k.shape[:2]
@@ -1135,7 +1140,7 @@ def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
         index=None if index is None else (index[0], index[2], pool[4]))
     if index is not None:
         out = out[0]
-    x, _ = _layer_close(x, out, lp, cfg, route)
+    x, _ = _layer_close(x, out, lp, cfg, mix, route)
     if win is not None:
         return x, window
     return (x, *(a for a in (kp, vp, ksp, vsp, kip) if a is not None))
@@ -1187,6 +1192,7 @@ def paged_forward(params, cfg: ModelConfig, tokens: jax.Array,
     # an absent pool tensor rides the scan as None (no leaf)
     x, new_pools = lax.scan(body, x, (layer_stack(params["layers"], cfg),
                                       *pool_leaves(cache, absent=True)))
+    x = stream_fold(x, cfg)
     if last_index is not None:
         x = jnp.take_along_axis(
             x, last_index[:, None, None].astype(jnp.int32), axis=1)
@@ -1245,7 +1251,7 @@ def paged_forward_window(params, cfg: ModelConfig, tokens: jax.Array,
 
     (x, _, window), _ = lax.scan(body, (x, 0, window),
                                  layer_stack(params["layers"], cfg))
-    return final_logits(params, cfg, x), window
+    return final_logits(params, cfg, stream_fold(x, cfg)), window
 
 
 class PackedRows(NamedTuple):
@@ -1345,7 +1351,7 @@ def packed_layer(x, lp, pools, window: Optional[KVWindow], rows: PackedRows,
     more, latent_paged_attend's count (and `load` is that alone in a
     leading dense layer, which routes nothing)."""
     S, (P, C) = rows.written.shape[0], rows.chunk_pos.shape
-    lp, q, k, v, route, sliding_window, index = _layer_open(
+    lp, q, k, v, route, sliding_window, index, mix = _layer_open(
         x, lp, cfg, rows.cos, rows.sin)
     dec_win = chunk_win = None
     if window is not None:
@@ -1396,7 +1402,7 @@ def packed_layer(x, lp, pools, window: Optional[KVWindow], rows: PackedRows,
             out_c = out_c[0]
         out = jnp.concatenate(
             [out, out_c.reshape(P * C, 1, *out_c.shape[2:])])
-    x, load = _layer_close(x, out, lp, cfg, route, rows.ok[:, None])
+    x, load = _layer_close(x, out, lp, cfg, mix, route, rows.ok[:, None])
     if count is not None:
         if load is None and not cfg.is_latent:
             load = jnp.zeros((3,), jnp.float32)
@@ -1507,6 +1513,20 @@ def window_leaves(window: KVWindow, staged=None, absent: bool = False):
     return _leaves(window, _WINDOW_LEAVES, staged, absent)
 
 
+def _mixed_rows(load, rows: PackedRows, cfg: ModelConfig):
+    """A packed step's `load` with, for a model of n residual streams
+    (cfg.hc_mult), ONE value more at its end: the positions whose
+    streams this step mixed (every real row: a live decode row, a
+    chunk's real columns; filler and idle rows none), what the tick
+    record's `hc_rows` sums. A dense such model's load is three zeros
+    before it (no routing). Every other model's load as it is."""
+    if not cfg.hc_mult:
+        return load
+    mixed = jnp.sum(rows.ok).astype(jnp.float32)[None]
+    return jnp.concatenate(
+        [jnp.zeros((3,), jnp.float32) if load is None else load, mixed])
+
+
 def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
                          cache: PagedKVCache, chunk_tokens: jax.Array,
                          chunk_slot: jax.Array, chunk_count: jax.Array,
@@ -1555,15 +1575,19 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
     the return gains the state as a fourth value; `load` gains two:
     the positions pushed through a recurrence this step (decode rows
     and real chunk columns) and the slots that started from zero.
+    A model of n residual streams (cfg.hc_mult): x is [n, N, 1, D]
+    through the layers, and `load` ends in the positions mixed
+    (_mixed_rows).
     """
     x, rows = packed_rows(params, cfg, tokens, cache, chunk_tokens,
                           chunk_slot, chunk_count, active, window, win_len)
     if cfg.has_ssm or cfg.first_k_dense:
         x, kv, state, load = _packed_runs(params, cfg, x, rows, cache,
                                           window, state, use_kernel)
-        logits = final_logits(params, cfg, x[rows.head])[:, 0]
+        logits = final_logits(params, cfg,
+                              stream_fold(x, cfg)[rows.head])[:, 0]
         if not cfg.has_ssm:
-            return logits, kv, load
+            return logits, kv, _mixed_rows(load, rows, cfg)
         ssm = jnp.stack([jnp.sum(rows.ok), jnp.sum(
             rows.chunk_ok & (rows.chunk_pos[:, 0] == 0))])
         load = jnp.concatenate([load, ssm.astype(jnp.float32)])
@@ -1595,4 +1619,5 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
                                        layer_stack(params["layers"], cfg))
     if load is not None:
         load = load.mean(axis=0)
-    return final_logits(params, cfg, x[rows.head])[:, 0], state, load
+    return (final_logits(params, cfg, stream_fold(x, cfg)[rows.head])[:, 0],
+            state, _mixed_rows(load, rows, cfg))

@@ -55,8 +55,8 @@ from jax import lax
 
 from butterfly_tpu.core.config import ModelConfig
 from butterfly_tpu.models.common import (
-    ffn_close, pre_norm, residual_add, ssm_conv, ssm_gate_out, ssm_in_proj,
-    ssm_scan, ssm_skip, ssm_step_inputs)
+    ffn_close, ssm_conv, ssm_gate_out, ssm_in_proj, ssm_scan, ssm_skip,
+    ssm_step_inputs, stream_read, stream_write)
 from butterfly_tpu.ops.ssm_step import fits, ssm_step
 
 
@@ -163,7 +163,8 @@ def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig,
     models.common.ffn_close's."""
     S, (P, C) = rows.active.shape[0], rows.chunk_pos.shape
     Nh, Hd, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
-    z, xbc, dt = ssm_in_proj(pre_norm(x, lp["ln1"], cfg), mp, cfg)
+    h, mix = stream_read(x, lp, 1, cfg)
+    z, xbc, dt = ssm_in_proj(h, mp, cfg)
     h_all = lax.dynamic_index_in_dim(state.h, m, 0, keepdims=False)
     tails = lax.dynamic_index_in_dim(state.conv, m, 0, keepdims=False)
     tails = jnp.swapaxes(tails, 0, 1)                  # [slots, K-1, Dc]
@@ -208,6 +209,6 @@ def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig,
     conv = lax.dynamic_update_index_in_dim(
         state.conv, jnp.swapaxes(tails_new, 0, 1), m, 0)
     y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
-    x = residual_add(x, ssm_gate_out(y, z, mp, cfg), cfg)
+    x = stream_write(x, ssm_gate_out(y, z, mp, cfg), mix, cfg)
     x, load = ffn_close(x, lp, cfg, ok=rows.ok[:, None])
     return x, SSMState(h=h, conv=conv), load

@@ -6,6 +6,7 @@ butterfly_tpu.ckpt.sharded (slice 7).
 """
 from __future__ import annotations
 
+import importlib
 import json
 from pathlib import Path
 from typing import Any, Dict
@@ -35,22 +36,27 @@ def _load_hf_state_dict(path: Path) -> Dict[str, Any]:
         f"no *.safetensors or pytorch_model*.bin found under {path}")
 
 
+#: arch -> the module of butterfly_tpu.models that converts its tensors
+_CONVERTERS = {"gpt2": "gpt2", "llama": "llama", "mixtral": "mixtral",
+               "smallthinker": "smallthinker"}
+
+
 def load_checkpoint(path: str, cfg: ModelConfig):
     """Load model weights from `path` (HF-format dir) into our param pytree."""
+    # the family first: one without a converter is refused by name
+    # before a byte is read
+    if cfg.arch not in _CONVERTERS:
+        raise ValueError(
+            f"no checkpoint converter for arch {cfg.arch!r}: the loader "
+            f"knows {sorted(_CONVERTERS)}; the other families are served "
+            "from seeded weights only")
+    params_from_hf_state_dict = importlib.import_module(
+        "butterfly_tpu.models." + _CONVERTERS[cfg.arch]
+    ).params_from_hf_state_dict
     p = Path(path)
     if not p.is_dir():
         raise FileNotFoundError(f"checkpoint dir not found: {path}")
     sd = _load_hf_state_dict(p)
-    if cfg.arch == "gpt2":
-        from butterfly_tpu.models.gpt2 import params_from_hf_state_dict
-    elif cfg.arch == "llama":
-        from butterfly_tpu.models.llama import params_from_hf_state_dict
-    elif cfg.arch == "mixtral":
-        from butterfly_tpu.models.mixtral import params_from_hf_state_dict
-    elif cfg.arch == "smallthinker":
-        from butterfly_tpu.models.smallthinker import params_from_hf_state_dict
-    else:
-        raise ValueError(f"unknown arch {cfg.arch!r}")
     return params_from_hf_state_dict(sd, cfg)
 
 

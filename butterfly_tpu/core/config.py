@@ -19,16 +19,18 @@ class ModelConfig:
     """Architecture hyperparameters for a transformer LM.
 
     One config class covers the model families (GPT-2, Llama-3,
-    Mixtral, SmallThinker, Keye, Granite-4.0-H, JoyAI-LLM-Flash) — the
-    family is selected by `arch`, the MoE fields, the per-layer
-    attention pattern, the sparse-attention indexer, the per-layer KIND
-    (`layer_types`: Mamba-2 mixers beside attention layers) and the
-    latent-attention fields (`kv_lora_rank` and the split head dims:
-    one cached latent a token in place of heads of keys and values).
+    Mixtral, SmallThinker, Keye, Granite-4.0-H, JoyAI-LLM-Flash,
+    Xing4.0) — the family is selected by `arch`, the MoE fields, the
+    per-layer attention pattern, the sparse-attention indexer, the
+    per-layer KIND (`layer_types`: Mamba-2 mixers beside attention
+    layers), the latent-attention fields (`kv_lora_rank` and the split
+    head dims: one cached latent a token in place of heads of keys and
+    values) and the residual path (`hc_mult`: n streams mixed by
+    hyper-connections in place of one).
     """
 
     arch: str = "llama"  # "gpt2" | "llama" | "mixtral" | "smallthinker"
-                         # | "keye" | "granite_hybrid" | "joyai"
+                         # | "keye" | "granite_hybrid" | "joyai" | "xing"
     vocab_size: int = 32000
     hidden_size: int = 4096
     num_layers: int = 32
@@ -48,6 +50,28 @@ class ModelConfig:
     pos_embedding: str = "rope"       # "rope" | "learned" | "none" (no
                                       # positional encoding in any layer)
     rope_theta: float = 500000.0
+    # a scaled rotation, as published (a dict; held as its sorted items,
+    # which hash). Only `type` "yarn" is known: factor, beta_fast,
+    # beta_slow, original_max_position_embeddings, mscale, mscale_all_dim
+    # in the DeepSeek-V2/V3 convention (yarn_inv_freq, attn_scale_mult).
+    # () = plain rotation at rope_theta
+    rope_scaling: Tuple[Tuple[str, object], ...] = ()
+
+    # the residual path: hc_mult n > 0 = n STREAMS a token in place of
+    # one, mixed by manifold-constrained hyper-connections
+    # (arXiv:2512.24880): a sublayer reads a learned mix of the streams,
+    # writes its output onto all of them, and the streams mix among
+    # themselves through an n x n matrix made doubly stochastic by
+    # hc_sinkhorn_iters rounds of column and row normalisation of
+    # exp(logits clipped to [hc_clamp_min, hc_clamp_max]); hc_eps is in
+    # the mixing's norm and in every Sinkhorn denominator
+    # (models/common.py stream_read / stream_write). 0 = one stream,
+    # x + F(norm(x))
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp_min: float = -30.0
+    hc_clamp_max: float = 30.0
 
     # MoE (mixtral)
     num_experts: int = 0              # 0 => dense FFN
@@ -201,6 +225,8 @@ class ModelConfig:
             raise ValueError(f"unknown router_input {self.router_input!r}")
         self._check_latent()
         self._check_router()
+        self._check_rope_scaling()
+        self._check_streams()
         if bool(self.sliding_window_layout) != (self.sliding_window > 0):
             raise ValueError("sliding_window and sliding_window_layout "
                              "come together: which layers slide is stated")
@@ -280,6 +306,107 @@ class ModelConfig:
                 "first_k_dense without kv_lora_rank: feed-forwards of two "
                 "shapes run as layer runs, which the latent-attention "
                 "family's forward carries and no other yet")
+
+    def _check_rope_scaling(self):
+        """A published `rope_scaling` group, held as sorted items: of
+        type "yarn" with every key yarn_inv_freq and attn_scale_mult
+        read, on a model that rotates."""
+        rs = dict(self.rope_scaling or ())
+        object.__setattr__(self, "rope_scaling", tuple(sorted(rs.items())))
+        if not rs:
+            return
+        if rs.get("type") != "yarn":
+            raise ValueError(f"rope_scaling of type {rs.get('type')!r}: the "
+                             "program knows plain rotation and 'yarn'")
+        missing = [k for k in ("factor", "original_max_position_embeddings")
+                   if not rs.get(k)]
+        if missing:
+            raise ValueError(f"rope_scaling 'yarn' without {missing}")
+        if self.pos_embedding != "rope" or self.layer_pattern() is not None:
+            raise ValueError(
+                "rope_scaling on a model that does not rotate every layer "
+                f"(pos_embedding {self.pos_embedding!r}, or a per-layer "
+                "pattern) is not supported")
+        if rs.get("mscale_all_dim") and not self.is_latent:
+            raise ValueError(
+                "rope_scaling.mscale_all_dim scales the softmax of latent "
+                "attention (kv_lora_rank); no other attention here takes it")
+        if rs.get("mscale_all_dim") \
+                and rs.get("mscale", 1) != rs["mscale_all_dim"]:
+            raise ValueError(
+                f"rope_scaling mscale {rs.get('mscale')} beside "
+                f"mscale_all_dim {rs['mscale_all_dim']}: cos and sin would "
+                "carry their ratio, which the program does not apply")
+
+    def _check_streams(self):
+        """hc_mult streams come with at least one Sinkhorn round, an
+        ordered clamp, and beside nothing that reads a layer's input
+        around the sublayer's own read of the streams."""
+        if self.hc_mult < 0 or self.hc_mult == 1:
+            raise ValueError(f"hc_mult {self.hc_mult}: 0 (one stream, "
+                             "x + F(norm(x))) or two streams and more")
+        if not self.hc_mult:
+            return
+        if self.hc_sinkhorn_iters < 1 or self.hc_eps <= 0 \
+                or not self.hc_clamp_min < self.hc_clamp_max:
+            raise ValueError(
+                f"hc_mult {self.hc_mult} with hc_sinkhorn_iters "
+                f"{self.hc_sinkhorn_iters}, hc_eps {self.hc_eps}, clamp "
+                f"[{self.hc_clamp_min}, {self.hc_clamp_max}]: one round "
+                "or more, a positive eps, an ordered clamp")
+        for name, on in (("layer_types", bool(self.layer_types)),
+                         ("index_topk", self.has_indexer),
+                         ("router_input 'attn'",
+                          self.is_moe and self.router_input == "attn"),
+                         ("residual_multiplier",
+                          bool(self.residual_multiplier)),
+                         ("arch 'gpt2'", self.arch == "gpt2")):
+            if on:
+                raise ValueError(f"{name} beside hc_mult (n residual "
+                                 "streams) is not supported")
+
+    def yarn_inv_freq(self) -> np.ndarray:
+        """The rotation rate of each pair of rope_dim under rope_scaling
+        "yarn", float32 [rope_dim / 2]: pair i's plain rate f_i =
+        theta^(-2i/rope_dim) where it turns more than beta_fast times
+        over the original context, f_i / factor where it turns fewer
+        than beta_slow times, and a linear ramp between the two pairs
+        those counts fall on (the correction range: floor and ceil,
+        clipped to [0, rope_dim - 1], as the DeepSeek-V2/V3 code the
+        keys come from has it)."""
+        rs = dict(self.rope_scaling)
+        half = self.rope_dim // 2
+        freq = self.rope_theta ** (-np.arange(half, dtype=np.float64) / half)
+        orig = rs["original_max_position_embeddings"]
+
+        def pair_of(turns):    # the pair that turns `turns` times in orig
+            return half * np.log(orig / (turns * 2 * np.pi)) \
+                / np.log(self.rope_theta)
+
+        lo = max(np.floor(pair_of(rs.get("beta_fast", 32))), 0)
+        hi = min(np.ceil(pair_of(rs.get("beta_slow", 1))), 2 * half - 1)
+        ramp = np.clip((np.arange(half) - lo) / max(hi - lo, 1e-3), 0, 1)
+        return (freq / rs["factor"] * ramp
+                + freq * (1 - ramp)).astype(np.float32)
+
+    @property
+    def attn_scale_mult(self) -> float:
+        """What rope_scaling "yarn" puts on the softmax scale of latent
+        attention: m^2, m = 0.1 x mscale_all_dim x ln(factor) + 1 (1.0
+        without). Cos and sin stay unscaled: their factor is m(mscale)
+        / m(mscale_all_dim), 1 where the two are equal
+        (_check_rope_scaling refuses a file in which they differ)."""
+        rs = dict(self.rope_scaling)
+        if not rs or not rs.get("mscale_all_dim") or rs["factor"] <= 1:
+            return 1.0
+        m = 0.1 * rs["mscale_all_dim"] * np.log(rs["factor"]) + 1.0
+        return float(m * m)
+
+    @property
+    def attn_scale(self) -> float:
+        """The softmax scale of latent attention: (nope + rope) ** -0.5
+        times attn_scale_mult."""
+        return self.qk_head_dim ** -0.5 * self.attn_scale_mult
 
     @property
     def is_moe(self) -> bool:
@@ -485,6 +612,35 @@ def joyai_llm_flash() -> ModelConfig:
     )
 
 
+def xing4_29b_a4b() -> ModelConfig:
+    """Xing4.0-29B-A4B (huggingface.co/XingChen-AGI, `xing4_0`): 40
+    layers whose residual path is FOUR streams mixed by
+    manifold-constrained hyper-connections (hc_mult 4, 20 Sinkhorn
+    rounds) around latent attention (32 heads of 128 + 64 rotary against
+    ONE cached row of 512 + 64 a token, values of 128, the query through
+    a latent of 768; YaRN, factor 64 over 4,096) and a feed-forward
+    that is dense (9,216) in layers 0-1 and 64 sigmoid-routed experts
+    of 1,024, 4 a token with a selection bias and weights times 2, plus
+    one shared expert, behind them; untied head. The published
+    prediction layer (`num_nextn_predict_layers` 1) takes no part in
+    the next-token distribution and is not held."""
+    return ModelConfig(
+        arch="xing", vocab_size=131072, hidden_size=3584, num_layers=40,
+        num_heads=32, num_kv_heads=32, intermediate_size=9216,
+        max_seq_len=262144, norm_eps=1e-6, rope_theta=10000.0,
+        rope_scaling={"type": "yarn", "factor": 64, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": 4096},
+        num_experts=64, num_experts_per_tok=4, moe_intermediate_size=1024,
+        shared_intermediate_size=1024, first_k_dense=2,
+        router_score="sigmoid", router_bias=True, routed_scaling_factor=2.0,
+        kv_lora_rank=512, q_lora_rank=768, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, rope_interleave=True,
+        hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
+        hc_clamp_min=-30.0, hc_clamp_max=30.0,
+    )
+
+
 def tiny(arch: str = "llama", **kw) -> ModelConfig:
     """Small config for tests: runs in <1s on CPU, exercises every code path."""
     base = dict(
@@ -536,6 +692,25 @@ def tiny(arch: str = "llama", **kw) -> ModelConfig:
                     routed_scaling_factor=2.5, kv_lora_rank=32,
                     q_lora_rank=48, qk_nope_head_dim=16,
                     qk_rope_head_dim=8, v_head_dim=16, rope_interleave=True)
+    if arch == "xing":
+        # every mechanism of the real one: four residual streams mixed
+        # with 20 Sinkhorn rounds, TWO leading dense layers before the
+        # experts, latent attention under a YaRN rotation whose ramp
+        # falls inside the toy's 4 pairs, sigmoid routing with a bias
+        # and a scale, a shared expert
+        base.update(num_layers=4, intermediate_size=96,
+                    moe_intermediate_size=32, shared_intermediate_size=32,
+                    num_experts=8, num_experts_per_tok=3, first_k_dense=2,
+                    router_score="sigmoid", router_bias=True,
+                    routed_scaling_factor=2.0, kv_lora_rank=32,
+                    q_lora_rank=48, qk_nope_head_dim=16,
+                    qk_rope_head_dim=8, v_head_dim=16, rope_interleave=True,
+                    rope_theta=10000.0,
+                    rope_scaling={"type": "yarn", "factor": 64,
+                                  "beta_fast": 32, "beta_slow": 1,
+                                  "mscale": 1, "mscale_all_dim": 1,
+                                  "original_max_position_embeddings": 64},
+                    hc_mult=4)
     base.update(kw)
     return ModelConfig(arch=arch, **base)
 
@@ -549,6 +724,7 @@ PRESETS = {
     "keye-vl2-30b-a3b": keye_vl2_30b_a3b,
     "granite-4.0-h-small": granite_4_h_small,
     "joyai-llm-flash": joyai_llm_flash,
+    "xing4.0-29b-a4b": xing4_29b_a4b,
 }
 
 

@@ -67,7 +67,8 @@ from butterfly_tpu.ops import kernel_mode, kernels_default, record_kernels
 from butterfly_tpu.engine.sampling import _filter_logits, speculative_accept
 from butterfly_tpu.cache.ssm_state import SSMState, init_ssm_state
 from butterfly_tpu.models.common import (
-    Model, indexer_unsupported, latent_unsupported, ssm_unsupported)
+    Model, indexer_unsupported, latent_unsupported, ssm_unsupported,
+    streams_unsupported)
 
 
 #: the span a program launch runs under (`bf.tick.dispatch.launch` in a
@@ -242,6 +243,17 @@ class ServingEngine:
         if not self.runtime.mixed_dispatch \
                 or self.runtime.scheduler != "continuous":
             ssm_unsupported(self.cfg, ALTERNATING)
+        # and what adds a sublayer's output to ONE stream in a layer body
+        # of its own refuses a model of n residual streams (hc_mult).
+        # Speculation is not among them: its verify forward is
+        # paged_forward[_window], whose layers call the one pair
+        # (models/common.py stream_read / stream_write), and a rejected
+        # draft leaves nothing in the streams, which no step keeps
+        for axis, what in (("stage", "pipeline serving (a stage hands "
+                                     "its successor [rows, D])"),
+                           ("seq", "the sequence-parallel prefill lane")):
+            if mesh is not None and mesh.shape.get(axis, 1) > 1:
+                streams_unsupported(self.cfg, what)
         # speculation has one path, the speculative mixed block: nothing
         # falls back in silence to a scheduler that cannot run it
         if self.runtime.speculative_gamma > 0 and (
@@ -1307,7 +1319,9 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
     through a recurrence (decode rows and real chunk columns) and the
     slots that started from zero. A latent-attention model's `load`
     ends in ONE such sum: the cached rows its decode rows read, over
-    the layers (cache/paged.py latent_paged_attend).
+    the layers (cache/paged.py latent_paged_attend); a model of n
+    residual streams' in one more, LAST: the positions mixed
+    (cache/paged.py _mixed_rows).
     """
     S = tokens.shape[0]
     H = pbuf.shape[1]
@@ -1376,7 +1390,7 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
         rows = load[:, 3:].sum(axis=0)
         load = jnp.concatenate([experts, rows[1:] / jnp.maximum(rows[0], 1)]) \
             if cfg.has_indexer else experts
-        if cfg.has_ssm or cfg.is_latent:
+        if cfg.has_ssm or cfg.is_latent or cfg.hc_mult:
             load = jnp.concatenate([experts, rows])
     return (block, valid, final, cursor, cache, window, win_len, load,
             st[0] if st else None)

@@ -26,6 +26,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from butterfly_tpu.core.config import ModelConfig
@@ -182,7 +183,10 @@ def rope_freqs(cfg: ModelConfig, positions: jax.Array) -> Tuple[jax.Array, jax.A
     """cos/sin tables for positions [..., T] -> [..., T, rope_dim/2], f32
     (rope_dim: head_dim, or a latent model's rotary part)."""
     half = cfg.rope_dim // 2
-    inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if cfg.rope_scaling:    # "yarn": the blended rates, constants of cfg
+        inv_freq = jnp.asarray(cfg.yarn_inv_freq())
+    else:
+        inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
     angles = positions.astype(jnp.float32)[..., None] * inv_freq  # [..., T, half]
     return jnp.cos(angles), jnp.sin(angles)
 
@@ -521,7 +525,8 @@ def attn_output(out: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
 # its "values": o'_h = sum p c_kv, and attn_output expands o'_h W_uv,h.
 # The same numbers; no per-head key or value of the context exists in
 # the absorbed form, and the row is read once. scale = (nope + rope) **
-# -0.5 both ways. latent_proj, latent_queries and the two attends below
+# -0.5 both ways (times m^2 under a "yarn" rotation: cfg.attn_scale).
+# latent_proj, latent_queries and the two attends below
 # are the ONE definition that the contiguous path and the paged path
 # (cache/paged.py latent_paged_attend) share.
 # ---------------------------------------------------------------------------
@@ -576,7 +581,7 @@ def latent_attend(q: jax.Array, rows: jax.Array, mask: jax.Array,
     """The absorbed read: q [B,T,Nq,latent_row] (latent_queries) against
     cached rows [B,S,latent_row], mask [B,T,S]. Returns o'
     [B,T,Nq,kv_lora_rank]. Softmax in float32, as attend's."""
-    scale = cfg.qk_head_dim ** -0.5
+    scale = cfg.attn_scale
     s = jnp.einsum("btnr,bsr->bnts", q, rows,
                    preferred_element_type=jnp.float32) * scale
     s = jnp.where(mask[:, None], s, -1e30)
@@ -594,7 +599,7 @@ def latent_attend_expanded(q_nope, q_rope, rows, mask, p: Params,
     c, k_r = rows[..., :cfg.kv_lora_rank], rows[..., cfg.kv_lora_rank:]
     k_nope = qeinsum("bsr,rnh->bsnh", c, p["w_uk"], dt)
     v = qeinsum("bsr,rnh->bsnh", c, p["w_uv"], dt)
-    scale = cfg.qk_head_dim ** -0.5
+    scale = cfg.attn_scale
     s = (jnp.einsum("btnh,bsnh->bnts", q_nope, k_nope,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("btnh,bsh->bnts", q_rope, k_r,
@@ -874,12 +879,123 @@ def ffn_block(h: jax.Array, lp: Params, cfg: ModelConfig,
     return mlp_block(h, lp["mlp"], cfg)
 
 
-def residual_add(x: jax.Array, y: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """x + y, a sublayer's output onto the stream; a family with a
-    residual multiplier (Granite) scales the output first."""
-    if cfg.residual_multiplier:
-        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
-    return x + y
+# ---------------------------------------------------------------------------
+# The residual path: ONE pair, stream_read before a sublayer and
+# stream_write behind it, at every site that runs a sublayer (the
+# contiguous forwards here, cache/paged.py, cache/ssm_state.py).
+#
+# cfg.hc_mult 0, every family but one: the carry of a layer scan is one
+# stream x [B,T,D]; a sublayer reads norm(x) and writes x + y (times
+# Granite's residual multiplier).
+#
+# cfg.hc_mult n > 0 (manifold-constrained hyper-connections,
+# arXiv:2512.24880 over arXiv:2409.19606): the carry is n streams X
+# [n,B,T,D] (embed_tokens copies the embedding n times, stream_fold sums
+# them before the final norm). Each sublayer has leaves phi [nD, n+n+n^2],
+# b [n+n+n^2], alpha [3] (lp["hc1"] the mixer's, lp["hc2"] the
+# feed-forward's) and, a token, in float32:
+#
+#   v      = vec(X) / sqrt(mean(vec(X)^2) + hc_eps)     over all n D values
+#   pre~, post~, res~ = alpha[0|1|2] * (v @ phi[:, 0:n | n:2n | 2n:]) + b[..]
+#   H_pre  = sigmoid(pre~) [n];   H_post = 2 sigmoid(post~) [n]
+#   H_res  = M after hc_sinkhorn_iters rounds of { M /= colsum(M) + hc_eps;
+#            M /= rowsum(M) + hc_eps },  M0 = exp(clip(res~ [n,n]))
+#   h      = sum_i H_pre[i] X[i]            what the sublayer sees
+#   X'     = H_res @ X + outer(H_post, F(norm(h)))
+#
+# The mixing is float32 whatever cfg.dtype is (_MIX): H_res multiplies
+# every stream in every sublayer, and the streams are the model's whole
+# memory of a token; only the carried streams are cfg.dtype, as the one
+# stream is. The coefficients are held rows-minor ([n,n,R], [n,R]: R the
+# B*T rows): the 20 rounds over a 4 x 4 are then elementwise over whole
+# vectors of rows, not reductions inside a register.
+# ---------------------------------------------------------------------------
+
+_MIX = jnp.float32
+
+
+def streams_unsupported(cfg: ModelConfig, what: str) -> None:
+    """Refuse a model whose residual path is n streams on a path whose
+    layer bodies add a sublayer's output to ONE stream themselves."""
+    if cfg.hc_mult:
+        raise NotImplementedError(
+            f"{what} carries one residual stream [rows, D] between its "
+            f"layers, not the {cfg.hc_mult} mixed by hyper-connections "
+            "(hc_mult): not supported for this model")
+
+
+def stream_read(x: jax.Array, lp: Params, sub: int, cfg: ModelConfig):
+    """What sublayer `sub` of a layer (1 the mixer, 2 the feed-forward)
+    reads of the residual path x, under the sublayer's own pre-norm
+    lp["ln<sub>"], and what stream_write takes to put its output back:
+    (h [B,T,D], mix). hc_mult 0: (norm(x), None). Else x is [n,B,T,D]
+    and mix = (H_res [n,n,R], H_post [n,R]) of lp["hc<sub>"] (the
+    equations above)."""
+    norm = lp[f"ln{sub}"]
+    if not cfg.hc_mult:
+        return pre_norm(x, norm, cfg), None
+    with jax.named_scope("hc_mix"):
+        n, eps = cfg.hc_mult, cfg.hc_eps
+        hp = lp[f"hc{sub}"]
+        xf = x.astype(_MIX)
+        D = x.shape[-1]
+        # v @ phi as (X . phi) / rms: the product is linear in v
+        inv = lax.rsqrt(jnp.mean(xf * xf, axis=(0, -1)) + eps)    # [B,T]
+        z = jnp.einsum("nbtd,ndk->kbt", xf,
+                       hp["phi"].astype(_MIX).reshape(n, D, -1),
+                       precision=lax.Precision.HIGHEST) * inv
+        z = z.reshape(z.shape[0], -1)                            # [K, R]
+        alpha = jnp.repeat(hp["alpha"].astype(_MIX), np.array((n, n, n * n)),
+                           total_repeat_length=n * (2 + n))
+        z = z * alpha[:, None] + hp["b"].astype(_MIX)[:, None]
+        pre = jax.nn.sigmoid(z[:n])                              # [n, R]
+        post = 2.0 * jax.nn.sigmoid(z[n:2 * n])
+        res = jnp.exp(jnp.clip(z[2 * n:], cfg.hc_clamp_min, cfg.hc_clamp_max))
+        # H_res entry by entry, each a vector of rows, and every sum
+        # written out: 20 rounds are then elementwise over [R] from end
+        # to end, which XLA makes a few fusions of. As m [n, n, R] with
+        # jnp.sum over an axis each round was 150 operations a sublayer
+        # of a microsecond each on the chip (PERF.md, PR 49)
+        m = [[res[i * n + j] for j in range(n)] for i in range(n)]
+        for _ in range(cfg.hc_sinkhorn_iters):
+            cols = [sum(m[i][j] for i in range(n)) + eps for j in range(n)]
+            m = [[m[i][j] / cols[j] for j in range(n)] for i in range(n)]
+            rows = [sum(m[i]) + eps for i in range(n)]
+            m = [[m[i][j] / rows[i] for j in range(n)] for i in range(n)]
+        col = x.shape[1:-1] + (1,)
+        h = sum(pre[i].reshape(col) * xf[i] for i in range(n))
+        return pre_norm(h.astype(x.dtype), norm, cfg), \
+            (jnp.stack([jnp.stack(r) for r in m]), post)
+
+
+def stream_write(x: jax.Array, y: jax.Array, mix, cfg: ModelConfig
+                 ) -> jax.Array:
+    """A sublayer's output y [B,T,D] onto the residual path x, with
+    stream_read's mix. hc_mult 0 (mix None): x + y, the output first
+    times a family's residual multiplier (Granite). Else X' = H_res @ X
+    + outer(H_post, y) over the n streams, in float32."""
+    if mix is None:
+        if cfg.residual_multiplier:
+            y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+        return x + y
+    with jax.named_scope("hc_mix"):
+        m, post = mix
+        n = cfg.hc_mult
+        rows = x.shape[1:-1] + (1,)
+        xf, yf = x.astype(_MIX), y.astype(_MIX)
+        return jnp.stack([
+            sum(m[i, j].reshape(rows) * xf[j] for j in range(n))
+            + post[i].reshape(rows) * yf for i in range(n)]).astype(x.dtype)
+
+
+def stream_fold(x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The residual path behind the last layer as ONE stream [B,T,D]:
+    the n streams summed (hyper-connections' own fold), x itself for
+    hc_mult 0. Every forward calls it where its layer scans end, before
+    it picks the rows the head reads."""
+    if not cfg.hc_mult:
+        return x
+    return jnp.sum(x.astype(_MIX), axis=0).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -1070,14 +1186,14 @@ def ffn_close(x: jax.Array, lp: Params, cfg: ModelConfig, route=None,
     (x, load): with `ok` [B,T], the rows that are real, and a model of
     experts, `load` is what the layer's routing asked of them
     (expert_load), else None."""
-    h = pre_norm(x, lp["ln2"], cfg)
+    h, mix = stream_read(x, lp, 2, cfg)
     load = None
     if ok is not None and cfg.is_moe and "moe" in lp:
         if route is None:
             route = router_logits(h, lp["moe"]["router"])
         load = expert_load(route, cfg.num_experts_per_tok, ok,
                            cfg.router_score, lp["moe"].get("router_bias"))
-    return residual_add(x, ffn_block(h, lp, cfg, route), cfg), load
+    return stream_write(x, ffn_block(h, lp, cfg, route), mix, cfg), load
 
 
 def transformer_layer(x: jax.Array, lp: Params, cfg: ModelConfig,
@@ -1088,22 +1204,23 @@ def transformer_layer(x: jax.Array, lp: Params, cfg: ModelConfig,
                       k_s: Optional[jax.Array] = None,
                       v_s: Optional[jax.Array] = None,
                       cki: Optional[jax.Array] = None):
-    """Pre-norm residual block: x + attn(norm(x)); x + ffn(norm(x)).
+    """Pre-norm residual block: x + attn(norm(x)); x + ffn(norm(x)),
+    or the same two sublayers over n streams (stream_read, stream_write).
 
     Returns (x, ck, cv), or (x, ck, cv, k_s, v_s) with an int8 cache,
     and the layer's index keys cki after them for a model with an
     indexer; in attention_block's no-cache fresh mode (ck None),
     (x, k, v) with the layer's raw projected K/V.
     """
-    h = pre_norm(x, lp["ln1"], cfg)
+    h, mix = stream_read(x, lp, 1, cfg)
+    # x itself where a router or an indexer reads the layer's input:
+    # ModelConfig refuses either beside hc_mult
     route = early_router_logits(x, lp, cfg)
     index = index_proj(x, lp, cfg, cos, sin) if cfg.has_indexer else None
     attn_out, *rest = attention_block(
         h, lp["attn"], cfg, ck, cv, positions, mask, cos, sin, fresh,
         k_s, v_s, lp.get("pattern"), index, cki)
-    x = residual_add(x, attn_out, cfg)
-    x = residual_add(x, ffn_block(pre_norm(x, lp["ln2"], cfg), lp, cfg,
-                                  route), cfg)
+    x, _ = ffn_close(stream_write(x, attn_out, mix, cfg), lp, cfg, route)
     return (x, *rest)
 
 
@@ -1175,12 +1292,16 @@ def layer_mask(mask: jax.Array, positions: jax.Array, sliding_window):
 def embed_tokens(params: Params, cfg: ModelConfig, tokens: jax.Array,
                  positions: jax.Array
                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Token (+pos) embedding. Returns (x [B,T,D], cos, sin)."""
+    """Token (+pos) embedding. Returns (x [B,T,D], cos, sin); x is
+    [n,B,T,D] for a model of n residual streams (cfg.hc_mult): the
+    embedding, n times."""
     B, T = tokens.shape
     compute_dtype = jnp.dtype(cfg.dtype)
     x = params["embed"]["tok"].astype(compute_dtype)[tokens]
     if cfg.embedding_multiplier:
         x = x * jnp.asarray(cfg.embedding_multiplier, compute_dtype)
+    if cfg.hc_mult:
+        x = jnp.broadcast_to(x, (cfg.hc_mult,) + x.shape)
     if cfg.pos_embedding == "learned":
         x = x + params["embed"]["pos"].astype(compute_dtype)[positions]
         cos = sin = jnp.zeros((B, T, cfg.head_dim // 2), jnp.float32)
@@ -1359,15 +1480,14 @@ def _decode_layer_body(x, lp, cfg: ModelConfig, cache: KVCache, i,
     if cache.quantized:
         k_s = lax.dynamic_index_in_dim(cache.k_scale, i, 0, keepdims=False)
         v_s = lax.dynamic_index_in_dim(cache.v_scale, i, 0, keepdims=False)
-    h = pre_norm(x, lp["ln1"], cfg)
+    h, mix = stream_read(x, lp, 1, cfg)
     route = early_router_logits(x, lp, cfg)
     rope, sw = layer_pattern_of(lp.get("pattern"))
     q, k, v = qkv_proj(h, lp["attn"], cfg, cos, sin, rope)
     out = decode_attend(q, k, v, ck, cv, start, cfg, k_s, v_s,
                         wk_i, wv_i, wks_i, wvs_i, sliding_window=sw)
-    x = residual_add(x, attn_output(out, lp["attn"], cfg), cfg)
-    x = residual_add(x, ffn_block(pre_norm(x, lp["ln2"], cfg), lp, cfg,
-                                  route), cfg)
+    x = stream_write(x, attn_output(out, lp["attn"], cfg), mix, cfg)
+    x, _ = ffn_close(x, lp, cfg, route)
     return x, k, v
 
 
@@ -1404,7 +1524,7 @@ def _decode_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
     (x, _), outs = lax.scan(layer, (x, 0),
                             layer_stack(params["layers"], cfg))
-    logits = final_logits(params, cfg, x)
+    logits = final_logits(params, cfg, stream_fold(x, cfg))
 
     if quant:
         # codes: [L,B,Kv,S,H] <- scan outputs [L,B,1,Kv,H] -> [L,B,Kv,1,H]
@@ -1499,7 +1619,7 @@ def decode_step_win(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
     (x, _), new_kv = lax.scan(
         layer, (x, 0), (layer_stack(params["layers"], cfg), win))
-    return final_logits(params, cfg, x), new_kv
+    return final_logits(params, cfg, stream_fold(x, cfg)), new_kv
 
 
 def flush_window(cache: KVCache, steps: list,
@@ -1621,6 +1741,7 @@ def _fresh_prefill_forward(params: Params, cfg: ModelConfig,
     pools0 = (cache.k, cache.v, cache.k_scale, cache.v_scale)
     (x, pools, _), _ = lax.scan(body, (x, pools0, 0),
                                 layer_stack(params["layers"], cfg))
+    x = stream_fold(x, cfg)
     if last_index is not None:
         x = jnp.take_along_axis(
             x, last_index[:, None, None].astype(jnp.int32), axis=1)
@@ -1665,12 +1786,13 @@ def _hybrid_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         x, ck, cv = carry
         l, a = idx
         lp = layer_at(params["layers"], l, cfg)
+        h, mix = stream_read(x, lp, 1, cfg)
         out, k, v = attention_block(
-            pre_norm(x, lp["ln1"], cfg), layer_at(params["attn"], a, cfg),
+            h, layer_at(params["attn"], a, cfg),
             cfg, lax.dynamic_index_in_dim(ck, a, 0, keepdims=False),
             lax.dynamic_index_in_dim(cv, a, 0, keepdims=False),
             positions, mask, cos, sin)
-        x, _ = ffn_close(residual_add(x, out, cfg), lp, cfg)
+        x, _ = ffn_close(stream_write(x, out, mix, cfg), lp, cfg)
         return (x, lax.dynamic_update_index_in_dim(ck, k, a, 0),
                 lax.dynamic_update_index_in_dim(cv, v, a, 0)), None
 
@@ -1711,8 +1833,8 @@ def _latent_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     def layer(ffn, carry, l):
         x, ck = carry
         lp = run_layer_at(params, ffn, l, cfg)
-        q_nope, q_rope, rows = latent_proj(pre_norm(x, lp["ln1"], cfg),
-                                           lp["attn"], cfg, cos, sin)
+        h, mix = stream_read(x, lp, 1, cfg)
+        q_nope, q_rope, rows = latent_proj(h, lp["attn"], cfg, cos, sin)
         # the layer's rows [B,S,latent_row], as index keys are written
         mine = index_cache_write(
             lax.dynamic_index_in_dim(ck, l, 0, keepdims=False)[:, :, 0],
@@ -1724,7 +1846,7 @@ def _latent_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             out = latent_attend(
                 latent_queries(q_nope, q_rope, lp["attn"], cfg), mine,
                 mask, cfg)
-        x = residual_add(x, attn_output(out, lp["attn"], cfg), cfg)
+        x = stream_write(x, attn_output(out, lp["attn"], cfg), mix, cfg)
         x, _ = ffn_close(x, lp, cfg)
         return (x, lax.dynamic_update_index_in_dim(ck, mine[:, :, None], l,
                                                    0)), None
@@ -1733,6 +1855,7 @@ def _latent_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     for _, first, n, _ in layer_runs(cfg):
         (x, ck), _ = lax.scan(partial(layer, ffn_run(params, first, cfg)),
                               (x, ck), first + jnp.arange(n))
+    x = stream_fold(x, cfg)
     if last_index is not None:
         x = jnp.take_along_axis(
             x, last_index[:, None, None].astype(jnp.int32), axis=1)
@@ -1786,6 +1909,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     x, new_k, new_v, *rest = scan_layers(
         params["layers"], cfg, x, cache.k, cache.v, positions, mask, cos,
         sin, fresh, cache.k_scale, cache.v_scale, cache.ki)
+    x = stream_fold(x, cfg)
     if last_index is not None:
         x = jnp.take_along_axis(
             x, last_index[:, None, None].astype(jnp.int32), axis=1)
@@ -1928,6 +2052,19 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         params["final_norm"]["bias"] = jnp.zeros((D,), pdt)
     if not cfg.tie_embeddings:
         params["lm_head"] = w(next(keys), D, V)
+    if cfg.hc_mult:
+        # the residual streams' mixing, a sublayer (stream_read): drawn
+        # last, so that no other leaf's key moves. b away from zero and
+        # alpha small: a seeded model's H_res is a generic doubly
+        # stochastic matrix, NOT the identity a trained one starts
+        # from, which would hide a wrong mix from every comparison
+        n = cfg.hc_mult
+        for sub in ("hc1", "hc2"):
+            layers[sub] = {
+                "phi": w(next(keys), L, n * D, n * (2 + n)),
+                "b": w(next(keys), L, n * (2 + n), std=1.0),
+                "alpha": jnp.full((L, 3), 0.01, pdt),
+            }
     return params
 
 

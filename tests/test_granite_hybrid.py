@@ -669,6 +669,8 @@ WINDOWED = {
     "runs_granite": (("granite_hybrid", dict(head_dim=128, ssm_state=128)),
                      "none"),
     "runs_joyai": (("joyai", dict(kv_lora_rank=128, num_layers=4)), "none"),
+    # four residual streams [4, N, 1, D] in the carry beside the window
+    "runs_xing": (("xing", dict(kv_lora_rank=128, hidden_size=128)), "none"),
     "keye": (("keye", dict(head_dim=128)), "none"),
 }
 
@@ -687,7 +689,10 @@ def test_a_block_moves_no_window_inside_its_loops(model, chunks, one_chip,
     every step: PERF.md, PR 47). A plain scan with bfloat16 and int8
     leaves and their scales, a sliding model, the runs of granite's and
     JoyAI's layers, Keye's token-major leaves and index keys, which
-    XLA stages."""
+    XLA stages; and the runs of a model of four residual streams, the
+    second thing to ride the carry: every sublayer computes them anew,
+    so a fusion of their shape is the mixing itself, and what they are
+    held to is no `copy` and no `transpose` of [n, N, 1, D] in a loop."""
     import sys
     from functools import partial
     from pathlib import Path
@@ -744,6 +749,10 @@ def test_a_block_moves_no_window_inside_its_loops(model, chunks, one_chip,
     hlo = compiled.as_text()
     leaves = jax.tree.leaves(window)
     assert window_moves(hlo, leaves) == []
+    if cfg.hc_mult:
+        streams = sds((cfg.hc_mult, S + chunks * C, 1, cfg.hidden_size),
+                      jnp.bfloat16)
+        assert window_moves(hlo, [streams], kinds=("copy", "transpose")) == []
     calls = [line for line in hlo.splitlines()
              if "tpu_custom_call" in line and " custom-call(" in line]
     # the Mosaic writer stages every window but Keye's (rows of 16

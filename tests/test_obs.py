@@ -591,6 +591,45 @@ def test_the_latent_rows_gauge_is_registered_with_its_help():
     assert "latent rows" in text
 
 
+@pytest.mark.parametrize("kw, rows, steps", [
+    (dict(), None, None),                       # one stream, or no block
+    (dict(hc_load=[4 * 128.0 + 32, 4]), 544.0, 4),
+    (dict(hc_load=[64.0, 8], latent_load=[0.0, 8]), 64.0, 8),
+])
+def test_ticklog_record_carries_the_positions_mixed(kw, rows, steps):
+    """The tick records of a model of n residual streams (hc_mult): the
+    positions whose streams the drained blocks' steps mixed and the
+    steps those blocks ran, as sums; null for every other model, beside
+    the other families' counters."""
+    from butterfly_tpu.obs.ticklog import TickLog
+    log = TickLog()
+    log.record(0.02, {"mixed": 0.02}, program="bf_mixed_block_win",
+               expert_load=[64.0, 12.0, 8.0], **kw)
+    tick, = log.dump()["ticks"]
+    assert (tick["hc_rows"], tick["hc_steps"]) == (rows, steps)
+    assert tick["experts_touched"] == 64.0 and tick["ssm_rows"] is None
+    json.dumps(tick)
+
+
+def test_the_positions_mixed_counter_is_registered_with_its_help():
+    """`butterfly_hc_rows_mixed_total` in the scheduler's registry: a
+    counter every model's scheduler holds (0 for a model of one stream),
+    named in the exposition with its help text."""
+    import jax
+    from butterfly_tpu.core.config import RuntimeConfig, tiny
+    from butterfly_tpu.engine.serving import ServingEngine
+    from butterfly_tpu.models.common import Model
+    from butterfly_tpu.sched.scheduler import Scheduler
+    cfg = tiny("llama", dtype="float32")
+    sched = Scheduler(ServingEngine(
+        Model(cfg), Model(cfg).init(jax.random.PRNGKey(0)),
+        RuntimeConfig(max_batch_size=2, max_seq_len=32, page_size=4)))
+    assert sched.registry.snapshot()["hc_rows_mixed_total"] == 0
+    text = sched.registry.render()
+    assert "# TYPE butterfly_hc_rows_mixed_total counter" in text
+    assert "residual streams" in text
+
+
 def test_trace_report_prints_the_starved_seconds(tmp_path):
     """`trace_report.py --ticks` reads a /debug/ticks dump without the
     benchmark: the starved seconds as a share of what the ticks span, by

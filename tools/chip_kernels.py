@@ -361,10 +361,13 @@ def state_copies(hlo: str, h) -> list:
     return [m.group(1) for m in map(made.match, hlo.splitlines()) if m]
 
 
-def window_moves(hlo: str, leaves) -> list:
+def window_moves(hlo: str, leaves,
+                 kinds=("copy", "transpose", "fusion")) -> list:
     """The instructions INSIDE a compiled HLO text's loops that make a
     value of a window leaf's whole shape [L, S, Kv, W, H] or of one
-    layer's slice of it: a `copy`, a `transpose` or a fusion in any
+    layer's slice of it: a `copy`, a `transpose` or a fusion (`kinds`;
+    a carried leaf that every layer COMPUTES anew, the residual streams
+    of a model of n, is held to the first two) in any
     computation that is some `while`'s body. (The loops' carries, the
     Mosaic writer's aliased result and a chunk's one-slot view are none
     of these: tuples, a custom call, another shape.) `leaves`: arrays or
@@ -379,7 +382,7 @@ def window_moves(hlo: str, leaves) -> list:
     bodies = set(re.findall(r"body=%([^\s,)]+)", hlo))
     head = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{\s*$")
     made = re.compile(
-        r"^\s*(?:ROOT )?%(\S+) = (.*?) (?:copy|transpose|fusion)\(")
+        r"^\s*(?:ROOT )?%(\S+) = (.*?) (?:" + "|".join(kinds) + r")\(")
     found, inside = [], False
     for line in hlo.splitlines():
         m = head.match(line)
@@ -391,6 +394,115 @@ def window_moves(hlo: str, leaves) -> list:
                          re.findall(r"\w+\[(?:1,)*([\d,]+)\]", m.group(2))):
                 found.append(m.group(1))
     return found
+
+
+def cell_blocks(config: dict, hlo_dir=None) -> list:
+    """A cell's two block programs, the mixed block (one chunk of C
+    beside the decode rows) and the decode block, at the sizes of its
+    configuration file, compiled for a DESCRIBED v5e (no chip: shapes
+    only, `python3 tools/chip_kernels.py --cell <config.json>` under
+    JAX_PLATFORMS=cpu), built as engine/serving.py builds them
+    (_packed_scan over paged_forward_packed, kernels on, the cursor, the
+    cache, the window and its counts donated). A record a program: what
+    the compiler says it holds (arguments, temporaries, whether that
+    fits the chip's 15.75 GiB), the Mosaic calls by name, and the
+    instructions inside its loops that move a carried leaf: a copy,
+    transpose or fusion of a window leaf's shape, a copy or transpose
+    of the residual streams' [n, rows, 1, D] (window_moves). The
+    compiler's figures were the chip's to the megabyte (PR 41).
+    hlo_dir: where to write each program's compiled text
+    (`<name>.hlo.txt`: what a trace will name, and what two checkouts'
+    programs are compared by)."""
+    import re
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from butterfly_tpu.cache.paged import (
+        init_kv_window, init_paged_cache, paged_forward_packed)
+    from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
+    from butterfly_tpu.engine.serving import _packed_scan
+    from butterfly_tpu.quant.int8 import init_params_by_leaf
+    from servebench.launcher import model_fields
+
+    cfg = ModelConfig(**model_fields(config))
+    sv = config["serve"]
+    rt = RuntimeConfig(max_batch_size=sv["max_batch"],
+                       max_seq_len=sv["max_seq"], page_size=sv["page_size"],
+                       kv_quant=sv.get("kv_quant", "none"),
+                       decode_steps_per_tick=sv["decode_steps_per_tick"])
+    S, k = rt.max_batch_size, rt.decode_steps_per_tick
+    C = min(rt.prefill_inline_budget, rt.prefill_chunk)
+    chip = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=chip), tree)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    params = on_chip(jax.eval_shape(lambda: init_params_by_leaf(
+        cfg, jax.random.PRNGKey(0), quant=sv.get("quant", "none"))))
+    cache = jax.eval_shape(lambda: init_paged_cache(cfg, rt))
+    window = on_chip(jax.eval_shape(
+        lambda: init_kv_window(cache, rt.inflight_blocks * k * C)))
+    i32 = jnp.int32
+    args = [params, sds((S,), i32), sds((S,), i32), on_chip(cache), window,
+            sds((S,), i32), sds((S, rt.max_seq_len), i32), sds((S,), i32),
+            sds((S,), bool), sds((S,), jnp.float32), sds((S,), i32),
+            sds((S,), i32), 0, 1.0, sds((2,), jnp.uint32)]
+    donated = (2, 3, 4, 5)
+    if cfg.has_ssm:
+        from butterfly_tpu.cache.ssm_state import init_ssm_state
+        args.append(on_chip(jax.eval_shape(lambda: init_ssm_state(cfg, S))))
+        donated += (15,)
+    real_backend, out = jax.default_backend, []
+    jax.default_backend = lambda: "tpu"     # kernels compile, not interpret
+    try:
+        for name, P in (("mixed", 1), ("decode", 0)):
+            rec = {"name": name, "rows": S + P * C, "ok": False}
+            t0 = time.perf_counter()
+            try:
+                compiled = jax.jit(
+                    partial(_packed_scan, cfg, paged_forward_packed, k, C, P,
+                            use_kernel=True),
+                    static_argnums=(12, 13),
+                    donate_argnums=donated).lower(*args).compile()
+            except Exception as e:
+                rec["error"] = f"{type(e).__name__}: {e}"[:1500]
+                out.append(rec)
+                continue
+            hlo, mem = compiled.as_text(), compiled.memory_analysis()
+            if hlo_dir:
+                Path(hlo_dir).mkdir(parents=True, exist_ok=True)
+                (Path(hlo_dir) / f"{name}.hlo.txt").write_text(hlo)
+            held = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes
+            n = getattr(cfg, "hc_mult", 0)      # 0 on an older checkout
+            streams = [sds((n, S + P * C, 1, cfg.hidden_size),
+                           jnp.dtype(cfg.dtype))] if n else []
+            rec.update(
+                compile_s=round(time.perf_counter() - t0, 1),
+                argument_gb=round(mem.argument_size_in_bytes / 1e9, 3),
+                temp_gb=round(mem.temp_size_in_bytes / 1e9, 3),
+                held_gib=round(held / 2 ** 30, 3),
+                mosaic_calls=sorted(set(re.findall(
+                    r"%(\w+?)[.\d]* = [^=]*custom-call\([^\n]*"
+                    + MOSAIC_CALL, hlo))),
+                window_moves=window_moves(hlo, jax.tree.leaves(window)),
+                stream_moves=window_moves(hlo, streams,
+                                          kinds=("copy", "transpose")))
+            rec["ok"] = held < 15.75 * 2 ** 30 and not rec["window_moves"] \
+                and not rec["stream_moves"] and bool(rec["mosaic_calls"])
+            out.append(rec)
+    finally:
+        jax.default_backend = real_backend
+    return out
 
 
 def run_ssm_step(name, small, want):
@@ -863,7 +975,23 @@ def main() -> int:
                     help="run only the cases whose name contains this "
                          "(e.g. '@' = the mesh cases, already proven alone)")
     ap.add_argument("--out", default=None, help="also write the JSON here")
+    ap.add_argument("--cell", default=None, metavar="CONFIG.json",
+                    help="compile that configuration's mixed and decode "
+                         "block for a DESCRIBED v5e and print what they "
+                         "hold and move (cell_blocks); needs no chip, and "
+                         "nothing else runs")
+    ap.add_argument("--hlo-dir", default=None,
+                    help="with --cell: write each block's compiled text here")
     args = ap.parse_args()
+    if args.cell:
+        recs = cell_blocks(json.loads(Path(args.cell).read_text()),
+                           args.hlo_dir)
+        line = json.dumps({"ok": all(r["ok"] for r in recs), "blocks": recs})
+        if args.out:
+            Path(args.out).parent.mkdir(parents=True, exist_ok=True)
+            Path(args.out).write_text(line + "\n")
+        print(line)
+        return 0 if all(r["ok"] for r in recs) else 1
 
     import jax
 

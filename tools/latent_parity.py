@@ -37,6 +37,23 @@ group's rows is what is held to it: in bfloat16 a near-tie among 256
 router scores flips an expert in some rows, while a wrong read moves
 every row. The largest reading of each group is reported beside it.
 
+For a model whose residual path is n streams (`hc_mult`, Xing4.0's
+family: tests/test_xing.py) the served logits do NOT hold the mixing's
+arithmetic: in bfloat16 it is as exact as the bfloat16 streams it
+mixes, and at the seeded leaves (alpha 0.01) the Sinkhorn has converged
+before its last round (PERF.md, PR 49: the same stream served with
+either read 0.0129 and 0.0123 beside the clean 0.0119). So the
+mixing's COEFFICIENTS are held themselves (`mixing`, part of `ok`): the
+program's `stream_read` as the chip compiles it, over MIX_ROWS rows of n
+streams at the published widths, against the reference's `mixing` in
+float32, H_res and H_post entry by entry, the largest difference over
+the rows. The leaves are layer 0's, with `alpha_res` raised to
+MIX_ALPHA_RES: each row's 4 x 4 logits then spread over +-10 and the
+rounds have NOT converged by the last, as a trained model's need not.
+Beside the clean reading, the mixing's arithmetic in bfloat16
+(`mix_bfloat16`) and one round fewer (`sinkhorn_19`): both must pass
+MIX_LIMIT, the clean reading stay under it.
+
 The tool reports chip evidence and refuses to run without a TPU; `--toy`
 (the CPU rehearsal of tests/test_joyai.py) says so in its output.
 """
@@ -66,6 +83,17 @@ DECODE = 84
 #: the position past which a chunk's last column is compared
 PAST = 4096
 FAULTS = ("clean", "pages_astray", "chunk_blind")
+#: the mixing's coefficients, for a model of n residual streams: the
+#: clean program and its two faults
+MIXING = ("clean", "mix_bfloat16", "sinkhorn_19")
+#: rows of n streams the coefficients are read over, and the alpha_res
+#: they are read at (the seeded .01 leaves every row the sublayer's b)
+MIX_ROWS, MIX_ALPHA_RES = 1024, 2.0
+#: largest difference of an entry of H_res or H_post / 2 from the
+#: reference's. Set between the chip's readings (PERF.md, PR 49): clean
+#: 1.58e-6, 19 rounds 4.42e-3, a bfloat16 mixing 1.09e-2: 190 times over
+#: the first, 15 under the second
+MIX_LIMIT = 3e-4
 
 
 @contextlib.contextmanager
@@ -94,14 +122,55 @@ def planted(fault: str, past: int):
         return real_attend(q, kp, layer, positions=positions, mask=mask,
                            **kw)
 
+    from butterfly_tpu.models import common
+    real_mix = common._MIX
     if fault == "pages_astray":
         paged.latent_paged_attend = pages_astray
     elif fault == "chunk_blind":
         paged.latent_paged_attend = chunk_blind
+    elif fault == "mix_bfloat16":
+        common._MIX = jnp.bfloat16
     try:
         yield
     finally:
         paged.latent_paged_attend = real_attend
+        common._MIX = real_mix
+
+
+def mixing_readings(cfg, params, config: dict, tokens) -> dict:
+    """{fault: largest difference of a mixing coefficient from the
+    reference's} for the program as it is and with each fault of MIXING
+    planted (the module's head says over what)."""
+    import jax
+    import jax.numpy as jnp
+    from butterfly_tpu.models import common
+    from servebench.refcheck import load_reference
+
+    n, R = cfg.hc_mult, min(MIX_ROWS, len(tokens) // cfg.hc_mult)
+    # n streams of R rows as the program carries them: cfg.dtype
+    x = jnp.take(params["embed"]["tok"], jnp.asarray(tokens[:n * R]), axis=0
+                 ).reshape(n, 1, R, -1).astype(cfg.dtype)
+    hc = {k: v[0] for k, v in params["layers"]["hc1"].items()}
+    hc["alpha"] = hc["alpha"].at[2].set(MIX_ALPHA_RES)
+    lp = {"ln1": jax.tree_util.tree_map(lambda v: v[0],
+                                        params["layers"]["ln1"]), "hc1": hc}
+    f32 = {k: jnp.asarray(v, jnp.float32) for k, v in hc.items()}
+    with jax.default_matmul_precision("highest"):
+        _, post, res = load_reference(config["reference"]).mixing(
+            jnp.moveaxis(x[:, 0], 0, 1).astype(jnp.float32), f32["phi"],
+            f32["b"], f32["alpha"], config)
+    want = jnp.concatenate([jnp.moveaxis(res, 0, -1).reshape(n * n, R),
+                            post.T / 2.0])
+    out = {}
+    for fault in MIXING:
+        c = cfg.replace(hc_sinkhorn_iters=cfg.hc_sinkhorn_iters - 1) \
+            if fault == "sinkhorn_19" else cfg
+        with planted(fault, 0):
+            _, (m, h_post) = jax.jit(
+                lambda x, lp, c=c: common.stream_read(x, lp, 1, c))(x, lp)
+        got = jnp.concatenate([m.reshape(n * n, R), h_post / 2.0])
+        out[fault] = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
+    return out
 
 
 def check(config: dict, toy: bool = False, stream: int = STREAM,
@@ -168,6 +237,13 @@ def check(config: dict, toy: bool = False, stream: int = STREAM,
         max(clean["chunks_median"], clean["decoded_median"]) < LIMIT
         and astray["chunks_median"] < LIMIT < astray["decoded_median"]
         and min(blind["chunks_median"], blind["decoded_median"]) > LIMIT)
+    if cfg.hc_mult:
+        mix = out["mixing"] = dict(
+            mixing_readings(cfg, params, config, tokens), limit=MIX_LIMIT,
+            rows=min(MIX_ROWS, stream // cfg.hc_mult),
+            alpha_res=MIX_ALPHA_RES)
+        out["ok"] = bool(out["ok"] and mix["clean"] < MIX_LIMIT < min(
+            mix["mix_bfloat16"], mix["sinkhorn_19"]))
     return out
 
 
