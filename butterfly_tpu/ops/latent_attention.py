@@ -12,22 +12,38 @@ as keys and again as values, would fetch it twice):
 * the WHOLE pool [L, P, 1, page, R] stays where it lies in HBM
   (memory space ANY); the layer, the block table and the lengths ride
   the scalar prefetch;
-* grid (slots): a slot loops over ITS OWN context in chunks of
-  PAGES_PER_CHUNK pages, a dynamic trip count, so a short stream costs
-  a short loop and nobody visits the tail of a table sized for
-  max_seq. A chunk is PAGES_PER_CHUNK page copies (one DMA a page: the
-  pages of a stream lie anywhere) into one VMEM buffer [chunk, R], and
-  the next chunk's copies are in flight while this one is multiplied
-  (ops/paged_attention.py walks so too since PR 46, its copies rolled;
-  its page chain, one page a grid step, ran at a seventh of the
-  memory bandwidth until then: PERF.md, PRs 41 and 46);
-* the chunk's scores are ONE product q [Nq, R] x rows^T and its sum ONE
+* grid (slots): a slot loops over ITS OWN live pages in chunks of
+  PAGES_PER_CHUNK pages, a dynamic trip count from its length, so a
+  short stream costs a short loop and nobody visits the tail of a table
+  sized for max_seq. A chunk is up to PAGES_PER_CHUNK page copies (one
+  DMA a page: the pages of a stream lie anywhere) into one of two VMEM
+  buffers [chunk, R], issued and awaited by a ROLLED loop over the
+  groups of GROUP_PAGES pages that the chunk's LIVE pages fill: a
+  group's starts side by side, its wait one; a group the stream has no
+  page in is not copied, and the lowered body is the same size whatever
+  the chunk (a serving program holds this body once for each of its
+  traced calls, and traces and lowers it at every start:
+  tests/test_joyai.py holds the jaxpr's size). The next chunk's copies
+  are in flight while this one is multiplied, and beside a slot's LAST
+  chunk the first chunk of the slot after it, so a short context does
+  not wait out a copy's latency slot after slot (ops/paged_attention.py
+  is the same walk over two pools with scales and head groups, since
+  PR 46; until PR 50 this kernel copied every chunk whole, its 32
+  copies unrolled, and awaited a slot's first chunk with nothing beside
+  it: PERF.md);
+* a page's copy is a dozen scalar operations, and on the chip they, not
+  the bytes, bound the call: the wrapper hands the kernel the pool's
+  layers end to end and the table flat, so an address is one sum and
+  nothing is clamped page by page;
+* a chunk's scores are ONE product q [Nq, R] x rows^T and its sum ONE
   product p [Nq, chunk] x rows[:, :rank], bfloat16 operands into
-  float32, the online softmax carried in float32 as the loop's values;
-* a table entry past a stream's pages names the null page, which holds
-  finite numbers like every page (the pool is born zero and only ever
-  written with projections): its columns are masked to probability 0,
-  so whole chunks are copied without a branch a page;
+  float32, the online softmax carried in float32 as the loop's values
+  (steps by the chunk's live pages read slower at every step size: a
+  step is a chain of 0.28 us whatever its width, PERF.md, PR 50);
+* the rows of a chunk that the stream does not have are masked to
+  probability 0, and what lies there in the buffer is numbers: the
+  buffers are cleared at slot 0 and hold copied pages ever after (the
+  pool is born zero and only ever written with projections);
 * the write-combined window [L, S, 1, W, R] (cache/paged.py: staged
   rows at positions lengths .. lengths + win_count - 1) comes whole, as
   it rides the layer scan; (layer, slot)'s block of it is one more
@@ -53,6 +69,9 @@ NEG_INF = -1e30
 #: pages one chunk of the context takes: 32 pages of 16 tokens are 512
 #: rows, 590 KB of bfloat16 a buffer, two buffers
 PAGES_PER_CHUNK = 32
+#: pages whose copies are started side by side and awaited as one: a
+#: chunk's copies are a rolled loop over the groups its live pages fill
+GROUP_PAGES = 8
 
 
 def fits(pages: jax.Array, rank: int) -> bool:
@@ -61,7 +80,7 @@ def fits(pages: jax.Array, rank: int) -> bool:
     values are whole lanes (Mosaic copies and slices whole tiles);
     interpreted (the CPU backend) any pool of rows will do. Any other
     pool takes the `jnp` read."""
-    if pages.shape[2] != 1:
+    if pages.shape[2] != 1 or pages.shape[1] < GROUP_PAGES:
         return False
     return resolve_interpret(None) or (
         pages.shape[3] % sublane_multiple(pages.dtype) == 0
@@ -89,54 +108,114 @@ def _update(q, rows, live, carry, rank: int, scale: float):
 
 
 def _latent_kernel(layer_ref, table_ref, len_ref, *rest, page: int,
-                   pages_per_chunk: int, rank: int, scale: float,
-                   window: int):
+                   pages_per_chunk: int, group_pages: int, max_pages: int,
+                   pool_pages: int, rank: int, scale: float, window: int):
+    """One grid step is one slot. The pool lies in HBM, its layers end
+    to end [L * pool_pages, page, R]; the table is flat, a slot's
+    `max_pages` entries after another's (and a group of page 0 behind
+    the last, where they are no whole groups). The slot's live pages are
+    copied `pages_per_chunk` at a time into one of two buffers
+    [n, page, R] by a ROLLED loop over groups of `group_pages` (the body
+    is the same size whatever the chunk) while the chunk before is
+    multiplied, one online-softmax step a chunk. window > 0: (layer,
+    slot)'s block [W, R] of the write-combined window is one more step,
+    its first win_count rows live."""
     if window:
-        wc_ref, q_ref, pool_ref, win_ref, o_ref, buf, sem = rest
-    else:
-        q_ref, pool_ref, o_ref, buf, sem = rest
+        wc_ref, *rest = rest
+    q_ref, pool_ref, *rest = rest
+    win_ref = None
+    if window:
+        win_ref, *rest = rest
+    o_ref, buf, sem, par = rest
     slot = pl.program_id(0)
-    length = len_ref[slot]
-    layer = layer_ref[0]
-    max_pages = table_ref.shape[1]
-    chunk = pages_per_chunk * page
-    nchunks = (length + chunk - 1) // chunk
+    n, grp = pages_per_chunk, group_pages
+    layer_base = layer_ref[0] * pool_pages
     q = q_ref[0]                                           # [Nq, R]
-    Nq = q.shape[0]
+    Nq, R = q.shape
 
-    def copies(b, c):
-        """The page copies of chunk c into buffer b."""
-        return [pltpu.make_async_copy(
-            pool_ref.at[layer, table_ref[slot, jnp.minimum(
-                c * pages_per_chunk + i, max_pages - 1)], 0],
-            buf.at[b, pl.ds(i * page, page)], sem.at[b])
-            for i in range(pages_per_chunk)]
+    @pl.when(slot == 0)
+    def _clear():
+        # a step multiplies the whole buffer though fewer pages were
+        # copied: what lies behind them is masked, and must be numbers
+        buf[...] = jnp.zeros_like(buf)
 
-    @pl.when(nchunks > 0)
+    def live_pages(s):
+        return jnp.minimum((len_ref[s] + page - 1) // page, max_pages)
+
+    def copies(s, c, b, go):
+        """Start (go) or await the page copies of slot s's chunk c into
+        buffer b, a group of `grp` pages at a time as far as the chunk
+        has live pages: a group's starts side by side, its wait ONE (a
+        wait counts bytes, a group's; its descriptor's source is never
+        read). The last group's entries past the stream's pages name the
+        null page, or any page: rows that are masked."""
+        first = s * max_pages + c * n       # in the flat table
+        live = jnp.minimum(n, live_pages(s) - c * n)
+
+        def group(g, _):
+            at = pl.multiple_of(g * grp, grp)
+            if not go:
+                pltpu.make_async_copy(pool_ref.at[pl.ds(0, grp)],
+                                      buf.at[b, pl.ds(at, grp)],
+                                      sem.at[b]).wait()
+                return 0
+            for i in range(grp):
+                pltpu.make_async_copy(
+                    pool_ref.at[layer_base + table_ref[first + at + i]],
+                    buf.at[b, at + i], sem.at[b]).start()
+            return 0
+        jax.lax.fori_loop(0, (live + grp - 1) // grp, group, 0)
+
+    length, npages = len_ref[slot], live_pages(slot)
+    nchunks = (npages + n - 1) // n
+    # A slot's first chunk is on its way before its grid step begins:
+    # the slot before starts it beside its own last chunk, so a short
+    # context does not wait out a copy's latency slot after slot. Slot 0
+    # starts its own, here; a slot with no pages passes the start on to
+    # the slot after it. `par` says which buffer it went to.
+    after = jnp.minimum(slot + 1, pl.num_programs(0) - 1)
+    more = slot + 1 < pl.num_programs(0)
+
+    @pl.when(slot == 0)
     def _first():
-        for dma in copies(0, 0):
-            dma.start()
+        par[0] = 0
 
-    def body(c, carry):
-        b = c % 2
+    b0 = par[0]
+    par[0] = (b0 + nchunks) % 2
 
-        @pl.when(c + 1 < nchunks)
+    @pl.when((slot == 0) | ((nchunks == 0) & more))
+    def _start():
+        copies(jnp.where(nchunks > 0, slot, after), 0, b0, True)
+
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, n * page), 1)
+
+    def chunk(c, carry):
+        b = (b0 + c) % 2
+        last = c + 1 == nchunks
+
+        @pl.when(jnp.logical_not(last) | more)
         def _next():
-            for dma in copies(1 - b, c + 1):
-                dma.start()
+            copies(jnp.where(last, after, slot), jnp.where(last, 0, c + 1),
+                   1 - b, True)
 
-        for dma in copies(b, c):
-            dma.wait()
-        pos = c * chunk + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
-        return _update(q, buf[b], pos < length, carry, rank, scale)
+        copies(slot, c, b, False)
+
+        # ONE product over the whole chunk, the rows past the stream's
+        # end masked: a step is a chain (scores, maximum, exponential,
+        # sums, a second product) of 0.28 us on the chip whatever its
+        # width, so steps by the live pages read slower at every size
+        # (PERF.md, PR 50). [n, page, R] collapses to rows as whole tiles.
+        pos = c * n * page + col
+        return _update(q, buf[b].reshape(n * page, R), pos < length, carry,
+                       rank, scale)
 
     carry = (jnp.full((Nq, 1), -jnp.inf, jnp.float32),
              jnp.zeros((Nq, 1), jnp.float32),
              jnp.zeros((Nq, rank), jnp.float32))
-    carry = jax.lax.fori_loop(0, nchunks, body, carry)
+    carry = jax.lax.fori_loop(0, nchunks, chunk, carry)
     if window:
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, window), 1)
-        carry = _update(q, win_ref[0, 0], col < wc_ref[slot], carry, rank,
+        wcol = jax.lax.broadcasted_iota(jnp.int32, (1, window), 1)
+        carry = _update(q, win_ref[0, 0], wcol < wc_ref[slot], carry, rank,
                         scale)
     _, l, acc = carry
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
@@ -171,20 +250,28 @@ def latent_attention(q: jax.Array, pages: jax.Array, layer,
     (win_count INCLUDES the just-staged current token; `lengths` is then
     the FLUSHED length alone), as ops/paged_attention.py takes them."""
     S, Nq, R = q.shape
-    page = pages.shape[3]
+    L, P, _, page, _ = pages.shape
     window = 0 if win is None else win.shape[3]
     interpret = resolve_interpret(interpret)
     note_kernel("latent" + ("_win" if window else ""), interpret)
-    chunk = PAGES_PER_CHUNK * page
+    # A page's copy is a dozen scalar operations on the chip, and they
+    # bound the call (PERF.md, PR 50): the pool's layers end to end and
+    # the table flat make an address one sum, and nothing is clamped page
+    # by page: a slot's last group reads past its entries only where
+    # they are no whole groups, and there the table gets page 0 behind it.
+    pool = pages.reshape(L * P, page, R)
+    table = page_table.reshape(-1)
+    group = min(PAGES_PER_CHUNK, GROUP_PAGES)
+    if page_table.shape[1] % group:
+        table = jnp.pad(table, (0, group))
 
     def slot_map(s, *_):
         return (s, 0, 0)
 
     in_specs = [pl.BlockSpec((1, Nq, R), slot_map),
                 pl.BlockSpec(memory_space=pl.ANY)]
-    args = [q, pages]
-    prefetch = [jnp.asarray(layer, jnp.int32).reshape(1), page_table,
-                lengths]
+    args = [q, pool]
+    prefetch = [jnp.asarray(layer, jnp.int32).reshape(1), table, lengths]
     if window:
         in_specs.append(pl.BlockSpec(
             (None, 1, 1, window, R),
@@ -194,14 +281,17 @@ def latent_attention(q: jax.Array, pages: jax.Array, layer,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch), grid=(S,), in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Nq, rank), slot_map),
-        scratch_shapes=[pltpu.VMEM((2, chunk, R), pages.dtype),
-                        pltpu.SemaphoreType.DMA((2,))])
+        scratch_shapes=[pltpu.VMEM((2, PAGES_PER_CHUNK, page, R), pages.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((1,), jnp.int32)])
     kernel = functools.partial(
         _latent_kernel, page=page, pages_per_chunk=PAGES_PER_CHUNK,
+        group_pages=group, max_pages=page_table.shape[1], pool_pages=P,
         rank=rank, scale=scale, window=window)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, Nq, rank), q.dtype),
+        # the buffers are cleared at slot 0 and reused slot after slot
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,

@@ -31,7 +31,7 @@ slot's block table and touches ONLY its live pages:
   block copy, one grid step's bookkeeping and two small products for
   every 16-token page, which ran at a seventh of the memory's rate
   (PERF.md, PRs 40-41); ops/latent_attention.py walks its pages the
-  same way, its copies unrolled.
+  same way, its copies rolled by groups of eight since PR 50.
 * a chunk is multiplied SUB_PAGES pages an online-softmax step (float32
   scores, maxima and sums carried as the loop's values), a page ONE
   product of all Nq heads against its flattened [Kv*page, H] rows with

@@ -337,63 +337,159 @@ def test_packed_steps_chunks_filler_decode_rows_and_a_reused_slot(
     assert 0 < loads[:, 0].max() <= CFG.num_experts
 
 
-def test_the_kernel_read_is_the_jnp_read_through_the_packed_run(
-        params, tokens, want):
+@pytest.fixture
+def chunk_of(monkeypatch):
+    """Set ops/latent_attention.py's chunk to so many pages for one
+    test: the traced programs that hold the module's own value are
+    dropped before and after (a jitted call is keyed by its arguments,
+    not by a constant of the module)."""
+    import butterfly_tpu.ops.latent_attention as la
+
+    def drop():
+        la.latent_attention.__wrapped__.clear_cache()
+        _packed_step.clear_cache()
+
+    def set_to(pages):
+        drop()
+        monkeypatch.setattr(la, "PAGES_PER_CHUNK", pages)
+    yield set_to
+    drop()
+
+
+def kernel_read_through_the_packed_run(params, tokens, want, cfg, pages,
+                                       chunk_of):
     """Kernels on (interpreted on the CPU): the decode rows' read is the
     Pallas call over pages and the window, and every row is still the
-    reference's."""
+    reference's; at a chunk of 2 pages of 4 rows a stream of 30 walks
+    four chunks, the next slot's first among them."""
     from butterfly_tpu.ops import record_kernels
+    chunk_of(pages)
     log = {}
     with record_kernels(log):
-        out, _, _ = scripted_run(params, tokens, use_kernel=True)
+        out, _, _ = scripted_run(params, tokens, cfg=cfg, use_kernel=True)
     assert log.get("latent_win:interpret") and "dense_fallback" not in log
     for s, pos, row in out:
         assert err(row, want[s, pos]) < TOL, (s, pos)
 
 
-def test_the_kernel_alone_against_jnp_pages_window_and_dead_slots():
-    """ops/latent_attention.py in interpret mode: contexts that end
-    inside a page, inside a chunk and past one, a dead slot, with and
-    without the window, the first, the middle and the last layer; the
-    pool and the window WHOLE and the layer's index, to the bit what the
-    layer's slices alone give as a pool and a window of one."""
+@pytest.mark.parametrize("pages", [32, 2], ids=["one_chunk", "chunks_of_2"])
+def test_the_kernel_read_is_the_jnp_read_through_the_packed_run(
+        params, tokens, want, pages, chunk_of):
+    kernel_read_through_the_packed_run(params, tokens, want, CFG, pages,
+                                       chunk_of)
+
+
+#: the kernel alone: name -> (pages a chunk, pages a slot's table holds,
+#: each slot's flushed rows, each slot's staged rows). Pages of 16: at
+#: the module's own chunk of 32 a length of 511, 512 and 513 ends a row
+#: short of a chunk, on it and a row past it; at a chunk of 4 a stream
+#: of 144 rows is two chunks and a page, and its neighbour's first chunk
+#: is started beside the page.
+KERNEL_ALONE = {
+    "length_0": (32, 40, [0, 37, 0], [2, 8, 0]),
+    "length_5": (32, 40, [5, 37, 5], [2, 8, 0]),
+    "length_511": (32, 40, [511, 37, 511], [2, 8, 0]),
+    "length_512": (32, 40, [512, 37, 512], [2, 8, 0]),
+    "length_513": (32, 40, [513, 37, 513], [2, 8, 0]),
+    "a_page_short_of_the_table": (32, 40, [624, 37, 624], [2, 8, 0]),
+    "the_whole_table": (32, 40, [640, 37, 640], [2, 8, 0]),
+    "dead_slot_first": (4, 9, [0, 37, 144, 64], [0, 3, 8, 1]),
+    "dead_slot_last": (4, 9, [37, 144, 64, 0], [3, 8, 1, 0]),
+    "three_dead_between_live": (4, 9, [144, 0, 0, 0, 37, 130],
+                                [1, 0, 0, 0, 8, 3]),
+    "every_slot_dead": (4, 9, [0, 0, 0], [0, 0, 0]),
+    "window_rows_and_no_flushed_rows": (4, 9, [0, 0, 37, 0], [3, 8, 1, 5]),
+    "contexts_ten_times_apart": (4, 88, [14, 1400, 140, 1399],
+                                 [1, 2, 3, 4]),
+    "a_chunk_of_one_page": (1, 9, [16, 0, 33, 144], [1, 1, 0, 8]),
+}
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["pool", "window"])
+@pytest.mark.parametrize("case", list(KERNEL_ALONE))
+def test_the_kernel_alone_against_jnp_pages_window_and_dead_slots(
+        case, windowed, chunk_of):
+    """ops/latent_attention.py in interpret mode (KERNEL_ALONE), with
+    and without the window, the first, the middle and the last layer;
+    the pool and the window WHOLE and the layer's index, to the bit what
+    the layer's slices alone give as a pool and a window of one. A slot
+    with nothing to attend reads zeros."""
     import butterfly_tpu.ops.latent_attention as la
-    L, P, page, R, rank, Nq, S, mp, W = 3, 40, 16, 256, 128, 4, 4, 9, 8
+    pages, mp, lens, wc = KERNEL_ALONE[case]
+    L, page, R, rank, Nq, S, W = 3, 16, 256, 128, 4, len(lens), 8
+    P = S * mp + 1
     rs = np.random.RandomState(0)
     pool = jnp.asarray(rs.randn(L, P, 1, page, R), jnp.float32)
     q = jnp.asarray(rs.randn(S, Nq, R), jnp.float32)
-    table = jnp.asarray(rs.permutation(P - 1)[:S * mp].reshape(S, mp),
-                        jnp.int32)
-    lens = jnp.asarray([0, 37, 144, 64], jnp.int32)
+    table = jnp.asarray(rs.permutation(P - 1).reshape(S, mp), jnp.int32)
+    lens, wc = jnp.asarray(lens, jnp.int32), jnp.asarray(wc, jnp.int32)
     win = jnp.asarray(rs.randn(L, S, 1, W, R), jnp.float32)
-    wc = jnp.asarray([0, 3, 8, 1], jnp.int32)
     assert la.fits(pool, rank) and not la.fits(pool[:, :, :0], rank)
-    pages = la.PAGES_PER_CHUNK
-    la.PAGES_PER_CHUNK = 4          # chunks of 64: two and a bit of 144
-    try:
-        for layer, windowed in ((0, False), (0, True), (1, True), (2, True)):
-            got = la.latent_attention(
-                q, pool, layer, table, lens, *((win, wc) if windowed else ()),
-                rank=rank, scale=0.1)
-            alone = la.latent_attention(
-                q, pool[layer][None], 0, table, lens,
-                *((win[layer][None], wc) if windowed else ()),
-                rank=rank, scale=0.1)
-            np.testing.assert_array_equal(np.asarray(got), np.asarray(alone))
-            rows = pool[layer][table][:, :, 0].reshape(S, mp * page, R)
-            live = jnp.arange(mp * page)[None] < lens[:, None]
-            if windowed:
-                rows = jnp.concatenate([rows, win[layer, :, 0]], 1)
-                live = jnp.concatenate(
-                    [live, jnp.arange(W)[None] < wc[:, None]], 1)
-            s = jnp.einsum("snr,scr->snc", q, rows) * 0.1
-            p = jax.nn.softmax(jnp.where(live[:, None], s, -1e30), -1) \
-                * live[:, None]
-            want = jnp.einsum("snc,scr->snr", p, rows[..., :rank])
-            assert float(jnp.max(jnp.abs(got - want))) < 1e-5
-            assert not np.asarray(got[0]).any()     # nothing to attend
-    finally:
-        la.PAGES_PER_CHUNK = pages
+    chunk_of(pages)
+    for layer in range(L):
+        got = la.latent_attention(
+            q, pool, layer, table, lens, *((win, wc) if windowed else ()),
+            rank=rank, scale=0.1)
+        alone = la.latent_attention(
+            q, pool[layer][None], 0, table, lens,
+            *((win[layer][None], wc) if windowed else ()),
+            rank=rank, scale=0.1)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(alone))
+        rows = pool[layer][table][:, :, 0].reshape(S, mp * page, R)
+        live = jnp.arange(mp * page)[None] < lens[:, None]
+        if windowed:
+            rows = jnp.concatenate([rows, win[layer, :, 0]], 1)
+            live = jnp.concatenate(
+                [live, jnp.arange(W)[None] < wc[:, None]], 1)
+        s = jnp.einsum("snr,scr->snc", q, rows) * 0.1
+        p = jax.nn.softmax(jnp.where(live[:, None], s, -1e30), -1) \
+            * live[:, None]
+        want = jnp.einsum("snc,scr->snr", p, rows[..., :rank])
+        assert float(jnp.max(jnp.abs(got - want))) < 1e-5
+        dead = ~np.asarray(live).any(1)
+        assert not np.asarray(got)[dead].any()      # nothing to attend
+        assert np.asarray(got)[~dead].any(-1).all()
+
+
+def _kernel_body(pages_per_chunk, monkeypatch):
+    """The kernel body's jaxpr at a chunk of so many pages (the cells'
+    32 heads and rows of 640 lanes, the window on)."""
+    import butterfly_tpu.ops.latent_attention as la
+    monkeypatch.setattr(la, "PAGES_PER_CHUNK", pages_per_chunk)
+    S, Nq, R, page, P, W = 2, 32, 640, 16, 9, 8
+    bf = jnp.bfloat16
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    jaxpr = jax.make_jaxpr(
+        lambda *a: la.latent_attention.__wrapped__.__wrapped__(
+            *a, rank=512, scale=0.1, interpret=True))(
+        sds((S, Nq, R), bf), sds((1, P, 1, page, R), bf),
+        sds((), jnp.int32), sds((S, 64), jnp.int32), sds((S,), jnp.int32),
+        sds((1, S, 1, W, R), bf), sds((S,), jnp.int32))
+    call, = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["grid_mapping"].grid == (S,)
+    return call.params["jaxpr"]
+
+
+def test_the_kernel_body_does_not_grow_with_the_chunk(monkeypatch):
+    """Set-up by construction (ISSUE 50, as tests/test_kernels.py holds
+    the paged body since PR 46): a chunk's copies are issued and awaited
+    by a rolled loop over its live pages, so the body a serving program
+    traces and lowers for each of its calls at every start is the same
+    size at a chunk of 8 pages as at 32, and holds a group's starts
+    twice (slot 0's own first chunk or a dead slot's hand-on; the next
+    chunk's or the next slot's) and one wait, where the parent's held 64
+    and 32 in 532 equations."""
+    from butterfly_tpu.ops.latent_attention import GROUP_PAGES
+    from test_kernels import _eqns
+    bodies = {n: _kernel_body(n, monkeypatch) for n in (8, 32)}
+    sizes = {n: sum(1 for _ in _eqns(b)) for n, b in bodies.items()}
+    assert sizes[8] == sizes[32] < 450, sizes
+    text = str(bodies[32])
+    assert text.count("dma_start") == 2 * GROUP_PAGES < 64
+    assert text.count("dma_wait") == 1
 
 
 # -- the router ---------------------------------------------------------------
@@ -769,18 +865,23 @@ def one_chip():
     env.undo()
 
 
+@pytest.mark.parametrize("S, L, P, mp", [(96, 6, 49153, 512),
+                                         (128, 10, 18433, 144)],
+                         ids=["joyai48b.longthink", "xing29b.rollout"])
 def test_the_read_compiles_for_the_chip_at_the_published_geometry(
-        one_chip, monkeypatch):
-    """Mosaic takes the kernel at the cell's sizes (96 slots, 32 heads, a
-    pool of 49,153 pages of 16 rows of 640 lanes in six layers, a
-    window of 256) and the program holds no copy of the pool."""
+        one_chip, monkeypatch, S, L, P, mp):
+    """Mosaic takes the kernel at the cells' sizes (JoyAI's 96 slots over
+    a pool of 49,153 pages in six layers and a table of 512; Xing's 128
+    slots over 18,433 pages in ten and a table of 144; 32 heads, pages
+    of 16 rows of 640 lanes, a window of 256) and the program holds no
+    copy of the pool."""
     import butterfly_tpu.ops.latent_attention as la
     monkeypatch.setattr(la, "resolve_interpret", lambda i: False)
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    S, Nq, Rp, L, P, W = 96, 32, 640, 6, 49153, 256
+    Nq, Rp, W = 32, 640, 256
     bf = jnp.bfloat16
     fn = jax.jit(lambda q, pool, layer, t, n, w, wc:
                  la.latent_attention.__wrapped__(
@@ -789,7 +890,7 @@ def test_the_read_compiles_for_the_chip_at_the_published_geometry(
     try:
         compiled = fn.lower(
             sds((S, Nq, Rp), bf), sds((L, P, 1, 16, Rp), bf),
-            sds((), jnp.int32), sds((S, 512), jnp.int32),
+            sds((), jnp.int32), sds((S, mp), jnp.int32),
             sds((S,), jnp.int32), sds((L, S, 1, W, Rp), bf),
             sds((S,), jnp.int32)).compile()
     except Exception as e:  # the TPU library is one process's at a time

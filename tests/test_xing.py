@@ -24,7 +24,9 @@ from butterfly_tpu.quant.int8 import (
     init_params_by_leaf, is_quantized_leaf, quantize_int8)
 from servebench.references import xing_f32 as ref
 
-from test_joyai import err, leaf_of, scripted_run
+from test_joyai import (chunk_of, err,  # noqa: F401 (a fixture)
+                        kernel_read_through_the_packed_run, leaf_of,
+                        scripted_run)
 
 #: the toy at FOUR Sinkhorn rounds for the bulk of the file, and at the
 #: published twenty where the count is what is tested (CFG20): XLA's
@@ -251,15 +253,11 @@ def test_packed_steps_chunks_filler_decode_rows_and_a_reused_slot(
     assert 0 < loads[:, 0].max() <= CFG.num_experts
 
 
+@pytest.mark.parametrize("pages", [32, 2], ids=["one_chunk", "chunks_of_2"])
 def test_the_kernel_read_is_the_jnp_read_through_the_packed_run(
-        params, tokens, want):
-    from butterfly_tpu.ops import record_kernels
-    log = {}
-    with record_kernels(log):
-        out, _, _ = scripted_run(params, tokens, cfg=CFG, use_kernel=True)
-    assert log.get("latent_win:interpret") and "dense_fallback" not in log
-    for s, pos, row in out:
-        assert err(row, want[s, pos]) < TOL, (s, pos)
+        params, tokens, want, pages, chunk_of):
+    kernel_read_through_the_packed_run(params, tokens, want, CFG, pages,
+                                       chunk_of)
 
 
 # -- weights ------------------------------------------------------------------

@@ -25,8 +25,10 @@ its compiled HLO must hold no copy of the state. `latent`, the absorbed
 read of a latent-attention model's cached rows (ops/latent_attention.py),
 runs at JoyAI-LLM-Flash's geometry (96 slots, 32 heads, rows of 576
 values in 640 lanes, a pool of 6 GB in six layers, a window of 256)
-against `jnp` over the same pages, and is timed over one step's six
-layers beside the bytes the model's count gives the rows. `paged_cell_*`
+and as `latent_rollout` at `xing29b.rollout`'s (128 slots, ten layers, a
+table of 144 pages, contexts drawn 64-2,304 and flat at 1,290) against
+`jnp` over the same pages, and is timed over one step's layers beside
+the bytes the model's count gives the rows. `paged_cell_*`
 run ops/paged_attention.py at the cells' own geometries (PAGED_CELLS: 32
 slots at contexts of 170 over an int8 pool, over SmallThinker's 28 heads
 on 4 with the sliding scalar live and over a tensor=4 shard's 8 on 2; 128
@@ -567,16 +569,28 @@ def run_ssm_step(name, small, want):
     return rec
 
 
+#: the cells' own geometries for ops/latent_attention.py (32 heads
+#: against one cached row of 576 values in 640 lanes, 512 of them the
+#: values; page 16, a window of 256): name -> slots, layers, pages a
+#: slot, the contexts the timed step draws (low, high) and the context
+#: it then runs flat
+LATENT_CELLS = {
+    "latent": (96, 6, 512, (3100, 4200), 3100),
+    "latent_rollout": (128, 10, 144, (64, 2304), 1290),
+}
+
+
 def run_latent(name, small, want):
-    """ops/latent_attention.py at JoyAI-LLM-Flash's geometry, the cell's
-    own: 96 slots, 32 heads against one cached row of 576 values in 640
-    lanes, 512 of them the values, a pool of 49,153 pages of 16 rows in
-    six layers (6 GB), a window of 256, contexts from a few tokens to
-    8,192 that end inside a page, a dead slot. The kernel against `jnp`
-    over the same pages, then its time for the six layers of one step
-    at contexts of 3,100 (the window's opening) beside the bytes the
-    model's count gives the rows (servebench/latent_peaks.py): what
-    `latent_attn_roofline` will read in the cell."""
+    """ops/latent_attention.py at a cell's own geometry (LATENT_CELLS:
+    `joyai48b.longthink`'s 96 slots over a pool of 49,153 pages in six
+    layers, 6 GB; `xing29b.rollout`'s 128 slots of 144 pages in ten).
+    The kernel against `jnp` over the same pages at contexts from
+    nothing to the whole table that end inside a page, a dead slot
+    first; then its time for the layers of one step, at contexts drawn
+    over the cell's range and at one flat context, a call's microseconds
+    beside the bytes the model's count gives the rows
+    (servebench/latent_peaks.py): what `latent_attn_roofline` will read
+    in the cell."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -584,10 +598,10 @@ def run_latent(name, small, want):
     from butterfly_tpu.ops.latent_attention import latent_attention
 
     rec = {"name": name, "ok": False}
-    S, Nq, Rp, R, rank, L, page, W = 96, 32, 640, 576, 512, 6, 16, 256
-    mp = 512
+    Nq, Rp, R, rank, page, W = 32, 640, 576, 512, 16, 256
+    S, L, mp, drawn, flat = LATENT_CELLS[name]
     if small:
-        S, Nq, L, mp, W = 4, 4, 2, 8, 16
+        S, Nq, L, mp, W, drawn, flat = 4, 4, 2, 8, 16, (5, 100), 60
     P = S * mp + 1
     t0 = time.perf_counter()
     try:
@@ -639,23 +653,32 @@ def run_latent(name, small, want):
                      (1 + np.abs(np.asarray(want_out))))
         rec["max_err"] = round(float(err), 5)
         rec["dead_slot_zero"] = not np.asarray(out[0], np.float32).any()
-        # one step's six layers at the opening's contexts
-        ctx = jnp.full((S,), min(3100, mp * page - W), jnp.int32)
+        # one step's layers, at the cell's drawn contexts and at one
         step = jax.jit(lambda q, pool, t, n, w, c: sum(
             latent_attention(q, pool, ly, t, n, w, c, rank=rank,
                              scale=scale).astype(jnp.float32).sum()
             for ly in range(L)))
         one = jnp.ones((S,), jnp.int32)
-        jax.block_until_ready(step(q, pool, table, ctx, win, one))
-        t1 = time.perf_counter()
-        for _ in range(10):
-            r = step(q, pool, table, ctx, win, one)
-        jax.block_until_ready(r)
-        rec["step_ms"] = round((time.perf_counter() - t1) / 10 * 1e3, 3)
-        rows_bytes = float(S) * L * float(ctx[0]) * R * 2
-        rec["rows_gb"] = round(rows_bytes / 1e9, 3)
-        rec["share_of_819_gb_s"] = round(
-            100 * rows_bytes / 819e9 / (rec["step_ms"] / 1e3), 1)
+
+        def timed(ctx):
+            """(ms a step, the rows' GB, their share of 819 GB/s)"""
+            ctx = jnp.asarray(np.minimum(ctx, mp * page - W), jnp.int32)
+            jax.block_until_ready(step(q, pool, table, ctx, win, one))
+            t1 = time.perf_counter()
+            for _ in range(10):
+                r = step(q, pool, table, ctx, win, one)
+            jax.block_until_ready(r)
+            ms = (time.perf_counter() - t1) / 10 * 1e3
+            rows_bytes = float(L) * float(ctx.sum()) * R * 2
+            return (round(ms, 3), round(rows_bytes / 1e9, 3),
+                    round(100 * rows_bytes / 819e9 / (ms / 1e3), 1))
+
+        ms, _, share = timed(rs.randint(drawn[0], drawn[1] + 1, S))
+        rec["drawn_call_us"] = round(ms * 1e3 / L, 1)
+        rec["drawn_share_of_819_gb_s"] = share
+        rec["step_ms"], rec["rows_gb"], rec["share_of_819_gb_s"] = timed(
+            np.full(S, flat))
+        rec["flat_call_us"] = round(rec["step_ms"] * 1e3 / L, 1)
         rec["ok"] = bool(np.isfinite(err) and err < 3e-2
                          and rec["dead_slot_zero"]
                          and all(rec["hlo_has"].values()))
@@ -1013,8 +1036,8 @@ def main() -> int:
                for n, k, r, a, _ in cases if wanted(n)]
     if wanted("ssm_step"):
         results.append(run_ssm_step("ssm_step", args.small, want))
-    if wanted("latent"):
-        results.append(run_latent("latent", args.small, want))
+    results += [run_latent(n, args.small, want)
+                for n in LATENT_CELLS if wanted(n)]
     results += [run_paged_cell(n, args.small, want)
                 for n in PAGED_CELLS if wanted(n)]
     results += [run_stage_cell(n, args.small, want)
@@ -1042,6 +1065,10 @@ def main() -> int:
               + (f" step={r['step_ms']}ms rows={r['rows_gb']}GB "
                  f"({r['share_of_819_gb_s']}% of 819 GB/s)"
                  if "step_ms" in r else "")
+              + (f" call={r['flat_call_us']}us flat, "
+                 f"{r['drawn_call_us']}us drawn "
+                 f"({r['drawn_share_of_819_gb_s']}%)"
+                 if "flat_call_us" in r else "")
               + (f" call={r['call_us']}us rows={r['rows_mb']}MB "
                  f"({r['share_of_819_gb_s']}% of 819 GB/s) trace+lower="
                  f"{r['trace_lower_s']}s compile={r['compile_s']}s"
