@@ -90,7 +90,7 @@ from butterfly_tpu.models.common import (
     stream_write,
     select_topk, ssm_unsupported)
 from butterfly_tpu.ops import note_kernel
-from butterfly_tpu.ops import latent_attention
+from butterfly_tpu.ops import latent_attention, sparse_attention
 from butterfly_tpu.ops.flash_attention import flash_attention_sharded
 from butterfly_tpu.ops.paged_attention import paged_attention_sharded
 from butterfly_tpu.ops.window_stage import stage_window_sharded, window_step
@@ -742,8 +742,23 @@ def _pool_rows(pages: jax.Array, row: jax.Array) -> jax.Array:
     return got.reshape(*row.shape, R)
 
 
+#: the masked read (ops/sparse_attention.py) serves a table of up to
+#: this many times index_topk positions; a longer one takes the gather.
+#: The masked read moves every LIVE row, the gather the SELECTED ones.
+#: On a v5e at Keye's geometry (`tools/chip_kernels.py --only
+#: sparse_cell`, PERF.md PR 51) the walk costs 2.9-3.2 ns a live row
+#: (671 us for 32 slots of 7,168) and the gather's side of the branch
+#: (two gathers, the sort's payload, the window's lookups, the product
+#: over what was gathered) 27 ns a selected row: at live = 3.5 x topk
+#: the masked branch still reads 1.10 ms under the gather's, and the two
+#: would meet near live = 9 x topk, where no cell or configuration has a
+#: table. 4 is inside what was measured
+MASKED_READ_SPAN = 4
+
+
 def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
-                        page_table, positions, mask, win=None,
+                        page_table, positions, mask, active=None,
+                        use_kernel: bool = False, win=None,
                         select: str = "index"):
     """paged_attend for a model with a sparse-attention indexer: each
     query scores every live position of its stream against the cached
@@ -754,19 +769,31 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
     to read; mask [B,T,S_max] what each query MAY attend (causal,
     live); win as paged_attend's, AFTER staging: the whole window
     (leaves [L,S,1,W,Kv*H], the index keys' [L,S,1,W,Hi]), of which the
-    rows' slots' entries of `layer` are read (window_rows). Returns
-    (out [B,T,Nq,H], count f32 [3]): the rows that
-    had anything to attend, the positions they could attend and the
-    positions they read, summed over the rows.
+    rows' slots' entries of `layer` are read (window_rows). active [B]
+    (the kernel's read alone: a row that is not live reads nothing
+    there) and use_kernel as paged_attend's. Returns
+    (out [B,T,Nq,H], count f32 [4]): the rows that
+    had anything to attend, the positions they could attend, the
+    positions they ATTENDED and the rows of keys (and as many of values)
+    the read MOVED out of the pool and the window for them, summed over
+    the rows.
 
-    A decode row (T == 1) READS ONLY WHAT IT SELECTED: the index keys
-    of its context (128 B a position), then the selected rows of keys
-    and values out of the pool, a token a row, by the row's address
+    A decode row (T == 1) ATTENDS ONLY WHAT IT SELECTED, and there are
+    two ways to move those rows, told apart by the table's span against
+    index_topk, which the program's shapes show (MASKED_READ_SPAN): (1)
+    where kernels are on and the table is a few times index_topk, nearly
+    every live page holds a selected row: the Pallas kernel
+    (ops/sparse_attention.py) walks the slot's LIVE pages, a page a
+    copy, and the selection (select_mask) joins its length mask; (2) a
+    longer table, or kernels off: the selected rows of keys and values
+    out of the pool, a token a row, by the row's address
     (_row_addresses, _pool_rows), beside the window's few staged
-    entries, which are read whole and masked to the selection. A chunk's rows (T > 1) share one stream's prefix:
+    entries, which are read whole and masked to the selection. The same
+    sum either way. A chunk's rows (T > 1) share one stream's prefix:
     it is read once, whole, and masked row by row (select_mask), which
     is the same mathematics and cheaper than T gathers. So is any
-    program whose whole context is no longer than index_topk.
+    program without the kernel whose whole context is no longer than
+    index_topk.
 
     select (tools/sparse_parity.py's controls; "index" everywhere
     else): "all" attends every position, "recent" the last index_topk
@@ -793,7 +820,22 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
         scores = jnp.broadcast_to(jnp.arange(S_max, dtype=scores.dtype),
                                   scores.shape)
     live = jnp.sum(mask, axis=-1)                          # [B,T]
-    if T > 1 or S_max <= topk:
+    if T == 1 and use_kernel and S_max <= MASKED_READ_SPAN * cfg.index_topk \
+            and (win is None or slots is None) and sparse_attention.fits(
+                kp, q.shape[-1], 0 if win is None else window.width):
+        sel = select_mask(scores[:, 0], mask[:, 0], topk)  # [B,S_max]
+        # pool rows up to the FLUSHED length and the window's staged run
+        # with the token just staged (the window whole: the kernel reads
+        # the layer's blocks of it, under the selection at the staged
+        # rows' positions), or the pool alone with the token just written
+        lens = (jnp.where(active, base, 0), sel, window.k, window.v,
+                jnp.where(active, win_len + 1, 0)) if win is not None \
+            else (jnp.where(active, positions[:, 0] + 1, 0), sel)
+        out = sparse_attention.sparse_attention(
+            q[:, 0], kp, vp, layer, page_table, *lens)[:, None]
+        read = jnp.sum(sel, axis=-1)[:, None]
+        moved = live
+    elif T > 1 or S_max <= topk:
         sel = select_mask(scores, mask, topk)
         with jax.named_scope("attn_sparse"):
             ck = gather_paged_layer(kp, page_table, layer)
@@ -804,6 +846,7 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
             out = attend_token_rows(
                 q, *_settled(ck[:, :, 0], cv[:, :, 0]), sel)
         read = jnp.sum(sel, axis=-1)
+        moved = S_max * (live > 0)      # the slot's whole view
     else:
         # a position's pool row rides the selection as the sort's
         # payload (a lookup of the K selected positions in the table
@@ -831,8 +874,9 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
                 vg = jnp.concatenate([vg, wv[:, 0]], axis=1)
                 ok = jnp.concatenate([ok, wsel], axis=1)
             out = attend_token_rows(q, kg, vg, ok[:, None])
-        read = jnp.sum(ok, axis=-1)[:, None]
-    count = jnp.stack([jnp.sum(live > 0), jnp.sum(live), jnp.sum(read)])
+        read = moved = jnp.sum(ok, axis=-1)[:, None]
+    count = jnp.stack([jnp.sum(live > 0), jnp.sum(live), jnp.sum(read),
+                       jnp.sum(moved)])
     return out, count.astype(jnp.float32)
 
 
@@ -929,7 +973,8 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
         qi, w, kip = index
         return sparse_paged_attend(
             q, qi, w, kp, vp, kip, layer, cfg=cfg, page_table=page_table,
-            positions=positions, mask=mask, win=win)
+            positions=positions, mask=mask, active=active,
+            use_kernel=use_kernel, win=win)
     T = q.shape[1]
     quant = ksp is not None
     start = positions[:, 0]
@@ -1346,7 +1391,7 @@ def packed_layer(x, lp, pools, window: Optional[KVWindow], rows: PackedRows,
     what the
     layer's routing asked of its experts for the step's real rows
     (_layer_close; None for a dense model). For a model with an indexer
-    `load` carries three values more: sparse_paged_attend's count of
+    `load` carries four values more: sparse_paged_attend's count of
     the step's DECODE rows; for a latent-attention model one value
     more, latent_paged_attend's count (and `load` is that alone in a
     leading dense layer, which routes nothing)."""
@@ -1562,10 +1607,11 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
     `load` f32 [3] is models.common.expert_load of the step's real
     rows, the mean over the layers (None for a dense model): distinct
     experts touched, rows of the fullest expert, mean rows an expert.
-    A model with an indexer adds sparse_paged_attend's three: decode
-    rows, the positions they could attend, the positions they read; a
-    latent-attention model one: the cached rows its decode rows read,
-    summed over the layers (latent_paged_attend).
+    A model with an indexer adds sparse_paged_attend's four: decode
+    rows, the positions they could attend, the positions they attended,
+    the rows the read moved; a latent-attention model one: the cached
+    rows its decode rows read, summed over the layers
+    (latent_paged_attend).
     (Under pipeline stages: parallel/pipeline.py paged_pipeline_packed,
     the same pieces over stage-local layers.)
 

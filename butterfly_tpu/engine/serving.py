@@ -1307,9 +1307,10 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
     asked of the experts (models.common.expert_load: distinct experts
     touched, rows of the fullest expert, mean rows an expert), the mean
     over its layers and the steps with a real row; None for a dense
-    model. A model with a sparse-attention indexer adds two: the
-    positions a live decode row could attend and the positions it
-    read, the mean over the block's layers, rows and steps.
+    model. A model with a sparse-attention indexer adds three: the
+    positions a live decode row could attend, the positions it
+    attended and the rows its read moved out of the cache, the mean
+    over the block's layers, rows and steps.
 
     state (a model with Mamba-2 layers; None for every other): the
     slots' recurrent state (cache/ssm_state.py). It is read and written
@@ -1385,8 +1386,9 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
         had = (load[:, 2] > 0).astype(load.dtype)
         experts = (load[:, :3] * had[:, None]).sum(axis=0) \
             / jnp.maximum(had.sum(), 1)
-        # a model with an indexer: what a live decode row could attend
-        # and what it read, the mean over the block's rows
+        # a model with an indexer: what a live decode row could attend,
+        # what it attended and what its read moved, the mean over the
+        # block's rows
         rows = load[:, 3:].sum(axis=0)
         load = jnp.concatenate([experts, rows[1:] / jnp.maximum(rows[0], 1)]) \
             if cfg.has_indexer else experts

@@ -109,9 +109,10 @@ class TickLog:
         drained (a model of experts; models.common.expert_load), as
         `experts_touched`, `expert_rows_max` and `expert_rows_mean`;
         None for a dense model or a tick that drained no block. A model
-        with a sparse-attention indexer gives two values more,
-        `kv_rows_live` and `kv_rows_selected`: the positions a live
-        decode row could attend and those it read (null otherwise).
+        with a sparse-attention indexer gives three values more,
+        `kv_rows_live`, `kv_rows_selected` and `kv_rows_moved`: the
+        positions a live decode row could attend, those it attended
+        and the rows its read moved out of the cache (null otherwise).
         `ssm_load` (a model with Mamba-2 layers; null otherwise):
         [rows, resets, steps] SUMMED over the mixed blocks the tick
         drained, as `ssm_rows` (positions their steps pushed through a
@@ -138,8 +139,8 @@ class TickLog:
         was in (`other`: the tick's own time; `outside_tick`: between
         two ticks). `gap_s`: this tick's start less the last tick's
         end. `profiled`: a /debug/profile capture was running."""
-        touched, rows_max, rows_mean, kv_live, kv_selected = \
-            (tuple(expert_load or ()) + (None,) * 5)[:5]
+        touched, rows_max, rows_mean, kv_live, kv_selected, kv_moved = \
+            (tuple(expert_load or ()) + (None,) * 6)[:6]
         ssm_rows, state_resets, ssm_steps = ssm_load or (None,) * 3
         latent_rows, latent_steps = latent_load or (None,) * 2
         hc_rows, hc_steps = hc_load or (None,) * 2
@@ -168,6 +169,7 @@ class TickLog:
             "expert_rows_mean": rows_mean,
             "kv_rows_live": kv_live,
             "kv_rows_selected": kv_selected,
+            "kv_rows_moved": kv_moved,
             "ssm_rows": ssm_rows,
             "state_resets": state_resets,
             "ssm_steps": ssm_steps,

@@ -730,9 +730,15 @@ class Scheduler:
             "model with a sparse-attention indexer; 0 without)")
         self._g_kv_rows_selected = reg.gauge(
             "kv_rows_selected",
-            "Cached positions a live decode row read, the index_topk its "
-            "indexer selected at most: the mean over the newest drained "
-            "block's layers, rows and steps")
+            "Cached positions a live decode row attended, the index_topk "
+            "its indexer selected at most: the mean over the newest "
+            "drained block's layers, rows and steps")
+        self._g_kv_rows_moved = reg.gauge(
+            "kv_rows_moved",
+            "Rows of keys (and as many of values) that a live decode "
+            "row's read moved out of the cache: its live rows where the "
+            "kernel walks its pages and masks the selection, the selected "
+            "rows where they are gathered; the same mean")
         # per-phase histograms in the registry: real _bucket series per
         # structural phase, so dashboards see distributions, not means
         self._h_phase = {
@@ -2431,6 +2437,7 @@ class Scheduler:
                 if len(load[0]) > 3:       # a model with an indexer
                     self._g_kv_rows_live.set(float(load[0][3]))
                     self._g_kv_rows_selected.set(float(load[0][4]))
+                    self._g_kv_rows_moved.set(float(load[0][5]))
             for slot, (req, gen) in snapshot.items():
                 if req.done or req.slot != slot or req.preemptions != gen:
                     continue

@@ -247,13 +247,14 @@ def test_packed_step_with_a_chunk_that_crosses_topk(params, tokens, want,
 
 
 def test_kv_rows_counted_by_hand(params, tokens):
-    """`load`'s last three: the step's live decode rows, the positions
-    they could attend and those they read. Sequence 0 decodes alone from
-    position 20: it could attend t + 1 positions and read topk."""
+    """`load`'s last four: the step's live decode rows, the positions
+    they could attend, those they attended and the rows the read moved
+    (on the gather's path the selected ones). Sequence 0 decodes alone
+    from position 20: it could attend t + 1 positions and read topk."""
     _, loads = packed_run(params, tokens)
     for i, load in enumerate(loads):
-        assert load.shape == (6,)
-        np.testing.assert_allclose(load[3:], [1, 20 + i + 1, TOPK])
+        assert load.shape == (7,)
+        np.testing.assert_allclose(load[3:], [1, 20 + i + 1, TOPK, TOPK])
     assert loads[0][0] > 0          # the experts' counts ride as they were
 
 
@@ -663,10 +664,13 @@ def test_served_tokens_are_the_reference_s_greedy_tokens(params):
     for t in ticks:
         assert t["kv_rows_selected"] == min(TOPK, t["kv_rows_selected"])
         assert t["kv_rows_live"] >= t["kv_rows_selected"] > 0
+        # kernels off: the selected rows are gathered, and no others move
+        assert t["kv_rows_moved"] == t["kv_rows_selected"]
         assert t["experts_touched"] is not None
     assert any(t["kv_rows_live"] > TOPK == t["kv_rows_selected"]
                for t in ticks)
     assert sched._g_kv_rows_selected.value == TOPK
+    assert sched._g_kv_rows_moved.value == TOPK
     assert sched._g_kv_rows_live.value > TOPK
 
 
@@ -696,6 +700,7 @@ def test_a_model_without_an_indexer_s_ticks_carry_no_kv_rows():
     ticks = sched.ticklog.dump()["ticks"]
     assert any(t["experts_touched"] is not None for t in ticks)
     assert all(t["kv_rows_live"] is None and t["kv_rows_selected"] is None
+               and t["kv_rows_moved"] is None
                for t in ticks)
 
 
@@ -750,6 +755,7 @@ def test_sparse_parity_tool_separates_its_controls_on_the_toy():
     import sparse_parity
     out = sparse_parity.check(toy_file(), toy=True, stream=60, decode=12)
     assert out["evidence"] == "cpu toy", out
+    assert out["decode_read"] == "gather"       # kernels off on the CPU
     assert out["rows_before"] >= 1 and out["rows_after"] >= 12
     assert out["clean"]["after_max"] < 1e-4 > out["clean"]["before_max"]
     for control in ("select_all", "select_recent"):

@@ -35,6 +35,10 @@ on 4 with the sliding scalar live and over a tensor=4 shard's 8 on 2; 128
 slots at lengths 64-2,304), a window of 256 with 1-8 staged, against
 `jnp` over the same pages, and print a call's time beside its rows'
 bytes and the seconds the call took to trace and lower and to compile.
+`sparse_cell` runs ops/sparse_attention.py at `keye30b.think`'s geometry
+(SPARSE_CELL) beside the gather of the selected rows it stands in for,
+both branches of cache/paged.py sparse_paged_attend on the same
+operands, and times each at the cell's contexts and at the table's ends.
 
 Last, the program `serve` spends its time in — the serving engine's
 fused decode block, at the full 8B width with the depth cut to two
@@ -830,6 +834,152 @@ def run_paged_cell(name, small, want):
     return rec
 
 
+#: `keye30b.think`'s geometry for ops/sparse_attention.py (32 queries
+#: over 4 KV heads of 128, token-major pages of 16 in eight layers, index
+#: keys of 64 under 16 index heads, a window of 256 of which a decode
+#: row has staged 1-4): slots, pages a slot (a table of 7,168), topk,
+#: and the contexts every slot then holds flat beside the drawn ones
+SPARSE_CELL = (32, 448, 2048, (512, 7168))
+
+
+def run_sparse_cell(name, small, want):
+    """A decode row's read of a model with an indexer at `keye30b.think`'s
+    geometry (SPARSE_CELL), both ways cache/paged.py sparse_paged_attend
+    moves the rows: the Pallas kernel over the slot's live pages with
+    the selection as a mask (ops/sparse_attention.py) and the gather of
+    the selected rows, on the same operands. Their outputs against each
+    other (a dead slot among them), then microseconds a call over one
+    step's layers: the kernel ALONE under a selection of topk of each
+    slot's live rows, and each branch whole (index scores, selection and
+    read), at contexts drawn as `think` draws them (a prompt log-uniform
+    over 1,024-4,096 and 0-3,072 answered) and at 512 and 7,168 in every
+    slot: the ends of the table, which say where the masked read stops
+    paying (MASKED_READ_SPAN: the kernel pays by the LIVE row, the
+    gather by the selected one)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from butterfly_tpu.cache.paged import KVWindow, sparse_paged_attend
+    from butterfly_tpu.core.config import tiny
+    from butterfly_tpu.ops.sparse_attention import sparse_attention
+
+    rec = {"name": name, "ok": False}
+    S, mp, topk, flats = SPARSE_CELL
+    L, Nq, Kv, H, page, Hi, Ni, W = 8, 32, 4, 128, 16, 64, 16, 256
+    if small:
+        S, mp, topk, flats, L, Nq, Kv, H, page, Hi, Ni, W = (
+            4, 48, 48, (30, 192), 2, 4, 2, 8, 4, 6, 2, 8)
+    P, S_max = S * mp + 1, mp * page
+    cfg = tiny("keye", num_heads=Nq, num_kv_heads=Kv, head_dim=H,
+               index_heads=Ni, index_head_dim=Hi, index_topk=topk)
+    try:
+        keys = jax.random.split(jax.random.PRNGKey(51), 9)
+        bf, f32 = jnp.bfloat16, jnp.float32
+
+        def rnd(k, shape, dt=bf):
+            return jax.jit(lambda k: jax.random.normal(k, shape, dt))(k)
+
+        kp, vp = (rnd(k, (L, P, 1, page, Kv * H)) for k in keys[:2])
+        kip = rnd(keys[2], (L, P, 1, page, Hi))
+        window = KVWindow(k=rnd(keys[3], (L, S, 1, W, Kv * H)),
+                          v=rnd(keys[4], (L, S, 1, W, Kv * H)),
+                          ki=rnd(keys[5], (L, S, 1, W, Hi)))
+        q = rnd(keys[6], (S, 1, Nq, H))
+        qi, w = rnd(keys[7], (S, 1, Ni, Hi), f32), rnd(keys[8], (S, 1, Ni),
+                                                       f32)
+        rs = np.random.RandomState(51)
+        table = jnp.asarray(rs.permutation(P - 1).reshape(S, mp) + 1,
+                            jnp.int32)
+        drawn = np.minimum(S_max, np.exp(rs.uniform(
+            np.log(S_max / 7), np.log(S_max * 4 / 7), S)).astype(int)
+            + rs.randint(0, S_max * 3 // 7 + 1, S))
+
+        def operands(ctx):
+            """A decode row a slot at position ctx - 1 (0: a dead slot),
+            1-4 rows staged before it."""
+            ctx = np.asarray(ctx)
+            pos = jnp.asarray(np.maximum(ctx - 1, 0), jnp.int32)
+            staged = jnp.asarray(np.minimum(rs.randint(1, 5, S),
+                                            np.maximum(ctx - 1, 0)), jnp.int32)
+            return pos, jnp.asarray(ctx > 0), staged
+
+        def branch(use_kernel, layers):
+            def run(q, qi, w, kp, vp, kip, table, window, pos, active,
+                    staged):
+                mask = active[:, None, None] & (
+                    jnp.arange(S_max)[None, None, :] <= pos[:, None, None])
+                return [sparse_paged_attend(
+                    q, qi, w, kp, vp, kip, ly, cfg=cfg, page_table=table,
+                    positions=pos[:, None], mask=mask, active=active,
+                    use_kernel=use_kernel, win=(window, staged, None))
+                    for ly in layers]
+            return run
+
+        fixed = (q, qi, w, kp, vp, kip, table, window)
+        # agreement, a dead slot and the table's two ends among the slots
+        ctx = drawn.copy()
+        ctx[0], ctx[1], ctx[2] = 0, S_max, min(topk // 4, S_max)
+        probe = operands(ctx)
+        masked = jax.jit(branch(True, [3 % L])).lower(*fixed, *probe).compile()
+        rec["hlo_has"] = {w_: w_ in masked.as_text() for w_ in want}
+        (got, got_n), = masked(*fixed, *probe)
+        (ref, ref_n), = jax.jit(branch(False, [3 % L]))(*fixed, *probe)
+        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+        rec["max_err"] = round(float(np.max(
+            np.abs(got[1:] - ref[1:]) / (1 + np.abs(ref[1:])))), 5)
+        rec["dead_slot_zero"] = not got[0].any()
+        # what was attended is what was attended; what moved is not
+        rec["count_masked"] = np.asarray(got_n).tolist()
+        rec["count_gather"] = np.asarray(ref_n).tolist()
+
+        def timed(fn, *args, n=10):
+            jax.block_until_ready(fn(*args))
+            t0 = time.perf_counter()
+            for _ in range(n):
+                r = fn(*args)
+            jax.block_until_ready(r)
+            return round((time.perf_counter() - t0) / n / L * 1e6, 1)
+
+        def step(use_kernel):
+            run = branch(use_kernel, range(L))
+            return jax.jit(lambda *a: sum(
+                o.astype(f32).sum() for o, _ in run(*a)))
+
+        alone = jax.jit(lambda q, kp, vp, table, lens, sel, wk, wv, wc: sum(
+            sparse_attention(q, kp, vp, ly, table, lens, sel, wk, wv,
+                             wc).astype(f32).sum() for ly in range(L)))
+        steps = {True: step(True), False: step(False)}
+        rec["contexts"] = {}
+        for label, ctx in [("drawn", drawn)] + [
+                (str(n), np.full(S, n)) for n in flats]:
+            pos, active, staged = operands(ctx)
+            live = jnp.arange(S_max)[None, :] <= pos[:, None]
+            keep = jax.random.uniform(keys[0], (S, S_max)) \
+                < topk / jnp.maximum(pos[:, None] + 1, topk)
+            rows = int(np.sum(ctx))
+            kernel_us = timed(alone, q[:, 0], kp, vp, table, pos - staged,
+                              live & keep, window.k, window.v, staged + 1)
+            rec["contexts"][label] = {
+                "live_rows": rows,
+                "selected_rows": int(np.minimum(ctx, topk).sum()),
+                "kernel_alone_us": kernel_us,
+                "kernel_ns_a_live_row": round(kernel_us * 1e3 / rows, 2),
+                "kernel_share_of_819_gb_s": round(
+                    100 * rows * 4 * Kv * H / 819e9 / (kernel_us / 1e6), 1),
+                "masked_branch_us": timed(steps[True], *fixed, pos, active,
+                                          staged),
+                "gather_branch_us": timed(steps[False], *fixed, pos, active,
+                                          staged)}
+        rec["ok"] = bool(np.isfinite(rec["max_err"]) and rec["max_err"] < 3e-2
+                         and rec["dead_slot_zero"]
+                         and rec["count_masked"][:3] == rec["count_gather"][:3]
+                         and all(rec["hlo_has"].values()))
+    except Exception as e:  # a compiler refusal is the finding: record it
+        rec["error"] = f"{type(e).__name__}: {e}"[:1500]
+    return rec
+
+
 #: the cells' windows for ops/window_stage.py (W 256, a chunk of 32
 #: columns beside the decode rows): name -> layers, slots, the row
 #: leaves' (heads, width) and dtype, int8 scale leaves too? (Keye's
@@ -1042,6 +1192,8 @@ def main() -> int:
                 for n in PAGED_CELLS if wanted(n)]
     results += [run_stage_cell(n, args.small, want)
                 for n in STAGE_CELLS if wanted(n)]
+    if wanted("sparse_cell"):
+        results.append(run_sparse_cell("sparse_cell", args.small, want))
     if wanted("serve_block"):
         results.append(run_serving_block("serve_block", args.small, None,
                                          want))
@@ -1074,6 +1226,13 @@ def main() -> int:
                  f"{r['trace_lower_s']}s compile={r['compile_s']}s"
                  if "call_us" in r else "")
               + (f" calls={r['kernel_calls']}" if "kernel_calls" in r else "")
+              + "".join(
+                  f"\n     {label}: kernel alone {c['kernel_alone_us']}us "
+                  f"({c['kernel_ns_a_live_row']} ns a live row, "
+                  f"{c['kernel_share_of_819_gb_s']}% of 819 GB/s), the "
+                  f"masked branch {c['masked_branch_us']}us, the gather "
+                  f"branch {c['gather_branch_us']}us"
+                  for label, c in r.get("contexts", {}).items())
               + (f"\n     {r['error']}" if "error" in r else ""))
     ok = mode == "compiled" and all(r["ok"] for r in results)
     if mode != "compiled":

@@ -9,9 +9,11 @@ indexer first leaves a position out. Here ONE stream of STREAM tokens goes
 through the packed mixed step the server runs (tools/window_parity.py's
 driver: chunks of C prompt tokens into the write-combined window, a flush
 every k steps as the scheduler drains, then decode rows, each of which
-reads only the rows it selected: cache/paged.py sparse_paged_attend), and
-its logits are compared with the configuration's reference computed in
-blocks of rows: every chunk's last column and every decode row, in two
+attends only the rows it selected: cache/paged.py sparse_paged_attend,
+by the masked read of its live pages where kernels are on, SLOTS x MAX_SEQ
+being a table the kernel serves, else by the gather; `decode_read` in the
+output says which), and its logits are compared with the
+configuration's reference computed in blocks of rows: every chunk's last column and every decode row, in two
 groups: BEFORE position topk (its last quarter: nothing is left out yet)
 and PAST 2 x topk - topk / 32 (4,032 at 2,048: half the context or more
 is left out). Logits, not tokens; the reading is the rms of the
@@ -58,14 +60,22 @@ DECODE = 84
 FAULTS = {"clean": "index", "select_all": "all", "select_recent": "recent"}
 
 
+#: what the steps' traces noted of their kernels (ops.note_kernel): which
+#: read served the decode rows of the newest check
+KERNELS: dict = {}
+
+
 @contextlib.contextmanager
 def planted(fault: str):
-    """The program's selection replaced while a step is traced."""
+    """The program's selection replaced while a step is traced, and the
+    trace's kernel notes kept (KERNELS)."""
     from butterfly_tpu.cache import paged
+    from butterfly_tpu.ops import record_kernels
     real = paged.sparse_paged_attend
     paged.sparse_paged_attend = partial(real, select=FAULTS[fault])
     try:
-        yield
+        with record_kernels(KERNELS):
+            yield
     finally:
         paged.sparse_paged_attend = real
 
@@ -103,8 +113,10 @@ def check(config: dict, toy: bool = False, stream: int = STREAM,
     tokens = np.random.default_rng(seed).integers(
         1, cfg.vocab_size, stream).astype(np.int32)
     n_prompt = (stream - decode) // C * C
+    KERNELS.clear()
     served = served_rows(cfg, params, rt, tokens, n_prompt,
                          faults={f: {} for f in FAULTS}, planted=planted)
+    kernels = dict(KERNELS)
     pos = np.asarray(served["clean"][0])
     before = (pos >= topk * 3 // 4) & (pos < topk)
     after = pos >= past
@@ -117,7 +129,10 @@ def check(config: dict, toy: bool = False, stream: int = STREAM,
            "limit": LIMIT, "stream": int(stream),
            "prompt": int(n_prompt), "chunk_width": C, "index_topk": topk,
            "past": past, "rows_before": int((~after).sum()),
-           "rows_after": int(after.sum())}
+           "rows_after": int(after.sum()), "kernels": kernels,
+           "decode_read": "masked (ops/sparse_attention.py)" if any(
+               k.startswith("sparse_attention") for k in kernels)
+           else "gather"}
     for fault, (_, got) in served.items():
         read = _reading(got[keep], want)
         out[fault] = {
