@@ -61,6 +61,11 @@ semantics, TPU-native mechanics):
   at a time, nothing to shard by head: int8 KV and every mesh refuse
   it by name. Writes, staging, the flush and the table gathers see one
   head of Rp, as they see a token-major pool.
+* A latent-attention model whose rows an indexer SELECTS (GLM-5: DSA
+  over MLA) holds that row AND the index-key pool [L, P, 1, page, Hi]
+  beside it, under the one table: two leaves in the pool, the window,
+  the stage and the flush, where every other model has one, three or
+  five.
 """
 from __future__ import annotations
 
@@ -756,6 +761,67 @@ def _pool_rows(pages: jax.Array, row: jax.Array) -> jax.Array:
 MASKED_READ_SPAN = 4
 
 
+def _index_selection(index, wki, base, page_table, layer, mask, topk: int,
+                     select: str, scatter: bool):
+    """The read side of a sparse-attention indexer, for keys and values
+    (sparse_paged_attend) and for latent rows (latent_paged_attend)
+    alike: index (qi [B,T,Ni,Hi], w [B,T,Ni], kip [L,P,1,page,Hi]) as
+    index_proj and the pool give them; wki [B,1,W,Hi] the rows' slots'
+    staged index keys of `layer` (window_rows) or None without a window,
+    base [B] the flushed pool length they start at; mask [B,T,S_max]
+    what each query MAY attend. Returns (scores [B,T,S_max], topk,
+    live [B,T]): every query's score of every position of its stream's
+    table (models.common.index_scores), how many of them it attends, and
+    how many it could.
+
+    scatter, the ONE difference between the two callers, and only in how
+    the staged keys reach their positions: False inserts the KEYS into
+    the table's view and scores the view (Keye's program since PR 36:
+    its 64-wide window keys' slice fuses into the insert); True scores
+    them where they lie and the SCORES take their positions, without a
+    write of the keys into the view (at 128 wide XLA cut the layer's
+    slice of the carried window leaf out in a fusion of its own, 2 MB a
+    layer, which tools/chip_kernels.py's window_moves refuses: PERF.md
+    section 7, PR 52). The same scores either way.
+
+    select (tools/sparse_parity.py's controls; "index" everywhere
+    else): "all" attends every position, "recent" the last topk in
+    place of the indexer's choice."""
+    qi, w, kip = index
+    S_max = mask.shape[-1]
+    with jax.named_scope("attn_index"):
+        # the stream's index keys as keys are viewed: one KV head
+        kiv = gather_paged_layer(kip, page_table, layer)   # [B,S_max,1,Hi]
+        if wki is not None and not scatter:
+            kiv = insert_window_view(kiv, wki, base)
+        scores = index_scores(qi, w, kiv[:, :, 0])         # [B,T,S_max]
+        if wki is not None and scatter:
+            B, T = qi.shape[:2]
+            at = base[:, None] + jnp.arange(wki.shape[2])[None, :]
+            scores = scores.at[
+                jnp.arange(B)[:, None, None], jnp.arange(T)[None, :, None],
+                at[:, None, :]].set(index_scores(qi, w, wki[:, 0]),
+                                    mode="drop")
+    if select == "all":
+        scores = jnp.zeros_like(scores)
+        topk = S_max
+    elif select == "recent":
+        scores = jnp.broadcast_to(jnp.arange(S_max, dtype=scores.dtype),
+                                  scores.shape)
+    return scores, topk, jnp.sum(mask, axis=-1)
+
+
+def _selection_count(live, read, moved):
+    """f32 [4], what a selecting read counts for the tick record
+    (kv_rows_live / _selected / _moved): the rows that had anything to
+    attend, the positions they could attend, the positions they
+    ATTENDED and the cached rows the read MOVED for them, summed over
+    the rows."""
+    count = jnp.stack([jnp.sum(live > 0), jnp.sum(live), jnp.sum(read),
+                       jnp.sum(moved)])
+    return count.astype(jnp.float32)
+
+
 def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
                         page_table, positions, mask, active=None,
                         use_kernel: bool = False, win=None,
@@ -795,31 +861,19 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
     program without the kernel whose whole context is no longer than
     index_topk.
 
-    select (tools/sparse_parity.py's controls; "index" everywhere
-    else): "all" attends every position, "recent" the last index_topk
-    in place of the indexer's choice."""
+    select: tools/sparse_parity.py's controls (_index_selection)."""
     B, T = q.shape[:2]
     page = kp.shape[3]
     S_max = page_table.shape[1] * page
-    topk = cfg.index_topk
+    wki = base = None
     if win is not None:
         window, win_len, slots = win
         wk, wv, wki = (window_rows(a, layer, slots)
                        for a in (window.k, window.v, window.ki))
         base = positions[:, 0] - win_len    # flushed pool length per row
-    with jax.named_scope("attn_index"):
-        # the stream's index keys as keys are viewed: one KV head
-        kiv = gather_paged_layer(kip, page_table, layer)   # [B,S_max,1,Hi]
-        if win is not None:
-            kiv = insert_window_view(kiv, wki, base)
-        scores = index_scores(qi, w, kiv[:, :, 0])         # [B,T,S_max]
-    if select == "all":
-        scores = jnp.zeros_like(scores)
-        topk = S_max
-    elif select == "recent":
-        scores = jnp.broadcast_to(jnp.arange(S_max, dtype=scores.dtype),
-                                  scores.shape)
-    live = jnp.sum(mask, axis=-1)                          # [B,T]
+    scores, topk, live = _index_selection(
+        (qi, w, kip), wki, base, page_table, layer, mask, cfg.index_topk,
+        select, scatter=False)
     if T == 1 and use_kernel and S_max <= MASKED_READ_SPAN * cfg.index_topk \
             and (win is None or slots is None) and sparse_attention.fits(
                 kp, q.shape[-1], 0 if win is None else window.width):
@@ -875,14 +929,12 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
                 ok = jnp.concatenate([ok, wsel], axis=1)
             out = attend_token_rows(q, kg, vg, ok[:, None])
         read = moved = jnp.sum(ok, axis=-1)[:, None]
-    count = jnp.stack([jnp.sum(live > 0), jnp.sum(live), jnp.sum(read),
-                       jnp.sum(moved)])
-    return out, count.astype(jnp.float32)
+    return out, _selection_count(live, read, moved)
 
 
 def latent_paged_attend(q, kp, layer, *, cfg: ModelConfig, page_table,
                         positions, mask, active, use_kernel: bool,
-                        win=None):
+                        win=None, index=None, select: str = "index"):
     """paged_attend for a latent-attention model, the ABSORBED read
     (models/common.py): q [B,T,Nq,Rp] latent_queries' of these rows, in
     the pool's lanes; kp [L,P,1,page,Rp] the WHOLE pool of rows (there
@@ -894,6 +946,19 @@ def latent_paged_attend(q, kp, layer, *, cfg: ModelConfig, page_table,
     among them; a chunk's rows count nothing), what the tick record's
     `latent_rows` sums over the layers.
 
+    index (a model with a sparse-attention indexer beside its latent
+    rows): (qi, w, kip) as sparse_paged_attend takes them, the window's
+    index keys in `win`. Every query scores its stream's live positions
+    (_index_selection) and `mask` narrows to the cfg.index_topk that
+    score highest (select_mask) before either read below: a decode row's
+    kernel walks the slot's LIVE pages and takes the selection as a
+    mask (ops/latent_attention.py latent_select_attention), a chunk's
+    rows and every row with kernels off attend the gathered view under
+    it. count is then sparse_paged_attend's f32 [4] (_selection_count;
+    MOVED: the live rows through the kernel, the slot's whole view
+    through the gather). select: the parity tool's controls
+    (_index_selection).
+
     A decode row (T == 1) reads its stream's live pages ONCE through
     the Pallas kernel (ops/latent_attention.py) where kernels are on;
     a chunk's rows (T > 1) share one stream's context, which is
@@ -902,11 +967,20 @@ def latent_paged_attend(q, kp, layer, *, cfg: ModelConfig, page_table,
     context exist: scores and sums are over the rows as cached."""
     T = q.shape[1]
     start = positions[:, 0]
+    base = None
     if win is not None:
         window, win_len, slots = win
         base = start - win_len      # flushed pool length per row
+    if index is not None:
+        wki = None if win is None else window_rows(window.ki, layer, slots)
+        scores, topk, live = _index_selection(
+            index, wki, base, page_table, layer, mask, cfg.index_topk,
+            select, scatter=True)
+        mask = select_mask(scores, mask, topk)
     out = None
-    if use_kernel and T == 1 and latent_attention.fits(kp, cfg.kv_lora_rank):
+    if use_kernel and T == 1 and latent_attention.fits(
+            kp, cfg.kv_lora_rank, index is not None,
+            0 if win is None else window.width):
         # pool rows up to the FLUSHED length and the window's staged run
         # with the token just staged (the window whole: the kernel reads
         # the layer's block of it), or the pool alone with the token
@@ -914,10 +988,16 @@ def latent_paged_attend(q, kp, layer, *, cfg: ModelConfig, page_table,
         lens = (jnp.where(active, base, 0), window.k,
                 jnp.where(active, win_len + 1, 0)) if win is not None \
             else (jnp.where(active, start + 1, 0),)
-        out = latent_attention.latent_attention(
-            q[:, 0], kp, layer, page_table, *lens, rank=cfg.kv_lora_rank,
-            scale=cfg.attn_scale)
+        if index is None:
+            out = latent_attention.latent_attention(
+                q[:, 0], kp, layer, page_table, *lens,
+                rank=cfg.kv_lora_rank, scale=cfg.attn_scale)
+        else:
+            out = latent_attention.latent_select_attention(
+                q[:, 0], kp, layer, page_table, lens[0], mask[:, 0],
+                *lens[1:], rank=cfg.kv_lora_rank, scale=cfg.attn_scale)
         out = out[:, None]
+        moved = None if index is None else live
     if out is None:
         if use_kernel and T == 1:
             note_kernel("dense_fallback")
@@ -926,6 +1006,9 @@ def latent_paged_attend(q, kp, layer, *, cfg: ModelConfig, page_table,
             rows = insert_window_view(
                 rows, window_rows(window.k, layer, slots), base)
         out = latent_attend(q, *_settled(rows[:, :, 0]), mask, cfg)
+        moved = None if index is None else mask.shape[-1] * (live > 0)
+    if index is not None:
+        return out, _selection_count(live, mask, moved)
     read = jnp.sum(jnp.where(active, start + 1, 0)) if T == 1 else 0
     return out, jnp.asarray(read, jnp.float32).reshape(1)
 
@@ -963,12 +1046,13 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
     Returns [B,T,Nq,H]; with `index`, that and sparse_paged_attend's
     count. A latent-attention model (q its absorbed queries, k its
     rows, no v and no vp) attends through latent_paged_attend and
-    nowhere else, and returns that function's pair."""
+    nowhere else, its `index` with it where it has an indexer, and
+    returns that function's pair."""
     if cfg.is_latent:
         return latent_paged_attend(
             q, kp, layer, cfg=cfg, page_table=page_table,
             positions=positions, mask=mask, active=active,
-            use_kernel=use_kernel, win=win)
+            use_kernel=use_kernel, win=win, index=index)
     if index is not None:
         qi, w, kip = index
         return sparse_paged_attend(
@@ -1095,11 +1179,15 @@ def _layer_open(x, lp, cfg: ModelConfig, cos, sin):
     if cfg.is_latent:
         # the absorbed queries and the row a token caches, each laid in
         # the pool's lanes (pool_row: zeros behind the values); no v
-        q_nope, q_rope, row = latent_proj(h, lp["attn"], cfg, cos, sin)
+        q_nope, q_rope, row, cq = latent_proj(h, lp["attn"], cfg, cos, sin)
         q = latent_queries(q_nope, q_rope, lp["attn"], cfg)
         pad = [(0, 0)] * 3 + [(0, pool_row(cfg)[1] - cfg.latent_row)]
+        # the indexer's queries read the query latent, its key and
+        # weights the normed input
+        index = index_proj(h, lp, cfg, cos, sin, cq) if cfg.has_indexer \
+            else None
         return (lp, jnp.pad(q, pad), jnp.pad(row[:, :, None], pad), None,
-                None, None, None, mix)
+                None, None, index, mix)
     rope, sliding_window = layer_pattern_of(lp.get("pattern"))
     q, k, v = qkv_proj(h, lp["attn"], cfg, cos, sin, rope)
     index = index_proj(x, lp, cfg, cos, sin) if cfg.has_indexer else None
@@ -1394,7 +1482,10 @@ def packed_layer(x, lp, pools, window: Optional[KVWindow], rows: PackedRows,
     `load` carries four values more: sparse_paged_attend's count of
     the step's DECODE rows; for a latent-attention model one value
     more, latent_paged_attend's count (and `load` is that alone in a
-    leading dense layer, which routes nothing)."""
+    leading dense layer, which routes nothing); a latent-attention
+    model with an indexer sparse_paged_attend's four in its place.
+    Under cfg.experts_held the share's two values (expert_load) stay
+    LAST, behind every count (before_share)."""
     S, (P, C) = rows.written.shape[0], rows.chunk_pos.shape
     lp, q, k, v, route, sliding_window, index, mix = _layer_open(
         x, lp, cfg, rows.cos, rows.sin)
@@ -1451,7 +1542,7 @@ def packed_layer(x, lp, pools, window: Optional[KVWindow], rows: PackedRows,
     if count is not None:
         if load is None and not cfg.is_latent:
             load = jnp.zeros((3,), jnp.float32)
-        load = count if load is None else jnp.concatenate([load, count])
+        load = count if load is None else before_share(load, count, cfg)
     return x, pools, window, load
 
 
@@ -1520,12 +1611,26 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
                              None if a[0] is None else jnp.concatenate(a)
                              for a in zip(*written)))
     if cfg.is_latent:
-        # a dense layer's load is its count alone, an expert layer's
-        # ends in it
-        experts = jnp.concatenate([l[:, :3] for l in loads
-                                   if l.shape[1] > 1]).mean(axis=0)
-        read = sum(l[:, -1].sum() for l in loads)
-        return x, kv, state, jnp.concatenate([experts, read[None]])
+        # a dense layer's load is its read's count alone (one value, an
+        # indexer's four); an expert layer's holds it behind the
+        # experts' three and before the share's two (before_share). The
+        # experts' and the share's are the mean over the layers that
+        # route; the count is the SUM over the layers, an indexer's four
+        # the mean, as every model with an indexer gives them
+        n = 4 if cfg.has_indexer else 1
+        routed = [l for l in loads if l.shape[1] > n]
+        parts = [jnp.concatenate([l[:, :3] for l in routed]).mean(axis=0)]
+        if cfg.has_indexer:
+            parts.append(jnp.concatenate(
+                [l if l.shape[1] == n else l[:, 3:3 + n]
+                 for l in loads]).mean(axis=0))
+        else:
+            parts.append(sum(l[:, -1 if l.shape[1] == n else 3].sum()
+                             for l in loads)[None])
+        if cfg.experts_held:
+            parts.append(jnp.concatenate(
+                [l[:, 3 + n:] for l in routed]).mean(axis=0))
+        return x, kv, state, jnp.concatenate(parts)
     return x, kv, state, jnp.concatenate(loads).mean(axis=0)
 
 
@@ -1558,6 +1663,17 @@ def window_leaves(window: KVWindow, staged=None, absent: bool = False):
     return _leaves(window, _WINDOW_LEAVES, staged, absent)
 
 
+def before_share(load, more, cfg: ModelConfig):
+    """A step's `load` with the values `more` at its end, or, under
+    cfg.experts_held, before its last two: the share's counts
+    (models.common.expert_load: the assignments that fell on a held
+    expert, and all) stay LAST whatever a family adds, where the block
+    scan and the scheduler find them."""
+    if not cfg.experts_held:
+        return jnp.concatenate([load, more])
+    return jnp.concatenate([load[:-2], more, load[-2:]])
+
+
 def _mixed_rows(load, rows: PackedRows, cfg: ModelConfig):
     """A packed step's `load` with, for a model of n residual streams
     (cfg.hc_mult), ONE value more at its end: the positions whose
@@ -1568,8 +1684,9 @@ def _mixed_rows(load, rows: PackedRows, cfg: ModelConfig):
     if not cfg.hc_mult:
         return load
     mixed = jnp.sum(rows.ok).astype(jnp.float32)[None]
-    return jnp.concatenate(
-        [jnp.zeros((3,), jnp.float32) if load is None else load, mixed])
+    if load is None:
+        return jnp.concatenate([jnp.zeros((3,), jnp.float32), mixed])
+    return before_share(load, mixed, cfg)
 
 
 def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
@@ -1636,7 +1753,7 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
             return logits, kv, _mixed_rows(load, rows, cfg)
         ssm = jnp.stack([jnp.sum(rows.ok), jnp.sum(
             rows.chunk_ok & (rows.chunk_pos[:, 0] == 0))])
-        load = jnp.concatenate([load, ssm.astype(jnp.float32)])
+        load = before_share(load, ssm.astype(jnp.float32), cfg)
         return logits, kv, load, state
     # an absent pool or window tensor rides the scan as None (no leaf)
     if window is None:

@@ -20,7 +20,7 @@ class ModelConfig:
 
     One config class covers the model families (GPT-2, Llama-3,
     Mixtral, SmallThinker, Keye, Granite-4.0-H, JoyAI-LLM-Flash,
-    Xing4.0) — the family is selected by `arch`, the MoE fields, the
+    Xing4.0, GLM-5) — the family is selected by `arch`, the MoE fields, the
     per-layer attention pattern, the sparse-attention indexer, the
     per-layer KIND (`layer_types`: Mamba-2 mixers beside attention
     layers), the latent-attention fields (`kv_lora_rank` and the split
@@ -31,6 +31,7 @@ class ModelConfig:
 
     arch: str = "llama"  # "gpt2" | "llama" | "mixtral" | "smallthinker"
                          # | "keye" | "granite_hybrid" | "joyai" | "xing"
+                         # | "glm5"
     vocab_size: int = 32000
     hidden_size: int = 4096
     num_layers: int = 32
@@ -110,6 +111,17 @@ class ModelConfig:
                                       # their weights
     routed_scaling_factor: float = 0.0  # on the routed experts' weights;
                                       # 0 = the family has none
+    # ONE chip's share of a deployment that splits each layer's experts
+    # over chips: experts_held of the num_experts, from experts_first on,
+    # have weights here (the expert leaves are [experts_held, ..]). The
+    # router still ranges over all num_experts and a token's gates are
+    # normalised over its chosen k wherever they live; the layer
+    # computes the part of its result that the held experts give, plus
+    # the shared expert, and what the absent experts would add is left
+    # out (no code stands in for the other chips or their exchange).
+    # 0 = every expert is held: the whole layer
+    experts_held: int = 0
+    experts_first: int = 0
 
     # latent attention (MLA): the query through a latent of q_lora_rank,
     # keys and values through ONE joint latent
@@ -179,6 +191,10 @@ class ModelConfig:
     # index_topk positions that score highest (all of them while there
     # are no more). The index keys are a cached row of their own
     # (cache/paged.py). All three are 0 for a model without an indexer.
+    # Beside latent attention (DeepSeek-V3.2's own form, GLM-5) the
+    # index queries read the QUERY LATENT c_q, the index key and the
+    # weights the layer's normed input, and only the first
+    # qk_rope_head_dim dims of an index head rotate (index_rope_dim)
     index_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
@@ -265,8 +281,12 @@ class ModelConfig:
         if self.pos_embedding != "rope":
             raise ValueError("latent attention rotates its shared key: "
                              f"pos_embedding is {self.pos_embedding!r}")
+        if self.has_indexer and self.index_head_dim < self.qk_rope_head_dim:
+            raise ValueError(
+                f"index_head_dim {self.index_head_dim} under "
+                f"qk_rope_head_dim {self.qk_rope_head_dim}: beside latent "
+                "attention an index head's first qk_rope_head_dim dims rotate")
         for name, on in (("layer_types", bool(self.layer_types)),
-                         ("index_topk", self.has_indexer),
                          ("sliding_window", self.layer_pattern() is not None),
                          ("qk_norm", self.qk_norm),
                          ("use_bias", self.use_bias),
@@ -306,6 +326,23 @@ class ModelConfig:
                 "first_k_dense without kv_lora_rank: feed-forwards of two "
                 "shapes run as layer runs, which the latent-attention "
                 "family's forward carries and no other yet")
+        if not self.experts_held:
+            if self.experts_first:
+                raise ValueError("experts_first without experts_held: the "
+                                 "share's first expert comes with its count")
+            return
+        if not 0 <= self.experts_first \
+                <= self.num_experts - self.experts_held \
+                or self.experts_held < 0:
+            raise ValueError(
+                f"experts_held {self.experts_held} from expert "
+                f"{self.experts_first} on, of num_experts "
+                f"{self.num_experts}: the share lies inside the experts")
+        if self.moe_impl == "ep":
+            raise ValueError(
+                "expert parallelism (moe_impl 'ep') does not carry "
+                "experts_held: it splits ALL the experts over a mesh and "
+                "runs their exchange, a share holds some and runs none")
 
     def _check_rope_scaling(self):
         """A published `rope_scaling` group, held as sorted items: of
@@ -456,6 +493,19 @@ class ModelConfig:
     @property
     def has_indexer(self) -> bool:
         return self.index_topk > 0
+
+    @property
+    def index_rope_dim(self) -> int:
+        """Dims of an index head that rotate, its first: beside latent
+        attention the rotary part's width (the rest pass unrotated, as a
+        head's q_nope does), else all of them."""
+        return self.qk_rope_head_dim if self.is_latent \
+            else self.index_head_dim
+
+    @property
+    def local_experts(self) -> int:
+        """Experts whose weights are here: the share, or all of them."""
+        return self.experts_held or self.num_experts
 
     @property
     def has_ssm(self) -> bool:
@@ -641,6 +691,33 @@ def xing4_29b_a4b() -> ModelConfig:
     )
 
 
+def glm5() -> ModelConfig:
+    """GLM-5 (huggingface.co/zai-org, `glm_moe_dsa`, 744B-A40B): 78
+    layers of latent attention (64 heads of 192 + 64 rotary against ONE
+    cached row of 512 + 64 a token, values of 256, the query through a
+    latent of 2,048) whose rows a lightning indexer SELECTS (DeepSeek
+    Sparse Attention: 32 index heads of 128 fed from the query latent,
+    one index key a token, the 2,048 positions that score highest);
+    layers 0-2 have a dense feed-forward (12,288), every other layer 256
+    sigmoid-routed experts of 2,048, 8 a token chosen with a selection
+    bias and weighted by their normalised scores times 2.5, plus one
+    shared expert; untied head. No chip holds one expert layer (9.66 G
+    parameters): a deployment splits the experts (experts_held). The
+    published prediction layer (`num_nextn_predict_layers` 1) takes no
+    part in the next-token distribution and is not held."""
+    return ModelConfig(
+        arch="glm5", vocab_size=154880, hidden_size=6144, num_layers=78,
+        num_heads=64, num_kv_heads=64, head_dim=64, intermediate_size=12288,
+        max_seq_len=202752, norm_eps=1e-5, rope_theta=1e6,
+        num_experts=256, num_experts_per_tok=8, moe_intermediate_size=2048,
+        shared_intermediate_size=2048, first_k_dense=3,
+        router_score="sigmoid", router_bias=True, routed_scaling_factor=2.5,
+        kv_lora_rank=512, q_lora_rank=2048, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256, rope_interleave=True,
+        index_heads=32, index_head_dim=128, index_topk=2048,
+    )
+
+
 def tiny(arch: str = "llama", **kw) -> ModelConfig:
     """Small config for tests: runs in <1s on CPU, exercises every code path."""
     base = dict(
@@ -711,6 +788,22 @@ def tiny(arch: str = "llama", **kw) -> ModelConfig:
                                   "mscale": 1, "mscale_all_dim": 1,
                                   "original_max_position_embeddings": 64},
                     hc_mult=4)
+    if arch == "glm5":
+        # every mechanism of the real one: JoyAI's toy with a key part
+        # wider than the rotary one and values wider still, an indexer
+        # fed from the query latent whose heads are TWICE the rotary
+        # width (half of a head rotates, half passes) and whose top-k
+        # binds inside the toy's contexts; all experts held (a test
+        # gives it a share)
+        base.update(num_layers=3, intermediate_size=96,
+                    moe_intermediate_size=32, shared_intermediate_size=32,
+                    num_experts=8, num_experts_per_tok=3, first_k_dense=1,
+                    router_score="sigmoid", router_bias=True,
+                    routed_scaling_factor=2.5, kv_lora_rank=32,
+                    q_lora_rank=48, qk_nope_head_dim=24,
+                    qk_rope_head_dim=8, v_head_dim=40, rope_interleave=True,
+                    rope_theta=1e6, index_heads=2, index_head_dim=16,
+                    index_topk=8)
     base.update(kw)
     return ModelConfig(arch=arch, **base)
 
@@ -725,6 +818,7 @@ PRESETS = {
     "granite-4.0-h-small": granite_4_h_small,
     "joyai-llm-flash": joyai_llm_flash,
     "xing4.0-29b-a4b": xing4_29b_a4b,
+    "glm-5": glm5,
 }
 
 

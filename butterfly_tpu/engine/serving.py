@@ -1322,7 +1322,10 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
     ends in ONE such sum: the cached rows its decode rows read, over
     the layers (cache/paged.py latent_paged_attend); a model of n
     residual streams' in one more, LAST: the positions mixed
-    (cache/paged.py _mixed_rows).
+    (cache/paged.py _mixed_rows). A latent-attention model WITH an
+    indexer gives the indexer's three means and no sum of rows read.
+    Under cfg.experts_held two sums follow everything else: the block's
+    expert assignments that fell on a held expert, and all of them.
     """
     S = tokens.shape[0]
     H = pbuf.shape[1]
@@ -1390,10 +1393,21 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
         # what it attended and what its read moved, the mean over the
         # block's rows
         rows = load[:, 3:].sum(axis=0)
-        load = jnp.concatenate([experts, rows[1:] / jnp.maximum(rows[0], 1)]) \
-            if cfg.has_indexer else experts
-        if cfg.has_ssm or cfg.is_latent or cfg.hc_mult:
+        # one chip's share of the experts (cfg.experts_held): the
+        # block's assignments that fell on a held expert and all of
+        # them, the mean over the layers, SUMS over the steps; they ride
+        # last (cache/paged.py before_share)
+        if cfg.experts_held:
+            rows, share = rows[:-2], rows[-2:]
+        if cfg.has_indexer:
+            load = jnp.concatenate(
+                [experts, rows[1:] / jnp.maximum(rows[0], 1)])
+        elif cfg.has_ssm or cfg.is_latent or cfg.hc_mult:
             load = jnp.concatenate([experts, rows])
+        else:
+            load = experts
+        if cfg.experts_held:
+            load = jnp.concatenate([load, share])
     return (block, valid, final, cursor, cache, window, win_len, load,
             st[0] if st else None)
 

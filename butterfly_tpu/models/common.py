@@ -100,7 +100,10 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
         return KVCache(
             k=jnp.zeros((cfg.num_layers, batch, max_seq, 1, cfg.latent_row),
                         dtype),
-            v=None, length=jnp.zeros((batch,), jnp.int32))
+            v=None, length=jnp.zeros((batch,), jnp.int32),
+            ki=jnp.zeros((cfg.num_layers, batch, max_seq,
+                          cfg.index_head_dim), dtype)
+            if cfg.has_indexer else None)
     if cfg.has_ssm:
         if quant != "none":
             ssm_unsupported(cfg, "the int8 contiguous KV cache")
@@ -387,27 +390,47 @@ def qkv_proj(x: jax.Array, p: Params, cfg: ModelConfig,
 
 @jax.named_scope("attn_index")
 def index_proj(x: jax.Array, lp: Params, cfg: ModelConfig,
-               cos: jax.Array, sin: jax.Array):
+               cos: jax.Array, sin: jax.Array,
+               cq: Optional[jax.Array] = None):
     """The indexer's three projections of a layer's INPUT x [B,T,D] (the
     norm is taken again in float32, as early_router_logits does):
     (qI [B,T,Ni,Hi], kI [B,T,Hi], w [B,T,Ni]), float32. kI passes a
     LayerNorm; qI and kI are rotated (rotate-half over all Hi dims, the
     model's theta). cos/sin are the attention's own [B,T,H/2]: the
     index head's frequencies theta^(-j/(Hi/2)) are every (H/Hi)-th of
-    the attention head's theta^(-m/(H/2)), to the bit."""
+    the attention head's theta^(-m/(H/2)), to the bit.
+
+    Beside latent attention (DeepSeek-V3.2's own form; GLM-5) x is the
+    layer's NORMED input h, which kI and w read, and the index QUERIES
+    read cq [B,T,q_lora_rank], the query latent that latent_proj hands
+    on (w_qi is [Rq,Ni,Hi]); only the first cfg.index_rope_dim dims of
+    qI's heads and of kI rotate, as the attention's rotary part does
+    (by pairs under cfg.rope_interleave, at its cos/sin [B,T,rope/2]),
+    and the rest pass."""
     ip = lp["index"]
     hp = lax.Precision.HIGHEST
-    h = pre_norm(x.astype(jnp.float32), lp["ln1"], cfg)
     f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
-    qi = jnp.einsum("btd,dnh->btnh", h, f32(ip["w_qi"]), precision=hp)
+    h = f32(x) if cfg.is_latent \
+        else pre_norm(x.astype(jnp.float32), lp["ln1"], cfg)
+    qi = jnp.einsum("btd,dnh->btnh", f32(cq) if cfg.is_latent else h,
+                    f32(ip["w_qi"]), precision=hp)
     ki = jnp.einsum("btd,dh->bth", h, f32(ip["w_ki"]), precision=hp)
     w = jnp.einsum("btd,dn->btn", h, f32(ip["w_w"]), precision=hp)
     ki = layer_norm(ki, ip["k_norm"]["scale"], ip["k_norm"]["bias"],
                     cfg.norm_eps)
-    step = cfg.head_dim // cfg.index_head_dim
-    cos, sin = cos[..., ::step], sin[..., ::step]
-    qi = apply_rope(qi, cos, sin)
-    ki = apply_rope(ki[:, :, None], cos, sin)[:, :, 0]
+    if cfg.is_latent:
+        turn = apply_rope_pairs if cfg.rope_interleave else apply_rope
+        R = cfg.index_rope_dim
+
+        def rotate(a, cos, sin):
+            return jnp.concatenate([turn(a[..., :R], cos, sin), a[..., R:]],
+                                   axis=-1)
+    else:
+        rotate = apply_rope
+        step = cfg.head_dim // cfg.index_head_dim
+        cos, sin = cos[..., ::step], sin[..., ::step]
+    qi = rotate(qi, cos, sin)
+    ki = rotate(ki[:, :, None], cos, sin)[:, :, 0]
     return qi, ki, w
 
 
@@ -547,7 +570,9 @@ def latent_unsupported(cfg: ModelConfig, what: str) -> None:
 def latent_proj(h: jax.Array, p: Params, cfg: ModelConfig, cos, sin):
     """The projections of the normed input h [B,T,D]: (q_nope
     [B,T,Nq,nope], q_rope [B,T,Nq,rope] rotated, row [B,T,latent_row] =
-    [norm_kv(c_kv) | rotated k_r], what the token caches)."""
+    [norm_kv(c_kv) | rotated k_r], what the token caches, c_q
+    [B,T,q_lora_rank] the normed query latent, which an indexer's
+    queries read: index_proj)."""
     dt = h.dtype
     rotate = apply_rope_pairs if cfg.rope_interleave else apply_rope
     cq = rms_norm(qeinsum("btd,dr->btr", h, p["w_dq"], dt),
@@ -560,7 +585,7 @@ def latent_proj(h: jax.Array, p: Params, cfg: ModelConfig, cos, sin):
                  cfg.norm_eps)
     k_r = rotate(ckv[..., None, cfg.kv_lora_rank:], cos, sin)[:, :, 0]
     return q_nope, rotate(q_rope, cos, sin), \
-        jnp.concatenate([c, k_r], axis=-1)
+        jnp.concatenate([c, k_r], axis=-1), cq
 
 
 @jax.named_scope("attn_latent_proj")
@@ -790,18 +815,30 @@ def route_tokens(x: jax.Array, router_w: jax.Array, k: int,
 @jax.named_scope("moe_route")
 def expert_load(logits: jax.Array, k: int, ok: jax.Array,
                 score: str = "softmax",
-                bias: Optional[jax.Array] = None) -> jax.Array:
+                bias: Optional[jax.Array] = None,
+                held: Optional[Tuple[int, int]] = None) -> jax.Array:
     """What one layer's routing asks of its experts in one step, f32 [3]:
     how many DISTINCT experts the rows marked `ok` touch (the experts a
     dispatch that skips unrouted ones would still stream), the rows of
     the fullest expert, and the mean rows of an expert (ok rows x k /
     E). logits [B,T,E] as route_tokens takes them, ok [B,T] bool;
-    score and bias as route_tokens'."""
+    score and bias as route_tokens'.
+
+    held (first, count): the chip's share of the experts
+    (cfg.experts_held). The three then count over the experts HELD, and
+    two values follow: of the step's ok rows x k assignments those that
+    fell on a held expert, and all of them."""
     E = logits.shape[-1]
     idx, _ = _route_choice(logits, k, score, bias)
     rows = jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32)
                    * ok[..., None, None], axis=(0, 1, 2))           # [E]
-    return jnp.stack([jnp.sum(rows > 0), jnp.max(rows), jnp.sum(rows) / E])
+    if held is None:
+        return jnp.stack([jnp.sum(rows > 0), jnp.max(rows),
+                          jnp.sum(rows) / E])
+    mine = rows[held[0]:held[0] + held[1]]
+    return jnp.stack([jnp.sum(mine > 0), jnp.max(mine),
+                      jnp.sum(mine) / held[1], jnp.sum(mine),
+                      jnp.sum(rows)])
 
 
 @jax.named_scope("moe_experts")
@@ -812,6 +849,12 @@ def moe_block(x: jax.Array, p: Params, cfg: ModelConfig,
     The expert-parallel all_to_all path lives in parallel/expert.py; this
     dense form is the single-device reference and the EP fallback.
     `logits`: as route_tokens'.
+
+    cfg.experts_held (one chip's share of a deployment's experts): the
+    router ranges over all cfg.num_experts and the gates are what the
+    whole layer would give them; the leaves hold the experts
+    experts_first .. + experts_held alone and the result is THEIR part
+    of the sum. What the absent experts would add is left out.
     """
     B, T, D = x.shape
     weights, idx = route_tokens(
@@ -820,6 +863,9 @@ def moe_block(x: jax.Array, p: Params, cfg: ModelConfig,
         scale=cfg.routed_scaling_factor)
     onehot = jax.nn.one_hot(idx, cfg.num_experts, dtype=jnp.float32)  # [B,T,k,E]
     comb = jnp.einsum("btk,btke->bte", weights, onehot)  # [B,T,E]
+    if cfg.experts_held:
+        comb = comb[..., cfg.experts_first:
+                    cfg.experts_first + cfg.experts_held]
 
     act = ACTIVATIONS[cfg.act]
     dt = x.dtype
@@ -869,8 +915,9 @@ def ffn_block(h: jax.Array, lp: Params, cfg: ModelConfig,
     if cfg.is_moe and "moe" in lp:   # not a leading dense layer's (first_k_dense)
         if cfg.moe_impl == "ep":   # no early logits here: ModelConfig refuses
             from butterfly_tpu.parallel.expert import moe_block_ep
-            return moe_block_ep(h, lp["moe"], cfg)
-        out = moe_block(h, lp["moe"], cfg, logits)
+            out = moe_block_ep(h, lp["moe"], cfg)
+        else:
+            out = moe_block(h, lp["moe"], cfg, logits)
         if cfg.shared_intermediate_size:
             # one shared expert, every token, added unweighted
             with jax.named_scope("moe_shared"):
@@ -1185,14 +1232,17 @@ def ffn_close(x: jax.Array, lp: Params, cfg: ModelConfig, route=None,
     norm and residual. route: early_router_logits' of the layer. Returns
     (x, load): with `ok` [B,T], the rows that are real, and a model of
     experts, `load` is what the layer's routing asked of them
-    (expert_load), else None."""
+    (expert_load: five values under cfg.experts_held), else None."""
     h, mix = stream_read(x, lp, 2, cfg)
     load = None
     if ok is not None and cfg.is_moe and "moe" in lp:
         if route is None:
             route = router_logits(h, lp["moe"]["router"])
-        load = expert_load(route, cfg.num_experts_per_tok, ok,
-                           cfg.router_score, lp["moe"].get("router_bias"))
+        load = expert_load(
+            route, cfg.num_experts_per_tok, ok, cfg.router_score,
+            lp["moe"].get("router_bias"),
+            (cfg.experts_first, cfg.experts_held) if cfg.experts_held
+            else None)
     return stream_write(x, ffn_block(h, lp, cfg, route), mix, cfg), load
 
 
@@ -1831,35 +1881,49 @@ def _latent_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     mask = make_mask(positions, T if expanded else cache.max_seq)
 
     def layer(ffn, carry, l):
-        x, ck = carry
+        x, ck, cki = carry
         lp = run_layer_at(params, ffn, l, cfg)
         h, mix = stream_read(x, lp, 1, cfg)
-        q_nope, q_rope, rows = latent_proj(h, lp["attn"], cfg, cos, sin)
+        q_nope, q_rope, rows, cq = latent_proj(h, lp["attn"], cfg, cos, sin)
         # the layer's rows [B,S,latent_row], as index keys are written
         mine = index_cache_write(
             lax.dynamic_index_in_dim(ck, l, 0, keepdims=False)[:, :, 0],
             rows, positions[:, 0])
+        sel = mask
+        if cfg.has_indexer:
+            # the layer's index keys are written, then every query's
+            # mask narrows to its selection (attention_block does so)
+            qi, ki, w = index_proj(h, lp, cfg, cos, sin, cq)
+            keys = index_cache_write(
+                lax.dynamic_index_in_dim(cki, l, 0, keepdims=False), ki,
+                positions[:, 0])
+            cki = lax.dynamic_update_index_in_dim(cki, keys, l, 0)
+            sel = select_mask(
+                index_scores(qi, w, ki if expanded else keys), mask,
+                cfg.index_topk)
         if expanded:
-            out = latent_attend_expanded(q_nope, q_rope, rows, mask,
+            out = latent_attend_expanded(q_nope, q_rope, rows, sel,
                                          lp["attn"], cfg)
         else:
             out = latent_attend(
                 latent_queries(q_nope, q_rope, lp["attn"], cfg), mine,
-                mask, cfg)
+                sel, cfg)
         x = stream_write(x, attn_output(out, lp["attn"], cfg), mix, cfg)
         x, _ = ffn_close(x, lp, cfg)
         return (x, lax.dynamic_update_index_in_dim(ck, mine[:, :, None], l,
-                                                   0)), None
+                                                   0), cki), None
 
-    ck = cache.k
+    ck, cki = cache.k, cache.ki
     for _, first, n, _ in layer_runs(cfg):
-        (x, ck), _ = lax.scan(partial(layer, ffn_run(params, first, cfg)),
-                              (x, ck), first + jnp.arange(n))
+        (x, ck, cki), _ = lax.scan(
+            partial(layer, ffn_run(params, first, cfg)), (x, ck, cki),
+            first + jnp.arange(n))
     x = stream_fold(x, cfg)
     if last_index is not None:
         x = jnp.take_along_axis(
             x, last_index[:, None, None].astype(jnp.int32), axis=1)
-    return final_logits(params, cfg, x), KVCache(ck, None, cache.length + T)
+    return final_logits(params, cfg, x), KVCache(
+        ck, None, cache.length + T, ki=cki)
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -1972,7 +2036,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     if cfg.has_indexer:
         Ni, Hi = cfg.index_heads, cfg.index_head_dim
         layers["index"] = {
-            "w_qi": w(next(keys), L, D, Ni, Hi),
+            # beside latent attention the index queries read the query
+            # latent (index_proj)
+            "w_qi": w(next(keys), L, cfg.q_lora_rank or D, Ni, Hi),
             "w_ki": w(next(keys), L, D, Hi),
             "w_w": w(next(keys), L, D, Ni),
             "k_norm": {"scale": jnp.ones((L, Hi), pdt),
@@ -1993,11 +2059,14 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     dense = {} if Ld else layers
     if cfg.is_moe:
         E, Fe, Ls = cfg.num_experts, cfg.expert_width, L - Ld
+        # the router ranges over every expert; the expert leaves hold
+        # the chip's share where the configuration states one
+        Eh = cfg.local_experts
         sparse["moe"] = {
             "router": w(next(keys), Ls, D, E),
-            "w_gate": w(next(keys), Ls, E, D, Fe),
-            "w_up": w(next(keys), Ls, E, D, Fe),
-            "w_down": w(next(keys), Ls, E, Fe, D),
+            "w_gate": w(next(keys), Ls, Eh, D, Fe),
+            "w_up": w(next(keys), Ls, Eh, D, Fe),
+            "w_down": w(next(keys), Ls, Eh, Fe, D),
         }
         if cfg.router_bias:
             # a stored buffer, zero in a checkpoint that never balanced;

@@ -98,7 +98,8 @@ class TickLog:
                gap_s: float = 0.0, profiled: bool = False,
                ssm_load: Optional[Sequence[float]] = None,
                latent_load: Optional[Sequence[float]] = None,
-               hc_load: Optional[Sequence[float]] = None) -> None:
+               hc_load: Optional[Sequence[float]] = None,
+               share_load: Optional[Sequence[float]] = None) -> None:
         """Append one tick record (hot path: one dict build + one
         locked append per TICK, never per token). `phases` is copied —
         callers may reuse/zero their accumulator dict.
@@ -129,6 +130,14 @@ class TickLog:
         drained, as `hc_rows` (the positions whose streams their steps
         mixed: a live decode row one, a chunk its real columns, filler
         none) and `hc_steps` (the steps those blocks ran).
+        `share_load` (one chip's share of a deployment's experts,
+        ModelConfig.experts_held; null otherwise): [local, routed]
+        SUMMED over the mixed blocks the tick drained, as
+        `expert_rows_local` (of their steps' real rows x experts a
+        token, the assignments that fell on an expert held here, the
+        mean over the layers that route) and `expert_rows_routed` (all
+        of them); `experts_touched`, `expert_rows_max` and
+        `expert_rows_mean` then count over the experts HELD.
         The starvation clock (Scheduler._starve): `starved_s`, the
         seconds the device waited for the host before this tick's
         launches, whichever tick the wait began in (0.0 where they
@@ -144,6 +153,7 @@ class TickLog:
         ssm_rows, state_resets, ssm_steps = ssm_load or (None,) * 3
         latent_rows, latent_steps = latent_load or (None,) * 2
         hc_rows, hc_steps = hc_load or (None,) * 2
+        rows_local, rows_routed = share_load or (None,) * 2
         entry = {
             "seq": self._seq,
             "t_wall": time.time(),
@@ -177,6 +187,8 @@ class TickLog:
             "latent_steps": latent_steps,
             "hc_rows": hc_rows,
             "hc_steps": hc_steps,
+            "expert_rows_local": rows_local,
+            "expert_rows_routed": rows_routed,
             "starved_s": starved_s,
             "starved_cause": starved_cause,
             "starved_by": dict(starved_by or {}),

@@ -47,7 +47,20 @@ as keys and again as values, would fetch it twice):
 * the write-combined window [L, S, 1, W, R] (cache/paged.py: staged
   rows at positions lengths .. lengths + win_count - 1) comes whole, as
   it rides the layer scan; (layer, slot)'s block of it is one more
-  chunk, pipelined a slot by its BlockSpec.
+  chunk, pipelined a slot by its BlockSpec;
+* a model whose rows a sparse-attention indexer SELECTS (GLM-5: DSA
+  over MLA) reads through the same walk, the selection joining the
+  length mask as in ops/sparse_attention.py (PR 51): `sel` [S, S_max]
+  comes a slot a block, int32 and a chunk a row, a row that is dead OR
+  unselected gets probability 0, and the window's staged rows are
+  masked by the selection at their positions (the one or two chunk rows
+  they fall in, rotated by the offset inside a chunk, so such a window
+  is no wider than a chunk). At a table of a few times index_topk
+  nearly every live page holds a selected row (at 2,048 of 7,168 rows
+  a page of 16 holds none with probability 0.5 %), so a walk of the
+  pages that hold one is this walk. It is a call of its own name,
+  `latent_select_attention`: nothing of the unselected read's program
+  changes, and a trace tells the two apart.
 
 On the CPU backend the wrapper runs the kernel in interpreter mode;
 everywhere else it is compiled (ops/__init__.py has the rule).
@@ -63,6 +76,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from butterfly_tpu.ops import (note_kernel, resolve_interpret,
                                sublane_multiple)
+from butterfly_tpu.ops.sparse_attention import window_selected
 from butterfly_tpu.ops.window_stage import in_hbm
 
 NEG_INF = -1e30
@@ -74,17 +88,23 @@ PAGES_PER_CHUNK = 32
 GROUP_PAGES = 8
 
 
-def fits(pages: jax.Array, rank: int) -> bool:
+def fits(pages: jax.Array, rank: int, select: bool = False,
+         window: int = 0) -> bool:
     """Can the kernel serve this pool [L, P, 1, page, Rp]? Compiled, a
     page is whole sublane tiles of the pool's dtype and a row and its
     values are whole lanes (Mosaic copies and slices whole tiles);
     interpreted (the CPU backend) any pool of rows will do. Any other
-    pool takes the `jnp` read."""
-    if pages.shape[2] != 1 or pages.shape[1] < GROUP_PAGES:
+    pool takes the `jnp` read. select: the read takes a selection, whose
+    chunk rows are then whole lanes and no narrower than the
+    write-combined window of `window` rows a slot."""
+    page = pages.shape[3]
+    if pages.shape[2] != 1 or pages.shape[1] < GROUP_PAGES \
+            or (select and window > PAGES_PER_CHUNK * page):
         return False
     return resolve_interpret(None) or (
-        pages.shape[3] % sublane_multiple(pages.dtype) == 0
-        and pages.shape[4] % 128 == 0 and rank % 128 == 0)
+        page % sublane_multiple(pages.dtype) == 0
+        and pages.shape[4] % 128 == 0 and rank % 128 == 0
+        and not (select and (PAGES_PER_CHUNK * page) % 128))
 
 
 def _update(q, rows, live, carry, rank: int, scale: float):
@@ -109,7 +129,8 @@ def _update(q, rows, live, carry, rank: int, scale: float):
 
 def _latent_kernel(layer_ref, table_ref, len_ref, *rest, page: int,
                    pages_per_chunk: int, group_pages: int, max_pages: int,
-                   pool_pages: int, rank: int, scale: float, window: int):
+                   pool_pages: int, rank: int, scale: float, window: int,
+                   select: bool = False):
     """One grid step is one slot. The pool lies in HBM, its layers end
     to end [L * pool_pages, page, R]; the table is flat, a slot's
     `max_pages` entries after another's (and a group of page 0 behind
@@ -119,10 +140,16 @@ def _latent_kernel(layer_ref, table_ref, len_ref, *rest, page: int,
     is the same size whatever the chunk) while the chunk before is
     multiplied, one online-softmax step a chunk. window > 0: (layer,
     slot)'s block [W, R] of the write-combined window is one more step,
-    its first win_count rows live."""
+    its first win_count rows live. select: a chunk's columns are masked
+    by its row of the selection sel_ref [1, chunks, n * page] besides,
+    and the window's rows by the selection at their positions, length ..
+    length + W - 1 (ops/sparse_attention.py's two differences)."""
     if window:
         wc_ref, *rest = rest
-    q_ref, pool_ref, *rest = rest
+    q_ref, *rest = rest
+    if select:
+        sel_ref, *rest = rest
+    pool_ref, *rest = rest
     win_ref = None
     if window:
         win_ref, *rest = rest
@@ -206,8 +233,11 @@ def _latent_kernel(layer_ref, table_ref, len_ref, *rest, page: int,
         # width, so steps by the live pages read slower at every size
         # (PERF.md, PR 50). [n, page, R] collapses to rows as whole tiles.
         pos = c * n * page + col
-        return _update(q, buf[b].reshape(n * page, R), pos < length, carry,
-                       rank, scale)
+        rows = buf[b].reshape(n * page, R)
+        live = pos < length
+        if select:
+            live = live & (sel_ref[0, pl.ds(c, 1), :] != 0)
+        return _update(q, rows, live, carry, rank, scale)
 
     carry = (jnp.full((Nq, 1), -jnp.inf, jnp.float32),
              jnp.zeros((Nq, 1), jnp.float32),
@@ -215,8 +245,13 @@ def _latent_kernel(layer_ref, table_ref, len_ref, *rest, page: int,
     carry = jax.lax.fori_loop(0, nchunks, chunk, carry)
     if window:
         wcol = jax.lax.broadcasted_iota(jnp.int32, (1, window), 1)
-        carry = _update(q, win_ref[0, 0], wcol < wc_ref[slot], carry, rank,
-                        scale)
+        staged = win_ref[0, 0]
+        live = wcol < wc_ref[slot]
+        if select:
+            live = live \
+                & (window_selected(sel_ref, length, col, window) != 0) \
+                & (length + wcol < max_pages * page)
+        carry = _update(q, staged, live, carry, rank, scale)
     _, l, acc = carry
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
@@ -249,11 +284,42 @@ def latent_attention(q: jax.Array, pages: jax.Array, layer,
     staged rows at positions lengths[s] .. lengths[s] + win_count[s] - 1
     (win_count INCLUDES the just-staged current token; `lengths` is then
     the FLUSHED length alone), as ops/paged_attention.py takes them."""
+    return _read(q, pages, layer, page_table, lengths, None, win, win_count,
+                 rank, scale, interpret)
+
+
+# A call of its own name (`latent_select_attention.N = bf16[S, Nq,
+# rank]`): the benchmark tells the selecting read from the plain one by
+# it (servebench/dsa_peaks.py), and nothing of latent_attention's
+# program moves.
+@jax.named_scope("attn_latent")
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+def latent_select_attention(q: jax.Array, pages: jax.Array, layer,
+                            page_table: jax.Array, lengths: jax.Array,
+                            sel: jax.Array, win: jax.Array = None,
+                            win_count: jax.Array = None, *, rank: int,
+                            scale: float,
+                            interpret: bool | None = None) -> jax.Array:
+    """latent_attention over the SELECTED positions of each slot's
+    cached rows, read by its live pages: sel [slots, max_pages * page]
+    bool, of the positions `lengths` (and the window's count) make live
+    the ones the row attends (models.common.select_mask); it holds the
+    window's positions as it holds the pool's. Everything else as
+    latent_attention's."""
+    return _read(q, pages, layer, page_table, lengths, sel, win, win_count,
+                 rank, scale, interpret)
+
+
+def _read(q, pages, layer, page_table, lengths, sel, win, win_count,
+          rank: int, scale: float, interpret) -> jax.Array:
+    """latent_attention and latent_select_attention (sel None: the
+    plain read)."""
     S, Nq, R = q.shape
     L, P, _, page, _ = pages.shape
     window = 0 if win is None else win.shape[3]
     interpret = resolve_interpret(interpret)
-    note_kernel("latent" + ("_win" if window else ""), interpret)
+    note_kernel("latent" + ("" if sel is None else "_select")
+                + ("_win" if window else ""), interpret)
     # A page's copy is a dozen scalar operations on the chip, and they
     # bound the call (PERF.md, PR 50): the pool's layers end to end and
     # the table flat make an address one sum, and nothing is clamped page
@@ -268,9 +334,19 @@ def latent_attention(q: jax.Array, pages: jax.Array, layer,
     def slot_map(s, *_):
         return (s, 0, 0)
 
-    in_specs = [pl.BlockSpec((1, Nq, R), slot_map),
-                pl.BlockSpec(memory_space=pl.ANY)]
-    args = [q, pool]
+    in_specs = [pl.BlockSpec((1, Nq, R), slot_map)]
+    args = [q]
+    if sel is not None:
+        # the selection a chunk a row: [S, chunks, n * page] int32 (a
+        # 32-bit row is its own sublane, so a chunk's slice is an index)
+        rows = PAGES_PER_CHUNK * page
+        chunks = -(-page_table.shape[1] // PAGES_PER_CHUNK)
+        sel = jnp.pad(sel.astype(jnp.int32),
+                      ((0, 0), (0, chunks * rows - sel.shape[1])))
+        in_specs.append(pl.BlockSpec((1, chunks, rows), slot_map))
+        args.append(sel.reshape(S, chunks, rows))
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+    args.append(pool)
     prefetch = [jnp.asarray(layer, jnp.int32).reshape(1), table, lengths]
     if window:
         in_specs.append(pl.BlockSpec(
@@ -287,7 +363,7 @@ def latent_attention(q: jax.Array, pages: jax.Array, layer,
     kernel = functools.partial(
         _latent_kernel, page=page, pages_per_chunk=PAGES_PER_CHUNK,
         group_pages=group, max_pages=page_table.shape[1], pool_pages=P,
-        rank=rank, scale=scale, window=window)
+        rank=rank, scale=scale, window=window, select=sel is not None)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, Nq, rank), q.dtype),

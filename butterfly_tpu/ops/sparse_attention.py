@@ -109,6 +109,22 @@ def _update(q, k, v, live, carry, scale: float):
             acc * corr + pv)
 
 
+def window_selected(sel_ref, length, col, window: int):
+    """[1, window] int32, nonzero where selected: the selection at the
+    write-combined window's staged rows, which lie at positions length .. length + window - 1.
+    sel_ref [1, chunks, rows] holds the selection a chunk a row; col is
+    the iota [1, rows]. The positions fall in the chunk row that
+    `length` falls in and the one after it: both rotated left by its
+    offset in a chunk, the first up to the chunk's end and the second
+    behind it (so a window is no wider than a chunk). Shared with
+    ops/latent_attention.py's selecting read."""
+    rows, last_row = sel_ref.shape[2], sel_ref.shape[1] - 1
+    first, off = jnp.minimum(length // rows, last_row), length % rows
+    turned = [pltpu.roll(sel_ref[0, pl.ds(c, 1), :], (rows - off) % rows, 1)
+              for c in (first, jnp.minimum(first + 1, last_row))]
+    return jnp.where(col + off < rows, *turned)[:, :window]
+
+
 def _sparse_kernel(layer_ref, table_ref, len_ref, *rest, page: int,
                    pages_per_chunk: int, group_pages: int, max_pages: int,
                    pool_pages: int, window: int):
@@ -222,16 +238,7 @@ def _sparse_kernel(layer_ref, table_ref, len_ref, *rest, page: int,
              jnp.zeros((Kv, G, H), jnp.float32))
     carry = jax.lax.fori_loop(0, nchunks, chunk, carry)
     if window:
-        # the staged rows lie at positions length .. length + W - 1, in
-        # the chunk row of the selection that `length` falls in and the
-        # one after it: both rotated left by its offset in a chunk, the
-        # first up to the chunk's end and the second behind it
-        rows, last_row = n * page, sel_ref.shape[1] - 1
-        first, off = jnp.minimum(length // rows, last_row), length % rows
-        turned = [pltpu.roll(sel_ref[0, pl.ds(c, 1), :], (rows - off) % rows,
-                             1)
-                  for c in (first, jnp.minimum(first + 1, last_row))]
-        wsel = jnp.where(col + off < rows, *turned)[:, :window]
+        wsel = window_selected(sel_ref, length, col, window)
         wcol = col[:, :window]
         live = (wcol < wc_ref[slot]) & (wsel != 0) \
             & (length + wcol < max_pages * page)

@@ -688,7 +688,23 @@ class Scheduler:
         # the block's decode rows read, over its layers and steps; the
         # tick record holds [rows, steps] over the blocks it drained
         self._is_latent = engine.cfg.is_latent
+        # with an indexer over its rows the vector holds the indexer's
+        # three means there instead (kv_rows_*), as every such model's
+        self._has_indexer = engine.cfg.has_indexer
         self._tick_latent: Optional[List[float]] = None
+        # one chip's share of a deployment's experts (experts_held): the
+        # vector ends in the block's expert assignments that fell on a
+        # held expert and all of them (the mean over the layers, sums
+        # over its steps); the tick record holds their sums over the
+        # blocks it drained
+        self._experts_held = engine.cfg.experts_held > 0
+        self._tick_share: Optional[List[float]] = None
+        self._c_expert_rows_local = reg.counter(
+            "expert_rows_local_total",
+            "Expert assignments (a step's real rows x experts a token, "
+            "the mean over the layers that route) that fell on an expert "
+            "this chip HOLDS (ModelConfig.experts_held: its share of a "
+            "deployment's experts); stays 0 where every expert is held")
         self._g_latent_rows = reg.gauge(
             "latent_rows_read",
             "Cached latent rows that the decode rows of one step read, "
@@ -1143,6 +1159,7 @@ class Scheduler:
         self._tick_expert_loads = []
         self._tick_ssm = None
         self._tick_latent = None
+        self._tick_share = None
         self._tick_hc = None
         blocks0 = self.engine.blocks_launched
         with TraceAnnotation("bf.tick", seq=self.ticklog.next_seq,
@@ -1283,6 +1300,7 @@ class Scheduler:
         self.ticklog.record(wall, tp, fetch_s=fetch, expert_load=load,
                             ssm_load=self._tick_ssm,
                             latent_load=self._tick_latent,
+                            share_load=self._tick_share,
                             hc_load=self._tick_hc,
                             overlapped=self._tick_overlapped,
                             inflight=len(self._inflight),
@@ -2409,6 +2427,13 @@ class Scheduler:
             # lane emits at most one token per step, valid only on
             # decode steps and the completion step's first token
             rows, ok, *load = vals if kind == "mixed" else (vals, None)
+            if load and self._experts_held:
+                # [.., local, routed] summed over the block's steps
+                sh = self._tick_share = self._tick_share or [0.0, 0.0]
+                sh[0] += float(load[0][-2])
+                sh[1] += float(load[0][-1])
+                self._c_expert_rows_local.inc(float(load[0][-2]))
+                load = [load[0][:-2]]
             if load and self._has_streams:
                 # [.., positions mixed] summed over the block's steps
                 hc = self._tick_hc = self._tick_hc or [0.0, 0]
@@ -2423,7 +2448,7 @@ class Scheduler:
                 ssm[1] += float(load[0][4])
                 ssm[2] += len(rows)
                 load = [load[0][:3]]
-            if load and self._is_latent:
+            if load and self._is_latent and not self._has_indexer:
                 # [.., rows read] summed over the block's steps
                 lat = self._tick_latent = self._tick_latent or [0.0, 0]
                 lat[0] += float(load[0][3])

@@ -402,6 +402,27 @@ def window_moves(hlo: str, leaves,
     return found
 
 
+def selecting_calls(config: dict, hlo: str):
+    """How many instructions of a compiled HLO text the benchmark's
+    pattern for the selecting latent read tells
+    (servebench/dsa_peaks.py dsa_patterns: the call's name and its
+    result's [.., heads, kv_lora_rank], on the name as a trace gives
+    it, servebench/xplane.py clean); None for a configuration without
+    an indexer over a latent cache, or on a checkout older than the
+    pattern."""
+    import re
+    try:
+        from servebench.dsa_peaks import dsa_patterns, is_dsa
+        from servebench.xplane import clean
+    except ImportError:
+        return None
+    if not is_dsa(config):
+        return None
+    call = dsa_patterns(config)["call"]
+    return sum(bool(call.search(clean(m)))
+               for m in re.findall(r"^\s*(?:ROOT )?(%\S+ = \S+)", hlo, re.M))
+
+
 def cell_blocks(config: dict, hlo_dir=None) -> list:
     """A cell's two block programs, the mixed block (one chunk of C
     beside the decode rows) and the decode block, at the sizes of its
@@ -414,8 +435,10 @@ def cell_blocks(config: dict, hlo_dir=None) -> list:
     fits the chip's 15.75 GiB), the Mosaic calls by name, and the
     instructions inside its loops that move a carried leaf: a copy,
     transpose or fusion of a window leaf's shape, a copy or transpose
-    of the residual streams' [n, rows, 1, D] (window_moves). The
-    compiler's figures were the chip's to the megabyte (PR 41).
+    of the residual streams' [n, rows, 1, D] (window_moves); for a
+    latent cache whose rows an indexer selects, the instructions the
+    benchmark's pattern tells as the selecting read (selecting_calls).
+    The compiler's figures were the chip's to the megabyte (PR 41).
     hlo_dir: where to write each program's compiled text
     (`<name>.hlo.txt`: what a trace will name, and what two checkouts'
     programs are compared by)."""
@@ -505,6 +528,14 @@ def cell_blocks(config: dict, hlo_dir=None) -> list:
                                           kinds=("copy", "transpose")))
             rec["ok"] = held < 15.75 * 2 ** 30 and not rec["window_moves"] \
                 and not rec["stream_moves"] and bool(rec["mosaic_calls"])
+            told = selecting_calls(config, hlo)
+            if told is not None:
+                # a latent cache whose rows an indexer selects: every
+                # decode row's read is the call the benchmark's pattern
+                # tells, and the plain latent read is not in the program
+                rec["selecting_calls"] = told
+                rec["ok"] = rec["ok"] and told > 0 \
+                    and "latent_attention" not in rec["mosaic_calls"]
             out.append(rec)
     finally:
         jax.default_backend = real_backend

@@ -11,7 +11,9 @@ driver: chunks of C prompt tokens into the write-combined window, a flush
 every k steps as the scheduler drains, then decode rows, each of which
 attends only the rows it selected: cache/paged.py sparse_paged_attend,
 by the masked read of its live pages where kernels are on, SLOTS x MAX_SEQ
-being a table the kernel serves, else by the gather; `decode_read` in the
+being a table the kernel serves, else by the gather; a latent-attention
+model with an indexer, GLM-5's family, through latent_paged_attend, its
+selection over the cached LATENT rows; `decode_read` in the
 output says which), and its logits are compared with the
 configuration's reference computed in blocks of rows: every chunk's last column and every decode row, in two
 groups: BEFORE position topk (its last quarter: nothing is left out yet)
@@ -23,13 +25,15 @@ Beside the clean run, two controls planted in the program: `select_all`
 (every position is attended: the model without its indexer) and
 `select_recent` (the last topk positions in place of the indexer's
 choice: a sliding window). Each must read as the clean run BEFORE topk
-and pass LIMIT past it. A reading means something only between the clean
-run's and a control's, and LIMIT lies there (PERF.md, PR 36, gives the
-readings it was set from). The MEDIAN over a group's rows is what is held
-to it: in bfloat16 a near-tie among 128 router logits flips an expert in
-some rows, and a near-tie at the selection's edge swaps one of 2,048
-attended rows for another, while a wrong selection moves every row. The
-largest reading of each group is reported beside it.
+and pass the limit past it. A reading means something only between the
+clean run's and a control's, and the limit lies there: LIMIT (PERF.md,
+PR 36, gives the readings it was set from) or the configuration's own
+`parity_tolerance`, stated in its file beside ITS readings. The MEDIAN
+over a group's rows is what is held to it: in bfloat16 a near-tie among
+128 router logits flips an expert in some rows, and a near-tie at the
+selection's edge swaps one of 2,048 attended rows for another, while a
+wrong selection moves every row. The largest reading of each group is
+reported beside it.
 
 The tool reports chip evidence and refuses to run without a TPU; `--toy`
 (the CPU rehearsal of tests/test_keye.py) says so in its output.
@@ -51,7 +55,11 @@ from window_parity import _reading, served_rows  # noqa: E402
 #: rms of the difference over the reference's spread: a group of rows whose
 #: median is above it is wrong. Set between the chip's two readings past
 #: 2 x topk (PERF.md, PR 36): the clean run 0.0206, `select_all` 0.1234
-#: (2.4 times of room on either side; `select_recent` reads 0.71)
+#: (2.4 times of room on either side; `select_recent` reads 0.71). A
+#: configuration whose own two readings lie elsewhere states its limit
+#: beside them in its file (`parity_tolerance`, `parity_tolerance_why`,
+#: as `reference_tolerance` states refcheck.py's); this is the limit of
+#: a file that states none
 LIMIT = 0.05
 STREAM, SLOTS, MAX_SEQ = 4500, 8, 5120
 #: decode rows behind the prompt
@@ -71,13 +79,18 @@ def planted(fault: str):
     trace's kernel notes kept (KERNELS)."""
     from butterfly_tpu.cache import paged
     from butterfly_tpu.ops import record_kernels
-    real = paged.sparse_paged_attend
-    paged.sparse_paged_attend = partial(real, select=FAULTS[fault])
+    # the selection over keys and values, and the one over latent rows
+    # (a latent-attention model with an indexer: GLM-5's family)
+    names = ("sparse_paged_attend", "latent_paged_attend")
+    real = [getattr(paged, n) for n in names]
+    for n, fn in zip(names, real):
+        setattr(paged, n, partial(fn, select=FAULTS[fault]))
     try:
         with record_kernels(KERNELS):
             yield
     finally:
-        paged.sparse_paged_attend = real
+        for n, fn in zip(names, real):
+            setattr(paged, n, fn)
 
 
 def check(config: dict, toy: bool = False, stream: int = STREAM,
@@ -93,6 +106,7 @@ def check(config: dict, toy: bool = False, stream: int = STREAM,
         raise SystemExit(f"no TPU here ({kind}): this is chip evidence; "
                          "--toy rehearses on the CPU and says so")
     cfg = ModelConfig(**model_fields(config))
+    limit = float(config.get("parity_tolerance", LIMIT))
     sv = config["serve"]
     rt = RuntimeConfig(
         max_batch_size=sv["max_batch"] if toy else SLOTS,
@@ -126,12 +140,14 @@ def check(config: dict, toy: bool = False, stream: int = STREAM,
         rows=pos[keep].tolist()), np.float32)
     after = after[keep]
     out = {"device": kind, "evidence": "cpu toy" if toy else "chip",
-           "limit": LIMIT, "stream": int(stream),
+           "limit": limit, "stream": int(stream),
            "prompt": int(n_prompt), "chunk_width": C, "index_topk": topk,
            "past": past, "rows_before": int((~after).sum()),
            "rows_after": int(after.sum()), "kernels": kernels,
            "decode_read": "masked (ops/sparse_attention.py)" if any(
                k.startswith("sparse_attention") for k in kernels)
+           else "masked (ops/latent_attention.py latent_select_attention)"
+           if any(k.startswith("latent_select") for k in kernels)
            else "gather"}
     for fault, (_, got) in served.items():
         read = _reading(got[keep], want)
@@ -145,8 +161,8 @@ def check(config: dict, toy: bool = False, stream: int = STREAM,
     out["positions"] = pos[keep].tolist()
     clean = out["clean"]
     out["ok"] = bool(
-        max(clean["before_median"], clean["after_median"]) < LIMIT
-        and all(out[f]["before_median"] < LIMIT < out[f]["after_median"]
+        max(clean["before_median"], clean["after_median"]) < limit
+        and all(out[f]["before_median"] < limit < out[f]["after_median"]
                 for f in FAULTS if f != "clean"))
     return out
 
