@@ -66,6 +66,14 @@ semantics, TPU-native mechanics):
   beside it, under the one table: two leaves in the pool, the window,
   the stage and the flush, where every other model has one, three or
   five.
+* An index key too is laid in whole lanes (index_row: Keye's 64 values
+  in a row of 128, the lanes behind them zero; GLM-5's 128 as they
+  are), in the pool and in the window alike, for the latent row's
+  reason: the tiled layout pads a row of 64 bfloat16 to a lane tile
+  whatever is declared, and Mosaic copies whole tiles, so only a pool
+  declared so can be read where it lies (ops/index_scores.py; PERF.md,
+  PR 53). The writers pad a key on its way in (_in_lanes) and XLA's
+  reader cuts the view back to Hi.
 """
 from __future__ import annotations
 
@@ -95,6 +103,7 @@ from butterfly_tpu.models.common import (
     stream_write,
     select_topk, ssm_unsupported)
 from butterfly_tpu.ops import note_kernel
+from butterfly_tpu.ops import index_scores as paged_index
 from butterfly_tpu.ops import latent_attention, sparse_attention
 from butterfly_tpu.ops.flash_attention import flash_attention_sharded
 from butterfly_tpu.ops.paged_attention import paged_attention_sharded
@@ -166,6 +175,18 @@ def pool_row(cfg: ModelConfig) -> Tuple[int, int]:
     return cfg.num_kv_heads, cfg.head_dim
 
 
+def index_row(cfg: ModelConfig) -> int:
+    """Width of a cached index key, in the pool and in the window:
+    index_head_dim in whole lanes (the module's docstring)."""
+    return -(-cfg.index_head_dim // LANES) * LANES
+
+
+def _in_lanes(ki: jax.Array, width: int) -> jax.Array:
+    """Index keys [.., Hi] as they are cached: zeros behind them up to
+    the pool's `width` (index_row)."""
+    return jnp.pad(ki, [(0, 0)] * (ki.ndim - 1) + [(0, width - ki.shape[-1])])
+
+
 def pool_layout(cfg: ModelConfig) -> str:
     """pool_row by name, as /health reports it: "token", "head", or
     "latent" (one row a token and no value pool)."""
@@ -198,7 +219,7 @@ def init_paged_cache(cfg: ModelConfig, runtime: RuntimeConfig,
     if runtime.kv_quant == "int8":
         indexer_unsupported(cfg, "the int8 KV cache")
         latent_unsupported(cfg, "the int8 KV cache")
-    ki_shape = (L, P, 1, page, cfg.index_head_dim)
+    ki_shape = (L, P, 1, page, index_row(cfg))
 
     def build():
         table = jnp.full((runtime.max_batch_size, max_pages), P - 1,
@@ -298,13 +319,13 @@ def write_index_layer(ki_pages: jax.Array, page_table: jax.Array,
                       ki: jax.Array, start: jax.Array,
                       active: Optional[jax.Array] = None) -> jax.Array:
     """write_paged_layer for the third kind of row: index keys ki
-    [B, T, Hi] into one layer's ki_pages [P, 1, page, Hi], at the pages
-    and offsets their keys and values go to."""
-    Pp, _, page, _ = ki_pages.shape
+    [B, T, Hi] into one layer's ki_pages [P, 1, page, index_row], at the
+    pages and offsets their keys and values go to."""
+    Pp, _, page, width = ki_pages.shape
     B, T = ki.shape[:2]
     pages, off = _write_targets(page_table, Pp, page, start, T, active)
     return ki_pages.at[pages, 0, off].set(
-        ki.reshape(B * T, -1).astype(ki_pages.dtype))
+        _in_lanes(ki.reshape(B * T, -1), width).astype(ki_pages.dtype))
 
 
 def _table_pages(pages: jax.Array, page_table: jax.Array, layer):
@@ -513,6 +534,8 @@ def stage_window_layer(window: KVWindow, layer, k, v, ki, slot, idx, runs,
     if window.quantized:
         (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
         scale_rows = [ks, vs]
+    if ki is not None:
+        ki = _in_lanes(ki, window.ki.shape[4])
     for leaf, a in ((window.k, k), (window.v, v), (window.ki, ki)):
         if leaf is not None:
             rows.append(a.reshape(N, *leaf.shape[2:3],
@@ -761,47 +784,74 @@ def _pool_rows(pages: jax.Array, row: jax.Array) -> jax.Array:
 MASKED_READ_SPAN = 4
 
 
-def _index_selection(index, wki, base, page_table, layer, mask, topk: int,
-                     select: str, scatter: bool):
+def _index_selection(index, win, layer, *, page_table, positions, mask,
+                     active, topk: int, select: str, scatter: bool,
+                     use_kernel: bool):
     """The read side of a sparse-attention indexer, for keys and values
     (sparse_paged_attend) and for latent rows (latent_paged_attend)
-    alike: index (qi [B,T,Ni,Hi], w [B,T,Ni], kip [L,P,1,page,Hi]) as
-    index_proj and the pool give them; wki [B,1,W,Hi] the rows' slots'
-    staged index keys of `layer` (window_rows) or None without a window,
-    base [B] the flushed pool length they start at; mask [B,T,S_max]
-    what each query MAY attend. Returns (scores [B,T,S_max], topk,
-    live [B,T]): every query's score of every position of its stream's
-    table (models.common.index_scores), how many of them it attends, and
-    how many it could.
+    alike: index (qi [B,T,Ni,Hi], w [B,T,Ni], kip [L,P,1,page,index_row])
+    as index_proj and the pool give them; win, page_table, positions,
+    active as paged_attend's (the window AFTER staging, its index keys
+    [L,S,1,W,index_row] among its leaves); mask [B,T,S_max] what each
+    query MAY attend. Returns (scores [B,T,S_max], topk, live [B,T]):
+    every query's score of every position of its stream's table
+    (models.common.index_scores), how many of them it attends, and how
+    many it could. A score at a position the mask leaves out is a number
+    that means nothing.
 
-    scatter, the ONE difference between the two callers, and only in how
-    the staged keys reach their positions: False inserts the KEYS into
-    the table's view and scores the view (Keye's program since PR 36:
-    its 64-wide window keys' slice fuses into the insert); True scores
-    them where they lie and the SCORES take their positions, without a
-    write of the keys into the view (at 128 wide XLA cut the layer's
-    slice of the carried window leaf out in a fusion of its own, 2 MB a
-    layer, which tools/chip_kernels.py's window_moves refuses: PERF.md
-    section 7, PR 52). The same scores either way.
+    A decode row (T == 1) with kernels on scores its slot's LIVE pages
+    where they lie in the pool, and the window's staged keys where they
+    lie in the window, through the Pallas call (ops/index_scores.py:
+    pool positions up to the FLUSHED length, or with no window up to
+    the token just written). Every other program gathers the table's
+    index keys to one view of S_max positions (gather_paged_layer) and
+    scores the view, the staged keys reaching their positions one of
+    two ways:
+
+    scatter, the ONE difference between the two callers, and only on
+    the view's path: False inserts the KEYS into the table's view and
+    scores the view (Keye's program since PR 36: its window keys' slice
+    fuses into the insert); True scores them where they lie and the
+    SCORES take their positions, without a write of the keys into the
+    view (GLM-5's: XLA cut the layer's slice of the carried window leaf
+    out in a fusion of its own, 2 MB a layer, which
+    tools/chip_kernels.py's window_moves refuses: PERF.md section 7,
+    PR 52). The same scores either way.
 
     select (tools/sparse_parity.py's controls; "index" everywhere
     else): "all" attends every position, "recent" the last topk in
     place of the indexer's choice."""
     qi, w, kip = index
+    B, T, _, Hi = qi.shape
     S_max = mask.shape[-1]
+    window = base = slots = None
+    if win is not None:
+        window, win_len, slots = win
+        base = positions[:, 0] - win_len    # flushed pool length per row
     with jax.named_scope("attn_index"):
-        # the stream's index keys as keys are viewed: one KV head
-        kiv = gather_paged_layer(kip, page_table, layer)   # [B,S_max,1,Hi]
-        if wki is not None and not scatter:
-            kiv = insert_window_view(kiv, wki, base)
-        scores = index_scores(qi, w, kiv[:, :, 0])         # [B,T,S_max]
-        if wki is not None and scatter:
-            B, T = qi.shape[:2]
-            at = base[:, None] + jnp.arange(wki.shape[2])[None, :]
-            scores = scores.at[
-                jnp.arange(B)[:, None, None], jnp.arange(T)[None, :, None],
-                at[:, None, :]].set(index_scores(qi, w, wki[:, 0]),
-                                    mode="drop")
+        if T == 1 and use_kernel and slots is None and paged_index.fits(
+                kip, 0 if win is None else window.width):
+            lens = positions[:, 0] + 1 if win is None else base
+            scores = paged_index.index_scores(
+                qi[:, 0], w[:, 0], kip, layer, page_table,
+                jnp.where(active, lens, 0),
+                None if win is None else window.ki)[:, None]
+        else:
+            # the stream's index keys as keys are viewed: one KV head,
+            # the lanes behind a key cut off (index_row)
+            kiv = gather_paged_layer(kip, page_table, layer)[..., :Hi]
+            wki = None if win is None else window_rows(
+                window.ki, layer, slots)[..., :Hi]         # [B,1,W,Hi]
+            if wki is not None and not scatter:
+                kiv = insert_window_view(kiv, wki, base)
+            scores = index_scores(qi, w, kiv[:, :, 0])     # [B,T,S_max]
+            if wki is not None and scatter:
+                at = base[:, None] + jnp.arange(wki.shape[2])[None, :]
+                scores = scores.at[
+                    jnp.arange(B)[:, None, None],
+                    jnp.arange(T)[None, :, None],
+                    at[:, None, :]].set(index_scores(qi, w, wki[:, 0]),
+                                        mode="drop")
     if select == "all":
         scores = jnp.zeros_like(scores)
         topk = S_max
@@ -865,15 +915,16 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
     B, T = q.shape[:2]
     page = kp.shape[3]
     S_max = page_table.shape[1] * page
-    wki = base = None
+    base = None
     if win is not None:
         window, win_len, slots = win
-        wk, wv, wki = (window_rows(a, layer, slots)
-                       for a in (window.k, window.v, window.ki))
+        wk, wv = (window_rows(a, layer, slots) for a in (window.k, window.v))
         base = positions[:, 0] - win_len    # flushed pool length per row
     scores, topk, live = _index_selection(
-        (qi, w, kip), wki, base, page_table, layer, mask, cfg.index_topk,
-        select, scatter=False)
+        (qi, w, kip), win, layer, page_table=page_table,
+        positions=positions, mask=mask, active=active,
+        topk=cfg.index_topk, select=select, scatter=False,
+        use_kernel=use_kernel)
     if T == 1 and use_kernel and S_max <= MASKED_READ_SPAN * cfg.index_topk \
             and (win is None or slots is None) and sparse_attention.fits(
                 kp, q.shape[-1], 0 if win is None else window.width):
@@ -972,10 +1023,10 @@ def latent_paged_attend(q, kp, layer, *, cfg: ModelConfig, page_table,
         window, win_len, slots = win
         base = start - win_len      # flushed pool length per row
     if index is not None:
-        wki = None if win is None else window_rows(window.ki, layer, slots)
         scores, topk, live = _index_selection(
-            index, wki, base, page_table, layer, mask, cfg.index_topk,
-            select, scatter=True)
+            index, win, layer, page_table=page_table, positions=positions,
+            mask=mask, active=active, topk=cfg.index_topk, select=select,
+            scatter=True, use_kernel=use_kernel)
         mask = select_mask(scores, mask, topk)
     out = None
     if use_kernel and T == 1 and latent_attention.fits(

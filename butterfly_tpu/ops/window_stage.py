@@ -62,8 +62,9 @@ def window_step(window: int) -> int:
 def fits(leaf: jax.Array) -> bool:
     """Can the kernel stage into this leaf [L, S, Kv, W, H]? Compiled, a
     group is whole tiles of any dtype and a row whole lanes (Mosaic
-    refuses to copy Keye's index keys, rows of 64); interpreted (the
-    CPU backend) any leaf will do."""
+    refuses to copy a row of 64, which is why an index key is cached in
+    a row of 128: cache/paged.py index_row); interpreted (the CPU
+    backend) any leaf will do."""
     return resolve_interpret(None) or (
         leaf.shape[3] % WINDOW_STEP == 0 and leaf.shape[4] % 128 == 0)
 

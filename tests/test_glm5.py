@@ -203,11 +203,11 @@ def test_the_pool_holds_a_latent_row_and_an_index_key_a_token():
     cache = init_paged_cache(CFG, RT)
     assert pool_row(CFG) == (1, LANES) and pool_layout(CFG) == "latent"
     assert cache.k_pages.shape == (3, 3 * 16 + 1, 1, 4, LANES)
-    assert cache.ki_pages.shape == (3, 3 * 16 + 1, 1, 4, CFG.index_head_dim)
+    assert cache.ki_pages.shape == (3, 3 * 16 + 1, 1, 4, LANES)
     assert cache.v_pages is None and cache.k_scale_pages is None
     win = init_kv_window(cache, 8)
     assert win.v is None and win.k.shape == (3, 3, 1, 8, LANES)
-    assert win.ki.shape == (3, 3, 1, 8, CFG.index_head_dim)
+    assert win.ki.shape == (3, 3, 1, 8, LANES)
     # the published sizes: a row of 576 values in five lane tiles, an
     # index key of 128
     big = glm5()
@@ -345,9 +345,19 @@ def test_both_callers_of_the_indexer_s_read_side_score_alike(select):
     base = jnp.asarray([0, 9, S_max - 2], jnp.int32)
     mask = jnp.arange(S_max)[None, None] < (
         base[:, None, None] + 1 + jnp.arange(Tq)[None, :, None])
-    got = [paged._index_selection((qi, w, kip), wki, base, table, 1, mask,
-                                  8, select, scatter=scatter)
-           for scatter in (False, True)]
+    # the window whole, as the function takes it: layer 1 holds the keys
+    window = paged.KVWindow(
+        k=jnp.zeros((L, S, 1, W, 1)), v=None,
+        ki=jnp.stack([jnp.zeros_like(wki), wki]))
+
+    def selection(win, scatter):
+        return paged._index_selection(
+            (qi, w, kip), win, 1, page_table=table, positions=base[:, None],
+            mask=mask, active=None, topk=8, select=select, scatter=scatter,
+            use_kernel=False)
+
+    staged_at_base = (window, jnp.zeros((S,), jnp.int32), None)
+    got = [selection(staged_at_base, scatter) for scatter in (False, True)]
     for a, b in zip(*got):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     scores, topk, live = got[0]
@@ -356,8 +366,7 @@ def test_both_callers_of_the_indexer_s_read_side_score_alike(select):
     if select == "index":
         # the staged keys' scores stand at base .. base + W - 1, and a
         # window leaves every other position's score as the pool's
-        pool, _, _ = paged._index_selection(
-            (qi, w, kip), None, None, table, 1, mask, 8, select, scatter=True)
+        pool, _, _ = selection(None, True)
         staged = np.asarray(common.index_scores(qi, w, wki[:, 0]))
         for s_, b_ in enumerate(np.asarray(base)):
             n = min(W, S_max - b_)

@@ -5,6 +5,7 @@ LOGITS (and states) against the plain float32 reference
 (servebench/references/granite_hybrid_f32.py), which shares no code
 with the program."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -672,39 +673,33 @@ WINDOWED = {
     # four residual streams [4, N, 1, D] in the carry beside the window
     "runs_xing": (("xing", dict(kv_lora_rank=128, hidden_size=128)), "none"),
     "keye": (("keye", dict(head_dim=128)), "none"),
+    # an indexer over LATENT rows: two leaves, the row and the index key
+    "glm5": (("glm5", dict(kv_lora_rank=128)), "none"),
 }
 
 
-@pytest.mark.parametrize("chunks", [1, 0], ids=["mixed", "decode"])
-@pytest.mark.parametrize("model", sorted(WINDOWED))
-def test_a_block_moves_no_window_inside_its_loops(model, chunks, one_chip,
-                                                  monkeypatch):
-    """The engine's mixed block and its decode block (engine/serving.py
-    _packed_scan: steps around layers), kernels ON, compiled for the
-    TPU: the window rides every layer scan whole, in the carry, and is
-    written in place, so no `copy`, `transpose` or fusion inside any
-    loop makes a value of a leaf's whole shape [L, S, Kv, W, H] nor of
-    one layer's slice of it (tools/chip_kernels.py window_moves; as
-    scanned inputs and stacked outputs the leaves were copied whole in
-    every step: PERF.md, PR 47). A plain scan with bfloat16 and int8
-    leaves and their scales, a sliding model, the runs of granite's and
-    JoyAI's layers, Keye's token-major leaves and index keys, which
-    XLA stages; and the runs of a model of four residual streams, the
-    second thing to ride the carry: every sublayer computes them anew,
-    so a fusion of their shape is the mixing itself, and what they are
-    held to is no `copy` and no `transpose` of [n, N, 1, D] in a loop."""
+def _tools():
+    """tools/chip_kernels.py, which is no package's."""
     import sys
-    from functools import partial
     from pathlib import Path
-
-    from butterfly_tpu.cache.paged import paged_forward_packed
-    from butterfly_tpu.engine.serving import _packed_scan
     root = Path(__file__).resolve().parent.parent
     sys.path.insert(0, str(root / "tools"))
     try:
-        from chip_kernels import window_moves
+        import chip_kernels
     finally:
         sys.path.remove(str(root / "tools"))
+    return chip_kernels
+
+
+def _compiled_block(model, chunks, one_chip, monkeypatch):
+    """(cfg, cache, window, S, C, compiled text): the engine's mixed
+    (chunks 1) or decode block (engine/serving.py _packed_scan: steps
+    around layers) of a WINDOWED model, kernels ON, compiled for a
+    described v5e."""
+    from functools import partial
+
+    from butterfly_tpu.cache.paged import paged_forward_packed
+    from butterfly_tpu.engine.serving import _packed_scan
     (arch, kw), kv_quant = WINDOWED[model]
     cfg = tiny(arch, **kw).replace(dtype="bfloat16")
     S, k, C, W = 4, 2, 32, 64
@@ -746,22 +741,86 @@ def test_a_block_moves_no_window_inside_its_loops(model, chunks, one_chip,
     finally:
         monkeypatch.undo()
         jax.clear_caches()
-    hlo = compiled.as_text()
+    return cfg, cache, window, S, C, compiled.as_text()
+
+
+def _mosaic_calls(hlo, name):
+    return [line for line in hlo.splitlines()
+            if "tpu_custom_call" in line and " custom-call(" in line
+            and line.strip().startswith("%" + name)]
+
+
+@pytest.mark.parametrize("chunks", [1, 0], ids=["mixed", "decode"])
+@pytest.mark.parametrize("model", sorted(WINDOWED))
+def test_a_block_moves_no_window_inside_its_loops(model, chunks, one_chip,
+                                                  monkeypatch):
+    """The engine's mixed block and its decode block (engine/serving.py
+    _packed_scan: steps around layers), kernels ON, compiled for the
+    TPU: the window rides every layer scan whole, in the carry, and is
+    written in place, so no `copy`, `transpose` or fusion inside any
+    loop makes a value of a leaf's whole shape [L, S, Kv, W, H] nor of
+    one layer's slice of it (tools/chip_kernels.py window_moves; as
+    scanned inputs and stacked outputs the leaves were copied whole in
+    every step: PERF.md, PR 47). A plain scan with bfloat16 and int8
+    leaves and their scales, a sliding model, the runs of granite's and
+    JoyAI's layers, Keye's token-major leaves and index keys, GLM-5's
+    latent rows and index keys; and the runs of a model of four residual
+    streams, the second thing to ride the carry: every sublayer computes
+    them anew, so a fusion of their shape is the mixing itself, and what
+    they are held to is no `copy` and no `transpose` of [n, N, 1, D] in
+    a loop.
+
+    A model with an indexer (PR 53): its decode rows' index scores come
+    from ops/index_scores.py, ONE call a layer scan beside the one
+    selecting read, the window's index keys whole among its operands,
+    and NO value inside a loop has the shape of the table's index keys
+    as a view of every slot (tools/chip_kernels.py index_views: the
+    gather and its relayout were a quarter of `keye30b.think`'s busy
+    time; a chunk's view is one slot's and stays)."""
+    tools = _tools()
+    cfg, cache, window, S, C, hlo = _compiled_block(model, chunks, one_chip,
+                                                    monkeypatch)
     leaves = jax.tree.leaves(window)
-    assert window_moves(hlo, leaves) == []
+    assert tools.window_moves(hlo, leaves) == []
     if cfg.hc_mult:
-        streams = sds((cfg.hc_mult, S + chunks * C, 1, cfg.hidden_size),
-                      jnp.bfloat16)
-        assert window_moves(hlo, [streams], kinds=("copy", "transpose")) == []
-    calls = [line for line in hlo.splitlines()
-             if "tpu_custom_call" in line and " custom-call(" in line]
-    # the Mosaic writer stages every window but Keye's (rows of 16
-    # index keys are no whole lanes), and where it does the leaves are
-    # its operands whole
-    staged = [c for c in calls if c.strip().startswith("%stage_window")]
-    assert bool(staged) == (model != "keye")
+        streams = jax.ShapeDtypeStruct(
+            (cfg.hc_mult, S + chunks * C, 1, cfg.hidden_size), jnp.bfloat16)
+        assert tools.window_moves(hlo, [streams],
+                                  kinds=("copy", "transpose")) == []
+    # the Mosaic writer stages every window (Keye's too since PR 53: an
+    # index key is cached in whole lanes), the leaves its operands whole
+    staged = _mosaic_calls(hlo, "stage_window")
     whole = "[" + ",".join(map(str, leaves[0].shape)) + "]"
-    assert all(whole in c for c in staged)
+    assert staged and all(whole in c for c in staged)
+    scored = _mosaic_calls(hlo, "index_scores")
+    assert bool(scored) == cfg.has_indexer
+    if cfg.has_indexer:
+        # one call a layer scan: a run of layers of one kind is one scan
+        assert len(scored) == len(layer_runs(cfg))
+        keys = "[" + ",".join(map(str, window.ki.shape)) + "]"
+        assert all(keys in c for c in scored)
+        # its result is [S, S_max] in XLA's own dense tiles: declared
+        # [S, 1, S_max], a row was a TILE (T(1,128)), the selection's
+        # sort inherited the layout, and `glm5-ep16.think` read 27 %
+        # slower for it (PERF.md, PR 53)
+        assert all(re.match(rf"\s*%\S+ = f32\[{S},\d+\]\{{1,0:T\([48],128\)", c)
+                   for c in scored)
+        assert not [line for line in hlo.splitlines()
+                    if " sort(" in line and "T(1,128)" in line]
+        assert tools.index_views(hlo, cache, cfg.index_head_dim) == []
+
+
+def test_the_view_of_every_slot_s_index_keys_is_what_the_walk_replaced(
+        one_chip, monkeypatch):
+    """The control of the check above: the same decode block with the
+    call refused (ops/index_scores.py fits) gathers the table's index
+    keys to one view of every slot, and index_views finds it."""
+    from butterfly_tpu.ops import index_scores
+    monkeypatch.setattr(index_scores, "fits", lambda *a, **k: False)
+    cfg, cache, _, _, _, hlo = _compiled_block("keye", 0, one_chip,
+                                               monkeypatch)
+    assert not _mosaic_calls(hlo, "index_scores")
+    assert _tools().index_views(hlo, cache, cfg.index_head_dim)
 
 
 def test_weights_built_leaf_by_leaf_have_the_same_tree():

@@ -154,7 +154,7 @@ def test_paged_prefill_chunks_then_decode(params, tokens, want):
     """A fresh chunk inside topk, a warm chunk that crosses it, then
     decode steps one token at a time (each reads only what it selected)."""
     cache = paged_cache()
-    assert cache.ki_pages.shape == (2, 33, 1, 4, 16)
+    assert cache.ki_pages.shape == (2, 33, 1, 4, paged.index_row(CFG))
     a, cache = paged_forward(params, CFG, jnp.asarray(tokens[:, :5]), cache,
                              fresh=True)
     b, cache = paged_forward(params, CFG, jnp.asarray(tokens[:, 5:24]), cache)
@@ -173,7 +173,7 @@ def test_windowed_decode_across_a_flush(params, tokens, want):
     a, cache = paged_forward(params, CFG, jnp.asarray(tokens[:, :20]), cache,
                              fresh=True)
     window = init_kv_window(cache, 8)
-    assert window.ki.shape == (2, 2, 1, 8, 16)
+    assert window.ki.shape == (2, 2, 1, 8, paged.index_row(CFG))
     wlen = jnp.zeros((2,), jnp.int32)
     rows = [a]
     for t in range(20, T):
@@ -455,13 +455,15 @@ def test_a_model_without_an_indexer_has_no_third_pool(arch):
 
 def test_a_model_with_an_indexer_holds_a_token_as_one_row():
     """Keys and values [L, P, 1, page, Kv*H], the window beside them,
-    the index keys as they were; under a tensor mesh the row's minor
-    dim is what is sharded, a chip's KV heads contiguous in it."""
+    the index keys one head of a whole lane tile; under a tensor mesh
+    the row's minor dim is what is sharded, a chip's KV heads contiguous
+    in it."""
     L, Kv, H = CFG.num_layers, CFG.num_kv_heads, CFG.head_dim
     assert pool_layout(CFG) == "token" and paged.pool_row(CFG) == (1, Kv * H)
     cache = init_paged_cache(CFG, RT)
     assert cache.k_pages.shape == cache.v_pages.shape == (L, 33, 1, 4, Kv * H)
-    assert cache.ki_pages.shape == (L, 33, 1, 4, CFG.index_head_dim)
+    assert paged.index_row(CFG) == paged.LANES > CFG.index_head_dim
+    assert cache.ki_pages.shape == (L, 33, 1, 4, paged.LANES)
     assert cache.page_size == 4 and cache.null_page == 32
     window = init_kv_window(cache, 8)
     assert window.k.shape == window.v.shape == (L, 2, 1, 8, Kv * H)

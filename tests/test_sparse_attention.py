@@ -195,15 +195,19 @@ def test_the_branch_is_chosen_by_the_table_and_topk_alone(staged):
     """The rule between the two reads is a shape: a table of up to
     MASKED_READ_SPAN x topk positions goes through the kernel (no sort
     with a payload, no gather of rows), one page more lowers to the
-    gather and notes no kernel; kernels off is the gather at any size.
-    (Three slots, as no other test here has: the wrapper notes its
-    kernel when it is TRACED, and jit keeps a trace of shapes it saw.)"""
+    gather and notes no read kernel; kernels off is the gather at any
+    size. The index SCORES of such a row come from their own call
+    wherever kernels are on, whatever the table (ops/index_scores.py,
+    PR 53). (Three slots, as no other test here has: the wrapper notes
+    its kernel when it is TRACED, and jit keeps a trace of shapes it saw.)"""
     text, log = traced(case((100, 60, 9), staged=staged), use_kernel=True)
-    assert log == {"sparse_attention:interpret": 1}
-    assert "pallas_call" in text and SORT not in text
+    assert log == {"index_scores:interpret": 1,
+                   "sparse_attention:interpret": 1}
+    assert text.count("pallas_call") == 2 and SORT not in text
     text, log = traced(case((100, 60, 9), staged=staged, mp=MP + 1),
                        use_kernel=True)
-    assert log == {} and "pallas_call" not in text and SORT in text
+    assert log == {"index_scores:interpret": 1}
+    assert text.count("pallas_call") == 1 and SORT in text
     text, log = traced(case((100, 60, 9), staged=staged), use_kernel=False)
     assert log == {} and "pallas_call" not in text and SORT in text
 

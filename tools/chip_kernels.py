@@ -39,6 +39,11 @@ bytes and the seconds the call took to trace and lower and to compile.
 (SPARSE_CELL) beside the gather of the selected rows it stands in for,
 both branches of cache/paged.py sparse_paged_attend on the same
 operands, and times each at the cell's contexts and at the table's ends.
+`index_cell_*` run ops/index_scores.py at both selecting cells' geometries
+(INDEX_CELLS) beside the gathered view it stands in for, both branches of
+cache/paged.py _index_selection on the same operands: the scores and the
+selections against each other, a call's time, a live page's, and the
+model's index-key bytes against the memory's rate.
 
 Last, the program `serve` spends its time in — the serving engine's
 fused decode block, at the full 8B width with the depth cut to two
@@ -402,6 +407,42 @@ def window_moves(hlo: str, leaves,
     return found
 
 
+def index_views(hlo: str, cache, key_width: int) -> list:
+    """The instructions INSIDE a compiled HLO text's loops that make the
+    index keys of every slot's whole table as ONE value: a result
+    [S * S_max, w], [S, S_max, (1,) w], [S * mp, page, w] or
+    [S, mp, (1,) page, w], w the width of a cached key (the pool's rows,
+    whole lanes) or of the key itself (`key_width`, index_head_dim:
+    XLA's reader cuts the lanes behind a key off). What a decode
+    row's scores were made from until PR 53 (gather_paged_layer of the
+    index-key pool, a relayout behind it) and what a program whose rows
+    score through ops/index_scores.py holds none of; a chunk's view is
+    ONE slot's, another shape. cache: the paged cache, arrays or shapes
+    (None for a model without an indexer: nothing to find)."""
+    import re
+    if cache.ki_pages is None:
+        return []
+    S, mp = cache.page_table.shape
+    page, width = cache.ki_pages.shape[3:]
+    w = rf"(?:{width}|{key_width})"
+    view = re.compile(
+        rf"\w+\[(?:{S * mp * page},{w}|{S},{mp * page},(?:1,)?{w}"
+        rf"|{S * mp},{page},{w}|{S},{mp},(?:1,)?{page},{w})\]")
+    bodies = set(re.findall(r"body=%([^\s,)]+)", hlo))
+    head = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{\s*$")
+    made = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\S+) ")
+    found, inside = [], False
+    for line in hlo.splitlines():
+        m = head.match(line)
+        if m:
+            inside = m.group(1) in bodies
+        elif inside:
+            m = made.match(line)
+            if m and view.match(m.group(2)):
+                found.append(m.group(1))
+    return found
+
+
 def selecting_calls(config: dict, hlo: str):
     """How many instructions of a compiled HLO text the benchmark's
     pattern for the selecting latent read tells
@@ -435,7 +476,10 @@ def cell_blocks(config: dict, hlo_dir=None) -> list:
     fits the chip's 15.75 GiB), the Mosaic calls by name, and the
     instructions inside its loops that move a carried leaf: a copy,
     transpose or fusion of a window leaf's shape, a copy or transpose
-    of the residual streams' [n, rows, 1, D] (window_moves); for a
+    of the residual streams' [n, rows, 1, D] (window_moves); for a model
+    with an indexer the values of the shape of the table's index keys
+    as a view of every slot (index_views: none since PR 53, the decode
+    rows score through ops/index_scores.py); for a
     latent cache whose rows an indexer selects, the instructions the
     benchmark's pattern tells as the selecting read (selecting_calls).
     The compiler's figures were the chip's to the megabyte (PR 41).
@@ -525,9 +569,11 @@ def cell_blocks(config: dict, hlo_dir=None) -> list:
                     + MOSAIC_CALL, hlo))),
                 window_moves=window_moves(hlo, jax.tree.leaves(window)),
                 stream_moves=window_moves(hlo, streams,
-                                          kinds=("copy", "transpose")))
+                                          kinds=("copy", "transpose")),
+                index_views=index_views(hlo, cache, cfg.index_head_dim))
             rec["ok"] = held < 15.75 * 2 ** 30 and not rec["window_moves"] \
-                and not rec["stream_moves"] and bool(rec["mosaic_calls"])
+                and not rec["stream_moves"] and not rec["index_views"] \
+                and bool(rec["mosaic_calls"])
             told = selecting_calls(config, hlo)
             if told is not None:
                 # a latent cache whose rows an indexer selects: every
@@ -1011,10 +1057,157 @@ def run_sparse_cell(name, small, want):
     return rec
 
 
+#: the two indexers ops/index_scores.py serves, at their cells' geometry
+#: (SPARSE_CELL's slots and table, pages of 16 in eight layers, a window
+#: of 256): name -> index heads, the width of an index key, and which
+#: way the view's path takes the window's keys (cache/paged.py
+#: _index_selection's `scatter`)
+INDEX_CELLS = {
+    "index_cell_keye": (16, 64, False),
+    "index_cell_glm5": (32, 128, True),
+}
+
+
+def run_index_cell(name, small, want):
+    """A decode row's index scores at a selecting cell's geometry
+    (INDEX_CELLS), both ways cache/paged.py _index_selection makes them,
+    on the same operands: the Pallas walk of the slot's live index-key
+    pages (ops/index_scores.py) and the table's keys gathered to a view
+    and scored by XLA. Their scores against each other at the positions
+    a row may attend (against the summands' size: XLA's product at
+    HIGHEST is itself six bfloat16 passes on the chip, no float64) and
+    the two selections as masks (a dead slot and
+    the table's two ends among the slots), then microseconds a call
+    over one step's layers, nanoseconds a live page and the model's
+    bytes (a live position's index_head_dim bfloat16) against 819 GB/s,
+    at contexts drawn as `think` draws them and flat at 512, the cell's
+    mean and 7,168 in every slot."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from butterfly_tpu.cache.paged import (LANES, KVWindow,
+                                           _index_selection)
+    from butterfly_tpu.models.common import select_mask
+    from butterfly_tpu.ops.index_scores import index_scores
+
+    rec = {"name": name, "ok": False}
+    S, mp, topk, flats = SPARSE_CELL
+    Ni, Hi, scatter = INDEX_CELLS[name]
+    L, page, W, flats = 8, 16, 256, (flats[0], 3584, flats[1])
+    if small:
+        S, mp, topk, flats, L, page, Ni, Hi, W = (
+            4, 48, 48, (30, 192), 2, 4, 2, Hi // 8, 8)
+    P, S_max, width = S * mp + 1, mp * page, -(-Hi // LANES) * LANES
+    try:
+        keys = jax.random.split(jax.random.PRNGKey(53), 4)
+        bf, f32 = jnp.bfloat16, jnp.float32
+
+        def cached(k, shape):
+            """Index keys as the pool holds them: zeros behind Hi."""
+            return jax.jit(lambda k: jnp.pad(
+                jax.random.normal(k, (*shape, Hi), bf),
+                [(0, 0)] * len(shape) + [(0, width - Hi)]))(k)
+
+        kip = cached(keys[0], (L, P, 1, page))
+        window = KVWindow(k=jnp.zeros((L, S, 1, W, LANES), bf), v=None,
+                          ki=cached(keys[1], (L, S, 1, W)))
+        qi = jax.random.normal(keys[2], (S, 1, Ni, Hi), f32)
+        w = jax.random.normal(keys[3], (S, 1, Ni), f32)
+        rs = np.random.RandomState(53)
+        table = jnp.asarray(rs.permutation(P - 1).reshape(S, mp) + 1,
+                            jnp.int32)
+        drawn = np.minimum(S_max, np.exp(rs.uniform(
+            np.log(S_max / 7), np.log(S_max * 4 / 7), S)).astype(int)
+            + rs.randint(0, S_max * 3 // 7 + 1, S))
+
+        def operands(ctx):
+            """A decode row a slot at position ctx - 1 (0: a dead slot),
+            1-4 rows staged before it."""
+            ctx = np.asarray(ctx)
+            pos = jnp.asarray(np.maximum(ctx - 1, 0), jnp.int32)
+            staged = jnp.asarray(np.minimum(rs.randint(1, 5, S),
+                                            np.maximum(ctx - 1, 0)), jnp.int32)
+            return pos, jnp.asarray(ctx > 0), staged
+
+        def branch(use_kernel, layers, size=False):
+            def run(qi, w, kip, table, window, pos, active, staged):
+                mask = active[:, None, None] & (
+                    jnp.arange(S_max)[None, None, :] <= pos[:, None, None])
+                return mask, [_index_selection(
+                    (qi, jnp.abs(w) if size else w, kip),
+                    (window, staged, None), ly,
+                    page_table=table, positions=pos[:, None], mask=mask,
+                    active=active, topk=topk, select="index",
+                    scatter=scatter, use_kernel=use_kernel)[0]
+                    for ly in layers]
+            return run
+
+        fixed = (qi, w, kip, table, window)
+        ctx = drawn.copy()
+        ctx[0], ctx[1], ctx[2] = 0, S_max, min(topk // 4, S_max)
+        probe = operands(ctx)
+        walked = jax.jit(branch(True, [3 % L])).lower(*fixed, *probe).compile()
+        rec["hlo_has"] = {w_: w_ in walked.as_text() for w_ in want}
+        mask, (got,) = walked(*fixed, *probe)
+        _, (ref,) = jax.jit(branch(False, [3 % L]))(*fixed, *probe)
+        # rounding is of the summands, relu(s) w a head, whose signs
+        # cancel in a score: the same sum under |w| is their size
+        _, (size,) = jax.jit(branch(False, [3 % L], True))(*fixed, *probe)
+        picked = [np.asarray(select_mask(a[:, 0], mask[:, 0], topk))
+                  for a in (got, ref)]
+        got, ref, size, mask = (np.asarray(a) for a in (got, ref, size, mask))
+        rec["max_err"] = float(np.max(
+            np.where(mask, np.abs(got - ref) / (1 + size), 0)))
+        rec["finite"] = bool(np.isfinite(got).all())
+        rec["selections_differ_at"] = int((picked[0] != picked[1]).sum())
+
+        def timed(fn, *args, n=10):
+            jax.block_until_ready(fn(*args))
+            t0 = time.perf_counter()
+            for _ in range(n):
+                r = fn(*args)
+            jax.block_until_ready(r)
+            return round((time.perf_counter() - t0) / n / L * 1e6, 1)
+
+        def step(use_kernel):
+            run = branch(use_kernel, range(L))
+            return jax.jit(lambda *a: sum(o.sum() for o in run(*a)[1]))
+
+        alone = jax.jit(lambda qi, w, kip, table, lens, wki: sum(
+            index_scores(qi[:, 0], w[:, 0], kip, ly, table, lens, wki).sum()
+            for ly in range(L)))
+        steps = {True: step(True), False: step(False)}
+        rec["contexts"] = {}
+        for label, ctx in [("drawn", drawn)] + [
+                (str(n), np.full(S, n)) for n in flats]:
+            pos, active, staged = operands(ctx)
+            rows = int(np.sum(ctx))
+            pages = int(np.sum(-(-np.asarray(pos - staged) // page)))
+            us = timed(alone, qi, w, kip, table, pos - staged, window.ki)
+            rec["contexts"][label] = {
+                "live_rows": rows, "live_pages": pages,
+                "kernel_alone_us": us,
+                "kernel_ns_a_live_page": round(us * 1e3 / max(pages, 1), 2),
+                "kernel_share_of_819_gb_s": round(
+                    100 * rows * 2 * Hi / 819e9 / (us / 1e6), 1),
+                "walk_branch_us": timed(steps[True], *fixed, pos, active,
+                                        staged),
+                "view_branch_us": timed(steps[False], *fixed, pos, active,
+                                        staged)}
+        rec["ok"] = bool(rec["finite"] and rec["max_err"] < 5e-6
+                         and rec["selections_differ_at"] == 0
+                         and all(rec["hlo_has"].values()))
+    except Exception as e:  # a compiler refusal is the finding: record it
+        rec["error"] = f"{type(e).__name__}: {e}"[:1500]
+    return rec
+
+
 #: the cells' windows for ops/window_stage.py (W 256, a chunk of 32
 #: columns beside the decode rows): name -> layers, slots, the row
 #: leaves' (heads, width) and dtype, int8 scale leaves too? (Keye's
-#: window is staged by XLA: Mosaic copies no row of 64, its index keys'.)
+#: window, three leaves since its index keys lie in whole lanes, PR 53,
+#: has no case here yet.)
 STAGE_CELLS = {
     "stage_cell_int8": (32, 32, ((8, 128),) * 2, "int8", True),
     "stage_cell_bf16": (16, 32, ((4, 128),) * 2, "bfloat16", False),
@@ -1225,6 +1418,8 @@ def main() -> int:
                 for n in STAGE_CELLS if wanted(n)]
     if wanted("sparse_cell"):
         results.append(run_sparse_cell("sparse_cell", args.small, want))
+    results += [run_index_cell(n, args.small, want)
+                for n in INDEX_CELLS if wanted(n)]
     if wanted("serve_block"):
         results.append(run_serving_block("serve_block", args.small, None,
                                          want))
@@ -1258,12 +1453,21 @@ def main() -> int:
                  if "call_us" in r else "")
               + (f" calls={r['kernel_calls']}" if "kernel_calls" in r else "")
               + "".join(
+                  f"\n     {label}: the walk alone {c['kernel_alone_us']}us "
+                  f"({c['kernel_ns_a_live_page']} ns a live page, "
+                  f"{c['kernel_share_of_819_gb_s']}% of 819 GB/s), its "
+                  f"branch {c['walk_branch_us']}us, the view's "
+                  f"{c['view_branch_us']}us"
+                  for label, c in r.get("contexts", {}).items()
+                  if "walk_branch_us" in c)
+              + "".join(
                   f"\n     {label}: kernel alone {c['kernel_alone_us']}us "
                   f"({c['kernel_ns_a_live_row']} ns a live row, "
                   f"{c['kernel_share_of_819_gb_s']}% of 819 GB/s), the "
                   f"masked branch {c['masked_branch_us']}us, the gather "
                   f"branch {c['gather_branch_us']}us"
-                  for label, c in r.get("contexts", {}).items())
+                  for label, c in r.get("contexts", {}).items()
+                  if "masked_branch_us" in c)
               + (f"\n     {r['error']}" if "error" in r else ""))
     ok = mode == "compiled" and all(r["ok"] for r in results)
     if mode != "compiled":
