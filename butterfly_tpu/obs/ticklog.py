@@ -13,8 +13,12 @@ Two bounded, always-cheap instruments the scheduler feeds:
   dispatched and the rows one step of it computes, the loop's wait
   for the serving lock, the process's count of compilations and, for a
   model of experts, what the drained blocks' routing asked of them,
-  and the starvation clock: how long the device waited for the host
-  before the tick's launches, why, and in which spans. The sequence number is also the ``seq`` of
+  the starvation clock: how long the device waited for the host
+  before the tick's launches, why, and in which spans, and the CPU
+  clock: the tick thread's CPU seconds, where it was OFF a CPU by
+  span, what the process's other threads, its collections and the
+  machine did meanwhile, and the account of a tick that stalled.
+  The sequence number is also the ``seq`` of
   the tick's ``bf.tick`` span in a profiler trace: the join between
   the two needs no clock. One dict append per tick under an
   uncontended lock — the software answer to "where does the tick's
@@ -99,7 +103,14 @@ class TickLog:
                ssm_load: Optional[Sequence[float]] = None,
                latent_load: Optional[Sequence[float]] = None,
                hc_load: Optional[Sequence[float]] = None,
-               share_load: Optional[Sequence[float]] = None) -> None:
+               share_load: Optional[Sequence[float]] = None,
+               cpu_s: Optional[float] = None,
+               off_cpu_by: Optional[Dict[str, float]] = None,
+               proc_cpu_s: Optional[float] = None,
+               gc_s: float = 0.0, gc_collections: int = 0,
+               gc_generation: Optional[int] = None,
+               run_delay_s: Optional[float] = None,
+               stall: Optional[Dict[str, Any]] = None) -> None:
         """Append one tick record (hot path: one dict build + one
         locked append per TICK, never per token). `phases` is copied —
         callers may reuse/zero their accumulator dict.
@@ -147,7 +158,24 @@ class TickLog:
         `starved_by`, the same seconds by the innermost span the host
         was in (`other`: the tick's own time; `outside_tick`: between
         two ticks). `gap_s`: this tick's start less the last tick's
-        end. `profiled`: a /debug/profile capture was running."""
+        end. `profiled`: a /debug/profile capture was running.
+        The CPU clock (Scheduler._lap, _account): `cpu_s`, the tick
+        thread's CPU seconds inside the tick; `off_cpu_by`, in a tick
+        that read the CPU clock at every span boundary (None in the
+        others: Scheduler._cpu_period), for each innermost span's
+        own name the wall of its laps less their CPU seconds, signed,
+        so the values sum to `wall_s - cpu_s`: in `drain.fetch` and
+        `drain.flush_count` the wait for the device, anywhere else a
+        lock, the interpreter lock, the runtime or the machine; and
+        `proc_cpu_s`, the CPU seconds of ALL the process's threads
+        inside such a tick (None in the others). `run_delay_s`: the
+        tick thread's seconds runnable with no CPU inside the tick
+        (None where the kernel keeps no schedstat). Since the last
+        tick's end: `gc_s`, `gc_collections` and `gc_generation`, the
+        interpreter's collections on any thread (seconds, count, the
+        oldest generation examined or None). `stall`: None, or
+        {phase, span, cause, excess_s} of a tick that took over ten
+        times the usual (Scheduler._note_stall)."""
         touched, rows_max, rows_mean, kv_live, kv_selected, kv_moved = \
             (tuple(expert_load or ()) + (None,) * 6)[:6]
         ssm_rows, state_resets, ssm_steps = ssm_load or (None,) * 3
@@ -194,6 +222,14 @@ class TickLog:
             "starved_by": dict(starved_by or {}),
             "gap_s": gap_s,
             "profiled": profiled,
+            "cpu_s": cpu_s,
+            "off_cpu_by": None if off_cpu_by is None else dict(off_cpu_by),
+            "proc_cpu_s": proc_cpu_s,
+            "gc_s": gc_s,
+            "gc_collections": gc_collections,
+            "gc_generation": gc_generation,
+            "run_delay_s": run_delay_s,
+            "stall": stall,
         }
         with self._lock:
             self._ring.append(entry)

@@ -55,8 +55,8 @@ engine/serving.py:
 from __future__ import annotations
 
 import contextlib
-import gc
 import itertools
+import math
 import statistics
 import time
 from collections import deque
@@ -72,6 +72,7 @@ from butterfly_tpu.cache.allocator import make_page_allocator
 from butterfly_tpu.cache.ssm_state import state_info
 from butterfly_tpu.engine.serving import (
     LAUNCH_SPAN, ServingEngine, bucket_len, sample_batched)
+from butterfly_tpu.obs.profile import run_delay_s
 from butterfly_tpu.obs.registry import (
     BATCH_BUCKETS, LATENCY_BUCKETS, TOKEN_BUCKETS, MetricsRegistry)
 from butterfly_tpu.obs.ticklog import TICK_PHASES, TickLog
@@ -94,11 +95,36 @@ def _device_ready(x) -> bool:
         return False
 
 
-#: a block fetch is a STALL (flight recorder note `stall`) when it took
-#: more than STALL_FACTOR times the median of the last 64 block fetches
-#: and more than STALL_MIN_S seconds
+#: a tick is a STALL (tick record `stall`, flight recorder note `stall`)
+#: when its wall took more than STALL_FACTOR times the median of the
+#: last 64 ticks that launched a block and compiled nothing, and more
+#: than STALL_MIN_S seconds, whatever phase held it; so is a block fetch
+#: by the same rule over the last 64 block fetches
 STALL_FACTOR = 10.0
 STALL_MIN_S = 0.25
+#: what a SAMPLED tick's clock reads (the thread's CPU clock at every
+#: span boundary, the process's at both ends) may cost a tick on
+#: average. On a plain Linux host they are fast calls (0.3 and 0.7 us)
+#: and every tick is sampled; on the sealed machine the benchmark's chip
+#: sits in they are slow ones (5.8 us in a loop, the process's 20-40 us
+#: inside a serving tick; both move in steps of 10 ms) and one tick in
+#: `Scheduler._cpu_period` is (seven to nine there)
+CPU_CLOCK_BUDGET_S = 25e-6
+#: span boundaries of a steady tick (ten spans, entered and left, and
+#: the starvation clock's start)
+SPAN_READS_A_TICK = 24
+
+
+def _thread_time_cost() -> float:
+    """Seconds one read of the thread's CPU clock takes HERE: the least
+    of three timings of eight reads."""
+    cost = math.inf
+    for _ in range(3):
+        t0 = time.monotonic()
+        for _ in range(8):
+            time.thread_time()
+        cost = min(cost, (time.monotonic() - t0) / 8)
+    return cost
 
 
 @dataclass
@@ -329,8 +355,13 @@ class Scheduler:
         # sets it): the tick record says so, because a capture
         # multiplies the host's phases
         self.profiled = False
-        # the last 64 block fetches, seconds: what a stall is told by
+        # the last 64 block fetches, seconds, and the last 64 ticks that
+        # launched a block and compiled nothing, (wall, CPU seconds,
+        # wall by phase, wall by span): what a stall is told by
         self._fetches: Deque[float] = deque(maxlen=64)
+        self._sound_ticks: Deque[tuple] = deque(maxlen=64)
+        # this tick's stall, once one is noted: one note a tick
+        self._tick_stall: Optional[Dict] = None
         # First tokens sampled on-device at admission, not yet fetched:
         # [(req, generation=req.preemptions, slot, device scalar)].
         # Fetched with the next drain, all in one jax.device_get (a
@@ -642,6 +673,25 @@ class Scheduler:
         # the last boundary, from which the innermost span is owed
         self._span_stack: List[str] = ["other"]
         self._span_t = time.monotonic()
+        # the tick thread's CPU clock at that boundary: a lap's wall
+        # less its CPU seconds is what the thread WAITED in the span
+        # (for the device, a lock, the interpreter lock, a CPU). Every
+        # tick reads it at its start and end; one tick in _cpu_period
+        # (`_cpu_on`) at every span boundary
+        self._span_cpu = self._tick_cpu0 = time.thread_time()
+        self._cpu_on = False
+        # what a read of each CPU clock costs: the thread's timed once,
+        # here; the process's at every read (it sums the threads there
+        # are), and with it how many ticks share a sampled one
+        self._thread_cost = _thread_time_cost()
+        self._proc_cost = 0.0
+        self._cpu_period = 1
+        self._tick_proc0 = 0.0
+        # the tick under way: its wall by the innermost span's own name
+        # and, where the CPU clock is on, its off-CPU seconds likewise
+        self._tick_wall_by: Dict[str, float] = {}
+        self._tick_off_cpu: Dict[str, float] = {}
+        self._tick_t0 = self._span_t
         # the innermost open span by its own name (sub-spans too):
         # "other" inside a tick, "outside_tick" between two
         self._span_name = "outside_tick"
@@ -666,6 +716,25 @@ class Scheduler:
         reg.counter(
             "compile_seconds_total",
             "Seconds spent tracing, lowering and compiling programs")
+        # the interpreter's collections (obs/profile.py
+        # count_collections feeds them, whichever thread collects): one
+        # holds the interpreter lock, so the tick waits through it
+        self._c_gc_s = reg.counter(
+            "gc_seconds_total",
+            "Seconds the interpreter spent in garbage collections, with "
+            "its lock held: every thread of the process waits")
+        gens = reg.counter_family(
+            "gc_collections_total",
+            "Garbage collections of the interpreter, by the oldest "
+            "generation each examined (2: a full collection)",
+            ("generation",))
+        self._c_gc_gen = [gens.labels(str(g)) for g in range(3)]
+        # the process's figures as the last tick ended (_account):
+        # collections' seconds and counts by generation, programs
+        # compiled; and the tick thread's own run delay as this tick
+        # began (its thread's to read)
+        self._tick_base: tuple = self._process_figures()
+        self._tick_delay0: Optional[float] = None
         # the fetch's device wait within this tick's drains: feeds
         # the host/device split (tick_host_frac / tick_device_frac) —
         # the fetch is the one tick section that blocks on the device
@@ -765,24 +834,46 @@ class Scheduler:
                 LATENCY_BUCKETS)
             for p in TICK_PHASES}
 
-    def _lap(self, now: float) -> None:
-        """A span boundary: the time since the last one is owed to the
-        innermost open span's phase and, while the starvation clock
-        runs, to the innermost span's own name in the clock's table.
+    def _clocks(self) -> tuple:
+        """A span boundary's two readings: the wall clock, and the tick
+        thread's CPU clock in a tick that reads it there (None in the
+        others)."""
+        return (time.monotonic(),
+                time.thread_time() if self._cpu_on else None)
+
+    def _lap(self, now: float, cpu: Optional[float]) -> None:
+        """A span boundary on both clocks (_clocks): the wall time since
+        the last one is owed to the innermost open span's phase and to
+        its own name, and what it holds beyond the tick thread's CPU
+        time since then (the thread WAITED) to the span's own name in
+        `off_cpu_by`. That difference is kept signed, so the table sums
+        to the tick's wall less its CPU seconds whatever the CPU
+        clock's step (10 ms where the kernel counts CPU time by timer
+        ticks: a lap shorter than a step is charged none or a whole
+        one, and only sums over many laps say what it used). While the
+        starvation clock runs the wall also goes to the clock's table.
         Plain dict arithmetic — never a sync."""
         d = now - self._span_t
         self._span_t = now
+        name = self._span_name
         self._tick_phases[self._span_stack[-1]] += d
+        by = self._tick_wall_by
+        by[name] = by.get(name, 0.0) + d
+        if cpu is not None:
+            by = self._tick_off_cpu
+            by[name] = by.get(name, 0.0) + d - (cpu - self._span_cpu)
+            self._span_cpu = cpu
         by = self._starved_by
         if by is not None:
-            by[self._span_name] = by.get(self._span_name, 0.0) + d
+            by[name] = by.get(name, 0.0) + d
 
     @contextlib.contextmanager
     def _span(self, name: str, **attrs):
         """One section of the tick on both clocks: a TraceAnnotation
         `bf.tick.<name>` in the profiler's trace (with no capture
         running: an atomic load, and `attrs` are never formatted),
-        and exclusive time.monotonic() time in the tick record.
+        and exclusive time.monotonic() and time.thread_time() time in
+        the tick record.
         Entering pauses the enclosing span's timer and leaving resumes
         it, so phases never overlap and sum to the tick's wall time. A
         TICK_PHASES name is charged to itself, any other name (a
@@ -793,15 +884,14 @@ class Scheduler:
         annotation (set_metadata adds what is known only at the
         end). Plain dict arithmetic — never a sync."""
         stack = self._span_stack
-        self._lap(time.monotonic())
+        self._lap(*self._clocks())
         stack.append(name if name in self._tick_phases else stack[-1])
         outer, self._span_name = self._span_name, name
         with TraceAnnotation("bf.tick." + name, **attrs) as ann:
             try:
                 yield ann
             finally:
-                now = time.monotonic()
-                self._lap(now)
+                self._lap(*self._clocks())
                 stack.pop()
                 self._span_name = outer
                 if name == LAUNCH_SPAN:
@@ -823,7 +913,7 @@ class Scheduler:
         the clock is a lower bound, exact where the fetch blocked. A
         clock that already runs keeps its start and its cause."""
         if self._starved_by is None:
-            self._lap(time.monotonic())
+            self._lap(*self._clocks())
             self._starved_by = {}
             self._starved_cause = cause
 
@@ -1144,12 +1234,22 @@ class Scheduler:
         # barrier-cause list, zero the fetch wait. The time since the
         # last tick's end (lock, wake, profile poll) is this tick's
         # gap, and the starvation clock's `outside_tick` if it runs.
-        t_tick0 = time.monotonic()
+        t_tick0 = self._tick_t0 = time.monotonic()
         self._tick_gap = t_tick0 - self._span_t
-        self._lap(t_tick0)
+        self._lap(t_tick0, None)
         self._span_name = "other"
         for p in TICK_PHASES:
             self._tick_phases[p] = 0.0
+        self._span_cpu = self._tick_cpu0 = time.thread_time()
+        self._tick_delay0 = run_delay_s()
+        self._cpu_on = self.ticklog.next_seq % self._cpu_period == 0
+        if self._cpu_on:
+            t0 = time.monotonic()
+            self._tick_proc0 = time.process_time()
+            self._proc_cost = time.monotonic() - t0
+        self._tick_wall_by = {}
+        self._tick_off_cpu = {}
+        self._tick_stall = None
         self._tick_starved = self._tick_starved_cause = None
         self._tick_starved_by = {}
         self._tick_causes = []
@@ -1281,14 +1381,25 @@ class Scheduler:
         poll. Host arithmetic only — no device value is ever touched
         here."""
         tp = self._tick_phases
-        now = time.monotonic()
-        self._lap(now)
+        now, cpu = time.monotonic(), time.thread_time()
+        self._lap(now, cpu if self._cpu_on else None)
         self._span_name = "outside_tick"
         if not self.has_work:
             # an empty server is not starved
             self._starved_by = None
         wall = now - t_tick0
         blocks = self.engine.blocks_launched
+        acct, figures = self._account(cpu)
+        sound = self._sound_ticks
+        if wall > STALL_MIN_S and sound and self._tick_stall is None:
+            usual = statistics.median(t[0] for t in sound)
+            if wall > STALL_FACTOR * usual:
+                over = self._tick_overlapped
+                self._note_stall(wall - usual, *self._held(), acct,
+                                 None if over is None else not over)
+        if blocks > blocks0 and not self._compiled():
+            sound.append((wall, acct["cpu_s"], dict(tp), self._tick_wall_by))
+        self._tick_base = figures
         for name, h in self._h_phase.items():
             h.observe(tp[name])
         fetch = min(self._tick_fetch, wall)
@@ -1319,7 +1430,10 @@ class Scheduler:
                             starved_s=self._tick_starved,
                             starved_cause=self._tick_starved_cause,
                             starved_by=self._tick_starved_by,
-                            gap_s=self._tick_gap, profiled=self.profiled)
+                            gap_s=self._tick_gap, profiled=self.profiled,
+                            off_cpu_by=self._tick_off_cpu
+                            if self._cpu_on else None,
+                            stall=self._tick_stall, **acct)
         self.loop_lock_s = 0.0
         if self.flightrec is not None:
             self.flightrec.poll({
@@ -1333,6 +1447,104 @@ class Scheduler:
         if ts is not None and ts.due():
             gauges, rates = self._timeseries_signals()
             ts.sample(gauges, rates=rates, t_wall=time.time())
+
+    def _process_figures(self) -> tuple:
+        """The process's running figures every tick takes deltas of: the
+        collections' seconds and their counts by generation, and the
+        programs compiled (counters of the registry: no system call)."""
+        return (self._c_gc_s.value, [c.value for c in self._c_gc_gen],
+                self._c_compiles.value)
+
+    def _account(self, cpu: float) -> tuple:
+        """The tick record's fields of these names, which are also what
+        a stall's cause is told by, and the figures they were taken
+        from. Since the tick began: `cpu_s`, the tick thread's CPU
+        seconds (`cpu`: its CPU clock now); `run_delay_s`, its time
+        runnable with no CPU to run on (None where the kernel keeps
+        none); and in a sampled tick `proc_cpu_s`, the CPU seconds of
+        ALL the process's threads (None in the others): less `cpu_s`
+        it is what the OTHER threads burned meanwhile (each a holder of
+        the interpreter lock, or native code beside it). Since the LAST
+        tick ended, the fraction of a millisecond between two ticks
+        included: `gc_s`, `gc_collections` and `gc_generation` (the
+        oldest generation examined, None where none ran), which count
+        collections on any thread."""
+        gc0, gens0, _ = self._tick_base
+        figures = gc_s, gens, _ = self._process_figures()
+        delay0, delay = self._tick_delay0, run_delay_s()
+        ran = [int(b - a) for a, b in zip(gens0, gens)]
+        proc = None
+        if self._cpu_on:
+            # the process's clock, and what its read costs with the
+            # threads there are now (the cheaper of the tick's two
+            # reads: one may have waited for a CPU), which says how
+            # many ticks share the next sampled one
+            t0 = time.monotonic()
+            proc = time.process_time() - self._tick_proc0
+            cost = min(self._proc_cost, time.monotonic() - t0)
+            self._cpu_period = max(1, math.ceil(
+                (SPAN_READS_A_TICK * self._thread_cost + 2 * cost)
+                / CPU_CLOCK_BUDGET_S))
+        return {"cpu_s": cpu - self._tick_cpu0, "proc_cpu_s": proc,
+                "gc_s": gc_s - gc0, "gc_collections": sum(ran),
+                "gc_generation": max((g for g, n in enumerate(ran) if n),
+                                     default=None),
+                "run_delay_s": None if delay is None or delay0 is None
+                else delay - delay0}, figures
+
+    def _compiled(self) -> bool:
+        """A program was compiled since the last tick ended."""
+        return self._c_compiles.value > self._tick_base[-1]
+
+    def _held(self) -> tuple:
+        """The TICK_PHASES name and the span's own name whose wall in
+        the tick under way lies furthest over their medians in the sound
+        ticks: where a stalled tick's excess sits."""
+        past = self._sound_ticks
+
+        def most(now: Dict[str, float], i: int) -> str:
+            return max(now, key=lambda k: now[k] - statistics.median(
+                t[i].get(k, 0.0) for t in past))
+        return most(self._tick_phases, 2), most(self._tick_wall_by, 3)
+
+    def _note_stall(self, excess: float, phase: str, span: str, acct: Dict,
+                    newest_ready: Optional[bool],
+                    fetch_s: Optional[float] = None) -> None:
+        """The one writer of a stall: the tick record's `stall` and the
+        flight recorder's note, one a tick. `excess` is the seconds over
+        the usual (a tick's wall over the sound ticks' median, a fetch
+        over the fetches'), `phase` and `span` where they sat, `acct`
+        the account so far (_account). The cause is the first of these
+        figures to cover half the excess: a compilation (any in the
+        tick), collections, the tick thread runnable without a CPU, the
+        process's other threads on CPUs (the interpreter lock's other
+        holders; known in a sampled tick alone), the tick thread's own
+        CPU beyond the usual; else the thread was `blocked`, off a CPU
+        for none of those reasons: a system call, a lock, the runtime,
+        the device."""
+        half = excess / 2.0
+        usual_cpu = statistics.median(
+            t[1] for t in self._sound_ticks) if self._sound_ticks else 0.0
+        if self._compiled():
+            cause = "compile"
+        elif acct["gc_s"] >= half:
+            cause = "gc"
+        elif (acct["run_delay_s"] or 0.0) >= half:
+            cause = "descheduled"
+        elif (acct["proc_cpu_s"] or 0.0) - acct["cpu_s"] >= half:
+            cause = "other_threads"
+        elif acct["cpu_s"] - usual_cpu >= half:
+            cause = "on_cpu"
+        else:
+            cause = "blocked"
+        self._tick_stall = {"phase": phase, "span": span, "cause": cause,
+                            "excess_s": excess}
+        if self.flightrec is not None:
+            self.flightrec.note(
+                "stall", tick=self.ticklog.next_seq, **self._tick_stall,
+                wall_s=time.monotonic() - self._tick_t0,
+                fetch_s=self._tick_fetch if fetch_s is None else fetch_s,
+                newest_ready=newest_ready, profiled=self.profiled, **acct)
 
     def _timeseries_signals(self):
         """The SignalRecorder's per-interval snapshot (gauges, rates):
@@ -2381,21 +2593,20 @@ class Scheduler:
 
     def _note_fetch(self, fetch_s: float,
                     newest_ready: Optional[bool]) -> None:
-        """Keep the last 64 block fetches, and leave a `stall` note in
-        the flight recorder where this one took more than STALL_FACTOR
-        times their median and more than STALL_MIN_S: one fetch in a
-        few thousand takes 0.3-8 s on the chip (ROADMAP.md A7), and
-        until now only a run's throughput reading low told of it.
-        `tick` is the seq of the tick under way (the note's own `seq`
-        is the recorder's), `newest_ready` whether the newest block in
-        flight had ended when the fetch returned (None: none was)."""
+        """Keep the last 64 block fetches, and call this one a stall
+        (_note_stall, with the account as the fetch returned) where it
+        took more than STALL_FACTOR times their median and more than
+        STALL_MIN_S: one fetch in a few thousand takes 0.3-8 s on the
+        chip (ROADMAP.md A7). `newest_ready`: whether the newest block
+        in flight had ended when the fetch returned (None: none was)."""
         past = self._fetches
-        if (fetch_s > STALL_MIN_S and past and self.flightrec is not None
-                and fetch_s > STALL_FACTOR * statistics.median(past)):
-            self.flightrec.note(
-                "stall", tick=self.ticklog.next_seq, fetch_s=fetch_s,
-                newest_ready=newest_ready, gc=list(gc.get_count()),
-                profiled=self.profiled)
+        if fetch_s > STALL_MIN_S and past and self._tick_stall is None:
+            usual = statistics.median(past)
+            if fetch_s > STALL_FACTOR * usual:
+                self._note_stall(fetch_s - usual, self._span_stack[-1],
+                                 "drain.fetch",
+                                 self._account(time.thread_time())[0],
+                                 newest_ready, fetch_s)
         past.append(fetch_s)
 
     def _emit_drained(self, firsts: List[tuple], first_vals: List,

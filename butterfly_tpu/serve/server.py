@@ -1424,8 +1424,9 @@ def run_server(args) -> int:
                       slo_ttft_s=slo_ttft / 1e3 if slo_ttft else None,
                       slo_itl_s=slo_itl / 1e3 if slo_itl else None,
                       flightrec=flightrec, timeseries=timeseries)
-    from butterfly_tpu.obs.profile import count_compiles
+    from butterfly_tpu.obs.profile import count_collections, count_compiles
     count_compiles(sched.registry)  # once: the warm-up's count as set-up
+    count_collections(sched.registry)
     # On-demand XProf server (--profiler-port): TensorBoard/XProf can
     # then trigger captures of the live process. Failure to start
     # (port in use, no profiler plugin) logs and serves without it —

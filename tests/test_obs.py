@@ -686,6 +686,84 @@ def test_trace_report_prints_the_starved_seconds(tmp_path):
     assert "by cause: finish 30.0ms" in out.stdout
 
 
+@pytest.mark.parametrize("tool", ["tick_report", "trace_report"])
+def test_both_reports_print_the_cpu_clock_and_a_stalls_account(tmp_path, tool):
+    """`tick_report.py` and `trace_report.py --ticks` read the CPU clock
+    of a /debug/ticks dump: the tick thread's CPU seconds, where it was
+    off a CPU by span (the device waits marked), what the process did
+    meanwhile and the account of every stalled tick; nothing for the
+    records of a program older than the clock."""
+    from butterfly_tpu.obs.ticklog import TickLog
+    log = TickLog()
+    for _ in range(3):
+        log.record(0.10, {"other": 0.10}, fetch_s=0.06, cpu_s=0.03,
+                   off_cpu_by={"drain.fetch": 0.06, "drain.emit": 0.01},
+                   proc_cpu_s=0.05, gc_s=0.002, gc_collections=1,
+                   gc_generation=0, run_delay_s=0.001)
+    stall = {"phase": "mixed", "span": "dispatch.launch",
+             "cause": "blocked", "excess_s": 3.75}
+    log.record(3.85, {"mixed": 3.85}, fetch_s=0.03, cpu_s=0.04,
+               off_cpu_by={"drain.fetch": 0.03, "dispatch.launch": 3.78},
+               proc_cpu_s=0.10, run_delay_s=0.0, stall=stall)
+    # one that read the CPU clock at its two ends alone: no table
+    log.record(0.10, {"other": 0.10}, fetch_s=0.06, cpu_s=0.02,
+               run_delay_s=0.0)
+    log.record(0.10, {"other": 0.10})          # an older caller: no clock
+    ticks = tmp_path / "ticks.json"
+    ticks.write_text(json.dumps(log.dump()))
+    if tool == "tick_report":
+        cmd = [str(REPO / "tools" / "tick_report.py"), str(ticks)]
+    else:
+        cmd = [str(REPO / "tools" / "trace_report.py"),
+               str(_synthetic_dump(tmp_path / "trace.json")),
+               "--ticks", str(ticks)]
+    out = subprocess.run([sys.executable, *cmd], capture_output=True,
+                         text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+    text = out.stdout
+    # five clocked ticks of 4.25 s, 0.15 s of them on a CPU; the four that
+    # carry the table span 4.15 s: 0.21 s in the device waits, 3.81 s off
+    # a CPU elsewhere
+    assert "tick thread on a CPU 0.1500s (3.5% of tick wall, 30.000 ms a " \
+        "tick)" in text
+    assert "in the 4 of 5 tick(s) that clocked every span (4.1500s wall): " \
+        "other threads' CPU 0.1200s; off a CPU 0.2100s (5.1%) waiting for " \
+        "the device, 3.8100s (91.8%) elsewhere" in text
+    assert "meanwhile: 3 collection(s) 0.0060s, runnable with no CPU " \
+        "0.0030s" in text
+    spans = text[text.index("off-CPU seconds by span:"):]
+    assert spans.index("dispatch.launch") < spans.index("drain.fetch") \
+        < spans.index("drain.emit")
+    assert "(device wait)" in spans.splitlines()[2]
+    assert "stalled ticks: 1" in text
+    assert "tick 3: blocked in mixed / dispatch.launch, 3.750s over the " \
+        "usual; wall_s=3.85 fetch_s=0.03 cpu_s=0.04 proc_cpu_s=0.1" in text
+    # a dump of a program older than the clock: not a line of it
+    old = {"ticks": [{k: v for k, v in t.items()
+                      if k not in ("cpu_s", "off_cpu_by", "stall")}
+                     for t in log.dump()["ticks"]]}
+    ticks.write_text(json.dumps(old))
+    out = subprocess.run([sys.executable, *cmd], capture_output=True,
+                         text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+    assert "tick thread" not in out.stdout and "stalled" not in out.stdout
+    # the bare list of records, as the benchmark keeps it in `ticks.json`
+    ticks.write_text(json.dumps(log.dump()["ticks"]))
+    out = subprocess.run([sys.executable, *cmd], capture_output=True,
+                         text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+    assert "stalled ticks: 1" in out.stdout
+    if tool == "tick_report":
+        import importlib.util
+        spec = importlib.util.spec_from_file_location("tick_report", cmd[0])
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        line = mod.tick_line(log.dump()["ticks"][3])
+        assert "cpu=0.0400s STALL=blocked@dispatch.launch " in line
+        assert "cpu=" not in mod.tick_line(log.dump()["ticks"][5])
+        assert mod.phase_stats(old)["cpu"] == {}
+
+
 # -- anomaly flight recorder (ISSUE 15) -------------------------------------
 
 def _validate_artifact(art):

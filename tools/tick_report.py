@@ -5,13 +5,18 @@ The software answer to "what are the top host terms in a serving tick"
 (ROADMAP item 1) — a top-terms table of the structural tick phases
 (total seconds, share of tick wall, p50/p95), the host/device wall
 split, the per-cause barrier counts beside the finishes taken at a lazy
-drain without a barrier, and a reconciliation line proving the phase
-sums account for the measured tick wall time.
+drain without a barrier, a reconciliation line proving the phase
+sums account for the measured tick wall time, and the CPU clock: the
+tick thread's CPU seconds, where it was off a CPU by span (the two
+device waits apart from the host's own waiting), what the process's
+other threads, its collections and the machine did meanwhile, and the
+account of every tick that stalled.
 
 stdlib-only (no jax, no numpy): runs anywhere, like trace_report.py.
 
 Usage:  curl -s host:8000/debug/ticks > ticks.json
         python tools/tick_report.py ticks.json [--json]
+        python tools/tick_report.py chiprun_out/servebench/<run>/ticks.json
         python tools/tick_report.py http://host:8000 --follow
 
 ``--follow`` polls ``GET /debug/ticks?since=<seq>`` incrementally —
@@ -50,10 +55,15 @@ PHASE_NOTES = {
 def load_dump(path: str) -> dict:
     with open(path) as f:
         dump = json.load(f)
+    if isinstance(dump, list) and dump and all(
+            isinstance(t, dict) and "wall_s" in t for t in dump):
+        # the bare records, as the benchmark keeps them (`ticks.json`
+        # in the directory of every run)
+        dump = {"ticks": dump}
     if not isinstance(dump, dict) or "ticks" not in dump:
         raise ValueError(
             f"{path} is not a /debug/ticks dump (expected a JSON object "
-            f"with a 'ticks' list)")
+            f"with a 'ticks' list, or the list itself)")
     return dump
 
 
@@ -63,6 +73,84 @@ def percentile(values: List[float], q: float) -> float:
     s = sorted(values)
     idx = min(len(s) - 1, max(0, int(round(q / 100.0 * (len(s) - 1)))))
     return float(s[idx])
+
+
+#: the spans in which the tick thread waits for the device
+DEVICE_WAITS = ("drain.fetch", "drain.flush_count")
+#: a stalled tick's account, as the tick record holds it
+ACCOUNT = ("wall_s", "fetch_s", "cpu_s", "proc_cpu_s", "gc_s",
+           "gc_collections", "gc_generation", "run_delay_s")
+
+
+def cpu_stats(ticks: List[dict]) -> dict:
+    """The CPU clock of the ticks that carry it (`cpu_s` not null):
+    totals, the off-CPU seconds by span (most first; over the ticks that
+    read the CPU clock at every span boundary, `off_cpu_by` not null:
+    one in a few where a read is costly) and every stalled tick's
+    account. The process's own clock is read in those ticks too: what
+    its other threads burned is over them. Empty for the records of an
+    older program."""
+    ticks = [t for t in ticks if t.get("cpu_s") is not None]
+    if not ticks:
+        return {}
+    sampled = [t for t in ticks if t["off_cpu_by"] is not None]
+    off: Dict[str, float] = {}
+    for t in sampled:
+        for name, v in t["off_cpu_by"].items():
+            off[name] = off.get(name, 0.0) + v
+    delays = [t["run_delay_s"] for t in ticks]
+    return {
+        "ticks": len(ticks),
+        "wall_s": sum(t["wall_s"] for t in ticks),
+        "cpu_s": sum(t["cpu_s"] for t in ticks),
+        "cpu_mean_s": sum(t["cpu_s"] for t in ticks) / len(ticks),
+        "off_cpu_ticks": len(sampled),
+        "off_cpu_wall_s": sum(t["wall_s"] for t in sampled),
+        "off_cpu_by": sorted(off.items(), key=lambda kv: -kv[1]),
+        "off_cpu_device_s": sum(v for k, v in off.items()
+                                if k in DEVICE_WAITS),
+        "off_cpu_host_s": sum(v for k, v in off.items()
+                              if k not in DEVICE_WAITS),
+        "other_threads_cpu_s": sum(t["proc_cpu_s"] - t["cpu_s"]
+                                   for t in sampled),
+        "gc_s": sum(t["gc_s"] for t in ticks),
+        "gc_collections": sum(t["gc_collections"] for t in ticks),
+        "run_delay_s": None if None in delays else sum(delays),
+        "stalls": [dict({k: t.get(k) for k in ("seq",) + ACCOUNT},
+                        **t["stall"]) for t in ticks if t.get("stall")],
+    }
+
+
+def cpu_lines(c: dict) -> List[str]:
+    """`cpu_stats` as the report's lines."""
+    if not c:
+        return []
+    wall = c["wall_s"] or 1.0
+    off_wall = c["off_cpu_wall_s"] or 1.0
+    delay = c["run_delay_s"]
+    out = ["", f"tick thread on a CPU {c['cpu_s']:.4f}s "
+           f"({100 * c['cpu_s'] / wall:.1f}% of tick wall, "
+           f"{1e3 * c['cpu_mean_s']:.3f} ms a tick)",
+           f"meanwhile: {c['gc_collections']} collection(s) "
+           f"{c['gc_s']:.4f}s, runnable with no CPU "
+           + ("n/a" if delay is None else f"{delay:.4f}s"),
+           f"in the {c['off_cpu_ticks']} of {c['ticks']} tick(s) "
+           f"that clocked every span ({c['off_cpu_wall_s']:.4f}s wall): "
+           f"other threads' CPU {c['other_threads_cpu_s']:.4f}s; off a CPU "
+           f"{c['off_cpu_device_s']:.4f}s "
+           f"({100 * c['off_cpu_device_s'] / off_wall:.1f}%) waiting for "
+           f"the device, {c['off_cpu_host_s']:.4f}s "
+           f"({100 * c['off_cpu_host_s'] / off_wall:.1f}%) elsewhere",
+           "off-CPU seconds by span:"]
+    out += [f"  {name:>18} {v:>10.4f} {100 * v / off_wall:>6.1f}%"
+            + ("  (device wait)" if name in DEVICE_WAITS else "")
+            for name, v in c["off_cpu_by"]]
+    out.append(f"stalled ticks: {len(c['stalls'])}")
+    for st in c["stalls"]:
+        out.append(f"  tick {st['seq']}: {st['cause']} in {st['phase']} / "
+                   f"{st['span']}, {st['excess_s']:.3f}s over the usual; "
+                   + " ".join(f"{k}={st[k]}" for k in ACCOUNT))
+    return out
 
 
 def phase_stats(dump: dict) -> dict:
@@ -106,6 +194,7 @@ def phase_stats(dump: dict) -> dict:
         # finishes a lazy drain took with the newer blocks in flight
         # (mixed dispatch without speculation): no barrier ran for them
         "finishes_inline": finishes_inline,
+        "cpu": cpu_stats(ticks),
     }
 
 
@@ -138,6 +227,7 @@ def render(dump: dict) -> str:
         lines.append("no full drain barriers in the window")
     lines.append(f"finishes taken at a lazy drain, no barrier: "
                  f"{s['finishes_inline']}")
+    lines.extend(cpu_lines(s["cpu"]))
     return "\n".join(lines)
 
 
@@ -148,9 +238,12 @@ def tick_line(t: dict) -> str:
     timed = {k: v for k, v in phases.items() if k != "other"}
     dom = max(timed, key=timed.get) if timed else "-"
     causes = ",".join(t.get("barrier_causes", ())) or "-"
+    cpu, stall = t.get("cpu_s"), t.get("stall")
     return (f"tick {t.get('seq', '?'):>7} {t.get('wall_s', 0.0):>9.4f}s "
             f"dom={dom}:{timed.get(dom, 0.0):.4f}s "
             f"fetch={t.get('fetch_s', 0.0):.4f}s "
+            + (f"cpu={cpu:.4f}s " if cpu is not None else "")
+            + (f"STALL={stall['cause']}@{stall['span']} " if stall else "") +
             f"batch={t.get('batch', 0)} wait={t.get('waiting', 0)} "
             f"inflight={t.get('inflight', 0)} "
             f"pages={t.get('pages_free', 0)} "

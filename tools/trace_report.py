@@ -14,8 +14,10 @@ did this request's time go" view.
 ``--ticks FILE`` (a ``GET /debug/ticks`` body) adds the count of
 scheduler ticks and of the tokens they generated to the summary, the
 full drain barriers by cause beside the finishes taken at a lazy drain
-without one, and the starvation clock's reading of those ticks: the seconds the device
-waited for the host before their launches, by cause and by span.
+without one, the starvation clock's reading of those ticks: the seconds the device
+waited for the host before their launches, by cause and by span; and
+the CPU clock's: the tick thread's CPU seconds, where it was off a CPU
+by span, and every stalled tick's account (tools/tick_report.py's lines).
 
 ``--fleet`` renders a MERGED cross-replica trace instead — the JSON a
 fleet control plane returns from ``GET /fleet/trace?request_id=``: the
@@ -118,6 +120,7 @@ def render_summary(dump: Dict[str, Any],
                    "generated")
         out.extend(barrier_lines(recs))
         out.extend(starved_lines(recs))
+        out.extend(cpu_clock_lines(recs))
     return "\n".join(out)
 
 
@@ -168,6 +171,19 @@ def starved_lines(recs) -> List[str]:
                 f"{k} {_fmt_s(v)}" for k, v in
                 sorted(table.items(), key=lambda kv: -kv[1])))
     return out
+
+
+def cpu_clock_lines(recs) -> List[str]:
+    """The CPU clock of a /debug/ticks dump, as tools/tick_report.py
+    prints it (the file beside this one; stdlib only): nothing for the
+    records of a program older than the clock."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "tick_report", os.path.join(os.path.dirname(
+            os.path.abspath(__file__)), "tick_report.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return [ln for ln in mod.cpu_lines(mod.cpu_stats(list(recs))) if ln]
 
 
 def render_timeline(dump: Dict[str, Any], rid: int) -> str:
@@ -296,6 +312,8 @@ def main(argv=None) -> int:
             if args.ticks:
                 with open(args.ticks) as f:
                     ticks = json.load(f)
+                if isinstance(ticks, list):   # the benchmark's ticks.json
+                    ticks = {"ticks": ticks}
             print(render_summary(dump, ticks))
     except (ValueError, OSError) as e:
         print(f"error: {e}", file=sys.stderr)
