@@ -105,6 +105,7 @@ from butterfly_tpu.models.common import (
 from butterfly_tpu.ops import note_kernel
 from butterfly_tpu.ops import index_scores as paged_index
 from butterfly_tpu.ops import latent_attention, sparse_attention
+from butterfly_tpu.ops import select_mask as paged_select
 from butterfly_tpu.ops.flash_attention import flash_attention_sharded
 from butterfly_tpu.ops.paged_attention import paged_attention_sharded
 from butterfly_tpu.ops.window_stage import stage_window_sharded, window_step
@@ -861,6 +862,26 @@ def _index_selection(index, win, layer, *, page_table, positions, mask,
     return scores, topk, jnp.sum(mask, axis=-1)
 
 
+def _selection(scores, valid, k: int, use_kernel: bool):
+    """models.common.select_mask over the last axis of scores [..., n]:
+    `valid` narrowed to its k highest scores, THE SAME positions either
+    way. With kernels on, where there is anything to leave out (n > k)
+    and the rows fit (ops/select_mask.py fits), the k-th score and its
+    tie are found by counting in a Pallas call and the selection comes
+    back int32, what the two selecting reads take it in; else lax.top_k
+    and a running count give it as bool. The rows go to the call as
+    [R, n] and the selection is shaped back after it: neither side of
+    the call sees a unit dim before the lanes."""
+    n = scores.shape[-1]
+    flat = scores.reshape(-1, n)
+    if use_kernel and paged_select.fits(flat, k):
+        return paged_select.select_mask(
+            flat, valid.reshape(-1, n), k).reshape(valid.shape)
+    if use_kernel and n > k:
+        note_kernel("dense_fallback")
+    return select_mask(scores, valid, k)
+
+
 def _selection_count(live, read, moved):
     """f32 [4], what a selecting read counts for the tick record
     (kv_rows_live / _selected / _moved): the rows that had anything to
@@ -900,13 +921,13 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
     where kernels are on and the table is a few times index_topk, nearly
     every live page holds a selected row: the Pallas kernel
     (ops/sparse_attention.py) walks the slot's LIVE pages, a page a
-    copy, and the selection (select_mask) joins its length mask; (2) a
+    copy, and the selection (_selection) joins its length mask; (2) a
     longer table, or kernels off: the selected rows of keys and values
     out of the pool, a token a row, by the row's address
     (_row_addresses, _pool_rows), beside the window's few staged
     entries, which are read whole and masked to the selection. The same
     sum either way. A chunk's rows (T > 1) share one stream's prefix:
-    it is read once, whole, and masked row by row (select_mask), which
+    it is read once, whole, and masked row by row (_selection), which
     is the same mathematics and cheaper than T gathers. So is any
     program without the kernel whose whole context is no longer than
     index_topk.
@@ -928,7 +949,8 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
     if T == 1 and use_kernel and S_max <= MASKED_READ_SPAN * cfg.index_topk \
             and (win is None or slots is None) and sparse_attention.fits(
                 kp, q.shape[-1], 0 if win is None else window.width):
-        sel = select_mask(scores[:, 0], mask[:, 0], topk)  # [B,S_max]
+        sel = _selection(scores[:, 0], mask[:, 0], topk,
+                         use_kernel)                       # [B,S_max]
         # pool rows up to the FLUSHED length and the window's staged run
         # with the token just staged (the window whole: the kernel reads
         # the layer's blocks of it, under the selection at the staged
@@ -941,7 +963,7 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
         read = jnp.sum(sel, axis=-1)[:, None]
         moved = live
     elif T > 1 or S_max <= topk:
-        sel = select_mask(scores, mask, topk)
+        sel = _selection(scores, mask, topk, use_kernel)
         with jax.named_scope("attn_sparse"):
             ck = gather_paged_layer(kp, page_table, layer)
             cv = gather_paged_layer(vp, page_table, layer)
@@ -949,7 +971,7 @@ def sparse_paged_attend(q, qi, w, kp, vp, kip, layer, *, cfg: ModelConfig,
                 ck = insert_window_view(ck, wk, base)
                 cv = insert_window_view(cv, wv, base)
             out = attend_token_rows(
-                q, *_settled(ck[:, :, 0], cv[:, :, 0]), sel)
+                q, *_settled(ck[:, :, 0], cv[:, :, 0]), sel.astype(bool))
         read = jnp.sum(sel, axis=-1)
         moved = S_max * (live > 0)      # the slot's whole view
     else:
@@ -1001,7 +1023,7 @@ def latent_paged_attend(q, kp, layer, *, cfg: ModelConfig, page_table,
     rows): (qi, w, kip) as sparse_paged_attend takes them, the window's
     index keys in `win`. Every query scores its stream's live positions
     (_index_selection) and `mask` narrows to the cfg.index_topk that
-    score highest (select_mask) before either read below: a decode row's
+    score highest (_selection) before either read below: a decode row's
     kernel walks the slot's LIVE pages and takes the selection as a
     mask (ops/latent_attention.py latent_select_attention), a chunk's
     rows and every row with kernels off attend the gathered view under
@@ -1027,7 +1049,7 @@ def latent_paged_attend(q, kp, layer, *, cfg: ModelConfig, page_table,
             index, win, layer, page_table=page_table, positions=positions,
             mask=mask, active=active, topk=cfg.index_topk, select=select,
             scatter=True, use_kernel=use_kernel)
-        mask = select_mask(scores, mask, topk)
+        mask = _selection(scores, mask, topk, use_kernel)
     out = None
     if use_kernel and T == 1 and latent_attention.fits(
             kp, cfg.kv_lora_rank, index is not None,
@@ -1056,7 +1078,8 @@ def latent_paged_attend(q, kp, layer, *, cfg: ModelConfig, page_table,
         if win is not None:
             rows = insert_window_view(
                 rows, window_rows(window.k, layer, slots), base)
-        out = latent_attend(q, *_settled(rows[:, :, 0]), mask, cfg)
+        out = latent_attend(q, *_settled(rows[:, :, 0]), mask.astype(bool),
+                            cfg)
         moved = None if index is None else mask.shape[-1] * (live > 0)
     if index is not None:
         return out, _selection_count(live, mask, moved)

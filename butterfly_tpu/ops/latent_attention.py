@@ -302,10 +302,10 @@ def latent_select_attention(q: jax.Array, pages: jax.Array, layer,
                             interpret: bool | None = None) -> jax.Array:
     """latent_attention over the SELECTED positions of each slot's
     cached rows, read by its live pages: sel [slots, max_pages * page]
-    bool, of the positions `lengths` (and the window's count) make live
-    the ones the row attends (models.common.select_mask); it holds the
-    window's positions as it holds the pool's. Everything else as
-    latent_attention's."""
+    int32 or bool, of the positions `lengths` (and the window's count)
+    make live the ones the row attends (cache/paged.py _selection); it
+    holds the window's positions as it holds the pool's. Everything else
+    as latent_attention's."""
     return _read(q, pages, layer, page_table, lengths, sel, win, win_count,
                  rank, scale, interpret)
 

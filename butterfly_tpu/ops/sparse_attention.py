@@ -267,8 +267,8 @@ def sparse_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     layer: int32 scalar; page_table: [slots, max_pages] int32; lengths:
     [slots] int32, the rows of the pool a slot could attend (0: none,
     and with no window rows either its output is zeros); sel [slots,
-    max_pages * page] bool: of those positions the ones it attends
-    (models.common.select_mask). Returns [slots, Nq, H].
+    max_pages * page] int32 or bool: of those positions the ones it
+    attends (cache/paged.py _selection). Returns [slots, Nq, H].
 
     win_k, win_v [L, S, 1, W, Kv*H] + win_count [S]: the write-combined
     window, whole, of which `layer` is read: its staged rows at

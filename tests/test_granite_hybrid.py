@@ -672,7 +672,9 @@ WINDOWED = {
     "runs_joyai": (("joyai", dict(kv_lora_rank=128, num_layers=4)), "none"),
     # four residual streams [4, N, 1, D] in the carry beside the window
     "runs_xing": (("xing", dict(kv_lora_rank=128, hidden_size=128)), "none"),
-    "keye": (("keye", dict(head_dim=128)), "none"),
+    # a table of 128 within MASKED_READ_SPAN x topk: the decode rows
+    # read through ops/sparse_attention.py, as `keye30b.think`'s do
+    "keye": (("keye", dict(head_dim=128, index_topk=32)), "none"),
     # an indexer over LATENT rows: two leaves, the row and the index key
     "glm5": (("glm5", dict(kv_lora_rank=128)), "none"),
 }
@@ -776,7 +778,9 @@ def test_a_block_moves_no_window_inside_its_loops(model, chunks, one_chip,
     and NO value inside a loop has the shape of the table's index keys
     as a view of every slot (tools/chip_kernels.py index_views: the
     gather and its relayout were a quarter of `keye30b.think`'s busy
-    time; a chunk's view is one slot's and stays)."""
+    time; a chunk's view is one slot's and stays). Since PR 55 its
+    selections COUNT (ops/select_mask.py): no sort of a row of the
+    table's span and no running count over it is left."""
     tools = _tools()
     cfg, cache, window, S, C, hlo = _compiled_block(model, chunks, one_chip,
                                                     monkeypatch)
@@ -808,6 +812,17 @@ def test_a_block_moves_no_window_inside_its_loops(model, chunks, one_chip,
         assert not [line for line in hlo.splitlines()
                     if " sort(" in line and "T(1,128)" in line]
         assert tools.index_views(hlo, cache, cfg.index_head_dim) == []
+        # the selection COUNTS (PR 55): one call of ops/select_mask.py a
+        # layer scan for the decode rows and one more for a chunk's, its
+        # result [R, S_max] int32 in dense tiles, and nothing is left
+        # that sorts a row of the table's span or scans it for the ties
+        S_max = cache.page_table.shape[1] * cache.ki_pages.shape[3]
+        chosen = _mosaic_calls(hlo, "select_mask")
+        assert len(chosen) == len(layer_runs(cfg)) * (1 + chunks)
+        assert all(re.match(
+            rf"\s*%\S+ = s32\[\d+,{S_max}\]\{{1,0:T\([48],128\)", c)
+            for c in chosen)
+        assert tools.span_sorts(hlo, S_max) == []
 
 
 def test_the_view_of_every_slot_s_index_keys_is_what_the_walk_replaced(
@@ -821,6 +836,47 @@ def test_the_view_of_every_slot_s_index_keys_is_what_the_walk_replaced(
                                                monkeypatch)
     assert not _mosaic_calls(hlo, "index_scores")
     assert _tools().index_views(hlo, cache, cfg.index_head_dim)
+
+
+@pytest.mark.parametrize("model", ["keye", "glm5"])
+def test_the_sort_of_a_row_s_scores_is_what_the_count_replaced(
+        model, one_chip, monkeypatch):
+    """The control of the check above: the same mixed block with the
+    call refused (ops/select_mask.py fits) asks lax.top_k for the
+    threshold, a sort of every row of the table's span, and span_sorts
+    finds it."""
+    from butterfly_tpu.ops import select_mask
+    monkeypatch.setattr(select_mask, "fits", lambda *a, **k: False)
+    cfg, cache, _, _, _, hlo = _compiled_block(model, 1, one_chip,
+                                               monkeypatch)
+    assert not _mosaic_calls(hlo, "select_mask")
+    S_max = cache.page_table.shape[1] * cache.ki_pages.shape[3]
+    assert _tools().span_sorts(hlo, S_max)
+
+
+def test_a_model_without_an_indexer_compiles_what_it_compiled(
+        one_chip, monkeypatch):
+    """No program of a model without an indexer reaches the selection:
+    JoyAI's mixed block (latent rows through latent_paged_attend, the
+    function GLM-5's selection lives in) compiles to the SAME text with
+    cache/paged.py _selection there as with it refusing every call,
+    and names no select_mask."""
+    from butterfly_tpu.cache import paged
+    *_, hlo = _compiled_block("runs_joyai", 1, one_chip, monkeypatch)
+
+    def unreachable(*a, **k):
+        raise AssertionError("a model without an indexer selected")
+    monkeypatch.setattr(paged, "_selection", unreachable)
+    *_, again = _compiled_block("runs_joyai", 1, one_chip, monkeypatch)
+
+    def instructions(text):
+        """Instruction for instruction; where each was traced from (this
+        file's lines among them) apart."""
+        return [re.sub(r", metadata=\{[^}]*\}", "", line)
+                for line in text.splitlines()
+                if re.match(r"\s*(?:ROOT )?%\S+ = ", line)]
+    assert instructions(again) == instructions(hlo)
+    assert len(instructions(hlo)) > 1000 and "select_mask" not in hlo
 
 
 def test_weights_built_leaf_by_leaf_have_the_same_tree():

@@ -763,3 +763,11 @@ def test_sparse_parity_tool_separates_its_controls_on_the_toy():
     for control in ("select_all", "select_recent"):
         assert out[control]["before_max"] < 1e-4
         assert out[control]["after_median"] > 100 * out["clean"]["after_max"]
+    # every selection of the programs is held to the plain path's as
+    # it runs (PR 55): none differs but under the planted threshold,
+    # one ulp too high, which the logits' limit is not asked about
+    counted = out["selection"]
+    assert set(counted) == set(sparse_parity.FAULTS)
+    assert all(c["calls"] > 0 for c in counted.values())
+    assert [f for f, c in counted.items() if c["rows_differ"]] == \
+        ["threshold_ulp"] and out["ok"]

@@ -183,6 +183,9 @@ def traced(args, **kw):
     log = {}
     arrays = {k: v for k, v in args.items()
               if k not in ("cfg", "layer", "win")}
+    # a wrapper notes its kernel when it is TRACED, and the selection's
+    # operands have one shape with a window and without
+    jax.clear_caches()
     with record_kernels(log):
         jaxpr = jax.make_jaxpr(lambda a: sparse_paged_attend(
             **a, cfg=args["cfg"], layer=args["layer"], win=args.get("win"),
@@ -198,12 +201,14 @@ def test_the_branch_is_chosen_by_the_table_and_topk_alone(staged):
     gather and notes no read kernel; kernels off is the gather at any
     size. The index SCORES of such a row come from their own call
     wherever kernels are on, whatever the table (ops/index_scores.py,
-    PR 53). (Three slots, as no other test here has: the wrapper notes
+    PR 53), and the masked read's selection from the counting call
+    (ops/select_mask.py, PR 55: no sort at all). (Three slots, as no other test here has: the wrapper notes
     its kernel when it is TRACED, and jit keeps a trace of shapes it saw.)"""
     text, log = traced(case((100, 60, 9), staged=staged), use_kernel=True)
-    assert log == {"index_scores:interpret": 1,
+    assert log == {"index_scores:interpret": 1, "select_mask:interpret": 1,
                    "sparse_attention:interpret": 1}
-    assert text.count("pallas_call") == 2 and SORT not in text
+    assert text.count("pallas_call") == 3 and SORT not in text
+    assert "top_k" not in text and "cumsum" not in text
     text, log = traced(case((100, 60, 9), staged=staged, mp=MP + 1),
                        use_kernel=True)
     assert log == {"index_scores:interpret": 1}

@@ -43,7 +43,11 @@ operands, and times each at the cell's contexts and at the table's ends.
 (INDEX_CELLS) beside the gathered view it stands in for, both branches of
 cache/paged.py _index_selection on the same operands: the scores and the
 selections against each other, a call's time, a live page's, and the
-model's index-key bytes against the memory's rate.
+model's index-key bytes against the memory's rate. `select_cell_*` run
+ops/select_mask.py at the three arrays of scores those cells select over
+(SELECT_CELLS) beside lax.top_k and the running count it stands in for,
+both branches of cache/paged.py _selection on the same scores: the two
+masks against each other, what each program still sorts, a call's time.
 
 Last, the program `serve` spends its time in — the serving engine's
 fused decode block, at the full 8B width with the depth cut to two
@@ -443,6 +447,23 @@ def index_views(hlo: str, cache, key_width: int) -> list:
     return found
 
 
+def span_sorts(hlo: str, span: int) -> list:
+    """The instructions of a compiled HLO text that ORDER or SCAN a
+    whole row of the table's span: a `sort` whose result's last dim is
+    `span` (lax.top_k of every position's index score: a full bitonic
+    sort for one threshold), and a `reduce-window` over [.., span] or
+    over its lanes [.., span / 128, 128] (the running count of the ties
+    at that threshold). What a selection made by COUNTING leaves none
+    of (ops/select_mask.py; PERF.md, PR 55); the experts' router sorts
+    its few logits a row, another last dim."""
+    import re
+    tail = rf"(?:{span}|{max(span // 128, 1)},128)\]"
+    made = re.compile(
+        rf"^\s*(?:ROOT )?%(\S+) = \(?\w+\[(?:\d+,)*(?:{span}\]"
+        rf"[^=]*? sort\(|{tail}[^=]*? reduce-window\()")
+    return [m.group(1) for m in map(made.match, hlo.splitlines()) if m]
+
+
 def selecting_calls(config: dict, hlo: str):
     """How many instructions of a compiled HLO text the benchmark's
     pattern for the selecting latent read tells
@@ -479,7 +500,9 @@ def cell_blocks(config: dict, hlo_dir=None) -> list:
     of the residual streams' [n, rows, 1, D] (window_moves); for a model
     with an indexer the values of the shape of the table's index keys
     as a view of every slot (index_views: none since PR 53, the decode
-    rows score through ops/index_scores.py); for a
+    rows score through ops/index_scores.py) and the sorts and running
+    counts over a row of the table's span (span_sorts: none since
+    PR 55, the selection counts in ops/select_mask.py); for a
     latent cache whose rows an indexer selects, the instructions the
     benchmark's pattern tells as the selecting read (selecting_calls).
     The compiler's figures were the chip's to the megabyte (PR 41).
@@ -570,10 +593,12 @@ def cell_blocks(config: dict, hlo_dir=None) -> list:
                 window_moves=window_moves(hlo, jax.tree.leaves(window)),
                 stream_moves=window_moves(hlo, streams,
                                           kinds=("copy", "transpose")),
-                index_views=index_views(hlo, cache, cfg.index_head_dim))
+                index_views=index_views(hlo, cache, cfg.index_head_dim),
+                span_sorts=span_sorts(hlo, rt.max_seq_len)
+                if cfg.has_indexer else [])
             rec["ok"] = held < 15.75 * 2 ** 30 and not rec["window_moves"] \
                 and not rec["stream_moves"] and not rec["index_views"] \
-                and bool(rec["mosaic_calls"])
+                and not rec["span_sorts"] and bool(rec["mosaic_calls"])
             told = selecting_calls(config, hlo)
             if told is not None:
                 # a latent cache whose rows an indexer selects: every
@@ -1203,6 +1228,95 @@ def run_index_cell(name, small, want):
     return rec
 
 
+#: the arrays of index scores the selecting cells' programs select over
+#: (SPARSE_CELL's slots, table and topk): Keye's decode rows, a chunk's
+#: 32 rows of its one slot, GLM-5's decode rows
+SELECT_CELLS = {
+    "select_cell_keye": lambda S, n: (S, n),
+    "select_cell_chunk": lambda S, n: (1, S, n),
+    "select_cell_glm5": lambda S, n: (S, 1, n),
+}
+
+
+def run_select_cell(name, small, want):
+    """A selection at a selecting cell's geometry (SELECT_CELLS), both
+    ways cache/paged.py _selection makes it, on the same scores: by
+    COUNTING in the Pallas call (ops/select_mask.py) and by lax.top_k
+    and a running count (models/common.py select_mask). The two masks
+    against each other (rows of distinct scores, rows of a few levels
+    that tie astride the cut, -inf among the valid, a dead slot and a
+    full table among the rows), what each program still sorts
+    (span_sorts), then microseconds a call over one step's layers."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from butterfly_tpu.cache.paged import _selection
+
+    rec = {"name": name, "ok": False}
+    S, mp, topk, _ = SPARSE_CELL
+    L, page = 8, 16
+    if small:
+        S, mp, topk, L = 8, 16, 48, 2
+    n = mp * page
+    shape = SELECT_CELLS[name](S, n)
+    try:
+        rs = np.random.RandomState(55)
+        ctx = np.minimum(n, np.exp(rs.uniform(
+            np.log(n / 7), np.log(n * 4 / 7), S)).astype(int)
+            + rs.randint(0, n * 3 // 7 + 1, S))
+        ctx[0], ctx[1] = 0, n
+        valid = jnp.asarray((np.arange(n)[None] < ctx[:, None])
+                            .reshape(shape))
+        scores = jax.random.normal(jax.random.PRNGKey(55), (L, S, n),
+                                   jnp.float32)
+        levels = jnp.round(scores * 4) / 4
+        scores = scores.at[:, 1::4].set(levels[:, 1::4])
+        scores = scores.at[:, 2::4].set(jnp.where(
+            levels[:, 2::4] < 0, -jnp.inf, levels[:, 2::4]))
+        scores = scores.reshape(L, *shape)
+
+        def branch(use_kernel):
+            return jax.jit(lambda scores, valid: [
+                _selection(scores[ly], valid, topk, use_kernel) != 0
+                for ly in range(L)])
+
+        def step(use_kernel):
+            return jax.jit(lambda scores, valid: sum(
+                _selection(scores[ly], valid, topk, use_kernel).sum()
+                for ly in range(L)))
+
+        counted = branch(True).lower(scores, valid).compile()
+        plain = branch(False).lower(scores, valid).compile()
+        rec["hlo_has"] = {w_: w_ in counted.as_text() for w_ in want}
+        rec["sorts"] = {"counted": span_sorts(counted.as_text(), n),
+                        "plain": span_sorts(plain.as_text(), n)}
+        got, ref = (np.stack([np.asarray(m) for m in fn(scores, valid)])
+                    for fn in (counted, plain))
+        rec["selected"] = int(ref.sum())
+        rec["selections_differ_at"] = int((got != ref).sum())
+        rec["max_err"] = float(rec["selections_differ_at"] > 0)
+
+        def timed(fn, reps=30):
+            jax.block_until_ready(fn(scores, valid))
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                r = fn(scores, valid)
+            jax.block_until_ready(r)
+            return round((time.perf_counter() - t0) / reps / L * 1e6, 1)
+
+        rec["counted_us"] = timed(step(True))
+        rec["plain_us"] = timed(step(False))
+        rec["ok"] = bool(rec["selections_differ_at"] == 0
+                         and rec["selected"] > 0
+                         and not rec["sorts"]["counted"]
+                         and rec["sorts"]["plain"]
+                         and all(rec["hlo_has"].values()))
+    except Exception as e:  # a compiler refusal is the finding: record it
+        rec["error"] = f"{type(e).__name__}: {e}"[:1500]
+    return rec
+
+
 #: the cells' windows for ops/window_stage.py (W 256, a chunk of 32
 #: columns beside the decode rows): name -> layers, slots, the row
 #: leaves' (heads, width) and dtype, int8 scale leaves too? (Keye's
@@ -1420,6 +1534,8 @@ def main() -> int:
         results.append(run_sparse_cell("sparse_cell", args.small, want))
     results += [run_index_cell(n, args.small, want)
                 for n in INDEX_CELLS if wanted(n)]
+    results += [run_select_cell(n, args.small, want)
+                for n in SELECT_CELLS if wanted(n)]
     if wanted("serve_block"):
         results.append(run_serving_block("serve_block", args.small, None,
                                          want))
@@ -1452,6 +1568,9 @@ def main() -> int:
                  f"{r['trace_lower_s']}s compile={r['compile_s']}s"
                  if "call_us" in r else "")
               + (f" calls={r['kernel_calls']}" if "kernel_calls" in r else "")
+              + (f" counted={r['counted_us']}us a call, lax.top_k and the "
+                 f"running count {r['plain_us']}us"
+                 if "counted_us" in r else "")
               + "".join(
                   f"\n     {label}: the walk alone {c['kernel_alone_us']}us "
                   f"({c['kernel_ns_a_live_page']} ns a live page, "

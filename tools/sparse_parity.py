@@ -35,6 +35,18 @@ selection's edge swaps one of 2,048 attended rows for another, while a
 wrong selection moves every row. The largest reading of each group is
 reported beside it.
 
+What the logits cannot see, a selection that is off by a row, is COUNTED
+(PR 55): while a run's steps are traced, every selection of the program
+(cache/paged.py _selection: by counting in ops/select_mask.py where
+kernels are on) is held to the plain path's over the same scores
+(models/common.py select_mask: lax.top_k and a running count) inside the
+program, position for position, and the calls, the rows that differ and
+the positions are tallied from the device (`selection` in the output).
+The clean run and both controls must differ NOWHERE, at any layer or
+step; a third fault, `threshold_ulp` (a threshold one ulp above the k-th
+score: what ties at the cut is left out), must be counted in every row
+past topk though its logits read as the clean run's.
+
 The tool reports chip evidence and refuses to run without a TPU; `--toy`
 (the CPU rehearsal of tests/test_keye.py) says so in its output.
 """
@@ -64,13 +76,56 @@ LIMIT = 0.05
 STREAM, SLOTS, MAX_SEQ = 4500, 8, 5120
 #: decode rows behind the prompt
 DECODE = 84
-#: the clean run and the controls: what sparse_paged_attend selects by
-FAULTS = {"clean": "index", "select_all": "all", "select_recent": "recent"}
+#: the clean run, the controls and the fault the count alone can see:
+#: what sparse_paged_attend selects by
+FAULTS = {"clean": "index", "select_all": "all", "select_recent": "recent",
+          "threshold_ulp": "index"}
+#: the two whose LOGITS must pass the limit past topk
+CONTROLS = ("select_all", "select_recent")
 
 
 #: what the steps' traces noted of their kernels (ops.note_kernel): which
 #: read served the decode rows of the newest check
 KERNELS: dict = {}
+#: fault -> [selections made, rows of them that are not the plain
+#: path's, positions that differ], tallied from inside the programs
+SELECTIONS: dict = {}
+
+
+def _tally(fault, rows, positions):
+    held = SELECTIONS.setdefault(fault, [0, 0, 0])
+    held[0] += 1
+    held[1] += int(rows)
+    held[2] += int(positions)
+
+
+def _ulp_high(scores, valid, k, use_kernel):
+    """The planted fault: what scores ABOVE the k-th score, the
+    selection of a threshold one ulp too high."""
+    import jax.numpy as jnp
+    from jax import lax
+    if scores.shape[-1] <= k:
+        return valid
+    s = jnp.where(valid, scores, -jnp.inf)
+    return valid & (s > lax.top_k(s, k)[0][..., -1:])
+
+
+def _held_to_plain(fault, real):
+    """cache/paged.py _selection, its result unchanged (threshold_ulp:
+    replaced), with the plain path's selection over the same scores
+    beside it in the program and their difference tallied."""
+    import jax
+    import jax.numpy as jnp
+    from butterfly_tpu.models.common import select_mask
+
+    def selection(scores, valid, k, use_kernel):
+        made = _ulp_high if fault == "threshold_ulp" else real
+        got = made(scores, valid, k, use_kernel)
+        off = jnp.sum((got != 0) != select_mask(scores, valid, k), axis=-1)
+        jax.debug.callback(partial(_tally, fault), jnp.sum(off > 0),
+                           jnp.sum(off))
+        return got
+    return selection
 
 
 @contextlib.contextmanager
@@ -81,10 +136,11 @@ def planted(fault: str):
     from butterfly_tpu.ops import record_kernels
     # the selection over keys and values, and the one over latent rows
     # (a latent-attention model with an indexer: GLM-5's family)
-    names = ("sparse_paged_attend", "latent_paged_attend")
+    names = ("sparse_paged_attend", "latent_paged_attend", "_selection")
     real = [getattr(paged, n) for n in names]
-    for n, fn in zip(names, real):
+    for n, fn in zip(names[:2], real):
         setattr(paged, n, partial(fn, select=FAULTS[fault]))
+    paged._selection = _held_to_plain(fault, real[2])
     try:
         with record_kernels(KERNELS):
             yield
@@ -128,8 +184,10 @@ def check(config: dict, toy: bool = False, stream: int = STREAM,
         1, cfg.vocab_size, stream).astype(np.int32)
     n_prompt = (stream - decode) // C * C
     KERNELS.clear()
+    SELECTIONS.clear()
     served = served_rows(cfg, params, rt, tokens, n_prompt,
                          faults={f: {} for f in FAULTS}, planted=planted)
+    jax.effects_barrier()       # the last steps' tallies
     kernels = dict(KERNELS)
     pos = np.asarray(served["clean"][0])
     before = (pos >= topk * 3 // 4) & (pos < topk)
@@ -159,11 +217,17 @@ def check(config: dict, toy: bool = False, stream: int = STREAM,
             (got[keep].argmax(-1) == want.argmax(-1)).sum())
         out[fault]["rows"] = [round(float(r), 4) for r in read]
     out["positions"] = pos[keep].tolist()
+    out["selection"] = {
+        f: dict(zip(("calls", "rows_differ", "positions_differ"), held))
+        for f, held in SELECTIONS.items()}
     clean = out["clean"]
     out["ok"] = bool(
         max(clean["before_median"], clean["after_median"]) < limit
         and all(out[f]["before_median"] < limit < out[f]["after_median"]
-                for f in FAULTS if f != "clean"))
+                for f in CONTROLS)
+        and all(out["selection"][f]["calls"] > 0
+                and (out["selection"][f]["rows_differ"] > 0)
+                == (f == "threshold_ulp") for f in FAULTS))
     return out
 
 
