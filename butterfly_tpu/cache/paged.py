@@ -101,7 +101,7 @@ from butterfly_tpu.models.common import (
     layer_pattern_of, layer_runs, layer_stack, make_mask, qkv_proj,
     quantize_kv, run_layer_at, select_mask, stream_fold, stream_read,
     stream_write,
-    select_topk, ssm_unsupported)
+    select_topk, ssm_unsupported, RECURRENT_STACKS)
 from butterfly_tpu.ops import note_kernel
 from butterfly_tpu.ops import index_scores as paged_index
 from butterfly_tpu.ops import latent_attention, sparse_attention
@@ -115,7 +115,7 @@ from butterfly_tpu.cache.ssm_state import SSMState, advance_packed
 class PagedKVCache(NamedTuple):
     # [L, P, Kv, page, H] (int8 codes when quantized); token-major
     # [L, P, 1, page, Kv*H] for a model with an indexer (pool_row). L
-    # counts the layers that OWN rows: a model with Mamba-2 layers holds
+    # counts the layers that OWN rows: a model with recurrent layers holds
     # pages for its attention layers alone (cfg.num_attn_layers, 0 for
     # a model of Mamba layers only: the table, the lengths and the
     # flush still work, over no layer)
@@ -1625,12 +1625,14 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
                  state: Optional[SSMState], use_kernel: bool):
     """The layers of a packed step for a model whose layers are of
     unlike SHAPES, in the published order: mixers of two kinds
-    (cfg.layer_types) or feed-forwards of two kinds (cfg.first_k_dense).
+    (cfg.layer_types: one recurrent kind beside attention) or
+    feed-forwards of two kinds (cfg.first_k_dense).
     Each run of one kind (models.common.layer_runs) is one scan that
     rides the layers' indices, into params["layers"] (what every layer
-    has), into its mixer's own stack where there is one (params["mamba"],
-    params["attn"]) and into its feed-forward's (ffn_run). A Mamba run
-    carries the recurrent state (ssm_state.advance_packed); an
+    has), into its mixer's own stack where there is one (params["mamba"]
+    or params["gdn"], params["attn"]) and into its feed-forward's
+    (ffn_run). A recurrent run (Mamba-2 or Gated DeltaNet) carries the
+    recurrent state (ssm_state.advance_packed by the layer's kind); an
     attention run is packed_layer as every other model runs it, over the
     pool's layer a (the pool holds attention layers only): window on,
     the read-only pool whole and the window, whole, in the carry of
@@ -1643,12 +1645,13 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
     pools = pool_leaves(cache, absent=True)
     written, loads = [], []
 
-    def mamba(carry, idx):
+    def recurrent(carry, idx):
         x, st = carry
         l, m = idx
         x, st, load = advance_packed(
             x, layer_at(params["layers"], l, cfg),
-            layer_at(params["mamba"], m, cfg), st, m, rows, cfg, use_kernel)
+            layer_at(params[RECURRENT_STACKS[cfg.recurrent_kind]], m, cfg),
+            st, m, rows, cfg, use_kernel)
         return (x, st), load
 
     def attention(ffn, looped, carry, scanned):
@@ -1667,8 +1670,8 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
 
     for kind, first, n, at in layer_runs(cfg):
         idx = (first + jnp.arange(n), at + jnp.arange(n))
-        if kind == "mamba":
-            (x, state), load = lax.scan(mamba, (x, state), idx)
+        if kind != "attention":
+            (x, state), load = lax.scan(recurrent, (x, state), idx)
         else:
             mine = () if window is not None else (
                 None if a is None else a[at:at + n] for a in pools)
@@ -1705,7 +1708,11 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
             parts.append(jnp.concatenate(
                 [l[:, 3 + n:] for l in routed]).mean(axis=0))
         return x, kv, state, jnp.concatenate(parts)
-    return x, kv, state, jnp.concatenate(loads).mean(axis=0)
+    # a dense model's layers route nothing: three zeros hold the
+    # experts' places before what a family adds (_mixed_rows does so)
+    routed = [l for l in loads if l is not None]
+    return x, kv, state, jnp.concatenate(routed).mean(axis=0) if routed \
+        else jnp.zeros((3,), jnp.float32)
 
 
 _POOL_LEAVES = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages",
@@ -1806,7 +1813,7 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
     (Under pipeline stages: parallel/pipeline.py paged_pipeline_packed,
     the same pieces over stage-local layers.)
 
-    state (a model with Mamba-2 layers: cache/ssm_state.py): every
+    state (a model with recurrent layers: cache/ssm_state.py): every
     slot's recurrent state, which the step's real rows advance. The
     layers then run as scans over runs of one kind (_packed_runs) and
     the return gains the state as a fourth value; `load` gains two:

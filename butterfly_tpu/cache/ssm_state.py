@@ -1,13 +1,14 @@
-"""The recurrent state of a model with Mamba-2 layers: a second KIND of
-per-stream memory beside the paged pool.
+"""The recurrent state of a model with recurrent layers (Mamba-2 mixers,
+or Gated DeltaNet mixers: cfg.recurrent_kind says which ONE kind a model
+has): a second KIND of per-stream memory beside the paged pool.
 
 A page pool grows with a stream: a token adds a row to every attention
-layer. A Mamba-2 layer keeps a FIXED-SIZE state a stream instead, the
-same bytes at position 10 and at position 100,000: the state of each
-head H [Nh, Hd, N] and the last K-1 inputs of the causal conv
-[K-1, Dc]. It is never paged, hashed or exported; it belongs to a SLOT,
-is read and written by every step, and starts from zero when a stream
-starts (position 0), whatever the slot's last tenant left.
+layer. A recurrent layer keeps a FIXED-SIZE state a stream instead, the
+same bytes at position 10 and at position 100,000: each head's state
+and the last K-1 inputs of the causal conv [K-1, Dc]. It is never
+paged, hashed or exported; it belongs to a SLOT, is read and written by
+every step, and starts from zero when a stream starts (position 0),
+whatever the slot's last tenant left.
 
 The interface is narrow on purpose (the scheduler learns nothing of it):
 
@@ -16,37 +17,50 @@ The interface is narrow on purpose (the scheduler learns nothing of it):
 * the value is a pytree (SSMState): the engine donates it to every
   block program and rebinds it from the result, exactly like the KV
   window, and it rides the block scan's CARRY (engine/serving.py);
-* `advance_packed(...)`: one Mamba-2 layer of a packed mixed step: a
-  decode row advances its slot's state by one position, a prefill chunk
-  advances its OWN slot's state by its real columns, filler columns,
-  idle chunks and dead rows advance nothing, and a chunk that starts at
-  position 0 starts from zero INSIDE the program (a recomputed
-  preemption or a reused slot needs no host edit);
+* `advance_packed(...)`: one recurrent layer of a packed mixed step, by
+  the layer's kind: a decode row advances its slot's state by one
+  position, a prefill chunk advances its OWN slot's state by its real
+  columns, filler columns, idle chunks and dead rows advance nothing,
+  and a chunk that starts at position 0 starts from zero INSIDE the
+  program (a recomputed preemption or a reused slot needs no host edit);
 * `reset_slots(state, slots)`: zero slots from the host (tests, and a
   caller that wants a scrubbed slot; serving never needs it);
-* `state_info(cfg, slots)`: what /health and the ready line say.
+* `state_info(cfg, slots)`: what /health and the ready line say: the
+  kind, the layout held and its bytes a slot.
 
-Layout: h [Lm, S, Nh, Hd, N] (the two minor dims are whole tiles:
-N on the lanes) and conv [Lm, K-1, S, Dc] (slots on the sublanes: a
-[.., K-1, Dc] minor pair would pad K-1 = 3 to a tile of 16 rows in
-bfloat16, five times the bytes). Every write is a dynamic-update-slice
-of the carried buffer at the layer's index, which XLA performs in place
-(a scatter into a scan carry copies the whole buffer: cache/paged.py's
-window docs have the measurement).
+Layout, by kind (`state_shapes`). The conv's tail is [Ls, K-1, S, Dc]
+for both (slots on the sublanes: a [.., K-1, Dc] minor pair would pad
+K-1 = 3 to a tile of 16 rows in bfloat16, five times the bytes).
+Mamba-2: h [Lm, S, Nh, Hd, N], a head's state with N on the lanes.
+Gated DeltaNet: a head's state is [dv, dk] and neither need be a whole
+number of 128 lanes (Olmo-Hybrid: 192 x 96), so g heads' VALUES share a
+row of lanes, g the fewest that fill whole lanes (cfg.gdn_head_group: 2
+x 192 = 384 = 3 x 128): h [Ls, S, H/g, dk, g dv], keys down the
+sublanes (96 = 6 tiles of 16 in bfloat16). The DECLARED shape is then
+what the memory holds, to the byte (`state_info`'s `whole_tiles` says
+whether a geometry's is: a layout that the device pads says so). Every
+write is a dynamic-update-slice of the carried buffer at the layer's
+index, which XLA performs in place (a scatter into a scan carry copies
+the whole buffer: cache/paged.py's window docs have the measurement).
 
-Who computes the recurrence: a DECODE row's one step is the Pallas
-kernel ops/ssm_step.py when the engine's kernels are on and the state's
-minor dims are whole tiles (the kernel takes the whole h, aliased to
-its result, and the layer's index: one pass over layer m's slots where
-they lie, y read from the float32 value before it is rounded to the
-stored dtype); with kernels off (the CPU) or any other state it is
-models.common.ssm_scan at T == 1 and the update in place, which is also
-what the kernel is tested against. A CHUNK's is ssm_scan's scan over its
-positions, either way; it reads its slot after the decode rows' step (a
-chunk's slot is no live decode row, so that step left it as it was).
+Who computes the recurrence. Mamba-2: a DECODE row's one step is the
+Pallas kernel ops/ssm_step.py when the engine's kernels are on and the
+state's minor dims are whole tiles (the kernel takes the whole h,
+aliased to its result, and the layer's index: one pass over layer m's
+slots where they lie, y read from the float32 value before it is
+rounded to the stored dtype); with kernels off (the CPU) or any other
+state it is models.common.ssm_scan at T == 1 and the update in place,
+which is also what the kernel is tested against. A CHUNK's is ssm_scan's
+scan over its positions, either way. Gated DeltaNet: a decode row's
+step is models.common.gdn_step (XLA's: one reduction over the layer's
+state and the update in place), a chunk's is gdn_chunk (the chunkwise
+form). Either kind: the chunks read and write their own slots FIRST and
+the decode rows' step follows on the result (a chunk's slot is no live
+decode row, so that step leaves it as it is).
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, NamedTuple, Optional
 
 import jax
@@ -55,14 +69,16 @@ from jax import lax
 
 from butterfly_tpu.core.config import ModelConfig
 from butterfly_tpu.models.common import (
-    ffn_close, ssm_conv, ssm_gate_out, ssm_in_proj, ssm_scan, ssm_skip,
-    ssm_step_inputs, stream_read, stream_write)
+    RECURRENT_NAMES, ffn_close, gdn_chunk, gdn_conv, gdn_gate_out,
+    gdn_in_proj, gdn_step, gdn_step_inputs, ssm_conv, ssm_gate_out,
+    ssm_in_proj, ssm_scan, ssm_skip, ssm_step_inputs, stream_read,
+    stream_write)
 from butterfly_tpu.ops.ssm_step import fits, ssm_step
 
 
 class SSMState(NamedTuple):
-    h: jax.Array      # [Lm, S, Nh, Hd, N]
-    conv: jax.Array   # [Lm, K-1, S, Dc]
+    h: jax.Array      # by kind (state_shapes): [Ls, S, ...a slot's state]
+    conv: jax.Array   # [Ls, K-1, S, Dc]
 
     @property
     def num_slots(self) -> int:
@@ -80,20 +96,57 @@ class StateRows(NamedTuple):
     chunk_pos: jax.Array   # [P, C]
 
 
+def state_shapes(cfg: ModelConfig, slots: int) -> Dict[str, tuple]:
+    """{"h", "conv"}: the shapes the state is held in, by the model's
+    recurrent kind (the module's docstring has why)."""
+    Ls = cfg.num_ssm_layers
+    if cfg.recurrent_kind == "linear_attention":
+        g = cfg.gdn_head_group
+        return {"h": (Ls, slots, cfg.gdn_heads // g, cfg.gdn_key_dim,
+                      g * cfg.gdn_value_dim),
+                "conv": (Ls, cfg.gdn_conv - 1, slots, cfg.gdn_conv_dim)}
+    return {"h": (Ls, slots, cfg.ssm_heads, cfg.ssm_head_dim,
+                  cfg.ssm_state),
+            "conv": (Ls, cfg.ssm_conv - 1, slots, cfg.ssm_conv_dim)}
+
+
+def _held(shape, itemsize: int) -> int:
+    """Values a TPU holds for `shape`: the two minor dims in whole
+    tiles (128 lanes; 8 sublanes of 32 bits, so 16 rows of bfloat16)."""
+    *major, rows, lanes = shape
+    sub = 8 * 4 // itemsize
+    return math.prod(major) * -(-rows // sub) * sub * -(-lanes // 128) * 128
+
+
 def bytes_per_slot(cfg: ModelConfig) -> int:
-    """What one stream's state weighs, all Mamba layers."""
-    per = cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state \
-        + (cfg.ssm_conv - 1) * cfg.ssm_conv_dim
-    return cfg.num_ssm_layers * per * jnp.dtype(cfg.dtype).itemsize
+    """What one stream's state weighs, all recurrent layers: the
+    declared values' bytes, which are what a TPU holds where the layout
+    has whole tiles (state_info's `whole_tiles`: both accepted
+    geometries)."""
+    shapes = state_shapes(cfg, 1)
+    return (math.prod(shapes["h"]) + math.prod(shapes["conv"])) \
+        * jnp.dtype(cfg.dtype).itemsize
 
 
 def state_info(cfg: ModelConfig, slots: int) -> Optional[Dict]:
-    """{layers, bytes_per_slot, dtype, bytes}, or None for a model
-    without a recurrent state."""
+    """{kind, layers, layout, whole_tiles, bytes_per_slot, dtype, bytes},
+    or None for a model without a recurrent state. `layout` names the
+    dims of h as held (state_shapes); `whole_tiles`: the declared shapes
+    are what a TPU's memory holds, to the byte (else it pads them)."""
     if not cfg.has_ssm:
         return None
     per = bytes_per_slot(cfg)
-    return {"layers": cfg.num_ssm_layers, "bytes_per_slot": per,
+    size = jnp.dtype(cfg.dtype).itemsize
+    shapes = state_shapes(cfg, slots)
+    dims = "layers, slots, heads/g, key_dim, g*value_dim" \
+        if cfg.recurrent_kind == "linear_attention" \
+        else "layers, slots, heads, head_dim, state"
+    return {"kind": RECURRENT_NAMES[cfg.recurrent_kind],
+            "layers": cfg.num_ssm_layers,
+            "layout": f"h [{dims}] = {list(shapes['h'])}",
+            "whole_tiles": all(_held(sh, size) == math.prod(sh)
+                               for sh in shapes.values()),
+            "bytes_per_slot": per,
             "dtype": str(jnp.dtype(cfg.dtype)), "bytes": per * slots}
 
 
@@ -105,14 +158,11 @@ def init_ssm_state(cfg: ModelConfig, slots: int,
     if not cfg.has_ssm:
         return None
     dt = jnp.dtype(cfg.dtype)
-    Lm = cfg.num_ssm_layers
+    shapes = state_shapes(cfg, slots)
 
     def build():
-        return SSMState(
-            h=jnp.zeros((Lm, slots, cfg.ssm_heads, cfg.ssm_head_dim,
-                         cfg.ssm_state), dt),
-            conv=jnp.zeros((Lm, cfg.ssm_conv - 1, slots, cfg.ssm_conv_dim),
-                           dt))
+        return SSMState(h=jnp.zeros(shapes["h"], dt),
+                        conv=jnp.zeros(shapes["conv"], dt))
 
     return jax.jit(build, out_shardings=sharding)()
 
@@ -126,7 +176,7 @@ def reset_slots(state: SSMState, slots) -> SSMState:
 
 def decode_rows_step(h, m, u, dt, mp, cfg: ModelConfig, count,
                      use_kernel: bool = False):
-    """The recurrence of layer m's decode rows, one position: h
+    """The Mamba-2 recurrence of layer m's decode rows, one position: h
     [Lm, S, Nh, Hd, N] the whole carried state, u [S, 1, Dc] float32
     (ssm_conv), dt [S, 1, Nh], count [S] (1: the row decodes). With
     use_kernel and a state the kernel can cut (ops/ssm_step.py fits) one
@@ -143,16 +193,92 @@ def decode_rows_step(h, m, u, dt, mp, cfg: ModelConfig, count,
     return y, lax.dynamic_update_index_in_dim(h, new.astype(h.dtype), m, 0)
 
 
+def gdn_heads_of(st: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Slots' Gated DeltaNet state as held, st [P, H/g, dk, g dv], a
+    head at a time: [P, H, dv, dk], the reference's. For a reader of
+    the state (tools/state_parity.py, the tests); no step transposes
+    it (models.common.gdn_step and gdn_chunk read the held layout as
+    it lies)."""
+    P, J, dk, _ = st.shape
+    g, dv = cfg.gdn_head_group, cfg.gdn_value_dim
+    st = st.reshape(P, J, dk, g, dv).transpose(0, 1, 3, 4, 2)
+    return st.reshape(P, J * g, dv, dk)
+
+
+def gdn_lanes_of(st: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """gdn_heads_of's inverse: [P, H, dv, dk] -> [P, H/g, dk, g dv]."""
+    P, H, dv, dk = st.shape
+    g = cfg.gdn_head_group
+    st = st.reshape(P, H // g, g, dv, dk).transpose(0, 1, 4, 2, 3)
+    return st.reshape(P, H // g, dk, g * dv)
+
+
+class _Mamba:
+    """advance_packed's Mamba-2 layer: the pieces of models/common.py
+    in the order the skeleton calls them."""
+    conv = staticmethod(ssm_conv)
+
+    @staticmethod
+    def project(h, mp, cfg):
+        z, xbc, dt = ssm_in_proj(h, mp, cfg)
+        return xbc, (z, dt)
+
+    @staticmethod
+    def decode(h, m, u, aux, mp, cfg, count, use_kernel):
+        return decode_rows_step(h, m, u, aux[1], mp, cfg, count, use_kernel)
+
+    @staticmethod
+    def chunk(st0, u, aux, mp, cfg, count):
+        return ssm_scan(u, aux[1], mp, cfg, st0.astype(jnp.float32), count)
+
+    @staticmethod
+    def close(y, aux, mp, cfg):
+        return ssm_gate_out(y, aux[0], mp, cfg)
+
+
+class _DeltaNet:
+    """advance_packed's Gated DeltaNet layer."""
+    conv = staticmethod(gdn_conv)
+
+    @staticmethod
+    def project(h, gp, cfg):
+        qkv, z, a, b = gdn_in_proj(h, gp, cfg)
+        return qkv, (z, a, b)
+
+    @staticmethod
+    def decode(h, m, u, aux, gp, cfg, count, use_kernel):
+        q, k, v, la, beta = gdn_step_inputs(u, aux[1], aux[2], gp, cfg,
+                                            count)
+        o, h = gdn_step(h, m, q[:, 0], k[:, 0], v[:, 0], la[:, 0],
+                        beta[:, 0], cfg)
+        return o.reshape(o.shape[0], 1, cfg.gdn_heads, cfg.gdn_value_dim), h
+
+    @staticmethod
+    def chunk(st0, u, aux, gp, cfg, count):
+        return gdn_chunk(*gdn_step_inputs(u, aux[1], aux[2], gp, cfg, count),
+                         st0.astype(jnp.float32), cfg)
+
+    @staticmethod
+    def close(o, aux, gp, cfg):
+        return gdn_gate_out(o, aux[0], gp, cfg)
+
+
+_MIXERS = {"mamba": _Mamba, "linear_attention": _DeltaNet}
+
+
 def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig,
                    use_kernel: bool = False):
-    """One Mamba-2 layer (mixer, feed-forward, both residuals) of a
+    """One recurrent layer (mixer, feed-forward, both residuals) of a
     packed mixed step over x [N, 1, D], N = S + P*C rows: the S decode
     rows first, then P chunks of C columns (`rows`: StateRows'
-    fields). lp, mp: the layer's slices of params["layers"] and
-    params["mamba"]; m: its index among the Mamba layers (traced).
-    use_kernel: the engine's kernel rule (ops/__init__.py); with it,
-    and a state of whole tiles, the decode rows' recurrence is the
-    ssm_step kernel, one pass over the state where it lies.
+    fields). lp, mp: the layer's slices of params["layers"] and of its
+    kind's stack (params["mamba"] or params["gdn"]); m: its index among
+    the recurrent layers (traced). The layer's KIND (cfg.recurrent_kind)
+    picks the mixer's pieces (_Mamba, _DeltaNet); the order below is
+    the same for both. use_kernel: the engine's kernel rule
+    (ops/__init__.py); with it, and a Mamba-2 state of whole tiles, the
+    decode rows' recurrence is the ssm_step kernel, one pass over the
+    state where it lies.
 
     The projections, the gate and the feed-forward run once over all N
     rows (the weights stream once); the conv and the recurrence run on
@@ -161,54 +287,67 @@ def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig,
     T == C against its own slot's, from zero where the chunk starts at
     position 0. Returns (x, state, load): load as
     models.common.ffn_close's."""
+    mixer = _MIXERS[cfg.recurrent_kind]
     S, (P, C) = rows.active.shape[0], rows.chunk_pos.shape
-    Nh, Hd, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
-    h, mix = stream_read(x, lp, 1, cfg)
-    z, xbc, dt = ssm_in_proj(h, mp, cfg)
-    h_all = lax.dynamic_index_in_dim(state.h, m, 0, keepdims=False)
+    hin, mix = stream_read(x, lp, 1, cfg)
+    xbc, aux = mixer.project(hin, mp, cfg)
     tails = lax.dynamic_index_in_dim(state.conv, m, 0, keepdims=False)
     tails = jnp.swapaxes(tails, 0, 1)                  # [slots, K-1, Dc]
-    sdt = h_all.dtype
-    h, tails_new, ys = state.h, tails, []
+    sdt = state.h.dtype
+    h, y_d, y_c = state.h, None, None
+    if P:
+        # the chunks FIRST: each reads its own slot of the state as it
+        # came and writes it back in place; the decode rows' step then
+        # reads and writes the result, and leaves a chunk's slot as it
+        # is (no live decode row: count 0). Behind the decode rows' step
+        # a chunk's read was a second reader of that step's result, and
+        # XLA computed the whole update twice in every mixed step
+        # (`fusion.N.remat`: PERF.md, PR 56; with two chunks a step that
+        # order's decode rows read 0.13 off the reference on the chip,
+        # 5e-7 on the CPU); read from the state as it came while the
+        # step wrote it, the state was copied whole
+        chunk_count = jnp.sum(rows.ok[S:].reshape(P, C), axis=1)
+        fresh = (rows.chunk_pos[:, 0] == 0)[:, None, None]
+        one = (1, 1) + h.shape[2:]
+        at = [(m, rows.chunk_slot[p]) + (0,) * (h.ndim - 2)
+              for p in range(P)]
+        st0 = jnp.where(fresh[..., None], 0, jnp.concatenate(
+            [lax.dynamic_slice(h, at[p], one)[0] for p in range(P)]))
+        tail_c0 = jnp.where(fresh, 0, tails[rows.chunk_slot])
+        u_c, tail_c = mixer.conv(xbc[S:].reshape(P, C, -1), tail_c0, mp,
+                                 chunk_count)
+        y_c, st_c = mixer.chunk(
+            st0, u_c, tuple(a[S:].reshape(P, C, -1) for a in aux), mp, cfg,
+            chunk_count)
+        y_c = y_c.reshape((P * C, 1) + y_c.shape[2:])
+        for p in range(P):
+            # an idle chunk (argmax of nothing: slot 0) writes back what
+            # is there NOW: read behind the chunks before it, one of
+            # which may have written slot 0 this step
+            h = lax.dynamic_update_slice(
+                h, jnp.where(rows.chunk_ok[p],
+                             st_c[p].astype(sdt)[None, None],
+                             lax.dynamic_slice(h, at[p], one)), at[p])
+    tails_new = tails
     if S:
         # decode rows: slot s is row s; a row that does not decode this
         # step (free, dead, in prefill phase) has count 0 and keeps its
         # state
         count = rows.active.astype(jnp.int32)
-        u, tail_d = ssm_conv(xbc[:S], tails, mp, count)
-        y, h = decode_rows_step(h, m, u, dt[:S], mp, cfg, count, use_kernel)
-        if use_kernel:
-            # a chunk's slot is no live decode row: the step left it as it
-            # was, and reading it from the RESULT leaves no reader of the
-            # buffer a kernel wrote in place (else: a copy of it)
-            h_all = lax.dynamic_index_in_dim(h, m, 0, keepdims=False)
+        u, tail_d = mixer.conv(xbc[:S], tails, mp, count)
+        y_d, h = mixer.decode(h, m, u, tuple(a[:S] for a in aux), mp, cfg,
+                              count, use_kernel)
         tails_new = tail_d.astype(sdt)
-        ys.append(y)
-    if P:
-        chunk_count = jnp.sum(rows.ok[S:].reshape(P, C), axis=1)
-        fresh = (rows.chunk_pos[:, 0] == 0)[:, None, None]
-        h_c0 = jnp.where(fresh[..., None], 0, h_all[rows.chunk_slot])
-        tail_c0 = jnp.where(fresh, 0, tails[rows.chunk_slot])
-        u_c, tail_c = ssm_conv(xbc[S:].reshape(P, C, -1), tail_c0, mp,
-                               chunk_count)
-        y_c, h_c = ssm_scan(u_c, dt[S:].reshape(P, C, -1), mp, cfg,
-                            h_c0.astype(jnp.float32), chunk_count)
-        ys.append(y_c.reshape(P * C, 1, Nh, Hd))
-        for p in range(P):
-            # the chunk's own slot, in place; an idle chunk (argmax of
-            # nothing: slot 0) writes back what is there
-            slot, ok = rows.chunk_slot[p], rows.chunk_ok[p]
-            at = (m, slot, 0, 0, 0)
-            old = lax.dynamic_slice(h, at, (1, 1, Nh, Hd, N))
-            h = lax.dynamic_update_slice(
-                h, jnp.where(ok, h_c[p].astype(sdt)[None, None], old), at)
-            old_t = lax.dynamic_slice_in_dim(tails_new, slot, 1, axis=0)
-            tails_new = lax.dynamic_update_slice_in_dim(
-                tails_new, jnp.where(ok, tail_c[p].astype(sdt)[None], old_t),
-                slot, axis=0)
+    for p in range(P):
+        slot = rows.chunk_slot[p]
+        old_t = lax.dynamic_slice_in_dim(tails_new, slot, 1, axis=0)
+        tails_new = lax.dynamic_update_slice_in_dim(
+            tails_new, jnp.where(rows.chunk_ok[p],
+                                 tail_c[p].astype(sdt)[None], old_t),
+            slot, axis=0)
     conv = lax.dynamic_update_index_in_dim(
         state.conv, jnp.swapaxes(tails_new, 0, 1), m, 0)
-    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
-    x = stream_write(x, ssm_gate_out(y, z, mp, cfg), mix, cfg)
+    y = jnp.concatenate([y for y in (y_d, y_c) if y is not None])
+    x = stream_write(x, mixer.close(y, aux, mp, cfg), mix, cfg)
     x, load = ffn_close(x, lp, cfg, ok=rows.ok[:, None])
     return x, SSMState(h=h, conv=conv), load

@@ -20,10 +20,11 @@ class ModelConfig:
 
     One config class covers the model families (GPT-2, Llama-3,
     Mixtral, SmallThinker, Keye, Granite-4.0-H, JoyAI-LLM-Flash,
-    Xing4.0, GLM-5) — the family is selected by `arch`, the MoE fields, the
-    per-layer attention pattern, the sparse-attention indexer, the
-    per-layer KIND (`layer_types`: Mamba-2 mixers beside attention
-    layers), the latent-attention fields (`kv_lora_rank` and the split
+    Xing4.0, GLM-5, Olmo-Hybrid) — the family is selected by `arch`, the MoE
+    fields, the per-layer attention pattern, the sparse-attention indexer,
+    the per-layer KIND (`layer_types`: Mamba-2 mixers or Gated DeltaNet
+    mixers beside attention layers), the latent-attention fields
+    (`kv_lora_rank` and the split
     head dims: one cached latent a token in place of heads of keys and
     values) and the residual path (`hc_mult`: n streams mixed by
     hyper-connections in place of one).
@@ -31,7 +32,7 @@ class ModelConfig:
 
     arch: str = "llama"  # "gpt2" | "llama" | "mixtral" | "smallthinker"
                          # | "keye" | "granite_hybrid" | "joyai" | "xing"
-                         # | "glm5"
+                         # | "glm5" | "olmo_hybrid"
     vocab_size: int = 32000
     hidden_size: int = 4096
     num_layers: int = 32
@@ -141,14 +142,16 @@ class ModelConfig:
     rope_interleave: bool = False     # the rotation pairs dims (2i, 2i+1)
                                       # of a head, not (i, i + half)
 
-    # per-layer KIND: "mamba" (a Mamba-2 mixer with a fixed-size
-    # recurrent state a stream: cache/ssm_state.py) or "attention" (keys
-    # and values in the paged pool), one entry a layer (a longer list is
-    # read up to num_layers); () = every layer is attention. Kinds have
-    # unlike parameter SHAPES, so each kind's mixer weights are stacked
-    # apart (params["mamba"], params["attn"]) and the layers run as
-    # scans over runs of one kind (models/common.py layer_runs); the
-    # feed-forward of all layers is one stack (params["layers"]).
+    # per-layer KIND: "mamba" (a Mamba-2 mixer), "linear_attention" (a
+    # Gated DeltaNet mixer), both with a fixed-size recurrent state a
+    # stream (cache/ssm_state.py), or "attention" (keys and values in
+    # the paged pool), one entry a layer (a longer list is read up to
+    # num_layers); () = every layer is attention. A model has ONE kind
+    # of recurrent layer (recurrent_kind). Kinds have unlike parameter
+    # SHAPES, so each kind's mixer weights are stacked apart
+    # (params["mamba"] or params["gdn"], params["attn"]) and the layers
+    # run as scans over runs of one kind (models/common.py layer_runs);
+    # the feed-forward of all layers is one stack (params["layers"]).
     layer_types: Tuple[str, ...] = ()
     ssm_heads: int = 0                # Mamba-2 heads
     ssm_head_dim: int = 0             # values a head
@@ -156,8 +159,20 @@ class ModelConfig:
                                       # [ssm_head_dim, ssm_state]
     ssm_groups: int = 1               # groups that share B and C
     ssm_conv: int = 4                 # taps of the causal depthwise conv
-    # what a slot keeps between steps (state, conv tail) is in `dtype`;
-    # a step's arithmetic is float32
+    # a Gated DeltaNet mixer (arXiv:2412.06464): gdn_heads heads whose
+    # state is a matrix [gdn_value_dim, gdn_key_dim] updated by the
+    # delta rule; q, k and v pass ONE causal depthwise conv of gdn_conv
+    # taps (no bias) over 2 x heads x key_dim + heads x value_dim
+    # channels; gdn_neg_eigval: beta = 2 sigmoid(b) in (0, 2), so the
+    # transition I - beta k k^T has an eigenvalue in (-1, 1) (else beta
+    # in (0, 1))
+    gdn_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv: int = 4
+    gdn_neg_eigval: bool = False
+    # what a slot keeps between steps (state, conv tail), either kind, is
+    # in `dtype`; a step's arithmetic is float32
 
     # Granite's four multipliers; 0 = the family has none (the term is
     # left out of the program, not multiplied by one)
@@ -185,6 +200,15 @@ class ModelConfig:
     qk_norm: bool = False             # an RMSNorm with a learned weight over
                                       # each head's queries and keys, before
                                       # the rotation
+    qk_norm_wide: bool = False        # an RMSNorm with a learned weight over
+                                      # the WHOLE query and the whole key
+                                      # projection (all heads at once),
+                                      # before the heads are split (OLMo 2)
+    post_norm: bool = False           # the norm of a sublayer sits on its
+                                      # OUTPUT, x + norm(F(x)), and none on
+                                      # its input (OLMo 2); False = x +
+                                      # F(norm(x)). models/common.py
+                                      # stream_read / stream_write
     # learned sparse attention (the lightning indexer of DeepSeek Sparse
     # Attention): index_heads queries of index_head_dim and ONE index key
     # a token score every cached position, and a query attends only the
@@ -223,20 +247,32 @@ class ModelConfig:
         kinds = tuple(str(k) for k in self.layer_types)[:self.num_layers]
         if kinds:
             if len(kinds) < self.num_layers \
-                    or set(kinds) - {"mamba", "attention"}:
+                    or set(kinds) - {"mamba", "linear_attention", "attention"}:
                 raise ValueError(
                     f"layer_types names {len(kinds)} layers of "
-                    f"{self.num_layers}, each 'mamba' or 'attention': "
-                    f"{kinds}")
+                    f"{self.num_layers}, each 'mamba', 'linear_attention' "
+                    f"or 'attention': {kinds}")
+            if "mamba" in kinds and "linear_attention" in kinds:
+                raise ValueError(
+                    "layer_types names 'mamba' and 'linear_attention' "
+                    "layers: a model has one kind of recurrent layer")
             if "mamba" in kinds and not (self.ssm_heads and self.ssm_head_dim
                                          and self.ssm_state):
                 raise ValueError("a 'mamba' layer needs ssm_heads, "
                                  "ssm_head_dim and ssm_state")
+            if "linear_attention" in kinds and not (
+                    self.gdn_heads and self.gdn_key_dim
+                    and self.gdn_value_dim and self.gdn_conv > 1):
+                raise ValueError(
+                    "a 'linear_attention' layer needs gdn_heads, "
+                    "gdn_key_dim, gdn_value_dim and a conv of two taps "
+                    "or more (gdn_conv)")
             if self.layer_pattern() is not None or self.has_indexer:
                 raise ValueError(
                     "layer_types beside a per-layer attention pattern or "
                     "a sparse-attention indexer is not supported")
         object.__setattr__(self, "layer_types", kinds)
+        self._check_norms()
         if self.router_input not in ("ffn", "attn"):
             raise ValueError(f"unknown router_input {self.router_input!r}")
         self._check_latent()
@@ -256,6 +292,25 @@ class ModelConfig:
         if self.router_input == "attn" and self.moe_impl == "ep":
             raise ValueError("expert parallelism (moe_impl 'ep') does not "
                              "carry router logits taken before attention")
+
+    def _check_norms(self):
+        """post_norm and qk_norm_wide beside what carries them: the
+        paths of a model with a recurrent layer kind norm through
+        stream_read / stream_write alone; others have bodies that norm
+        a sublayer's input themselves."""
+        if self.qk_norm and self.qk_norm_wide:
+            raise ValueError("qk_norm (a head at a time) and qk_norm_wide "
+                             "(the whole projection): one or the other")
+        if not self.post_norm:
+            return
+        for name, on in (("no recurrent layer kind (layer_types)",
+                          not self.has_ssm),
+                         ("hc_mult", bool(self.hc_mult)),
+                         ("router_input 'attn'",
+                          self.is_moe and self.router_input == "attn"),
+                         ("arch 'gpt2'", self.arch == "gpt2")):
+            if on:
+                raise ValueError(f"post_norm beside {name} is not supported")
 
     def _check_latent(self):
         """The latent-attention fields come together, and beside
@@ -288,7 +343,7 @@ class ModelConfig:
                 "attention an index head's first qk_rope_head_dim dims rotate")
         for name, on in (("layer_types", bool(self.layer_types)),
                          ("sliding_window", self.layer_pattern() is not None),
-                         ("qk_norm", self.qk_norm),
+                         ("qk_norm", self.qk_norm or self.qk_norm_wide),
                          ("use_bias", self.use_bias),
                          ("attention_multiplier",
                           bool(self.attention_multiplier))):
@@ -508,14 +563,24 @@ class ModelConfig:
         return self.experts_held or self.num_experts
 
     @property
+    def recurrent_kind(self) -> str:
+        """The model's ONE kind of recurrent layer ("mamba" or
+        "linear_attention"), "" for a model without."""
+        return next((k for k in ("mamba", "linear_attention")
+                     if k in self.layer_types), "")
+
+    @property
     def has_ssm(self) -> bool:
-        """Some layer is a Mamba-2 mixer: a stream holds a recurrent
-        state beside (or in place of) its pages."""
-        return "mamba" in self.layer_types
+        """Some layer is a recurrent mixer (Mamba-2 or Gated DeltaNet):
+        a stream holds a fixed-size state beside (or in place of) its
+        pages."""
+        return bool(self.recurrent_kind)
 
     @property
     def num_ssm_layers(self) -> int:
-        return self.layer_types.count("mamba")
+        """Layers of the recurrent kind."""
+        return self.layer_types.count(self.recurrent_kind) \
+            if self.has_ssm else 0
 
     @property
     def num_attn_layers(self) -> int:
@@ -532,6 +597,31 @@ class ModelConfig:
     def ssm_conv_dim(self) -> int:
         """Channels through the conv: x, B and C."""
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def gdn_key_width(self) -> int:
+        """All heads' keys (and queries) of a Gated DeltaNet mixer."""
+        return self.gdn_heads * self.gdn_key_dim
+
+    @property
+    def gdn_value_width(self) -> int:
+        """All heads' values (and the output gate)."""
+        return self.gdn_heads * self.gdn_value_dim
+
+    @property
+    def gdn_conv_dim(self) -> int:
+        """Channels through the conv: q, k and v."""
+        return 2 * self.gdn_key_width + self.gdn_value_width
+
+    @property
+    def gdn_head_group(self) -> int:
+        """Heads whose values share one row of lanes in the stored
+        state (cache/ssm_state.py): the fewest that make a whole number
+        of 128 lanes of gdn_value_dim-wide values, if they divide the
+        heads; else 1 (a head's values alone, padded to whole lanes by
+        the device)."""
+        g = 128 // np.gcd(self.gdn_value_dim, 128)
+        return int(g) if self.gdn_heads % g == 0 else 1
 
     @property
     def q_per_kv(self) -> int:
@@ -718,6 +808,26 @@ def glm5() -> ModelConfig:
     )
 
 
+def olmo_hybrid_7b() -> ModelConfig:
+    """Olmo-Hybrid-7B (huggingface.co/allenai, `olmo_hybrid`): 32 layers,
+    of every four three Gated DeltaNet mixers (30 heads whose state is
+    192 x 96, updated by the delta rule with beta in (0, 2); q, k and v
+    through one causal conv of 4 taps; a gated norm a head) and one full
+    attention layer of 30 heads over 30 KV heads of 128 with a norm over
+    the whole query and key projections and NO rotation; a dense SwiGLU
+    of 11,008 in every layer; the norm of each sublayer on its OUTPUT;
+    untied head."""
+    return ModelConfig(
+        arch="olmo_hybrid", vocab_size=100352, hidden_size=3840,
+        num_layers=32, num_heads=30, num_kv_heads=30, head_dim=128,
+        intermediate_size=11008, max_seq_len=65536, norm_eps=1e-6,
+        pos_embedding="none", post_norm=True, qk_norm_wide=True,
+        layer_types=(("linear_attention",) * 3 + ("attention",)) * 8,
+        gdn_heads=30, gdn_key_dim=96, gdn_value_dim=192, gdn_conv=4,
+        gdn_neg_eigval=True,
+    )
+
+
 def tiny(arch: str = "llama", **kw) -> ModelConfig:
     """Small config for tests: runs in <1s on CPU, exercises every code path."""
     base = dict(
@@ -756,6 +866,18 @@ def tiny(arch: str = "llama", **kw) -> ModelConfig:
                     pos_embedding="none", tie_embeddings=True,
                     embedding_multiplier=12.0, residual_multiplier=0.22,
                     attention_multiplier=0.0625, logits_scaling=16.0)
+    if arch == "olmo_hybrid":
+        # every mechanism of the real one: two Gated DeltaNet layers
+        # and a full one, values twice as wide as keys, FOUR heads'
+        # values in one row of 128 lanes (the real one: two in 384),
+        # beta to 2, one query a KV head, the projection-wide norms, no
+        # rotation, the norm on each sublayer's output, an untied head
+        base.update(num_layers=3, num_kv_heads=4,
+                    layer_types=("linear_attention", "linear_attention",
+                                 "attention"),
+                    gdn_heads=4, gdn_key_dim=16, gdn_value_dim=32,
+                    gdn_neg_eigval=True, pos_embedding="none",
+                    post_norm=True, qk_norm_wide=True, norm_eps=1e-6)
     if arch == "joyai":
         # every mechanism of the real one: the query's latent, one cached
         # row of latent + rotary key, value heads narrower than the
@@ -819,6 +941,7 @@ PRESETS = {
     "joyai-llm-flash": joyai_llm_flash,
     "xing4.0-29b-a4b": xing4_29b_a4b,
     "glm-5": glm5,
+    "olmo-hybrid-7b": olmo_hybrid_7b,
 }
 
 

@@ -221,8 +221,9 @@ class ServingEngine:
         if self.runtime.prefix_caching:
             indexer_unsupported(self.cfg, "prefix caching (and the host "
                                           "KV tier behind it)")
-        # so does what cannot take a recurrent state a slot (Mamba-2
-        # layers): it has no pages to hash, export or roll back, and no
+        # so does what cannot take a recurrent state a slot (Mamba-2 or
+        # Gated DeltaNet layers): it has no pages to hash, export or roll
+        # back, and no
         # sharding of its own yet
         for axis, what in (("stage", "pipeline serving"),
                            ("seq", "the sequence-parallel prefill lane"),
@@ -303,7 +304,8 @@ class ServingEngine:
                 quant=self.runtime.kv_quant == "int8"), mesh)
         self.cache = init_paged_cache(self.cfg, self.runtime,
                                       shardings=cache_shardings)
-        # a model with Mamba-2 layers: every slot's recurrent state
+        # a model with recurrent layers (Mamba-2, Gated DeltaNet): every
+        # slot's recurrent state
         # (cache/ssm_state.py), DONATED to each mixed block and rebound
         # from its result like the window; None for every other model
         self._ssm_state: Optional[SSMState] = init_ssm_state(
@@ -1312,7 +1314,7 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
     attended and the rows its read moved out of the cache, the mean
     over the block's layers, rows and steps.
 
-    state (a model with Mamba-2 layers; None for every other): the
+    state (a model with recurrent layers; None for every other): the
     slots' recurrent state (cache/ssm_state.py). It is read and written
     by every step, so it rides the CARRY (the pool stays outside it,
     read-only, as ever) and comes back as the ninth value; `load` then

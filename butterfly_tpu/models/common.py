@@ -151,11 +151,19 @@ def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 # Building blocks
 # ---------------------------------------------------------------------------
 
-def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+def rms_norm(x: jax.Array, scale: jax.Array, eps: float,
+             axis=-1) -> jax.Array:
     dt = x.dtype
     x = x.astype(jnp.float32)
-    x = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    x = x * lax.rsqrt(jnp.mean(x * x, axis=axis, keepdims=True) + eps)
     return (x * scale.astype(jnp.float32)).astype(dt)
+
+
+def wide_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm over the two minor dims of x [..., N, H] at once (a
+    whole projection, before its heads are split) with a learned weight
+    [N, H]."""
+    return rms_norm(x, scale, eps, axis=(-2, -1))
 
 
 def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
@@ -342,6 +350,8 @@ def qkv_proj(x: jax.Array, p: Params, cfg: ModelConfig,
     Mixtral, SmallThinker, Keye).
     cfg.qk_norm: an RMSNorm with a learned weight [H] over each head's
     queries and keys, before the rotation (the Qwen3 families, Keye).
+    cfg.qk_norm_wide: ONE RMSNorm over the whole projection, all heads,
+    with a learned weight [N, H] (OLMo 2's family: wide_norm).
     rope: the layer's flag out of its pattern (layer_stack), a traced
     scalar: 0 = this layer has no positional encoding. It turns the
     rotation into the identity (cos 1, sin 0), exactly: x*1 - y*0."""
@@ -356,6 +366,9 @@ def qkv_proj(x: jax.Array, p: Params, cfg: ModelConfig,
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"]["scale"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"]["scale"], cfg.norm_eps)
+    if cfg.qk_norm_wide:
+        q = wide_norm(q, p["q_norm"]["scale"], cfg.norm_eps)
+        k = wide_norm(k, p["k_norm"]["scale"], cfg.norm_eps)
     if cfg.attention_multiplier:
         # the family's score scale in place of H ** -0.5, which every
         # attend and kernel behind this applies: the queries carry the
@@ -975,10 +988,15 @@ def stream_read(x: jax.Array, lp: Params, sub: int, cfg: ModelConfig):
     """What sublayer `sub` of a layer (1 the mixer, 2 the feed-forward)
     reads of the residual path x, under the sublayer's own pre-norm
     lp["ln<sub>"], and what stream_write takes to put its output back:
-    (h [B,T,D], mix). hc_mult 0: (norm(x), None). Else x is [n,B,T,D]
+    (h [B,T,D], mix). hc_mult 0: (norm(x), None); under cfg.post_norm
+    (x, the norm's leaves): the norm waits for the sublayer's OUTPUT.
+    Else x is [n,B,T,D]
     and mix = (H_res [n,n,R], H_post [n,R]) of lp["hc<sub>"] (the
     equations above)."""
     norm = lp[f"ln{sub}"]
+    if cfg.post_norm:
+        # the sublayer reads x as it is; stream_write norms its output
+        return x, norm
     if not cfg.hc_mult:
         return pre_norm(x, norm, cfg), None
     with jax.named_scope("hc_mix"):
@@ -1019,8 +1037,11 @@ def stream_write(x: jax.Array, y: jax.Array, mix, cfg: ModelConfig
                  ) -> jax.Array:
     """A sublayer's output y [B,T,D] onto the residual path x, with
     stream_read's mix. hc_mult 0 (mix None): x + y, the output first
-    times a family's residual multiplier (Granite). Else X' = H_res @ X
+    times a family's residual multiplier (Granite); under cfg.post_norm
+    x + norm(y), mix the norm's leaves. Else X' = H_res @ X
     + outer(H_post, y) over the n streams, in float32."""
+    if cfg.post_norm:
+        y, mix = pre_norm(y, mix, cfg), None
     if mix is None:
         if cfg.residual_multiplier:
             y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
@@ -1047,7 +1068,8 @@ def stream_fold(x: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 # ---------------------------------------------------------------------------
 # Mamba-2 mixer (cfg.layer_types "mamba"): a layer whose memory of a
-# stream is a FIXED-SIZE recurrent state, not rows that grow.
+# stream is a FIXED-SIZE recurrent state, not rows that grow. (The
+# other recurrent kind, Gated DeltaNet, follows it below.)
 #
 #   [z | xBC | dt] = in_proj(h)          widths Di | Dc | Nh, no bias
 #   xBC = silu(conv(xBC))                causal, depthwise, K taps with
@@ -1068,14 +1090,23 @@ def stream_fold(x: jax.Array, cfg: ModelConfig) -> jax.Array:
 # columns are real.
 # ---------------------------------------------------------------------------
 
+#: a recurrent layer kind (cfg.recurrent_kind) by the name of its mixer
+RECURRENT_NAMES = {"mamba": "Mamba-2", "linear_attention": "Gated DeltaNet"}
+
+
+#: the top-level stack of params that holds a recurrent kind's mixers
+RECURRENT_STACKS = {"mamba": "mamba", "linear_attention": "gdn"}
+
+
 def ssm_unsupported(cfg: ModelConfig, what: str) -> None:
-    """Refuse a model with Mamba-2 layers on a path that does not carry
-    a recurrent state a stream (it has no pages to hash, export, roll
-    back or shard)."""
+    """Refuse a model with recurrent layers (Mamba-2 or Gated DeltaNet)
+    on a path that does not carry a recurrent state a stream (it has no
+    pages to hash, export, roll back or shard)."""
     if cfg.has_ssm:
         raise NotImplementedError(
             f"{what} does not carry the recurrent state of a model with "
-            f"Mamba-2 layers ({cfg.num_ssm_layers} of {cfg.num_layers}): "
+            f"{RECURRENT_NAMES[cfg.recurrent_kind]} layers "
+            f"({cfg.num_ssm_layers} of {cfg.num_layers}): "
             "not supported for this model")
 
 
@@ -1089,7 +1120,7 @@ def layer_runs(cfg: ModelConfig):
     a run ends where the leading dense layers do (ffn_run says which
     stack a run's feed-forward is in)."""
     kinds = cfg.layer_types or ("attention",) * cfg.num_layers
-    runs, seen = [], {"mamba": 0, "attention": 0}
+    runs, seen = [], {"mamba": 0, "linear_attention": 0, "attention": 0}
     for l, kind in enumerate(kinds):
         if runs and runs[-1][0] == kind and l != cfg.first_k_dense:
             runs[-1][2] += 1
@@ -1138,10 +1169,10 @@ def ssm_in_proj(h: jax.Array, mp: Params, cfg: ModelConfig):
     return zxd[..., :Di], zxd[..., Di:Di + Dc], zxd[..., Di + Dc:]
 
 
-@jax.named_scope("ssm_conv")
-def ssm_conv(xbc: jax.Array, tail: jax.Array, mp: Params, count):
+def _causal_conv(xbc: jax.Array, tail: jax.Array, mp: Params, count):
     """The causal depthwise conv over a row's stream: xbc [B,T,Dc] the
-    call's inputs, tail [B,K-1,Dc] the K-1 before them. Returns
+    call's inputs, tail [B,K-1,Dc] the K-1 before them, mp["conv_w"]
+    [K,Dc] and, where the family has one, mp["conv_b"]. Returns
     (silu(conv) [B,T,Dc] float32, the tail after the row's first
     `count` [B] inputs: unchanged where count is 0)."""
     T = xbc.shape[1]
@@ -1149,9 +1180,13 @@ def ssm_conv(xbc: jax.Array, tail: jax.Array, mp: Params, count):
     K = w.shape[0]
     full = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
     u = sum(full[:, k:k + T].astype(jnp.float32) * w[k] for k in range(K))
-    u = jax.nn.silu(u + mp["conv_b"].astype(jnp.float32))
+    if "conv_b" in mp:
+        u = u + mp["conv_b"].astype(jnp.float32)
     at = count[:, None] + jnp.arange(K - 1)[None, :]         # [B, K-1]
-    return u, jnp.take_along_axis(full, at[:, :, None], axis=1)
+    return jax.nn.silu(u), jnp.take_along_axis(full, at[:, :, None], axis=1)
+
+
+ssm_conv = jax.named_scope("ssm_conv")(_causal_conv)
 
 
 def ssm_step_inputs(u: jax.Array, dt: jax.Array, mp: Params,
@@ -1224,6 +1259,246 @@ def ssm_gate_out(y: jax.Array, z: jax.Array, mp: Params,
     g = y.reshape(B, T, -1) * jax.nn.silu(z.astype(jnp.float32))
     g = rms_norm(g, mp["norm"]["scale"], cfg.norm_eps).astype(z.dtype)
     return qeinsum("bti,id->btd", g, mp["out_proj"], z.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Gated DeltaNet mixer (cfg.layer_types "linear_attention";
+# arXiv:2412.06464): a layer whose memory of a stream is ONE MATRIX a
+# head, rewritten by the delta rule. H heads, keys of dk, values of dv:
+#
+#   [q | k | v | z] = in_proj(x)         widths H dk | H dk | H dv | H dv
+#   [a | b] = ab_proj(x)                 H | H, no bias anywhere
+#   [q | k | v] = silu(conv([q | k | v]))  causal, depthwise, K taps, no
+#                                        bias: ONE conv over 2 H dk + H dv
+#   q = q / |q| * dk^-0.5;  k = k / |k|  a head at a time (|.|^2 + 1e-6)
+#   beta = sigmoid(b) (x 2 under gdn_neg_eigval)
+#   alpha = exp(-exp(A_log) softplus(a + dt_bias))       (a head)
+#   S' = alpha S;  u = beta (v - S' k);  S = S' + u k^T;  o = S q
+#   out = out_proj(RMSNorm_dv(o) * w * silu(z))   norm a head, THEN gate
+#
+# S [dv, dk] a head, zero at position 0. The delta rule READS what the
+# state holds for the incoming key and writes the difference, where
+# Mamba-2's state is decayed and added to. What a stream keeps between
+# calls is S and the conv's last K-1 inputs, in cfg.dtype; a call's
+# arithmetic is float32. The pieces are composed into a layer in ONE
+# place, cache/ssm_state.py advance_packed, beside the Mamba-2 pieces:
+# a decode row is gdn_step over the state where it lies, a chunk
+# gdn_chunk (the chunkwise form: a chunk's positions at once).
+# ---------------------------------------------------------------------------
+
+#: positions gdn_chunk solves at once; longer rows scan such pieces
+GDN_CHUNK = 32
+
+
+@jax.named_scope("gdn_proj")
+def gdn_in_proj(h: jax.Array, gp: Params, cfg: ModelConfig):
+    """(qkv [B,T,Dc], z [B,T,H dv], a [B,T,H], b [B,T,H]) of the
+    mixer's input."""
+    qkvz = qeinsum("btd,dp->btp", h, gp["in_proj"], h.dtype)
+    ab = qeinsum("btd,dp->btp", h, gp["ab_proj"], h.dtype)
+    Dc, H = cfg.gdn_conv_dim, cfg.gdn_heads
+    return qkvz[..., :Dc], qkvz[..., Dc:], ab[..., :H], ab[..., H:]
+
+
+gdn_conv = jax.named_scope("gdn_conv")(_causal_conv)
+
+
+def gdn_step_inputs(u: jax.Array, a: jax.Array, b: jax.Array, gp: Params,
+                    cfg: ModelConfig, count):
+    """What the recurrence reads of its positions, formed before any
+    state is touched: u [B,T,Dc] float32 (gdn_conv), a and b [B,T,H] as
+    projected, count [B] the row's real positions. Returns (q [B,T,H,dk]
+    normalised and scaled, k [B,T,H,dk] normalised, v [B,T,H dv] FLAT,
+    log_alpha [B,T,H], beta [B,T,H]), float32. A position that is not
+    real has log_alpha 0 and beta 0: it leaves a state as it is, bit
+    for bit."""
+    B, T = u.shape[:2]
+    H, dk, Dk = cfg.gdn_heads, cfg.gdn_key_dim, cfg.gdn_key_width
+
+    def unit(x):
+        x = x.reshape(B, T, H, dk)
+        return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+    q, k, v = unit(u[..., :Dk]) * dk ** -0.5, unit(u[..., Dk:2 * Dk]), \
+        u[..., 2 * Dk:]
+    real = jnp.arange(T)[None, :, None] < count[:, None, None]  # [B,T,1]
+    beta = jax.nn.sigmoid(b.astype(jnp.float32))
+    if cfg.gdn_neg_eigval:
+        beta = 2.0 * beta
+    dt = jax.nn.softplus(a.astype(jnp.float32)
+                         + gp["dt_bias"].astype(jnp.float32))
+    log_alpha = -jnp.exp(gp["A_log"].astype(jnp.float32)) * dt
+    return q, k, v, jnp.where(real, log_alpha, 0.0), \
+        jnp.where(real, beta, 0.0)
+
+
+def head_lanes(a: jax.Array, dv: int) -> jax.Array:
+    """a [..., g], a number a head of a group -> [..., g dv]: head i's
+    over its own dv lanes. A chain of selects over a broadcast: no
+    reshape of the lanes, which the device would copy where dv is no
+    whole number of 128."""
+    g = a.shape[-1]
+    head = np.arange(g * dv) // dv      # a constant of the program
+    out = jnp.broadcast_to(a[..., :1], a.shape[:-1] + (g * dv,))
+    for i in range(1, g):
+        out = jnp.where(head == i, a[..., i:i + 1], out)
+    return out
+
+
+@jax.named_scope("gdn_step")
+def gdn_step(h: jax.Array, m, q: jax.Array, k: jax.Array, v: jax.Array,
+             log_alpha: jax.Array, beta: jax.Array, cfg: ModelConfig):
+    """One position of the delta rule for every slot of layer m, over
+    the state where it lies: h [Ls, S, H/g, dk, g dv] the WHOLE carried
+    state (cache/ssm_state.py has the layout: g heads' values share a
+    row of lanes), q and k [S,H,dk], v [S, H dv] flat, log_alpha and
+    beta [S,H] (gdn_step_inputs at T == 1). One reduction over layer
+    m's state gives alpha S k and alpha S q at once, the readout is
+    S q = alpha S q + u (k . q), and the update alpha S + u k^T is
+    written in place at the layer's index: the state is read twice and
+    written once, and no value of its size is formed beside it.
+    Returns (o [S, H dv] float32 flat, h)."""
+    S, H, dk = k.shape
+    g, dv = cfg.gdn_head_group, cfg.gdn_value_dim
+    J = H // g
+
+    def lanes(a):               # [S,H,X] -> [S,J,X,g dv]
+        return head_lanes(jnp.moveaxis(a.reshape(S, J, g, -1), 2, -1), dv)
+
+    st = lax.dynamic_index_in_dim(h, m, 0, keepdims=False) \
+        .astype(jnp.float32)                               # [S,J,dk,g dv]
+    kx, qx = lanes(k), lanes(q)
+    alpha = lanes(jnp.exp(log_alpha)[..., None])           # [S,J,1,g dv]
+    bt = lanes(beta[..., None])[:, :, 0]                   # [S,J,g dv]
+    kq = lanes(jnp.sum(k * q, axis=-1, keepdims=True))[:, :, 0]
+    r = alpha[:, :, 0] * jnp.sum(st * kx, axis=2)          # alpha S k
+    p = alpha[:, :, 0] * jnp.sum(st * qx, axis=2)          # alpha S q
+    u = bt * (v.reshape(S, J, g * dv) - r)
+    new = alpha * st + u[:, :, None, :] * kx
+    h = lax.dynamic_update_index_in_dim(h, new.astype(h.dtype), m, 0)
+    return (p + u * kq).reshape(S, H * dv), h
+
+
+def _unit_lower_inverse(A: jax.Array) -> jax.Array:
+    """(I + A)^-1 for A [..., c, c] strictly lower triangular, c a power
+    of two: forward substitution by blocks. With X the inverse of the
+    diagonal blocks of size b and L the blocks under them that pair
+    them up, the inverse of the blocks of size 2b is X - X L X."""
+    c = A.shape[-1]
+    t = np.arange(c)                    # the masks: constants
+    X = jnp.broadcast_to(jnp.eye(c, dtype=A.dtype), A.shape)
+    b = 1
+    while b < c:
+        under = (t[:, None] // (2 * b) == t[None, :] // (2 * b)) \
+            & (t[:, None] % (2 * b) >= b) & (t[None, :] % (2 * b) < b)
+        L = jnp.where(under, A, 0.0)
+        X = X - jnp.einsum("...ij,...jk,...kl->...il", X, L, X,
+                           precision=lax.Precision.HIGHEST)
+        b *= 2
+    return X
+
+
+def _gdn_piece(cfg: ModelConfig, state, piece):
+    """gdn_chunk over c positions solved at once: state [P,H/g,dk,g dv]
+    AS HELD; piece = (q, k [P,c,H,dk], v [P,c,H dv] flat, log_alpha,
+    beta [P,c,H]). With G_t the running sum of log_alpha and D[t,i] =
+    exp(G_t - G_i):
+        (I + A) U = beta (V - exp(G) K S0^T),  A[t,i] = beta_t D[t,i] k_t.k_i, i < t
+        o_t = exp(G_t) S0 q_t + sum_{i<=t} D[t,i] (q_t.k_i) u_i
+        S   = exp(G_c) S0 + sum_i exp(G_c - G_i) u_i k_i^T
+    which is the per-position rule unrolled (U's rows are its u_t). The
+    two things that touch the state, S0 k and S0 q before and the sum of
+    u k^T after, are a product with it and a sum into it in the
+    layout it is HELD in: it is never transposed
+    (XLA gave the whole carried state the transposed layout, and copied
+    it, when one slot of it was). The c x c solve is a head at a time,
+    on values of a chunk's size."""
+    q, k, v, la, beta = piece
+    hi = lax.Precision.HIGHEST
+    P, c, H, dk = k.shape
+    g, dv = cfg.gdn_head_group, cfg.gdn_value_dim
+    J = H // g
+
+    def lanes(a):               # [P,n,H,X] -> [P,n,J,X,g dv]
+        return head_lanes(
+            jnp.moveaxis(a.reshape(P, a.shape[1], J, g, -1), 3, -1), dv)
+
+    def heads(a):               # [P,c,J,g dv] -> [P,H,c,dv]
+        return jnp.moveaxis(a.reshape(P, c, H, dv), 1, 2)
+
+    kx = lanes(k)                                          # [P,c,J,dk,L]
+    # S0 k_t and S0 q_t: one product over the state as it is held, a
+    # group's g heads' keys and queries against all of its g dv lanes,
+    # of which each head keeps its own (as a broadcast product and a
+    # sum over dk, 32 positions cost what 64 slots' decode rows do)
+    kq = jnp.concatenate([k.reshape(P, c, J, g, dk),
+                          q.reshape(P, c, J, g, dk)], axis=3)
+    own = np.arange(g * dv) // dv == np.arange(g)[:, None]  # [g, L]
+    r = jnp.einsum("pcjik,pjkl->pcjil", kq, state, precision=hi)
+    sk = heads(jnp.sum(jnp.where(own, r[..., :g, :], 0.0), axis=-2))
+    sq = heads(jnp.sum(jnp.where(own, r[..., g:, :], 0.0), axis=-2))
+    q, k, v = (jnp.moveaxis(a.reshape(P, c, H, -1), 1, 2) for a in (q, k, v))
+    la, beta = (jnp.moveaxis(a, 1, 2) for a in (la, beta))  # [P,H,c]
+    G = jnp.cumsum(la, axis=-1)
+    t = np.arange(c)
+    seen = t[:, None] >= t[None, :]                        # i <= t
+    D = jnp.exp(jnp.where(seen, G[..., :, None] - G[..., None, :], -jnp.inf))
+    eG = jnp.exp(G)[..., None]
+    kk = jnp.einsum("phtk,phik->phti", k, k, precision=hi)
+    A = jnp.where(t[:, None] > t[None, :], beta[..., None] * D * kk, 0.0)
+    U = jnp.einsum("phti,phiv->phtv", _unit_lower_inverse(A),
+                   beta[..., None] * (v - eG * sk), precision=hi)
+    qk = jnp.einsum("phtk,phik->phti", q, k, precision=hi)
+    o = eG * sq + jnp.einsum("phti,phiv->phtv", D * qk, U, precision=hi)
+    # the state after: decayed, plus each u_i k_i^T decayed from i on
+    uw = U * jnp.exp(G[..., -1:] - G)[..., None]           # [P,H,c,dv]
+    uw = jnp.moveaxis(uw, 2, 1).reshape(P, c, J, 1, g * dv)
+    decay = lanes(jnp.moveaxis(G, 1, 2)[:, -1:, :, None])[:, 0]  # [P,J,1,L]
+    state = jnp.exp(decay) * state + jnp.sum(uw * kx, axis=1)
+    return state, jnp.moveaxis(o, 1, 2)                    # [P,c,H,dv]
+
+
+@jax.named_scope("gdn_chunk")
+def gdn_chunk(q: jax.Array, k: jax.Array, v: jax.Array,
+              log_alpha: jax.Array, beta: jax.Array, state: jax.Array,
+              cfg: ModelConfig):
+    """The delta rule over rows of T positions, in the chunkwise form:
+    q and k [P,T,H,dk], v [P,T,H dv] flat, log_alpha and beta [P,T,H]
+    (gdn_step_inputs: a position that is not real has 0 and 0, and
+    passes the state on), state [P,H/g,dk,g dv] float32 BEFORE the
+    call, in the layout it is held in. Pieces of GDN_CHUNK positions
+    (fewer for a short row: the next power of two) are solved at once
+    (_gdn_piece), the state handed from piece to piece; the row is
+    padded to whole pieces with positions that are not real. Returns
+    (o [P,T,H,dv] float32, state after)."""
+    T = q.shape[1]
+    c = min(GDN_CHUNK, 1 << max(T - 1, 0).bit_length())
+    n = -(-T // c)
+
+    def pieces(a):             # [P,T,..] -> [n,P,c,..]
+        a = jnp.pad(a, ((0, 0), (0, n * c - T)) + ((0, 0),) * (a.ndim - 2))
+        return jnp.moveaxis(a.reshape((a.shape[0], n, c) + a.shape[2:]), 1, 0)
+
+    xs = tuple(pieces(a) for a in (q, k, v, log_alpha, beta))
+    if n == 1:
+        state, o = _gdn_piece(cfg, state, tuple(a[0] for a in xs))
+    else:
+        state, o = lax.scan(partial(_gdn_piece, cfg), state, xs)
+        o = jnp.moveaxis(o, 0, 1).reshape((o.shape[1], n * c) + o.shape[3:])
+    return o[:, :T], state
+
+
+@jax.named_scope("gdn_gate")
+def gdn_gate_out(o: jax.Array, z: jax.Array, gp: Params,
+                 cfg: ModelConfig) -> jax.Array:
+    """o [B,T,H,dv] float32 normed a head at a time (ONE learned weight
+    of dv shared by the heads), THEN gated by silu(z), then the
+    out-projection (Mamba-2's ssm_gate_out gates first and norms all
+    heads at once)."""
+    B, T = o.shape[:2]
+    y = rms_norm(o, gp["norm"]["scale"], cfg.norm_eps).reshape(B, T, -1)
+    y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype)
+    return qeinsum("bti,id->btd", y, gp["out_proj"], z.dtype)
 
 
 def ffn_close(x: jax.Array, lp: Params, cfg: ModelConfig, route=None,
@@ -1804,10 +2079,11 @@ def _hybrid_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                     cache: KVCache, positions: jax.Array,
                     last_index: Optional[jax.Array]
                     ) -> Tuple[jax.Array, KVCache]:
-    """forward for a model with Mamba-2 layers (cfg.layer_types): the
+    """forward for a model with recurrent layers (cfg.layer_types:
+    Mamba-2 or Gated DeltaNet): the
     layers run as scans over runs of one kind (layer_runs), each run
     riding its kind's stack by index. cache.k/v hold the attention
-    layers alone. A Mamba layer is the packed serving step's
+    layers alone. A recurrent layer is the packed serving step's
     (cache/ssm_state.py advance_packed): the batch's B rows are B chunks
     of T columns, row b the chunk of slot b of cache.ssm, so a row's
     state advances by its REAL positions: all T, or last_index + 1
@@ -1824,12 +2100,14 @@ def _hybrid_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         ok=(jnp.arange(T)[None, :] < count[:, None]).reshape(-1),
         chunk_slot=jnp.arange(B), chunk_ok=count > 0, chunk_pos=positions)
 
-    def mamba(carry, idx):
+    mixers = params[RECURRENT_STACKS[cfg.recurrent_kind]]
+
+    def recurrent(carry, idx):
         x, state = carry
         l, m = idx
         x, state, _ = advance_packed(
             x, layer_at(params["layers"], l, cfg),
-            layer_at(params["mamba"], m, cfg), state, m, rows, cfg)
+            layer_at(mixers, m, cfg), state, m, rows, cfg)
         return (x, state), None
 
     def attention(carry, idx):
@@ -1849,9 +2127,9 @@ def _hybrid_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     k, v, state = cache.k, cache.v, cache.ssm
     for kind, first, n, at in layer_runs(cfg):
         idx = (first + jnp.arange(n), at + jnp.arange(n))
-        if kind == "mamba":
+        if kind != "attention":
             (x, state), _ = lax.scan(
-                mamba, (x.reshape(B * T, 1, -1), state), idx)
+                recurrent, (x.reshape(B * T, 1, -1), state), idx)
             x = x.reshape(B, T, -1)
         else:
             (x, k, v), _ = lax.scan(attention, (x, k, v), idx)
@@ -1987,9 +2265,32 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
 # Init
 # ---------------------------------------------------------------------------
 
+def stream_seed(cfg: ModelConfig) -> Tuple[float, float]:
+    """(the embedding's std, a sublayer norm's scale) of a SEEDED model.
+    A pre-norm sublayer reads norm(x), so the stream's size is nothing
+    to it: N(0, .02) and ones. Under cfg.post_norm a sublayer reads the
+    RAW stream and adds a vector of the norm's scale to it whatever it
+    read: with ones over an embedding of .02 the stream is the first
+    sublayer's output from the first layer on and grows to sqrt(2L), a
+    rounding of the stream is a large TURN of it while it is short
+    (each sublayer multiplies an error's square by 1 + g^2 / l at depth
+    l, g the sublayer's own gain: 1e-3 at the embedding came out 0.3
+    to 0.6 at the logits of 32 layers), and a and b of a Gated DeltaNet
+    mixer sit at +-10 (beta at 0 or 2, alpha at 0 or 1). Nothing could
+    be compared with such a model below the noise (PERF.md, PR 56). So
+    the embedding is seeded at 1 and each of the 2L norms at
+    (2L)^-1/2: the stream stays between 1 and sqrt(2), every sublayer
+    counts the same, and a, b stay where beta spans (0, 2)."""
+    if cfg.post_norm:
+        return 1.0, (2 * cfg.num_layers) ** -0.5
+    return 0.02, 1.0
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
-    """Random init (normal, 0.02 std — GPT-2 style) in cfg.param_dtype."""
+    """Random init (normal, 0.02 std — GPT-2 style; stream_seed has what
+    a post_norm model changes) in cfg.param_dtype."""
     pdt = jnp.dtype(cfg.param_dtype)
+    emb_std, ln = stream_seed(cfg)
     L, D, Nq, Kv, H, F, V = (cfg.num_layers, cfg.hidden_size, cfg.num_heads,
                              cfg.num_kv_heads, cfg.head_dim,
                              cfg.intermediate_size, cfg.vocab_size)
@@ -2025,14 +2326,17 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             "wo": w(next(keys), La, Nq, H, D),
         }
     layers: Params = {
-        "ln1": {"scale": jnp.ones((L, D), pdt)},
-        "ln2": {"scale": jnp.ones((L, D), pdt)},
+        "ln1": {"scale": jnp.full((L, D), ln, pdt)},
+        "ln2": {"scale": jnp.full((L, D), ln, pdt)},
     }
     if not cfg.layer_types:
         layers["attn"] = attn
     if cfg.qk_norm:
         attn["q_norm"] = {"scale": jnp.ones((La, H), pdt)}
         attn["k_norm"] = {"scale": jnp.ones((La, H), pdt)}
+    if cfg.qk_norm_wide:
+        attn["q_norm"] = {"scale": jnp.ones((La, Nq, H), pdt)}
+        attn["k_norm"] = {"scale": jnp.ones((La, Kv, H), pdt)}
     if cfg.has_indexer:
         Ni, Hi = cfg.index_heads, cfg.index_head_dim
         layers["index"] = {
@@ -2094,7 +2398,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         }
 
     params: Params = {
-        "embed": {"tok": w(next(keys), V, D)},
+        "embed": {"tok": w(next(keys), V, D, std=emb_std)},
         "layers": layers,
         "final_norm": {"scale": jnp.ones((D,), pdt)},
     }
@@ -2102,7 +2406,21 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         params["dense"], params["sparse"] = dense, sparse
     if cfg.layer_types:
         params["attn"] = attn
-    if cfg.has_ssm:
+    if cfg.recurrent_kind == "linear_attention":
+        Ls, Dc, Dv = cfg.num_ssm_layers, cfg.gdn_conv_dim, cfg.gdn_value_width
+        Hg = cfg.gdn_heads
+        params["gdn"] = {
+            # q | k | v | z, then a | b: the second stays out of the
+            # int8 quantiser (quant/int8.py), two numbers a head
+            "in_proj": w(next(keys), Ls, D, Dc + Dv),
+            "ab_proj": w(next(keys), Ls, D, 2 * Hg),
+            "conv_w": w(next(keys), Ls, cfg.gdn_conv, Dc, std=0.5),
+            "dt_bias": w(next(keys), Ls, Hg),
+            "A_log": w(next(keys), Ls, Hg),
+            "norm": {"scale": jnp.ones((Ls, cfg.gdn_value_dim), pdt)},
+            "out_proj": w(next(keys), Ls, Dv, D),
+        }
+    if cfg.recurrent_kind == "mamba":
         Lm, Di, Dc = cfg.num_ssm_layers, cfg.ssm_inner, cfg.ssm_conv_dim
         Nh = cfg.ssm_heads
         params["mamba"] = {

@@ -109,6 +109,10 @@ def quantize_int8(params: Params, cfg) -> Params:
       shared w_* [L,D,F] / [L,F,D] -> as the dense mlp's
       mamba in_proj [Lm,D,P] -> D;  out_proj [Lm,Di,D] -> Di (the
         conv, A_log, D, dt_bias and the norm stay float)
+      gdn in_proj [Ls,D,q|k|v|z] -> D;  out_proj [Ls,H dv,D] -> H dv: a
+        Gated DeltaNet mixer's five wide projections (ab_proj, two
+        numbers a head, the conv, A_log, dt_bias and the norm stay
+        float)
       latent attention: w_dq [L,D,Rq], w_uq [L,Rq,N,H], w_dkv [L,D,R+r],
         w_uk / w_uv [L,R,N,H] -> the dim behind L (w_uk is contracted
         over H in the absorbed read, which dequantizes it first:
@@ -120,7 +124,7 @@ def quantize_int8(params: Params, cfg) -> Params:
         (models/common.py stream_read) and a thousandth of a layer
     The leaf's PATH decides (_contraction_axes), wherever its stack
     lies: under params["layers"], or top-level for a model whose layers
-    run as runs (params["attn"], "mamba", "dense", "sparse").
+    run as runs (params["attn"], "mamba", "gdn", "dense", "sparse").
     Runs as one jit so a large tree quantizes device-side in one program.
     """
 
@@ -166,20 +170,26 @@ def _contraction_axes(path_names) -> Optional[Tuple[int, ...]]:
         return (2,)
     if parent in ("mlp", "shared") and name in ("w_gate", "w_up", "w_down"):
         return (1,)
-    if parent == "mamba" and name in ("in_proj", "out_proj"):
+    if parent in ("mamba", "gdn") and name in ("in_proj", "out_proj"):
         return (1,)
     if name == "lm_head":
         return (0,)
     return None
 
 
-def _leaf_kind(names) -> str:
+def _leaf_kind(names, stream):
     """How init_params seeds the leaf at this tree path: a norm's scale
     "ones", a bias "zeros", a weight "normal" (N(0, .02)); of the
     residual streams' mixing (hc1 / hc2), b "normal_1" (N(0, 1)) and
-    alpha "hundredths" (the constant .01: init_params says why)."""
+    alpha "hundredths" (the constant .01: init_params says why).
+    `stream`: models.common.stream_seed's pair, where a model seeds the
+    embedding at 1 ("normal_1") and its sublayers' norms at a constant
+    (a kind that is a number is that constant)."""
+    emb_std, ln = stream
+    if list(names) == ["embed", "tok"] and emb_std == 1.0:
+        return "normal_1"
     if names[-1] == "scale":
-        return "ones"
+        return ln if names[-2] in ("ln1", "ln2") and ln != 1.0 else "ones"
     if len(names) > 1 and names[-2].startswith("hc"):
         return {"b": "normal_1", "alpha": "hundredths"}.get(names[-1],
                                                             "normal")
@@ -194,8 +204,8 @@ def _leaf_values(k, *, shape, kind, axes, dt):
     """One leaf in its final form: a constant (_FILLS), or N(0, .02)
     (N(0, 1) for "normal_1") quantized over `axes` when given, else
     cast to the compute dtype."""
-    if kind in _FILLS:
-        return jnp.full(shape, _FILLS[kind], dt)
+    if not str(kind).startswith("normal"):
+        return jnp.full(shape, _FILLS.get(kind, kind), dt)
     std = 1.0 if kind == "normal_1" else 0.02
     w = jax.random.normal(k, shape, jnp.float32) * std
     return _quant(w, axes, dt) if axes is not None else w.astype(dt)
@@ -251,10 +261,11 @@ def init_params_by_leaf(cfg, key: jax.Array, quant: str = "none",
     and the tools (real deployments load checkpoints via ckpt/)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from butterfly_tpu.models.common import init_params
+    from butterfly_tpu.models.common import init_params, stream_seed
 
     if quant not in ("none", "int8"):
         raise ValueError(f"unknown weight quant {quant!r}")
+    stream = stream_seed(cfg)
     dt = jnp.dtype(cfg.dtype)
     shapes = jax.eval_shape(_partial(init_params, cfg),
                             jax.ShapeDtypeStruct(key.shape, key.dtype))
@@ -272,7 +283,7 @@ def init_params_by_leaf(cfg, key: jax.Array, quant: str = "none",
     for (path, sd), k, spec in zip(leaves, keys, specs):
         names = _path_names(path)
         axes = _contraction_axes(names) if quant == "int8" else None
-        kind = _leaf_kind(names)
+        kind = _leaf_kind(names, stream)
         sharding, factor = None, (1,) * len(sd.shape)
         if mesh is not None:
             dims = tuple(spec) + (None,) * (len(sd.shape) - len(spec))
@@ -292,7 +303,7 @@ def init_params_by_leaf(cfg, key: jax.Array, quant: str = "none",
                 _leaf_values, out_shardings=sharding,
                 static_argnames=("shape", "kind", "axes", "dt"))
         plan = _chunk_plan(sd.shape, axes, factor) \
-            if kind.startswith("normal") else None
+            if str(kind).startswith("normal") else None
         if plan is None:
             out.append(prog(k, shape=sd.shape, kind=kind, axes=axes, dt=dt))
             continue

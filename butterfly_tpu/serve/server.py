@@ -32,9 +32,12 @@ zero-egress environment):
   site that wanted a kernel and took the dense path}, "allocator"
   (native | python) and "pool_layout" (head | token: a page of the KV
   pool holds a row a KV head, or, for a model with a sparse-attention
-  indexer, a row a token) and "state" ({layers, bytes_per_slot, dtype,
-  bytes}: the recurrent state a slot keeps for a model with Mamba-2
-  layers, cache/ssm_state.py; null for every other model). 503 with a
+  indexer, a row a token) and "state" ({kind, layers, layout,
+  whole_tiles, bytes_per_slot, dtype, bytes}: the recurrent state a slot
+  keeps for a model with Mamba-2 or Gated DeltaNet layers, the kind, the
+  layout it is held in, whether that is whole tiles of a TPU's memory
+  (the declared bytes are then the held ones), cache/ssm_state.py; null
+  for every other model). 503 with a
   detail string when wedged.
 * GET /kv/pages?hashes=h1,h2,...   export registered prefix-cache KV
   pages by chain hash (fleet/kvtransfer.py payload: base64 page bytes +
@@ -1470,7 +1473,8 @@ def run_server(args) -> int:
           f"pool={rep['pool_layout']}"
           + ("" if rep["state"] is None else " state: " + json.dumps(
               {k: rep["state"][k]
-               for k in ("layers", "bytes_per_slot", "dtype")}))
+               for k in ("kind", "layers", "layout", "whole_tiles",
+                         "bytes_per_slot", "dtype")}))
           + ")", flush=True)
     # SIGTERM ends serving the way Ctrl-C does: serve_forever returns,
     # and the exit code says whether serving was wedged

@@ -12,33 +12,23 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from butterfly_tpu.cache.paged import (
-    flush_paged_window, init_kv_window, init_paged_cache,
-    paged_forward_packed)
+import packed_driver
+from packed_driver import C, err, forward, leaf_of
+from butterfly_tpu.cache.paged import init_kv_window, init_paged_cache
 from butterfly_tpu.cache.ssm_state import (
     bytes_per_slot, init_ssm_state, reset_slots, state_info)
 from butterfly_tpu.core.config import (
     PRESETS, ModelConfig, RuntimeConfig, granite_4_h_small, tiny)
 from servebench.references import granite_hybrid_f32 as ref
-from butterfly_tpu.models.common import (
-    Model, forward, init_cache, layer_runs)
+from butterfly_tpu.models.common import Model, init_cache, layer_runs
 from butterfly_tpu.quant.int8 import (
     init_params_by_leaf, is_quantized_leaf, quantize_int8)
-
-#: an eager lax.scan compiles its body at every call (its closures are
-#: new functions each time): the tests drive jitted programs, one compile
-#: a shape, as the engines do
-forward = jax.jit(forward, static_argnums=(1,))
-_packed_step = jax.jit(
-    lambda params, cfg, *a, state, use_kernel=False: paged_forward_packed(
-        params, cfg, *a, state=state, use_kernel=use_kernel),
-    static_argnums=(1,), static_argnames=("use_kernel",))
 
 CFG = tiny("granite_hybrid", dtype="float32", param_dtype="float32")
 #: no attention layer at all: the pool holds no layer, and the paths
 #: that flush and read pages still work
 ALL_MAMBA = CFG.replace(num_layers=2, layer_types=("mamba", "mamba"))
-T, C = 40, 6
+T = 40
 #: rms difference over the standard deviation of the reference's logits
 #: at the position. float32 on both sides on the CPU reads 1e-7 to 1e-6
 #: (sums in another order); a bfloat16 program reads 1e-2, a term left
@@ -61,20 +51,6 @@ def file_config(cfg: ModelConfig) -> dict:
         residual_multiplier=cfg.residual_multiplier,
         attention_multiplier=cfg.attention_multiplier,
         logits_scaling=cfg.logits_scaling)
-
-
-def leaf_of(params):
-    def leaf(path, layer=None):
-        node = params
-        for key in path.split("/"):
-            node = node[key]
-        if is_quantized_leaf(node):
-            q8, s = node["q8"], node["s"]
-            if layer is not None:
-                q8, s = q8[layer], s[layer]
-            return q8.astype(jnp.float32) * s.astype(jnp.float32)
-        return (node if layer is None else node[layer]).astype(jnp.float32)
-    return leaf
 
 
 def seeded_params(cfg=CFG):
@@ -124,12 +100,6 @@ def want(params, tokens):
     return np.stack([reference(params, t) for t in tokens])
 
 
-def err(got, want):
-    """rms difference over the std of the reference's row."""
-    d = np.asarray(got, np.float64) - want
-    return float(np.sqrt(np.mean(d * d)) / np.std(want))
-
-
 # -- the contiguous cache ---------------------------------------------------
 
 def test_the_reference_is_not_trivial(want, tokens):
@@ -173,108 +143,13 @@ def test_prefill_then_decode_through_the_cache_and_the_state(params, tokens,
 
 # -- the packed mixed step ----------------------------------------------------
 
-RT = RuntimeConfig(max_batch_size=3, max_seq_len=64, page_size=4)
-
-
-class Packed:
-    """What engine._packed_scan does around one packed step, by hand:
-    three slots with a page-table row each, the KV window and its flush
-    every third step, the recurrent state through every step."""
-
-    def __init__(self, params, cfg=CFG, windowed=True, width=C,
-                 use_kernel=False):
-        self.params, self.cfg, self.C = params, cfg, width
-        self.use_kernel = use_kernel
-        cache = init_paged_cache(cfg, RT)
-        S, mp = cache.page_table.shape
-        self.cache = cache._replace(page_table=jnp.arange(
-            S * mp, dtype=jnp.int32).reshape(S, mp))
-        self.window = init_kv_window(self.cache, 3 * width) \
-            if windowed else None
-        self.wlen = jnp.zeros((S,), jnp.int32) if windowed else None
-        self.state = init_ssm_state(cfg, S)
-        self.steps, self.loads = 0, []
-
-    def flush(self):
-        if self.window is not None:
-            self.cache, self.wlen, _ = flush_paged_window(
-                self.cache, self.window, self.wlen)
-
-    def restart(self, slot):
-        """A slot's next tenant: what Scheduler._seed_mixed_slot edits
-        (lengths and staged count at zero) and nothing of the state."""
-        self.flush()
-        self.cache = self.cache._replace(
-            lengths=self.cache.lengths.at[slot].set(0))
-
-    def step(self, decode: dict, chunk=None):
-        """decode {slot: token}; chunk (slot, tokens up to C) or None.
-        Returns {slot: logits [V]} of the rows the head read."""
-        S = self.cache.num_slots
-        if self.steps % 3 == 0:
-            self.flush()
-        self.steps += 1
-        toks, active = np.zeros((S,), np.int32), np.zeros((S,), bool)
-        for s, t in decode.items():
-            toks[s], active[s] = t, True
-        ctok, cslot, count = np.zeros((1, self.C), np.int32), 0, 0
-        if chunk is not None:
-            cslot, count = chunk[0], len(chunk[1])
-            ctok[0, :count] = chunk[1]
-        logits, kv, load, self.state = _packed_step(
-            self.params, self.cfg, jnp.asarray(toks), self.cache,
-            jnp.asarray(ctok), jnp.asarray([cslot]), jnp.asarray([count]),
-            jnp.asarray(active), self.window, self.wlen, state=self.state,
-            use_kernel=self.use_kernel)
-        adv = jnp.asarray(active, jnp.int32).at[cslot].add(count)
-        if self.window is not None:
-            self.window, self.wlen = kv, self.wlen + adv
-        else:
-            self.cache = kv._replace(lengths=self.cache.lengths + adv)
-        self.loads.append(np.asarray(load))
-        heads = dict(decode)
-        if count:
-            heads[cslot] = None
-        return {s: np.asarray(logits[s]) for s in heads}
+def Packed(params, cfg=CFG, windowed=True, **kw):
+    return packed_driver.Packed(params, cfg, windowed, **kw)
 
 
 def scripted_run(params, tokens, windowed=True, cfg=CFG, use_kernel=False):
-    """Slot 1 takes sequence 1's first 20 tokens in chunks of 6 (the
-    last holds 2 and 4 of filler) and decodes to position 30 while slot
-    0 takes sequence 0's first 15 (6, 6, 3) and decodes beside it; then
-    slot 1's stream ends and the slot is given to sequence 2 from
-    position 0 while slot 0 decodes on. Slot 2 never holds a stream.
-    Returns ([(sequence, position, logits)], the driver, {slot:
-    (sequence, tokens it has seen)})."""
-    drv, out = Packed(params, cfg, windowed, use_kernel=use_kernel), []
-    at = {0: 0, 1: 0}                       # positions fed, by slot
-    seq = {0: 0, 1: 1}
-
-    def feed(decode_slots, chunk_slot=None, n=0):
-        decode = {s: tokens[seq[s], at[s]] for s in decode_slots}
-        chunk = None if chunk_slot is None else (
-            chunk_slot, tokens[seq[chunk_slot],
-                               at[chunk_slot]:at[chunk_slot] + n])
-        got = drv.step(decode, chunk)
-        for s in decode_slots:
-            at[s] += 1
-        if chunk_slot is not None:
-            at[chunk_slot] += n
-        out.extend((seq[s], at[s] - 1, row) for s, row in got.items())
-
-    for n in (6, 6, 6, 2):
-        feed([], 1, n)
-    for n in (6, 6, 3):
-        feed([1], 0, n)
-    while at[1] < 30:
-        feed([0, 1])
-    drv.restart(1)
-    seq[1], at[1] = 2, 0
-    for n in (6, 6, 5):
-        feed([0], 1, n)
-    for _ in range(4):
-        feed([0, 1])
-    return out, drv, {s: (seq[s], at[s]) for s in at}
+    return packed_driver.scripted_run(params, tokens, cfg, windowed,
+                                      use_kernel)
 
 
 @pytest.fixture(scope="module")
@@ -364,6 +239,26 @@ def test_chunked_prefill_equals_one_shot(params, tokens):
     assert np.abs(ha - hb).max() < 1e-5 * np.abs(hb).max()
     np.testing.assert_allclose(np.asarray(a.state.conv[:, :, 0]),
                                np.asarray(b.state.conv[:, :, 0]), atol=1e-5)
+
+
+def test_an_idle_chunk_leaves_what_slot_0s_chunk_wrote(params, tokens):
+    """Two chunks a step (prefill_inline_budget over the chunk's width),
+    the second idle: its slot reads 0, and slot 0 is where the real
+    chunk writes. What it writes back is the state as the real chunk
+    LEFT it, not as the step found it: logits and states are the scan's."""
+    seq = tokens[0]
+    out, drv = packed_driver.idle_chunk_run(params, seq, CFG)
+    want = reference(params, seq[:19])
+    for slot, pos, row in out:
+        assert err(row, want[pos]) < TOL, (slot, pos)
+    for slot, n in ((0, 19), (1, 5)):
+        held = []
+        reference(params, seq[:n], states=held)
+        for m, (H, tail) in enumerate(held):
+            assert np.abs(np.asarray(drv.state.h[m, slot]) - H).max() \
+                < 1e-5 * np.abs(np.asarray(H)).max(), (slot, m)
+            assert np.abs(np.asarray(drv.state.conv[m, :, slot]) - tail) \
+                .max() < 1e-5 * np.abs(tail).max()
 
 
 def test_a_model_of_mamba_layers_only_has_a_pool_of_no_layer(tokens):
@@ -897,10 +792,23 @@ def test_weights_built_leaf_by_leaf_have_the_same_tree():
 
 # -- what cannot take the state refuses the model by name ---------------------
 
+@pytest.fixture(scope="module")
+def engine(params):
+    """The module's ONE serving engine of the family's toy: the
+    scheduler's scenario runs on it, and so do the refusals that need an
+    engine that was built (export, import, the lane-wide prefill)."""
+    from butterfly_tpu.engine.serving import ServingEngine
+    return ServingEngine(Model(CFG), params, RuntimeConfig(
+        max_batch_size=2, max_seq_len=64, page_size=4, num_pages=16,
+        decode_steps_per_tick=2, prefill_inline_budget=4))
+
+
 def _engine(**rt):
+    """An engine that refuses at its construction: no weights are built
+    for it."""
     from butterfly_tpu.engine.serving import ServingEngine
     mesh = rt.pop("mesh", None)
-    return ServingEngine(Model(CFG), seeded_params(), RuntimeConfig(
+    return ServingEngine(Model(CFG), None, RuntimeConfig(
         max_batch_size=2, max_seq_len=64, page_size=4, **rt), mesh=mesh)
 
 
@@ -925,29 +833,33 @@ def _fused_generate():
     decode_step_win(None, CFG, None, None, [], 0)
 
 
+#: name -> the call, given the module's engine
 REFUSALS = {
-    "prefix caching": lambda: _engine(prefix_caching=True),
-    "host KV tier": lambda: _engine(prefix_caching=True, host_kv_tier_mb=1),
-    "export": lambda: _engine().read_pages([0]),
-    "import": lambda: _engine().write_pages([0], None, None),
-    "pipeline serving": lambda: _engine(mesh=_mesh("stage")),
-    "pipeline": _stages,
-    "sequence-parallel prefill lane": lambda: _engine(mesh=_mesh("seq")),
-    "sequence parallelism": _seq_parallel,
-    "tensor parallelism": lambda: _engine(mesh=_mesh("tensor")),
-    "speculative": lambda: _engine(speculative_gamma=2),
-    "alternating prefill/decode path": lambda: _engine(mixed_dispatch=False),
-    "paged_forward": lambda: _engine().prefill_slot(0, [1, 2, 3]),
-    "static scheduler": lambda: _engine(scheduler="static"),
-    "int8 contiguous KV cache": lambda: init_cache(CFG, 1, 16, quant="int8"),
-    "write-combined fused generate": _fused_generate,
+    "prefix caching": lambda e: _engine(prefix_caching=True),
+    "host KV tier": lambda e: _engine(prefix_caching=True,
+                                      host_kv_tier_mb=1),
+    "export": lambda e: e.read_pages([0]),
+    "import": lambda e: e.write_pages([0], None, None),
+    "pipeline serving": lambda e: _engine(mesh=_mesh("stage")),
+    "pipeline": lambda e: _stages(),
+    "sequence-parallel prefill lane": lambda e: _engine(mesh=_mesh("seq")),
+    "sequence parallelism": lambda e: _seq_parallel(),
+    "tensor parallelism": lambda e: _engine(mesh=_mesh("tensor")),
+    "speculative": lambda e: _engine(speculative_gamma=2),
+    "alternating prefill/decode path":
+        lambda e: _engine(mixed_dispatch=False),
+    "paged_forward": lambda e: e.prefill_slot(0, [1, 2, 3]),
+    "static scheduler": lambda e: _engine(scheduler="static"),
+    "int8 contiguous KV cache":
+        lambda e: init_cache(CFG, 1, 16, quant="int8"),
+    "write-combined fused generate": lambda e: _fused_generate(),
 }
 
 
 @pytest.mark.parametrize("what", list(REFUSALS))
-def test_refused_by_name(what):
+def test_refused_by_name(what, engine):
     with pytest.raises(NotImplementedError, match=what) as e:
-        REFUSALS[what]()
+        REFUSALS[what](engine)
     assert "recurrent state" in str(e.value)
     assert "Mamba-2" in str(e.value)
 
@@ -984,8 +896,8 @@ def greedy_of_the_reference(params, prompt, output, cfg=CFG):
         assert tok == order[-1], i
 
 
-def test_served_tokens_slot_reuse_and_a_recomputed_preemption(params,
-                                                              monkeypatch):
+def test_served_tokens_slot_reuse_and_a_recomputed_preemption(
+        params, engine, monkeypatch):
     """Four requests over two slots through the continuous scheduler
     (mixed blocks, the lazy drain, the window and its flush), a pool of
     16 pages that the first two streams outgrow together: the younger
@@ -1004,8 +916,9 @@ def test_served_tokens_slot_reuse_and_a_recomputed_preemption(params,
     prompts = [rng.randint(1, CFG.vocab_size, n).tolist()
                for n in (5, 6, 13, 9)]
     new = (40, 40, 10, 6)
-    sched, reqs = served(params, prompts, new, together=True, num_pages=16,
-                         prefill_inline_budget=4)
+    sched = Scheduler(engine, seed=0)
+    reqs = [sched.submit(p, max_new_tokens=n) for p, n in zip(prompts, new)]
+    sched.run_until_done()
     for prompt, req, n in zip(prompts, reqs, new):
         assert len(req.output) == n
         greedy_of_the_reference(params, prompt, req.output)
@@ -1100,16 +1013,21 @@ def test_a_model_without_mamba_layers_has_no_state(arch):
     assert sched.registry.snapshot()["ssm_state_bytes"] == 0
 
 
-def test_the_runtime_report_says_what_state_a_slot_keeps(params):
-    from butterfly_tpu.engine.serving import ServingEngine
+def test_the_runtime_report_says_what_state_a_slot_keeps(engine):
     from butterfly_tpu.sched.scheduler import Scheduler
     from butterfly_tpu.serve.server import runtime_report
-    sched = Scheduler(ServingEngine(Model(CFG), params, RuntimeConfig(
-        max_batch_size=2, max_seq_len=64, page_size=4)))
+    sched = Scheduler(engine)
     state = runtime_report(sched)["state"]
     per = 3 * (8 * 16 * 16 + 3 * (8 * 16 + 2 * 16)) * 4
-    assert state == {"layers": 3, "bytes_per_slot": per, "dtype": "float32",
+    # the toy's rows of 16 and 160 values are no whole lanes of a TPU;
+    # granite-4.0-h-small's 128 and 8,448 are
+    assert state == {"kind": "Mamba-2", "layers": 3,
+                     "layout": "h [layers, slots, heads, head_dim, state] "
+                               "= [3, 2, 8, 16, 16]",
+                     "whole_tiles": False,
+                     "bytes_per_slot": per, "dtype": "float32",
                      "bytes": 2 * per}
+    assert state_info(granite_4_h_small(), 128)["whole_tiles"]
     assert runtime_report(sched)["pool_layout"] == "head"
 
 
@@ -1150,7 +1068,8 @@ def test_preset_is_the_published_model():
 def test_layer_types_are_checked():
     with pytest.raises(ValueError, match="layer_types names 2 layers of 4"):
         tiny("granite_hybrid", layer_types=("mamba", "attention"))
-    with pytest.raises(ValueError, match="'mamba' or 'attention'"):
+    with pytest.raises(ValueError,
+                       match="'mamba', 'linear_attention' or 'attention'"):
         tiny("granite_hybrid", layer_types=("mamba", "conv", "mamba", "mamba"))
     with pytest.raises(ValueError, match="needs ssm_heads"):
         tiny("granite_hybrid", ssm_state=0)

@@ -366,7 +366,7 @@ def run_serving_block(name, small, mesh, want):
 
 def state_copies(hlo: str, h) -> list:
     """The instructions of a compiled HLO text that COPY the recurrent
-    state h [Lm, S, Nh, Hd, N], whole or one layer of it: a `copy`, or a
+    state h [Ls, S, ...] (either kind), whole or one layer of it: a `copy`, or a
     fusion the compiler named for one (`copy_bitcast_fusion`)."""
     import re
     whole = ",".join(map(str, h.shape))
@@ -502,7 +502,9 @@ def cell_blocks(config: dict, hlo_dir=None) -> list:
     as a view of every slot (index_views: none since PR 53, the decode
     rows score through ops/index_scores.py) and the sorts and running
     counts over a row of the table's span (span_sorts: none since
-    PR 55, the selection counts in ops/select_mask.py); for a
+    PR 55, the selection counts in ops/select_mask.py); for a model
+    with a recurrent state the copies of it, whole or a layer
+    (state_copies); for a
     latent cache whose rows an indexer selects, the instructions the
     benchmark's pattern tells as the selecting read (selecting_calls).
     The compiler's figures were the chip's to the megabyte (PR 41).
@@ -553,9 +555,11 @@ def cell_blocks(config: dict, hlo_dir=None) -> list:
             sds((S,), bool), sds((S,), jnp.float32), sds((S,), i32),
             sds((S,), i32), 0, 1.0, sds((2,), jnp.uint32)]
     donated = (2, 3, 4, 5)
+    state = None
     if cfg.has_ssm:
         from butterfly_tpu.cache.ssm_state import init_ssm_state
-        args.append(on_chip(jax.eval_shape(lambda: init_ssm_state(cfg, S))))
+        state = on_chip(jax.eval_shape(lambda: init_ssm_state(cfg, S)))
+        args.append(state)
         donated += (15,)
     real_backend, out = jax.default_backend, []
     jax.default_backend = lambda: "tpu"     # kernels compile, not interpret
@@ -595,8 +599,10 @@ def cell_blocks(config: dict, hlo_dir=None) -> list:
                                           kinds=("copy", "transpose")),
                 index_views=index_views(hlo, cache, cfg.index_head_dim),
                 span_sorts=span_sorts(hlo, rt.max_seq_len)
-                if cfg.has_indexer else [])
+                if cfg.has_indexer else [],
+                state_copies=state_copies(hlo, state.h) if state else [])
             rec["ok"] = held < 15.75 * 2 ** 30 and not rec["window_moves"] \
+                and not rec["state_copies"] \
                 and not rec["stream_moves"] and not rec["index_views"] \
                 and not rec["span_sorts"] and bool(rec["mosaic_calls"])
             told = selecting_calls(config, hlo)

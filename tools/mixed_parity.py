@@ -22,13 +22,28 @@ the slot becomes. Once with P = 1 (the cells' setting) and once with P = 2.
 Beside the clean run, two planted faults on the packed side, from the same
 fork. `chunk_shift` feeds every chunk its prompt one token late: a reading
 means something only between the clean run's and this one's, LIMIT lies
-there (PERF.md, PR 29 gives the readings it was set from), and the run is
+there (PERF.md, PR 29 gives the readings it was set from; LIMITS has a
+recurrent kind's own), and the run is
 `ok` when the clean run stays under it and the fault passes it, for both P.
 `index_shift` starts a chunk slot's staged count one too high, so its
 entries land one window index and one position late behind an entry nobody
 wrote: rotary attention is relative, so this is the mildest positional
 fault there is, and `index_shift_seen` says whether this reading resolves
 it from the noise between two programs (in float32 on the CPU it does).
+
+A model with RECURRENT layers (Mamba-2, Gated DeltaNet) has no lane-wide
+step to stand beside: `paged_forward_window` refuses it, its state being
+advanced by the packed step alone. Its packed step is read against the
+configuration's PLAIN REFERENCE instead (`run_recurrent`): every slot is
+fed a fixed token stream of its own (teacher-forced, so both sides see the
+same tokens whatever a near-tie decides), the slots prefill through the
+packed step's own chunks, P slots are admitted mid-block with prompts of
+2 C + 6 and C + C/2 + 3 tokens (the first crosses TWO chunk edges and a
+flush, its last chunk mostly filler; refcheck.py's 16-token prompts stay
+inside one chunk) and then decode, and the logits of a few slots' rows (the
+chunk slots and two decoding ones) are held to the reference's rows at the
+same positions. `chunk_shift` is planted as above; `index_shift` has no
+meaning where nothing rotates and is left out.
 
 The tool reports chip evidence and refuses to run without a TPU; `--toy`
 (the CPU rehearsal of tests/test_mixed_dispatch.py) says so in its output.
@@ -45,6 +60,13 @@ sys.path.insert(0, str(ROOT))
 
 #: rms of the difference over the reference's spread: above it a row is wrong
 LIMIT = 0.2
+#: a recurrent kind read against its plain reference has its own where
+#: the chip has read it (PERF.md, PR 56, second session: Olmo-Hybrid-7B
+#: clean 0.0199-0.0202 at every row, P 1 and 2; a chunk fed a token late
+#: 0.32 at the median decode row and 1.38 at a chunk's: their geometric
+#: middle. The first tree's order, decode rows before the chunks, read
+#: 0.130-0.138 at the decode rows of P 2: over this, under LIMIT)
+LIMITS = {"linear_attention": 0.08}
 BLOCKS = 3
 FAULTS = ("clean", "chunk_shift", "index_shift")
 
@@ -193,6 +215,137 @@ def run(cfg, params, mesh, sv, P, seed=29):
     return res
 
 
+def run_recurrent(cfg, params, sv, config, P, seed=29):
+    """The packed step of a model with recurrent layers against its
+    plain reference, for P chunks; returns readings per fault."""
+    import jax
+    import jax.numpy as jnp
+    from butterfly_tpu.cache.paged import (
+        flush_paged_window, paged_forward_packed)
+    from butterfly_tpu.core.config import RuntimeConfig
+    from butterfly_tpu.engine.serving import ServingEngine
+    from butterfly_tpu.models.common import Model
+    from butterfly_tpu.quant.int8 import is_quantized_leaf
+    from servebench.refcheck import leaf_reader, load_reference
+
+    k, S = sv["decode_steps_per_tick"], sv["max_batch"]
+    steps = BLOCKS * k
+    probe = RuntimeConfig()
+    C = min(probe.prefill_inline_budget, probe.prefill_chunk)
+    hi = min(128, sv["max_seq"] // 3)
+    # a pool of what the longest stream here holds, a slot
+    per = -(-(max(hi, 2 * C + 6) + steps + C) // sv["page_size"])
+    rt = RuntimeConfig(max_batch_size=S, max_seq_len=sv["max_seq"],
+                       page_size=sv["page_size"], num_pages=S * per,
+                       kv_quant=sv.get("kv_quant", "none"),
+                       decode_steps_per_tick=k)
+    rng = np.random.default_rng(seed)
+    plens = np.exp(rng.uniform(np.log(hi / 4), np.log(hi), S)).astype(int)
+    chunk_slots = list(range(S - P, S))
+    plens[chunk_slots] = [2 * C + 6, C + C // 2 + 3][:P]
+    seqs = [rng.integers(3, cfg.vocab_size, int(n) + steps + 1)
+            for n in plens]
+    checked = sorted({0, 1, *chunk_slots})
+    reference = load_reference(config["reference"])
+    leaf = leaf_reader(params, is_quantized_leaf)
+    want = {s: np.asarray(reference.logits(
+        seqs[s][:plens[s] + steps], leaf, config), np.float32)
+        for s in checked}
+
+    def one(fault):
+        eng = ServingEngine(Model(cfg), params, rt)
+        for s in range(S):
+            eng.set_table_row(s, list(range(s * per, (s + 1) * per)))
+        eng._ensure_window(k * C)
+        eng._sync_table()
+        packed = jax.jit(partial(paged_forward_packed,
+                                 use_kernel=eng._use_kernels),
+                         static_argnums=(1,),
+                         donate_argnames=("window", "state"))
+        flush = jax.jit(flush_paged_window)
+        cache, win, wlen = eng.cache, eng._kv_window, eng._win_len
+        state, eng._ssm_state, eng._kv_window = eng._ssm_state, None, None
+        at = np.zeros((S,), np.int64)          # tokens each slot has seen
+        o = {"decode": [], "chunk": [], "rows": 0, "argmax_agree": 0}
+
+        def step(decoding, chunk):
+            """decoding [S] bool; chunk [(slot, n)] up to P of them."""
+            nonlocal win, wlen, state
+            toks = np.array([seqs[s][at[s]] for s in range(S)], np.int32)
+            slot = np.array(([s for s, _ in chunk] + [0] * P)[:P], np.int32)
+            cnt = np.array(([n for _, n in chunk] + [0] * P)[:P], np.int32)
+            shift = 1 if fault == "chunk_shift" else 0
+            ctoks = np.zeros((P, C), np.int32)
+            for i, (s, n) in enumerate(chunk):
+                ctoks[i, :n] = seqs[s][at[s] + shift:at[s] + shift + n]
+            got, win, _, state = packed(
+                params, cfg, jnp.asarray(toks), cache, jnp.asarray(ctoks),
+                jnp.asarray(slot), jnp.asarray(cnt), jnp.asarray(decoding),
+                window=win, win_len=wlen, state=state)
+            adv = decoding.astype(np.int64)
+            for s, n in chunk:
+                adv[s] += n
+            wlen = wlen + jnp.asarray(adv, jnp.int32)
+            at[:] = at + adv
+            return np.asarray(got, np.float32)
+
+        def drain():
+            nonlocal cache, wlen
+            cache, wlen, _ = flush(cache, win, wlen)
+
+        with eng._mesh_ctx():
+            # the decoding slots' prompts, P chunks a step, a flush
+            # every k steps as the scheduler drains
+            todo = [s for s in range(S) if s not in chunk_slots]
+            n_steps = 0
+            while todo:
+                now = todo[:P]
+                step(np.zeros((S,), bool),
+                     [(s, int(min(C, plens[s] - at[s]))) for s in now])
+                todo = [s for s in todo if at[s] < plens[s]]
+                n_steps += 1
+                if n_steps % k == 0:
+                    drain()
+            drain()
+            dec = np.ones((S,), bool)
+            dec[chunk_slots] = False
+            start = k // 2  # admitted mid-block: the prompt crosses a flush
+            for i in range(steps):
+                pf = [s for s in chunk_slots
+                      if i >= start and at[s] < plens[s]]
+                chunk = [(s, int(min(C, plens[s] - at[s]))) for s in pf]
+                decoding = dec | np.array(
+                    [i >= start and s in chunk_slots and s not in pf
+                     for s in range(S)])
+                got = step(decoding, chunk)
+                for s in checked:
+                    if not (decoding[s] or s in pf) or at[s] < plens[s]:
+                        continue    # a chunk that is not the prompt's last
+                    read = float(_reading(got[s], want[s][at[s] - 1]))
+                    o["chunk" if s in pf else "decode"].append(read)
+                    o["argmax_agree"] += int(
+                        got[s].argmax() == want[s][at[s] - 1].argmax())
+                    o["rows"] += 1
+                if (i + 1) % k == 0:
+                    drain()
+        return o
+
+    def summary(o):
+        res = {"argmax_agree": o["argmax_agree"], "rows": o["rows"]}
+        for key in ("decode", "chunk"):
+            res[key + "_max"] = float(np.max(o[key]))
+            res[key + "_median"] = float(np.median(o[key]))
+        res["max"] = max(res["decode_max"], res["chunk_max"])
+        return res
+
+    res = {f: summary(one(f)) for f in FAULTS[:2]}
+    res["chunk_width"], res["steps"] = C, steps
+    res["chunk_prompts"] = [int(plens[s]) for s in chunk_slots]
+    res["against"] = "the plain reference " + config["reference"]
+    res["checked_slots"] = checked
+    return res
+
+
 def check(config: dict, toy: bool = False) -> dict:
     import jax
     from butterfly_tpu.core.config import MeshConfig, ModelConfig
@@ -211,14 +364,17 @@ def check(config: dict, toy: bool = False) -> dict:
         if tp > 1 else None
     params = init_params_by_leaf(cfg, jax.random.PRNGKey(0),
                                  quant=sv.get("quant", "none"), mesh=mesh)
+    limit = LIMITS.get(cfg.recurrent_kind, LIMIT)
     out = {"device": kind, "evidence": "cpu toy" if toy else "chip",
-           "tensor_parallel": tp, "limit": LIMIT}
+           "tensor_parallel": tp, "limit": limit}
     for P in (1, 2):
-        out[f"P{P}"] = run(cfg, params, mesh, sv, P)
-    out["ok"] = all(out[p]["clean"]["max"] < LIMIT
+        out[f"P{P}"] = run_recurrent(cfg, params, sv, config, P) \
+            if cfg.has_ssm else run(cfg, params, mesh, sv, P)
+    out["ok"] = all(out[p]["clean"]["max"] < limit
                     < out[p]["chunk_shift"]["max"] for p in ("P1", "P2"))
-    out["index_shift_seen"] = all(out[p]["index_shift"]["max"] > LIMIT
-                                  for p in ("P1", "P2"))
+    if not cfg.has_ssm:
+        out["index_shift_seen"] = all(out[p]["index_shift"]["max"] > limit
+                                      for p in ("P1", "P2"))
     return out
 
 

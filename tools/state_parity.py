@@ -1,4 +1,5 @@
-"""What holds a model with Mamba-2 layers to its plain reference over a
+"""What holds a model with recurrent layers (Mamba-2 or Gated DeltaNet:
+the configuration's ONE recurrent kind) to its plain reference over a
 WHOLE stream, on the chip, at the published widths, through the server's
 own path:
 
@@ -14,12 +15,13 @@ window and its flush) over TWO slots: `long` (a prompt of LONG[0] tokens
 as chunks of C, the last partly filler, then LONG[1] decode steps: the
 rollout cell's stream), `first` beside it, and `second`, which waits and
 is admitted into the slot `first` leaves, with `long`'s blocks in flight.
-What is compared is the STATE each slot holds at the end (every Mamba
-layer's H [Nh, Hd, N] and the conv's last K-1 inputs) with the state the
+What is compared is the STATE each slot holds at the end (every recurrent
+layer's heads' state, a head at a time, H [Nh, Hd, N] or S [H, dv, dk],
+and the conv's last K-1 inputs) with the state the
 reference's position-by-position loop holds after the same tokens (the
 prompt and all served tokens but the last, which is never fed back): rms
 of the difference over the rms of the reference's, a layer; the WORST
-layer's H of a slot is held to LIMIT. States, not tokens: with seeded
+layer's H of a slot is held to LIMITS' of the kind. States, not tokens: with seeded
 random weights two programs part at the first near-tie, and a state is
 what a leak changes first. Where the program keeps a state in bfloat16
 the loop rounds H to bfloat16 after every position too (the reference's
@@ -30,18 +32,23 @@ the stream, and held to nothing.
 
 The seeded weights forget within ten positions (A = -1, dt = 0.69), which
 would hide every fault here, so the tool gives `A_log` and `dt_bias` the
-family's own initial ranges (A 1-16, dt 0.001-0.1, seeded): heads that
-remember from one position to a thousand, in the program and the
-reference alike.
+family's own initial ranges (A 1-16, dt 0.001-0.1, seeded; a Gated
+DeltaNet head's decay has the same form, exp(-A softplus(a + dt_bias))):
+heads that remember from one position to a thousand, in the program and
+the reference alike.
+
+A configuration whose `max_seq` does not hold LONG's stream (the
+hybrid of 400 positions) is given LONG's prompt and as many new tokens as
+fit, less a page: its long stream is then under 400 positions, over 256.
 
 Beside the clean run, two faults planted in the program (`long` cut to
 LONG_SHORT new tokens: it only has to outlast the other two), each of
-which must pass LIMIT in the reused slot:
+which must pass the limit in the reused slot:
 `no_reset` (a chunk at position 0 does not start from zero: the slot's
 last tenant leaks) and `filler_advances` (a chunk's filler columns go
 through the recurrence). A reading means something only between the clean
-run's and a fault's, and LIMIT lies there (PERF.md, PR 41, gives the
-readings it was set from).
+run's and a fault's, and the kind's limit lies there (PERF.md, PRs 41
+and 56, gives the readings each was set from).
 
 The tool reports chip evidence and refuses to run without a TPU; `--toy`
 (the CPU rehearsal of tests/test_granite_hybrid.py) says so in its output.
@@ -62,8 +69,13 @@ sys.path.insert(0, str(ROOT))
 #: the chip's readings (PERF.md, PR 41): the clean run 0.0218 (the long
 #: stream's slot after 2,247 positions; the reused slot 0.0154) and
 #: `no_reset` 0.0728 in the reused slot (1.8 times of room on either
-#: side; `filler_advances` reads 0.328)
-LIMIT = 0.04
+#: side; `filler_advances` reads 0.328). A Gated DeltaNet model's is its
+#: own (PERF.md, PR 56, second session): the clean run reads 0.0060 in
+#: the first layer and climbs with depth to 0.0360 in the 24th (long,
+#: 383 positions; the reused slot 0.0353: a state's input is the stream,
+#: 0.02 off the reference's by then, refcheck), `no_reset` 0.2357 and
+#: `filler_advances` 0.3669: their geometric middle, 2.5 times of room
+LIMITS = {"mamba": 0.04, "linear_attention": 0.09}
 SLOTS = 2
 #: (prompt, new tokens) of each request; a chunk is 32 wide
 LONG, FIRST, SECOND = (200, 2048), (70, 40), (100, 40)
@@ -95,18 +107,19 @@ def planted(fault: str):
         paged.advance_packed = real
 
 
-def remembering(params, seed: int):
-    """The tree with `A_log` and `dt_bias` drawn from Mamba-2's initial
-    ranges (A uniform 1-16; dt log-uniform 0.001-0.1, its bias the
-    inverse softplus)."""
+def remembering(params, seed: int, stack: str = "mamba"):
+    """The tree with `A_log` and `dt_bias` of the recurrent kind's
+    stack (params["mamba"] or params["gdn"]) drawn from Mamba-2's
+    initial ranges (A uniform 1-16; dt log-uniform 0.001-0.1, its bias
+    the inverse softplus)."""
     import jax.numpy as jnp
     rng = np.random.default_rng(seed)
-    mamba = dict(params["mamba"])
-    shape, dtype = mamba["A_log"].shape, mamba["A_log"].dtype
+    mixers = dict(params[stack])
+    shape, dtype = mixers["A_log"].shape, mixers["A_log"].dtype
     dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), shape))
-    mamba["A_log"] = jnp.asarray(np.log(rng.uniform(1, 16, shape)), dtype)
-    mamba["dt_bias"] = jnp.asarray(np.log(np.expm1(dt)), dtype)
-    return {**params, "mamba": mamba}
+    mixers["A_log"] = jnp.asarray(np.log(rng.uniform(1, 16, shape)), dtype)
+    mixers["dt_bias"] = jnp.asarray(np.log(np.expm1(dt)), dtype)
+    return {**params, stack: mixers}
 
 
 def served_states(cfg, params, rt, prompts, new, fault="clean"):
@@ -136,10 +149,13 @@ def _rel(got, want):
                  / np.sqrt(np.mean(want ** 2)))
 
 
-def slot_reading(state, slot, held):
+def slot_reading(state, slot, held, heads=lambda h: h):
     """{h, conv: [a layer's reading]} of one slot against the
-    reference's states (`held`: [(H, tail)] in layer order)."""
-    return {"h": [_rel(state.h[m, slot], H) for m, (H, _) in enumerate(held)],
+    reference's states (`held`: [(H, tail)] in layer order). heads: a
+    slot's state of one layer as the program holds it, to the
+    reference's a head at a time."""
+    return {"h": [_rel(heads(state.h[m, slot]), H)
+                  for m, (H, _) in enumerate(held)],
             "conv": [_rel(state.conv[m, :, slot], t)
                      for m, (_, t) in enumerate(held)]}
 
@@ -148,7 +164,9 @@ def check(config: dict, toy: bool = False, seed: int = 41,
           requests=(LONG, FIRST, SECOND), long_short=LONG_SHORT) -> dict:
     import jax
     import jax.numpy as jnp
+    from butterfly_tpu.cache.ssm_state import gdn_heads_of
     from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
+    from butterfly_tpu.models.common import RECURRENT_NAMES, RECURRENT_STACKS
     from butterfly_tpu.quant.int8 import init_params_by_leaf, is_quantized_leaf
     from servebench.launcher import model_fields
     from servebench.refcheck import leaf_reader, load_reference
@@ -165,21 +183,34 @@ def check(config: dict, toy: bool = False, seed: int = 41,
         decode_steps_per_tick=sv["decode_steps_per_tick"],
         prefill_inline_budget=sv.get("prefill_inline_budget", 32))
     C = min(rt.prefill_inline_budget, rt.prefill_chunk)
+    if requests[0] == LONG and sum(LONG) > rt.max_seq_len:
+        requests = ((LONG[0], rt.max_seq_len - LONG[0] - rt.page_size),) \
+            + tuple(requests[1:])
+        long_short = min(long_short, requests[0][1])
     if not cfg.has_ssm or any(p + n > rt.max_seq_len or p % C == 0
                               for p, n in requests):
         raise ValueError(
-            "a model with Mamba-2 layers, and requests that fit max_seq "
+            "a model with recurrent layers, and requests that fit max_seq "
             f"{rt.max_seq_len} with a last chunk of {C} partly filler")
-    params = remembering(init_params_by_leaf(
-        cfg, jax.random.PRNGKey(0), quant=sv.get("quant", "none")), seed)
+    params = remembering(
+        init_params_by_leaf(cfg, jax.random.PRNGKey(0),
+                            quant=sv.get("quant", "none")), seed,
+        RECURRENT_STACKS[cfg.recurrent_kind])
+    # a slot's state of one layer as the reference holds it: Mamba-2's as
+    # held; Gated DeltaNet's lanes-of-a-group layout a head at a time
+    heads = (lambda h: np.asarray(gdn_heads_of(
+        jnp.asarray(h, jnp.float32)[None], cfg)[0])) \
+        if cfg.recurrent_kind == "linear_attention" else (lambda h: h)
     rng = np.random.default_rng(seed)
     prompts = [rng.integers(1, cfg.vocab_size, p).astype(np.int32)
                for p, _ in requests]
     reference = load_reference(config["reference"])
     leaf = leaf_reader(params, is_quantized_leaf)
     store = jnp.dtype(cfg.dtype)    # what a slot keeps between steps
+    limit = LIMITS[cfg.recurrent_kind]
     out = {"device": kind, "evidence": "cpu toy" if toy else "chip",
-           "limit": LIMIT, "chunk_width": C, "slots": SLOTS,
+           "kind": RECURRENT_NAMES[cfg.recurrent_kind],
+           "limit": limit, "chunk_width": C, "slots": SLOTS,
            "requests": {name: {"prompt": p, "new": n} for name, (p, n)
                         in zip(("long", "first", "second"), requests)}}
     for fault in FAULTS:
@@ -200,12 +231,12 @@ def check(config: dict, toy: bool = False, seed: int = 41,
             # beside the float32 loop (`drift`: what keeping a state in
             # the storage dtype costs), and beside the loop that rounds
             # H to that dtype after every position, as the program's
-            # steps do (the path: what LIMIT holds)
+            # steps do (the path: what the limit holds)
             for keep in (None, store)[:2 if store != jnp.float32 else 1]:
                 held = []
                 reference.logits(seq, leaf, config, rows=[0], states=held,
                                  keep=keep)
-                read = slot_reading(state, slots[i], held)
+                read = slot_reading(state, slots[i], held, heads)
                 if keep is None and store != jnp.float32:
                     got[name].update(drift=read["h"],
                                      drift_worst=max(read["h"]))
@@ -214,8 +245,8 @@ def check(config: dict, toy: bool = False, seed: int = 41,
                                      conv_worst=max(read["conv"]), **read)
     clean = out["clean"]
     out["ok"] = bool(
-        all(clean[n]["h_worst"] < LIMIT for n in ("long", "second"))
-        and all(out[f]["second"]["h_worst"] > LIMIT for f in FAULTS[1:]))
+        all(clean[n]["h_worst"] < limit for n in ("long", "second"))
+        and all(out[f]["second"]["h_worst"] > limit for f in FAULTS[1:]))
     return out
 
 
