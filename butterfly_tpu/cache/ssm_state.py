@@ -51,12 +51,19 @@ slots where they lie, y read from the float32 value before it is
 rounded to the stored dtype); with kernels off (the CPU) or any other
 state it is models.common.ssm_scan at T == 1 and the update in place,
 which is also what the kernel is tested against. A CHUNK's is ssm_scan's
-scan over its positions, either way. Gated DeltaNet: a decode row's
-step is models.common.gdn_step (XLA's: one reduction over the layer's
-state and the update in place), a chunk's is gdn_chunk (the chunkwise
-form). Either kind: the chunks read and write their own slots FIRST and
-the decode rows' step follows on the result (a chunk's slot is no live
-decode row, so that step leaves it as it is).
+scan over its positions, either way. Gated DeltaNet, by the same rule:
+a decode row's one step is the Pallas kernel ops/gdn_step.py when the
+engine's kernels are on and the state's minor dims are whole tiles
+(ops/gdn_step.py fits: the whole h aliased to its result and the
+layer's index, ONE pass over layer m's slots where they lie, alpha S k,
+alpha S q, the update and the readout from one float32 value of the
+block); with kernels off (the CPU) or any other state it is
+models.common.gdn_step (XLA's: one reduction over the layer's state and
+the update in place, the state read twice), which is also what the
+kernel is tested against. A chunk's is gdn_chunk (the chunkwise form),
+either way. Either kind: the chunks read and write their own slots
+FIRST and the decode rows' step follows on the result (a chunk's slot
+is no live decode row, so that step leaves it as it is).
 """
 from __future__ import annotations
 
@@ -73,6 +80,7 @@ from butterfly_tpu.models.common import (
     gdn_in_proj, gdn_step, gdn_step_inputs, ssm_conv, ssm_gate_out,
     ssm_in_proj, ssm_scan, ssm_skip, ssm_step_inputs, stream_read,
     stream_write)
+from butterfly_tpu.ops import gdn_step as gdn_kernel
 from butterfly_tpu.ops.ssm_step import fits, ssm_step
 
 
@@ -237,7 +245,10 @@ class _Mamba:
 
 
 class _DeltaNet:
-    """advance_packed's Gated DeltaNet layer."""
+    """advance_packed's Gated DeltaNet layer. A decode row's step: with
+    use_kernel and a state the kernel can cut (ops/gdn_step.py fits)
+    one pass over the state where it lies; else models.common.gdn_step,
+    the kernel's reference."""
     conv = staticmethod(gdn_conv)
 
     @staticmethod
@@ -247,10 +258,12 @@ class _DeltaNet:
 
     @staticmethod
     def decode(h, m, u, aux, gp, cfg, count, use_kernel):
-        q, k, v, la, beta = gdn_step_inputs(u, aux[1], aux[2], gp, cfg,
-                                            count)
-        o, h = gdn_step(h, m, q[:, 0], k[:, 0], v[:, 0], la[:, 0],
-                        beta[:, 0], cfg)
+        q, k, v, la, beta = (a[:, 0] for a in gdn_step_inputs(
+            u, aux[1], aux[2], gp, cfg, count))
+        if use_kernel and gdn_kernel.fits(h, cfg.gdn_heads):
+            o, h = gdn_kernel.gdn_step(h, m, q, k, v, la, beta, count > 0)
+        else:
+            o, h = gdn_step(h, m, q, k, v, la, beta, cfg)
         return o.reshape(o.shape[0], 1, cfg.gdn_heads, cfg.gdn_value_dim), h
 
     @staticmethod
@@ -276,9 +289,9 @@ def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig,
     the recurrent layers (traced). The layer's KIND (cfg.recurrent_kind)
     picks the mixer's pieces (_Mamba, _DeltaNet); the order below is
     the same for both. use_kernel: the engine's kernel rule
-    (ops/__init__.py); with it, and a Mamba-2 state of whole tiles, the
-    decode rows' recurrence is the ssm_step kernel, one pass over the
-    state where it lies.
+    (ops/__init__.py); with it, and a state of whole tiles, the decode
+    rows' recurrence is its kind's kernel (ssm_step, gdn_step), one pass
+    over the state where it lies.
 
     The projections, the gate and the feed-forward run once over all N
     rows (the weights stream once); the conv and the recurrence run on

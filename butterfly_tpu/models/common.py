@@ -1356,7 +1356,11 @@ def gdn_step(h: jax.Array, m, q: jax.Array, k: jax.Array, v: jax.Array,
     m's state gives alpha S k and alpha S q at once, the readout is
     S q = alpha S q + u (k . q), and the update alpha S + u k^T is
     written in place at the layer's index: the state is read twice and
-    written once, and no value of its size is formed beside it.
+    written once, and no value of its size is formed beside it. A decode
+    row takes this step where the engine's kernels are off (the CPU) or
+    the state is not whole tiles; elsewhere it takes ops/gdn_step.py,
+    the same step as one pass, which is tested against this one
+    (cache/ssm_state.py _DeltaNet.decode chooses).
     Returns (o [S, H dv] float32 flat, h)."""
     S, H, dk = k.shape
     g, dv = cfg.gdn_head_group, cfg.gdn_value_dim

@@ -12,8 +12,11 @@ import numpy as np
 import pytest
 
 from butterfly_tpu.core.config import RuntimeConfig, tiny
-from butterfly_tpu.cache.ssm_state import decode_rows_step
+from butterfly_tpu.cache.ssm_state import (
+    _DeltaNet, decode_rows_step, state_shapes)
 from butterfly_tpu.models.common import Model, attend
+from butterfly_tpu.ops import gdn_step as gdn_kernel
+from butterfly_tpu.ops import record_kernels
 from butterfly_tpu.ops.flash_attention import flash_attention
 from butterfly_tpu.ops.paged_attention import paged_attention
 from butterfly_tpu.ops.ssm_step import fits, heads_per_block, ssm_step
@@ -738,3 +741,112 @@ def test_ssm_step_is_the_jnp_step(case):
     if case == "other-layers":
         assert np.array_equal(np.asarray(h_k[0]), np.asarray(h[0]))
         assert np.array_equal(np.asarray(h_k[2]), np.asarray(h[2]))
+
+
+# -- gdn_step: a decode row's delta rule, one pass over the state -------------
+
+def _gdn_case(dtype, dv, dk=32, seed=0):
+    """A toy state of whole tiles (keys of 32 down the sublanes; values
+    of 192, two heads a row of 384 lanes, or of 128, a head a row),
+    three Gated DeltaNet layers, four slots of which slot 2 does not
+    decode, and what gdn_conv and the in-projection would hand one
+    decode step (b loud enough that beta spans the whole of (0, 2))."""
+    cfg = tiny("olmo_hybrid", gdn_heads=4, gdn_key_dim=dk, gdn_value_dim=dv)
+    Ls, S, H = 3, 4, cfg.gdn_heads
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    h = jax.random.normal(
+        ks[0], (Ls,) + state_shapes(cfg, S)["h"][1:]).astype(dtype)
+    u = jax.random.normal(ks[1], (S, 1, cfg.gdn_conv_dim))
+    aux = (None, jax.random.normal(ks[2], (S, 1, H)),
+           3 * jax.random.normal(ks[3], (S, 1, H)))
+    gp = {"dt_bias": jax.random.normal(ks[4], (H,)),
+          "A_log": jax.random.uniform(ks[5], (H,), minval=-1.0, maxval=1.0)}
+    count = jnp.asarray([1, 1, 0, 1], jnp.int32)
+    return cfg, h, u, aux, gp, count
+
+
+@pytest.mark.parametrize("case", [
+    "bf16-g2", "f32-g2", "bf16-g1", "f32-g1", "dead-row", "other-layers",
+    "layer-in-a-scan", "beta-to-2", "a-state-that-does-not-fit"])
+def test_gdn_step_is_the_jnp_step(case):
+    """The kernel (interpreted) against models/common.py gdn_step
+    (cache/ssm_state.py _DeltaNet.decode, kernels on and off): the state
+    as stored in both dtypes, two heads' values a row of lanes and one,
+    a row that does not decode, the layers the call does not name, the
+    layer's index traced inside a scan as the engine's runs do, beta
+    over the whole of (0, 2), and a state that is not whole tiles, which
+    takes the `jnp` step."""
+    dtype = jnp.float32 if case.startswith("f32") else jnp.bfloat16
+    cfg, h, u, aux, gp, count = _gdn_case(dtype,
+                                          128 if case.endswith("g1") else 192)
+    H = cfg.gdn_heads
+    assert cfg.gdn_head_group == (1 if case.endswith("g1") else 2)
+    # one bfloat16 ulp where a float32 sum in another order rounds the
+    # other way; float32 to its own rounding
+    tol = dict(rtol=8e-3, atol=1e-6) if dtype == jnp.bfloat16 \
+        else dict(rtol=2e-6, atol=1e-6)
+
+    def decode(h, m, use_kernel):
+        return _DeltaNet.decode(h, m, u, aux, gp, cfg, count, use_kernel)
+
+    if case == "a-state-that-does-not-fit":
+        # keys of 8 are half a tile of bfloat16's 16 sublanes
+        cfg, small, u, aux, gp, count = _gdn_case(dtype, 192, dk=8)
+        assert gdn_kernel.fits(h, H) and not gdn_kernel.fits(small, H)
+        assert gdn_kernel.fits(small.astype(jnp.float32), H)
+        assert not gdn_kernel.fits(h[..., :64], H)
+        # blocks of groups: all of them or 8 at a time, three pieces of a
+        # block's heads in the MXU's 128 lanes, a block under its bytes
+        olmo = jnp.zeros((1, 1, 15, 96, 384), jnp.bfloat16)
+        assert gdn_kernel.groups_per_block(olmo, 30) == 15
+        assert not gdn_kernel.fits(olmo.astype(jnp.float32), 30)
+        assert gdn_kernel.groups_per_block(
+            jnp.zeros((1, 1, 32, 16, 256), jnp.bfloat16), 64) == 16
+        assert not gdn_kernel.fits(jnp.zeros((1, 1, 44, 16, 128)), 44)
+        with pytest.raises(ValueError, match="whole tiles"):
+            gdn_kernel.gdn_step(small, 0, *(jnp.zeros((1, H, 1)),) * 6)
+        with record_kernels({}) as calls:   # the jnp step, asked or not
+            o_k, h_k = decode(small, jnp.int32(1), True)
+        assert not calls
+        o_j, h_j = decode(small, jnp.int32(1), False)
+        assert np.array_equal(np.asarray(o_k), np.asarray(o_j))
+        assert np.array_equal(np.asarray(h_k, np.float32),
+                              np.asarray(h_j, np.float32))
+        return
+    with record_kernels({}) as calls:
+        if case == "layer-in-a-scan":
+            def run(use_kernel):
+                def body(h, m):
+                    o, h = decode(h, m, use_kernel)
+                    return h, o
+                return jax.jit(
+                    lambda h: jax.lax.scan(body, h, jnp.arange(3)))(h)
+            (h_k, o_k), (h_j, o_j) = run(True), run(False)
+            assert not np.array_equal(np.asarray(h_k[0], np.float32),
+                                      np.asarray(h[0], np.float32))
+        else:
+            m = jnp.int32(1)
+            o_k, h_k = decode(h, m, True)
+            o_j, h_j = decode(h, m, False)
+    assert calls == {"gdn_step:interpret": 1}
+    assert h_k.dtype == h.dtype and o_k.dtype == jnp.float32
+    # a row that does not decode is copied through and reads out zero
+    # (the `jnp` step reads its state out; nothing takes that row's o)
+    live = np.asarray(count) > 0
+    np.testing.assert_allclose(np.asarray(o_k)[..., live, :, :, :],
+                               np.asarray(o_j)[..., live, :, :, :],
+                               rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(h_k, np.float32),
+                               np.asarray(h_j, np.float32), **tol)
+    if case == "dead-row":      # bit for bit, where the live rows moved
+        assert np.array_equal(np.asarray(h_k[1, 2]), np.asarray(h[1, 2]))
+        assert not np.array_equal(np.asarray(h_k[1, 1]), np.asarray(h[1, 1]))
+        assert not np.asarray(o_k)[2].any() and np.asarray(o_j)[2].any()
+    if case == "other-layers":
+        assert np.array_equal(np.asarray(h_k[0]), np.asarray(h[0]))
+        assert np.array_equal(np.asarray(h_k[2]), np.asarray(h[2]))
+    if case == "beta-to-2":     # a reflection is among what was compared
+        from butterfly_tpu.models.common import gdn_step_inputs
+        beta = np.asarray(gdn_step_inputs(u, aux[1], aux[2], gp, cfg,
+                                          count)[4])[count > 0]
+        assert beta.min() < 0.2 and beta.max() > 1.8

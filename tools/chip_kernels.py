@@ -21,7 +21,11 @@ tensor=4 mesh: 8 query and 2 KV heads per shard; the ring under
 at granite-4.0-h-small's state geometry (9 layers, 128 slots, 128 heads
 of 64, state 128, bfloat16: 2.4 GB) against `ssm_scan` at T == 1 and
 the update in place, the state donated as the engine donates it, and
-its compiled HLO must hold no copy of the state. `latent`, the absorbed
+its compiled HLO must hold no copy of the state. `gdn_step`, the Gated
+DeltaNet decode step, runs at `olmohybrid7b.batch`'s (24 layers, 64
+slots, 15 groups of [96, 384], bfloat16: 1.8 GB) against
+models/common.py gdn_step the same way, and a layer-step of each is
+timed beside the bytes of one pass over a layer's state. `latent`, the absorbed
 read of a latent-attention model's cached rows (ops/latent_attention.py),
 runs at JoyAI-LLM-Flash's geometry (96 slots, 32 heads, rows of 576
 values in 640 lanes, a pool of 6 GB in six layers, a window of 256)
@@ -675,6 +679,106 @@ def run_ssm_step(name, small, want):
         rec["ok"] = bool(np.isfinite(max(errs)) and max(errs) < 3e-2
                          and rec["dead_rows_kept"] and len(dead)
                          and not rec["state_copies"]
+                         and all(rec["hlo_has"].values()))
+    except Exception as e:  # a compiler refusal is the finding: record it
+        rec["error"] = f"{type(e).__name__}: {e}"[:1500]
+    return rec
+
+
+def run_gdn_step(name, small, want):
+    """ops/gdn_step.py at `olmohybrid7b.batch`'s state geometry (24
+    layers, 64 slots, 15 groups of [96, 384], bfloat16: 1.8 GB), the
+    layer index traced, against the `jnp` step it replaces
+    (models/common.py gdn_step: one reduction over the layer's state and
+    the update in place), both through cache/ssm_state.py
+    _DeltaNet.decode; a fifth of the slots do not decode. Then a
+    layer-step's time, kernel and `jnp`, over a scan of every layer
+    with the state carried and donated as the engine's block does,
+    beside the bytes of one pass (a layer's state read and written)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from butterfly_tpu.cache.ssm_state import _DeltaNet, state_shapes
+    from butterfly_tpu.core.config import olmo_hybrid_7b, tiny
+
+    cfg = tiny("olmo_hybrid", gdn_key_dim=32, gdn_value_dim=192,
+               dtype="bfloat16") if small \
+        else olmo_hybrid_7b().replace(dtype="bfloat16")
+    Ls, S = (3, 4) if small else (cfg.num_ssm_layers, 64)
+    H = cfg.gdn_heads
+    shape = (Ls,) + state_shapes(cfg, S)["h"][1:]
+    ks = jax.random.split(jax.random.PRNGKey(0), 7)
+    h = jax.random.normal(ks[0], shape, jnp.bfloat16)
+    u = jax.random.normal(ks[1], (S, 1, cfg.gdn_conv_dim))
+    aux = (None, jax.random.normal(ks[2], (S, 1, H)),
+           3 * jax.random.normal(ks[3], (S, 1, H)))
+    gp = {"dt_bias": jax.random.normal(ks[4], (H,)),
+          "A_log": jax.random.uniform(ks[5], (H,), minval=-1., maxval=1.)}
+    count = (jax.random.uniform(ks[6], (S,)) > 0.2).astype(jnp.int32)
+    m = jnp.int32(Ls // 2)
+
+    def step(use_kernel):
+        return lambda h, m, u, aux, gp, count: _DeltaNet.decode(
+            h, m, u, aux, gp, cfg, count, use_kernel)
+
+    def every_layer(use_kernel):
+        def run(h, u, aux, gp, count):
+            def body(h, m):
+                o, h = step(use_kernel)(h, m, u, aux, gp, count)
+                return h, o.sum()
+            return jax.lax.scan(body, h, jnp.arange(Ls))
+        return jax.jit(run, donate_argnums=0)
+
+    rec = {"name": name, "ok": False}
+    t0 = time.perf_counter()
+    try:
+        args = (m, u, aux, gp, count)
+        want_o, want_h = jax.jit(step(False))(h, *args)
+        dead = np.flatnonzero(np.asarray(count) == 0)
+        live = int(np.flatnonzero(np.asarray(count) > 0)[0])
+        kept, was = h[m][dead], h[m][live]
+        compiled = jax.jit(step(True), donate_argnums=0).lower(
+            h, *args).compile()
+        hlo = compiled.as_text()
+        o, h = jax.block_until_ready(compiled(h, *args))    # h is consumed
+        rec["compile_run_s"] = round(time.perf_counter() - t0, 2)
+        rec["hlo_has"] = {w: w in hlo for w in want}
+        rec["state_copies"] = state_copies(hlo, h)
+
+        @jax.jit        # one fused reduction: no float32 copy of 1.8 GB
+        def err(a, b):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            return jnp.max(jnp.abs(a - b) / (1 + jnp.abs(b)))
+
+        # a row that does not decode reads out zero from the kernel and
+        # its state from the `jnp` step: nothing takes it
+        decodes = (count > 0).reshape(S, 1, 1, 1)
+        errs = [float(err(jnp.where(decodes, o, 0),
+                          jnp.where(decodes, want_o, 0))),
+                float(err(h, want_h))]
+        del want_h
+        rec["max_err"] = round(max(errs), 5)
+        rec["dead_rows_kept"] = bool(jnp.array_equal(h[m][dead], kept))
+        rec["live_rows_moved"] = not bool(jnp.array_equal(h[m][live], was))
+        layer_mb = 2 * h[0].size * h.dtype.itemsize / 1e6
+        for label, use_kernel in (("kernel", True), ("jnp", False)):
+            fn = every_layer(use_kernel)
+            h, _ = fn(h, *args[1:])
+            jax.block_until_ready(h)
+            t1 = time.perf_counter()
+            for _ in range(10):
+                h, sums = fn(h, *args[1:])
+            jax.block_until_ready(h)
+            us = (time.perf_counter() - t1) / 10 / Ls * 1e6
+            rec[label + "_us_a_layer_step"] = round(us, 1)
+            rec[label + "_gb_s"] = round(layer_mb / us * 1e3, 1)
+        rec["layer_pass_mb"] = round(layer_mb, 1)
+        rec["finite"] = bool(np.isfinite(np.asarray(sums)).all())
+        rec["ok"] = bool(np.isfinite(max(errs)) and max(errs) < 3e-2
+                         and rec["dead_rows_kept"] and len(dead)
+                         and rec["live_rows_moved"]
+                         and not rec["state_copies"] and rec["finite"]
                          and all(rec["hlo_has"].values()))
     except Exception as e:  # a compiler refusal is the finding: record it
         rec["error"] = f"{type(e).__name__}: {e}"[:1500]
@@ -1530,6 +1634,8 @@ def main() -> int:
                for n, k, r, a, _ in cases if wanted(n)]
     if wanted("ssm_step"):
         results.append(run_ssm_step("ssm_step", args.small, want))
+    if wanted("gdn_step"):
+        results.append(run_gdn_step("gdn_step", args.small, want))
     results += [run_latent(n, args.small, want)
                 for n in LATENT_CELLS if wanted(n)]
     results += [run_paged_cell(n, args.small, want)
@@ -1574,6 +1680,11 @@ def main() -> int:
                  f"{r['trace_lower_s']}s compile={r['compile_s']}s"
                  if "call_us" in r else "")
               + (f" calls={r['kernel_calls']}" if "kernel_calls" in r else "")
+              + (f" a layer-step {r['kernel_us_a_layer_step']}us "
+                 f"({r['kernel_gb_s']} GB/s of {r['layer_pass_mb']}MB), the "
+                 f"jnp step {r['jnp_us_a_layer_step']}us "
+                 f"({r['jnp_gb_s']} GB/s)"
+                 if "jnp_us_a_layer_step" in r else "")
               + (f" counted={r['counted_us']}us a call, lax.top_k and the "
                  f"running count {r['plain_us']}us"
                  if "counted_us" in r else "")
