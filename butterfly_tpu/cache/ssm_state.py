@@ -1,6 +1,7 @@
 """The recurrent state of a model with recurrent layers (Mamba-2 mixers,
-or Gated DeltaNet mixers: cfg.recurrent_kind says which ONE kind a model
-has): a second KIND of per-stream memory beside the paged pool.
+Gated DeltaNet mixers or Mamba-1 mixers: cfg.recurrent_kind says which
+ONE kind a model has): a second KIND of per-stream memory beside the
+paged pool.
 
 A page pool grows with a stream: a token adds a row to every attention
 layer. A recurrent layer keeps a FIXED-SIZE state a stream instead, the
@@ -29,14 +30,19 @@ The interface is narrow on purpose (the scheduler learns nothing of it):
   kind, the layout held and its bytes a slot.
 
 Layout, by kind (`state_shapes`). The conv's tail is [Ls, K-1, S, Dc]
-for both (slots on the sublanes: a [.., K-1, Dc] minor pair would pad
+for all three (slots on the sublanes: a [.., K-1, Dc] minor pair would pad
 K-1 = 3 to a tile of 16 rows in bfloat16, five times the bytes).
 Mamba-2: h [Lm, S, Nh, Hd, N], a head's state with N on the lanes.
 Gated DeltaNet: a head's state is [dv, dk] and neither need be a whole
 number of 128 lanes (Olmo-Hybrid: 192 x 96), so g heads' VALUES share a
 row of lanes, g the fewest that fill whole lanes (cfg.gdn_head_group: 2
 x 192 = 384 = 3 x 128): h [Ls, S, H/g, dk, g dv], keys down the
-sublanes (96 = 6 tiles of 16 in bfloat16). The DECLARED shape is then
+sublanes (96 = 6 tiles of 16 in bfloat16). Mamba-1: a channel's state
+is N numbers and N = 16 is an eighth of a row of lanes (held [.., Di, N]
+a slot of Jamba2-3B's 5 MB would be 40), so the CHANNELS lie on the
+lanes and the state index down the sublanes: h [Lm, S, N, Di] (16 rows
+are one tile of bfloat16, 5,120 channels 40 rows of lanes); its conv's
+tail is over the Di channels of u alone. The DECLARED shape is then
 what the memory holds, to the byte (`state_info`'s `whole_tiles` says
 whether a geometry's is: a layout that the device pads says so). Every
 write is a dynamic-update-slice of the carried buffer at the layer's
@@ -77,9 +83,10 @@ from jax import lax
 from butterfly_tpu.core.config import ModelConfig
 from butterfly_tpu.models.common import (
     RECURRENT_NAMES, ffn_close, gdn_chunk, gdn_conv, gdn_gate_out,
-    gdn_in_proj, gdn_step, gdn_step_inputs, ssm_conv, ssm_gate_out,
-    ssm_in_proj, ssm_scan, ssm_skip, ssm_step_inputs, stream_read,
-    stream_write)
+    gdn_in_proj, gdn_step, gdn_step_inputs, mamba1_conv, mamba1_gate_out,
+    mamba1_in_proj, mamba1_scan, mamba1_step, mamba1_step_inputs, ssm_conv,
+    ssm_gate_out, ssm_in_proj, ssm_scan, ssm_skip, ssm_step_inputs,
+    stream_read, stream_write)
 from butterfly_tpu.ops import gdn_step as gdn_kernel
 from butterfly_tpu.ops.ssm_step import fits, ssm_step
 
@@ -113,9 +120,18 @@ def state_shapes(cfg: ModelConfig, slots: int) -> Dict[str, tuple]:
         return {"h": (Ls, slots, cfg.gdn_heads // g, cfg.gdn_key_dim,
                       g * cfg.gdn_value_dim),
                 "conv": (Ls, cfg.gdn_conv - 1, slots, cfg.gdn_conv_dim)}
+    if cfg.recurrent_kind == "mamba1":
+        return {"h": (Ls, slots, cfg.mamba1_state, cfg.mamba1_inner),
+                "conv": (Ls, cfg.mamba1_conv - 1, slots, cfg.mamba1_inner)}
     return {"h": (Ls, slots, cfg.ssm_heads, cfg.ssm_head_dim,
                   cfg.ssm_state),
             "conv": (Ls, cfg.ssm_conv - 1, slots, cfg.ssm_conv_dim)}
+
+
+#: the dims of h as held (state_shapes), by kind: state_info's `layout`
+_LAYOUTS = {"mamba": "layers, slots, heads, head_dim, state",
+            "linear_attention": "layers, slots, heads/g, key_dim, g*value_dim",
+            "mamba1": "layers, slots, state, channels"}
 
 
 def _held(shape, itemsize: int) -> int:
@@ -129,7 +145,7 @@ def _held(shape, itemsize: int) -> int:
 def bytes_per_slot(cfg: ModelConfig) -> int:
     """What one stream's state weighs, all recurrent layers: the
     declared values' bytes, which are what a TPU holds where the layout
-    has whole tiles (state_info's `whole_tiles`: both accepted
+    has whole tiles (state_info's `whole_tiles`: the three accepted
     geometries)."""
     shapes = state_shapes(cfg, 1)
     return (math.prod(shapes["h"]) + math.prod(shapes["conv"])) \
@@ -146,9 +162,7 @@ def state_info(cfg: ModelConfig, slots: int) -> Optional[Dict]:
     per = bytes_per_slot(cfg)
     size = jnp.dtype(cfg.dtype).itemsize
     shapes = state_shapes(cfg, slots)
-    dims = "layers, slots, heads/g, key_dim, g*value_dim" \
-        if cfg.recurrent_kind == "linear_attention" \
-        else "layers, slots, heads, head_dim, state"
+    dims = _LAYOUTS[cfg.recurrent_kind]
     return {"kind": RECURRENT_NAMES[cfg.recurrent_kind],
             "layers": cfg.num_ssm_layers,
             "layout": f"h [{dims}] = {list(shapes['h'])}",
@@ -276,7 +290,35 @@ class _DeltaNet:
         return gdn_gate_out(o, aux[0], gp, cfg)
 
 
-_MIXERS = {"mamba": _Mamba, "linear_attention": _DeltaNet}
+class _Mamba1:
+    """advance_packed's Mamba-1 layer: dt, B and C come from the conv's
+    OUTPUT, so they are formed where the recurrence is (decode, chunk)
+    and `aux` carries the gate alone. No kernel of this kind: a decode
+    row's step is XLA's over the state where it lies."""
+    conv = staticmethod(mamba1_conv)
+
+    @staticmethod
+    def project(h, mp, cfg):
+        u, z = mamba1_in_proj(h, mp, cfg)
+        return u, (z,)
+
+    @staticmethod
+    def decode(h, m, u, aux, mp, cfg, count, use_kernel):
+        return mamba1_step(h, m, u, *mamba1_step_inputs(u, mp, cfg), mp,
+                           count)
+
+    @staticmethod
+    def chunk(st0, u, aux, mp, cfg, count):
+        return mamba1_scan(u, *mamba1_step_inputs(u, mp, cfg), mp,
+                           st0.astype(jnp.float32), count)
+
+    @staticmethod
+    def close(y, aux, mp, cfg):
+        return mamba1_gate_out(y, aux[0], mp)
+
+
+_MIXERS = {"mamba": _Mamba, "linear_attention": _DeltaNet,
+           "mamba1": _Mamba1}
 
 
 def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig,
@@ -285,10 +327,10 @@ def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig,
     packed mixed step over x [N, 1, D], N = S + P*C rows: the S decode
     rows first, then P chunks of C columns (`rows`: StateRows'
     fields). lp, mp: the layer's slices of params["layers"] and of its
-    kind's stack (params["mamba"] or params["gdn"]); m: its index among
+    kind's stack (params["mamba"], "gdn" or "mamba1"); m: its index among
     the recurrent layers (traced). The layer's KIND (cfg.recurrent_kind)
-    picks the mixer's pieces (_Mamba, _DeltaNet); the order below is
-    the same for both. use_kernel: the engine's kernel rule
+    picks the mixer's pieces (_Mamba, _DeltaNet, _Mamba1); the order
+    below is the same for all. use_kernel: the engine's kernel rule
     (ops/__init__.py); with it, and a state of whole tiles, the decode
     rows' recurrence is its kind's kernel (ssm_step, gdn_step), one pass
     over the state where it lies.
@@ -324,8 +366,9 @@ def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig,
         one = (1, 1) + h.shape[2:]
         at = [(m, rows.chunk_slot[p]) + (0,) * (h.ndim - 2)
               for p in range(P)]
-        st0 = jnp.where(fresh[..., None], 0, jnp.concatenate(
-            [lax.dynamic_slice(h, at[p], one)[0] for p in range(P)]))
+        came = jnp.concatenate(
+            [lax.dynamic_slice(h, at[p], one)[0] for p in range(P)])
+        st0 = jnp.where(fresh.reshape((P,) + (1,) * (came.ndim - 1)), 0, came)
         tail_c0 = jnp.where(fresh, 0, tails[rows.chunk_slot])
         u_c, tail_c = mixer.conv(xbc[S:].reshape(P, C, -1), tail_c0, mp,
                                  chunk_count)

@@ -14,16 +14,21 @@ from typing import Optional, Tuple
 import numpy as np
 
 
+#: the recurrent layer kinds `layer_types` may name: Mamba-2, Gated
+#: DeltaNet, Mamba-1 (a model has one of them)
+RECURRENT_KINDS = ("mamba", "linear_attention", "mamba1")
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters for a transformer LM.
 
     One config class covers the model families (GPT-2, Llama-3,
     Mixtral, SmallThinker, Keye, Granite-4.0-H, JoyAI-LLM-Flash,
-    Xing4.0, GLM-5, Olmo-Hybrid) — the family is selected by `arch`, the MoE
-    fields, the per-layer attention pattern, the sparse-attention indexer,
-    the per-layer KIND (`layer_types`: Mamba-2 mixers or Gated DeltaNet
-    mixers beside attention layers), the latent-attention fields
+    Xing4.0, GLM-5, Olmo-Hybrid, Jamba) — the family is selected by `arch`,
+    the MoE fields, the per-layer attention pattern, the sparse-attention
+    indexer, the per-layer KIND (`layer_types`: Mamba-2, Gated DeltaNet or
+    Mamba-1 mixers beside attention layers), the latent-attention fields
     (`kv_lora_rank` and the split
     head dims: one cached latent a token in place of heads of keys and
     values) and the residual path (`hc_mult`: n streams mixed by
@@ -32,7 +37,7 @@ class ModelConfig:
 
     arch: str = "llama"  # "gpt2" | "llama" | "mixtral" | "smallthinker"
                          # | "keye" | "granite_hybrid" | "joyai" | "xing"
-                         # | "glm5" | "olmo_hybrid"
+                         # | "glm5" | "olmo_hybrid" | "jamba"
     vocab_size: int = 32000
     hidden_size: int = 4096
     num_layers: int = 32
@@ -76,7 +81,9 @@ class ModelConfig:
     hc_clamp_max: float = 30.0
 
     # MoE (mixtral)
-    num_experts: int = 0              # 0 => dense FFN
+    num_experts: int = 0              # 0 => dense FFN; 1 => the ONE
+                                      # expert IS the feed-forward: a
+                                      # dense FFN, no router (`routed`)
     num_experts_per_tok: int = 2
     moe_impl: str = "dense"           # "dense" | "ep" (GShard dispatch)
     moe_capacity_factor: float = 2.0  # per-expert slots multiplier (ep)
@@ -143,13 +150,14 @@ class ModelConfig:
                                       # of a head, not (i, i + half)
 
     # per-layer KIND: "mamba" (a Mamba-2 mixer), "linear_attention" (a
-    # Gated DeltaNet mixer), both with a fixed-size recurrent state a
-    # stream (cache/ssm_state.py), or "attention" (keys and values in
-    # the paged pool), one entry a layer (a longer list is read up to
-    # num_layers); () = every layer is attention. A model has ONE kind
-    # of recurrent layer (recurrent_kind). Kinds have unlike parameter
-    # SHAPES, so each kind's mixer weights are stacked apart
-    # (params["mamba"] or params["gdn"], params["attn"]) and the layers
+    # Gated DeltaNet mixer), "mamba1" (a Mamba-1 mixer), each with a
+    # fixed-size recurrent state a stream (cache/ssm_state.py), or
+    # "attention" (keys and values in the paged pool), one entry a layer
+    # (a longer list is read up to num_layers); () = every layer is
+    # attention. A model has ONE kind of recurrent layer
+    # (recurrent_kind). Kinds have unlike parameter SHAPES, so each
+    # kind's mixer weights are stacked apart (params["mamba"],
+    # params["gdn"] or params["mamba1"], params["attn"]) and the layers
     # run as scans over runs of one kind (models/common.py layer_runs);
     # the feed-forward of all layers is one stack (params["layers"]).
     layer_types: Tuple[str, ...] = ()
@@ -171,7 +179,21 @@ class ModelConfig:
     gdn_value_dim: int = 0
     gdn_conv: int = 4
     gdn_neg_eigval: bool = False
-    # what a slot keeps between steps (state, conv tail), either kind, is
+    # a Mamba-1 mixer (arXiv:2312.00752, as the `jamba` family has it):
+    # mamba1_inner channels, each with a state of mamba1_state numbers
+    # decayed by exp(dt[c] A[c, n]): a step size a CHANNEL (dt reaches
+    # the channels through a bottleneck of mamba1_dt_rank) and a rate a
+    # channel and state index, where a Mamba-2 head has one scalar; dt,
+    # B and C are projected from the conv's OUTPUT (the conv of
+    # mamba1_conv taps, with a bias, runs over the inner stream alone);
+    # mamba1_norms: an RMSNorm with a learned weight on each of dt's
+    # bottleneck, B and C (the family's; the published layer has none)
+    mamba1_inner: int = 0
+    mamba1_state: int = 0
+    mamba1_dt_rank: int = 0
+    mamba1_conv: int = 4
+    mamba1_norms: bool = False
+    # what a slot keeps between steps (state, conv tail), any kind, is
     # in `dtype`; a step's arithmetic is float32
 
     # Granite's four multipliers; 0 = the family has none (the term is
@@ -247,15 +269,16 @@ class ModelConfig:
         kinds = tuple(str(k) for k in self.layer_types)[:self.num_layers]
         if kinds:
             if len(kinds) < self.num_layers \
-                    or set(kinds) - {"mamba", "linear_attention", "attention"}:
+                    or set(kinds) - {*RECURRENT_KINDS, "attention"}:
                 raise ValueError(
                     f"layer_types names {len(kinds)} layers of "
-                    f"{self.num_layers}, each 'mamba', 'linear_attention' "
-                    f"or 'attention': {kinds}")
-            if "mamba" in kinds and "linear_attention" in kinds:
+                    f"{self.num_layers}, each 'mamba', 'linear_attention', "
+                    f"'mamba1' or 'attention': {kinds}")
+            both = [k for k in RECURRENT_KINDS if k in kinds]
+            if len(both) > 1:
                 raise ValueError(
-                    "layer_types names 'mamba' and 'linear_attention' "
-                    "layers: a model has one kind of recurrent layer")
+                    "layer_types names " + " and ".join(map(repr, both))
+                    + " layers: a model has one kind of recurrent layer")
             if "mamba" in kinds and not (self.ssm_heads and self.ssm_head_dim
                                          and self.ssm_state):
                 raise ValueError("a 'mamba' layer needs ssm_heads, "
@@ -267,6 +290,13 @@ class ModelConfig:
                     "a 'linear_attention' layer needs gdn_heads, "
                     "gdn_key_dim, gdn_value_dim and a conv of two taps "
                     "or more (gdn_conv)")
+            if "mamba1" in kinds and not (
+                    self.mamba1_inner and self.mamba1_state
+                    and self.mamba1_dt_rank and self.mamba1_conv > 1):
+                raise ValueError(
+                    "a 'mamba1' layer needs mamba1_inner, mamba1_state, "
+                    "mamba1_dt_rank and a conv of two taps or more "
+                    "(mamba1_conv)")
             if self.layer_pattern() is not None or self.has_indexer:
                 raise ValueError(
                     "layer_types beside a per-layer attention pattern or "
@@ -307,7 +337,7 @@ class ModelConfig:
                           not self.has_ssm),
                          ("hc_mult", bool(self.hc_mult)),
                          ("router_input 'attn'",
-                          self.is_moe and self.router_input == "attn"),
+                          self.routed and self.router_input == "attn"),
                          ("arch 'gpt2'", self.arch == "gpt2")):
             if on:
                 raise ValueError(f"post_norm beside {name} is not supported")
@@ -365,7 +395,7 @@ class ModelConfig:
                          ("first_k_dense", bool(self.first_k_dense))):
             if not on:
                 continue
-            if not self.is_moe:
+            if not self.routed:
                 raise ValueError(f"{name} without num_experts: it "
                                  "describes a model of routed experts")
             if self.moe_impl == "ep" and name != "moe_intermediate_size":
@@ -449,7 +479,7 @@ class ModelConfig:
         for name, on in (("layer_types", bool(self.layer_types)),
                          ("index_topk", self.has_indexer),
                          ("router_input 'attn'",
-                          self.is_moe and self.router_input == "attn"),
+                          self.routed and self.router_input == "attn"),
                          ("residual_multiplier",
                           bool(self.residual_multiplier)),
                          ("arch 'gpt2'", self.arch == "gpt2")):
@@ -502,7 +532,20 @@ class ModelConfig:
 
     @property
     def is_moe(self) -> bool:
+        """The configuration states experts (num_experts >= 1): what a
+        benchmark file's fields are held to. The program asks `routed`
+        wherever it builds, shards or checks anything of a router."""
         return self.num_experts > 0
+
+    @property
+    def routed(self) -> bool:
+        """A router chooses among experts. With ONE expert there is
+        nothing to choose: the layer is that expert, a dense
+        feed-forward of intermediate_size under params["layers"]["mlp"],
+        and no router is built (the `jamba` family publishes
+        `num_experts` 1 for exactly that and builds its plain
+        feed-forward there)."""
+        return self.num_experts > 1
 
     @property
     def is_latent(self) -> bool:
@@ -564,16 +607,16 @@ class ModelConfig:
 
     @property
     def recurrent_kind(self) -> str:
-        """The model's ONE kind of recurrent layer ("mamba" or
-        "linear_attention"), "" for a model without."""
-        return next((k for k in ("mamba", "linear_attention")
-                     if k in self.layer_types), "")
+        """The model's ONE kind of recurrent layer (of
+        RECURRENT_KINDS), "" for a model without."""
+        return next((k for k in RECURRENT_KINDS if k in self.layer_types),
+                    "")
 
     @property
     def has_ssm(self) -> bool:
-        """Some layer is a recurrent mixer (Mamba-2 or Gated DeltaNet):
-        a stream holds a fixed-size state beside (or in place of) its
-        pages."""
+        """Some layer is a recurrent mixer (Mamba-2, Gated DeltaNet or
+        Mamba-1): a stream holds a fixed-size state beside (or in place
+        of) its pages."""
         return bool(self.recurrent_kind)
 
     @property
@@ -828,6 +871,26 @@ def olmo_hybrid_7b() -> ModelConfig:
     )
 
 
+def jamba2_3b() -> ModelConfig:
+    """AI21-Jamba2-3B (huggingface.co/ai21labs, `jamba`): 28 layers of
+    which 26 are Mamba-1 mixers (5,120 channels with a state of 16 each,
+    decayed a channel and a state index at a time; dt through a rank of
+    160; a conv of 4 taps with a bias over the inner stream; an RMSNorm
+    on each of dt, B and C) and layers 7 and 21 attention of 20 heads
+    over ONE key-value head of 128 without rotation; a dense SwiGLU of
+    8,192 in every layer (`num_experts` 1: no router); tied embeddings."""
+    return ModelConfig(
+        arch="jamba", vocab_size=65536, hidden_size=2560, num_layers=28,
+        num_heads=20, num_kv_heads=1, head_dim=128, intermediate_size=8192,
+        max_seq_len=262144, norm_eps=1e-6, pos_embedding="none",
+        tie_embeddings=True, num_experts=1, num_experts_per_tok=1,
+        layer_types=tuple("attention" if l % 14 == 7 else "mamba1"
+                          for l in range(28)),
+        mamba1_inner=5120, mamba1_state=16, mamba1_dt_rank=160,
+        mamba1_conv=4, mamba1_norms=True,
+    )
+
+
 def tiny(arch: str = "llama", **kw) -> ModelConfig:
     """Small config for tests: runs in <1s on CPU, exercises every code path."""
     base = dict(
@@ -878,6 +941,17 @@ def tiny(arch: str = "llama", **kw) -> ModelConfig:
                     gdn_heads=4, gdn_key_dim=16, gdn_value_dim=32,
                     gdn_neg_eigval=True, pos_embedding="none",
                     post_norm=True, qk_norm_wide=True, norm_eps=1e-6)
+    if arch == "jamba":
+        # every mechanism of the real one: two Mamba-1 layers and an
+        # attention layer of 4 heads over ONE key-value head, a state of
+        # 16 a channel, dt through a bottleneck, the three inner norms,
+        # no rotation, ONE "expert" (a dense feed-forward), a tied head
+        base.update(num_layers=3, num_kv_heads=1, num_experts=1,
+                    num_experts_per_tok=1,
+                    layer_types=("mamba1", "attention", "mamba1"),
+                    mamba1_inner=128, mamba1_state=16, mamba1_dt_rank=8,
+                    mamba1_norms=True, pos_embedding="none",
+                    tie_embeddings=True, norm_eps=1e-6)
     if arch == "joyai":
         # every mechanism of the real one: the query's latent, one cached
         # row of latent + rotary key, value heads narrower than the
@@ -942,6 +1016,7 @@ PRESETS = {
     "xing4.0-29b-a4b": xing4_29b_a4b,
     "glm-5": glm5,
     "olmo-hybrid-7b": olmo_hybrid_7b,
+    "jamba2-3b": jamba2_3b,
 }
 
 

@@ -221,10 +221,9 @@ class ServingEngine:
         if self.runtime.prefix_caching:
             indexer_unsupported(self.cfg, "prefix caching (and the host "
                                           "KV tier behind it)")
-        # so does what cannot take a recurrent state a slot (Mamba-2 or
-        # Gated DeltaNet layers): it has no pages to hash, export or roll
-        # back, and no
-        # sharding of its own yet
+        # so does what cannot take a recurrent state a slot (Mamba-2,
+        # Gated DeltaNet or Mamba-1 layers): it has no pages to hash,
+        # export or roll back, and no sharding of its own yet
         for axis, what in (("stage", "pipeline serving"),
                            ("seq", "the sequence-parallel prefill lane"),
                            ("tensor", "tensor parallelism (the mixer's "
@@ -304,8 +303,8 @@ class ServingEngine:
                 quant=self.runtime.kv_quant == "int8"), mesh)
         self.cache = init_paged_cache(self.cfg, self.runtime,
                                       shardings=cache_shardings)
-        # a model with recurrent layers (Mamba-2, Gated DeltaNet): every
-        # slot's recurrent state
+        # a model with recurrent layers (Mamba-2, Gated DeltaNet,
+        # Mamba-1): every slot's recurrent state
         # (cache/ssm_state.py), DONATED to each mixed block and rebound
         # from its result like the window; None for every other model
         self._ssm_state: Optional[SSMState] = init_ssm_state(

@@ -21,6 +21,7 @@ implementation exists (see SURVEY.md §0).
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -912,7 +913,7 @@ def early_router_logits(x: jax.Array, lp: Params,
     layer's INPUT: the norm is taken again in float32 for the router
     (router_logits says why; the attention reads the compute dtype's).
     None where the router reads the feed-forward's own input."""
-    if cfg.is_moe and cfg.router_input == "attn":
+    if cfg.routed and cfg.router_input == "attn":
         h = pre_norm(x.astype(jnp.float32), lp["ln1"], cfg)
         return router_logits(h, lp["moe"]["router"])
     return None
@@ -925,7 +926,7 @@ def ffn_block(h: jax.Array, lp: Params, cfg: ModelConfig,
     pipeline, sequence-parallel): dense MLP, dense MoE, or EP MoE per
     cfg — one definition so the variants can't drift. `logits`:
     early_router_logits' of this layer, where the model has them."""
-    if cfg.is_moe and "moe" in lp:   # not a leading dense layer's (first_k_dense)
+    if cfg.routed and "moe" in lp:   # not a leading dense layer's (first_k_dense)
         if cfg.moe_impl == "ep":   # no early logits here: ModelConfig refuses
             from butterfly_tpu.parallel.expert import moe_block_ep
             out = moe_block_ep(h, lp["moe"], cfg)
@@ -1069,7 +1070,7 @@ def stream_fold(x: jax.Array, cfg: ModelConfig) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Mamba-2 mixer (cfg.layer_types "mamba"): a layer whose memory of a
 # stream is a FIXED-SIZE recurrent state, not rows that grow. (The
-# other recurrent kind, Gated DeltaNet, follows it below.)
+# other recurrent kinds, Gated DeltaNet and Mamba-1, follow it below.)
 #
 #   [z | xBC | dt] = in_proj(h)          widths Di | Dc | Nh, no bias
 #   xBC = silu(conv(xBC))                causal, depthwise, K taps with
@@ -1091,17 +1092,19 @@ def stream_fold(x: jax.Array, cfg: ModelConfig) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 #: a recurrent layer kind (cfg.recurrent_kind) by the name of its mixer
-RECURRENT_NAMES = {"mamba": "Mamba-2", "linear_attention": "Gated DeltaNet"}
+RECURRENT_NAMES = {"mamba": "Mamba-2", "linear_attention": "Gated DeltaNet",
+                   "mamba1": "Mamba-1"}
 
 
 #: the top-level stack of params that holds a recurrent kind's mixers
-RECURRENT_STACKS = {"mamba": "mamba", "linear_attention": "gdn"}
+RECURRENT_STACKS = {"mamba": "mamba", "linear_attention": "gdn",
+                    "mamba1": "mamba1"}
 
 
 def ssm_unsupported(cfg: ModelConfig, what: str) -> None:
-    """Refuse a model with recurrent layers (Mamba-2 or Gated DeltaNet)
-    on a path that does not carry a recurrent state a stream (it has no
-    pages to hash, export, roll back or shard)."""
+    """Refuse a model with recurrent layers (Mamba-2, Gated DeltaNet or
+    Mamba-1) on a path that does not carry a recurrent state a stream
+    (it has no pages to hash, export, roll back or shard)."""
     if cfg.has_ssm:
         raise NotImplementedError(
             f"{what} does not carry the recurrent state of a model with "
@@ -1120,7 +1123,7 @@ def layer_runs(cfg: ModelConfig):
     a run ends where the leading dense layers do (ffn_run says which
     stack a run's feed-forward is in)."""
     kinds = cfg.layer_types or ("attention",) * cfg.num_layers
-    runs, seen = [], {"mamba": 0, "linear_attention": 0, "attention": 0}
+    runs, seen = [], dict.fromkeys((*RECURRENT_STACKS, "attention"), 0)
     for l, kind in enumerate(kinds):
         if runs and runs[-1][0] == kind and l != cfg.first_k_dense:
             runs[-1][2] += 1
@@ -1505,6 +1508,122 @@ def gdn_gate_out(o: jax.Array, z: jax.Array, gp: Params,
     return qeinsum("bti,id->btd", y, gp["out_proj"], z.dtype)
 
 
+# ---------------------------------------------------------------------------
+# Mamba-1 mixer (cfg.layer_types "mamba1"; arXiv:2312.00752, as the
+# `jamba` family has it): a layer whose memory of a stream is N numbers a
+# CHANNEL, each decayed at a rate of its own. Di channels, a state of N,
+# dt through a bottleneck of R:
+#
+#   [u | z] = in_proj(x)                 widths Di | Di, no bias
+#   u = silu(conv(u) + b)                causal, depthwise, K taps, over
+#                                        u ALONE
+#   [r | B | C] = x_proj(u)              R | N | N, no bias: from the
+#                                        conv's OUTPUT
+#   r, B, C = RMSNorm(r) w, RMSNorm(B) w, RMSNorm(C) w   (mamba1_norms)
+#   dt = softplus(dt_proj(r) + dt_bias)  [Di]: a step size a channel
+#   A = -exp(A_log)                      [N, Di] as held here
+#   h[n, c] = exp(dt[c] A[n, c]) h[n, c] + dt[c] B[n] u[c]
+#   y[c] = sum_n h[n, c] C[n] + D[c] u[c]
+#   out = out_proj(y * silu(z))          NO norm behind the gate
+#
+# Mamba-2 decays a head's whole [Hd, N] state by ONE scalar and projects
+# dt, B and C from the layer's input; here every one of the Di x N state
+# values has its own rate and its own exponential a step. What a stream
+# keeps between calls is h [N, Di] (channels on the lanes: N = 16 is one
+# tile of bfloat16 rows; cache/ssm_state.py) and the conv's last K-1
+# inputs [K-1, Di], in cfg.dtype; a call's arithmetic is float32. The
+# pieces are composed in cache/ssm_state.py advance_packed beside the
+# other two kinds'.
+# ---------------------------------------------------------------------------
+
+
+@jax.named_scope("mamba1_proj")
+def mamba1_in_proj(h: jax.Array, mp: Params, cfg: ModelConfig):
+    """(u [B,T,Di], z [B,T,Di]) of the normed input: u first."""
+    uz = qeinsum("btd,dp->btp", h, mp["in_proj"], h.dtype)
+    return uz[..., :cfg.mamba1_inner], uz[..., cfg.mamba1_inner:]
+
+
+mamba1_conv = jax.named_scope("mamba1_conv")(_causal_conv)
+
+
+@jax.named_scope("mamba1_inputs")
+def mamba1_step_inputs(u: jax.Array, mp: Params, cfg: ModelConfig):
+    """What the recurrence reads of its positions beside u itself, from
+    the conv's OUTPUT u [B,T,Di] float32 (mamba1_conv): (dt [B,T,Di]
+    after its bias and softplus, B and C [B,T,N]), float32. The two
+    small products take their operand in the compute dtype and sum in
+    float32."""
+    R, N = cfg.mamba1_dt_rank, cfg.mamba1_state
+    cdt, f32 = jnp.dtype(cfg.dtype), jnp.float32
+    rbc = jnp.einsum("bti,ip->btp", u.astype(cdt), mp["x_proj"].astype(cdt),
+                     preferred_element_type=f32)
+    r, Bm, Cm = rbc[..., :R], rbc[..., R:R + N], rbc[..., R + N:]
+    # the family's inner norms (cfg.mamba1_norms: init_params), each
+    # where the layer's weights have one, as _causal_conv takes a bias
+    r, Bm, Cm = (rms_norm(a, mp[n]["scale"], cfg.norm_eps) if n in mp else a
+                 for a, n in ((r, "dt_norm"), (Bm, "b_norm"), (Cm, "c_norm")))
+    dt = jnp.einsum("btr,ri->bti", r.astype(cdt), mp["dt_proj"].astype(cdt),
+                    preferred_element_type=f32)
+    return jax.nn.softplus(dt + mp["dt_bias"].astype(f32)), Bm, Cm
+
+
+def _mamba1_positions(u, dt, Bm, Cm, mp: Params, state, count):
+    """The recurrence over a row's positions: u and dt [B,T,Di], Bm and
+    Cm [B,T,N] float32, state [B,N,Di] float32 BEFORE the call. A row's
+    state advances through its first `count` [B] positions and no
+    further. T == 1 is one step; longer rows scan their positions, one
+    exponential a state value and position. Returns (y [B,T,Di] float32
+    with the skip term D u, state after)."""
+    T = u.shape[1]
+    A = -jnp.exp(mp["A_log"].astype(jnp.float32))           # [N, Di]
+    real = jnp.arange(T)[None, :] < count[:, None]           # [B,T]
+
+    def step(h, t):
+        u_t, dt_t, B_t, C_t, real_t = t
+        new = jnp.exp(dt_t[:, None, :] * A) * h \
+            + (dt_t * u_t)[:, None, :] * B_t[:, :, None]
+        h = jnp.where(real_t[:, None, None], new, h)
+        return h, jnp.sum(h * C_t[:, :, None], axis=1)       # [B,Di]
+
+    if T == 1:
+        state, y = step(state, (u[:, 0], dt[:, 0], Bm[:, 0], Cm[:, 0],
+                                real[:, 0]))
+        y = y[:, None]
+    else:
+        state, y = lax.scan(step, state, tuple(
+            jnp.moveaxis(a, 1, 0) for a in (u, dt, Bm, Cm, real)))
+        y = jnp.moveaxis(y, 0, 1)
+    return y + mp["D"].astype(jnp.float32) * u, state
+
+
+@jax.named_scope("mamba1_step")
+def mamba1_step(h: jax.Array, m, u, dt, Bm, Cm, mp: Params, count):
+    """One position of every slot of layer m, over the state where it
+    lies: h [Lm, S, N, Di] the WHOLE carried state, u and dt [S,1,Di],
+    Bm and Cm [S,1,N] (mamba1_step_inputs at T == 1), count [S] (1: the
+    row decodes). The layer's slots are read as float32, stepped, and
+    written back in place at the layer's index. Returns (y [S,1,Di]
+    float32, h)."""
+    st = lax.dynamic_index_in_dim(h, m, 0, keepdims=False)
+    y, new = _mamba1_positions(u, dt, Bm, Cm, mp, st.astype(jnp.float32),
+                               count)
+    return y, lax.dynamic_update_index_in_dim(h, new.astype(h.dtype), m, 0)
+
+
+#: a chunk's recurrence: rows of T positions, a position at a time
+#: (state [P,N,Di] float32 BEFORE the call)
+mamba1_scan = jax.named_scope("mamba1_scan")(_mamba1_positions)
+
+
+@jax.named_scope("mamba1_gate")
+def mamba1_gate_out(y: jax.Array, z: jax.Array, mp: Params) -> jax.Array:
+    """y [B,T,Di] float32 gated by silu(z), then the out-projection: no
+    norm between them (Mamba-2's ssm_gate_out has one)."""
+    g = (y * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype)
+    return qeinsum("bti,id->btd", g, mp["out_proj"], z.dtype)
+
+
 def ffn_close(x: jax.Array, lp: Params, cfg: ModelConfig, route=None,
               ok=None):
     """A layer from its mixer's residual on: the feed-forward with its
@@ -1514,7 +1633,7 @@ def ffn_close(x: jax.Array, lp: Params, cfg: ModelConfig, route=None,
     (expert_load: five values under cfg.experts_held), else None."""
     h, mix = stream_read(x, lp, 2, cfg)
     load = None
-    if ok is not None and cfg.is_moe and "moe" in lp:
+    if ok is not None and cfg.routed and "moe" in lp:
         if route is None:
             route = router_logits(h, lp["moe"]["router"])
         load = expert_load(
@@ -1597,7 +1716,7 @@ def uniform_layers_only(cfg: ModelConfig, what: str) -> None:
     (pipeline stages, sequence-parallel bodies): neither carries the
     pattern nor the early router logits yet."""
     if cfg.layer_pattern() is not None \
-            or (cfg.is_moe and cfg.router_input == "attn"):
+            or (cfg.routed and cfg.router_input == "attn"):
         raise NotImplementedError(
             f"{what} runs models whose layers are all alike; this one "
             "has a per-layer attention pattern or routes before attention")
@@ -2084,7 +2203,7 @@ def _hybrid_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                     last_index: Optional[jax.Array]
                     ) -> Tuple[jax.Array, KVCache]:
     """forward for a model with recurrent layers (cfg.layer_types:
-    Mamba-2 or Gated DeltaNet): the
+    Mamba-2, Gated DeltaNet or Mamba-1): the
     layers run as scans over runs of one kind (layer_runs), each run
     riding its kind's stack by index. cache.k/v hold the attention
     layers alone. A recurrent layer is the packed serving step's
@@ -2290,9 +2409,48 @@ def stream_seed(cfg: ModelConfig) -> Tuple[float, float]:
     return 0.02, 1.0
 
 
+#: how a SEEDED Mamba-1 mixer's small leaves are drawn, by leaf name
+#: (`drawn`'s kinds; init_params and quant/int8.py init_params_by_leaf
+#: alike; every other weight of the stack is N(0, .02)). At N(0, .02)
+#: every one of the Di x N rates is -1 and every step .69: one decay for
+#: the whole layer, forgotten within ten positions, behind a conv and a
+#: skip that pass a fiftieth of their input, so the mixer adds nothing a
+#: comparison with the reference could see (PERF.md, PR 58). So the taps
+#: and the skip are drawn as a Mamba-2 mixer's are (init_params), and
+#: the rates and steps over the ranges Mamba-1 is trained from
+#: (arXiv:2312.00752: A in 1-16, dt log-uniform in .001-.1), here a
+#: number of its own for EVERY channel and state index: a step that
+#: decays a layer, a head or a channel by one number differs from such a
+#: model at once. dt's projection is drawn at the scale the same source
+#: gives it, R^-1/2 (N(0, .02) through a rank of 160 moves a step by a
+#: fifth, and a program without the norm on dt passed the check).
+MAMBA1_SEEDS = {"conv_w": "normal_half", "D": "normal_1",
+                "A_log": "rates", "dt_bias": "steps", "dt_proj": "fan_in"}
+_STD = {"normal": 0.02, "normal_half": 0.5, "normal_1": 1.0}
+
+
+def drawn(k: jax.Array, shape, kind: str) -> jax.Array:
+    """A seeded leaf's float32 values by kind: "normal" N(0, .02),
+    "normal_half" N(0, .5), "normal_1" N(0, 1), "fan_in" N(0, n^-1/2)
+    of a projection [.., n, m]; of a Mamba-1 mixer (MAMBA1_SEEDS)
+    "rates" (A_log: log of A uniform in 1-16) and "steps" (dt_bias: the
+    inverse softplus of dt log-uniform in .001-.1)."""
+    if kind in _STD or kind == "fan_in":
+        std = _STD[kind] if kind in _STD else shape[-2] ** -0.5
+        return jax.random.normal(k, shape, jnp.float32) * std
+    if kind == "rates":
+        return jnp.log(jax.random.uniform(k, shape, jnp.float32, 1.0, 16.0))
+    if kind == "steps":
+        dt = jnp.exp(jax.random.uniform(k, shape, jnp.float32,
+                                        math.log(1e-3), math.log(1e-1)))
+        return jnp.log(jnp.expm1(dt))
+    raise ValueError(f"no seeded leaf is drawn as {kind!r}")
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     """Random init (normal, 0.02 std — GPT-2 style; stream_seed has what
-    a post_norm model changes) in cfg.param_dtype."""
+    a post_norm model changes, MAMBA1_SEEDS a Mamba-1 mixer's small
+    leaves) in cfg.param_dtype."""
     pdt = jnp.dtype(cfg.param_dtype)
     emb_std, ln = stream_seed(cfg)
     L, D, Nq, Kv, H, F, V = (cfg.num_layers, cfg.hidden_size, cfg.num_heads,
@@ -2365,7 +2523,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     Ld = cfg.first_k_dense
     sparse = {} if Ld else layers
     dense = {} if Ld else layers
-    if cfg.is_moe:
+    if cfg.routed:
         E, Fe, Ls = cfg.num_experts, cfg.expert_width, L - Ld
         # the router ranges over every expert; the expert leaves hold
         # the chip's share where the configuration states one
@@ -2388,12 +2546,12 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
                 "w_up": w(next(keys), Ls, D, Fs),
                 "w_down": w(next(keys), Ls, Fs, D),
             }
-    if cfg.arch == "gpt2" and not cfg.is_moe:
+    if cfg.arch == "gpt2" and not cfg.routed:
         layers["mlp"] = {
             "w_up": w(next(keys), L, D, F), "b_up": jnp.zeros((L, F), pdt),
             "w_down": w(next(keys), L, F, D), "b_down": jnp.zeros((L, D), pdt),
         }
-    elif Ld or not cfg.is_moe:
+    elif Ld or not cfg.routed:
         n = Ld or L
         dense["mlp"] = {
             "w_gate": w(next(keys), n, D, F),
@@ -2437,6 +2595,31 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             "norm": {"scale": jnp.ones((Lm, Di), pdt)},
             "out_proj": w(next(keys), Lm, Di, D),
         }
+    if cfg.recurrent_kind == "mamba1":
+        Lm, Di, N = cfg.num_ssm_layers, cfg.mamba1_inner, cfg.mamba1_state
+        R = cfg.mamba1_dt_rank
+        shapes = {
+            # u | z; x_proj r | B | C and dt_proj stay out of the int8
+            # quantiser (quant/int8.py): a fortieth of the mixer, and
+            # what they give is exponentiated
+            "in_proj": (Lm, D, 2 * Di),
+            "conv_w": (Lm, cfg.mamba1_conv, Di),
+            "conv_b": (Lm, Di),
+            "x_proj": (Lm, Di, R + 2 * N),
+            "dt_proj": (Lm, R, Di),
+            "dt_bias": (Lm, Di),
+            # held [N, Di], as the state is: channels on the lanes
+            "A_log": (Lm, N, Di),
+            "D": (Lm, Di),
+            "out_proj": (Lm, Di, D),
+        }
+        params["mamba1"] = {
+            name: drawn(next(keys), shape,
+                        MAMBA1_SEEDS.get(name, "normal")).astype(pdt)
+            for name, shape in shapes.items()}
+        if cfg.mamba1_norms:
+            for name, n in (("dt_norm", R), ("b_norm", N), ("c_norm", N)):
+                params["mamba1"][name] = {"scale": jnp.ones((Lm, n), pdt)}
     if cfg.pos_embedding == "learned":
         params["embed"]["pos"] = w(next(keys), cfg.max_seq_len, D)
     if cfg.arch == "gpt2":

@@ -125,8 +125,8 @@ class TickLog:
         `kv_rows_live`, `kv_rows_selected` and `kv_rows_moved`: the
         positions a live decode row could attend, those it attended
         and the rows its read moved out of the cache (null otherwise).
-        `ssm_load` (a model with recurrent layers, Mamba-2 or Gated
-        DeltaNet; null otherwise):
+        `ssm_load` (a model with recurrent layers, Mamba-2, Gated
+        DeltaNet or Mamba-1; null otherwise):
         [rows, resets, steps] SUMMED over the mixed blocks the tick
         drained, as `ssm_rows` (positions their steps pushed through a
         recurrence: decode rows and real chunk columns),

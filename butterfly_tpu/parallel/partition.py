@@ -99,7 +99,7 @@ def param_specs(cfg: ModelConfig, mesh: Mesh) -> Specs:
             bq=P(L, tp(Nq), None), bk=P(L, tp(Kv), None),
             bv=P(L, tp(Kv), None), bo=P(L, None),
         )
-    if cfg.is_moe:
+    if cfg.routed:
         E = cfg.num_experts
         ep = _div(E, mesh, "expert")
         layers["moe"] = {

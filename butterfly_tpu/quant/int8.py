@@ -113,6 +113,10 @@ def quantize_int8(params: Params, cfg) -> Params:
         Gated DeltaNet mixer's five wide projections (ab_proj, two
         numbers a head, the conv, A_log, dt_bias and the norm stay
         float)
+      mamba1 in_proj [Lm,D,u|z] -> D;  out_proj [Lm,Di,D] -> Di: a
+        Mamba-1 mixer's two wide projections (x_proj, dt_proj, the conv,
+        A_log, D, dt_bias and the three inner norms stay float: what
+        they give is exponentiated, and they are a fortieth of a mixer)
       latent attention: w_dq [L,D,Rq], w_uq [L,Rq,N,H], w_dkv [L,D,R+r],
         w_uk / w_uv [L,R,N,H] -> the dim behind L (w_uk is contracted
         over H in the absorbed read, which dequantizes it first:
@@ -124,7 +128,8 @@ def quantize_int8(params: Params, cfg) -> Params:
         (models/common.py stream_read) and a thousandth of a layer
     The leaf's PATH decides (_contraction_axes), wherever its stack
     lies: under params["layers"], or top-level for a model whose layers
-    run as runs (params["attn"], "mamba", "gdn", "dense", "sparse").
+    run as runs (params["attn"], "mamba", "gdn", "mamba1", "dense",
+    "sparse").
     Runs as one jit so a large tree quantizes device-side in one program.
     """
 
@@ -170,7 +175,8 @@ def _contraction_axes(path_names) -> Optional[Tuple[int, ...]]:
         return (2,)
     if parent in ("mlp", "shared") and name in ("w_gate", "w_up", "w_down"):
         return (1,)
-    if parent in ("mamba", "gdn") and name in ("in_proj", "out_proj"):
+    if parent in ("mamba", "gdn", "mamba1") \
+            and name in ("in_proj", "out_proj"):
         return (1,)
     if name == "lm_head":
         return (0,)
@@ -181,7 +187,9 @@ def _leaf_kind(names, stream):
     """How init_params seeds the leaf at this tree path: a norm's scale
     "ones", a bias "zeros", a weight "normal" (N(0, .02)); of the
     residual streams' mixing (hc1 / hc2), b "normal_1" (N(0, 1)) and
-    alpha "hundredths" (the constant .01: init_params says why).
+    alpha "hundredths" (the constant .01: init_params says why); of a
+    Mamba-1 mixer the taps, the skip, the rates and the steps as
+    models.common.MAMBA1_SEEDS draws them.
     `stream`: models.common.stream_seed's pair, where a model seeds the
     embedding at 1 ("normal_1") and its sublayers' norms at a constant
     (a kind that is a number is that constant)."""
@@ -193,6 +201,9 @@ def _leaf_kind(names, stream):
     if len(names) > 1 and names[-2].startswith("hc"):
         return {"b": "normal_1", "alpha": "hundredths"}.get(names[-1],
                                                             "normal")
+    if len(names) > 1 and names[-2] == "mamba1":
+        from butterfly_tpu.models.common import MAMBA1_SEEDS
+        return MAMBA1_SEEDS.get(names[-1], "normal")
     return "zeros" if names[-1].startswith("b") else "normal"
 
 
@@ -201,13 +212,13 @@ _FILLS = {"ones": 1.0, "zeros": 0.0, "hundredths": 0.01}
 
 
 def _leaf_values(k, *, shape, kind, axes, dt):
-    """One leaf in its final form: a constant (_FILLS), or N(0, .02)
-    (N(0, 1) for "normal_1") quantized over `axes` when given, else
-    cast to the compute dtype."""
-    if not str(kind).startswith("normal"):
+    """One leaf in its final form: a constant (_FILLS or a number), or
+    drawn by kind (models.common.drawn: N(0, .02) for "normal") and
+    quantized over `axes` when given, else cast to the compute dtype."""
+    from butterfly_tpu.models.common import drawn
+    if kind in _FILLS or not isinstance(kind, str):
         return jnp.full(shape, _FILLS.get(kind, kind), dt)
-    std = 1.0 if kind == "normal_1" else 0.02
-    w = jax.random.normal(k, shape, jnp.float32) * std
+    w = drawn(k, shape, kind)
     return _quant(w, axes, dt) if axes is not None else w.astype(dt)
 
 

@@ -747,11 +747,11 @@ class Scheduler:
         # [touched, rows_max, rows_mean] a block. Empty for a dense
         # model. The gauges hold the newest block's.
         self._tick_expert_loads: List = []
-        # a model with recurrent layers (Mamba-2 or Gated DeltaNet): the
-        # same vector ends in the block's recurrence rows and state
-        # resets (sums over its
-        # steps); the tick record holds their sums over the blocks it
-        # drained, with the steps those ran. None for every other model
+        # a model with recurrent layers (Mamba-2, Gated DeltaNet or
+        # Mamba-1): the same vector ends in the block's recurrence rows
+        # and state resets (sums over its steps); the tick record holds
+        # their sums over the blocks it drained, with the steps those
+        # ran. None for every other model
         self._has_ssm = engine.cfg.has_ssm
         self._tick_ssm: Optional[List[float]] = None
         # a latent-attention model: the vector ends in the cached rows
@@ -795,7 +795,8 @@ class Scheduler:
         self._g_ssm_state_bytes = reg.gauge(
             "ssm_state_bytes",
             "Bytes of recurrent state the slots hold for a model with "
-            "Mamba-2 or Gated DeltaNet layers (cache/ssm_state.py): fixed, "
+            "Mamba-2, Gated DeltaNet or Mamba-1 layers "
+            "(cache/ssm_state.py): fixed, "
             "whatever the streams' lengths; 0 for a model without")
         self._g_ssm_state_bytes.set(float(
             (state_info(engine.cfg, engine.num_slots) or {}).get("bytes", 0)))

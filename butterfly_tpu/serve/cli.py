@@ -425,7 +425,7 @@ def resolve_model(args):
         cfg = PRESETS[args.model]()
     if args.dtype:
         cfg = cfg.replace(dtype=args.dtype)
-    if getattr(args, "expert_parallel", 1) > 1 and cfg.is_moe:
+    if getattr(args, "expert_parallel", 1) > 1 and cfg.routed:
         # EP means GShard all_to_all dispatch, not an expert-sharded
         # dense MoE where every expert still computes every token.
         cfg = cfg.replace(moe_impl="ep")

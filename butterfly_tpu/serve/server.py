@@ -34,8 +34,8 @@ zero-egress environment):
   pool holds a row a KV head, or, for a model with a sparse-attention
   indexer, a row a token) and "state" ({kind, layers, layout,
   whole_tiles, bytes_per_slot, dtype, bytes}: the recurrent state a slot
-  keeps for a model with Mamba-2 or Gated DeltaNet layers, the kind, the
-  layout it is held in, whether that is whole tiles of a TPU's memory
+  keeps for a model with Mamba-2, Gated DeltaNet or Mamba-1 layers, the
+  kind, the layout it is held in, whether that is whole tiles of a TPU's memory
   (the declared bytes are then the held ones), cache/ssm_state.py; null
   for every other model). 503 with a
   detail string when wedged.

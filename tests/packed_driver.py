@@ -1,6 +1,7 @@
 """What engine._packed_scan does around one packed step, by hand, for
 the tests of a model with recurrent layers (tests/test_granite_hybrid.py:
-Mamba-2; tests/test_olmo_hybrid.py: Gated DeltaNet): the driver, the
+Mamba-2; tests/test_olmo_hybrid.py: Gated DeltaNet; tests/test_jamba.py:
+Mamba-1): the driver, the
 scripted run both families go through, and the readers of a parameter
 tree and of an error that both compare with."""
 import jax

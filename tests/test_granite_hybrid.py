@@ -1069,7 +1069,8 @@ def test_layer_types_are_checked():
     with pytest.raises(ValueError, match="layer_types names 2 layers of 4"):
         tiny("granite_hybrid", layer_types=("mamba", "attention"))
     with pytest.raises(ValueError,
-                       match="'mamba', 'linear_attention' or 'attention'"):
+                       match="'mamba', 'linear_attention', 'mamba1' or "
+                             "'attention'"):
         tiny("granite_hybrid", layer_types=("mamba", "conv", "mamba", "mamba"))
     with pytest.raises(ValueError, match="needs ssm_heads"):
         tiny("granite_hybrid", ssm_state=0)

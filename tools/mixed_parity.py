@@ -66,6 +66,9 @@ LIMIT = 0.2
 #: 0.32 at the median decode row and 1.38 at a chunk's: their geometric
 #: middle. The first tree's order, decode rows before the chunks, read
 #: 0.130-0.138 at the decode rows of P 2: over this, under LIMIT)
+#: A Mamba-1 model keeps LIMIT (PERF.md, PR 58: Jamba2-3B clean 0.051
+#: at the median row and 0.059, 0.061 at the worst of 66 and 85, a
+#: chunk fed a token late 1.43)
 LIMITS = {"linear_attention": 0.08}
 BLOCKS = 3
 FAULTS = ("clean", "chunk_shift", "index_shift")

@@ -1,5 +1,6 @@
-"""What holds a model with recurrent layers (Mamba-2 or Gated DeltaNet:
-the configuration's ONE recurrent kind) to its plain reference over a
+"""What holds a model with recurrent layers (Mamba-2, Gated DeltaNet or
+Mamba-1: the configuration's ONE recurrent kind) to its plain reference
+over a
 WHOLE stream, on the chip, at the published widths, through the server's
 own path:
 
@@ -17,7 +18,8 @@ rollout cell's stream), `first` beside it, and `second`, which waits and
 is admitted into the slot `first` leaves, with `long`'s blocks in flight.
 What is compared is the STATE each slot holds at the end (every recurrent
 layer's heads' state, a head at a time, H [Nh, Hd, N] or S [H, dv, dk],
-and the conv's last K-1 inputs) with the state the
+or a Mamba-1 layer's h [Di, N] a channel at a time, and the conv's last
+K-1 inputs) with the state the
 reference's position-by-position loop holds after the same tokens (the
 prompt and all served tokens but the last, which is never fed back): rms
 of the difference over the rms of the reference's, a layer; the WORST
@@ -74,8 +76,15 @@ sys.path.insert(0, str(ROOT))
 #: the first layer and climbs with depth to 0.0360 in the 24th (long,
 #: 383 positions; the reused slot 0.0353: a state's input is the stream,
 #: 0.02 off the reference's by then, refcheck), `no_reset` 0.2357 and
-#: `filler_advances` 0.3669: their geometric middle, 2.5 times of room
-LIMITS = {"mamba": 0.04, "linear_attention": 0.09}
+#: `filler_advances` 0.3669: their geometric middle, 2.5 times of room.
+#: A Mamba-1 model's (PERF.md, PR 58, on the mixers as they are seeded
+#: live: models/common.py MAMBA1_SEEDS): the clean run 0.0057 in the
+#: first layer to 0.0930 in the 26th (long, 2,247 positions; the reused
+#: slot 0.0873 after 139: depth, not length; the stream a state is fed
+#: is 5 % off the reference's by then, as the logits are), `no_reset`
+#: 0.3108 and `filler_advances` 1.1029: the geometric middle of 0.0930
+#: and 0.3108, 1.8 times of room
+LIMITS = {"mamba": 0.04, "linear_attention": 0.09, "mamba1": 0.17}
 SLOTS = 2
 #: (prompt, new tokens) of each request; a chunk is 32 wide
 LONG, FIRST, SECOND = (200, 2048), (70, 40), (100, 40)
@@ -109,14 +118,16 @@ def planted(fault: str):
 
 def remembering(params, seed: int, stack: str = "mamba"):
     """The tree with `A_log` and `dt_bias` of the recurrent kind's
-    stack (params["mamba"] or params["gdn"]) drawn from Mamba-2's
+    stack (params["mamba"], "gdn" or "mamba1") drawn from Mamba-2's
     initial ranges (A uniform 1-16; dt log-uniform 0.001-0.1, its bias
-    the inverse softplus)."""
+    the inverse softplus), each in its leaf's own shape (a number a
+    head, or Mamba-1's a channel and a state index and a channel)."""
     import jax.numpy as jnp
     rng = np.random.default_rng(seed)
     mixers = dict(params[stack])
     shape, dtype = mixers["A_log"].shape, mixers["A_log"].dtype
-    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), shape))
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1),
+                            mixers["dt_bias"].shape))
     mixers["A_log"] = jnp.asarray(np.log(rng.uniform(1, 16, shape)), dtype)
     mixers["dt_bias"] = jnp.asarray(np.log(np.expm1(dt)), dtype)
     return {**params, stack: mixers}
@@ -197,10 +208,12 @@ def check(config: dict, toy: bool = False, seed: int = 41,
                             quant=sv.get("quant", "none")), seed,
         RECURRENT_STACKS[cfg.recurrent_kind])
     # a slot's state of one layer as the reference holds it: Mamba-2's as
-    # held; Gated DeltaNet's lanes-of-a-group layout a head at a time
-    heads = (lambda h: np.asarray(gdn_heads_of(
-        jnp.asarray(h, jnp.float32)[None], cfg)[0])) \
-        if cfg.recurrent_kind == "linear_attention" else (lambda h: h)
+    # held; Gated DeltaNet's lanes-of-a-group layout a head at a time;
+    # Mamba-1's [N, Di] a channel at a time
+    heads = {"linear_attention": lambda h: np.asarray(gdn_heads_of(
+        jnp.asarray(h, jnp.float32)[None], cfg)[0]),
+        "mamba1": lambda h: np.asarray(h).T}.get(cfg.recurrent_kind,
+                                                 lambda h: h)
     rng = np.random.default_rng(seed)
     prompts = [rng.integers(1, cfg.vocab_size, p).astype(np.int32)
                for p, _ in requests]
