@@ -67,7 +67,17 @@ block); with kernels off (the CPU) or any other state it is
 models.common.gdn_step (XLA's: one reduction over the layer's state and
 the update in place, the state read twice), which is also what the
 kernel is tested against. A chunk's is gdn_chunk (the chunkwise form),
-either way. Either kind: the chunks read and write their own slots
+either way. Mamba-1, by the same rule: a decode row's one step is the
+Pallas kernel ops/mamba1_step.py when the engine's kernels are on and
+the state's minor dims are whole tiles (ops/mamba1_step.py fits: the
+whole h aliased to its result and the layer's index, ONE pass over
+layer m's slots where they lie, the decay, the input and the readout
+from one float32 value of a slot's state); with kernels off (the CPU)
+or any other state it is models.common.mamba1_step (XLA's: the update
+in place and a readout that forms it a second time, the state read
+twice), which is also what the kernel is tested against. A chunk's is
+mamba1_scan's scan over its positions, either way. Any kind: the
+chunks read and write their own slots
 FIRST and the decode rows' step follows on the result (a chunk's slot
 is no live decode row, so that step leaves it as it is).
 """
@@ -88,6 +98,7 @@ from butterfly_tpu.models.common import (
     ssm_gate_out, ssm_in_proj, ssm_scan, ssm_skip, ssm_step_inputs,
     stream_read, stream_write)
 from butterfly_tpu.ops import gdn_step as gdn_kernel
+from butterfly_tpu.ops import mamba1_step as mamba1_kernel
 from butterfly_tpu.ops.ssm_step import fits, ssm_step
 
 
@@ -293,8 +304,10 @@ class _DeltaNet:
 class _Mamba1:
     """advance_packed's Mamba-1 layer: dt, B and C come from the conv's
     OUTPUT, so they are formed where the recurrence is (decode, chunk)
-    and `aux` carries the gate alone. No kernel of this kind: a decode
-    row's step is XLA's over the state where it lies."""
+    and `aux` carries the gate alone. A decode row's step: with
+    use_kernel and a state the kernel can cut (ops/mamba1_step.py fits)
+    one pass over the state where it lies; else
+    models.common.mamba1_step, the kernel's reference."""
     conv = staticmethod(mamba1_conv)
 
     @staticmethod
@@ -304,8 +317,14 @@ class _Mamba1:
 
     @staticmethod
     def decode(h, m, u, aux, mp, cfg, count, use_kernel):
-        return mamba1_step(h, m, u, *mamba1_step_inputs(u, mp, cfg), mp,
-                           count)
+        dt, Bm, Cm = mamba1_step_inputs(u, mp, cfg)
+        if not (use_kernel and mamba1_kernel.fits(h)):
+            return mamba1_step(h, m, u, dt, Bm, Cm, mp, count)
+        f32 = jnp.float32
+        y, h = mamba1_kernel.mamba1_step(
+            h, m, u[:, 0], dt[:, 0], Bm[:, 0], Cm[:, 0],
+            -jnp.exp(mp["A_log"].astype(f32)), count > 0)
+        return (y + mp["D"].astype(f32) * u[:, 0])[:, None], h
 
     @staticmethod
     def chunk(st0, u, aux, mp, cfg, count):
@@ -332,8 +351,8 @@ def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig,
     picks the mixer's pieces (_Mamba, _DeltaNet, _Mamba1); the order
     below is the same for all. use_kernel: the engine's kernel rule
     (ops/__init__.py); with it, and a state of whole tiles, the decode
-    rows' recurrence is its kind's kernel (ssm_step, gdn_step), one pass
-    over the state where it lies.
+    rows' recurrence is its kind's kernel (ssm_step, gdn_step,
+    mamba1_step), one pass over the state where it lies.
 
     The projections, the gate and the feed-forward run once over all N
     rows (the weights stream once); the conv and the recurrence run on

@@ -430,22 +430,158 @@ def test_the_recurrence_is_tied_to_the_loop(params, tokens, scripted):
     assert not np.asarray(drv.state.conv[:, :, 2]).any()
 
 
-def test_kernels_on_changes_nothing_of_this_kind(params, tokens, scripted):
-    """There is no Pallas call for a Mamba-1 step in this tree: with the
-    engine's kernels on, the recurrent layers' programs are the same
-    (the attention layer's paged read is the kernel's, interpreted
-    here), and logits and states are the `jnp` run's."""
+def test_kernels_on_takes_mamba1_step_for_the_decode_rows(params, tokens,
+                                                          scripted):
+    """The scripted run (chunks, filler, decode rows beside a chunk, a
+    reused slot), kernels on (interpreted here; the toy's state [.., 16,
+    128] is whole tiles of float32 and of bfloat16): the decode rows'
+    recurrence is the mamba1_step kernel, a chunk's is the scan over its
+    positions (no other kernel of a recurrent kind; the attention
+    layer's paged read is the kernel's too), and logits and states are
+    the `jnp` run's."""
+    from butterfly_tpu.ops import mamba1_step as mamba1_kernel
     from butterfly_tpu.ops import record_kernels
     out_j, drv_j, _ = scripted
+    for dtype in (jnp.float32, jnp.bfloat16):
+        assert mamba1_kernel.fits(drv_j.state.h.astype(dtype))
     with record_kernels({}) as calls:
         out_k, drv_k, _ = packed_driver.scripted_run(params, tokens, CFG,
                                                      use_kernel=True)
+    # a call site is a traced run's body, in the one program the driver
+    # steps
+    assert calls["mamba1_step:interpret"] >= 1
     assert not any(k.startswith(("ssm_step", "gdn_step")) for k in calls)
     assert "dense_fallback" not in calls
+    assert [(s, pos) for s, pos, _ in out_k] == \
+        [(s, pos) for s, pos, _ in out_j] and len(out_k) > 30
     for (s, pos, row_k), (_, _, row_j) in zip(out_k, out_j):
         assert err(row_k, row_j) < TOL, (s, pos)
-    np.testing.assert_allclose(np.asarray(drv_k.state.h),
-                               np.asarray(drv_j.state.h), atol=1e-5)
+    for got, ref_ in ((drv_k.state.h, drv_j.state.h),
+                      (drv_k.state.conv, drv_j.state.conv)):
+        got, ref_ = np.asarray(got), np.asarray(ref_)
+        assert np.abs(ref_).max() > 1e-3
+        assert np.abs(got - ref_).max() < 1e-5 * np.abs(ref_).max()
+    assert not np.asarray(drv_k.state.h[:, 2]).any()
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e chip (no chip attached: the TPU's compiler is
+    installed here); skipped where none can be described. The library
+    reads where to log when it loads: told not to, for the module's
+    tests only."""
+    import os
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    env = pytest.MonkeyPatch()
+    if "TPU_LOG_DIR" not in os.environ:
+        env.setenv("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        env.undo()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    env.undo()
+
+
+def test_the_decode_rows_step_compiles_for_the_chip_as_one_pass_in_place(
+        one_chip, monkeypatch):
+    """A mixed step's Mamba-1 layers at the cell's own shapes (128
+    decode rows beside one chunk of 32 at the published widths, the
+    state riding two scans as the engine's block carries it, donated),
+    kernels on, compiled for the TPU: the decode rows' recurrence is the
+    Mosaic call `mamba1_step` behind the chunk's write in place, nothing
+    copies the state, whole or a layer of it, nothing computes its
+    update a second time (`.remat`: PERF.md, PR 56), no result sits a
+    row a tile (`T(1,128)`: PERF.md, PR 53), and the call's HLO text,
+    which is all a device trace knows of it, is caught by the
+    benchmark's reader of the mixers (servebench/mamba1_peaks.py) and
+    not by the paged kernel's."""
+    import json
+    import re
+    import sys
+
+    from butterfly_tpu.cache.ssm_state import StateRows, advance_packed
+    from butterfly_tpu.models.common import layer_at
+    from servebench.mamba1_peaks import mamba1_patterns
+    from servebench.xplane import clean
+    sys.path.insert(0, str(ROOT / "tools"))
+    try:
+        from chip_kernels import state_copies
+    finally:
+        sys.path.remove(str(ROOT / "tools"))
+    # the published pattern's first four layers: mixers all
+    cfg = jamba2_3b().replace(
+        num_layers=4, layer_types=jamba2_3b().layer_types[:4],
+        dtype="bfloat16")
+    S, P, C, Lm = 128, 1, 32, cfg.num_ssm_layers
+    assert Lm == 4
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: init_params_by_leaf(
+        cfg, jax.random.PRNGKey(0))))
+    state = on_chip(jax.eval_shape(lambda: init_ssm_state(cfg, S)))
+    assert state.h.shape == (Lm, S, 16, 5120)
+    rows = on_chip(StateRows(
+        active=jnp.zeros((S,), bool), ok=jnp.zeros((S + P * C,), bool),
+        chunk_slot=jnp.zeros((P,), jnp.int32), chunk_ok=jnp.zeros((P,), bool),
+        chunk_pos=jnp.zeros((P, C), jnp.int32)))
+
+    def prog(x, state, params, rows):
+        def layer(carry, i):
+            x, st = carry
+            x, st, _ = advance_packed(
+                x, layer_at(params["layers"], i, cfg),
+                layer_at(params["mamba1"], i, cfg), st, i, rows, cfg,
+                use_kernel=True)
+            return (x, st), None
+
+        def step(carry, _):     # a block is steps of a run of layers
+            return jax.lax.scan(layer, carry, jnp.arange(Lm))[0], None
+        return jax.lax.scan(step, (x, state), jnp.arange(4))[0]
+
+    jax.clear_caches()          # no interpreted trace of the kernel is met
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        with jax.disable_jit(False):
+            compiled = jax.jit(prog, donate_argnums=1).lower(
+                on_chip(jnp.zeros((S + P * C, 1, cfg.hidden_size),
+                                  jnp.bfloat16)), state, params, rows
+            ).compile()
+    except Exception as e:  # the TPU library is one process's at a time
+        if "Mosaic" in str(e):      # the kernel refused is no skip
+            raise
+        pytest.skip(f"the TPU compiler could not be used here: {e}")
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    hlo = compiled.as_text()
+    assert state_copies(hlo, state.h) == []
+    whole = ",".join(map(str, state.h.shape))
+    assert not re.findall(rf"%\S*remat\S* = \(?\w+\[{whole}\]", hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256e6
+    calls = [line.strip() for line in hlo.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert calls and all(c.startswith("%mamba1_step") for c in calls)
+    # a row a tile: the call's own results, and any value of a row a
+    # slot and Di or 2 Di wide that XLA forms around it (a weight's one
+    # row [1, Di] is held so by right)
+    for c in calls:
+        assert "T(1,128)" not in c.split(" custom-call(")[0], c[:200]
+    assert not re.findall(
+        rf"= \w+\[(?:{S + P * C}|{S}),(?:1,)?(?:5120|10240)\]"
+        rf"\{{[^}}]*T\(1,128\)",
+        hlo)
+    config = json.loads((ROOT / "servebench" / "configs"
+                         / "jamba2-3b.json").read_text())
+    mixers = mamba1_patterns(config)
+    for name in map(clean, calls):  # as xplane.py names an operation
+        assert mixers.search(name) and "paged_att" not in name, name
 
 
 def test_a_prompt_of_70_as_chunks_of_32_32_6_is_the_loop(params, tokens):

@@ -13,9 +13,10 @@ import pytest
 
 from butterfly_tpu.core.config import RuntimeConfig, tiny
 from butterfly_tpu.cache.ssm_state import (
-    _DeltaNet, decode_rows_step, state_shapes)
+    _DeltaNet, _Mamba1, decode_rows_step, state_shapes)
 from butterfly_tpu.models.common import Model, attend
 from butterfly_tpu.ops import gdn_step as gdn_kernel
+from butterfly_tpu.ops import mamba1_step as mamba1_kernel
 from butterfly_tpu.ops import record_kernels
 from butterfly_tpu.ops.flash_attention import flash_attention
 from butterfly_tpu.ops.paged_attention import paged_attention
@@ -850,3 +851,126 @@ def test_gdn_step_is_the_jnp_step(case):
         beta = np.asarray(gdn_step_inputs(u, aux[1], aux[2], gp, cfg,
                                           count)[4])[count > 0]
         assert beta.min() < 0.2 and beta.max() > 1.8
+
+
+# -- mamba1_step: a decode row's Mamba-1 recurrence, one pass over the state --
+
+def _mamba1_case(dtype, inner=256, state=16, S=10, seed=0):
+    """A toy state of whole tiles (16 state indices down the sublanes,
+    `inner` channels on the lanes), three Mamba-1 layers, S slots of
+    which a fifth (slots 2 and 7) do not decode, and what mamba1_conv
+    would hand one decode step, over weights whose rates differ a
+    channel and a state index."""
+    cfg = tiny("jamba", mamba1_inner=inner, mamba1_state=state)
+    N, Di, R = cfg.mamba1_state, cfg.mamba1_inner, cfg.mamba1_dt_rank
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    h = jax.random.normal(
+        ks[0], (3,) + state_shapes(cfg, S)["h"][1:]).astype(dtype)
+    u = jax.random.normal(ks[1], (S, 1, Di))
+    mp = {"x_proj": 0.2 * jax.random.normal(ks[2], (Di, R + 2 * N)),
+          "dt_proj": 0.5 * jax.random.normal(ks[3], (R, Di)),
+          "dt_bias": jax.random.normal(ks[4], (Di,)),
+          "A_log": jax.random.uniform(ks[5], (N, Di), minval=-1.0,
+                                      maxval=1.0),
+          "D": jax.random.normal(ks[6], (Di,))}
+    count = (jnp.arange(S) % 5 != 2).astype(jnp.int32)
+    return cfg, h, u, mp, count
+
+
+@pytest.mark.parametrize("case", [
+    "bf16", "f32", "one-lane-tile", "dead-rows", "other-layers",
+    "layer-in-a-scan", "slots-that-do-not-divide-the-block",
+    "lanes-in-two-blocks", "a-state-that-does-not-fit"])
+def test_mamba1_step_is_the_jnp_step(case, monkeypatch):
+    """The kernel (interpreted) against models/common.py mamba1_step
+    (cache/ssm_state.py _Mamba1.decode, kernels on and off): the state
+    as stored in both dtypes, two lane tiles a slot and one, a fifth of
+    the slots not decoding, the layers the call does not name, the
+    layer's index traced inside a scan as the engine's runs do, a last
+    block of fewer slots than the others, a slot's channels in two
+    blocks of lanes (a state wider than a block's tiles), and a state
+    that is not whole tiles, which takes the `jnp` step."""
+    dtype = jnp.float32 if case == "f32" else jnp.bfloat16
+    cfg, h, u, mp, count = _mamba1_case(
+        dtype, 128 if case == "one-lane-tile" else 256)
+    # one bfloat16 ulp where a float32 sum in another order rounds the
+    # other way; float32 to its own rounding
+    tol = dict(rtol=8e-3, atol=1e-6) if dtype == jnp.bfloat16 \
+        else dict(rtol=2e-6, atol=1e-6)
+
+    def decode(h, m, use_kernel):
+        return _Mamba1.decode(h, m, u, (None,), mp, cfg, count, use_kernel)
+
+    if case == "a-state-that-does-not-fit":
+        # the cell's own geometry fits, eight slots a block of 1.3 MB
+        cell = jnp.zeros((26, 128, 16, 5120), jnp.bfloat16)
+        assert mamba1_kernel.fits(cell) and mamba1_kernel.fits(h)
+        assert mamba1_kernel.slots_per_block(cell) == 8
+        assert mamba1_kernel.lanes_per_block(cell) == 5120
+        assert mamba1_kernel.slots_per_block(h) == h.shape[1]
+        # 8 state indices are half a tile of bfloat16's 16 sublanes; 64
+        # channels half a row of lanes; one slot over a block's bytes
+        cfg, small, u, mp, count = _mamba1_case(dtype, state=8)
+        assert not mamba1_kernel.fits(small)
+        assert mamba1_kernel.fits(small.astype(jnp.float32))
+        assert not mamba1_kernel.fits(h[..., :64])
+        assert not mamba1_kernel.fits(jnp.zeros((1, 8, 16, 1 << 16)))
+        with pytest.raises(ValueError, match="mamba1_step cannot cut"):
+            mamba1_kernel.mamba1_step(small, 0, *(jnp.zeros((1, 1)),) * 6)
+        with record_kernels({}) as calls:   # the jnp step, asked or not
+            y_k, h_k = decode(small, jnp.int32(1), True)
+        assert not calls
+        y_j, h_j = decode(small, jnp.int32(1), False)
+        assert np.array_equal(np.asarray(y_k), np.asarray(y_j))
+        assert np.array_equal(np.asarray(h_k, np.float32),
+                              np.asarray(h_j, np.float32))
+        return
+    if case == "slots-that-do-not-divide-the-block":
+        # 10 slots in blocks of 8: the second block holds two
+        monkeypatch.setattr(mamba1_kernel, "BLOCK_BYTES",
+                            8 * h[0, 0].size * h.dtype.itemsize)
+        assert mamba1_kernel.slots_per_block(h) == 8 < h.shape[1]
+        jax.clear_caches()      # the wrapper's trace reads the constant
+    if case == "lanes-in-two-blocks":
+        monkeypatch.setattr(mamba1_kernel, "BLOCK_TILES", 1)
+        assert mamba1_kernel.lanes_per_block(h) == 128 < h.shape[3]
+        jax.clear_caches()
+    with record_kernels({}) as calls:
+        if case == "layer-in-a-scan":
+            def run(use_kernel):
+                def body(h, m):
+                    y, h = decode(h, m, use_kernel)
+                    return h, y
+                return jax.jit(
+                    lambda h: jax.lax.scan(body, h, jnp.arange(3)))(h)
+            (h_k, y_k), (h_j, y_j) = run(True), run(False)
+            assert not np.array_equal(np.asarray(h_k[0], np.float32),
+                                      np.asarray(h[0], np.float32))
+        else:
+            m = jnp.int32(1)
+            y_k, h_k = decode(h, m, True)
+            y_j, h_j = decode(h, m, False)
+    if case in ("slots-that-do-not-divide-the-block", "lanes-in-two-blocks"):
+        jax.clear_caches()
+    assert calls == {"mamba1_step:interpret": 1}
+    assert h_k.dtype == h.dtype and y_k.dtype == jnp.float32
+    assert y_k.shape == y_j.shape
+    # a row that does not decode is copied through and reads out the
+    # skip term alone (the `jnp` step reads its state out too; nothing
+    # takes that row's y)
+    live = np.asarray(count) > 0
+    assert 0 < (~live).sum() == len(live) // 5
+    np.testing.assert_allclose(np.asarray(y_k)[..., live, :, :],
+                               np.asarray(y_j)[..., live, :, :],
+                               rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(h_k, np.float32),
+                               np.asarray(h_j, np.float32), **tol)
+    if case == "dead-rows":     # bit for bit, where the live rows moved
+        for s in np.flatnonzero(~live):
+            assert np.array_equal(np.asarray(h_k[1, s]), np.asarray(h[1, s]))
+            np.testing.assert_array_equal(
+                np.asarray(y_k[s, 0]), np.asarray(mp["D"] * u[s, 0]))
+        assert not np.array_equal(np.asarray(h_k[1, 1]), np.asarray(h[1, 1]))
+    if case == "other-layers":
+        assert np.array_equal(np.asarray(h_k[0]), np.asarray(h[0]))
+        assert np.array_equal(np.asarray(h_k[2]), np.asarray(h[2]))

@@ -25,7 +25,10 @@ its compiled HLO must hold no copy of the state. `gdn_step`, the Gated
 DeltaNet decode step, runs at `olmohybrid7b.batch`'s (24 layers, 64
 slots, 15 groups of [96, 384], bfloat16: 1.8 GB) against
 models/common.py gdn_step the same way, and a layer-step of each is
-timed beside the bytes of one pass over a layer's state. `latent`, the absorbed
+timed beside the bytes of one pass over a layer's state. `mamba1_step`,
+the Mamba-1 decode step, runs at `jamba2-3b.rollout`'s (26 layers, 128
+slots of [16, 5120], bfloat16: 545 MB) against models/common.py
+mamba1_step the same way, timed the same way. `latent`, the absorbed
 read of a latent-attention model's cached rows (ops/latent_attention.py),
 runs at JoyAI-LLM-Flash's geometry (96 slots, 32 heads, rows of 576
 values in 640 lanes, a pool of 6 GB in six layers, a window of 256)
@@ -685,6 +688,33 @@ def run_ssm_step(name, small, want):
     return rec
 
 
+def time_layer_steps(rec, every_layer, h, rest):
+    """A layer-step's time of a recurrent kind's decode step, kernel and
+    `jnp`: every_layer(use_kernel) is a jitted scan of every layer of
+    the carried state h (donated) that returns (h, a sum a layer). Into
+    rec: the microseconds and GB/s of each beside the bytes of one pass
+    (a layer's state read and written), and whether the sums are finite.
+    Returns h as the last run left it."""
+    import jax
+    import numpy as np
+    layers = h.shape[0]
+    layer_mb = 2 * h[0].size * h.dtype.itemsize / 1e6
+    for label, use_kernel in (("kernel", True), ("jnp", False)):
+        fn = every_layer(use_kernel)
+        h, _ = fn(h, *rest)
+        jax.block_until_ready(h)
+        t1 = time.perf_counter()
+        for _ in range(10):
+            h, sums = fn(h, *rest)
+        jax.block_until_ready(h)
+        us = (time.perf_counter() - t1) / 10 / layers * 1e6
+        rec[label + "_us_a_layer_step"] = round(us, 1)
+        rec[label + "_gb_s"] = round(layer_mb / us * 1e3, 1)
+    rec["layer_pass_mb"] = round(layer_mb, 1)
+    rec["finite"] = bool(np.isfinite(np.asarray(sums)).all())
+    return h
+
+
 def run_gdn_step(name, small, want):
     """ops/gdn_step.py at `olmohybrid7b.batch`'s state geometry (24
     layers, 64 slots, 15 groups of [96, 384], bfloat16: 1.8 GB), the
@@ -761,20 +791,108 @@ def run_gdn_step(name, small, want):
         rec["max_err"] = round(max(errs), 5)
         rec["dead_rows_kept"] = bool(jnp.array_equal(h[m][dead], kept))
         rec["live_rows_moved"] = not bool(jnp.array_equal(h[m][live], was))
-        layer_mb = 2 * h[0].size * h.dtype.itemsize / 1e6
-        for label, use_kernel in (("kernel", True), ("jnp", False)):
-            fn = every_layer(use_kernel)
-            h, _ = fn(h, *args[1:])
-            jax.block_until_ready(h)
-            t1 = time.perf_counter()
-            for _ in range(10):
-                h, sums = fn(h, *args[1:])
-            jax.block_until_ready(h)
-            us = (time.perf_counter() - t1) / 10 / Ls * 1e6
-            rec[label + "_us_a_layer_step"] = round(us, 1)
-            rec[label + "_gb_s"] = round(layer_mb / us * 1e3, 1)
-        rec["layer_pass_mb"] = round(layer_mb, 1)
-        rec["finite"] = bool(np.isfinite(np.asarray(sums)).all())
+        h = time_layer_steps(rec, every_layer, h, args[1:])
+        rec["ok"] = bool(np.isfinite(max(errs)) and max(errs) < 3e-2
+                         and rec["dead_rows_kept"] and len(dead)
+                         and rec["live_rows_moved"]
+                         and not rec["state_copies"] and rec["finite"]
+                         and all(rec["hlo_has"].values()))
+    except Exception as e:  # a compiler refusal is the finding: record it
+        rec["error"] = f"{type(e).__name__}: {e}"[:1500]
+    return rec
+
+
+def run_mamba1_step(name, small, want):
+    """ops/mamba1_step.py at `jamba2-3b.rollout`'s state geometry (26
+    layers, 128 slots, [16, 5120], bfloat16: 545 MB), the layer index
+    traced, against the `jnp` step it replaces (models/common.py
+    mamba1_step: the update in place and a readout that forms it a
+    second time), both through cache/ssm_state.py _Mamba1.decode; a
+    fifth of the slots do not decode. Then a layer-step's time, kernel
+    and `jnp`, over a scan of every layer with the state carried and
+    donated as the engine's block does, beside the bytes of one pass (a
+    layer's state read and written). Where Mosaic's exponential is not
+    XLA's the two differ in the last place: `y_ulps_max` and
+    `y_differ_share` say by how much of float32 and where, and
+    `state_differ_share` what share of the stored values."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from butterfly_tpu.cache.ssm_state import _Mamba1, state_shapes
+    from butterfly_tpu.core.config import jamba2_3b, tiny
+
+    cfg = tiny("jamba", dtype="bfloat16") if small \
+        else jamba2_3b().replace(dtype="bfloat16")
+    Lm, S = (3, 12) if small else (cfg.num_ssm_layers, 128)
+    N, Di, R = cfg.mamba1_state, cfg.mamba1_inner, cfg.mamba1_dt_rank
+    shape = (Lm,) + state_shapes(cfg, S)["h"][1:]
+    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+    h = jax.random.normal(ks[0], shape, jnp.bfloat16)
+    u = jax.random.normal(ks[1], (S, 1, Di))
+    # rates as the family's seeded stack has them (models/common.py
+    # MAMBA1_SEEDS: A in 1-16, dt in .001-.1), so that a state is
+    # neither kept whole nor forgotten in one step
+    mp = {"x_proj": jax.random.normal(ks[2], (Di, R + 2 * N)) * Di ** -0.5,
+          "dt_proj": jax.random.normal(ks[3], (R, Di)) * R ** -0.5,
+          "dt_bias": jnp.log(jnp.expm1(jax.random.uniform(
+              ks[4], (Di,), minval=1e-3, maxval=0.1))),
+          "A_log": jnp.log(jax.random.uniform(ks[5], (N, Di), minval=1.,
+                                              maxval=16.)),
+          "D": jax.random.normal(ks[6], (Di,))}
+    count = (jax.random.uniform(ks[7], (S,)) > 0.2).astype(jnp.int32)
+    m = jnp.int32(Lm // 2)
+
+    def step(use_kernel):
+        return lambda h, m, u, mp, count: _Mamba1.decode(
+            h, m, u, (None,), mp, cfg, count, use_kernel)
+
+    def every_layer(use_kernel):
+        def run(h, u, mp, count):
+            def body(h, m):
+                y, h = step(use_kernel)(h, m, u, mp, count)
+                return h, y.sum()
+            return jax.lax.scan(body, h, jnp.arange(Lm))
+        return jax.jit(run, donate_argnums=0)
+
+    rec = {"name": name, "ok": False}
+    t0 = time.perf_counter()
+    try:
+        args = (m, u, mp, count)
+        want_y, want_h = jax.jit(step(False))(h, *args)
+        dead = np.flatnonzero(np.asarray(count) == 0)
+        live = int(np.flatnonzero(np.asarray(count) > 0)[0])
+        kept, was = h[m][dead], h[m][live]
+        compiled = jax.jit(step(True), donate_argnums=0).lower(
+            h, *args).compile()
+        hlo = compiled.as_text()
+        y, h = jax.block_until_ready(compiled(h, *args))    # h is consumed
+        rec["compile_run_s"] = round(time.perf_counter() - t0, 2)
+        rec["hlo_has"] = {w: w in hlo for w in want}
+        rec["state_copies"] = state_copies(hlo, h)
+
+        @jax.jit        # fused reductions: no float32 copy of the state
+        def read(y, want_y, h, want_h):
+            decodes = (count > 0)[:, None, None]
+            a, b = jnp.where(decodes, y, 0), jnp.where(decodes, want_y, 0)
+            ulp = jnp.abs(a - b) / jnp.maximum(
+                jnp.abs(b) * 2.0 ** -23, 2.0 ** -126)
+            hf, wf = h.astype(jnp.float32), want_h.astype(jnp.float32)
+            return (jnp.max(jnp.abs(a - b) / (1 + jnp.abs(b))),
+                    jnp.max(jnp.abs(hf - wf) / (1 + jnp.abs(wf))),
+                    jnp.max(ulp), jnp.mean(a != b), jnp.mean(h[m] != want_h[m]))
+
+        # a row that does not decode reads out zero from the kernel and
+        # its state from the `jnp` step: nothing takes it
+        *errs, ulps, y_share, h_share = map(float, read(y, want_y, h, want_h))
+        del want_h
+        rec["max_err"] = round(max(errs), 5)
+        rec["y_ulps_max"] = round(ulps, 1)
+        rec["y_differ_share"] = round(y_share, 6)
+        rec["state_differ_share"] = round(h_share, 6)
+        rec["dead_rows_kept"] = bool(jnp.array_equal(h[m][dead], kept))
+        rec["live_rows_moved"] = not bool(jnp.array_equal(h[m][live], was))
+        h = time_layer_steps(rec, every_layer, h, args[1:])
         rec["ok"] = bool(np.isfinite(max(errs)) and max(errs) < 3e-2
                          and rec["dead_rows_kept"] and len(dead)
                          and rec["live_rows_moved"]
@@ -1636,6 +1754,8 @@ def main() -> int:
         results.append(run_ssm_step("ssm_step", args.small, want))
     if wanted("gdn_step"):
         results.append(run_gdn_step("gdn_step", args.small, want))
+    if wanted("mamba1_step"):
+        results.append(run_mamba1_step("mamba1_step", args.small, want))
     results += [run_latent(n, args.small, want)
                 for n in LATENT_CELLS if wanted(n)]
     results += [run_paged_cell(n, args.small, want)
