@@ -286,9 +286,8 @@ def write_paged_layer(k_pages: jax.Array, v_pages: jax.Array,
     the chunk width C — a decode-phase lane writes its one token at
     start=length with the chunk tail masked inactive, a prefill-phase
     lane writes its next C prompt tokens at start=cursor. Both reduce
-    to exactly this scatter; no new write primitive exists for the
-    fused path, which is why fused and alternating pools are
-    bit-identical.
+    to exactly this scatter; no other write primitive exists for the
+    fused path.
     """
     Pp, Kv, page, H = k_pages.shape
     B, T = k.shape[0], k.shape[1]
@@ -725,9 +724,10 @@ def flush_paged_window(cache: PagedKVCache, window: KVWindow, win_len):
 #: what paged_forward and paged_forward_window are to a model whose
 #: streams hold a recurrent state, or whose layers run as runs of two
 #: feed-forward shapes over a latent pool: the packed mixed step
-#: carries both
-ALTERNATING = ("the alternating prefill/decode path (paged_forward: "
-               "mixed_dispatch off, the static scheduler)")
+#: carries both, and what still calls these two (the speculative
+#: block's verify, tools that read a lane-wide step) does not
+LANE_WIDE = ("the lane-wide forward (paged_forward, paged_forward_window: "
+             "the speculative block's verify)")
 
 
 def _settled(*view):
@@ -1377,8 +1377,8 @@ def paged_forward(params, cfg: ModelConfig, tokens: jax.Array,
     that index — logits come back [B,1,V] (models.common.forward docs:
     the full-T head dominates prefill memory at LLM vocab sizes).
     """
-    ssm_unsupported(cfg, ALTERNATING)
-    latent_unsupported(cfg, ALTERNATING)
+    ssm_unsupported(cfg, LANE_WIDE)
+    latent_unsupported(cfg, LANE_WIDE)
     B, T = tokens.shape
     if positions is None:
         positions = cache.lengths[:, None] + jnp.arange(T)[None, :]
@@ -1436,8 +1436,8 @@ def paged_forward_window(params, cfg: ModelConfig, tokens: jax.Array,
     CARRY, whole, and is written and read by the layer's index like the
     pool (the comment above KVWindow says why not as xs/ys).
     """
-    ssm_unsupported(cfg, ALTERNATING)
-    latent_unsupported(cfg, ALTERNATING)
+    ssm_unsupported(cfg, LANE_WIDE)
+    latent_unsupported(cfg, LANE_WIDE)
     B, T = tokens.shape
     if active is None:
         active = jnp.ones((B,), bool)

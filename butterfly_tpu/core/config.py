@@ -1067,32 +1067,22 @@ class RuntimeConfig:
 
     max_batch_size: int = 8
     max_seq_len: int = 2048
-    prefill_chunk: int = 512          # max prefill tokens per scheduler tick;
-                                      # long prompts continue across ticks.
-                                      # NB: chunks pad to the engine's
-                                      # 16-token bucket floor — values < 16
-                                      # add compute without cutting latency
-    prefill_max_batch: int = 8        # max waiting requests gang-admitted
-                                      # into ONE batched [B, Tbucket]
-                                      # prefill dispatch per scheduler
-                                      # tick (sched/scheduler.py group
-                                      # admission). B is bucketed to the
-                                      # next power of two (clamped here)
-                                      # so at most log2(this)+1 batch
-                                      # shapes ever compile per T bucket
-    mixed_dispatch: bool = True       # fused mixed dispatch: each tick's
-                                      # jitted block carries BOTH phases —
-                                      # decode/spec slots advance tokens
-                                      # while freshly admitted slots chew
-                                      # budget-bounded prefill chunks in
-                                      # the same scan (per-slot phase
-                                      # masks + chunk cursors riding the
-                                      # carry), retiring admission-cause
-                                      # drain barriers as a class. False
-                                      # = the alternating prefill/decode
-                                      # path, the parity reference.
-                                      # Continuous scheduler only
-    prefill_inline_budget: int = 32   # mixed dispatch: max prefill
+    prefill_chunk: int = 512          # upper bound on the width C of a
+                                      # prompt chunk in a mixed block's
+                                      # step (C is the smaller of this
+                                      # and prefill_inline_budget), and
+                                      # each shard's share of a
+                                      # seq-parallel lane dispatch; long
+                                      # prompts continue across steps
+                                      # and ticks
+    prefill_max_batch: int = 8        # how many one-token requests the
+                                      # server's warm-up submits as a
+                                      # burst before it listens
+                                      # (serve/server.py run_server):
+                                      # nothing else reads it since the
+                                      # batched prefill dispatch it
+                                      # capped went (ROADMAP C5)
+    prefill_inline_budget: int = 32   # max prefill
                                       # tokens chewed per scan STEP
                                       # across all prefilling slots —
                                       # the ITL-tail knob. Each
@@ -1126,8 +1116,6 @@ class RuntimeConfig:
                                       # worth of work per dispatch
     page_size: int = 16               # paged-KV tokens per block
     num_pages: int = 0                # 0 => derive from max_batch/max_seq
-    scheduler: str = "continuous"     # "continuous" (chunked-prefill/decode
-                                      # interleave) | "static" (drain batches)
     max_queue: int = 256
     decode_steps_per_tick: int = 1    # fused decode block width: the
                                       # scheduler runs this many decode
@@ -1146,22 +1134,6 @@ class RuntimeConfig:
     prefix_caching: bool = False      # content-hash KV page reuse across
                                       # requests (cache/prefix.py): shared
                                       # prompt prefixes skip prefill entirely
-    prefill_flash_warm: bool = True   # warm-prefix flash prefill: the
-                                      # serving engine's WARM prefill
-                                      # program (chunk continuations,
-                                      # prefix-cache resumes) compiles
-                                      # with the flash kernel attending
-                                      # cached prefix + fresh chunk,
-                                      # instead of the dense O(T*S)
-                                      # gather fallback; also lets a
-                                      # prefill gang mix fresh and warm
-                                      # members in one dispatch (the
-                                      # all-or-nothing freshness
-                                      # downgrade is gone). Only
-                                      # engages where kernels do
-                                      # (use_kernels, i.e. TPU by
-                                      # default); False = dense warm
-                                      # prefill, the parity reference
     kv_quant: str = "none"            # "int8" stores the contiguous KV
                                       # cache as int8 codes + per-vector
                                       # scales: half the HBM bytes in the
@@ -1216,9 +1188,7 @@ class RuntimeConfig:
                                       # accept/rollback computed on
                                       # device inside the speculative
                                       # mixed block
-                                      # (engine._mixed_spec_scan; needs
-                                      # mixed_dispatch and the
-                                      # continuous scheduler).
+                                      # (engine._mixed_spec_scan).
                                       # Sampling-safe: temperature /
                                       # top-k / top-p requests get the
                                       # exact rejection-sampling

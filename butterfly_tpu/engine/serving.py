@@ -1,48 +1,44 @@
 """Slot-based serving engine over the paged KV cache.
 
-The continuous-batching scheduler (sched/scheduler.py) drives two jitted
-device programs, both static-shape so batch composition changes never
-recompile (SURVEY.md §7 "hard parts"):
+The continuous-batching scheduler (sched/scheduler.py) drives ONE kind
+of jitted device program a tick, static-shape so batch composition
+changes never recompile (SURVEY.md §7 "hard parts"):
 
-* `prefill_batch`: B requests' padded prompt chunks [B, Tbucket] against
-  the shared page pool as ONE dispatch, each row targeting only that
-  request's block-table row (per-row start/length masking — the same
-  write/mask machinery paged_forward uses for a single slot). Chunk
-  lengths bucket to the next power of two and B buckets to the next
-  power of two clamped at runtime.prefill_max_batch, so at most
-  (#B-buckets x #T-buckets) prefill programs ever compile per
-  fresh/warm flavor; the single-request path is simply B=1 (same jit
-  cache, same [1, Tbucket] programs as before).
-* `decode_active`: one token for ALL slots [S,1]; inactive slots are
-  masked via `active` (their lengths don't advance, their writes land on
-  the null page). Sampling is vectorized with per-slot temperature so
-  requests with different sampling settings batch together.
-* `decode_block`: k chained decode iterations inside ONE jitted
-  `lax.scan` (`_decode_scan`) — one host dispatch and one stacked fetch
-  per scheduler tick instead of k. Per-step RNG keys are derived on
-  device (`fold_in`), and per-slot stop ids + remaining-token budgets
-  ride the carry so a slot that finishes mid-block goes dead on device
-  (no further writes, no length growth, frozen tokens). The returned
-  final-token carry is the dispatch-ahead contract: the scheduler
-  chains block t+1 on it BEFORE draining block t (up to
-  RuntimeConfig.inflight_blocks undrained), so the device runs blocks
-  back-to-back while the host schedules; a dead slot's carry stays
-  frozen at its stop id, which starts it dead in every later block.
+* `mixed_block` (ISSUE 18; packed since ISSUE 29): k chained steps
+  inside ONE jitted `lax.scan` (`_packed_scan`) — one host dispatch and
+  one stacked fetch per scheduler tick instead of k. Each step carries
+  BOTH phases: a decode-phase slot advances one token (one row) while
+  up to P slots in prefill phase chew a C-token chunk of their prompt,
+  the S + P*C rows packed into one forward, with the first token
+  sampled on device at the step a slot's prefill completes. Phase is a
+  pure function of the per-slot chunk cursor riding the carry
+  (`cursor < plen`), so admission is a host-side cursor/buffer edit
+  between dispatches, never a drain barrier or a dispatch of its own.
+  Inactive slots are masked (their lengths don't advance, their writes
+  land on the null page). Sampling is vectorized with per-slot
+  temperature so requests with different sampling settings batch
+  together; per-step RNG keys are derived on device (`fold_in`), and
+  per-slot stop ids + remaining-token budgets ride the carry so a slot
+  that finishes mid-block goes dead on device (no further writes, no
+  length growth, frozen tokens). The returned final-token carry is the
+  dispatch-ahead contract: the scheduler chains block t+1 on it BEFORE
+  draining block t (up to RuntimeConfig.inflight_blocks undrained), so
+  the device runs blocks back-to-back while the host schedules; a dead
+  slot's carry stays frozen at its stop id, which starts it dead in
+  every later block. With no prompt in flight the scheduler asks for
+  P == 0: the same body without a chunk, named `bf_decode_block[_win]`.
+* `mixed_spec_block` (speculative_gamma > 0): the lane-wide twin whose
+  decode lanes run draft/verify/accept rounds (`_mixed_spec_scan[_win]`
+  over cache/paged.py paged_forward[_window]).
 
-* `mixed_block` (ISSUE 18): the decode block generalized to carry
-  BOTH phases — each scan step, decode-phase slots advance one token
-  (or one speculative round) while prefill-phase slots chew a C-token
-  chunk of their prompt through the warm multi-token path, with the
-  first token sampled on device at the step a slot's prefill completes.
-  Phase is a pure function of the per-slot chunk cursor riding the
-  carry (`cursor < plen`), so admission becomes a host-side cursor/
-  buffer edit between dispatches instead of a drain barrier + separate
-  prefill dispatch (the admission-cause barrier class this retires).
+Beside them: `sp_prefill_chunk`, the long-prompt seq-parallel lane's
+chunk program, and the write-combined window's flush.
 
 Parity contract: tests/test_sched.py and tests/test_serving_mesh.py check
 token-for-token equality with InferenceEngine.generate on the contiguous
 cache (single-device and meshed respectively); tests/test_mixed_dispatch.py
-pins the mixed block token-for-token against the alternating path.
+holds the packed block's tokens to that engine's and to each request
+served alone.
 """
 from __future__ import annotations
 
@@ -57,8 +53,7 @@ from jax import lax
 from jax.profiler import TraceAnnotation
 
 from butterfly_tpu.cache.paged import (
-    ALTERNATING, KVWindow, PagedKVCache, flush_paged_window, init_kv_window,
-    pool_leaves,
+    KVWindow, PagedKVCache, flush_paged_window, init_kv_window,
     init_paged_cache, paged_forward, paged_forward_packed,
     paged_forward_window)
 from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
@@ -109,20 +104,6 @@ def bucket_len(n: int, lo: int = 16, hi: Optional[int] = None) -> int:
     if hi is not None and b > hi:
         b = hi
     return b
-
-
-def bucket_batch(n: int, hi: int) -> int:
-    """Next power-of-two batch bucket >= n, clamped to hi.
-
-    n > hi returns n exactly (still a static shape — the caller asked
-    for a wider gang than the configured cap, so pay one extra program
-    rather than refuse)."""
-    if n >= hi:
-        return n
-    b = 1
-    while b < n:
-        b *= 2
-    return min(b, hi)
 
 
 @jax.named_scope("sample")
@@ -186,8 +167,6 @@ class ServingEngine:
         if rt.prefix_caching:
             latent_unsupported(self.cfg, "prefix caching (and the host KV "
                                          "tier behind it)")
-        if not rt.mixed_dispatch or rt.scheduler != "continuous":
-            latent_unsupported(self.cfg, ALTERNATING)
 
     def __init__(self, model: Model, params,
                  runtime: Optional[RuntimeConfig] = None, mesh=None,
@@ -240,9 +219,6 @@ class ServingEngine:
             ssm_unsupported(self.cfg, "prefix caching (and the host KV "
                                       "tier behind it: a state has no "
                                       "pages to key by token hash)")
-        if not self.runtime.mixed_dispatch \
-                or self.runtime.scheduler != "continuous":
-            ssm_unsupported(self.cfg, ALTERNATING)
         # and what adds a sublayer's output to ONE stream in a layer body
         # of its own refuses a model of n residual streams (hc_mult).
         # Speculation is not among them: its verify forward is
@@ -254,17 +230,6 @@ class ServingEngine:
                            ("seq", "the sequence-parallel prefill lane")):
             if mesh is not None and mesh.shape.get(axis, 1) > 1:
                 streams_unsupported(self.cfg, what)
-        # speculation has one path, the speculative mixed block: nothing
-        # falls back in silence to a scheduler that cannot run it
-        if self.runtime.speculative_gamma > 0 and (
-                not self.runtime.mixed_dispatch
-                or self.runtime.scheduler != "continuous"):
-            raise ValueError(
-                f"speculative_gamma={self.runtime.speculative_gamma} needs "
-                f"mixed_dispatch=True and scheduler='continuous' (got "
-                f"mixed_dispatch={self.runtime.mixed_dispatch}, scheduler="
-                f"{self.runtime.scheduler!r}): the speculative mixed block "
-                f"is the one speculative path")
         if use_kernels is None:
             # on everywhere but the CPU backend (ops/__init__.py); under
             # a mesh the call sites go through ops/*_sharded (shard_map
@@ -328,32 +293,8 @@ class ServingEngine:
         else:
             fwd = paged_forward
             self._packed_fwd = paged_forward_packed
-        prefill_cfg = self.cfg.replace(attn_impl="flash") \
-            if use_kernels else self.cfg
-        # Two prefill programs: fresh (start==0, flash over the chunk
-        # alone) and warm (chunk continuation / prefix-hit resume).
-        # With runtime.prefill_flash_warm (default) the warm program
-        # compiles with the flash cfg too — the kernel attends cached
-        # prefix + fresh chunk (ISSUE 13) — else it keeps the dense
-        # gather fallback (the parity reference).
-        warm_cfg = prefill_cfg if self.runtime.prefill_flash_warm \
-            else self.cfg
-        self._prefill = jax.jit(
-            named(partial(_prefill_slot, prefill_cfg, True, fwd),
-                  "bf_prefill"),
-            donate_argnums=(2,))
-        self._prefill_warm = jax.jit(
-            named(partial(_prefill_slot, warm_cfg, False, fwd),
-                  "bf_prefill_warm"),
-            donate_argnums=(2,))
-        self._decode = jax.jit(
-            named(partial(_decode_all, self.cfg, fwd,
-                          use_kernel=use_kernels), "bf_decode_step"),
-            static_argnums=(5, 6), donate_argnums=(2,))
-        # Fused decode blocks: one jitted program per block width k
-        # (_decode_scan — k is a static scan length). Built lazily; a
-        # deployment runs ONE decode_steps_per_tick, so this compiles
-        # once in practice.
+        # the speculative pair's verify forward (paged_forward, or its
+        # stage-pipelined twin)
         self._fwd = fwd
         self._use_kernels = use_kernels
         # what _launch last called and how many calls it has made: the
@@ -370,7 +311,6 @@ class ServingEngine:
         # The scheduler fetches it with the block's tokens.
         self.last_expert_load = None
         self.blocks_launched = 0
-        self._decode_blocks: Dict[int, object] = {}
         # Write-combined KV decode window (RuntimeConfig.kv_write_combine,
         # default on): fused decode/spec blocks stage fresh K/V into an
         # engine-held KVWindow riding the scan carry — the page pool is
@@ -387,7 +327,6 @@ class ServingEngine:
         self._win_len = None       # [S] staged count; None = seed zeros
         self._win_dirty = False    # staged entries not yet flushed
         self._win_hwm = 0          # host upper bound on staged entries
-        self._decode_win_blocks: Dict[int, object] = {}
         # Mixed blocks: the decode scan with P prefill chunks of C
         # tokens packed beside its S rows, keyed (k, C, P) — static
         # shapes; the scheduler asks for P == 0 whenever no slot is in
@@ -441,27 +380,6 @@ class ServingEngine:
     @property
     def num_slots(self) -> int:
         return self.runtime.max_batch_size
-
-    @property
-    def warm_prefill_flash(self) -> bool:
-        """True when the warm prefill program attends through the flash
-        kernel (cached prefix + fresh chunk) rather than the dense
-        gather fallback — kernels on AND runtime.prefill_flash_warm."""
-        return self._use_kernels and bool(self.runtime.prefill_flash_warm)
-
-    @property
-    def prefill_gang_split_fresh(self) -> bool:
-        """Must the scheduler split prefill gangs by freshness? Only
-        with prefill_flash_warm OFF — the seed behavior, where the warm
-        program was dense and mixing would drag cold members off the
-        flash path (or, kernels off, where splitting was merely
-        harmless). With warm-prefix flash on, a mixed gang rides ONE
-        dispatch and loses nothing: wherever kernels run the warm
-        program is flash too (fresh members ride with prefix_len 0),
-        and where they don't, both flavors compile the same dense
-        attention. The all-or-nothing freshness downgrade — a warm
-        member forcing the whole dispatch dense — is gone (ISSUE 13)."""
-        return not bool(self.runtime.prefill_flash_warm)
 
     @property
     def supports_seq_parallel(self) -> bool:
@@ -573,92 +491,12 @@ class ServingEngine:
         self._win_hwm = 0
         self._win_len = None
 
-    def prefill_slot(self, slot: int, prompt: list[int]) -> jax.Array:
-        """Run one request's whole prompt; returns last-token logits [V]."""
-        return self.prefill_chunk(slot, prompt, 0)
-
-    def prefill_chunk(self, slot: int, tokens: list[int],
-                      start: int) -> jax.Array:
-        """Run one chunk of one request's prompt; returns the chunk's
-        last-token logits [V]. The B=1 case of prefill_batch — same jit
-        cache, same [1, Tbucket] programs."""
-        return self.prefill_batch([slot], [tokens], [start])[0]
-
-    def prefill_batch(self, slots: list[int], chunks: list[list[int]],
-                      starts: list[int]) -> jax.Array:
-        """Run one prompt chunk for EACH of B requests as ONE jitted
-        [B, Tbucket] dispatch; returns last-position logits [B, V]
-        (device-resident — row i is member i's next-token distribution,
-        so every gang member's first token can sample from the same
-        dispatch).
-
-        Member i's chunk occupies absolute positions
-        starts[i]..starts[i]+len(chunks[i])-1 of its slot's pages; rows
-        are individually length-masked (paged_forward's per-slot
-        start/length machinery), so members with different chunk lengths
-        share a dispatch. B pads to the next power-of-two bucket
-        (clamped at runtime.prefill_max_batch); padding rows carry a
-        null-page table row, so their writes land on the null page and
-        their logits are discarded. An all-fresh gang (every start==0)
-        dispatches the fresh program (flash over the chunks alone); any
-        warm member routes the gang through the warm program — with
-        prefill_flash_warm that program is flash too (cached prefix +
-        fresh chunk, per-row start masking, so fresh members simply ride
-        with prefix_len 0) and gangs may mix freely; only when the warm
-        program is dense while kernels are on does the scheduler still
-        split gangs by freshness (prefill_gang_split_fresh).
-        """
-        B = len(slots)
-        T = bucket_len(max(len(c) for c in chunks), hi=self.cache.max_seq)
-        Bb = bucket_batch(B, max(1, min(self.runtime.prefill_max_batch,
-                                        self.num_slots)))
-        buf = np.zeros((Bb, T), np.int32)
-        # padding rows: 1 token (a real last_index), null table row
-        lens = np.ones((Bb,), np.int32)
-        sts = np.zeros((Bb,), np.int32)
-        rows = np.full((Bb, self.cache.page_table.shape[1]),
-                       self.cache.null_page, np.int32)
-        for i, (slot, toks, start) in enumerate(zip(slots, chunks, starts)):
-            buf[i, :len(toks)] = toks
-            lens[i] = len(toks)
-            sts[i] = start
-            # host mirror is authoritative (host is the only writer):
-            # no device gather of the slot's table row needed
-            rows[i] = self._host_table[slot]
-        # a prefill writes the pool at each slot's FLUSHED length, so
-        # staged window entries must land first (the scheduler barriers
-        # before admission anyway — this is the engine-level backstop)
-        if self._win_dirty:
-            self.flush_kv_window()
-        fresh = all(s == 0 for s in starts)
-        prog = self._prefill if fresh else self._prefill_warm
-        if self.tracer is not None:
-            self.tracer.event(None, "engine.prefill_dispatch",
-                              slots=list(slots), batch=B, batch_bucket=Bb,
-                              tokens=int(sum(len(c) for c in chunks)),
-                              bucket=T, fresh=fresh)
-        buf, rows, lens_dev, sts_dev = self._put(
-            (buf, None), (rows, None), (lens, None), (sts, None))
-        with self._mesh_ctx():
-            # pools are donated (scatters land in place); the table rows
-            # ride separately so the donation set has no unaliasable
-            # leaves (the rows have no matching output)
-            logits, pools = self._launch(
-                prog, buf.shape[0] * buf.shape[1], self.params, buf,
-                pool_leaves(self.cache, absent=True), rows, lens_dev,
-                sts_dev)
-            new_lens = jnp.asarray(sts[:B] + lens[:B])
-            self.cache = pool_leaves(self.cache, pools)._replace(
-                lengths=self.cache.lengths.at[
-                    np.asarray(slots, np.int32)].set(new_lens))
-        return logits[:B]
-
     # -- seq-parallel long-prompt prefill (ISSUE 20 move 3) -----------------
 
     def _sp_chunk_prog(self, C: int):
         """Jitted seq-parallel chunk-prefill program for bucket width C.
 
-        One program per chunk bucket (like _decode_blocks per k): gather
+        One program per chunk bucket (like _mixed_blocks per k): gather
         the slot's flushed pool prefix for ALL layers, run the chunk
         seq-sharded through sp_chunk_body (ring over the fresh chunk,
         flash-stats merge with the replicated prefix), then scatter the
@@ -763,13 +601,13 @@ class ServingEngine:
         chunk's last-token logits [V] (device-resident).
 
         The scheduler's long-prompt lane (seq_parallel_threshold)
-        calls this instead of prefill_chunk when the prompt outgrows
-        what a single-device chunk program should chew: the chunk is
+        calls this when the prompt outgrows
+        what a block's chunks on one device should chew: the chunk is
         sharded over the seq axis (each shard computes C/N tokens of
         qkv + ring attention), the already-flushed pool prefix is
         attended via the same flash-stats merge, and the chunk's K/V
-        lands in the slot's pages — identical pool state to the dense
-        path, so prefix registry/export/eviction all apply.
+        lands in the slot's pages — the pool state a block's chunks
+        leave, so prefix registry/export/eviction all apply.
         """
         N = self.sp_degree
         C = bucket_len(len(tokens), hi=self.cache.max_seq)
@@ -796,115 +634,6 @@ class ServingEngine:
                 lengths=self.cache.lengths.at[slot].set(
                     start + len(tokens)))
         return logits
-
-    def decode_active(self, tokens: np.ndarray, active: np.ndarray,
-                      temps: np.ndarray, key: jax.Array
-                      ) -> Tuple[np.ndarray, jax.Array]:
-        """One decode step for every slot; returns (next tokens [S], logits)."""
-        nxt, logits = self.decode_active_async(tokens, active, temps, key)
-        return np.asarray(nxt), logits
-
-    def decode_active_async(self, tokens, active: np.ndarray,
-                            temps: np.ndarray, key: jax.Array
-                            ) -> Tuple[jax.Array, jax.Array]:
-        """Dispatch one decode step WITHOUT host synchronization.
-
-        Returns the device-resident next-token vector [S]; feeding it
-        back as `tokens` of the next call chains steps entirely on the
-        device, so the host can dispatch step N+1 before reading step
-        N's tokens (sched/scheduler.py overlap — VERDICT r4 item 5:
-        the synchronous per-token readback made ITL host-bound at small
-        batch). `tokens` may be a host array or a previous call's
-        device vector.
-        """
-        # the single-step path writes the pool per token; flush any
-        # staged window first so lengths/pool state line up
-        if self._win_dirty:
-            self.flush_kv_window()
-        tokens, active, temps = self._put(
-            (tokens, None), (active, None), (temps, None))
-        with self._mesh_ctx():
-            nxt, logits, cache = self._launch(
-                self._decode, self.num_slots, self.params, tokens,
-                self.cache, active,
-                temps, self.runtime_top_k, self.runtime_top_p, key)
-        self.cache = cache
-        return nxt, logits
-
-    def _decode_block_prog(self, k: int):
-        prog = self._decode_blocks.get(k)
-        if prog is None:
-            prog = jax.jit(
-                named(partial(_decode_scan, self.cfg, self._fwd, k,
-                              use_kernel=self._use_kernels),
-                      "bf_decode_block"),
-                static_argnums=(7, 8), donate_argnums=(2,))
-            self._decode_blocks[k] = prog
-        return prog
-
-    def _decode_block_win_prog(self, k: int):
-        """Windowed twin of _decode_block_prog: the cache, the window
-        buffer, and the staged-count vector are all donated — the pool
-        passes through unmodified (aliased), the window carries the
-        staged K/V to the next dispatch or flush."""
-        prog = self._decode_win_blocks.get(k)
-        if prog is None:
-            prog = jax.jit(
-                named(partial(_decode_scan_win, self.cfg, k,
-                              use_kernel=self._use_kernels),
-                      "bf_decode_block_win"),
-                static_argnums=(9, 10), donate_argnums=(2, 3, 4))
-            self._decode_win_blocks[k] = prog
-        return prog
-
-    def decode_block_async(self, tokens, active: np.ndarray,
-                           temps: np.ndarray, stops: np.ndarray,
-                           budgets: np.ndarray, key: jax.Array,
-                           k: int) -> Tuple[jax.Array, jax.Array]:
-        """Dispatch ONE fused k-step decode block, no host sync.
-
-        k chained decode iterations run inside a single jitted lax.scan
-        (_decode_scan): one dispatch, per-step keys derived on device,
-        donated KV pools riding the carry. `stops` [S] holds each
-        slot's EOS id (-1 = none) and `budgets` [S] its remaining-token
-        allowance; a slot that emits its stop token or spends its
-        budget mid-block goes dead ON DEVICE — lengths stop advancing,
-        writes land on the null page — instead of generating garbage
-        the host must discard. Returns (block [k, S], final [S]), both
-        device-resident: the stacked per-step tokens for the
-        scheduler's stacked drain, and the final token vector for
-        chaining the next dispatch (the same contract
-        decode_active_async's return value carries).
-
-        kv_write_combine: the block stages its K/V into the engine-held
-        window (pool read-only inside the scan) and the scheduler's
-        next drain flushes it — one pool scatter per drain instead of
-        k x L per block. Token outputs are byte-identical either way.
-        """
-        tokens, active, temps, stops, budgets = self._put(
-            (tokens, None), (active, bool), (temps, None),
-            (stops, jnp.int32), (budgets, jnp.int32))
-        if self._window_mode:
-            self._ensure_window(k)
-            with self._mesh_ctx():
-                block, final, cache, window, wlen = self._launch(
-                    self._decode_block_win_prog(k), self.num_slots,
-                    self.params, tokens, self.cache,
-                    self._kv_window, self._win_len,
-                    active, temps, stops, budgets,
-                    self.runtime_top_k, self.runtime_top_p, key)
-            self.cache, self._kv_window, self._win_len = cache, window, wlen
-            self._win_dirty = True
-            self._win_hwm += k
-            return block, final
-        with self._mesh_ctx():
-            block, final, cache = self._launch(
-                self._decode_block_prog(k), self.num_slots,
-                self.params, tokens, self.cache,
-                active, temps, stops, budgets,
-                self.runtime_top_k, self.runtime_top_p, key)
-        self.cache = cache
-        return block, final
 
     @property
     def spec_emit_width(self) -> int:
@@ -958,10 +687,12 @@ class ServingEngine:
         completion), and the chain/cursor carries for the next
         dispatch.
 
-        kv_write_combine: stages through the engine window like
-        decode_block_async — worst case k * C staged entries (a
+        kv_write_combine: the block stages its K/V into the engine-held
+        window (pool read-only inside the scan) and the scheduler's
+        next drain flushes it — one pool scatter per drain instead of
+        k x L per block; worst case k * C staged entries (a
         prefilling slot advances win_len by its real chunk length), k
-        with no chunk."""
+        with no chunk. Token outputs are byte-identical either way."""
         tokens, plen, active, temps, stops, budgets = self._put(
             (tokens, None), (plen, jnp.int32), (active, bool),
             (temps, None), (stops, jnp.int32), (budgets, jnp.int32))
@@ -1080,7 +811,7 @@ class ServingEngine:
         the scan (rejection-sampling correction at temperature > 0,
         the `_accept_drafts` greedy semantics at 0), and per-slot stop
         ids + remaining budgets kill finished slots on device exactly
-        like the decode block. The history carry doubles as the prompt
+        like the plain block. The history carry doubles as the prompt
         buffer (a freshly admitted slot's hist row holds its full
         prompt, hist_len == prompt length); `cursor` is the donated
         chunk-cursor carry and `plen` the per-slot prompt lengths.
@@ -1139,132 +870,6 @@ class ServingEngine:
         return self.runtime.top_p
 
 
-def _prefill_slot(cfg: ModelConfig, fresh: bool, fwd, params, tokens,
-                  pools, table_rows, true_len, start):
-    """[B,T] prompt chunks against B slots' table rows; pool-wide scatter.
-
-    `pools` is the cache's pool tensors, all five places
-    (cache/paged.py pool_leaves; donated —
-    scatters land in place), paired with the B member slots' table rows
-    [B, max_pages]; `start` [B] is each chunk's first absolute position;
-    `fresh` (static) means every start==0 and the members' pages are
-    empty (flash-path eligible). `fwd` is paged_forward or its
-    stage-pipelined twin. B=1 is the classic single-slot prefill; the
-    batched gang prefill (ServingEngine.prefill_batch) is the same
-    program at B>1.
-    """
-    cache1 = PagedKVCache(pools[0], pools[1], table_rows,
-                          jnp.zeros((tokens.shape[0],), jnp.int32),
-                          *pools[2:])
-    B, T = tokens.shape
-    positions = start[:, None] + jnp.broadcast_to(jnp.arange(T)[None, :],
-                                                  (B, T))
-    # last chunk token's logits only (paged_forward last_index docs);
-    # the pipeline path ignores the hint — gather its full-T logits.
-    logits, cache1 = fwd(params, cfg, tokens, cache1, positions, fresh=fresh,
-                         last_index=true_len - 1)
-    if logits.shape[1] != 1:
-        logits = jnp.take_along_axis(logits, (true_len - 1)[:, None, None],
-                                     axis=1)
-    return logits[:, 0, :], pool_leaves(cache1, absent=True)
-
-
-def _decode_all(cfg: ModelConfig, fwd, params, tokens, cache: PagedKVCache,
-                active, temps, top_k: int, top_p: float, key,
-                use_kernel: bool = False):
-    logits, cache = fwd(params, cfg, tokens[:, None], cache,
-                        active=active, use_kernel=use_kernel)
-    last = logits[:, -1, :]
-    nxt = sample_batched(last, key, temps, top_k, top_p)
-    return nxt, last, cache
-
-
-def _decode_scan(cfg: ModelConfig, fwd, k: int, params, tokens,
-                 cache: PagedKVCache, active, temps, stops, budgets,
-                 top_k: int, top_p: float, key, use_kernel: bool = False):
-    """k chained decode iterations in ONE lax.scan; [S] slots each step.
-
-    Carry: (cur tokens [S], cache, live [S] bool, remaining budgets
-    [S]). Step i consumes cur — writing its K/V where live, advancing
-    live lengths — and samples the next token with the device-derived
-    key fold_in(key, i), so the host pays one dispatch, one operand
-    conversion, and one RNG split per BLOCK instead of per token.
-
-    Liveness is the device twin of the host's stop/max_new truncation:
-    a slot starts dead if it is inactive, its budget is already spent,
-    or its incoming chain token is its stop id (an undrained
-    admission-time first token can be EOS); it goes dead the moment a
-    sampled token hits the stop id or spends the budget. Dead steps
-    freeze the slot's token (the drain discards them anyway), write to
-    the null page, and leave lengths at the written-token count — so a
-    mid-block finish can never grow pages or attend past the EOS.
-
-    Returns (block [k, S] stacked step tokens, final [S] chain vector,
-    cache).
-    """
-    has_stop = stops >= 0
-    live = active & (budgets > 0) \
-        & jnp.where(has_stop, tokens != stops, True)
-
-    def body(carry, i):
-        cur, cache, live, rem = carry
-        logits, cache = fwd(params, cfg, cur[:, None], cache,
-                            active=live, use_kernel=use_kernel)
-        nxt = sample_batched(logits[:, -1, :], jax.random.fold_in(key, i),
-                             temps, top_k, top_p)
-        nxt = jnp.where(live, nxt, cur)
-        rem = jnp.where(live, rem - 1, rem)
-        live = live & (rem > 0) & jnp.where(has_stop, nxt != stops, True)
-        return (nxt, cache, live, rem), nxt
-
-    (final, cache, _, _), block = lax.scan(
-        body, (tokens, cache, live, budgets),
-        jnp.arange(k, dtype=jnp.int32))
-    return block, final, cache
-
-
-def _decode_scan_win(cfg: ModelConfig, k: int, params, tokens,
-                     cache: PagedKVCache, window: KVWindow, win_len,
-                     active, temps, stops, budgets, top_k: int,
-                     top_p: float, key, use_kernel: bool = False):
-    """Write-combined twin of _decode_scan — the liveness/budget/RNG
-    semantics are IDENTICAL (the parity grid pins byte-equality); only
-    the K/V write target differs. The pool is READ-ONLY (closed over by
-    paged_forward_window, returned unmodified for donation aliasing):
-    each step stages its fresh K/V into the window carry at per-slot
-    offset win_len, which advances with the slot's liveness exactly as
-    cache.lengths does window-off. The pool scatter this scan no longer
-    pays per step — and the pool COPY the scatter forced, because XLA
-    cannot alias a scatter into a scan carry — happens once per
-    scheduler drain (engine.flush_kv_window). The window is carried
-    through the layers whole as it is through the steps, and written in
-    place (cache/paged.py stage_window_layer).
-
-    Returns (block [k, S], final [S], cache, window, win_len).
-    """
-    has_stop = stops >= 0
-    live = active & (budgets > 0) \
-        & jnp.where(has_stop, tokens != stops, True)
-
-    def body(carry, i):
-        cur, win, wlen, live, rem = carry
-        logits, win = paged_forward_window(params, cfg, cur[:, None],
-                                           cache, win, wlen, active=live,
-                                           use_kernel=use_kernel)
-        nxt = sample_batched(logits[:, -1, :], jax.random.fold_in(key, i),
-                             temps, top_k, top_p)
-        nxt = jnp.where(live, nxt, cur)
-        wlen = jnp.where(live, wlen + 1, wlen)
-        rem = jnp.where(live, rem - 1, rem)
-        live = live & (rem > 0) & jnp.where(has_stop, nxt != stops, True)
-        return (nxt, win, wlen, live, rem), nxt
-
-    (final, window, win_len, _, _), block = lax.scan(
-        body, (tokens, window, win_len, live, budgets),
-        jnp.arange(k, dtype=jnp.int32))
-    return block, final, cache, window, win_len
-
-
 def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
                  tokens, cursor, cache: PagedKVCache,
                  window: Optional[KVWindow], win_len, pbuf, plen, active,
@@ -1273,7 +878,7 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
                  use_kernel: bool = False):
     """k chained PACKED mixed iterations in ONE lax.scan: each step,
     every slot is in exactly one phase. A decode slot advances one
-    token, _decode_scan[_win]'s semantics token for token; a slot in
+    token; a slot in
     prefill phase chews the next C tokens of its prompt-buffer row.
     A step computes S + P*C rows (`fwd`: cache/paged.py
     paged_forward_packed, or its pipelined twin under stages):
@@ -1295,13 +900,27 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
 
     Emissions: a decode step emits its sampled token; a prefill step
     emits ONLY at the step its prefill completes, the slot's first
-    token, sampled on device from the chunk's last real column (the
-    same last-position logits the alternating path's gang prefill
-    hands _finish_prefill). valid[i, s] marks block[i, s] as a real
+    token, sampled on device from the chunk's last real column.
+    valid[i, s] marks block[i, s] as a real
     emission; the drain walks it like the spec block's validity mask.
-    Sampling (fold_in(key, i)), liveness, budgets and stops are
-    _decode_scan's: the parity grid pins the tokens to the alternating
-    path's.
+    Step i samples with the device-derived key fold_in(key, i), so the
+    host pays one dispatch, one operand conversion, and one RNG split
+    per BLOCK instead of per token.
+
+    Liveness is the device twin of the host's stop/max_new truncation:
+    a slot starts dead if it is inactive, its budget is already spent,
+    or (in decode phase) its incoming chain token is its stop id (an
+    undrained first token can be EOS); it goes dead the moment an
+    emitted token hits the stop id or spends the budget. Dead steps
+    freeze the slot's token (the drain discards them anyway), write to
+    the null page, and leave lengths at the written-token count — so a
+    mid-block finish can never grow pages or attend past the EOS.
+    Window on, the pool scatter the scan does not pay per step — and
+    the pool COPY the scatter forced, because XLA cannot alias a
+    scatter into a scan carry — happens once per scheduler drain
+    (engine.flush_kv_window); the window is carried through the layers
+    whole as it is through the steps, and written in place
+    (cache/paged.py stage_window_layer).
 
     Returns (block [k, S], valid [k, S], final [S], cursor, cache,
     window, win_len, load): load f32 [3] is what the block's routing
@@ -1457,7 +1076,7 @@ def _mixed_spec_scan(cfg: ModelConfig, fwd, rounds: int, gamma: int,
     slot's allocated pages (the last verify's slack) land on the null
     page via the block-table default, same as dead-slot decode writes.
 
-    Liveness is the decode block's contract: a slot starts dead if
+    Liveness is the plain block's contract: a slot starts dead if
     inactive, out of budget, or (in decode phase) its last history
     token is its stop id; it goes dead the round a valid emission hits
     the stop id or spends the budget (lengths freeze, later writes

@@ -59,8 +59,8 @@ TICK_PHASES = ("expire", "drain_oldest", "drain_barrier", "admit",
 
 #: Closed label set for drain_barriers_total{cause=...} — the
 #: membership-change classes that force a FULL drain barrier.
-BARRIER_CAUSES = ("admission", "finish", "page_pressure", "cancel",
-                  "spec", "idle", "expired", "flush")
+BARRIER_CAUSES = ("finish", "page_pressure", "cancel", "spec", "idle",
+                  "expired", "sp_prefill", "flush")
 
 
 def percentile(values: List[float], q: float) -> float:

@@ -300,8 +300,11 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     this prefill entry point — inside the scan every lane (decode OR
     prefill chunk) attends through the per-step paged/window attention
     of the decode program, with per-slot lengths/cursors doing the
-    masking. This kernel remains the ALTERNATING path's chunked-prefill
-    engine (`mixed_dispatch=False`).
+    masking. This kernel serves the contiguous engine's prefill, the
+    seq-parallel lane's chunks and, where a ModelConfig says
+    attn_impl="flash", cache/paged.py paged_attend's multi-token
+    branches (a packed step's chunk with the window off, the
+    speculative verify).
 
     sliding_window: the layer's window out of its pattern (a traced
     scalar, 0 = a full layer; None = the model has no pattern and the

@@ -173,7 +173,7 @@ def paged_pipeline_forward(params: Params, cfg: ModelConfig,
 
     `last_index` is accepted for signature parity with paged_forward but
     ignored — the GPipe schedule emits full-T logits per microbatch and
-    the caller gathers (engine/serving.py _prefill_slot).
+    the caller gathers.
 
     Same contract as cache.paged.paged_forward — [B,T] tokens against the
     shared page pool — but the layer stack and the pool's L dim are stage-

@@ -1,4 +1,5 @@
-"""Continuous-batching scheduler: admission, decode interleave, preemption.
+"""Continuous-batching scheduler: admission, one fused block a tick,
+preemption.
 
 Realizes the reference's planned "Scheduling System" layer
 (/root/reference/CLAUDE.md:22 — "Workload distribution and synchronization
@@ -6,43 +7,44 @@ across compute nodes"; no implementation exists, SURVEY.md §0) for the
 BASELINE.json configs[4] serving shape.
 
 Host-side policy over the static-shape device programs in
-engine/serving.py:
+engine/serving.py. There is ONE dispatch path:
 
-* tick() = [lazy drain — the OLDEST in-flight decode block only, and
-  only when the in-flight queue is full] then [≤ prefill_chunk tokens
-  of GROUP prefill work — waiting requests are gang-admitted, up to
-  prefill_max_batch of them, and their next chunks run as batched
-  [B, Tbucket] dispatches (engine.prefill_batch), bucketed by chunk
-  length] then [ONE fused decode block of decode_steps_per_tick
-  iterations for all active slots — a single jitted scan,
-  engine._decode_scan — CHAINED on the previous block's
-  device-resident carry]. Up to RuntimeConfig.inflight_blocks decode
-  blocks stay in flight (dispatch-ahead): block t+1 is dispatched
-  before block t is drained, so the tick's host section — admission,
-  operand assembly, emission — overlaps the device computing the newer
-  blocks instead of idling it: a drain reads only arrays the blocks it
-  drains produced themselves and launches no program to read them, so
-  its fetch returns when the oldest block ends. A membership change
-  (admission work on the alternating path, preemption, cancel, an
-  expired deadline) forces a FULL drain barrier so host and device
-  bookkeeping reconcile before the next dispatch. A finish surfacing
-  at a lazy drain forces one too on the alternating and the
-  speculative path; under mixed dispatch without speculation it is
-  taken there, with the newer blocks still in flight (tick()'s
-  docstring has the argument). Speculative mode dispatches speculative
-  mixed blocks through the same pipeline: drafts come from a
-  device-resident token history, acceptance (with the
-  rejection-sampling correction at temperature > 0) is computed inside
-  the scan, and blocks chain on the (history, budgets) carry — no
-  per-round barrier. Long prompts are
-  split into prefill_chunk-sized pieces that continue the warm cache
-  across ticks (partially-prefilled gang members carry over), so a
-  max-length admission can never head-of-line-block decoding requests
-  for more than one chunk, and a burst of arrivals prefills as a
-  group instead of one prompt per tick.
-* scheduler="static" disables interleaving: a whole batch is admitted
-  (full prompts at once) only when the previous batch has fully drained —
-  the classic throughput-oriented static-batching mode.
+* tick() = [deadline scrub] then [lazy drain — the OLDEST in-flight
+  block only, and only when the in-flight queue is full] then [at most
+  one chunk of the seq-parallel long-prompt lane, where a mesh has
+  one] then [inline admission: waiting requests take free slots by
+  host bookkeeping and per-slot carry edits, no dispatch of their own
+  (_admit_inline)] then [page preallocation for every step in flight
+  and this block's] then [ONE fused block of decode_steps_per_tick
+  steps (_mixed_block): every step, a decode-phase slot advances one
+  token (or, under speculation, one draft/verify round) while up to P
+  slots in prefill phase chew a C-token chunk of their prompt, packed
+  into one forward — a single jitted scan, engine._packed_scan or
+  _mixed_spec_scan[_win] — CHAINED on the previous block's
+  device-resident carry]. With no prompt in flight the block carries
+  no chunk and is a decode block (`bf_decode_block[_win]`). Up to
+  RuntimeConfig.inflight_blocks blocks stay in flight
+  (dispatch-ahead): block t+1 is dispatched before block t is
+  drained, so the tick's host section — admission, operand assembly,
+  emission — overlaps the device computing the newer blocks instead
+  of idling it: a drain reads only arrays the blocks it drains
+  produced themselves and launches no program to read them, so its
+  fetch returns when the oldest block ends. A membership change the
+  blocks in flight cannot absorb (preemption under page pressure,
+  cancel, an expired deadline, a seq-parallel lane dispatch) forces a
+  FULL drain barrier so host and device bookkeeping reconcile before
+  the next dispatch. Admission forces none. A finish surfacing at a
+  lazy drain is taken there, with the newer blocks still in flight
+  (tick()'s docstring has the argument), except under speculation
+  and with the seq-parallel lane, which keep the barrier. Speculative
+  mode dispatches speculative mixed blocks through the same pipeline:
+  drafts come from a device-resident token history, acceptance (with
+  the rejection-sampling correction at temperature > 0) is computed
+  inside the scan, and blocks chain on the (history, budgets) carry —
+  no per-round barrier. A long prompt continues across steps and
+  ticks a chunk at a time, so a max-length admission never holds the
+  decoding requests for more than a step's chunks
+  (prefill_inline_budget tokens).
 * Admission allocates pages for prompt+1; each decode step grows a slot's
   pages just-in-time. If the pool is exhausted, the youngest running
   request is PREEMPTED (pages freed, request requeued; its prompt +
@@ -71,7 +73,7 @@ from jax.profiler import TraceAnnotation
 from butterfly_tpu.cache.allocator import make_page_allocator
 from butterfly_tpu.cache.ssm_state import state_info
 from butterfly_tpu.engine.serving import (
-    LAUNCH_SPAN, ServingEngine, bucket_len, sample_batched)
+    LAUNCH_SPAN, ServingEngine, sample_batched)
 from butterfly_tpu.obs.profile import run_delay_s
 from butterfly_tpu.obs.registry import (
     BATCH_BUCKETS, LATENCY_BUCKETS, TOKEN_BUCKETS, MetricsRegistry)
@@ -221,9 +223,6 @@ class Scheduler:
         if tracer is not None and hasattr(engine, "tracer"):
             engine.tracer = tracer
         rt = engine.runtime
-        if rt.scheduler not in ("continuous", "static"):
-            raise ValueError(f"unknown scheduler {rt.scheduler!r}: "
-                             "expected 'continuous' or 'static'")
         max_pages = engine.cache.page_table.shape[1]
         if rt.prefix_caching:
             from butterfly_tpu.cache.prefix import PrefixCachingAllocator
@@ -235,15 +234,11 @@ class Scheduler:
                                              num_slots=engine.num_slots)
         self.waiting: Deque[Request] = deque()
         self.running: List[Request] = []
-        # Mixed dispatch (ISSUE 18): prefill chunks and decode/spec
-        # tokens ride ONE fused block per tick (engine._packed_scan, and
-        # under speculation _mixed_spec_scan[_win]) — admission becomes
-        # a host-side carry edit between dispatches (_seed_mixed_slot)
-        # instead of a drain barrier + separate prefill dispatch,
-        # retiring the admission barrier cause as a class. Continuous
-        # scheduler only.
-        self._mixed_mode = (rt.scheduler == "continuous"
-                            and rt.mixed_dispatch)
+        # Prefill chunks and decode/spec tokens ride ONE fused block per
+        # tick (engine._packed_scan, and under speculation
+        # _mixed_spec_scan[_win]): admission is a host-side carry edit
+        # between dispatches (_seed_mixed_slot), never a drain barrier
+        # or a dispatch of its own.
         # per-step chunk width C: under spec the verify shape pins it
         # to gamma+1; otherwise the inline budget (clamped by the tick
         # chunk budget) IS the width — one prefilling slot chews C
@@ -268,13 +263,10 @@ class Scheduler:
         self._pbuf_dev = None
         self._plen_host = np.zeros((engine.num_slots,), np.int32)
         # The prefill GROUP: requests admitted to slots whose prompts are
-        # not yet fully in the KV cache. Each tick their next chunks are
-        # packed under the prefill_chunk token budget and dispatched as
-        # batched [B, Tbucket] prefills (engine.prefill_batch);
-        # partially-prefilled members carry over to the next tick. This
-        # replaces the old single `_prefilling` request — a burst of
-        # arrivals no longer serializes one [1, Tbucket] dispatch per
-        # prompt while decode slots sit idle.
+        # not yet fully in the KV cache. Their lanes ride the fused
+        # blocks in prefill phase (at most _mixed_max_pf at once get a
+        # chunk a step); a member leaves at the drain of the block its
+        # prompt completed in (_mixed_transitions).
         self._prefill_group: List[Request] = []
         # Long-prompt seq-parallel lane (ISSUE 20): prompts longer than
         # RuntimeConfig.seq_parallel_threshold prefill through chunked
@@ -305,18 +297,17 @@ class Scheduler:
         self._key = jax.random.PRNGKey(seed)
         self._next_tokens = np.zeros((engine.num_slots,), np.int32)
         # In-flight fused blocks, tagged tuples in dispatch order:
-        #   ("decode", final [S] carry, block [k, S], k, snapshot, t)
         #   ("mixed",  final [S], (block [k, S], valid [k, S]), k,
         #              snapshot, t, pf_done slots, emit_vec [S])
         #   ("mixed_spec", hist_len [S], (toks, valid) [R, S, C], R,
         #              snapshot, t, pf_done slots, None)
-        # where snapshot maps slot -> (request, generation); mixed
-        # entries additionally carry the slots whose prefill completed
+        # where snapshot maps slot -> (request, generation); the
+        # entries carry the slots whose prefill completed
         # inside the block (drain-time state transitions) and, plain
         # mixed, the host-simulated per-slot emission counts the next
         # dispatch's budget look-ahead subtracts. Each tick
-        # dispatches ONE jitted scan (engine.decode_block_async or a
-        # mixed block) chained on the previous block's device-resident
+        # dispatches ONE jitted scan chained on the previous block's
+        # device-resident
         # carry, and up to RuntimeConfig.inflight_blocks of them stay
         # undrained
         # (dispatch-ahead): the host fetches only the OLDEST block when
@@ -329,7 +320,7 @@ class Scheduler:
         # Batch-membership epoch: bumped whenever the running set, the
         # pending-first set, or any runner's drained output changes
         # (admission completing, finish, preemption, any drain).
-        # _decode_block caches its host operand assembly — the
+        # _assemble caches the host operand assembly — the
         # active/temps/stops/base-budget arrays and the slot snapshot —
         # keyed on it, so back-to-back blocks over an unchanged batch
         # skip the per-slot Python rebuild and the np.asarray churn.
@@ -362,14 +353,15 @@ class Scheduler:
         self._sound_ticks: Deque[tuple] = deque(maxlen=64)
         # this tick's stall, once one is noted: one note a tick
         self._tick_stall: Optional[Dict] = None
-        # First tokens sampled on-device at admission, not yet fetched:
+        # First tokens sampled on-device as the seq-parallel lane's
+        # prompt completed (_finish_prefill), not yet fetched:
         # [(req, generation=req.preemptions, slot, device scalar)].
         # Fetched with the next drain, all in one jax.device_get (a
         # per-admission host fetch would pay the full dispatch+fetch
         # RTT per request).
         self._pending_first: List[tuple] = []
         # Membership index over _pending_first, keyed (request id,
-        # preemptions) and refreshed at drain time: _decode_block's
+        # preemptions) and refreshed at drain time: _assemble's
         # budget computation and _written ask "does req have an
         # undrained first token?" per runner — a set lookup instead of
         # the old O(running x pending) linear scan.
@@ -396,15 +388,14 @@ class Scheduler:
             self._hist_dev = jnp.zeros((engine.num_slots, H), jnp.int32)
             self._hist_len_dev = jnp.zeros((engine.num_slots,), jnp.int32)
         # A finish that surfaces at a lazy drain is taken there, with
-        # the newer blocks in flight, where the scheduler runs mixed
-        # dispatch without speculation (tick()'s docstring). SEPARATE
-        # by mode, not a parameter: the speculative path resets the
-        # device's budget carry (_spec_rem) to host truth at a finish
-        # barrier, the alternating path's admission barriers anyway,
-        # and the seq-parallel lane donates the pool binding in
-        # dispatches of its own; they keep the barrier.
-        self._finish_inline = (self._mixed_mode and not self._spec_mode
-                               and not self._sp_enabled)
+        # the newer blocks in flight, where the scheduler runs without
+        # speculation and without the seq-parallel lane (tick()'s
+        # docstring). SEPARATE by mode, not a parameter: the
+        # speculative path resets the device's budget carry (_spec_rem)
+        # to host truth at a finish barrier, and the seq-parallel lane
+        # donates the pool binding in dispatches of its own; they keep
+        # the barrier.
+        self._finish_inline = not self._spec_mode and not self._sp_enabled
         # Typed instruments (obs/registry.py) replace the old ad-hoc
         # Dict[str, float]: counters for the monotonic totals, fixed-
         # bucket histograms for the latency/size distributions /metrics
@@ -451,8 +442,8 @@ class Scheduler:
             "drain_barriers_total",
             "FULL drain barriers (every in-flight block fetched, "
             "pipeline restarts cold), by membership-change cause "
-            "(admission, finish, page_pressure, cancel, spec, idle, "
-            "expired, flush). Compare the sum with spec_forwards_total "
+            "(finish, page_pressure, cancel, spec, idle, expired, "
+            "sp_prefill, flush). Compare the sum with spec_forwards_total "
             "/ tick count: a healthy pipeline drains lazily and "
             "barriers only on membership changes, never once per "
             "decode or spec round", ("cause",))
@@ -460,8 +451,8 @@ class Scheduler:
             "finishes_inline_total",
             "Requests whose finish surfaced at a lazy drain and was "
             "taken there, with the newer blocks still in flight and no "
-            "full barrier (mixed dispatch without speculation); a "
-            "finish on any other path counts in "
+            "full barrier (no speculation, no seq-parallel lane); a "
+            "finish with either counts in "
             "drain_barriers_total{cause=\"finish\"}")
         self._c_overlap = reg.counter_family(
             "drain_overlap_total",
@@ -495,11 +486,6 @@ class Scheduler:
             "seq-parallel lane (chunked ring-attention dispatches; "
             "zero when seq_parallel_threshold is off or no prompt "
             "exceeded it)")
-        self._h_prefill_batch = reg.histogram(
-            "prefill_batch_size",
-            "Requests packed into one batched [B, Tbucket] prefill "
-            "dispatch (group admission; 1 = a lone member in its "
-            "chunk-length bucket)", BATCH_BUCKETS)
         self._h_decode_block = reg.histogram(
             "decode_block_seconds",
             "Fused decode block in-flight residency: dispatch to "
@@ -1154,11 +1140,11 @@ class Scheduler:
         raise RuntimeError("scheduler did not drain")
 
     def tick(self) -> int:
-        """One scheduling round: lazy drain, bounded prefill work, then
-        a dispatch-ahead decode block.
+        """One scheduling round: lazy drain, inline admission, then a
+        dispatch-ahead fused block.
 
-        Continuous mode keeps up to `RuntimeConfig.inflight_blocks`
-        fused decode blocks in flight: block t+1 chains on block t's
+        Up to `RuntimeConfig.inflight_blocks` fused blocks stay in
+        flight: block t+1 chains on block t's
         device-resident carry BEFORE t is drained, so this tick's host
         section — drain bookkeeping, admission, operand assembly —
         overlaps the device computing earlier blocks instead of idling
@@ -1174,20 +1160,21 @@ class Scheduler:
         (everything drained) runs only when host and device state must
         reconcile:
 
-        * the alternating path only: admission can make progress (a
-          mid-prefill group, or a waiter with a free slot) — prefill
-          bookkeeping and budget assembly need every in-flight token
-          on the host;
-        * the alternating and the speculative path only: a finish
-          surfaced at a lazy drain — the speculative budget carry
-          (_spec_rem) is reset to host truth there, and the
-          alternating path's admission barriers anyway;
+        * under speculation and with the seq-parallel lane only: a
+          finish surfaced at a lazy drain — the speculative budget
+          carry (_spec_rem) is reset to host truth there, and the
+          lane donates the pool binding in dispatches of its own
+          (each of which drains first: cause `sp_prefill`);
         * page pressure (_ensure_or_preempt) — preemption must never
           reclaim pages a dispatched block still writes;
         * cancel() and an expired deadline — same hazard, external
           trigger: the request's lane is LIVE in the blocks in flight.
 
-        Under mixed dispatch without speculation (_finish_inline) a
+        Admission is none of them: it is host bookkeeping and carry
+        edits between dispatches (_admit_inline), and the prompt rides
+        the next block's chunks.
+
+        Without speculation and without the lane (_finish_inline) a
         finish that surfaces at a lazy drain is taken THERE, with the
         newer blocks still in flight: the freed slot and pages are
         visible to this tick's admission, page preallocation and
@@ -1225,8 +1212,7 @@ class Scheduler:
         device-resident token history, acceptance is computed
         inside the scan, and the chained carry is (history, lengths,
         remaining budgets) instead of the final-token vector — no
-        barrier per round (the pre-block-machinery implementation
-        drained every round to draft on the host).
+        barrier per round.
 
         Returns the number of tokens generated this round (throughput
         accounting for the serve loop)."""
@@ -1288,11 +1274,11 @@ class Scheduler:
         with self._span("expire"):
             self._expire_due()
         # lazy drain: consume the oldest block once the queue is full
-        # (depth=1 degenerates to the old drain-every-tick loop). A
-        # finish surfacing there is taken there under mixed dispatch
-        # without speculation (the newer blocks stay in flight: the
-        # finished lane is dead in them); on every other path it is a
-        # membership change -> full barrier.
+        # (depth=1 degenerates to a drain every tick). A
+        # finish surfacing there is taken there without speculation
+        # and without the seq-parallel lane (the newer blocks stay in
+        # flight: the finished lane is dead in them); with either it
+        # is a membership change -> full barrier.
         while len(self._inflight) >= depth:
             finished = self._drain_oldest()
             if not finished:
@@ -1302,39 +1288,22 @@ class Scheduler:
                 self._tick_finishes_inline += finished
             else:
                 self._drain_inflight("finish")
-        mixed = self._mixed_mode
-        # seq-parallel long-prompt lane (ISSUE 20): at most one chunk
-        # per tick — the lane's dispatch donates the pool binding, so
-        # _sp_prefill_step drains in-flight blocks itself. The chunk's
-        # per-device share counts against this tick's prefill budget
-        # below (decode-ITL interference stays bounded by the declared
-        # prefill_inline_budget just like ordinary chunked prefill).
-        sp_used = 0
-        if self._sp_enabled:
-            with self._span("admit"):
-                self._sp_admit()
-                sp_used = self._sp_prefill_step()
-        # admission barrier — retired as a class under mixed dispatch,
-        # where admission is a host-side carry edit between dispatches
-        # (_admit_inline) and the prompt rides the next fused block.
-        # The alternating path still barriers whenever admission can
-        # actually make progress, so a standing queue behind full
-        # slots doesn't serialize the pipeline.
-        if not mixed and (self._prefill_group
-                          or (self.waiting
-                              and self._free_slot() is not None)):
-            self._drain_inflight("admission")
         with self._span("admit"):
-            if mixed:
-                self._admit_inline()
-            else:
-                self._admit(sp_used // max(1, self.engine.sp_degree))
+            # seq-parallel long-prompt lane (ISSUE 20): at most one
+            # chunk per tick — the lane's dispatch donates the pool
+            # binding, so _sp_prefill_step drains in-flight blocks itself
+            if self._sp_enabled:
+                self._sp_admit()
+                self._sp_prefill_step()
+            # admission is a host-side carry edit between dispatches,
+            # no barrier: the prompt rides the next fused block
+            self._admit_inline()
         if self.running:
             self._h_batch.observe(len(self.running))
         # Preallocate pages for every step still in flight PLUS this
         # block up front: device lengths run ahead of the host mirror
         # by up to `step` tokens per undrained block (k samples for a
-        # decode block, k rounds x (gamma+1) emissions for a spec
+        # plain block, k rounds x (gamma+1) emissions for a spec
         # block), so the horizon is (inflight+1)*step + 1 (chain token
         # + the new samples) — and the block table dirties (syncs to
         # the device) at most once per TICK. Any more would
@@ -1349,7 +1318,7 @@ class Scheduler:
                 need = min(len(req.all_tokens) + horizon,
                            len(req.prompt) + req.max_new_tokens)
                 self._ensure_or_preempt(req, need)
-        if mixed and self._prefill_group:
+        if self._prefill_group:
             # prefill lanes advance up to C tokens per scan step, so
             # their device write horizon is k*C per undrained block
             pf_h = (len(self._inflight) + 1) * k * self._mixed_chunk + 1
@@ -1361,11 +1330,8 @@ class Scheduler:
         # the fused block covers both phases: its dispatch section
         # gets its own phase label so tick anatomy stays honest about
         # where admission+prefill time went
-        with self._span("mixed" if mixed else "dispatch"):
-            if mixed:
-                dispatched = self._mixed_block(k)
-            else:
-                dispatched = self._decode_block(k)
+        with self._span("mixed"):
+            dispatched = self._mixed_block(k)
         if not dispatched and (self._inflight or self._pending_first):
             # nothing dispatchable (every budget is spent on device):
             # the remaining tokens exist only in flight — fetch them
@@ -1702,13 +1668,10 @@ class Scheduler:
         if total > 0:
             m["tick_host_frac"] = self._t_host_total / total
             m["tick_device_frac"] = self._t_device_total / total
-        if self._mixed_mode:
-            # prompt tokens that rode fused mixed blocks (ISSUE 18) —
-            # under mixed dispatch ALL prefill work is inline, so this
-            # pairs with drain_barriers admission == 0 as the evidence
-            # that the admission barrier class is retired
-            m["mixed_dispatch_prefill_tokens_inline"] = \
-                self._c_chunk_tokens.value
+        # prompt tokens that rode fused mixed blocks (ISSUE 18): all
+        # prefill work but the seq-parallel lane's
+        m["mixed_dispatch_prefill_tokens_inline"] = \
+            self._c_chunk_tokens.value
         if self._sp_enabled:
             m["seq_parallel_prefill_tokens_total"] = \
                 self._c_sp_tokens.value
@@ -1806,7 +1769,7 @@ class Scheduler:
         if slot is None:
             return
         if self._shares_inflight_prefix(req):
-            return  # defer: a gang member is writing req's prefix
+            return  # defer: a prefilling member is writing req's prefix
         cached = self.alloc.admit(slot, req.all_tokens,
                                   len(req.all_tokens) + 1)
         if cached is None:
@@ -1831,29 +1794,27 @@ class Scheduler:
                              resumed=req.preemptions > 0,
                              seq_parallel=True)
 
-    def _sp_prefill_step(self) -> int:
+    def _sp_prefill_step(self) -> None:
         """Dispatch ONE seq-parallel prefill chunk for the lane's
-        request (engine.sp_prefill_chunk). Returns the prompt tokens
-        dispatched (0 = lane empty or blocked).
+        request (engine.sp_prefill_chunk), if it has one.
 
         The chunk program donates the newest pool binding, so any
-        in-flight decode blocks drain first — the established donation
-        barrier (same hazard as admission prefills on the alternating
-        path). On completion the request leaves through
-        _finish_prefill like any gang member: pages publish to the
+        in-flight blocks drain first (the donation barrier). On
+        completion the request leaves through
+        _finish_prefill: pages publish to the
         prefix registry and the first token samples from the chunk's
         last-position logits."""
         if not self._sp_group:
-            return 0
+            return
         req = self._sp_group[0]
         if self._inflight or self._pending_first:
             self._drain_inflight("sp_prefill")
             if req.done or req.slot is None:
-                return 0  # the drain finished or preempted it
+                return  # the drain finished or preempted it
         toks = req.all_tokens
         chunk = toks[req.prefilled:req.prefilled + self._sp_chunk]
         if not chunk:
-            return 0
+            return
         if self.trace is not None:
             self.trace.event(req.id, "sp_prefill_chunk",
                              start=req.prefilled, tokens=len(chunk),
@@ -1868,41 +1829,9 @@ class Scheduler:
             # mixed carries: the slot enters decode phase (plen 0); its
             # pool length was set by the chunk dispatches themselves
             self._plen_host[req.slot] = 0
-        return len(chunk)
-
-    def _admit(self, sp_spent: int = 0) -> None:
-        """Group admission: gang-admit waiting requests and run the
-        prefill group's next chunks as batched dispatches, repeating
-        while budget remains and progress is possible (a round whose
-        members all complete cheaply leaves budget for another gang).
-
-        `sp_spent`: per-shard prompt tokens the seq-parallel lane
-        already dispatched this tick — it counts against the tick's
-        prefill budget so a tick never chews more than ~prefill_chunk
-        tokens per device."""
-        rt = self.engine.runtime
-        if rt.scheduler == "static":
-            # Static batching: no interleave — admit (and fully prefill)
-            # whole batches only once the previous batch has drained;
-            # budget None = whole prompts at once.
-            if self.running or self._prefill_group:
-                return
-            budget = None
-        else:
-            budget = max(1, rt.prefill_chunk) - sp_spent
-            if budget <= 0:
-                return
-        while True:
-            used = self._admit_round(budget)
-            if used is None:
-                return
-            if budget is not None:
-                budget -= used
-                if budget <= 0:
-                    return
 
     def _admit_inline(self) -> None:
-        """Mixed-dispatch admission (ISSUE 18): pull waiting requests
+        """Admission: pull waiting requests
         into free slots WITHOUT a drain barrier or a separate prefill
         dispatch — the prompt rides the next fused block's prefill
         lanes. Admission here is pure host bookkeeping plus per-slot
@@ -1928,7 +1857,7 @@ class Scheduler:
             if self._sp_qualifies(req):
                 break  # long prompt: waits for the seq-parallel lane
             if self._shares_inflight_prefix(req):
-                break  # defer: a gang member is writing req's prefix
+                break  # defer: a prefilling member is writing req's prefix
             cached = self.alloc.admit(slot, req.all_tokens,
                                       len(req.all_tokens) + 1)
             if cached is None:
@@ -2022,138 +1951,14 @@ class Scheduler:
                 self._pbuf_dev = self._pbuf_dev.at[slot].set(
                     jnp.asarray(row))
 
-    def _admit_round(self, budget: Optional[int]) -> Optional[int]:
-        """One gang-admission round: pull waiting requests into the
-        prefill group (bounded by free slots, pages, prefill_max_batch,
-        and the remaining token budget), pack every member's next chunk
-        under the budget FCFS, and dispatch the chunks as batched
-        [B, Tbucket] prefills bucketed by chunk length (plus freshness
-        only when engine.prefill_gang_split_fresh — the seed rule,
-        kept for prefill_flash_warm=False).
-
-        Returns the number of prompt tokens dispatched, or None if no
-        progress was possible (nothing admissible and nothing to
-        prefill)."""
-        rt = self.engine.runtime
-        cap = max(1, min(rt.prefill_max_batch, self.engine.num_slots))
-        demand = sum(len(r.all_tokens) - r.prefilled
-                     for r in self._prefill_group)
-        while (self.waiting and len(self._prefill_group) < cap
-               and (budget is None or demand < budget)):
-            slot = self._free_slot()
-            if slot is None:
-                break
-            req = self.waiting[0]
-            if self._sp_qualifies(req):
-                break  # long prompt: waits for the seq-parallel lane
-            if self._shares_inflight_prefix(req):
-                break  # defer: a gang member is writing req's prefix
-            # all_tokens includes output if preempted earlier; admit
-            # may attach already-cached prefix pages (prefix caching),
-            # whose tokens skip prefill entirely via the warm path.
-            cached = self.alloc.admit(slot, req.all_tokens,
-                                      len(req.all_tokens) + 1)
-            if cached is None:
-                break  # pool exhausted; decode will free/preempt
-            self.waiting.popleft()
-            req.slot, req.state = slot, "prefilling"
-            req.prefilled = req.cached_at_admit = cached
-            self.slots[slot] = req
-            self._prefill_group.append(req)
-            self.engine.set_table_row(slot, self.alloc.pages_of(slot))
-            demand += len(req.all_tokens) - cached
-            wait = time.monotonic() - req.t_enqueued
-            self._h_queue_wait.observe(wait)
-            if self.flightrec is not None:
-                self.flightrec.note("admit", id=req.id, slot=slot,
-                                    queue_wait_s=wait, cached=cached)
-            if self.trace is not None:
-                self.trace.event(req.id, "admit", slot=slot,
-                                 queue_wait_s=wait,
-                                 prefix_cache_hit_tokens=cached,
-                                 resumed=req.preemptions > 0)
-            # (no length bookkeeping for `cached` needed: the member's
-            # first warm chunk sets lengths[slot] = cached + len(chunk))
-        if not self._prefill_group:
-            return None
-
-        # pack each member's next chunk under the budget, FCFS — members
-        # admitted earlier win budget, exactly like the old serialized
-        # admission, so carried members can't starve behind new arrivals
-        plan: List[tuple] = []  # (req, chunk, start)
-        used = 0
-        for req in self._prefill_group:
-            room = None if budget is None else budget - used
-            if room is not None and room <= 0:
-                break
-            prefix = req.all_tokens
-            end = len(prefix) if room is None \
-                else min(len(prefix), req.prefilled + room)
-            chunk = prefix[req.prefilled:end]
-            if not chunk:
-                continue
-            plan.append((req, chunk, req.prefilled))
-            used += len(chunk)
-        if not plan:
-            return None
-
-        # bucket by (freshness, padded chunk length): members sharing a
-        # bucket ride ONE [B, Tbucket] dispatch. Freshness splits the
-        # gang ONLY when the engine's fresh program is kernelized but
-        # its warm one is dense (prefill_gang_split_fresh) — there a
-        # warm prefix-cache or carried member would drag cold members
-        # off the flash path. With warm-prefix flash (ISSUE 13, the
-        # default where kernels run) the warm program takes the kernel
-        # too, so mixed gangs ride one dispatch and the all-or-nothing
-        # freshness downgrade is gone.
-        split_fresh = self.engine.prefill_gang_split_fresh
-        hi = self.engine.cache.max_seq
-        dispatches: Dict[tuple, List[tuple]] = {}
-        for req, chunk, start in plan:
-            key = (start == 0 if split_fresh else True,
-                   bucket_len(len(chunk), hi=hi))
-            dispatches.setdefault(key, []).append((req, chunk, start))
-        for (_, bucket), members in dispatches.items():
-            self._h_prefill_batch.observe(len(members))
-            if self.trace is not None:
-                self.trace.event(None, "prefill_batch",
-                                 members=len(members),
-                                 slots=[m[0].slot for m in members],
-                                 bucket=bucket,
-                                 tokens=sum(len(m[1]) for m in members),
-                                 fresh=all(m[2] == 0 for m in members))
-                for req, chunk, start in members:
-                    self.trace.event(req.id, "prefill_chunk",
-                                     start=start, tokens=len(chunk))
-            logits = self.engine.prefill_batch(
-                [m[0].slot for m in members], [m[1] for m in members],
-                [m[2] for m in members])
-            done_rows, done_reqs = [], []
-            for i, (req, chunk, start) in enumerate(members):
-                req.prefilled = start + len(chunk)
-                if req.prefilled >= len(req.all_tokens):
-                    done_rows.append(i)
-                    done_reqs.append(req)
-            if done_reqs:
-                # device-side row gather: completing members' first
-                # tokens sample from THIS dispatch, no host sync
-                self._finish_prefill(done_reqs,
-                                     logits[jnp.asarray(done_rows)])
-        return used
-
     def _shares_inflight_prefix(self, req: Request) -> bool:
-        """Prefix caching only: would `req` hit KV pages a current gang
-        member is still writing? Serialized admission accidentally
-        guaranteed that a request arriving behind a same-prefix request
-        admitted AFTER the first registered its pages — and so shared
-        them. Gang admission would put both in one group and pay the
-        shared prefix's prefill twice. Keep the guarantee deliberately:
-        if req's leading full block chain-matches an in-flight member's,
-        defer its admission one round — the member registers at
-        prefill_done and req then admits with a cache hit. FIFO order is
-        preserved (admission simply stops for the round), matching the
-        old behavior where such a request blocked behind the serialized
-        prefill anyway."""
+        """Prefix caching only: would `req` hit KV pages a member of the
+        prefill group is still writing? Admitted beside it, req would
+        pay the shared prefix's prefill a second time. So if req's
+        leading full block chain-matches a member's, its admission
+        waits — the member registers its pages when its prompt
+        completes and req then admits with a cache hit. FIFO order is
+        preserved (admission simply stops for the tick)."""
         if not self.engine.runtime.prefix_caching or not self._prefill_group:
             return False
         from butterfly_tpu.cache.prefix import chain_block_hashes
@@ -2165,19 +1970,18 @@ class Scheduler:
                    for m in self._prefill_group)
 
     def _finish_prefill(self, reqs: List[Request], logits) -> None:
-        """Members whose prompt is now fully in cache: publish pages for
+        """The seq-parallel lane's members whose prompt is now fully in
+        cache (a prompt that rode a block's chunks leaves through
+        _mixed_transitions): publish pages for
         prefix reuse (no-op without prefix caching), sample every
-        member's first token ON DEVICE from the shared dispatch's logits
+        member's first token ON DEVICE from the dispatch's logits
         [M, V] in one vectorized draw, start decoding. Tokens are
         fetched at the next stacked drain; even a max_new==1 request
         keeps its slot until then (its extra decode steps are discarded
         like any post-finish in-flight work)."""
         for req in reqs:
             self.alloc.register(req.slot, req.all_tokens)
-            if req in self._prefill_group:
-                self._prefill_group.remove(req)
-            else:  # the seq-parallel lane finishes through here too
-                self._sp_group.remove(req)
+            self._sp_group.remove(req)
             req.state = "running"
             self.running.append(req)
             ran = len(req.all_tokens) - req.cached_at_admit
@@ -2216,62 +2020,13 @@ class Scheduler:
             self._pending_first_keys.add((req.id, req.preemptions))
         self._epoch += 1  # running set + pending-first set changed
 
-    def _decode_block(self, k: int) -> bool:
-        """Dispatch ONE fused k-step decode block for the running set
-        (engine.decode_block_async), chained on the previous block's
-        device-resident carry — the previous block need NOT be drained
-        first (dispatch-ahead). Host work — operand assembly, the
-        jnp.asarray conversions, the RNG split, the dispatch itself —
-        is paid once per BLOCK instead of once per token, and the
-        operand assembly itself is cached on the batch-membership
-        epoch: back-to-back blocks over an unchanged batch reuse the
-        active/temps/stops arrays and the slot snapshot, refreshing
-        only the budget vector (base minus the steps already in
-        flight — the device decrements its own copy inside each scan,
-        so the host estimate must run ahead the same way). Page growth
-        happened at tick start (the len + (inflight+1)*k + 1
-        preallocation covers every step of every undrained scan).
-
-        Per-slot stop ids and remaining-token budgets ride into the
-        scan so a slot that finishes mid-block is masked ON DEVICE
-        (lengths freeze, writes land on the null page) rather than
-        generating garbage the drain discards; a finished slot's chain
-        token stays frozen at its stop id, so every later in-flight
-        block starts it dead too.
-
-        Returns True iff a block was dispatched.
-        """
-        if not self.running:
-            return False
-        active, temps, stops, base, specm, snapshot = self._assemble()
-        # steps dispatched but undrained: the device consumed (at most)
-        # this much of each live slot's budget already. A slot that
-        # went dead early consumed less, but its chain token is frozen
-        # at its stop id (or its budget is genuinely spent), so
-        # under-budgeting it cannot drop real tokens.
-        ahead = sum(e[3] for e in self._inflight)
-        budgets = np.maximum(base - ahead, 0) if ahead else base
-        if not (active & (budgets > 0)).any():
-            return False  # every runner is out of budget on device
-        self._key, sub = jax.random.split(self._key)
-        # chain on the device token vector admissions write into (which
-        # the previous block's final vector seeded); the host vector
-        # only on the cold first dispatch
-        cur = self._next_dev if self._next_dev is not None \
-            else self.engine.carry(self._next_tokens)
-        block, final = self.engine.decode_block_async(
-            cur, active, temps, stops, budgets, sub, k)
-        self._next_dev = final
-        self._enqueue_block("decode", final, block, k, snapshot)
-        return True
-
     def _assemble(self) -> tuple:
         """Per-block host operands — the active/temps/stops/base-budget
         /spec-mask arrays and the slot snapshot — cached on the batch-
         membership epoch: back-to-back blocks over an unchanged batch
         skip the per-slot Python rebuild and the np.asarray churn.
 
-        Mixed dispatch extends the batch to prefill-group members too:
+        The batch is the running set and the prefill group:
         their lanes ride the same block (phase decided on device by
         cursor < plen), and their budget is the full remaining
         emission allowance (output is empty unless resumed from a
@@ -2287,8 +2042,7 @@ class Scheduler:
                 # seq-parallel-lane members never ride a block: their
                 # prefill happens in dedicated sp_prefill_chunk dispatches
                 # and they enter `running` only via _finish_prefill.
-                batch = (list(self.running) + list(self._prefill_group)
-                         if self._mixed_mode else self.running)
+                batch = list(self.running) + list(self._prefill_group)
                 for req in batch:
                     active[req.slot] = True
                     temps[req.slot] = req.temperature
@@ -2322,8 +2076,19 @@ class Scheduler:
         """Dispatch ONE fused MIXED block (ISSUE 18): decode (or spec)
         lanes and prefill lanes ride the same k-step jitted program
         (engine.mixed_block_async / mixed_spec_block_async), chained
-        on the device carries exactly like _decode_block — one
-        dispatch per tick covering both phases.
+        on the previous block's device-resident carries — the previous
+        block need NOT be drained first (dispatch-ahead) — one
+        dispatch per tick covering both phases. Host work — operand
+        assembly (_assemble), the RNG split, the dispatch itself — is
+        paid once per BLOCK instead of once per token. Page growth
+        happened at tick start.
+
+        Per-slot stop ids and remaining-token budgets ride into the
+        scan so a slot that finishes mid-block is masked ON DEVICE
+        (lengths freeze, writes land on the null page) rather than
+        generating garbage the drain discards; a finished slot's chain
+        token stays frozen at its stop id, so every later in-flight
+        block starts it dead too.
 
         The host runs a cheap lockstep simulation of each prefill
         lane's cursor: chunk progress is deterministic while a lane is
@@ -2334,8 +2099,10 @@ class Scheduler:
         for drain-time state transitions (_mixed_transitions). For
         plain mixed the same simulation also yields per-slot emission
         counts, the budget look-ahead chained dispatches subtract
-        (stop-deaths make it an over-estimate, which is safe for the
-        same frozen-chain-token reason as _decode_block).
+        (stop-deaths make it an over-estimate, which is safe: a slot
+        that went dead early consumed less, but its chain token is
+        frozen at its stop id, so under-budgeting it cannot drop real
+        tokens).
 
         Spec mixed budgets: the first dispatch after a full barrier
         seeds the device budget vector from exact host state (base,
@@ -2463,8 +2230,9 @@ class Scheduler:
         state.
 
         `cause` labels the barrier in drain_barriers_total{cause=}
-        (the membership-change class that forced it: admission, finish,
-        page_pressure, cancel, spec, idle, expired, flush) and rides
+        (the membership-change class that forced it: finish,
+        page_pressure, cancel, spec, idle, expired, sp_prefill, flush)
+        and rides
         the tick's timeline record + the flight-recorder ring."""
         with self._span("drain_barrier"):
             if self._inflight or self._pending_first:
@@ -2495,9 +2263,9 @@ class Scheduler:
         t+1 while the host emits block t). The fetch reads arrays that
         block t produced itself and launches nothing, so it returns
         when block t ends, whatever was launched after it. Returns
-        how many requests finished (the caller takes them there under
-        mixed dispatch without speculation, and escalates to a full
-        barrier on every other path)."""
+        how many requests finished (the caller takes them there
+        without speculation and the seq-parallel lane, and escalates
+        to a full barrier with either)."""
         with self._span("drain_oldest"):
             finished = self._drain_blocks([self._inflight.pop(0)]
                                           if self._inflight else [])
@@ -2518,7 +2286,8 @@ class Scheduler:
         in chronological order (`cause`: the full barrier's, None for
         a lazy drain); returns how many requests finished. Pending
         first tokens always ride along:
-        they are queued at an admission barrier, when nothing is in
+        the seq-parallel lane queues them behind its own barrier, when
+        nothing is in
         flight, so they predate every dispatched block; each block's
         [k, S] rows are then emitted in step order per live slot,
         truncated per request at its stop token / max_new by _emit.
@@ -2626,20 +2395,19 @@ class Scheduler:
         for ent, vals in zip(blocks, block_vals):
             kind, _, _, _, snapshot, t_dispatch = ent[:6]
             self._h_decode_block.observe(now - t_dispatch)
-            if kind in ("mixed", "mixed_spec"):
-                # prefill lanes that completed inside this block leave
-                # the prefill group BEFORE their first token (riding
-                # the block's emission arrays) is emitted below
-                self._mixed_transitions(ent[6], snapshot)
+            # prefill lanes that completed inside this block leave
+            # the prefill group BEFORE their first token (riding
+            # the block's emission arrays) is emitted below
+            self._mixed_transitions(ent[6], snapshot)
             if kind == "mixed_spec":
                 toks3, valid3 = vals  # [rounds, S, C] each
                 with self._span("spec_emit"):
                     self._emit_spec(toks3, valid3, snapshot)
                 continue
-            # [k, S] tokens; a mixed block adds the validity mask: a
+            # [k, S] tokens and their validity mask: a
             # lane emits at most one token per step, valid only on
             # decode steps and the completion step's first token
-            rows, ok, *load = vals if kind == "mixed" else (vals, None)
+            rows, ok, *load = vals
             if load and self._experts_held:
                 # [.., local, routed] summed over the block's steps
                 sh = self._tick_share = self._tick_share or [0.0, 0.0]
@@ -2683,8 +2451,7 @@ class Scheduler:
                 # live slot instead of k per-element int(row[slot])
                 # casts over the whole [k, S] block (O(k*S) Python work
                 # per drain at S=32, k=16)
-                toks = rows[:, slot] if ok is None \
-                    else rows[:, slot][ok[:, slot]]
+                toks = rows[:, slot][ok[:, slot]]
                 for tok in toks.tolist():
                     self._next_tokens[slot] = tok
                     self._emit(req, tok)
@@ -2696,8 +2463,7 @@ class Scheduler:
         prefill lanes: members whose prompt finished inside the block
         (the dispatch-time host simulation recorded the set) leave the
         prefill group and start decoding. Pages publish for prefix
-        reuse exactly where the alternating path's _finish_prefill did
-        it — after a point where every staged K/V byte is flushed
+        reuse after a point where every staged K/V byte is flushed
         (this drain flushed the window first). The generation check
         skips members cancelled or preempted since dispatch."""
         for slot in pf_slots:
@@ -2741,11 +2507,10 @@ class Scheduler:
             t_rows = toks3[:, slot, :].tolist()
             v_rows = valid3[:, slot, :].tolist()
             for r in range(R):
-                # mixed dispatch: a round that emits the request's very
+                # a round that emits the request's very
                 # first token is the prefill-completion round, not a
                 # verify round — it must not count as a zero-acceptance
-                # observation (the alternating path's first token never
-                # passes through here either)
+                # observation
                 first_round = req.t_first_token is None
                 cnt = 0
                 for tok, ok in zip(t_rows[r], v_rows[r]):
@@ -2861,7 +2626,7 @@ class Scheduler:
         while a dispatched block still writes them); only then preempt
         the youngest live request (possibly req itself) until it fits —
         older requests always win page pressure. The victim pool
-        includes partially-prefilled gang members: a young mid-prefill
+        includes partially-prefilled members: a young mid-prefill
         admission is the cheapest eviction (no generated tokens to
         recompute) and must not be able to starve an older decoding
         request of pages."""
@@ -2910,7 +2675,7 @@ class Scheduler:
         """Recompute-style preemption: free pages, requeue at the front.
         With prefix caching the pages stay warm in the registry, so
         readmission's "recompute" is usually a cache hit. The victim may
-        be a partially-prefilled gang member (state "prefilling"): its
+        be a partially-prefilled member (state "prefilling"): its
         prefilled-so-far pages register for reuse like any other and it
         restarts its prompt on readmission."""
         self._epoch += 1  # batch membership changes below

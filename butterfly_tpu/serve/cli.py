@@ -146,15 +146,12 @@ def build_parser() -> argparse.ArgumentParser:
                         "to amortize per-token host overhead (tokens "
                         "then surface in bursts)")
     s.add_argument("--prefill-max-batch", type=positive_int, default=8,
-                   help="max waiting requests gang-admitted into ONE "
-                        "batched [B, Tbucket] prefill dispatch per "
-                        "scheduler tick (group admission). A burst of "
-                        "arrivals prefills as a group under the "
-                        "prefill-chunk token budget instead of one "
-                        "prompt per tick — the TTFT lever under bursty "
-                        "load. B buckets to powers of two clamped "
-                        "here, so raising it adds at most one compiled "
-                        "program per prompt-length bucket")
+                   help="how many one-token requests the warm-up submits "
+                        "as a burst before the server listens. Nothing "
+                        "else reads it since the batched prefill "
+                        "dispatch it capped went: a burst of arrivals "
+                        "rides the fused blocks' chunks, bounded by "
+                        "prefill_inline_budget a step")
     s.add_argument("--seq-parallel-threshold", type=int, default=0,
                    help="long-context admission lane: prompts LONGER "
                         "than this many tokens prefill through chunked "

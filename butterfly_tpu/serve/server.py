@@ -1440,8 +1440,8 @@ def run_server(args) -> int:
         if start_profiler_server(prof_port):
             print(f"[butterfly] xprof profiler server on :{prof_port}",
                   flush=True)
-    # Warm the serving programs (fresh-chunk prefill, warm-chunk
-    # continuation, batched decode) before listening: the first user
+    # Warm the serving programs (the mixed block and the decode block)
+    # before listening: the first user
     # doesn't pay 20-40s of XLA compile, and the heartbeat watchdog
     # never mistakes the startup compile for a dead device.
     print("[butterfly] warming serving programs...", flush=True)
@@ -1452,11 +1452,10 @@ def run_server(args) -> int:
     # a tick.
     warm_new = 2 * rt.decode_steps_per_tick + 2
     warm_len = min(2 * rt.prefill_chunk, rt.max_seq_len - warm_new - 2)
-    # a full gang of smallest-bucket prompts first (compiles the widest
-    # [B, 16] batched-prefill program a burst will hit), then the long
-    # chunked prompt (fresh + warm-continuation [1, T] buckets)
-    gang = max(1, min(rt.prefill_max_batch, rt.max_batch_size))
-    warms = [sched.submit([1], max_new_tokens=2) for _ in range(gang)]
+    # a burst of one-token prompts first, then the long prompt, which
+    # crosses chunks and blocks
+    burst = max(1, min(rt.prefill_max_batch, rt.max_batch_size))
+    warms = [sched.submit([1], max_new_tokens=2) for _ in range(burst)]
     warms.append(sched.submit([1] * max(1, warm_len),
                               max_new_tokens=warm_new))
     sched.run_until_done()

@@ -43,7 +43,7 @@ def pytest_configure(config):
 
 
 #: Two-tier suite: `-m "not slow"` is tier-1, what the driver runs after
-#: every PR (`-n 6 --dist loadfile`). It holds the engine/scheduler/cache/server parity on tiny
+#: every PR (`-n 6 --dist load`). It holds the engine/scheduler/cache/server parity on tiny
 #: models, the benchmark's own tests, and the parity of the code both
 #: benchmark cells run: the paged forward (test_paged.py), the Pallas
 #: kernels against their jnp twins in interpret mode (test_kernels.py),
@@ -84,7 +84,6 @@ SLOW_TESTS = {
     "test_preemption_under_page_pressure",
     "test_chunked_prefill_parity",
     "test_chunked_prefill_interleaves_decode",
-    "test_static_scheduler_drains_batches",
     "test_stop_token_frees_slot",
     "test_request_sized_to_page_cap_completes",
     # fused-block scenarios that compile a second scheduler / a wide
@@ -92,14 +91,10 @@ SLOW_TESTS = {
     # parity test decodes through it, incl. test_decode_steps_per_tick)
     "test_fused_block_greedy_parity",
     "test_fused_block_seeded_sampling_reproducible",
-    # batched group-prefill scenarios that compile a second scheduler
-    # or several reference engines (the fast tier still covers the gang
-    # path: prefill_max_batch defaults to 8, so every core parity test
-    # prefills through batched dispatches, and
-    # test_gang_admission_single_tick pins the one-dispatch property)
-    "test_batched_prefill_parity",
+    # burst-of-prompts scenarios that run several reference engines
+    # (the fast tier still covers a burst riding the blocks' chunks:
+    # tests/test_mixed_dispatch.py's grid)
     "test_batched_prefill_budget_and_carry",
-    "test_mixed_warm_cold_group_admission",
     "test_preempt_partially_prefilled_group_member",
     "test_prefill_group_member_is_preemption_victim",
     # dispatch-ahead scenarios that compile a second scheduler / run a

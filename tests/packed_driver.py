@@ -1,7 +1,8 @@
 """What engine._packed_scan does around one packed step, by hand, for
 the tests of a model with recurrent layers (tests/test_granite_hybrid.py:
 Mamba-2; tests/test_olmo_hybrid.py: Gated DeltaNet; tests/test_jamba.py:
-Mamba-1): the driver, the
+Mamba-1) and, for the run with an idle chunk, of one with attention
+alone (tests/test_mixed_dispatch.py): the driver, the
 scripted run both families go through, and the readers of a parameter
 tree and of an error that both compare with."""
 import jax
@@ -9,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from butterfly_tpu.cache.paged import (
-    flush_paged_window, init_kv_window, init_paged_cache,
+    flush_paged_window, init_kv_window, init_paged_cache, paged_forward,
     paged_forward_packed)
 from butterfly_tpu.cache.ssm_state import init_ssm_state
 from butterfly_tpu.core.config import RuntimeConfig
@@ -103,12 +104,14 @@ class Packed:
             cslot, count = chunk[0], len(chunk[1])
             ctok[0, :count] = chunk[1]
         rest = [0] * self.idle
-        logits, kv, load, self.state = _packed_step(
+        # a model without recurrent layers returns no state
+        logits, kv, load, *st = _packed_step(
             self.params, self.cfg, jnp.asarray(toks), self.cache,
             jnp.asarray(ctok), jnp.asarray([cslot] + rest),
             jnp.asarray([count] + rest),
             jnp.asarray(active), self.window, self.wlen, state=self.state,
             use_kernel=self.use_kernel)
+        self.state = st[0] if st else None
         adv = jnp.asarray(active, jnp.int32).at[cslot].add(count)
         if self.window is not None:
             self.window, self.wlen = kv, self.wlen + adv
@@ -121,13 +124,27 @@ class Packed:
         return {s: np.asarray(logits[s]) for s in heads}
 
 
-def idle_chunk_run(params, seq, cfg):
+def lane_wide_chunk(eng, slot, tokens):
+    """One chunk of one slot's prompt through a ServingEngine's pool by
+    the lane-wide forward (cache/paged.py paged_forward: every other
+    slot inactive), at the slot's written length. Returns the chunk's
+    last-position logits [V]; the engine's cache holds the chunk."""
+    eng._sync_table()
+    buf = np.zeros((eng.num_slots, len(tokens)), np.int32)
+    buf[slot] = tokens
+    logits, eng.cache = paged_forward(
+        eng.params, eng.cfg, jnp.asarray(buf), eng.cache,
+        active=jnp.arange(eng.num_slots) == slot)
+    return logits[slot, -1]
+
+
+def idle_chunk_run(params, seq, cfg, idle=1, **driver):
     """Slot 0 takes seq's first 17 tokens as chunks of 6 (6, 6, 5) and
     decodes two more while slot 1 decodes seq beside it from the first
-    step, every step carrying a second chunk that is IDLE (its slot
-    reads 0, the slot the real chunk writes). Returns ([(slot,
+    step, every step carrying `idle` more chunks that are IDLE (their
+    slot reads 0, the slot the real chunk writes). Returns ([(slot,
     position, logits)], the driver)."""
-    drv, out = Packed(params, cfg, idle=1), []
+    drv, out = Packed(params, cfg, idle=idle, **driver), []
     for t, lo in enumerate((0, 6, 12)):
         got = drv.step({1: seq[t]}, (0, seq[lo:min(lo + 6, 17)]))
         out += [(0, min(lo + 6, 17) - 1, got[0]), (1, t, got[1])]

@@ -49,7 +49,7 @@ def _step_win(params, toks, cache, window, wlen):
 class WindowEngine:
     """Blessed window-carry pattern (ISSUE 12): every donated carry —
     cache, staged-window buffer, staged count — is rebound from the
-    result before any later read (serving.py decode_block_async)."""
+    result before any later read (serving.py mixed_block_async)."""
 
     def __init__(self):
         self._win_progs = {}

@@ -59,7 +59,7 @@ def _step_win(params, toks, cache, window, wlen):
 class WindowEngine:
     """The write-combined-window carry: one program donates the cache
     AND the staged-window buffer + count (serving.py's
-    _decode_block_win_prog shape)."""
+    _mixed_block_prog shape)."""
 
     def __init__(self):
         self._win_progs = {}

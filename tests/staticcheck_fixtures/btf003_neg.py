@@ -12,16 +12,16 @@ class Sched:
         # operand assembly from host lists is host->host, not a sync
         temps = np.asarray([r.temperature for r in self.running])
         active = np.zeros((8,), bool)
-        return self._decode_block(4), temps, active
+        return self._mixed_block(4), temps, active
 
-    def _decode_block(self, k: int):
+    def _mixed_block(self, k: int):
         budgets = np.maximum(self._base - k, 0)   # numpy math, no fetch
         return budgets
 
-    def prefill_batch(self, slots: List[int], chunks: list):
+    def sp_prefill_chunk(self, slot: int, tokens: List[int]):
         # annotated host-container params: asarray over them is assembly
-        rows = np.asarray(slots, np.int32)
-        return rows
+        buf = np.asarray(tokens, np.int32)
+        return buf
 
     def _drain_blocks(self, blocks):
         # the drain is the one blessed fetch point (not a hot function)

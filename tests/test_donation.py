@@ -64,21 +64,28 @@ def test_serving_paths_alias(tiny_model):
     eng = ServingEngine(model, params, rt)
     alloc = PageAllocator(64, 16, 8)
     eng.set_table_row(0, alloc.grow(0, 64))
+    cursor = eng.carry(np.zeros(4, np.int32))
+    pbuf = np.zeros((4, 128), np.int32)
+    pbuf[0, :5] = [1, 2, 3, 4, 5]
+    plen = np.array([5, 0, 0, 0], np.int32)
+    active = np.array([1, 0, 0, 0], bool)
+    temps = np.zeros(4, np.float32)
+    stops = np.full(4, -1, np.int32)
+    budgets = np.full(4, 12, np.int32)
     with _NoDonationWarnings():
-        eng.prefill_slot(0, [1, 2, 3, 4, 5])
-        toks = np.zeros(4, np.int32)
-        active = np.array([1, 0, 0, 0], np.int32)
-        temps = np.zeros(4, np.float32)
-        for i in range(3):
-            toks, _ = eng.decode_active(toks, active, temps,
-                                        jax.random.PRNGKey(i))
-        # fused decode block: the scan-carried pools must alias too
-        # (a non-aliasing carry would keep a second pool live for the
-        # whole block — the exact cost the fusion exists to avoid)
-        stops = np.full(4, -1, np.int32)
-        budgets = np.full(4, 4, np.int32)
-        eng.decode_block_async(toks, active, temps, stops, budgets,
-                               jax.random.PRNGKey(9), 4)
+        # a block with a chunk takes the prompt in, then blocks without
+        # one decode: the scan-carried window and the pool beside it
+        # must alias in both (a non-aliasing carry would keep a second
+        # copy live for the whole block — the cost the fusion exists to
+        # avoid)
+        _, _, toks, cursor = eng.mixed_block_async(
+            np.zeros(4, np.int32), cursor, pbuf, plen, active, temps,
+            stops, budgets, jax.random.PRNGKey(0), 2, 8, 1)
+        for i in range(2):
+            _, _, toks, cursor = eng.mixed_block_async(
+                toks, cursor, pbuf, plen, active, temps, stops, budgets,
+                jax.random.PRNGKey(1 + i), 4, 8, 0)
+        eng.flush_kv_window()
 
 
 def test_pipeline_generate_aliases(tiny_model):

@@ -1,5 +1,5 @@
-"""A finish is taken at the lazy drain (ISSUE 40): under mixed dispatch
-without speculation a request that ends in the oldest block in flight
+"""A finish is taken at the lazy drain (ISSUE 40): without speculation
+and without the seq-parallel lane a request that ends in the oldest block in flight
 is finished THERE, with the newer block still on the device; no full
 barrier runs, the freed slot and pages go to a waiter in the same tick,
 and the block that tick dispatches chains on the newest one in flight.
@@ -10,7 +10,8 @@ argument for each):
 * (i) the finished request's lane is dead from the first step of every
   newer block, after a budget finish and after a stop-token finish: the
   tokens every request is served equal those it is served alone, one
-  request at a time, by the alternating path, which keeps every barrier;
+  request at a time and ONE block in flight, where nothing newer than
+  the block a finish surfaces in exists;
 * (ii) the slot's next request starts with its OWN budget: a newer
   block's emission estimate for the slot's old request is not
   subtracted from it (positive after a stop-death);
@@ -27,8 +28,8 @@ argument for each):
 
 and the counters: `drain_barriers_total{cause="finish"}` stays 0,
 `finishes_inline_total` counts every finish a lazy drain took, the tick
-record carries the tick's. The speculative and the alternating paths
-keep their barrier.
+record carries the tick's. The speculative path and a scheduler with
+the seq-parallel lane keep their barrier.
 """
 import jax
 import pytest
@@ -63,10 +64,10 @@ def make_sched(mesh=None, **rt_kw):
 
 def alone(jobs, **rt_kw):
     """Every job served ALONE, one request at a time in the order given,
-    by the alternating path (every barrier kept, nothing in flight at a
-    finish), in one scheduler so that a prefix cache fills as it does in
-    the run under test."""
-    sched = make_sched(mixed_dispatch=False, **rt_kw)
+    one block in flight (nothing newer in flight at a finish), in one
+    scheduler so that a prefix cache fills as it does in the run under
+    test."""
+    sched = make_sched(**{**rt_kw, "inflight_blocks": 1})
     outs = []
     for prompt, max_new, stop in jobs:
         r = sched.submit(list(prompt), max_new_tokens=max_new,
@@ -368,19 +369,22 @@ def test_finish_at_the_lazy_drain_under_the_mesh():
     assert len(lengths.sharding.device_set) > 1
 
 
-@pytest.mark.parametrize("rt_kw,inline", [
-    (dict(), True),
-    (dict(mixed_dispatch=False), False),
-    (dict(speculative_gamma=3), False),
-    (dict(scheduler="static"), False),
-], ids=["mixed", "alternating", "mixed-spec", "static"])
-def test_which_paths_take_a_finish_without_a_barrier(rt_kw, inline):
-    """SEPARATE by mode: mixed dispatch without speculation takes a
-    finish at the lazy drain; the alternating path, the speculative
-    path and the static scheduler keep the full barrier, and count
-    nothing under finishes_inline_total."""
+@pytest.mark.parametrize("rt_kw,seq,inline", [
+    (dict(), 0, True),
+    (dict(speculative_gamma=3), 0, False),
+    (dict(seq_parallel_threshold=64), 2, False),
+], ids=["mixed", "mixed-spec", "seq-lane"])
+def test_which_paths_take_a_finish_without_a_barrier(rt_kw, seq, inline):
+    """SEPARATE by mode: the plain mixed block takes a
+    finish at the lazy drain; the speculative
+    path and a scheduler whose mesh gives it the seq-parallel lane (its
+    prompts here are all under the lane's threshold) keep the full
+    barrier, and count nothing under finishes_inline_total."""
     jobs = _staggered(n=5)
-    sched = make_sched(**rt_kw)
+    mesh = make_mesh(MeshConfig(seq=seq), jax.devices()[:seq]) if seq \
+        else None
+    sched = make_sched(mesh=mesh, **rt_kw)
+    assert sched._sp_enabled is bool(seq)
     assert sched._finish_inline is inline
     reqs = [sched.submit(list(p), max_new_tokens=n) for p, n, _ in jobs]
     sched.run_until_done()
@@ -394,5 +398,4 @@ def test_which_paths_take_a_finish_without_a_barrier(rt_kw, inline):
         assert m["finishes_inline_total"] >= 3
     else:
         assert m["finishes_inline_total"] == 0
-        assert rt_kw.get("scheduler") == "static" \
-            or causes.get("finish", 0) >= 1
+        assert causes.get("finish", 0) >= 1

@@ -15,6 +15,7 @@ import pytest
 
 import packed_driver
 from packed_driver import err, forward, leaf_of
+from butterfly_tpu.cache.paged import paged_forward, paged_forward_window
 from butterfly_tpu.cache.ssm_state import (
     _held, bytes_per_slot, init_ssm_state, state_info, state_shapes)
 from butterfly_tpu.core.config import (
@@ -782,6 +783,13 @@ def _fused_generate():
     decode_step_win(None, CFG, None, None, [], 0)
 
 
+def _lane_wide(forward):
+    """cache/paged.py's lane-wide forwards refuse the model before they
+    read an argument: what still calls them (the speculative block's
+    verify) does not carry what this model caches."""
+    return forward(None, CFG, *[None] * 4)
+
+
 #: name -> the call; those that take `engine` run on the module's one
 REFUSALS = {
     "prefix caching": lambda e: _engine(prefix_caching=True),
@@ -796,10 +804,8 @@ REFUSALS = {
     "tensor parallelism": lambda e: _engine(mesh=_mesh("tensor")),
     "data-parallel mesh": lambda e: _engine(mesh=_mesh("data")),
     "speculative": lambda e: _engine(speculative_gamma=2),
-    "alternating prefill/decode path":
-        lambda e: _engine(mixed_dispatch=False),
-    "paged_forward": lambda e: e.prefill_slot(0, [1, 2, 3]),
-    "static scheduler": lambda e: _engine(scheduler="static"),
+    "paged_forward_window": lambda e: _lane_wide(paged_forward_window),
+    "lane-wide forward \\(paged_forward": lambda e: _lane_wide(paged_forward),
     "int8 contiguous KV cache":
         lambda e: init_cache(CFG, 1, 16, quant="int8"),
     "write-combined fused generate": lambda e: _fused_generate(),

@@ -15,7 +15,8 @@ import pytest
 
 from butterfly_tpu.cache.paged import (
     LANES, flush_paged_window, init_kv_window, init_paged_cache,
-    paged_forward_packed, pool_layout, pool_row)
+    paged_forward, paged_forward_packed, paged_forward_window, pool_layout,
+    pool_row)
 from butterfly_tpu.core.config import (
     PRESETS, ModelConfig, RuntimeConfig, joyai_llm_flash, tiny)
 from servebench.references import joyai_f32 as ref
@@ -623,6 +624,13 @@ def _param_specs():
     param_specs(CFG, _mesh("tensor"))
 
 
+def _lane_wide(forward):
+    """cache/paged.py's lane-wide forwards refuse the model before they
+    read an argument: what still calls them (the speculative block's
+    verify) does not carry what this model caches."""
+    return forward(None, CFG, *[None] * 4)
+
+
 REFUSALS = {
     "int8 KV cache": lambda: _engine(kv_quant="int8"),
     "int8 contiguous KV cache": lambda: init_cache(CFG, 1, 16, quant="int8"),
@@ -637,9 +645,8 @@ REFUSALS = {
     "host KV tier": lambda: _engine(prefix_caching=True, host_kv_tier_mb=1),
     "prefix caching": lambda: _engine(prefix_caching=True),
     "speculative": lambda: _engine(speculative_gamma=2),
-    "alternating prefill/decode path": lambda: _engine(mixed_dispatch=False),
-    "paged_forward": lambda: _engine().prefill_slot(0, [1, 2, 3]),
-    "static scheduler": lambda: _engine(scheduler="static"),
+    "paged_forward_window": lambda: _lane_wide(paged_forward_window),
+    "lane-wide forward \\(paged_forward": lambda: _lane_wide(paged_forward),
     "write-combined fused generate": _fused_generate,
 }
 

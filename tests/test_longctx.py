@@ -11,10 +11,10 @@ Four layers, bottom up:
   correction `exp(m_a - m)` is load-bearing here: without it the
   merge diverges from dense);
 * engine surface: `sp_prefill_chunk` (seq=4 mesh, int8 KV) vs the
-  dense `prefill_chunk` logits, chunk by chunk;
+  lane-wide forward's logits on one device, chunk by chunk;
 * scheduler: long prompts admitted through the seq-parallel lane
-  (chunked SP prefill -> ordinary paged decode) match the dense-path
-  scheduler token for token, and the pages the lane writes are
+  (chunked SP prefill -> ordinary paged decode) match the scheduler
+  without the lane token for token, and the pages the lane writes are
   prefix-registry-visible on resubmission.
 """
 import functools
@@ -32,6 +32,8 @@ from butterfly_tpu.ops.ring_attention import (
     finalize_stats, merge_stats, ring_block_stats, ring_block_stats_ref,
     zero_stats)
 from butterfly_tpu.sched.scheduler import Scheduler
+from packed_driver import lane_wide_chunk
+from test_sched import _HostOnly
 
 
 # ---------------------------------------------------------------------------
@@ -150,7 +152,7 @@ SHORT = [int(t) for t in (np.arange(12) * 5 + 1) % 256]
 
 def test_sp_chunk_prefill_int8_logits_parity(tiny_model, sp_mesh):
     """Fast-tier anchor: seq-parallel chunk prefill with int8 KV matches
-    the dense chunk path's logits chunk for chunk (dequant happens inside
+    the lane-wide forward's logits chunk for chunk (dequant happens inside
     the ring blocks — the engine-level guard that used to reject this
     combination is gone)."""
     model, params = tiny_model
@@ -165,26 +167,24 @@ def test_sp_chunk_prefill_int8_logits_parity(tiny_model, sp_mesh):
     dense.set_table_row(0, pages)
     sp.set_table_row(0, pages)
     for lo, hi in ((0, 24), (24, 40)):
-        ld = dense.prefill_chunk(0, prompt[lo:hi], lo)
+        ld = lane_wide_chunk(dense, 0, prompt[lo:hi])
         ls = sp.sp_prefill_chunk(0, prompt[lo:hi], lo)
         np.testing.assert_allclose(np.asarray(ls), np.asarray(ld),
                                    rtol=3e-4, atol=3e-4)
     assert int(np.asarray(jax.device_get(sp.cache.lengths))[0]) == 40
 
 
-@pytest.mark.parametrize("mode,kvq", [
-    ("alternating", "none"), ("alternating", "int8"), ("mixed", "none"),
-], ids=["alt-float", "alt-int8", "mixed-float"])
-def test_sp_sched_long_prefill_parity(tiny_model, sp_mesh, mode, kvq):
+@pytest.mark.parametrize("kvq", ["none", "int8"], ids=["float", "int8"])
+def test_sp_sched_long_prefill_parity(tiny_model, sp_mesh, kvq):
     """A long prompt (above seq_parallel_threshold) admitted through the
     scheduler's SP lane plus a concurrent short prompt on the normal
-    path: both must match the dense-path scheduler token for token, and
+    path: both must match the scheduler without the lane (the long
+    prompt a block's chunks) token for token, and
     the lane must actually have dispatched SP chunks."""
     model, params = tiny_model
     rt = RuntimeConfig(max_batch_size=2, page_size=16, max_seq_len=160,
                        kv_quant=kvq, prefill_chunk=16,
-                       seq_parallel_threshold=64,
-                       mixed_dispatch=(mode == "mixed"))
+                       seq_parallel_threshold=64)
     sp = Scheduler(ServingEngine(model, params, rt, mesh=sp_mesh), seed=0)
     assert sp._sp_enabled
     dn = Scheduler(ServingEngine(
@@ -217,3 +217,73 @@ def test_prefix_hit_after_long_prefill(tiny_model, sp_mesh):
     s.run_until_done()
     assert b.cached_at_admit > 0
     assert a.output == b.output
+
+
+# ---------------------------------------------------------------------------
+# the lane's first token: sampled on the device where its last chunk
+# ran, queued (Scheduler._pending_first) and fetched with the next drain
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def sp2_mesh():
+    return make_mesh(MeshConfig(seq=2), jax.devices()[:2])
+
+
+def _lane_sched(tiny_model, mesh, **rt_kw):
+    model, params = tiny_model
+    rt = RuntimeConfig(max_batch_size=2, page_size=8, max_seq_len=64,
+                       prefill_chunk=16, seq_parallel_chunk=16,
+                       seq_parallel_threshold=12 if mesh else 0, **rt_kw)
+    return Scheduler(ServingEngine(model, params, rt, mesh=mesh), seed=0)
+
+
+def test_lane_first_token_is_pending_until_the_next_drain(tiny_model,
+                                                          sp2_mesh):
+    """The (id, preemptions)-keyed index over undrained first tokens is
+    filled where the lane's prompt completes and cleared by the drain
+    that fetches them; until then every prompt token's K/V is written
+    and _written subtracts nothing (it would lose a page of prefix
+    registration at a page boundary: the prompt is exactly two pages)."""
+    sched = _lane_sched(tiny_model, sp2_mesh, inflight_blocks=1)
+    req = sched.submit(LONG[:16], max_new_tokens=4)
+    sched.tick()    # one lane chunk takes the whole prompt
+    assert req.state == "running" and req.output == []
+    assert (req.id, req.preemptions) in sched._pending_first_keys
+    assert [f[0] for f in sched._pending_first] == [req]
+    assert sched._written(req) == 16    # the whole prompt, no -1
+    sched.tick()    # the drain: the first token lands on the host
+    assert len(req.output) >= 1
+    assert not sched._pending_first_keys and not sched._pending_first
+    # once drained, the last sampled token's K/V is indeed unwritten
+    assert sched._written(req) == len(req.all_tokens) - 1
+    sched.run_until_done()
+    assert req.state == "finished" and len(req.output) == 4
+
+
+def test_lane_first_tokens_ride_the_drain_s_one_fetch(tiny_model, sp2_mesh):
+    """A pending first token rides the same fetch as the blocks: read as
+    it is in the drain's one `jax.device_get`, no program launched to
+    read it; and the tokens are the scheduler's without the lane."""
+    sched = _lane_sched(tiny_model, sp2_mesh)
+    req = sched.submit(LONG[:16], max_new_tokens=6)
+    for _ in range(4):
+        if sched._pending_first:
+            break
+        sched.tick()
+    assert len(sched._pending_first) == 1
+
+    sched._pending_first = [f[:3] + (_HostOnly(f[3]),)
+                            for f in sched._pending_first]
+    calls, real = [], jax.device_get
+    try:
+        jax.device_get = lambda x: calls.append(x) or real(x)
+        sched._drain_inflight("idle")
+    finally:
+        jax.device_get = real
+    assert len(calls) == 1 and len(calls[0][0]) == 1
+    sched.run_until_done()
+    plain = _lane_sched(tiny_model, None)
+    assert not plain._sp_enabled
+    want = plain.submit(LONG[:16], max_new_tokens=6)
+    plain.run_until_done()
+    assert req.output == want.output

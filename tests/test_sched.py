@@ -26,10 +26,22 @@ def make_sched(max_batch=2, max_seq=64, page=8, num_pages=0, seed=0, **rt_kw):
     return Scheduler(ServingEngine(model, params, rt), seed=seed), params
 
 
+_REF = {}
+
+
 def ref_tokens(params, prompt, max_new):
-    eng = InferenceEngine(Model(CFG), params)
-    res = eng.generate([prompt], SamplingParams(max_new_tokens=max_new))
-    return res.tokens[0, :int(res.lengths[0])].tolist()
+    """The contiguous engine's greedy tokens. ONE engine for the module
+    (every scheduler here is built over make_sched's seeded parameters)
+    and every answer kept: an engine a call compiled its programs anew
+    each time."""
+    key = (tuple(prompt), max_new)
+    if key not in _REF:
+        if "engine" not in _REF:
+            _REF["engine"] = InferenceEngine(Model(CFG), params)
+        res = _REF["engine"].generate(
+            [prompt], SamplingParams(max_new_tokens=max_new))
+        _REF[key] = res.tokens[0, :int(res.lengths[0])].tolist()
+    return list(_REF[key])
 
 
 def test_single_request_greedy_parity():
@@ -176,14 +188,13 @@ def test_oversized_request_rejected_at_submit():
 
 
 def test_cancel_running_request_frees_resources():
-    # mixed_dispatch=False: documents the ALTERNATING path's cadence
-    # (prefill completes inside the admission tick); the fused-path
-    # twins live in test_mixed_dispatch.py
-    sched, _ = make_sched(mixed_dispatch=False)
+    sched, _ = make_sched()
     r1 = sched.submit([5, 7], max_new_tokens=50)
     r2 = sched.submit([3], max_new_tokens=4)
-    sched.tick()
-    assert r1.state == "running"
+    # a prompt that completed inside a block runs from that block's drain
+    for _ in range(3):
+        sched.tick()
+    assert r1.state == "running" and sched._inflight
     sched.cancel(r1)
     assert r1.state == "cancelled" and r1.slot is None
     sched.run_until_done()
@@ -244,19 +255,18 @@ def test_cancel_mid_prefill_frees_resources():
 def test_decode_steps_per_tick():
     # inflight_blocks=1: the synchronous drain-every-tick cadence this
     # test documents (the pipelined cadence has its own tests below)
-    sched, params = make_sched(decode_steps_per_tick=3, inflight_blocks=1,
-                               mixed_dispatch=False)
+    sched, params = make_sched(decode_steps_per_tick=3, inflight_blocks=1)
     req = sched.submit([5, 7, 11], max_new_tokens=10)
-    # admission samples the first token on-device and the tick's 3
-    # decode steps are dispatched chained on it; everything drains in
+    # the block's first step takes the prompt in and samples the first
+    # token on the device, its other two decode; everything drains in
     # one stacked fetch at the NEXT tick's start (scheduler._inflight
-    # docs), so the host sees 1+3 tokens one tick later
+    # docs), so the host sees 1+2 tokens one tick later
     sched.tick()
     assert len(req.output) == 0
-    sched.tick()  # drains first + 3 in-flight steps, dispatches 3 more
-    assert len(req.output) == 4
+    sched.tick()  # drains first + 2 decode steps, dispatches 3 more
+    assert len(req.output) == 3
     sched.tick()
-    assert len(req.output) == 7
+    assert len(req.output) == 6
     sched.run_until_done()
     assert req.output == ref_tokens(params, [5, 7, 11], 10)
 
@@ -272,22 +282,6 @@ def test_request_sized_to_page_cap_completes():
     sched.run_until_done(max_ticks=200)
     assert req.state == "finished"
     assert req.output == ref_tokens(params, prompt, 8)
-
-
-def test_static_scheduler_drains_batches():
-    """scheduler="static": a waiting request is only admitted once the
-    in-flight batch has fully drained (no continuous admission)."""
-    sched, params = make_sched(max_batch=2, scheduler="static")
-    r1 = sched.submit([5, 7, 11], max_new_tokens=3)
-    r2 = sched.submit([3, 1], max_new_tokens=6)
-    sched.tick()
-    r3 = sched.submit([9], max_new_tokens=2)
-    while r3.state == "waiting":
-        sched.tick()
-    # r3 was only admitted after BOTH batch members finished
-    assert r1.done and r2.done
-    sched.run_until_done()
-    assert r3.output == ref_tokens(params, [9], 2)
 
 
 def test_cancel_waiting_request():
@@ -417,17 +411,15 @@ def test_speculative_parity_grid(grid_want, k, depth):
     assert [r.output for r in got] == grid_want
 
 
-@pytest.mark.parametrize("rt_kw", [
-    dict(mixed_dispatch=False), dict(scheduler="static"),
-], ids=["alternating", "static"])
-def test_speculation_refused_off_the_packed_path(rt_kw):
-    """Speculation has one path: asked for with mixed_dispatch off or
-    the static scheduler, the engine refuses where it is built and
-    names both fields; nothing falls back in silence."""
-    with pytest.raises(ValueError) as err:
-        make_sched(speculative_gamma=2, **rt_kw)
-    assert all(n in str(err.value) for n in (
-        "speculative_gamma", "mixed_dispatch", "scheduler"))
+@pytest.mark.parametrize("field", ["mixed_dispatch", "scheduler",
+                                   "prefill_flash_warm"])
+def test_the_options_of_the_paths_that_went_are_no_fields(field):
+    """One dispatch path: the options that selected another are gone
+    from RuntimeConfig, and passing one fails where it is passed."""
+    with pytest.raises(TypeError, match=field):
+        RuntimeConfig(**{field: True})
+    with pytest.raises(TypeError, match=field):
+        RuntimeConfig().replace(**{field: True})
 
 
 def test_speculative_pipelines_without_per_round_barriers():
@@ -497,7 +489,7 @@ def test_speculative_scheduler_stop_token():
     assert got.output == want.output
 
 
-# -- fused decode block (engine._decode_scan, ISSUE 3) ----------------------
+# -- fused block (engine._packed_scan; ISSUE 3) -------------------------------
 
 
 def test_fused_block_greedy_parity():
@@ -565,93 +557,20 @@ def test_fused_block_eos_mid_block():
     assert sched.alloc.free_pages == sched.alloc.num_pages
 
 
-# -- batched group prefill (engine.prefill_batch, ISSUE 4) ------------------
-
-
-def test_batched_prefill_parity():
-    """Tentpole contract: N requests gang-admitted and prefilled as ONE
-    [B, Tbucket] dispatch produce token-for-token the same outputs as
-    sequential single-slot prefill (prefill_max_batch=1) and as the
-    offline reference, across members with different prompt lengths."""
-    # alternating path: batched prefill dispatches only exist there
-    # (mixed dispatch rides prompts inside the fused decode block)
-    seq, params = make_sched(max_batch=4, max_seq=64, prefill_max_batch=1,
-                             mixed_dispatch=False)
-    gang, _ = make_sched(max_batch=4, max_seq=64, prefill_max_batch=4,
-                         mixed_dispatch=False)
-    prompts = [[5, 7, 11], [3, 3, 3, 3, 3], [2], list(range(1, 9))]
-    want = [seq.submit(p, max_new_tokens=10) for p in prompts]
-    seq.run_until_done()
-    got = [gang.submit(p, max_new_tokens=10) for p in prompts]
-    gang.run_until_done()
-    assert [r.output for r in got] == [r.output for r in want]
-    assert want[0].output == ref_tokens(params, prompts[0], 10)
-    # the gang really was ONE dispatch of 4 (all chunks share the
-    # 16-token bucket); the sequential control was 4 dispatches of 1
-    h = gang.registry.get("prefill_batch_size")
-    assert h.count == 1 and h.sum == 4
-    h = seq.registry.get("prefill_batch_size")
-    assert h.count == 4 and h.sum == 4
-
-
-def test_gang_admission_single_tick():
-    """A burst of waiting requests is admitted AND fully prefilled in
-    one tick when budget and slots allow — the gang property that cuts
-    burst TTFT (previously: one [1, T] dispatch per prompt)."""
-    sched, _ = make_sched(max_batch=4, prefill_max_batch=4,
-                          mixed_dispatch=False)
-    reqs = [sched.submit([i + 1, i + 2], max_new_tokens=4)
-            for i in range(4)]
-    sched.tick()
-    assert all(r.state == "running" for r in reqs)
-    assert sched.registry.get("prefill_batch_size").count == 1
-    sched.run_until_done()
-    assert all(r.state == "finished" for r in reqs)
+# -- a burst of prompts rides the blocks' chunks ------------------------------
 
 
 def test_batched_prefill_budget_and_carry():
-    """A gang whose chunk demand exceeds prefill_chunk is budget-split:
-    partially-prefilled members carry across ticks (mixing warm
-    continuation chunks with fresh admissions in later rounds) and every
+    """A burst whose prompts exceed a step's chunks: members wait for
+    a chunk, partially-prefilled members carry across steps and ticks,
+    and every
     member still matches the reference token-for-token."""
-    gang, params = make_sched(max_batch=3, max_seq=64, prefill_max_batch=3,
-                              prefill_chunk=8)
+    gang, params = make_sched(max_batch=3, max_seq=64, prefill_chunk=8)
     prompts = [list(range(2, 14)), list(range(3, 9)), [4, 2]]
     got = [gang.submit(p, max_new_tokens=5) for p in prompts]
     gang.run_until_done()
     for p, r in zip(prompts, got):
         assert r.output == ref_tokens(params, p, 5)
-
-
-def test_mixed_warm_cold_group_admission():
-    """A gang containing a prefix-cache-warm member (start > 0) and a
-    cold member (start == 0): with warm-prefix flash (the default) the
-    mixed gang rides the warm program together — freshness no longer
-    splits it (ISSUE 13) — and with prefill_flash_warm=False the seed
-    behavior returns (separate freshness buckets, so a warm member
-    never drags cold members off the flash path). Both members match
-    their references either way."""
-    for warm_flash in (True, False):
-        sched, params = make_sched(max_batch=4, max_seq=64, page=8,
-                                   prefix_caching=True, prefill_max_batch=4,
-                                   prefill_flash_warm=warm_flash,
-                                   mixed_dispatch=False)
-        shared = list(range(1, 17))  # two full pages
-        r0 = sched.submit(shared + [5], max_new_tokens=4)
-        sched.run_until_done()
-        n0 = sched.registry.get("prefill_batch_size").count
-        rw = sched.submit(shared + [9], max_new_tokens=6)  # warm: prefix hit
-        rc = sched.submit([7, 3, 2], max_new_tokens=6)     # cold
-        sched.tick()
-        assert rw.cached_at_admit == 16 and rc.cached_at_admit == 0
-        # chunk lengths share the 16-token bucket, so the dispatch count
-        # pins the gang-freshness rule directly: merged = ONE dispatch,
-        # split (the seed rule) = one per freshness flavor
-        n_disp = sched.registry.get("prefill_batch_size").count - n0
-        assert n_disp == (1 if warm_flash else 2)
-        sched.run_until_done()
-        assert rw.output == ref_tokens(params, shared + [9], 6)
-        assert rc.output == ref_tokens(params, [7, 3, 2], 6)
 
 
 def test_preempt_partially_prefilled_group_member():
@@ -682,9 +601,8 @@ def test_prefill_group_member_is_preemption_victim():
     members: the youngest live request loses page pressure even if it
     is still prefilling (it cannot starve an older decoding request)."""
     sched, params = make_sched(max_batch=2, max_seq=32, page=4, num_pages=6,
-                               prefill_chunk=4, mixed_dispatch=False)
+                               prefill_chunk=4)
     r1 = sched.submit([5, 7, 11], max_new_tokens=12)
-    sched.tick()
     sched.tick()
     # r2's admission takes 4 of the 6 pages and holds them across
     # several prefill ticks; r1's decode growth must be able to evict it
@@ -694,23 +612,6 @@ def test_prefill_group_member_is_preemption_victim():
     assert sched.metrics()["preemptions_total"] > 0
     assert r1.output == ref_tokens(params, [5, 7, 11], 12)
     assert r2.output == ref_tokens(params, list(range(1, 13)), 4)
-
-
-def test_pending_first_set_tracks_drain():
-    """The (id, preemptions)-keyed index over undrained first tokens is
-    populated at admission and refreshed (cleared) at drain time — the
-    budget computation reads it instead of scanning the pending list."""
-    # alternating path: _pending_first only exists there (mixed
-    # dispatch samples completion first tokens inside the fused block)
-    sched, _ = make_sched(inflight_blocks=1, mixed_dispatch=False)
-    req = sched.submit([5, 7, 11], max_new_tokens=4)
-    sched.tick()
-    assert (req.id, req.preemptions) in sched._pending_first_keys
-    assert len(sched._pending_first) == 1
-    sched.tick()  # stacked drain consumed the first token
-    assert not sched._pending_first_keys
-    assert not sched._pending_first
-    sched.run_until_done()
 
 
 # -- pipelined dispatch-ahead serving (ISSUE 5) -----------------------------
@@ -752,38 +653,37 @@ def test_pipelined_lazy_drain_cadence():
     """Steady state at inflight_blocks=2: block t+1 is dispatched while
     block t is still undrained; the host fetches only once the queue is
     full (the dispatch-ahead overlap, made visible by token timing)."""
-    sched, params = make_sched(decode_steps_per_tick=2, inflight_blocks=2,
-                               mixed_dispatch=False)
+    sched, params = make_sched(decode_steps_per_tick=2, inflight_blocks=2)
     req = sched.submit([5, 7, 11], max_new_tokens=12)
-    sched.tick()  # admit + first token (pending) + dispatch block 1
+    sched.tick()  # admit + dispatch block 1 (prompt, first token, a step)
     assert len(req.output) == 0 and len(sched._inflight) == 1
     sched.tick()  # queue not full: block 2 chains, still nothing drained
     assert len(req.output) == 0 and len(sched._inflight) == 2
-    sched.tick()  # queue full: drain first + block 1, dispatch block 3
-    assert len(req.output) == 3
+    sched.tick()  # queue full: drain block 1, dispatch block 3
+    assert len(req.output) == 2
     assert sched.metrics()["inflight_depth"] == 2
     sched.run_until_done()
     assert req.output == ref_tokens(params, [5, 7, 11], 12)
 
 
-def test_pipelined_admission_forces_drain_barrier():
-    """A waiter with a free slot forces a FULL drain barrier before
-    admission: every in-flight block reconciles, then the gang admits
-    in the same tick."""
-    # alternating path: the admission barrier class this documents is
-    # exactly what mixed dispatch (the default) retires
-    sched, params = make_sched(max_batch=2, inflight_blocks=2,
-                               mixed_dispatch=False)
+def test_pipelined_admission_forces_no_barrier():
+    """A waiter with a free slot is admitted with every block still in
+    flight: nothing drains for it, its prompt rides the tick's block,
+    and no barrier of any cause is counted."""
+    sched, params = make_sched(max_batch=2, inflight_blocks=2)
     r1 = sched.submit([5, 7, 11], max_new_tokens=16)
     sched.tick()
     sched.tick()
     assert len(sched._inflight) == 2 and len(r1.output) == 0
+    newer = sched._inflight[1]
     r2 = sched.submit([3, 1], max_new_tokens=6)
     sched.tick()
-    assert r2.state == "running"      # admitted this very tick
-    assert len(r1.output) >= 3        # the barrier drained everything
-    assert len(sched._inflight) == 1  # only the fresh block remains
+    assert r2.state == "prefilling" and r2.slot is not None
+    # one lazy drain, one dispatch: the newer block is still in flight
+    assert len(sched._inflight) == 2 and sched._inflight[0] is newer
+    assert sched.barrier_causes() == {}
     sched.run_until_done()
+    assert "admission" not in sched.barrier_causes()
     assert r1.output == ref_tokens(params, [5, 7, 11], 16)
     assert r2.output == ref_tokens(params, [3, 1], 6)
 
@@ -814,7 +714,7 @@ def test_page_pressure_drains_before_preempting():
     FULL drain barrier runs before any victim is chosen — preemption
     must never reclaim pages a dispatched block still writes to."""
     sched, _ = make_sched(max_batch=2, max_seq=32, page=4, num_pages=6,
-                          inflight_blocks=2, mixed_dispatch=False)
+                          inflight_blocks=2, prefill_chunk=4)
     r1 = sched.submit([5, 7, 11], max_new_tokens=20)
     r2 = sched.submit([3, 1], max_new_tokens=20)
     sched.tick()
@@ -872,9 +772,7 @@ def test_scheduler_trace_timeline():
     from butterfly_tpu.obs.trace import Tracer
     model = Model(CFG)
     params = model.init(jax.random.PRNGKey(42))
-    # alternating path: prefill_chunk trace events only exist there
-    rt = RuntimeConfig(max_batch_size=2, max_seq_len=64, page_size=8,
-                       mixed_dispatch=False)
+    rt = RuntimeConfig(max_batch_size=2, max_seq_len=64, page_size=8)
     tr = Tracer()
     sched = Scheduler(ServingEngine(model, params, rt), tracer=tr)
     req = sched.submit([5, 7, 11], max_new_tokens=4,
@@ -883,7 +781,7 @@ def test_scheduler_trace_timeline():
     tl = tr.timeline(req.id)
     assert tl["request_id"] == "trace-me"
     names = [e["name"] for e in tl["events"]]
-    for needed in ("submit", "admit", "prefill_chunk", "prefill_done",
+    for needed in ("submit", "admit", "prefill_done",
                    "first_token", "finish"):
         assert needed in names
     ts = [e["t"] for e in tl["events"]]
@@ -893,7 +791,7 @@ def test_scheduler_trace_timeline():
     # the global ring saw the engine's dispatches; what each tick held
     # (batch, waiting, inflight, generated, spec) is in its tick record
     globs = [e["name"] for e in tr.global_events()]
-    assert "engine.prefill_dispatch" in globs
+    assert "engine.table_sync" in globs
     ticks = sched.ticklog.dump()["ticks"]
     assert sum(t["generated"] for t in ticks) == 4
     assert any(t["batch"] == 1 for t in ticks)
@@ -943,26 +841,6 @@ def test_registry_histograms_observe_through_scheduler():
     assert m["requests_total"] == 2 and m["requests_finished"] == 2
 
 
-def test_written_counts_undrained_first_token():
-    """r5 review off-by-one: after prefill sampled the first token
-    on-device but before the stacked drain, every prompt token's K/V is
-    written — _written must not subtract one (it loses a page of
-    prefix-cache registration at page boundaries)."""
-    sched, _ = make_sched(max_batch=2, max_seq=64, page=8,
-                          inflight_blocks=1,  # per-tick drain cadence
-                          mixed_dispatch=False)  # alternating cadence
-    req = sched.submit([1] * 8, max_new_tokens=4)  # exactly one page
-    sched.tick()  # admit + prefill + on-device first sample (undrained)
-    assert req.state == "running" and req.output == []
-    assert any(f[0] is req for f in sched._pending_first)
-    assert sched._written(req) == 8  # the whole prompt, no -1
-    sched.tick()  # drain: first token lands on the host
-    assert len(req.output) >= 1
-    # once drained, the last sampled token's K/V is indeed unwritten
-    assert sched._written(req) == len(req.all_tokens) - 1
-    sched.run_until_done()
-
-
 # ---------------------------------------------------------------------------
 # overload protection (ISSUE 8): deadlines, SLO-aware shedding, priorities
 # ---------------------------------------------------------------------------
@@ -995,11 +873,12 @@ def test_deadline_expired_while_running():
     barrier — it never consumes a decode dispatch after expiry — while
     a co-running request decodes on unharmed."""
     import time
-    sched, params = make_sched(max_batch=2, mixed_dispatch=False)
+    sched, params = make_sched(max_batch=2)
     doomed = sched.submit([5, 7, 11], max_new_tokens=50)
     ok = sched.submit([3, 1], max_new_tokens=8)
-    sched.tick()
-    assert doomed.state == "running"
+    for _ in range(3):
+        sched.tick()
+    assert doomed.state == "running" and sched._inflight
     doomed.deadline_s = time.monotonic() - 1e-3  # fires before next tick
     sched.tick()
     assert doomed.state == "expired" and doomed.expired_where == "running"
@@ -1085,13 +964,8 @@ def test_kv_window_off_matches_on():
     to the per-token write path — and only the window mode populates
     the flush instruments."""
     prompts = [[5, 7, 11], [3, 1]]
-    # alternating path: the flushed-token arithmetic below assumes
-    # prompts land via dedicated prefill scatters (under mixed dispatch
-    # prompt K/V stages through the window too; parity twins in
-    # test_mixed_dispatch.py)
-    on, _ = make_sched(max_batch=2, mixed_dispatch=False)
-    off, _ = make_sched(max_batch=2, kv_write_combine=False,
-                        mixed_dispatch=False)
+    on, _ = make_sched(max_batch=2)
+    off, _ = make_sched(max_batch=2, kv_write_combine=False)
     a = [on.submit(p, max_new_tokens=10) for p in prompts]
     b = [off.submit(p, max_new_tokens=10) for p in prompts]
     on.run_until_done()
@@ -1101,11 +975,14 @@ def test_kv_window_off_matches_on():
     assert m_on["kv_window_tokens_flushed_total"] > 0
     assert "kv_flush_p50" in m_on and "kv_flush_p95" in m_on
     assert "kv_window_tokens_flushed_total" not in m_off
+    # every prompt token (a chunk stages through the window too) and
     # every generated-and-consumed token was flushed exactly once; the
     # final sampled token of each request is never written (decode
-    # contract), so flushed == generated - one per finished request
+    # contract), so flushed == prompts + generated - one per finished
+    # request
     assert m_on["kv_window_tokens_flushed_total"] == \
-        m_on["tokens_generated_total"] - len(prompts)
+        sum(map(len, prompts)) + m_on["tokens_generated_total"] \
+        - len(prompts)
 
 
 def test_kv_window_greedy_parity_grid():
@@ -1288,13 +1165,12 @@ def test_tick_anatomy_ring_and_phase_reconciliation():
     """Every tick lands one record in the timeline ring: monotonic
     seq, the phase vocabulary, and phase sums reconciling with tick
     wall time (the 'other' residual makes the accounting explicit).
-    The admission barrier-cause fires when a waiter admits while
-    blocks are in flight."""
+    A waiter that admits while blocks are in flight, and a finish at a
+    lazy drain, cost no barrier; the run's last tokens, which exist
+    only in flight, are fetched by one of cause `idle`."""
     from butterfly_tpu.obs.ticklog import TICK_PHASES
 
-    # alternating path: the admission barrier-cause assertion below is
-    # the behavior mixed dispatch (the default) retires
-    sched, params = make_sched(max_batch=2, mixed_dispatch=False)
+    sched, params = make_sched(max_batch=2)
     r1 = sched.submit([5, 7, 11], max_new_tokens=12)
     for _ in range(3):
         sched.tick()  # fill the dispatch-ahead pipeline
@@ -1326,13 +1202,37 @@ def test_tick_anatomy_ring_and_phase_reconciliation():
     assert m["tick_device_frac"] > 0.0  # the stacked fetch is real
 
     causes = sched.barrier_causes()
-    assert causes.get("admission", 0) >= 1  # r2 admitted mid-pipeline
-    assert causes.get("finish", 0) >= 1
+    assert set(causes) == {"idle"}  # r2 admitted mid-pipeline: no cause
+    assert m["finishes_inline_total"] >= 1
     # compat: the unlabeled sum is preserved and equals the breakdown
     assert m["drain_barriers_total"] == sum(causes.values())
     # the per-tick records carry the same causes the family counted
     ring_causes = [c for t in ticks for c in t["barrier_causes"]]
-    assert ring_causes.count("admission") == causes["admission"]
+    assert ring_causes.count("idle") == causes["idle"]
+
+
+def test_the_vocabularies_are_what_the_code_says():
+    """obs/ticklog.py BARRIER_CAUSES is the causes the scheduler and the
+    server can raise, no more (`admission` went with the path that
+    raised it) and no fewer; tools/tick_report.py has a note for every
+    phase of TICK_PHASES."""
+    import re
+    import sys
+    from pathlib import Path
+    from butterfly_tpu.obs.ticklog import BARRIER_CAUSES, TICK_PHASES
+    root = Path(__file__).resolve().parent.parent
+    raised = set()
+    for src in ("sched/scheduler.py", "serve/server.py"):
+        text = (root / "butterfly_tpu" / src).read_text()
+        for args in re.findall(r"_drain_inflight\(([^)]*)\)", text):
+            raised.update(re.findall(r'"(\w+)"', args))
+    assert raised == set(BARRIER_CAUSES)
+    sys.path.insert(0, str(root / "tools"))
+    try:
+        import tick_report
+    finally:
+        sys.path.remove(str(root / "tools"))
+    assert set(tick_report.PHASE_NOTES) == set(TICK_PHASES)
 
 
 def test_barrier_causes_page_pressure_and_cancel():
@@ -1434,12 +1334,7 @@ _WIN = dict(kv_write_combine=True)
 
 
 @pytest.mark.parametrize("build,rt_kw,name", [
-    (lambda e: e._prefill, {}, "bf_prefill"),
-    (lambda e: e._prefill_warm, {}, "bf_prefill_warm"),
-    (lambda e: e._decode, {}, "bf_decode_step"),
     (lambda e: e._flush, {}, "flush_paged_window"),
-    (lambda e: e._decode_block_prog(4), {}, "bf_decode_block"),
-    (lambda e: e._decode_block_win_prog(4), {}, "bf_decode_block_win"),
     (lambda e: e._mixed_block_prog(4, 8, 1), {}, "bf_mixed_block"),
     (lambda e: e._mixed_block_prog(4, 8, 1), _WIN, "bf_mixed_block_win"),
     # no chunk: no slot prefills, a decode block in shape and in use
@@ -1570,7 +1465,6 @@ class _Carry:
 
 
 _BLOCK_KINDS = {
-    "decode": dict(mixed_dispatch=False),
     "mixed": dict(),
     "mixed_spec": dict(speculative_gamma=2),
 }
@@ -1660,32 +1554,7 @@ def test_lazy_drain_launches_no_program_to_read(kind, monkeypatch):
         assert r.output == ref_tokens(params, p, 24)
 
 
-def test_pending_firsts_fetched_without_a_program():
-    """The alternating path's pending first tokens ride the same fetch:
-    read as they are, many small arrays in one `jax.device_get`."""
-    sched, params = make_sched(mixed_dispatch=False, decode_steps_per_tick=2)
-    reqs = [sched.submit(p, max_new_tokens=8) for p in _DRAIN_PROMPTS]
-    for _ in range(10):
-        if sched._pending_first:
-            break
-        sched.tick()
-    assert len(sched._pending_first) == 2
-    sched._pending_first = [f[:3] + (_HostOnly(f[3]),)
-                            for f in sched._pending_first]
-    calls = []
-    real = jax.device_get
-    try:
-        jax.device_get = lambda x: calls.append(x) or real(x)
-        sched._drain_inflight("idle")
-    finally:
-        jax.device_get = real
-    assert len(calls) == 1 and len(calls[0][0]) == 2
-    sched.run_until_done()
-    for r, p in zip(reqs, _DRAIN_PROMPTS):
-        assert r.output == ref_tokens(params, p, 8)
-
-
-@pytest.mark.parametrize("kind", ["decode", "mixed"])
+@pytest.mark.parametrize("kind", list(_BLOCK_KINDS))
 def test_flush_counts_read_later_are_not_lost(kind):
     """`kv_window_tokens_flushed_total`, once the scheduler is idle, is
     the sum of every flush's count, as when each rode its own drain's

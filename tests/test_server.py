@@ -817,19 +817,15 @@ def test_capture_holds_the_ticks_spans_and_exports_off_the_loop(tmp_path):
     assert any(t["overlapped"] is not None for t in ticks)
 
 
-def test_capture_holds_the_alternating_and_spec_phases(tmp_path):
-    """`dispatch` (the alternating path) and `spec_emit` (speculation,
-    on the packed path) are spans too: two servers, one capture each."""
-    with _serving(mixed_dispatch=False) as (url, _):
-        _, _, events, _ = _capture(url, tmp_path / "alternating", 1500)
-    names = {n for n, _ in events}
-    assert {"bf.tick.dispatch", "bf.tick.admit",
-            "bf.tick.drain.emit"} <= names
-    assert "bf.tick.mixed" not in names
+def test_capture_holds_the_spec_phases(tmp_path):
+    """`spec_emit` (speculation) is a span too, beside `mixed`; no tick
+    of either kind has a `dispatch` span of its own (its put and launch
+    are sub-spans of `mixed`)."""
     with _serving(speculative_gamma=2) as (url, _):
         _, _, events, _ = _capture(url, tmp_path / "spec", 1500)
     names = {n for n, _ in events}
-    assert {"bf.tick.mixed", "bf.tick.spec_emit"} <= names
+    assert {"bf.tick.mixed", "bf.tick.spec_emit", "bf.tick.admit",
+            "bf.tick.drain.emit"} <= names
     assert "bf.tick.dispatch" not in names
     assert {st["program"] for n, st in events
             if n == "bf.tick.dispatch.launch"} \

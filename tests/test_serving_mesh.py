@@ -265,7 +265,7 @@ def test_packed_mixed_block_on_tp4_matches_single_device(use_kernels):
     rt = RuntimeConfig(max_batch_size=4, max_seq_len=96, page_size=8,
                        prefill_chunk=8, prefill_inline_budget=8,
                        decode_steps_per_tick=2)
-    assert rt.kv_write_combine and rt.mixed_dispatch
+    assert rt.kv_write_combine
 
     def run(mesh, kernels):
         eng = ServingEngine(Model(cfg), params, rt, mesh=mesh,

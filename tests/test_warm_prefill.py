@@ -1,18 +1,18 @@
-"""Warm-prefix flash prefill (ISSUE 13): kernel + serving-path parity.
+"""Warm-prefix flash attention (ISSUE 13): kernel + serving-path parity.
 
-The warm multi-token prefill path (chunk continuations, prefix-cache
-resumes, warm gang members) dispatches the flash kernel with a cached-
-prefix segment instead of the dense O(T*S_max) fallback. Contract:
+A multi-token forward behind a cached prefix (a packed step's chunk
+with the window off, the speculative verify, the contiguous engine's
+chunk continuation) takes the flash kernel with a cached-prefix
+segment where the ModelConfig says attn_impl="flash", instead of the
+dense O(T*S_max) view. Contract:
 
 * kernel level — the prefix segment folds into the online softmax
   exactly like an inserted dense view, per-row count-masked at `start`
   (garbage past it NEVER contributes: recycled buffers are not zeroed);
 * serving level — greedy outputs are token-identical to the dense path
-  across fresh/warm x chunk sizes x int8/f32 cache x ragged-start gangs
-  with padding rows x prefix-hit resume;
-* policy level — prefill gangs stop splitting by freshness when the
-  warm program is flash-capable (prefill_flash_warm), and
-  prefill_flash_warm=False restores the seed behavior exactly.
+  across chunk sizes x int8/f32 cache x bursts of ragged lengths x
+  prefix-hit resume;
+* the branch is taken where it is asked for and nowhere else.
 
 Interpret mode runs the exact kernel code path on CPU (tier-1).
 """
@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from butterfly_tpu.cache.paged import init_paged_cache, paged_forward
 from butterfly_tpu.core.config import RuntimeConfig, tiny
 from butterfly_tpu.engine.serving import ServingEngine
 from butterfly_tpu.models.common import (Model, attend, forward, init_cache,
@@ -123,15 +124,16 @@ def _prompts(seed, lens):
 
 def _run(model, params, prompts, *, use_kernels, warm_flash, kv_quant="none",
          chunk=16, max_new=8, prefix_caching=False, resume=None):
-    # mixed_dispatch=False: this file exercises the ALTERNATING path's
-    # batched warm/dense prefill programs (under mixed dispatch, the
-    # default, prompts ride the fused decode block and prefill_batch
-    # never dispatches — test_mixed_dispatch.py covers that path)
+    # the window off: a packed step's chunk then reads its slot's
+    # cached prefix out of the pool, which is where paged_attend's
+    # warm branch takes the kernel for a ModelConfig that says flash
+    # (window on, the chunk's own staged rows are not in the pool)
     rt = RuntimeConfig(max_batch_size=4, max_seq_len=128, page_size=8,
-                       prefill_chunk=chunk, prefill_max_batch=4,
-                       prefill_flash_warm=warm_flash, kv_quant=kv_quant,
-                       prefix_caching=prefix_caching,
-                       mixed_dispatch=False)
+                       prefill_chunk=chunk, prefill_inline_budget=2 * chunk,
+                       kv_quant=kv_quant, kv_write_combine=False,
+                       prefix_caching=prefix_caching)
+    if warm_flash:
+        model = Model(model.cfg.replace(attn_impl="flash"))
     sched = Scheduler(ServingEngine(model, params, rt,
                                     use_kernels=use_kernels))
     reqs = [sched.submit(p, max_new_tokens=max_new) for p in prompts]
@@ -139,8 +141,8 @@ def _run(model, params, prompts, *, use_kernels, warm_flash, kv_quant="none",
     outs = [r.output for r in reqs]
     if resume is not None:
         # prefix-hit resume: a later request sharing a registered prefix
-        # admits warm (cached_at_admit > 0) and its FIRST chunk runs the
-        # warm path at start = cached
+        # admits warm (cached_at_admit > 0) and its FIRST chunk starts
+        # at the cached length
         r = sched.submit(resume, max_new_tokens=max_new)
         sched.run_until_done()
         if prefix_caching:
@@ -151,10 +153,10 @@ def _run(model, params, prompts, *, use_kernels, warm_flash, kv_quant="none",
 
 def test_serving_warm_flash_vs_dense_parity():
     """Chunked multi-request prefill through the scheduler: the flash
-    engine (fresh + warm kernels, merged gangs) must be token-identical
-    to the all-dense engine. Prompt lengths straddle chunk boundaries so
-    admission rounds mix warm continuations with fresh arrivals (ragged
-    starts) and odd gang widths pad (padding rows ride the null page)."""
+    engine (its chunks through the warm kernel, two chunks a step) must
+    be token-identical to the all-dense engine. Prompt lengths straddle
+    chunk boundaries, so a step's chunks start at ragged lengths and a
+    chunk's tail is filler."""
     model = Model(CFG)
     params = model.init(jax.random.PRNGKey(42))
     prompts = _prompts(0, (40, 23, 37))
@@ -202,31 +204,40 @@ def test_warm_flash_parity_grid(kv_quant, chunk):
     assert dense == kernel_dense
 
 
-def test_engine_prefill_batch_ragged_starts_direct():
-    """Engine-level unit: ONE warm prefill_batch dispatch with ragged
-    starts (a carried warm member, a shorter warm member, a fresh
-    member) and an implicit padding row (B=3 buckets to 4). Last-token
-    logits must match the dense engine's bit-for-near-bit."""
+def test_lane_wide_warm_forward_ragged_starts_direct():
+    """cache/paged.py paged_forward, ONE warm multi-token forward with
+    ragged starts (a long cached prefix, a shorter one, a row with
+    none) and an inactive row: under attn_impl="flash" every row's
+    logits are the dense forward's bit-for-near-bit."""
     model = Model(CFG)
     params = model.init(jax.random.PRNGKey(45))
     rt = RuntimeConfig(max_batch_size=4, max_seq_len=64, page_size=8)
     rng = np.random.RandomState(3)
-    toks = [rng.randint(1, 250, (n,)).tolist() for n in (24, 8, 10)]
+    seed = np.zeros((4, 24), np.int32)
+    lens = (24, 8, 0, 0)
+    for b, n in enumerate(lens):
+        seed[b, :n] = rng.randint(1, 250, (n,))
+    warm = jnp.asarray(rng.randint(1, 250, (4, 6)), jnp.int32)
     outs = {}
-    for use_k in (False, True):
-        eng = ServingEngine(model, params, rt, use_kernels=use_k)
+    for cfg in (CFG, CFG.replace(attn_impl="flash")):
+        cache = init_paged_cache(cfg, rt)
         # hand each slot a private page run (no allocator needed)
-        for slot in range(3):
-            eng.set_table_row(slot, list(range(slot * 8, slot * 8 + 8)))
-        # seed slots 0/1 with fresh context of different lengths
-        eng.prefill_batch([0, 1], [toks[0], toks[1]], [0, 0])
-        # ONE warm gang: starts 24 / 8 / 0 — ragged + a fresh row
-        logits = eng.prefill_batch([0, 1, 2], [[5, 9, 2], [7, 7], toks[2]],
-                                   [24, 8, 0])
-        outs[use_k] = np.asarray(logits)
-    np.testing.assert_allclose(outs[True], outs[False],
+        cache = cache._replace(page_table=jnp.arange(
+            32, dtype=jnp.int32).reshape(4, 8))
+        # seed slots 0/1 with cached context of different lengths
+        for b, n in enumerate(lens[:2]):
+            _, cache = paged_forward(
+                params, cfg, jnp.asarray(seed[:, :n]), cache, fresh=True,
+                active=jnp.arange(4) == b)
+        assert cache.lengths.tolist() == list(lens)
+        # ONE warm forward: starts 24 / 8 / 0 — ragged + an inactive row
+        logits, cache = paged_forward(
+            params, cfg, warm, cache, active=jnp.arange(4) < 3)
+        assert cache.lengths.tolist() == [30, 14, 6, 0]
+        outs[cfg.attn_impl] = np.asarray(logits[:3])
+    np.testing.assert_allclose(outs["flash"], outs["dense"],
                                rtol=3e-5, atol=3e-5)
-    assert (outs[True].argmax(-1) == outs[False].argmax(-1)).all()
+    assert (outs["flash"].argmax(-1) == outs["dense"].argmax(-1)).all()
 
 
 def test_contiguous_warm_flash_parity():
@@ -256,9 +267,10 @@ def test_contiguous_warm_flash_parity():
 
 
 def test_warm_flash_dispatches_kernel(monkeypatch):
-    """The warm program must actually take the kernel: count
-    flash_attention_sharded calls carrying a prefix segment from inside
-    the paged layer body. Flag off, warm dispatches must make none."""
+    """A chunk behind a cached prefix must actually take the kernel
+    where the ModelConfig says flash: count flash_attention_sharded
+    calls carrying a prefix segment from inside the packed layer.
+    A dense ModelConfig must make none, kernels on or not."""
     import butterfly_tpu.cache.paged as paged
 
     calls = {"prefix": 0, "fresh": 0}
@@ -273,29 +285,9 @@ def test_warm_flash_dispatches_kernel(monkeypatch):
     params = model.init(jax.random.PRNGKey(46))
     prompts = _prompts(9, (20,))
     _run(model, params, prompts, use_kernels=True, warm_flash=True, chunk=8)
-    assert calls["prefix"] > 0 and calls["fresh"] > 0
+    # every chunk goes through the warm branch, the first too (a prefix
+    # of length 0): no caller says `fresh` any more
+    assert calls["prefix"] > 0 and calls["fresh"] == 0
     calls.update(prefix=0, fresh=0)
     _run(model, params, prompts, use_kernels=True, warm_flash=False, chunk=8)
-    assert calls["prefix"] == 0  # dense warm program never sees a prefix
-
-
-def test_gang_split_policy_properties():
-    """prefill_gang_split_fresh pins the bucketing rule: split ONLY with
-    prefill_flash_warm off (the seed behavior); warm_prefill_flash says
-    whether the warm program is actually kernelized (kernels AND flag)."""
-    model = Model(CFG)
-    params = model.init(jax.random.PRNGKey(47))
-    rt = RuntimeConfig(max_batch_size=2, max_seq_len=64, page_size=8)
-    grid = [
-        # (use_kernels, flag) -> (warm_prefill_flash, split_fresh)
-        ((True, True), (True, False)),
-        ((True, False), (False, True)),
-        ((False, True), (False, False)),
-        ((False, False), (False, True)),
-    ]
-    for (use_k, flag), (want_flash, want_split) in grid:
-        eng = ServingEngine(model, params,
-                            rt.replace(prefill_flash_warm=flag),
-                            use_kernels=use_k)
-        assert eng.warm_prefill_flash == want_flash
-        assert eng.prefill_gang_split_fresh == want_split
+    assert calls == {"prefix": 0, "fresh": 0}

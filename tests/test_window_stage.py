@@ -115,7 +115,8 @@ def _same(got: KVWindow, want):
 
 
 #: lanes: every slot stages T tokens at its count (rows=None: the
-#: alternating path). name -> (W, T, counts before)
+#: lane-wide forward of the speculative verify). name -> (W, T, counts
+#: before)
 LANES = {
     "one_token": (64, 1, (0, 31, 32, 63)),
     "a_few_and_a_dropped_tail": (64, 3, (0, 30, 62, 64)),

@@ -614,7 +614,7 @@ def test_paths_that_carry_one_stream_refuse_n_by_name(what):
 
 
 def test_the_family_itself_is_refused_where_a_latent_model_is():
-    """Xing is latent: a mesh, speculation and the alternating path
+    """Xing is latent: a mesh, speculation and the lane-wide forwards
     refuse it by that name first (tests/test_joyai.py has the list);
     the checkpoint loader knows no converter for the family."""
     from butterfly_tpu.ckpt.load import load_checkpoint

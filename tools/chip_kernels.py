@@ -1706,7 +1706,7 @@ def main() -> int:
                     help="window widths to check: inflight_blocks x "
                          "decode_steps_per_tick x chunk width; 256 is "
                          "`serve --decode-steps-per-tick 4` (chip_smoke), "
-                         "16 is two blocks of 8 with mixed dispatch off")
+                         "16 is two blocks of 8 steps with no chunk")
     ap.add_argument("--small", action="store_true",
                     help="cut shapes so an interpreted CPU run finishes "
                          "(debugging aid; such a run still exits 1)")

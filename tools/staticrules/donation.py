@@ -19,7 +19,7 @@ next-iteration read is seen):
 
 * donating callables are discovered from ``self.X = jax.jit(...,
   donate_argnums=...)`` assignments, from factory methods that build and
-  return such a jit (``self._decode_block_prog(k)(...)`` and
+  return such a jit (``self._mixed_block_prog(k, C, P)(...)`` and
   ``verify = self._verify_program(...)``), from ``A if c else B``
   aliases of two same-signature donators, and from the
   ``KNOWN_DONATING_METHODS`` table for cross-module engine APIs whose
@@ -48,8 +48,8 @@ from . import (FileContext, Finding, Rule, assigned_handles, handle_of,
 #: mixed_spec_block_async its ``hist`` and ``cursor`` (0 and 2); the
 #: prompt buffer is deliberately NOT donated (the scheduler edits it
 #: host-side between dispatches at admission).
-#: decode_block_async / decode_active_async donate only the engine's own
-#: self.cache, never a caller argument, so they are absent by design.
+#: What a method donates of the engine's own (self.cache, the window)
+#: is no caller argument and is absent by design.
 KNOWN_DONATING_METHODS: Dict[str, Tuple[int, ...]] = {
     "mixed_block_async": (1,),
     "mixed_spec_block_async": (0, 2),
@@ -117,7 +117,7 @@ def _collect_class_tables(tree: ast.AST) -> Dict[ast.ClassDef, _ClassTable]:
                                     t.value.id == "self":
                                 table.attrs[t.attr] = idx
             # a method that builds a donating jit and returns something
-            # is a program factory (the _decode_block_prog /
+            # is a program factory (the _mixed_block_prog /
             # _verify_program caching pattern)
             if jit_indices and has_return:
                 table.factories[meth.name] = jit_indices

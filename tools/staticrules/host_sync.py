@@ -39,12 +39,12 @@ from . import FileContext, Finding, Rule, call_name, dotted_name, register
 #: hot section, so they are IN the set: a timer that materialized a
 #: device value would reintroduce exactly the sync it exists to find.
 HOT_FUNCTIONS: Set[str] = {
-    "tick", "_tick_sections", "_decode_block",
-    "_enqueue_block", "_assemble", "_admit",
-    "_admit_round", "_finish_prefill",
+    "tick", "_tick_sections", "_mixed_block",
+    "_enqueue_block", "_assemble", "_admit_inline",
+    "_seed_mixed_slot", "_finish_prefill",
     "_lap", "_starve", "_fed", "_note_fetch",
-    "decode_block_async", "decode_active_async",
-    "prefill_batch", "_sync_table",
+    "mixed_block_async", "mixed_spec_block_async",
+    "_sync_table",
     # ISSUE 20: the seq-parallel long-prompt lane — one chunk dispatch
     # per tick; a per-chunk readback would serialize the whole prefill
     "_sp_prefill_step", "sp_prefill_chunk",

@@ -42,10 +42,10 @@ PHASE_NOTES = {
     "drain_barrier": "FULL drain (membership change forced it)",
     "admit": "admission: slot grant + prompt staging",
     "assemble": "per-tick operand assembly for the batch",
-    "dispatch": "alternating-path prefill/decode dispatch "
-                "(mixed_dispatch off, or the static scheduler)",
+    "dispatch": "always 0: a key the record keeps from before the "
+                "fused block (its put and launch are under `mixed`)",
     "mixed": "ONE fused dispatch: prefill chunks + decode/spec "
-             "blocks together (mixed_dispatch, the default)",
+             "blocks together",
     "spec_emit": "host accept/emit walk over drafted tokens",
     "flush": "write-combined KV window flush",
     "other": "unattributed residual of the tick wall",
