@@ -95,9 +95,10 @@ from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
 # wrappers import nothing project-local at module level.
 from butterfly_tpu.models.common import (
     _cast_float, attend, attend_token_rows, attn_output,
-    early_router_logits, embed_tokens, ffn_close, final_logits,
-    ffn_run, index_proj, index_scores, indexer_unsupported, latent_attend,
-    latent_proj, latent_queries, latent_unsupported, layer_at, layer_mask,
+    early_router_logits, embed_tokens, experts_in_place, ffn_close,
+    final_logits, ffn_run, index_proj, index_scores, indexer_unsupported,
+    latent_attend, latent_proj, latent_queries, latent_unsupported, layer_at,
+    layer_experts, layer_mask,
     layer_pattern_of, layer_runs, layer_stack, make_mask, qkv_proj,
     quantize_kv, run_layer_at, select_mask, stream_fold, stream_read,
     stream_write,
@@ -1654,10 +1655,10 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
             st, m, rows, cfg, use_kernel)
         return (x, st), load
 
-    def attention(ffn, looped, carry, scanned):
+    def attention(ffn, held, looped, carry, scanned):
         x, win = carry
         (l, a), *mine = scanned
-        lp = run_layer_at(params, ffn, l, cfg)
+        lp = run_layer_at(params, ffn, l, cfg, held)
         if "attn" in params:
             lp = {**lp, "attn": layer_at(params["attn"], a, cfg)}
         if win is None:
@@ -1675,8 +1676,15 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
         else:
             mine = () if window is not None else (
                 None if a is None else a[at:at + n] for a in pools)
+            ffn, held = ffn_run(params, first, cfg), None
+            if ffn is not None:
+                # a run's experts stay whole where its steps take the
+                # Mosaic call (experts_in_place), its layer by index
+                stack, held = experts_in_place(ffn[0], rows.ok.shape[0], cfg,
+                                               use_kernel)
+                ffn = (stack, ffn[1])
             (x, window), (new, load) = lax.scan(
-                partial(attention, ffn_run(params, first, cfg), n > 1),
+                partial(attention, ffn, held, n > 1),
                 (x, window), (idx, *mine))
             written.append(new)
         loads.append(load)
@@ -1836,17 +1844,22 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
             rows.chunk_ok & (rows.chunk_pos[:, 0] == 0))])
         load = before_share(load, ssm.astype(jnp.float32), cfg)
         return logits, kv, load, state
+    # the experts' codes ride no scan where the step takes the Mosaic
+    # call: they stay whole, read at the layer's index (experts_in_place)
+    layers, held = experts_in_place(layer_stack(params["layers"], cfg),
+                                    rows.ok.shape[0], cfg, use_kernel)
     # an absent pool or window tensor rides the scan as None (no leaf)
     if window is None:
         def body(x, scanned):
-            lp, *pools = scanned
-            x, pools, _, load = packed_layer(x, lp, pools, None, rows, cfg,
-                                             use_kernel)
+            i, lp, *pools = scanned
+            x, pools, _, load = packed_layer(
+                x, layer_experts(lp, held, i), pools, None, rows, cfg,
+                use_kernel)
             return x, (pools, load)
 
         x, (pools, load) = lax.scan(
-            body, x, (layer_stack(params["layers"], cfg),
-                      *pool_leaves(cache, absent=True)))
+            body, x, (held and jnp.arange(len(jax.tree.leaves(layers)[0])),
+                      layers, *pool_leaves(cache, absent=True)))
         state = pool_leaves(cache, pools)
     else:
         # the pool is read-only and goes in whole beside the layer's
@@ -1855,12 +1868,12 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
         def body(carry, lp):
             x, i, window = carry
             x, _, window, load = packed_layer(
-                x, lp, pool_leaves(cache, absent=True), window, rows, cfg,
+                x, layer_experts(lp, held, i),
+                pool_leaves(cache, absent=True), window, rows, cfg,
                 use_kernel, layer=i)
             return (x, i + 1, window), load
 
-        (x, _, state), load = lax.scan(body, (x, 0, window),
-                                       layer_stack(params["layers"], cfg))
+        (x, _, state), load = lax.scan(body, (x, 0, window), layers)
     if load is not None:
         load = load.mean(axis=0)
     return (final_logits(params, cfg, stream_fold(x, cfg)[rows.head])[:, 0],

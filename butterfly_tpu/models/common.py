@@ -35,6 +35,7 @@ from butterfly_tpu.core.config import ModelConfig
 # a lazy in-function import executes on every trace — the same per-trace
 # tax PR 12's quantize_kv hoist removed from cache/paged.py. No cycle:
 # ops.flash_attention imports nothing project-local at module level.
+from butterfly_tpu.ops import moe_experts as moe_kernel
 from butterfly_tpu.ops import note_kernel
 from butterfly_tpu.ops.flash_attention import flash_attention_sharded
 from butterfly_tpu.quant.int8 import maybe_dequant, qeinsum
@@ -855,22 +856,13 @@ def expert_load(logits: jax.Array, k: int, ok: jax.Array,
                       jnp.sum(rows)])
 
 
-@jax.named_scope("moe_experts")
-def moe_block(x: jax.Array, p: Params, cfg: ModelConfig,
-              logits: Optional[jax.Array] = None) -> jax.Array:
-    """Dense-compute MoE (every expert sees every token, masked by router).
-
-    The expert-parallel all_to_all path lives in parallel/expert.py; this
-    dense form is the single-device reference and the EP fallback.
-    `logits`: as route_tokens'.
-
-    cfg.experts_held (one chip's share of a deployment's experts): the
-    router ranges over all cfg.num_experts and the gates are what the
-    whole layer would give them; the leaves hold the experts
-    experts_first .. + experts_held alone and the result is THEIR part
-    of the sum. What the absent experts would add is left out.
-    """
-    B, T, D = x.shape
+def expert_gates(x: jax.Array, p: Params, cfg: ModelConfig,
+                 logits: Optional[jax.Array] = None) -> jax.Array:
+    """comb [B,T,E held]: a row's gate on each expert the leaves hold
+    (route_tokens' k weights scattered over the experts; zero where the
+    row did not choose it), float32. Under cfg.experts_held the columns
+    of the experts experts_first .. + experts_held alone, the gates what
+    the whole layer would give them. `logits`: as route_tokens'."""
     weights, idx = route_tokens(
         x, p["router"], cfg.num_experts_per_tok, logits,
         score=cfg.router_score, bias=p.get("router_bias"),
@@ -880,8 +872,14 @@ def moe_block(x: jax.Array, p: Params, cfg: ModelConfig,
     if cfg.experts_held:
         comb = comb[..., cfg.experts_first:
                     cfg.experts_first + cfg.experts_held]
+    return comb
 
-    act = ACTIVATIONS[cfg.act]
+
+def dense_experts(x: jax.Array, comb: jax.Array, p: Params,
+                  act) -> jax.Array:
+    """sum_e comb[.., e] * expert_e(x) with every expert's products over
+    every row: x [B,T,D], comb [B,T,E] (expert_gates), p one layer's
+    w_gate / w_up [E,D,F] and w_down [E,F,D]."""
     dt = x.dtype
     # The experts' codes stand FIRST in the gate and up products: the
     # TPU compiler then reads them as they are stored. Handed the rows
@@ -896,6 +894,70 @@ def moe_block(x: jax.Array, p: Params, cfg: ModelConfig,
     h = act(g) * u
     y = qeinsum("ebtf,efd->ebtd", h, p["w_down"], dt)
     return jnp.einsum("ebtd,bte->btd", y, comb.astype(y.dtype))
+
+
+@jax.named_scope("moe_experts")
+def moe_block(x: jax.Array, p: Params, cfg: ModelConfig,
+              logits: Optional[jax.Array] = None,
+              ok: Optional[jax.Array] = None) -> jax.Array:
+    """Dense-compute MoE (every expert sees every token, masked by router).
+
+    The expert-parallel all_to_all path lives in parallel/expert.py; this
+    dense form is the single-device reference and the EP fallback.
+    `logits`: as route_tokens'.
+
+    cfg.experts_held (one chip's share of a deployment's experts): the
+    router ranges over all cfg.num_experts and the gates are what the
+    whole layer would give them; the leaves hold the experts
+    experts_first .. + experts_held alone and the result is THEIR part
+    of the sum. What the absent experts would add is left out.
+
+    Where p holds "layer" (experts_in_place left the experts' leaves
+    WHOLE, layer-stacked, and this layer's index beside them) the same
+    sum comes from ops/moe_experts.py, which reads the experts some real
+    row chose and no other; ok [B,T]: the rows that are real (None:
+    all), whose gates alone count there.
+    """
+    B, T, D = x.shape
+    comb = expert_gates(x, p, cfg, logits)
+    act = ACTIVATIONS[cfg.act]
+    if "layer" not in p:
+        return dense_experts(x, comb, p, act)
+    if ok is not None:
+        comb = jnp.where(ok[..., None], comb, 0.0)
+    out = moe_kernel.moe_experts(x.reshape(B * T, D),
+                                 comb.reshape(B * T, -1), p, p["layer"], act)
+    return out.astype(x.dtype).reshape(B, T, D)
+
+
+def experts_in_place(stack: Params, rows: int, cfg: ModelConfig,
+                     use_kernel: bool):
+    """A layer-stacked tree for a loop over its layers whose steps have
+    `rows` rows: (what the loop slices a layer at a time, held). Where
+    the step takes ops/moe_experts.py (its `takes`: kernels on, no mesh,
+    int8 codes, few rows, experts that even routing leaves untouched)
+    the experts' codes and scales leave the tree and are `held`: a slice
+    of them handed to a custom call is a COPY of one layer's codes, so
+    they stay whole and the loop's body lays them into its layer's slice
+    with the layer's index (layer_experts). Anywhere else the tree as it
+    is, and None."""
+    moe = stack.get("moe")
+    if moe is None or not cfg.routed or cfg.moe_impl == "ep" \
+            or not moe_kernel.takes(rows, moe, cfg.num_experts_per_tok,
+                                    cfg.num_experts, use_kernel):
+        return stack, None
+    held = {n: moe[n] for n in moe_kernel.LEAVES}
+    return {**stack, "moe": {k: v for k, v in moe.items()
+                             if k not in held}}, held
+
+
+def layer_experts(lp: Params, held: Optional[Params], i) -> Params:
+    """Layer i's slice `lp` of a tree experts_in_place cut, with the
+    experts it held back laid in WHOLE and i (traced) beside them under
+    "layer", which is how moe_block knows; lp itself where none were."""
+    if held is None:
+        return lp
+    return {**lp, "moe": {**lp["moe"], **held, "layer": i}}
 
 
 def pre_norm(x: jax.Array, norm_p: Params, cfg: ModelConfig) -> jax.Array:
@@ -921,17 +983,19 @@ def early_router_logits(x: jax.Array, lp: Params,
 
 @jax.named_scope("mlp")
 def ffn_block(h: jax.Array, lp: Params, cfg: ModelConfig,
-              logits: Optional[jax.Array] = None) -> jax.Array:
+              logits: Optional[jax.Array] = None,
+              ok: Optional[jax.Array] = None) -> jax.Array:
     """FFN dispatch shared by every forward variant (contiguous, paged,
     pipeline, sequence-parallel): dense MLP, dense MoE, or EP MoE per
     cfg — one definition so the variants can't drift. `logits`:
-    early_router_logits' of this layer, where the model has them."""
+    early_router_logits' of this layer, where the model has them; ok:
+    moe_block's."""
     if cfg.routed and "moe" in lp:   # not a leading dense layer's (first_k_dense)
         if cfg.moe_impl == "ep":   # no early logits here: ModelConfig refuses
             from butterfly_tpu.parallel.expert import moe_block_ep
             out = moe_block_ep(h, lp["moe"], cfg)
         else:
-            out = moe_block(h, lp["moe"], cfg, logits)
+            out = moe_block(h, lp["moe"], cfg, logits, ok)
         if cfg.shared_intermediate_size:
             # one shared expert, every token, added unweighted
             with jax.named_scope("moe_shared"):
@@ -1146,12 +1210,18 @@ def ffn_run(params: Params, first: int, cfg: ModelConfig):
         else (params["sparse"], cfg.first_k_dense)
 
 
-def run_layer_at(params: Params, ffn, l, cfg: ModelConfig) -> Params:
+def run_layer_at(params: Params, ffn, l, cfg: ModelConfig,
+                 held: Optional[Params] = None) -> Params:
     """Layer l (traced) of a model whose layers run as runs: what every
     layer has (params["layers"]) beside its run's feed-forward
-    (ffn_run's)."""
+    (ffn_run's); held: the experts experts_in_place kept out of that
+    run's stack, laid in whole at the layer's index in it
+    (layer_experts)."""
     lp = layer_at(params["layers"], l, cfg)
-    return lp if ffn is None else {**lp, **layer_at(ffn[0], l - ffn[1], cfg)}
+    if ffn is None:
+        return lp
+    return layer_experts({**lp, **layer_at(ffn[0], l - ffn[1], cfg)}, held,
+                         l - ffn[1])
 
 
 def layer_at(stack: Params, i, cfg: ModelConfig) -> Params:
@@ -1641,7 +1711,7 @@ def ffn_close(x: jax.Array, lp: Params, cfg: ModelConfig, route=None,
             lp["moe"].get("router_bias"),
             (cfg.experts_first, cfg.experts_held) if cfg.experts_held
             else None)
-    return stream_write(x, ffn_block(h, lp, cfg, route), mix, cfg), load
+    return stream_write(x, ffn_block(h, lp, cfg, route, ok), mix, cfg), load
 
 
 def transformer_layer(x: jax.Array, lp: Params, cfg: ModelConfig,

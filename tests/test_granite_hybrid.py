@@ -775,6 +775,71 @@ def test_a_model_without_an_indexer_compiles_what_it_compiled(
     assert len(instructions(hlo)) > 1000 and "select_mask" not in hlo
 
 
+def _glm5_mixed_block(tmp_path):
+    """(record, compiled text) of GLM-5's mixed block at the benchmark
+    file's own widths and a cut depth (one dense layer, two of experts),
+    kernels on, compiled for a described v5e (tools/chip_kernels.py
+    cell_blocks, which builds the block as the engine does)."""
+    import json
+    from pathlib import Path
+    root = Path(__file__).resolve().parent.parent
+    config = json.loads(
+        (root / "servebench" / "configs" / "glm-5-ep16.json").read_text())
+    config["num_hidden_layers"] = 3
+    rec, = _tools().cell_blocks(config, tmp_path, blocks=("mixed",))
+    if "error" in rec:
+        if "Mosaic" in rec["error"]:        # a kernel refused is no skip
+            pytest.fail(rec["error"])
+        pytest.skip(f"the TPU compiler could not be used here: "
+                    f"{rec['error']}")
+    return rec, (tmp_path / "mixed.hlo.txt").read_text()
+
+
+def test_a_layer_of_experts_is_one_call_over_the_codes_where_they_lie(
+        one_chip, tmp_path):
+    """GLM-5's packed step (64 rows: 32 slots beside a chunk of 32; 16
+    held experts of 256 in int8 codes, 604 MB a layer), kernels on,
+    compiled for the TPU: the layers of experts are ONE loop that holds
+    ONE Mosaic call `moe_experts`, whose operands are the layer-stacked
+    codes WHOLE (the layer rides its scalars), and nothing in the
+    program makes a value of the stacked codes' shape or of one layer's
+    (tools/chip_kernels.py expert_moves: no copy, no dynamic-slice, no
+    fusion's result). The dense products' [E, rows, F] values are gone
+    with them."""
+    rec, hlo = _glm5_mixed_block(tmp_path)
+    assert rec["ok"] and "moe_experts" in rec["mosaic_calls"]
+    assert rec["expert_moves"] == []
+    calls = _mosaic_calls(hlo, "moe_experts")
+    assert len(calls) == 1
+    # two layers of experts here: gate, up and down whole, and their scales
+    for dims in ("s8[2,16,6144,2048]", "s8[2,16,2048,6144]",
+                 "bf16[2,16,1,2048]", "bf16[2,16,1,6144]"):
+        assert dims in calls[0].split("custom-call(")[1]
+    assert calls[0].strip().startswith("%moe_experts")
+    assert re.match(r"\s*%\S+ = f32\[64,6144\]", calls[0])
+    assert not re.search(r"= bf16\[16,64,1?,?2048\]", hlo)
+
+
+def test_a_layer_s_slice_of_the_codes_is_what_the_index_replaced(
+        one_chip, tmp_path, monkeypatch):
+    """The control of the check above: the same block with ONE layer's
+    slice of the codes handed to the call (the stack cut at the layer's
+    index in XLA, the call told layer 0) materialises that layer's codes
+    before every call, and expert_moves finds it."""
+    from butterfly_tpu.models import common
+
+    def sliced(lp, held, i):
+        if held is None:        # the leading dense layer's run
+            return lp
+        cut = jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(a, i, 1),
+                           held)
+        return {**lp, "moe": {**lp["moe"], **cut, "layer": jnp.int32(0)}}
+    monkeypatch.setattr(common, "layer_experts", sliced)
+    rec, hlo = _glm5_mixed_block(tmp_path)
+    assert _mosaic_calls(hlo, "moe_experts")
+    assert rec["expert_moves"] and not rec["ok"]
+
+
 def test_weights_built_leaf_by_leaf_have_the_same_tree():
     """cli.load_params' path (no checkpoint): every leaf born in its
     final form, the tied head's codes beside the embedding."""

@@ -55,6 +55,11 @@ ops/select_mask.py at the three arrays of scores those cells select over
 (SELECT_CELLS) beside lax.top_k and the running count it stands in for,
 both branches of cache/paged.py _selection on the same scores: the two
 masks against each other, what each program still sorts, a call's time.
+`expert_cell_*` run ops/moe_experts.py at the expert geometries of the
+cells whose steps are few rows over int8 codes (EXPERT_CELLS) beside
+XLA's dense products of the same operands (models/common.py
+dense_experts): parity, then a layer's time of each at 32 and 64 rows
+with every held expert touched, the cell's own share and one.
 
 Last, the program `serve` spends its time in — the serving engine's
 fused decode block, at the full 8B width with the depth cut to two
@@ -383,6 +388,40 @@ def state_copies(hlo: str, h) -> list:
     return [m.group(1) for m in map(made.match, hlo.splitlines()) if m]
 
 
+def expert_moves(hlo: str, experts) -> list:
+    """The instructions of a compiled HLO text that MAKE a value of the
+    shape of the experts' layer-stacked codes ([L, E, D, F] gate and up,
+    [L, E, F, D] down: `experts` the stacked leaves, arrays or shapes)
+    or of one layer's codes [E, ..] in the device's memory: a `copy`, a
+    `dynamic-slice`, a fusion's result, whatever is not a parameter, a
+    tuple's element or a bitcast (those name a value and make none; a
+    loop's carry is a tuple), in any computation that is not a fusion's
+    own (inside one, a slice of the stack is how the dense products READ
+    a layer where it lies). What a program that hands ops/moe_experts.py
+    the WHOLE stack and the layer's index holds none of: one layer's
+    slice handed to a custom call is materialised, 604 MB a layer at
+    GLM-5's widths (PERF.md, PR 61)."""
+    import re
+    shapes = set()
+    for name in ("w_gate", "w_up", "w_down"):
+        dims = experts[name]["q8"].shape
+        shapes |= {",".join(map(str, dims)), ",".join(map(str, dims[1:]))}
+    fused = set(re.findall(r" fusion\(.*?calls=%([^\s,)]+)", hlo))
+    head = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{\s*$")
+    made = re.compile(r"^\s*(?:ROOT )?%(\S+) = s8\[(?:1,)?([\d,]+)\]\S* "
+                      r"(?!parameter|get-tuple-element|bitcast)[\w-]+\(")
+    found, inside = [], False
+    for line in hlo.splitlines():
+        m = head.match(line)
+        if m:
+            inside = m.group(1) not in fused
+        elif inside:
+            m = made.match(line)
+            if m and m.group(2) in shapes:
+                found.append(m.group(1))
+    return found
+
+
 def window_moves(hlo: str, leaves,
                  kinds=("copy", "transpose", "fusion")) -> list:
     """The instructions INSIDE a compiled HLO text's loops that make a
@@ -492,7 +531,8 @@ def selecting_calls(config: dict, hlo: str):
                for m in re.findall(r"^\s*(?:ROOT )?(%\S+ = \S+)", hlo, re.M))
 
 
-def cell_blocks(config: dict, hlo_dir=None) -> list:
+def cell_blocks(config: dict, hlo_dir=None,
+                blocks=("mixed", "decode")) -> list:
     """A cell's two block programs, the mixed block (one chunk of C
     beside the decode rows) and the decode block, at the sizes of its
     configuration file, compiled for a DESCRIBED v5e (no chip: shapes
@@ -513,7 +553,11 @@ def cell_blocks(config: dict, hlo_dir=None) -> list:
     with a recurrent state the copies of it, whole or a layer
     (state_copies); for a
     latent cache whose rows an indexer selects, the instructions the
-    benchmark's pattern tells as the selecting read (selecting_calls).
+    benchmark's pattern tells as the selecting read (selecting_calls);
+    for a model of experts in int8 codes the instructions that make a
+    value of the codes' shape, stacked or one layer's (expert_moves:
+    none where the step takes ops/moe_experts.py, which reads the stack
+    where it lies). `blocks`: which of the two to compile.
     The compiler's figures were the chip's to the megabyte (PR 41).
     hlo_dir: where to write each program's compiled text
     (`<name>.hlo.txt`: what a trace will name, and what two checkouts'
@@ -570,8 +614,12 @@ def cell_blocks(config: dict, hlo_dir=None) -> list:
         donated += (15,)
     real_backend, out = jax.default_backend, []
     jax.default_backend = lambda: "tpu"     # kernels compile, not interpret
+    moe = params.get("sparse", params["layers"]).get("moe", {})
+    coded = isinstance(moe.get("w_gate"), dict)
     try:
         for name, P in (("mixed", 1), ("decode", 0)):
+            if name not in blocks:
+                continue
             rec = {"name": name, "rows": S + P * C, "ok": False}
             t0 = time.perf_counter()
             try:
@@ -607,9 +655,12 @@ def cell_blocks(config: dict, hlo_dir=None) -> list:
                 index_views=index_views(hlo, cache, cfg.index_head_dim),
                 span_sorts=span_sorts(hlo, rt.max_seq_len)
                 if cfg.has_indexer else [],
-                state_copies=state_copies(hlo, state.h) if state else [])
+                state_copies=state_copies(hlo, state.h) if state else [],
+                expert_moves=expert_moves(hlo, moe) if coded else [])
             rec["ok"] = held < 15.75 * 2 ** 30 and not rec["window_moves"] \
                 and not rec["state_copies"] \
+                and not ("moe_experts" in rec["mosaic_calls"]
+                         and rec["expert_moves"]) \
                 and not rec["stream_moves"] and not rec["index_views"] \
                 and not rec["span_sorts"] and bool(rec["mosaic_calls"])
             told = selecting_calls(config, hlo)
@@ -897,6 +948,132 @@ def run_mamba1_step(name, small, want):
                          and rec["dead_rows_kept"] and len(dead)
                          and rec["live_rows_moved"]
                          and not rec["state_copies"] and rec["finite"]
+                         and all(rec["hlo_has"].values()))
+    except Exception as e:  # a compiler refusal is the finding: record it
+        rec["error"] = f"{type(e).__name__}: {e}"[:1500]
+    return rec
+
+
+#: the geometries of the cells whose steps are few rows over experts
+#: in int8 codes, for ops/moe_experts.py: name -> layers of experts in
+#: the stack, experts HELD, of E the router ranges over, k a row, D, F,
+#: and the experts of the held a step of the cell touches (the cell's
+#: own `experts_touched_share`: 69.5 % of 16, 85.3 % of 128, 95.4 % of
+#: 64; ledger, PR 60)
+EXPERT_CELLS = {
+    "expert_cell_glm5": (10, 16, 256, 8, 6144, 2048, 11),
+    "expert_cell_keye": (8, 128, 128, 8, 2048, 768, 109),
+    "expert_cell_smallthinker": (16, 64, 64, 6, 2560, 768, 61),
+}
+
+
+def seeded_experts(key, L: int, E: int, D: int, F: int):
+    """A stack of experts as the kernel and the dense products take it:
+    int8 codes drawn a layer at a time (a draw of the whole stack holds
+    four times its bytes), scales that keep a row of unit size near unit
+    size through an expert."""
+    import jax
+    import jax.numpy as jnp
+
+    def codes(key, shape, contracted):
+        def layer(k):
+            return jax.lax.bitcast_convert_type(
+                jax.random.bits(k, shape, jnp.uint8), jnp.int8)
+        s = jax.random.uniform(jax.random.fold_in(key, 1),
+                               (L, E, 1, shape[-1]), minval=0.5, maxval=1.5)
+        return {"q8": jax.jit(lambda ks: jax.lax.map(layer, ks))(
+                    jax.random.split(key, L)),
+                "s": (s / (74.0 * contracted ** 0.5)).astype(jnp.bfloat16)}
+
+    kg, ku, kd = jax.random.split(key, 3)
+    return {"w_gate": codes(kg, (E, D, F), D), "w_up": codes(ku, (E, D, F), D),
+            "w_down": codes(kd, (E, F, D), F)}
+
+
+def run_expert_cell(name, small, want):
+    """ops/moe_experts.py at a cell's expert geometry (EXPERT_CELLS),
+    the layer index traced over a stack as the engine's layer loop
+    rides it, beside XLA's dense products of the same operands
+    (models/common.py dense_experts, the stack sliced by the scan as
+    the programs slice it today): parity of the two on this backend
+    (the untouched experts' gates are zero in both), then a layer's
+    time of each at 32 and at 64 rows with ALL held experts touched, the
+    cell's own share and ONE, with the GB/s over the touched experts'
+    codes; the dense products stream every expert whatever is
+    touched."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from butterfly_tpu.models.common import dense_experts
+    from butterfly_tpu.ops.moe_experts import moe_experts
+
+    L, E, of, k, D, F, cell = (2, 8, 16, 2, 256, 384, 5) if small \
+        else EXPERT_CELLS[name]
+    per_row = max(1, round(k * E / of))
+    act = jax.nn.silu
+    rec = {"name": name, "ok": False, "expert_mb": round(3 * D * F / 1e6, 2)}
+    t0 = time.perf_counter()
+
+    def gates(rows, touched):
+        """comb [rows, E]: each row's gate 1/k on `per_row` of `touched`
+        experts spread over the held, every one of them chosen."""
+        ids = np.linspace(0, E - 1, touched).round().astype(int)
+        comb = np.zeros((rows, E), np.float32)
+        for r in range(rows):
+            for j in range(min(per_row, touched)):
+                comb[r, ids[(r * per_row + j) % touched]] = 1.0 / k
+        return jnp.asarray(comb)
+
+    def kernel_layers(x, comb, ex):
+        def body(x, l):
+            out = moe_experts(x, comb, ex, l, act)
+            return x + (0.1 * out).astype(x.dtype), out
+        return jax.lax.scan(body, x, jnp.arange(L))
+
+    def dense_layers(x, comb, ex):
+        def body(x, p):
+            out = dense_experts(x[:, None], comb[:, None], p, act)[:, 0]
+            return x + (0.1 * out).astype(x.dtype), out
+        return jax.lax.scan(body, x, ex)
+
+    def a_layer_us(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t1 = time.perf_counter()
+        for _ in range(10):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t1) / 10 / L * 1e6
+
+    try:
+        ex = seeded_experts(jax.random.PRNGKey(0), L, E, D, F)
+        x64 = jax.random.normal(jax.random.PRNGKey(1), (64, D), jnp.bfloat16)
+        kernel, dense = jax.jit(kernel_layers), jax.jit(dense_layers)
+        compiled = kernel.lower(x64, gates(64, cell), ex).compile()
+        hlo = compiled.as_text()
+        rec["hlo_has"] = {w: w in hlo for w in want}
+        rec["expert_moves"] = expert_moves(hlo, ex)
+        _, out = compiled(x64, gates(64, cell), ex)
+        _, ref = dense(x64, gates(64, cell), ex)
+        out, ref = (np.asarray(a, np.float32) for a in (out, ref))
+        rec["max_err"] = round(float(np.max(
+            np.abs(out - ref) / (1 + np.abs(ref)))), 5)
+        rec["out_rms"] = round(float(np.sqrt(np.mean(ref ** 2))), 4)
+        rec["compile_run_s"] = round(time.perf_counter() - t0, 2)
+        rec["rows"] = {}
+        for rows in (32, 64):
+            x = x64[:rows]
+            at = {"dense_us_a_layer": round(
+                a_layer_us(dense, x, gates(rows, E), ex), 1)}
+            at["dense_gb_s"] = round(
+                E * 3 * D * F / at["dense_us_a_layer"] / 1e3, 1)
+            for label, touched in (("all", E), ("cell", cell), ("one", 1)):
+                us = a_layer_us(kernel, x, gates(rows, touched), ex)
+                at[label] = {"touched": touched, "us_a_layer": round(us, 1),
+                             "gb_s": round(touched * 3 * D * F / us / 1e3, 1)}
+            rec["rows"][str(rows)] = at
+        rec["ok"] = bool(np.isfinite(out).all() and rec["max_err"] < 3e-2
+                         and not rec["expert_moves"]
                          and all(rec["hlo_has"].values()))
     except Exception as e:  # a compiler refusal is the finding: record it
         rec["error"] = f"{type(e).__name__}: {e}"[:1500]
@@ -1760,6 +1937,8 @@ def main() -> int:
                 for n in LATENT_CELLS if wanted(n)]
     results += [run_paged_cell(n, args.small, want)
                 for n in PAGED_CELLS if wanted(n)]
+    results += [run_expert_cell(n, args.small, want)
+                for n in EXPERT_CELLS if wanted(n)]
     results += [run_stage_cell(n, args.small, want)
                 for n in STAGE_CELLS if wanted(n)]
     if wanted("sparse_cell"):
@@ -1808,6 +1987,14 @@ def main() -> int:
               + (f" counted={r['counted_us']}us a call, lax.top_k and the "
                  f"running count {r['plain_us']}us"
                  if "counted_us" in r else "")
+              + "".join(
+                  f"\n     {rows} rows: dense {at['dense_us_a_layer']}us a "
+                  f"layer ({at['dense_gb_s']} GB/s)" + "".join(
+                      f", {at[w]['touched']} touched {at[w]['us_a_layer']}us"
+                      f" ({at[w]['gb_s']} GB/s)"
+                      for w in ("all", "cell", "one"))
+                  for rows, at in r.get("rows", {}).items()
+                  if "dense_us_a_layer" in at)
               + "".join(
                   f"\n     {label}: the walk alone {c['kernel_alone_us']}us "
                   f"({c['kernel_ns_a_live_page']} ns a live page, "
