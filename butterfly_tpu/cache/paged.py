@@ -74,12 +74,32 @@ semantics, TPU-native mechanics):
   declared so can be read where it lies (ops/index_scores.py; PERF.md,
   PR 53). The writers pad a key on its way in (_in_lanes) and XLA's
   reader cuts the view back to Hi.
+* TWO LIFETIMES of rows, where a model has sliding layers and streams
+  that outlive the window (ring_pages): a sliding layer at position p
+  reads rows p - window + 1 .. p and never an older one, so its rows
+  live in a pool of their own, k_ring / v_ring [Ls, S*R + 1, Kv, page,
+  H], which a slot owns R pages of from admission to release, as a
+  RING: ring_table [S, R], position p's row in entry (p // page) % R.
+  R = ceil((window + staged rows) / page) + 1 pages hold the window,
+  what the write-combined window may stage and a page more, so a page
+  is rewritten only when no query of the stream can reach its rows
+  again: a sliding layer holds R pages a stream whatever the context.
+  k_pages / v_pages then hold the FULL layers alone under the page
+  table and the free list, as ever. The write-combined window keeps
+  every attention layer (it is small), read by the layer's index among
+  them; the pools are read by the index among the layers of the kind
+  (models.common.layer_runs by_window: a run of layers is of one kind,
+  and which pool it reads is a choice made while tracing). The flush
+  writes a staged row to the pool of its layer's kind. Nothing else
+  changes, and a model without sliding layers, or one whose table is
+  no longer than its ring, allocates and compiles what it did.
 """
 from __future__ import annotations
 
 from functools import partial
 from typing import NamedTuple, Optional, Tuple
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -94,9 +114,10 @@ from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
 # ops.flash_attention, none of which import this module; the ops kernel
 # wrappers import nothing project-local at module level.
 from butterfly_tpu.models.common import (
-    _cast_float, attend, attend_token_rows, attn_output,
+    _cast_float, attend, attend_token_rows, attn_gate, attn_output,
     early_router_logits, embed_tokens, experts_in_place, ffn_close,
-    final_logits, ffn_run, index_proj, index_scores, indexer_unsupported,
+    final_logits, ffn_run, gate_unsupported, index_proj, index_scores,
+    indexer_unsupported,
     latent_attend, latent_proj, latent_queries, latent_unsupported, layer_at,
     layer_experts, layer_mask,
     layer_pattern_of, layer_runs, layer_stack, make_mask, qkv_proj,
@@ -131,6 +152,30 @@ class PagedKVCache(NamedTuple):
     # attention indexer (one a token), a third kind of cached row under
     # the same page table, free list, window and flush
     ki_pages: Optional[jax.Array] = None
+    # the SLIDING layers' rows, where they are kept apart (ring_pages;
+    # the module's docstring): pools [Ls, S*R + 1, Kv, page, H] (the
+    # last page null), the ring [S, R] of the pages a slot owns (fixed:
+    # slot s owns s*R .. s*R + R - 1; it is a table so that the kernel
+    # and the flush walk it as they walk page_table), and which of the
+    # attention layers, as the window counts them, are full and which
+    # slide ([Lf], [Ls] int32). k_pages / v_pages then hold the full
+    # layers alone. All None for a cache of one kind
+    k_ring: Optional[jax.Array] = None
+    v_ring: Optional[jax.Array] = None
+    ring_table: Optional[jax.Array] = None
+    full_layers: Optional[jax.Array] = None
+    ring_layers: Optional[jax.Array] = None
+
+    @property
+    def by_kind(self) -> bool:
+        return self.k_ring is not None
+
+    @property
+    def num_layers(self) -> int:
+        """Attention layers with rows here, both kinds: the window's
+        leading dim."""
+        return self.k_pages.shape[0] + (self.k_ring.shape[0]
+                                        if self.by_kind else 0)
 
     @property
     def page_size(self) -> int:
@@ -197,11 +242,57 @@ def pool_layout(cfg: ModelConfig) -> str:
     return "token" if pool_row(cfg)[0] != cfg.num_kv_heads else "head"
 
 
+def staged_most(runtime: RuntimeConfig) -> int:
+    """The most rows a slot's write-combined window ever holds: what
+    the engine sizes it to (engine/serving.py _ensure_window), blocks in
+    flight x steps a block x the chunk's width."""
+    return max(1, runtime.inflight_blocks) * runtime.decode_steps_per_tick \
+        * max(1, min(runtime.prefill_chunk, runtime.prefill_inline_budget))
+
+
+def ring_pages(cfg: ModelConfig, runtime: RuntimeConfig,
+               meshed: bool = False) -> int:
+    """R, the pages of a sliding layer's ring a slot (the module's
+    docstring), or 0 where the cache is of ONE kind, as it always was:
+    a model none of whose layers slides; a table no longer than the
+    ring would be (max_seq within the window and what is staged:
+    nothing to save); and what the ring is not carried through yet: no
+    write-combined window (the ring is written by its flush alone), an
+    int8 pool (its scale pools), speculation (its verify is the
+    lane-wide forward), pipeline stages and every other mesh."""
+    if not cfg.slides or cfg.has_indexer or cfg.is_latent:
+        return 0
+    if not runtime.kv_write_combine or runtime.kv_quant != "none" \
+            or runtime.speculative_gamma > 0 or meshed:
+        return 0
+    page = runtime.page_size
+    R = -(-(cfg.sliding_window + staged_most(runtime)) // page) + 1
+    return R if R < -(-runtime.max_seq_len // page) else 0
+
+
+def pool_kinds(cache: PagedKVCache) -> Optional[dict]:
+    """What /health reports of a cache by kind, None for a cache of one:
+    for "full" (the page table's pool) and "slide" (the rings') the
+    layers, the pages (the null page apart), the bytes of the pool's
+    keys and values, and for the rings the pages a slot owns."""
+    if not cache.by_kind:
+        return None
+
+    def kind(k, v):
+        return {"layers": k.shape[0], "pages": k.shape[1] - 1,
+                "bytes": int(k.nbytes + v.nbytes)}
+
+    return {"full": kind(cache.k_pages, cache.v_pages),
+            "slide": {**kind(cache.k_ring, cache.v_ring),
+                      "ring_pages": cache.ring_table.shape[1]}}
+
+
 def init_paged_cache(cfg: ModelConfig, runtime: RuntimeConfig,
                      dtype: Optional[jnp.dtype] = None,
-                     shardings: Optional[PagedKVCache] = None
-                     ) -> PagedKVCache:
+                     shardings: Optional[PagedKVCache] = None,
+                     ring: int = 0) -> PagedKVCache:
     """Pool sized from the runtime config (+1 reserved null page).
+    ring: ring_pages' R where the sliding layers' rows are kept apart.
 
     runtime.kv_quant="int8" allocates int8 code pools + f32 scale pools
     (the serving-path twin of models.common.init_cache(quant="int8")).
@@ -215,6 +306,10 @@ def init_paged_cache(cfg: ModelConfig, runtime: RuntimeConfig,
     P += 1  # null page
     heads, width = pool_row(cfg)
     L = cfg.num_attn_layers
+    S = runtime.max_batch_size
+    slides = np.asarray(cfg.slides[:L], bool) if ring else None
+    if ring:
+        L = int((~slides).sum())
     shape = (L, P, heads, page, width)
     if runtime.kv_quant not in ("none", "int8"):
         raise ValueError(f"unknown kv quant {runtime.kv_quant!r}")
@@ -236,12 +331,22 @@ def init_paged_cache(cfg: ModelConfig, runtime: RuntimeConfig,
                 k_scale_pages=jnp.zeros(sshape, jnp.float32),
                 v_scale_pages=jnp.zeros(sshape, jnp.float32),
             )
+        by_kind = {}
+        if ring:
+            rshape = (int(slides.sum()), S * ring + 1, heads, page, width)
+            by_kind = dict(
+                k_ring=jnp.zeros(rshape, dtype),
+                v_ring=jnp.zeros(rshape, dtype),
+                ring_table=jnp.arange(S * ring, dtype=jnp.int32
+                                      ).reshape(S, ring),
+                full_layers=jnp.asarray(np.flatnonzero(~slides), jnp.int32),
+                ring_layers=jnp.asarray(np.flatnonzero(slides), jnp.int32))
         return PagedKVCache(
             k_pages=jnp.zeros(shape, dtype),
             v_pages=None if cfg.is_latent else jnp.zeros(shape, dtype),
             page_table=table, lengths=lengths,
             ki_pages=jnp.zeros(ki_shape, dtype) if cfg.has_indexer else None,
-        )
+            **by_kind)
 
     return jax.jit(build, out_shardings=shardings)()
 
@@ -443,8 +548,8 @@ def init_kv_window(cache: PagedKVCache, width: int,
     """Allocate a window sized to `width` staged tokens per slot, in the
     pool's representation (and, given `shardings` from
     parallel/partition.py kv_window_specs, in its mesh layout)."""
-    L, _, Kv, _, H = cache.k_pages.shape
-    S = cache.num_slots
+    _, _, Kv, _, H = cache.k_pages.shape
+    L, S = cache.num_layers, cache.num_slots
     shape = (L, S, Kv, width, H)
     quantized, dtype = cache.quantized, cache.k_pages.dtype
     values = cache.v_pages is not None
@@ -576,7 +681,7 @@ def window_rows(leaf, layer, slots=None):
 
 
 @jax.named_scope("kv_gather")
-def insert_window_view(view, wl, base):
+def insert_window_view(view, wl, base, ring: bool = False):
     """Insert a layer's window entries into the gathered float view at
     their absolute positions: view [B, S_max, Kv, H], wl [S, Kv, W, H],
     base [S] flushed length per slot. Entries past a slot's valid count
@@ -584,10 +689,13 @@ def insert_window_view(view, wl, base):
     position) and positions past S_max drop, so the whole window inserts
     unconditionally — the result is element-wise identical to the
     window-off path's written pool view, which is the byte-parity
-    contract."""
+    contract. ring: the view is a ring's cells (ring_positions), and an
+    entry lands in the cell of its position."""
     B = view.shape[0]
     W = wl.shape[2]
     pos = base[:, None] + jnp.arange(W)[None, :]        # [B, W]
+    if ring:
+        pos = pos % view.shape[1]
     return view.at[jnp.arange(B)[:, None], pos].set(
         wl.transpose(0, 2, 1, 3), mode="drop")
 
@@ -632,8 +740,11 @@ def _staged_runs(cache: PagedKVCache, win_len, W: int):
                                         method="compare_all"), S - 1)
     lp = first[slot] + j - (ends - runs)[slot]             # logical page
     pg = cache.page_table[slot, jnp.clip(lp, 0, mp - 1)]
-    return ends[-1], jnp.stack([slot, pg, lp * page - ln[slot], n[slot]],
-                               axis=1).astype(jnp.int32)
+    cols = [slot, pg, lp * page - ln[slot], n[slot]]
+    if cache.by_kind:
+        # the same run in the sliding layers' ring: a fifth column
+        cols.append(cache.ring_table[slot, lp % cache.ring_table.shape[1]])
+    return ends[-1], jnp.stack(cols, axis=1).astype(jnp.int32)
 
 
 def flush_paged_window(cache: PagedKVCache, window: KVWindow, win_len):
@@ -665,8 +776,14 @@ def flush_paged_window(cache: PagedKVCache, window: KVWindow, win_len):
     interleave with a predecessor's. Returns (cache with lengths
     advanced by win_len, zeroed win_len, flushed token count
     [scalar]).
+
+    A cache by kind writes each run twice: the full layers' rows into
+    their page of k_pages / v_pages and the sliding layers' into their
+    ring's (the window holds every attention layer: a run's rows are
+    cut from it whole, [L, 1, Kv, seg, H], and each pool takes the
+    layers of its kind).
     """
-    L, _, _, page, _ = cache.k_pages.shape
+    L, page = window.k.shape[0], cache.page_size
     W, Kv = window.width, window.k.shape[2]
     seg = min(page, W)          # window rows one run can take
     ws = window_step(W)
@@ -674,21 +791,29 @@ def flush_paged_window(cache: PagedKVCache, window: KVWindow, win_len):
     runs, table = _staged_runs(cache, win_len, W)
     rows = jnp.arange(page, dtype=jnp.int32)
     staged_leaves = window_leaves(window)
+    # (the page's column in the run table, the window's layers a pool
+    # holds) a pool leaf: all of them, or a kind's
+    kinds = [(1, None)] * len(staged_leaves)
+    if cache.by_kind:
+        kinds = [(1, cache.full_layers)] * 2 + [(4, cache.ring_layers)] * 2
+        staged_leaves = staged_leaves * 2
 
     def body(i, pools):
-        slot, pg, base, n = lax.dynamic_slice(table, (i, 0), (1, 4))[0]
+        run = lax.dynamic_slice(table, (i, 0), (1, table.shape[1]))[0]
+        slot, _, base, n = run[:4]
         # the page's row r holds window index base + r: a slice of the
         # window from the nearest index that keeps it inside, rolled so
         # that its rows meet the page's
         keep = (base + rows >= 0) & (base + rows < n)           # [page]
         at = jnp.clip(base, 0, W - seg)
 
-        def merge(pool, staged):
-            """pool [L, P, ...] with the run's rows of page pg taken
-            from staged [L, S, Kv, W, ...]."""
+        def merge(pool, staged, kind):
+            """pool [Lp, P, ...] with the run's rows of its page taken
+            from staged [L, S, Kv, W, ...], the layers `kind` names."""
             tail = staged.shape[4:]
+            pg, layers = run[kind[0]], kind[1]
             old = lax.dynamic_slice(pool, (0, pg) + (0,) * (pool.ndim - 2),
-                                    (L, 1) + pool.shape[2:])
+                                    (pool.shape[0], 1) + pool.shape[2:])
             src, mine, first = staged, slot, at
             if staged.ndim == 4:
                 # an int8 window's scales, a step a row (KVWindow): the
@@ -700,6 +825,8 @@ def flush_paged_window(cache: PagedKVCache, window: KVWindow, win_len):
             new = lax.dynamic_slice(
                 src, (0, mine, 0, first) + (0,) * len(tail),
                 (L, 1, src.shape[2], seg) + tail)
+            if layers is not None:
+                new = new[layers]
             if seg < page:
                 new = jnp.pad(new, [(0, 0)] * 3 + [(0, page - seg)]
                               + [(0, 0)] * len(tail))
@@ -710,9 +837,16 @@ def flush_paged_window(cache: PagedKVCache, window: KVWindow, win_len):
             return lax.dynamic_update_slice(
                 pool, new.reshape(old.shape), (0, pg) + (0,) * (pool.ndim - 2))
 
-        return tuple(merge(p, w) for p, w in zip(pools, staged_leaves))
+        return tuple(merge(p, w, k)
+                     for p, w, k in zip(pools, staged_leaves, kinds))
 
-    pools = lax.fori_loop(0, runs, body, pool_leaves(cache))
+    mine = pool_leaves(cache)
+    if cache.by_kind:
+        mine += (cache.k_ring, cache.v_ring)
+    pools = lax.fori_loop(0, runs, body, mine)
+    if cache.by_kind:
+        cache = cache._replace(k_ring=pools[2], v_ring=pools[3])
+        pools = pools[:2]
     cache = pool_leaves(cache, pools)._replace(
         lengths=cache.lengths + win_len)
     return cache, jnp.zeros_like(win_len), win_len.sum()
@@ -729,6 +863,17 @@ def flush_paged_window(cache: PagedKVCache, window: KVWindow, win_len):
 #: block's verify, tools that read a lane-wide step) does not
 LANE_WIDE = ("the lane-wide forward (paged_forward, paged_forward_window: "
              "the speculative block's verify)")
+
+
+def by_kind_unsupported(cache: PagedKVCache, what: str) -> None:
+    """Refuse a cache that keeps the sliding layers' rows in a ring of
+    their own (PagedKVCache.by_kind) on a path that reads every layer
+    under the one page table."""
+    if cache.by_kind:
+        raise NotImplementedError(
+            f"{what} reads every layer's rows under ONE page table; this "
+            "cache keeps the sliding layers' in a ring of their own "
+            "(cache/paged.py ring_pages): not supported for this cache")
 
 
 def _settled(*view):
@@ -1088,10 +1233,20 @@ def latent_paged_attend(q, kp, layer, *, cfg: ModelConfig, page_table,
     return out, jnp.asarray(read, jnp.float32).reshape(1)
 
 
+def ring_positions(written, cells: int) -> jax.Array:
+    """[B, cells] int32: the absolute position whose row each cell of a
+    stream's ring holds (cell r = ring entry r // page, offset r % page)
+    once `written` [B] positions are in it: the last position below
+    `written` that is r modulo the ring's size; negative where none has
+    been written."""
+    r = jnp.arange(cells, dtype=jnp.int32)[None, :]
+    return (written[:, None] - 1 - r) // cells * cells + r
+
+
 def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
                  positions, mask, active, use_kernel: bool, fresh: bool,
                  ksp=None, vsp=None, win=None, sliding_window=None,
-                 index=None):
+                 index=None, ring=None):
     """One layer's attention for [B,T] queries whose K/V is already
     written: the dispatch between the paged kernel (T == 1), the flash
     kernels (fresh chunk; warm chunk over the cached prefix) and the
@@ -1122,7 +1277,15 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
     count. A latent-attention model (q its absorbed queries, k its
     rows, no v and no vp) attends through latent_paged_attend and
     nowhere else, its `index` with it where it has an indexer, and
-    returns that function's pair."""
+    returns that function's pair.
+    ring (a cache by kind, window on): None, or the layer's index in
+    the WINDOW's leaves, `layer` then being its index in the pool of its
+    kind (kp/vp: that pool); page_table is then that kind's too. True
+    where the kind is the sliding one: the table is the slot's ring
+    (ring_table [B, R]) and a row of position p lies in entry
+    (p // page) % R; the kernel walks it so, and the dense branch reads
+    the ring's cells whole and masks each by the position it holds
+    (ring_positions)."""
     if cfg.is_latent:
         return latent_paged_attend(
             q, kp, layer, cfg=cfg, page_table=page_table,
@@ -1142,6 +1305,8 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
         base = start - win_len  # flushed pool length per row
     out = None
     tried_kernel = True
+    win_layer, slides = (layer, False) if ring is None else ring
+    by_kind = {} if ring is None else dict(win_layer=win_layer, ring=slides)
     if use_kernel and T == 1:
         if win is not None:
             # pool-valid lengths are the FLUSHED base; the staged run
@@ -1155,7 +1320,8 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
                                           win_count=wcnt,
                                           win_k_scale=window.k_scale,
                                           win_v_scale=window.v_scale,
-                                          sliding_window=sliding_window)
+                                          sliding_window=sliding_window,
+                                          **by_kind)
         else:
             # lengths INCLUDING the token just written (inactive: 0 ->
             # no pages visited, output discarded)
@@ -1210,9 +1376,20 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
         # mesh that should shard it this is a fault, not a choice.
         if tried_kernel:
             note_kernel("dense_fallback")
-        mask = layer_mask(mask, positions, sliding_window)
+        if slides:
+            # the ring's cells, each masked by the position it holds
+            # once the whole window is inserted: what is staged past a
+            # row's count lies beyond every query, as ever, and the cell
+            # it takes held a row no query reaches any more (ring_pages)
+            cells = page_table.shape[1] * kp.shape[3]
+            at = ring_positions(base + window.width, cells)[:, None, :]
+            p = positions[:, :, None]
+            mask = mask[:, :, :1] & (at >= 0) & (at <= p) \
+                & (p - at < sliding_window)
+        else:
+            mask = layer_mask(mask, positions, sliding_window)
         if win is not None:
-            wk, wv, wks, wvs = (window_rows(a, layer, slots) for a in (
+            wk, wv, wks, wvs = (window_rows(a, win_layer, slots) for a in (
                 window.k, window.v, window.k_scale, window.v_scale))
         if quant:
             ck, k_s = gather_paged_layer_q(kp, ksp, page_table, layer)
@@ -1228,8 +1405,8 @@ def paged_attend(q, k, v, kp, vp, layer, *, cfg: ModelConfig, page_table,
             ck = gather_paged_layer(kp, page_table, layer)
             cv = gather_paged_layer(vp, page_table, layer)
             if win is not None:
-                ck = insert_window_view(ck, wk, base)
-                cv = insert_window_view(cv, wv, base)
+                ck = insert_window_view(ck, wk, base, slides)
+                cv = insert_window_view(cv, wv, base, slides)
             out = attend(q, *_settled(ck, cv), mask, cfg)
     return out
 
@@ -1238,7 +1415,7 @@ def _layer_open(x, lp, cfg: ModelConfig, cos, sin):
     """A layer up to its attention: the weights in the compute dtype,
     the pre-norm, the router's logits where the router stands before
     attention, and the projections, rotated where the layer rotates.
-    Returns (lp, q, k, v, route, sliding_window, index, mix): `route`
+    Returns (lp, q, k, v, route, sliding_window, index, mix, gate): `route`
     (None for most models) is carried across attention to _layer_close,
     the layer's sliding window (None for a model without a pattern)
     goes to paged_attend, and so does `index`, the indexer's (qI, kI, w)
@@ -1246,7 +1423,9 @@ def _layer_open(x, lp, cfg: ModelConfig, cos, sin):
     one), once kI is cached beside k and v; `mix` is the residual
     path's (models.common.stream_read: x is [n,B,T,D] for a model of n
     streams, and None comes back for every other), which _layer_close
-    takes. With _layer_close, the part of a layer that paged_layer_body
+    takes, as it does `gate`, the attention's output gate of these rows
+    (models.common.attn_gate; None for a model without). With
+    _layer_close, the part of a layer that paged_layer_body
     and the packed step (packed_layer) share, so that a change to a
     norm, a projection or the residual path reaches both."""
     lp = jax.tree.map(lambda a: _cast_float(a, jnp.dtype(cfg.dtype)), lp)
@@ -1262,22 +1441,24 @@ def _layer_open(x, lp, cfg: ModelConfig, cos, sin):
         index = index_proj(h, lp, cfg, cos, sin, cq) if cfg.has_indexer \
             else None
         return (lp, jnp.pad(q, pad), jnp.pad(row[:, :, None], pad), None,
-                None, None, index, mix)
+                None, None, index, mix, None)
     rope, sliding_window = layer_pattern_of(lp.get("pattern"))
     q, k, v = qkv_proj(h, lp["attn"], cfg, cos, sin, rope)
     index = index_proj(x, lp, cfg, cos, sin) if cfg.has_indexer else None
     return (lp, q, k, v, early_router_logits(x, lp, cfg), sliding_window,
-            index, mix)
+            index, mix, attn_gate(h, lp["attn"], cfg))
 
 
-def _layer_close(x, out, lp, cfg: ModelConfig, mix, route=None, ok=None):
+def _layer_close(x, out, lp, cfg: ModelConfig, mix, route=None, ok=None,
+                 gate=None):
     """A layer from its attention's output on: the output projection
     and the feed-forward, each written back onto the residual path
-    (models.common.stream_write; ffn_close). mix, route: _layer_open's.
+    (models.common.stream_write; ffn_close). mix, route, gate:
+    _layer_open's.
     Returns (x, load): with `ok` [B,T], the rows that are real, and a
     model of experts, `load` is what the layer's routing asked of them
     (models.common.expert_load), else None."""
-    x = stream_write(x, attn_output(out, lp["attn"], cfg), mix, cfg)
+    x = stream_write(x, attn_output(out, lp["attn"], cfg, gate), mix, cfg)
     return ffn_close(x, lp, cfg, route, ok)
 
 
@@ -1318,7 +1499,7 @@ def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
     given; what was written comes back last.
     """
     quant = ksp is not None
-    lp, q, k, v, route, sliding_window, index, mix = _layer_open(
+    lp, q, k, v, route, sliding_window, index, mix, gate = _layer_open(
         x, lp, cfg, cos, sin)
     if win is not None:
         window, win_len = win
@@ -1348,7 +1529,7 @@ def paged_layer_body(x, lp, kp, vp, *, cfg: ModelConfig, page_table,
         index=None if index is None else (index[0], index[2], pool[4]))
     if index is not None:
         out = out[0]
-    x, _ = _layer_close(x, out, lp, cfg, mix, route)
+    x, _ = _layer_close(x, out, lp, cfg, mix, route, gate=gate)
     if win is not None:
         return x, window
     return (x, *(a for a in (kp, vp, ksp, vsp, kip) if a is not None))
@@ -1380,6 +1561,9 @@ def paged_forward(params, cfg: ModelConfig, tokens: jax.Array,
     """
     ssm_unsupported(cfg, LANE_WIDE)
     latent_unsupported(cfg, LANE_WIDE)
+    by_kind_unsupported(cache, LANE_WIDE)
+    if cfg.first_k_dense:
+        gate_unsupported(cfg, LANE_WIDE)
     B, T = tokens.shape
     if positions is None:
         positions = cache.lengths[:, None] + jnp.arange(T)[None, :]
@@ -1439,6 +1623,9 @@ def paged_forward_window(params, cfg: ModelConfig, tokens: jax.Array,
     """
     ssm_unsupported(cfg, LANE_WIDE)
     latent_unsupported(cfg, LANE_WIDE)
+    by_kind_unsupported(cache, LANE_WIDE)
+    if cfg.first_k_dense:
+        gate_unsupported(cfg, LANE_WIDE)
     B, T = tokens.shape
     if active is None:
         active = jnp.ones((B,), bool)
@@ -1487,6 +1674,10 @@ class PackedRows(NamedTuple):
     chunk_mask: jax.Array
     chunk_table: jax.Array  # [P, max_pages] each chunk's OWN slot's row
     head: jax.Array         # [S] the row the LM head reads for a slot
+    # a cache by kind: the decode rows' rings [S, R] and each chunk's
+    # own slot's [P, R]
+    ring_table: Optional[jax.Array] = None
+    chunk_ring: Optional[jax.Array] = None
 
 
 def packed_rows(params, cfg: ModelConfig, tokens, cache: PagedKVCache,
@@ -1532,12 +1723,15 @@ def packed_rows(params, cfg: ModelConfig, tokens, cache: PagedKVCache,
         chunk_pos=chunk_pos,
         chunk_mask=make_mask(chunk_pos, cache.max_seq)
         & chunk_ok[:, None, None],
-        chunk_table=cache.page_table[chunk_slot], head=head)
+        chunk_table=cache.page_table[chunk_slot], head=head,
+        ring_table=cache.ring_table,
+        chunk_ring=None if cache.ring_table is None
+        else cache.ring_table[chunk_slot])
 
 
 def packed_layer(x, lp, pools, window: Optional[KVWindow], rows: PackedRows,
                  cfg: ModelConfig, use_kernel: bool, layer=None,
-                 looped: bool = True):
+                 looped: bool = True, kind=None):
     """One layer of the packed step. pools: (kp, vp, ksp, vsp, kip),
     scales None unless int8 and kip None unless the model has an
     indexer: with the window off this layer's slices, which it
@@ -1560,9 +1754,13 @@ def packed_layer(x, lp, pools, window: Optional[KVWindow], rows: PackedRows,
     leading dense layer, which routes nothing); a latent-attention
     model with an indexer sparse_paged_attend's four in its place.
     Under cfg.experts_held the share's two values (expert_load) stay
-    LAST, behind every count (before_share)."""
+    LAST, behind every count (before_share).
+    kind (a cache by kind, window on): (the layer's index in the pool
+    of its kind, whether that kind slides); `pools` is then that kind's
+    pool, `layer` stays the layer's index in the window, and a sliding
+    layer reads its rows through the slots' rings."""
     S, (P, C) = rows.written.shape[0], rows.chunk_pos.shape
-    lp, q, k, v, route, sliding_window, index, mix = _layer_open(
+    lp, q, k, v, route, sliding_window, index, mix, gate = _layer_open(
         x, lp, cfg, rows.cos, rows.sin)
     dec_win = chunk_win = None
     if window is not None:
@@ -1582,16 +1780,21 @@ def packed_layer(x, lp, pools, window: Optional[KVWindow], rows: PackedRows,
                  kip)
     (kp, vp, ksp, vsp, kip), layer = (pools, layer) if window is not None \
         else _as_pool(pools)
+    ring, slides = None, False
+    if kind is not None:
+        ring, layer, slides = (layer, kind[1]), kind[0], kind[1]
     attend_rows = partial(paged_attend, kp=kp, vp=vp, layer=layer, cfg=cfg,
                           use_kernel=use_kernel, fresh=False,
-                          ksp=ksp, vsp=vsp, sliding_window=sliding_window)
+                          ksp=ksp, vsp=vsp, sliding_window=sliding_window,
+                          ring=ring)
 
     def rows_index(cut):
         """paged_attend's `index` for one group of rows."""
         return None if index is None else (cut(index[0]), cut(index[2]), kip)
 
     out = attend_rows(q[:S], k[:S], None if v is None else v[:S],
-                      page_table=rows.page_table,
+                      page_table=rows.ring_table if slides
+                      else rows.page_table,
                       positions=rows.written[:, None], mask=rows.dec_mask,
                       active=rows.active, win=dec_win,
                       index=rows_index(lambda a: a[:S]))
@@ -1605,7 +1808,8 @@ def packed_layer(x, lp, pools, window: Optional[KVWindow], rows: PackedRows,
             return None if a is None else a[S:].reshape(P, C, *a.shape[2:])
 
         out_c = attend_rows(chunks(q), chunks(k), chunks(v),
-                            page_table=rows.chunk_table,
+                            page_table=rows.chunk_ring if slides
+                            else rows.chunk_table,
                             positions=rows.chunk_pos, mask=rows.chunk_mask,
                             active=rows.chunk_ok, win=chunk_win,
                             index=rows_index(chunks))
@@ -1613,7 +1817,8 @@ def packed_layer(x, lp, pools, window: Optional[KVWindow], rows: PackedRows,
             out_c = out_c[0]
         out = jnp.concatenate(
             [out, out_c.reshape(P * C, 1, *out_c.shape[2:])])
-    x, load = _layer_close(x, out, lp, cfg, mix, route, rows.ok[:, None])
+    x, load = _layer_close(x, out, lp, cfg, mix, route, rows.ok[:, None],
+                           gate)
     if count is not None:
         if load is None and not cfg.is_latent:
             load = jnp.zeros((3,), jnp.float32)
@@ -1628,6 +1833,11 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
     unlike SHAPES, in the published order: mixers of two kinds
     (cfg.layer_types: one recurrent kind beside attention) or
     feed-forwards of two kinds (cfg.first_k_dense).
+    A cache that keeps the sliding layers' rows apart
+    (PagedKVCache.by_kind) runs every model so: a run then also ends
+    where sliding layers meet full ones, and `load` ends (before a
+    share's two) in the rows the sliding layers' decode rows read and
+    what they would have read with no window.
     Each run of one kind (models.common.layer_runs) is one scan that
     rides the layers' indices, into params["layers"] (what every layer
     has), into its mixer's own stack where there is one (params["mamba"]
@@ -1655,9 +1865,9 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
             st, m, rows, cfg, use_kernel)
         return (x, st), load
 
-    def attention(ffn, held, looped, carry, scanned):
+    def attention(ffn, held, looped, slides, carry, scanned):
         x, win = carry
-        (l, a), *mine = scanned
+        (l, a, *kth), *mine = scanned
         lp = run_layer_at(params, ffn, l, cfg, held)
         if "attn" in params:
             lp = {**lp, "attn": layer_at(params["attn"], a, cfg)}
@@ -1665,12 +1875,18 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
             x, new, _, load = packed_layer(x, lp, mine, None, rows, cfg,
                                            use_kernel)
             return (x, None), (new, load)
-        x, _, win, load = packed_layer(x, lp, pools, win, rows, cfg,
-                                       use_kernel, layer=a, looped=looped)
+        # a cache by kind (kth): the run's layers are of ONE window
+        # kind, read from that kind's pool at their index in it
+        x, _, win, load = packed_layer(
+            x, lp, ring_pools if slides else pools, win, rows, cfg,
+            use_kernel, layer=a, looped=looped,
+            kind=(kth[0], slides) if kth else None)
         return (x, win), (None, load)
 
-    for kind, first, n, at in layer_runs(cfg):
-        idx = (first + jnp.arange(n), at + jnp.arange(n))
+    ring_pools = (cache.k_ring, cache.v_ring, None, None, None)
+    for kind, first, n, at, *of_kind in layer_runs(cfg, cache.by_kind):
+        idx = (first + jnp.arange(n), at + jnp.arange(n),
+               *((of_kind[0] + jnp.arange(n),) if of_kind else ()))
         if kind != "attention":
             (x, state), load = lax.scan(recurrent, (x, state), idx)
         else:
@@ -1684,7 +1900,8 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
                                                use_kernel)
                 ffn = (stack, ffn[1])
             (x, window), (new, load) = lax.scan(
-                partial(attention, ffn, held, n > 1),
+                partial(attention, ffn, held, n > 1,
+                        bool(of_kind and of_kind[1])),
                 (x, window), (idx, *mine))
             written.append(new)
         loads.append(load)
@@ -1719,8 +1936,19 @@ def _packed_runs(params, cfg: ModelConfig, x, rows: PackedRows,
     # a dense model's layers route nothing: three zeros hold the
     # experts' places before what a family adds (_mixed_rows does so)
     routed = [l for l in loads if l is not None]
-    return x, kv, state, jnp.concatenate(routed).mean(axis=0) if routed \
+    load = jnp.concatenate(routed).mean(axis=0) if routed \
         else jnp.zeros((3,), jnp.float32)
+    if cache.by_kind:
+        # what the sliding layers' decode rows READ this step and what
+        # they would have read with no window, summed over those layers
+        # (the tick record's swa_rows_read / swa_rows_whole): a live
+        # row at position p reads min(p + 1, window) rows a layer
+        n = jnp.where(rows.active, rows.written + 1, 0)
+        ls = cache.k_ring.shape[0]
+        swa = jnp.stack([jnp.sum(jnp.minimum(n, cfg.sliding_window)),
+                         jnp.sum(n)]) * ls
+        load = before_share(load, swa.astype(jnp.float32), cfg)
+    return x, kv, state, load
 
 
 _POOL_LEAVES = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages",
@@ -1833,7 +2061,7 @@ def paged_forward_packed(params, cfg: ModelConfig, tokens: jax.Array,
     """
     x, rows = packed_rows(params, cfg, tokens, cache, chunk_tokens,
                           chunk_slot, chunk_count, active, window, win_len)
-    if cfg.has_ssm or cfg.first_k_dense:
+    if cfg.has_ssm or cfg.first_k_dense or cache.by_kind:
         x, kv, state, load = _packed_runs(params, cfg, x, rows, cache,
                                           window, state, use_kernel)
         logits = final_logits(params, cfg,

@@ -25,7 +25,7 @@ class ModelConfig:
 
     One config class covers the model families (GPT-2, Llama-3,
     Mixtral, SmallThinker, Keye, Granite-4.0-H, JoyAI-LLM-Flash,
-    Xing4.0, GLM-5, Olmo-Hybrid, Jamba) — the family is selected by `arch`,
+    Xing4.0, GLM-5, Olmo-Hybrid, Jamba, Trinity) — the family is selected by `arch`,
     the MoE fields, the per-layer attention pattern, the sparse-attention
     indexer, the per-layer KIND (`layer_types`: Mamba-2, Gated DeltaNet or
     Mamba-1 mixers beside attention layers), the latent-attention fields
@@ -37,7 +37,7 @@ class ModelConfig:
 
     arch: str = "llama"  # "gpt2" | "llama" | "mixtral" | "smallthinker"
                          # | "keye" | "granite_hybrid" | "joyai" | "xing"
-                         # | "glm5" | "olmo_hybrid" | "jamba"
+                         # | "glm5" | "olmo_hybrid" | "jamba" | "trinity"
     vocab_size: int = 32000
     hidden_size: int = 4096
     num_layers: int = 32
@@ -231,6 +231,20 @@ class ModelConfig:
                                       # its input (OLMo 2); False = x +
                                       # F(norm(x)). models/common.py
                                       # stream_read / stream_write
+    sandwich_norm: bool = False       # a norm on BOTH sides of a sublayer,
+                                      # x + norm_post(F(norm_in(x))): four
+                                      # learned weights a layer (ln1,
+                                      # ln1_post, ln2, ln2_post); the same
+                                      # pair carries it
+    attn_gate: bool = False           # an output gate on attention: g = h
+                                      # W_g [D, Nq x H] of the sublayer's
+                                      # normed input, and the heads' output
+                                      # times sigmoid(g), elementwise,
+                                      # BEFORE the output projection
+                                      # (models/common.py attn_gate)
+    mup_embed: bool = False           # the token embedding times
+                                      # sqrt(hidden_size), in float32
+                                      # before it is rounded to `dtype`
     # learned sparse attention (the lightning indexer of DeepSeek Sparse
     # Attention): index_heads queries of index_head_dim and ONE index key
     # a token score every cached position, and a query attends only the
@@ -331,6 +345,16 @@ class ModelConfig:
         if self.qk_norm and self.qk_norm_wide:
             raise ValueError("qk_norm (a head at a time) and qk_norm_wide "
                              "(the whole projection): one or the other")
+        if self.sandwich_norm:
+            for name, on in (("post_norm", self.post_norm),
+                             ("hc_mult", bool(self.hc_mult)),
+                             ("arch 'gpt2'", self.arch == "gpt2")):
+                if on:
+                    raise ValueError(
+                        f"sandwich_norm beside {name} is not supported")
+        if self.attn_gate and (self.is_latent or self.use_bias):
+            raise ValueError("attn_gate beside kv_lora_rank (latent "
+                             "attention) or use_bias is not supported")
         if not self.post_norm:
             return
         for name, on in (("no recurrent layer kind (layer_types)",
@@ -406,11 +430,13 @@ class ModelConfig:
                 f"first_k_dense {self.first_k_dense} of {self.num_layers} "
                 "layers: the leading dense layers are fewer than the "
                 "layers, and the rest have experts")
-        if self.first_k_dense and not self.is_latent:
+        if self.first_k_dense and (self.has_ssm or self.has_indexer) \
+                and not self.is_latent:
             raise ValueError(
-                "first_k_dense without kv_lora_rank: feed-forwards of two "
-                "shapes run as layer runs, which the latent-attention "
-                "family's forward carries and no other yet")
+                "first_k_dense beside layer_types or an indexer over keys "
+                "and values: feed-forwards of two shapes run as layer runs, "
+                "which the latent-attention family's forward and the plain "
+                "grouped-query one (models/common.py _runs_forward) carry")
         if not self.experts_held:
             if self.experts_first:
                 raise ValueError("experts_first without experts_held: the "
@@ -587,6 +613,16 @@ class ModelConfig:
         return {"sliding_window": np.asarray(slides, np.int32)
                 * np.int32(self.sliding_window),
                 "rope": np.asarray(rope, np.int32)}
+
+    @property
+    def slides(self) -> Tuple[int, ...]:
+        """One entry a layer, 1 where it slides; () for a model none of
+        whose layers does. What the cache reads to keep a sliding
+        layer's rows apart (cache/paged.py ring_pages)."""
+        if self.sliding_window <= 0:
+            return ()
+        kinds = self.sliding_window_layout[:self.num_layers]
+        return kinds if any(kinds) else ()
 
     @property
     def has_indexer(self) -> bool:
@@ -891,6 +927,45 @@ def jamba2_3b() -> ModelConfig:
     )
 
 
+def trinity_large(layers: int = 60) -> ModelConfig:
+    """Trinity-Large-Preview (huggingface.co/arcee-ai, `afmoe`,
+    400B-A13B): 60 layers of grouped-query attention, 48 queries over 8
+    key-value heads of 128 with a norm on each head's queries and keys
+    and an OUTPUT GATE (sigmoid of a projection of the layer's normed
+    input, before the output projection); of every four layers three
+    slide over 4,096 tokens and rotate, the fourth is full and does not;
+    a norm on both sides of each sublayer; the embedding times
+    sqrt(3,072); layers 0-5 a dense SwiGLU of 12,288, every other layer
+    256 sigmoid-routed experts of 3,072, 4 a token chosen with a
+    selection bias and weighted by their normalised scores times 2.448,
+    plus one shared expert; untied head. `layers`: the first so many of
+    the published sixty."""
+    return ModelConfig(
+        arch="trinity", vocab_size=200192, hidden_size=3072,
+        num_layers=layers, num_heads=48, num_kv_heads=8, head_dim=128,
+        intermediate_size=12288, max_seq_len=262144, norm_eps=1e-5,
+        rope_theta=10000.0, num_experts=256, num_experts_per_tok=4,
+        moe_intermediate_size=3072, shared_intermediate_size=3072,
+        first_k_dense=6, router_score="sigmoid", router_bias=True,
+        routed_scaling_factor=2.448, qk_norm=True, sliding_window=4096,
+        sliding_window_layout=(1, 1, 1, 0) * 15,
+        rope_layout=(1, 1, 1, 0) * 15,
+        sandwich_norm=True, attn_gate=True, mup_embed=True,
+    )
+
+
+def trinity_large_ep8() -> ModelConfig:
+    """ONE chip of an 8-way expert-parallel stage of
+    Trinity-Large-Preview, as servebench/configs/trinity-large-ep8.json
+    cuts it: published layers 5-12 (the last leading dense layer, then
+    seven expert layers: S | S F S S S F S), experts 0-31 of each
+    layer's 256, an eighth of the vocabulary."""
+    kinds = (1, 1, 0, 1, 1, 1, 0, 1)
+    return trinity_large(8).replace(
+        first_k_dense=1, experts_held=32, experts_first=0, vocab_size=25024,
+        sliding_window_layout=kinds, rope_layout=kinds)
+
+
 def tiny(arch: str = "llama", **kw) -> ModelConfig:
     """Small config for tests: runs in <1s on CPU, exercises every code path."""
     base = dict(
@@ -1000,6 +1075,24 @@ def tiny(arch: str = "llama", **kw) -> ModelConfig:
                     qk_rope_head_dim=8, v_head_dim=40, rope_interleave=True,
                     rope_theta=1e6, index_heads=2, index_head_dim=16,
                     index_topk=8)
+    if arch == "trinity":
+        # every mechanism of the real one: six queries a KV head, norms
+        # on heads, the output gate, a norm on both sides of a sublayer,
+        # the scaled embedding, sliding layers that rotate beside full
+        # ones that do not (the published S S S F), a window short
+        # enough to bind, a leading dense layer of its own width,
+        # sigmoid routing with a bias and a scale, a shared expert; all
+        # experts held (a test gives it a share)
+        base.update(num_layers=5, num_heads=12, num_kv_heads=2,
+                    intermediate_size=96, moe_intermediate_size=32,
+                    shared_intermediate_size=32, num_experts=8,
+                    num_experts_per_tok=2, first_k_dense=1,
+                    router_score="sigmoid", router_bias=True,
+                    routed_scaling_factor=2.448, qk_norm=True,
+                    rope_theta=10000.0, sliding_window=8,
+                    sliding_window_layout=(1, 1, 1, 0, 1),
+                    rope_layout=(1, 1, 1, 0, 1), sandwich_norm=True,
+                    attn_gate=True, mup_embed=True)
     base.update(kw)
     return ModelConfig(arch=arch, **base)
 
@@ -1017,6 +1110,8 @@ PRESETS = {
     "glm-5": glm5,
     "olmo-hybrid-7b": olmo_hybrid_7b,
     "jamba2-3b": jamba2_3b,
+    "trinity-large": trinity_large,
+    "trinity-large-ep8": trinity_large_ep8,
 }
 
 

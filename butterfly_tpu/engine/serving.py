@@ -54,7 +54,8 @@ from jax.profiler import TraceAnnotation
 
 from butterfly_tpu.cache.paged import (
     KVWindow, PagedKVCache, flush_paged_window, init_kv_window,
-    init_paged_cache, paged_forward, paged_forward_packed,
+    by_kind_unsupported, init_paged_cache, paged_forward,
+    paged_forward_packed, ring_pages, staged_most,
     paged_forward_window)
 from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
 from butterfly_tpu.core.mesh import mesh_ctx
@@ -62,8 +63,8 @@ from butterfly_tpu.ops import kernel_mode, kernels_default, record_kernels
 from butterfly_tpu.engine.sampling import _filter_logits, speculative_accept
 from butterfly_tpu.cache.ssm_state import SSMState, init_ssm_state
 from butterfly_tpu.models.common import (
-    Model, indexer_unsupported, latent_unsupported, ssm_unsupported,
-    streams_unsupported)
+    Model, gate_unsupported, indexer_unsupported, latent_unsupported,
+    ssm_unsupported, streams_unsupported)
 
 
 #: the span a program launch runs under (`bf.tick.dispatch.launch` in a
@@ -230,6 +231,15 @@ class ServingEngine:
                            ("seq", "the sequence-parallel prefill lane")):
             if mesh is not None and mesh.shape.get(axis, 1) > 1:
                 streams_unsupported(self.cfg, what)
+        # and so does a model whose leaves no mesh lays out yet (an
+        # attention output gate, norms behind the sublayers, feed-forwards
+        # of two shapes outside latent attention)
+        if mesh is not None and mesh.size > 1:
+            gate_unsupported(self.cfg, "a device mesh (" + ", ".join(
+                f"{a}={n}" for a, n in mesh.shape.items() if n > 1) + ")")
+        if self.runtime.speculative_gamma > 0 and self.cfg.first_k_dense:
+            gate_unsupported(self.cfg, "speculative decoding (its verify "
+                                       "is the lane-wide forward)")
         if use_kernels is None:
             # on everywhere but the CPU backend (ops/__init__.py); under
             # a mesh the call sites go through ops/*_sharded (shard_map
@@ -266,8 +276,23 @@ class ServingEngine:
             cache_shardings = to_shardings(paged_cache_specs(
                 self.cfg, mesh, self.runtime.max_batch_size,
                 quant=self.runtime.kv_quant == "int8"), mesh)
+        # a model with sliding layers whose streams outlive the window
+        # keeps those layers' rows in a ring a slot (cache/paged.py
+        # ring_pages; 0: one kind of row, as ever). Prefix reuse shares
+        # pages between streams, and a ring is one stream's own
+        ring = ring_pages(self.cfg, self.runtime,
+                          meshed=mesh is not None and mesh.size > 1)
+        if ring and self.runtime.prefix_caching:
+            raise NotImplementedError(
+                "prefix caching (and the host KV tier behind it) shares "
+                "pages between streams; this model's sliding layers "
+                f"(window {self.cfg.sliding_window}, max_seq "
+                f"{self.runtime.max_seq_len}) keep their rows in a ring of "
+                f"{ring} pages a slot, which is one stream's own "
+                "(cache/paged.py ring_pages): not supported for this model "
+                "at this max_seq")
         self.cache = init_paged_cache(self.cfg, self.runtime,
-                                      shardings=cache_shardings)
+                                      shardings=cache_shardings, ring=ring)
         # a model with recurrent layers (Mamba-2, Gated DeltaNet,
         # Mamba-1): every slot's recurrent state
         # (cache/ssm_state.py), DONATED to each mixed block and rebound
@@ -445,6 +470,12 @@ class ServingEngine:
                 self.flush_kv_window()
             if width < need:
                 width = max(1, self.runtime.inflight_blocks) * need
+                if self.cache.by_kind and width > staged_most(self.runtime):
+                    raise ValueError(
+                        f"a window of {width} staged rows a slot: the "
+                        "sliding layers' ring was sized for "
+                        f"{staged_most(self.runtime)} (cache/paged.py "
+                        "ring_pages)")
                 shardings = self._home
                 if self.mesh is not None:
                     from butterfly_tpu.parallel.partition import (
@@ -726,6 +757,7 @@ class ServingEngine:
         REGISTERED pages (content-immutable — a shared full page is
         never rewritten) may be exported, so in-flight decode blocks
         writing other pages cannot race the bytes."""
+        by_kind_unsupported(self.cache, "KV page export (read_pages)")
         indexer_unsupported(self.cfg, "KV page export (read_pages)")
         ssm_unsupported(self.cfg, "KV page export (read_pages)")
         latent_unsupported(self.cfg, "KV page export (read_pages)")
@@ -750,6 +782,7 @@ class ServingEngine:
         admission attaches them read-only via the prefix registry — so
         no in-flight dispatch can be reading them while this scatter
         runs."""
+        by_kind_unsupported(self.cache, "KV page import (write_pages)")
         indexer_unsupported(self.cfg, "KV page import (write_pages)")
         ssm_unsupported(self.cfg, "KV page import (write_pages)")
         latent_unsupported(self.cfg, "KV page import (write_pages)")
@@ -944,6 +977,9 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
     residual streams' in one more, LAST: the positions mixed
     (cache/paged.py _mixed_rows). A latent-attention model WITH an
     indexer gives the indexer's three means and no sum of rows read.
+    A cache that keeps the sliding layers' rows apart (cache.by_kind)
+    gives two sums: the rows those layers' decode rows read, and what
+    they would have read with no window (cache/paged.py _packed_runs).
     Under cfg.experts_held two sums follow everything else: the block's
     expert assignments that fell on a held expert, and all of them.
     """
@@ -1022,7 +1058,7 @@ def _packed_scan(cfg: ModelConfig, fwd, k: int, C: int, P: int, params,
         if cfg.has_indexer:
             load = jnp.concatenate(
                 [experts, rows[1:] / jnp.maximum(rows[0], 1)])
-        elif cfg.has_ssm or cfg.is_latent or cfg.hc_mult:
+        elif cfg.has_ssm or cfg.is_latent or cfg.hc_mult or cache.by_kind:
             load = jnp.concatenate([experts, rows])
         else:
             load = experts

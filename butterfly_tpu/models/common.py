@@ -534,14 +534,47 @@ def indexer_unsupported(cfg: ModelConfig, what: str) -> None:
 
 
 @jax.named_scope("attn")
-def attn_output(out: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
+def attn_gate(h: jax.Array, p: Params, cfg: ModelConfig
+              ) -> Optional[jax.Array]:
+    """The attention's output gate (cfg.attn_gate): sigmoid(h W_g)
+    [B,T,Nq,H] of the sublayer's normed input h [B,T,D], which
+    attn_output multiplies onto the heads' output before the output
+    projection; None for a model without. Every site that projects
+    queries, keys and values from h takes the gate from the same h."""
+    if not cfg.attn_gate:
+        return None
+    return jax.nn.sigmoid(qeinsum("btd,dnh->btnh", h, p["wg"], h.dtype))
+
+
+def gate_unsupported(cfg: ModelConfig, what: str) -> None:
+    """Refuse a model whose attention has an output gate, a norm on
+    both sides of a sublayer, or leading dense layers before its experts
+    (without latent attention, whose forwards run as layer runs
+    everywhere), on a path with a layer body or a layer scan of its own
+    that carries none of them."""
+    if cfg.attn_gate or cfg.sandwich_norm \
+            or (cfg.first_k_dense and not cfg.is_latent):
+        raise NotImplementedError(
+            f"{what} has a layer body of its own over ONE stack of layers, "
+            "which carries neither an attention output gate (attn_gate) "
+            "nor a norm behind a sublayer (sandwich_norm) nor "
+            "feed-forwards of two shapes (first_k_dense): not supported "
+            "for this model")
+
+
+@jax.named_scope("attn")
+def attn_output(out: jax.Array, p: Params, cfg: ModelConfig,
+                gate: Optional[jax.Array] = None) -> jax.Array:
     """Output projection of the attention sublayer. out: [B,T,Nq,H];
     for a latent model [B,T,Nq,v_head_dim], or the ABSORBED read's o'
     [B,T,Nq,kv_lora_rank], the weighted sum of latents, which each
-    head's value expansion W_uv takes to its v_head_dim first."""
+    head's value expansion W_uv takes to its v_head_dim first. gate:
+    attn_gate's, multiplied on before the projection."""
     if cfg.is_latent and out.shape[-1] == cfg.kv_lora_rank:
         with jax.named_scope("attn_latent_expand"):
             out = qeinsum("btnr,rnh->btnh", out, p["w_uv"], out.dtype)
+    if gate is not None:
+        out = out * gate.astype(out.dtype)
     out = qeinsum("btnh,nhd->btd", out, p["wo"], out.dtype)
     if cfg.use_bias:
         out = out + p["bo"]
@@ -695,6 +728,7 @@ def attention_block(x: jax.Array, p: Params, cfg: ModelConfig,
     """
     rope, sw = layer_pattern_of(pattern)
     q, k, v = qkv_proj(x, p, cfg, cos, sin, rope)
+    gate = attn_gate(x, p, cfg)
     mask = layer_mask(mask, positions, sw)
     if index is not None:
         qi, ki, w = index
@@ -710,7 +744,7 @@ def attention_block(x: jax.Array, p: Params, cfg: ModelConfig,
                 note_kernel("dense_fallback")
         if out is None:
             out = attend(q, k, v, mask, cfg)
-        return attn_output(out, p, cfg), k, v
+        return attn_output(out, p, cfg, gate), k, v
     start = positions[:, 0]  # write offset per sequence
     if k_s is not None:  # int8 cache: write codes + scales
         ck, cv, k_s, v_s = update_cache_layer_q(ck, cv, k_s, v_s, k, v,
@@ -751,8 +785,8 @@ def attention_block(x: jax.Array, p: Params, cfg: ModelConfig,
         out = attend(q, ck, cv, mask, cfg, k_s, v_s)
     tail = () if index is None else (cki,)
     if k_s is not None:
-        return (attn_output(out, p, cfg), ck, cv, k_s, v_s, *tail)
-    return (attn_output(out, p, cfg), ck, cv, *tail)
+        return (attn_output(out, p, cfg, gate), ck, cv, k_s, v_s, *tail)
+    return (attn_output(out, p, cfg, gate), ck, cv, *tail)
 
 
 def mlp_block(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
@@ -1054,7 +1088,9 @@ def stream_read(x: jax.Array, lp: Params, sub: int, cfg: ModelConfig):
     reads of the residual path x, under the sublayer's own pre-norm
     lp["ln<sub>"], and what stream_write takes to put its output back:
     (h [B,T,D], mix). hc_mult 0: (norm(x), None); under cfg.post_norm
-    (x, the norm's leaves): the norm waits for the sublayer's OUTPUT.
+    (x, the norm's leaves): the norm waits for the sublayer's OUTPUT;
+    under cfg.sandwich_norm (norm(x), the leaves of lp["ln<sub>_post"]):
+    a norm before the sublayer and another on its output.
     Else x is [n,B,T,D]
     and mix = (H_res [n,n,R], H_post [n,R]) of lp["hc<sub>"] (the
     equations above)."""
@@ -1062,6 +1098,9 @@ def stream_read(x: jax.Array, lp: Params, sub: int, cfg: ModelConfig):
     if cfg.post_norm:
         # the sublayer reads x as it is; stream_write norms its output
         return x, norm
+    if cfg.sandwich_norm:
+        # a norm on both sides: the second's leaves go to stream_write
+        return pre_norm(x, norm, cfg), lp[f"ln{sub}_post"]
     if not cfg.hc_mult:
         return pre_norm(x, norm, cfg), None
     with jax.named_scope("hc_mix"):
@@ -1103,9 +1142,9 @@ def stream_write(x: jax.Array, y: jax.Array, mix, cfg: ModelConfig
     """A sublayer's output y [B,T,D] onto the residual path x, with
     stream_read's mix. hc_mult 0 (mix None): x + y, the output first
     times a family's residual multiplier (Granite); under cfg.post_norm
-    x + norm(y), mix the norm's leaves. Else X' = H_res @ X
+    or cfg.sandwich_norm x + norm(y), mix the norm's leaves. Else X' = H_res @ X
     + outer(H_post, y) over the n streams, in float32."""
-    if cfg.post_norm:
+    if cfg.post_norm or cfg.sandwich_norm:
         y, mix = pre_norm(y, mix, cfg), None
     if mix is None:
         if cfg.residual_multiplier:
@@ -1177,7 +1216,7 @@ def ssm_unsupported(cfg: ModelConfig, what: str) -> None:
             "not supported for this model")
 
 
-def layer_runs(cfg: ModelConfig):
+def layer_runs(cfg: ModelConfig, by_window: bool = False):
     """The model's layers as runs of one kind, in the published order:
     [(kind, first layer, layers in the run, index of the first among the
     layers of ITS kind)]. Kinds have unlike parameter shapes, so one
@@ -1185,16 +1224,27 @@ def layer_runs(cfg: ModelConfig):
     stack. A model without layer_types is one run of attention. So have
     a dense feed-forward and a layer of experts (cfg.first_k_dense):
     a run ends where the leading dense layers do (ffn_run says which
-    stack a run's feed-forward is in)."""
+    stack a run's feed-forward is in).
+
+    by_window (a cache that keeps the sliding layers' rows apart:
+    cache/paged.py): a run of attention also ends where sliding layers
+    meet full ones (cfg.slides), since the two kinds read unlike pools,
+    and each run comes as (kind, first, n, index among the attention
+    layers, index among the layers of ITS window kind, slides)."""
     kinds = cfg.layer_types or ("attention",) * cfg.num_layers
+    slides = cfg.slides if by_window else ()
     runs, seen = [], dict.fromkeys((*RECURRENT_STACKS, "attention"), 0)
+    of_kind = [0, 0]
     for l, kind in enumerate(kinds):
-        if runs and runs[-1][0] == kind and l != cfg.first_k_dense:
+        slide = bool(slides and slides[l])
+        if runs and runs[-1][0] == kind and l != cfg.first_k_dense \
+                and runs[-1][-1] == slide:
             runs[-1][2] += 1
         else:
-            runs.append([kind, l, 1, seen[kind]])
+            runs.append([kind, l, 1, seen[kind], of_kind[slide], slide])
         seen[kind] += 1
-    return [tuple(r) for r in runs]
+        of_kind[slide] += 1
+    return [tuple(r if by_window else r[:4]) for r in runs]
 
 
 def ffn_run(params: Params, first: int, cfg: ModelConfig):
@@ -1214,10 +1264,16 @@ def run_layer_at(params: Params, ffn, l, cfg: ModelConfig,
                  held: Optional[Params] = None) -> Params:
     """Layer l (traced) of a model whose layers run as runs: what every
     layer has (params["layers"]) beside its run's feed-forward
-    (ffn_run's); held: the experts experts_in_place kept out of that
-    run's stack, laid in whole at the layer's index in it
-    (layer_experts)."""
+    (ffn_run's) and, where the model's layers are unlike
+    (cfg.layer_pattern), its pattern; held: the experts experts_in_place
+    kept out of that run's stack, laid in whole at the layer's index in
+    it (layer_experts)."""
     lp = layer_at(params["layers"], l, cfg)
+    pattern = cfg.layer_pattern()
+    if pattern is not None:
+        # the layer's entry, as layer_stack hands a scan's body its slice
+        lp = {**lp, "pattern": {k: jnp.asarray(v)[l]
+                                for k, v in pattern.items()}}
     if ffn is None:
         return lp
     return layer_experts({**lp, **layer_at(ffn[0], l - ffn[1], cfg)}, held,
@@ -1790,6 +1846,7 @@ def uniform_layers_only(cfg: ModelConfig, what: str) -> None:
         raise NotImplementedError(
             f"{what} runs models whose layers are all alike; this one "
             "has a per-layer attention pattern or routes before attention")
+    gate_unsupported(cfg, what)
 
 
 def layer_pattern_of(pattern: Optional[Params]):
@@ -1816,6 +1873,9 @@ def embed_tokens(params: Params, cfg: ModelConfig, tokens: jax.Array,
     B, T = tokens.shape
     compute_dtype = jnp.dtype(cfg.dtype)
     x = params["embed"]["tok"].astype(compute_dtype)[tokens]
+    if cfg.mup_embed:
+        x = (x.astype(jnp.float32) * cfg.hidden_size ** 0.5
+             ).astype(compute_dtype)
     if cfg.embedding_multiplier:
         x = x * jnp.asarray(cfg.embedding_multiplier, compute_dtype)
     if cfg.hc_mult:
@@ -2004,7 +2064,8 @@ def _decode_layer_body(x, lp, cfg: ModelConfig, cache: KVCache, i,
     q, k, v = qkv_proj(h, lp["attn"], cfg, cos, sin, rope)
     out = decode_attend(q, k, v, ck, cv, start, cfg, k_s, v_s,
                         wk_i, wv_i, wks_i, wvs_i, sliding_window=sw)
-    x = stream_write(x, attn_output(out, lp["attn"], cfg), mix, cfg)
+    x = stream_write(x, attn_output(out, lp["attn"], cfg,
+                                    attn_gate(h, lp["attn"], cfg)), mix, cfg)
     x, _ = ffn_close(x, lp, cfg, route)
     return x, k, v
 
@@ -2397,6 +2458,40 @@ def _latent_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         ck, None, cache.length + T, ki=cki)
 
 
+def _runs_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
+                  cache: KVCache, positions: jax.Array, fresh: bool,
+                  last_index: Optional[jax.Array]
+                  ) -> Tuple[jax.Array, KVCache]:
+    """forward for a grouped-query model whose feed-forwards are of two
+    shapes (cfg.first_k_dense without latent attention): the layers as
+    runs (layer_runs), each a scan that rides the layers' indices and
+    carries the cache; a layer is transformer_layer, its pattern read at
+    the layer's index as layer_stack would hand it."""
+    x, cos, sin = embed_tokens(params, cfg, tokens, positions)
+    mask = make_mask(positions, cache.max_seq)
+
+    def layer(ffn, carry, l):
+        x, ck, cv = carry
+        lp = run_layer_at(params, ffn, l, cfg)
+        x, k, v = transformer_layer(
+            x, lp, cfg, lax.dynamic_index_in_dim(ck, l, 0, keepdims=False),
+            lax.dynamic_index_in_dim(cv, l, 0, keepdims=False), positions,
+            mask, cos, sin, fresh)
+        return (x, lax.dynamic_update_index_in_dim(ck, k, l, 0),
+                lax.dynamic_update_index_in_dim(cv, v, l, 0)), None
+
+    ck, cv = cache.k, cache.v
+    for _, first, n, _ in layer_runs(cfg):
+        (x, ck, cv), _ = lax.scan(
+            partial(layer, ffn_run(params, first, cfg)), (x, ck, cv),
+            first + jnp.arange(n))
+    if last_index is not None:
+        x = jnp.take_along_axis(
+            x, last_index[:, None, None].astype(jnp.int32), axis=1)
+    return final_logits(params, cfg, x), KVCache(ck, cv, cache.length
+                                                 + tokens.shape[1])
+
+
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             cache: KVCache, positions: Optional[jax.Array] = None,
             fresh: bool = False,
@@ -2429,6 +2524,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     if cfg.is_latent:
         return _latent_forward(params, cfg, tokens, cache, positions, fresh,
                                last_index)
+    if cfg.first_k_dense:
+        return _runs_forward(params, cfg, tokens, cache, positions, fresh,
+                             last_index)
     # A model with an indexer takes the general path for every shape:
     # the two fast paths below attend before the cache is written, and
     # neither carries the index keys (the serving path, cache/paged.py,
@@ -2557,10 +2655,18 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             "wv": w(next(keys), La, D, Kv, H),
             "wo": w(next(keys), La, Nq, H, D),
         }
+    if cfg.attn_gate:
+        attn["wg"] = w(next(keys), La, D, Nq, H)
     layers: Params = {
         "ln1": {"scale": jnp.full((L, D), ln, pdt)},
         "ln2": {"scale": jnp.full((L, D), ln, pdt)},
     }
+    if cfg.sandwich_norm:
+        # the norms BEHIND the sublayers, seeded at (2L)^-1/2 as the
+        # family scales them by depth: with ones every sublayer would
+        # add a vector of the stream's own size (stream_seed)
+        for sub in ("ln1_post", "ln2_post"):
+            layers[sub] = {"scale": jnp.full((L, D), (2 * L) ** -0.5, pdt)}
     if not cfg.layer_types:
         layers["attn"] = attn
     if cfg.qk_norm:

@@ -104,6 +104,7 @@ class TickLog:
                latent_load: Optional[Sequence[float]] = None,
                hc_load: Optional[Sequence[float]] = None,
                share_load: Optional[Sequence[float]] = None,
+               kind_load: Optional[Sequence[float]] = None,
                cpu_s: Optional[float] = None,
                off_cpu_by: Optional[Dict[str, float]] = None,
                proc_cpu_s: Optional[float] = None,
@@ -150,6 +151,17 @@ class TickLog:
         mean over the layers that route) and `expert_rows_routed` (all
         of them); `experts_touched`, `expert_rows_max` and
         `expert_rows_mean` then count over the experts HELD.
+        `kind_load` (a cache that keeps the sliding layers' rows in a
+        ring of their own, cache/paged.py ring_pages; null otherwise):
+        [read, whole, slide, full, wraps] as `swa_rows_read` and
+        `swa_rows_whole` (the cached rows the sliding layers' decode
+        rows read in the mixed blocks the tick drained, and what they
+        would have read with no window: SUMS over layers, rows and
+        steps; null in a tick that drained none), `kv_pages_slide` (the
+        ring's pages x the slots that hold a request), `kv_pages_full`
+        (the full layers' pages in use: the pool less `pages_free`) and
+        `ring_wraps` (times a stream's written length passed a whole
+        ring since the last tick).
         The starvation clock (Scheduler._starve): `starved_s`, the
         seconds the device waited for the host before this tick's
         launches, whichever tick the wait began in (0.0 where they
@@ -183,6 +195,8 @@ class TickLog:
         latent_rows, latent_steps = latent_load or (None,) * 2
         hc_rows, hc_steps = hc_load or (None,) * 2
         rows_local, rows_routed = share_load or (None,) * 2
+        swa_read, swa_whole, pages_slide, pages_full, ring_wraps = \
+            kind_load or (None,) * 5
         entry = {
             "seq": self._seq,
             "t_wall": time.time(),
@@ -218,6 +232,11 @@ class TickLog:
             "hc_steps": hc_steps,
             "expert_rows_local": rows_local,
             "expert_rows_routed": rows_routed,
+            "swa_rows_read": swa_read,
+            "swa_rows_whole": swa_whole,
+            "kv_pages_slide": pages_slide,
+            "kv_pages_full": pages_full,
+            "ring_wraps": ring_wraps,
             "starved_s": starved_s,
             "starved_cause": starved_cause,
             "starved_by": dict(starved_by or {}),

@@ -150,9 +150,12 @@ def _update(q, k, v, mask, carry, ks, vs, scale: float):
 
 def _paged_kernel(meta_ref, table_ref, len_ref, *rest, page: int,
                   kv_heads: int, pages_per_chunk: int, quant: bool,
-                  window: int, sliding: bool):
+                  window: int, sliding: bool, ring: bool = False):
     """One grid step is one slot. meta_ref [layer] or, with `sliding`,
-    [layer, sliding_window] (0 = a full layer). The pools k_ref, v_ref
+    [layer, sliding_window] (0 = a full layer); its LAST entry is the
+    layer's index in the window's leaves (the pool's own where the
+    caller gave no other). ring: the table is a ring of its R entries,
+    position p's page in entry (p // page) % R. The pools k_ref, v_ref
     [L, P, Kv, page, H] lie in HBM; sc_ref [P, 2, lanes] is the layer's
     K and V scale rows of an int8 pool, a page's two side by side. The
     slot's live pages, from the page its lower bound falls in to the
@@ -221,7 +224,9 @@ def _paged_kernel(meta_ref, table_ref, len_ref, *rest, page: int,
         _, _, first, npages = span(s)
 
         def one(i, _):
-            pid = table_ref[s, jnp.minimum(first + c * n + i, max_pages - 1)]
+            at = first + c * n + i
+            pid = table_ref[s, at % max_pages if ring
+                            else jnp.minimum(at, max_pages - 1)]
             dmas = [pltpu.make_async_copy(k_ref.at[layer, pid],
                                           kbuf.at[b, i], sem.at[b]),
                     pltpu.make_async_copy(v_ref.at[layer, pid],
@@ -343,7 +348,8 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
                             win_count: jax.Array = None,
                             win_k_scale: jax.Array = None,
                             win_v_scale: jax.Array = None,
-                            sliding_window=None) -> jax.Array:
+                            sliding_window=None, win_layer=None,
+                            ring: bool = False) -> jax.Array:
     """Mesh-aware paged attention for meshed serving (SURVEY.md §7 stage 6).
 
     shard_map (flash_attention.shard_kernel: manual over every mesh
@@ -371,6 +377,9 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
     sliding_window: the layer's window out of its pattern (a traced
     scalar, 0 = a full layer; None = the model has no pattern and the
     kernel compiles without it). It rides beside `layer` to every shard.
+
+    ring, win_layer: paged_attention's (a pool of the sliding layers
+    alone whose table is a ring; the layer's index in the window).
     """
     from jax.sharding import PartitionSpec as P
 
@@ -406,17 +415,19 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
     if sliding_window is not None:
         named.update(sliding_window=(
             jnp.asarray(sliding_window, jnp.int32), P()))
+    if win_layer is not None:
+        named.update(win_layer=(jnp.asarray(win_layer, jnp.int32), P()))
 
     def _kernel(*a):
-        return paged_attention(*a[:6], **dict(zip(named, a[6:])))
+        return paged_attention(*a[:6], ring=ring, **dict(zip(named, a[6:])))
 
-    fn = shard_kernel(_kernel if named else paged_attention,
+    fn = shard_kernel(_kernel if named or ring else paged_attention,
                       in_specs=(*in_specs, *(s for _, s in named.values())),
                       out_specs=P(d, t, None))
     return fn(*args, *(v for v, _ in named.values()))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "ring"))
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     layer, page_table: jax.Array, lengths: jax.Array,
                     k_scale_pages: jax.Array = None,
@@ -426,7 +437,8 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     win_count: jax.Array = None,
                     win_k_scale: jax.Array = None,
                     win_v_scale: jax.Array = None,
-                    sliding_window=None,
+                    sliding_window=None, win_layer=None,
+                    ring: bool = False,
                     interpret: bool | None = None) -> jax.Array:
     """Single-token attention over each slot's paged KV.
 
@@ -459,6 +471,14 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     sliding_window (int32 scalar, may be traced; None = none): the
     slot's one query, at the last of its lengths (+ win_count)
     positions, attends only the last `sliding_window` of them; 0 = all.
+
+    ring (static): the pool holds the SLIDING layers alone and
+    page_table [slots, R] is a ring: position p's row lies in page
+    table[s, (p // page) % R] (cache/paged.py ring_pages: R pages hold
+    the window, what is staged and a page more, so a page is rewritten
+    only when no query can reach its rows). win_layer: the layer's
+    index in the window's leaves where it is not `layer` (the window
+    holds every attention layer, a pool by kind its own).
     """
     S, Nq, H = q.shape
     L, Pp, Kv, page, H2 = k_pages.shape
@@ -473,6 +493,9 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     meta = [jnp.asarray(layer, jnp.int32).reshape(1)]
     if sliding:
         meta.append(jnp.asarray(sliding_window, jnp.int32).reshape(1))
+    if win_layer is not None:
+        meta.append(jnp.asarray(win_layer, jnp.int32).reshape(1))
+    w_at = len(meta) - 1 if win_layer is not None else 0
     prefetch = [jnp.concatenate(meta), page_table, lengths]
     if window:
         prefetch.append(win_count)
@@ -482,7 +505,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 
     def win_map(rank):
         """(layer, slot)'s block of a window leaf of `rank` dims."""
-        return lambda s, meta_ref, *_: (meta_ref[0], s) + (0,) * (rank - 2)
+        return lambda s, meta_ref, *_: (meta_ref[w_at], s) + (0,) * (rank - 2)
 
     n = _pages_per_chunk(Kv, page, H, k_pages.dtype)
     pool = pl.BlockSpec(memory_space=pl.ANY)
@@ -530,7 +553,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                         pltpu.SMEM((1,), jnp.int32)])
     kernel = functools.partial(_paged_kernel, page=page, kv_heads=Kv,
                                pages_per_chunk=n, quant=quant, window=window,
-                               sliding=sliding)
+                               sliding=sliding, ring=ring)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, Nq, H), q.dtype),

@@ -103,7 +103,8 @@ def quantize_int8(params: Params, cfg) -> Params:
     """Quantize every matmul weight of an init_params-shaped tree.
 
     Contraction axes per leaf (leading L = stacked layers):
-      wq/wk/wv [L,D,N,H] -> D;  wo [L,N,H,D] -> (N,H)
+      wq/wk/wv [L,D,N,H] -> D;  wo [L,N,H,D] -> (N,H); the output
+        gate's wg [L,D,N,H] (cfg.attn_gate) as wq
       mlp w_gate/w_up [L,D,F] -> D;  w_down [L,F,D] -> F
       moe w_* [L,E,D,F] / [L,E,F,D] -> the D/F contraction axis
       shared w_* [L,D,F] / [L,F,D] -> as the dense mlp's
@@ -167,7 +168,8 @@ def _contraction_axes(path_names) -> Optional[Tuple[int, ...]]:
     the MoE router)."""
     name = path_names[-1]
     parent = path_names[-2] if len(path_names) > 1 else ""
-    if name in ("wq", "wk", "wv", "w_dq", "w_uq", "w_dkv", "w_uk", "w_uv"):
+    if name in ("wq", "wk", "wv", "wg", "w_dq", "w_uq", "w_dkv", "w_uk",
+                "w_uv"):
         return (1,)
     if name == "wo":
         return (1, 2)
@@ -183,7 +185,7 @@ def _contraction_axes(path_names) -> Optional[Tuple[int, ...]]:
     return None
 
 
-def _leaf_kind(names, stream):
+def _leaf_kind(names, stream, post=1.0):
     """How init_params seeds the leaf at this tree path: a norm's scale
     "ones", a bias "zeros", a weight "normal" (N(0, .02)); of the
     residual streams' mixing (hc1 / hc2), b "normal_1" (N(0, 1)) and
@@ -192,11 +194,14 @@ def _leaf_kind(names, stream):
     models.common.MAMBA1_SEEDS draws them.
     `stream`: models.common.stream_seed's pair, where a model seeds the
     embedding at 1 ("normal_1") and its sublayers' norms at a constant
-    (a kind that is a number is that constant)."""
+    (a kind that is a number is that constant); `post`: the constant of
+    the norms BEHIND the sublayers of a cfg.sandwich_norm model."""
     emb_std, ln = stream
     if list(names) == ["embed", "tok"] and emb_std == 1.0:
         return "normal_1"
     if names[-1] == "scale":
+        if names[-2] in ("ln1_post", "ln2_post"):
+            return post
         return ln if names[-2] in ("ln1", "ln2") and ln != 1.0 else "ones"
     if len(names) > 1 and names[-2].startswith("hc"):
         return {"b": "normal_1", "alpha": "hundredths"}.get(names[-1],
@@ -294,7 +299,7 @@ def init_params_by_leaf(cfg, key: jax.Array, quant: str = "none",
     for (path, sd), k, spec in zip(leaves, keys, specs):
         names = _path_names(path)
         axes = _contraction_axes(names) if quant == "int8" else None
-        kind = _leaf_kind(names, stream)
+        kind = _leaf_kind(names, stream, (2 * cfg.num_layers) ** -0.5)
         sharding, factor = None, (1,) * len(sd.shape)
         if mesh is not None:
             dims = tuple(spec) + (None,) * (len(sd.shape) - len(spec))

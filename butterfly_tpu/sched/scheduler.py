@@ -755,6 +755,44 @@ class Scheduler:
         # blocks it drained
         self._experts_held = engine.cfg.experts_held > 0
         self._tick_share: Optional[List[float]] = None
+        # a cache by kind (cache/paged.py ring_pages: the sliding
+        # layers' rows in a ring a slot, the full layers' under the page
+        # table): the vector holds, before a share's two, the rows the
+        # sliding layers' decode rows read and what they would have read
+        # with no window (sums over the block's steps); the tick record
+        # holds their sums over the blocks it drained beside the pages
+        # held BY KIND and the rings' wraps. The free list and
+        # `pages_free` are the full kind's, as ever: a slot's ring is
+        # its own from admission to release, so admission, growth and
+        # preemption have nothing to learn of it but that it is there
+        self._ring_pages = engine.cache.ring_table.shape[1] \
+            if engine.cache.by_kind else 0
+        self._tick_swa: Optional[List[float]] = None
+        self._ring_wraps: Dict[int, int] = {}
+        self._c_swa_read = reg.counter(
+            "swa_rows_read_total",
+            "Cached rows the sliding layers' decode rows read (a live row "
+            "at position p reads min(p + 1, window) in each such layer); "
+            "stays 0 for a cache of one kind")
+        self._c_swa_whole = reg.counter(
+            "swa_rows_whole_total",
+            "Cached rows the sliding layers' decode rows would have read "
+            "with no window (p + 1 a layer); stays 0 for a cache of one "
+            "kind")
+        self._c_ring_wraps = reg.counter(
+            "kv_ring_wraps_total",
+            "Times a stream's written length passed a whole ring of the "
+            "sliding layers' pages (every entry of it rewritten once "
+            "more); stays 0 for a cache of one kind")
+        self._g_pages_slide = reg.gauge(
+            "kv_pages_slide",
+            "Pages of the sliding layers' pool that slots with a request "
+            "hold: the ring's pages x those slots, whatever their "
+            "contexts; 0 for a cache of one kind")
+        self._g_pages_full = reg.gauge(
+            "kv_pages_full",
+            "Pages of the full layers' pool in use (the page table's): "
+            "every layer's for a cache of one kind")
         self._c_expert_rows_local = reg.counter(
             "expert_rows_local_total",
             "Expert assignments (a step's real rows x experts a token, "
@@ -1249,6 +1287,7 @@ class Scheduler:
         self._tick_latent = None
         self._tick_share = None
         self._tick_hc = None
+        self._tick_swa = None
         blocks0 = self.engine.blocks_launched
         with TraceAnnotation("bf.tick", seq=self.ticklog.next_seq,
                              batch=len(self.running),
@@ -1377,6 +1416,7 @@ class Scheduler:
         load = [float(sum(v[i] for v in loads)) / len(loads)
                 for i in range(len(loads[0]))] if loads else None
         self.ticklog.record(wall, tp, fetch_s=fetch, expert_load=load,
+                            kind_load=self._pages_by_kind(),
                             ssm_load=self._tick_ssm,
                             latent_load=self._tick_latent,
                             share_load=self._tick_share,
@@ -1415,6 +1455,28 @@ class Scheduler:
         if ts is not None and ts.due():
             gauges, rates = self._timeseries_signals()
             ts.sample(gauges, rates=rates, t_wall=time.time())
+
+    def _pages_by_kind(self) -> Optional[List[float]]:
+        """The tick record's `kind_load` for a cache by kind, None for
+        every other: [rows read, rows whole] of the blocks the tick
+        drained (None where it drained none), the pages held by kind
+        and the rings' wraps since the last tick (a slot's written
+        length over the ring's rows, against what it was)."""
+        R = self._ring_pages
+        if not R:
+            return None
+        rows = R * self.engine.cache.page_size
+        held = {r.slot: self._written(r) // rows for r in self._all_live
+                if r.slot is not None}
+        wraps = sum(max(0, n - self._ring_wraps.get(s, 0))
+                    for s, n in held.items())
+        self._ring_wraps = held
+        self._c_ring_wraps.inc(wraps)
+        full = self.alloc.num_pages - self.alloc.free_pages
+        self._g_pages_slide.set(float(R * len(held)))
+        self._g_pages_full.set(float(full))
+        return [*(self._tick_swa or (None, None)), R * len(held), full,
+                wraps]
 
     def _process_figures(self) -> tuple:
         """The process's running figures every tick takes deltas of: the
@@ -2422,6 +2484,14 @@ class Scheduler:
                 hc[1] += len(rows)
                 self._c_hc_rows.inc(float(load[0][-1]))
                 load = [load[0][:-1]]
+            if load and self._ring_pages:
+                # [.., rows read, rows whole] summed over the block's steps
+                swa = self._tick_swa = self._tick_swa or [0.0, 0.0]
+                swa[0] += float(load[0][3])
+                swa[1] += float(load[0][4])
+                self._c_swa_read.inc(float(load[0][3]))
+                self._c_swa_whole.inc(float(load[0][4]))
+                load = [load[0][:3]]
             if load and self._has_ssm:
                 # [.., rows, resets] summed over the block's steps
                 ssm = self._tick_ssm = self._tick_ssm or [0.0, 0.0, 0]

@@ -27,7 +27,8 @@ def build_parser() -> argparse.ArgumentParser:
     def common(sp):
         sp.add_argument("--model", default="tiny",
                         help="preset name (gpt2-124m, llama3-8b, llama3-70b, "
-                             "mixtral-8x7b) or 'tiny'")
+                             "mixtral-8x7b, ...), 'tiny', or 'tiny-<arch>' "
+                             "for a family's toy (tiny-trinity)")
         sp.add_argument("--ckpt", default=None, help="checkpoint path")
         sp.add_argument("--tokenizer", default=None)
         sp.add_argument("--dtype", default=None, help="override compute dtype")
@@ -88,6 +89,12 @@ def build_parser() -> argparse.ArgumentParser:
     s.add_argument("--host", default="0.0.0.0")
     s.add_argument("--max-batch", type=int, default=8)
     s.add_argument("--page-size", type=int, default=16)
+    s.add_argument("--num-pages", type=int, default=0,
+                   help="pages of the KV pool under the page table (0: "
+                        "max-batch x max-seq / page-size, room for every "
+                        "slot at max-seq): a deployment whose streams are "
+                        "mostly shorter than max-seq holds fewer, and a "
+                        "request that finds none left is preempted")
     s.add_argument("--top-k", type=int, default=0,
                    help="serving-wide top-k sampling filter")
     s.add_argument("--top-p", type=float, default=1.0)
@@ -416,10 +423,16 @@ def build_parser() -> argparse.ArgumentParser:
 def resolve_model(args):
     from butterfly_tpu.core.config import PRESETS, tiny
     from butterfly_tpu.models.common import Model
-    if args.model == "tiny":
-        cfg = tiny("llama", dtype="float32", param_dtype="float32")
-    else:
+    if args.model in PRESETS:
         cfg = PRESETS[args.model]()
+    elif args.model == "tiny" or args.model.startswith("tiny-"):
+        # "tiny-<arch>": the toy of a family (core/config.py tiny)
+        cfg = tiny(args.model[5:] or "llama", dtype="float32",
+                   param_dtype="float32")
+    else:
+        raise KeyError(f"no model {args.model!r}: a preset "
+                       f"({', '.join(sorted(PRESETS))}), 'tiny' or "
+                       "'tiny-<arch>'")
     if args.dtype:
         cfg = cfg.replace(dtype=args.dtype)
     if getattr(args, "expert_parallel", 1) > 1 and cfg.routed:

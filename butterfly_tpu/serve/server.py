@@ -32,7 +32,11 @@ zero-egress environment):
   site that wanted a kernel and took the dense path}, "allocator"
   (native | python) and "pool_layout" (head | token: a page of the KV
   pool holds a row a KV head, or, for a model with a sparse-attention
-  indexer, a row a token) and "state" ({kind, layers, layout,
+  indexer, a row a token), "pool_kinds" ({full, slide}: {layers,
+  pages, bytes} of the page table's pool and of the sliding layers'
+  rings, the latter with ring_pages a slot, for a cache that keeps the
+  two kinds apart, cache/paged.py ring_pages; null for a cache of one
+  kind) and "state" ({kind, layers, layout,
   whole_tiles, bytes_per_slot, dtype, bytes}: the recurrent state a slot
   keeps for a model with Mamba-2, Gated DeltaNet or Mamba-1 layers, the
   kind, the layout it is held in, whether that is whole tiles of a TPU's memory
@@ -160,13 +164,14 @@ def runtime_report(sched) -> dict:
     the device as JAX reports it, which page allocator the scheduler
     got and which layout the page pool has (cache/paged.py pool_row).
     Static for the life of the process."""
-    from butterfly_tpu.cache.paged import pool_layout
+    from butterfly_tpu.cache.paged import pool_kinds, pool_layout
     from butterfly_tpu.cache.ssm_state import state_info
     from butterfly_tpu.core.mesh import device_report
     native = type(sched.alloc).__name__ == "NativePageAllocator"
     return {"device": device_report(),
             "allocator": "native" if native else "python",
             "pool_layout": pool_layout(sched.engine.cfg),
+            "pool_kinds": pool_kinds(sched.engine.cache),
             "state": state_info(sched.engine.cfg, sched.engine.num_slots)}
 
 
@@ -694,6 +699,7 @@ def make_handler(state: ServerState):
                                        "memory": device_memory()},
                             "allocator": state.runtime["allocator"],
                             "pool_layout": state.runtime["pool_layout"],
+                            "pool_kinds": state.runtime["pool_kinds"],
                             "state": state.runtime["state"],
                             # programs compiled since the ready line:
                             # each one stalled a tick of live serving
@@ -1371,6 +1377,7 @@ def run_server(args) -> int:
     params = load_params(model, args, mesh)
     rt = RuntimeConfig(max_batch_size=args.max_batch,
                        max_seq_len=args.max_seq, page_size=args.page_size,
+                       num_pages=getattr(args, "num_pages", 0),
                        top_k=args.top_k, top_p=args.top_p,
                        max_queue=args.max_queue,
                        prefix_caching=getattr(args, "prefix_caching", False),
@@ -1470,6 +1477,8 @@ def run_server(args) -> int:
           f"device_kind={dev['kind']!r} devices={dev['count']} "
           f"kernels={engine.kernel_mode} allocator={rep['allocator']} "
           f"pool={rep['pool_layout']}"
+          + ("" if rep["pool_kinds"] is None else " kinds: "
+             + json.dumps(rep["pool_kinds"]))
           + ("" if rep["state"] is None else " state: " + json.dumps(
               {k: rep["state"][k]
                for k in ("kind", "layers", "layout", "whole_tiles",

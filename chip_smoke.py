@@ -360,6 +360,24 @@ def generate_phase(logs: Path) -> None:
         raise Failed(f"generate: ran on platform {d.group(1)!r}")
     check_kernels({"mode": d.group(4), "calls": json.loads(d.group(5))},
                   ["flash"], False, "generate")
+    # the newest family's toy through the same contiguous engine (gated
+    # attention under sandwich norms, sliding layers that rotate beside
+    # full ones that do not, a leading dense layer before sigmoid-routed
+    # experts: core/config.py tiny("trinity")): a forward the TPU's
+    # compiler has to take as the CPU's does
+    log = logs / "generate_trinity.log"
+    cmd = [sys.executable, "-m", "butterfly_tpu.serve.cli", "generate",
+           "--model", "tiny-trinity", "--max-seq", "128", "--max-new", "32",
+           "--prompt", "The quick brown fox"]
+    say(f"generate: {' '.join(cmd[1:])}")
+    rc = run(cmd, log, timeout=600)
+    text = log.read_text(errors="replace")
+    m = re.search(r"\[butterfly\] (\d+) tokens in", text)
+    if rc != 0 or not m or int(m.group(1)) != 32 \
+            or f"platform={PLATFORM}" not in text:
+        raise Failed(f"generate (tiny-trinity): exit code {rc}, no 32 "
+                     f"tokens on {PLATFORM}\n" + tail(log))
+    say("generate: tiny-trinity, 32 tokens")
 
 
 def kernels_phase(logs: Path) -> None:

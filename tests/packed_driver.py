@@ -59,14 +59,16 @@ class Packed:
     every third step, the recurrent state through every step."""
 
     def __init__(self, params, cfg, windowed=True, width=C,
-                 use_kernel=False, rt=RT, idle=0):
+                 use_kernel=False, rt=RT, idle=0, ring=0):
         self.params, self.cfg, self.C = params, cfg, width
         # chunks a step carries BESIDE the one `step` fills, all idle
         # (count 0, slot 0: the scheduler's argmax of nothing), as a
         # step has them when prefill_inline_budget holds several chunks
         self.idle = idle
         self.use_kernel = use_kernel
-        cache = init_paged_cache(cfg, rt)
+        # ring: the pages of a sliding layer's ring a slot, where the
+        # cache keeps those layers' rows apart (cache/paged.py ring_pages)
+        cache = init_paged_cache(cfg, rt, ring=ring)
         S, mp = cache.page_table.shape
         self.cache = cache._replace(page_table=jnp.arange(
             S * mp, dtype=jnp.int32).reshape(S, mp))
@@ -154,7 +156,8 @@ def idle_chunk_run(params, seq, cfg, idle=1, **driver):
     return out, drv
 
 
-def scripted_run(params, tokens, cfg, windowed=True, use_kernel=False):
+def scripted_run(params, tokens, cfg, windowed=True, use_kernel=False,
+                 **driver):
     """Slot 1 takes sequence 1's first 20 tokens in chunks of 6 (the
     last holds 2 and 4 of filler) and decodes to position 30 while slot
     0 takes sequence 0's first 15 (6, 6, 3) and decodes beside it; then
@@ -162,7 +165,8 @@ def scripted_run(params, tokens, cfg, windowed=True, use_kernel=False):
     position 0 while slot 0 decodes on. Slot 2 never holds a stream.
     Returns ([(sequence, position, logits)], the driver, {slot:
     (sequence, tokens it has seen)})."""
-    drv, out = Packed(params, cfg, windowed, use_kernel=use_kernel), []
+    drv, out = Packed(params, cfg, windowed, use_kernel=use_kernel,
+                      **driver), []
     at = {0: 0, 1: 0}                       # positions fed, by slot
     seq = {0: 0, 1: 1}
 

@@ -571,7 +571,7 @@ def cell_blocks(config: dict, hlo_dir=None,
     from jax.sharding import SingleDeviceSharding
 
     from butterfly_tpu.cache.paged import (
-        init_kv_window, init_paged_cache, paged_forward_packed)
+        init_kv_window, init_paged_cache, paged_forward_packed, ring_pages)
     from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
     from butterfly_tpu.engine.serving import _packed_scan
     from butterfly_tpu.quant.int8 import init_params_by_leaf
@@ -582,6 +582,7 @@ def cell_blocks(config: dict, hlo_dir=None,
     rt = RuntimeConfig(max_batch_size=sv["max_batch"],
                        max_seq_len=sv["max_seq"], page_size=sv["page_size"],
                        kv_quant=sv.get("kv_quant", "none"),
+                       num_pages=sv.get("num_pages", 0),
                        decode_steps_per_tick=sv["decode_steps_per_tick"])
     S, k = rt.max_batch_size, rt.decode_steps_per_tick
     C = min(rt.prefill_inline_budget, rt.prefill_chunk)
@@ -597,7 +598,10 @@ def cell_blocks(config: dict, hlo_dir=None,
 
     params = on_chip(jax.eval_shape(lambda: init_params_by_leaf(
         cfg, jax.random.PRNGKey(0), quant=sv.get("quant", "none"))))
-    cache = jax.eval_shape(lambda: init_paged_cache(cfg, rt))
+    # the sliding layers' rows in a ring of their own where the engine
+    # would keep them so (ServingEngine.__init__)
+    cache = jax.eval_shape(lambda: init_paged_cache(
+        cfg, rt, ring=ring_pages(cfg, rt)))
     window = on_chip(jax.eval_shape(
         lambda: init_kv_window(cache, rt.inflight_blocks * k * C)))
     i32 = jnp.int32

@@ -30,6 +30,18 @@ row in four and sets that row's reading (0.015-0.05 where its neighbours
 read 0.006), while a wrong mask or rotation moves every row. The largest
 reading of each group is reported beside it.
 
+A file may state its own: `parity_stream` (the stream's length: a model
+whose sliding layers keep their rows in a RING, cache/paged.py
+ring_pages, states one past two windows and more, so that every entry
+of the ring is rewritten twice; a third group of rows, `after2`, past
+TWO windows, is then read beside the two), `parity_controls` (two of
+FAULTS by name, each of which must read as the clean run BEFORE and
+pass the limit AFTER unless it is `rope_everywhere`: `all_slide`, the
+full layers cut to the window too, beside `window_off`) and
+`parity_tolerance` (the limit, with the readings it was set from in
+`parity_tolerance_why`). Each run has an engine, and so a cache, of its
+own: a fault's ModelConfig decides which layers' rows go where.
+
 The tool reports chip evidence and refuses to run without a TPU; `--toy`
 (the CPU rehearsal of tests/test_smallthinker.py) says so in its output.
 """
@@ -50,7 +62,10 @@ STREAM, SLOTS, MAX_SEQ = 4500, 8, 5120
 #: decode rows behind the prompt
 DECODE = 84
 FAULTS = {"clean": {}, "window_off": {"sliding_window_layout": 0},
-          "rope_everywhere": {"rope_layout": 1}}
+          "rope_everywhere": {"rope_layout": 1},
+          "all_slide": {"sliding_window_layout": 1}}
+#: the controls of a file that names none
+CONTROLS = ("window_off", "rope_everywhere")
 
 
 def _reading(a, b):
@@ -76,19 +91,21 @@ def served_rows(cfg, params, rt, tokens, n_prompt, faults=None, planted=None):
     k, S = rt.decode_steps_per_tick, rt.max_batch_size
     C = min(rt.prefill_inline_budget, rt.prefill_chunk)
     per = rt.max_seq_len // rt.page_size
-    # the engine as the factory of the state: pool, window and weights in
-    # their layout, the kernels' switch
-    eng = ServingEngine(Model(cfg), params, rt)
-    for s in range(S):
-        eng.set_table_row(s, list(range(s * per, (s + 1) * per)))
-    eng._ensure_window(k * C)
-    eng._sync_table()
     flush = jax.jit(flush_paged_window)
     L = cfg.num_layers
     out = {}
-    with eng._mesh_ctx():
-        for fault, layouts in (FAULTS if faults is None else faults).items():
-            fcfg = cfg.replace(**{name: (v,) * L for name, v in layouts.items()})
+    for fault, layouts in ({f: FAULTS[f] for f in ("clean",) + CONTROLS}
+                           if faults is None else faults).items():
+        fcfg = cfg.replace(**{name: (v,) * L for name, v in layouts.items()})
+        # the engine as the factory of the state: pool, window and weights
+        # in their layout, the kernels' switch. One a fault: which layers'
+        # rows lie in a ring is the fault's ModelConfig's to say
+        eng = ServingEngine(Model(fcfg), params, rt)
+        for s in range(S):
+            eng.set_table_row(s, list(range(s * per, (s + 1) * per)))
+        eng._ensure_window(k * C)
+        eng._sync_table()
+        with eng._mesh_ctx():
             # a step of its own for each fault: what is planted is traced
             packed = jax.jit(partial(paged_forward_packed,
                                      use_kernel=eng._use_kernels),
@@ -116,10 +133,11 @@ def served_rows(cfg, params, rt, tokens, n_prompt, faults=None, planted=None):
                 if (i + 1) % k == 0:    # the drain's flush
                     cache, wlen, _ = flush(cache, win, wlen)
             out[fault] = (pos, np.stack(rows))
+        del eng, cache, win
     return out
 
 
-def check(config: dict, toy: bool = False, stream: int = STREAM,
+def check(config: dict, toy: bool = False, stream: int = 0,
           decode: int = DECODE, seed: int = 34) -> dict:
     import jax
     from butterfly_tpu.core.config import ModelConfig, RuntimeConfig
@@ -133,9 +151,13 @@ def check(config: dict, toy: bool = False, stream: int = STREAM,
                          "--toy rehearses on the CPU and says so")
     cfg = ModelConfig(**model_fields(config))
     sv = config["serve"]
+    stream = stream or int(config.get("parity_stream", STREAM))
+    limit = float(config.get("parity_tolerance", LIMIT))
+    controls = tuple(config.get("parity_controls", CONTROLS))
     rt = RuntimeConfig(
         max_batch_size=sv["max_batch"] if toy else SLOTS,
-        max_seq_len=sv["max_seq"] if toy else MAX_SEQ,
+        max_seq_len=sv["max_seq"] if toy
+        else max(MAX_SEQ, -(-(stream + 64) // 512) * 512),
         page_size=sv["page_size"], kv_quant=sv.get("kv_quant", "none"),
         decode_steps_per_tick=sv["decode_steps_per_tick"],
         prefill_inline_budget=sv.get("prefill_inline_budget", 32))
@@ -150,7 +172,8 @@ def check(config: dict, toy: bool = False, stream: int = STREAM,
     tokens = np.random.default_rng(seed).integers(
         1, cfg.vocab_size, stream).astype(np.int32)
     n_prompt = (stream - decode) // C * C
-    served = served_rows(cfg, params, rt, tokens, n_prompt)
+    served = served_rows(cfg, params, rt, tokens, n_prompt,
+                         {f: FAULTS[f] for f in ("clean",) + controls})
     pos = np.asarray(served["clean"][0])
     window = cfg.sliding_window
     # compare where the window is about to bind, and where it has bound
@@ -161,8 +184,11 @@ def check(config: dict, toy: bool = False, stream: int = STREAM,
         tokens, leaf_reader(params, is_quantized_leaf), config,
         rows=pos[keep].tolist()), np.float32)
     after = after[keep]
+    # past TWO windows every entry of a ring has been rewritten twice
+    after2 = pos[keep] >= 2 * window + window // 16
     out = {"device": kind, "evidence": "cpu toy" if toy else "chip",
-           "limit": LIMIT, "stream": int(stream),
+           "limit": limit, "stream": int(stream),
+           "rows_after2": int(after2.sum()), "controls": list(controls),
            "prompt": int(n_prompt), "chunk_width": C,
            "sliding_window": cfg.sliding_window,
            "rows_before": int((~after).sum()), "rows_after": int(after.sum())}
@@ -170,17 +196,26 @@ def check(config: dict, toy: bool = False, stream: int = STREAM,
         read = _reading(got[keep], want)
         out[fault] = {
             f"{group}_{stat}": float(fn(read[sel]))
-            for group, sel in (("before", ~after), ("after", after))
-            for stat, fn in (("max", np.max), ("median", np.median))}
+            for group, sel in (("before", ~after), ("after", after),
+                               ("after2", after2))
+            for stat, fn in (("max", np.max), ("median", np.median))
+            if sel.any()}
         out[fault]["argmax_agree"] = int(
             (got[keep].argmax(-1) == want.argmax(-1)).sum())
         out[fault]["rows"] = [round(float(r), 4) for r in read]
     out["positions"] = pos[keep].tolist()
-    clean, off, rope = (out[f] for f in FAULTS)
-    out["ok"] = bool(
-        max(clean["before_median"], clean["after_median"]) < LIMIT
-        and off["before_median"] < LIMIT < off["after_median"]
-        and min(rope["before_median"], rope["after_median"]) > LIMIT)
+    # the clean run under the limit in every group; a control that
+    # changes the mask alone reads as the clean run before the window
+    # binds and passes the limit behind it; one that rotates the full
+    # layers passes it everywhere
+    ok = all(v < limit for key, v in out["clean"].items()
+             if key.endswith("_median"))
+    for name in controls:
+        c = out[name]
+        ok = ok and (min(c["before_median"], c["after_median"]) > limit
+                     if name == "rope_everywhere"
+                     else c["before_median"] < limit < c["after_median"])
+    out["ok"] = bool(ok)
     return out
 
 
