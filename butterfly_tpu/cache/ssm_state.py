@@ -31,7 +31,17 @@ The interface is narrow on purpose (the scheduler learns nothing of it):
 
 Layout, by kind (`state_shapes`). The conv's tail is [Ls, K-1, S, Dc]
 for all three (slots on the sublanes: a [.., K-1, Dc] minor pair would pad
-K-1 = 3 to a tile of 16 rows in bfloat16, five times the bytes).
+K-1 = 3 to a tile of 16 rows in bfloat16, five times the bytes): K-1
+PLANES [S, Dc] a layer, plane k every slot's input k - (K-1) positions
+back, each in whole tiles. Nobody reads it swapped. The decode rows read
+and write layer m's planes where they lie (`decode_rows_conv`: a live
+row's new plane k is its old plane k + 1, the last one its input, and
+the conv's output is the sum of K planes times K rows of taps, all
+elementwise over planes: models.common.conv_step and an update in
+place a plane at (m, k), XLA's own, kernels on or off), and only a
+CHUNK's own slot is cut out,
+a row of Dc at a time (`_slot_tail`: a few KB), run through
+models.common._causal_conv as [K-1, Dc] and written back by slot.
 Mamba-2: h [Lm, S, Nh, Hd, N], a head's state with N on the lanes.
 Gated DeltaNet: a head's state is [dv, dk] and neither need be a whole
 number of 128 lanes (Olmo-Hybrid: 192 x 96), so g heads' VALUES share a
@@ -78,8 +88,9 @@ in place and a readout that forms it a second time, the state read
 twice), which is also what the kernel is tested against. A chunk's is
 mamba1_scan's scan over its positions, either way. Any kind: the
 chunks read and write their own slots
-FIRST and the decode rows' step follows on the result (a chunk's slot
-is no live decode row, so that step leaves it as it is).
+FIRST, the state's and the tails', and the decode rows' step follows on
+the result (a chunk's slot is no live decode row, so that step leaves
+it as it is).
 """
 from __future__ import annotations
 
@@ -92,7 +103,7 @@ from jax import lax
 
 from butterfly_tpu.core.config import ModelConfig
 from butterfly_tpu.models.common import (
-    RECURRENT_NAMES, ffn_close, gdn_chunk, gdn_conv, gdn_gate_out,
+    RECURRENT_NAMES, conv_step, ffn_close, gdn_chunk, gdn_conv, gdn_gate_out,
     gdn_in_proj, gdn_step, gdn_step_inputs, mamba1_conv, mamba1_gate_out,
     mamba1_in_proj, mamba1_scan, mamba1_step, mamba1_step_inputs, ssm_conv,
     ssm_gate_out, ssm_in_proj, ssm_scan, ssm_skip, ssm_step_inputs,
@@ -224,6 +235,34 @@ def decode_rows_step(h, m, u, dt, mp, cfg: ModelConfig, count,
     h_m = lax.dynamic_index_in_dim(h, m, 0, keepdims=False)
     y, new = ssm_scan(u, dt, mp, cfg, h_m.astype(jnp.float32), count)
     return y, lax.dynamic_update_index_in_dim(h, new.astype(h.dtype), m, 0)
+
+
+def _slot_tail(conv, m, slot):
+    """One slot's tail of layer m, [K-1, Dc], cut out of the carried
+    conv [Ls, K-1, S, Dc] a ROW at a time: a row has one dim that is
+    not 1 and so no layout to disagree about (asked for a slot's
+    [K-1, 1, Dc] in one slice, XLA laid the WHOLE conv out slots-major
+    for that reader's sake, a copy of all of it every layer-step:
+    PERF.md, PR 64)."""
+    Dc = conv.shape[3]
+    return jnp.concatenate(
+        [lax.dynamic_slice(conv, (m, k, slot, 0), (1, 1, 1, Dc)).reshape(1, Dc)
+         for k in range(conv.shape[1])])
+
+
+def decode_rows_conv(conv, m, x, mp, live):
+    """The causal conv of layer m's decode rows, one position, any
+    kind: conv [Ls, K-1, S, Dc] the whole carried tails, x [S, Dc] the
+    rows' inputs, live [S] bool (the row decodes). One pass over layer
+    m's planes WHERE THEY LIE (models.common.conv_step has the
+    arithmetic), each new plane written in place at (m, k) by an update
+    that holds its select. Returns (u [S, 1, Dc] float32, conv)."""
+    planes = lax.dynamic_index_in_dim(conv, m, 0, keepdims=False)
+    u, new = conv_step(planes, x, mp, live)
+    for k, plane in enumerate(new):
+        conv = lax.dynamic_update_slice(
+            conv, plane.astype(conv.dtype)[None, None], (m, k, 0, 0))
+    return u, conv
 
 
 def gdn_heads_of(st: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -365,10 +404,8 @@ def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig,
     S, (P, C) = rows.active.shape[0], rows.chunk_pos.shape
     hin, mix = stream_read(x, lp, 1, cfg)
     xbc, aux = mixer.project(hin, mp, cfg)
-    tails = lax.dynamic_index_in_dim(state.conv, m, 0, keepdims=False)
-    tails = jnp.swapaxes(tails, 0, 1)                  # [slots, K-1, Dc]
     sdt = state.h.dtype
-    h, y_d, y_c = state.h, None, None
+    h, conv, y_d, y_c = state.h, state.conv, None, None
     if P:
         # the chunks FIRST: each reads its own slot of the state as it
         # came and writes it back in place; the decode rows' step then
@@ -379,7 +416,9 @@ def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig,
         # (`fusion.N.remat`: PERF.md, PR 56; with two chunks a step that
         # order's decode rows read 0.13 off the reference on the chip,
         # 5e-7 on the CPU); read from the state as it came while the
-        # step wrote it, the state was copied whole
+        # step wrote it, the state was copied whole. The conv's tail
+        # goes the same way: a chunk's K-1 rows are cut out of layer m's
+        # planes where they lie and written back there
         chunk_count = jnp.sum(rows.ok[S:].reshape(P, C), axis=1)
         fresh = (rows.chunk_pos[:, 0] == 0)[:, None, None]
         one = (1, 1) + h.shape[2:]
@@ -388,7 +427,8 @@ def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig,
         came = jnp.concatenate(
             [lax.dynamic_slice(h, at[p], one)[0] for p in range(P)])
         st0 = jnp.where(fresh.reshape((P,) + (1,) * (came.ndim - 1)), 0, came)
-        tail_c0 = jnp.where(fresh, 0, tails[rows.chunk_slot])
+        tail_c0 = jnp.where(fresh, 0, jnp.stack(
+            [_slot_tail(conv, m, rows.chunk_slot[p]) for p in range(P)]))
         u_c, tail_c = mixer.conv(xbc[S:].reshape(P, C, -1), tail_c0, mp,
                                  chunk_count)
         y_c, st_c = mixer.chunk(
@@ -403,25 +443,18 @@ def advance_packed(x, lp, mp, state: SSMState, m, rows, cfg: ModelConfig,
                 h, jnp.where(rows.chunk_ok[p],
                              st_c[p].astype(sdt)[None, None],
                              lax.dynamic_slice(h, at[p], one)), at[p])
-    tails_new = tails
+            conv = lax.dynamic_update_slice(
+                conv, jnp.where(rows.chunk_ok[p], tail_c[p].astype(sdt),
+                                _slot_tail(conv, m, rows.chunk_slot[p])
+                                )[None, :, None],
+                (m, 0, rows.chunk_slot[p], 0))
     if S:
         # decode rows: slot s is row s; a row that does not decode this
         # step (free, dead, in prefill phase) has count 0 and keeps its
-        # state
-        count = rows.active.astype(jnp.int32)
-        u, tail_d = mixer.conv(xbc[:S], tails, mp, count)
+        # state and its tail
+        u, conv = decode_rows_conv(conv, m, xbc[:S, 0], mp, rows.active)
         y_d, h = mixer.decode(h, m, u, tuple(a[:S] for a in aux), mp, cfg,
-                              count, use_kernel)
-        tails_new = tail_d.astype(sdt)
-    for p in range(P):
-        slot = rows.chunk_slot[p]
-        old_t = lax.dynamic_slice_in_dim(tails_new, slot, 1, axis=0)
-        tails_new = lax.dynamic_update_slice_in_dim(
-            tails_new, jnp.where(rows.chunk_ok[p],
-                                 tail_c[p].astype(sdt)[None], old_t),
-            slot, axis=0)
-    conv = lax.dynamic_update_index_in_dim(
-        state.conv, jnp.swapaxes(tails_new, 0, 1), m, 0)
+                              rows.active.astype(jnp.int32), use_kernel)
     y = jnp.concatenate([y for y in (y_d, y_c) if y is not None])
     x = stream_write(x, mixer.close(y, aux, mp, cfg), mix, cfg)
     x, load = ffn_close(x, lp, cfg, ok=rows.ok[:, None])

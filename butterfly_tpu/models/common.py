@@ -1318,6 +1318,30 @@ def _causal_conv(xbc: jax.Array, tail: jax.Array, mp: Params, count):
 ssm_conv = jax.named_scope("ssm_conv")(_causal_conv)
 
 
+@jax.named_scope("conv_step")
+def conv_step(planes: jax.Array, x: jax.Array, mp: Params, live: jax.Array):
+    """_causal_conv at T == 1 over the tail AS THE STATE HOLDS IT, any
+    recurrent kind: planes [K-1, S, Dc] one layer's tails (plane k the
+    input k - (K-1) positions back, slots down the sublanes:
+    cache/ssm_state.py), x [S, Dc] the rows' inputs, live [S] bool the
+    rows that decode. The same float32 sum, term for term, with x the
+    last plane; a live row's new plane k is the old plane k + 1 (the
+    last one x), any other row keeps its tail. Elementwise over whole
+    [S, Dc] planes: nothing is transposed, joined or gathered. Returns
+    (silu(conv) [S, 1, Dc] float32, the K-1 new planes [S, Dc] in x's
+    dtype, A LIST: stacked into one [K-1, S, Dc] value they were a
+    concatenate whose layout XLA chose slots-major at granite's widths,
+    a relayout of the layer's planes every layer-step; PERF.md, PR 64)."""
+    w = mp["conv_w"].astype(jnp.float32)                     # [K, Dc]
+    K = w.shape[0]
+    full = [planes[k].astype(x.dtype) for k in range(K - 1)] + [x]
+    u = sum(full[k].astype(jnp.float32) * w[k] for k in range(K))
+    if "conv_b" in mp:
+        u = u + mp["conv_b"].astype(jnp.float32)
+    return jax.nn.silu(u)[:, None], [
+        jnp.where(live[:, None], full[k + 1], full[k]) for k in range(K - 1)]
+
+
 def ssm_step_inputs(u: jax.Array, dt: jax.Array, mp: Params,
                     cfg: ModelConfig, count):
     """What the recurrence reads of its positions, formed before any

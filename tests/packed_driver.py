@@ -197,3 +197,57 @@ def scripted_run(params, tokens, cfg, windowed=True, use_kernel=False,
     return out, drv, {s: (seq[s], at[s]) for s in at}
 
 
+def swapped_tails(hlo: str, conv, rows: int, copies=()) -> list:
+    """The instructions of a compiled mixed step's text that hold a
+    value of the conv's tails in any shape but the stored one (PR 64):
+    a slot's tail [S, K-1, Dc] or [S (K-1), Dc], a tail joined with its
+    row's input [S, K, Dc] (what the transpose, the concatenate and the
+    gather of PR 63's decode rows made of a layer's planes), the whole
+    conv [Ls, K-1, S, Dc] or a layer of it laid out slots-major (what
+    XLA made of the new planes STACKED, at granite's widths), or a
+    value of a row a slot and Dc wide that sits a row a tile
+    (`T(1,128)`: PERF.md, PR 53); and of `copies` (tools/chip_kernels.py
+    state_copies of the tails) all but a move between memories (a toy's
+    few layers of tails the compiler may move whole into its fast
+    memory and back, once a step: no cell's tails fit there). conv: the
+    state's tails (a shape will do); rows: the step's rows, S + P C."""
+    import re
+    Ls, K1, S, Dc = conv.shape
+    swapped = re.compile(
+        rf"^\s*(?:ROOT )?%\S+ = \(?\w+\[(?:{S},(?:{K1}|{K1 + 1})|{S * K1}),"
+        rf"{Dc}\]"
+        rf"|= \(?\w+\[(?:{Ls}|1),{K1},{S},{Dc}\]\{{3,1,2,0"
+        rf"|= \(?\w+\[(?:{S}|{rows}),{Dc}\]\{{[^}}]*T\(1,128\)")
+    return [line.strip()[:160] for line in hlo.splitlines()
+            if swapped.search(line)] \
+        + [c for c in copies if not c.startswith(("copy-start", "copy-done"))]
+
+
+def planes_unread(hlo: str, conv, mixers) -> list:
+    """The operations of a compiled mixed step's text whose result
+    carries the conv's planes (the whole conv [Ls, K-1, S, Dc], or
+    layer m's [K-1, S, Dc]) that the cell's reader of its mixers
+    (`mixers`: servebench's compiled *_patterns) does NOT catch under
+    the name servebench/xplane.py gives an operation: the benchmark's
+    shares of the mixers must go on counting the conv. A parameter, a
+    tuple's element, a bitcast, a loop and a move between memories make
+    no operation of a device trace."""
+    import re
+
+    from servebench.xplane import clean
+    Ls, K1, S, Dc = conv.shape
+    made = re.compile(
+        rf"^\s*(?:ROOT )?%\S+ = \(?[^=]*\w+\[(?:{Ls},|1,)?{K1},{S},{Dc}\]"
+        rf"[^=]* (?!parameter|get-tuple-element|bitcast|tuple|while|copy-"
+        rf"|slice-|custom-call\(%slice)[\w-]+\(")
+    fused = set(re.findall(r" fusion\(.*?calls=%([^\s,)]+)", hlo))
+    head = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{\s*$")
+    missed, inside = [], False
+    for line in hlo.splitlines():
+        m = head.match(line)
+        if m:
+            inside = m.group(1) not in fused
+        elif inside and made.match(line) \
+                and not mixers.search(clean(line.strip())):
+            missed.append(line.strip()[:160])
+    return missed

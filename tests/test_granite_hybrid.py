@@ -430,7 +430,13 @@ def test_the_decode_rows_step_compiles_for_the_chip_as_one_pass_in_place(
     chunk reads ITS slot from the kernel's result, so no reader of the
     old buffer is left), and the call's HLO text, which is all a device
     trace knows of it, is caught by the benchmark's reader of the mixers
-    (servebench/ssm_peaks.py) and not by the paged kernel's."""
+    (servebench/ssm_peaks.py) and not by the paged kernel's.
+    Since PR 64 the conv's tails are read and written where they lie:
+    no value of a tail's swapped shape, no conv laid out slots-major, no
+    row-a-tile value a slot and Dc wide (packed_driver.swapped_tails),
+    no copy of the tails, and every operation whose result carries the
+    planes is one the benchmark's reader of the mixers counts
+    (packed_driver.planes_unread)."""
     import json
     import sys
     from pathlib import Path
@@ -494,11 +500,15 @@ def test_the_decode_rows_step_compiles_for_the_chip_as_one_pass_in_place(
     calls = [line.strip() for line in hlo.splitlines()
              if "tpu_custom_call" in line and " custom-call(" in line]
     assert calls and all(c.startswith("%ssm_step") for c in calls)
+    # the conv's tails are read and written where they lie (PR 64)
+    assert packed_driver.swapped_tails(
+        hlo, state.conv, S + P * C, state_copies(hlo, state.conv)) == []
     config = json.loads((root / "servebench" / "configs"
                          / "granite-4.0-h-small.json").read_text())
     mixers = ssm_patterns(config)
     for name in map(clean, calls):  # as xplane.py names an operation
         assert mixers.search(name) and "paged_att" not in name, name
+    assert packed_driver.planes_unread(hlo, state.conv, mixers) == []
 
 
 #: the cells' geometries of ops/paged_attention.py: slots, query heads,

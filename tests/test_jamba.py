@@ -499,7 +499,13 @@ def test_the_decode_rows_step_compiles_for_the_chip_as_one_pass_in_place(
     row a tile (`T(1,128)`: PERF.md, PR 53), and the call's HLO text,
     which is all a device trace knows of it, is caught by the
     benchmark's reader of the mixers (servebench/mamba1_peaks.py) and
-    not by the paged kernel's."""
+    not by the paged kernel's.
+    Since PR 64 the conv's tails are read and written where they lie:
+    no value of a tail's swapped shape, no conv laid out slots-major, no
+    row-a-tile value a slot and Dc wide (packed_driver.swapped_tails),
+    no copy of the tails, and every operation whose result carries the
+    planes is one the benchmark's reader of the mixers counts
+    (packed_driver.planes_unread)."""
     import json
     import re
     import sys
@@ -513,12 +519,14 @@ def test_the_decode_rows_step_compiles_for_the_chip_as_one_pass_in_place(
         from chip_kernels import state_copies
     finally:
         sys.path.remove(str(ROOT / "tools"))
-    # the published pattern's first four layers: mixers all
+    # the published pattern's first seven layers: mixers all (147 MB of
+    # state: four layers' 84 MB the compiler moved whole into its fast
+    # memory and back around every step, which no cell's 545 MB fit)
     cfg = jamba2_3b().replace(
-        num_layers=4, layer_types=jamba2_3b().layer_types[:4],
+        num_layers=7, layer_types=jamba2_3b().layer_types[:7],
         dtype="bfloat16")
     S, P, C, Lm = 128, 1, 32, cfg.num_ssm_layers
-    assert Lm == 4
+    assert Lm == 7
 
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -569,6 +577,9 @@ def test_the_decode_rows_step_compiles_for_the_chip_as_one_pass_in_place(
     calls = [line.strip() for line in hlo.splitlines()
              if "tpu_custom_call" in line and " custom-call(" in line]
     assert calls and all(c.startswith("%mamba1_step") for c in calls)
+    # the conv's tails are read and written where they lie (PR 64)
+    assert packed_driver.swapped_tails(
+        hlo, state.conv, S + P * C, state_copies(hlo, state.conv)) == []
     # a row a tile: the call's own results, and any value of a row a
     # slot and Di or 2 Di wide that XLA forms around it (a weight's one
     # row [1, Di] is held so by right)
@@ -583,6 +594,7 @@ def test_the_decode_rows_step_compiles_for_the_chip_as_one_pass_in_place(
     mixers = mamba1_patterns(config)
     for name in map(clean, calls):  # as xplane.py names an operation
         assert mixers.search(name) and "paged_att" not in name, name
+    assert packed_driver.planes_unread(hlo, state.conv, mixers) == []
 
 
 def test_a_prompt_of_70_as_chunks_of_32_32_6_is_the_loop(params, tokens):

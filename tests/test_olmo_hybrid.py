@@ -474,7 +474,13 @@ def test_the_decode_rows_step_compiles_for_the_chip_as_one_pass_in_place(
     of it, nothing computes its update a second time (`.remat`: PERF.md,
     PR 56), and the call's HLO text, which is all a device trace knows
     of it, is caught by the benchmark's reader of the mixers
-    (servebench/gdn_peaks.py) and not by the paged kernel's."""
+    (servebench/gdn_peaks.py) and not by the paged kernel's.
+    Since PR 64 the conv's tails are read and written where they lie:
+    no value of a tail's swapped shape, no conv laid out slots-major, no
+    row-a-tile value a slot and Dc wide (packed_driver.swapped_tails),
+    no copy of the tails, and every operation whose result carries the
+    planes is one the benchmark's reader of the mixers counts
+    (packed_driver.planes_unread)."""
     import json
     import re
     import sys
@@ -543,11 +549,15 @@ def test_the_decode_rows_step_compiles_for_the_chip_as_one_pass_in_place(
     calls = [line.strip() for line in hlo.splitlines()
              if "tpu_custom_call" in line and " custom-call(" in line]
     assert calls and all(c.startswith("%gdn_step") for c in calls)
+    # the conv's tails are read and written where they lie (PR 64)
+    assert packed_driver.swapped_tails(
+        hlo, state.conv, S + P * C, state_copies(hlo, state.conv)) == []
     config = json.loads((ROOT / "servebench" / "configs"
                          / "olmo-hybrid-7b.json").read_text())
     mixers = gdn_patterns(config)
     for name in map(clean, calls):  # as xplane.py names an operation
         assert mixers.search(name) and "paged_att" not in name, name
+    assert packed_driver.planes_unread(hlo, state.conv, mixers) == []
 
 
 def test_a_prompt_of_70_as_chunks_of_32_32_6_is_the_loop(params, tokens):
